@@ -33,7 +33,7 @@ sys.path.insert(0, os.path.dirname(os.path.abspath(__file__)))
 
 import numpy as np
 
-from bench import _timed_steps, _sync, _peak
+from bench import _timed_steps, _sync, _peak, _mfu_field
 
 
 def bench_bert_longseq(impl, batch=4, seq=2048, n_masks=20):
@@ -86,11 +86,9 @@ def bench_bert_longseq(impl, batch=4, seq=2048, n_masks=20):
             lambda: scope.find_var("word_emb"), n_short=4, n_long=16)
     flops = program_flops(main, batch=1)["total"]
     peak, kind = _peak()
-    mfu = flops / per_step / peak if peak else None
-    if mfu is not None and mfu > 1.0:  # physical sanity (bench.py method)
+    if peak and flops / per_step / peak > 1.0:  # physical sanity (bench.py)
         per_step = per_step_cons
-        mfu = flops / per_step / peak
-    return per_step, mfu, kind
+    return per_step, _mfu_field(flops, per_step, peak), kind
 
 
 def main():
@@ -105,7 +103,7 @@ def main():
         "unit": "steps/sec (batch=4 seq=2048, impl=auto)",
         "vs_baseline": None,
         "step_time_ms": round(dt_auto * 1e3, 2),
-        "mfu": round(mfu, 3) if mfu else None,
+        **mfu,
         "device_kind": kind,
     }), flush=True)
     print(json.dumps({
@@ -119,4 +117,6 @@ def main():
 
 
 if __name__ == "__main__":
+    from paddle_tpu.utils import compile_cache as _compile_cache
+    _compile_cache.arm()
     main()

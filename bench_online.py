@@ -340,4 +340,6 @@ def main(argv=None):
 
 
 if __name__ == "__main__":
+    from paddle_tpu.utils import compile_cache as _compile_cache
+    _compile_cache.arm()
     main()

@@ -88,28 +88,9 @@ def _bf16_roundtrip(x):
     return x.astype(jnp.bfloat16).astype(jnp.float32)
 
 
-def shard_map_nocheck_kwargs(shard_map_fn) -> dict:
-    """The kwargs that disable ``shard_map``'s static replication check
-    under the running jax version (``check_vma`` / ``check_rep`` -- the
-    kwarg has been renamed across releases), or {} when none exists.  One
-    helper so the executor's explicit-dp compile and the bench sweep
-    cannot drift when jax renames it again."""
-    import inspect
-    try:
-        params = inspect.signature(shard_map_fn).parameters
-    except (TypeError, ValueError):
-        return {}
-    if "check_vma" in params:
-        return {"check_vma": False}
-    if "check_rep" in params:
-        return {"check_rep": False}
-    return {}
-
-
 def axis_size(axis_name: str) -> int:
     """Static size of a bound mesh axis (psum of a literal 1 folds to a
-    Python int under tracing -- the jax.lax.axis_size replacement the
-    collective lowerings already use)."""
+    Python int under tracing -- what the collective lowerings use)."""
     import jax
     return int(jax.lax.psum(1, axis_name))
 

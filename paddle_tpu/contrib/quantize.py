@@ -43,11 +43,9 @@ def quantized_mul(ctx, ins):
     Kernel choice: on TPU-supported shapes this lowers to the FUSED Pallas
     kernel (ops/pallas_int8.py: quantize-to-VMEM-once + int8 MXU dot +
     fused rescale; MEASURED v5e 4096^3: 1.04x bf16, vs 0.73x for the
-    unfused XLA path this falls back to on other backends/shapes — CPU/GPU
-    serving stays compiled; tests/test_pallas_int8.py drives the kernel in
+    unfused XLA path taken on other platforms/shapes — CPU/GPU serving
+    stays compiled; tests/test_pallas_int8.py drives the kernel in
     interpret mode directly)."""
-    import jax
-    import jax.numpy as jnp
     from ..ops import pallas_int8
     x, w8, wscale = ins["X"][0], ins["Y"][0], ins["YScale"][0]
     ncol = ctx.attr("x_num_col_dims", 1) or 1
@@ -60,22 +58,32 @@ def quantized_mul(ctx, ins):
     # fused kernel on TPU only; elsewhere the XLA path compiles (interpret
     # mode is a test-only tool — tests/test_pallas_int8.py drives it
     # directly, so CPU/GPU serving keeps compiled speed)
-    if (not ctx.abstract and jax.default_backend() == "tpu"
+    from ..ops import pallas_mode
+    if (not ctx.abstract and pallas_mode.on_tpu()
             and pallas_int8.supports_fused(m, x2.shape[1],
                                            x2.dtype.itemsize)):
         out = pallas_int8.fused_int8_matmul(x2, w8, wscale)
     else:
-        a_scale = jnp.max(jnp.abs(x2.astype(jnp.float32)), axis=1,
-                          keepdims=True) / 127.0
-        a_scale = jnp.maximum(a_scale, 1e-12)
-        xq = jnp.clip(jnp.round(x2.astype(jnp.float32) / a_scale),
-                      -127, 127).astype(jnp.int8)
-        acc = jax.lax.dot_general(
-            xq, w8, (((1,), (0,)), ((), ())),
-            preferred_element_type=jnp.int32)
-        out = (acc.astype(jnp.float32) *
-               (a_scale * wscale[None, :])).astype(x.dtype)
+        out = int8_matmul_xla(x2, w8, wscale)
     return {"Out": [out.reshape(tuple(xshape[:ncol]) + (N,))]}
+
+
+def int8_matmul_xla(x2, w8, wscale):
+    """quantized_mul's unfused XLA formulation ([M,K] float x [K,N] int8 ->
+    [M,N] x2.dtype): the lowering off TPU and outside the fused kernel's
+    shape gate, and the reference chip_smoke.py holds the kernel to."""
+    import jax
+    import jax.numpy as jnp
+    a_scale = jnp.max(jnp.abs(x2.astype(jnp.float32)), axis=1,
+                      keepdims=True) / 127.0
+    a_scale = jnp.maximum(a_scale, 1e-12)
+    xq = jnp.clip(jnp.round(x2.astype(jnp.float32) / a_scale),
+                  -127, 127).astype(jnp.int8)
+    acc = jax.lax.dot_general(
+        xq, w8, (((1,), (0,)), ((), ())),
+        preferred_element_type=jnp.int32)
+    return (acc.astype(jnp.float32) *
+            (a_scale * wscale[None, :])).astype(x2.dtype)
 
 
 @register("dequantize_weight", grad=None,

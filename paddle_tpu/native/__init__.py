@@ -1,14 +1,20 @@
 """Native runtime components, loaded via ctypes (no pybind11 in this stack).
 
 The compute path is JAX/XLA/Pallas; these are the host-runtime pieces the
-reference implements in C++ (data_feed.cc parsing threads). Each component
-compiles on first use with g++ if the prebuilt .so is missing and degrades
-to a documented pure-Python fallback when no toolchain exists.
+reference implements in C++ (data_feed.cc parsing threads). The shared
+object is never committed: it is built from ``fast_parser.cpp`` with g++ on
+first use, and again whenever the source's hash differs from the one
+recorded beside the ``.so`` (a copy of the tree does not keep mtimes, a hash
+survives it). With no g++ on the host the pure-Python parser is used
+(``available()`` is False); a build that was attempted and failed raises
+with the compiler's stderr -- it is never swallowed into the slow path.
 """
 from __future__ import annotations
 
 import ctypes
+import hashlib
 import os
+import shutil
 import subprocess
 import threading
 
@@ -20,34 +26,57 @@ _LIB = None
 _LIB_TRIED = False
 
 
+class NativeBuildError(RuntimeError):
+    """g++ was found and failed to build the native parser."""
+
+
+def _build(src: str, so: str, stamp: str, digest: str) -> None:
+    tmp = f"{so}.tmp.{os.getpid()}"
+    try:
+        proc = subprocess.run(
+            ["g++", "-O3", "-shared", "-fPIC", "-std=c++17", "-pthread",
+             "-o", tmp, src],
+            capture_output=True, text=True, timeout=300)
+        if proc.returncode != 0:
+            raise NativeBuildError(
+                f"g++ failed to build {src} (exit {proc.returncode}):\n"
+                f"{proc.stderr[-4000:]}")
+        os.replace(tmp, so)
+    finally:
+        if os.path.exists(tmp):
+            os.remove(tmp)
+    with open(stamp, "w") as f:
+        f.write(digest)
+
+
 def _load():
     global _LIB, _LIB_TRIED
     with _LOCK:
         if _LIB_TRIED:
             return _LIB
-        _LIB_TRIED = True
         so = os.path.join(_DIR, "libfast_parser.so")
         src = os.path.join(_DIR, "fast_parser.cpp")
-        if not os.path.exists(so) or (os.path.exists(src) and
-                                      os.path.getmtime(src) >
-                                      os.path.getmtime(so)):
-            try:
-                subprocess.run(
-                    ["g++", "-O3", "-shared", "-fPIC", "-std=c++17",
-                     "-pthread", "-o", so, src],
-                    check=True, capture_output=True, timeout=120)
-            except Exception:
-                return None
+        stamp = so + ".srchash"
+        with open(src, "rb") as f:
+            digest = hashlib.sha256(f.read()).hexdigest()
         try:
-            lib = ctypes.CDLL(so)
+            with open(stamp) as f:
+                built_from = f.read().strip()
         except OSError:
-            return None
+            built_from = None
+        if not os.path.exists(so) or built_from != digest:
+            if shutil.which("g++") is None:
+                _LIB_TRIED = True
+                return None     # no toolchain: the Python parser serves
+            _build(src, so, stamp, digest)
+        lib = ctypes.CDLL(so)
         lib.parse_slot_file.restype = ctypes.c_int64
         lib.parse_slot_file.argtypes = [
             ctypes.c_char_p, ctypes.c_int64,
             ctypes.POINTER(ctypes.c_float), ctypes.c_int64,
             ctypes.POINTER(ctypes.c_int64), ctypes.c_int32]
         _LIB = lib
+        _LIB_TRIED = True
         return _LIB
 
 
@@ -59,8 +88,8 @@ def parse_slot_file(path: str, n_slots: int, n_threads: int = 0):
     """Parse a rectangular slot-text file natively.
 
     Returns (rows: int, columns: list of float32 arrays [rows, width_s]) or
-    None when the native library is unavailable (caller falls back to the
-    Python parser).
+    None when the host has no g++ to build the library with (the caller
+    then uses the Python parser).
     """
     lib = _load()
     if lib is None:

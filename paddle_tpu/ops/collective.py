@@ -258,7 +258,7 @@ def collective_permute(ctx, ins):
     if not _axis_bound(name):
         return {"Out": [x]}
     _record("permute", x, name)
-    # static axis size via psum-of-1 (jax.lax.axis_size was removed)
+    # static axis size via psum-of-1
     n = jax.lax.psum(1, name)
     off = ctx.attr("offset", 1)
     perm = [(i, (i + off) % n) for i in range(n)]

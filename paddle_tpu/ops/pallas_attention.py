@@ -16,18 +16,23 @@ Design:
     below; see the measured crossover at the constant), the ring schedule
     under an sp>1 mesh, and the composed jnp lowering otherwise or for
     unsupported shapes. `impl='pallas'` forces the kernel at any supported S
-    (interpret-mode on CPU so tests exercise the same code path).
-  * Whole K/V rows for one (batch, head) are staged in VMEM (S*D*2 bytes each --
-    fits to S~8k); Q is blocked at BLK_Q rows. Softmax is computed in f32 in
-    VMEM. Matmuls hit the MXU with preferred_element_type=f32.
+    and raises off TPU (ops/pallas_mode.py: only the test harness may ask for
+    the Pallas interpreter, so the CPU suite exercises the same kernel body).
+  * Whole K/V rows for one (batch, head) are staged in VMEM (S*D*2 bytes
+    each); Q is blocked at BLK_Q rows. Softmax is computed in f32 in VMEM.
+    Matmuls hit the MXU with preferred_element_type=f32. The backward's
+    [BLK_Q, S] f32 temporaries and whole-row dK/dV blocks bound S: on a v5e
+    (PR 21 chip runs) S=2048 and S=4096 compile, the S=8192 backward is
+    refused (21.2 MB of scoped VMEM against the 16 MB limit); longer rows
+    need the K axis blocked (ROADMAP S5).
   * Backward is a custom-VJP Pallas kernel that *recomputes* the probabilities
     per Q block (flash-style: FLOPs are cheap, HBM is not) and accumulates
-    dK/dV across Q blocks by revisiting the same output block over the
-    sequential TPU grid.
+    dK/dV across Q blocks by revisiting the same output block: grid axis 1
+    is declared "arbitrary" (sequential) for that, axis 0 "parallel".
   * Attention dropout uses the in-kernel PRNG (pltpu.prng_random_bits) seeded
     per (step, batch*head, q-block); the backward kernel reseeds identically so
     the mask matches without storing it. In-kernel PRNG has no interpreter
-    lowering, so dropout>0 uses the Pallas path only on real TPU.
+    lowering, so dropout>0 takes the Pallas path only on a TPU.
 """
 from __future__ import annotations
 
@@ -199,6 +204,17 @@ def _specs(B, H, S, D, has_bias, block_q):
     return qspec, kvspec, in_specs
 
 
+def _compiler_params(interpret):
+    """Grid axis 0 (batch*head) is independent; axis 1 (Q blocks) must run
+    in order -- the backward accumulates dK/dV into a revisited output
+    block. The interpreter takes no Mosaic parameters."""
+    if interpret:
+        return {}
+    _, pltpu = _pl()
+    return {"compiler_params": pltpu.CompilerParams(
+        dimension_semantics=("parallel", "arbitrary"))}
+
+
 import jax as _jax  # custom_vjp must wrap at def time
 
 @functools.partial(_jax.custom_vjp, nondiff_argnums=(5, 6, 7, 8, 9))
@@ -231,6 +247,7 @@ def _flash_fwd_impl(q, k, v, bias, seed, scale, dropout, causal, interpret,
         out_specs=qspec,
         out_shape=jax.ShapeDtypeStruct((BH, S, D), q.dtype),
         interpret=interpret,
+        **_compiler_params(interpret),
     )(*args)
     return out.reshape(B, H, S, D)
 
@@ -268,6 +285,7 @@ def _flash_bwd(scale, dropout, causal, interpret, block_q, res, g):
             jax.ShapeDtypeStruct((BH, S, D), jnp.float32),
         ],
         interpret=interpret,
+        **_compiler_params(interpret),
     )(*args)
     shape = (B, H, S, D)
     import numpy as np
@@ -325,7 +343,8 @@ def fused_attention(ctx, ins):
     dropout = 0.0 if ctx.attr("is_test", False) else ctx.attr("dropout_prob", 0.0)
     causal = bool(ctx.attr("causal", False))
     impl = ctx.attr("impl", "auto")
-    is_tpu = jax.default_backend() == "tpu"
+    from . import pallas_mode
+    is_tpu = pallas_mode.on_tpu()
 
     if ctx.abstract:
         # eval_shape inference: mesh/backend are unknown here, and every impl
@@ -365,13 +384,15 @@ def fused_attention(ctx, ins):
             q, k, v, bias, float(scale), float(dropout), causal, seed, gm)]}
 
     bias_shape = None if bias is None else bias.shape
-    if impl == "pallas" and not supports_pallas(B, H, S, D, bias_shape,
-                                                dropout, is_tpu):
-        raise ValueError(
-            f"fused_attention impl='pallas' requires S % {BLK_Q} == 0, a "
-            f"[B,1,1,S] bias, and (for dropout>0) a real TPU; got S={S}, "
-            f"bias={bias_shape}, dropout={dropout}, backend_tpu={is_tpu}. "
-            f"Use impl='auto' to fall back to the composed lowering.")
+    if impl == "pallas":
+        pallas_mode.require("fused_attention impl='pallas'")
+        if not supports_pallas(B, H, S, D, bias_shape, dropout, is_tpu):
+            raise ValueError(
+                f"fused_attention impl='pallas' requires S % {BLK_Q} == 0, "
+                f"a [B,1,1,S] bias, and (for dropout>0) a TPU; got S={S}, "
+                f"bias={bias_shape}, dropout={dropout}, "
+                f"backend_tpu={is_tpu}. Use impl='auto' to let the op "
+                f"choose the composed lowering.")
     # impl='auto' backend + block sizes are tunable choice points: with a
     # persisted autotune decision (PADDLE_TPU_TUNE=cached/search) the
     # measured winner is used; without one the default reproduces the
@@ -381,14 +402,14 @@ def fused_attention(ctx, ins):
                    "has_bias": bias is not None, "dropout": float(dropout),
                    "causal": causal, "scale": float(scale)}
     use_pallas = impl == "pallas" or (
-        impl == "auto" and
+        impl == "auto" and pallas_mode.available() and
         supports_pallas(B, H, S, D, bias_shape, dropout, is_tpu) and
         _decide("fused_attention.backend", tune_params) == "pallas")
     if use_pallas:
         block_q, _ = _decide("fused_attention.block_sizes", tune_params)
         seed = jax.random.randint(ctx.rng(), (), 0, 2**31 - 1, jnp.int32)
         out = _flash(q, k, v, bias, seed, float(scale), float(dropout), causal,
-                     not is_tpu, block_q)
+                     pallas_mode.interpret(), block_q)
     else:
         out = composed_attention(q, k, v, bias, float(scale), float(dropout),
                                  causal, ctx.rng())

@@ -272,12 +272,14 @@ def conv2d_bn_fused(ctx, ins):
                 "MeanOut": [sg(mean_in)], "VarianceOut": [sg(var_in)],
                 "SavedMean": [sg(mean_in)], "SavedVariance": [sg(inv)]}
 
-    is_tpu = jax.default_backend() == "tpu"
+    from . import pallas_mode
     # Pallas-vs-XLA is a tunable choice point: a persisted autotune decision
     # (PADDLE_TPU_TUNE=cached/search) picks the measured winner per shape
     # bucket; the default keeps the pre-autotuner behavior (Pallas whenever
-    # the shape gate admits it). Abstract (eval_shape) lowering always takes
-    # the XLA formulation -- same shapes/dtypes, no kernel launch.
+    # the shape gate admits it and the kernel can run here -- off TPU it
+    # is not a candidate, ops/pallas_mode.py). Abstract (eval_shape)
+    # lowering always takes the XLA formulation -- same shapes/dtypes, no
+    # kernel launch.
     if ctx.abstract or not supports_fused(M, C, O):
         backend = "xla"
     else:
@@ -288,7 +290,7 @@ def conv2d_bn_fused(ctx, ins):
         dummy = jnp.zeros((C,), jnp.float32)
         y2, s, ss = fused_conv1x1_bn(
             x2, w2, dummy, jnp.ones((C,), jnp.float32), dummy, dummy,
-            eps, False, False, not is_tpu)
+            eps, False, False, pallas_mode.interpret())
         mean = s / M
         var = ss / M - mean * mean
     else:  # 'xla' (and shapes outside the kernel gate): same math via XLA

@@ -6,7 +6,13 @@ the reference-compatible PADDLE_TRAINER_* names). On a real TPU pod each host
 runs one process (the TPU runtime owns all local chips); this launcher exists
 for localhost simulation and CPU-mesh testing::
 
-    python -m paddle_tpu.parallel.launch --nproc 2 train.py --lr 0.1
+    JAX_PLATFORMS=cpu python -m paddle_tpu.parallel.launch --nproc 2 \
+        train.py --lr 0.1
+
+Nothing here partitions a host's chips between its ranks: on a TPU host,
+N > 1 local ranks all open the same chips and the second one dies at
+start-up (libtpu's lockfile error, ~15 s on a v5e, PR 21); the monitor then
+stops the rest and says why.
 """
 from __future__ import annotations
 
@@ -312,6 +318,18 @@ def _launch_once(nproc, script_argv, coordinator, devices_per_proc, log_dir,
                 f"{codes[r]}; terminated {len(terminated)} "
                 f"surviving rank(s). Log tail ({logs[r]}):\n"
                 f"{tail.decode(errors='replace')}\n")
+            if nproc > 1 and b"libtpu" in tail and (
+                    b"lockfile" in tail or b"already in use" in tail):
+                # measured on a v5e host (PR 21): the second rank to open
+                # the TPU dies like this within seconds
+                sys.stderr.write(
+                    "[paddle_tpu.launch] the ranks of one host inherit one "
+                    "environment and all opened the same TPU chips; a chip "
+                    "belongs to one process. On a TPU host run ONE process "
+                    "that drives every local chip as a mesh "
+                    "(CompiledProgram.with_strategy); this launcher's "
+                    "local ranks are for the CPU simulation "
+                    "(JAX_PLATFORMS=cpu, --devices_per_proc N).\n")
             return [p.returncode for p in procs], terminated
         if all(c is not None for c in codes):
             return list(codes), set()

@@ -43,11 +43,8 @@ def pipeline_spmd(stage_fn: Callable, stacked_params: Any, x, mesh,
     """
     import jax
     import jax.numpy as jnp
+    from jax import shard_map
     from jax.sharding import PartitionSpec as P
-    try:
-        from jax import shard_map
-    except ImportError:  # older jax
-        from jax.experimental.shard_map import shard_map
 
     global TRACE_COUNT
     TRACE_COUNT += 1
@@ -133,14 +130,9 @@ def pipeline_spmd(stage_fn: Callable, stacked_params: Any, x, mesh,
     xspec = tree.tree_map(
         lambda _: P(None, mb_axis) if mb_axis else P(), x)
     cspec = tree.tree_map(lambda _: P(), consts) if have_consts else P()
-    try:
-        fn = shard_map(per_device, mesh=mesh,
-                       in_specs=(pspec, xspec, cspec), out_specs=xspec,
-                       check_vma=False)
-    except TypeError:  # pre-0.8 jax spells it check_rep
-        fn = shard_map(per_device, mesh=mesh,
-                       in_specs=(pspec, xspec, cspec), out_specs=xspec,
-                       check_rep=False)
+    fn = shard_map(per_device, mesh=mesh,
+                   in_specs=(pspec, xspec, cspec), out_specs=xspec,
+                   check_vma=False)
     # one flight-recorder span per schedule trace+dispatch: the compiled
     # schedule has no per-tick host visibility, so the span carries the
     # shape (S stages, M microbatches, M+S-1 ticks) instead

@@ -26,14 +26,6 @@ import functools
 TRACE_COUNT = 0
 
 
-def _shard_map():
-    try:
-        from jax import shard_map
-    except ImportError:
-        from jax.experimental.shard_map import shard_map
-    return shard_map
-
-
 def _ring_local(q, k, v, bias, seed, scale, dropout, causal, axis,
                 vary_axes):
     """Local computation: q/k/v [B,H,Sl,D] shards, bias [B,1,1,Sl] shard.
@@ -45,7 +37,6 @@ def _ring_local(q, k, v, bias, seed, scale, dropout, causal, axis,
 
     key = jax.random.PRNGKey(seed[0])
     # static axis size: psum of a Python int folds to size*1 at trace time
-    # (jax.lax.axis_size was removed from current JAX)
     n = jax.lax.psum(1, axis)
     my = jax.lax.axis_index(axis)
     B, H, Sq, D = q.shape
@@ -53,16 +44,7 @@ def _ring_local(q, k, v, bias, seed, scale, dropout, causal, axis,
     def varying(x):
         # scan carries must enter with the same varying-over-mesh-axes type
         # the body produces (jax vma typing for shard_map)
-        try:
-            return jax.lax.pcast(x, vary_axes, to="varying")
-        except AttributeError:
-            pass
-        try:
-            return jax.lax.pvary(x, vary_axes)
-        except AttributeError:
-            # pre-vma jax (< 0.6): no varying-type system, carries need no
-            # cast -- identity is correct
-            return x
+        return jax.lax.pcast(x, vary_axes, to="varying")
 
     m0 = varying(jnp.full((B, H, Sq, 1), -jnp.inf, jnp.float32))
     l0 = varying(jnp.zeros((B, H, Sq, 1), jnp.float32))
@@ -115,6 +97,7 @@ def ring_attention(q, k, v, bias, scale, dropout, causal, seed, mesh,
     ``mesh``; batch rides ``batch_axis`` and heads ``head_axis`` when those
     axes exist and divide the dims, so no resharding is forced on them.
     """
+    import jax
     import jax.numpy as jnp
     from jax.sharding import PartitionSpec as P
 
@@ -136,7 +119,7 @@ def ring_attention(q, k, v, bias, scale, dropout, causal, seed, mesh,
     local = functools.partial(
         _ring_local, scale=scale, dropout=dropout, causal=causal, axis=sp,
         vary_axes=tuple(a for a in (dp, mp, sp) if a is not None))
-    f = _shard_map()(
+    f = jax.shard_map(
         local, mesh=mesh,
         in_specs=(P(dp, mp, sp, None), P(dp, mp, sp, None),
                   P(dp, mp, sp, None), P(dp, None, None, sp), P()),

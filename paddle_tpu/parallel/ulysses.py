@@ -23,14 +23,6 @@ import functools
 TRACE_COUNT = 0
 
 
-def _shard_map():
-    try:
-        from jax import shard_map
-    except ImportError:
-        from jax.experimental.shard_map import shard_map
-    return shard_map
-
-
 def _ulysses_local(q, k, v, bias, seed, scale, dropout, causal, axis):
     """q/k/v: [B, H, Sl, D] sequence shards; bias: [B, 1, 1, Sl] shard."""
     import jax
@@ -67,6 +59,7 @@ def ulysses_attention(q, k, v, bias, scale, dropout, causal, seed, mesh,
                       seq_axis="sp", batch_axis="dp", head_axis="mp"):
     """softmax(QK^T*scale + bias)V, sequence-sharded over ``seq_axis`` via
     head-scatter all-to-all. Requires H divisible by the sp size."""
+    import jax
     import jax.numpy as jnp
     from jax.sharding import PartitionSpec as P
 
@@ -96,7 +89,7 @@ def ulysses_attention(q, k, v, bias, scale, dropout, causal, seed, mesh,
     seed = jnp.asarray(seed, jnp.int32).reshape(1)
     local = functools.partial(_ulysses_local, scale=scale, dropout=dropout,
                               causal=causal, axis=seq_axis)
-    f = _shard_map()(
+    f = jax.shard_map(
         local, mesh=mesh,
         in_specs=(P(dp, mp, seq_axis, None), P(dp, mp, seq_axis, None),
                   P(dp, mp, seq_axis, None), P(dp, None, None, seq_axis),

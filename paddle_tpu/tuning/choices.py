@@ -54,25 +54,14 @@ def pow2_bucket(n: int) -> int:
 
 @functools.lru_cache(maxsize=None)
 def device_kind() -> str:
-    try:
-        import jax
-        return jax.devices()[0].device_kind
-    except Exception:
-        return "unknown"
+    import jax
+    return jax.devices()[0].device_kind
 
 
 @functools.lru_cache(maxsize=None)
 def jax_version() -> str:
-    try:
-        import jax
-        return jax.__version__
-    except Exception:
-        return "unknown"
-
-
-def _is_tpu() -> bool:
     import jax
-    return jax.default_backend() == "tpu"
+    return jax.__version__
 
 
 class TunableChoice:
@@ -191,9 +180,11 @@ class ConvBnBackend(TunableChoice):
                 "n": int(params["n"])}
 
     def candidates(self, params):
+        from ..ops import pallas_mode
         from ..ops.pallas_conv_bn import supports_fused
         out = ["xla"]
-        if supports_fused(params["m"], params["k"], params["n"]):
+        if (pallas_mode.available()
+                and supports_fused(params["m"], params["k"], params["n"])):
             out.append("pallas")
         return out
 
@@ -212,8 +203,11 @@ class ConvBnBackend(TunableChoice):
         x2 = _np_zeros((m, k), params.get("dtype", "float32"))
         w2 = _np_zeros((k, n), params.get("dtype", "float32"))
         if candidate == "pallas":
+            from ..ops import pallas_mode
             from ..ops.pallas_conv_bn import fused_conv1x1_bn_fwd
-            interpret = not _is_tpu()
+            if not pallas_mode.available():
+                return None
+            interpret = pallas_mode.interpret()
 
             def pallas_fn(x2, w2):
                 dummy = jnp.zeros((k,), jnp.float32)
@@ -283,13 +277,15 @@ class FlashBackend(TunableChoice):
         return _attn_bucket(params)
 
     def candidates(self, params):
+        from ..ops import pallas_mode
         from ..ops.pallas_attention import supports_pallas
         bias_shape = ((int(params["b"]), 1, 1, int(params["s"]))
                       if params.get("has_bias") else None)
         out = ["xla"]
-        if supports_pallas(params["b"], params["h"], params["s"], params["d"],
-                           bias_shape, float(params.get("dropout", 0.0)),
-                           _is_tpu()):
+        if pallas_mode.available() and supports_pallas(
+                params["b"], params["h"], params["s"], params["d"],
+                bias_shape, float(params.get("dropout", 0.0)),
+                pallas_mode.on_tpu()):
             out.append("pallas")
         return out
 
@@ -308,8 +304,11 @@ class FlashBackend(TunableChoice):
         dropout = float(params.get("dropout", 0.0))
         causal = bool(params.get("causal"))
         if candidate == "pallas":
+            from ..ops import pallas_mode
             from ..ops.pallas_attention import _flash
-            interpret = not _is_tpu()
+            if not pallas_mode.available():
+                return None
+            interpret = pallas_mode.interpret()
 
             def pallas_fn(q, k, v):
                 return _flash(q, k, v, bias, 0, scale, dropout, causal,
@@ -366,8 +365,11 @@ class FlashBlockSizes(TunableChoice):
         scale = float(params.get("scale") or 1.0 / math.sqrt(int(params["d"])))
         dropout = float(params.get("dropout", 0.0))
         causal = bool(params.get("causal"))
+        from ..ops import pallas_mode
         from ..ops.pallas_attention import _flash
-        interpret = not _is_tpu()
+        if not pallas_mode.available():
+            return None
+        interpret = pallas_mode.interpret()
         bq = int(candidate[0])
 
         def fn(q, k, v):

@@ -28,9 +28,26 @@ _PEAK_BF16 = {
 }
 
 
+def _peak(table: Dict[str, float], what: str,
+          device_kind: str) -> Optional[float]:
+    """Table lookup by ``device_kind``. A TPU that is not in the table is an
+    error naming the kind, never a default and never a silent None: every
+    MFU / roofline number divides by this. Off TPU (``cpu``, GPUs) there is
+    no peak to claim and the answer is None -- callers then publish no
+    utilization at all."""
+    peak = table.get(device_kind)
+    if peak is None and device_kind.startswith("TPU"):
+        raise ValueError(
+            f"no {what} for device_kind {device_kind!r} in "
+            f"paddle_tpu/utils/flops.py (known: {sorted(table)}); add the "
+            f"published figure with its source")
+    return peak
+
+
 def device_peak_flops(device_kind: str) -> Optional[float]:
-    """Peak bf16 FLOP/s for a jax device kind string, or None if unknown."""
-    return _PEAK_BF16.get(device_kind)
+    """Peak bf16 FLOP/s for a jax device kind string; None off TPU, raises
+    for a TPU kind the table does not have."""
+    return _peak(_PEAK_BF16, "peak bf16 FLOP/s", device_kind)
 
 
 # HBM bandwidth peaks, bytes/s per *JAX device* (v2/v3 report per-core
@@ -64,13 +81,15 @@ _PEAK_ICI = {
 
 
 def device_peak_hbm_bw(device_kind: str) -> Optional[float]:
-    """Peak HBM bytes/s for a jax device kind, or None if unknown."""
-    return _PEAK_HBM.get(device_kind)
+    """Peak HBM bytes/s for a jax device kind; None off TPU, raises for a
+    TPU kind the table does not have."""
+    return _peak(_PEAK_HBM, "peak HBM bytes/s", device_kind)
 
 
 def device_peak_ici_bw(device_kind: str) -> Optional[float]:
-    """Peak per-chip ICI egress bytes/s, or None if unknown."""
-    return _PEAK_ICI.get(device_kind)
+    """Peak per-chip ICI egress bytes/s; None off TPU, raises for a TPU
+    kind the table does not have."""
+    return _peak(_PEAK_ICI, "peak ICI bytes/s", device_kind)
 
 
 def bandwidth_sanity(value_gbps: float, device_kind: str, domain: str):
@@ -83,7 +102,8 @@ def bandwidth_sanity(value_gbps: float, device_kind: str, domain: str):
     the physical peak is reported AS the peak with suspect=True so an
     impossible number can never be recorded as a measurement.
     """
-    peak = (_PEAK_HBM if domain == "hbm" else _PEAK_ICI).get(device_kind)
+    peak = (device_peak_hbm_bw if domain == "hbm"
+            else device_peak_ici_bw)(device_kind)
     if peak is None:
         return value_gbps, False, None
     bound = peak / 1e9

@@ -82,10 +82,7 @@ def versions() -> dict:
 
 def device_kind() -> str:
     import jax
-    try:
-        return jax.devices()[0].device_kind
-    except Exception:
-        return "unknown"
+    return jax.devices()[0].device_kind
 
 
 def topology(world_dependent: bool) -> dict:

@@ -1,33 +1,35 @@
 """Tier-A safety probe: is executable (de)serialization safe on this build?
 
-PR 1 found that this jaxlib CPU build's compiled-executable
-(de)serialization intermittently corrupts the glibc heap ("corrupted
-double-linked list" SIGABRT/SIGSEGV, ~50% reproduction on
-tests/test_slim.py with the XLA persistent compilation cache armed).
-A crash like that cannot be caught in-process -- by the time free()
-aborts, the damage happened long ago -- so the verdict is decided by:
+An earlier jaxlib CPU build's compiled-executable (de)serialization
+intermittently corrupted the glibc heap (PR 1). A crash like that cannot
+be caught in-process -- by the time free() aborts, the damage happened
+long ago -- so the verdict is decided by:
 
 1. a **forced verdict** (``PADDLE_TPU_WARMSTORE_PROBE=pass|fail``) for
    tests and the CLI selftest;
-2. a **static denylist** of builds with *known* heap corruption (this
-   CPU jaxlib line, per PR 1 -- re-confirmed by measurement in PR 20:
-   the corruption is probabilistic and workload-dependent, so a small
-   dynamic probe passing proves nothing on a known-bad build);
+2. a **static denylist** of builds with *known* heap corruption
+   (jaxlib <= 0.4.36 on CPU; it cannot match the installed 0.9.0);
 3. a **cached verdict** from a previous dynamic probe, keyed per
    (jax, jaxlib, device_kind) -- one subprocess per build, ever;
 4. the **dynamic probe**: a subprocess running serialize -> deserialize
-   -> execute round-trips plus an XLA persistent-cache compile/reload
-   cycle; any crash or wrong answer fails the verdict without taking
-   the parent down.
+   -> execute round-trips; any crash or wrong answer fails the verdict
+   without taking the parent down.
+
+One process for each chip: the probe child needs a device of its own. A
+process that runs on a TPU already holds the chip, so from there the child
+is never spawned -- the verdict is "not probed", tier A stays off for this
+process, nothing is written to the verdict cache, and the store serves
+tier B. (Until PR 21 the child was spawned anyway and sat out its timeout
+or failed, and that failure was cached as the build's verdict.)
 
 A failing verdict self-disables tier A (the store serves tier-B
-StableHLO re-compiles instead, safe everywhere) with a one-time
-warning, and keeps the suite's JAX persistent compilation cache off
-(tests/conftest.py consults the same verdict).
+StableHLO re-compiles instead, safe everywhere) with a one-time warning.
+JAX's own persistent compilation cache is not this module's business:
+``paddle_tpu.utils.compile_cache`` places it.
 
-Nothing here runs unless the warm store is armed or a caller
-(conftest, CLI) explicitly asks: disarmed processes never import this
-module, never stat a verdict file, never spawn a probe subprocess.
+Nothing here runs unless the warm store is armed or a caller (the CLI)
+explicitly asks: disarmed processes never import this module, never stat
+a verdict file, never spawn a probe subprocess.
 """
 from __future__ import annotations
 
@@ -64,12 +66,11 @@ _warned_tier_a = False
 
 @dataclasses.dataclass(frozen=True)
 class Verdict:
-    """The per-build probe outcome. ``tier_a`` gates both the store's
-    serialized-executable tier and the test suite's JAX persistent
-    compilation cache (same deserialization machinery)."""
+    """The per-build probe outcome. ``tier_a`` gates the store's
+    serialized-executable tier."""
     tier_a: bool
     reason: str
-    source: str          # forced | denylist | cached | subprocess
+    source: str          # forced | denylist | cached | subprocess | unprobed
     jax: str = ""
     jaxlib: str = ""
     device_kind: str = ""
@@ -156,26 +157,31 @@ def run_subprocess_probe(timeout: float = 180.0) -> Verdict:
     (SIGSEGV/SIGABRT), timeout, or missing OK marker fails the build."""
     global SPAWNS
     import subprocess
-    import tempfile
     sig = build_signature()
+    import jax
+    if jax.default_backend() == "tpu":
+        # build_signature() above initialised the backend: this process
+        # holds the chip, and a child that needs it would fail or hang
+        return Verdict(False, "not probed: this process holds the TPU and "
+                              "the probe child needs a device of its own "
+                              "(one process for each chip)",
+                       "unprobed", **sig)
     with _lock:
         SPAWNS += 1
-    with tempfile.TemporaryDirectory(prefix="paddle_tpu_wsprobe_") as td:
-        env = dict(os.environ)
-        env.pop(ENV_FORCE, None)
-        env.pop("PADDLE_TPU_WARMSTORE", None)
-        try:
-            proc = subprocess.run(
-                [sys.executable, "-m", "paddle_tpu.warmstore.probe",
-                 "--child", td],
-                stdout=subprocess.PIPE, stderr=subprocess.STDOUT,
-                timeout=timeout, env=env)
-        except subprocess.TimeoutExpired:
-            return Verdict(False, "probe subprocess timed out",
-                           "subprocess", **sig)
-        except OSError as e:
-            return Verdict(False, f"probe subprocess unlaunchable: {e}",
-                           "subprocess", **sig)
+    env = dict(os.environ)
+    env.pop(ENV_FORCE, None)
+    env.pop("PADDLE_TPU_WARMSTORE", None)
+    try:
+        proc = subprocess.run(
+            [sys.executable, "-m", "paddle_tpu.warmstore.probe", "--child"],
+            stdout=subprocess.PIPE, stderr=subprocess.STDOUT,
+            timeout=timeout, env=env)
+    except subprocess.TimeoutExpired:
+        return Verdict(False, "probe subprocess timed out",
+                       "subprocess", **sig)
+    except OSError as e:
+        return Verdict(False, f"probe subprocess unlaunchable: {e}",
+                       "subprocess", **sig)
     out = (proc.stdout or b"").decode("utf-8", "replace")
     if proc.returncode == 0 and "PROBE-OK" in out:
         return Verdict(True, "serialize/deserialize/execute round-trips "
@@ -211,7 +217,8 @@ def verdict(cache_dir: Optional[str] = None,
         v = _load_cached(cache_dir, sig)
         if v is None:
             v = run_subprocess_probe()
-            _store_cached(cache_dir, sig, v)
+            if v.source == "subprocess":    # "unprobed" is not a verdict
+                _store_cached(cache_dir, sig, v)
     with _lock:
         _mem_cache[ck] = v
     return v
@@ -245,16 +252,12 @@ def reset_for_tests() -> None:
 
 # ---------------------------------------------------------------- child --
 
-def _child_main(workdir: str) -> int:
+def _child_main() -> int:
     """The probe body, run in a throwaway subprocess: round-trip a
-    conv+grad training-step-shaped program through (a) the
-    serialize_executable path tier A uses and (b) an XLA persistent
-    compilation cache in ``workdir`` (the machinery conftest would arm).
-    Any heap corruption kills THIS process, not the trainer."""
+    conv+grad training-step-shaped program through the
+    serialize_executable path tier A uses. Any heap corruption kills THIS
+    process, not the trainer. The child arms no compilation cache."""
     import jax
-    jax.config.update("jax_compilation_cache_dir",
-                      os.path.join(workdir, "xla_cache"))
-    jax.config.update("jax_persistent_cache_min_compile_time_secs", 0.0)
     import jax.numpy as jnp
     import numpy as np
     from jax.experimental import serialize_executable as se
@@ -283,7 +286,7 @@ def _child_main(workdir: str) -> int:
         if not np.isfinite(float(l)):
             print("PROBE-BAD: nonfinite loss after round-trip")
             return 1
-        jax.clear_caches()   # next jit re-reads the persistent cache
+        jax.clear_caches()
     print("PROBE-OK")
     return 0
 
@@ -291,7 +294,7 @@ def _child_main(workdir: str) -> int:
 def main(argv=None) -> int:
     argv = list(sys.argv[1:] if argv is None else argv)
     if argv and argv[0] == "--child":
-        return _child_main(argv[1] if len(argv) > 1 else ".")
+        return _child_main()
     v = verdict()
     print(json.dumps(v.to_dict(), indent=1, sort_keys=True))
     return 0 if v.tier_a else 1

@@ -54,7 +54,6 @@ def main():
     os.environ["JAX_PLATFORMS"] = "cpu"
     os.environ["XLA_FLAGS"] = "--xla_force_host_platform_device_count=4"
     import jax
-    jax.config.update("jax_platforms", "cpu")
 
     sys.path.insert(0, os.path.dirname(os.path.dirname(
         os.path.abspath(__file__))))
@@ -73,10 +72,7 @@ def main():
 
     import jax.numpy as jnp
     from jax.sharding import Mesh, PartitionSpec as P
-    try:
-        from jax import shard_map
-    except ImportError:
-        from jax.experimental.shard_map import shard_map
+    from jax import shard_map
 
     devices = np.array(jax.devices())
     mesh = Mesh(devices, ("dp",))
@@ -93,12 +89,8 @@ def main():
             lambda v: v,
             x)
 
-    try:
-        fn = shard_map(per_device, mesh=mesh, in_specs=P("dp"),
-                       out_specs=P("dp"), check_vma=False)
-    except TypeError:
-        fn = shard_map(per_device, mesh=mesh, in_specs=P("dp"),
-                       out_specs=P("dp"), check_rep=False)
+    fn = shard_map(per_device, mesh=mesh, in_specs=P("dp"),
+                   out_specs=P("dp"), check_vma=False)
 
     x = jnp.arange(len(devices) * 4, dtype=jnp.float32).reshape(-1, 4)
     out = jax.jit(fn)(x)

@@ -50,7 +50,6 @@ def main():
             f"hang@dispatch:seconds={slow_ms / 1e3}:times=0"
 
     import jax
-    jax.config.update("jax_platforms", "cpu")
     sys.path.insert(0, os.path.dirname(os.path.dirname(
         os.path.abspath(__file__))))
     import numpy as np

@@ -27,7 +27,6 @@ def main():
     os.environ["JAX_PLATFORMS"] = "cpu"
     os.environ["XLA_FLAGS"] = "--xla_force_host_platform_device_count=4"
     import jax
-    jax.config.update("jax_platforms", "cpu")
 
     sys.path.insert(0, os.path.dirname(os.path.dirname(
         os.path.abspath(__file__))))

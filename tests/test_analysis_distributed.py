@@ -1144,6 +1144,8 @@ def test_divergent_collective_deadlocks_multirank():
     every rank would mean PT041 cries wolf."""
     port = _free_port()
     env = dict(os.environ)
+    # ranks start on the machine's default platform (see
+    # test_multihost._ranks_would_run_cpu: under tier-1 this test skips)
     env.pop("XLA_FLAGS", None)
     env.pop("JAX_PLATFORMS", None)
     procs = [subprocess.Popen(

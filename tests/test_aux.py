@@ -368,7 +368,7 @@ def test_allreduce_bench_multi_device_branch():
 def test_bandwidth_sanity_and_estimator():
     """VERDICT r4 #2: the bench estimator must never report a physically
     impossible bandwidth. bandwidth_sanity clamps to the chip spec; the
-    differenced estimator survives synthetic relay-jitter timings."""
+    differenced estimator survives synthetic sync-jitter timings."""
     from paddle_tpu.utils import bandwidth_sanity
     from paddle_tpu.utils.benchtime import median_differenced_estimate
 
@@ -377,9 +377,12 @@ def test_bandwidth_sanity_and_estimator():
     assert suspect and val == bound == 819.0
     ok, suspect2, _ = bandwidth_sanity(650.0, "TPU v5 lite", "hbm")
     assert not suspect2 and ok == 650.0
-    # ICI domain + unknown chip passes through unflagged
-    v, s, b = bandwidth_sanity(1e6, "TPU weird", "ici")
+    # off TPU there is no peak to clamp to: passes through unflagged
+    v, s, b = bandwidth_sanity(1e6, "cpu", "ici")
     assert not s and b is None and v == 1e6
+    # a TPU the table does not know is an error naming it, not a default
+    with pytest.raises(ValueError, match="TPU weird"):
+        bandwidth_sanity(1e6, "TPU weird", "ici")
 
     # estimator: true per-call 1 ms, fixed overhead 0.3 s, jitter +-50 ms.
     # With seconds-scale segments the median differenced estimate lands

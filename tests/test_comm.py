@@ -104,14 +104,8 @@ def test_apply_transfer_device_round_trips():
     collectives on a 4-device CPU mesh reproduce the array exactly."""
     import jax.numpy as jnp  # noqa: F401
     from jax.sharding import Mesh, NamedSharding, PartitionSpec as JP
-    try:
-        from jax import shard_map
-    except ImportError:
-        from jax.experimental.shard_map import shard_map
-    import inspect
-    sig = inspect.signature(shard_map).parameters
-    ck = ({"check_vma": False} if "check_vma" in sig else
-          {"check_rep": False} if "check_rep" in sig else {})
+    from jax import shard_map
+    ck = {"check_vma": False}
     mesh = Mesh(np.array(jax.devices()[:4]), ("dp",))
     x = np.arange(48 * 8, dtype=np.float32).reshape(48, 8)
     S = reshard.ShardSpec

@@ -815,10 +815,7 @@ def test_collective_prod_is_product():
     import jax
     import jax.numpy as jnp
     from jax.sharding import Mesh, PartitionSpec as P
-    try:
-        from jax import shard_map
-    except ImportError:
-        from jax.experimental.shard_map import shard_map
+    from jax import shard_map
 
     devs = np.array(jax.devices()[:8])
     mesh = Mesh(devs, ("dp",))
