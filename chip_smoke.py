@@ -17,8 +17,10 @@ there), at the full width of BERT-base, and checks what comes out.
 It exits non-zero, printing no result line, when JAX finds no TPU; it never
 selects a platform itself. Any phase failure propagates: there is no
 try/except around a phase. The times it prints are observations stamped
-with the device, not metrics. The last line of stdout is one JSON object:
-``{"ok": true, "device": {"platform": "tpu", "kind": ..., "count": ...}, ...}``.
+with the device, not metrics. The line before last is ``summary: {...}``
+(versions, compile-cache state, per-phase wall / compile seconds); the last
+line of stdout is the verdict alone, one JSON object with exactly these keys:
+``{"ok": true, "device": {"platform": "tpu", "kind": "...", "count": 1}}``.
 """
 from __future__ import annotations
 
@@ -615,7 +617,7 @@ def main(argv=None) -> int:
     import jaxlib
     dev = jax.devices()[0]
     device = {"platform": dev.platform, "kind": dev.device_kind,
-              "count": jax.device_count()}
+              "count": len(jax.devices())}
     rehearsal = args.cpu_rehearsal
     if rehearsal:
         _LABEL = f"[cpu-rehearsal on {dev.platform}, not a chip run] "
@@ -672,8 +674,7 @@ def main(argv=None) -> int:
             f"persistent-cache hits {h1 - h0} misses {m1 - m0}")
         for k, v in facts.items():
             say(f"  {k}: {v}")
-    result = {
-        "ok": not rehearsal, "device": device,
+    summary = {
         "versions": {"jax": jax.__version__, "jaxlib": jaxlib.__version__,
                      "libtpu": libtpu_version},
         "compile_cache": {"dir": cache_dir,
@@ -686,8 +687,11 @@ def main(argv=None) -> int:
         "phases": phases,
     }
     if "mesh" not in phases:
-        result["phases"]["mesh"] = "not run (not asked for)"
-    say(json.dumps(result))
+        summary["phases"]["mesh"] = "not run (not asked for)"
+    say(f"summary: {json.dumps(summary)}")
+    # The verdict line: these keys and no others. Every phase that ran
+    # passed, or its exception ended the process above.
+    say(json.dumps({"ok": not rehearsal, "device": device}))
     return 3 if rehearsal else 0
 
 

@@ -44,6 +44,25 @@ def test_chip_smoke_alone_in_a_directory_fails(tmp_path):
     assert "{" not in r.stdout
 
 
+def test_verdict_line_has_the_contract_keys_and_no_others():
+    """The driver parses the LAST stdout line: exactly {ok, device{platform,
+    kind, count}}. Everything else the run learned goes on the summary line
+    before it. Seen here through the rehearsal, which labels each line."""
+    import json
+    r = _run_smoke(["--cpu-rehearsal", "--phases", "dataset"])
+    assert r.returncode == 3, r.stderr[-800:]
+    label = "[cpu-rehearsal on cpu, not a chip run] "
+    lines = r.stdout.strip().splitlines()
+    assert all(ln.startswith(label) for ln in lines)
+    verdict = json.loads(lines[-1][len(label):])
+    assert set(verdict) == {"ok", "device"} and verdict["ok"] is False
+    assert set(verdict["device"]) == {"platform", "kind", "count"}
+    assert verdict["device"] == {"platform": "cpu", "kind": "cpu", "count": 1}
+    summary = lines[-2][len(label):]
+    assert summary.startswith("summary: ")
+    assert json.loads(summary[len("summary: "):])["phases"]["dataset"]["ok"]
+
+
 def test_compile_cache_helper_places_it_once(monkeypatch):
     import jax
     from paddle_tpu.utils import compile_cache
