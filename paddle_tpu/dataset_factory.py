@@ -24,6 +24,8 @@ from typing import Callable, List, Optional
 
 import numpy as np
 
+from .observability import timeline as _timeline
+
 BAD_SAMPLE_POLICIES = ("raise", "quarantine")
 MISSING_FILE_POLICIES = ("raise", "skip")
 
@@ -295,29 +297,44 @@ class DatasetBase:
             if not os.path.exists(path):
                 if self._missing_file(path):
                     continue
-            native = self._read_native(path)
-            if native is not None and not samples:
+            parsed, columnar = self._parse_file(path)
+            if columnar and not samples:
                 if col_parts is None:
-                    col_parts = [[] for _ in native]
-                for parts, c in zip(col_parts, native):
+                    col_parts = [[] for _ in parsed]
+                for parts, c in zip(col_parts, parsed):
                     parts.append(c)
                 continue
-            if native is not None:      # mixed native/python files: demote
-                samples.extend(zip(*[list(c) for c in native]))
+            if columnar:                # mixed native/python files: demote
+                samples.extend(zip(*[list(c) for c in parsed]))
                 continue
             if col_parts is not None:   # demote earlier columnar reads
                 cols = [np.concatenate(p) for p in col_parts]
                 samples.extend(zip(*[list(c) for c in cols]))
                 col_parts = None
+            samples.extend(parsed)
+        if col_parts is not None and not samples:
+            return [np.concatenate(p) for p in col_parts]
+        return samples
+
+    def _parse_file(self, path):
+        """One file read, under one ``parse_file`` span: ``(columns, True)``
+        from the native parser where the file qualifies, else ``(rows,
+        False)`` from the Python line parser under the bad-sample policy."""
+        with _timeline.phase("parse_file", cat="dataset",
+                             bytes=os.path.getsize(path)):
+            native = self._read_native(path)
+            if native is not None:
+                _timeline.annotate(rows=int(native[0].shape[0]), native=True)
+                return native, True
+            rows = []
             with open(path) as f:
                 for ln, line in enumerate(f, 1):
                     if line.strip():
                         s = self._parse_guarded(line, where=f"{path}:{ln}")
                         if s is not None:
-                            samples.append(s)
-        if col_parts is not None and not samples:
-            return [np.concatenate(p) for p in col_parts]
-        return samples
+                            rows.append(s)
+            _timeline.annotate(rows=len(rows), native=False)
+            return rows, False
 
     def _read_native(self, path):
         """Multithreaded C++ slot parser (native/fast_parser.cpp, the
@@ -506,19 +523,7 @@ class QueueDataset(DatasetBase):
             if not os.path.exists(path):
                 if self._missing_file(path):
                     continue
-            native = self._read_native(path)
-            if native is not None:
-                cols, columnar = native, True
-            else:
-                rows = []
-                with open(path) as f:
-                    for ln, line in enumerate(f, 1):
-                        if line.strip():
-                            s = self._parse_guarded(
-                                line, where=f"{path}:{ln}")
-                            if s is not None:
-                                rows.append(s)
-                cols, columnar = rows, False
+            cols, columnar = self._parse_file(path)
             if columnar_mode is None:
                 columnar_mode = columnar
             elif columnar_mode != columnar:

@@ -118,7 +118,8 @@ def snapshot(reason: str, error: Optional[BaseException] = None,
                 "spans": [{"name": n, "cat": c, "t0": t0, "dur": dur,
                            "args": args, "tid": tid}
                           for (n, c, t0, dur, args, tid)
-                          in _timeline.spans()[-SPAN_TAIL:]],
+                          in (s[:6] for s in
+                              _timeline.spans()[-SPAN_TAIL:])],
                 "counters": _timeline.counters()}),
             ("metrics", _export.to_dict),
             ("alerts", _alerts_doc),

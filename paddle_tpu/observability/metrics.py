@@ -170,6 +170,10 @@ class MetricsRegistry:
     def __init__(self):
         self._lock = threading.Lock()
         self._families: Dict[str, _Family] = {}
+        #: bumped whenever a child may have been dropped: whoever keeps a
+        #: child's handle (the flight recorder does, per phase) compares
+        #: this before using it, so it never feeds an orphan
+        self.generation = 0
 
     def _family(self, name: str, kind: str, help: str,
                 buckets=None) -> _Family:
@@ -212,6 +216,7 @@ class MetricsRegistry:
             return False
         key = tuple(sorted((str(k), str(v)) for k, v in labels.items()))
         with fam._lock:
+            self.generation += 1
             return fam.children.pop(key, None) is not None
 
     def collect(self) -> List[_Family]:
@@ -224,6 +229,7 @@ class MetricsRegistry:
     def reset(self):
         """Drop all families (tests / bench isolation)."""
         with self._lock:
+            self.generation += 1
             self._families.clear()
 
 

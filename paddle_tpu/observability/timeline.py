@@ -1,37 +1,64 @@
-"""Flight-recorder timeline: per-step phase spans -> one Chrome trace.
+"""Flight-recorder timeline: a tree of phase spans, on the profiler's clock.
 
 The reference framework answered "where did step N's time go" with
 platform/profiler RecordEvent push/pop plus tools/timeline.py (Chrome
-trace).  Here every hot path (Executor.run feed-prep/dispatch/fetch,
-train_from_dataset batch waits, Predictor.run, the GPipe schedule trace)
-records ``phase(...)`` spans into a bounded in-process ring -- an append is
-two ``perf_counter`` calls and a deque push, cheap enough to stay always
-on, like the journal ring.  Nothing is written to disk until
-``export_chrome_trace`` is called (``bench.py --emit-trace``), so with
-``PADDLE_TPU_OBS`` unset the hot path still performs zero file I/O.
+trace).  Here every hot path (Executor.run and its feed-prep / h2d /
+dispatch / fetch, the compile path, train_from_dataset and its prefetch
+worker, the dataset's file parse, Predictor.run, the GPipe schedule trace)
+records ``phase(...)`` spans into ONE bounded in-process ring.
 
-The exporter unifies three sources onto one trace-event-format timeline
-(all clocked by ``time.perf_counter``, so spans interleave correctly):
+A span is a :class:`Span`: besides name, category, start and duration on
+``time.perf_counter`` it carries an ``id`` and the id of the span that was
+open on the same thread when it began (``parent``, 0 for a root), so a
+reader gets self time (a span's duration minus its children's) without
+guessing from overlaps.  ``step=`` is the identifier one step's spans
+share.
+
+``phase()`` also opens ``jax.profiler.TraceAnnotation("paddle_tpu.<cat>.
+<name>")``.  That is the shared clock: in any profiler capture, whoever
+started it, the program's phases lie on the host plane beside the device
+lines, and a device gap can be read against the phase that was open.  The
+annotation carries the span's id (``span_id``), so an export from a
+capture finds the ring's entry again.  The ring keeps ``perf_counter``
+seconds; a capture's timestamps count from its own start, and one paired
+event fixes the offset between the two (measured on the CPU, jax 0.9.0:
+five annotations over 240 ms disagreed by 4.3 us).
+
+Cost, always on: a ``phase()`` is two ``perf_counter`` calls, an idle
+``TraceAnnotation``, a thread-local stack push/pop, a lock'd deque append
+and one histogram observe through a handle cached per (name, category) --
+no import and no label lookup per span: about 4.5 us on the v5e machine's
+host, about 5 us under a capture, where it was 6.2 before the handle was
+cached (PERF.md, PR 23, has the readings).  Nothing is written to disk
+until ``export_chrome_trace`` is called (``bench.py --emit-trace``), so
+with ``PADDLE_TPU_OBS`` unset the hot path still performs zero file I/O.
+
+The exporter writes one trace-event-format timeline from
 
 - flight-recorder phase spans (this module's ring),
 - legacy ``profiler.record_event`` host spans (``profiler._agg.spans``),
 - counter samples (device-memory telemetry from ``observability.memory``)
-  as Chrome counter ("C") tracks,
+  as Chrome counter ("C") tracks.
 
-and can additionally splice in the XLA xplane capture that
-``profiler.export_chrome_tracing`` decompresses, giving device op events
-next to the host phases.  Load the output in chrome://tracing or
-https://ui.perfetto.dev.
+Given the ``trace_dir`` of a finished profiler capture it starts from the
+capture instead: phases and RecordEvent spans are already in it as
+annotations, so only the counter tracks are spliced in, and each phase
+gets the args and parent link the ring holds for its ``span_id``.  Load
+the output in chrome://tracing or https://ui.perfetto.dev.
 """
 from __future__ import annotations
 
 import collections
-import contextlib
+import itertools
 import json
 import os
 import threading
 import time
-from typing import Dict, List, Optional
+from typing import Dict, List, NamedTuple, Optional, Tuple
+
+from jax.profiler import TraceAnnotation
+
+from .metrics import REGISTRY
 
 # pids for the synthesized process tracks; chosen above the xplane capture's
 # pid range and distinct from profiler._host_span_events' 90000 default
@@ -39,55 +66,157 @@ PID_PHASES = 90001
 PID_COUNTERS = 90002
 
 _SPAN_CAP = 65536
+
+
+class Span(NamedTuple):
+    """One ring entry.  The first six fields are the historical tuple
+    (readers index them); ``id`` / ``parent`` were appended for the tree."""
+    name: str
+    cat: str
+    t0: float               # time.perf_counter seconds
+    dur: float
+    args: Optional[dict]
+    tid: int                # threading.get_ident() of the recording thread
+    id: int
+    parent: int             # id of the enclosing span on that thread, 0: root
+
+
 _lock = threading.Lock()
-# (name, category, t0_seconds, dur_seconds, args or None)
 _spans: "collections.deque" = collections.deque(maxlen=_SPAN_CAP)
+_ids = itertools.count(1)
 # (track_name, t_seconds, {series: value})
 _counters: "collections.deque" = collections.deque(maxlen=_SPAN_CAP)
-# [earliest span start, latest span end] over the executor/dataset
-# categories, for the WHOLE process -- the ring above is bounded (~13k
-# steps), so anything deriving a run window from ring contents alone
-# (the goodput ledger) would silently shrink its wall-clock once the
-# ring wraps while the cumulative phase_seconds sums keep growing
+# [earliest span start, latest span end] over WINDOW_PHASES, for the WHOLE
+# process -- the ring above is bounded (~13k steps), so anything deriving a
+# run window from ring contents alone (the goodput ledger) would silently
+# shrink its wall-clock once the ring wraps while the cumulative
+# phase_seconds sums keep growing
 _window = [None, None]
+#: the phases whose extent is that window: the ones the goodput ledger gives
+#: a cause (``goodput._PHASE_CAUSE``; a test holds the two equal) and their
+#: container ``megastep``.  The tree's other containers and the prefetch
+#: worker's spans stay out: ``run`` opens before ``feed_prep``, and
+#: ``parse_file`` also runs in ``load_into_memory()``, long before training;
+#: either would stretch the wall the ledger divides by.
+WINDOW_PHASES = frozenset(
+    [(n, "executor") for n in ("feed_prep", "dispatch", "fetch_sync",
+                               "journal", "compile", "warm_restore",
+                               "verify", "megastep")]
+    + [("feed_wait", "dataset")])
 
 
-@contextlib.contextmanager
-def phase(name: str, cat: str = "executor", **args):
-    """Record one flight-recorder span around the body.
+class _Open(threading.local):
+    """The phases open on this thread, outermost first."""
 
-    Also mirrors the duration into the ``phase_seconds`` histogram (labels
-    phase=name) so obs_report can summarize phases without a trace export.
-    """
-    t0 = time.perf_counter()
-    try:
-        yield
-    finally:
-        record_span(name, t0, time.perf_counter() - t0, cat=cat, **args)
+    def __init__(self):
+        self.stack: List["phase"] = []
 
 
-def record_span(name: str, t0: float, dur: float, cat: str = "executor",
-                **args):
-    """Append an already-timed span (t0 from time.perf_counter); mirrors
-    into the ``phase_seconds`` histogram.  Labeled by phase AND category:
-    executor and Predictor both record dispatch/feed_prep/fetch_sync and
-    their durations differ by orders of magnitude -- one merged series
-    would describe neither workload."""
+_open = _Open()
+# (name, cat) -> (annotation name, phase_seconds child, in WINDOW_PHASES),
+# valid for one generation of the registry: reset() / remove_labeled()
+# start another, or a span after a reset would feed a histogram nobody can
+# read
+_handles: Dict[Tuple[str, str], tuple] = {}
+_handles_generation = -1
+
+
+def _handle(name: str, cat: str) -> tuple:
+    global _handles_generation
+    if REGISTRY.generation != _handles_generation:
+        _handles.clear()
+        _handles_generation = REGISTRY.generation
+    h = _handles.get((name, cat))
+    if h is None:
+        # labeled by phase AND category: executor and Predictor both record
+        # dispatch/feed_prep/fetch_sync and their durations differ by orders
+        # of magnitude -- one merged series would describe neither workload
+        h = _handles[(name, cat)] = (
+            f"paddle_tpu.{cat}.{name}",
+            REGISTRY.histogram("phase_seconds",
+                               "flight-recorder phase durations by phase "
+                               "and category", phase=name, cat=cat),
+            (name, cat) in WINDOW_PHASES)
+    return h
+
+
+def _record(name, cat, t0, dur, args, span_id, parent, hist, in_window):
     with _lock:
-        # recording thread rides along: concurrent Predictor.run spans must
-        # land on separate trace tracks, not garble one tid-0 line
-        _spans.append((name, cat, t0, dur, args or None,
-                       threading.get_ident()))
-        if cat in ("executor", "dataset"):
+        # recording thread rides along: concurrent spans must land on
+        # separate trace tracks, not garble one tid-0 line
+        _spans.append(Span._make((name, cat, t0, dur, args or None,
+                                  threading.get_ident(), span_id, parent)))
+        if in_window:
             if _window[0] is None or t0 < _window[0]:
                 _window[0] = t0
             end = t0 + max(dur, 0.0)
             if _window[1] is None or end > _window[1]:
                 _window[1] = end
-    from .metrics import REGISTRY
-    REGISTRY.histogram("phase_seconds",
-                       "flight-recorder phase durations by phase and "
-                       "category", phase=name, cat=cat).observe(dur)
+    hist.observe(dur)
+
+
+class phase:
+    """``with phase(name, cat, **args):`` records one flight-recorder span
+    around the body, child of the phase open on this thread, and opens the
+    ``paddle_tpu.<cat>.<name>`` profiler annotation for its duration.  The
+    annotation carries the span's id (``span_id``), by which an export from
+    a capture finds the ring's entry again (args, parent).
+
+    Never hold one open across a ``yield``: the stack is per thread, and a
+    span left on it while another frame runs adopts children that are not
+    its own.  Wrap the call, not the loop body."""
+
+    __slots__ = ("name", "cat", "args", "id", "_t0", "_h", "_note")
+
+    def __init__(self, name: str, cat: str = "executor", **args):
+        self.name, self.cat, self.args = name, cat, args
+
+    def __enter__(self):
+        self._h = _handle(self.name, self.cat)
+        self.id = next(_ids)
+        self._note = TraceAnnotation(self._h[0], span_id=self.id)
+        self._note.__enter__()
+        _open.stack.append(self)
+        self._t0 = time.perf_counter()
+
+    def __exit__(self, *exc):
+        dur = time.perf_counter() - self._t0
+        stack = _open.stack
+        stack.pop()
+        self._note.__exit__(*exc)
+        h = self._h
+        if h is not None:               # None: discard()ed
+            _record(self.name, self.cat, self._t0, dur, self.args, self.id,
+                    stack[-1].id if stack else 0, h[1], h[2])
+        return False
+
+
+def annotate(**args):
+    """Add ``args`` to the innermost phase open on this thread (what is
+    known only once the work is under way: a step index, bytes moved)."""
+    stack = _open.stack
+    if stack:
+        stack[-1].args.update(args)
+
+
+def discard():
+    """Let the innermost phase open on this thread end without a span: the
+    work turned out not to be what the phase names (a warm-store consult
+    that missed, a compile that raised), and a reader that sums the phase
+    must not count it."""
+    stack = _open.stack
+    if stack:
+        stack[-1]._h = None
+
+
+def record_span(name: str, t0: float, dur: float, cat: str = "executor",
+                **args):
+    """Append an already-timed span (t0 from time.perf_counter), child of
+    the phase open on this thread now; mirrors into ``phase_seconds``."""
+    stack = _open.stack
+    h = _handle(name, cat)
+    _record(name, cat, t0, dur, args, next(_ids),
+            stack[-1].id if stack else 0, h[1], h[2])
 
 
 def counter_sample(track: str, values: Dict[str, float],
@@ -115,9 +244,9 @@ def counters(track: Optional[str] = None) -> List[tuple]:
 
 
 def span_window():
-    """(earliest start, latest end) perf_counter pair over every
-    executor/dataset span this process EVER recorded -- survives ring
-    wrap, unlike reading the ring.  (None, None) before the first span."""
+    """(earliest start, latest end) perf_counter pair over every span of
+    ``WINDOW_PHASES`` this process EVER recorded -- survives ring wrap,
+    unlike reading the ring.  (None, None) before the first such span."""
     with _lock:
         return (_window[0], _window[1])
 
@@ -129,8 +258,10 @@ def clear():
         _window[0] = _window[1] = None
 
 
-def _trace_events(host_pid: int = PID_PHASES) -> List[dict]:
-    """The ring contents as trace-event dicts (ts/dur in microseconds).
+def _trace_events(host_pid: int = PID_PHASES,
+                  phases: bool = True) -> List[dict]:
+    """The ring contents as trace-event dicts (ts/dur in microseconds);
+    ``phases=False`` leaves the spans out and keeps the counter tracks.
 
     Under a multi-rank job the process tracks are rank-tagged, so
     per-rank exports merged by ``profiler.merge_chrome_traces`` keep
@@ -139,14 +270,16 @@ def _trace_events(host_pid: int = PID_PHASES) -> List[dict]:
     r = current_rank()
     tag = "" if r is None else f" [rank {r}]"
     events: List[dict] = [
-        {"ph": "M", "pid": host_pid, "name": "process_name",
-         "args": {"name": f"paddle_tpu flight recorder (phases){tag}"}},
         {"ph": "M", "pid": PID_COUNTERS, "name": "process_name",
          "args": {"name": f"paddle_tpu telemetry (counters){tag}"}},
     ]
     with _lock:
-        span_list = list(_spans)
+        span_list = list(_spans) if phases else []
         counter_list = list(_counters)
+    if phases:
+        events.insert(0, {
+            "ph": "M", "pid": host_pid, "name": "process_name",
+            "args": {"name": f"paddle_tpu flight recorder (phases){tag}"}})
     tid_map = {t: i for i, t in enumerate(
         sorted({s[5] for s in span_list if len(s) > 5}))}
     for s in span_list:
@@ -157,6 +290,9 @@ def _trace_events(host_pid: int = PID_PHASES) -> List[dict]:
               "cat": cat, "ts": max(t0, 0.0) * 1e6, "dur": max(dur, 0.0) * 1e6}
         if args:
             ev["args"] = args
+        if len(s) > 7:
+            # the tree, for readers of the file (obs_report's self time)
+            ev["span_id"], ev["parent_id"] = s[6], s[7]
         events.append(ev)
     for track, t, values in counter_list:
         events.append({"ph": "C", "pid": PID_COUNTERS, "name": track,
@@ -166,7 +302,8 @@ def _trace_events(host_pid: int = PID_PHASES) -> List[dict]:
 
 def _shift_onto_xplane(perf_events: List[dict], xplane_events: List[dict],
                        trace_dir: Optional[str] = None) -> List[dict]:
-    """Re-clock perf_counter-domain events onto the xplane trace's epoch.
+    """Re-clock perf_counter-domain events (the counter tracks; phases ride
+    the capture itself) onto the xplane trace's epoch.
 
     The two sources tick different clocks: our spans carry raw
     ``time.perf_counter()*1e6`` (epoch ~system boot) while the xplane
@@ -211,14 +348,14 @@ def export_chrome_trace(output_path: str = "timeline.json",
                         include_profiler: bool = True) -> str:
     """Write the unified Chrome-trace/Perfetto JSON timeline.
 
-    Merges the flight-recorder phase spans and counter tracks with the
-    legacy profiler RecordEvent spans (same perf_counter clock -> same
-    timeline), plus -- when ``trace_dir`` points at a finished
-    ``profiler(trace_dir=...)`` capture -- the XLA xplane chrome trace's
-    device events.  Returns ``output_path``.
+    Host-only (``trace_dir=None``): the flight-recorder phase spans and
+    counter tracks with the legacy profiler RecordEvent spans (same
+    perf_counter clock -> same timeline).  With the ``trace_dir`` of a
+    finished capture: the capture's own chrome trace -- device events,
+    and the phases and RecordEvent spans that rode it as annotations --
+    plus the counter tracks.  Returns ``output_path``.
     """
     from .. import profiler as _profiler
-    events = _trace_events()
     src = (_profiler._find_xplane_chrome_trace(trace_dir)
            if trace_dir is not None else None)
     if trace_dir is not None and src is None:
@@ -231,10 +368,8 @@ def export_chrome_trace(output_path: str = "timeline.json",
             f"the capture stopped, or trace_dir=None for a host-only "
             f"timeline")
     if src is not None:
-        # RecordEvent spans are NOT synthesized here: they already ride the
-        # xplane capture via TraceAnnotation -- re-synthesizing would
-        # double-count every span in obs_report.
-        return splice_into_xplane(src, events, trace_dir, output_path)
+        return splice_into_xplane(src, trace_dir, output_path)
+    events = _trace_events()
     if include_profiler:
         host = _profiler._host_span_events()
         # skip the metadata record when there are no spans behind it
@@ -248,22 +383,34 @@ def export_chrome_trace(output_path: str = "timeline.json",
     return output_path
 
 
-def splice_into_xplane(src: str, perf_events: List[dict],
-                       trace_dir: Optional[str], output_path: str) -> str:
-    """Merge perf_counter-domain events into the xplane chrome trace at
-    ``src`` (gzip JSON): re-clock them onto the capture's epoch, keep the
-    xplane file's own top-level keys (displayTimeUnit, metadata), sort,
-    write.  The single splice implementation behind both
+def splice_into_xplane(src: str, trace_dir: Optional[str],
+                       output_path: str) -> str:
+    """Write the xplane chrome trace at ``src`` (gzip JSON) with this
+    module's counter tracks re-clocked onto the capture's epoch; the file's
+    own top-level keys (displayTimeUnit, metadata) are kept.  Phases and
+    RecordEvent spans are NOT synthesized: both ride the capture as
+    ``TraceAnnotation`` s, and a second copy would double-count every span
+    in obs_report.  A phase's annotation carries its ``span_id``; where the
+    ring still holds that span, the event gets what the host-only export
+    gives it: the span's args and its ``span_id`` / ``parent_id``.  The
+    single splice implementation behind both
     ``export_chrome_trace(trace_dir=...)`` and
     ``profiler.export_chrome_tracing``."""
     import gzip
     with gzip.open(src, "rt") as f:
         trace = json.load(f)
-    trace.setdefault("traceEvents", [])
-    # the two sources tick different clocks -- re-anchor ours onto the
-    # xplane epoch before they share a file
-    trace["traceEvents"].extend(
-        _shift_onto_xplane(perf_events, trace["traceEvents"], trace_dir))
+    # the profiler closes its event list with an empty object, which is no
+    # trace event (validate_trace, rightly, refuses one)
+    trace["traceEvents"] = [e for e in trace.get("traceEvents", []) if e]
+    ring = {s.id: s for s in spans()}
+    for e in trace["traceEvents"]:
+        if e.get("name", "").startswith("paddle_tpu."):
+            s = ring.get(int((e.get("args") or {}).get("span_id", 0)))
+            if s is not None:
+                e["args"] = dict(s.args or {})
+                e["span_id"], e["parent_id"] = s.id, s.parent
+    trace["traceEvents"].extend(_shift_onto_xplane(
+        _trace_events(phases=False), trace["traceEvents"], trace_dir))
     trace["traceEvents"].sort(key=lambda e: (e.get("ph") != "M",
                                              e.get("ts", 0.0)))
     with open(output_path, "w") as f:

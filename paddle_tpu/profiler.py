@@ -202,11 +202,10 @@ def export_chrome_tracing(trace_dir: Optional[str] = None,
             f"timeline")
     from .observability import timeline as _obs_timeline
     if src is not None:
-        # the flight recorder's executor phase spans + counter tracks ride
-        # along on their own pids (RecordEvent spans already appear in the
+        # the flight recorder's counter tracks ride along on their own pid
+        # (its phases, like the RecordEvent spans, already appear in the
         # xplane capture via TraceAnnotation -- not re-synthesized here)
-        return _obs_timeline.splice_into_xplane(
-            src, _obs_timeline._trace_events(), trace_dir, output_path)
+        return _obs_timeline.splice_into_xplane(src, trace_dir, output_path)
     if not _agg.spans and not _obs_timeline.spans():
         raise ValueError(
             "nothing to export: pass the trace_dir used with "

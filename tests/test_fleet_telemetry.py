@@ -340,6 +340,11 @@ def test_metrics_endpoint_roundtrip_and_stability(obs_env, monkeypatch):
 def test_healthz_reflects_watchdog_state(obs_env, monkeypatch):
     monkeypatch.setenv("PADDLE_TPU_OBS_PORT", "0")
     monkeypatch.setenv("PADDLE_TPU_OBS_HEALTH", "warn")
+    # the verdict reads a process-wide counter: forget what earlier tests
+    # of this worker tripped, or the outcome depends on the schedule
+    fam = REGISTRY.get("tensor_nonfinite_total")
+    for labels, _ in (fam.items() if fam is not None else ()):
+        REGISTRY.remove_labeled("tensor_nonfinite_total", **dict(labels))
     srv = server.start()
     assert srv is not None
     doc = json.load(urllib.request.urlopen(srv.url + "/healthz"))

@@ -133,6 +133,380 @@ def test_train_from_dataset_records_feed_wait_spans(tmp_path):
     assert timeline.spans("feed_wait"), "prefetch consumer recorded no waits"
 
 
+# --------------------------------------------------------------- span tree --
+
+def _children(spans, parent):
+    return [s for s in spans if s.parent == parent.id]
+
+
+def _only(spans, name, cat="executor"):
+    got = [s for s in spans if s.name == name and s.cat == cat]
+    assert len(got) == 1, (name, [s.name for s in spans])
+    return got[0]
+
+
+def _self_time(spans, span):
+    return span.dur - sum(c.dur for c in _children(spans, span))
+
+
+def test_span_tuple_keeps_its_first_six_fields():
+    """Readers index the ring's tuples (``benchmark/probe.py`` [0] [2] [3];
+    goodput, blackbox and the exporter the first six): the tree's fields
+    come after them."""
+    assert timeline.Span._fields == ("name", "cat", "t0", "dur", "args",
+                                     "tid", "id", "parent")
+    timeline.clear()
+    import threading
+    with timeline.phase("outer", cat="test", step=3):
+        timeline.record_span("timed_by_hand", 5.0, 0.25, cat="test")
+    by_hand, outer = timeline.spans()
+    name, cat, t0, dur, args, tid = by_hand[:6]
+    assert (name, cat, t0, dur, args, tid) == (
+        "timed_by_hand", "test", 5.0, 0.25, None, threading.get_ident())
+    assert outer[:2] == ("outer", "test") and outer[4] == {"step": 3}
+    # an explicit t0 takes the stack's top as its parent; ids are unique
+    assert by_hand.parent == outer.id and outer.parent == 0
+    assert by_hand.id != outer.id
+
+
+def test_span_tree_over_one_executor_run():
+    """One miss and one hit of ``Executor.run``: every span hangs off its
+    ``run``, the compile path shows on the miss only, and ``run``'s self
+    time is what the children leave."""
+    main, startup, loss = _loss_program(dim=11)
+    exe = fluid.Executor()
+    feed = {"x": np.ones((2, 11), "float32")}
+    with fluid.scope_guard(fluid.Scope()):
+        exe.run(startup)
+        timeline.clear()
+        exe.run(main, feed=feed, fetch_list=[loss])
+        miss = timeline.spans()
+        timeline.clear()
+        exe.run(main, feed=feed, fetch_list=[loss])
+        hit = timeline.spans()
+
+    for spans, was_miss in ((miss, True), (hit, False)):
+        run = _only(spans, "run")
+        assert run.parent == 0                      # a root
+        assert run.args["program"].startswith(str(id(main)))
+        kids = {s.name for s in _children(spans, run)}
+        assert kids == ({"feed_prep", "dispatch"}
+                        | ({"compile"} if was_miss else set()))
+        prep = _only(spans, "feed_prep")
+        assert [s.name for s in _children(spans, prep)] == [
+            "state_lookup", "h2d"]
+        assert prep.args["step"] == run.args["step"]
+        names = {s.name for s in spans}
+        if was_miss:
+            comp = _only(spans, "compile")
+            # the Python trace + lower; the rest of compile is the backend
+            assert [s.name for s in _children(spans, comp)] == [
+                "trace_lower"]
+            assert _self_time(spans, comp) >= 0
+        else:
+            assert not names & {"compile", "trace_lower"}
+        # children lie inside their parent, so self time is well defined
+        for s in spans:
+            for c in _children(spans, s):
+                assert s.t0 <= c.t0 and c.t0 + c.dur <= s.t0 + s.dur
+        assert 0 <= _self_time(spans, run) < run.dur
+        assert {s.tid for s in spans} == {run.tid}
+
+
+@pytest.mark.parametrize("kind", ["numpy", "device", "list"])
+def test_h2d_span_counts_host_feed_bytes(kind):
+    """``h2d``'s ``bytes`` is the sum of ``nbytes`` of the feeds that were
+    host arrays; a feed that already lives on the device counts 0, and a
+    list, which has no ``nbytes`` and is converted once, what arrived."""
+    import jax.numpy as jnp
+    main, startup, loss = _loss_program(dim=9)
+    exe = fluid.Executor()
+    x = np.ones((4, 9), "float32")
+    feed = {"numpy": x, "device": jnp.asarray(x), "list": x.tolist()}[kind]
+    with fluid.scope_guard(fluid.Scope()):
+        exe.run(startup)
+        timeline.clear()
+        exe.run(main, feed={"x": feed}, fetch_list=[loss])
+    h2d = _only(timeline.spans(), "h2d")
+    assert h2d.args == {"bytes": 0 if kind == "device" else x.nbytes, "n": 1}
+
+
+def _two_slot_dataset(tmp_path, rows=12, batch=4):
+    data_file = tmp_path / "d.txt"
+    data_file.write_text("".join(
+        "%d;%d\n" % (i % 5, i % 3) for i in range(rows)))
+    main, startup = fluid.Program(), fluid.Program()
+    with fluid.unique_name.guard(), fluid.program_guard(main, startup):
+        a = fluid.data("a", [1], "int64")
+        b = fluid.data("b", [1], "int64")
+        s = fluid.layers.cast(a + b, "float32")
+        loss = fluid.layers.mean(fluid.layers.fc(s, 2))
+    ds = fluid.DatasetFactory().create_dataset("QueueDataset")
+    ds.set_batch_size(batch)
+    ds.set_use_var([a, b])
+    ds.set_filelist([str(data_file)])
+    return main, startup, loss, ds, data_file
+
+
+def test_span_tree_over_one_dataset_epoch(tmp_path):
+    """One ``train_from_dataset`` epoch: the calling thread holds
+    ``train_from_dataset`` > ``run`` / ``feed_wait`` / ``fetch_sync``; the
+    prefetch worker's spans are roots of its own thread, with the file
+    read under the ``produce`` that reached it."""
+    main, startup, loss, ds, data_file = _two_slot_dataset(tmp_path)
+    exe = fluid.Executor()
+    with fluid.scope_guard(fluid.Scope()):
+        exe.run(startup)
+        timeline.clear()
+        exe.train_from_dataset(main, dataset=ds, fetch_list=[loss])
+    spans = timeline.spans()
+    epoch = _only(spans, "train_from_dataset", cat="dataset")
+    assert epoch.parent == 0
+    runs = [s for s in spans if s.name == "run"]
+    assert len(runs) == 3 and all(r.parent == epoch.id for r in runs)
+    assert [r.args["step"] for r in runs] == [0, 1, 2]
+    sync = _only(spans, "fetch_sync", cat="dataset")
+    assert sync.parent == epoch.id
+    assert all(s.parent == epoch.id for s in spans if s.name == "feed_wait")
+    assert 0 <= _self_time(spans, epoch) < epoch.dur
+
+    worker = [s for s in spans if s.tid != epoch.tid]
+    assert {s.name for s in worker} <= {"produce", "parse_file", "put_wait"}
+    produce = [s for s in worker if s.name == "produce"]
+    # one per next(batches): three batches and the exhausted call
+    assert len(produce) == 4
+    assert all(s.parent == 0 and s.cat == "dataset" for s in produce)
+    parse = _only(spans, "parse_file", cat="dataset")
+    assert parse.parent == produce[0].id and parse.tid == produce[0].tid
+    assert parse.args["bytes"] == data_file.stat().st_size
+    assert parse.args["rows"] == 12
+    assert isinstance(parse.args["native"], bool)
+    assert produce[0].dur >= parse.dur
+
+
+@pytest.mark.parametrize("slow", ["producer", "consumer"])
+def test_queue_waits_recorded_only_when_dry_or_full(slow):
+    """``feed_wait`` only when the consumer finds the queue empty,
+    ``put_wait`` only when the worker finds it full."""
+    import time
+
+    def batches():
+        for i in range(6):
+            if slow == "producer":
+                time.sleep(0.03)
+            yield {"x": np.full((1,), i, "float32")}
+
+    timeline.clear()
+    got = []
+    # depth 2 under the slow producer: the end sentinel, put right behind
+    # the last batch, then finds room too
+    depth = 2 if slow == "producer" else 1
+    for item in fluid.Executor._prefetch_batches(batches(), depth=depth):
+        got.append(int(item["x"][0]))
+        if slow == "consumer":
+            time.sleep(0.03)
+    assert got == list(range(6))
+    waits = {n: len(timeline.spans(n)) for n in ("feed_wait", "put_wait")}
+    if slow == "producer":
+        # every get found the queue dry; the worker never found it full
+        assert waits["feed_wait"] >= 6 and waits["put_wait"] == 0
+    else:
+        # only the very first get (the worker had not started) can be dry
+        assert waits["feed_wait"] <= 1 and waits["put_wait"] >= 3
+    assert all(s.parent == 0 for s in timeline.spans("put_wait"))
+
+
+def test_abandoned_epoch_stops_the_worker_at_once():
+    """The consumer left (``Executor.run`` raised mid-epoch): the worker
+    hands on nothing more, however much room the queue has -- it does not
+    produce and parse ``depth`` further batches first."""
+    import threading
+    import time
+    produced = []
+
+    def batches():
+        for i in range(50):
+            time.sleep(0.02)
+            produced.append(i)
+            yield {"x": np.full((1,), i, "float32")}
+
+    epoch = fluid.Executor._prefetch_batches(batches(), depth=8)
+    assert int(next(epoch)["x"][0]) == 0
+    epoch.close()                       # while the worker makes batch 1
+    for t in threading.enumerate():
+        if t.name == "dataset-prefetch":
+            t.join(5.0)
+            assert not t.is_alive()
+    assert produced == [0, 1]
+
+
+@pytest.mark.parametrize("outcome", ["compiled", "raised"])
+def test_compile_span_only_for_a_compile_that_succeeded(monkeypatch, outcome):
+    """A compile that raises leaves no ``compile`` span (readers sum the
+    phase as compile time of programs that ran); ``run`` spans an
+    exception like any return."""
+    main, startup, loss = _loss_program(dim=17)
+    exe = fluid.Executor()
+    feed = {"x": np.ones((2, 17), "float32")}
+
+    def refuse(self, key, compiled, args):
+        self._cache.pop(key, None)
+        raise RuntimeError("Mosaic says no")
+
+    with fluid.scope_guard(fluid.Scope()):
+        exe.run(startup)
+        timeline.clear()
+        if outcome == "raised":
+            monkeypatch.setattr(fluid.Executor, "_aot_compile", refuse)
+            with pytest.raises(RuntimeError, match="Mosaic says no"):
+                exe.run(main, feed=feed, fetch_list=[loss])
+        else:
+            exe.run(main, feed=feed, fetch_list=[loss])
+    spans = timeline.spans()
+    assert len(timeline.spans("compile")) == (outcome == "compiled")
+    run = _only(spans, "run")
+    assert _only(spans, "feed_prep").parent == run.id
+
+
+def test_goodput_window_keeps_to_the_phases_the_ledger_sums(tmp_path):
+    """``span_window()`` is the wall ``goodput.compute_live`` divides by:
+    a file load before training, ``run``'s bookkeeping before its first
+    ``feed_prep`` and the worker's spans do not stretch it."""
+    from paddle_tpu.observability import goodput
+    assert timeline.WINDOW_PHASES == (set(goodput._PHASE_CAUSE)
+                                      | {("megastep", "executor")})
+    main, startup, loss, _, data_file = _two_slot_dataset(tmp_path)
+    ds = fluid.DatasetFactory().create_dataset("InMemoryDataset")
+    ds.set_batch_size(4)
+    ds.set_use_var([main.global_block().var("a"),
+                    main.global_block().var("b")])
+    ds.set_filelist([str(data_file)])
+    exe = fluid.Executor()
+    with fluid.scope_guard(fluid.Scope()):
+        exe.run(startup)
+        timeline.clear()
+        ds.load_into_memory()
+        assert timeline.spans("parse_file")
+        assert timeline.span_window() == (None, None)
+        exe.train_from_dataset(main, dataset=ds, fetch_list=[loss])
+    spans = timeline.spans()
+    summed = [s for s in spans if (s.name, s.cat) in timeline.WINDOW_PHASES]
+    t0, t1 = timeline.span_window()
+    assert t0 == min(s.t0 for s in summed)
+    assert t1 == max(s.t0 + s.dur for s in summed)
+    # the containers reach further on both sides, and do not count
+    epoch = _only(spans, "train_from_dataset", cat="dataset")
+    assert epoch.t0 < t0 and t1 < epoch.t0 + epoch.dur
+
+
+def test_record_span_keeps_its_histogram_handle(monkeypatch):
+    """No import and no label lookup per span: the ``phase_seconds`` child
+    is looked up once per (name, category) -- and again after the registry
+    dropped it, or the span would feed a histogram nobody can read."""
+    timeline.record_span("cached_phase", 1.0, 0.001, cat="test")
+    lookups = []
+    real = REGISTRY.histogram
+    monkeypatch.setattr(
+        REGISTRY, "histogram",
+        lambda *a, **kw: lookups.append(kw) or real(*a, **kw))
+    with timeline.phase("cached_phase", cat="test"):
+        pass
+    timeline.record_span("cached_phase", 2.0, 0.001, cat="test")
+    assert lookups == []
+    monkeypatch.undo()
+    h = REGISTRY.histogram("phase_seconds", phase="cached_phase", cat="test")
+    n0 = h.count
+    assert REGISTRY.remove_labeled("phase_seconds", phase="cached_phase",
+                                   cat="test")
+    timeline.record_span("cached_phase", 3.0, 0.001, cat="test")
+    fresh = REGISTRY.histogram("phase_seconds", phase="cached_phase",
+                               cat="test")
+    assert fresh is not h and fresh.count == 1 and h.count == n0
+    # reset() drops every family: a private registry shows the generation
+    reg = MetricsRegistry()
+    g0 = reg.generation
+    reg.reset()
+    assert reg.generation == g0 + 1
+
+
+def test_phases_ride_a_plain_jax_capture(tmp_path, monkeypatch):
+    """A capture started with plain ``jax.profiler.start_trace`` -- not
+    through ``paddle_tpu.profiler`` -- holds the program's phases on the
+    host plane, nested as in the ring, the compile-miss path's included;
+    the export from that capture holds each phase once, with the args and
+    the parent links the ring has for it."""
+    import glob
+    import jax
+    monkeypatch.setenv("PADDLE_TPU_VALIDATE", "warn")
+    main, startup, loss = _loss_program(dim=7)
+    exe = fluid.Executor()
+    feed = {"x": np.ones((2, 7), "float32")}
+    options = jax.profiler.ProfileOptions()
+    options.python_tracer_level = 0
+    with fluid.scope_guard(fluid.Scope()):
+        exe.run(startup)
+        timeline.clear()
+        jax.profiler.start_trace(str(tmp_path), profiler_options=options)
+        for _ in range(3):              # a miss, then two hits
+            exe.run(main, feed=feed, fetch_list=[loss])
+        jax.profiler.stop_trace()
+    path, = glob.glob(str(tmp_path / "plugins/profile/*/*.xplane.pb"))
+    data = jax.profiler.ProfileData.from_file(path)
+    host = [(e.name, e.start_ns, e.start_ns + e.duration_ns)
+            for p in data.planes if p.name.startswith("/host:")
+            for ln in p.lines for e in ln.events
+            if e.name.startswith("paddle_tpu.")]
+    by = {}
+    for n, a, b in host:
+        by.setdefault(n, []).append((a, b))
+    assert len(by["paddle_tpu.executor.dispatch"]) == 3
+    assert len(by["paddle_tpu.executor.h2d"]) == 3
+    # the miss: every phase of its path is an annotation, none a span
+    # recorded after the fact
+    (va, vb), = by["paddle_tpu.executor.verify"]
+    (ca, cb), = by["paddle_tpu.executor.compile"]
+    (ta, tb), = by["paddle_tpu.executor.trace_lower"]
+    ra, rb = by["paddle_tpu.executor.run"][0]
+    assert ra <= va <= vb <= ca <= ta <= tb <= cb <= rb
+    for (ra, rb), (da, db), (ha, hb), (fa, fb) in zip(
+            by["paddle_tpu.executor.run"],
+            by["paddle_tpu.executor.dispatch"],
+            by["paddle_tpu.executor.h2d"],
+            by["paddle_tpu.executor.feed_prep"]):
+        assert ra <= fa <= ha and hb <= fb <= da and db <= rb
+    # one clock: annotation minus ring start is the same offset for every
+    # span, to within what two clock reads a few lines apart can differ
+    ring = timeline.spans("dispatch")
+    offsets = [a - s.t0 * 1e9 for (a, _), s in
+               zip(by["paddle_tpu.executor.dispatch"], ring)]
+    assert max(offsets) - min(offsets) < 50e3, offsets
+
+    out = timeline.export_chrome_trace(str(tmp_path / "t.json"),
+                                       trace_dir=str(tmp_path))
+    events = [e for e in timeline.validate_trace(out) if e.get("ph") == "X"]
+    xs = [e["name"] for e in events]
+    for phase_name in ("run", "feed_prep", "h2d", "dispatch"):
+        assert xs.count(f"paddle_tpu.executor.{phase_name}") == 3
+        assert xs.count(phase_name) == 0
+    # what the annotation cannot carry comes from the ring, found by the
+    # span's id: args known only at the end, and the tree
+    ring = {s.id: s for s in timeline.spans()}
+    ours = [e for e in events if e["name"].startswith("paddle_tpu.")]
+    assert ours and all(e["args"] == (ring[e["span_id"]].args or {})
+                        and e["parent_id"] == ring[e["span_id"]].parent
+                        for e in ours)
+    runs = [e for e in ours if e["name"] == "paddle_tpu.executor.run"]
+    assert [e["args"]["step"] for e in runs] == [0, 1, 2]
+    h2d = [e for e in ours if e["name"] == "paddle_tpu.executor.h2d"]
+    assert all(e["args"] == {"bytes": feed["x"].nbytes, "n": 1} for e in h2d)
+    # so obs_report's self time works on a capture's export too
+    from tools import obs_report
+    table = obs_report.render_timeline(timeline.validate_trace(out))
+    line, = [ln for ln in table.splitlines()
+             if ln.strip().startswith("paddle_tpu.executor.run:")]
+    assert " self=" in line
+
+
 # ------------------------------------------------------------------ health --
 
 def test_health_raise_names_offending_fetch(monkeypatch, tmp_path):
@@ -446,9 +820,9 @@ def test_merge_chrome_traces_missing_and_empty_inputs(tmp_path):
 
 
 def test_export_with_xplane_capture_skips_host_span_synthesis(tmp_path):
-    """With an xplane capture the RecordEvent spans already ride it via
-    TraceAnnotation -- synthesizing them again would double-count every
-    span in obs_report's timeline section."""
+    """With an xplane capture the RecordEvent spans AND the flight-recorder
+    phases already ride it via TraceAnnotation -- synthesizing them again
+    would double-count every span in obs_report's timeline section."""
     import gzip
     from paddle_tpu import profiler
     timeline.clear()
@@ -458,18 +832,30 @@ def test_export_with_xplane_capture_skips_host_span_synthesis(tmp_path):
         with timeline.phase("exec_phase_x", step=0):
             pass
     profiler.stop_profiler(profile_path=os.devnull)
+    timeline.counter_sample("device_memory_bytes", {"cpu:0": 1e6})
     (tmp_path / "cap").mkdir()
     (tmp_path / "cap" / "x.trace.json.gz").write_bytes(gzip.compress(
         json.dumps({"traceEvents": [
             {"ph": "X", "name": "dup_host_span", "ts": 10.0, "dur": 2.0,
-             "pid": 1}]}).encode()))
+             "pid": 1},
+            {"ph": "X", "name": "paddle_tpu.executor.exec_phase_x",
+             "ts": 10.5, "dur": 1.0, "pid": 1, "args": {"span_id": str(
+                 timeline.spans("exec_phase_x")[0].id)}}]}).encode()))
     out = timeline.export_chrome_trace(str(tmp_path / "t.json"),
                                        trace_dir=str(tmp_path))
     events = timeline.validate_trace(out)
-    assert sum(1 for e in events if e.get("ph") == "X"
-               and e["name"] == "dup_host_span") == 1
-    assert any(e.get("ph") == "X" and e["name"] == "exec_phase_x"
-               for e in events)   # flight-recorder phases still ride along
+    xs = [e["name"] for e in events if e.get("ph") == "X"]
+    # each span once, under the name it has in the capture; the ring's copy
+    # of the phase is not spliced in beside it
+    assert sorted(xs) == ["dup_host_span",
+                          "paddle_tpu.executor.exec_phase_x"]
+    # flight-recorder phases still ride along, args and all
+    assert any(e.get("ph") == "X"
+               and e["name"] == "paddle_tpu.executor.exec_phase_x"
+               and e["args"] == {"step": 0} for e in events)
+    # the counter tracks are what the capture lacks: they still ride along
+    assert any(e.get("ph") == "C" and e["name"] == "device_memory_bytes"
+               for e in events)
     # a trace_dir with no capture is a caller error, not a silent host-only
     # file masquerading as the device timeline
     (tmp_path / "empty").mkdir()
@@ -655,8 +1041,15 @@ def test_predictor_phases_and_health(tmp_path, monkeypatch):
 
 def test_obs_report_trace_cli(tmp_path):
     timeline.clear()
-    timeline.record_span("feed_prep", 1.0, 0.001, step=0)
-    timeline.record_span("dispatch", 1.001, 0.004, step=0)
+    # run [0.9995, 1.0065] holds feed_prep and dispatch: 7 ms, 2 its own
+    with timeline._lock:
+        for span in (("feed_prep", "executor", 1.0, 0.001, {"step": 0},
+                      0, 2, 1),
+                     ("dispatch", "executor", 1.001, 0.004, {"step": 0},
+                      0, 3, 1),
+                     ("run", "executor", 0.9995, 0.007, {"step": 0},
+                      0, 1, 0)):
+            timeline._spans.append(timeline.Span(*span))
     timeline.counter_sample("device_memory_bytes", {"cpu:0": 1e6}, t=1.005)
     tpath = timeline.export_chrome_trace(str(tmp_path / "t.json"))
     repo = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
@@ -668,6 +1061,11 @@ def test_obs_report_trace_cli(tmp_path):
     assert "== Timeline ==" in r.stdout
     assert "feed_prep" in r.stdout and "dispatch" in r.stdout
     assert "device_memory_bytes" in r.stdout
+    # the phase table prints self time beside total, from the parent links
+    table = {ln.split(":")[0].strip(): ln for ln in r.stdout.splitlines()}
+    assert "total=7.000 self=2.000" in table["run"]
+    assert "total=4.000 self=4.000" in table["dispatch"]
+    timeline.clear()
 
 
 def test_obs_report_health_memory_sections():

@@ -173,6 +173,35 @@ def test_fresh_executor_restores_byte_identical(tmp_path, monkeypatch):
     assert _sum_counter("warmstore_hits_total", tier="b") >= 1
 
 
+def test_consult_leaves_a_span_only_for_a_restore(tmp_path, monkeypatch):
+    """The armed store is consulted under a ``warm_restore`` phase (so a
+    restore reaches any profiler capture): a miss leaves the ``compile``
+    span alone, a restore ``warm_restore`` alone -- goodput sums each
+    under its own cause."""
+    from paddle_tpu.observability import timeline
+    monkeypatch.setenv("PADDLE_TPU_WARMSTORE", str(tmp_path / "store"))
+    main, startup, loss = _eval_program(dim=5)
+    feed = _feed(dim=5)
+    scope = fluid.Scope()
+    seen = {}
+    for who in ("cold", "warm"):
+        exe = fluid.Executor()
+        with fluid.scope_guard(scope):
+            if who == "cold":
+                exe.run(startup)
+            timeline.clear()
+            exe.run(main, feed=feed, fetch_list=[loss])
+        assert ws.flush(30.0)
+        seen[who] = {s.name: s for s in timeline.spans()
+                     if s.name in ("run", "compile", "warm_restore")}
+    assert set(seen["cold"]) == {"run", "compile"}
+    assert set(seen["warm"]) == {"run", "warm_restore"}
+    restore, run = seen["warm"]["warm_restore"], seen["warm"]["run"]
+    assert restore.parent == run.id and restore.cat == "executor"
+    assert restore.args["program"] == run.args["program"]
+    timeline.clear()
+
+
 # -------------------------------------------------------- probe self-off --
 
 def test_probe_self_disable_never_touches_tier_a(tmp_path, monkeypatch):

@@ -925,18 +925,33 @@ def render_timeline(trace_events: List[dict]) -> str:
         lines.append("(no trace events)")
         return "\n".join(lines)
     by_name = {}
+    # self time from the parent links the exporter writes (span_id /
+    # parent_id): a span's duration minus its children's.  Events without
+    # them (a trace from before the span tree) print their total alone.
+    child_ms = {}
+    for e in spans:
+        if e.get("parent_id"):
+            child_ms[e["parent_id"]] = child_ms.get(e["parent_id"], 0.0) \
+                + float(e.get("dur", 0.0)) / 1e3
+    self_ms = {}
     for e in spans:
         # group by (name, category): executor and Predictor both record
         # dispatch/feed_prep/fetch_sync spans and merging them would
         # describe neither workload
         key = (e.get("name", "?"), e.get("cat", ""))
-        by_name.setdefault(key, []).append(
-            float(e.get("dur", 0.0)) / 1e3)   # us -> ms
-    lines.append(f"{len(spans)} spans over {len(by_name)} phases:")
+        dur = float(e.get("dur", 0.0)) / 1e3   # us -> ms
+        by_name.setdefault(key, []).append(dur)
+        if "span_id" in e:
+            self_ms[key] = self_ms.get(key, 0.0) + max(
+                0.0, dur - child_ms.get(e["span_id"], 0.0))
+    lines.append(f"{len(spans)} spans over {len(by_name)} phases (ms):")
     for (name, cat), durs in sorted(by_name.items(),
                                     key=lambda kv: -sum(kv[1])):
         shown = name if cat in ("", "executor") else f"{name} [{cat}]"
-        lines.append(f"  {shown}: " + _stats(durs))
+        line = f"  {shown}: " + _stats(durs) + f" total={sum(durs):.3f}"
+        if (name, cat) in self_ms:
+            line += f" self={self_ms[(name, cat)]:.3f}"
+        lines.append(line)
     tracks = {}
     for e in counts:
         tracks.setdefault(e.get("name", "?"), 0)
@@ -1307,10 +1322,14 @@ def selftest() -> int:
         obs_timeline.clear()
         try:
             with obs_timeline._lock:
-                obs_timeline._spans.append(
-                    ("feed_prep", "executor", 1.0, 0.002, {"step": 0}))
-                obs_timeline._spans.append(
-                    ("dispatch", "executor", 1.002, 0.009, {"step": 0}))
+                for span in (
+                        ("feed_prep", "executor", 1.0, 0.002, {"step": 0},
+                         0, 2, 1),
+                        ("dispatch", "executor", 1.002, 0.009, {"step": 0},
+                         0, 3, 1),
+                        ("run", "executor", 0.999, 0.013, {"step": 0},
+                         0, 1, 0)):
+                    obs_timeline._spans.append(obs_timeline.Span(*span))
                 obs_timeline._counters.append(
                     ("device_memory_bytes", 1.011, {"cpu:0": 512e6}))
             tpath = obs_timeline.export_chrome_trace(
@@ -1464,8 +1483,9 @@ def selftest() -> int:
                      # memory section (incl. the static-planner comparison)
                      "cpu:0", "512.000 MB", "peak 1.500 GB",
                      "static plan 1.800 GB", "(1.20x of XLA)",
-                     # timeline section
-                     "feed_prep", "dispatch",
+                     # timeline section: run 13 ms, of which its two
+                     # children took 11
+                     "feed_prep", "dispatch", "total=13.000 self=2.000",
                      "counter track 'device_memory_bytes'"):
             assert must in report, f"selftest: {must!r} missing from:\n{report}"
         # prometheus dump must also load + render
