@@ -335,6 +335,46 @@ def phase_kernels(sizes, ctx):
                                   "loss_composed": round(loss_comp, 5),
                                   "rtol": LOSS_RTOL}
 
+    # The kernel's forward and backward must draw the same dropout mask. With
+    # the seed fixed the output is linear in V, so <g, f(v2)> == <dV(g), v2>
+    # for any g, v2, and only then: an independent mask on either side moves
+    # a tenth of the terms. Each product carries one bf16 rounding (half a
+    # step, 2^-9) of its kernel-made factor, out or dV, and one of the
+    # probabilities inside; independent, so each side is off by about
+    # sqrt(2) x 2^-9 x the 2-norm of its terms. Held to 2^-8 x both norms.
+    from paddle_tpu.ops import pallas_attention as pa
+    b, seq = ls["batch"], ls["seq"]
+    heads = sizes["bert"]["n_heads"]
+    d = sizes["bert"]["hidden"] // heads
+    rng = np.random.RandomState(3)
+    q, k, g, v2 = (jnp.asarray(rng.randn(b, heads, seq, d), jnp.bfloat16)
+                   for _ in range(4))
+    bias = jnp.asarray(np.where(np.arange(seq) < seq - seq // 8, 0.0, -1e4)
+                       .reshape(1, 1, 1, seq).repeat(b, 0), jnp.float32)
+
+    def attend(v):
+        if on_tpu:
+            return pa._flash(q, k, v, bias, jnp.int32(11), d ** -0.5, 0.1,
+                             False, False)
+        return pa.composed_attention(q, k, v, bias, d ** -0.5, 0.1, False,
+                                     jax.random.PRNGKey(11))
+
+    @jax.jit
+    def adjoint_sides(v2, g):
+        out, vjp = jax.vjp(attend, v2)
+        f32 = jnp.float32
+        lhs = g.astype(f32) * out.astype(f32)
+        rhs = vjp(g)[0].astype(f32) * v2.astype(f32)
+        return (lhs.sum(), rhs.sum(),
+                jnp.sqrt((lhs * lhs).sum()) + jnp.sqrt((rhs * rhs).sum()))
+
+    lhs, rhs, norms = (float(x) for x in adjoint_sides(v2, g))
+    assert abs(lhs - rhs) <= 2.0 ** -8 * norms, \
+        f"flash forward and backward disagree: <g, f(v2)> {lhs} vs " \
+        f"<dV(g), v2> {rhs}, allowed {2.0 ** -8 * norms}"
+    facts["flash_dropout_adjoint"] = {"lhs": lhs, "rhs": rhs,
+                                      "allowed": 2.0 ** -8 * norms}
+
     # int8 matmul: fc -> quantize_weights(int8_compute) -> quantized_mul
     n = sizes["int8"]
     main, startup = fluid.Program(), fluid.Program()
@@ -395,7 +435,9 @@ def phase_kernels(sizes, ctx):
     assert err <= BF16_TOL * float(np.abs(want).max()), err
     facts["conv2d_bn_fused"] = {"mosaic_calls": fwd, "max_abs_err": err}
     return {"asserted": "Mosaic custom calls in the S=2048 step (fwd+bwd, "
-                        "dropout 0.1), the int8 matmul and conv+BN; "
+                        "dropout 0.1), one dropout mask in the flash "
+                        "forward and backward (adjoint identity), the int8 "
+                        "matmul and conv+BN; "
                         "numerics within bf16 tolerance of the XLA paths",
             **facts}
 

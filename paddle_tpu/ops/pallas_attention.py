@@ -19,20 +19,31 @@ Design:
     and raises off TPU (ops/pallas_mode.py: only the test harness may ask for
     the Pallas interpreter, so the CPU suite exercises the same kernel body).
   * Whole K/V rows for one (batch, head) are staged in VMEM (S*D*2 bytes
-    each); Q is blocked at BLK_Q rows. Softmax is computed in f32 in VMEM.
-    Matmuls hit the MXU with preferred_element_type=f32. The backward's
-    [BLK_Q, S] f32 temporaries and whole-row dK/dV blocks bound S: on a v5e
-    (PR 21 chip runs) S=2048 and S=4096 compile, the S=8192 backward is
-    refused (21.2 MB of scoped VMEM against the 16 MB limit); longer rows
-    need the K axis blocked (ROADMAP S5).
+    each); Q is blocked at BLK_Q rows. The softmax statistics (max, sum) and
+    every accumulation are f32; the MXU is fed both operands of every product
+    in the input's dtype with preferred_element_type=f32 (bf16 inputs: the
+    probabilities and dS are rounded to bf16 once, as the composed lowering
+    rounds p; f32 inputs keep f32 products). Nothing is divided per score:
+    a power-of-two scale moves into the [BLK_Q, D] q block (exact), the
+    softmax's 1/sum and the dropout's 1/(1-prob) scale [*, D] results.
+    The [BLK_Q, S] f32 temporaries bound S: the forward fits Mosaic's 16 MiB
+    of scoped VMEM to S=8192 at block_q 128, the backward takes
+    BWD_VMEM_LIMIT_BYTES and compiles to S=4096 at BLK_Q (S=8192 at block_q
+    128 only; PR 25, compiled for a described v5e); longer rows need the K
+    axis blocked (ROADMAP S5).
   * Backward is a custom-VJP Pallas kernel that *recomputes* the probabilities
-    per Q block (flash-style: FLOPs are cheap, HBM is not) and accumulates
-    dK/dV across Q blocks by revisiting the same output block: grid axis 1
-    is declared "arbitrary" (sequential) for that, axis 0 "parallel".
+    per Q block from q, k, bias alone (flash-style: FLOPs are cheap, HBM is
+    not; and a residual the forward kernel wrote would make a Program's grad
+    op run that kernel a second time, see _flash_fwd). dK^T and dV^T [D, S]
+    accumulate across the Q blocks in f32 VMEM scratch and leave in the
+    input's dtype on the last one: grid axis 1 is declared "arbitrary"
+    (sequential) for that, axis 0 "parallel".
   * Attention dropout uses the in-kernel PRNG (pltpu.prng_random_bits) seeded
-    per (step, batch*head, q-block); the backward kernel reseeds identically so
-    the mask matches without storing it. In-kernel PRNG has no interpreter
-    lowering, so dropout>0 takes the Pallas path only on a TPU.
+    per (step, batch*head, 128-row block); the backward kernel reseeds
+    identically so the mask matches without storing it, and the mask is the
+    same under every block_q. In-kernel PRNG has no interpreter lowering, so
+    dropout>0 takes the Pallas path only on a TPU (chip_smoke.py checks the
+    two kernels' masks against each other there).
 """
 from __future__ import annotations
 
@@ -41,7 +52,22 @@ import math
 
 from ..core.registry import register
 
-BLK_Q = 128
+# Q rows a grid step: 256 beats 128 forward + backward at S=1024, 2048 and
+# 4096 (3.99 / 6.56 / 11.78 against 4.42 / 7.01 / 12.18 ms a layer of 16k
+# tokens; 512 gains 2% more at S=2048 and does not fit at 4096. Chip runs, PR
+# 25). The default of the `fused_attention.block_sizes` tunable choice.
+BLK_Q = 256
+
+# The smallest Q block. Every S the kernel takes is a multiple of it
+# (supports_pallas), a block_q that does not divide S falls back to it, and
+# the dropout mask is drawn by blocks of it whatever block_q is.
+_MIN_BLK_Q = 128
+
+# Scoped VMEM the backward kernel may take. Mosaic's default (16 MiB of the
+# v5e's 128) holds its [block_q, S] temporaries to S=2048 at BLK_Q; S=4096
+# needs 20 MiB (28 with f32 inputs). The forward fits the default to S=8192
+# and is 4% slower under a raised limit (chip runs, PR 25), so it keeps it.
+BWD_VMEM_LIMIT_BYTES = 64 * 1024 * 1024
 
 # 'auto' uses the Pallas kernel only from this sequence length up: measured
 # on TPU v5e (bf16, H=12 D=64, B*S fixed at 16k tokens), XLA's own fused
@@ -90,17 +116,37 @@ def composed_attention(q, k, v, bias, scale, dropout, causal, rng):
 # pallas kernels
 # --------------------------------------------------------------------------------------
 
-def _probs(q_blk, k_all, bias_row, seed_ref, iq, scale, dropout, causal):
-    """[block_q, S] softmax probabilities (f32) + dropped variant for one Q
-    block (block_q comes from the staged q_blk's leading dim)."""
+_NT = (((1,), (1,)), ((), ()))   # a @ b.T
+_NN = (((1,), (0,)), ((), ()))   # a @ b
+_TN = (((0,), (0,)), ((), ()))   # a.T @ b
+
+
+def _dot(a, b, dims):
+    """MXU product of two blocks in their own dtype, accumulated in f32."""
     import jax
     import jax.numpy as jnp
-    pl, pltpu = _pl()
+    return jax.lax.dot_general(a, b, dims, preferred_element_type=jnp.float32)
+
+
+def _scale_is_exact(scale):
+    """A power of two only moves the exponent: q * scale is exact in q's dtype,
+    so the scale can leave the [block_q, S] scores for the [block_q, D] q."""
+    return math.frexp(scale)[0] == 0.5
+
+
+def _scores(q_blk, k_all, bias_row, iq, scale, causal):
+    """[block_q, S] f32 scores of one Q block, and the q block that went into
+    them (scaled when the scale is folded; the backward's dK needs it)."""
+    import jax
+    import jax.numpy as jnp
 
     blk_q = q_blk.shape[0]
-    s = jax.lax.dot_general(
-        q_blk, k_all, (((1,), (1,)), ((), ())),
-        preferred_element_type=jnp.float32) * scale          # [block_q, S]
+    fold = _scale_is_exact(scale)
+    if fold:
+        q_blk = q_blk * jnp.asarray(scale, q_blk.dtype)
+    s = _dot(q_blk, k_all, _NT)
+    if not fold:
+        s = s * scale
     if bias_row is not None:
         s = s + bias_row.astype(jnp.float32)                 # [1,S] broadcasts
     if causal:
@@ -108,18 +154,24 @@ def _probs(q_blk, k_all, bias_row, seed_ref, iq, scale, dropout, causal):
         qi = iq * blk_q + jax.lax.broadcasted_iota(jnp.int32, (blk_q, S_k), 0)
         ki = jax.lax.broadcasted_iota(jnp.int32, (blk_q, S_k), 1)
         s = jnp.where(ki <= qi, s, jnp.float32(-1e30))
-    m = jnp.max(s, axis=-1, keepdims=True)
-    e = jnp.exp(s - m)
-    p = e / jnp.sum(e, axis=-1, keepdims=True)
-    if not dropout:
-        return p, p
-    # Deterministic per (step seed, batch*head, q block): backward reseeds the same.
-    pltpu.prng_seed(seed_ref[0] + pl.program_id(0) * 1000003 + iq * 7919)
-    bits = pltpu.bitcast(pltpu.prng_random_bits(p.shape), jnp.uint32)
-    thresh = jnp.uint32(int(dropout * float(2**32)))
-    keep = bits >= thresh
-    pd = jnp.where(keep, p / (1.0 - dropout), 0.0)
-    return p, pd
+    return s, q_blk
+
+
+def _keep_mask(shape, seed_ref, iq, dropout):
+    """Bernoulli(1 - dropout) keep mask of one Q block, drawn by blocks of
+    _MIN_BLK_Q rows, each seeded by (step seed, batch*head, its index in the
+    sequence): the backward reseeds the same, and the mask does not depend on
+    block_q."""
+    import jax.numpy as jnp
+    pl, pltpu = _pl()
+    n = shape[0] // _MIN_BLK_Q
+    bits = []
+    for j in range(n):
+        pltpu.prng_seed(seed_ref[0] + pl.program_id(0) * 1000003
+                        + (iq * n + j) * 7919)
+        bits.append(pltpu.prng_random_bits((_MIN_BLK_Q, shape[1])))
+    bits = pltpu.bitcast(jnp.concatenate(bits, axis=0), jnp.uint32)
+    return bits >= jnp.uint32(int(dropout * float(2**32)))
 
 
 def _fwd_kernel(scale, dropout, causal, has_bias, *refs):
@@ -132,87 +184,102 @@ def _fwd_kernel(scale, dropout, causal, has_bias, *refs):
         q_ref, k_ref, v_ref, seed_ref, o_ref = refs
         bias_row = None
     iq = pl.program_id(1)
-    import jax
-    _, pd = _probs(q_ref[0], k_ref[0], bias_row, seed_ref, iq, scale, dropout,
-                   causal)
-    o = jax.lax.dot_general(pd.astype(v_ref.dtype), v_ref[0],
-                            (((1,), (0,)), ((), ())),
-                            preferred_element_type=jnp.float32)
-    o_ref[0] = o.astype(o_ref.dtype)
+    s, _ = _scores(q_ref[0], k_ref[0], bias_row, iq, scale, causal)
+    m = jnp.max(s, axis=-1, keepdims=True)
+    e = jnp.exp(s - m)
+    l = jnp.sum(e, axis=-1, keepdims=True)
+    if dropout:
+        e = jnp.where(_keep_mask(e.shape, seed_ref, iq, dropout), e, 0.0)
+    # the softmax's 1/l and the dropout's 1/(1-prob) scale the [block_q, D]
+    # product, one reciprocal a row, not the [block_q, S] probabilities
+    o = _dot(e.astype(v_ref.dtype), v_ref[0], _NN)
+    o_ref[0] = (o * (1.0 / (l * (1.0 - dropout)))).astype(o_ref.dtype)
 
 
 def _bwd_kernel(scale, dropout, causal, has_bias, *refs):
-    import jax
     import jax.numpy as jnp
     pl, _ = _pl()
     if has_bias:
         (q_ref, k_ref, v_ref, bias_ref, seed_ref, do_ref,
-         dq_ref, dk_ref, dv_ref) = refs
+         dq_ref, dk_ref, dv_ref, dkt_acc, dvt_acc) = refs
         bias_row = bias_ref[0]                               # [1, S]
     else:
-        q_ref, k_ref, v_ref, seed_ref, do_ref, dq_ref, dk_ref, dv_ref = refs
+        (q_ref, k_ref, v_ref, seed_ref, do_ref,
+         dq_ref, dk_ref, dv_ref, dkt_acc, dvt_acc) = refs
         bias_row = None
     iq = pl.program_id(1)
-    p, pd = _probs(q_ref[0], k_ref[0], bias_row, seed_ref, iq, scale, dropout,
-                   causal)
-    do = do_ref[0].astype(jnp.float32)                       # [BLK_Q, D]
-    v = v_ref[0].astype(jnp.float32)                         # [S, D]
-    dv_blk = jax.lax.dot_general(pd, do, (((0,), (0,)), ((), ())),
-                                 preferred_element_type=jnp.float32)  # [S, D]
-    dpd = jax.lax.dot_general(do, v, (((1,), (1,)), ((), ())),
-                              preferred_element_type=jnp.float32)     # [BLK_Q, S]
+    dtype = q_ref.dtype
+    s, q_s = _scores(q_ref[0], k_ref[0], bias_row, iq, scale, causal)
+    e = jnp.exp(s - jnp.max(s, axis=-1, keepdims=True))
+    p = e * (1.0 / jnp.sum(e, axis=-1, keepdims=True))       # [BLK_Q, S] f32
+    do = do_ref[0]                                           # [BLK_Q, D]
+    dpd = _dot(do, v_ref[0], _NT)                            # [BLK_Q, S] f32
+    # Dropout multiplies p and its gradient by keep * c, c = 1/(1-prob). The
+    # mask is applied here; c is a constant of every product below, so it
+    # scales the [*, D] results (pk, dp, ds are the true values over c).
+    c = 1.0 / (1.0 - dropout)
     if dropout:
-        # d(dropout(p))/dp: the same keep/(1-p) factor -- pd/p where p>0 encodes it,
-        # but recompute from the mask-free relation: pd = p*keep/(1-prob)
-        # => dp = dpd * keep/(1-prob) = dpd * (pd / jnp.where(p == 0, 1, p)).
-        dp = dpd * (pd / jnp.where(p == 0.0, 1.0, p))
+        keep = _keep_mask(p.shape, seed_ref, iq, dropout)
+        pk = jnp.where(keep, p, 0.0)
+        dp = jnp.where(keep, dpd, 0.0)
     else:
-        dp = dpd
+        pk, dp = p, dpd
     row = jnp.sum(dp * p, axis=-1, keepdims=True)
-    ds = p * (dp - row)                                      # [BLK_Q, S] f32
-    dq_blk = jax.lax.dot_general(ds, k_ref[0].astype(jnp.float32),
-                                 (((1,), (0,)), ((), ())),
-                                 preferred_element_type=jnp.float32) * scale
-    dk_blk = jax.lax.dot_general(ds, q_ref[0].astype(jnp.float32),
-                                 (((0,), (0,)), ((), ())),
-                                 preferred_element_type=jnp.float32) * scale
-    dq_ref[0] = dq_blk.astype(dq_ref.dtype)
+    ds = (p * (dp - row)).astype(dtype)
+    dq_ref[0] = (_dot(ds, k_ref[0], _NN) * (c * scale)).astype(dq_ref.dtype)
 
     @pl.when(iq == 0)
     def _():
-        dk_ref[0] = jnp.zeros_like(dk_ref[0])
-        dv_ref[0] = jnp.zeros_like(dv_ref[0])
+        dkt_acc[...] = jnp.zeros_like(dkt_acc)
+        dvt_acc[...] = jnp.zeros_like(dvt_acc)
 
-    dk_ref[0] += dk_blk
-    dv_ref[0] += dv_blk
+    # dK^T, dV^T [D, S]: contracting the Q rows of both operands transposes
+    # the [BLK_Q, D] block, not the [BLK_Q, S] one
+    dkt_acc[...] += _dot(q_s, ds, _TN)
+    dvt_acc[...] += _dot(do, pk.astype(dtype), _TN)
+
+    @pl.when(iq == pl.num_programs(1) - 1)
+    def _():
+        k_scale = c if _scale_is_exact(scale) else c * scale  # q_s has it
+        dk_ref[0] = (dkt_acc[...] * k_scale).T.astype(dk_ref.dtype)
+        dv_ref[0] = (dvt_acc[...] * c).T.astype(dv_ref.dtype)
 
 
-def _specs(B, H, S, D, has_bias, block_q):
+def _operands(q, k, v, bias, seed, block_q):
+    """The kernels' common operands and block specs, and the number of Q
+    blocks: of block_q rows if that divides S, else of _MIN_BLK_Q."""
     import jax.numpy as jnp
     pl, pltpu = _pl()
+    B, H, S, D = q.shape
+    if S % block_q:
+        block_q = _MIN_BLK_Q
+    args = [x.reshape(B * H, S, D) for x in (q, k, v)]
     qspec = pl.BlockSpec((1, block_q, D), lambda b, i: (b, i, 0),
                          memory_space=pltpu.VMEM)
     kvspec = pl.BlockSpec((1, S, D), lambda b, i: (b, 0, 0),
                           memory_space=pltpu.VMEM)
     in_specs = [qspec, kvspec, kvspec]
-    if has_bias:
+    if bias is not None:
         # [B,1,S] with block (1,1,S): the last two dims equal the array dims,
         # satisfying the TPU (8,128)-divisible-or-full block constraint.
+        args.append(bias.reshape(B, 1, S))
         in_specs.append(pl.BlockSpec((1, 1, S), lambda b, i: (b // H, 0, 0),
                                      memory_space=pltpu.VMEM))
-    in_specs.append(pl.BlockSpec(memory_space=pltpu.SMEM))  # seed
-    return qspec, kvspec, in_specs
+    args.append(jnp.asarray(seed, jnp.int32).reshape(1))
+    in_specs.append(pl.BlockSpec(memory_space=pltpu.SMEM))
+    return args, in_specs, qspec, kvspec, S // block_q
 
 
-def _compiler_params(interpret):
+def _compiler_params(interpret, vmem_limit_bytes=None):
     """Grid axis 0 (batch*head) is independent; axis 1 (Q blocks) must run
-    in order -- the backward accumulates dK/dV into a revisited output
-    block. The interpreter takes no Mosaic parameters."""
+    in order -- the backward accumulates dK/dV over it in scratch and writes
+    them on the last block. The interpreter takes no Mosaic parameters."""
     if interpret:
         return {}
     _, pltpu = _pl()
     return {"compiler_params": pltpu.CompilerParams(
-        dimension_semantics=("parallel", "arbitrary"))}
+        dimension_semantics=("parallel", "arbitrary"),
+        vmem_limit_bytes=vmem_limit_bytes)}
 
 
 import jax as _jax  # custom_vjp must wrap at def time
@@ -227,25 +294,16 @@ def _flash(q, k, v, bias, seed, scale, dropout, causal, interpret,
 def _flash_fwd_impl(q, k, v, bias, seed, scale, dropout, causal, interpret,
                     block_q):
     import jax
-    import jax.numpy as jnp
-    pl, pltpu = _pl()
+    pl, _ = _pl()
     B, H, S, D = q.shape
-    BH = B * H
-    qf = q.reshape(BH, S, D)
-    kf = k.reshape(BH, S, D)
-    vf = v.reshape(BH, S, D)
-    has_bias = bias is not None
-    args = [qf, kf, vf]
-    if has_bias:
-        args.append(bias.reshape(B, 1, S))
-    args.append(jnp.asarray(seed, jnp.int32).reshape(1))
-    qspec, _, in_specs = _specs(B, H, S, D, has_bias, block_q)
+    args, in_specs, qspec, _, n_q = _operands(q, k, v, bias, seed, block_q)
     out = pl.pallas_call(
-        functools.partial(_fwd_kernel, scale, dropout, causal, has_bias),
-        grid=(BH, S // block_q),
+        functools.partial(_fwd_kernel, scale, dropout, causal,
+                          bias is not None),
+        grid=(B * H, n_q),
         in_specs=in_specs,
         out_specs=qspec,
-        out_shape=jax.ShapeDtypeStruct((BH, S, D), q.dtype),
+        out_shape=jax.ShapeDtypeStruct((B * H, S, D), q.dtype),
         interpret=interpret,
         **_compiler_params(interpret),
     )(*args)
@@ -256,42 +314,39 @@ def _flash_fwd(q, k, v, bias, seed, scale, dropout, causal, interpret,
                block_q=BLK_Q):
     out = _flash_fwd_impl(q, k, v, bias, seed, scale, dropout, causal,
                           interpret, block_q)
+    # Inputs only. A Program's generic grad op (core/registry.py) lowers the
+    # forward again under jax.vjp: a kernel output among the residuals (a
+    # log-sum-exp, say) keeps that second forward kernel alive, which costs
+    # five times what it saves the backward (chip runs, PR 25).
     return out, (q, k, v, bias, seed)
 
 
 def _flash_bwd(scale, dropout, causal, interpret, block_q, res, g):
     import jax
     import jax.numpy as jnp
+    import numpy as np
     pl, pltpu = _pl()
     q, k, v, bias, seed = res
     B, H, S, D = q.shape
-    BH = B * H
-    has_bias = bias is not None
-    args = [q.reshape(BH, S, D), k.reshape(BH, S, D), v.reshape(BH, S, D)]
-    if has_bias:
-        args.append(bias.reshape(B, 1, S))
-    args.append(jnp.asarray(seed, jnp.int32).reshape(1))
-    args.append(g.reshape(BH, S, D))
-    qspec, kvspec, in_specs = _specs(B, H, S, D, has_bias, block_q)
-    in_specs.append(qspec)  # do
+    args, in_specs, qspec, kvspec, n_q = _operands(q, k, v, bias, seed,
+                                                   block_q)
+    args.append(g.reshape(B * H, S, D))
+    in_specs.append(qspec)
     dq, dk, dv = pl.pallas_call(
-        functools.partial(_bwd_kernel, scale, dropout, causal, has_bias),
-        grid=(BH, S // block_q),
+        functools.partial(_bwd_kernel, scale, dropout, causal,
+                          bias is not None),
+        grid=(B * H, n_q),
         in_specs=in_specs,
         out_specs=[qspec, kvspec, kvspec],
-        out_shape=[
-            jax.ShapeDtypeStruct((BH, S, D), q.dtype),
-            jax.ShapeDtypeStruct((BH, S, D), jnp.float32),
-            jax.ShapeDtypeStruct((BH, S, D), jnp.float32),
-        ],
+        out_shape=[jax.ShapeDtypeStruct((B * H, S, D), x.dtype)
+                   for x in (q, k, v)],
+        scratch_shapes=[pltpu.VMEM((D, S), jnp.float32),
+                        pltpu.VMEM((D, S), jnp.float32)],
         interpret=interpret,
-        **_compiler_params(interpret),
+        **_compiler_params(interpret, BWD_VMEM_LIMIT_BYTES),
     )(*args)
     shape = (B, H, S, D)
-    import numpy as np
-    return (dq.reshape(shape),
-            dk.reshape(shape).astype(k.dtype),
-            dv.reshape(shape).astype(v.dtype),
+    return (dq.reshape(shape), dk.reshape(shape), dv.reshape(shape),
             None if bias is None else jnp.zeros_like(bias),
             np.zeros(np.shape(seed), jax.dtypes.float0))
 
@@ -301,7 +356,7 @@ _flash.defvjp(_flash_fwd, _flash_bwd)
 
 def supports_pallas(B, H, S, D, bias_shape, dropout, is_tpu):
     """Shape/placement gate for the Pallas lowering."""
-    if S % BLK_Q != 0 or S < BLK_Q:
+    if S % _MIN_BLK_Q != 0 or S < _MIN_BLK_Q:
         return False
     if dropout and not is_tpu:
         return False  # in-kernel PRNG has no interpreter lowering
@@ -388,9 +443,9 @@ def fused_attention(ctx, ins):
         pallas_mode.require("fused_attention impl='pallas'")
         if not supports_pallas(B, H, S, D, bias_shape, dropout, is_tpu):
             raise ValueError(
-                f"fused_attention impl='pallas' requires S % {BLK_Q} == 0, "
-                f"a [B,1,1,S] bias, and (for dropout>0) a TPU; got S={S}, "
-                f"bias={bias_shape}, dropout={dropout}, "
+                f"fused_attention impl='pallas' requires S % {_MIN_BLK_Q} "
+                f"== 0, a [B,1,1,S] bias, and (for dropout>0) a TPU; got "
+                f"S={S}, bias={bias_shape}, dropout={dropout}, "
                 f"backend_tpu={is_tpu}. Use impl='auto' to let the op "
                 f"choose the composed lowering.")
     # impl='auto' backend + block sizes are tunable choice points: with a

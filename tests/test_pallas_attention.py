@@ -34,23 +34,112 @@ def test_flash_forward_parity(causal, use_bias):
     np.testing.assert_allclose(np.asarray(ref), np.asarray(out), atol=1e-5)
 
 
-def test_flash_grad_parity():
-    q, k, v, bias = _qkv()
+def _f32(x):
+    return x.astype(jnp.float32)
 
-    def loss(att):
-        def f(q, k, v):
-            return (att(q, k, v) ** 2).sum()
-        return f
 
-    ref_f = loss(lambda q, k, v: pa.composed_attention(
-        q, k, v, bias, 0.125, 0.0, False, jax.random.PRNGKey(0)))
-    fl_f = loss(lambda q, k, v: pa._flash(
-        q, k, v, bias, jnp.int32(7), 0.125, 0.0, False, True))
-    gr = jax.grad(ref_f, (0, 1, 2))(q, k, v)
-    gf = jax.grad(fl_f, (0, 1, 2))(q, k, v)
-    for a, b in zip(gr, gf):
-        np.testing.assert_allclose(np.asarray(a), np.asarray(b),
-                                   atol=5e-5, rtol=1e-4)
+def _grads(attend, q, k, v, g):
+    """(dq, dk, dv) of <attend(q, k, v), g>, as float32 numpy arrays."""
+    out, vjp = jax.vjp(attend, q, k, v)
+    return [np.asarray(_f32(x)) for x in vjp(g.astype(out.dtype))]
+
+
+@pytest.mark.parametrize("use_bias", [False, True])
+@pytest.mark.parametrize("causal", [False, True])
+@pytest.mark.parametrize("dtype", [jnp.float32, jnp.bfloat16])
+def test_flash_grad_parity(dtype, causal, use_bias):
+    """dQ, dK, dV against the composed lowering evaluated in float32 on the
+    same (exactly representable) inputs.
+
+    float32 inputs take float32 products: atol 5e-5 / rtol 1e-4, as ever.
+
+    bfloat16 keeps 8 significant bits, so one rounding is off by at most
+    half a step: 2^-9 relative. Every gradient element is a sum over a row of
+    S products (dQ over the keys, dK and dV over the queries) one factor of
+    which (ds, or the probabilities) the kernel rounds to bfloat16 first,
+    and the sum is rounded once more on the way out. A sum of S terms of
+    independent sign is about sqrt(S) terms large, so a term is about
+    rms(gradient) / sqrt(S); if all S rounding errors line up (the worst
+    case) they come to 2^-9 x S x that = 2^-9 x sqrt(S) x rms(gradient).
+    The last rounding adds at most 2^-9 x max|gradient|.
+    """
+    q, k, v, bias = _qkv(dtype=dtype)
+    S = q.shape[2]
+    g = jax.random.normal(jax.random.PRNGKey(1), q.shape, dtype)
+    b = bias if use_bias else None
+    ref = _grads(lambda q, k, v: pa.composed_attention(
+        q, k, v, b, 0.125, 0.0, causal, None), _f32(q), _f32(k), _f32(v), g)
+    got = _grads(lambda q, k, v: pa._flash(
+        q, k, v, b, jnp.int32(7), 0.125, 0.0, causal, True), q, k, v, g)
+    for r, x in zip(ref, got):
+        if dtype == jnp.float32:
+            np.testing.assert_allclose(x, r, atol=5e-5, rtol=1e-4)
+        else:
+            atol = 2.0 ** -9 * (np.sqrt(S) * np.sqrt((r * r).mean())
+                                + np.abs(r).max())
+            np.testing.assert_allclose(x, r, atol=atol, rtol=0)
+
+
+@pytest.mark.parametrize("dtype", [jnp.float32, jnp.bfloat16])
+def test_flash_dk_dv_rounded_once(dtype):
+    """dK and dV come back in the input's dtype, and accumulating them over
+    the Q blocks costs no rounding: four blocks summed in the float32
+    scratch and one block (one MXU product, float32 accumulation) give the
+    same float32 sum up to its order, so after the one rounding to bfloat16
+    they differ nowhere by more than the last bit (or, where a sum cancels
+    to near nothing, by the float32 order noise: 2^-24 x S x the largest
+    element), and almost nowhere at all. Rounding at every Q block would be
+    off by up to two bits."""
+    q, k, v, bias = _qkv(S=512, dtype=dtype)
+    g = jax.random.normal(jax.random.PRNGKey(1), q.shape, dtype)
+
+    def grads(block_q):
+        out, vjp = jax.vjp(lambda q, k, v: pa._flash(
+            q, k, v, bias, jnp.int32(7), 0.125, 0.0, False, True, block_q),
+            q, k, v)
+        return vjp(g)
+
+    one, four = grads(512), grads(128)
+    for a, b, x in zip(one, four, (q, k, v)):
+        assert a.dtype == b.dtype == x.dtype
+    for a, b in zip(one[1:], four[1:]):
+        a, b = np.asarray(_f32(a)), np.asarray(_f32(b))
+        if dtype == jnp.float32:
+            np.testing.assert_allclose(a, b, atol=1e-5, rtol=1e-5)
+        else:
+            last_bit = 2.0 ** -7 * np.maximum(np.abs(a), np.abs(b))
+            order = 2.0 ** -24 * q.shape[2] * np.abs(a).max()
+            assert (np.abs(a - b) <= last_bit + order).all()
+            assert (a == b).mean() > 0.99
+
+
+@pytest.mark.parametrize("dtype", [jnp.float32, jnp.bfloat16])
+def test_flash_scale_not_a_power_of_two(dtype):
+    """0.125 moves into q exactly; 0.17 cannot, and stays on the scores."""
+    assert pa._scale_is_exact(0.125) and not pa._scale_is_exact(0.17)
+    q, k, v, bias = _qkv(dtype=dtype)
+    g = jax.random.normal(jax.random.PRNGKey(1), q.shape, dtype)
+    ref_f = lambda q, k, v: pa.composed_attention(
+        q, k, v, bias, 0.17, 0.0, False, None)
+    fl_f = lambda q, k, v: pa._flash(
+        q, k, v, bias, jnp.int32(7), 0.17, 0.0, False, True)
+    tol = 5e-5 if dtype == jnp.float32 else 2e-2   # test_flash_bf16_close's
+    np.testing.assert_allclose(
+        np.asarray(_f32(fl_f(q, k, v))),
+        np.asarray(ref_f(_f32(q), _f32(k), _f32(v))), atol=tol)
+    for r, x in zip(_grads(ref_f, _f32(q), _f32(k), _f32(v), g),
+                    _grads(fl_f, q, k, v, g)):
+        np.testing.assert_allclose(x, r, atol=tol * np.abs(r).max(), rtol=0)
+
+
+def test_flash_block_q_falls_back_where_it_does_not_divide_s():
+    """S=384 is a multiple of 128 and not of BLK_Q=256."""
+    assert 384 % pa.BLK_Q and pa.supports_pallas(2, 2, 384, 32, None, 0.0,
+                                                  is_tpu=False)
+    q, k, v, bias = _qkv(S=384)
+    ref = pa.composed_attention(q, k, v, bias, 0.125, 0.0, False, None)
+    out = pa._flash(q, k, v, bias, jnp.int32(7), 0.125, 0.0, False, True)
+    np.testing.assert_allclose(np.asarray(ref), np.asarray(out), atol=1e-5)
 
 
 def test_flash_bf16_close():

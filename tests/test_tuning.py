@@ -12,6 +12,7 @@ import numpy as np
 import pytest
 
 import paddle_tpu as fluid
+from paddle_tpu.ops.pallas_attention import BLK_Q
 from paddle_tpu import tuning
 from paddle_tpu.tuning import cache as tcache
 from paddle_tpu.tuning import choices as tchoices
@@ -85,7 +86,7 @@ def test_off_and_cached_modes_never_measure(tune_cache, monkeypatch):
         assert tuning.decide("conv2d_bn_fused.backend", CONVBN) == "pallas"
         assert tuning.decide("fused_attention.backend", FLASH) == "pallas"
         assert tuning.decide("fused_attention.block_sizes", FLASH) == \
-            (128, 2048)
+            (BLK_Q, 2048)
 
 
 def test_defaults_reproduce_static_heuristics(tune_cache, monkeypatch):
