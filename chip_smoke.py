@@ -45,6 +45,7 @@ FULL = dict(
     bert=dict(vocab_size=30522, hidden=768, n_layers=12, n_heads=12),
     train=dict(batch=128, seq=128, steps=10),
     longseq=dict(batch=4, seq=2048),
+    causal=dict(batch=2, heads=16, seq=4096, head_dim=128),  # OLMoE's flash
     int8=4096,
     convbn=dict(batch=128, hw=14, cin=1024, cout=256),  # res4 1x1 expand
     serve=dict(image=224, batches=(1, 16), requests=36),
@@ -54,6 +55,7 @@ TINY = dict(
     bert=dict(vocab_size=512, hidden=64, n_layers=2, n_heads=4),
     train=dict(batch=8, seq=16, steps=4),
     longseq=dict(batch=1, seq=256),
+    causal=dict(batch=1, heads=2, seq=256, head_dim=32),
     int8=256,
     convbn=dict(batch=7, hw=8, cin=128, cout=128),
     serve=dict(image=32, batches=(1, 4), requests=8),
@@ -342,38 +344,50 @@ def phase_kernels(sizes, ctx):
     # step, 2^-9) of its kernel-made factor, out or dV, and one of the
     # probabilities inside; independent, so each side is off by about
     # sqrt(2) x 2^-9 x the 2-norm of its terms. Held to 2^-8 x both norms.
+    # Twice: BERT's shape (d=64, padding bias, dropout) and the decoder's
+    # (causal, d=128, S=4096, no bias, no dropout), where the identity holds
+    # only if both kernels mask the same triangle.
     from paddle_tpu.ops import pallas_attention as pa
-    b, seq = ls["batch"], ls["seq"]
+
+    def adjoint(b, heads, seq, d, dropout, causal, padded):
+        rng = np.random.RandomState(3)
+        q, k, g, v2 = (jnp.asarray(rng.randn(b, heads, seq, d), jnp.bfloat16)
+                       for _ in range(4))
+        bias = jnp.asarray(
+            np.where(np.arange(seq) < seq - seq // 8, 0.0, -1e4)
+            .reshape(1, 1, 1, seq).repeat(b, 0), jnp.float32) \
+            if padded else None
+
+        def attend(v):
+            if on_tpu:
+                return pa._flash(q, k, v, bias, jnp.int32(11), d ** -0.5,
+                                 dropout, causal, False)
+            return pa.composed_attention(q, k, v, bias, d ** -0.5, dropout,
+                                         causal, jax.random.PRNGKey(11))
+
+        @jax.jit
+        def adjoint_sides(v2, g):
+            out, vjp = jax.vjp(attend, v2)
+            f32 = jnp.float32
+            lhs = g.astype(f32) * out.astype(f32)
+            rhs = vjp(g)[0].astype(f32) * v2.astype(f32)
+            return (lhs.sum(), rhs.sum(),
+                    jnp.sqrt((lhs * lhs).sum()) + jnp.sqrt((rhs * rhs).sum()))
+
+        lhs, rhs, norms = (float(x) for x in adjoint_sides(v2, g))
+        assert abs(lhs - rhs) <= 2.0 ** -8 * norms, \
+            f"flash forward and backward disagree at {(b, heads, seq, d)} " \
+            f"causal={causal}: <g, f(v2)> {lhs} vs <dV(g), v2> {rhs}, " \
+            f"allowed {2.0 ** -8 * norms}"
+        return {"lhs": lhs, "rhs": rhs, "allowed": 2.0 ** -8 * norms}
+
     heads = sizes["bert"]["n_heads"]
-    d = sizes["bert"]["hidden"] // heads
-    rng = np.random.RandomState(3)
-    q, k, g, v2 = (jnp.asarray(rng.randn(b, heads, seq, d), jnp.bfloat16)
-                   for _ in range(4))
-    bias = jnp.asarray(np.where(np.arange(seq) < seq - seq // 8, 0.0, -1e4)
-                       .reshape(1, 1, 1, seq).repeat(b, 0), jnp.float32)
-
-    def attend(v):
-        if on_tpu:
-            return pa._flash(q, k, v, bias, jnp.int32(11), d ** -0.5, 0.1,
-                             False, False)
-        return pa.composed_attention(q, k, v, bias, d ** -0.5, 0.1, False,
-                                     jax.random.PRNGKey(11))
-
-    @jax.jit
-    def adjoint_sides(v2, g):
-        out, vjp = jax.vjp(attend, v2)
-        f32 = jnp.float32
-        lhs = g.astype(f32) * out.astype(f32)
-        rhs = vjp(g)[0].astype(f32) * v2.astype(f32)
-        return (lhs.sum(), rhs.sum(),
-                jnp.sqrt((lhs * lhs).sum()) + jnp.sqrt((rhs * rhs).sum()))
-
-    lhs, rhs, norms = (float(x) for x in adjoint_sides(v2, g))
-    assert abs(lhs - rhs) <= 2.0 ** -8 * norms, \
-        f"flash forward and backward disagree: <g, f(v2)> {lhs} vs " \
-        f"<dV(g), v2> {rhs}, allowed {2.0 ** -8 * norms}"
-    facts["flash_dropout_adjoint"] = {"lhs": lhs, "rhs": rhs,
-                                      "allowed": 2.0 ** -8 * norms}
+    facts["flash_dropout_adjoint"] = adjoint(
+        ls["batch"], heads, ls["seq"], sizes["bert"]["hidden"] // heads,
+        0.1, False, True)
+    c = sizes["causal"]
+    facts["flash_causal_adjoint"] = adjoint(
+        c["batch"], c["heads"], c["seq"], c["head_dim"], 0.0, True, False)
 
     # int8 matmul: fc -> quantize_weights(int8_compute) -> quantized_mul
     n = sizes["int8"]

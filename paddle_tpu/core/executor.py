@@ -613,6 +613,8 @@ class Executor:
             program, feed_shapes, feed_names, fetch_names,
             wrapper, label, xla_parts)
         _obs_memory.sample_device_memory("compile")
+        from ..observability import moe as _obs_moe
+        _obs_moe.update_moe_gauges(program, label)
         # IR->HLO attribution walk: once per compile miss, only when obs /
         # PADDLE_TPU_OBS_ATTRIB / an armed --emit-hlo capture asks for it
         # (on_compile is a no-op otherwise and never raises)

@@ -1071,3 +1071,104 @@ def fused_attention(q, k, v, bias=None, scale=None, dropout_prob=0.0,
                             "causal": bool(causal), "is_test": bool(is_test),
                             "impl": impl})
     return _var(helper, out)
+
+
+# -- decoder-LM vocabulary (ops/decoder_ops.py) ------------------------------------------
+
+def rms_norm(input, epsilon=1e-5, param_attr=None, name=None):
+    """RMSNorm over the last axis with a learned scale (initialised to 1):
+    ``x / sqrt(mean(x^2) + epsilon) * scale``, float32 inside the op."""
+    from ..initializer import Constant
+    helper = LayerHelper("rms_norm", name=name)
+    scale = helper.create_parameter(param_attr, [int(input.shape[-1])],
+                                    input.dtype,
+                                    default_initializer=Constant(1.0))
+    y = _out(helper, input.dtype)
+    helper.append_op("rms_norm", inputs={"X": [input], "Scale": [scale]},
+                     outputs={"Y": [y]}, attrs={"epsilon": float(epsilon)})
+    return _var(helper, y)
+
+
+def rotary_embedding(x, theta=10000.0, name=None):
+    """Rotary position embedding (rotate-half convention) over ``x [..., S,
+    D]``, positions 0..S-1 along axis -2."""
+    helper = LayerHelper("rotary_embedding", name=name)
+    out = _out(helper, x.dtype)
+    helper.append_op("rotary_embedding", inputs={"X": [x]},
+                     outputs={"Out": [out]}, attrs={"theta": float(theta)})
+    return _var(helper, out)
+
+
+def swiglu(gate, up, row_scale=None, name=None):
+    """``silu(gate) * up``: the gated product of a gated feed-forward layer;
+    with ``row_scale [rows]``, each row of it times its scale."""
+    helper = LayerHelper("swiglu", name=name)
+    out = _out(helper, gate.dtype)
+    inputs = {"X": [gate], "Y": [up]}
+    if row_scale is not None:
+        inputs["Scale"] = [row_scale]
+    helper.append_op("swiglu", inputs=inputs, outputs={"Out": [out]})
+    return _var(helper, out)
+
+
+def moe_ffn(x, num_experts, experts_per_token, expert_width, param_attr=None,
+            name="moe"):
+    """A dropless mixture-of-experts feed-forward layer over tokens
+    ``x [T, H]``: a float32 router (softmax over the experts, top-k values
+    used as they are), every one of the T x k assignments sent to its expert
+    (sort by expert -> grouped matmuls over three stacked weights -> sum of
+    each token's k rows; no capacity, nothing dropped), each expert
+    ``W_down (silu(W_gate x) * (W_up x))``, its router weight applied to the
+    gated product before the down projection.
+
+    Parameters, by name: ``<name>_router_w [H, E]`` float32 and
+    ``<name>_gate_w`` / ``<name>_up_w [E, H, width]``, ``<name>_down_w
+    [E, width, H]`` in x's dtype; ``param_attr`` supplies the initializer.
+
+    Returns ``(out [T, H], aux)`` with ``aux`` the router's variables:
+    ``prob [T, E]``, ``logz [T]`` (logsumexp of the logits), ``index
+    [T, k]`` and ``load [E]`` (assignments received by each expert, int32),
+    the last two without gradient, for the router losses and to be fetched.
+    """
+    from ..layer_helper import ParamAttr
+    helper = LayerHelper("moe_ffn", name=name)
+    H = int(x.shape[-1])
+    E, k, width = int(num_experts), int(experts_per_token), int(expert_width)
+    init = ParamAttr._to_attr(param_attr).initializer
+
+    def param(suffix, shape, dtype):
+        return helper.create_parameter(
+            ParamAttr(name=f"{name}_{suffix}", initializer=init), shape, dtype)
+
+    def op(type, inputs, outputs, attrs=None):
+        helper.append_op(type, inputs=inputs, outputs=outputs,
+                         attrs=attrs or {})
+
+    weight, prob, logz = (_out(helper, "float32") for _ in range(3))
+    index, order, slot, load = (_out(helper, "int32", stop_gradient=True)
+                                for _ in range(4))
+    op("moe_router", {"X": [x], "W": [param("router_w", [H, E], "float32")]},
+       {"Weight": [weight], "Index": [index], "Prob": [prob],
+        "LogZ": [logz]}, {"k": k})
+    rows, row_weight = _out(helper, x.dtype), _out(helper, "float32")
+    op("moe_dispatch", {"X": [x], "Index": [index], "Weight": [weight]},
+       {"Out": [rows], "RowWeight": [row_weight], "Order": [order],
+        "Slot": [slot], "Count": [load]}, {"num_experts": E})
+
+    def experts(inp, suffix, shape):
+        out = _out(helper, x.dtype)
+        op("moe_expert_matmul",
+           {"X": [inp], "W": [param(suffix, shape, x.dtype)],
+            "Count": [load]}, {"Out": [out]})
+        return out
+
+    gated = swiglu(experts(rows, "gate_w", [E, H, width]),
+                   experts(rows, "up_w", [E, H, width]), row_weight)
+    down = experts(gated, "down_w", [E, width, H])
+    out = _out(helper, x.dtype)
+    op("moe_combine", {"X": [down], "Order": [order], "Slot": [slot]},
+       {"Out": [out]})
+    blk = helper.main_program.current_block()
+    return blk.var(out.name), {n: blk.var(v.name) for n, v in
+                               (("prob", prob), ("logz", logz),
+                                ("index", index), ("load", load))}

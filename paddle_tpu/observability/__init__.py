@@ -33,6 +33,9 @@ reproduction gets the counterpart the whole-program-jit design enables:
   (``hlo_op_bytes{category}`` gauges, copy-pair blame feeding PT060,
   ``--emit-hlo`` capture) and the ``hlo_diff`` regression explainer
   (``python -m paddle_tpu.observability.attribution A B``).
+- ``moe`` -- expert-layer counts of a compiled program as gauges
+  (``moe_layers``, ``moe_experts``, ``moe_assignments_per_step``,
+  ``moe_expert_param_bytes``) and ``load_stats`` for a fetched load vector.
 
 Render everything with ``python -m tools.obs_report``.
 """
@@ -64,6 +67,7 @@ from .server import (ObsServer,  # noqa: F401
                      stop as stop_server)
 from .fleet import FleetMonitor, detect_stragglers  # noqa: F401
 from . import attribution  # noqa: F401
+from . import moe  # noqa: F401
 from . import alerts  # noqa: F401
 from . import slo  # noqa: F401
 from . import blackbox  # noqa: F401

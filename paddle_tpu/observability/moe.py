@@ -1,0 +1,58 @@
+"""Mixture-of-experts telemetry: what a compiled step's expert layers hold,
+as gauges set once per compile from the Program's static shapes (counts, not
+times), and the reading of a fetched expert-load vector.
+
+The device values themselves are Program variables (``layers.moe_ffn``
+returns ``load``, the assignments each expert received): fetch them beside
+the loss and hand them to ``load_stats``.
+"""
+from __future__ import annotations
+
+from typing import Dict, Optional
+
+import numpy as np
+
+from .metrics import REGISTRY, MetricsRegistry
+
+def update_moe_gauges(program_ir, program: str,
+                      registry: Optional[MetricsRegistry] = None) -> None:
+    """``moe_layers``, ``moe_experts``, ``moe_assignments_per_step`` (tokens
+    x top-k, all layers) and ``moe_expert_param_bytes`` (the stacked expert
+    weights) of one compiled program; nothing is set for a program without
+    an expert layer."""
+    from ..analysis.distributed import dtype_bytes
+    registry = registry or REGISTRY
+    block = program_ir.global_block()
+    layers = experts = assignments = param_bytes = 0
+    for op in block.ops:
+        if op.type == "moe_dispatch":
+            layers += 1
+            experts = int(op.attr("num_experts"))
+            index = block.find_var_recursive(op.inputs["Index"][0])
+            assignments += int(np.prod(index.shape))
+        elif op.type == "moe_expert_matmul":
+            w = block.find_var_recursive(op.inputs["W"][0])
+            param_bytes += int(np.prod(w.shape)) * dtype_bytes(w.dtype)
+    if not layers:
+        return
+    for name, help, value in (
+            ("moe_layers", "expert layers in the compiled program", layers),
+            ("moe_experts", "routed experts a layer", experts),
+            ("moe_assignments_per_step",
+             "tokens x top-k assignments routed a step, all layers (a count "
+             "from static shapes)", assignments),
+            ("moe_expert_param_bytes",
+             "bytes of the stacked expert weights (a count from static "
+             "shapes)", param_bytes)):
+        registry.gauge(name, help, program=program).set(float(value))
+
+
+def load_stats(load) -> Dict[str, float]:
+    """A fetched ``[experts]`` load vector as max / mean / their ratio (1.0 is
+    perfectly even; the slowest expert of an expert-parallel layout waits
+    for the fullest) and the number of experts that received nothing."""
+    load = np.asarray(load, np.float64).reshape(-1)
+    mean = float(load.mean())
+    return {"max": float(load.max()), "mean": mean,
+            "max_over_mean": float(load.max() / mean) if mean else 0.0,
+            "empty": int((load == 0).sum())}

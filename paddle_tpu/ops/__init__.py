@@ -29,3 +29,4 @@ from . import ctc_crf_ops    # noqa: F401
 from . import sampled_ops    # noqa: F401
 from . import host_table     # noqa: F401
 from . import pipeline_op    # noqa: F401
+from . import decoder_ops   # noqa: F401

@@ -1,0 +1,217 @@
+"""Decoder-LM ops: RMSNorm, rotary embedding, the SiLU-gated product, and a
+dropless mixture-of-experts layer in four ops (``moe_router`` ->
+``moe_dispatch`` -> ``moe_expert_matmul`` x3 around ``swiglu`` ->
+``moe_combine``).
+
+The expert layer never drops an assignment and has no capacity: the
+tokens x top-k assignments are sorted by expert (one stable sort in
+``moe_dispatch``), the experts run as grouped matmuls over the sorted rows
+against stacked ``[experts, in, out]`` weights, and ``moe_combine`` sums each
+token's k expert outputs. The router's weight of an assignment multiplies its
+row of the gated product, before the down projection (``W (w h) = w (W h)``):
+the combine is then a plain sum, its gradient needs no expert output, and
+the ``[assignments, hidden]`` output of the last matmul is never kept for
+the backward. The sorted buffer holds expert 0's rows first, then expert
+1's, ..., with ``Count`` giving each expert's rows: an all-to-all over an
+``ep`` axis can split it by expert range without sorting again.
+
+What moves rows is written so that forward and backward are both gathers
+(``_movers``: a permutation's transpose is the inverse permutation, which
+the sort already gave), never a scatter-add of wide rows. The three matmuls
+are separate ops so that what the backward needs (sorted rows, gate, up, the
+weighted gated product) are Program variables: a grad op re-lowers its
+forward under ``jax.vjp`` (core/registry.py), a grouped matmul's own output
+is not needed for its gradients, and XLA drops the copy.
+
+Kernel choice for the grouped matmul, from what the code can see: on a TPU
+the megablox Pallas kernels that ship with JAX (``gmm`` forward and for the
+rows' gradient, ``tgmm`` for the weights'); elsewhere ``jax.lax.ragged_dot``.
+XLA's own TPU expansion of ``ragged_dot`` is a Mosaic kernel too, but it
+discards the instruction's ``op_name`` metadata, so its time cannot be joined
+to a Program op (PERF.md section 6, PR 26, has both timed on the chip).
+"""
+from __future__ import annotations
+
+import functools
+
+from ..core.registry import register
+
+# m, k, n tile of the megablox kernels (chip runs, PR 26: PERF.md section 6)
+GMM_TILING = (512, 1024, 1024)
+
+
+@register("rms_norm")
+def rms_norm(ctx, ins):
+    """y = x / sqrt(mean(x^2, last axis) + epsilon) * Scale, computed in
+    float32 whatever x's dtype, returned in x's dtype."""
+    import jax
+    import jax.numpy as jnp
+    x = ins["X"][0]
+    xf = x.astype(jnp.float32)
+    y = xf * jax.lax.rsqrt(jnp.mean(jnp.square(xf), axis=-1, keepdims=True)
+                           + ctx.attr("epsilon", 1e-5))
+    scale = ins.get("Scale", [None])[0]
+    if scale is not None:
+        y = y * scale.astype(jnp.float32)
+    return {"Y": [y.astype(x.dtype)]}
+
+
+@register("rotary_embedding")
+def rotary_embedding(ctx, ins):
+    """Rotary position embedding in the rotate-half convention over
+    ``X [..., S, D]``, positions 0..S-1 along axis -2:
+    ``x * cos + concat(-x[D/2:], x[:D/2]) * sin`` with angle
+    ``pos * theta^(-2i/D)`` for both halves' element i. float32 inside."""
+    import jax.numpy as jnp
+    x = ins["X"][0]
+    S, D = x.shape[-2], x.shape[-1]
+    inv_freq = ctx.attr("theta", 10000.0) ** (
+        -jnp.arange(0, D, 2, dtype=jnp.float32) / D)
+    ang = jnp.arange(S, dtype=jnp.float32)[:, None] * inv_freq[None, :]
+    cos = jnp.cos(jnp.concatenate([ang, ang], axis=-1))         # [S, D]
+    sin = jnp.sin(jnp.concatenate([-ang, ang], axis=-1))        # signed
+    xf = x.astype(jnp.float32)
+    # concat(-x2, x1) as a rotation of the lanes by D/2 with the sign in sin
+    return {"Out": [(xf * cos + jnp.roll(xf, D // 2, axis=-1) * sin)
+                    .astype(x.dtype)]}
+
+
+@register("swiglu")
+def swiglu(ctx, ins):
+    """silu(X) * Y, the gated product of a gated feed-forward layer; with
+    ``Scale [rows]`` each row of it times its scale (an expert layer's router
+    weights). float32 inside."""
+    import jax
+    import jax.numpy as jnp
+    g, u = ins["X"][0], ins["Y"][0]
+    gf = g.astype(jnp.float32)
+    out = gf * jax.nn.sigmoid(gf) * u.astype(jnp.float32)
+    scale = ins.get("Scale", [None])[0]
+    if scale is not None:
+        out = out * scale.astype(jnp.float32)[:, None]
+    return {"Out": [out.astype(g.dtype)]}
+
+
+@register("moe_router", nondiff_outputs=("Index",))
+def moe_router(ctx, ins):
+    """Router of a mixture-of-experts layer, in float32 throughout (the
+    product too: at default precision a TPU multiplies float32 in bfloat16
+    passes). ``X [T, H]`` any float dtype, ``W [H, E]`` ->
+    ``Prob [T, E]`` = softmax(X W), ``Weight`` / ``Index [T, k]`` its k
+    largest entries as they are (not renormalised), ``LogZ [T]`` =
+    logsumexp(X W) for the router z-loss. ``Index`` carries no gradient."""
+    import jax
+    import jax.numpy as jnp
+    x, w = ins["X"][0], ins["W"][0]
+    logits = jnp.dot(x.astype(jnp.float32), w.astype(jnp.float32),
+                     precision=jax.lax.Precision.HIGHEST)
+    logz = jax.nn.logsumexp(logits, axis=-1)
+    prob = jnp.exp(logits - logz[:, None])
+    weight, index = jax.lax.top_k(prob, int(ctx.attr("k")))
+    return {"Weight": [weight], "Index": [index.astype(jnp.int32)],
+            "Prob": [prob], "LogZ": [logz]}
+
+
+def _moved(fwd_rows, bwd_rows):
+    """A row movement whose transpose is another row movement: ``f(x, order,
+    slot)`` with integer ``order [A]`` (the flat assignment held by each
+    sorted row) and ``slot [T, k]`` (the sorted row of each assignment)."""
+    import jax
+
+    @jax.custom_vjp
+    def f(x, order, slot):
+        return fwd_rows(x, order, slot)
+
+    def fwd(x, order, slot):
+        return fwd_rows(x, order, slot), (order, slot)
+
+    def bwd(res, g):        # every movement keeps its argument's dtype
+        return bwd_rows(g, *res), None, None
+
+    f.defvjp(fwd, bwd)
+    return f
+
+
+def _gather_tokens(x, order, slot):         # [T, ...] -> [A, ...]
+    return x[order // slot.shape[1]]
+
+
+def _sum_slots(g, order, slot):             # [A, H] -> [T, H]
+    import jax.numpy as jnp
+    return jnp.sum(g[slot], axis=1, dtype=jnp.float32).astype(g.dtype)
+
+
+def _gather_assignments(w, order, slot):    # [T, k] -> [A]
+    return w.reshape(-1)[order]
+
+
+def _gather_slots(g, order, slot):          # [A] -> [T, k]
+    return g[slot]
+
+
+@functools.lru_cache(maxsize=None)
+def _movers():
+    """(token rows -> sorted rows, sorted rows -> token sums, router weights
+    -> sorted weights): each one's transpose is another of these gathers."""
+    return (_moved(_gather_tokens, _sum_slots),
+            _moved(_sum_slots, _gather_tokens),
+            _moved(_gather_assignments, _gather_slots))
+
+
+@register("moe_dispatch", nondiff_inputs=("Index",),
+          nondiff_outputs=("Order", "Slot", "Count"))
+def moe_dispatch(ctx, ins):
+    """Sort the T x k assignments by expert (stable: within an expert, by
+    token) and bring each one's token row and router weight into place.
+    ``X [T, H]``, ``Index`` / ``Weight [T, k]`` -> ``Out [T*k, H]`` (expert
+    0's rows first) and ``RowWeight [T*k]``, ``Order [T*k]`` the flat
+    assignment (token * k + choice) of each sorted row, ``Slot [T, k]`` the
+    sorted row of each assignment, ``Count [E]`` the rows of each expert.
+    Every assignment has a row: nothing is dropped whatever the routing."""
+    import jax.numpy as jnp
+    x, index, weight = ins["X"][0], ins["Index"][0], ins["Weight"][0]
+    n_experts = int(ctx.attr("num_experts"))
+    flat = index.reshape(-1).astype(jnp.int32)
+    order = jnp.argsort(flat, stable=True).astype(jnp.int32)
+    rows = jnp.arange(flat.shape[0], dtype=jnp.int32)
+    slot = jnp.zeros_like(rows).at[order].set(
+        rows, unique_indices=True).reshape(index.shape)
+    count = jnp.sum(flat[:, None] == jnp.arange(n_experts, dtype=jnp.int32),
+                    axis=0, dtype=jnp.int32)
+    to_rows, _, to_row_weights = _movers()
+    return {"Out": [to_rows(x, order, slot)],
+            "RowWeight": [to_row_weights(weight, order, slot)],
+            "Order": [order], "Slot": [slot], "Count": [count]}
+
+
+def grouped_matmul(x, w, count):
+    """``x [A, K]`` rows sorted by group, ``w [G, K, N]``, ``count [G]`` rows
+    a group (summing to A) -> ``[A, N]`` in x's dtype: row a times the
+    weight of its group, accumulated in float32."""
+    import jax
+    from . import pallas_mode
+    if pallas_mode.on_tpu():
+        from jax.experimental.pallas.ops.tpu.megablox import ops as megablox
+        tiling = tuple(min(t, d) for t, d in
+                       zip(GMM_TILING, (x.shape[0], x.shape[1], w.shape[2])))
+        return megablox.gmm(x, w, count, x.dtype, tiling)
+    return jax.lax.ragged_dot(x, w, count)
+
+
+@register("moe_expert_matmul", nondiff_inputs=("Count",))
+def moe_expert_matmul(ctx, ins):
+    """One of an expert layer's products over the sorted rows: ``X [A, K]``,
+    stacked ``W [E, K, N]``, ``Count [E]`` -> ``Out [A, N]``."""
+    return {"Out": [grouped_matmul(ins["X"][0], ins["W"][0],
+                                   ins["Count"][0])]}
+
+
+@register("moe_combine", nondiff_inputs=("Order", "Slot"))
+def moe_combine(ctx, ins):
+    """Each token's output: the sum of its k assignments' rows (already
+    weighted, see ``swiglu``'s ``Scale``). ``X [T*k, H]`` sorted rows,
+    ``Order`` / ``Slot`` from ``moe_dispatch`` -> ``Out [T, H]`` in X's
+    dtype, summed in float32."""
+    _, to_tokens, _ = _movers()
+    return {"Out": [to_tokens(ins["X"][0], ins["Order"][0],
+                              ins["Slot"][0])]}

@@ -1,0 +1,276 @@
+"""The decoder-LM ops (ops/decoder_ops.py) through ``layers.*`` ->
+``Program`` -> ``Executor``: each against a one-line ``jax.numpy`` form, its
+gradient under ``append_backward`` against ``jax.grad`` of that form, and the
+dropless expert layer against the dense form in which every expert is applied
+to every token and masked by the router's choice."""
+import jax
+import jax.numpy as jnp
+import numpy as np
+import pytest
+
+import paddle_tpu as fluid
+from paddle_tpu import layers
+from paddle_tpu.observability import moe as obs_moe
+
+HI = jax.lax.Precision.HIGHEST
+
+
+def run_with_grads(build, feeds, wrt, extra=()):
+    """Build ``out = build(*data vars)`` and the loss sum(out * g) for a
+    fixed random ``g``; return out, the gradients of the loss with respect
+    to the feeds / parameters named in ``wrt``, the named ``extra``
+    variables, and ``g``."""
+    main, startup = fluid.Program(), fluid.Program()
+    with fluid.unique_name.guard(), fluid.program_guard(main, startup):
+        data = [fluid.data(n, list(v.shape), str(v.dtype),
+                           append_batch_size=False) for n, v in feeds.items()]
+        for d in data:
+            d.stop_gradient = False
+        # one consumer each: a leaf's gradient contributions are summed
+        # only where something asks for the sum
+        out = build(*[layers.scale(d, 1.0) if "float" in str(d.dtype)
+                      else d for d in data])
+        g = np.random.RandomState(9).randn(
+            *[int(d) for d in out.shape]).astype("float32")
+        gv = layers.assign(g)
+        loss = layers.reduce_sum(layers.elementwise_mul(
+            layers.cast(out, "float32"), gv))
+        fluid.append_backward(loss)
+    exe, scope = fluid.Executor(), fluid.Scope()
+    exe.run(startup, scope=scope)
+    fetch = [out.name] + [n + "@GRAD" for n in wrt] + list(extra)
+    got = exe.run(main, feed=feeds, fetch_list=fetch, scope=scope)
+    exe.close()
+    k = 1 + len(wrt)
+    return got[0], got[1:k], got[k:], g, scope
+
+
+def close(a, b, tol=2e-5):
+    a, b = np.asarray(a, np.float32), np.asarray(b, np.float32)
+    np.testing.assert_allclose(a, b, atol=tol * max(1.0, np.abs(b).max()),
+                               rtol=0)
+
+
+def rng(seed=0):
+    return np.random.RandomState(seed)
+
+
+def test_rms_norm_matches_its_one_line_form_and_gradient():
+    x = rng().randn(6, 16).astype("float32")
+
+    def form(x, w):
+        return x / jnp.sqrt(jnp.mean(x * x, -1, keepdims=True) + 1e-5) * w
+
+    out, (dx, dw), _, g, _ = run_with_grads(
+        lambda xv: layers.rms_norm(xv, 1e-5, fluid.ParamAttr(name="w")),
+        {"x": x}, ["x", "w"])
+    w = np.ones(16, "float32")
+    close(out, form(x, w))
+    want = jax.grad(lambda x, w: jnp.sum(form(x, w) * g), (0, 1))(x, w)
+    close(dx, want[0])
+    close(dw, want[1])
+
+
+def test_rms_norm_computes_in_float32_on_bfloat16_input():
+    x = jnp.asarray(rng().randn(4, 32), jnp.bfloat16)
+    out, _, _, _, _ = run_with_grads(
+        lambda xv: layers.rms_norm(xv, 1e-5), {"x": np.asarray(x)}, [])
+    xf = np.asarray(x, np.float32)
+    want = xf / np.sqrt((xf * xf).mean(-1, keepdims=True) + 1e-5)
+    assert str(out.dtype) == "bfloat16"
+    # one rounding of the float32 result, nothing rounded inside
+    np.testing.assert_array_equal(
+        np.asarray(out, np.float32),
+        np.asarray(jnp.asarray(want).astype(jnp.bfloat16), np.float32))
+
+
+def test_rotary_embedding_matches_rotate_half_and_gradient():
+    x = rng().randn(2, 3, 8, 16).astype("float32")
+
+    def form(x, theta=10000.0):
+        S, D = x.shape[-2:]
+        ang = jnp.arange(S)[:, None] * theta ** (-jnp.arange(0, D, 2) / D)
+        cos, sin = (jnp.tile(f(ang), (1, 2)) for f in (jnp.cos, jnp.sin))
+        rot = jnp.concatenate([-x[..., D // 2:], x[..., :D // 2]], -1)
+        return x * cos + rot * sin
+
+    out, (dx,), _, g, _ = run_with_grads(
+        lambda xv: layers.rotary_embedding(xv, 10000.0), {"x": x}, ["x"])
+    close(out, form(x))
+    close(dx, jax.grad(lambda x: jnp.sum(form(x) * g))(x))
+    # position 0 is not rotated; a rotation keeps every pair's length
+    np.testing.assert_allclose(out[:, :, 0], x[:, :, 0], atol=1e-6)
+    pair = lambda t: t[..., :8] ** 2 + t[..., 8:] ** 2      # noqa: E731
+    np.testing.assert_allclose(pair(out), pair(x), rtol=1e-4, atol=1e-5)
+
+
+def test_swiglu_matches_its_one_line_form_and_gradient():
+    a, b = rng(1).randn(5, 8).astype("float32"), \
+        rng(2).randn(5, 8).astype("float32")
+    form = lambda a, b: a * jax.nn.sigmoid(a) * b           # noqa: E731
+    out, (da, db), _, g, _ = run_with_grads(
+        lambda av, bv: layers.swiglu(av, bv), {"a": a, "b": b}, ["a", "b"])
+    close(out, form(a, b))
+    want = jax.grad(lambda a, b: jnp.sum(form(a, b) * g), (0, 1))(a, b)
+    close(da, want[0])
+    close(db, want[1])
+
+
+# ---------------------------------------------------------------------------
+# the expert layer
+# ---------------------------------------------------------------------------
+
+def dense_moe(x, w_router, w_gate, w_up, w_down, k):
+    """Every expert on every token, masked by the top-k: no sort, no groups."""
+    prob = jax.nn.softmax(jnp.dot(x, w_router, precision=HI), -1)
+    top_w, top_i = jax.lax.top_k(prob, k)
+    E = w_router.shape[1]
+    gate = jnp.sum(jax.nn.one_hot(top_i, E) * top_w[..., None], axis=1)
+    ein = lambda s, a, b: jnp.einsum(s, a, b, precision=HI)  # noqa: E731
+    h = jax.nn.silu(ein("th,ehi->eti", x, w_gate)) * ein(
+        "th,ehi->eti", x, w_up)
+    return jnp.einsum("te,eth->th", gate, ein("eti,eih->eth", h, w_down),
+                      precision=HI)
+
+
+MOE_PARAMS = ["moe_router_w", "moe_gate_w", "moe_up_w", "moe_down_w"]
+
+
+def moe_case(x, router_w, E=4, k=2, width=8):
+    """layers.moe_ffn over ``x`` with the router's weight forced to
+    ``router_w`` (None: as initialised); returns the program's output, its
+    gradients, the router's variables and the dense form's."""
+    main, startup = fluid.Program(), fluid.Program()
+    startup.random_seed = 3
+    with fluid.unique_name.guard(), fluid.program_guard(main, startup):
+        xv = fluid.data("x", list(x.shape), "float32",
+                        append_batch_size=False)
+        xv.stop_gradient = False
+        out, aux = layers.moe_ffn(
+            layers.scale(xv, 1.0), E, k, width, name="moe",
+            param_attr=fluid.ParamAttr(
+                initializer=fluid.initializer.Normal(0.0, 0.5)))
+        g = rng(9).randn(*x.shape).astype("float32")
+        loss = layers.reduce_sum(layers.elementwise_mul(out, layers.assign(g)))
+        fluid.append_backward(loss)
+    exe, scope = fluid.Executor(), fluid.Scope()
+    exe.run(startup, scope=scope)
+    if router_w is not None:
+        scope.set_var("moe_router_w", jnp.asarray(router_w, jnp.float32))
+    names = (["x@GRAD"] + [p + "@GRAD" for p in MOE_PARAMS]
+             + [aux[n].name for n in ("index", "load", "prob", "logz")])
+    got = exe.run(main, feed={"x": x}, fetch_list=[out.name] + names,
+                  scope=scope)
+    exe.close()
+    weights = [np.asarray(scope.find_var(p)) for p in MOE_PARAMS]
+    want = dense_moe(x, *weights, k)
+    want_grads = jax.grad(
+        lambda x, *w: jnp.sum(dense_moe(x, *w, k) * g),
+        tuple(range(5)))(x, *weights)
+    return {"out": got[0], "grads": got[1:6], "index": got[6],
+            "load": got[7], "prob": got[8], "logz": got[9], "want": want,
+            "want_grads": want_grads, "weights": weights}
+
+
+def routing_cases():
+    H, E = 16, 4
+    x = rng(4).randn(12, H).astype("float32")
+    duplicated = np.concatenate([x[:6], x[:6]])             # every row twice
+    # a router that never picks expert 3 / that sends everything to expert
+    # 0 first and expert 1 second, whatever the token
+    never_3 = rng(5).randn(H, E).astype("float32") * 0.1
+    never_3[:, 3] = 0.0
+    pos = np.abs(x)                                          # logits >= 0
+    one = np.zeros((H, E), "float32")
+    one[:, 0], one[:, 1] = 3.0, 1.0
+    return [pytest.param(x, None, None, id="random_routing"),
+            pytest.param(duplicated, None, None, id="duplicated_tokens"),
+            pytest.param(pos, never_3 - 5.0 * (np.arange(E) == 3), 3,
+                         id="an_expert_with_no_token"),
+            pytest.param(pos, one, None, id="all_tokens_on_one_expert")]
+
+
+@pytest.mark.parametrize("x,router_w,empty", routing_cases())
+def test_dropless_layer_equals_the_dense_masked_form(x, router_w, empty):
+    r = moe_case(x, router_w)
+    T, k = x.shape[0], 2
+    # every one of the T x k assignments reached an expert: nothing dropped
+    assert int(r["load"].sum()) == T * k
+    np.testing.assert_array_equal(
+        r["load"], np.bincount(r["index"].reshape(-1), minlength=4))
+    if empty is not None:
+        assert r["load"][empty] == 0
+    if router_w is not None and empty is None:      # all on expert 0, then 1
+        np.testing.assert_array_equal(r["load"], [T, T, 0, 0])
+    close(r["out"], r["want"])
+    for got, want in zip(r["grads"], r["want_grads"]):
+        close(got, want, 5e-5)
+    close(r["prob"].sum(-1), np.ones(T))
+    close(r["logz"], jax.nn.logsumexp(
+        jnp.dot(x, r["weights"][0], precision=HI), -1))
+
+
+def test_duplicated_tokens_get_identical_outputs():
+    x = rng(4).randn(6, 16).astype("float32")
+    r = moe_case(np.concatenate([x, x]), None)
+    np.testing.assert_allclose(r["out"][:6], r["out"][6:], atol=1e-6)
+    np.testing.assert_array_equal(r["index"][:6], r["index"][6:])
+
+
+def test_router_is_float32_and_its_indices_carry_no_gradient():
+    main, startup = fluid.Program(), fluid.Program()
+    with fluid.unique_name.guard(), fluid.program_guard(main, startup):
+        x = fluid.data("x", [8, 16], "bfloat16", append_batch_size=False)
+        out, aux = layers.moe_ffn(x, 4, 2, 8, name="moe")
+        assert out.dtype == "bfloat16" and tuple(out.shape) == (8, 16)
+        assert aux["prob"].dtype == "float32"
+        assert aux["index"].dtype == "int32" and aux["index"].stop_gradient
+        assert aux["load"].dtype == "int32" and aux["load"].stop_gradient
+        block = main.global_block()
+        assert block.var("moe_router_w").dtype == "float32"
+        assert block.var("moe_gate_w").dtype == "bfloat16"
+        assert tuple(block.var("moe_gate_w").shape) == (4, 16, 8)
+        assert tuple(block.var("moe_down_w").shape) == (4, 8, 16)
+        fluid.append_backward(layers.reduce_sum(layers.cast(out, "float32")))
+    types = [op.type for op in main.global_block().ops]
+    assert types.count("moe_expert_matmul") == 3
+    assert types.count("moe_expert_matmul_grad") == 3
+    assert not [v for v in main.global_block().vars
+                if "@GRAD" in v and ("index" in v.lower() or "count" in
+                                     v.lower())]
+    # one glob covers the expert layer
+    moe_ops = [t for t in types if t.startswith(("moe_", "swiglu"))]
+    assert {"moe_router", "moe_dispatch", "moe_expert_matmul", "swiglu",
+            "moe_combine"} <= set(moe_ops)
+
+
+def test_moe_ops_have_no_capacity_attribute():
+    main, startup = fluid.Program(), fluid.Program()
+    with fluid.unique_name.guard(), fluid.program_guard(main, startup):
+        x = fluid.data("x", [8, 16], "float32", append_batch_size=False)
+        layers.moe_ffn(x, 4, 2, 8, name="moe")
+    for op in main.global_block().ops:
+        assert not [a for a in op.attrs if "capacity" in a.lower()
+                    or "drop" in a.lower()], (op.type, op.attrs)
+
+
+def test_moe_gauges_and_load_stats():
+    from paddle_tpu.observability.metrics import MetricsRegistry
+    main, startup = fluid.Program(), fluid.Program()
+    with fluid.unique_name.guard(), fluid.program_guard(main, startup):
+        x = fluid.data("x", [8, 16], "bfloat16", append_batch_size=False)
+        h, _ = layers.moe_ffn(x, 4, 2, 8, name="a")
+        layers.moe_ffn(h, 4, 2, 8, name="b")
+    reg = MetricsRegistry()
+    obs_moe.update_moe_gauges(main, "p", registry=reg)
+    value = lambda n: reg.gauge(n, program="p").value       # noqa: E731
+    assert value("moe_layers") == 2 and value("moe_experts") == 4
+    assert value("moe_assignments_per_step") == 2 * 8 * 2
+    assert value("moe_expert_param_bytes") == 2 * 3 * 4 * 16 * 8 * 2
+    empty = fluid.Program()
+    obs_moe.update_moe_gauges(empty, "q", registry=reg)
+    # a program without an expert layer sets nothing
+    assert set(reg.get("moe_layers").children) == {(("program", "p"),)}
+    stats = obs_moe.load_stats([4, 0, 8, 4])
+    assert stats == {"max": 8.0, "mean": 4.0, "max_over_mean": 2.0,
+                     "empty": 1}
