@@ -2,9 +2,11 @@
 Pallas kernel (VERDICT r4 #3).
 
 Every other bench runs S=128 (BERT) or S=64 (NMT), below the
-AUTO_PALLAS_MIN_S=1024 crossover (ops/pallas_attention.py) -- so the Pallas
-kernel's on-TPU win was asserted from a microbench, never recorded as a
-driver artifact. This bench pretrains BERT-base at S=2048 (the auto
+AUTO_PALLAS_MIN_S crossover (ops/pallas_attention.py: 256 since PR 27, where
+the measured table is) -- so the Pallas kernel's on-TPU win was asserted
+from a microbench, never recorded as a driver artifact. (The benchmark's
+cells bert_base.pretrain_s512 / _s2048 and olmoe_1b_7b.pretrain_s4096 now
+record it: PERF.md.) This bench pretrains BERT-base at S=2048 (the auto
 policy's Pallas domain) twice -- impl='auto' (must select the flash kernel)
 and impl='composed' (the XLA path) -- and prints:
 
@@ -12,7 +14,8 @@ and impl='composed' (the XLA path) -- and prints:
     with MFU counted by program_flops (attention matmuls included);
   - flash_vs_composed: the measured end-to-end step-time ratio. >1 means
     the Pallas kernel wins at this length, the claim that justifies its
-    existence; if it ever drops below 1, retune AUTO_PALLAS_MIN_S.
+    existence; if it ever drops below 1, rerun `python -m
+    paddle_tpu.tuning --suite flash` and rewrite AUTO_PALLAS_MIN_S from it.
 
 vs_baseline: null -- the reference publishes no V100 number for S=2048
 pretraining (its max_position_embeddings caps at 512); the line exists to

@@ -45,6 +45,7 @@ FULL = dict(
     bert=dict(vocab_size=30522, hidden=768, n_layers=12, n_heads=12),
     train=dict(batch=128, seq=128, steps=10),
     longseq=dict(batch=4, seq=2048),
+    midseq=dict(batch=32, seq=512),     # bert_base.pretrain_s512's attention
     causal=dict(batch=2, heads=16, seq=4096, head_dim=128),  # OLMoE's flash
     int8=4096,
     convbn=dict(batch=128, hw=14, cin=1024, cout=256),  # res4 1x1 expand
@@ -55,6 +56,7 @@ TINY = dict(
     bert=dict(vocab_size=512, hidden=64, n_layers=2, n_heads=4),
     train=dict(batch=8, seq=16, steps=4),
     longseq=dict(batch=1, seq=256),
+    midseq=dict(batch=4, seq=128),
     causal=dict(batch=1, heads=2, seq=256, head_dim=32),
     int8=256,
     convbn=dict(batch=7, hw=8, cin=128, cout=128),
@@ -344,9 +346,10 @@ def phase_kernels(sizes, ctx):
     # step, 2^-9) of its kernel-made factor, out or dV, and one of the
     # probabilities inside; independent, so each side is off by about
     # sqrt(2) x 2^-9 x the 2-norm of its terms. Held to 2^-8 x both norms.
-    # Twice: BERT's shape (d=64, padding bias, dropout) and the decoder's
-    # (causal, d=128, S=4096, no bias, no dropout), where the identity holds
-    # only if both kernels mask the same triangle.
+    # Three times: BERT's shape (d=64, padding bias, dropout) at S=2048 and
+    # at S=512 (the kernels' shortest cell), and the decoder's (causal,
+    # d=128, S=4096, no bias, no dropout), where the identity holds only if
+    # both kernels mask the same triangle.
     from paddle_tpu.ops import pallas_attention as pa
 
     def adjoint(b, heads, seq, d, dropout, causal, padded):
@@ -384,6 +387,10 @@ def phase_kernels(sizes, ctx):
     heads = sizes["bert"]["n_heads"]
     facts["flash_dropout_adjoint"] = adjoint(
         ls["batch"], heads, ls["seq"], sizes["bert"]["hidden"] // heads,
+        0.1, False, True)
+    ms = sizes["midseq"]        # one Q block a head (block_q = S)
+    facts["flash_dropout_adjoint_s512"] = adjoint(
+        ms["batch"], heads, ms["seq"], sizes["bert"]["hidden"] // heads,
         0.1, False, True)
     c = sizes["causal"]
     facts["flash_causal_adjoint"] = adjoint(
@@ -613,7 +620,10 @@ def phase_dataset(sizes, ctx):
 
 def phase_mesh(sizes, ctx):
     """One process, four chips: BERT-base under CompiledProgram.with_strategy
-    as dp=4 and dp=2 x mp=2, then the dry run's sp / ep / pp layouts."""
+    as dp=4 and dp=2 x mp=2, dp=4 again at bert_base.pretrain_s512's length
+    (where one chip takes the flash kernels and a mesh without sp must not:
+    GSPMD cannot partition a Mosaic call), then the dry run's sp / ep / pp
+    layouts."""
     import paddle_tpu as fluid
     from paddle_tpu.models import bert
     import __graft_entry__ as layouts
@@ -641,13 +651,44 @@ def phase_mesh(sizes, ctx):
             "layer0_attn_qkv_w", want_shard=(hidden, 3 * hidden // 2),
             tol=LOSS_RTOL, reference_loss=one_chip),
     ]
+    # the length at which one chip takes the flash kernels: the dp mesh has
+    # to keep XLA's composed lowering and still give the one-device loss
+    from paddle_tpu.observability.metrics import REGISTRY
+    m = sizes["midseq"]
+
+    def lowerings():
+        total = {}
+        for k, c in (REGISTRY.get("attention_lowering_total") or {}).items():
+            if dict(k)["s"] == str(m["seq"]):
+                impl = dict(k)["impl"]
+                total[impl] = total.get(impl, 0) + c.value
+        return total
+    mid_build = lambda: bert_program(                       # noqa: E731
+        sizes, m["batch"], m["seq"], dropout=0.0)[:3]
+    mid_feed = bert_feed(sizes, m["batch"], m["seq"])
+    mid_one_chip = layouts.one_device_loss(mid_build, mid_feed)
+    before = lowerings()
+    records.append(layouts.run_layout(
+        f"bert-base dp4 S={m['seq']}", mid_build, mid_feed,
+        fluid.DistributedStrategy(mesh_shape={"dp": 4},
+                                  data_rules=data_rules),
+        "layer0_attn_qkv_w", want_shard=(hidden, 3 * hidden),
+        tol=LOSS_RTOL, reference_loss=mid_one_chip))
+    under_dp = {k: int(v - before.get(k, 0)) for k, v in lowerings().items()
+                if v != before.get(k, 0)}
+    say(f"  mesh: fused_attention at S={m['seq']}: one device "
+        f"{json.dumps({k: int(v) for k, v in before.items()})}, dp4 "
+        f"{json.dumps(under_dp)}")
+    assert set(under_dp) == {"xla"}, under_dp
     records += layouts.run_layouts(4)
     for rec in records:
         say(f"  mesh: {json.dumps(rec)}")
     return {"asserted": "per layout: first-step loss equals the one-device "
                         "loss; params and feeds on four distinct devices "
                         "with the shard shapes the specs imply; bytes in "
-                        "use grew on every device",
+                        "use grew on every device; dp4 at the length one "
+                        "chip gives the flash kernels lowers every "
+                        "fused_attention on XLA's composed path",
             "layouts": [r["layout"] for r in records]}
 
 

@@ -615,6 +615,9 @@ class Executor:
         _obs_memory.sample_device_memory("compile")
         from ..observability import moe as _obs_moe
         _obs_moe.update_moe_gauges(program, label)
+        from ..observability import attention as _obs_attention
+        _obs_attention.count_lowerings(
+            program._lowering_notes.pop("fused_attention", {}), label)
         # IR->HLO attribution walk: once per compile miss, only when obs /
         # PADDLE_TPU_OBS_ATTRIB / an armed --emit-hlo capture asks for it
         # (on_compile is a no-op otherwise and never raises)
@@ -656,6 +659,10 @@ class Executor:
                 if restored is None:
                     # the span is for restores (their own goodput cause)
                     _obs_timeline.discard()
+        # what the op lowerings note while this compile traces them
+        # (LowerCtx.note) is read by _post_compile_telemetry below; anything
+        # older was left by a trace that was not the executor's
+        program._lowering_notes.clear()
         if restored is not None:
             compiled.executable = restored
         else:

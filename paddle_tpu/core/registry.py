@@ -72,6 +72,17 @@ class LowerCtx:
     def attr(self, name, default=None):
         return self.attrs.get(name, default)
 
+    def note(self, kind: str, value) -> None:
+        """Leave a trace-time fact about this op (which kernel its lowering
+        took, say) for the executor, which empties the notes before a
+        compile and reads them after it (``Executor._materialize_miss``).
+        Keyed by the op's salt: the forward a grad op lowers again under
+        ``jax.vjp`` lands on its forward op's entry. Nothing is kept where
+        no Program is being lowered."""
+        if self.program is not None:
+            self.program._lowering_notes.setdefault(kind, {})[
+                self._salt] = value
+
     def rng(self, offset: int = 0):
         import jax
         key = self._base_key

@@ -1,0 +1,31 @@
+"""Attention telemetry: which lowering each ``fused_attention`` op of a
+compiled program took, as a labelled count added once per compile.
+
+The op notes its choice while the executor traces it (``ctx.note`` in
+``ops/pallas_attention.py``, keyed by the op's salt: the forward a grad op
+lowers again under ``jax.vjp`` lands on the same key), and the executor hands
+the notes of the compile it just made to ``count_lowerings``;
+``autotune_decisions_total{choice,source}`` says beside it whether a default
+or a persisted decision answered.
+"""
+from __future__ import annotations
+
+from collections import Counter
+from typing import Optional
+
+from .metrics import REGISTRY, MetricsRegistry
+
+
+def count_lowerings(notes: dict, program: str,
+                    registry: Optional[MetricsRegistry] = None) -> None:
+    """``attention_lowering_total{program,impl,s,block_q}``: the
+    ``fused_attention`` ops the trace just compiled, by lowering (``pallas``,
+    ``xla``, ``ring``, ``ulysses``), sequence length and the kernels' Q
+    block (0 where no kernel ran). ``notes`` maps each op's salt to its
+    ``(impl, s, block_q)``; nothing is added for a program without the op."""
+    registry = registry or REGISTRY
+    for (impl, s, block_q), n in Counter(notes.values()).items():
+        registry.counter(
+            "attention_lowering_total",
+            "fused_attention ops compiled, by the lowering each took",
+            program=program, impl=impl, s=str(s), block_q=str(block_q)).inc(n)

@@ -12,14 +12,19 @@ per step round-tripped, ~3x that in backward).
 Design:
   * Per the registry's kernel-choice contract (core/registry.py:10), this is an
     *alternative lowering* for the `fused_attention` op: `impl=auto` picks the
-    Pallas kernel on TPU from S >= AUTO_PALLAS_MIN_S up (XLA's own fusion wins
-    below; see the measured crossover at the constant), the ring schedule
-    under an sp>1 mesh, and the composed jnp lowering otherwise or for
-    unsupported shapes. `impl='pallas'` forces the kernel at any supported S
-    and raises off TPU (ops/pallas_mode.py: only the test harness may ask for
-    the Pallas interpreter, so the CPU suite exercises the same kernel body).
+    Pallas kernels on TPU from S >= AUTO_PALLAS_MIN_S up (256 since PR 27;
+    XLA's own fusion wins at S=128; the measured table is at the constant)
+    where the step is jitted for one device, the ring schedule under an
+    sp>1 mesh, and the composed jnp lowering otherwise (a dp / mp mesh
+    without sp: GSPMD cannot partition a Mosaic call) or for unsupported
+    shapes. `impl='pallas'` forces the kernels
+    at any supported S and raises off TPU (ops/pallas_mode.py: only the test
+    harness may ask for the Pallas interpreter, so the CPU suite exercises
+    the same kernel body).
   * Whole K/V rows for one (batch, head) are staged in VMEM (S*D*2 bytes
-    each); Q is blocked at BLK_Q rows. The softmax statistics (max, sum) and
+    each); Q is blocked at default_block_q(S) rows: the whole of S below
+    1024, BLK_Q from there (the `fused_attention.block_sizes` choice's
+    default). The softmax statistics (max, sum) and
     every accumulation are f32; the MXU is fed both operands of every product
     in the input's dtype with preferred_element_type=f32 (bf16 inputs: the
     probabilities and dS are rounded to bf16 once, as the composed lowering
@@ -52,16 +57,30 @@ import math
 
 from ..core.registry import register
 
-# Q rows a grid step: 256 beats 128 forward + backward at S=1024, 2048 and
-# 4096 (3.99 / 6.56 / 11.78 against 4.42 / 7.01 / 12.18 ms a layer of 16k
-# tokens; 512 gains 2% more at S=2048 and does not fit at 4096. Chip runs, PR
-# 25). The default of the `fused_attention.block_sizes` tunable choice.
+# The smallest Q block. Every S the kernel takes is a multiple of it
+# (supports_pallas), every block_q too, and the dropout mask is drawn by
+# blocks of it whatever block_q is.
+_MIN_BLK_Q = 128
+
+# Q rows a grid step from S=1024 up: 256 beats 128 forward + backward at
+# S=1024, 2048 and 4096 (3.99 / 6.56 / 11.78 against 4.42 / 7.01 / 12.18 ms a
+# layer of 16k tokens; chip runs, PR 25). 512 and 1024 gain 2-8% more at
+# S=1024 and 2048 (PERF.md section 7) and 512 does not fit at 4096.
 BLK_Q = 256
 
-# The smallest Q block. Every S the kernel takes is a multiple of it
-# (supports_pallas), a block_q that does not divide S falls back to it, and
-# the dropout mask is drawn by blocks of it whatever block_q is.
-_MIN_BLK_Q = 128
+
+def default_block_q(S):
+    """The Q block the kernels take at sequence length S where no tuning
+    decision says otherwise; always divides S. Below 1024 one block a
+    (batch, head): the whole [S, S] tile in one grid step beats every
+    smaller block at S=256 ... 768 (2.43 against 2.67 ms at 256 and 3.33 at
+    128, S=512, forward + backward of 16k tokens; table in PERF.md section 6,
+    chip runs, PR 27) and compiles to S=896 in bf16 and f32. From 1024 up
+    BLK_Q, or _MIN_BLK_Q where that does not divide S."""
+    if S < 1024:
+        return S
+    return BLK_Q if S % BLK_Q == 0 else _MIN_BLK_Q
+
 
 # Scoped VMEM the backward kernel may take. Mosaic's default (16 MiB of the
 # v5e's 128) holds its [block_q, S] temporaries to S=2048 at BLK_Q; S=4096
@@ -69,15 +88,20 @@ _MIN_BLK_Q = 128
 # and is 4% slower under a raised limit (chip runs, PR 25), so it keeps it.
 BWD_VMEM_LIMIT_BYTES = 64 * 1024 * 1024
 
-# 'auto' uses the Pallas kernel only from this sequence length up: measured
-# on TPU v5e (bf16, H=12 D=64, B*S fixed at 16k tokens), XLA's own fused
-# attention wins below it (6.1 vs 7.3 ms at S=128) and flash wins above
-# (7.4 vs 10.0 ms at S=2048) -- the online-softmax tiling pays off once the
-# S x S score tile stops fitting cache-friendly shapes. impl='pallas' forces
-# the kernel regardless. This crossover is now only the DEFAULT of the
-# `fused_attention.backend` tunable choice (paddle_tpu/tuning/): a persisted
-# autotune decision overrides it per (shape bucket, device).
-AUTO_PALLAS_MIN_S = 1024
+# 'auto' takes the Pallas kernels from this sequence length up; the default
+# of the `fused_attention.backend` tunable choice (paddle_tpu/tuning/), which
+# a persisted autotune decision overrides per (shape bucket, device).
+# Measured on the v5e, forward + backward of 16k tokens, bf16, H=12 D=64, a
+# [B,1,1,S] bias, dropout 0.1, ms (chip runs, PR 27; XLA's composed lowering
+# against the kernels at default_block_q): S=128 2.13 / 3.13, 256 3.94 /
+# 2.33, 384 5.56 / 2.39, 512 8.17 / 2.43, 1024 13.07 / 4.00, 2048 25.48 /
+# 6.81; without dropout at S=512 4.97 / 2.14; causal, D=128, no bias, S=512
+# 7.00 / 1.92. The composed lowering's S x S scores, probabilities and mask
+# in HBM grow with S; the kernels' cost for 16k tokens hardly moves below
+# S=512. XLA wins at S=128. Under a mesh of more than one device without
+# sp the op keeps the composed lowering at every S (fused_attention: a
+# Mosaic call has no partitioning rule). impl='pallas' forces the kernels.
+AUTO_PALLAS_MIN_S = 256
 
 
 def _pl():
@@ -247,12 +271,10 @@ def _bwd_kernel(scale, dropout, causal, has_bias, *refs):
 
 def _operands(q, k, v, bias, seed, block_q):
     """The kernels' common operands and block specs, and the number of Q
-    blocks: of block_q rows if that divides S, else of _MIN_BLK_Q."""
+    blocks (block_q divides S: _flash has seen to it)."""
     import jax.numpy as jnp
     pl, pltpu = _pl()
     B, H, S, D = q.shape
-    if S % block_q:
-        block_q = _MIN_BLK_Q
     args = [x.reshape(B * H, S, D) for x in (q, k, v)]
     qspec = pl.BlockSpec((1, block_q, D), lambda b, i: (b, i, 0),
                          memory_space=pltpu.VMEM)
@@ -282,17 +304,44 @@ def _compiler_params(interpret, vmem_limit_bytes=None):
         vmem_limit_bytes=vmem_limit_bytes)}
 
 
-import jax as _jax  # custom_vjp must wrap at def time
+import jax as _jax  # custom_vjp and jit must wrap at def time
+
+# _flash's arguments after the arrays, all static
+_STATIC = ("scale", "dropout", "causal", "interpret", "block_q")
+
+
+def _flash(q, k, v, bias, seed, scale, dropout, causal, interpret,
+           block_q=None):
+    """The flash kernels, differentiable in q, k and v. ``block_q`` (Q rows a
+    grid step) divides S; None takes ``default_block_q(S)``."""
+    S = q.shape[2]
+    if block_q is None:
+        block_q = default_block_q(S)
+    if S % block_q or block_q % _MIN_BLK_Q:
+        raise ValueError(
+            f"flash attention: block_q={block_q} must divide S={S} and be a "
+            f"multiple of {_MIN_BLK_Q}")
+    return _flash_vjp(q, k, v, bias, seed, scale, dropout, causal, interpret,
+                      block_q)
+
 
 @functools.partial(_jax.custom_vjp, nondiff_argnums=(5, 6, 7, 8, 9))
-def _flash(q, k, v, bias, seed, scale, dropout, causal, interpret,
-           block_q=BLK_Q):
-    return _flash_fwd_impl(q, k, v, bias, seed, scale, dropout, causal,
-                           interpret, block_q)
+def _flash_vjp(q, k, v, bias, seed, scale, dropout, causal, interpret,
+               block_q):
+    return _fwd_call(q, k, v, bias, seed, scale, dropout, causal, interpret,
+                     block_q)
 
 
-def _flash_fwd_impl(q, k, v, bias, seed, scale, dropout, causal, interpret,
-                    block_q):
+# Each kernel call sits behind a jit of its own. The layers of a model call
+# it with the same shapes and static arguments: the first call traces the
+# kernel body and lowers it to Mosaic, the others (and the forward a
+# Program's grad op traces again under jax.vjp) find that trace, and the
+# lowered module holds one function a kernel, called once a layer. Without
+# it every call is traced and lowered by itself: 4 s of set-up at 12 layers
+# (compile.trace_lower_s 8.3 against 4.4 s, ledger, PR 26).
+@functools.partial(_jax.jit, static_argnames=_STATIC)
+def _fwd_call(q, k, v, bias, seed, scale, dropout, causal, interpret,
+              block_q):
     import jax
     pl, _ = _pl()
     B, H, S, D = q.shape
@@ -311,9 +360,9 @@ def _flash_fwd_impl(q, k, v, bias, seed, scale, dropout, causal, interpret,
 
 
 def _flash_fwd(q, k, v, bias, seed, scale, dropout, causal, interpret,
-               block_q=BLK_Q):
-    out = _flash_fwd_impl(q, k, v, bias, seed, scale, dropout, causal,
-                          interpret, block_q)
+               block_q):
+    out = _fwd_call(q, k, v, bias, seed, scale, dropout, causal, interpret,
+                    block_q)
     # Inputs only. A Program's generic grad op (core/registry.py) lowers the
     # forward again under jax.vjp: a kernel output among the residuals (a
     # log-sum-exp, say) keeps that second forward kernel alive, which costs
@@ -321,12 +370,12 @@ def _flash_fwd(q, k, v, bias, seed, scale, dropout, causal, interpret,
     return out, (q, k, v, bias, seed)
 
 
-def _flash_bwd(scale, dropout, causal, interpret, block_q, res, g):
+@functools.partial(_jax.jit, static_argnames=_STATIC)
+def _bwd_call(q, k, v, bias, seed, g, scale, dropout, causal, interpret,
+              block_q):
     import jax
     import jax.numpy as jnp
-    import numpy as np
     pl, pltpu = _pl()
-    q, k, v, bias, seed = res
     B, H, S, D = q.shape
     args, in_specs, qspec, kvspec, n_q = _operands(q, k, v, bias, seed,
                                                    block_q)
@@ -346,12 +395,21 @@ def _flash_bwd(scale, dropout, causal, interpret, block_q, res, g):
         **_compiler_params(interpret, BWD_VMEM_LIMIT_BYTES),
     )(*args)
     shape = (B, H, S, D)
-    return (dq.reshape(shape), dk.reshape(shape), dv.reshape(shape),
-            None if bias is None else jnp.zeros_like(bias),
+    return dq.reshape(shape), dk.reshape(shape), dv.reshape(shape)
+
+
+def _flash_bwd(scale, dropout, causal, interpret, block_q, res, g):
+    import jax
+    import jax.numpy as jnp
+    import numpy as np
+    q, k, v, bias, seed = res
+    dq, dk, dv = _bwd_call(q, k, v, bias, seed, g, scale, dropout, causal,
+                           interpret, block_q)
+    return (dq, dk, dv, None if bias is None else jnp.zeros_like(bias),
             np.zeros(np.shape(seed), jax.dtypes.float0))
 
 
-_flash.defvjp(_flash_fwd, _flash_bwd)
+_flash_vjp.defvjp(_flash_fwd, _flash_bwd)
 
 
 def supports_pallas(B, H, S, D, bias_shape, dropout, is_tpu):
@@ -385,8 +443,11 @@ def fused_attention(ctx, ins):
     GSPMD alone would all-gather K/V to every device; 'ulysses' instead does
     the all-to-all head-scatter schedule (parallel/ulysses.py, needs heads
     divisible by sp). Otherwise 'auto' is the Pallas flash kernel on
-    TPU-supported shapes from S >= AUTO_PALLAS_MIN_S (below that XLA's own
-    fusion is measurably faster), else the composed jnp path.
+    TPU-supported shapes from S >= AUTO_PALLAS_MIN_S (at S=128 XLA's own
+    fusion is measurably faster) where the jit spans one device, else the
+    composed jnp path (a dp or mp mesh without sp: a Mosaic call cannot be
+    partitioned by GSPMD). Which one an op took is counted at each compile
+    (``ctx.note``; observability/attention.py).
     """
     import jax
     import jax.numpy as jnp
@@ -429,11 +490,13 @@ def fused_attention(ctx, ins):
                 f"({h_local} heads per mp shard), "
                 f"bias={None if bias is None else bias.shape}")
         from ..parallel import ulysses as _uly
+        ctx.note("fused_attention", ("ulysses", S, 0))
         seed = jax.random.randint(ctx.rng(), (), 0, 2**31 - 1, jnp.int32)
         return {"Out": [_uly.ulysses_attention(
             q, k, v, bias, float(scale), float(dropout), causal, seed, gm)]}
     if ring_ok and impl in ("auto", "ring"):
         from ..parallel import ring_attention as _ring
+        ctx.note("fused_attention", ("ring", S, 0))
         seed = jax.random.randint(ctx.rng(), (), 0, 2**31 - 1, jnp.int32)
         return {"Out": [_ring.ring_attention(
             q, k, v, bias, float(scale), float(dropout), causal, seed, gm)]}
@@ -448,24 +511,43 @@ def fused_attention(ctx, ins):
                 f"S={S}, bias={bias_shape}, dropout={dropout}, "
                 f"backend_tpu={is_tpu}. Use impl='auto' to let the op "
                 f"choose the composed lowering.")
-    # impl='auto' backend + block sizes are tunable choice points: with a
-    # persisted autotune decision (PADDLE_TPU_TUNE=cached/search) the
-    # measured winner is used; without one the default reproduces the
-    # static S >= AUTO_PALLAS_MIN_S crossover and BLK_Q exactly.
+    # A Mosaic call has no partitioning rule: a jit over more than one device
+    # refuses to lower one outside a shard_map ("Mosaic kernels cannot be
+    # automatically partitioned"). Under such a mesh only the islands above
+    # hold the kernels; 'auto' takes the composed lowering, which GSPMD
+    # partitions by batch and heads, at every S and whatever a persisted
+    # decision says (a batch/head island of its own: PERF.md section 7).
+    one_device = gm is None or gm.size == 1
+    # impl='auto' backend + block sizes are tunable choice points: a
+    # persisted autotune decision (PADDLE_TPU_TUNE=cached/search) answers
+    # where there is one, else the defaults measured on the v5e
+    # (AUTO_PALLAS_MIN_S, default_block_q).
     from ..tuning import decide as _decide
     tune_params = {"b": B, "h": H, "s": S, "d": D, "dtype": str(q.dtype),
                    "has_bias": bias is not None, "dropout": float(dropout),
                    "causal": causal, "scale": float(scale)}
     use_pallas = impl == "pallas" or (
-        impl == "auto" and pallas_mode.available() and
+        impl == "auto" and one_device and pallas_mode.available() and
         supports_pallas(B, H, S, D, bias_shape, dropout, is_tpu) and
         _decide("fused_attention.backend", tune_params) == "pallas")
     if use_pallas:
         block_q, _ = _decide("fused_attention.block_sizes", tune_params)
-        seed = jax.random.randint(ctx.rng(), (), 0, 2**31 - 1, jnp.int32)
+        ctx.note("fused_attention", ("pallas", S, int(block_q)))
+        # The kernels read the seed for a dropout mask alone. A test-mode
+        # op draws none, like the dropout op under is_test: an inference
+        # program then holds no random op (0.5 s of set-up for a threefry
+        # lowering nothing reads). A training op without dropout draws it
+        # all the same: olmoe_1b_7b.pretrain_s4096, which has no other
+        # random op, runs 0.8% slower without it (XLA's schedule; chip runs,
+        # PR 27).
+        if dropout or not ctx.attr("is_test", False):
+            seed = jax.random.randint(ctx.rng(), (), 0, 2**31 - 1, jnp.int32)
+        else:
+            seed = jnp.int32(0)
         out = _flash(q, k, v, bias, seed, float(scale), float(dropout), causal,
                      pallas_mode.interpret(), block_q)
     else:
+        ctx.note("fused_attention", ("xla", S, 0))
         out = composed_attention(q, k, v, bias, float(scale), float(dropout),
                                  causal, ctx.rng())
     return {"Out": [out]}

@@ -84,9 +84,31 @@ RESNET_CONV_BN_SHAPES = (
     (6272, 2048, 512),
 )
 
-#: flash-attention suite: BERT-like heads (H=12, D=64) with B*S pinned at
-#: 16k tokens, sweeping S across the measured XLA/Pallas crossover
-FLASH_SUITE_S = (128, 512, 1024, 2048)
+#: flash-attention suite, B*S pinned at 16k tokens. The BERT cells' op (H=12,
+#: D=64, a [B,1,1,S] bias, dropout 0.1) with S across the XLA/Pallas
+#: crossover, and the decoder family's (models/decoder_lm.py: H=16, D=128,
+#: causal, no bias, no dropout)
+FLASH_SUITE_S = (128, 256, 384, 512, 640, 768, 1024, 2048)
+FLASH_SUITE_DECODER_S = (512, 1024, 2048)
+
+
+def _flash_suite_params(dtype: str) -> List[dict]:
+    """The suite's ops as ``decide()`` params. Off a TPU the kernel cannot
+    draw a dropout mask (no interpreter lowering), so dropout is 0 there;
+    on one, S=512 is also timed without it (a saved BERT served)."""
+    from ..ops import pallas_mode
+    drop = 0.1 if pallas_mode.on_tpu() else 0.0
+
+    def op(s, h, d, has_bias, dropout, causal):
+        return {"b": max(1, 16384 // s), "h": h, "s": s, "d": d,
+                "dtype": dtype, "has_bias": has_bias, "dropout": dropout,
+                "causal": causal}
+
+    out = [op(s, 12, 64, True, drop, False) for s in FLASH_SUITE_S]
+    if drop and 512 in FLASH_SUITE_S:
+        out.append(op(512, 12, 64, True, 0.0, False))
+    out += [op(s, 16, 128, False, 0.0, True) for s in FLASH_SUITE_DECODER_S]
+    return out
 
 
 def _suite_dtype() -> str:
@@ -134,10 +156,7 @@ def tune_suite(suite: str = "all", mode: Optional[str] = "search",
             out.append(_tune_one("conv2d_bn_fused.backend",
                                  {"m": m, "k": k, "n": n, "dtype": dt}, mode))
     if suite in ("flash", "all"):
-        for s in FLASH_SUITE_S:
-            params = {"b": max(1, 16384 // s), "h": 12, "s": s, "d": 64,
-                      "dtype": dt, "has_bias": False, "dropout": 0.0,
-                      "causal": False}
+        for params in _flash_suite_params(dt):
             out.append(_tune_one("fused_attention.backend", params, mode))
             if "pallas" in get_choice(
                     "fused_attention.backend").candidates(params):

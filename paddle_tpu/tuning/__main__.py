@@ -120,7 +120,7 @@ def _selftest() -> int:
     # ~MB-scale bench inputs): the selftest checks the decide -> measure ->
     # persist pipeline, not this host's actual crossovers
     real_convbn = tuning.RESNET_CONV_BN_SHAPES
-    real_flash = tuning.FLASH_SUITE_S
+    real_flash = tuning.FLASH_SUITE_S, tuning.FLASH_SUITE_DECODER_S
     tmp = tempfile.mkdtemp(prefix="paddle_tpu_tune_selftest_")
     path = os.path.join(tmp, "autotune.json")
     failures = []
@@ -128,7 +128,7 @@ def _selftest() -> int:
         measure_mod.time_callable = fake_time
         cache_mod.reset_for_tests(path)
         tuning.RESNET_CONV_BN_SHAPES = ((896, 64, 128), (896, 128, 128))
-        tuning.FLASH_SUITE_S = (128, 2048)
+        tuning.FLASH_SUITE_S, tuning.FLASH_SUITE_DECODER_S = (128, 2048), ()
         entries = tuning.tune_suite("all", mode="search", dtype="float32")
         if not entries:
             failures.append("tune_suite returned no entries")
@@ -171,7 +171,7 @@ def _selftest() -> int:
         measure_mod.time_callable = real_time
         cache_mod.CACHE = real_cache
         tuning.RESNET_CONV_BN_SHAPES = real_convbn
-        tuning.FLASH_SUITE_S = real_flash
+        tuning.FLASH_SUITE_S, tuning.FLASH_SUITE_DECODER_S = real_flash
         import shutil
         shutil.rmtree(tmp, ignore_errors=True)
     if failures:

@@ -25,11 +25,13 @@ The four wired choice points (the ROOFLINE/ISSUE set):
 ``conv2d_bn_fused.backend``     Pallas fused kernel vs XLA chain for the
                                 train-mode 1x1-conv+BN op
 ``fused_attention.backend``     Pallas flash kernel vs XLA's own fusion
-                                (replaces the hardcoded AUTO_PALLAS_MIN_S
-                                crossover as the *auto* policy)
+                                (the *auto* policy; its default is the
+                                measured AUTO_PALLAS_MIN_S crossover)
 ``fused_attention.block_sizes`` flash (block_q, block_k); block_k is pinned
                                 to S for now -- the kernel stages whole K/V
-                                rows in VMEM -- so the search is over block_q
+                                rows in VMEM -- so the search is over the
+                                block_q that divide S (default: the
+                                kernel's default_block_q(S))
 ``conv2d.layout``               run a conv NHWC vs NCHW regardless of the
                                 declared data_format (transposing at the op
                                 boundary; XLA cancels adjacent transposes)
@@ -270,8 +272,8 @@ def _attn_bucket(params):
 class FlashBackend(TunableChoice):
     id = "fused_attention.backend"
     doc = ("impl='auto' backend for fused_attention: 'pallas' (flash "
-           "kernel) or 'xla' (composed jnp attention, XLA-fused); replaces "
-           "the hardcoded S >= AUTO_PALLAS_MIN_S crossover with measurement")
+           "kernel) or 'xla' (composed jnp attention, XLA-fused); default: "
+           "the measured S >= AUTO_PALLAS_MIN_S crossover")
 
     def bucket(self, params):
         return _attn_bucket(params)
@@ -297,33 +299,52 @@ class FlashBackend(TunableChoice):
         return "xla"
 
     def bench(self, params, candidate):
-        import jax
-        import math
-        q, bias = _attn_inputs(params)
-        scale = float(params.get("scale") or 1.0 / math.sqrt(int(params["d"])))
-        dropout = float(params.get("dropout", 0.0))
-        causal = bool(params.get("causal"))
         if candidate == "pallas":
-            from ..ops import pallas_mode
-            from ..ops.pallas_attention import _flash
-            if not pallas_mode.available():
-                return None
-            interpret = pallas_mode.interpret()
-
-            def pallas_fn(q, k, v):
-                return _flash(q, k, v, bias, 0, scale, dropout, causal,
-                              interpret)
-
-            return pallas_fn, (q, q, q)
-
+            return _flash_bench(params, None)
+        import jax
         from ..ops.pallas_attention import composed_attention
+        q, bias, scale, dropout, causal = _attn_bench_args(params)
         rng = jax.random.PRNGKey(0)
 
         def xla_fn(q, k, v):
-            return composed_attention(q, k, v, bias, scale, dropout, causal,
-                                      rng)
+            return _fwd_bwd(lambda q, k, v: composed_attention(
+                q, k, v, bias, scale, dropout, causal, rng), q, k, v)
 
         return xla_fn, (q, q, q)
+
+
+def _attn_bench_args(params):
+    import math
+    q, bias = _attn_inputs(params)
+    scale = float(params.get("scale") or 1.0 / math.sqrt(int(params["d"])))
+    return (q, bias, scale, float(params.get("dropout", 0.0)),
+            bool(params.get("causal")))
+
+
+def _fwd_bwd(attend, q, k, v):
+    """What a training step pays for the op: the forward and the three
+    gradients (the output stands in for its cotangent)."""
+    import jax
+    out, vjp = jax.vjp(attend, q, k, v)
+    return out, vjp(out)
+
+
+def _flash_bench(params, block_q):
+    """(fn, args) timing the flash kernels forward + backward at ``block_q``
+    (None: the default for S), or None where they cannot run."""
+    from ..ops import pallas_mode
+    from ..ops.pallas_attention import _flash
+    if not pallas_mode.available():
+        return None
+    interpret = pallas_mode.interpret()
+    q, bias, scale, dropout, causal = _attn_bench_args(params)
+
+    def pallas_fn(q, k, v):
+        return _fwd_bwd(lambda q, k, v: _flash(
+            q, k, v, bias, 0, scale, dropout, causal, interpret, block_q),
+            q, k, v)
+
+    return pallas_fn, (q, q, q)
 
 
 # --------------------------------------------------------------------------------------
@@ -336,21 +357,24 @@ class FlashBlockSizes(TunableChoice):
     doc = ("(block_q, block_k) of the flash kernel. block_k is currently "
            "pinned to S -- the kernel stages whole K/V rows for one "
            "(batch, head) in VMEM -- so the live search is over block_q "
-           "(the Q rows per grid step).")
+           "(the Q rows per grid step), every candidate dividing S. The "
+           "benches time forward + backward.")
 
-    BLOCK_Q_CANDIDATES = (128, 256, 512)
+    #: every multiple of the kernel's 128 rows up to here that divides S
+    MAX_BLOCK_Q = 1024
 
     def bucket(self, params):
         return _attn_bucket(params)
 
     def candidates(self, params):
         s = int(params["s"])
-        return [(bq, s) for bq in self.BLOCK_Q_CANDIDATES
-                if bq <= s and s % bq == 0]
+        return [(bq, s) for bq in range(128, min(s, self.MAX_BLOCK_Q) + 1, 128)
+                if s % bq == 0]
 
     def default(self, params):
-        from ..ops.pallas_attention import BLK_Q
-        return (BLK_Q, int(params["s"]))
+        from ..ops.pallas_attention import default_block_q
+        s = int(params["s"])
+        return (default_block_q(s), s)
 
     def encode(self, candidate):
         return f"{int(candidate[0])},{int(candidate[1])}"
@@ -360,23 +384,7 @@ class FlashBlockSizes(TunableChoice):
         return (int(bq), int(bk))
 
     def bench(self, params, candidate):
-        import math
-        q, bias = _attn_inputs(params)
-        scale = float(params.get("scale") or 1.0 / math.sqrt(int(params["d"])))
-        dropout = float(params.get("dropout", 0.0))
-        causal = bool(params.get("causal"))
-        from ..ops import pallas_mode
-        from ..ops.pallas_attention import _flash
-        if not pallas_mode.available():
-            return None
-        interpret = pallas_mode.interpret()
-        bq = int(candidate[0])
-
-        def fn(q, k, v):
-            return _flash(q, k, v, bias, 0, scale, dropout, causal,
-                          interpret, bq)
-
-        return fn, (q, q, q)
+        return _flash_bench(params, int(candidate[0]))
 
 
 # --------------------------------------------------------------------------------------

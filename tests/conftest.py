@@ -43,6 +43,17 @@ from paddle_tpu.ops import pallas_mode  # noqa: E402
 
 pallas_mode.TEST_INTERPRET = True
 
+import pytest  # noqa: E402
+
+
+@pytest.fixture
+def as_on_the_chip(monkeypatch):
+    """``pallas_mode.on_tpu`` says what it will say on the chip (the backend
+    here is the CPU): what is lowered, or decided, is what a TPU would get --
+    the kernels draw a dropout mask only there, and a compile for a
+    described TPU takes the TPU's branches."""
+    monkeypatch.setattr(pallas_mode, "on_tpu", lambda: True)
+
 
 # ---------------------------------------------------------------------------
 # Tiering (VERDICT r3 #10): `pytest -m smoke` runs a <3-minute tier with at

@@ -132,14 +132,23 @@ def test_flash_scale_not_a_power_of_two(dtype):
         np.testing.assert_allclose(x, r, atol=tol * np.abs(r).max(), rtol=0)
 
 
-def test_flash_block_q_falls_back_where_it_does_not_divide_s():
-    """S=384 is a multiple of 128 and not of BLK_Q=256."""
+def test_flash_default_block_q_divides_s_and_no_other_is_taken():
+    """S=384 is a multiple of 128 and not of BLK_Q=256: the default block
+    divides it (nothing falls back silently inside the kernel's wrapper),
+    and a block_q that does not is refused."""
     assert 384 % pa.BLK_Q and pa.supports_pallas(2, 2, 384, 32, None, 0.0,
                                                   is_tpu=False)
+    for S in range(128, 4097, 128):
+        assert S % pa.default_block_q(S) == 0
+        assert pa.default_block_q(S) % 128 == 0
     q, k, v, bias = _qkv(S=384)
     ref = pa.composed_attention(q, k, v, bias, 0.125, 0.0, False, None)
     out = pa._flash(q, k, v, bias, jnp.int32(7), 0.125, 0.0, False, True)
     np.testing.assert_allclose(np.asarray(ref), np.asarray(out), atol=1e-5)
+    for block_q in (256, 192):
+        with pytest.raises(ValueError, match="block_q"):
+            pa._flash(q, k, v, bias, jnp.int32(7), 0.125, 0.0, False, True,
+                      block_q)
 
 
 def test_flash_bf16_close():
@@ -208,6 +217,49 @@ def test_bert_program_parity_fused_vs_composed():
     assert losses["pallas"][1] < losses["pallas"][0]  # it actually trains
 
 
+@pytest.mark.parametrize("S,dp,want", [(128, 1, ("xla", "0")),
+                                       (256, 1, ("pallas", "256")),
+                                       (256, 2, ("xla", "0"))])
+def test_executor_counts_the_lowering_each_attention_op_took(S, dp, want):
+    """impl='auto' with no tuning decision: XLA's lowering at S=128, the
+    kernels at one Q block a head from S=256, and XLA's again where the step
+    is jitted over a mesh of two devices (GSPMD cannot partition a Mosaic
+    call); the executor adds one count a fused_attention op at the compile
+    (the forward its grad op lowers again is the same op), labelled by
+    program."""
+    from paddle_tpu.observability.metrics import REGISTRY
+    B, M = 2, 8
+    rng = np.random.RandomState(0)
+    ids = lambda hi, shape: rng.randint(0, hi, shape).astype(np.int32)  # noqa: E731
+    feed = {"src_ids": ids(64, (B, S)),
+            "pos_ids": np.tile(np.arange(S, dtype=np.int32), (B, 1)),
+            "sent_ids": ids(2, (B, S)),
+            "input_mask": np.ones((B, S), np.float32),
+            "mask_pos": ids(B * S, (M, 1)), "mask_label": ids(64, (M, 1)),
+            "nsp_label": ids(2, (B, 1))}
+    main, startup, total = _bert_program("auto", S=S)
+    run = main if dp == 1 else fluid.CompiledProgram(main).with_strategy(
+        fluid.DistributedStrategy(
+            mesh_shape={"dp": dp},
+            data_rules=[("mask_pos|mask_label", ()), (".", ("dp",))]))
+
+    def counts():
+        fam = REGISTRY.get("attention_lowering_total")
+        return {} if fam is None else {
+            (dict(k)["impl"], dict(k)["block_q"], dict(k)["s"]): c.value
+            for k, c in fam.items()}
+    before = counts()
+    exe = fluid.Executor()
+    with fluid.scope_guard(fluid.Scope()):
+        exe.run(startup)
+        exe.run(run, feed=feed, fetch_list=[total])
+        exe.run(run, feed=feed, fetch_list=[total])     # no second compile
+    after = counts()
+    grown = {k: v - before.get(k, 0) for k, v in after.items()
+             if v != before.get(k, 0)}
+    assert grown == {want + (str(S),): 1}
+
+
 def test_clone_for_test_disables_attention_dropout():
     """clone(for_test=True) must flip is_test on fused_attention (round-3
     review finding: inference was stochastic otherwise)."""
@@ -217,6 +269,25 @@ def test_clone_for_test_disables_attention_dropout():
            if op.type == "fused_attention"]
     assert ops, "expected fused_attention ops in the cloned program"
     assert all(op.attrs.get("is_test") for op in ops)
+
+
+def test_a_test_mode_op_on_the_kernels_draws_no_random_number():
+    """The kernels read their seed for a dropout mask alone: an is_test op
+    (an inference clone, a saved model) holds no random op, as the dropout
+    op does not; a training op still draws the seed the parent drew."""
+    import paddle_tpu.core.registry as registry
+    d = registry.get("fused_attention")
+    q = jnp.zeros((1, 2, 256, 32), jnp.float32)
+
+    def jaxpr(is_test):
+        ctx = registry.LowerCtx(
+            {"impl": "auto", "is_test": is_test, "dropout_prob": 0.1},
+            base_key=jax.random.PRNGKey(0))
+        return str(jax.make_jaxpr(lambda q: d.lower(
+            ctx, {"Q": [q], "K": [q], "V": [q]})["Out"][0])(q))
+    assert "pallas_call" in jaxpr(True) and "random_" not in jaxpr(True)
+    # in training the CPU has no in-kernel PRNG: the composed lowering's mask
+    assert "random_" in jaxpr(False)
 
 
 def test_forced_pallas_rejects_bad_shapes():

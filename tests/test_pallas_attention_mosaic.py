@@ -17,11 +17,11 @@ from paddle_tpu.ops import pallas_attention as pa
 
 
 @pytest.fixture(scope="module")
-def one_chip():
+def v5e():
+    """The described 2x2 of TPU v5e chips."""
     os.environ.setdefault("TPU_LOG_DIR", "disabled")
     from jax.experimental import topologies
     from jax.experimental.compilation_cache import compilation_cache
-    from jax.sharding import SingleDeviceSharding
     try:
         topo = topologies.get_topology_desc(platform="tpu",
                                             topology_name="v5e:2x2")
@@ -31,20 +31,31 @@ def one_chip():
     was = jax.config.jax_enable_compilation_cache
     jax.config.update("jax_enable_compilation_cache", False)
     compilation_cache.reset_cache()
-    yield SingleDeviceSharding(topo.devices[0])
+    yield topo
     jax.config.update("jax_enable_compilation_cache", was)
     compilation_cache.reset_cache()
+
+
+@pytest.fixture(scope="module")
+def one_chip(v5e):
+    from jax.sharding import SingleDeviceSharding
+    return SingleDeviceSharding(v5e.devices[0])
 
 
 def _kernels(compiled):
     return compiled.as_text().count('custom_call_target="tpu_custom_call"')
 
 
-# (batch, heads, S, head dim, dtype, dropout, causal, bias):
-# bert_base.pretrain_s2048's attention; the longest S the whole-row kernel
+# (batch, heads, S, head dim, dtype, dropout, causal, bias), each at the
+# block_q the op takes by default: bert_base.pretrain_s512's attention (one Q
+# block a head), the same tokens at the shortest S that 'auto' gives the
+# kernels, and at S=384; bert_base.pretrain_s2048's attention; the longest S the whole-row kernel
 # takes at BLK_Q; f32 inputs; olmoe_1b_7b.pretrain_s4096's (its second
 # shape: causal, no bias, no dropout, d=128) at 2 and at 4 sequences
-CASES = [(8, 12, 2048, 64, jnp.bfloat16, 0.1, False, True),
+CASES = [(32, 12, 512, 64, jnp.bfloat16, 0.1, False, True),
+         (64, 12, 256, 64, jnp.bfloat16, 0.1, False, True),
+         (42, 12, 384, 64, jnp.bfloat16, 0.1, False, True),
+         (8, 12, 2048, 64, jnp.bfloat16, 0.1, False, True),
          (4, 12, 4096, 64, jnp.bfloat16, 0.1, False, True),
          (8, 12, 2048, 64, jnp.float32, 0.0, True, False),
          (2, 16, 4096, 128, jnp.bfloat16, 0.0, True, False),
@@ -71,12 +82,37 @@ def test_flash_compiles_for_v5e(one_chip, B, H, S, D, dtype, dropout, causal,
     assert _kernels(jax.jit(grads).lower(x, x, x, bias, x).compile()) == 1
 
 
-@pytest.fixture
-def as_on_the_chip(monkeypatch):
-    """``pallas_mode.on_tpu`` says what it will say on the chip (the backend
-    here is the CPU; the compile is for the described TPU)."""
-    from paddle_tpu.ops import pallas_mode
-    monkeypatch.setattr(pallas_mode, "on_tpu", lambda: True)
+@pytest.mark.parametrize("S", [384, 512])
+@pytest.mark.parametrize("dtype", [jnp.float32, jnp.bfloat16])
+def test_flash_matches_composed_below_1024(S, dtype):
+    """The lengths 'auto' newly gives the kernels (interpreter, no dropout):
+    forward and gradients against the composed lowering in float32, at the
+    default block_q. Tolerances as tests/test_pallas_attention.py derives
+    them."""
+    import numpy as np
+    ks = jax.random.split(jax.random.PRNGKey(S), 5)
+    q, k, v, g = (jax.random.normal(kk, (2, 2, S, 64), dtype)
+                  for kk in ks[:4])
+    bias = jnp.where(jax.random.bernoulli(ks[4], 0.9, (2, 1, 1, S)),
+                     0.0, -1e4).astype(dtype)
+    f32 = lambda x: x.astype(jnp.float32)                   # noqa: E731
+    assert S % pa.default_block_q(S) == 0
+
+    def both(attend, *xs):
+        out, vjp = jax.vjp(attend, *xs)
+        return [np.asarray(f32(x)) for x in (out, *vjp(g.astype(out.dtype)))]
+
+    ref = both(lambda q, k, v: pa.composed_attention(
+        q, k, v, f32(bias), 0.125, 0.0, False, None), f32(q), f32(k), f32(v))
+    got = both(lambda q, k, v: pa._flash(
+        q, k, v, bias, jnp.int32(7), 0.125, 0.0, False, True), q, k, v)
+    for r, x in zip(ref, got):
+        if dtype == jnp.float32:
+            np.testing.assert_allclose(x, r, atol=5e-5, rtol=1e-4)
+        else:
+            atol = 2.0 ** -9 * (np.sqrt(S) * np.sqrt((r * r).mean())
+                                + np.abs(r).max())
+            np.testing.assert_allclose(x, r, atol=atol, rtol=0)
 
 
 @pytest.mark.parametrize("rows,k,n", [(131072, 2048, 1024),
@@ -104,6 +140,32 @@ def test_grouped_expert_matmul_compiles_for_v5e(one_chip, as_on_the_chip,
     assert _kernels(jax.jit(grads).lower(x, w, count, g).compile()) == 2
 
 
+def _captured_step(main, feed, fetch, scope):
+    """The jitted train step of ``main`` and its arguments, taken from the
+    executor where it would compile them."""
+    import paddle_tpu as fluid
+    from paddle_tpu.core.executor import Executor
+    taken = {}
+
+    class Captured(Exception):
+        pass
+
+    def capture(self, key, compiled, args):
+        taken["fn"], taken["args"] = compiled.fn, args
+        raise Captured()
+
+    exe = fluid.Executor()
+    real = Executor._aot_compile
+    Executor._aot_compile = capture
+    try:
+        with pytest.raises(Captured):
+            exe.run(main, feed=feed, fetch_list=fetch, scope=scope)
+    finally:
+        Executor._aot_compile = real
+        exe.close()
+    return taken["fn"], taken["args"]
+
+
 def test_expert_layer_step_holds_nine_grouped_matmuls_and_one_sort_by_expert(
         one_chip, as_on_the_chip):
     """D11 on the decoder: every grad op re-lowers its forward under
@@ -116,7 +178,6 @@ def test_expert_layer_step_holds_nine_grouped_matmuls_and_one_sort_by_expert(
     import numpy as np
 
     import paddle_tpu as fluid
-    from paddle_tpu.core.executor import Executor
     from paddle_tpu.models import decoder_lm
 
     model = {"hidden_size": 256, "num_hidden_layers": 1,
@@ -135,32 +196,16 @@ def test_expert_layer_step_holds_nine_grouped_matmuls_and_one_sort_by_expert(
         fluid.optimizer.AdamW(4e-4, weight_decay=0.1).minimize(out["loss"])
     exe, scope = fluid.Executor(), fluid.Scope()
     exe.run(startup, scope=scope)
-    taken = {}
-
-    class Captured(Exception):
-        pass
-
-    def capture(self, key, compiled, args):
-        taken["fn"], taken["args"] = compiled.fn, args
-        raise Captured()
-
-    real = Executor._aot_compile
-    Executor._aot_compile = capture
-    try:
-        with pytest.raises(Captured):
-            exe.run(main, feed={
-                "ids": np.zeros((batch, seq), np.int32),
-                "labels": np.zeros((batch * seq, 1), np.int32)},
-                fetch_list=[out["loss"]], scope=scope)
-    finally:
-        Executor._aot_compile = real
-        exe.close()
+    exe.close()
+    fn, args = _captured_step(main, {
+        "ids": np.zeros((batch, seq), np.int32),
+        "labels": np.zeros((batch * seq, 1), np.int32)}, [out["loss"]], scope)
 
     def spec(x):
         x = x if hasattr(x, "dtype") else np.asarray(x)
         return jax.ShapeDtypeStruct(x.shape, x.dtype, sharding=one_chip)
-    text = taken["fn"].lower(
-        *jax.tree_util.tree_map(spec, taken["args"])).compile().as_text()
+    text = fn.lower(
+        *jax.tree_util.tree_map(spec, args)).compile().as_text()
     kernels = [ln for ln in text.splitlines()
                if 'custom_call_target="tpu_custom_call"' in ln]
     in_scope = lambda ln, scope: re.search(                 # noqa: E731
@@ -176,3 +221,118 @@ def test_expert_layer_step_holds_nine_grouped_matmuls_and_one_sort_by_expert(
     assert sum(in_scope(ln, "moe_router") for ln in sorts) == 1
     assert not [ln for ln in sorts if in_scope(ln, "moe_dispatch_grad")
                 or in_scope(ln, "moe_router_grad")]
+
+
+def _bert_s512_step(strategy=None):
+    """A 12-layer BERT train step at S=512 (bert_base.pretrain_s512's op:
+    bias, dropout 0.1, d=64; narrow otherwise) with ``impl='auto'`` and no
+    tuning decision on disk, under ``strategy`` where one is given: the
+    Program, the jitted step and its arguments."""
+    import numpy as np
+
+    import paddle_tpu as fluid
+    from paddle_tpu.models import bert
+
+    B, S, M, heads = 4, 512, 8, 2
+    cfg = bert.BertConfig(vocab_size=128, hidden=64 * heads, n_layers=12,
+                          n_heads=heads, max_seq_len=S, dropout=0.1,
+                          attn_impl="auto", dtype="bfloat16")
+    main, startup = fluid.Program(), fluid.Program()
+    with fluid.unique_name.guard(), fluid.program_guard(main, startup):
+        A = dict(append_batch_size=False)
+        names = ("src_ids", "pos_ids", "sent_ids")
+        ids = [fluid.data(n, [B, S], "int64", **A) for n in names]
+        mask = fluid.data("input_mask", [B, S], "float32", **A)
+        mpos = fluid.data("mask_pos", [M, 1], "int64", **A)
+        mlabel = fluid.data("mask_label", [M, 1], "int64", **A)
+        nsp = fluid.data("nsp_label", [B, 1], "int64", **A)
+        total, _, _ = bert.pretrain(*ids, mask, mpos, mlabel, nsp, cfg)
+        fluid.optimizer.Adam(1e-4).minimize(total)
+    scope = fluid.Scope()
+    exe = fluid.Executor()
+    exe.run(startup, scope=scope)
+    exe.close()
+    feed = {n: np.zeros((B, S), np.int32) for n in names}
+    feed.update(input_mask=np.ones((B, S), np.float32),
+                mask_pos=np.zeros((M, 1), np.int32),
+                mask_label=np.zeros((M, 1), np.int32),
+                nsp_label=np.zeros((B, 1), np.int32))
+    run = main if strategy is None else \
+        fluid.CompiledProgram(main).with_strategy(strategy)
+    return (main,) + _captured_step(run, feed, [total], scope)
+
+
+def _lowering_counts(main):
+    """What the executor's counter would add for the trace just made:
+    {"<impl>@<block_q>": ops}."""
+    from paddle_tpu.observability import attention as obs_attention
+    from paddle_tpu.observability.metrics import MetricsRegistry
+    registry = MetricsRegistry()
+    obs_attention.count_lowerings(
+        main._lowering_notes.pop("fused_attention"), "step", registry)
+    return {dict(k)["impl"] + "@" + dict(k)["block_q"]: c.value
+            for k, c in registry.get("attention_lowering_total").items()}
+
+
+def test_bert_s512_step_holds_24_kernels_lowered_once_and_no_score_matrix(
+        one_chip, as_on_the_chip):
+    """The step on one chip: the defaults give every layer the kernels. The
+    compiled step holds 12 forward + 12 backward Mosaic calls and no
+    [B, heads, S, S] array; the lowered module holds each kernel once (the
+    layers, and the forwards the grad ops trace again, share one trace and
+    one lowered function: the set-up half of ISSUE 27); the lowering counter
+    says 12 x pallas at block_q 512."""
+    import re
+
+    import numpy as np
+
+    main, fn, args = _bert_s512_step()
+
+    def spec(x):
+        x = x if hasattr(x, "dtype") else np.asarray(x)
+        return jax.ShapeDtypeStruct(x.shape, x.dtype, sharding=one_chip)
+    lowered = fn.lower(*jax.tree_util.tree_map(spec, args))
+    assert lowered.as_text().count("tpu_custom_call") == 2
+    assert _lowering_counts(main) == {"pallas@512": 12}
+    text = lowered.compile().as_text()
+    kernels = [ln for ln in text.splitlines()
+               if 'custom_call_target="tpu_custom_call"' in ln]
+    in_scope = lambda ln, scope: re.search(                 # noqa: E731
+        r'op_name="[^"]*/' + scope + r'#\d+/', ln) is not None
+    assert sum(in_scope(ln, "fused_attention") for ln in kernels) == 12
+    assert sum(in_scope(ln, "fused_attention_grad") for ln in kernels) == 12
+    assert len(kernels) == 24
+    assert "[4,2,512,512]" not in text
+
+
+def test_bert_s512_step_under_a_dp_mesh_compiles_on_the_composed_lowering(
+        v5e, as_on_the_chip, monkeypatch):
+    """The same step data-parallel over the four described chips. A Mosaic
+    call has no partitioning rule, so a jit over more than one device
+    refuses to lower one outside a shard_map: 'auto' must read the mesh and
+    keep XLA's composed lowering there (REVIEW of PR 27: with the crossover
+    at 256 alone this step stopped compiling). It compiles, holds no Mosaic
+    call and the gradients' all-reduces, and the counter says 12 x xla."""
+    import numpy as np
+    from jax.sharding import Mesh
+
+    import paddle_tpu as fluid
+    from paddle_tpu.compiler import DistributedStrategy
+
+    monkeypatch.setattr(
+        DistributedStrategy, "build_mesh",
+        lambda self, devices=None: Mesh(np.array(v5e.devices[:4]), ("dp",)))
+    strategy = fluid.DistributedStrategy(
+        mesh_shape={"dp": 4},
+        data_rules=[("mask_pos|mask_label", ()), ("nsp_label", ("dp",)),
+                    ("src_ids|pos_ids|sent_ids|input_mask", ("dp",))])
+    main, fn, args = _bert_s512_step(strategy)
+
+    def spec(x):            # the layout's jit carries its own in_shardings
+        x = x if hasattr(x, "dtype") else np.asarray(x)
+        return jax.ShapeDtypeStruct(x.shape, x.dtype)
+    lowered = fn.lower(*jax.tree_util.tree_map(spec, args))
+    assert _lowering_counts(main) == {"xla@0": 12}
+    text = lowered.compile().as_text()
+    assert 'custom_call_target="tpu_custom_call"' not in text
+    assert "all-reduce" in text

@@ -96,9 +96,10 @@ def test_defaults_reproduce_static_heuristics(tune_cache, monkeypatch):
     assert tuning.decide("conv2d_bn_fused.backend", CONVBN) == "pallas"
     bad = dict(CONVBN, m=897)  # not divisible by BM
     assert tuning.decide("conv2d_bn_fused.backend", bad) == "xla"
-    # attention: the S >= AUTO_PALLAS_MIN_S crossover
+    # attention: the S >= AUTO_PALLAS_MIN_S crossover (every length of the
+    # sweep: test_attention_defaults_follow_the_sweep)
     assert tuning.decide("fused_attention.backend", FLASH) == "pallas"
-    short = dict(FLASH, s=256)
+    short = dict(FLASH, s=128)
     assert tuning.decide("fused_attention.backend", short) == "xla"
     # conv layout: as declared
     conv = {"x_shape": (2, 3, 8, 8), "w_shape": (4, 3, 3, 3),
@@ -129,10 +130,11 @@ def test_search_reproduces_roofline_verdicts_from_timings(tune_cache,
                                                           monkeypatch):
     """The acceptance shape set: with the ROOFLINE_RESNET.md measurements
     injected as timings, search elects XLA at every ResNet-50 conv+BN
-    bottleneck shape; with the attention-crossover measurements, Pallas at
-    S=2048 and XLA at S=128. (The same decisions fall out of live device
-    measurement via `bench.py --tune` / the CLI on the TPU host -- here the
-    *selection logic* is pinned against the recorded numbers.)"""
+    bottleneck shape (the attention sweep:
+    test_search_reproduces_the_attention_sweep). The same decisions fall out
+    of live device measurement via `bench.py --tune` / the CLI on the TPU
+    host -- here the *selection logic* is pinned against the recorded
+    numbers."""
     roofline_us = {  # (M, K, N) -> (pallas_us, xla_us), ROOFLINE_RESNET.md §2
         (401408, 64, 256): (468, 423),
         (401408, 256, 64): (572, 375),
@@ -164,29 +166,95 @@ def test_search_reproduces_roofline_verdicts_from_timings(tune_cache,
         monkeypatch.setattr(choice, "bench", bench)
         assert tuning.decide("conv2d_bn_fused.backend", params,
                              mode="search") == "xla", (m, k, n)
-    # attention crossover (the AUTO_PALLAS_MIN_S measurement: S=128 XLA
-    # 6.1 vs flash 7.3 ms; S=2048 flash 7.4 vs XLA 10.0 ms)
-    attn_ms = {128: (7.3, 6.1), 2048: (7.4, 10.0)}
-    fchoice = tchoices.get_choice("fused_attention.backend")
 
-    def fbench(p, cand):
-        def pallas_fn():
+
+# The sweep the attention defaults were written from (chip runs, PR 27;
+# PERF.md section 6): ms forward + backward of 16k tokens on the v5e, bf16,
+# H=12 D=64, a [B,1,1,S] bias, dropout 0.1. S -> (XLA's composed lowering,
+# {block_q: the flash kernels}).
+ATTN_SWEEP_MS = {
+    128: (2.134, {128: 3.128}),
+    256: (3.940, {128: 3.205, 256: 2.325}),
+    384: (5.559, {128: 3.325, 384: 2.385}),
+    512: (8.170, {128: 3.326, 256: 2.672, 512: 2.430}),
+    640: (8.350, {128: 3.739, 640: 2.866}),
+    768: (9.743, {128: 3.927, 256: 3.383, 384: 3.248, 768: 3.014}),
+    1024: (13.065, {128: 4.452, 256: 4.001, 512: 3.822, 1024: 3.688}),
+    2048: (25.476, {128: 7.244, 256: 6.809, 512: 6.713, 1024: 6.697}),
+}
+
+
+def _bert_op(s):
+    return {"b": 16384 // s, "h": 12, "s": s, "d": 64, "dtype": "bfloat16",
+            "has_bias": True, "dropout": 0.1, "causal": False}
+
+
+@pytest.mark.parametrize("s", sorted(ATTN_SWEEP_MS))
+def test_attention_defaults_follow_the_sweep(tune_cache, as_on_the_chip, s):
+    """With no decision on disk (the driver's machine has none) the defaults
+    answer: the kernels from S=256 up, XLA's lowering at S=128; a block_q
+    that divides S, the table's fastest below S=1024 and BLK_Q from there
+    (where 512 and 1024 would gain 2-8%: PERF.md section 7)."""
+    from paddle_tpu.ops.pallas_attention import AUTO_PALLAS_MIN_S
+    xla_ms, flash_ms = ATTN_SWEEP_MS[s]
+    for mode in ("off", "cached"):
+        backend = tuning.decide("fused_attention.backend", _bert_op(s),
+                                mode=mode)
+        block_q, block_k = tuning.decide("fused_attention.block_sizes",
+                                         _bert_op(s), mode=mode)
+        assert backend == ("pallas" if s >= AUTO_PALLAS_MIN_S else "xla")
+        assert backend == ("pallas" if min(flash_ms.values()) < xla_ms
+                           else "xla")
+        assert s % block_q == 0 and block_k == s
+        assert (block_q, s) in tchoices.get_choice(
+            "fused_attention.block_sizes").candidates(_bert_op(s))
+        if s < 1024:
+            assert flash_ms[block_q] == min(flash_ms.values())
+        else:
+            assert block_q == BLK_Q
+            assert flash_ms[block_q] <= 1.09 * min(flash_ms.values())
+    # the serving path of a saved model (is_test: no dropout) and the
+    # decoder's op (causal, d=128, no bias) get the same answer
+    for op in (dict(_bert_op(s), dropout=0.0),
+               dict(_bert_op(s), h=16, d=128, has_bias=False, dropout=0.0,
+                    causal=True)):
+        assert tuning.decide("fused_attention.backend", op,
+                             mode="off") == backend
+
+
+@pytest.mark.parametrize("s", sorted(ATTN_SWEEP_MS))
+def test_search_reproduces_the_attention_sweep(tune_cache, monkeypatch,
+                                               as_on_the_chip, s):
+    """The recorded measurements injected as timings: search elects what the
+    chip elected, for the backend and for block_q (the selection logic is
+    pinned here; the numbers come from `python -m paddle_tpu.tuning --suite
+    flash` on the TPU host)."""
+    xla_ms, flash_ms = ATTN_SWEEP_MS[s]
+    backend = tchoices.get_choice("fused_attention.backend")
+    blocks = tchoices.get_choice("fused_attention.block_sizes")
+    assert [bq for bq, _ in blocks.candidates(_bert_op(s))] == \
+        sorted(flash_ms)
+
+    def marker(cand):
+        def fn():
             pass
-        def xla_fn():
-            pass
-        return (pallas_fn if cand == "pallas" else xla_fn), ()
-    monkeypatch.setattr(fchoice, "bench", fbench)
-    for s, (p_ms, x_ms) in attn_ms.items():
-        def fake2(fn, args, warmup=None, iters=None, _p=p_ms, _x=x_ms):
-            ms = _p if "pallas" in fn.__name__ else _x
-            return {"compile_ms": 0.0, "run_ms": ms, "runs_ms": [ms]}
-        monkeypatch.setattr(tmeasure, "time_callable", fake2)
-        params = {"b": 16384 // s, "h": 12, "s": s, "d": 64,
-                  "dtype": "bfloat16", "has_bias": False, "dropout": 0.0,
-                  "causal": False}
-        want = "pallas" if s == 2048 else "xla"
-        assert tuning.decide("fused_attention.backend", params,
-                             mode="search") == want, s
+        fn.cand = cand
+        return fn, ()
+    monkeypatch.setattr(backend, "bench", lambda p, cand: marker(cand))
+    monkeypatch.setattr(blocks, "bench", lambda p, cand: marker(cand))
+    default_bq = blocks.default(_bert_op(s))[0]
+
+    def fake(fn, args, warmup=None, iters=None):
+        ms = (xla_ms if fn.cand == "xla" else flash_ms[default_bq]
+              if fn.cand == "pallas" else flash_ms[fn.cand[0]])
+        return {"compile_ms": 0.0, "run_ms": ms, "runs_ms": [ms]}
+    monkeypatch.setattr(tmeasure, "time_callable", fake)
+    assert tuning.decide("fused_attention.backend", _bert_op(s),
+                         mode="search") == ("xla" if s == 128 else "pallas")
+    best = min(flash_ms, key=flash_ms.get)
+    assert tuning.decide("fused_attention.block_sizes", _bert_op(s),
+                         mode="search") == (best, s)
+    assert best == (s if s <= 1024 else 1024)
 
 
 def test_failed_candidate_excluded_not_fatal(tune_cache, monkeypatch):
@@ -221,8 +289,11 @@ def test_stale_cached_decision_falls_back_to_default(tune_cache, monkeypatch):
 def test_block_size_candidates_divide_s():
     ch = tchoices.get_choice("fused_attention.block_sizes")
     assert ch.candidates({"b": 1, "h": 1, "s": 2048, "d": 64}) == \
-        [(128, 2048), (256, 2048), (512, 2048)]
-    assert ch.candidates({"b": 1, "h": 1, "s": 384, "d": 64}) == [(128, 384)]
+        [(128, 2048), (256, 2048), (512, 2048), (1024, 2048)]
+    assert ch.candidates({"b": 1, "h": 1, "s": 384, "d": 64}) == \
+        [(128, 384), (384, 384)]
+    assert ch.candidates({"b": 1, "h": 1, "s": 768, "d": 64}) == \
+        [(128, 768), (256, 768), (384, 768), (768, 768)]
     assert ch.decode(ch.encode((256, 2048))) == (256, 2048)
 
 
