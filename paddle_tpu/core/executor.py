@@ -8,7 +8,9 @@ dispatch, the executor *traces* the entire block into one pure JAX function
 
     step(state, feed, key) -> (fetches, new_state)
 
-and jit-compiles it with the state buffers donated. Parameters, optimizer moments and
+and jit-compiles it with the state buffers donated (``_make_step`` defines it once;
+``Executor._compile`` wraps it in a plain or GSPMD jit, a ``shard_map`` over dp or a scan
+of K, and ``_jit_step`` jits whichever came out). Parameters, optimizer moments and
 batch-norm stats are the functional ``state``; writes to persistable vars inside the
 program come back as ``new_state`` and are stored to the Scope. This makes a whole
 training step (forward + backward + optimizer update) a single XLA program -- the
@@ -308,6 +310,105 @@ def trace_block(block: Block, env: Dict[str, Any], base_key, block_runner=None,
                             f"{v.dtype} (would retrace every step)")
                 env[n] = vals[i]
     return env
+
+
+def _make_step(program: Program, fetch_names, state_out, mesh_kw=None,
+               rng_fold=None, fetch_hook=None):
+    """The one definition of the step every builder of ``Executor._compile``
+    wraps: ``step(mut_state, ro_state, feed, rng_counter) -> (fetches,
+    new_state)``.  The parameters are what the builders differ in:
+    ``mesh_kw`` is the mesh keyword ``trace_block`` gets (``gspmd_mesh`` under
+    a jit over a mesh, ``mesh`` inside a ``shard_map``, none on one device),
+    ``rng_fold()`` is traced as one more ``fold_in`` into the step's key, and
+    ``fetch_hook(name, value)`` replaces each fetch."""
+    block = program.global_block()
+    mesh_kw = mesh_kw or {}
+    seed = program.random_seed if program.random_seed is not None else 0
+
+    def step(mut_state, ro_state, feed, rng_counter):
+        import jax
+        rng = jax.random.fold_in(jax.random.PRNGKey(seed), rng_counter)
+        if rng_fold is not None:
+            rng = jax.random.fold_in(rng, rng_fold())
+        env: Dict[str, Any] = {}
+        env.update(mut_state)
+        env.update(ro_state)
+        env.update(feed)
+
+        def block_runner(idx, sub_env, key=rng):
+            # Sub-blocks see the enclosing env (parameters and outer temps
+            # become loop constants under lax.scan/while), with the loop's
+            # own carries/inputs taking precedence.
+            sub_block = program.blocks[idx]
+            merged = dict(env)
+            merged.update(sub_env)
+            return trace_block(sub_block, merged, key, block_runner, **mesh_kw)
+
+        trace_block(block, env, rng, block_runner, **mesh_kw)
+        fetches = []
+        for n in fetch_names:
+            if n not in env:
+                raise KeyError(f"fetch variable {n!r} was not produced by the "
+                               f"program and is not in the feed/scope")
+            fetches.append(env[n] if fetch_hook is None
+                           else fetch_hook(n, env[n]))
+        new_state = {n: env[n] for n in state_out if n in env}
+        return fetches, new_state
+
+    return step
+
+
+def _mesh_shardings(program: Program, feed_names, fetch_names, mut_names,
+                    ro_names, state_out, wrapper, state_sharding):
+    """``(in_shardings, out_shardings)`` of a step jitted over the wrapper's
+    mesh: each state var by ``state_sharding(name)``, each feed by the
+    strategy's ``data_spec``, the run counter and the fetches replicated."""
+    from jax.sharding import NamedSharding, PartitionSpec as P
+    ds = wrapper.dist_strategy
+    mesh = wrapper.mesh
+    var_of = program.global_block().find_var_recursive
+
+    def state(names):
+        return {n: state_sharding(n) for n in names}
+
+    feeds = {n: NamedSharding(
+        mesh, ds.data_spec(n, len(var_of(n).shape)
+                           if var_of(n) is not None else 1))
+             for n in feed_names}
+    replicated = NamedSharding(mesh, P())
+    return ((state(mut_names), state(ro_names), feeds, replicated),
+            ([replicated] * len(fetch_names), state(state_out)))
+
+
+def _jit_step(fn, mut_names, ro_names, state_out, fetch_names,
+              shardings=None) -> _CompiledStep:
+    """jit a built step with the mutable state donated and the XLA options
+    of the flags; ``shardings`` is ``_mesh_shardings``'s pair under a mesh."""
+    import jax
+    jit_kw = {}
+    if _xla_options():
+        jit_kw["compiler_options"] = _xla_options()
+    state_sh = feed_sh = None
+    if shardings is not None:
+        jit_kw["in_shardings"], jit_kw["out_shardings"] = shardings
+        mut_sh, ro_sh, feed_sh, _ = shardings[0]
+        state_sh = {**mut_sh, **ro_sh}
+    return _CompiledStep(jax.jit(fn, donate_argnums=(0,), **jit_kw),
+                         (mut_names, ro_names), state_out, fetch_names,
+                         state_shardings=state_sh, feed_shardings=feed_sh)
+
+
+def _front_door(program, fetch_list, scope):
+    """What ``run`` and ``run_fused`` were handed, resolved: ``(Program,
+    CompiledProgram wrapper or None, fetch names, Scope)``."""
+    program = program or default_main_program()
+    wrapper = None
+    if not isinstance(program, Program):  # CompiledProgram front door
+        wrapper = program
+        program = wrapper.program
+    fetch_names = [v.name if isinstance(v, Variable) else str(v)
+                   for v in (fetch_list or [])]
+    return program, wrapper, fetch_names, scope or global_scope()
 
 
 class Executor:
@@ -867,15 +968,9 @@ class Executor:
             return_numpy: bool = True, use_prune: bool = False):
         import jax
 
-        program = program or default_main_program()
-        compiled_wrapper = None
-        if not isinstance(program, Program):  # CompiledProgram front door
-            compiled_wrapper = program
-            program = compiled_wrapper.program
+        program, compiled_wrapper, fetch_names, scope = _front_door(
+            program, fetch_list, scope)
         feed = dict(feed or {})
-        fetch_names = [v.name if isinstance(v, Variable) else str(v)
-                       for v in (fetch_list or [])]
-        scope = scope or global_scope()
 
         # PS schedule hoisting (ops/host_table.py): eligible host-table
         # pulls run as host gathers BEFORE the compiled step (rows enter as
@@ -977,6 +1072,27 @@ class Executor:
             # and the program reverts to the GSPMD path.
             from .. import comm as _comm
             _comm.sync_program(program, compiled_wrapper)
+        fetches = self._dispatch(program, compiled_wrapper, feed,
+                                 fetch_names, scope, n_user_fetch)
+        if host_pushes:
+            from ..ops import host_table as _ht
+            fetched = dict(feed)
+            fetched.update(zip(fetch_names, fetches))
+            _ht.run_pushes(host_pushes, fetched)
+            fetches = fetches[:n_user_fetch]
+        if return_numpy:
+            return [np.asarray(f) for f in fetches]
+        return list(fetches)
+
+    def _dispatch(self, program, wrapper, feed, fetch_names, scope,
+                  n_user_fetch, k=1):
+        """The one body behind ``run`` (``k`` = 1) and ``run_fused`` (``feed``
+        stacked to (k, ...), k > 1): state names, cache key, lookup or
+        compile, feed prep, the call, telemetry, scope write-back and health.
+        Returns the fetches as the compiled step gave them."""
+        import jax
+
+        fused = k > 1
         state_in, state_out = self._state_names(program, feed, fetch_names)
         if any(_comm_is_residual(n) for n in state_in):
             # error-feedback residuals start at zero; they are created by
@@ -1016,20 +1132,36 @@ class Executor:
         # never needing to track which decisions each lazy jax trace read.
         from .. import tuning as _tuning
         self._startup_prefetch()
-
-        feed_sig = tuple(sorted((k, tuple(np.shape(v)), str(np.asarray(v).dtype)
-                                 if not hasattr(v, "dtype") else str(v.dtype))
-                                for k, v in feed.items()))
+        from ..observability import health as _obs_health
+        hmode = _obs_health.mode()
+        health_on = hmode != "off"
+        include_state = health_on and _obs_health.include_state()
+        # a megastep's feed signature is PER-STEP (leading K stripped): the
+        # verifier and the recompile detector reason about the program's own
+        # shapes, and K gets its own key component below
+        lead = 1 if fused else 0
+        feed_sig = tuple(sorted(
+            (n, tuple(np.shape(v))[lead:], str(np.asarray(v).dtype)
+             if not hasattr(v, "dtype") else str(v.dtype))
+            for n, v in feed.items()))
         # random_seed is baked into the compiled step (the per-run key is derived
         # on device from the run counter: rng = fold_in(PRNGKey(seed), counter),
         # avoiding a per-step host->device key transfer that stalls dispatch).
         seed = program.random_seed if program.random_seed is not None else 0
-        from .. import flags as _flagsmod
+        from .. import flags as _flags
+        # a megastep takes the strategy's slot (a fused program has none):
+        # a K=4 scan is another entry than the K=1 step, here and in the
+        # warm store, as it must be
+        fuse = (k, health_on, include_state) if fused else None
+        if fused:
+            strategy = ("__fused__",) + fuse
+        else:
+            strategy = wrapper.strategy_signature() \
+                if wrapper is not None else ()
         key = (id(program), program._version, feed_sig, tuple(fetch_names), seed,
-               _flagsmod.get_flag("xla_compiler_options"),
-               compiled_wrapper.strategy_signature()
-               if compiled_wrapper is not None else (),
+               _flags.get_flag("xla_compiler_options"), strategy,
                _tuning.state_token())
+        label = f"{id(program)}:v{program._version}"
         compiled = self._cache.get(key)
         was_miss = compiled is None
         if was_miss:
@@ -1039,70 +1171,78 @@ class Executor:
                 # cached yet, so a retry recompiles cleanly)
                 _rfaults.fire("compile",
                               getattr(program, "_rng_run_counter", 0),
-                              program=f"{id(program)}:v{program._version}")
+                              program=label)
             # opt-in static verification, before any trace/compile work so
             # PADDLE_TPU_VALIDATE=raise fails with lint diagnostics instead
             # of a mid-trace stack (and never runs on warm steps); the
             # CompiledProgram wrapper hands its strategy to the PT04x
             # distributed checks, the feed shapes resolve the planner batch
             # (feed_shapes is reused by the static-memory gauge below)
-            feed_shapes = {k: np.shape(v) for k, v in feed.items()}
+            feed_shapes = {n: tuple(np.shape(v))[lead:]
+                           for n, v in feed.items()}
             self._maybe_verify(program, list(feed), fetch_names,
-                               wrapper=compiled_wrapper,
-                               feed_shapes=feed_shapes)
+                               wrapper=wrapper, feed_shapes=feed_shapes,
+                               fuse_k=k if fused else None)
             # recompile detector: which cache-key component changed since this
             # Program last compiled (shape = feed shapes/dtypes, flags = XLA
             # compiler options, strategy = dist strategy, plus version/
             # fetches/seed)?
             self._note_compile(program, {
                 "version": key[1], "shape": key[2], "fetches": key[3],
-                "seed": key[4], "flags": key[5], "strategy": key[6],
-                "fuse": None, "tuning": key[7]})
+                "seed": key[4], "flags": key[5],
+                "strategy": () if fused else key[6],
+                "fuse": key[6] if fused else None, "tuning": key[7]})
             # black-box forensics: remember what the LAST compile saw
             # (miss-time only -- zero warm-step cost)
             self._last_compile_info = {
-                "program": f"{id(program)}:v{program._version}",
+                "program": label,
                 "feed_shapes": {n: list(s) for n, s in feed_shapes.items()},
-                "fetches": list(fetch_names)[:32], "fuse_k": None}
+                "fetches": list(fetch_names)[:32],
+                "fuse_k": k if fused else None}
             compiled = self._compile(program, list(feed), fetch_names,
-                                     state_in, state_out,
-                                     wrapper=compiled_wrapper)
+                                     state_in, state_out, wrapper=wrapper,
+                                     fuse=fuse)
             self._store_compiled(key, compiled)
         else:
             _cache_count("hits", "compile")
             self._cache.move_to_end(key)
 
-        label = f"{id(program)}:v{program._version}"
         _phase = _obs_timeline.phase
         step_idx, mut_vals, ro_vals, feed_vals, rng = self._feed_prep(
-            program, compiled, scope, feed, label)
+            program, compiled, scope, feed, label, k=k)
         _obs_timeline.annotate(step=step_idx, program=label)
 
         if was_miss:
             key = self._materialize_miss(
-                "train_step", program, key, compiled,
-                (mut_vals, ro_vals, feed_vals, rng), label, step_idx,
-                feed_shapes, list(feed), fetch_names, compiled_wrapper,
-                world_dependent=key[6] != ())
+                "fused_step" if fused else "train_step", program, key,
+                compiled, (mut_vals, ro_vals, feed_vals, rng), label, step_idx,
+                feed_shapes, list(feed), fetch_names, wrapper,
+                world_dependent=not fused and key[6] != ())
 
-        from .. import flags as _flags
-        from .. import profiler as _profiler
         obs_on = _obs_journal.enabled()
         step_fn = compiled.executable if compiled.executable is not None \
             else compiled.fn
-        cm = (_profiler.record_event(f"executor_run_v{program._version}")
-              if _flags.get_flag("profile_executor") else contextlib.nullcontext())
+        kargs = {"k": k} if fused else {}
+        if fused:
+            around = _phase("megastep", step=step_idx, program=label, k=k)
+        elif _flags.get_flag("profile_executor"):
+            from .. import profiler as _profiler
+            around = _profiler.record_event(
+                f"executor_run_v{program._version}")
+        else:
+            around = contextlib.nullcontext()
         if _rfaults._active:
             # fault site: transient dispatch error / hang, injected BEFORE
             # the launch so nothing has been donated and a retry is safe
             _rfaults.fire("dispatch", step_idx, program=label)
         t_run = time.perf_counter()
         fallback_retraced = False
-        with cm:
-            with _phase("dispatch", step=step_idx, program=label):
+        with around:
+            with _phase("dispatch", step=step_idx, program=label, **kargs):
+                # a megastep returns its in-scan health flags third
                 try:
-                    fetches, new_state = step_fn(mut_vals, ro_vals, feed_vals,
-                                                 rng)
+                    fetches, new_state, *hflags = step_fn(
+                        mut_vals, ro_vals, feed_vals, rng)
                 except TypeError:
                     if step_fn is compiled.fn:
                         raise
@@ -1117,8 +1257,8 @@ class Executor:
                     # propagate, not silently re-execute.
                     compiled.executable = None
                     fallback_retraced = True
-                    fetches, new_state = compiled.fn(mut_vals, ro_vals,
-                                                     feed_vals, rng)
+                    fetches, new_state, *hflags = compiled.fn(
+                        mut_vals, ro_vals, feed_vals, rng)
             if _flags.get_flag("benchmark"):
                 with _phase("fetch_sync", step=step_idx, program=label):
                     jax.block_until_ready(new_state)
@@ -1129,21 +1269,24 @@ class Executor:
         run_s = time.perf_counter() - t_run
         _OBS.histogram("executor_run_seconds",
                        "Executor.run dispatch/step wall time").observe(run_s)
-        _OBS.counter("executor_runs_total", "Executor.run calls").inc()
-        if (not was_miss and not fallback_retraced
-                and (obs_on or _flags.get_flag("benchmark"))):
+        _OBS.counter("executor_runs_total", "Executor.run calls").inc(k)
+        warm = not was_miss and not fallback_retraced
+        if warm and (obs_on or _flags.get_flag("benchmark")):
             # warm steps only: a compile (cache miss OR the TypeError
             # fallback's retrace) is an expected outlier and must neither
             # flag itself nor poison the rolling window.  Synced timing
             # only: without the block_until_ready above, run_s is bare
             # async dispatch time -- a device-side regression would be
             # invisible to the detector and host jitter would false-flag.
-            # Windowed per cache entry (key includes the feed signature):
-            # two shapes of one program may differ legitimately by large
-            # factors and must not share a median.
+            # Windowed per cache entry (key includes the feed signature
+            # and the fuse marker): two shapes of one program may differ
+            # legitimately by large factors and must not share a median,
+            # nor a K=8 megastep's amortized per-substep time with K=1
+            # steps of the same program.
             from ..observability import anomaly as _obs_anomaly
-            _obs_anomaly.DETECTOR.observe(label, run_s, key=key)
-        if (obs_on or _flags.get_flag("benchmark")) and not fallback_retraced:
+            _obs_anomaly.DETECTOR.observe(label, run_s / k, key=key)
+        if (obs_on or _flags.get_flag("benchmark")) and not (
+                fused or fallback_retraced):
             # both paths block_until_ready above, so run_s is true step wall
             # time and the derived FLOP/s + MFU gauges are meaningful (the
             # bare dispatch time of the async path would inflate them; a
@@ -1156,44 +1299,74 @@ class Executor:
             # detector; gather-mode collections key on the program's step
             # index (retry/rollback rewinds included) so every rank hits
             # the collective at the same committed step
-            _obs_fleet.MONITOR.on_step(
-                warm=not was_miss and not fallback_retraced, step=step_idx)
+            _obs_fleet.MONITOR.on_step(warm=warm, step=step_idx, **kargs)
         if obs_on:
             self._obs_step = getattr(self, "_obs_step", 0) + 1
             from ..observability import memory as _obs_memory
             if self._obs_step % _obs_memory.sample_interval() == 0:
                 _obs_memory.sample_device_memory("interval")
             with _phase("journal", step=step_idx, program=label):
+                # substep i of a megastep ran under step0 + i
                 _obs_journal.emit({
-                    "event": "run", "program": id(program),
-                    "version": program._version,
+                    "event": "megastep" if fused else "run",
+                    "program": id(program), "version": program._version,
                     "cache": "miss" if was_miss else "hit",
+                    **({"k": k, "step0": step_idx} if fused else {}),
                     "compile_ms": (round(compiled.compile_seconds * 1e3, 3)
                                    if was_miss and compiled.compile_seconds
                                    is not None else None),
                     "run_ms": round(run_s * 1e3, 3),
+                    **({"amortized_ms": round(run_s / k * 1e3, 3)}
+                       if fused else {}),
                     "feed": {n: [list(shape), dtype]
                              for n, shape, dtype in feed_sig},
                     "fetch": list(fetch_names[:n_user_fetch]),
                 })
+        corrupted = False
         if _rfaults._active:
             # fault sites: transient fetch/d2h error or hang, and NaN/Inf
             # corruption of named fetches/state BEFORE the scope commit --
             # the health watchdog and the step guardian both see it
-            _rfaults.fire("fetch", step_idx, program=label)
-            fetches, new_state = _rfaults.corrupt_step(
-                step_idx, list(fetch_names), fetches, new_state,
-                program=label)
+            if not fused:
+                _rfaults.fire("fetch", step_idx, program=label)
+                fetches, new_state = _rfaults.corrupt_step(
+                    step_idx, list(fetch_names), fetches, new_state,
+                    program=label)
+            else:
+                fired0 = sum(f.fired for f in _rfaults._active)
+                for i in range(k):
+                    _rfaults.fire("fetch", step_idx + i, program=label)
+                rows = [[f[i] for f in fetches] for i in range(k)]
+                for i in range(k):
+                    rows[i], new_state = _rfaults.corrupt_step(
+                        step_idx + i, list(fetch_names), rows[i], new_state,
+                        program=label)
+                if sum(f.fired for f in _rfaults._active) != fired0:
+                    corrupted = True
+                    # restack the (possibly corrupted) substep rows; chaos
+                    # mode only -- the clean path never materializes here
+                    fetches = [np.stack([np.asarray(rows[i][j])
+                                         for i in range(k)])
+                               for j in range(len(fetch_names))]
         for n, v in new_state.items():
             scope.set_var(n, v)
-        from ..observability import health as _obs_health
-        hmode = _obs_health.mode()
-        if hmode != "off":
+        if health_on and fused and not corrupted:
+            # the any-nonfinite reduction ran inside the scan: one packed
+            # (K, n_watch) read per megastep
+            if hflags[0] is not None:
+                _obs_health.check_flag_matrix(
+                    _obs_health.read_flags(hflags[0]), compiled.health_names,
+                    label, where="executor", health_mode=hmode,
+                    step0=step_idx)
+        elif health_on:
             # one compiled any-nonfinite reduction over the user fetches
             # (+ written state when PADDLE_TPU_OBS_HEALTH_STATE=1): a single
-            # packed-bool device->host read, never a per-tensor sync
+            # packed-bool device->host read, never a per-tensor sync.  Also
+            # what scans a megastep whose injected corruption came AFTER the
+            # in-scan flags (chaos path; attribution loses the substep, keeps
+            # the var -- each stacked (K, ...) fetch is scanned whole)
             named = list(zip(fetch_names, fetches))[:n_user_fetch]
-            if _obs_health.include_state():
+            if include_state:
                 named += list(new_state.items())
             _obs_health.check(named, label, where="executor",
                               health_mode=hmode)
@@ -1203,17 +1376,9 @@ class Executor:
                    not np.isfinite(np.asarray(v)).all()]
             if bad:
                 raise FloatingPointError(
-                    f"NaN/Inf detected in state vars {bad[:5]} after run "
-                    f"(FLAGS_check_nan_inf)")
-        if host_pushes:
-            from ..ops import host_table as _ht
-            fetched = dict(feed)
-            fetched.update(zip(fetch_names, fetches))
-            _ht.run_pushes(host_pushes, fetched)
-            fetches = fetches[:n_user_fetch]
-        if return_numpy:
-            return [np.asarray(f) for f in fetches]
-        return list(fetches)
+                    f"NaN/Inf detected in state vars {bad[:5]} after "
+                    f"{'fused ' if fused else ''}run (FLAGS_check_nan_inf)")
+        return fetches
 
     # -- fused multi-step (megastep) execution -----------------------------------------
     def _fuse_ineligible(self, program, wrapper=None) -> Optional[str]:
@@ -1252,21 +1417,13 @@ class Executor:
         ~K-fold -- the reference's C++ device-worker amortization
         (executor.py:920) done in the compiler instead.
         """
-        import jax
-
-        program = program or default_main_program()
-        compiled_wrapper = None
-        if not isinstance(program, Program):
-            compiled_wrapper = program
-            program = compiled_wrapper.program
+        program, compiled_wrapper, fetch_names, scope = _front_door(
+            program, fetch_list, scope)
         reason = self._fuse_ineligible(program, compiled_wrapper)
         if reason is not None:
             raise ValueError(
                 f"run_fused: program cannot run fused ({reason}); run it "
                 f"unfused (fuse_steps=1 / Executor.run)")
-        fetch_names = [v.name if isinstance(v, Variable) else str(v)
-                       for v in (fetch_list or [])]
-        scope = scope or global_scope()
         if stacked_feed is not None:
             feed = dict(stacked_feed)
             if not feed:
@@ -1287,185 +1444,8 @@ class Executor:
                             scope=scope, return_numpy=return_numpy)
             return [v[None] for v in vals]
 
-        state_in, state_out = self._state_names(program, feed, fetch_names)
-        missing = [n for n in state_in if not scope.has_var(n) or
-                   scope.find_var(n) is None]
-        if missing:
-            raise RuntimeError(
-                f"persistable variables {missing[:8]} are uninitialized; "
-                f"run the startup program first.")
-
-        from .. import tuning as _tuning
-        self._startup_prefetch()
-        from ..observability import health as _obs_health
-        hmode = _obs_health.mode()
-        health_on = hmode != "off"
-        include_state = health_on and _obs_health.include_state()
-        # the feed signature is PER-STEP (leading K stripped): the verifier
-        # and the recompile detector reason about the program's own shapes,
-        # and K gets its own key component below
-        feed_sig = tuple(sorted(
-            (kk, tuple(np.shape(v))[1:], str(np.asarray(v).dtype)
-             if not hasattr(v, "dtype") else str(v.dtype))
-            for kk, v in feed.items()))
-        seed = program.random_seed if program.random_seed is not None else 0
-        from .. import flags as _flagsmod
-        key = (id(program), program._version, feed_sig, tuple(fetch_names),
-               seed, _flagsmod.get_flag("xla_compiler_options"),
-               ("__fused__", k, health_on, include_state),
-               _tuning.state_token())
-        compiled = self._cache.get(key)
-        was_miss = compiled is None
-        if was_miss:
-            _cache_count("misses", "compile")
-            if _rfaults._active:
-                _rfaults.fire("compile",
-                              getattr(program, "_rng_run_counter", 0),
-                              program=f"{id(program)}:v{program._version}")
-            feed_shapes = {kk: tuple(np.shape(v))[1:]
-                           for kk, v in feed.items()}
-            self._maybe_verify(program, list(feed), fetch_names,
-                               wrapper=compiled_wrapper,
-                               feed_shapes=feed_shapes, fuse_k=k)
-            self._note_compile(program, {
-                "version": key[1], "shape": key[2], "fetches": key[3],
-                "seed": key[4], "flags": key[5], "strategy": (),
-                "fuse": key[6], "tuning": key[7]})
-            self._last_compile_info = {
-                "program": f"{id(program)}:v{program._version}",
-                "feed_shapes": {n: list(s) for n, s in feed_shapes.items()},
-                "fetches": list(fetch_names)[:32], "fuse_k": k}
-            compiled = self._compile_fused(program, list(feed), fetch_names,
-                                           state_in, state_out, k,
-                                           health_on, include_state)
-            self._store_compiled(key, compiled)
-        else:
-            _cache_count("hits", "compile")
-            self._cache.move_to_end(key)
-
-        label = f"{id(program)}:v{program._version}"
-        _phase = _obs_timeline.phase
-        step_idx, mut_vals, ro_vals, feed_vals, rng = self._feed_prep(
-            program, compiled, scope, feed, label, k=k)
-        counter = step_idx      # substep i runs under counter + i
-        _obs_timeline.annotate(step=step_idx, program=label)
-
-        if was_miss:
-            # the megastep key's strategy slot carries ("__fused__", k, ...)
-            # -- a K=4 scan is a different store entry than the K=1 step,
-            # as it must be
-            key = self._materialize_miss(
-                "fused_step", program, key, compiled,
-                (mut_vals, ro_vals, feed_vals, rng), label, step_idx,
-                feed_shapes, list(feed), fetch_names, compiled_wrapper,
-                world_dependent=False)
-
-        from .. import flags as _flags
-        obs_on = _obs_journal.enabled()
-        step_fn = compiled.executable if compiled.executable is not None \
-            else compiled.fn
-        if _rfaults._active:
-            _rfaults.fire("dispatch", step_idx, program=label)
-        t_run = time.perf_counter()
-        fallback_retraced = False
-        with _phase("megastep", step=step_idx, program=label, k=k):
-            with _phase("dispatch", step=step_idx, program=label, k=k):
-                try:
-                    fetches, new_state, hflags = step_fn(
-                        mut_vals, ro_vals, feed_vals, rng)
-                except TypeError:
-                    if step_fn is compiled.fn:
-                        raise
-                    compiled.executable = None
-                    fallback_retraced = True
-                    fetches, new_state, hflags = compiled.fn(
-                        mut_vals, ro_vals, feed_vals, rng)
-            if _flags.get_flag("benchmark"):
-                with _phase("fetch_sync", step=step_idx, program=label):
-                    jax.block_until_ready(new_state)
-            elif obs_on:
-                with _phase("fetch_sync", step=step_idx, program=label):
-                    jax.block_until_ready((fetches, new_state))
-        run_s = time.perf_counter() - t_run
-        _OBS.histogram("executor_run_seconds",
-                       "Executor.run dispatch/step wall time").observe(run_s)
-        _OBS.counter("executor_runs_total", "Executor.run calls").inc(k)
-
-        faults_fired = False
-        if _rfaults._active:
-            fired0 = sum(f.fired for f in _rfaults._active)
-            for i in range(k):
-                _rfaults.fire("fetch", counter + i, program=label)
-            rows = [[f[i] for f in fetches] for i in range(k)]
-            for i in range(k):
-                rows[i], new_state = _rfaults.corrupt_step(
-                    counter + i, list(fetch_names), rows[i], new_state,
-                    program=label)
-            if sum(f.fired for f in _rfaults._active) != fired0:
-                faults_fired = True
-                # restack the (possibly corrupted) substep rows; chaos
-                # mode only -- the clean path never materializes here
-                fetches = [np.stack([np.asarray(rows[i][j])
-                                     for i in range(k)])
-                           for j in range(len(fetch_names))]
-        for n, v in new_state.items():
-            scope.set_var(n, v)
-        if health_on:
-            if faults_fired:
-                # injected corruption happened AFTER the in-scan flags were
-                # computed: scan the corrupted host values instead (chaos
-                # path; attribution loses the substep, keeps the var --
-                # each stacked (K, ...) fetch is scanned whole)
-                named = list(zip(fetch_names, fetches))
-                if include_state:
-                    named += list(new_state.items())
-                _obs_health.check(named, label, where="executor",
-                                  health_mode=hmode)
-            elif hflags is not None:
-                flag_rows = _obs_health.read_flags(hflags)
-                _obs_health.check_flag_matrix(
-                    flag_rows, compiled.health_names, label,
-                    where="executor", health_mode=hmode, step0=counter)
-        if _flags.get_flag("check_nan_inf"):
-            bad = [n for n, v in new_state.items()
-                   if np.issubdtype(np.asarray(v).dtype, np.floating) and
-                   not np.isfinite(np.asarray(v)).all()]
-            if bad:
-                raise FloatingPointError(
-                    f"NaN/Inf detected in state vars {bad[:5]} after fused "
-                    f"run (FLAGS_check_nan_inf)")
-        amortized = run_s / k
-        if (not was_miss and not fallback_retraced
-                and (obs_on or _flags.get_flag("benchmark"))):
-            # anomaly windows are keyed per (cache entry, K): the key holds
-            # the fuse marker, so a K=8 megastep's amortized per-substep
-            # time never shares a median with K=1 steps of the same program
-            from ..observability import anomaly as _obs_anomaly
-            _obs_anomaly.DETECTOR.observe(label, amortized, key=key)
-        if _obs_fleet.MONITOR is not None:
-            _obs_fleet.MONITOR.on_step(
-                warm=not was_miss and not fallback_retraced, k=k,
-                step=step_idx)
-        if obs_on:
-            self._obs_step = getattr(self, "_obs_step", 0) + 1
-            from ..observability import memory as _obs_memory
-            if self._obs_step % _obs_memory.sample_interval() == 0:
-                _obs_memory.sample_device_memory("interval")
-            with _phase("journal", step=step_idx, program=label):
-                _obs_journal.emit({
-                    "event": "megastep", "program": id(program),
-                    "version": program._version,
-                    "cache": "miss" if was_miss else "hit",
-                    "k": k, "step0": counter,
-                    "compile_ms": (round(compiled.compile_seconds * 1e3, 3)
-                                   if was_miss and compiled.compile_seconds
-                                   is not None else None),
-                    "run_ms": round(run_s * 1e3, 3),
-                    "amortized_ms": round(amortized * 1e3, 3),
-                    "feed": {n: [list(shape), dtype]
-                             for n, shape, dtype in feed_sig},
-                    "fetch": list(fetch_names),
-                })
+        fetches = self._dispatch(program, compiled_wrapper, feed,
+                                 fetch_names, scope, len(fetch_names), k=k)
         if return_numpy:
             return [np.asarray(f) for f in fetches]
         return list(fetches)
@@ -1984,50 +1964,19 @@ class Executor:
         return read, written
 
     def _compile(self, program: Program, feed_names, fetch_names, state_in,
-                 state_out, wrapper=None):
-        import jax
-
-        block = program.global_block()
+                 state_out, wrapper=None, fuse=None):
+        """Build the step for this call and jit it: ``_make_step``'s one
+        definition, wrapped by what the call is.  ``fuse`` is ``(k,
+        health_on, include_state)`` for a megastep of k steps, else None."""
         # Buffers both read and written (params under an optimizer update, bn stats)
         # are donated so XLA updates them in place; read-only state is not donated so
         # eval programs can share the same Scope entries.
         mut_names = [n for n in state_in if n in state_out]
         ro_names = [n for n in state_in if n not in state_out]
-        # When jitting over a mesh, ops may open shard_map islands over it
-        # (ring attention over "sp"); they see it via LowerCtx.gspmd_mesh.
-        gmesh = (wrapper.mesh if wrapper is not None and
-                 wrapper.dist_strategy is not None else None)
-
-        seed = program.random_seed if program.random_seed is not None else 0
-
-        def step(mut_state, ro_state, feed, rng_counter):
-            import jax as _jax
-            rng = _jax.random.fold_in(_jax.random.PRNGKey(seed), rng_counter)
-            env: Dict[str, Any] = {}
-            env.update(mut_state)
-            env.update(ro_state)
-            env.update(feed)
-
-            def block_runner(idx, sub_env, key=rng):
-                # Sub-blocks see the enclosing env (parameters and outer temps
-                # become loop constants under lax.scan/while), with the loop's
-                # own carries/inputs taking precedence.
-                sub_block = program.blocks[idx]
-                merged = dict(env)
-                merged.update(sub_env)
-                return trace_block(sub_block, merged, key, block_runner,
-                                   gspmd_mesh=gmesh)
-
-            trace_block(block, env, rng, block_runner, gspmd_mesh=gmesh)
-            fetches = []
-            for n in fetch_names:
-                if n not in env:
-                    raise KeyError(f"fetch variable {n!r} was not produced by the "
-                                   f"program and is not in the feed/scope")
-                fetches.append(env[n])
-            new_state = {n: env[n] for n in state_out if n in env}
-            return fetches, new_state
-
+        names = (program, feed_names, fetch_names, mut_names, ro_names,
+                 state_out)
+        if fuse is not None:
+            return self._scan_of_k(*names, *fuse)
         if wrapper is not None and wrapper.dist_strategy is not None and \
                 getattr(program, "_comm_explicit", None):
             # Explicit-dp path (comm compression on): the whole step runs
@@ -2037,9 +1986,7 @@ class Executor:
             # f32 reduction.  Replication of the state outputs holds by
             # construction (every shard-divergent path passes through a
             # collective) and is pinned by the parity tests.
-            return self._compile_explicit_dp(
-                program, feed_names, fetch_names, mut_names, ro_names,
-                state_out, wrapper, seed)
+            return self._explicit_dp(*names, wrapper)
         if wrapper is not None and wrapper.dist_strategy is not None:
             # SPMD path (the ParallelExecutor analog): jit over the strategy's mesh
             # with sharding constraints on state and feeds; XLA/GSPMD inserts the
@@ -2047,47 +1994,18 @@ class Executor:
             # Per-var shardings (incl. ZeRO accumulator sharding under
             # ReduceStrategy.Reduce) come from wrapper.state_sharding -- shared
             # with checkpoint reshard-on-load (io.py) so they always agree.
-            from jax.sharding import NamedSharding, PartitionSpec as P
-            ds = wrapper.dist_strategy
-            mesh = wrapper.mesh
-            var_of = block.find_var_recursive
+            # When jitting over a mesh, ops may open shard_map islands over it
+            # (ring attention over "sp"); they see it via LowerCtx.gspmd_mesh.
+            step = _make_step(program, fetch_names, state_out,
+                              {"gspmd_mesh": wrapper.mesh})
+            return _jit_step(step, mut_names, ro_names, state_out,
+                             fetch_names, _mesh_shardings(
+                                 *names, wrapper, wrapper.state_sharding))
+        return _jit_step(_make_step(program, fetch_names, state_out),
+                         mut_names, ro_names, state_out, fetch_names)
 
-            def state_sharding(names):
-                return {n: wrapper.state_sharding(n) for n in names}
-
-            in_shardings = (
-                state_sharding(mut_names),
-                state_sharding(ro_names),
-                {n: NamedSharding(
-                    mesh, ds.data_spec(n, len(var_of(n).shape)
-                                       if var_of(n) is not None else 1))
-                 for n in feed_names},
-                NamedSharding(mesh, P()),
-            )
-            out_shardings = (
-                [NamedSharding(mesh, P())] * len(fetch_names),
-                state_sharding(state_out),
-            )
-            jit_kw = {}
-            if _xla_options():
-                jit_kw["compiler_options"] = _xla_options()
-            jitted = jax.jit(step, donate_argnums=(0,),
-                             in_shardings=in_shardings,
-                             out_shardings=out_shardings, **jit_kw)
-            state_sh = dict(in_shardings[0])
-            state_sh.update(in_shardings[1])
-            return _CompiledStep(jitted, (mut_names, ro_names), state_out,
-                                 fetch_names, state_shardings=state_sh,
-                                 feed_shardings=in_shardings[2])
-        jit_kw = {}
-        if _xla_options():
-            jit_kw["compiler_options"] = _xla_options()
-        jitted = jax.jit(step, donate_argnums=(0,), **jit_kw)
-        return _CompiledStep(jitted, (mut_names, ro_names), state_out, fetch_names)
-
-    def _compile_explicit_dp(self, program: Program, feed_names,
-                             fetch_names, mut_names, ro_names, state_out,
-                             wrapper, seed):
+    def _explicit_dp(self, program: Program, feed_names, fetch_names,
+                     mut_names, ro_names, state_out, wrapper):
         """Compile the step as ``jit(shard_map(step))`` over the dp axis
         (comm compression -- see comm/rewrite.py).  Each shard traces the
         SAME trace_block as the GSPMD path but on its local batch slice,
@@ -2102,83 +2020,53 @@ class Executor:
         from jax import shard_map
         from jax.sharding import NamedSharding, PartitionSpec as P
 
-        block = program.global_block()
-        ds = wrapper.dist_strategy
         mesh = wrapper.mesh
         info = program._comm_explicit
         dp = info["axis"]
-        var_of = block.find_var_recursive
-        from ..comm.compress import is_residual
+        ndp = int(info["ndp"])
+        var_of = program.global_block().find_var_recursive
 
-        def state_spec(n):
-            if is_residual(n):
+        def state_sharding(n):
+            if _comm_is_residual(n):
                 v = var_of(n)
                 ndim = len(v.shape) if v is not None else 1
-                return P(dp, *([None] * (ndim - 1)))
-            return P()
+                return NamedSharding(mesh, P(dp, *([None] * (ndim - 1))))
+            return NamedSharding(mesh, P())
 
-        def feed_spec(n):
+        def across_shards(n, f):
             v = var_of(n)
-            return ds.data_spec(n, len(v.shape) if v is not None else 1)
+            d0 = v.shape[0] if v is not None and v.ndim else None
+            local0 = f.shape[0] if getattr(f, "ndim", 0) else None
+            if local0 is not None and (
+                    d0 == -1 or (isinstance(d0, int) and d0 > 0
+                                 and local0 * ndp == d0)):
+                # batch-carrying fetch: declared dim 0 is dynamic, or
+                # the traced local extent is exactly 1/ndp of the
+                # declared global one.  Each shard holds its
+                # contiguous block of rows -- all_gather reassembles
+                # the full global batch the GSPMD fetch returns
+                return jax.lax.all_gather(f, dp, axis=0, tiled=True)
+            if jnp.issubdtype(jnp.asarray(f).dtype, jnp.inexact):
+                # per-shard means -> global-batch mean (matches the
+                # GSPMD fetch of a loss/metric); non-float fetches
+                # must already be replicated
+                return jax.lax.pmean(f, dp)
+            return f
 
-        mut_specs = {n: state_spec(n) for n in mut_names}
-        ro_specs = {n: state_spec(n) for n in ro_names}
-        feed_specs = {n: feed_spec(n) for n in feed_names}
-        out_state_specs = {n: state_spec(n) for n in state_out}
-
-        ndp = int(info["ndp"])
-
-        def step(mut_state, ro_state, feed, rng_counter):
-            # per-shard stream: without the axis_index fold every shard
-            # would draw IDENTICAL random bits (correlated dropout masks
-            # across data-parallel shards).  Stochastic programs are
-            # therefore statistically equivalent to -- not bit-equal
-            # with -- the GSPMD trace; deterministic programs are pinned
-            # byte-identical.
-            rng = jax.random.fold_in(
-                jax.random.fold_in(jax.random.PRNGKey(seed), rng_counter),
-                jax.lax.axis_index(dp))
-            env: Dict[str, Any] = {}
-            env.update(mut_state)
-            env.update(ro_state)
-            env.update(feed)
-
-            def block_runner(idx, sub_env, key=rng):
-                sub_block = program.blocks[idx]
-                merged = dict(env)
-                merged.update(sub_env)
-                return trace_block(sub_block, merged, key, block_runner,
-                                   mesh=mesh)
-
-            trace_block(block, env, rng, block_runner, mesh=mesh)
-            fetches = []
-            for n in fetch_names:
-                if n not in env:
-                    raise KeyError(
-                        f"fetch variable {n!r} was not produced by the "
-                        f"program and is not in the feed/scope")
-                f = env[n]
-                v = var_of(n)
-                d0 = v.shape[0] if v is not None and v.ndim else None
-                local0 = f.shape[0] if getattr(f, "ndim", 0) else None
-                if local0 is not None and (
-                        d0 == -1 or (isinstance(d0, int) and d0 > 0
-                                     and local0 * ndp == d0)):
-                    # batch-carrying fetch: declared dim 0 is dynamic, or
-                    # the traced local extent is exactly 1/ndp of the
-                    # declared global one.  Each shard holds its
-                    # contiguous block of rows -- all_gather reassembles
-                    # the full global batch the GSPMD fetch returns
-                    f = jax.lax.all_gather(f, dp, axis=0, tiled=True)
-                elif jnp.issubdtype(jnp.asarray(f).dtype, jnp.inexact):
-                    # per-shard means -> global-batch mean (matches the
-                    # GSPMD fetch of a loss/metric); non-float fetches
-                    # must already be replicated
-                    f = jax.lax.pmean(f, dp)
-                fetches.append(f)
-            new_state = {n: env[n] for n in state_out if n in env}
-            return fetches, new_state
-
+        # per-shard stream: without the axis_index fold every shard
+        # would draw IDENTICAL random bits (correlated dropout masks
+        # across data-parallel shards).  Stochastic programs are
+        # therefore statistically equivalent to -- not bit-equal
+        # with -- the GSPMD trace; deterministic programs are pinned
+        # byte-identical.
+        step = _make_step(program, fetch_names, state_out, {"mesh": mesh},
+                          rng_fold=lambda: jax.lax.axis_index(dp),
+                          fetch_hook=across_shards)
+        shardings = _mesh_shardings(program, feed_names, fetch_names,
+                                    mut_names, ro_names, state_out, wrapper,
+                                    state_sharding)
+        in_specs, out_specs = jax.tree_util.tree_map(
+            lambda sh: sh.spec, shardings)
         # Replication is guaranteed by construction (every shard-divergent
         # path -- the gradients -- passes through the inserted collectives;
         # state updates are then deterministic functions of replicated
@@ -2187,40 +2075,14 @@ class Executor:
         # pessimistically 'varying'), so the check is disabled.  The
         # convergence-parity tests pin the actual replication: explicit-mode
         # losses match the GSPMD path.
-        local = shard_map(
-            step, mesh=mesh,
-            in_specs=(mut_specs, ro_specs, feed_specs, P()),
-            out_specs=([P()] * len(fetch_names), out_state_specs),
-            check_vma=False)
+        local = shard_map(step, mesh=mesh, in_specs=in_specs,
+                          out_specs=out_specs, check_vma=False)
+        return _jit_step(local, mut_names, ro_names, state_out, fetch_names,
+                         shardings)
 
-        def sharding(spec):
-            return NamedSharding(mesh, spec)
-
-        in_shardings = (
-            {n: sharding(s) for n, s in mut_specs.items()},
-            {n: sharding(s) for n, s in ro_specs.items()},
-            {n: sharding(s) for n, s in feed_specs.items()},
-            sharding(P()),
-        )
-        out_shardings = (
-            [sharding(P())] * len(fetch_names),
-            {n: sharding(s) for n, s in out_state_specs.items()},
-        )
-        jit_kw = {}
-        if _xla_options():
-            jit_kw["compiler_options"] = _xla_options()
-        jitted = jax.jit(local, donate_argnums=(0,),
-                         in_shardings=in_shardings,
-                         out_shardings=out_shardings, **jit_kw)
-        state_sh = dict(in_shardings[0])
-        state_sh.update(in_shardings[1])
-        return _CompiledStep(jitted, (mut_names, ro_names), state_out,
-                             fetch_names, state_shardings=state_sh,
-                             feed_shardings=in_shardings[2])
-
-    def _compile_fused(self, program: Program, feed_names, fetch_names,
-                       state_in, state_out, k: int, health_on: bool,
-                       include_state: bool):
+    def _scan_of_k(self, program: Program, feed_names, fetch_names,
+                   mut_names, ro_names, state_out, k: int, health_on: bool,
+                   include_state: bool):
         """Compile K training steps as one ``lax.scan``-of-step megastep.
 
         The scan body is the SAME trace the single step compiles (same
@@ -2236,41 +2098,14 @@ class Executor:
         import jax
         import jax.numpy as jnp
 
-        block = program.global_block()
-        mut_names = [n for n in state_in if n in state_out]
-        ro_names = [n for n in state_in if n not in state_out]
         tail_names = [n for n in state_out if n not in mut_names]
-        seed = program.random_seed if program.random_seed is not None else 0
         health_names: List[str] = []
-
-        def substep(mut_state, ro_state, feed, rng_counter):
-            rng = jax.random.fold_in(jax.random.PRNGKey(seed), rng_counter)
-            env: Dict[str, Any] = {}
-            env.update(mut_state)
-            env.update(ro_state)
-            env.update(feed)
-
-            def block_runner(idx, sub_env, key=rng):
-                sub_block = program.blocks[idx]
-                merged = dict(env)
-                merged.update(sub_env)
-                return trace_block(sub_block, merged, key, block_runner)
-
-            trace_block(block, env, rng, block_runner)
-            fetches = []
-            for n in fetch_names:
-                if n not in env:
-                    raise KeyError(
-                        f"fetch variable {n!r} was not produced by the "
-                        f"program and is not in the feed/scope")
-                fetches.append(env[n])
-            new_state = {n: env[n] for n in state_out if n in env}
-            return fetches, new_state
+        step = _make_step(program, fetch_names, state_out)
 
         def megastep(mut_state, ro_state, feeds, rng_counter0):
             def body(carry, feed):
                 mut, cnt = carry
-                fetches, new_state = substep(mut, ro_state, feed, cnt)
+                fetches, new_state = step(mut, ro_state, feed, cnt)
                 new_mut = {n: new_state.get(n, mut[n]) for n in mut_names}
                 tail = {n: new_state[n] for n in tail_names
                         if n in new_state}
@@ -2293,12 +2128,7 @@ class Executor:
                 new_state[n] = v[-1]
             return ys["fetch"], new_state, ys.get("health")
 
-        jit_kw = {}
-        if _xla_options():
-            jit_kw["compiler_options"] = _xla_options()
-        jitted = jax.jit(megastep, donate_argnums=(0,), **jit_kw)
-        cs = _CompiledStep(jitted, (mut_names, ro_names), state_out,
-                           fetch_names)
+        cs = _jit_step(megastep, mut_names, ro_names, state_out, fetch_names)
         cs.fused_k = k
         cs.health_names = health_names  # filled when the trace runs
         return cs

@@ -1,5 +1,8 @@
 """Executor tests (analog of reference test_executor_and_mul.py etc.)."""
+import os
+
 import numpy as np
+import pytest
 
 import paddle_tpu as fluid
 
@@ -76,3 +79,139 @@ def test_state_mutation_batch_norm_stats():
                 fetch_list=[y])
         after = np.asarray(scope.find_var(mean_name))
     assert not np.allclose(before, after), "running stats must update"
+
+
+# ------------------------------------------- one step, one dispatch body --
+
+def _dropout_mlp(seed=5):
+    main, startup = fluid.Program(), fluid.Program()
+    main.random_seed = startup.random_seed = seed
+    with fluid.unique_name.guard(), fluid.program_guard(main, startup):
+        x = fluid.data("x", [8], "float32")
+        label = fluid.data("label", [1], "int64")
+        h = fluid.layers.dropout(fluid.layers.fc(x, 16, act="relu"), 0.25)
+        loss = fluid.layers.mean(fluid.layers.softmax_with_cross_entropy(
+            fluid.layers.fc(h, 4), label))
+        fluid.optimizer.SGD(0.1).minimize(loss)
+    return main, startup, loss
+
+
+def _dropout_mlp_losses(how, monkeypatch, steps=4):
+    """Losses of ``steps`` steps of the dropout MLP from a fresh start, run
+    through the builder ``how`` names, and how often that traced the main
+    program's global block."""
+    from paddle_tpu.core import executor as executor_mod
+    main, startup, loss = _dropout_mlp()
+    main._rng_run_counter = startup._rng_run_counter = 0
+    rng = np.random.RandomState(0)
+    feeds = [{"x": rng.rand(8, 8).astype("float32"),
+              "label": rng.randint(0, 4, (8, 1)).astype("int64")}
+             for _ in range(steps)]
+    traced = []
+    real = executor_mod.trace_block
+
+    def counting(block, *args, **kwargs):
+        if block is main.global_block():
+            traced.append(sorted(k for k in ("mesh", "gspmd_mesh")
+                                 if kwargs.get(k) is not None))
+        return real(block, *args, **kwargs)
+
+    monkeypatch.setattr(executor_mod, "trace_block", counting)
+    target = main
+    if how in ("gspmd_dp2", "explicit_dp2"):
+        ds = fluid.DistributedStrategy(mesh_shape={"dp": 2})
+        if how == "explicit_dp2":
+            ds.comm_compression = "int8"
+            ds.comm_compress_min_bytes = 0
+        target = fluid.CompiledProgram(main).with_strategy(ds)
+    exe = fluid.Executor()
+    with fluid.scope_guard(fluid.Scope()):
+        exe.run(startup)
+        if how == "fused_k4":
+            out, = exe.run_fused(main, feeds=feeds, fetch_list=[loss],
+                                 return_numpy=True)
+            losses = out.reshape(-1)
+        else:
+            losses = np.asarray([
+                exe.run(target, feed=f, fetch_list=[loss])[0].reshape(())
+                for f in feeds])
+    return losses.astype(np.float32), traced
+
+
+@pytest.mark.parametrize("how", ["plain", "gspmd_dp2", "explicit_dp2",
+                                 "fused_k4"])
+def test_every_builder_traces_the_one_step_once(how, monkeypatch):
+    """Plain jit, GSPMD over dp=2, ``shard_map`` over dp=2 and a scan of
+    four steps wrap one step definition: a compile traces the main program's
+    global block exactly once, with the mesh keyword of its builder, and the
+    losses are the plain step's (bit for bit under the scan, which draws the
+    same per-step keys; to ``test_parallel``'s tolerance over a mesh; the
+    explicit-dp step folds its shard index into the key, so its dropout
+    masks -- and only they -- differ from the GSPMD step's)."""
+    plain, _ = _dropout_mlp_losses("plain", monkeypatch)
+    monkeypatch.undo()
+    losses, traced = _dropout_mlp_losses(how, monkeypatch)
+    mesh_kw = {"gspmd_dp2": ["gspmd_mesh"], "explicit_dp2": ["mesh"]}
+    assert traced == [mesh_kw.get(how, [])]
+    assert np.isfinite(losses).all()
+    if how in ("plain", "fused_k4"):
+        assert losses.tobytes() == plain.tobytes()
+    elif how == "gspmd_dp2":
+        np.testing.assert_allclose(losses, plain, rtol=2e-4, atol=1e-5)
+    else:
+        assert not np.allclose(losses, plain, rtol=2e-4, atol=1e-5)
+        np.testing.assert_allclose(losses, plain, rtol=0.2)
+
+
+def test_import_loads_no_kernel_and_no_optional_subsystem():
+    """``import paddle_tpu`` is part of every run's set-up: it loads no
+    Pallas module of JAX (about a second each process) and none of the
+    subsystems a training run reaches only when asked."""
+    import subprocess
+    import sys
+    code = (
+        "import sys, paddle_tpu\n"
+        "subsystems = ('paddle_tpu.tuning', 'paddle_tpu.warmstore',\n"
+        "              'paddle_tpu.serving', 'paddle_tpu.online')\n"
+        "print(sorted(m for m in sys.modules\n"
+        "             if (m.startswith('jax') and 'pallas' in m)\n"
+        "             or any(m == s or m.startswith(s + '.')\n"
+        "                    for s in subsystems)))\n")
+    repo = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+    out = subprocess.run(
+        [sys.executable, "-c", code], cwd=repo, capture_output=True,
+        text=True, timeout=300,
+        env={**os.environ, "JAX_PLATFORMS": "cpu"})
+    assert out.returncode == 0, out.stderr
+    assert out.stdout.strip().splitlines()[-1] == "[]", out.stdout
+
+
+def test_cache_keys_of_run_and_run_fused_element_by_element(monkeypatch):
+    """The executor's cache key, in its documented order: (program id,
+    program version, feed signature, fetch names, seed, XLA options flag,
+    strategy signature, tuning token).  A megastep's feed signature is per
+    step and its strategy slot is ("__fused__", k, health on, state too).
+    The warm store derives its keys from these positions."""
+    from paddle_tpu import flags, tuning
+    monkeypatch.delenv("PADDLE_TPU_OBS_HEALTH", raising=False)
+    main, startup, loss = _dropout_mlp(seed=9)
+    feed = {"x": np.ones((8, 8), "float32"),
+            "label": np.zeros((8, 1), "int64")}
+    sig = (("label", (8, 1), "int64"), ("x", (8, 8), "float32"))
+    exe = fluid.Executor()
+    with fluid.scope_guard(fluid.Scope()):
+        exe.run(startup)
+        exe.run(main, feed=feed, fetch_list=[loss])
+        run_key = next(reversed(exe._cache))
+        exe.run_fused(main, feeds=[feed] * 4, fetch_list=[loss])
+        fused_key = next(reversed(exe._cache))
+    head = (id(main), main._version, sig, (loss.name,), 9,
+            flags.get_flag("xla_compiler_options"))
+    assert len(run_key) == len(fused_key) == 8
+    for i, want in enumerate(head + ((), tuning.state_token())):
+        assert run_key[i] == want, (i, run_key[i], want)
+    for i, want in enumerate(head + (("__fused__", 4, False, False),
+                                     tuning.state_token())):
+        assert fused_key[i] == want, (i, fused_key[i], want)
+    assert exe._cache[run_key].executable is not None
+    assert exe._cache[fused_key].fused_k == 4
