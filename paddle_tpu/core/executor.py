@@ -250,7 +250,8 @@ class _CompiledStep:
 
 
 def trace_block(block: Block, env: Dict[str, Any], base_key, block_runner=None,
-                mesh=None, stop_at: Optional[int] = None, gspmd_mesh=None):
+                mesh=None, stop_at: Optional[int] = None, gspmd_mesh=None,
+                data_axis=None):
     """Execute/trace the ops of ``block`` over ``env`` (name -> jax value).
 
     This is the single place op lowerings are invoked -- used by the jitted whole-program
@@ -278,7 +279,7 @@ def trace_block(block: Block, env: Dict[str, Any], base_key, block_runner=None,
             (ns[0] for ns in op.outputs.values() if ns and ns[0] != EMPTY_VAR), op.type)
         ctx = LowerCtx(op.attrs, base_key, stable_salt(salt_name),
                        block_runner=block_runner, program=block.program, mesh=mesh,
-                       gspmd_mesh=gspmd_mesh)
+                       gspmd_mesh=gspmd_mesh, data_axis=data_axis)
         try:
             # IR->HLO attribution (observability/attribution.py): every HLO
             # instruction this lowering traces carries "<op_type>#<op_idx>"
@@ -317,8 +318,9 @@ def _make_step(program: Program, fetch_names, state_out, mesh_kw=None,
     """The one definition of the step every builder of ``Executor._compile``
     wraps: ``step(mut_state, ro_state, feed, rng_counter) -> (fetches,
     new_state)``.  The parameters are what the builders differ in:
-    ``mesh_kw`` is the mesh keyword ``trace_block`` gets (``gspmd_mesh`` under
-    a jit over a mesh, ``mesh`` inside a ``shard_map``, none on one device),
+    ``mesh_kw`` is the mesh keywords ``trace_block`` gets (``gspmd_mesh`` and
+    the strategy's ``data_axis`` under a jit over a mesh, ``mesh`` inside a
+    ``shard_map``, none on one device),
     ``rng_fold()`` is traced as one more ``fold_in`` into the step's key, and
     ``fetch_hook(name, value)`` replaces each fetch."""
     block = program.global_block()
@@ -719,6 +721,9 @@ class Executor:
         from ..observability import attention as _obs_attention
         _obs_attention.count_lowerings(
             program._lowering_notes.pop("fused_attention", {}), label)
+        from ..observability import masks as _obs_masks
+        _obs_masks.count_draws(
+            program._lowering_notes.pop("mask_draw", {}), label)
         # IR->HLO attribution walk: once per compile miss, only when obs /
         # PADDLE_TPU_OBS_ATTRIB / an armed --emit-hlo capture asks for it
         # (on_compile is a no-op otherwise and never raises)
@@ -1995,9 +2000,11 @@ class Executor:
             # ReduceStrategy.Reduce) come from wrapper.state_sharding -- shared
             # with checkpoint reshard-on-load (io.py) so they always agree.
             # When jitting over a mesh, ops may open shard_map islands over it
-            # (ring attention over "sp"); they see it via LowerCtx.gspmd_mesh.
+            # (ring attention over "sp", a dropout mask's shard over the data
+            # axis); they see it via LowerCtx.gspmd_mesh / .data_axis.
             step = _make_step(program, fetch_names, state_out,
-                              {"gspmd_mesh": wrapper.mesh})
+                              {"gspmd_mesh": wrapper.mesh,
+                               "data_axis": wrapper.dist_strategy.data_axis})
             return _jit_step(step, mut_names, ro_names, state_out,
                              fetch_names, _mesh_shardings(
                                  *names, wrapper, wrapper.state_sharding))

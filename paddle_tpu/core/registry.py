@@ -53,7 +53,8 @@ class LowerCtx:
     """
 
     def __init__(self, attrs: dict, base_key=None, salt: int = 0, block_runner=None,
-                 program=None, mesh=None, gspmd_mesh=None, abstract=False):
+                 program=None, mesh=None, gspmd_mesh=None, abstract=False,
+                 data_axis=None):
         self.attrs = attrs
         self._base_key = base_key
         self._salt = salt
@@ -64,6 +65,9 @@ class LowerCtx:
         # shard_map): ops may open their own shard_map islands over it
         # (ring attention) but must NOT call axis primitives directly
         self.gspmd_mesh = gspmd_mesh
+        # beside gspmd_mesh: the name of the strategy's data axis
+        # (DistributedStrategy.data_axis, the one data_spec lays feeds over)
+        self.data_axis = data_axis
         # True under eval_shape-based inference: the mesh/backend are unknown,
         # so impl choices must not be validated and shape-equivalent fallbacks
         # should be used (e.g. fused_attention lowers its composed path)
@@ -89,6 +93,39 @@ class LowerCtx:
         if key is None:  # shape-inference / eval path
             key = jax.random.PRNGKey(0)
         return jax.random.fold_in(key, (self._salt + offset) & 0x7FFFFFFF)
+
+    def bernoulli_mask(self, key, keep, shape):
+        """``jax.random.bernoulli(key, keep, shape)``: a training op's
+        dropout mask. Under a GSPMD mesh whose data axis has n > 1 devices
+        dividing ``shape[0]``, each device draws only its shard of the
+        leading (batch) dimension, in a ``shard_map`` island over the mesh
+        with its index on the data axis folded into ``key``: XLA's SPMD
+        partitioner has no rule for ``RngBitGenerator`` and would run it at
+        the global shape on every device (PERF.md section 6, PR 31). Such a
+        run's masks depend on n, like the explicit-dp step's
+        (``Executor._explicit_dp``); forward and re-lowered forward reach
+        the same island with the same key. Which way the op drew is noted
+        for ``mask_draw_total`` (observability/masks.py)."""
+        import jax
+        mesh, axis = self.gspmd_mesh, self.data_axis
+        n = mesh.shape.get(axis, 1) if mesh is not None else 1
+        # an op lowered inside another op's shard_map over the mesh (the
+        # pipeline's stages) sees gspmd_mesh too: no island inside an island
+        if (n <= 1 or not shape or shape[0] % n
+                or jax.sharding.get_abstract_mesh().manual_axes):
+            self.note("mask_draw", ("global", 1))
+            return jax.random.bernoulli(key, keep, shape)
+        from jax.sharding import PartitionSpec as P
+        self.note("mask_draw", ("shard", n))
+        local_shape = (shape[0] // n, *shape[1:])
+
+        def local(k):
+            k = jax.random.fold_in(k, jax.lax.axis_index(axis))
+            return jax.random.bernoulli(k, keep, local_shape)
+
+        return jax.shard_map(
+            local, mesh=mesh, in_specs=P(),
+            out_specs=P(axis, *([None] * (len(shape) - 1))))(key)
 
 
 def stable_salt(name: str) -> int:
@@ -251,7 +288,8 @@ def _generic_grad_lower(fwd: OpDef, ctx, ins):
         fwd_attrs = {k: v for k, v in ctx.attrs.items()
                      if not k.startswith("__fwd_")}
     fwd_ctx = LowerCtx(fwd_attrs, ctx._base_key, ctx._salt, ctx.block_runner,
-                       ctx.program, ctx.mesh, gspmd_mesh=ctx.gspmd_mesh)
+                       ctx.program, ctx.mesh, gspmd_mesh=ctx.gspmd_mesh,
+                       data_axis=ctx.data_axis)
 
     def f(*diff_vals):
         full = {s: list(ins[s]) for s in fwd_in_slots}

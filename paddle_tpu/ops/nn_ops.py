@@ -330,7 +330,8 @@ def dropout(ctx, ins):
         # Declared outputs are always produced (clone(for_test) keeps grad ops that
         # list Mask as input); an all-ones mask is free after XLA DCE.
         return {"Out": [out], "Mask": [jnp.ones_like(x)]}
-    keep = jax.random.bernoulli(ctx.rng(ctx.attr("seed", 0) or 0), 1.0 - p, x.shape)
+    keep = ctx.bernoulli_mask(ctx.rng(ctx.attr("seed", 0) or 0), 1.0 - p,
+                              x.shape)
     mask = keep.astype(x.dtype)
     if impl == "upscale_in_train":
         out = jnp.where(p >= 1.0, jnp.zeros_like(x), x * mask / (1.0 - p))

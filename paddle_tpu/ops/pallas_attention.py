@@ -114,8 +114,11 @@ def _pl():
 # composed (XLA-fused) reference path
 # --------------------------------------------------------------------------------------
 
-def composed_attention(q, k, v, bias, scale, dropout, causal, rng):
-    """Plain jnp attention: the numerics oracle and the non-TPU lowering."""
+def composed_attention(q, k, v, bias, scale, dropout, causal, rng,
+                       bernoulli=None):
+    """Plain jnp attention: the numerics oracle and the non-TPU lowering.
+    ``bernoulli(key, keep, shape)`` draws the dropout mask: the op hands its
+    ``LowerCtx.bernoulli_mask``, else ``jax.random.bernoulli``."""
     import jax
     import jax.numpy as jnp
 
@@ -130,7 +133,7 @@ def composed_attention(q, k, v, bias, scale, dropout, causal, rng):
         s = jnp.where(ki <= qi, s, jnp.float32(-1e30))
     p = jax.nn.softmax(s, axis=-1)
     if dropout:
-        keep = jax.random.bernoulli(rng, 1.0 - dropout, p.shape)
+        keep = (bernoulli or jax.random.bernoulli)(rng, 1.0 - dropout, p.shape)
         p = jnp.where(keep, p / (1.0 - dropout), 0.0)
     return jnp.einsum("bhqk,bhkd->bhqd", p.astype(v.dtype), v,
                       preferred_element_type=jnp.float32).astype(q.dtype)
@@ -549,5 +552,5 @@ def fused_attention(ctx, ins):
     else:
         ctx.note("fused_attention", ("xla", S, 0))
         out = composed_attention(q, k, v, bias, float(scale), float(dropout),
-                                 causal, ctx.rng())
+                                 causal, ctx.rng(), ctx.bernoulli_mask)
     return {"Out": [out]}

@@ -290,7 +290,7 @@ def depthwise_conv2d_transpose(ctx, ins):
     sub = LowerCtx({**ctx.attrs, "groups": int(x.shape[1])},
                    ctx._base_key, ctx._salt, ctx.block_runner, ctx.program,
                    ctx.mesh, gspmd_mesh=ctx.gspmd_mesh,
-                   abstract=ctx.abstract)
+                   abstract=ctx.abstract, data_axis=ctx.data_axis)
     return nn_ops.conv2d_transpose(sub, ins)
 
 

@@ -144,10 +144,12 @@ def test_every_builder_traces_the_one_step_once(how, monkeypatch):
     """Plain jit, GSPMD over dp=2, ``shard_map`` over dp=2 and a scan of
     four steps wrap one step definition: a compile traces the main program's
     global block exactly once, with the mesh keyword of its builder, and the
-    losses are the plain step's (bit for bit under the scan, which draws the
-    same per-step keys; to ``test_parallel``'s tolerance over a mesh; the
-    explicit-dp step folds its shard index into the key, so its dropout
-    masks -- and only they -- differ from the GSPMD step's)."""
+    losses are the plain step's: bit for bit under the scan, which draws the
+    same per-step keys; over a mesh only statistically, because both dp
+    steps draw each shard's dropout mask on the device that uses it, with
+    the shard's index folded into the key (the explicit-dp step into the
+    step's key, the GSPMD step in ``LowerCtx.bernoulli_mask``'s island), so
+    their masks -- and only they -- differ from the plain step's."""
     plain, _ = _dropout_mlp_losses("plain", monkeypatch)
     monkeypatch.undo()
     losses, traced = _dropout_mlp_losses(how, monkeypatch)
@@ -156,8 +158,6 @@ def test_every_builder_traces_the_one_step_once(how, monkeypatch):
     assert np.isfinite(losses).all()
     if how in ("plain", "fused_k4"):
         assert losses.tobytes() == plain.tobytes()
-    elif how == "gspmd_dp2":
-        np.testing.assert_allclose(losses, plain, rtol=2e-4, atol=1e-5)
     else:
         assert not np.allclose(losses, plain, rtol=2e-4, atol=1e-5)
         np.testing.assert_allclose(losses, plain, rtol=0.2)
