@@ -1111,8 +1111,40 @@ def swiglu(gate, up, row_scale=None, name=None):
     return _var(helper, out)
 
 
+def short_conv(x, seq, kernel_size=3, param_attr=None, name=None):
+    """The gated short convolution of a hybrid decoder layer, between its two
+    projections: ``x [T, 3C]`` holds the gates ``B | C`` and the signal ``u``
+    side by side; returns ``C * conv(B * u) [T, C]`` with one causal filter
+    of ``kernel_size`` taps a channel (parameter ``[C, kernel_size]``) over
+    sequences of ``seq`` consecutive rows, zeros before each sequence's
+    start (``ops/decoder_ops.py:short_conv``, which lowers the Pallas
+    kernels on a TPU and the composed form elsewhere)."""
+    helper = LayerHelper("short_conv", name=name)
+    w = helper.create_parameter(
+        param_attr, [int(x.shape[-1]) // 3, int(kernel_size)], x.dtype)
+    out = _out(helper, x.dtype)
+    helper.append_op("short_conv", inputs={"X": [x], "W": [w]},
+                     outputs={"Out": [out]},
+                     attrs={"seq": int(seq)})
+    return _var(helper, out)
+
+
+def moe_bias_update(bias, load, rate, name=None):
+    """``bias += rate * sign(mean(load) - load)``, written into ``bias``
+    itself: the router's selection bias follows the step's expert load
+    (``layers.moe_ffn``'s ``aux["bias"]`` / ``aux["load"]``). Append it
+    after ``minimize``: a grad op lowers its forward again from the op's
+    inputs, so the bias must not change before the backward has run."""
+    helper = LayerHelper("moe_bias_update", name=name)
+    helper.append_op("moe_bias_update", inputs={"Bias": [bias],
+                                                "Load": [load]},
+                     outputs={"BiasOut": [bias]}, attrs={"rate": float(rate)})
+    return bias
+
+
 def moe_ffn(x, num_experts, experts_per_token, expert_width, param_attr=None,
-            name="moe"):
+            name="moe", experts_held=None, scoring="softmax", norm_topk=False,
+            routed_scale=1.0, expert_bias=False):
     """A dropless mixture-of-experts feed-forward layer over tokens
     ``x [T, H]``: a float32 router (softmax over the experts, top-k values
     used as they are), every one of the T x k assignments sent to its expert
@@ -1121,19 +1153,48 @@ def moe_ffn(x, num_experts, experts_per_token, expert_width, param_attr=None,
     ``W_down (silu(W_gate x) * (W_up x))``, its router weight applied to the
     gated product before the down projection.
 
+    ``scoring="sigmoid"``: independent sigmoid scores; with ``expert_bias``
+    the k experts are chosen by score + a bias (a float32 state variable
+    ``<name>_router_bias [E]``, zero at start, no gradient; ``aux["bias"]``,
+    for ``layers.moe_bias_update``) and weighed by the bare score, over the
+    chosen scores' sum under ``norm_topk``, times ``routed_scale``.
+
+    ``experts_held=(first, count)``: this layer holds ``count`` of the
+    ``num_experts`` experts, from expert ``first`` on -- one chip's share of
+    a layer whose experts are split over several. The router keeps all
+    ``num_experts`` outputs and the weights of an assignment are what the
+    whole layer would give it; the stacked weights hold the held experts
+    only, and the output is the held experts' part of each token's sum (an
+    assignment to an expert held elsewhere adds nothing here: what an
+    exchange would bring is not stood in for). The row buffers keep all
+    T x k rows, so nothing can overflow whatever the routing.
+
     Parameters, by name: ``<name>_router_w [H, E]`` float32 and
-    ``<name>_gate_w`` / ``<name>_up_w [E, H, width]``, ``<name>_down_w
-    [E, width, H]`` in x's dtype; ``param_attr`` supplies the initializer.
+    ``<name>_gate_w`` / ``<name>_up_w [held, H, width]``, ``<name>_down_w
+    [held, width, H]`` in x's dtype; ``param_attr`` supplies the initializer.
 
     Returns ``(out [T, H], aux)`` with ``aux`` the router's variables:
-    ``prob [T, E]``, ``logz [T]`` (logsumexp of the logits), ``index
-    [T, k]`` and ``load [E]`` (assignments received by each expert, int32),
-    the last two without gradient, for the router losses and to be fetched.
+    ``prob [T, E]`` (the scores), ``logz [T]`` (logsumexp of the logits;
+    softmax scoring only), ``index [T, k]`` and ``load [E]`` (assignments
+    received by each of the E experts, held here or not, int32), the last
+    two without gradient, for the router losses and to be fetched.
     """
+    from ..initializer import Constant
     from ..layer_helper import ParamAttr
     helper = LayerHelper("moe_ffn", name=name)
     H = int(x.shape[-1])
     E, k, width = int(num_experts), int(experts_per_token), int(expert_width)
+    first, held = (0, E) if experts_held is None else map(int, experts_held)
+    if not (0 <= first and held >= 1 and first + held <= E):
+        raise ValueError(f"moe_ffn: experts_held={experts_held!r} is not a "
+                         f"range of the {E} experts")
+    if scoring not in ("softmax", "sigmoid"):
+        raise ValueError(f"moe_ffn: scoring={scoring!r}")
+    if scoring == "softmax" and (expert_bias or norm_topk
+                                 or routed_scale != 1.0):
+        raise NotImplementedError(
+            "moe_ffn: expert_bias, norm_topk and routed_scale are built for "
+            "scoring='sigmoid' only")
     init = ParamAttr._to_attr(param_attr).initializer
 
     def param(suffix, shape, dtype):
@@ -1147,28 +1208,46 @@ def moe_ffn(x, num_experts, experts_per_token, expert_width, param_attr=None,
     weight, prob, logz = (_out(helper, "float32") for _ in range(3))
     index, order, slot, load = (_out(helper, "int32", stop_gradient=True)
                                 for _ in range(4))
-    op("moe_router", {"X": [x], "W": [param("router_w", [H, E], "float32")]},
-       {"Weight": [weight], "Index": [index], "Prob": [prob],
-        "LogZ": [logz]}, {"k": k})
+    aux = {"prob": prob, "index": index, "load": load}
+    router_in = {"X": [x], "W": [param("router_w", [H, E], "float32")]}
+    routed = {"Weight": [weight], "Index": [index], "Prob": [prob]}
+    router_attrs = {"k": k}
+    if scoring == "softmax":
+        routed["LogZ"] = [logz]
+        aux["logz"] = logz
+    else:
+        router_attrs.update(scoring="sigmoid", norm_topk=bool(norm_topk),
+                            scale=float(routed_scale))
+        if expert_bias:
+            aux["bias"] = helper.create_global_variable(
+                [E], "float32", persistable=True,
+                name=f"{name}_router_bias", initializer=Constant(0.0))
+            router_in["Bias"] = [aux["bias"]]
+    op("moe_router", router_in, routed, router_attrs)
     rows, row_weight = _out(helper, x.dtype), _out(helper, "float32")
+    sorted_to = {"Out": [rows], "RowWeight": [row_weight], "Order": [order],
+                 "Slot": [slot], "Count": [load]}
+    sort_attrs = {"num_experts": E}
+    groups = load           # rows a group of the sorted buffer, in its order
+    if held < E:            # the sort starts at the first held expert
+        groups = _out(helper, "int32", stop_gradient=True)
+        sorted_to["GroupCount"] = [groups]
+        sort_attrs["first_expert"] = first
     op("moe_dispatch", {"X": [x], "Index": [index], "Weight": [weight]},
-       {"Out": [rows], "RowWeight": [row_weight], "Order": [order],
-        "Slot": [slot], "Count": [load]}, {"num_experts": E})
+       sorted_to, sort_attrs)
 
     def experts(inp, suffix, shape):
         out = _out(helper, x.dtype)
         op("moe_expert_matmul",
            {"X": [inp], "W": [param(suffix, shape, x.dtype)],
-            "Count": [load]}, {"Out": [out]})
+            "Count": [groups]}, {"Out": [out]})
         return out
 
-    gated = swiglu(experts(rows, "gate_w", [E, H, width]),
-                   experts(rows, "up_w", [E, H, width]), row_weight)
-    down = experts(gated, "down_w", [E, width, H])
+    gated = swiglu(experts(rows, "gate_w", [held, H, width]),
+                   experts(rows, "up_w", [held, H, width]), row_weight)
+    down = experts(gated, "down_w", [held, width, H])
     out = _out(helper, x.dtype)
     op("moe_combine", {"X": [down], "Order": [order], "Slot": [slot]},
        {"Out": [out]})
     blk = helper.main_program.current_block()
-    return blk.var(out.name), {n: blk.var(v.name) for n, v in
-                               (("prob", prob), ("logz", logz),
-                                ("index", index), ("load", load))}
+    return blk.var(out.name), {n: blk.var(v.name) for n, v in aux.items()}

@@ -1,31 +1,57 @@
 """A decoder-only language model built from a config dict that carries the
 key names of a published ``config.json`` (the catalog's names): token
 embedding -> ``num_hidden_layers`` x block -> final RMSNorm -> untied output
-projection -> next-token cross-entropy, plus the router losses.
+projection -> next-token cross-entropy, plus the router losses where the
+config names their coefficients.
 
 One builder for the decoder families (ROADMAP D6); what a config asks for and
-this file does not build yet raises by name. The first model through it is
-OLMoE-1B-7B (Muennighoff et al., arXiv:2409.02060; HF ``modeling_olmoe.py``):
+this file does not build yet raises by name (``_check``). A block is
+pre-norm, without biases or dropout: ``h = x + operator(norm(x))``,
+``y = h + feed_forward(norm(h))``. What it builds, by config key:
 
-- pre-norm block, no biases, no dropout: ``h = x + o_proj(attn(norm(x)))``,
-  ``y = h + moe(norm(h))``;
-- attention: separate q / k / v projections, RMSNorm over the whole projected
-  q and k (before the split into heads, each with its own scale), rotary
-  embedding (rotate-half) on q and k, causal ``fused_attention`` at scale
-  1/sqrt(head dim), ``impl="auto"``;
-- feed-forward: ``layers.moe_ffn`` -- float32 router, softmax then top-k with
-  the values used as they are (``norm_topk_prob`` false), dropless experts
-  ``W_down (silu(W_gate x) * (W_up x))`` of width ``intermediate_size``;
-- loss: mean next-token cross-entropy + ``router_aux_loss_coef`` x the
-  load-balancing loss (experts x sum over experts of the share of assignments
-  an expert received x its mean router probability, a layer, averaged over
-  the layers) + ``router_z_loss_coef`` x the mean of logsumexp(router
-  logits)^2 (likewise).
+- ``layer_types`` (default: every layer ``full_attention``), one operator a
+  layer. ``full_attention``: separate q / k / v projections with
+  ``num_key_value_heads`` key/value heads (grouped-query attention where
+  fewer than ``num_attention_heads``; ``fused_attention`` reads them in
+  place), RMSNorm of q and k -- ``qk_norm`` ``"projection"``: over the whole
+  projected q and k before the split into heads, one scale an element
+  (OLMoE); ``"head"``: over each head's values, one scale of head size
+  shared by the heads (LFM2) --, rotary embedding (rotate-half,
+  ``rope_theta``) on q and k, causal ``fused_attention`` at scale
+  1/sqrt(head dim), ``impl="auto"``. ``conv``: the gated short convolution
+  ``W_out (C * conv(B * u))`` with ``B, C, u = split(W_in x, 3)`` and a
+  causal depthwise filter of ``conv_L_cache`` taps (``layers.short_conv``).
+- feed-forward: the first ``num_dense_layers`` layers (default 0) a dense
+  SwiGLU ``W_down (silu(W_gate x) * (W_up x))`` of width
+  ``intermediate_size``; the others ``layers.moe_ffn`` of width
+  ``moe_intermediate_size`` (``intermediate_size`` where the config has no
+  such key). ``router_scoring`` ``"softmax"`` (default): float32 router,
+  softmax then top-k with the values used as they are; ``"sigmoid"``:
+  sigmoid scores, chosen by score + bias under ``use_expert_bias``, weighed
+  by the score over the chosen scores' sum under ``norm_topk_prob``, times
+  ``routed_scaling_factor``.
+- one chip's share of a layer that several chips hold: ``num_experts`` is
+  the experts held here, ``num_experts_routed`` (default: the same) the
+  router's width and ``first_expert_held`` (default 0) the first held; the
+  layer's output is the held experts' part (``layers.moe_ffn``). A sliced
+  vocabulary is a smaller ``vocab_size``.
+- loss: mean next-token cross-entropy, + ``router_aux_loss_coef`` x the
+  load-balancing loss (experts x sum over experts of the share of
+  assignments an expert received x its mean router probability, a layer,
+  averaged over the layers) + ``router_z_loss_coef`` x the mean of
+  logsumexp(router logits)^2 (likewise) where the config carries those keys
+  (softmax scoring).
+- ``balance_experts``: the selection bias' update from the step's load,
+  appended by the caller after ``minimize``.
+
+Models through it: OLMoE-1B-7B (Muennighoff et al., arXiv:2409.02060; HF
+``modeling_olmoe.py``) and LFM2-8B-A1B (HF ``modeling_lfm2_moe.py``).
 
 Dtypes follow ``models/bert.py``: the embedding table is float32 whatever
 ``dtype`` says, activations are cast to ``dtype`` right after the lookup,
-weights are created in ``dtype``; RMSNorm, the router and every softmax
-compute in float32 inside their ops; the logits are cast up for the loss.
+weights are created in ``dtype``; RMSNorm, the router, the short convolution
+and every softmax compute in float32 inside their ops; the logits are cast
+up for the loss.
 """
 from __future__ import annotations
 
@@ -35,9 +61,10 @@ from .. import layers
 from ..initializer import Normal
 from ..layer_helper import ParamAttr
 
-_REQUIRED = {"hidden_act": "silu", "norm_topk_prob": False,
-             "tie_word_embeddings": False, "attention_bias": False,
-             "clip_qkv": None, "rope_scaling": None}
+_REQUIRED = {"hidden_act": "silu", "tie_word_embeddings": False,
+             "attention_bias": False, "clip_qkv": None, "rope_scaling": None,
+             "conv_bias": False}
+_OPERATORS = ("full_attention", "conv")
 
 
 def _check(cfg: dict) -> None:
@@ -46,13 +73,51 @@ def _check(cfg: dict) -> None:
             raise NotImplementedError(
                 f"decoder_lm: {key}={cfg[key]!r} is not built yet "
                 f"(only {want!r})")
-    if cfg.get("num_key_value_heads",
-               cfg["num_attention_heads"]) != cfg["num_attention_heads"]:
+    kinds = _layer_types(cfg)
+    if len(kinds) != cfg["num_hidden_layers"]:
+        raise ValueError("layer_types must name every one of the "
+                         "num_hidden_layers layers")
+    for kind in kinds:
+        if kind not in _OPERATORS:
+            raise NotImplementedError(
+                f"decoder_lm: layer type {kind!r} is not built yet (only "
+                f"{_OPERATORS}: no sliding-window or chunked attention, no "
+                f"latent attention, no linear-attention scan)")
+    if cfg["hidden_size"] % cfg["num_attention_heads"] or \
+            cfg["num_attention_heads"] % _kv_heads(cfg):
+        raise ValueError("hidden_size must be a multiple of the head count, "
+                         "the head count of num_key_value_heads")
+    if cfg.get("qk_norm", "projection") not in ("projection", "head"):
         raise NotImplementedError(
-            "decoder_lm: grouped-query attention (num_key_value_heads != "
-            "num_attention_heads) is not built yet")
-    if cfg["hidden_size"] % cfg["num_attention_heads"]:
-        raise ValueError("hidden_size must be a multiple of the head count")
+            f"decoder_lm: qk_norm={cfg['qk_norm']!r} is not built yet")
+    if cfg.get("n_shared_experts") or cfg.get("num_shared_experts"):
+        raise NotImplementedError(
+            "decoder_lm: shared experts are not built yet")
+    sigmoid = cfg.get("router_scoring", "softmax") == "sigmoid"
+    if not sigmoid and (cfg.get("norm_topk_prob") or cfg.get(
+            "use_expert_bias") or cfg.get("routed_scaling_factor", 1) != 1):
+        raise NotImplementedError(
+            "decoder_lm: norm_topk_prob, use_expert_bias and "
+            "routed_scaling_factor are built for router_scoring='sigmoid' "
+            "only")
+    if sigmoid and ("router_aux_loss_coef" in cfg
+                    or "router_z_loss_coef" in cfg):
+        raise NotImplementedError(
+            "decoder_lm: the router losses are built for softmax scoring "
+            "only")
+
+
+def _layer_types(cfg: dict) -> list:
+    return list(cfg.get("layer_types")
+                or ["full_attention"] * cfg["num_hidden_layers"])
+
+
+def _kv_heads(cfg: dict) -> int:
+    return cfg.get("num_key_value_heads") or cfg["num_attention_heads"]
+
+
+def _eps(cfg: dict) -> float:
+    return cfg["rms_norm_eps"] if "rms_norm_eps" in cfg else cfg["norm_eps"]
 
 
 def _attr(name: str) -> ParamAttr:
@@ -64,41 +129,94 @@ def _linear(x, size: int, name: str):
 
 
 def attention(x, cfg: dict, batch: int, seq: int, name: str):
-    """Causal multi-head self-attention over tokens ``x [batch * seq, H]``."""
-    H, heads = cfg["hidden_size"], cfg["num_attention_heads"]
+    """Causal self-attention over tokens ``x [batch * seq, H]``, with
+    ``num_key_value_heads`` key/value heads."""
+    H, heads, kv_heads = (cfg["hidden_size"], cfg["num_attention_heads"],
+                          _kv_heads(cfg))
     d = H // heads
-    eps = cfg["rms_norm_eps"]
+    eps = _eps(cfg)
+    by_head = cfg.get("qk_norm", "projection") == "head"
 
-    def heads_of(t):                                # [B*S, H] -> [B, h, S, d]
-        t = layers.reshape(t, [batch, seq, heads, d])
+    def heads_of(t, n, norm_w=None):                # [B*S, n*d] -> [B, n, S, d]
+        t = layers.reshape(t, [batch, seq, n, d])
+        if norm_w:
+            t = layers.rms_norm(t, eps, ParamAttr(name=norm_w))
         return layers.transpose(t, [0, 2, 1, 3])
 
-    q = layers.rms_norm(_linear(x, H, name + "_q_w"), eps,
-                        ParamAttr(name=name + "_q_norm_w"))
-    k = layers.rms_norm(_linear(x, H, name + "_k_w"), eps,
-                        ParamAttr(name=name + "_k_norm_w"))
-    v = _linear(x, H, name + "_v_w")
-    q = layers.rotary_embedding(heads_of(q), cfg["rope_theta"])
-    k = layers.rotary_embedding(heads_of(k), cfg["rope_theta"])
-    ctx = layers.fused_attention(q, k, heads_of(v), causal=True,
+    q = _linear(x, H, name + "_q_w")
+    if not by_head:
+        q = layers.rms_norm(q, eps, ParamAttr(name=name + "_q_norm_w"))
+    k = _linear(x, kv_heads * d, name + "_k_w")
+    if not by_head:
+        k = layers.rms_norm(k, eps, ParamAttr(name=name + "_k_norm_w"))
+    v = _linear(x, kv_heads * d, name + "_v_w")
+    q = layers.rotary_embedding(
+        heads_of(q, heads, by_head and name + "_q_norm_w"), cfg["rope_theta"])
+    k = layers.rotary_embedding(
+        heads_of(k, kv_heads, by_head and name + "_k_norm_w"),
+        cfg["rope_theta"])
+    ctx = layers.fused_attention(q, k, heads_of(v, kv_heads), causal=True,
                                  scale=1.0 / math.sqrt(d), impl="auto")
     ctx = layers.reshape(layers.transpose(ctx, [0, 2, 1, 3]),
                          [batch * seq, H])
     return _linear(ctx, H, name + "_o_w")
 
 
-def block(x, cfg: dict, batch: int, seq: int, name: str):
-    """One decoder layer over ``x [batch * seq, H]``; returns the layer's
-    output and the router's variables (``layers.moe_ffn``)."""
-    eps = cfg["rms_norm_eps"]
-    normed = layers.rms_norm(x, eps, ParamAttr(name=name + "_attn_norm_w"))
-    h = layers.elementwise_add(
-        x, attention(normed, cfg, batch, seq, name + "_attn"))
+def short_conv(x, cfg: dict, seq: int, name: str):
+    """The gated short-convolution operator over ``x [batch * seq, H]``."""
+    H = cfg["hidden_size"]
+    mixed = layers.short_conv(_linear(x, 3 * H, name + "_in_w"), seq,
+                              cfg["conv_L_cache"], _attr(name + "_w"))
+    return _linear(mixed, H, name + "_out_w")
+
+
+def experts(x, cfg: dict, name: str):
+    """The layer's routed experts (``layers.moe_ffn``) from the config."""
+    held = cfg["num_experts"]
+    routed = cfg.get("num_experts_routed", held)
+    return layers.moe_ffn(
+        x, routed, cfg["num_experts_per_tok"],
+        cfg.get("moe_intermediate_size", cfg["intermediate_size"]),
+        param_attr=_attr(None), name=name,
+        experts_held=(None if held == routed
+                      else (cfg.get("first_expert_held", 0), held)),
+        scoring=cfg.get("router_scoring", "softmax"),
+        norm_topk=bool(cfg.get("norm_topk_prob", False)),
+        routed_scale=float(cfg.get("routed_scaling_factor", 1.0)),
+        expert_bias=bool(cfg.get("use_expert_bias", False)))
+
+
+def block(x, cfg: dict, batch: int, seq: int, name: str,
+          kind: str = "full_attention", dense: bool = False):
+    """One decoder layer over ``x [batch * seq, H]`` with the operator
+    ``kind``; returns the layer's output and the router's variables
+    (``layers.moe_ffn``; None for a ``dense`` feed-forward layer)."""
+    eps = _eps(cfg)
+    op_name = name + ("_conv" if kind == "conv" else "_attn")
+    normed = layers.rms_norm(x, eps, ParamAttr(name=op_name + "_norm_w"))
+    if kind == "conv":
+        mixed = short_conv(normed, cfg, seq, op_name)
+    else:
+        mixed = attention(normed, cfg, batch, seq, op_name)
+    h = layers.elementwise_add(x, mixed)
     normed = layers.rms_norm(h, eps, ParamAttr(name=name + "_ffn_norm_w"))
-    moe, aux = layers.moe_ffn(
-        normed, cfg["num_experts"], cfg["num_experts_per_tok"],
-        cfg["intermediate_size"], param_attr=_attr(None), name=name + "_moe")
+    if dense:
+        width = cfg["intermediate_size"]
+        gated = layers.swiglu(_linear(normed, width, name + "_ffn_gate_w"),
+                              _linear(normed, width, name + "_ffn_up_w"))
+        return layers.elementwise_add(
+            h, _linear(gated, cfg["hidden_size"], name + "_ffn_down_w")), None
+    moe, aux = experts(normed, cfg, name + "_moe")
     return layers.elementwise_add(h, moe), aux
+
+
+def balance_experts(out: dict, rate: float) -> None:
+    """Append the update of every expert layer's selection bias from the
+    step's load (``layers.moe_bias_update``; ``out`` is ``build``'s result).
+    Call it after ``minimize``: the bias must hold still until the backward
+    has run, and a clone taken before has no update in it."""
+    for bias, load in zip(out["expert_bias"], out["expert_load"]):
+        layers.moe_bias_update(bias, load, rate)
 
 
 def _mean_of(values):
@@ -113,38 +231,53 @@ def build(cfg: dict, ids, labels) -> dict:
     Returns the variables a caller trains on or fetches: ``loss`` (the
     total), ``ce`` (mean cross-entropy), ``each`` (every position's
     cross-entropy ``[batch * seq, 1]``), ``load_balancing`` and ``z_loss``
-    (the two router losses before their coefficients), and per layer
-    ``expert_load`` (``[experts]`` int32: assignments an expert received)
-    and ``expert_index`` (``[batch * seq, k]``: the experts chosen)."""
+    (the two router losses before their coefficients; only where the config
+    has them), and per expert layer ``expert_load`` (``[experts routed]``
+    int32: assignments an expert received), ``expert_index`` (``[batch *
+    seq, k]``: the experts chosen) and ``expert_bias`` (the selection bias,
+    under ``use_expert_bias``)."""
     _check(cfg)
     batch, seq = int(ids.shape[0]), int(ids.shape[1])
-    H, E = cfg["hidden_size"], cfg["num_experts"]
+    H = cfg["hidden_size"]
+    E = cfg.get("num_experts_routed", cfg.get("num_experts"))
+    router_losses = "router_aux_loss_coef" in cfg
     dtype = cfg.get("dtype", "float32")
     x = layers.embedding(ids, [cfg["vocab_size"], H], dtype="float32",
                          param_attr=_attr("tok_emb"))
     if dtype != "float32":
         x = layers.cast(x, dtype)
     x = layers.reshape(x, [batch * seq, H])
-    balance, z, loads, indices = [], [], [], []
-    for i in range(cfg["num_hidden_layers"]):
-        x, aux = block(x, cfg, batch, seq, f"layer{i}")
-        share = layers.scale(layers.cast(aux["load"], "float32"),
-                             1.0 / (batch * seq * cfg["num_experts_per_tok"]))
-        balance.append(layers.scale(layers.reduce_sum(layers.elementwise_mul(
-            share, layers.reduce_mean(aux["prob"], dim=0))), float(E)))
-        z.append(layers.mean(layers.square(aux["logz"])))
+    balance, z, loads, indices, biases = [], [], [], [], []
+    for i, kind in enumerate(_layer_types(cfg)):
+        x, aux = block(x, cfg, batch, seq, f"layer{i}", kind,
+                       dense=i < cfg.get("num_dense_layers", 0))
+        if aux is None:
+            continue
+        if router_losses:
+            share = layers.scale(
+                layers.cast(aux["load"], "float32"),
+                1.0 / (batch * seq * cfg["num_experts_per_tok"]))
+            balance.append(layers.scale(layers.reduce_sum(
+                layers.elementwise_mul(
+                    share, layers.reduce_mean(aux["prob"], dim=0))),
+                float(E)))
+            z.append(layers.mean(layers.square(aux["logz"])))
         loads.append(aux["load"])
         indices.append(aux["index"])
-    x = layers.rms_norm(x, cfg["rms_norm_eps"],
-                        ParamAttr(name="final_norm_w"))
+        if "bias" in aux:
+            biases.append(aux["bias"])
+    x = layers.rms_norm(x, _eps(cfg), ParamAttr(name="final_norm_w"))
     logits = _linear(x, cfg["vocab_size"], "lm_head_w")
     if dtype != "float32":
         logits = layers.cast(logits, "float32")
     each = layers.softmax_with_cross_entropy(logits, labels)
     ce = layers.mean(each)
-    balance, z = _mean_of(balance), _mean_of(z)
-    loss = layers.sums([
-        ce, layers.scale(balance, float(cfg["router_aux_loss_coef"])),
-        layers.scale(z, float(cfg["router_z_loss_coef"]))])
-    return {"loss": loss, "ce": ce, "each": each, "load_balancing": balance,
-            "z_loss": z, "expert_load": loads, "expert_index": indices}
+    out = {"loss": ce, "ce": ce, "each": each, "expert_load": loads,
+           "expert_index": indices, "expert_bias": biases}
+    if router_losses:
+        balance, z = _mean_of(balance), _mean_of(z)
+        out["loss"] = layers.sums([
+            ce, layers.scale(balance, float(cfg["router_aux_loss_coef"])),
+            layers.scale(z, float(cfg["router_z_loss_coef"]))])
+        out.update(load_balancing=balance, z_loss=z)
+    return out

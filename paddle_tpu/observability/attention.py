@@ -18,14 +18,17 @@ from .metrics import REGISTRY, MetricsRegistry
 
 def count_lowerings(notes: dict, program: str,
                     registry: Optional[MetricsRegistry] = None) -> None:
-    """``attention_lowering_total{program,impl,s,block_q}``: the
+    """``attention_lowering_total{program,impl,s,block_q,kv_heads}``: the
     ``fused_attention`` ops the trace just compiled, by lowering (``pallas``,
-    ``xla``, ``ring``, ``ulysses``), sequence length and the kernels' Q
-    block (0 where no kernel ran). ``notes`` maps each op's salt to its
-    ``(impl, s, block_q)``; nothing is added for a program without the op."""
+    ``xla``, ``ring``, ``ulysses``), sequence length, the kernels' Q block
+    (0 where no kernel ran) and key/value heads (fewer than the query's
+    under grouped-query attention). ``notes`` maps each op's salt to its
+    ``(impl, s, block_q, kv_heads)``; nothing is added for a program without
+    the op."""
     registry = registry or REGISTRY
-    for (impl, s, block_q), n in Counter(notes.values()).items():
+    for (impl, s, block_q, kv_heads), n in Counter(notes.values()).items():
         registry.counter(
             "attention_lowering_total",
             "fused_attention ops compiled, by the lowering each took",
-            program=program, impl=impl, s=str(s), block_q=str(block_q)).inc(n)
+            program=program, impl=impl, s=str(s), block_q=str(block_q),
+            kv_heads=str(kv_heads)).inc(n)

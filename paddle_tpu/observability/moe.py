@@ -16,14 +16,19 @@ from .metrics import REGISTRY, MetricsRegistry
 
 def update_moe_gauges(program_ir, program: str,
                       registry: Optional[MetricsRegistry] = None) -> None:
-    """``moe_layers``, ``moe_experts``, ``moe_assignments_per_step`` (tokens
-    x top-k, all layers) and ``moe_expert_param_bytes`` (the stacked expert
-    weights) of one compiled program; nothing is set for a program without
-    an expert layer."""
+    """``moe_layers``, ``moe_experts`` (the experts a layer's router scores),
+    ``moe_experts_held`` (the experts whose weights a layer holds here:
+    fewer under ``layers.moe_ffn``'s ``experts_held``),
+    ``moe_assignments_per_step`` (tokens x top-k, all layers; a layer's
+    sorted row buffer has a row for each of its assignments),
+    ``moe_expert_param_bytes`` (the stacked expert weights) and
+    ``short_conv_layers`` of one compiled program; nothing is set for a
+    program without an expert layer, and the last only where there is such
+    a layer."""
     from ..analysis.distributed import dtype_bytes
     registry = registry or REGISTRY
     block = program_ir.global_block()
-    layers = experts = assignments = param_bytes = 0
+    layers = experts = held = assignments = param_bytes = convs = 0
     for op in block.ops:
         if op.type == "moe_dispatch":
             layers += 1
@@ -32,12 +37,21 @@ def update_moe_gauges(program_ir, program: str,
             assignments += int(np.prod(index.shape))
         elif op.type == "moe_expert_matmul":
             w = block.find_var_recursive(op.inputs["W"][0])
+            held = int(w.shape[0])
             param_bytes += int(np.prod(w.shape)) * dtype_bytes(w.dtype)
+        elif op.type == "short_conv":
+            convs += 1
+    if convs:
+        registry.gauge("short_conv_layers", "gated short-convolution "
+                       "operators in the compiled program",
+                       program=program).set(float(convs))
     if not layers:
         return
     for name, help, value in (
             ("moe_layers", "expert layers in the compiled program", layers),
-            ("moe_experts", "routed experts a layer", experts),
+            ("moe_experts", "experts a layer's router scores", experts),
+            ("moe_experts_held", "experts whose weights a layer holds in "
+             "this program", held),
             ("moe_assignments_per_step",
              "tokens x top-k assignments routed a step, all layers (a count "
              "from static shapes)", assignments),

@@ -1,7 +1,8 @@
-"""Decoder-LM ops: RMSNorm, rotary embedding, the SiLU-gated product, and a
-dropless mixture-of-experts layer in four ops (``moe_router`` ->
-``moe_dispatch`` -> ``moe_expert_matmul`` x3 around ``swiglu`` ->
-``moe_combine``).
+"""Decoder-LM ops: RMSNorm, rotary embedding, the SiLU-gated product, the
+gated short convolution, and a dropless mixture-of-experts layer in four ops
+(``moe_router`` -> ``moe_dispatch`` -> ``moe_expert_matmul`` x3 around
+``swiglu`` -> ``moe_combine``) plus ``moe_bias_update`` for a router that
+balances by a selection bias.
 
 The expert layer never drops an assignment and has no capacity: the
 tokens x top-k assignments are sorted by expert (one stable sort in
@@ -13,7 +14,11 @@ the combine is then a plain sum, its gradient needs no expert output, and
 the ``[assignments, hidden]`` output of the last matmul is never kept for
 the backward. The sorted buffer holds expert 0's rows first, then expert
 1's, ..., with ``Count`` giving each expert's rows: an all-to-all over an
-``ep`` axis can split it by expert range without sorting again.
+``ep`` axis can split it by expert range without sorting again. A layer that
+holds a part of its experts (``moe_dispatch``'s ``first_expert``, stacked
+weights for the held experts only) starts the sort at its first expert, so
+its rows lead the buffer, and the grouped products stop after them: the
+rows of experts held elsewhere stay zero through the layer and add nothing.
 
 What moves rows is written so that forward and backward are both gathers
 (``_movers``: a permutation's transpose is the inverse permutation, which
@@ -92,24 +97,47 @@ def swiglu(ctx, ins):
     return {"Out": [out.astype(g.dtype)]}
 
 
-@register("moe_router", nondiff_outputs=("Index",))
+@register("moe_router", nondiff_inputs=("Bias",), nondiff_outputs=("Index",))
 def moe_router(ctx, ins):
     """Router of a mixture-of-experts layer, in float32 throughout (the
     product too: at default precision a TPU multiplies float32 in bfloat16
     passes). ``X [T, H]`` any float dtype, ``W [H, E]`` ->
     ``Prob [T, E]`` = softmax(X W), ``Weight`` / ``Index [T, k]`` its k
     largest entries as they are (not renormalised), ``LogZ [T]`` =
-    logsumexp(X W) for the router z-loss. ``Index`` carries no gradient."""
+    logsumexp(X W) for the router z-loss. ``Index`` carries no gradient.
+    Attr ``scoring="sigmoid"``: ``_sigmoid_routing``."""
     import jax
     import jax.numpy as jnp
     x, w = ins["X"][0], ins["W"][0]
     logits = jnp.dot(x.astype(jnp.float32), w.astype(jnp.float32),
                      precision=jax.lax.Precision.HIGHEST)
+    if ctx.attr("scoring", "softmax") == "sigmoid":
+        return _sigmoid_routing(ctx, logits, ins.get("Bias", [None])[0])
     logz = jax.nn.logsumexp(logits, axis=-1)
     prob = jnp.exp(logits - logz[:, None])
     weight, index = jax.lax.top_k(prob, int(ctx.attr("k")))
     return {"Weight": [weight], "Index": [index.astype(jnp.int32)],
             "Prob": [prob], "LogZ": [logz]}
+
+
+def _sigmoid_routing(ctx, logits, bias):
+    """``scoring="sigmoid"``: each expert's score is sigmoid(logit) by
+    itself; the k experts are chosen by score + ``Bias [E]`` (a balancing
+    state, no gradient through it or through the choice) and weighed by the
+    score without the bias, divided by the chosen scores' sum + 1e-6 under
+    ``norm_topk``, times ``scale``. ``Prob`` is the scores; no ``LogZ``."""
+    import jax
+    import jax.numpy as jnp
+    score = jax.nn.sigmoid(logits)
+    chosen_by = score if bias is None else score + bias.astype(jnp.float32)
+    _, index = jax.lax.top_k(jax.lax.stop_gradient(chosen_by),
+                             int(ctx.attr("k")))
+    weight = jnp.take_along_axis(score, index, axis=-1)
+    if ctx.attr("norm_topk", False):
+        weight = weight / (jnp.sum(weight, axis=-1, keepdims=True) + 1e-6)
+    weight = weight * float(ctx.attr("scale", 1.0))
+    return {"Weight": [weight], "Index": [index.astype(jnp.int32)],
+            "Prob": [score]}
 
 
 def _moved(fwd_rows, bwd_rows):
@@ -159,7 +187,7 @@ def _movers():
 
 
 @register("moe_dispatch", nondiff_inputs=("Index",),
-          nondiff_outputs=("Order", "Slot", "Count"))
+          nondiff_outputs=("Order", "Slot", "Count", "GroupCount"))
 def moe_dispatch(ctx, ins):
     """Sort the T x k assignments by expert (stable: within an expert, by
     token) and bring each one's token row and router weight into place.
@@ -167,11 +195,23 @@ def moe_dispatch(ctx, ins):
     0's rows first) and ``RowWeight [T*k]``, ``Order [T*k]`` the flat
     assignment (token * k + choice) of each sorted row, ``Slot [T, k]`` the
     sorted row of each assignment, ``Count [E]`` the rows of each expert.
-    Every assignment has a row: nothing is dropped whatever the routing."""
+    Every assignment has a row: nothing is dropped whatever the routing.
+
+    Attr ``first_expert`` (default 0) is the first expert this layer holds
+    of the ``num_experts`` routed over (``layers.moe_ffn``'s
+    ``experts_held``): the sort then starts at that expert and wraps
+    around, so the held experts' rows are the first of the buffer whatever
+    the range, and ``GroupCount [E]`` counts the rows in the sorted order
+    (expert ``first_expert`` first) while ``Count`` stays by expert id. The
+    buffers keep all T*k rows -- the worst case, every assignment local --
+    and the grouped products stop after the held experts' rows."""
     import jax.numpy as jnp
     x, index, weight = ins["X"][0], ins["Index"][0], ins["Weight"][0]
     n_experts = int(ctx.attr("num_experts"))
+    first = int(ctx.attr("first_expert", 0))
     flat = index.reshape(-1).astype(jnp.int32)
+    if first:
+        flat = (flat - first) % n_experts
     order = jnp.argsort(flat, stable=True).astype(jnp.int32)
     rows = jnp.arange(flat.shape[0], dtype=jnp.int32)
     slot = jnp.zeros_like(rows).at[order].set(
@@ -181,13 +221,19 @@ def moe_dispatch(ctx, ins):
     to_rows, _, to_row_weights = _movers()
     return {"Out": [to_rows(x, order, slot)],
             "RowWeight": [to_row_weights(weight, order, slot)],
-            "Order": [order], "Slot": [slot], "Count": [count]}
+            "Order": [order], "Slot": [slot], "GroupCount": [count],
+            "Count": [jnp.roll(count, first) if first else count]}
 
 
 def grouped_matmul(x, w, count):
-    """``x [A, K]`` rows sorted by group, ``w [G, K, N]``, ``count [G]`` rows
-    a group (summing to A) -> ``[A, N]`` in x's dtype: row a times the
-    weight of its group, accumulated in float32."""
+    """``x [A, K]`` rows sorted by group, ``w [G, K, N]``, ``count`` rows a
+    group (summing to A) -> ``[A, N]`` in x's dtype: row a times the
+    weight of its group, accumulated in float32. With weights for the first
+    G of ``count``'s groups only (an expert layer that holds a part of its
+    experts), the later groups' rows are not computed and come out zero, in
+    the product and in both gradients: megablox visits the groups it has
+    weights for and zeroes the rest, ``ragged_dot`` leaves rows beyond its
+    group sizes zero."""
     import jax
     from . import pallas_mode
     if pallas_mode.on_tpu():
@@ -195,13 +241,17 @@ def grouped_matmul(x, w, count):
         tiling = tuple(min(t, d) for t, d in
                        zip(GMM_TILING, (x.shape[0], x.shape[1], w.shape[2])))
         return megablox.gmm(x, w, count, x.dtype, tiling)
+    if w.shape[0] < count.shape[0]:
+        count = count[:w.shape[0]]
     return jax.lax.ragged_dot(x, w, count)
 
 
 @register("moe_expert_matmul", nondiff_inputs=("Count",))
 def moe_expert_matmul(ctx, ins):
     """One of an expert layer's products over the sorted rows: ``X [A, K]``,
-    stacked ``W [E, K, N]``, ``Count [E]`` -> ``Out [A, N]``."""
+    stacked ``W [E, K, N]``, ``Count [E]`` -> ``Out [A, N]``; with ``W``
+    stacking the first G < E groups only, the rows after theirs are zero
+    (``grouped_matmul``)."""
     return {"Out": [grouped_matmul(ins["X"][0], ins["W"][0],
                                    ins["Count"][0])]}
 
@@ -215,3 +265,63 @@ def moe_combine(ctx, ins):
     _, to_tokens, _ = _movers()
     return {"Out": [to_tokens(ins["X"][0], ins["Order"][0],
                               ins["Slot"][0])]}
+
+
+@register("moe_bias_update", grad=None)
+def moe_bias_update(ctx, ins):
+    """Auxiliary-loss-free load balancing (Wang et al., arXiv:2408.15664):
+    ``BiasOut = Bias + rate * sign(mean(Load) - Load)`` over ``Load [E]``,
+    this step's assignments by expert. The router's selection bias is a
+    state variable of the training program that no optimizer owns; the op
+    runs after the backward (``models/decoder_lm.py:balance_experts``), so
+    every op of a step reads the bias the step began with."""
+    import jax.numpy as jnp
+    bias, load = ins["Bias"][0], ins["Load"][0].astype(jnp.float32)
+    step = float(ctx.attr("rate")) * jnp.sign(jnp.mean(load) - load)
+    return {"BiasOut": [bias + step.astype(bias.dtype)]}
+
+
+@register("short_conv")
+def short_conv(ctx, ins):
+    """The gated short convolution between a hybrid decoder layer's two
+    projections: ``X [T, 3C]`` holds ``B | C | u`` side by side (the input
+    projection's output), ``W [C, L]`` one causal filter of length L a
+    channel; ``Out [T, C] = C * conv(B * u)`` with ``conv(z)[t] = sum_j
+    W[:, j] * z[t - (L-1) + j]``. The T rows are sequences of ``seq`` (attr)
+    consecutive positions: positions before a sequence's start count as
+    zero, nothing crosses from one sequence into the next. float32 inside.
+
+    Attr ``impl``: ``auto`` (default) lowers the Pallas kernels of
+    ``ops/pallas_short_conv.py`` where they can run (a TPU, or the test
+    harness' interpreter) and take the shapes, else the composed form below;
+    ``pallas`` / ``composed`` force one. The kernels are 8 to 13 times
+    faster than XLA's fusion of the composed form on a v5e (timed at
+    ``[16384, 3 x 2048]``: PERF.md section 6, PR 32)."""
+    import jax.numpy as jnp
+    from . import pallas_mode, pallas_short_conv
+    x, w = ins["X"][0], ins["W"][0]
+    seq, (rows, wide) = int(ctx.attr("seq")), x.shape
+    chan, taps = wide // 3, w.shape[1]
+    impl = ctx.attr("impl", "auto")
+    fits = pallas_short_conv.supports(seq, chan, taps)
+    if impl == "pallas":
+        pallas_mode.require("short_conv impl='pallas'")
+        if not fits:
+            raise ValueError(
+                f"short_conv impl='pallas' needs channels % "
+                f"{pallas_short_conv.BLK_C} == 0, seq % 16 == 0 and at most "
+                f"{pallas_short_conv.MAX_SEQ}; got seq={seq}, "
+                f"channels={chan}, taps={taps}")
+    if not ctx.abstract and (impl == "pallas" or (
+            impl == "auto" and fits and pallas_mode.available())):
+        return {"Out": [pallas_short_conv.short_conv(
+            x, w, seq, pallas_mode.interpret())]}
+    xf = x.astype(jnp.float32)
+    z = (xf[:, :chan] * xf[:, 2 * chan:]).reshape(rows // seq, seq, chan)
+    wf = w.astype(jnp.float32)
+    conv = z * wf[:, taps - 1]
+    for back in range(1, taps):             # z[t - back], zeros before t=0
+        past = jnp.pad(z, ((0, 0), (back, 0), (0, 0)))[:, :seq]
+        conv = conv + past * wf[:, taps - 1 - back]
+    return {"Out": [(xf[:, chan:2 * chan] * conv.reshape(rows, chan))
+                    .astype(x.dtype)]}

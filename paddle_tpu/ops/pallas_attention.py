@@ -122,8 +122,18 @@ def composed_attention(q, k, v, bias, scale, dropout, causal, rng,
     import jax
     import jax.numpy as jnp
 
-    s = jnp.einsum("bhqd,bhkd->bhqk", q, k,
-                   preferred_element_type=jnp.float32) * scale
+    out_shape, grouped = q.shape, k.shape[1] != q.shape[1]
+    scores, values = "bhqd,bhkd->bhqk", "bhqk,bhkd->bhqd"
+    if grouped:
+        # grouped-query attention: query head i reads key/value head
+        # i // group. The group is an axis of the products, so K and V are
+        # never repeated.
+        B, H, S, D = q.shape
+        q = q.reshape(B, k.shape[1], H // k.shape[1], S, D)
+        scores, values = "bhgqd,bhkd->bhgqk", "bhgqk,bhkd->bhgqd"
+        if bias is not None:
+            bias = bias[:, :, None]
+    s = jnp.einsum(scores, q, k, preferred_element_type=jnp.float32) * scale
     if bias is not None:
         s = s + bias.astype(jnp.float32)
     if causal:
@@ -135,8 +145,9 @@ def composed_attention(q, k, v, bias, scale, dropout, causal, rng,
     if dropout:
         keep = (bernoulli or jax.random.bernoulli)(rng, 1.0 - dropout, p.shape)
         p = jnp.where(keep, p / (1.0 - dropout), 0.0)
-    return jnp.einsum("bhqk,bhkd->bhqd", p.astype(v.dtype), v,
-                      preferred_element_type=jnp.float32).astype(q.dtype)
+    out = jnp.einsum(values, p.astype(v.dtype), v,
+                     preferred_element_type=jnp.float32).astype(q.dtype)
+    return out.reshape(out_shape) if grouped else out
 
 
 # --------------------------------------------------------------------------------------
@@ -184,17 +195,19 @@ def _scores(q_blk, k_all, bias_row, iq, scale, causal):
     return s, q_blk
 
 
-def _keep_mask(shape, seed_ref, iq, dropout):
+def _keep_mask(shape, seed_ref, iq, dropout, bh=None):
     """Bernoulli(1 - dropout) keep mask of one Q block, drawn by blocks of
     _MIN_BLK_Q rows, each seeded by (step seed, batch*head, its index in the
     sequence): the backward reseeds the same, and the mask does not depend on
-    block_q."""
+    block_q. ``bh`` is the query's batch*head index where grid axis 0 is not
+    it (the grouped backward)."""
     import jax.numpy as jnp
     pl, pltpu = _pl()
     n = shape[0] // _MIN_BLK_Q
     bits = []
     for j in range(n):
-        pltpu.prng_seed(seed_ref[0] + pl.program_id(0) * 1000003
+        pltpu.prng_seed(seed_ref[0]
+                        + (pl.program_id(0) if bh is None else bh) * 1000003
                         + (iq * n + j) * 7919)
         bits.append(pltpu.prng_random_bits((_MIN_BLK_Q, shape[1])))
     bits = pltpu.bitcast(jnp.concatenate(bits, axis=0), jnp.uint32)
@@ -223,7 +236,12 @@ def _fwd_kernel(scale, dropout, causal, has_bias, *refs):
     o_ref[0] = (o * (1.0 / (l * (1.0 - dropout)))).astype(o_ref.dtype)
 
 
-def _bwd_kernel(scale, dropout, causal, has_bias, *refs):
+def _bwd_kernel(scale, dropout, causal, has_bias, group, *refs):
+    """``group`` query heads share a key/value head. At 1 grid axis 1 is the
+    Q block; above 1 it runs over the group's heads and their Q blocks in
+    turn, so that dK^T / dV^T accumulate over the whole group in VMEM and a
+    key/value head's gradient is written once (no per-query-head dK, dV in
+    HBM to sum afterwards)."""
     import jax.numpy as jnp
     pl, _ = _pl()
     if has_bias:
@@ -234,7 +252,12 @@ def _bwd_kernel(scale, dropout, causal, has_bias, *refs):
         (q_ref, k_ref, v_ref, seed_ref, do_ref,
          dq_ref, dk_ref, dv_ref, dkt_acc, dvt_acc) = refs
         bias_row = None
-    iq = pl.program_id(1)
+    step = iq = pl.program_id(1)
+    bh = None
+    if group > 1:
+        n_q = pl.num_programs(1) // group
+        iq = step % n_q
+        bh = pl.program_id(0) * group + step // n_q
     dtype = q_ref.dtype
     s, q_s = _scores(q_ref[0], k_ref[0], bias_row, iq, scale, causal)
     e = jnp.exp(s - jnp.max(s, axis=-1, keepdims=True))
@@ -246,7 +269,7 @@ def _bwd_kernel(scale, dropout, causal, has_bias, *refs):
     # scales the [*, D] results (pk, dp, ds are the true values over c).
     c = 1.0 / (1.0 - dropout)
     if dropout:
-        keep = _keep_mask(p.shape, seed_ref, iq, dropout)
+        keep = _keep_mask(p.shape, seed_ref, iq, dropout, bh)
         pk = jnp.where(keep, p, 0.0)
         dp = jnp.where(keep, dpd, 0.0)
     else:
@@ -255,7 +278,7 @@ def _bwd_kernel(scale, dropout, causal, has_bias, *refs):
     ds = (p * (dp - row)).astype(dtype)
     dq_ref[0] = (_dot(ds, k_ref[0], _NN) * (c * scale)).astype(dq_ref.dtype)
 
-    @pl.when(iq == 0)
+    @pl.when(step == 0)
     def _():
         dkt_acc[...] = jnp.zeros_like(dkt_acc)
         dvt_acc[...] = jnp.zeros_like(dvt_acc)
@@ -265,34 +288,51 @@ def _bwd_kernel(scale, dropout, causal, has_bias, *refs):
     dkt_acc[...] += _dot(q_s, ds, _TN)
     dvt_acc[...] += _dot(do, pk.astype(dtype), _TN)
 
-    @pl.when(iq == pl.num_programs(1) - 1)
+    @pl.when(step == pl.num_programs(1) - 1)
     def _():
         k_scale = c if _scale_is_exact(scale) else c * scale  # q_s has it
         dk_ref[0] = (dkt_acc[...] * k_scale).T.astype(dk_ref.dtype)
         dv_ref[0] = (dvt_acc[...] * c).T.astype(dv_ref.dtype)
 
 
-def _operands(q, k, v, bias, seed, block_q):
+def _operands(q, k, v, bias, seed, block_q, by_kv_head=False):
     """The kernels' common operands and block specs, and the number of Q
-    blocks (block_q divides S: _flash has seen to it)."""
+    blocks (block_q divides S: _flash has seen to it). Grid axis 0 is the
+    query's batch*head and axis 1 the Q block; with fewer key/value heads
+    than query heads (k, v ``[B, Hkv, S, D]``) a query head's program reads
+    its key/value head's rows in place. ``by_kv_head`` (the grouped
+    backward): axis 0 is the key/value's batch*head, axis 1 the group's
+    heads x Q blocks."""
     import jax.numpy as jnp
     pl, pltpu = _pl()
     B, H, S, D = q.shape
-    args = [x.reshape(B * H, S, D) for x in (q, k, v)]
-    qspec = pl.BlockSpec((1, block_q, D), lambda b, i: (b, i, 0),
-                         memory_space=pltpu.VMEM)
-    kvspec = pl.BlockSpec((1, S, D), lambda b, i: (b, 0, 0),
-                          memory_space=pltpu.VMEM)
+    kv = k.shape[1]
+    group, n_q = H // kv, S // block_q
+    args = [q.reshape(B * H, S, D), k.reshape(B * kv, S, D),
+            v.reshape(B * kv, S, D)]
+    if group == 1:
+        q_at, kv_at, per_batch = (lambda b, i: (b, i, 0),
+                                  lambda b, i: (b, 0, 0), H)
+    elif by_kv_head:
+        q_at, kv_at, per_batch = (
+            lambda b, i: (b * group + i // n_q, i % n_q, 0),
+            lambda b, i: (b, 0, 0), kv)
+    else:
+        q_at, kv_at, per_batch = (lambda b, i: (b, i, 0),
+                                  lambda b, i: (b // group, 0, 0), H)
+    qspec = pl.BlockSpec((1, block_q, D), q_at, memory_space=pltpu.VMEM)
+    kvspec = pl.BlockSpec((1, S, D), kv_at, memory_space=pltpu.VMEM)
     in_specs = [qspec, kvspec, kvspec]
     if bias is not None:
         # [B,1,S] with block (1,1,S): the last two dims equal the array dims,
         # satisfying the TPU (8,128)-divisible-or-full block constraint.
         args.append(bias.reshape(B, 1, S))
-        in_specs.append(pl.BlockSpec((1, 1, S), lambda b, i: (b // H, 0, 0),
-                                     memory_space=pltpu.VMEM))
+        in_specs.append(pl.BlockSpec(
+            (1, 1, S), lambda b, i: (b // per_batch, 0, 0),
+            memory_space=pltpu.VMEM))
     args.append(jnp.asarray(seed, jnp.int32).reshape(1))
     in_specs.append(pl.BlockSpec(memory_space=pltpu.SMEM))
-    return args, in_specs, qspec, kvspec, S // block_q
+    return args, in_specs, qspec, kvspec, n_q
 
 
 def _compiler_params(interpret, vmem_limit_bytes=None):
@@ -380,25 +420,27 @@ def _bwd_call(q, k, v, bias, seed, g, scale, dropout, causal, interpret,
     import jax.numpy as jnp
     pl, pltpu = _pl()
     B, H, S, D = q.shape
+    kv = k.shape[1]
+    group = H // kv
     args, in_specs, qspec, kvspec, n_q = _operands(q, k, v, bias, seed,
-                                                   block_q)
+                                                   block_q, by_kv_head=True)
     args.append(g.reshape(B * H, S, D))
     in_specs.append(qspec)
     dq, dk, dv = pl.pallas_call(
         functools.partial(_bwd_kernel, scale, dropout, causal,
-                          bias is not None),
-        grid=(B * H, n_q),
+                          bias is not None, group),
+        grid=(B * kv, group * n_q),
         in_specs=in_specs,
         out_specs=[qspec, kvspec, kvspec],
-        out_shape=[jax.ShapeDtypeStruct((B * H, S, D), x.dtype)
+        out_shape=[jax.ShapeDtypeStruct((B * x.shape[1], S, D), x.dtype)
                    for x in (q, k, v)],
         scratch_shapes=[pltpu.VMEM((D, S), jnp.float32),
                         pltpu.VMEM((D, S), jnp.float32)],
         interpret=interpret,
         **_compiler_params(interpret, BWD_VMEM_LIMIT_BYTES),
     )(*args)
-    shape = (B, H, S, D)
-    return dq.reshape(shape), dk.reshape(shape), dv.reshape(shape)
+    return (dq.reshape(B, H, S, D), dk.reshape(B, kv, S, D),
+            dv.reshape(B, kv, S, D))
 
 
 def _flash_bwd(scale, dropout, causal, interpret, block_q, res, g):
@@ -436,8 +478,10 @@ def supports_pallas(B, H, S, D, bias_shape, dropout, is_tpu):
 def fused_attention(ctx, ins):
     """softmax(Q K^T * scale + Bias) V.
 
-    Inputs: Q/K/V [B, heads, S, D]; optional Bias [B, 1, 1, S] additive (already
-    -inf-masked). Attrs: scale (default 1/sqrt(D)), dropout_prob, causal,
+    Inputs: Q [B, heads, S, D], K/V [B, kv_heads, S, D] with kv_heads
+    dividing heads (grouped-query attention: query head i reads key/value
+    head i // (heads / kv_heads), in place -- no lowering repeats K or V);
+    optional Bias [B, 1, 1, S] additive (already -inf-masked). Attrs: scale (default 1/sqrt(D)), dropout_prob, causal,
     is_test, impl ('auto' | 'pallas' | 'ring' | 'ulysses' | 'composed').
 
     Kernel choice: under a GSPMD jit whose mesh has an "sp" axis >1 (sequence
@@ -458,6 +502,12 @@ def fused_attention(ctx, ins):
     q, k, v = ins["Q"][0], ins["K"][0], ins["V"][0]
     bias = ins.get("Bias", [None])[0]
     B, H, S, D = q.shape
+    kv_heads = k.shape[1]
+    if H % kv_heads or v.shape[1] != kv_heads:
+        raise ValueError(
+            f"fused_attention: {H} query heads over {kv_heads} key and "
+            f"{v.shape[1]} value heads; the query heads must be a multiple "
+            f"of the key/value heads")
     scale = ctx.attr("scale") or (1.0 / math.sqrt(D))
     dropout = 0.0 if ctx.attr("is_test", False) else ctx.attr("dropout_prob", 0.0)
     causal = bool(ctx.attr("causal", False))
@@ -474,6 +524,11 @@ def fused_attention(ctx, ins):
 
     gm = ctx.gspmd_mesh
     sp_n = gm.shape.get("sp", 1) if gm is not None else 1
+    if kv_heads != H and (sp_n > 1 or impl in ("ring", "ulysses")):
+        raise NotImplementedError(
+            "fused_attention: grouped-query attention (fewer key/value than "
+            "query heads) under sequence parallelism (ring / ulysses) is "
+            "not built yet")
     ring_ok = sp_n > 1 and S % sp_n == 0 and (
         bias is None or (len(bias.shape) == 4 and bias.shape[1] == 1
                          and bias.shape[2] == 1))
@@ -493,13 +548,13 @@ def fused_attention(ctx, ins):
                 f"({h_local} heads per mp shard), "
                 f"bias={None if bias is None else bias.shape}")
         from ..parallel import ulysses as _uly
-        ctx.note("fused_attention", ("ulysses", S, 0))
+        ctx.note("fused_attention", ("ulysses", S, 0, kv_heads))
         seed = jax.random.randint(ctx.rng(), (), 0, 2**31 - 1, jnp.int32)
         return {"Out": [_uly.ulysses_attention(
             q, k, v, bias, float(scale), float(dropout), causal, seed, gm)]}
     if ring_ok and impl in ("auto", "ring"):
         from ..parallel import ring_attention as _ring
-        ctx.note("fused_attention", ("ring", S, 0))
+        ctx.note("fused_attention", ("ring", S, 0, kv_heads))
         seed = jax.random.randint(ctx.rng(), (), 0, 2**31 - 1, jnp.int32)
         return {"Out": [_ring.ring_attention(
             q, k, v, bias, float(scale), float(dropout), causal, seed, gm)]}
@@ -535,7 +590,7 @@ def fused_attention(ctx, ins):
         _decide("fused_attention.backend", tune_params) == "pallas")
     if use_pallas:
         block_q, _ = _decide("fused_attention.block_sizes", tune_params)
-        ctx.note("fused_attention", ("pallas", S, int(block_q)))
+        ctx.note("fused_attention", ("pallas", S, int(block_q), kv_heads))
         # The kernels read the seed for a dropout mask alone. A test-mode
         # op draws none, like the dropout op under is_test: an inference
         # program then holds no random op (0.5 s of set-up for a threefry
@@ -550,7 +605,7 @@ def fused_attention(ctx, ins):
         out = _flash(q, k, v, bias, seed, float(scale), float(dropout), causal,
                      pallas_mode.interpret(), block_q)
     else:
-        ctx.note("fused_attention", ("xla", S, 0))
+        ctx.note("fused_attention", ("xla", S, 0, kv_heads))
         out = composed_attention(q, k, v, bias, float(scale), float(dropout),
                                  causal, ctx.rng(), ctx.bernoulli_mask)
     return {"Out": [out]}

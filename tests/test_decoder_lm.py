@@ -143,7 +143,7 @@ def test_bfloat16_agrees_at_the_written_tolerance():
 
 def test_config_a_builder_does_not_build_yet_raises_by_name():
     for key, value in (("norm_topk_prob", True), ("hidden_act", "gelu"),
-                       ("num_key_value_heads", 2),
+                       ("attention_bias", True),
                        ("tie_word_embeddings", True)):
         main, startup = fluid.Program(), fluid.Program()
         with fluid.unique_name.guard(), fluid.program_guard(main, startup):

@@ -82,6 +82,62 @@ def test_flash_compiles_for_v5e(one_chip, B, H, S, D, dtype, dropout, causal,
     assert _kernels(jax.jit(grads).lower(x, x, x, bias, x).compile()) == 1
 
 
+@pytest.mark.parametrize("B,dropout,use_bias", [(4, 0.0, False),
+                                                (2, 0.1, True)])
+def test_grouped_query_flash_compiles_for_v5e(one_chip, B, dropout, use_bias):
+    """lfm2_8b_a1b.pretrain_s4096's attention: 32 query heads over 8
+    key/value heads of 64 at S=4096, causal. The forward reads a key/value
+    head's rows in place (its block index is the query head's // 4) and the
+    backward's grid runs over a key/value head's four query heads, so dK and
+    dV leave at the key/value heads' shape: one kernel each way, and no
+    array of the query heads' shape among the backward's outputs but dQ."""
+    H, kv, S, D = 32, 8, 4096, 64
+    q = jax.ShapeDtypeStruct((B, H, S, D), jnp.bfloat16, sharding=one_chip)
+    k = jax.ShapeDtypeStruct((B, kv, S, D), jnp.bfloat16, sharding=one_chip)
+    bias = jax.ShapeDtypeStruct((B, 1, 1, S), jnp.float32, sharding=one_chip)
+
+    def attend(q, k, v, bias):
+        return pa._flash(q, k, v, bias if use_bias else None, jnp.int32(3),
+                         D ** -0.5, dropout, True, False)
+
+    def grads(q, k, v, bias, g):
+        return jax.vjp(lambda q, k, v: attend(q, k, v, bias), q, k, v)[1](g)
+
+    assert _kernels(jax.jit(attend).lower(q, k, k, bias).compile()) == 1
+    back = jax.jit(grads).lower(q, k, k, bias, q)
+    assert [tuple(o.shape) for o in back.out_info] == [
+        (B, H, S, D), (B, kv, S, D), (B, kv, S, D)]
+    assert _kernels(back.compile()) == 1
+
+
+@pytest.mark.parametrize("batch,seq,taps", [(4, 4096, 3), (1, 8192, 4)])
+def test_short_conv_kernels_compile_for_v5e(one_chip, batch, seq, taps):
+    """The gated short convolution at the LFM2 cell's shape ([16384, 3 x
+    2048] bf16, sequences of 4096) and at the longest sequence the kernels
+    take: one kernel forward; the backward a Program's grad op lowers holds
+    the backward kernel alone."""
+    from paddle_tpu.ops import pallas_short_conv as psc
+    C = 2048
+    assert psc.supports(seq, C, taps) and not psc.supports(seq, C + 64, taps)
+    x = jax.ShapeDtypeStruct((batch * seq, 3 * C), jnp.bfloat16,
+                             sharding=one_chip)
+    w = jax.ShapeDtypeStruct((C, taps), jnp.bfloat16, sharding=one_chip)
+    g = jax.ShapeDtypeStruct((batch * seq, C), jnp.bfloat16,
+                             sharding=one_chip)
+
+    def conv(x, w):
+        return psc.short_conv(x, w, seq, False)
+
+    def grads(x, w, g):
+        return jax.vjp(conv, x, w)[1](g)
+
+    assert _kernels(jax.jit(conv).lower(x, w).compile()) == 1
+    back = jax.jit(grads).lower(x, w, g)
+    assert [tuple(o.shape) for o in back.out_info] == [
+        (batch * seq, 3 * C), (C, taps)]
+    assert _kernels(back.compile()) == 1
+
+
 @pytest.mark.parametrize("S", [384, 512])
 @pytest.mark.parametrize("dtype", [jnp.float32, jnp.bfloat16])
 def test_flash_matches_composed_below_1024(S, dtype):
@@ -138,6 +194,31 @@ def test_grouped_expert_matmul_compiles_for_v5e(one_chip, as_on_the_chip,
     fwd = jax.jit(decoder_ops.grouped_matmul).lower(x, w, count).compile()
     assert _kernels(fwd) == 1
     assert _kernels(jax.jit(grads).lower(x, w, count, g).compile()) == 2
+
+
+@pytest.mark.parametrize("k,n", [(2048, 1792), (1792, 2048)])
+def test_held_expert_matmul_compiles_for_v5e(one_chip, as_on_the_chip, k, n):
+    """The LFM2 cell's expert products: 4 x 4096 tokens x top-4 rows (every
+    assignment has a row), the sizes of all 32 groups, and the stacked
+    weights of the 8 held experts only. The megablox kernels visit the held
+    groups' row tiles and the rest of the output is zero-filled: the same
+    kernel counts as with every expert held."""
+    from paddle_tpu.ops import decoder_ops
+    rows = 65536
+    x = jax.ShapeDtypeStruct((rows, k), jnp.bfloat16, sharding=one_chip)
+    w = jax.ShapeDtypeStruct((8, k, n), jnp.bfloat16, sharding=one_chip)
+    count = jax.ShapeDtypeStruct((32,), jnp.int32, sharding=one_chip)
+    g = jax.ShapeDtypeStruct((rows, n), jnp.bfloat16, sharding=one_chip)
+
+    def grads(x, w, count, g):
+        return jax.vjp(lambda x, w: decoder_ops.grouped_matmul(x, w, count),
+                       x, w)[1](g)
+
+    fwd = jax.jit(decoder_ops.grouped_matmul).lower(x, w, count).compile()
+    assert _kernels(fwd) == 1
+    back = jax.jit(grads).lower(x, w, count, g)
+    assert [tuple(o.shape) for o in back.out_info] == [(rows, k), (8, k, n)]
+    assert _kernels(back.compile()) == 2
 
 
 def _captured_step(main, feed, fetch, scope):
