@@ -36,8 +36,10 @@ reproduction gets the counterpart the whole-program-jit design enables:
 - ``moe`` -- expert-layer counts of a compiled program as gauges
   (``moe_layers``, ``moe_experts``, ``moe_assignments_per_step``,
   ``moe_expert_param_bytes``) and ``load_stats`` for a fetched load vector.
-- ``attention`` -- ``attention_lowering_total{program,impl,s,block_q}``: the
-  lowering each ``fused_attention`` op of a compiled program took.
+- ``attention`` -- ``attention_lowering_total{program,impl,s,block_q,block_k,
+  kv_heads}``: the lowering each ``fused_attention`` op of a compiled program
+  took; ``attention_k_tiles_total{program,state}``: the K tiles its flash
+  kernels visit and skip.
 
 Render everything with ``python -m tools.obs_report``.
 """

@@ -18,17 +18,30 @@ from .metrics import REGISTRY, MetricsRegistry
 
 def count_lowerings(notes: dict, program: str,
                     registry: Optional[MetricsRegistry] = None) -> None:
-    """``attention_lowering_total{program,impl,s,block_q,kv_heads}``: the
-    ``fused_attention`` ops the trace just compiled, by lowering (``pallas``,
-    ``xla``, ``ring``, ``ulysses``), sequence length, the kernels' Q block
-    (0 where no kernel ran) and key/value heads (fewer than the query's
-    under grouped-query attention). ``notes`` maps each op's salt to its
-    ``(impl, s, block_q, kv_heads)``; nothing is added for a program without
-    the op."""
+    """``attention_lowering_total{program,impl,s,block_q,block_k,kv_heads}``:
+    the ``fused_attention`` ops the trace just compiled, by lowering
+    (``pallas``, ``xla``, ``ring``, ``ulysses``), sequence length, the
+    kernels' Q block and K tile (0 where no kernel ran; ``block_k`` = ``s``
+    is one tile a row) and key/value heads (fewer than the query's under
+    grouped-query attention). ``attention_k_tiles_total{program,state}``: the
+    K tiles the forward kernel of each such op passes over for one (batch,
+    head) (``visited``) and those a causal op leaves out because they lie
+    wholly above the diagonal (``skipped``), from static shapes
+    (``ops/pallas_attention.py:k_tiles``). ``notes`` maps each op's salt to
+    its ``(impl, s, block_q, block_k, kv_heads, visited, skipped)``; nothing
+    is added for a program without the op."""
     registry = registry or REGISTRY
-    for (impl, s, block_q, kv_heads), n in Counter(notes.values()).items():
+    for (impl, s, block_q, block_k, kv_heads, visited, skipped), n in Counter(
+            notes.values()).items():
         registry.counter(
             "attention_lowering_total",
             "fused_attention ops compiled, by the lowering each took",
             program=program, impl=impl, s=str(s), block_q=str(block_q),
-            kv_heads=str(kv_heads)).inc(n)
+            block_k=str(block_k), kv_heads=str(kv_heads)).inc(n)
+        if impl == "pallas":
+            for state, tiles in (("visited", visited), ("skipped", skipped)):
+                registry.counter(
+                    "attention_k_tiles_total",
+                    "K tiles a (batch, head) of the compiled flash-attention "
+                    "ops' forward kernels", program=program,
+                    state=state).inc(n * tiles)

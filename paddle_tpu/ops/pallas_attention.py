@@ -22,33 +22,48 @@ Design:
     harness may ask for the Pallas interpreter, so the CPU suite exercises
     the same kernel body).
   * Whole K/V rows for one (batch, head) are staged in VMEM (S*D*2 bytes
-    each); Q is blocked at default_block_q(S) rows: the whole of S below
-    1024, BLK_Q from there (the `fused_attention.block_sizes` choice's
-    default). The softmax statistics (max, sum) and
-    every accumulation are f32; the MXU is fed both operands of every product
-    in the input's dtype with preferred_element_type=f32 (bf16 inputs: the
-    probabilities and dS are rounded to bf16 once, as the composed lowering
-    rounds p; f32 inputs keep f32 products). Nothing is divided per score:
-    a power-of-two scale moves into the [BLK_Q, D] q block (exact), the
-    softmax's 1/sum and the dropout's 1/(1-prob) scale [*, D] results.
-    The [BLK_Q, S] f32 temporaries bound S: the forward fits Mosaic's 16 MiB
-    of scoped VMEM to S=8192 at block_q 128, the backward takes
-    BWD_VMEM_LIMIT_BYTES and compiles to S=4096 at BLK_Q (S=8192 at block_q
-    128 only; PR 25, compiled for a described v5e); longer rows need the K
-    axis blocked (ROADMAP S5).
+    each); Q is blocked at default_block_q rows and the K axis is tiled at
+    default_block_k columns (the `fused_attention.block_sizes` choice's
+    default). Without `causal` one tile is the row (block_k = S) and a Q
+    block makes a single pass over [block_q, S] scores. Under `causal` from
+    CAUSAL_TILES_MIN_S a Q block loops over the K tiles at or under its
+    diagonal: a tile wholly above it costs no product, no exp and no
+    compare, a tile wholly under it takes no mask, and only the tiles the
+    diagonal crosses keep the `where` (_KTiles; at S=4096 and 512 x 1024
+    tiles 20 of 32 tiles are visited). The softmax statistics (max, sum) and
+    every accumulation are f32; the MXU is fed both operands of every
+    product in the input's dtype with preferred_element_type=f32 (bf16
+    inputs: the probabilities and dS are rounded to bf16 once, as the
+    composed lowering rounds p; f32 inputs keep f32 products). Nothing is
+    divided per score: a power-of-two scale moves into the [block_q, D] q
+    block (exact), the softmax's 1/sum and the dropout's 1/(1-prob) scale
+    [*, D] results.
+    What bounds S is VMEM: the staged rows and the [block_q, S] f32 of score
+    tiles a Q block keeps between its passes (temporaries with one tile, a
+    scratch with more). The forward fits Mosaic's 16 MiB of scoped VMEM to
+    S=4096 at block_q 512 and to S=8192 at block_q 128 and one tile; the
+    backward takes BWD_VMEM_LIMIT_BYTES and compiles to S=4096 (S=8192 at
+    block_q 128 and one tile only; compiled for a described v5e). Longer
+    rows need K/V staged by tile too (ROADMAP S1 (b)).
   * Backward is a custom-VJP Pallas kernel that *recomputes* the probabilities
     per Q block from q, k, bias alone (flash-style: FLOPs are cheap, HBM is
     not; and a residual the forward kernel wrote would make a Program's grad
-    op run that kernel a second time, see _flash_fwd). dK^T and dV^T [D, S]
+    op run that kernel a second time, see _flash_fwd). p needs its row's max
+    and sum before any tile of the row can be formed, so the Q block's score
+    tiles stay in VMEM over three passes (max; exp, sum and dP; dS and the
+    products): one exp a score and five products, where a second pass that
+    recomputes them pays two and seven (15% and 11% slower at the two
+    decoders' shapes; chip runs, PR 34). dK^T and dV^T [D, block_k] a K tile
     accumulate across the Q blocks in f32 VMEM scratch and leave in the
     input's dtype on the last one: grid axis 1 is declared "arbitrary"
     (sequential) for that, axis 0 "parallel".
   * Attention dropout uses the in-kernel PRNG (pltpu.prng_random_bits) seeded
-    per (step, batch*head, 128-row block); the backward kernel reseeds
-    identically so the mask matches without storing it, and the mask is the
-    same under every block_q. In-kernel PRNG has no interpreter lowering, so
-    dropout>0 takes the Pallas path only on a TPU (chip_smoke.py checks the
-    two kernels' masks against each other there).
+    per (step, batch*head, 128-row block, K tile); the backward kernel
+    reseeds identically so the mask matches without storing it, and the mask
+    is the same under every block_q (under every block_k it is not: a tile's
+    draw is [128, block_k] wide). In-kernel PRNG has no interpreter
+    lowering, so dropout>0 takes the Pallas path only on a TPU
+    (chip_smoke.py checks the two kernels' masks against each other there).
 """
 from __future__ import annotations
 
@@ -62,24 +77,85 @@ from ..core.registry import register
 # blocks of it whatever block_q is.
 _MIN_BLK_Q = 128
 
-# Q rows a grid step from S=1024 up: 256 beats 128 forward + backward at
-# S=1024, 2048 and 4096 (3.99 / 6.56 / 11.78 against 4.42 / 7.01 / 12.18 ms a
-# layer of 16k tokens; chip runs, PR 25). 512 and 1024 gain 2-8% more at
-# S=1024 and 2048 (PERF.md section 7) and 512 does not fit at 4096.
+# lanes of a vector register: a row statistic's partials (_KTiles)
+_LANES = 128
+
+# Q rows a grid step from S=1024 up where one K tile is the row: 256 beats 128
+# forward + backward at S=1024, 2048 and 4096 (3.99 / 6.56 / 11.78 against
+# 4.42 / 7.01 / 12.18 ms a layer of 16k tokens; chip runs, PR 25). 512 and
+# 1024 gain 2-8% more at S=1024 and 2048 (PERF.md section 7) and 512 does not
+# fit at 4096 with the whole row's scores in one block.
 BLK_Q = 256
 
+# (block_q, block_k) under `causal` from CAUSAL_TILES_MIN_S up, where both
+# divide S. Forward / backward kernel ms a layer of 4 x 4096 tokens, bf16
+# (chip runs, PR 34), at olmoe's shape (16 heads of 128) and at lfm2's (32
+# query over 8 key/value heads of 64); the first row is one tile a row at
+# BLK_Q, what a causal op takes below CAUSAL_TILES_MIN_S:
+#   (block_q, block_k)   olmoe fwd / bwd    lfm2 fwd / bwd
+#   (256, 4096) 1 tile   3.99 / 9.22        8.73 / 16.62
+#   (256, 256)           4.27 / 9.24 (*)    9.19 / 17.61 (*)
+#   (512, 256)           3.72 / 8.49 (*)    8.05 / 16.02 (*)
+#   (256, 512)           3.29 / 7.33 (*)    7.21 / 14.00 (*)
+#   (512, 512)           2.88 / 6.40        6.43 / 11.68
+#   (256, 1024)          2.94 / 6.50        6.56 / 12.14
+#   (512, 1024)          2.74 / 6.28        6.09 / 11.40
+#   (1024, 1024)         no fit / 6.79 (*)  no fit / 13.13 (*)
+# (*) before the backward's dQ product moved ahead of its dK^T / dV^T
+# accumulations, which took 8% (d=128) and 12% (d=64) off the backward at
+# (512, 512). A loop iteration costs about 0.2 us whatever the tile holds,
+# three iterations a tile in the backward: small tiles visit 53% of the square
+# and lose it again; 512 x 1024 visits 62.5%.
+CAUSAL_BLOCKS = (512, 1024)
+CAUSAL_TILES_MIN_S = 2048
 
-def default_block_q(S):
+
+def default_block_q(S, causal=False):
     """The Q block the kernels take at sequence length S where no tuning
     decision says otherwise; always divides S. Below 1024 one block a
     (batch, head): the whole [S, S] tile in one grid step beats every
     smaller block at S=256 ... 768 (2.43 against 2.67 ms at 256 and 3.33 at
     128, S=512, forward + backward of 16k tokens; table in PERF.md section 6,
     chip runs, PR 27) and compiles to S=896 in bf16 and f32. From 1024 up
-    BLK_Q, or _MIN_BLK_Q where that does not divide S."""
+    BLK_Q, or _MIN_BLK_Q where that does not divide S; with K tiles
+    (default_block_k) the Q block of CAUSAL_BLOCKS."""
     if S < 1024:
         return S
+    if default_block_k(S, causal) != S:
+        return CAUSAL_BLOCKS[0]
     return BLK_Q if S % BLK_Q == 0 else _MIN_BLK_Q
+
+
+def default_block_k(S, causal=False):
+    """The K tile at sequence length S; always divides S. Without ``causal``
+    the row: every tile would be visited, a narrower one only adds loop
+    iterations, and the dropout mask is drawn a tile at a time. With it, the
+    K tile of CAUSAL_BLOCKS from CAUSAL_TILES_MIN_S up where the pair divides
+    S (at S=4096 32% less kernel time than one tile, table above), chosen
+    from what the op sees: ``causal`` and S."""
+    q, k = CAUSAL_BLOCKS
+    if causal and S >= CAUSAL_TILES_MIN_S and S % q == 0 and S % k == 0:
+        return k
+    return S
+
+
+def _under_diagonal(iq, block_q, block_k):
+    """(clear, visited) of Q block ``iq`` under a causal mask: K tiles
+    [0, clear) lie wholly at or under its diagonal, [clear, visited) are
+    crossed by it, the tiles from ``visited`` up lie wholly above it. Python
+    ints or traced values, as ``iq`` is."""
+    return ((iq * block_q) // block_k,
+            ((iq + 1) * block_q - 1) // block_k + 1)
+
+
+def k_tiles(S, block_q, block_k, causal):
+    """(visited, skipped): the K tiles the Q blocks of one (batch, head)
+    pass over in the forward kernel, and those they leave out because they
+    lie wholly above the diagonal (_KTiles, summed over the Q blocks)."""
+    n_q, n_k = S // block_q, S // block_k
+    visited = sum(_under_diagonal(iq, block_q, block_k)[1]
+                  for iq in range(n_q)) if causal else n_q * n_k
+    return visited, n_q * n_k - visited
 
 
 # Scoped VMEM the backward kernel may take. Mosaic's default (16 MiB of the
@@ -172,35 +248,52 @@ def _scale_is_exact(scale):
     return math.frexp(scale)[0] == 0.5
 
 
-def _scores(q_blk, k_all, bias_row, iq, scale, causal):
-    """[block_q, S] f32 scores of one Q block, and the q block that went into
-    them (scaled when the scale is folded; the backward's dK needs it)."""
+def _fold_scale(q_blk, scale):
+    """The q block that goes into the scores: scaled where the scale is a
+    power of two (the backward's dK needs the same block)."""
+    import jax.numpy as jnp
+    if _scale_is_exact(scale):
+        return q_blk * jnp.asarray(scale, q_blk.dtype)
+    return q_blk
+
+
+def _rows(ref, t, block_k):
+    """K tile ``t`` of a staged [1, S, D] row block: [block_k, D]."""
+    pl, _ = _pl()
+    if isinstance(t, int):
+        return ref[0, t * block_k:(t + 1) * block_k, :]
+    return ref[0, pl.ds(pl.multiple_of(t * block_k, block_k), block_k), :]
+
+
+def _scores(q_s, k_ref, bias_ref, iq, t, block_k, scale, masked):
+    """[block_q, block_k] f32 scores of Q block ``iq`` against K tile ``t``.
+    ``q_s`` comes from _fold_scale; ``masked``: the diagonal crosses the
+    tile (a tile wholly under it takes no mask, one above it is not
+    visited: _KTiles)."""
     import jax
     import jax.numpy as jnp
 
-    blk_q = q_blk.shape[0]
-    fold = _scale_is_exact(scale)
-    if fold:
-        q_blk = q_blk * jnp.asarray(scale, q_blk.dtype)
-    s = _dot(q_blk, k_all, _NT)
-    if not fold:
+    blk_q = q_s.shape[0]
+    s = _dot(q_s, _rows(k_ref, t, block_k), _NT)
+    if not _scale_is_exact(scale):
         s = s * scale
-    if bias_row is not None:
-        s = s + bias_row.astype(jnp.float32)                 # [1,S] broadcasts
-    if causal:
-        S_k = s.shape[-1]
-        qi = iq * blk_q + jax.lax.broadcasted_iota(jnp.int32, (blk_q, S_k), 0)
-        ki = jax.lax.broadcasted_iota(jnp.int32, (blk_q, S_k), 1)
-        s = jnp.where(ki <= qi, s, jnp.float32(-1e30))
-    return s, q_blk
+    if bias_ref is not None:
+        s = s + bias_ref[0, t].astype(jnp.float32)           # [1, block_k]
+    if masked:
+        # column - row inside the tile, against where the tile lies
+        rel = (jax.lax.broadcasted_iota(jnp.int32, (blk_q, block_k), 1)
+               - jax.lax.broadcasted_iota(jnp.int32, (blk_q, block_k), 0))
+        s = jnp.where(rel <= iq * blk_q - t * block_k, s, jnp.float32(-1e30))
+    return s
 
 
-def _keep_mask(shape, seed_ref, iq, dropout, bh=None):
-    """Bernoulli(1 - dropout) keep mask of one Q block, drawn by blocks of
-    _MIN_BLK_Q rows, each seeded by (step seed, batch*head, its index in the
-    sequence): the backward reseeds the same, and the mask does not depend on
-    block_q. ``bh`` is the query's batch*head index where grid axis 0 is not
-    it (the grouped backward)."""
+def _keep_mask(shape, seed_ref, iq, dropout, bh=None, t=0):
+    """Bernoulli(1 - dropout) keep mask of one Q block against K tile ``t``,
+    drawn by blocks of _MIN_BLK_Q rows, each seeded by (step seed,
+    batch*head, its index in the sequence, the tile): the backward reseeds
+    the same, and the mask does not depend on block_q (on block_k it does:
+    the two kernels take the same). ``bh`` is the query's batch*head index
+    where grid axis 0 is not it (the grouped backward)."""
     import jax.numpy as jnp
     pl, pltpu = _pl()
     n = shape[0] // _MIN_BLK_Q
@@ -208,50 +301,155 @@ def _keep_mask(shape, seed_ref, iq, dropout, bh=None):
     for j in range(n):
         pltpu.prng_seed(seed_ref[0]
                         + (pl.program_id(0) if bh is None else bh) * 1000003
-                        + (iq * n + j) * 7919)
+                        + (iq * n + j) * 7919 + t * 104729)
         bits.append(pltpu.prng_random_bits((_MIN_BLK_Q, shape[1])))
     bits = pltpu.bitcast(jnp.concatenate(bits, axis=0), jnp.uint32)
     return bits >= jnp.uint32(int(dropout * float(2**32)))
 
 
-def _fwd_kernel(scale, dropout, causal, has_bias, *refs):
+def _plus(acc, x):
+    """``acc + x``; ``x`` where nothing has been carried yet."""
+    return x if acc is None else acc + x
+
+
+class _KTiles:
+    """The K tiles one Q block visits, in passes. Tiles [0, clear) lie wholly
+    at or under Q block ``iq``'s diagonal and take no mask, [clear, visited)
+    are crossed by it, and the tiles from ``visited`` up lie wholly above it:
+    no pass goes there. Without ``causal`` every tile is clear.
+
+    A score tile lives from one pass to a later one in a stage (``put`` /
+    ``get``), a [n_k, block_q, block_k] f32 VMEM scratch. A row statistic
+    (max, sum) is carried from tile to tile as its [block_q, _LANES]
+    partials, elementwise work; the cross-lane reduction is made once a pass
+    (``total``), not once a tile.
+
+    ``whole``: one tile is the row (block_k = S). Then nothing loops, nothing
+    is carried (a body's ``carry`` is None), a staged tile is the value
+    itself and a statistic is reduced where it is formed: a single pass over
+    [block_q, S], the BERT cells' path
+    (tests/test_pallas_attention.py keeps its plain body as an oracle)."""
+
+    def __init__(self, iq, block_q, block_k, n_k, causal, stage_refs=()):
+        self.whole, self.causal, self.block_q = n_k == 1, causal, block_q
+        self.clear, self.visited = _under_diagonal(
+            iq, block_q, block_k) if causal else (n_k, n_k)
+        self.stages = list(stage_refs)
+        self.staged = {}
+
+    def passes(self, body, init):
+        """``body(masked, t, carry)`` over the clear, then the crossed
+        tiles; ``init()`` is the carry before the first tile of a loop."""
+        import jax
+        if self.whole:
+            return body(self.causal, 0, None)
+        carry = jax.lax.fori_loop(0, self.clear,
+                                  functools.partial(body, False), init())
+        if not self.causal:
+            return carry
+        return jax.lax.fori_loop(self.clear, self.visited,
+                                 functools.partial(body, True), carry)
+
+    def put(self, i, t, x):
+        if self.whole:
+            self.staged[i] = x
+        else:
+            self.stages[i][t] = x
+
+    def get(self, i, t):
+        return self.staged[i] if self.whole else self.stages[i][t]
+
+    def stat(self, x, op):
+        """Tile ``x``'s part in a row statistic; ``op`` is jnp.max or
+        jnp.sum."""
+        import jax
+        import jax.numpy as jnp
+        if self.whole:
+            return op(x, axis=-1, keepdims=True)
+        # lax, not jnp: a kernel has some 200 of these slices and pairs, and
+        # a jnp call costs a millisecond of tracing each
+        both = jax.lax.max if op is jnp.max else jax.lax.add
+        return functools.reduce(both, [
+            jax.lax.slice_in_dim(x, j, j + _LANES, axis=1)
+            for j in range(0, x.shape[1], _LANES)])
+
+    def total(self, x, op):
+        """[block_q, 1] of a statistic carried over a pass."""
+        return x if self.whole else op(x, axis=-1, keepdims=True)
+
+    def stat_init(self, value):
+        """A statistic's carry before a pass's first tile."""
+        import jax.numpy as jnp
+        return jnp.full((self.block_q, _LANES), value, jnp.float32)
+
+    def row_max(self, scores):
+        """Pass 1 of both kernels: every visited tile's scores into stage 0,
+        and the row maxima [block_q, 1]. ``scores(masked, t)``."""
+        import jax.numpy as jnp
+
+        def body(masked, t, m):
+            s = scores(masked, t)
+            self.put(0, t, s)
+            m_t = self.stat(s, jnp.max)
+            return m_t if m is None else jnp.maximum(m, m_t)
+
+        return self.total(self.passes(
+            body, lambda: self.stat_init(-jnp.inf)), jnp.max)
+
+
+def _fwd_kernel(scale, dropout, causal, has_bias, block_k, *refs):
+    """One Q block against its K tiles in two passes: (1) scores, row max;
+    (2) exp, row sum and the product with V."""
     import jax.numpy as jnp
     pl, _ = _pl()
-    if has_bias:
-        q_ref, k_ref, v_ref, bias_ref, seed_ref, o_ref = refs
-        bias_row = bias_ref[0]                               # [1, S]
-    else:
-        q_ref, k_ref, v_ref, seed_ref, o_ref = refs
-        bias_row = None
+    refs = list(refs)
+    q_ref, k_ref, v_ref = refs[:3]
+    bias_ref = refs[3] if has_bias else None
+    seed_ref, o_ref = refs[3 + has_bias:5 + has_bias]
     iq = pl.program_id(1)
-    s, _ = _scores(q_ref[0], k_ref[0], bias_row, iq, scale, causal)
-    m = jnp.max(s, axis=-1, keepdims=True)
-    e = jnp.exp(s - m)
-    l = jnp.sum(e, axis=-1, keepdims=True)
-    if dropout:
-        e = jnp.where(_keep_mask(e.shape, seed_ref, iq, dropout), e, 0.0)
+    blk_q, D = q_ref.shape[1:]
+    tiles = _KTiles(iq, blk_q, block_k, k_ref.shape[1] // block_k, causal,
+                    refs[5 + has_bias:])
+    q_s = _fold_scale(q_ref[0], scale)
+    m = tiles.row_max(lambda masked, t: _scores(
+        q_s, k_ref, bias_ref, iq, t, block_k, scale, masked))
+
+    def product(masked, t, carry):
+        e = jnp.exp(tiles.get(0, t) - m)
+        l_t = tiles.stat(e, jnp.sum)
+        if dropout:
+            e = jnp.where(_keep_mask(e.shape, seed_ref, iq, dropout, t=t),
+                          e, 0.0)
+        o_t = _dot(e.astype(v_ref.dtype), _rows(v_ref, t, block_k), _NN)
+        l, o = carry or (None, None)
+        return _plus(l, l_t), _plus(o, o_t)
+
+    l, o = tiles.passes(product, lambda: (
+        tiles.stat_init(0.0), jnp.zeros((blk_q, D), jnp.float32)))
+    l = tiles.total(l, jnp.sum)
     # the softmax's 1/l and the dropout's 1/(1-prob) scale the [block_q, D]
     # product, one reciprocal a row, not the [block_q, S] probabilities
-    o = _dot(e.astype(v_ref.dtype), v_ref[0], _NN)
     o_ref[0] = (o * (1.0 / (l * (1.0 - dropout)))).astype(o_ref.dtype)
 
 
-def _bwd_kernel(scale, dropout, causal, has_bias, group, *refs):
+def _bwd_kernel(scale, dropout, causal, has_bias, group, block_k, *refs):
     """``group`` query heads share a key/value head. At 1 grid axis 1 is the
     Q block; above 1 it runs over the group's heads and their Q blocks in
     turn, so that dK^T / dV^T accumulate over the whole group in VMEM and a
     key/value head's gradient is written once (no per-query-head dK, dV in
-    HBM to sum afterwards)."""
+    HBM to sum afterwards).
+
+    Nothing comes from the forward, and p needs its row's max and sum: three
+    passes over the Q block's K tiles. (1) scores, row max; (2) exp, row sum,
+    dP = dO V^T and the row's sum of dP P; (3) dS and the three gradient
+    products."""
     import jax.numpy as jnp
     pl, _ = _pl()
-    if has_bias:
-        (q_ref, k_ref, v_ref, bias_ref, seed_ref, do_ref,
-         dq_ref, dk_ref, dv_ref, dkt_acc, dvt_acc) = refs
-        bias_row = bias_ref[0]                               # [1, S]
-    else:
-        (q_ref, k_ref, v_ref, seed_ref, do_ref,
-         dq_ref, dk_ref, dv_ref, dkt_acc, dvt_acc) = refs
-        bias_row = None
+    refs = list(refs)
+    q_ref, k_ref, v_ref = refs[:3]
+    bias_ref = refs[3] if has_bias else None
+    seed_ref, do_ref, dq_ref, dk_ref, dv_ref, dkt_acc, dvt_acc = \
+        refs[3 + has_bias:10 + has_bias]
     step = iq = pl.program_id(1)
     bh = None
     if group > 1:
@@ -259,45 +457,81 @@ def _bwd_kernel(scale, dropout, causal, has_bias, group, *refs):
         iq = step % n_q
         bh = pl.program_id(0) * group + step // n_q
     dtype = q_ref.dtype
-    s, q_s = _scores(q_ref[0], k_ref[0], bias_row, iq, scale, causal)
-    e = jnp.exp(s - jnp.max(s, axis=-1, keepdims=True))
-    p = e * (1.0 / jnp.sum(e, axis=-1, keepdims=True))       # [BLK_Q, S] f32
+    blk_q, D = q_ref.shape[1:]
+    n_k = k_ref.shape[1] // block_k
+    tiles = _KTiles(iq, blk_q, block_k, n_k, causal, refs[10 + has_bias:])
+    q_s = _fold_scale(q_ref[0], scale)
     do = do_ref[0]                                           # [BLK_Q, D]
-    dpd = _dot(do, v_ref[0], _NT)                            # [BLK_Q, S] f32
+    m = tiles.row_max(lambda masked, t: _scores(
+        q_s, k_ref, bias_ref, iq, t, block_k, scale, masked))
+
     # Dropout multiplies p and its gradient by keep * c, c = 1/(1-prob). The
-    # mask is applied here; c is a constant of every product below, so it
-    # scales the [*, D] results (pk, dp, ds are the true values over c).
+    # mask is applied to dp and to p; c is a constant of every product, so
+    # it scales the [*, D] results (pk, dp, ds are the true values over c).
     c = 1.0 / (1.0 - dropout)
-    if dropout:
-        keep = _keep_mask(p.shape, seed_ref, iq, dropout, bh)
-        pk = jnp.where(keep, p, 0.0)
-        dp = jnp.where(keep, dpd, 0.0)
+
+    def keep(t):
+        return _keep_mask((blk_q, block_k), seed_ref, iq, dropout, bh, t)
+    if tiles.whole:
+        keep = functools.lru_cache(None)(keep)               # drawn once
+    # (with more tiles passes 2 and 3 each draw a tile's mask: the draw is
+    # cheaper than a third staged tile)
+
+    def softmax(masked, t, carry):
+        e = jnp.exp(tiles.get(0, t) - m)
+        l_t = tiles.stat(e, jnp.sum)
+        if tiles.whole:
+            e = e * (1.0 / l_t)                          # p: the row is here
+        dp = _dot(do, _rows(v_ref, t, block_k), _NT)         # [BLK_Q, blk_k]
+        if dropout:
+            dp = jnp.where(keep(t), dp, 0.0)
+        tiles.put(0, t, e)
+        tiles.put(1, t, dp)
+        l, r = carry or (None, None)
+        return _plus(l, l_t), _plus(r, tiles.stat(dp * e, jnp.sum))
+
+    l, r = tiles.passes(softmax, lambda: (tiles.stat_init(0.0),
+                                          tiles.stat_init(0.0)))
+    if tiles.whole:
+        row = r                                              # sum of dp * p
     else:
-        pk, dp = p, dpd
-    row = jnp.sum(dp * p, axis=-1, keepdims=True)
-    ds = (p * (dp - row)).astype(dtype)
-    dq_ref[0] = (_dot(ds, k_ref[0], _NN) * (c * scale)).astype(dq_ref.dtype)
+        inv_l = 1.0 / tiles.total(l, jnp.sum)
+        row = tiles.total(r, jnp.sum) * inv_l
 
     @pl.when(step == 0)
     def _():
         dkt_acc[...] = jnp.zeros_like(dkt_acc)
         dvt_acc[...] = jnp.zeros_like(dvt_acc)
 
-    # dK^T, dV^T [D, S]: contracting the Q rows of both operands transposes
-    # the [BLK_Q, D] block, not the [BLK_Q, S] one
-    dkt_acc[...] += _dot(q_s, ds, _TN)
-    dvt_acc[...] += _dot(do, pk.astype(dtype), _TN)
+    def grads(masked, t, dq):
+        p = tiles.get(0, t)                                  # f32
+        if not tiles.whole:
+            p = p * inv_l
+        pk = jnp.where(keep(t), p, 0.0) if dropout else p
+        ds = (p * (tiles.get(1, t) - row)).astype(dtype)
+        dq = _plus(dq, _dot(ds, _rows(k_ref, t, block_k), _NN))
+        # dK^T, dV^T [D, block_k]: contracting the Q rows of both operands
+        # transposes the [BLK_Q, D] block, not the [BLK_Q, block_k] one
+        dkt_acc[t] += _dot(q_s, ds, _TN)
+        dvt_acc[t] += _dot(do, pk.astype(dtype), _TN)
+        return dq
+
+    dq = tiles.passes(grads, lambda: jnp.zeros((blk_q, D), jnp.float32))
+    dq_ref[0] = (dq * (c * scale)).astype(dq_ref.dtype)
 
     @pl.when(step == pl.num_programs(1) - 1)
     def _():
         k_scale = c if _scale_is_exact(scale) else c * scale  # q_s has it
-        dk_ref[0] = (dkt_acc[...] * k_scale).T.astype(dk_ref.dtype)
-        dv_ref[0] = (dvt_acc[...] * c).T.astype(dv_ref.dtype)
+        for t in range(n_k):
+            cols = slice(t * block_k, (t + 1) * block_k)
+            dk_ref[0, cols, :] = (dkt_acc[t] * k_scale).T.astype(dk_ref.dtype)
+            dv_ref[0, cols, :] = (dvt_acc[t] * c).T.astype(dv_ref.dtype)
 
 
-def _operands(q, k, v, bias, seed, block_q, by_kv_head=False):
+def _operands(q, k, v, bias, seed, block_q, block_k, by_kv_head=False):
     """The kernels' common operands and block specs, and the number of Q
-    blocks (block_q divides S: _flash has seen to it). Grid axis 0 is the
+    blocks (block_q and block_k divide S: _flash has seen to it). K and V
+    rows are staged whole, a row's bias by K tile. Grid axis 0 is the
     query's batch*head and axis 1 the Q block; with fewer key/value heads
     than query heads (k, v ``[B, Hkv, S, D]``) a query head's program reads
     its key/value head's rows in place. ``by_kv_head`` (the grouped
@@ -324,15 +558,27 @@ def _operands(q, k, v, bias, seed, block_q, by_kv_head=False):
     kvspec = pl.BlockSpec((1, S, D), kv_at, memory_space=pltpu.VMEM)
     in_specs = [qspec, kvspec, kvspec]
     if bias is not None:
-        # [B,1,S] with block (1,1,S): the last two dims equal the array dims,
-        # satisfying the TPU (8,128)-divisible-or-full block constraint.
-        args.append(bias.reshape(B, 1, S))
+        # [B, n_k, 1, block_k] with block (1, n_k, 1, block_k): the last two
+        # dims equal the array dims, satisfying the TPU (8,128)-divisible-
+        # or-full block constraint, and a tile's row is a leading index.
+        n_k = S // block_k
+        args.append(bias.reshape(B, n_k, 1, block_k))
         in_specs.append(pl.BlockSpec(
-            (1, 1, S), lambda b, i: (b // per_batch, 0, 0),
+            (1, n_k, 1, block_k), lambda b, i: (b // per_batch, 0, 0, 0),
             memory_space=pltpu.VMEM))
     args.append(jnp.asarray(seed, jnp.int32).reshape(1))
     in_specs.append(pl.BlockSpec(memory_space=pltpu.SMEM))
     return args, in_specs, qspec, kvspec, n_q
+
+
+def _stages(n, S, block_q, block_k):
+    """Scratch for ``n`` staged [block_q, block_k] f32 tiles a K tile
+    (_KTiles); none where one tile is the row."""
+    import jax.numpy as jnp
+    _, pltpu = _pl()
+    n_k = S // block_k
+    return [pltpu.VMEM((n_k, block_q, block_k), jnp.float32)] * (
+        n if n_k > 1 else 0)
 
 
 def _compiler_params(interpret, vmem_limit_bytes=None):
@@ -350,29 +596,33 @@ def _compiler_params(interpret, vmem_limit_bytes=None):
 import jax as _jax  # custom_vjp and jit must wrap at def time
 
 # _flash's arguments after the arrays, all static
-_STATIC = ("scale", "dropout", "causal", "interpret", "block_q")
+_STATIC = ("scale", "dropout", "causal", "interpret", "block_q", "block_k")
 
 
 def _flash(q, k, v, bias, seed, scale, dropout, causal, interpret,
-           block_q=None):
+           block_q=None, block_k=None):
     """The flash kernels, differentiable in q, k and v. ``block_q`` (Q rows a
-    grid step) divides S; None takes ``default_block_q(S)``."""
+    grid step) and ``block_k`` (columns a K tile, both kernels) divide S;
+    None takes ``default_block_q`` / ``default_block_k``."""
     S = q.shape[2]
     if block_q is None:
-        block_q = default_block_q(S)
-    if S % block_q or block_q % _MIN_BLK_Q:
-        raise ValueError(
-            f"flash attention: block_q={block_q} must divide S={S} and be a "
-            f"multiple of {_MIN_BLK_Q}")
+        block_q = default_block_q(S, causal)
+    if block_k is None:
+        block_k = default_block_k(S, causal)
+    for name, block in (("block_q", block_q), ("block_k", block_k)):
+        if S % block or block % _MIN_BLK_Q:
+            raise ValueError(
+                f"flash attention: {name}={block} must divide S={S} and be "
+                f"a multiple of {_MIN_BLK_Q}")
     return _flash_vjp(q, k, v, bias, seed, scale, dropout, causal, interpret,
-                      block_q)
+                      block_q, block_k)
 
 
-@functools.partial(_jax.custom_vjp, nondiff_argnums=(5, 6, 7, 8, 9))
+@functools.partial(_jax.custom_vjp, nondiff_argnums=(5, 6, 7, 8, 9, 10))
 def _flash_vjp(q, k, v, bias, seed, scale, dropout, causal, interpret,
-               block_q):
+               block_q, block_k):
     return _fwd_call(q, k, v, bias, seed, scale, dropout, causal, interpret,
-                     block_q)
+                     block_q, block_k)
 
 
 # Each kernel call sits behind a jit of its own. The layers of a model call
@@ -384,18 +634,20 @@ def _flash_vjp(q, k, v, bias, seed, scale, dropout, causal, interpret,
 # (compile.trace_lower_s 8.3 against 4.4 s, ledger, PR 26).
 @functools.partial(_jax.jit, static_argnames=_STATIC)
 def _fwd_call(q, k, v, bias, seed, scale, dropout, causal, interpret,
-              block_q):
+              block_q, block_k):
     import jax
     pl, _ = _pl()
     B, H, S, D = q.shape
-    args, in_specs, qspec, _, n_q = _operands(q, k, v, bias, seed, block_q)
+    args, in_specs, qspec, _, n_q = _operands(q, k, v, bias, seed, block_q,
+                                              block_k)
     out = pl.pallas_call(
         functools.partial(_fwd_kernel, scale, dropout, causal,
-                          bias is not None),
+                          bias is not None, block_k),
         grid=(B * H, n_q),
         in_specs=in_specs,
         out_specs=qspec,
         out_shape=jax.ShapeDtypeStruct((B * H, S, D), q.dtype),
+        scratch_shapes=_stages(1, S, block_q, block_k),
         interpret=interpret,
         **_compiler_params(interpret),
     )(*args)
@@ -403,9 +655,9 @@ def _fwd_call(q, k, v, bias, seed, scale, dropout, causal, interpret,
 
 
 def _flash_fwd(q, k, v, bias, seed, scale, dropout, causal, interpret,
-               block_q):
+               block_q, block_k):
     out = _fwd_call(q, k, v, bias, seed, scale, dropout, causal, interpret,
-                    block_q)
+                    block_q, block_k)
     # Inputs only. A Program's generic grad op (core/registry.py) lowers the
     # forward again under jax.vjp: a kernel output among the residuals (a
     # log-sum-exp, say) keeps that second forward kernel alive, which costs
@@ -415,27 +667,30 @@ def _flash_fwd(q, k, v, bias, seed, scale, dropout, causal, interpret,
 
 @functools.partial(_jax.jit, static_argnames=_STATIC)
 def _bwd_call(q, k, v, bias, seed, g, scale, dropout, causal, interpret,
-              block_q):
+              block_q, block_k):
     import jax
     import jax.numpy as jnp
     pl, pltpu = _pl()
     B, H, S, D = q.shape
     kv = k.shape[1]
     group = H // kv
-    args, in_specs, qspec, kvspec, n_q = _operands(q, k, v, bias, seed,
-                                                   block_q, by_kv_head=True)
+    args, in_specs, qspec, kvspec, n_q = _operands(
+        q, k, v, bias, seed, block_q, block_k, by_kv_head=True)
     args.append(g.reshape(B * H, S, D))
     in_specs.append(qspec)
+    # dK^T, dV^T by K tile; with more tiles than one, the score and dP tiles
+    # a Q block keeps between its passes
+    scratch = ([pltpu.VMEM((S // block_k, D, block_k), jnp.float32)] * 2
+               + _stages(2, S, block_q, block_k))
     dq, dk, dv = pl.pallas_call(
         functools.partial(_bwd_kernel, scale, dropout, causal,
-                          bias is not None, group),
+                          bias is not None, group, block_k),
         grid=(B * kv, group * n_q),
         in_specs=in_specs,
         out_specs=[qspec, kvspec, kvspec],
         out_shape=[jax.ShapeDtypeStruct((B * x.shape[1], S, D), x.dtype)
                    for x in (q, k, v)],
-        scratch_shapes=[pltpu.VMEM((D, S), jnp.float32),
-                        pltpu.VMEM((D, S), jnp.float32)],
+        scratch_shapes=scratch,
         interpret=interpret,
         **_compiler_params(interpret, BWD_VMEM_LIMIT_BYTES),
     )(*args)
@@ -443,13 +698,13 @@ def _bwd_call(q, k, v, bias, seed, g, scale, dropout, causal, interpret,
             dv.reshape(B, kv, S, D))
 
 
-def _flash_bwd(scale, dropout, causal, interpret, block_q, res, g):
+def _flash_bwd(scale, dropout, causal, interpret, block_q, block_k, res, g):
     import jax
     import jax.numpy as jnp
     import numpy as np
     q, k, v, bias, seed = res
     dq, dk, dv = _bwd_call(q, k, v, bias, seed, g, scale, dropout, causal,
-                           interpret, block_q)
+                           interpret, block_q, block_k)
     return (dq, dk, dv, None if bias is None else jnp.zeros_like(bias),
             np.zeros(np.shape(seed), jax.dtypes.float0))
 
@@ -548,13 +803,13 @@ def fused_attention(ctx, ins):
                 f"({h_local} heads per mp shard), "
                 f"bias={None if bias is None else bias.shape}")
         from ..parallel import ulysses as _uly
-        ctx.note("fused_attention", ("ulysses", S, 0, kv_heads))
+        ctx.note("fused_attention", ("ulysses", S, 0, 0, kv_heads, 0, 0))
         seed = jax.random.randint(ctx.rng(), (), 0, 2**31 - 1, jnp.int32)
         return {"Out": [_uly.ulysses_attention(
             q, k, v, bias, float(scale), float(dropout), causal, seed, gm)]}
     if ring_ok and impl in ("auto", "ring"):
         from ..parallel import ring_attention as _ring
-        ctx.note("fused_attention", ("ring", S, 0, kv_heads))
+        ctx.note("fused_attention", ("ring", S, 0, 0, kv_heads, 0, 0))
         seed = jax.random.randint(ctx.rng(), (), 0, 2**31 - 1, jnp.int32)
         return {"Out": [_ring.ring_attention(
             q, k, v, bias, float(scale), float(dropout), causal, seed, gm)]}
@@ -579,7 +834,7 @@ def fused_attention(ctx, ins):
     # impl='auto' backend + block sizes are tunable choice points: a
     # persisted autotune decision (PADDLE_TPU_TUNE=cached/search) answers
     # where there is one, else the defaults measured on the v5e
-    # (AUTO_PALLAS_MIN_S, default_block_q).
+    # (AUTO_PALLAS_MIN_S, default_block_q, default_block_k).
     from ..tuning import decide as _decide
     tune_params = {"b": B, "h": H, "s": S, "d": D, "dtype": str(q.dtype),
                    "has_bias": bias is not None, "dropout": float(dropout),
@@ -589,8 +844,10 @@ def fused_attention(ctx, ins):
         supports_pallas(B, H, S, D, bias_shape, dropout, is_tpu) and
         _decide("fused_attention.backend", tune_params) == "pallas")
     if use_pallas:
-        block_q, _ = _decide("fused_attention.block_sizes", tune_params)
-        ctx.note("fused_attention", ("pallas", S, int(block_q), kv_heads))
+        block_q, block_k = (int(b) for b in _decide(
+            "fused_attention.block_sizes", tune_params))
+        ctx.note("fused_attention", ("pallas", S, block_q, block_k, kv_heads)
+                 + k_tiles(S, block_q, block_k, causal))
         # The kernels read the seed for a dropout mask alone. A test-mode
         # op draws none, like the dropout op under is_test: an inference
         # program then holds no random op (0.5 s of set-up for a threefry
@@ -603,9 +860,9 @@ def fused_attention(ctx, ins):
         else:
             seed = jnp.int32(0)
         out = _flash(q, k, v, bias, seed, float(scale), float(dropout), causal,
-                     pallas_mode.interpret(), block_q)
+                     pallas_mode.interpret(), block_q, block_k)
     else:
-        ctx.note("fused_attention", ("xla", S, 0, kv_heads))
+        ctx.note("fused_attention", ("xla", S, 0, 0, kv_heads, 0, 0))
         out = composed_attention(q, k, v, bias, float(scale), float(dropout),
                                  causal, ctx.rng(), ctx.bernoulli_mask)
     return {"Out": [out]}

@@ -27,11 +27,10 @@ The four wired choice points (the ROOFLINE/ISSUE set):
 ``fused_attention.backend``     Pallas flash kernel vs XLA's own fusion
                                 (the *auto* policy; its default is the
                                 measured AUTO_PALLAS_MIN_S crossover)
-``fused_attention.block_sizes`` flash (block_q, block_k); block_k is pinned
-                                to S for now -- the kernel stages whole K/V
-                                rows in VMEM -- so the search is over the
-                                block_q that divide S (default: the
-                                kernel's default_block_q(S))
+``fused_attention.block_sizes`` flash (block_q, block_k), both dividing S;
+                                a K tile narrower than S only where the op
+                                is causal (default: the kernel's
+                                default_block_q / default_block_k)
 ``conv2d.layout``               run a conv NHWC vs NCHW regardless of the
                                 declared data_format (transposing at the op
                                 boundary; XLA cancels adjacent transposes)
@@ -329,9 +328,10 @@ def _fwd_bwd(attend, q, k, v):
     return out, vjp(out)
 
 
-def _flash_bench(params, block_q):
+def _flash_bench(params, block_q, block_k=None):
     """(fn, args) timing the flash kernels forward + backward at ``block_q``
-    (None: the default for S), or None where they cannot run."""
+    and ``block_k`` (None: the defaults for S), or None where they cannot
+    run."""
     from ..ops import pallas_mode
     from ..ops.pallas_attention import _flash
     if not pallas_mode.available():
@@ -341,7 +341,8 @@ def _flash_bench(params, block_q):
 
     def pallas_fn(q, k, v):
         return _fwd_bwd(lambda q, k, v: _flash(
-            q, k, v, bias, 0, scale, dropout, causal, interpret, block_q),
+            q, k, v, bias, 0, scale, dropout, causal, interpret, block_q,
+            block_k),
             q, k, v)
 
     return pallas_fn, (q, q, q)
@@ -354,27 +355,32 @@ def _flash_bench(params, block_q):
 
 class FlashBlockSizes(TunableChoice):
     id = "fused_attention.block_sizes"
-    doc = ("(block_q, block_k) of the flash kernel. block_k is currently "
-           "pinned to S -- the kernel stages whole K/V rows for one "
-           "(batch, head) in VMEM -- so the live search is over block_q "
-           "(the Q rows per grid step), every candidate dividing S. The "
-           "benches time forward + backward.")
+    doc = ("(block_q, block_k) of the flash kernels: the Q rows a grid step "
+           "and the columns a K tile, every candidate dividing S. One tile "
+           "is the row (block_k = S) for every op; a causal op, whose Q "
+           "blocks skip the tiles above their diagonal, may also take "
+           "narrower tiles. The benches time forward + backward.")
 
     #: every multiple of the kernel's 128 rows up to here that divides S
     MAX_BLOCK_Q = 1024
+    #: the K tiles narrower than S a causal op may take
+    CAUSAL_BLOCK_K = (256, 512, 1024)
 
     def bucket(self, params):
         return _attn_bucket(params)
 
     def candidates(self, params):
         s = int(params["s"])
-        return [(bq, s) for bq in range(128, min(s, self.MAX_BLOCK_Q) + 1, 128)
+        tiles = [s] + [bk for bk in self.CAUSAL_BLOCK_K
+                       if params.get("causal") and bk < s and s % bk == 0]
+        return [(bq, bk) for bk in tiles
+                for bq in range(128, min(s, self.MAX_BLOCK_Q) + 1, 128)
                 if s % bq == 0]
 
     def default(self, params):
-        from ..ops.pallas_attention import default_block_q
-        s = int(params["s"])
-        return (default_block_q(s), s)
+        from ..ops.pallas_attention import default_block_k, default_block_q
+        s, causal = int(params["s"]), bool(params.get("causal"))
+        return (default_block_q(s, causal), default_block_k(s, causal))
 
     def encode(self, candidate):
         return f"{int(candidate[0])},{int(candidate[1])}"
@@ -384,7 +390,7 @@ class FlashBlockSizes(TunableChoice):
         return (int(bq), int(bk))
 
     def bench(self, params, candidate):
-        return _flash_bench(params, int(candidate[0]))
+        return _flash_bench(params, int(candidate[0]), int(candidate[1]))
 
 
 # --------------------------------------------------------------------------------------

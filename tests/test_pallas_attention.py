@@ -151,6 +151,205 @@ def test_flash_default_block_q_divides_s_and_no_other_is_taken():
                       block_q)
 
 
+def _decoder_qkv(S, D, group, dtype, use_bias, B=2, kv=2):
+    ks = jax.random.split(jax.random.PRNGKey(S + D + group), 5)
+    q, g = (jax.random.normal(kk, (B, kv * group, S, D), dtype)
+            for kk in ks[:2])
+    k, v = (jax.random.normal(kk, (B, kv, S, D), dtype) for kk in ks[2:4])
+    bias = jnp.where(jax.random.bernoulli(ks[4], 0.9, (B, 1, 1, S)),
+                     0.0, -1e4).astype(jnp.float32) if use_bias else None
+    return q, k, v, g, bias
+
+
+# (S, block_q, block_k): K tiles narrower than, as wide as and wider than the
+# Q block, S a multiple of neither, one tile (block_k = S) among them
+TILES = [(256, 128, 128), (512, 256, 128), (512, 128, 256), (768, 256, 128),
+         (768, 384, 256), (1024, 256, 512), (1024, 512, 256),
+         (1024, 512, 1024)]
+
+
+@pytest.mark.parametrize("D,group,dtype,use_bias", [
+    (64, 1, jnp.float32, False), (128, 4, jnp.bfloat16, True),
+    (64, 4, jnp.float32, True), (128, 1, jnp.bfloat16, False)])
+@pytest.mark.parametrize("S,block_q,block_k", TILES)
+def test_causal_k_tiles_match_composed(S, block_q, block_k, D, group, dtype,
+                                       use_bias):
+    """Causal forward and gradients with the K axis in tiles (the Q blocks
+    loop over the tiles at or under their diagonal) against the composed
+    lowering in float32, with fewer key/value than query heads too.
+    Tolerances as test_flash_grad_parity derives them, a key/value head's
+    gradient summing over its group's rows too, and doubled in bfloat16: a
+    causal row's first few probabilities are large, not 1/S, so single
+    elements at d=128 lie up to 1.35 times the bound away with one tile a
+    row as with many."""
+    q, k, v, g, bias = _decoder_qkv(S, D, group, dtype, use_bias)
+    scale = D ** -0.5
+
+    def both(attend, *xs):
+        out, vjp = jax.vjp(attend, *xs)
+        return [np.asarray(_f32(x)) for x in (out, *vjp(g.astype(out.dtype)))]
+
+    ref = both(lambda q, k, v: pa.composed_attention(
+        q, k, v, bias, scale, 0.0, True, None), _f32(q), _f32(k), _f32(v))
+    got = both(lambda q, k, v: pa._flash(
+        q, k, v, bias, jnp.int32(7), scale, 0.0, True, True, block_q,
+        block_k), q, k, v)
+    for r, x in zip(ref, got):
+        if dtype == jnp.float32:
+            np.testing.assert_allclose(x, r, atol=5e-5, rtol=1e-4)
+        else:
+            atol = 2.0 ** -8 * (np.sqrt(S * group) * np.sqrt((r * r).mean())
+                                + np.abs(r).max())
+            np.testing.assert_allclose(x, r, atol=atol, rtol=0)
+
+
+@pytest.mark.parametrize("S,block_q,block_k", [
+    (512, 128, 128), (512, 256, 128), (512, 128, 256), (1024, 256, 512)])
+def test_causal_k_tiles_above_the_diagonal_are_not_visited(S, block_q,
+                                                           block_k):
+    """No counter needed: with the K and V rows of every tile wholly above
+    the first Q block's diagonal set to NaN, that block's output and dQ stay
+    finite. A tile computed and masked afterwards gives 0 x NaN there (one
+    tile a row does: the last assertion)."""
+    q, k, v, g, _ = _decoder_qkv(S, 64, 2, jnp.float32, False)
+    first_unvisited = -(-block_q // block_k) * block_k
+    assert pa.k_tiles(S, block_q, block_k, True)[1] > 0
+    rows = jnp.arange(S)[None, None, :, None] >= first_unvisited
+    k, v = (jnp.where(rows, jnp.nan, x) for x in (k, v))
+
+    def first_block(block_k):
+        out, vjp = jax.vjp(lambda q: pa._flash(
+            q, k, v, None, jnp.int32(7), 0.125, 0.0, True, True, block_q,
+            block_k), q)
+        return (np.asarray(out[:, :, :block_q]),
+                np.asarray(vjp(g)[0][:, :, :block_q]))
+
+    out, dq = first_block(block_k)
+    assert np.isfinite(out).all() and np.isfinite(dq).all()
+    assert np.abs(out).max() > 0 and np.abs(dq).max() > 0
+    assert not np.isfinite(first_block(S)[0]).any()
+
+
+def _single_pass(q, k, v, bias, g, scale, block_q):
+    """The kernels as they were before the K axis had tiles (PR 25's
+    bodies, no dropout): one pass over a Q block's [block_q, S] scores.
+    Kept here as the oracle for what one tile a row must still compute, bit
+    for bit: (out, dq, dk, dv)."""
+    import functools
+    from jax.experimental import pallas as pl
+    B, H, S, D = q.shape
+    n_q = S // block_q
+    dot = functools.partial(jax.lax.dot_general,
+                            preferred_element_type=jnp.float32)
+    nt, nn, tn = ((((1,), (1,)), ((), ())), (((1,), (0,)), ((), ())),
+                  (((0,), (0,)), ((), ())))
+
+    def scores(q_ref, k_ref, bias_ref):
+        q_s = q_ref[0] * jnp.asarray(scale, q_ref.dtype)
+        return dot(q_s, k_ref[0], nt) + bias_ref[0].astype(jnp.float32), q_s
+
+    def fwd(q_ref, k_ref, v_ref, bias_ref, o_ref):
+        s, _ = scores(q_ref, k_ref, bias_ref)
+        e = jnp.exp(s - jnp.max(s, axis=-1, keepdims=True))
+        l = jnp.sum(e, axis=-1, keepdims=True)
+        o = dot(e.astype(v_ref.dtype), v_ref[0], nn)
+        o_ref[0] = (o * (1.0 / (l * 1.0))).astype(o_ref.dtype)
+
+    def bwd(q_ref, k_ref, v_ref, bias_ref, do_ref, dq_ref, dk_ref, dv_ref,
+            dkt_acc, dvt_acc):
+        step = pl.program_id(1)
+        dtype = q_ref.dtype
+        s, q_s = scores(q_ref, k_ref, bias_ref)
+        e = jnp.exp(s - jnp.max(s, axis=-1, keepdims=True))
+        p = e * (1.0 / jnp.sum(e, axis=-1, keepdims=True))
+        do = do_ref[0]
+        dp = dot(do, v_ref[0], nt)
+        row = jnp.sum(dp * p, axis=-1, keepdims=True)
+        ds = (p * (dp - row)).astype(dtype)
+        dq_ref[0] = (dot(ds, k_ref[0], nn) * (1.0 * scale)).astype(
+            dq_ref.dtype)
+
+        @pl.when(step == 0)
+        def _():
+            dkt_acc[...] = jnp.zeros_like(dkt_acc)
+            dvt_acc[...] = jnp.zeros_like(dvt_acc)
+        dkt_acc[...] += dot(q_s, ds, tn)
+        dvt_acc[...] += dot(do, p.astype(dtype), tn)
+
+        @pl.when(step == pl.num_programs(1) - 1)
+        def _():
+            dk_ref[0] = (dkt_acc[...] * 1.0).T.astype(dk_ref.dtype)
+            dv_ref[0] = (dvt_acc[...] * 1.0).T.astype(dv_ref.dtype)
+
+    from jax.experimental.pallas import tpu as pltpu
+    qspec = pl.BlockSpec((1, block_q, D), lambda b, i: (b, i, 0))
+    kvspec = pl.BlockSpec((1, S, D), lambda b, i: (b, 0, 0))
+    bspec = pl.BlockSpec((1, 1, S), lambda b, i: (b // H, 0, 0))
+    flat = [x.reshape(B * H, S, D) for x in (q, k, v)] + [
+        bias.reshape(B, 1, S)]
+    shape = jax.ShapeDtypeStruct((B * H, S, D), q.dtype)
+    out = pl.pallas_call(
+        fwd, grid=(B * H, n_q), in_specs=[qspec, kvspec, kvspec, bspec],
+        out_specs=qspec, out_shape=shape, interpret=True)(*flat)
+    grads = pl.pallas_call(
+        bwd, grid=(B * H, n_q),
+        in_specs=[qspec, kvspec, kvspec, bspec, qspec],
+        out_specs=[qspec, kvspec, kvspec], out_shape=[shape] * 3,
+        scratch_shapes=[pltpu.VMEM((D, S), jnp.float32)] * 2,
+        interpret=True)(*flat, g.reshape(B * H, S, D))
+    return [x.reshape(B, H, S, D) for x in (out, *grads)]
+
+
+@pytest.mark.parametrize("dtype", [jnp.float32, jnp.bfloat16])
+@pytest.mark.parametrize("S,block_q", [(256, 256), (512, 128), (1024, 256)])
+def test_one_tile_a_row_is_the_single_pass_it_was(S, block_q, dtype):
+    """Without `causal` the K tile is the row (default_block_k), and the
+    kernels then compute what they computed before the K axis had tiles:
+    output and gradients equal the single-pass bodies' bit for bit, on a
+    padding bias and at a power-of-two scale as BERT's cells run them."""
+    assert pa.default_block_k(S) == S and pa.default_block_k(S, True) in (
+        S, pa.CAUSAL_BLOCKS[1])
+    q, k, v, bias = _qkv(S=S, D=64, dtype=dtype)
+    g = jax.random.normal(jax.random.PRNGKey(1), q.shape, dtype)
+    want = _single_pass(q, k, v, bias, g, 0.125, block_q)
+    out, vjp = jax.vjp(lambda q, k, v: pa._flash(
+        q, k, v, bias, jnp.int32(7), 0.125, 0.0, False, True, block_q), q, k,
+        v)
+    for w, x in zip(want, (out, *vjp(g))):
+        assert x.dtype == w.dtype
+        np.testing.assert_array_equal(np.asarray(_f32(x)), np.asarray(_f32(w)))
+
+
+@pytest.mark.parametrize("S,block_q,block_k,causal,want", [
+    (4096, 256, 256, True, (136, 120)), (4096, 512, 1024, True, (20, 12)),
+    (4096, 512, 512, True, (36, 28)), (4096, 256, 4096, True, (16, 0)),
+    (512, 512, 512, False, (1, 0)), (2048, 256, 2048, False, (8, 0)),
+    (1024, 256, 256, False, (16, 0))])
+def test_k_tiles_counts_what_the_q_blocks_visit(S, block_q, block_k, causal,
+                                                want):
+    """The figures attention_k_tiles_total adds for one op: a causal op's Q
+    blocks leave out the tiles wholly above their diagonal, one tile a row
+    leaves out none."""
+    assert pa.k_tiles(S, block_q, block_k, causal) == want
+    assert sum(want) == (S // block_q) * (S // block_k)
+
+
+@pytest.mark.parametrize("S", [1024, 1536, 2048, 2560, 4096])
+def test_default_blocks_are_chosen_from_causal_and_s(S):
+    """One tile a row without `causal` at every S, and with it below
+    CAUSAL_TILES_MIN_S or where the pair does not divide S; CAUSAL_BLOCKS
+    from there. Both always divide S."""
+    assert pa.default_block_k(S) == S
+    assert pa.default_block_q(S) == pa.BLK_Q
+    tiled = S >= pa.CAUSAL_TILES_MIN_S and S % 1024 == 0
+    assert (pa.default_block_q(S, True), pa.default_block_k(S, True)) == (
+        pa.CAUSAL_BLOCKS if tiled else (pa.BLK_Q, S))
+    with pytest.raises(ValueError, match="block_k"):
+        q, k, v, _ = _qkv(S=256)
+        pa._flash(q, k, v, None, jnp.int32(7), 0.125, 0.0, True, True, 128,
+                  192)
+
+
 def test_flash_bf16_close():
     q, k, v, _ = _qkv(dtype=jnp.bfloat16)
     ref = pa.composed_attention(q, k, v, None, 0.125, 0.0, False,
@@ -217,9 +416,9 @@ def test_bert_program_parity_fused_vs_composed():
     assert losses["pallas"][1] < losses["pallas"][0]  # it actually trains
 
 
-@pytest.mark.parametrize("S,dp,want", [(128, 1, ("xla", "0")),
-                                       (256, 1, ("pallas", "256")),
-                                       (256, 2, ("xla", "0"))])
+@pytest.mark.parametrize("S,dp,want", [(128, 1, ("xla", "0", "0")),
+                                       (256, 1, ("pallas", "256", "256")),
+                                       (256, 2, ("xla", "0", "0"))])
 def test_executor_counts_the_lowering_each_attention_op_took(S, dp, want):
     """impl='auto' with no tuning decision: XLA's lowering at S=128, the
     kernels at one Q block a head from S=256, and XLA's again where the step
@@ -246,9 +445,16 @@ def test_executor_counts_the_lowering_each_attention_op_took(S, dp, want):
     def counts():
         fam = REGISTRY.get("attention_lowering_total")
         return {} if fam is None else {
-            (dict(k)["impl"], dict(k)["block_q"], dict(k)["s"]): c.value
+            (dict(k)["impl"], dict(k)["block_q"], dict(k)["block_k"],
+             dict(k)["s"]): c.value
             for k, c in fam.items()}
-    before = counts()
+
+    def tiles():
+        out = {"visited": 0, "skipped": 0}
+        for k, c in (REGISTRY.get("attention_k_tiles_total") or {}).items():
+            out[dict(k)["state"]] += c.value
+        return out
+    before, tiles_before = counts(), tiles()
     exe = fluid.Executor()
     with fluid.scope_guard(fluid.Scope()):
         exe.run(startup)
@@ -258,6 +464,9 @@ def test_executor_counts_the_lowering_each_attention_op_took(S, dp, want):
     grown = {k: v - before.get(k, 0) for k, v in after.items()
              if v != before.get(k, 0)}
     assert grown == {want + (str(S),): 1}
+    # the one op's forward kernel: one Q block, one K tile, none left out
+    assert {k: v - tiles_before[k] for k, v in tiles().items()} == {
+        "visited": int(want[0] == "pallas"), "skipped": 0}
 
 
 def test_clone_for_test_disables_attention_dropout():

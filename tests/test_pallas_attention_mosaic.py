@@ -47,11 +47,12 @@ def _kernels(compiled):
 
 
 # (batch, heads, S, head dim, dtype, dropout, causal, bias), each at the
-# block_q the op takes by default: bert_base.pretrain_s512's attention (one Q
+# blocks the op takes by default: bert_base.pretrain_s512's attention (one Q
 # block a head), the same tokens at the shortest S that 'auto' gives the
-# kernels, and at S=384; bert_base.pretrain_s2048's attention; the longest S the whole-row kernel
-# takes at BLK_Q; f32 inputs; olmoe_1b_7b.pretrain_s4096's (its second
-# shape: causal, no bias, no dropout, d=128) at 2 and at 4 sequences
+# kernels, and at S=384; bert_base.pretrain_s2048's attention; the longest S
+# one K tile a row takes at BLK_Q; f32 inputs, causal, in K tiles;
+# olmoe_1b_7b.pretrain_s4096's (its second shape: causal, no bias, no
+# dropout, d=128; CAUSAL_BLOCKS' tiles) at 2 and at 4 sequences
 CASES = [(32, 12, 512, 64, jnp.bfloat16, 0.1, False, True),
          (64, 12, 256, 64, jnp.bfloat16, 0.1, False, True),
          (42, 12, 384, 64, jnp.bfloat16, 0.1, False, True),
@@ -86,7 +87,8 @@ def test_flash_compiles_for_v5e(one_chip, B, H, S, D, dtype, dropout, causal,
                                                 (2, 0.1, True)])
 def test_grouped_query_flash_compiles_for_v5e(one_chip, B, dropout, use_bias):
     """lfm2_8b_a1b.pretrain_s4096's attention: 32 query heads over 8
-    key/value heads of 64 at S=4096, causal. The forward reads a key/value
+    key/value heads of 64 at S=4096, causal, at the default tiles (and with
+    a bias and a dropout mask by tile). The forward reads a key/value
     head's rows in place (its block index is the query head's // 4) and the
     backward's grid runs over a key/value head's four query heads, so dK and
     dV leave at the key/value heads' shape: one kernel each way, and no
@@ -105,6 +107,38 @@ def test_grouped_query_flash_compiles_for_v5e(one_chip, B, dropout, use_bias):
 
     assert _kernels(jax.jit(attend).lower(q, k, k, bias).compile()) == 1
     back = jax.jit(grads).lower(q, k, k, bias, q)
+    assert [tuple(o.shape) for o in back.out_info] == [
+        (B, H, S, D), (B, kv, S, D), (B, kv, S, D)]
+    assert _kernels(back.compile()) == 1
+
+
+@pytest.mark.parametrize("block_q,block_k", [
+    pa.CAUSAL_BLOCKS, (512, 512), (256, 256), (256, 4096)])
+@pytest.mark.parametrize("H,kv,D", [(16, 16, 128), (32, 8, 64)])
+def test_causal_flash_compiles_for_v5e_in_k_tiles(one_chip, H, kv, D, block_q,
+                                                  block_k):
+    """The two decoder cells' attention (4 x 4096 tokens, causal, bf16:
+    olmoe_1b_7b's 16 heads of 128, lfm2_8b_a1b's 32 query over 8 key/value
+    heads of 64) at the tiles the op takes by default, at two narrower pairs
+    and at one tile a row (what a persisted decision may still choose): the
+    loops of dynamic trip count, the staged score tiles and dK^T / dV^T by
+    tile fit Mosaic's VMEM, and a grad op still holds the backward kernel
+    alone (no residual out of the forward)."""
+    B, S = 4, 4096
+    assert (pa.default_block_q(S, True),
+            pa.default_block_k(S, True)) == pa.CAUSAL_BLOCKS
+    q = jax.ShapeDtypeStruct((B, H, S, D), jnp.bfloat16, sharding=one_chip)
+    k = jax.ShapeDtypeStruct((B, kv, S, D), jnp.bfloat16, sharding=one_chip)
+
+    def attend(q, k, v):
+        return pa._flash(q, k, v, None, jnp.int32(3), D ** -0.5, 0.0, True,
+                         False, block_q, block_k)
+
+    def grads(q, k, v, g):
+        return jax.vjp(attend, q, k, v)[1](g)
+
+    assert _kernels(jax.jit(attend).lower(q, k, k).compile()) == 1
+    back = jax.jit(grads).lower(q, k, k, q)
     assert [tuple(o.shape) for o in back.out_info] == [
         (B, H, S, D), (B, kv, S, D), (B, kv, S, D)]
     assert _kernels(back.compile()) == 1

@@ -286,6 +286,29 @@ def test_stale_cached_decision_falls_back_to_default(tune_cache, monkeypatch):
     assert tuning.decide("conv2d_bn_fused.backend", CONVBN) == "pallas"
 
 
+@pytest.mark.parametrize("s,want", [(512, (512, 512)), (1024, (256, 1024)),
+                                    (1536, (256, 1536)), (2048, (512, 1024)),
+                                    (4096, (512, 1024))])
+def test_a_causal_op_may_take_k_tiles_narrower_than_s(tune_cache, s, want):
+    """block_k is live: a causal op's candidates hold the K tiles narrower
+    than S beside one tile a row, its default is the kernels' measured pair
+    (from CAUSAL_TILES_MIN_S up; one tile below), and an op without `causal`
+    keeps one tile a row in both."""
+    ch = tchoices.get_choice("fused_attention.block_sizes")
+    op = dict(_bert_op(s), h=16, d=128, has_bias=False, dropout=0.0)
+    causal = dict(op, causal=True)
+    assert ch.default(causal) == want and want in ch.candidates(causal)
+    assert tuning.decide("fused_attention.block_sizes", causal,
+                         mode="off") == want
+    assert {bk for _, bk in ch.candidates(op)} == {s}
+    assert ch.default(op)[1] == s
+    assert {bk for _, bk in ch.candidates(causal)} == {s} | {
+        bk for bk in (256, 512, 1024) if bk < s and s % bk == 0}
+    assert all(s % bq == 0 and s % bk == 0
+               for bq, bk in ch.candidates(causal))
+    assert ch.decode(ch.encode(want)) == want
+
+
 def test_block_size_candidates_divide_s():
     ch = tchoices.get_choice("fused_attention.block_sizes")
     assert ch.candidates({"b": 1, "h": 1, "s": 2048, "d": 64}) == \
