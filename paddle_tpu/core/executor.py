@@ -724,6 +724,11 @@ class Executor:
         from ..observability import masks as _obs_masks
         _obs_masks.count_draws(
             program._lowering_notes.pop("mask_draw", {}), label)
+        from ..observability import ssm as _obs_ssm
+        _obs_ssm.update_ssm_gauges(program, label)
+        _obs_ssm.count_lowerings(
+            program._lowering_notes.pop("ssd_scan", {}),
+            program._lowering_notes.pop("short_conv", {}), label)
         # IR->HLO attribution walk: once per compile miss, only when obs /
         # PADDLE_TPU_OBS_ATTRIB / an armed --emit-hlo capture asks for it
         # (on_compile is a no-op otherwise and never raises)

@@ -1111,21 +1111,49 @@ def swiglu(gate, up, row_scale=None, name=None):
     return _var(helper, out)
 
 
-def short_conv(x, seq, kernel_size=3, param_attr=None, name=None):
-    """The gated short convolution of a hybrid decoder layer, between its two
-    projections: ``x [T, 3C]`` holds the gates ``B | C`` and the signal ``u``
-    side by side; returns ``C * conv(B * u) [T, C]`` with one causal filter
-    of ``kernel_size`` taps a channel (parameter ``[C, kernel_size]``) over
-    sequences of ``seq`` consecutive rows, zeros before each sequence's
-    start (``ops/decoder_ops.py:short_conv``, which lowers the Pallas
-    kernels on a TPU and the composed form elsewhere)."""
+def short_conv(x, seq, kernel_size=3, param_attr=None, name=None,
+               bias_attr=False, gated=True, activation=None):
+    """The short causal convolution of a hybrid decoder layer, between its
+    two projections, one depthwise filter of ``kernel_size`` taps a channel
+    (parameter ``[C, kernel_size]``) over sequences of ``seq`` consecutive
+    rows, zeros before each sequence's start. ``gated`` (LFM2's): ``x [T,
+    3C]`` holds the gates ``B | C`` and the signal ``u`` side by side;
+    returns ``C * conv(B * u) [T, C]``. Not gated (a Mamba mixer's): ``x [T,
+    C]``, returns ``conv(x)``. ``bias_attr`` (not False) adds a bias ``[C]``
+    to the filter's output and ``activation`` (``"silu"``) follows it
+    (``ops/decoder_ops.py:short_conv``, which lowers the Pallas kernels on a
+    TPU and the composed form elsewhere)."""
     helper = LayerHelper("short_conv", name=name)
-    w = helper.create_parameter(
-        param_attr, [int(x.shape[-1]) // 3, int(kernel_size)], x.dtype)
+    chan = int(x.shape[-1]) // 3 if gated else int(x.shape[-1])
+    w = helper.create_parameter(param_attr, [chan, int(kernel_size)], x.dtype)
+    inputs = {"X": [x], "W": [w]}
+    if bias_attr is not False:
+        inputs["Bias"] = [helper.create_parameter(
+            bias_attr, [chan], x.dtype, is_bias=True)]
     out = _out(helper, x.dtype)
-    helper.append_op("short_conv", inputs={"X": [x], "W": [w]},
-                     outputs={"Out": [out]},
-                     attrs={"seq": int(seq)})
+    helper.append_op("short_conv", inputs=inputs, outputs={"Out": [out]},
+                     attrs={"seq": int(seq), "gated": bool(gated),
+                            "activation": activation or ""})
+    return _var(helper, out)
+
+
+def ssd_scan(x, dt, a, b, c, d, chunk=256, impl="auto", name=None):
+    """The state-space scan of a Mamba-2 layer, a head at a time with state
+    ``h [N, P]``: ``h_t = exp(dt_t a) h_{t-1} + b_t (x) (dt_t x_t)``, ``y_t =
+    c_t h_t + d x_t``, from a zero state at each sequence's start. ``x [B, S,
+    heads, P]``, ``dt [B, S, heads]`` (positive), ``a [heads]`` (negative),
+    ``b`` / ``c [B, S, N]`` shared by the heads, ``d [heads]``; returns ``y``
+    like ``x``. Computed in chunks of ``chunk`` positions
+    (``ops/decoder_ops.py:ssd_scan``: under ``impl="auto"`` the Pallas
+    kernels on a TPU where they take the shapes, the composed chunked form
+    elsewhere; ``"pallas"`` / ``"composed"`` force one)."""
+    helper = LayerHelper("ssd_scan", name=name)
+    out = _out(helper, x.dtype)
+    helper.append_op("ssd_scan",
+                     inputs={"X": [x], "Dt": [dt], "A": [a], "B": [b],
+                             "C": [c], "D": [d]},
+                     outputs={"Y": [out]},
+                     attrs={"chunk": int(chunk), "impl": impl})
     return _var(helper, out)
 
 

@@ -1,29 +1,37 @@
 """A decoder-only language model built from a config dict that carries the
 key names of a published ``config.json`` (the catalog's names): token
-embedding -> ``num_hidden_layers`` x block -> final RMSNorm -> untied output
-projection -> next-token cross-entropy, plus the router losses where the
-config names their coefficients.
+embedding -> ``num_hidden_layers`` x block -> final RMSNorm -> output
+projection (its own matrix, or the embedding table under
+``tie_word_embeddings``) -> next-token cross-entropy, plus the router losses
+where the config names their coefficients.
 
 One builder for the decoder families (ROADMAP D6); what a config asks for and
 this file does not build yet raises by name (``_check``). A block is
-pre-norm, without biases or dropout: ``h = x + operator(norm(x))``,
-``y = h + feed_forward(norm(h))``. What it builds, by config key:
+pre-norm, without biases or dropout: ``h = x + r operator(norm(x))``,
+``y = h + r feed_forward(norm(h))`` with ``r`` = ``residual_multiplier``
+(default 1). What it builds, by config key:
 
 - ``layer_types`` (default: every layer ``full_attention``), one operator a
-  layer. ``full_attention``: separate q / k / v projections with
+  layer. ``full_attention`` (also spelt ``attention``): separate q / k / v
+  projections with
   ``num_key_value_heads`` key/value heads (grouped-query attention where
   fewer than ``num_attention_heads``; ``fused_attention`` reads them in
   place), RMSNorm of q and k -- ``qk_norm`` ``"projection"``: over the whole
   projected q and k before the split into heads, one scale an element
   (OLMoE); ``"head"``: over each head's values, one scale of head size
-  shared by the heads (LFM2) --, rotary embedding (rotate-half,
-  ``rope_theta``) on q and k, causal ``fused_attention`` at scale
-  1/sqrt(head dim), ``impl="auto"``. ``conv``: the gated short convolution
-  ``W_out (C * conv(B * u))`` with ``B, C, u = split(W_in x, 3)`` and a
-  causal depthwise filter of ``conv_L_cache`` taps (``layers.short_conv``).
-- feed-forward: the first ``num_dense_layers`` layers (default 0) a dense
+  shared by the heads (LFM2); ``"none"``: no norm (Granite) --, rotary
+  embedding (rotate-half, ``rope_theta``) on q and k unless
+  ``position_embedding_type`` is ``"nope"``, causal ``fused_attention`` at
+  scale ``attention_multiplier`` (default 1/sqrt(head dim)),
+  ``impl="auto"``. ``conv``: the gated short convolution ``W_out (C * conv(B
+  * u))`` with ``B, C, u = split(W_in x, 3)`` and a causal depthwise filter
+  of ``conv_L_cache`` taps (``layers.short_conv``). ``mamba``: the Mamba-2
+  mixer (``mamba``, below).
+- feed-forward: the first ``num_dense_layers`` layers (default 0), and every
+  layer of a config without experts (``num_local_experts: 0``), a dense
   SwiGLU ``W_down (silu(W_gate x) * (W_up x))`` of width
-  ``intermediate_size``; the others ``layers.moe_ffn`` of width
+  ``intermediate_size`` (``shared_intermediate_size`` where the config has
+  that key); the others ``layers.moe_ffn`` of width
   ``moe_intermediate_size`` (``intermediate_size`` where the config has no
   such key). ``router_scoring`` ``"softmax"`` (default): float32 router,
   softmax then top-k with the values used as they are; ``"sigmoid"``:
@@ -35,6 +43,10 @@ pre-norm, without biases or dropout: ``h = x + operator(norm(x))``,
   router's width and ``first_expert_held`` (default 0) the first held; the
   layer's output is the held experts' part (``layers.moe_ffn``). A sliced
   vocabulary is a smaller ``vocab_size``.
+- ``embedding_multiplier`` times the looked-up rows, and the logits over
+  ``logits_scaling`` (both default 1); under ``tie_word_embeddings`` the
+  logits are ``x tok_emb^T`` from the one float32 table, cast to ``dtype``
+  for the product.
 - loss: mean next-token cross-entropy, + ``router_aux_loss_coef`` x the
   load-balancing loss (experts x sum over experts of the share of
   assignments an expert received x its mean router probability, a layer,
@@ -45,26 +57,31 @@ pre-norm, without biases or dropout: ``h = x + operator(norm(x))``,
   appended by the caller after ``minimize``.
 
 Models through it: OLMoE-1B-7B (Muennighoff et al., arXiv:2409.02060; HF
-``modeling_olmoe.py``) and LFM2-8B-A1B (HF ``modeling_lfm2_moe.py``).
+``modeling_olmoe.py``), LFM2-8B-A1B (HF ``modeling_lfm2_moe.py``) and
+granite-4.0-h-micro (HF ``modeling_granitemoehybrid.py``; the scan: Dao &
+Gu, arXiv:2405.21060).
 
 Dtypes follow ``models/bert.py``: the embedding table is float32 whatever
 ``dtype`` says, activations are cast to ``dtype`` right after the lookup,
-weights are created in ``dtype``; RMSNorm, the router, the short convolution
-and every softmax compute in float32 inside their ops; the logits are cast
-up for the loss.
+weights are created in ``dtype`` (a Mamba mixer's ``A_log``, ``D`` and
+``dt_bias``, one number a head, in float32); RMSNorm, the router, the short
+convolution, the scan's decays and state and every softmax compute in
+float32 inside their ops; the logits are cast up for the loss.
 """
 from __future__ import annotations
 
 import math
 
 from .. import layers
-from ..initializer import Normal
+from ..framework import default_startup_program
+from ..initializer import Constant, Initializer, Normal, Uniform
 from ..layer_helper import ParamAttr
 
-_REQUIRED = {"hidden_act": "silu", "tie_word_embeddings": False,
-             "attention_bias": False, "clip_qkv": None, "rope_scaling": None,
-             "conv_bias": False}
-_OPERATORS = ("full_attention", "conv")
+_REQUIRED = {"hidden_act": "silu", "attention_bias": False,
+             "clip_qkv": None, "rope_scaling": None, "conv_bias": False,
+             "mamba_proj_bias": False, "mamba_n_groups": 1,
+             "normalization_function": "rmsnorm"}
+_OPERATORS = ("full_attention", "conv", "mamba")
 
 
 def _check(cfg: dict) -> None:
@@ -82,17 +99,30 @@ def _check(cfg: dict) -> None:
             raise NotImplementedError(
                 f"decoder_lm: layer type {kind!r} is not built yet (only "
                 f"{_OPERATORS}: no sliding-window or chunked attention, no "
-                f"latent attention, no linear-attention scan)")
+                f"latent attention, no gated delta rule)")
+    if "mamba" in kinds and (
+            cfg["mamba_n_heads"] * cfg["mamba_d_head"]
+            != cfg["mamba_expand"] * cfg["hidden_size"]):
+        raise ValueError("mamba_n_heads x mamba_d_head must equal "
+                         "mamba_expand x hidden_size")
     if cfg["hidden_size"] % cfg["num_attention_heads"] or \
             cfg["num_attention_heads"] % _kv_heads(cfg):
         raise ValueError("hidden_size must be a multiple of the head count, "
                          "the head count of num_key_value_heads")
-    if cfg.get("qk_norm", "projection") not in ("projection", "head"):
+    if cfg.get("qk_norm", "projection") not in ("projection", "head",
+                                                 "none"):
         raise NotImplementedError(
             f"decoder_lm: qk_norm={cfg['qk_norm']!r} is not built yet")
-    if cfg.get("n_shared_experts") or cfg.get("num_shared_experts"):
+    if cfg.get("position_embedding_type", "rope") not in ("rope", "nope"):
         raise NotImplementedError(
-            "decoder_lm: shared experts are not built yet")
+            f"decoder_lm: position_embedding_type="
+            f"{cfg['position_embedding_type']!r} is not built yet (rotary "
+            f"or none)")
+    if (cfg.get("n_shared_experts") or cfg.get("num_shared_experts")
+            or cfg.get("num_local_experts")):
+        raise NotImplementedError(
+            "decoder_lm: shared experts (a dense feed-forward beside the "
+            "routed experts of a layer) are not built yet")
     sigmoid = cfg.get("router_scoring", "softmax") == "sigmoid"
     if not sigmoid and (cfg.get("norm_topk_prob") or cfg.get(
             "use_expert_bias") or cfg.get("routed_scaling_factor", 1) != 1):
@@ -108,8 +138,9 @@ def _check(cfg: dict) -> None:
 
 
 def _layer_types(cfg: dict) -> list:
-    return list(cfg.get("layer_types")
-                or ["full_attention"] * cfg["num_hidden_layers"])
+    kinds = cfg.get("layer_types") or (
+        ["full_attention"] * cfg["num_hidden_layers"])
+    return ["full_attention" if k == "attention" else k for k in kinds]
 
 
 def _kv_heads(cfg: dict) -> int:
@@ -135,28 +166,31 @@ def attention(x, cfg: dict, batch: int, seq: int, name: str):
                           _kv_heads(cfg))
     d = H // heads
     eps = _eps(cfg)
-    by_head = cfg.get("qk_norm", "projection") == "head"
+    norm = cfg.get("qk_norm", "projection")
+    by_head, whole = norm == "head", norm == "projection"
+    rotary = cfg.get("position_embedding_type", "rope") == "rope"
 
-    def heads_of(t, n, norm_w=None):                # [B*S, n*d] -> [B, n, S, d]
-        t = layers.reshape(t, [batch, seq, n, d])
+    def heads_of(t, n, norm_w=None, positions=rotary):
+        t = layers.reshape(t, [batch, seq, n, d])   # [B*S, n*d] -> [B, n, S, d]
         if norm_w:
             t = layers.rms_norm(t, eps, ParamAttr(name=norm_w))
-        return layers.transpose(t, [0, 2, 1, 3])
+        t = layers.transpose(t, [0, 2, 1, 3])
+        return layers.rotary_embedding(t, cfg["rope_theta"]) if positions \
+            else t
 
     q = _linear(x, H, name + "_q_w")
-    if not by_head:
+    if whole:
         q = layers.rms_norm(q, eps, ParamAttr(name=name + "_q_norm_w"))
     k = _linear(x, kv_heads * d, name + "_k_w")
-    if not by_head:
+    if whole:
         k = layers.rms_norm(k, eps, ParamAttr(name=name + "_k_norm_w"))
     v = _linear(x, kv_heads * d, name + "_v_w")
-    q = layers.rotary_embedding(
-        heads_of(q, heads, by_head and name + "_q_norm_w"), cfg["rope_theta"])
-    k = layers.rotary_embedding(
-        heads_of(k, kv_heads, by_head and name + "_k_norm_w"),
-        cfg["rope_theta"])
-    ctx = layers.fused_attention(q, k, heads_of(v, kv_heads), causal=True,
-                                 scale=1.0 / math.sqrt(d), impl="auto")
+    ctx = layers.fused_attention(
+        heads_of(q, heads, name + "_q_norm_w" if by_head else None),
+        heads_of(k, kv_heads, name + "_k_norm_w" if by_head else None),
+        heads_of(v, kv_heads, positions=False), causal=True,
+        scale=float(cfg.get("attention_multiplier", 1.0 / math.sqrt(d))),
+        impl="auto")
     ctx = layers.reshape(layers.transpose(ctx, [0, 2, 1, 3]),
                          [batch * seq, H])
     return _linear(ctx, H, name + "_o_w")
@@ -168,6 +202,85 @@ def short_conv(x, cfg: dict, seq: int, name: str):
     mixed = layers.short_conv(_linear(x, 3 * H, name + "_in_w"), seq,
                               cfg["conv_L_cache"], _attr(name + "_w"))
     return _linear(mixed, H, name + "_out_w")
+
+
+class _Drawn(Initializer):
+    """A uniform draw in ``[low, high]`` followed by unary ops, in place, in
+    the startup program: the Mamba mixer's per-head parameters, whose usual
+    start is a function of a uniform draw (``mamba``)."""
+
+    def __init__(self, low: float, high: float, then: list):
+        self.low, self.high, self.then = low, high, then
+
+    def __call__(self, var, block=None):
+        block = block or default_startup_program().global_block()
+        block.create_var(var.name, var.shape, var.dtype, persistable=True)
+        block.append_op("uniform_random", outputs={"Out": [var.name]},
+                        attrs={"shape": list(var.shape), "dtype": var.dtype,
+                               "min": self.low, "max": self.high, "seed": 0})
+        for kind, attrs in self.then:
+            block.append_op(kind, inputs={"X": [var.name]},
+                            outputs={"Out": [var.name]}, attrs=attrs)
+
+
+def mamba(x, cfg: dict, batch: int, seq: int, name: str):
+    """The Mamba-2 mixer over ``x [batch * seq, H]`` (HF
+    ``GraniteMoeHybridMambaLayer``; Dao & Gu, arXiv:2405.21060): ``[z | xBC |
+    dt] = W_in x``; ``xBC = silu(conv(xBC) + b)``, a causal depthwise filter
+    of ``mamba_d_conv`` taps; ``[x | B | C] = xBC`` with B and C (``mamba_d_
+    state`` wide) shared by the heads; ``dt = softplus(dt + dt_bias)``, ``A =
+    -exp(A_log)``; per head ``h_t = exp(dt_t A) h_{t-1} + B_t (x) dt_t x_t``,
+    ``y_t = C_t h_t + D x_t`` (``layers.ssd_scan``, in chunks of ``mamba_
+    chunk_size``, lowered as ``ssd_scan_impl`` says, default ``auto``);
+    ``W_out rmsnorm(y * silu(z))``, the norm over all heads' values.
+    ``A_log``, ``D`` and ``dt_bias`` are float32 and start as mamba_ssm's
+    ``Mamba2`` starts them: A uniform in [1, 16], D one, dt log-uniform in
+    [1e-3, 1e-1] through the inverse softplus (HF's constructor writes
+    ``log(1..heads)`` and ones, which a checkpoint overwrites: with dt near
+    1.3 and A up to 64 no state would outlive a few positions); the filter
+    and its bias uniform in +-1/sqrt(taps), a depthwise Conv1d's start in
+    both (at the projections' std of 0.02 x, B and C would be a hundredth of
+    z and the scan's part of y lost under ``D x``)."""
+    heads, p, n = (cfg["mamba_n_heads"], cfg["mamba_d_head"],
+                   cfg["mamba_d_state"])
+    inner = heads * p
+    z, xbc, dt = layers.split(
+        _linear(x, 2 * inner + 2 * n + heads, name + "_in_w"),
+        [inner, inner + 2 * n, heads], dim=-1)
+    taps = cfg["mamba_d_conv"]
+
+    def conv_attr(suffix):      # a depthwise Conv1d's usual start
+        return ParamAttr(name=name + suffix, initializer=Uniform(
+            -1.0 / math.sqrt(taps), 1.0 / math.sqrt(taps)))
+    xbc = layers.short_conv(
+        xbc, seq, taps, conv_attr("_conv_w"),
+        bias_attr=(conv_attr("_conv_b") if cfg.get("mamba_conv_bias")
+                   else False),
+        gated=False, activation="silu")
+    xs, b, c = layers.split(xbc, [inner, n, n], dim=-1)
+
+    def per_head(suffix, initializer):
+        return layers.create_parameter(
+            [heads], "float32", name=name + suffix,
+            default_initializer=initializer)
+    dt_bias = per_head("_dt_bias", _Drawn(
+        math.log(1e-3), math.log(1e-1),
+        [("exp", {}), ("exp", {}), ("scale", {"scale": 1.0, "bias": -1.0}),
+         ("log", {})]))                 # log(exp(dt) - 1), dt = exp(draw)
+    a_log = per_head("_A_log", _Drawn(1.0, 16.0, [("log", {})]))
+    d = per_head("_D", Constant(1.0))
+    dt = layers.softplus(layers.elementwise_add(
+        layers.cast(dt, "float32"), dt_bias))
+    y = layers.ssd_scan(
+        layers.reshape(xs, [batch, seq, heads, p]),
+        layers.reshape(dt, [batch, seq, heads]),
+        layers.scale(layers.exp(a_log), -1.0),
+        layers.reshape(b, [batch, seq, n]), layers.reshape(c, [batch, seq, n]),
+        d, chunk=cfg["mamba_chunk_size"], impl=cfg.get("ssd_scan_impl", "auto"))
+    y = layers.rms_norm(
+        layers.swiglu(z, layers.reshape(y, [batch * seq, inner])), _eps(cfg),
+        ParamAttr(name=name + "_gated_norm_w"))
+    return _linear(y, cfg["hidden_size"], name + "_out_w")
 
 
 def experts(x, cfg: dict, name: str):
@@ -192,22 +305,29 @@ def block(x, cfg: dict, batch: int, seq: int, name: str,
     ``kind``; returns the layer's output and the router's variables
     (``layers.moe_ffn``; None for a ``dense`` feed-forward layer)."""
     eps = _eps(cfg)
-    op_name = name + ("_conv" if kind == "conv" else "_attn")
+    r = float(cfg.get("residual_multiplier", 1.0))
+
+    def add(h, branch):
+        return layers.elementwise_add(
+            h, branch if r == 1.0 else layers.scale(branch, r))
+    op_name = name + {"conv": "_conv", "mamba": "_mamba"}.get(kind, "_attn")
     normed = layers.rms_norm(x, eps, ParamAttr(name=op_name + "_norm_w"))
     if kind == "conv":
         mixed = short_conv(normed, cfg, seq, op_name)
+    elif kind == "mamba":
+        mixed = mamba(normed, cfg, batch, seq, op_name)
     else:
         mixed = attention(normed, cfg, batch, seq, op_name)
-    h = layers.elementwise_add(x, mixed)
+    h = add(x, mixed)
     normed = layers.rms_norm(h, eps, ParamAttr(name=name + "_ffn_norm_w"))
     if dense:
-        width = cfg["intermediate_size"]
+        width = cfg.get("shared_intermediate_size", cfg["intermediate_size"])
         gated = layers.swiglu(_linear(normed, width, name + "_ffn_gate_w"),
                               _linear(normed, width, name + "_ffn_up_w"))
-        return layers.elementwise_add(
-            h, _linear(gated, cfg["hidden_size"], name + "_ffn_down_w")), None
+        return add(h, _linear(gated, cfg["hidden_size"],
+                              name + "_ffn_down_w")), None
     moe, aux = experts(normed, cfg, name + "_moe")
-    return layers.elementwise_add(h, moe), aux
+    return add(h, moe), aux
 
 
 def balance_experts(out: dict, rate: float) -> None:
@@ -244,13 +364,16 @@ def build(cfg: dict, ids, labels) -> dict:
     dtype = cfg.get("dtype", "float32")
     x = layers.embedding(ids, [cfg["vocab_size"], H], dtype="float32",
                          param_attr=_attr("tok_emb"))
+    if cfg.get("embedding_multiplier", 1) != 1:
+        x = layers.scale(x, float(cfg["embedding_multiplier"]))
     if dtype != "float32":
         x = layers.cast(x, dtype)
     x = layers.reshape(x, [batch * seq, H])
     balance, z, loads, indices, biases = [], [], [], [], []
     for i, kind in enumerate(_layer_types(cfg)):
         x, aux = block(x, cfg, batch, seq, f"layer{i}", kind,
-                       dense=i < cfg.get("num_dense_layers", 0))
+                       dense=(i < cfg.get("num_dense_layers", 0)
+                              or "num_experts" not in cfg))
         if aux is None:
             continue
         if router_losses:
@@ -267,9 +390,17 @@ def build(cfg: dict, ids, labels) -> dict:
         if "bias" in aux:
             biases.append(aux["bias"])
     x = layers.rms_norm(x, _eps(cfg), ParamAttr(name="final_norm_w"))
-    logits = _linear(x, cfg["vocab_size"], "lm_head_w")
+    if cfg.get("tie_word_embeddings"):
+        table = x.block.program.global_block().var("tok_emb")
+        if dtype != "float32":
+            table = layers.cast(table, dtype)
+        logits = layers.matmul(x, table, transpose_y=True)
+    else:
+        logits = _linear(x, cfg["vocab_size"], "lm_head_w")
     if dtype != "float32":
         logits = layers.cast(logits, "float32")
+    if cfg.get("logits_scaling", 1) != 1:
+        logits = layers.scale(logits, 1.0 / float(cfg["logits_scaling"]))
     each = layers.softmax_with_cross_entropy(logits, labels)
     ce = layers.mean(each)
     out = {"loss": ce, "ce": ce, "each": each, "expert_load": loads,

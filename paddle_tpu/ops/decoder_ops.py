@@ -283,13 +283,17 @@ def moe_bias_update(ctx, ins):
 
 @register("short_conv")
 def short_conv(ctx, ins):
-    """The gated short convolution between a hybrid decoder layer's two
-    projections: ``X [T, 3C]`` holds ``B | C | u`` side by side (the input
-    projection's output), ``W [C, L]`` one causal filter of length L a
-    channel; ``Out [T, C] = C * conv(B * u)`` with ``conv(z)[t] = sum_j
-    W[:, j] * z[t - (L-1) + j]``. The T rows are sequences of ``seq`` (attr)
-    consecutive positions: positions before a sequence's start count as
-    zero, nothing crosses from one sequence into the next. float32 inside.
+    """The short causal convolution between a hybrid decoder layer's two
+    projections, ``W [C, L]`` one depthwise filter of length L a channel:
+    ``conv(z)[t] = sum_j W[:, j] * z[t - (L-1) + j]``. Gated (attr ``gated``,
+    the default; LFM2): ``X [T, 3C]`` holds ``B | C | u`` side by side (the
+    input projection's output) and ``Out [T, C] = C * conv(B * u)``. Not
+    gated (a Mamba mixer): ``X [T, C]`` and ``Out = conv(X)``. Either way
+    ``Bias [C]``, where given, is added to the filter's output and attr
+    ``activation`` (``"silu"`` or none) applied to that, before the gate
+    ``C``. The T rows are sequences of ``seq`` (attr) consecutive positions:
+    positions before a sequence's start count as zero, nothing crosses from
+    one sequence into the next. float32 inside.
 
     Attr ``impl``: ``auto`` (default) lowers the Pallas kernels of
     ``ops/pallas_short_conv.py`` where they can run (a TPU, or the test
@@ -297,13 +301,18 @@ def short_conv(ctx, ins):
     ``pallas`` / ``composed`` force one. The kernels are 8 to 13 times
     faster than XLA's fusion of the composed form on a v5e (timed at
     ``[16384, 3 x 2048]``: PERF.md section 6, PR 32)."""
+    import jax
     import jax.numpy as jnp
     from . import pallas_mode, pallas_short_conv
     x, w = ins["X"][0], ins["W"][0]
+    bias = ins.get("Bias", [None])[0]
+    gated, act = bool(ctx.attr("gated", True)), ctx.attr("activation", "")
+    if act not in ("", "silu"):
+        raise ValueError(f"short_conv: activation {act!r} (only 'silu')")
     seq, (rows, wide) = int(ctx.attr("seq")), x.shape
-    chan, taps = wide // 3, w.shape[1]
+    chan, taps = wide // 3 if gated else wide, w.shape[1]
     impl = ctx.attr("impl", "auto")
-    fits = pallas_short_conv.supports(seq, chan, taps)
+    fits = pallas_short_conv.supports(seq, chan, taps, bias is not None)
     if impl == "pallas":
         pallas_mode.require("short_conv impl='pallas'")
         if not fits:
@@ -312,16 +321,108 @@ def short_conv(ctx, ins):
                 f"{pallas_short_conv.BLK_C} == 0, seq % 16 == 0 and at most "
                 f"{pallas_short_conv.MAX_SEQ}; got seq={seq}, "
                 f"channels={chan}, taps={taps}")
-    if not ctx.abstract and (impl == "pallas" or (
-            impl == "auto" and fits and pallas_mode.available())):
+    kernels = pallas_mode.lowers_kernels(impl, fits, ctx.abstract)
+    ctx.note("short_conv", ("pallas" if kernels else "composed",
+                            "gated" if gated else "plain", act or "none",
+                            taps))
+    if kernels:
         return {"Out": [pallas_short_conv.short_conv(
-            x, w, seq, pallas_mode.interpret())]}
+            x, w, seq, pallas_mode.interpret(), bias, gated, act)]}
     xf = x.astype(jnp.float32)
-    z = (xf[:, :chan] * xf[:, 2 * chan:]).reshape(rows // seq, seq, chan)
+    z = (xf[:, :chan] * xf[:, 2 * chan:] if gated else xf).reshape(
+        rows // seq, seq, chan)
     wf = w.astype(jnp.float32)
     conv = z * wf[:, taps - 1]
     for back in range(1, taps):             # z[t - back], zeros before t=0
         past = jnp.pad(z, ((0, 0), (back, 0), (0, 0)))[:, :seq]
         conv = conv + past * wf[:, taps - 1 - back]
-    return {"Out": [(xf[:, chan:2 * chan] * conv.reshape(rows, chan))
-                    .astype(x.dtype)]}
+    conv = conv.reshape(rows, chan)
+    if bias is not None:
+        conv = conv + bias.astype(jnp.float32)
+    if act:
+        conv = jax.nn.silu(conv)
+    if gated:
+        conv = xf[:, chan:2 * chan] * conv
+    return {"Out": [conv.astype(x.dtype)]}
+
+
+def composed_ssd_scan(x, dt, a, bm, cm, d, chunk):
+    """The chunked scan in plain ``jax.numpy`` (Dao & Gu, arXiv:2405.21060,
+    listing 1), float32 throughout: per chunk the ``[Q, Q]`` decay block of
+    every head, the chunk's state, the recurrence over the chunks'
+    states, and the entering state's part of the output. Shapes as the op's;
+    ``[batch, chunks, Q, Q, heads]`` arrays exist whole, which is what the
+    kernels are for."""
+    import jax
+    import jax.numpy as jnp
+    batch, seq, heads, p = x.shape
+    n, c = bm.shape[-1], seq // chunk
+    f32 = jnp.float32
+    xf = x.astype(f32)
+    dt = dt.astype(f32)
+    xd = (xf * dt[..., None]).reshape(batch, c, chunk, heads, p)
+    bm = bm.astype(f32).reshape(batch, c, chunk, n)
+    cm = cm.astype(f32).reshape(batch, c, chunk, n)
+    cum = jnp.cumsum((dt * a.astype(f32)).reshape(batch, c, chunk, heads),
+                     axis=2)
+    lower = jnp.tril(jnp.ones((chunk, chunk), bool))[:, :, None]
+    decay = jnp.exp(jnp.where(
+        lower, cum[:, :, :, None, :] - cum[:, :, None, :, :], -jnp.inf))
+    y = jnp.einsum("bcin,bcjn,bcijh,bcjhp->bcihp", cm, bm, decay, xd)
+    end = cum[:, :, -1:, :]
+    made = jnp.einsum("bcjn,bcjh,bcjhp->bchnp", bm, jnp.exp(end - cum), xd)
+
+    def carry(h, inp):                  # h: the state entering the chunk
+        made_c, keep_c = inp
+        return keep_c[..., None, None] * h + made_c, h
+
+    _, entering = jax.lax.scan(
+        carry, jnp.zeros((batch, heads, n, p), f32),
+        (made.transpose(1, 0, 2, 3, 4),
+         jnp.exp(end[:, :, 0]).transpose(1, 0, 2)))
+    y = y + jnp.einsum("bcin,cbhnp,bcih->bcihp", cm, entering, jnp.exp(cum))
+    y = y.reshape(x.shape) + xf * d.astype(f32)[:, None]
+    return y.astype(x.dtype)
+
+
+@register("ssd_scan")
+def ssd_scan(ctx, ins):
+    """The state-space scan of a Mamba-2 layer (Dao & Gu, arXiv:2405.21060),
+    a head at a time with state ``h [N, P]``: ``h_t = exp(dt_t A) h_{t-1} +
+    B_t (x) (dt_t x_t)``, ``y_t = C_t h_t + D x_t``, the state zero before each
+    sequence's start. ``X [B, S, heads, P]``, ``Dt [B, S, heads]`` (positive:
+    after its softplus), ``A [heads]`` (negative), ``B`` / ``C [B, S, N]``
+    (one group: shared by the heads), ``D [heads]`` -> ``Y`` like ``X``.
+    Computed in chunks of ``chunk`` (attr; the sequence where that is
+    shorter) positions, which equals the recurrence in exact arithmetic; the
+    decay, its running sums, the exps and the state in float32.
+
+    Attr ``impl``: ``auto`` (default) lowers the Pallas kernels of
+    ``ops/pallas_ssd.py`` where they can run (a TPU, or the test harness'
+    interpreter) and take the shapes, else ``composed_ssd_scan``; ``pallas``
+    / ``composed`` force one. Which one an op took is counted at each compile
+    (``ctx.note``; observability/ssm.py)."""
+    from . import pallas_mode, pallas_ssd
+    x, dt, a, bm, cm, d = (ins[k][0] for k in ("X", "Dt", "A", "B", "C", "D"))
+    _, seq, heads, p = x.shape
+    n = bm.shape[-1]
+    chunk = min(int(ctx.attr("chunk", 256)), seq)
+    if seq % chunk:
+        raise ValueError(f"ssd_scan: chunk {chunk} must divide seq {seq}")
+    impl = ctx.attr("impl", "auto")
+    fits = pallas_ssd.supports(seq, heads, p, n, chunk)
+    if impl == "pallas":
+        pallas_mode.require("ssd_scan impl='pallas'")
+        if not fits:
+            raise ValueError(
+                f"ssd_scan impl='pallas' needs heads of "
+                f"{pallas_ssd.HEAD_DIM}, heads % {pallas_ssd.HEADS} == 0, "
+                f"state % 128 == 0 and chunk % 128 == 0; got heads={heads} "
+                f"of {p}, state={n}, chunk={chunk}")
+    kernels = pallas_mode.lowers_kernels(impl, fits, ctx.abstract)
+    ctx.note("ssd_scan", ("pallas" if kernels else "composed", chunk, heads,
+                          n))
+    if kernels:
+        return {"Y": [pallas_ssd.ssd_scan(x, dt, a, bm, cm, d, chunk,
+                                          pallas_mode.interpret())]}
+    return {"Y": [composed_ssd_scan(x, dt, a, bm, cm, d, chunk)]}

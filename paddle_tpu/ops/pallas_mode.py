@@ -32,6 +32,15 @@ def available() -> bool:
     return on_tpu() or TEST_INTERPRET
 
 
+def lowers_kernels(impl: str, fits: bool, abstract: bool) -> bool:
+    """Whether an op with attr ``impl`` (``auto`` / ``pallas`` / another
+    lowering's name) lowers its Pallas kernels here: asked for by name, or
+    ``auto`` where the shapes fit and a kernel can run; never under shape
+    inference (``abstract``), where every lowering gives the same shapes."""
+    return not abstract and (impl == "pallas" or (
+        impl == "auto" and fits and available()))
+
+
 def require(what: str) -> None:
     if not available():
         import jax

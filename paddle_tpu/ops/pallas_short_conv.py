@@ -1,8 +1,11 @@
-"""The gated short convolution (``ops/decoder_ops.py:short_conv``) as a pair
-of Pallas TPU kernels, forward and backward.
+"""The short convolution (``ops/decoder_ops.py:short_conv``) as a pair of
+Pallas TPU kernels, forward and backward, in the forms the op is told: LFM2's
+``C * conv(B * u)`` over ``[T, 3C]`` and a Mamba mixer's ``silu(conv(x) +
+bias)`` over ``[T, C]`` share the bodies; the gates, the bias and the
+activation are Python branches of them.
 
-The op is bound by HBM: 4 arrays of ``[T, C]`` forward (the three parts of X
-in, the output out), 7 backward. XLA's fusion of the composed form moves them
+The op is bound by HBM: gated, 4 arrays of ``[T, C]`` forward (the three
+parts of X in, the output out), 7 backward; ungated 2 and 3. XLA's fusion of the composed form moves them
 at 75 / 48 GB/s on a v5e (the shifted reads along the sequence defeat its
 tiling); these kernels at 636 / 619 GB/s, 8.5 and 12.8 times faster at
 ``[16384, 3 x 2048]`` (chip runs, PR 32: PERF.md section 6). So on a TPU the
@@ -16,7 +19,8 @@ column ranges of the one ``[B, S, 3C]`` array, read in place. The backward
 recomputes ``B u`` and the filter's output from X (cheaper than keeping
 them), writes the three parts' gradients as three arrays (one block an
 operand a grid step; the caller joins them) and each sequence's part of the
-filter's gradient, summed over the sequences outside. float32 inside.
+filter's gradient (and the bias', in the row after the taps), summed over
+the sequences outside. float32 inside.
 """
 from __future__ import annotations
 
@@ -33,10 +37,11 @@ MAX_SEQ = 8192
 MAX_TAPS = 8
 
 
-def supports(seq: int, channels: int, taps: int) -> bool:
-    """Whether the kernels take these shapes (else the composed form)."""
+def supports(seq: int, channels: int, taps: int, bias: bool = False) -> bool:
+    """Whether the kernels take these shapes (else the composed form); the
+    bias takes a row of the eight beside the taps."""
     return (channels % BLK_C == 0 and seq % 16 == 0 and seq <= MAX_SEQ
-            and taps <= MAX_TAPS)
+            and taps + bool(bias) <= MAX_TAPS)
 
 
 def _pl():
@@ -68,34 +73,69 @@ def _ahead(z, k):
     return jnp.where(rows < n - k, pltpu.roll(z, n - k, axis=0), 0.0)
 
 
-def _fwd_kernel(taps, b_ref, c_ref, u_ref, w_ref, o_ref):
+def _silu(pre):
+    """silu(pre) and its derivative."""
+    import jax
+    s = jax.nn.sigmoid(pre)
+    return pre * s, s * (1.0 + pre * (1.0 - s))
+
+
+def _fwd_kernel(taps, gated, bias, act, *refs):
     import jax.numpy as jnp
-    z = b_ref[0].astype(jnp.float32) * u_ref[0].astype(jnp.float32)
-    w = w_ref[...]                                  # [8, BLK_C]: row j = tap j
+    if gated:
+        b_ref, c_ref, u_ref, w_ref, o_ref = refs
+        z = b_ref[0].astype(jnp.float32) * u_ref[0].astype(jnp.float32)
+    else:
+        u_ref, w_ref, o_ref = refs
+        z = u_ref[0].astype(jnp.float32)
+    w = w_ref[...]                 # [8, BLK_C]: row j = tap j, then the bias
     conv = sum(_behind(z, taps - 1 - j) * w[j:j + 1] for j in range(taps))
-    o_ref[0] = (c_ref[0].astype(jnp.float32) * conv).astype(o_ref.dtype)
+    if bias:
+        conv = conv + w[taps:taps + 1]
+    if act:
+        conv = _silu(conv)[0]
+    if gated:
+        conv = c_ref[0].astype(jnp.float32) * conv
+    o_ref[0] = conv.astype(o_ref.dtype)
 
 
-def _bwd_kernel(taps, b_ref, c_ref, u_ref, w_ref, g_ref,
-                db_ref, dc_ref, du_ref, dw_ref):
+def _bwd_kernel(taps, gated, bias, act, *refs):
     import jax.numpy as jnp
-    bf, cf, uf, g = (r[0].astype(jnp.float32)
-                     for r in (b_ref, c_ref, u_ref, g_ref))
+    if gated:
+        b_ref, c_ref, u_ref, w_ref, g_ref, db_ref, dc_ref, du_ref, dw_ref = \
+            refs
+        bf, cf, uf, g = (r[0].astype(jnp.float32)
+                         for r in (b_ref, c_ref, u_ref, g_ref))
+        z = bf * uf
+    else:
+        u_ref, w_ref, g_ref, du_ref, dw_ref = refs
+        z, g = u_ref[0].astype(jnp.float32), g_ref[0].astype(jnp.float32)
     w = w_ref[...]
-    z = bf * uf
     past = [_behind(z, taps - 1 - j) for j in range(taps)]
-    dc_ref[0] = (g * sum(p * w[j:j + 1] for j, p in enumerate(past))
-                 ).astype(dc_ref.dtype)
-    dconv = g * cf
+    conv = sum(p * w[j:j + 1] for j, p in enumerate(past))
+    if bias:
+        conv = conv + w[taps:taps + 1]
+    slope = None
+    if act:
+        conv, slope = _silu(conv)
+    if gated:
+        dc_ref[0] = (g * conv).astype(dc_ref.dtype)
+        g = g * cf
+    dconv = g if slope is None else g * slope
     dz = sum(_ahead(dconv, taps - 1 - j) * w[j:j + 1] for j in range(taps))
-    db_ref[0] = (dz * uf).astype(db_ref.dtype)
-    du_ref[0] = (dz * bf).astype(du_ref.dtype)
+    if gated:
+        db_ref[0] = (dz * uf).astype(db_ref.dtype)
+        du_ref[0] = (dz * bf).astype(du_ref.dtype)
+    else:
+        du_ref[0] = dz.astype(du_ref.dtype)
+    rows = [jnp.sum(dconv * p, axis=0, keepdims=True) for p in past]
+    if bias:
+        rows.append(jnp.sum(dconv, axis=0, keepdims=True))
     dw_ref[0] = jnp.concatenate(
-        [jnp.sum(dconv * p, axis=0, keepdims=True) for p in past]
-        + [jnp.zeros((8 - taps, z.shape[1]), jnp.float32)], axis=0)
+        rows + [jnp.zeros((8 - len(rows), z.shape[1]), jnp.float32)], axis=0)
 
 
-def _specs(batch, seq, channels):
+def _specs(batch, seq, channels, gated):
     pl, pltpu = _pl()
     n = channels // BLK_C
 
@@ -106,7 +146,8 @@ def _specs(batch, seq, channels):
                        memory_space=pltpu.VMEM)
     taps8 = pl.BlockSpec((8, BLK_C), lambda b, j: (0, j),
                          memory_space=pltpu.VMEM)
-    return [part(0), part(1), part(2), taps8], one, (batch, n)
+    ins = [part(0), part(1), part(2)] if gated else [one]
+    return ins + [taps8], one, (batch, n)
 
 
 def _params(interpret):
@@ -118,72 +159,87 @@ def _params(interpret):
         vmem_limit_bytes=VMEM_LIMIT_BYTES)}
 
 
-def _taps8(w):
-    """``w [C, taps]`` as float32 ``[8, C]``, tap j in row j."""
+def _taps8(w, bias=None):
+    """``w [C, taps]`` as float32 ``[8, C]``, tap j in row j and the bias
+    ``[C]``, where there is one, in the row after the taps."""
     import jax.numpy as jnp
-    return jnp.zeros((8, w.shape[0]), jnp.float32).at[:w.shape[1]].set(
+    taps = w.shape[1]
+    out = jnp.zeros((8, w.shape[0]), jnp.float32).at[:taps].set(
         w.astype(jnp.float32).T)
+    if bias is not None:
+        out = out.at[taps].set(bias.astype(jnp.float32))
+    return out
 
 
-@functools.partial(_jax.custom_vjp, nondiff_argnums=(2, 3))
-def short_conv(x, w, seq, interpret):
-    """``x [T, 3C]``, ``w [C, taps]`` -> ``[T, C]`` (the op's contract),
-    differentiable in both."""
-    return _fwd_call(x, w, seq, interpret)
+@functools.partial(_jax.custom_vjp, nondiff_argnums=(2, 3, 5, 6))
+def short_conv(x, w, seq, interpret, bias=None, gated=True, act=""):
+    """``w [C, taps]``, ``bias [C]`` or None. ``gated``: ``x [T, 3C]`` holds
+    ``B | C | u`` and the result is ``C * act(conv(B * u) + bias)``; else
+    ``x [T, C]`` and it is ``act(conv(x) + bias)``. ``act``: ``"silu"`` or
+    none. ``[T, C]`` (the op's contract), differentiable in x, w and bias."""
+    return _fwd_call(x, w, bias, seq, interpret, gated, act)
 
 
 # behind a jit of its own, like the flash kernels: the layers of a model
 # (and the forward a grad op traces again) share one trace and one lowering
-@functools.partial(_jax.jit, static_argnames=("seq", "interpret"))
-def _fwd_call(x, w, seq, interpret):
+@functools.partial(_jax.jit,
+                   static_argnames=("seq", "interpret", "gated", "act"))
+def _fwd_call(x, w, bias, seq, interpret, gated, act):
     import jax
     pl, _ = _pl()
     rows, wide = x.shape
-    batch, channels = rows // seq, wide // 3
+    batch, channels = rows // seq, wide // 3 if gated else wide
     x3 = x.reshape(batch, seq, wide)
-    in_specs, one, grid = _specs(batch, seq, channels)
+    in_specs, one, grid = _specs(batch, seq, channels, gated)
     out = pl.pallas_call(
-        functools.partial(_fwd_kernel, w.shape[1]), grid=grid,
-        in_specs=in_specs, out_specs=one,
+        functools.partial(_fwd_kernel, w.shape[1], gated, bias is not None,
+                          act),
+        grid=grid, in_specs=in_specs, out_specs=one,
         out_shape=jax.ShapeDtypeStruct((batch, seq, channels), x.dtype),
-        interpret=interpret, **_params(interpret))(x3, x3, x3, _taps8(w))
+        interpret=interpret, **_params(interpret),
+    )(*([x3] * (3 if gated else 1)), _taps8(w, bias))
     return out.reshape(rows, channels)
 
 
-@functools.partial(_jax.jit, static_argnames=("seq", "interpret"))
-def _bwd_call(x, w, g, seq, interpret):
+@functools.partial(_jax.jit,
+                   static_argnames=("seq", "interpret", "gated", "act"))
+def _bwd_call(x, w, bias, g, seq, interpret, gated, act):
     import jax
     import jax.numpy as jnp
     pl, pltpu = _pl()
     rows, wide = x.shape
-    batch, channels = rows // seq, wide // 3
+    batch, channels = rows // seq, wide // 3 if gated else wide
     taps = w.shape[1]
     x3 = x.reshape(batch, seq, wide)
-    in_specs, one, grid = _specs(batch, seq, channels)
+    in_specs, one, grid = _specs(batch, seq, channels, gated)
     part = jax.ShapeDtypeStruct((batch, seq, channels), x.dtype)
-    db, dc, du, dw = pl.pallas_call(
-        functools.partial(_bwd_kernel, taps), grid=grid,
-        in_specs=in_specs + [one],
-        out_specs=[one, one, one,
-                   pl.BlockSpec((1, 8, BLK_C), lambda b, j: (b, 0, j),
-                                memory_space=pltpu.VMEM)],
-        out_shape=[part, part, part,
-                   jax.ShapeDtypeStruct((batch, 8, channels), jnp.float32)],
+    n_parts = 3 if gated else 1
+    *dparts, dw = pl.pallas_call(
+        functools.partial(_bwd_kernel, taps, gated, bias is not None, act),
+        grid=grid, in_specs=in_specs + [one],
+        out_specs=[one] * n_parts + [
+            pl.BlockSpec((1, 8, BLK_C), lambda b, j: (b, 0, j),
+                         memory_space=pltpu.VMEM)],
+        out_shape=[part] * n_parts + [
+            jax.ShapeDtypeStruct((batch, 8, channels), jnp.float32)],
         interpret=interpret, **_params(interpret),
-    )(x3, x3, x3, _taps8(w), g.reshape(batch, seq, channels))
-    dx = jnp.concatenate([db, dc, du], axis=-1).reshape(rows, wide)
-    return dx, jnp.sum(dw, axis=0)[:taps].T.astype(w.dtype)
+    )(*([x3] * n_parts), _taps8(w, bias), g.reshape(batch, seq, channels))
+    dx = (jnp.concatenate(dparts, axis=-1) if gated else dparts[0]).reshape(
+        rows, wide)
+    dw = jnp.sum(dw, axis=0)
+    return (dx, dw[:taps].T.astype(w.dtype),
+            None if bias is None else dw[taps].astype(bias.dtype))
 
 
-def _vjp_fwd(x, w, seq, interpret):
+def _vjp_fwd(x, w, seq, interpret, bias, gated, act):
     # inputs only: a kernel output among the residuals would keep alive the
     # forward that a Program's grad op lowers again (pallas_attention.py)
-    return _fwd_call(x, w, seq, interpret), (x, w)
+    return _fwd_call(x, w, bias, seq, interpret, gated, act), (x, w, bias)
 
 
-def _vjp_bwd(seq, interpret, res, g):
-    x, w = res
-    return _bwd_call(x, w, g, seq, interpret)
+def _vjp_bwd(seq, interpret, gated, act, res, g):
+    x, w, bias = res
+    return _bwd_call(x, w, bias, g, seq, interpret, gated, act)
 
 
 short_conv.defvjp(_vjp_fwd, _vjp_bwd)

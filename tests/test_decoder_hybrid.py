@@ -386,7 +386,7 @@ def test_program_matches_the_reference_in_loss_gradients_and_updated_bias():
     ({"conv_bias": True}, "conv_bias"),
     ({"n_shared_experts": 1}, "shared experts"),
     ({"router_scoring": "softmax"}, "sigmoid"),
-    ({"qk_norm": "none"}, "qk_norm"),
+    ({"qk_norm": "layer"}, "qk_norm"),
     ({"router_aux_loss_coef": 0.01}, "router losses")])
 def test_what_the_builder_does_not_build_raises_by_name(change, match):
     with pytest.raises(NotImplementedError, match=match):

@@ -1,8 +1,10 @@
 """The Pallas kernels through Mosaic, at the benchmark's shapes, for a TPU v5e
 that is described and not attached: what the interpreter cannot see (VMEM
 budgets, tiling, the in-kernel PRNG). The flash kernels at BERT's and the
-decoder's shapes, the grouped expert matmuls at OLMoE's, and a small expert
-layer's whole train step (what a Program's grad ops leave in it). Nothing
+decoder's shapes, the short convolution in both its forms, the chunked
+state-space scan at Granite's, the grouped expert matmuls at OLMoE's, and a
+small expert layer's whole train step (what a Program's grad ops leave in
+it). Nothing
 runs, so this says nothing about results or times. All such compiles live in
 this one file: the worker that gets it loads libtpu, and keeps it until it
 exits.
@@ -170,6 +172,70 @@ def test_short_conv_kernels_compile_for_v5e(one_chip, batch, seq, taps):
     assert [tuple(o.shape) for o in back.out_info] == [
         (batch * seq, 3 * C), (C, taps)]
     assert _kernels(back.compile()) == 1
+
+
+@pytest.mark.parametrize("batch,seq", [(1, 4096), (2, 2048)])
+def test_ungated_conv_kernels_compile_for_v5e(one_chip, batch, seq):
+    """A Mamba mixer's ``silu(conv(xBC) + bias)`` at the Granite cell's shape
+    ([4096, 4352] bf16, 4 taps, 34 channel blocks): one kernel forward, the
+    backward kernel alone in what a grad op lowers, gradients for the input,
+    the filter and the bias."""
+    from paddle_tpu.ops import pallas_short_conv as psc
+    C, taps = 4352, 4
+    assert psc.supports(seq, C, taps, True)
+    x = jax.ShapeDtypeStruct((batch * seq, C), jnp.bfloat16,
+                             sharding=one_chip)
+    w = jax.ShapeDtypeStruct((C, taps), jnp.bfloat16, sharding=one_chip)
+    b = jax.ShapeDtypeStruct((C,), jnp.bfloat16, sharding=one_chip)
+
+    def conv(x, w, b):
+        return psc.short_conv(x, w, seq, False, b, False, "silu")
+
+    def grads(x, w, b, g):
+        return jax.vjp(conv, x, w, b)[1](g)
+
+    assert _kernels(jax.jit(conv).lower(x, w, b).compile()) == 1
+    back = jax.jit(grads).lower(x, w, b, x)
+    assert [tuple(o.shape) for o in back.out_info] == [
+        (batch * seq, C), (C, taps), (C,)]
+    assert _kernels(back.compile()) == 1
+
+
+@pytest.mark.parametrize("batch,seq,chunk", [(1, 4096, 256), (2, 1024, 128)])
+def test_ssd_scan_kernels_compile_for_v5e(one_chip, batch, seq, chunk):
+    """The chunked scan at the Granite cell's shape (64 heads of 64, state
+    128, chunks of 256, one sequence of 4096, bf16 with float32 dt): one
+    kernel forward; the backward a Program's grad op lowers holds the state
+    pass and the reverse kernel and not the forward (its outputs are not
+    residuals); no [.., 256, 256] array in the program around them."""
+    from paddle_tpu.ops import pallas_ssd
+    H, P, N = 64, 64, 128
+    assert pallas_ssd.supports(seq, H, P, N, chunk)
+
+    def sds(shape, dtype):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
+    args = (sds((batch, seq, H, P), jnp.bfloat16),
+            sds((batch, seq, H), jnp.float32), sds((H,), jnp.float32),
+            sds((batch, seq, N), jnp.bfloat16),
+            sds((batch, seq, N), jnp.bfloat16), sds((H,), jnp.float32))
+
+    def scan(*a):
+        return pallas_ssd.ssd_scan(*a, chunk, False)
+
+    def grads(*a):
+        return jax.vjp(scan, *a[:-1])[1](a[-1])
+
+    assert _kernels(jax.jit(scan).lower(*args).compile()) == 1
+    back = jax.jit(grads).lower(*args, args[0])
+    assert [tuple(o.shape) for o in back.out_info] == [
+        tuple(a.shape) for a in args]
+    compiled = back.compile()
+    assert _kernels(compiled) == 2
+    assert f"{chunk},{chunk}]" not in compiled.as_text().split(
+        "ENTRY")[1].replace("custom_call", "")
+    # the state a chunk, float32, and the padded per-head scalars: well
+    # under the composed form's 0.78 GB at this shape
+    assert compiled.memory_analysis().temp_size_in_bytes < 200e6
 
 
 @pytest.mark.parametrize("S", [384, 512])

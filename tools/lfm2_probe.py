@@ -221,6 +221,23 @@ def controls(args) -> dict:
     return result
 
 
+def leaf_errors(names, grads, want) -> list:
+    """A step's gradients against the reference's, a row a leaf: the largest
+    difference over the reference's largest entry, the difference's norm
+    over the reference's, the cosine, and the least-squares scale."""
+    rows = []
+    for name, g, r in zip(names, grads, want):
+        r = np.asarray(r, np.float32)
+        rows.append({
+            "name": name, "shape": list(r.shape),
+            "max": float(np.abs(g - r).max() / np.abs(r).max()),
+            "l2": float(np.linalg.norm(g - r) / np.linalg.norm(r)),
+            "cos": float(np.vdot(g, r) / (np.linalg.norm(g)
+                                          * np.linalg.norm(r))),
+            "scale": float(np.vdot(g, r) / np.vdot(r, r))})
+    return rows
+
+
 def gradients(args) -> dict:
     import jax
     import jax.numpy as jnp
@@ -264,16 +281,7 @@ def gradients(args) -> dict:
             f"routing by {routing} choice: loss {loss:.6f} against "
             f"{float(want_loss):.6f}; assignments not the reference's "
             f"{flips:.4%}")
-        rows = []
-        for name, g, r in zip(params, grads, want):
-            r = np.asarray(r, np.float32)
-            rows.append({
-                "name": name, "shape": list(r.shape),
-                "max": float(np.abs(g - r).max() / np.abs(r).max()),
-                "l2": float(np.linalg.norm(g - r) / np.linalg.norm(r)),
-                "cos": float(np.vdot(g, r) / (np.linalg.norm(g)
-                                              * np.linalg.norm(r))),
-                "scale": float(np.vdot(g, r) / np.vdot(r, r))})
+        rows = leaf_errors(params, grads, want)
         for row in sorted(rows, key=lambda r: -r["l2"]):
             say(f"  {row['name']:<24} {str(row['shape']):<18} |d|max/|ref|max"
                 f" {row['max']:.3e} |d|/|ref| {row['l2']:.3e} cos "
