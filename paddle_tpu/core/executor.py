@@ -722,8 +722,8 @@ class Executor:
         _obs_attention.count_lowerings(
             program._lowering_notes.pop("fused_attention", {}), label)
         from ..observability import masks as _obs_masks
-        _obs_masks.count_draws(
-            program._lowering_notes.pop("mask_draw", {}), label)
+        # takes the kinds it counts (mask_draw, gather_layout) out of them
+        _obs_masks.count_data_axis(program._lowering_notes, label)
         from ..observability import ssm as _obs_ssm
         _obs_ssm.update_ssm_gauges(program, label)
         _obs_ssm.count_lowerings(

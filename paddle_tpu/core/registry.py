@@ -108,11 +108,8 @@ class LowerCtx:
         for ``mask_draw_total`` (observability/masks.py)."""
         import jax
         mesh, axis = self.gspmd_mesh, self.data_axis
-        n = mesh.shape.get(axis, 1) if mesh is not None else 1
-        # an op lowered inside another op's shard_map over the mesh (the
-        # pipeline's stages) sees gspmd_mesh too: no island inside an island
-        if (n <= 1 or not shape or shape[0] % n
-                or jax.sharding.get_abstract_mesh().manual_axes):
+        n = self.data_shards(*shape[:1])
+        if n == 1:
             self.note("mask_draw", ("global", 1))
             return jax.random.bernoulli(key, keep, shape)
         from jax.sharding import PartitionSpec as P
@@ -126,6 +123,22 @@ class LowerCtx:
         return jax.shard_map(
             local, mesh=mesh, in_specs=P(),
             out_specs=P(axis, *([None] * (len(shape) - 1))))(key)
+
+    def data_shards(self, *dims) -> int:
+        """The devices of the strategy's data axis over which an op may lay
+        leading dimensions of sizes ``dims`` in a ``shard_map`` island of its
+        own, else 1: a GSPMD mesh whose data axis has n > 1 devices dividing
+        every one of ``dims`` (at least one), and no mesh axis manual
+        already -- an op lowered inside another op's ``shard_map`` over the
+        mesh (the pipeline's stages) sees ``gspmd_mesh`` too, and opens no
+        island inside the island."""
+        import jax
+        mesh = self.gspmd_mesh
+        n = mesh.shape.get(self.data_axis, 1) if mesh is not None else 1
+        if (n <= 1 or not dims or any(d % n for d in dims)
+                or jax.sharding.get_abstract_mesh().manual_axes):
+            return 1
+        return n
 
 
 def stable_salt(name: str) -> int:

@@ -718,6 +718,12 @@ def expand(x, expand_times, name=None):
 
 
 def gather(input, index, overwrite=True, axis=0):
+    """``input``'s slices along ``axis`` at ``index``. Under a mesh
+    (``CompiledProgram.with_strategy``) the rows gathered along axis 0 leave
+    the op laid over the strategy's data axis where its size divides both
+    ``input``'s rows and the index count, whatever the layout of ``index``
+    (BERT's flat ``mask_pos`` is replicated): each device runs what consumes
+    them on its own part."""
     helper = LayerHelper("gather")
     out = _out(helper, input.dtype)
     helper.append_op("gather", inputs={"X": [input], "Index": [index]},

@@ -159,8 +159,56 @@ def strided_slice(ctx, ins):
 @register("gather", nondiff_inputs=("Index",))
 def gather(ctx, ins):
     jnp = _jnp()
-    x, idx = ins["X"][0], ins["Index"][0]
-    return {"Out": [jnp.take(x, idx.astype("int32"), axis=ctx.attr("axis", 0))]}
+    x, idx = ins["X"][0], ins["Index"][0].astype("int32")
+    axis = ctx.attr("axis", 0)
+    if axis == 0:
+        n = 1
+        if jnp.issubdtype(x.dtype, jnp.number):     # rows that psum can add
+            n = ctx.data_shards(*x.shape[:1], *idx.shape[:1])
+        ctx.note("gather_layout", ("shard", n) if n > 1 else ("global", 1))
+        if n > 1:
+            return {"Out": [_rows_over_data_axis(
+                ctx.gspmd_mesh, ctx.data_axis, n, x, idx)]}
+    return {"Out": [jnp.take(x, idx, axis=axis)]}
+
+
+def _rows_over_data_axis(mesh, axis, n, x, idx):
+    """``jnp.take(x, idx, axis=0)`` under a GSPMD mesh, the rows leaving laid
+    over the mesh's data ``axis`` (``n`` devices): device r holds rows
+    ``[r M / n, (r + 1) M / n)`` of the M gathered. XLA's SPMD partitioner
+    gathers from a data-sharded ``x`` (activations ``[B * S, H]``) by
+    replicated indices (BERT's flat ``mask_pos``) under a mask, all-reduces
+    and leaves the rows replicated, so every device runs every row-wise
+    consumer, and its backward, at all M rows. The island does the same
+    masked take and all-reduce and hands each device its part, whichever way
+    ``idx`` is laid out; its backward pads and all-reduces the rows'
+    gradient. All-reduces only, by design: an all-gather (of sharded indices,
+    of the cotangent; what a sharding constraint on the output or
+    ``psum_scatter`` gets from XLA) is run asynchronously across the
+    neighbouring fusions on a TPU and cost BERT's 24 feed-forward products
+    their VMEM-resident operands, 3 ms of a 98.6 ms step (PERF.md section 6,
+    PR 36). An index outside ``x`` gives a row of zeros (``jnp.take``: NaN)."""
+    import jax
+    from jax.sharding import PartitionSpec as P
+    jnp = _jnp()
+    rows, m = x.shape[0] // n, idx.shape[0] // n
+
+    def local(x_here, idx_here):
+        r = jax.lax.axis_index(axis)
+        # every device's indices: mine in their place, summed
+        i = jax.lax.psum(jax.lax.dynamic_update_slice_in_dim(
+            jnp.zeros((m * n, *idx_here.shape[1:]), idx_here.dtype),
+            idx_here, r * m, axis=0), axis)
+        i = jnp.where(i < 0, i + rows * n, i) - r * rows
+        mine = (i >= 0) & (i < rows)
+        got = jnp.take(x_here, jnp.clip(i, 0, rows - 1), axis=0)
+        got = jnp.where(mine.reshape(mine.shape + (1,) * (x.ndim - 1)), got,
+                        jnp.zeros((), got.dtype))
+        return jax.lax.dynamic_slice_in_dim(
+            jax.lax.psum(got, axis), r * m, m, axis=0)
+
+    return jax.shard_map(local, mesh=mesh, in_specs=(P(axis), P(axis)),
+                         out_specs=P(axis))(x, idx)
 
 
 @register("gather_nd", nondiff_inputs=("Index",))
