@@ -50,6 +50,8 @@ _PROGRAM_GAUGES = ("program_flops", "program_bytes_accessed",
                    "program_arithmetic_intensity", "program_flops_per_sec",
                    "program_mfu", "program_peak_bytes", "program_temp_bytes",
                    "program_argument_bytes", "program_output_bytes",
+                   "program_alias_bytes", "program_xla_peak_bytes",
+                   "program_compile_seq",
                    "program_static_peak_bytes", "program_static_peak_ratio")
 
 
@@ -72,6 +74,10 @@ def _retire_program_gauges_if_dead(prog_id, version):
     # removal can't reach them -- the owning module retires its own series
     from ..observability import attribution as _obs_attrib
     _obs_attrib.retire_program(label)
+    # likewise the memory gauges with a class / stat label, and the weak
+    # step peak_live_set would work from
+    from ..observability import memory as _obs_memory
+    _obs_memory.retire_program(label)
 
 
 #: whether THIS process already paid the warm store's startup directory
@@ -688,18 +694,20 @@ class Executor:
 
     def _post_compile_telemetry(self, compiled, program, label,
                                 feed_shapes, feed_names, fetch_names,
-                                wrapper, warm: bool = False):
+                                wrapper, exe_args, warm: bool = False):
         """Compile-time gauges shared by the step and megastep paths:
         compile histogram, XLA cost/memory gauges, the static planner's
-        estimate beside them, and one occupancy sample (the ``compile`` /
-        ``warm_restore`` span itself is the phase ``_materialize_miss``
-        held open around the work).  ``warm=True`` marks a warm-store
-        restore: the wall time lands in ``warmstore_restore_seconds``
-        under a ``warm_restore`` span (its own goodput cause), NOT in the
-        compile histogram -- a warm fleet's ledger must show restores
-        shrinking where compiles were, and the recompile-count acceptance
-        check reads the compile histogram's count as "programs actually
-        compiled"."""
+        estimate beside them, the state the step takes in by class, and
+        one occupancy sample, which is also the allocator's marks before
+        this program's first run (the ``compile`` / ``warm_restore`` span
+        itself is the phase ``_materialize_miss`` held open around the
+        work; this block runs in its ``post_compile`` span).  ``warm=True``
+        marks a warm-store restore: the wall time lands in
+        ``warmstore_restore_seconds`` under a ``warm_restore`` span (its own
+        goodput cause), NOT in the compile histogram -- a warm fleet's
+        ledger must show restores shrinking where compiles were, and the
+        recompile-count acceptance check reads the compile histogram's
+        count as "programs actually compiled"."""
         if warm:
             _OBS.histogram("warmstore_restore_seconds",
                            "warm-store restore wall time per compile miss"
@@ -715,7 +723,10 @@ class Executor:
         _obs_memory.update_static_memory_gauges(
             program, feed_shapes, feed_names, fetch_names,
             wrapper, label, xla_parts)
-        _obs_memory.sample_device_memory("compile")
+        marks = _obs_memory.sample_device_memory("compile")
+        if xla_parts is not None:   # a step with an executable to ask
+            _obs_memory.note_compiled_step(compiled, program, label,
+                                           exe_args, marks)
         from ..observability import moe as _obs_moe
         _obs_moe.update_moe_gauges(program, label)
         from ..observability import attention as _obs_attention
@@ -795,9 +806,14 @@ class Executor:
         # timing-independent cost/memory gauges are set at compile time,
         # unconditionally (one cost_analysis() per compile); the static
         # planner's estimate lands beside XLA's exact answer
-        self._post_compile_telemetry(compiled, program, label, feed_shapes,
-                                     feed_names, fetch_names, wrapper,
-                                     warm=restored is not None)
+        # (its own span: cost and memory analysis, the planner, the
+        # lowering counters and the attribution hook are the program's
+        # bookkeeping, not the compile)
+        with _phase("post_compile", step=step_idx, program=label):
+            self._post_compile_telemetry(compiled, program, label,
+                                         feed_shapes, feed_names,
+                                         fetch_names, wrapper, exe_args,
+                                         warm=restored is not None)
         if restored is None and ws_store is not None:
             try:
                 self._warmstore_offer(ws_store, ws_key, compiled, exe_args,

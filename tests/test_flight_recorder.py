@@ -191,7 +191,8 @@ def test_span_tree_over_one_executor_run():
         assert run.args["program"].startswith(str(id(main)))
         kids = {s.name for s in _children(spans, run)}
         assert kids == ({"feed_prep", "dispatch"}
-                        | ({"compile"} if was_miss else set()))
+                        | ({"compile", "post_compile"} if was_miss
+                           else set()))
         prep = _only(spans, "feed_prep")
         assert [s.name for s in _children(spans, prep)] == [
             "state_lookup", "h2d"]
@@ -204,7 +205,7 @@ def test_span_tree_over_one_executor_run():
                 "trace_lower"]
             assert _self_time(spans, comp) >= 0
         else:
-            assert not names & {"compile", "trace_lower"}
+            assert not names & {"compile", "trace_lower", "post_compile"}
         # children lie inside their parent, so self time is well defined
         for s in spans:
             for c in _children(spans, s):

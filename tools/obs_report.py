@@ -674,10 +674,14 @@ def render_megastep(events: List[dict],
 
 # ----------------------------------------------------------------- memory --
 
-_MEMORY_FAMILIES = ("device_memory_bytes_in_use", "device_memory_peak_bytes",
-                    "program_peak_bytes", "program_temp_bytes",
-                    "program_argument_bytes", "program_output_bytes",
-                    "program_static_peak_bytes", "program_static_peak_ratio")
+_DEVICE_FAMILIES = ("device_memory_bytes_in_use", "device_memory_peak_bytes")
+_PROGRAM_FAMILIES = ("program_peak_bytes", "program_temp_bytes",
+                     "program_argument_bytes", "program_output_bytes",
+                     "program_alias_bytes", "program_xla_peak_bytes",
+                     "program_static_peak_bytes", "program_static_peak_ratio",
+                     "program_compile_seq", "program_state_bytes",
+                     "program_allocator_bytes")
+_MEMORY_FAMILIES = _DEVICE_FAMILIES + _PROGRAM_FAMILIES
 
 
 def _gb(v: float) -> str:
@@ -697,28 +701,37 @@ def render_memory(snapshot: dict) -> str:
             fams.setdefault(f["name"], {"samples": []})["samples"].extend(
                 f.get("samples", []))
     if not fams:
-        lines.append("(no memory samples; run with PADDLE_TPU_OBS=1 or "
-                     "compile at least one program)")
+        # the program gauges are set at every compile miss, whatever the
+        # environment says; only the per-step samples want PADDLE_TPU_OBS
+        lines.append("(no memory samples: no program has compiled yet)")
         return "\n".join(lines)
-    for name in ("device_memory_bytes_in_use", "device_memory_peak_bytes"):
+    for name in _DEVICE_FAMILIES:
         for s in fams.get(name, {}).get("samples", []):
             dev = s.get("labels", {}).get("device", "?")
             what = "in use" if name.endswith("in_use") else "peak"
             lines.append(f"  {dev}: {_gb(s.get('value', 0.0))} {what}")
     progs = {}
-    for name in ("program_peak_bytes", "program_temp_bytes",
-                 "program_argument_bytes", "program_output_bytes",
-                 "program_static_peak_bytes", "program_static_peak_ratio"):
+    for name in _PROGRAM_FAMILIES:
         for s in fams.get(name, {}).get("samples", []):
-            label = s.get("labels", {}).get("program", "?")
-            progs.setdefault(label, {})[name] = s.get("value", 0.0)
-    for label, parts in sorted(progs.items()):
+            labels = s.get("labels", {})
+            # the second label of a state / allocator gauge names the part
+            part = labels.get("class") or labels.get("stat")
+            progs.setdefault(labels.get("program", "?"), {})[
+                f"{name}:{part}" if part else name] = s.get("value", 0.0)
+    # in the order they compiled: start-up, ..., the train step last
+    for label, parts in sorted(progs.items(), key=lambda kv: (
+            kv[1].get("program_compile_seq", 0.0), kv[0])):
         peak = parts.get("program_peak_bytes", 0.0)
         line = (
             f"  program {label}: peak {_gb(peak)} "
             f"(args {_gb(parts.get('program_argument_bytes', 0.0))}, "
             f"temp {_gb(parts.get('program_temp_bytes', 0.0))}, "
-            f"out {_gb(parts.get('program_output_bytes', 0.0))})")
+            f"out {_gb(parts.get('program_output_bytes', 0.0))}")
+        if "program_alias_bytes" in parts:
+            line += f", aliased {_gb(parts['program_alias_bytes'])}"
+        line += ")"
+        if "program_xla_peak_bytes" in parts:
+            line += f"; XLA's own peak {_gb(parts['program_xla_peak_bytes'])}"
         static = parts.get("program_static_peak_bytes")
         if static is not None:
             # the analysis/memplan.py planner's estimate vs XLA's exact
@@ -728,6 +741,21 @@ def render_memory(snapshot: dict) -> str:
             if ratio:
                 line += f" ({ratio:.2f}x of XLA)"
         lines.append(line)
+        state = [(c, parts[f"program_state_bytes:{c}"]) for c in
+                 ("parameter", "optimizer", "other", "feed")
+                 if f"program_state_bytes:{c}" in parts]
+        if state:
+            lines.append("    takes in, a device: " + ", ".join(
+                f"{c} {_gb(v)}" for c, v in state))
+        marks = [(c, parts[f"program_allocator_bytes:{c}"]) for c in
+                 ("in_use", "peak_in_use", "peak_reserved")
+                 if f"program_allocator_bytes:{c}" in parts]
+        if marks:
+            seq = parts.get("program_compile_seq")
+            lines.append(
+                "    allocator before its first run"
+                + (f" (compile {seq:g})" if seq else "") + ": "
+                + ", ".join(f"{c} {_gb(v)}" for c, v in marks))
     return "\n".join(lines)
 
 
@@ -1080,6 +1108,11 @@ def selftest() -> int:
     reg.gauge("program_temp_bytes", program="1:v0").set(3e8)
     reg.gauge("program_static_peak_bytes", program="1:v0").set(1.8e9)
     reg.gauge("program_static_peak_ratio", program="1:v0").set(1.2)
+    reg.gauge("program_compile_seq", program="1:v0").set(3)
+    reg.gauge("program_state_bytes", program="1:v0",
+              **{"class": "optimizer"}).set(8e8)
+    reg.gauge("program_allocator_bytes", program="1:v0",
+              stat="peak_reserved").set(1.1e9)
     # attribution section sources (observability/attribution.py)
     reg.gauge("hlo_op_bytes", program="1:v0", category="fusion").set(3e8)
     reg.gauge("hlo_op_bytes", program="1:v0", category="layout").set(6.4e7)
