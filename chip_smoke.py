@@ -121,10 +121,13 @@ def newest_step(exe):
 
 
 def mosaic_calls(hlo_text: str):
-    """(forward, backward) counts of Mosaic custom calls in optimized HLO."""
+    """(forward, backward) counts of Mosaic custom calls in optimized HLO:
+    backward where a grad op's scope (``<type>_grad#<idx>``, whether its
+    lowering is the op's own or the generic vjp) or a bare ``jax.vjp``'s
+    transpose holds the call."""
     lines = [ln for ln in hlo_text.splitlines()
              if 'custom_call_target="tpu_custom_call"' in ln]
-    bwd = sum(1 for ln in lines if "transpose(" in ln)
+    bwd = sum(1 for ln in lines if "transpose(" in ln or "_grad#" in ln)
     return len(lines) - bwd, bwd
 
 
@@ -349,7 +352,10 @@ def phase_kernels(sizes, ctx):
     # Three times: BERT's shape (d=64, padding bias, dropout) at S=2048 and
     # at S=512 (the kernels' shortest cell), and the decoder's (causal,
     # d=128, S=4096, no bias, no dropout), where the identity holds only if
-    # both kernels mask the same triangle.
+    # both kernels mask the same triangle. And each time two ways: through
+    # jax.vjp of the kernels (the custom VJP, its own statistics) and
+    # through a Program, whose grad op draws the forward op's seed again
+    # and hands the forward op's Lse to the backward kernel.
     from paddle_tpu.ops import pallas_attention as pa
 
     def adjoint(b, heads, seq, d, dropout, causal, padded):
@@ -368,21 +374,63 @@ def phase_kernels(sizes, ctx):
             return pa.composed_attention(q, k, v, bias, d ** -0.5, dropout,
                                          causal, jax.random.PRNGKey(11))
 
-        @jax.jit
-        def adjoint_sides(v2, g):
+        def through_vjp(v2, g):
             out, vjp = jax.vjp(attend, v2)
+            return out, vjp(g)[0]
+
+        def through_program(v2, g):
+            """(out, dV) of one run of a Program: the fused_attention op and
+            the fused_attention_grad op that append_backward gives it."""
+            main, startup = fluid.Program(), fluid.Program()
+            feed = {"q": q, "k": k, "v": v2, "g": g}
+            if padded:
+                feed["bias"] = bias
+            with fluid.unique_name.guard(), \
+                    fluid.program_guard(main, startup):
+                data = {n: fluid.data(n, list(x.shape), str(x.dtype),
+                                      append_batch_size=False)
+                        for n, x in feed.items()}
+                data["v"].stop_gradient = False
+                v = fluid.layers.scale(data["v"], 1.0)
+                out = fluid.layers.fused_attention(
+                    data["q"], data["k"], v, bias=data.get("bias"),
+                    scale=d ** -0.5, dropout_prob=dropout, causal=causal)
+                cast = fluid.layers.cast
+                fluid.append_backward(fluid.layers.reduce_sum(
+                    fluid.layers.elementwise_mul(cast(out, "float32"),
+                                                 cast(data["g"], "float32"))))
+            exe = fluid.Executor()
+            with fluid.scope_guard(fluid.Scope()):
+                exe.run(startup)
+                got = exe.run(main, feed=feed, fetch_list=[out, "v@GRAD"])
+                step = newest_step(exe).executable.as_text()
+            exe.close()
+            if on_tpu:
+                assert mosaic_calls(step) == (1, 1), \
+                    f"a Program's attention at {(b, heads, seq, d)} holds " \
+                    f"{mosaic_calls(step)} (forward, backward) kernels"
+            return jnp.asarray(got[0]), jnp.asarray(got[1])
+
+        @jax.jit
+        def adjoint_sides(out, dv, v2, g):
             f32 = jnp.float32
             lhs = g.astype(f32) * out.astype(f32)
-            rhs = vjp(g)[0].astype(f32) * v2.astype(f32)
+            rhs = dv.astype(f32) * v2.astype(f32)
             return (lhs.sum(), rhs.sum(),
                     jnp.sqrt((lhs * lhs).sum()) + jnp.sqrt((rhs * rhs).sum()))
 
-        lhs, rhs, norms = (float(x) for x in adjoint_sides(v2, g))
-        assert abs(lhs - rhs) <= 2.0 ** -8 * norms, \
-            f"flash forward and backward disagree at {(b, heads, seq, d)} " \
-            f"causal={causal}: <g, f(v2)> {lhs} vs <dV(g), v2> {rhs}, " \
-            f"allowed {2.0 ** -8 * norms}"
-        return {"lhs": lhs, "rhs": rhs, "allowed": 2.0 ** -8 * norms}
+        fact = {}
+        for way, sides in (("vjp", jax.jit(through_vjp)),
+                           ("program", through_program)):
+            lhs, rhs, norms = (float(x) for x in adjoint_sides(
+                *sides(v2, g), v2, g))
+            assert abs(lhs - rhs) <= 2.0 ** -8 * norms, \
+                f"flash forward and backward disagree at " \
+                f"{(b, heads, seq, d)} causal={causal} through {way}: " \
+                f"<g, f(v2)> {lhs} vs <dV(g), v2> {rhs}, " \
+                f"allowed {2.0 ** -8 * norms}"
+            fact[way] = {"lhs": lhs, "rhs": rhs, "allowed": 2.0 ** -8 * norms}
+        return fact
 
     heads = sizes["bert"]["n_heads"]
     facts["flash_dropout_adjoint"] = adjoint(

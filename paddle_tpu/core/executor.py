@@ -732,6 +732,8 @@ class Executor:
         from ..observability import attention as _obs_attention
         _obs_attention.count_lowerings(
             program._lowering_notes.pop("fused_attention", {}), label)
+        _obs_attention.count_backwards(
+            program._lowering_notes.pop("fused_attention_grad", {}), label)
         from ..observability import masks as _obs_masks
         # takes the kinds it counts (mask_draw, gather_layout) out of them
         _obs_masks.count_data_axis(program._lowering_notes, label)

@@ -13,11 +13,17 @@ Design (deliberately different from the reference):
     automatically from the lowering with ``jax.eval_shape`` -- single source of truth.
     -1 (dynamic batch) dims are substituted with a sentinel prime and mapped back.
   * Grad ops (the reference's GradOpDescMakerBase, grad_op_desc_maker.h) are derived
-    automatically with ``jax.vjp`` over the forward lowering: every op type T gets a
-    generic "T_grad" whose lowering recomputes T's forward under vjp. XLA CSE/fusion
-    dedups the recompute against the forward pass, which doubles as free
-    rematerialization. Ops may override with a custom grad maker (``grad=callable``) or
-    declare themselves non-differentiable (``grad=None``).
+    automatically with ``jax.vjp`` over the forward lowering: an op type T that
+    registers nothing else gets a generic "T_grad" whose lowering recomputes T's
+    forward under vjp. XLA CSE/fusion dedups the recompute against the forward pass,
+    which doubles as free rematerialization -- as long as no output of that second
+    forward is read. An op whose backward wants something its forward knew declares
+    it as an output (``nondiff_outputs``: a Program variable like any other; the grad
+    op desc carries every forward output as an input) and registers a "T_grad"
+    lowering of its own with ``register_grad``, which reads it and lowers no forward
+    (``fused_attention``'s softmax statistics, ops/pallas_attention.py). Ops may also
+    override with a custom grad maker (``grad=callable``) or declare themselves
+    non-differentiable (``grad=None``).
 
 Empty-var convention: the name ``@EMPTY@`` in an op's input list means "no tensor here"
 (the reference's kEmptyVarName); the executor feeds None and lowerings must cope
@@ -210,6 +216,29 @@ def get(type: str) -> OpDef:
         f"op type {type!r} is not registered in paddle_tpu "
         f"({len(_REGISTRY)} ops registered). If this is a reference op not yet "
         f"ported, add a lowering in paddle_tpu/ops/.")
+
+
+def register_grad(fwd_type: str):
+    """Decorator: register ``fn(ctx, ins, generic) -> outs`` as the lowering
+    of ``<fwd_type>_grad`` in place of the generic vjp one. ``ins`` holds
+    what the generic grad op's would (the forward's input slots, its output
+    slots -- those the op declared for its backward among them -- and the
+    "<OutSlot>@GRAD" cotangents; outputs are "<InSlot>@GRAD"), and
+    ``generic()`` is the generic lowering on the same ``ctx`` and ``ins``,
+    for the cases ``fn`` has no path of its own for. Shapes, the desc maker
+    and second-order gradients are the generic grad op's."""
+
+    def deco(fn):
+        fwd = _REGISTRY[fwd_type]
+
+        def lower(ctx, ins):
+            return fn(ctx, ins, lambda: _generic_grad_lower(fwd, ctx, ins))
+
+        register(fwd_type + "_grad", infer_shape=_grad_infer_shape)(
+            functools.wraps(fn)(lower))
+        return fn
+
+    return deco
 
 
 def registered_types() -> List[str]:

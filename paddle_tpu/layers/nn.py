@@ -1064,14 +1064,18 @@ def fused_attention(q, k, v, bias=None, scale=None, dropout_prob=0.0,
     to one flash-attention Pallas kernel on TPU (ops/pallas_attention.py); the
     composed softmax(QK^T)V path otherwise. Reference analog: the subgraph that
     multihead_matmul_fuse_pass.cc:1 pattern-matches, exposed as one op.
+    The op has a second output, ``Lse`` [B, heads, 1, S] float32: the rows'
+    softmax statistics, which the flash kernels write for the op's own
+    backward (no gradient flows through it; the layer returns ``Out``).
     """
     helper = LayerHelper("fused_attention", name=name)
     out = _out(helper, q.dtype)
+    lse = _out(helper, "float32", stop_gradient=True)
     inputs = {"Q": [q], "K": [k], "V": [v]}
     if bias is not None:
         inputs["Bias"] = [bias]
     helper.append_op("fused_attention", inputs=inputs,
-                     outputs={"Out": [out]},
+                     outputs={"Out": [out], "Lse": [lse]},   # Out first: the op's salt
                      attrs={"scale": float(scale) if scale else 0.0,
                             "dropout_prob": float(dropout_prob),
                             "causal": bool(causal), "is_test": bool(is_test),

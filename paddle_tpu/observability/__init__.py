@@ -39,7 +39,9 @@ reproduction gets the counterpart the whole-program-jit design enables:
 - ``attention`` -- ``attention_lowering_total{program,impl,s,block_q,block_k,
   kv_heads}``: the lowering each ``fused_attention`` op of a compiled program
   took; ``attention_k_tiles_total{program,state}``: the K tiles its flash
-  kernels visit and skip.
+  kernels visit and skip; ``attention_backward_total{program,stats}``: where
+  each of its grad ops got the softmax statistics (``saved`` / ``recomputed``
+  / ``generic``).
 
 Render everything with ``python -m tools.obs_report``.
 """

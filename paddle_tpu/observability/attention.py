@@ -6,7 +6,8 @@ The op notes its choice while the executor traces it (``ctx.note`` in
 lowers again under ``jax.vjp`` lands on the same key), and the executor hands
 the notes of the compile it just made to ``count_lowerings``;
 ``autotune_decisions_total{choice,source}`` says beside it whether a default
-or a persisted decision answered.
+or a persisted decision answered. The op's grad op notes where its softmax
+statistics came from (``count_backwards``).
 """
 from __future__ import annotations
 
@@ -45,3 +46,22 @@ def count_lowerings(notes: dict, program: str,
                     "K tiles a (batch, head) of the compiled flash-attention "
                     "ops' forward kernels", program=program,
                     state=state).inc(n * tiles)
+
+
+def count_backwards(notes: dict, program: str,
+                    registry: Optional[MetricsRegistry] = None) -> None:
+    """``attention_backward_total{program,stats}``: the ``fused_attention_grad``
+    ops the trace just compiled, by where the backward got the rows' softmax
+    statistics. ``saved``: the backward kernel read the forward op's ``Lse``
+    (no forward lowered in the grad op); ``recomputed``: the kernels, on a
+    desc without ``Lse`` (the generic grad, whose vjp lowers the forward
+    kernel again for them; XLA merges that call with the forward op's); ``generic``: ``jax.vjp`` over another lowering (XLA's
+    composed one, a mesh's island). ``notes`` maps each op's salt to its
+    label; nothing is added for a program without the grad op (a test
+    clone)."""
+    registry = registry or REGISTRY
+    for stats, n in Counter(notes.values()).items():
+        registry.counter(
+            "attention_backward_total",
+            "fused_attention_grad ops compiled, by where the softmax "
+            "statistics came from", program=program, stats=stats).inc(n)

@@ -42,21 +42,37 @@ Design:
     tiles a Q block keeps between its passes (temporaries with one tile, a
     scratch with more). The forward fits Mosaic's 16 MiB of scoped VMEM to
     S=4096 at block_q 512 and to S=8192 at block_q 128 and one tile; the
-    backward takes BWD_VMEM_LIMIT_BYTES and compiles to S=4096 (S=8192 at
-    block_q 128 and one tile only; compiled for a described v5e). Longer
-    rows need K/V staged by tile too (ROADMAP S1 (b)).
-  * Backward is a custom-VJP Pallas kernel that *recomputes* the probabilities
-    per Q block from q, k, bias alone (flash-style: FLOPs are cheap, HBM is
-    not; and a residual the forward kernel wrote would make a Program's grad
-    op run that kernel a second time, see _flash_fwd). p needs its row's max
-    and sum before any tile of the row can be formed, so the Q block's score
-    tiles stay in VMEM over three passes (max; exp, sum and dP; dS and the
-    products): one exp a score and five products, where a second pass that
-    recomputes them pays two and seven (15% and 11% slower at the two
-    decoders' shapes; chip runs, PR 34). dK^T and dV^T [D, block_k] a K tile
-    accumulate across the Q blocks in f32 VMEM scratch and leave in the
-    input's dtype on the last one: grid axis 1 is declared "arbitrary"
-    (sequential) for that, axis 0 "parallel".
+    backward takes BWD_VMEM_LIMIT_BYTES and compiles to S=4096, and at
+    S=8192 at block_q 128 or 256 and one tile and in CAUSAL_BLOCKS' tiles
+    (where the forward, its stage 16 MB, does not; compiled for a described
+    v5e). Longer rows need K/V staged by tile too (ROADMAP S1 (b)).
+  * The forward kernel writes, beside the output, each row's softmax
+    statistic ``lse = max + log(sum)``: one float32 a row, [B, H, 1, S],
+    compact in HBM (a [block_q, 1] column is turned into a [1, block_q] row
+    in the kernel: _as_row). The backward kernel reads it and forms a
+    tile's probabilities as ``exp(s - lse)``: no pass for the row's max,
+    none for its sum, no 1/sum (PR 38; the three passes it made before, max
+    / exp, sum and dP / dS and the products, are two: p, dP and the row's
+    sum of dP P; dS and the products -- with one tile a row a single pass
+    with one cross-lane reduction). One exp a score and five products;
+    the p and dP tiles of a Q block stay in VMEM between the passes (a
+    second pass that recomputes them pays two exps and seven products: 15%
+    and 11% slower at the two decoders' shapes, chip runs, PR 34). dK^T and
+    dV^T [D, block_k] a K tile accumulate across the Q blocks in f32 VMEM
+    scratch and leave in the input's dtype on the last one: grid axis 1 is
+    declared "arbitrary" (sequential) for that, axis 0 "parallel".
+    How the statistic reaches the backward: the op declares it as an
+    output (``Lse``) and registers its own grad lowering
+    (fused_attention_grad), which calls the backward kernel on the forward
+    op's Lse and lowers no forward. The registry's generic grad op lowers
+    the forward a second time under jax.vjp, and XLA drops that copy only
+    while none of its outputs is read: a statistic handed over as a
+    custom-VJP residual made a Program run 24 forward kernels for 12 layers
+    (chip runs, PR 25). The custom VJP (_flash: a direct jax.vjp, the
+    tuner) keeps lse as its residual all the same: nothing lowers a second
+    forward there. A row every key of which is biased by -1e30 reads
+    lse = -1e30 (log S is under float32's spacing there), so the backward
+    forms p = 1 for it, not 1/S: such a row has no key to attend to.
   * Attention dropout uses the in-kernel PRNG (pltpu.prng_random_bits) seeded
     per (step, batch*head, 128-row block, K tile); the backward kernel
     reseeds identically so the mask matches without storing it, and the mask
@@ -70,7 +86,7 @@ from __future__ import annotations
 import functools
 import math
 
-from ..core.registry import register
+from ..core.registry import register, register_grad
 
 # The smallest Q block. Every S the kernel takes is a multiple of it
 # (supports_pallas), every block_q too, and the dropout mask is drawn by
@@ -103,9 +119,11 @@ BLK_Q = 256
 #   (1024, 1024)         no fit / 6.79 (*)  no fit / 13.13 (*)
 # (*) before the backward's dQ product moved ahead of its dK^T / dV^T
 # accumulations, which took 8% (d=128) and 12% (d=64) off the backward at
-# (512, 512). A loop iteration costs about 0.2 us whatever the tile holds,
-# three iterations a tile in the backward: small tiles visit 53% of the square
-# and lose it again; 512 x 1024 visits 62.5%.
+# (512, 512). A loop iteration costs about 0.2 us whatever the tile holds
+# (three iterations a tile in the backward then, two since it reads the
+# forward's lse, PR 38: 6.37 -> 5.60 and 11.22 -> 9.85 ms at (512, 1024)):
+# small tiles visit 53% of the square and lose it again; 512 x 1024 visits
+# 62.5%.
 CAUSAL_BLOCKS = (512, 1024)
 CAUSAL_TILES_MIN_S = 2048
 
@@ -312,6 +330,22 @@ def _plus(acc, x):
     return x if acc is None else acc + x
 
 
+def _as_row(col):
+    """A row statistic [block_q, 1] (a value a sublane) as [1, block_q] (a
+    value a lane): how it lies in HBM, ``[B * H, 1, S]`` float32, compact. A
+    128-lane copy a row is what Mosaic would store without a relayout, 512
+    bytes for 4; the transpose of one [block_q, 128] tile is nothing beside
+    a Q block's [block_q, S] scores."""
+    import jax.numpy as jnp
+    return jnp.broadcast_to(col, (col.shape[0], _LANES)).T[:1]
+
+
+def _as_col(row):
+    """[1, block_q] as it lies in HBM back to [block_q, 1]."""
+    import jax.numpy as jnp
+    return jnp.broadcast_to(row, (_LANES, row.shape[1])).T[:, :1]
+
+
 class _KTiles:
     """The K tiles one Q block visits, in passes. Tiles [0, clear) lie wholly
     at or under Q block ``iq``'s diagonal and take no mask, [clear, visited)
@@ -383,8 +417,8 @@ class _KTiles:
         return jnp.full((self.block_q, _LANES), value, jnp.float32)
 
     def row_max(self, scores):
-        """Pass 1 of both kernels: every visited tile's scores into stage 0,
-        and the row maxima [block_q, 1]. ``scores(masked, t)``."""
+        """Pass 1 of the forward kernel: every visited tile's scores into
+        stage 0, and the row maxima [block_q, 1]. ``scores(masked, t)``."""
         import jax.numpy as jnp
 
         def body(masked, t, m):
@@ -399,17 +433,19 @@ class _KTiles:
 
 def _fwd_kernel(scale, dropout, causal, has_bias, block_k, *refs):
     """One Q block against its K tiles in two passes: (1) scores, row max;
-    (2) exp, row sum and the product with V."""
+    (2) exp, row sum and the product with V. Beside the output it writes the
+    rows' softmax statistic, ``lse = max + log(sum)``, one float32 a row: all
+    the backward needs to form the probabilities again."""
     import jax.numpy as jnp
     pl, _ = _pl()
     refs = list(refs)
     q_ref, k_ref, v_ref = refs[:3]
     bias_ref = refs[3] if has_bias else None
-    seed_ref, o_ref = refs[3 + has_bias:5 + has_bias]
+    seed_ref, o_ref, lse_ref = refs[3 + has_bias:6 + has_bias]
     iq = pl.program_id(1)
     blk_q, D = q_ref.shape[1:]
     tiles = _KTiles(iq, blk_q, block_k, k_ref.shape[1] // block_k, causal,
-                    refs[5 + has_bias:])
+                    refs[6 + has_bias:])
     q_s = _fold_scale(q_ref[0], scale)
     m = tiles.row_max(lambda masked, t: _scores(
         q_s, k_ref, bias_ref, iq, t, block_k, scale, masked))
@@ -430,6 +466,7 @@ def _fwd_kernel(scale, dropout, causal, has_bias, block_k, *refs):
     # the softmax's 1/l and the dropout's 1/(1-prob) scale the [block_q, D]
     # product, one reciprocal a row, not the [block_q, S] probabilities
     o_ref[0] = (o * (1.0 / (l * (1.0 - dropout)))).astype(o_ref.dtype)
+    lse_ref[0] = _as_row(m + jnp.log(l))
 
 
 def _bwd_kernel(scale, dropout, causal, has_bias, group, block_k, *refs):
@@ -439,17 +476,19 @@ def _bwd_kernel(scale, dropout, causal, has_bias, group, block_k, *refs):
     key/value head's gradient is written once (no per-query-head dK, dV in
     HBM to sum afterwards).
 
-    Nothing comes from the forward, and p needs its row's max and sum: three
-    passes over the Q block's K tiles. (1) scores, row max; (2) exp, row sum,
-    dP = dO V^T and the row's sum of dP P; (3) dS and the three gradient
-    products."""
+    The rows' ``lse`` comes from the forward kernel, so a tile's
+    probabilities are ``exp(s - lse)`` as soon as its scores are: no pass
+    for the row's max, none for its sum, no 1/sum. Two passes over the Q
+    block's K tiles: (1) scores, p, dP = dO V^T and the row's sum of dP P;
+    (2) dS and the three gradient products. With one tile a row that is a
+    single pass with one cross-lane reduction."""
     import jax.numpy as jnp
     pl, _ = _pl()
     refs = list(refs)
     q_ref, k_ref, v_ref = refs[:3]
     bias_ref = refs[3] if has_bias else None
-    seed_ref, do_ref, dq_ref, dk_ref, dv_ref, dkt_acc, dvt_acc = \
-        refs[3 + has_bias:10 + has_bias]
+    (seed_ref, do_ref, lse_ref, dq_ref, dk_ref, dv_ref, dkt_acc,
+     dvt_acc) = refs[3 + has_bias:11 + has_bias]
     step = iq = pl.program_id(1)
     bh = None
     if group > 1:
@@ -459,11 +498,10 @@ def _bwd_kernel(scale, dropout, causal, has_bias, group, block_k, *refs):
     dtype = q_ref.dtype
     blk_q, D = q_ref.shape[1:]
     n_k = k_ref.shape[1] // block_k
-    tiles = _KTiles(iq, blk_q, block_k, n_k, causal, refs[10 + has_bias:])
+    tiles = _KTiles(iq, blk_q, block_k, n_k, causal, refs[11 + has_bias:])
     q_s = _fold_scale(q_ref[0], scale)
     do = do_ref[0]                                           # [BLK_Q, D]
-    m = tiles.row_max(lambda masked, t: _scores(
-        q_s, k_ref, bias_ref, iq, t, block_k, scale, masked))
+    lse = _as_col(lse_ref[0])                                # [BLK_Q, 1]
 
     # Dropout multiplies p and its gradient by keep * c, c = 1/(1-prob). The
     # mask is applied to dp and to p; c is a constant of every product, so
@@ -474,29 +512,21 @@ def _bwd_kernel(scale, dropout, causal, has_bias, group, block_k, *refs):
         return _keep_mask((blk_q, block_k), seed_ref, iq, dropout, bh, t)
     if tiles.whole:
         keep = functools.lru_cache(None)(keep)               # drawn once
-    # (with more tiles passes 2 and 3 each draw a tile's mask: the draw is
-    # cheaper than a third staged tile)
+    # (with more tiles each pass draws a tile's mask: the draw is cheaper
+    # than a third staged tile)
 
-    def softmax(masked, t, carry):
-        e = jnp.exp(tiles.get(0, t) - m)
-        l_t = tiles.stat(e, jnp.sum)
-        if tiles.whole:
-            e = e * (1.0 / l_t)                          # p: the row is here
+    def softmax(masked, t, r):
+        p = jnp.exp(_scores(q_s, k_ref, bias_ref, iq, t, block_k, scale,
+                            masked) - lse)
         dp = _dot(do, _rows(v_ref, t, block_k), _NT)         # [BLK_Q, blk_k]
         if dropout:
             dp = jnp.where(keep(t), dp, 0.0)
-        tiles.put(0, t, e)
+        tiles.put(0, t, p)
         tiles.put(1, t, dp)
-        l, r = carry or (None, None)
-        return _plus(l, l_t), _plus(r, tiles.stat(dp * e, jnp.sum))
+        return _plus(r, tiles.stat(dp * p, jnp.sum))
 
-    l, r = tiles.passes(softmax, lambda: (tiles.stat_init(0.0),
-                                          tiles.stat_init(0.0)))
-    if tiles.whole:
-        row = r                                              # sum of dp * p
-    else:
-        inv_l = 1.0 / tiles.total(l, jnp.sum)
-        row = tiles.total(r, jnp.sum) * inv_l
+    row = tiles.total(tiles.passes(
+        softmax, lambda: tiles.stat_init(0.0)), jnp.sum)     # sum of dp * p
 
     @pl.when(step == 0)
     def _():
@@ -505,8 +535,6 @@ def _bwd_kernel(scale, dropout, causal, has_bias, group, block_k, *refs):
 
     def grads(masked, t, dq):
         p = tiles.get(0, t)                                  # f32
-        if not tiles.whole:
-            p = p * inv_l
         pk = jnp.where(keep(t), p, 0.0) if dropout else p
         ds = (p * (tiles.get(1, t) - row)).astype(dtype)
         dq = _plus(dq, _dot(ds, _rows(k_ref, t, block_k), _NN))
@@ -529,14 +557,14 @@ def _bwd_kernel(scale, dropout, causal, has_bias, group, block_k, *refs):
 
 
 def _operands(q, k, v, bias, seed, block_q, block_k, by_kv_head=False):
-    """The kernels' common operands and block specs, and the number of Q
-    blocks (block_q and block_k divide S: _flash has seen to it). K and V
-    rows are staged whole, a row's bias by K tile. Grid axis 0 is the
-    query's batch*head and axis 1 the Q block; with fewer key/value heads
-    than query heads (k, v ``[B, Hkv, S, D]``) a query head's program reads
-    its key/value head's rows in place. ``by_kv_head`` (the grouped
-    backward): axis 0 is the key/value's batch*head, axis 1 the group's
-    heads x Q blocks."""
+    """The kernels' common operands and block specs, the spec of a row
+    statistic and the number of Q blocks (block_q and block_k divide S:
+    _flash_stats has seen to it). K and V rows are staged whole, a row's
+    bias by K tile. Grid axis 0 is the query's batch*head and axis 1 the Q
+    block; with fewer key/value heads than query heads (k, v ``[B, Hkv, S,
+    D]``) a query head's program reads its key/value head's rows in place.
+    ``by_kv_head`` (the grouped backward): axis 0 is the key/value's
+    batch*head, axis 1 the group's heads x Q blocks."""
     import jax.numpy as jnp
     pl, pltpu = _pl()
     B, H, S, D = q.shape
@@ -556,6 +584,11 @@ def _operands(q, k, v, bias, seed, block_q, block_k, by_kv_head=False):
                                   lambda b, i: (b // group, 0, 0), H)
     qspec = pl.BlockSpec((1, block_q, D), q_at, memory_space=pltpu.VMEM)
     kvspec = pl.BlockSpec((1, S, D), kv_at, memory_space=pltpu.VMEM)
+    # a Q block's rows of lse [B * H, 1, S]: q's block with the rows along
+    # the lanes (the middle 1 is the array's whole dim, as Mosaic wants)
+    lse_spec = pl.BlockSpec(
+        (1, 1, block_q), lambda b, i: (q_at(b, i)[0], 0, q_at(b, i)[1]),
+        memory_space=pltpu.VMEM)
     in_specs = [qspec, kvspec, kvspec]
     if bias is not None:
         # [B, n_k, 1, block_k] with block (1, n_k, 1, block_k): the last two
@@ -568,7 +601,7 @@ def _operands(q, k, v, bias, seed, block_q, block_k, by_kv_head=False):
             memory_space=pltpu.VMEM))
     args.append(jnp.asarray(seed, jnp.int32).reshape(1))
     in_specs.append(pl.BlockSpec(memory_space=pltpu.SMEM))
-    return args, in_specs, qspec, kvspec, n_q
+    return args, in_specs, qspec, kvspec, lse_spec, n_q
 
 
 def _stages(n, S, block_q, block_k):
@@ -595,16 +628,21 @@ def _compiler_params(interpret, vmem_limit_bytes=None):
 
 import jax as _jax  # custom_vjp and jit must wrap at def time
 
-# _flash's arguments after the arrays, all static
+# the kernel calls' arguments after the arrays, all static
 _STATIC = ("scale", "dropout", "causal", "interpret", "block_q", "block_k")
 
 
 def _flash(q, k, v, bias, seed, scale, dropout, causal, interpret,
            block_q=None, block_k=None):
-    """The flash kernels, differentiable in q, k and v. ``block_q`` (Q rows a
-    grid step) and ``block_k`` (columns a K tile, both kernels) divide S;
-    None takes ``default_block_q`` / ``default_block_k``."""
-    S = q.shape[2]
+    """The flash kernels, differentiable in q, k and v: _flash_stats' output
+    alone."""
+    return _flash_stats(q, k, v, bias, seed, scale, dropout, causal,
+                        interpret, block_q, block_k)[0]
+
+
+def _blocks(S, causal, block_q=None, block_k=None):
+    """(block_q, block_k) as given, None taking ``default_block_q`` /
+    ``default_block_k``; both must divide S in multiples of _MIN_BLK_Q."""
     if block_q is None:
         block_q = default_block_q(S, causal)
     if block_k is None:
@@ -614,8 +652,19 @@ def _flash(q, k, v, bias, seed, scale, dropout, causal, interpret,
             raise ValueError(
                 f"flash attention: {name}={block} must divide S={S} and be "
                 f"a multiple of {_MIN_BLK_Q}")
+    return block_q, block_k
+
+
+def _flash_stats(q, k, v, bias, seed, scale, dropout, causal, interpret,
+                 block_q=None, block_k=None):
+    """(out, lse) of the flash kernels: the output, differentiable in q, k
+    and v, and the rows' softmax statistic ``lse`` [B, H, 1, S] float32 (the
+    log of the sum of exp over a row's scores, bias and mask included),
+    which carries no gradient: it is what _bwd_call reads in place of a
+    pass for the max and the sum. ``block_q`` (Q rows a grid step) and
+    ``block_k`` (columns a K tile, both kernels) divide S."""
     return _flash_vjp(q, k, v, bias, seed, scale, dropout, causal, interpret,
-                      block_q, block_k)
+                      *_blocks(q.shape[2], causal, block_q, block_k))
 
 
 @functools.partial(_jax.custom_vjp, nondiff_argnums=(5, 6, 7, 8, 9, 10))
@@ -627,59 +676,70 @@ def _flash_vjp(q, k, v, bias, seed, scale, dropout, causal, interpret,
 
 # Each kernel call sits behind a jit of its own. The layers of a model call
 # it with the same shapes and static arguments: the first call traces the
-# kernel body and lowers it to Mosaic, the others (and the forward a
-# Program's grad op traces again under jax.vjp) find that trace, and the
+# kernel body and lowers it to Mosaic, the others find that trace, and the
 # lowered module holds one function a kernel, called once a layer. Without
 # it every call is traced and lowered by itself: 4 s of set-up at 12 layers
 # (compile.trace_lower_s 8.3 against 4.4 s, ledger, PR 26).
 @functools.partial(_jax.jit, static_argnames=_STATIC)
 def _fwd_call(q, k, v, bias, seed, scale, dropout, causal, interpret,
               block_q, block_k):
+    """(out [B, H, S, D], lse [B, H, 1, S] float32: the kernel's own
+    [B * H, 1, S] with the leading dim split, which costs XLA nothing; as
+    [B, H, S] it is a relayout copy each way, a row of S lanes a head
+    being tiled (1, 128) and a [H, S] matrix (8, 128))."""
     import jax
+    import jax.numpy as jnp
     pl, _ = _pl()
     B, H, S, D = q.shape
-    args, in_specs, qspec, _, n_q = _operands(q, k, v, bias, seed, block_q,
-                                              block_k)
-    out = pl.pallas_call(
+    args, in_specs, qspec, _, lse_spec, n_q = _operands(
+        q, k, v, bias, seed, block_q, block_k)
+    out, lse = pl.pallas_call(
         functools.partial(_fwd_kernel, scale, dropout, causal,
                           bias is not None, block_k),
         grid=(B * H, n_q),
         in_specs=in_specs,
-        out_specs=qspec,
-        out_shape=jax.ShapeDtypeStruct((B * H, S, D), q.dtype),
+        out_specs=[qspec, lse_spec],
+        out_shape=[jax.ShapeDtypeStruct((B * H, S, D), q.dtype),
+                   jax.ShapeDtypeStruct((B * H, 1, S), jnp.float32)],
         scratch_shapes=_stages(1, S, block_q, block_k),
         interpret=interpret,
         **_compiler_params(interpret),
     )(*args)
-    return out.reshape(B, H, S, D)
+    return out.reshape(B, H, S, D), lse.reshape(B, H, 1, S)
 
 
 def _flash_fwd(q, k, v, bias, seed, scale, dropout, causal, interpret,
                block_q, block_k):
-    out = _fwd_call(q, k, v, bias, seed, scale, dropout, causal, interpret,
-                    block_q, block_k)
-    # Inputs only. A Program's generic grad op (core/registry.py) lowers the
-    # forward again under jax.vjp: a kernel output among the residuals (a
-    # log-sum-exp, say) keeps that second forward kernel alive, which costs
-    # five times what it saves the backward (chip runs, PR 25).
-    return out, (q, k, v, bias, seed)
+    out, lse = _fwd_call(q, k, v, bias, seed, scale, dropout, causal,
+                         interpret, block_q, block_k)
+    # The inputs and the rows' statistic. Under a direct jax.vjp (the tests,
+    # the tuner) nothing lowers a second forward. A Program's grad op does
+    # not come here where its op declares Lse (fused_attention_grad calls
+    # _bwd_call with the forward op's own); one that does (a desc older
+    # than that output) lowers the forward kernel a second time for it,
+    # which XLA merges with the forward op's own call: same kernel, same
+    # operands (24 Mosaic calls for 12 layers either way; compiled for a
+    # described v5e, tests/test_pallas_attention_mosaic.py).
+    return (out, lse), (q, k, v, bias, seed, lse)
 
 
 @functools.partial(_jax.jit, static_argnames=_STATIC)
-def _bwd_call(q, k, v, bias, seed, g, scale, dropout, causal, interpret,
+def _bwd_call(q, k, v, bias, seed, g, lse, scale, dropout, causal, interpret,
               block_q, block_k):
+    """(dq, dk, dv) from the cotangent ``g`` of the output and the forward
+    kernel's ``lse`` (same blocks, same seed: the mask is drawn again)."""
     import jax
     import jax.numpy as jnp
     pl, pltpu = _pl()
     B, H, S, D = q.shape
     kv = k.shape[1]
     group = H // kv
-    args, in_specs, qspec, kvspec, n_q = _operands(
+    args, in_specs, qspec, kvspec, lse_spec, n_q = _operands(
         q, k, v, bias, seed, block_q, block_k, by_kv_head=True)
-    args.append(g.reshape(B * H, S, D))
-    in_specs.append(qspec)
-    # dK^T, dV^T by K tile; with more tiles than one, the score and dP tiles
-    # a Q block keeps between its passes
+    args += [g.reshape(B * H, S, D), lse.reshape(B * H, 1, S)]
+    in_specs += [qspec, lse_spec]
+    # dK^T, dV^T by K tile; with more tiles than one, the p and dP tiles a
+    # Q block keeps between its passes
     scratch = ([pltpu.VMEM((S // block_k, D, block_k), jnp.float32)] * 2
                + _stages(2, S, block_q, block_k))
     dq, dk, dv = pl.pallas_call(
@@ -702,9 +762,9 @@ def _flash_bwd(scale, dropout, causal, interpret, block_q, block_k, res, g):
     import jax
     import jax.numpy as jnp
     import numpy as np
-    q, k, v, bias, seed = res
-    dq, dk, dv = _bwd_call(q, k, v, bias, seed, g, scale, dropout, causal,
-                           interpret, block_q, block_k)
+    q, k, v, bias, seed, lse = res
+    dq, dk, dv = _bwd_call(q, k, v, bias, seed, g[0], lse, scale, dropout,
+                           causal, interpret, block_q, block_k)
     return (dq, dk, dv, None if bias is None else jnp.zeros_like(bias),
             np.zeros(np.shape(seed), jax.dtypes.float0))
 
@@ -729,53 +789,21 @@ def supports_pallas(B, H, S, D, bias_shape, dropout, is_tpu):
 # registry op
 # --------------------------------------------------------------------------------------
 
-@register("fused_attention", nondiff_inputs=("Bias",))
-def fused_attention(ctx, ins):
-    """softmax(Q K^T * scale + Bias) V.
-
-    Inputs: Q [B, heads, S, D], K/V [B, kv_heads, S, D] with kv_heads
-    dividing heads (grouped-query attention: query head i reads key/value
-    head i // (heads / kv_heads), in place -- no lowering repeats K or V);
-    optional Bias [B, 1, 1, S] additive (already -inf-masked). Attrs: scale (default 1/sqrt(D)), dropout_prob, causal,
-    is_test, impl ('auto' | 'pallas' | 'ring' | 'ulysses' | 'composed').
-
-    Kernel choice: under a GSPMD jit whose mesh has an "sp" axis >1 (sequence
-    parallelism), 'auto' opens the ring-attention shard_map island
-    (parallel/ring_attention.py) so the sequence dim STAYS partitioned --
-    GSPMD alone would all-gather K/V to every device; 'ulysses' instead does
-    the all-to-all head-scatter schedule (parallel/ulysses.py, needs heads
-    divisible by sp). Otherwise 'auto' is the Pallas flash kernel on
-    TPU-supported shapes from S >= AUTO_PALLAS_MIN_S (at S=128 XLA's own
-    fusion is measurably faster) where the jit spans one device, else the
-    composed jnp path (a dp or mp mesh without sp: a Mosaic call cannot be
-    partitioned by GSPMD). Which one an op took is counted at each compile
-    (``ctx.note``; observability/attention.py).
-    """
-    import jax
-    import jax.numpy as jnp
-
-    q, k, v = ins["Q"][0], ins["K"][0], ins["V"][0]
-    bias = ins.get("Bias", [None])[0]
+def _plan(ctx, q, k, v, bias):
+    """Which lowering this op takes, from its attrs, its shapes and where it
+    is lowered: ``(impl, scale, dropout, causal, blocks)`` with ``impl`` one
+    of 'ulysses', 'ring', 'pallas', 'xla' and ``blocks`` the kernels'
+    (block_q, block_k), None without them. The forward op and its grad op
+    both ask here, so the two cannot disagree; what cannot run raises."""
     B, H, S, D = q.shape
     kv_heads = k.shape[1]
-    if H % kv_heads or v.shape[1] != kv_heads:
-        raise ValueError(
-            f"fused_attention: {H} query heads over {kv_heads} key and "
-            f"{v.shape[1]} value heads; the query heads must be a multiple "
-            f"of the key/value heads")
-    scale = ctx.attr("scale") or (1.0 / math.sqrt(D))
-    dropout = 0.0 if ctx.attr("is_test", False) else ctx.attr("dropout_prob", 0.0)
+    scale = float(ctx.attr("scale") or (1.0 / math.sqrt(D)))
+    dropout = 0.0 if ctx.attr("is_test", False) else float(
+        ctx.attr("dropout_prob", 0.0))
     causal = bool(ctx.attr("causal", False))
     impl = ctx.attr("impl", "auto")
     from . import pallas_mode
     is_tpu = pallas_mode.on_tpu()
-
-    if ctx.abstract:
-        # eval_shape inference: mesh/backend are unknown here, and every impl
-        # produces the same output shape -- lower the composed path and defer
-        # impl validation to the executor's real lowering
-        return {"Out": [composed_attention(q, k, v, bias, float(scale), 0.0,
-                                           causal, ctx.rng())]}
 
     gm = ctx.gspmd_mesh
     sp_n = gm.shape.get("sp", 1) if gm is not None else 1
@@ -802,17 +830,9 @@ def fused_attention(ctx, ins):
                 f"[B,1,1,S] bias; got sp={sp_n}, S={S}, H={H} "
                 f"({h_local} heads per mp shard), "
                 f"bias={None if bias is None else bias.shape}")
-        from ..parallel import ulysses as _uly
-        ctx.note("fused_attention", ("ulysses", S, 0, 0, kv_heads, 0, 0))
-        seed = jax.random.randint(ctx.rng(), (), 0, 2**31 - 1, jnp.int32)
-        return {"Out": [_uly.ulysses_attention(
-            q, k, v, bias, float(scale), float(dropout), causal, seed, gm)]}
+        return "ulysses", scale, dropout, causal, None
     if ring_ok and impl in ("auto", "ring"):
-        from ..parallel import ring_attention as _ring
-        ctx.note("fused_attention", ("ring", S, 0, 0, kv_heads, 0, 0))
-        seed = jax.random.randint(ctx.rng(), (), 0, 2**31 - 1, jnp.int32)
-        return {"Out": [_ring.ring_attention(
-            q, k, v, bias, float(scale), float(dropout), causal, seed, gm)]}
+        return "ring", scale, dropout, causal, None
 
     bias_shape = None if bias is None else bias.shape
     if impl == "pallas":
@@ -837,32 +857,135 @@ def fused_attention(ctx, ins):
     # (AUTO_PALLAS_MIN_S, default_block_q, default_block_k).
     from ..tuning import decide as _decide
     tune_params = {"b": B, "h": H, "s": S, "d": D, "dtype": str(q.dtype),
-                   "has_bias": bias is not None, "dropout": float(dropout),
-                   "causal": causal, "scale": float(scale)}
-    use_pallas = impl == "pallas" or (
-        impl == "auto" and one_device and pallas_mode.available() and
-        supports_pallas(B, H, S, D, bias_shape, dropout, is_tpu) and
-        _decide("fused_attention.backend", tune_params) == "pallas")
-    if use_pallas:
-        block_q, block_k = (int(b) for b in _decide(
+                   "has_bias": bias is not None, "dropout": dropout,
+                   "causal": causal, "scale": scale}
+    if impl == "pallas" or (
+            impl == "auto" and one_device and pallas_mode.available() and
+            supports_pallas(B, H, S, D, bias_shape, dropout, is_tpu) and
+            _decide("fused_attention.backend", tune_params) == "pallas"):
+        return "pallas", scale, dropout, causal, tuple(int(b) for b in _decide(
             "fused_attention.block_sizes", tune_params))
-        ctx.note("fused_attention", ("pallas", S, block_q, block_k, kv_heads)
-                 + k_tiles(S, block_q, block_k, causal))
-        # The kernels read the seed for a dropout mask alone. A test-mode
-        # op draws none, like the dropout op under is_test: an inference
-        # program then holds no random op (0.5 s of set-up for a threefry
-        # lowering nothing reads). A training op without dropout draws it
-        # all the same: olmoe_1b_7b.pretrain_s4096, which has no other
-        # random op, runs 0.8% slower without it (XLA's schedule; chip runs,
-        # PR 27).
-        if dropout or not ctx.attr("is_test", False):
-            seed = jax.random.randint(ctx.rng(), (), 0, 2**31 - 1, jnp.int32)
-        else:
-            seed = jnp.int32(0)
-        out = _flash(q, k, v, bias, seed, float(scale), float(dropout), causal,
-                     pallas_mode.interpret(), block_q, block_k)
+    return "xla", scale, dropout, causal, None
+
+
+def _kernel_seed(ctx, dropout):
+    """The step's seed for the kernels' dropout mask, which they read for
+    nothing else; the forward op and its grad op draw the same (one salt).
+    A test-mode op draws none, like the dropout op under is_test: an
+    inference program then holds no random op (0.5 s of set-up for a
+    threefry lowering nothing reads). A training op without dropout draws
+    it all the same: olmoe_1b_7b.pretrain_s4096, which has no other random
+    op, runs 0.8% slower without it (XLA's schedule; chip runs, PR 27)."""
+    import jax
+    import jax.numpy as jnp
+    if dropout or not ctx.attr("is_test", False):
+        return jax.random.randint(ctx.rng(), (), 0, 2**31 - 1, jnp.int32)
+    return jnp.int32(0)
+
+
+@register("fused_attention", nondiff_inputs=("Bias",),
+          nondiff_outputs=("Lse",))
+def fused_attention(ctx, ins):
+    """softmax(Q K^T * scale + Bias) V.
+
+    Inputs: Q [B, heads, S, D], K/V [B, kv_heads, S, D] with kv_heads
+    dividing heads (grouped-query attention: query head i reads key/value
+    head i // (heads / kv_heads), in place -- no lowering repeats K or V);
+    optional Bias [B, 1, 1, S] additive (already -inf-masked). Attrs: scale (default 1/sqrt(D)), dropout_prob, causal,
+    is_test, impl ('auto' | 'pallas' | 'ring' | 'ulysses' | 'composed').
+    Outputs: Out [B, heads, S, D]; Lse [B, heads, 1, S] float32, the rows'
+    softmax statistic (log of the sum of exp of a row's scores), for the
+    op's own backward and no gradient's: the flash kernels write it and
+    fused_attention_grad hands it to the backward kernel. The other
+    lowerings have no use for it and leave zeros there that nothing reads
+    (XLA drops them).
+
+    Kernel choice (_plan): under a GSPMD jit whose mesh has an "sp" axis >1 (sequence
+    parallelism), 'auto' opens the ring-attention shard_map island
+    (parallel/ring_attention.py) so the sequence dim STAYS partitioned --
+    GSPMD alone would all-gather K/V to every device; 'ulysses' instead does
+    the all-to-all head-scatter schedule (parallel/ulysses.py, needs heads
+    divisible by sp). Otherwise 'auto' is the Pallas flash kernel on
+    TPU-supported shapes from S >= AUTO_PALLAS_MIN_S (at S=128 XLA's own
+    fusion is measurably faster) where the jit spans one device, else the
+    composed jnp path (a dp or mp mesh without sp: a Mosaic call cannot be
+    partitioned by GSPMD). Which one an op took is counted at each compile
+    (``ctx.note``; observability/attention.py).
+    """
+    import jax
+    import jax.numpy as jnp
+
+    q, k, v = ins["Q"][0], ins["K"][0], ins["V"][0]
+    bias = ins.get("Bias", [None])[0]
+    B, H, S, D = q.shape
+    kv_heads = k.shape[1]
+    if H % kv_heads or v.shape[1] != kv_heads:
+        raise ValueError(
+            f"fused_attention: {H} query heads over {kv_heads} key and "
+            f"{v.shape[1]} value heads; the query heads must be a multiple "
+            f"of the key/value heads")
+
+    def no_stats():
+        return jnp.zeros((B, H, 1, S), jnp.float32)
+
+    if ctx.abstract:
+        # eval_shape inference: mesh/backend are unknown here, and every impl
+        # produces the same output shapes -- lower the composed path and
+        # defer impl validation to the executor's real lowering
+        scale = ctx.attr("scale") or (1.0 / math.sqrt(D))
+        return {"Out": [composed_attention(
+            q, k, v, bias, float(scale), 0.0, bool(ctx.attr("causal", False)),
+            ctx.rng())], "Lse": [no_stats()]}
+
+    impl, scale, dropout, causal, blocks = _plan(ctx, q, k, v, bias)
+    if impl == "pallas":
+        from . import pallas_mode
+        ctx.note("fused_attention", ("pallas", S, *blocks, kv_heads)
+                 + k_tiles(S, *blocks, causal))
+        out, lse = _flash_stats(q, k, v, bias, _kernel_seed(ctx, dropout),
+                                scale, dropout, causal,
+                                pallas_mode.interpret(), *blocks)
+        return {"Out": [out], "Lse": [lse]}
+    ctx.note("fused_attention", (impl, S, 0, 0, kv_heads, 0, 0))
+    if impl == "xla":
+        out = composed_attention(q, k, v, bias, scale, dropout, causal,
+                                 ctx.rng(), ctx.bernoulli_mask)
     else:
-        ctx.note("fused_attention", ("xla", S, 0, 0, kv_heads, 0, 0))
-        out = composed_attention(q, k, v, bias, float(scale), float(dropout),
-                                 causal, ctx.rng(), ctx.bernoulli_mask)
-    return {"Out": [out]}
+        gm = ctx.gspmd_mesh
+        seed = jax.random.randint(ctx.rng(), (), 0, 2**31 - 1, jnp.int32)
+        if impl == "ulysses":
+            from ..parallel import ulysses as _uly
+            out = _uly.ulysses_attention(q, k, v, bias, scale, dropout,
+                                         causal, seed, gm)
+        else:
+            from ..parallel import ring_attention as _ring
+            out = _ring.ring_attention(q, k, v, bias, scale, dropout, causal,
+                                       seed, gm)
+    return {"Out": [out], "Lse": [no_stats()]}
+
+
+@register_grad("fused_attention")
+def fused_attention_grad(ctx, ins, generic):
+    """dQ, dK, dV. Where the forward op took the flash kernels (_plan, asked
+    again with the forward's attrs, shapes and mesh) and declared ``Lse``,
+    the backward kernel alone, on the forward's statistics, blocks and seed:
+    no forward is lowered here, so none can survive beside it. Every other
+    case is the generic grad (``jax.vjp`` over the forward's lowering): the
+    composed lowering, a mesh, and a desc from before the op had ``Lse``
+    (on the kernels that one lowers the forward kernel a second time for
+    the statistics). Which it was is noted for ``attention_backward_total``
+    (observability/attention.py)."""
+    q, k, v = ins["Q"][0], ins["K"][0], ins["V"][0]
+    bias = ins.get("Bias", [None])[0]
+    lse, g = ins.get("Lse", [None])[0], ins.get("Out@GRAD", [None])[0]
+    impl, scale, dropout, causal, blocks = _plan(ctx, q, k, v, bias)
+    if impl != "pallas" or lse is None or g is None:
+        ctx.note("fused_attention_grad",
+                 "recomputed" if impl == "pallas" else "generic")
+        return generic()
+    from . import pallas_mode
+    ctx.note("fused_attention_grad", "saved")
+    dq, dk, dv = _bwd_call(
+        q, k, v, bias, _kernel_seed(ctx, dropout), g.astype(q.dtype), lse,
+        scale, dropout, causal, pallas_mode.interpret(), *blocks)
+    return {"Q@GRAD": [dq], "K@GRAD": [dk], "V@GRAD": [dv]}

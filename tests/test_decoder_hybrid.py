@@ -102,13 +102,17 @@ def repeated(k, group):
     return jnp.repeat(k, group, axis=1)
 
 
-@pytest.mark.parametrize("impl,seq", [("composed", 16), ("pallas", 128)])
+@pytest.mark.parametrize("impl,seq", [("composed", 16), ("pallas", 128),
+                                      ("pallas", 1024)])
 def test_grouped_query_attention_equals_attention_with_k_and_v_repeated(
         impl, seq):
     """Query head i reads key/value head i // group; a key/value head's
     gradient is the sum over its group. ``pallas`` runs the kernel bodies in
     the interpreter (tests/conftest.py): the forward's in-place K/V block
-    index and the backward's grid over the group."""
+    index and the backward's grid over the group, whose steps read the
+    forward op's ``Lse`` by (the group's head, its Q block) as they read Q
+    (four Q blocks a head at S=1024; this is a Program, so the grad op is
+    fused_attention_grad on the forward's statistics)."""
     from paddle_tpu.ops.pallas_attention import composed_attention
     B, H, kv, D = 2, 4, 2, 8
     q = rng(1).randn(B, H, seq, D).astype("float32")

@@ -44,12 +44,51 @@ def _grads(attend, q, k, v, g):
     return [np.asarray(_f32(x)) for x in vjp(g.astype(out.dtype))]
 
 
+def _op_grads(q, k, v, bias, g, scale, causal, blocks, monkeypatch,
+              with_lse=True):
+    """(out, lse, dq, dk, dv) as a Program computes them: the registry's
+    ``fused_attention`` lowering, then ``fused_attention_grad``'s on what
+    the desc maker hands a grad op (the forward's inputs, its outputs
+    ``Out`` and ``Lse``, the cotangent), at the kernels' ``blocks``.
+    ``with_lse=False`` is a desc from before the op declared ``Lse``."""
+    import paddle_tpu.core.registry as registry
+    from paddle_tpu import tuning
+    monkeypatch.setattr(tuning, "decide", lambda choice, params: (
+        "pallas" if choice == "fused_attention.backend" else blocks))
+    attrs = {"impl": "auto", "scale": scale, "causal": causal,
+             "dropout_prob": 0.0, "is_test": False}
+    ins = {"Q": [q], "K": [k], "V": [v]}
+    if bias is not None:
+        ins["Bias"] = [bias]
+    key = jax.random.PRNGKey(0)
+    outs = registry.get("fused_attention").lower(
+        registry.LowerCtx(attrs, key, 5), dict(ins))
+    grad_ins = dict(ins, Out=outs["Out"], **{"Out@GRAD": [g]})
+    slots = ["Out"]
+    if with_lse:
+        grad_ins["Lse"] = outs["Lse"]
+        slots.append("Lse")
+    grads = registry.get("fused_attention_grad").lower(
+        registry.LowerCtx(dict(attrs, __fwd_out_slots__=slots), key, 5),
+        grad_ins)
+    assert set(grads) == {"Q@GRAD", "K@GRAD", "V@GRAD"}
+    return (outs["Out"][0], outs["Lse"][0],
+            *(grads[s + "@GRAD"][0] for s in "QKV"))
+
+
+@pytest.mark.parametrize("grouped", [False, True])
+@pytest.mark.parametrize("tiles", ["one_tile", "k_tiles"])
 @pytest.mark.parametrize("use_bias", [False, True])
 @pytest.mark.parametrize("causal", [False, True])
 @pytest.mark.parametrize("dtype", [jnp.float32, jnp.bfloat16])
-def test_flash_grad_parity(dtype, causal, use_bias):
+def test_flash_grad_parity(dtype, causal, use_bias, tiles, grouped,
+                           monkeypatch):
     """dQ, dK, dV against the composed lowering evaluated in float32 on the
-    same (exactly representable) inputs.
+    same (exactly representable) inputs: through ``jax.vjp`` of the kernels
+    (the custom VJP) and through the op's own grad lowering, which hands the
+    forward op's ``Lse`` to the backward kernel (what a Program runs); with
+    one tile a row and with K tiles, with as many key/value as query heads
+    and with half as many.
 
     float32 inputs take float32 products: atol 5e-5 / rtol 1e-4, as ever.
 
@@ -61,23 +100,75 @@ def test_flash_grad_parity(dtype, causal, use_bias):
     independent sign is about sqrt(S) terms large, so a term is about
     rms(gradient) / sqrt(S); if all S rounding errors line up (the worst
     case) they come to 2^-9 x S x that = 2^-9 x sqrt(S) x rms(gradient).
-    The last rounding adds at most 2^-9 x max|gradient|.
+    The last rounding adds at most 2^-9 x max|gradient|. A key/value head's
+    gradient sums over its group's rows too (S x group terms).
     """
-    q, k, v, bias = _qkv(dtype=dtype)
-    S = q.shape[2]
+    S, blocks = (128, (128, 128)) if tiles == "one_tile" else (256, (128, 128))
+    q, k, v, bias = _qkv(S=S, dtype=dtype)
+    group = 2 if grouped else 1
+    k, v = k[:, ::group], v[:, ::group]
     g = jax.random.normal(jax.random.PRNGKey(1), q.shape, dtype)
     b = bias if use_bias else None
     ref = _grads(lambda q, k, v: pa.composed_attention(
         q, k, v, b, 0.125, 0.0, causal, None), _f32(q), _f32(k), _f32(v), g)
     got = _grads(lambda q, k, v: pa._flash(
-        q, k, v, b, jnp.int32(7), 0.125, 0.0, causal, True), q, k, v, g)
-    for r, x in zip(ref, got):
+        q, k, v, b, jnp.int32(7), 0.125, 0.0, causal, True, *blocks),
+        q, k, v, g)
+    op = [np.asarray(_f32(x)) for x in _op_grads(
+        q, k, v, b, g, 0.125, causal, blocks, monkeypatch)[2:]]
+    for r, x, y in zip(ref, got, op):
+        np.testing.assert_array_equal(x, y)     # the same two kernels
         if dtype == jnp.float32:
             np.testing.assert_allclose(x, r, atol=5e-5, rtol=1e-4)
         else:
-            atol = 2.0 ** -9 * (np.sqrt(S) * np.sqrt((r * r).mean())
+            terms = S * (group if r.shape[1] != q.shape[1] else 1)
+            atol = 2.0 ** -9 * (np.sqrt(terms) * np.sqrt((r * r).mean())
                                 + np.abs(r).max())
             np.testing.assert_allclose(x, r, atol=atol, rtol=0)
+
+
+@pytest.mark.parametrize("S,blocks", [(128, (128, 128)), (512, (256, 128)),
+                                      (512, (128, 512))])
+@pytest.mark.parametrize("causal", [False, True])
+def test_flash_lse_is_the_logsumexp_of_the_composed_scores(causal, S, blocks):
+    """The statistic the forward kernel hands the backward, one float32 a
+    row: log(sum(exp(scores))) over the row's scores as the composed
+    lowering forms them -- scale, bias and the causal mask's -1e30 included.
+    A key biased by -1e30 adds nothing to its rows; a sequence whose every
+    key is (its rows have no key at all) reads -1e30, where log S is under
+    float32's spacing, as the plain logsumexp reads it."""
+    q, k, v, bias = _qkv(S=S)
+    bias = bias.at[0, :, :, -S // 4:].set(-1e30).at[1].set(-1e30)
+    s = jnp.einsum("bhqd,bhkd->bhqk", q, k) * 0.125 + bias
+    if causal:
+        s = jnp.where(jnp.tril(jnp.ones((S, S), bool)), s, jnp.float32(-1e30))
+    want = jax.scipy.special.logsumexp(s, axis=-1)
+    out, lse = pa._flash_stats(q, k, v, bias, jnp.int32(7), 0.125, 0.0,
+                               causal, True, *blocks)
+    B, H = q.shape[:2]
+    assert lse.shape == (B, H, 1, S) and lse.dtype == jnp.float32
+    np.testing.assert_allclose(np.asarray(lse[:, :, 0]), np.asarray(want),
+                               rtol=1e-6, atol=1e-5)
+    assert (np.asarray(lse[1]) == np.float32(-1e30)).all()
+    # and the output beside it is what it was (where a row has a key)
+    ref = pa.composed_attention(q, k, v, bias, 0.125, 0.0, causal, None)
+    np.testing.assert_allclose(np.asarray(out[0]), np.asarray(ref[0]),
+                               atol=1e-5)
+
+
+@pytest.mark.parametrize("causal", [False, True])
+def test_grad_op_without_lse_takes_the_generic_path_and_the_same_gradients(
+        causal, monkeypatch):
+    """A desc from before the op declared ``Lse`` has no such input on its
+    grad op: the lowering falls back to the generic grad (``jax.vjp`` over
+    the forward's lowering, the custom VJP's own statistics) and gives the
+    gradients the explicit path gives, bit for bit -- the same backward
+    kernel on the same numbers."""
+    q, k, v, bias = _qkv(S=256)
+    g = jax.random.normal(jax.random.PRNGKey(1), q.shape, q.dtype)
+    args = (q, k, v, bias, g, 0.125, causal, (128, 128), monkeypatch)
+    for a, b in zip(_op_grads(*args)[2:], _op_grads(*args, with_lse=False)[2:]):
+        np.testing.assert_array_equal(np.asarray(a), np.asarray(b))
 
 
 @pytest.mark.parametrize("dtype", [jnp.float32, jnp.bfloat16])
@@ -231,10 +322,11 @@ def test_causal_k_tiles_above_the_diagonal_are_not_visited(S, block_q,
 
 
 def _single_pass(q, k, v, bias, g, scale, block_q):
-    """The kernels as they were before the K axis had tiles (PR 25's
-    bodies, no dropout): one pass over a Q block's [block_q, S] scores.
-    Kept here as the oracle for what one tile a row must still compute, bit
-    for bit: (out, dq, dk, dv)."""
+    """The kernels written plainly for one tile a row (PR 25's bodies, no
+    dropout, no _KTiles): one pass over a Q block's [block_q, S] scores,
+    the forward handing the backward each row's ``lse`` (here as a 128-lane
+    copy a row, which only a test can afford). Kept here as the oracle for
+    what one tile a row must compute, bit for bit: (out, dq, dk, dv)."""
     import functools
     from jax.experimental import pallas as pl
     B, H, S, D = q.shape
@@ -248,20 +340,21 @@ def _single_pass(q, k, v, bias, g, scale, block_q):
         q_s = q_ref[0] * jnp.asarray(scale, q_ref.dtype)
         return dot(q_s, k_ref[0], nt) + bias_ref[0].astype(jnp.float32), q_s
 
-    def fwd(q_ref, k_ref, v_ref, bias_ref, o_ref):
+    def fwd(q_ref, k_ref, v_ref, bias_ref, o_ref, lse_ref):
         s, _ = scores(q_ref, k_ref, bias_ref)
-        e = jnp.exp(s - jnp.max(s, axis=-1, keepdims=True))
+        m = jnp.max(s, axis=-1, keepdims=True)
+        e = jnp.exp(s - m)
         l = jnp.sum(e, axis=-1, keepdims=True)
         o = dot(e.astype(v_ref.dtype), v_ref[0], nn)
         o_ref[0] = (o * (1.0 / (l * 1.0))).astype(o_ref.dtype)
+        lse_ref[0] = jnp.broadcast_to(m + jnp.log(l), lse_ref.shape[1:])
 
-    def bwd(q_ref, k_ref, v_ref, bias_ref, do_ref, dq_ref, dk_ref, dv_ref,
-            dkt_acc, dvt_acc):
+    def bwd(q_ref, k_ref, v_ref, bias_ref, do_ref, lse_ref, dq_ref, dk_ref,
+            dv_ref, dkt_acc, dvt_acc):
         step = pl.program_id(1)
         dtype = q_ref.dtype
         s, q_s = scores(q_ref, k_ref, bias_ref)
-        e = jnp.exp(s - jnp.max(s, axis=-1, keepdims=True))
-        p = e * (1.0 / jnp.sum(e, axis=-1, keepdims=True))
+        p = jnp.exp(s - lse_ref[0][:, :1])
         do = do_ref[0]
         dp = dot(do, v_ref[0], nt)
         row = jnp.sum(dp * p, axis=-1, keepdims=True)
@@ -288,15 +381,18 @@ def _single_pass(q, k, v, bias, g, scale, block_q):
     flat = [x.reshape(B * H, S, D) for x in (q, k, v)] + [
         bias.reshape(B, 1, S)]
     shape = jax.ShapeDtypeStruct((B * H, S, D), q.dtype)
-    out = pl.pallas_call(
+    lspec = pl.BlockSpec((1, block_q, 128), lambda b, i: (b, i, 0))
+    out, lse = pl.pallas_call(
         fwd, grid=(B * H, n_q), in_specs=[qspec, kvspec, kvspec, bspec],
-        out_specs=qspec, out_shape=shape, interpret=True)(*flat)
+        out_specs=[qspec, lspec],
+        out_shape=[shape, jax.ShapeDtypeStruct((B * H, S, 128), jnp.float32)],
+        interpret=True)(*flat)
     grads = pl.pallas_call(
         bwd, grid=(B * H, n_q),
-        in_specs=[qspec, kvspec, kvspec, bspec, qspec],
+        in_specs=[qspec, kvspec, kvspec, bspec, qspec, lspec],
         out_specs=[qspec, kvspec, kvspec], out_shape=[shape] * 3,
         scratch_shapes=[pltpu.VMEM((D, S), jnp.float32)] * 2,
-        interpret=True)(*flat, g.reshape(B * H, S, D))
+        interpret=True)(*flat, g.reshape(B * H, S, D), lse)
     return [x.reshape(B, H, S, D) for x in (out, *grads)]
 
 
@@ -304,9 +400,10 @@ def _single_pass(q, k, v, bias, g, scale, block_q):
 @pytest.mark.parametrize("S,block_q", [(256, 256), (512, 128), (1024, 256)])
 def test_one_tile_a_row_is_the_single_pass_it_was(S, block_q, dtype):
     """Without `causal` the K tile is the row (default_block_k), and the
-    kernels then compute what they computed before the K axis had tiles:
-    output and gradients equal the single-pass bodies' bit for bit, on a
-    padding bias and at a power-of-two scale as BERT's cells run them."""
+    kernels then compute what the plain single-pass bodies compute (_KTiles
+    adds nothing, and the row statistic's way through HBM as one float a
+    row changes no bit): output and gradients equal theirs bit for bit, on
+    a padding bias and at a power-of-two scale as BERT's cells run them."""
     assert pa.default_block_k(S) == S and pa.default_block_k(S, True) in (
         S, pa.CAUSAL_BLOCKS[1])
     q, k, v, bias = _qkv(S=S, D=64, dtype=dtype)
@@ -391,17 +488,20 @@ def _bert_program(impl, B=2, S=128, M=8):
     return main, startup, total
 
 
+def _feed(B, S, M):
+    rng = np.random.RandomState(0)
+    ids = lambda hi, shape: rng.randint(0, hi, shape).astype(np.int32)  # noqa: E731
+    return {"src_ids": ids(64, (B, S)),
+            "pos_ids": np.tile(np.arange(S, dtype=np.int32), (B, 1)),
+            "sent_ids": ids(2, (B, S)),
+            "input_mask": np.ones((B, S), np.float32),
+            "mask_pos": ids(B * S, (M, 1)), "mask_label": ids(64, (M, 1)),
+            "nsp_label": ids(2, (B, 1))}
+
+
 def test_bert_program_parity_fused_vs_composed():
     """Full train steps (fwd+bwd+Adam) agree between attention lowerings."""
-    B, S, M = 2, 128, 8
-    rng = np.random.RandomState(0)
-    feed = {"src_ids": rng.randint(0, 64, (B, S)).astype(np.int32),
-            "pos_ids": np.tile(np.arange(S, dtype=np.int32), (B, 1)),
-            "sent_ids": rng.randint(0, 2, (B, S)).astype(np.int32),
-            "input_mask": np.ones((B, S), np.float32),
-            "mask_pos": rng.randint(0, B * S, (M, 1)).astype(np.int32),
-            "mask_label": rng.randint(0, 64, (M, 1)).astype(np.int32),
-            "nsp_label": rng.randint(0, 2, (B, 1)).astype(np.int32)}
+    feed = _feed(2, 128, 8)
     losses = {}
     for impl in ("composed", "pallas"):
         main, startup, total = _bert_program(impl)
@@ -416,26 +516,30 @@ def test_bert_program_parity_fused_vs_composed():
     assert losses["pallas"][1] < losses["pallas"][0]  # it actually trains
 
 
-@pytest.mark.parametrize("S,dp,want", [(128, 1, ("xla", "0", "0")),
-                                       (256, 1, ("pallas", "256", "256")),
-                                       (256, 2, ("xla", "0", "0"))])
-def test_executor_counts_the_lowering_each_attention_op_took(S, dp, want):
+def _backward_counts():
+    from paddle_tpu.observability.metrics import REGISTRY
+    out = {}
+    for k, c in (REGISTRY.get("attention_backward_total") or {}).items():
+        out[dict(k)["stats"]] = out.get(dict(k)["stats"], 0) + c.value
+    return out
+
+
+@pytest.mark.parametrize("S,dp,want,stats", [
+    (128, 1, ("xla", "0", "0"), "generic"),
+    (256, 1, ("pallas", "256", "256"), "saved"),
+    (256, 2, ("xla", "0", "0"), "generic")])
+def test_executor_counts_the_lowering_each_attention_op_took(S, dp, want,
+                                                             stats):
     """impl='auto' with no tuning decision: XLA's lowering at S=128, the
     kernels at one Q block a head from S=256, and XLA's again where the step
     is jitted over a mesh of two devices (GSPMD cannot partition a Mosaic
     call); the executor adds one count a fused_attention op at the compile
-    (the forward its grad op lowers again is the same op), labelled by
-    program."""
+    (the forward a generic grad op lowers again is the same op), labelled by
+    program. Its grad op is counted by where its softmax statistics came
+    from: the forward op's ``Lse`` on the kernels (``saved``), the generic
+    vjp elsewhere."""
     from paddle_tpu.observability.metrics import REGISTRY
-    B, M = 2, 8
-    rng = np.random.RandomState(0)
-    ids = lambda hi, shape: rng.randint(0, hi, shape).astype(np.int32)  # noqa: E731
-    feed = {"src_ids": ids(64, (B, S)),
-            "pos_ids": np.tile(np.arange(S, dtype=np.int32), (B, 1)),
-            "sent_ids": ids(2, (B, S)),
-            "input_mask": np.ones((B, S), np.float32),
-            "mask_pos": ids(B * S, (M, 1)), "mask_label": ids(64, (M, 1)),
-            "nsp_label": ids(2, (B, 1))}
+    feed = _feed(2, S, 8)
     main, startup, total = _bert_program("auto", S=S)
     run = main if dp == 1 else fluid.CompiledProgram(main).with_strategy(
         fluid.DistributedStrategy(
@@ -454,7 +558,7 @@ def test_executor_counts_the_lowering_each_attention_op_took(S, dp, want):
         for k, c in (REGISTRY.get("attention_k_tiles_total") or {}).items():
             out[dict(k)["state"]] += c.value
         return out
-    before, tiles_before = counts(), tiles()
+    before, tiles_before, back_before = counts(), tiles(), _backward_counts()
     exe = fluid.Executor()
     with fluid.scope_guard(fluid.Scope()):
         exe.run(startup)
@@ -467,6 +571,42 @@ def test_executor_counts_the_lowering_each_attention_op_took(S, dp, want):
     # the one op's forward kernel: one Q block, one K tile, none left out
     assert {k: v - tiles_before[k] for k, v in tiles().items()} == {
         "visited": int(want[0] == "pallas"), "skipped": 0}
+    back = _backward_counts()
+    assert {k: v - back_before.get(k, 0) for k, v in back.items()
+            if v != back_before.get(k, 0)} == {stats: 1}
+
+
+def test_a_program_without_lse_trains_as_one_with_it():
+    """A Program built before the op declared ``Lse`` (here: the output
+    taken off the op and off its grad op's inputs, as such a desc reads)
+    still trains on the kernels: its grad op takes the generic path, counted
+    ``recomputed`` (the vjp lowers the forward kernel again for the
+    statistics),
+    and two steps' losses equal those of the Program that saves them."""
+    S = 256
+    losses = {}
+    for strip in (False, True):
+        main, startup, total = _bert_program("auto", S=S)
+        if strip:
+            for op in main.global_block().ops:
+                if op.type == "fused_attention":
+                    del op.outputs["Lse"]
+                elif op.type == "fused_attention_grad":
+                    del op.inputs["Lse"]
+                    op.attrs["__fwd_out_slots__"] = ["Out"]
+        before = _backward_counts()
+        exe = fluid.Executor()
+        with fluid.scope_guard(fluid.Scope()):
+            exe.run(startup)
+            losses[strip] = [float(np.asarray(exe.run(
+                main, feed=_feed(2, S, 8), fetch_list=[total])[0]).item())
+                for _ in range(2)]
+        after = _backward_counts()
+        assert {k: v - before.get(k, 0) for k, v in after.items()
+                if v != before.get(k, 0)} == {
+                    "recomputed" if strip else "saved": 1}
+    assert losses[True] == losses[False]
+    assert losses[True][1] < losses[True][0]
 
 
 def test_clone_for_test_disables_attention_dropout():

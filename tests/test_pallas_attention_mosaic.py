@@ -48,6 +48,23 @@ def _kernels(compiled):
     return compiled.as_text().count('custom_call_target="tpu_custom_call"')
 
 
+def _lse(q, one_chip):
+    """The forward op's ``Lse`` for queries ``q``, as a Program hands it to
+    the grad op."""
+    B, H, S, _ = q.shape
+    return jax.ShapeDtypeStruct((B, H, 1, S), jnp.float32, sharding=one_chip)
+
+
+def _grad_op(q, k, v, bias, g, lse, scale, dropout, causal, block_q=None,
+             block_k=None):
+    """What a Program's ``fused_attention_grad`` lowers on the kernels
+    (ops/pallas_attention.py): the backward kernel on the forward op's
+    statistics, and no forward."""
+    return pa._bwd_call(q, k, v, bias, jnp.int32(3), g, lse, scale, dropout,
+                        causal, False,
+                        *pa._blocks(q.shape[2], causal, block_q, block_k))
+
+
 # (batch, heads, S, head dim, dtype, dropout, causal, bias), each at the
 # blocks the op takes by default: bert_base.pretrain_s512's attention (one Q
 # block a head), the same tokens at the shortest S that 'auto' gives the
@@ -78,11 +95,17 @@ def test_flash_compiles_for_v5e(one_chip, B, H, S, D, dtype, dropout, causal,
     def grads(q, k, v, bias, g):
         return jax.vjp(lambda q, k, v: attend(q, k, v, bias), q, k, v)[1](g)
 
+    def grad_op(q, k, v, bias, g, lse):
+        return _grad_op(q, k, v, bias if use_bias else None, g, lse,
+                        D ** -0.5, dropout, causal)
+
     assert _kernels(jax.jit(attend).lower(x, x, x, bias).compile()) == 1
-    # What a Program's grad op lowers (core/registry.py: the forward again
-    # under jax.vjp, its output unused): the backward kernel alone. A
-    # residual written by the forward kernel would keep a second forward.
-    assert _kernels(jax.jit(grads).lower(x, x, x, bias, x).compile()) == 1
+    # What a Program's grad op lowers: the backward kernel alone, on the
+    # forward op's Lse. A direct jax.vjp (the custom VJP: tests, the tuner)
+    # keeps its own forward for the statistics: two kernels.
+    assert _kernels(jax.jit(grad_op).lower(
+        x, x, x, bias, x, _lse(x, one_chip)).compile()) == 1
+    assert _kernels(jax.jit(grads).lower(x, x, x, bias, x).compile()) == 2
 
 
 @pytest.mark.parametrize("B,dropout,use_bias", [(4, 0.0, False),
@@ -92,9 +115,10 @@ def test_grouped_query_flash_compiles_for_v5e(one_chip, B, dropout, use_bias):
     key/value heads of 64 at S=4096, causal, at the default tiles (and with
     a bias and a dropout mask by tile). The forward reads a key/value
     head's rows in place (its block index is the query head's // 4) and the
-    backward's grid runs over a key/value head's four query heads, so dK and
-    dV leave at the key/value heads' shape: one kernel each way, and no
-    array of the query heads' shape among the backward's outputs but dQ."""
+    backward's grid runs over a key/value head's four query heads (their
+    rows of the forward op's Lse with them), so dK and dV leave at the
+    key/value heads' shape: one kernel each way, and no array of the query
+    heads' shape among the backward's outputs but dQ."""
     H, kv, S, D = 32, 8, 4096, 64
     q = jax.ShapeDtypeStruct((B, H, S, D), jnp.bfloat16, sharding=one_chip)
     k = jax.ShapeDtypeStruct((B, kv, S, D), jnp.bfloat16, sharding=one_chip)
@@ -104,11 +128,12 @@ def test_grouped_query_flash_compiles_for_v5e(one_chip, B, dropout, use_bias):
         return pa._flash(q, k, v, bias if use_bias else None, jnp.int32(3),
                          D ** -0.5, dropout, True, False)
 
-    def grads(q, k, v, bias, g):
-        return jax.vjp(lambda q, k, v: attend(q, k, v, bias), q, k, v)[1](g)
+    def grad_op(q, k, v, bias, g, lse):
+        return _grad_op(q, k, v, bias if use_bias else None, g, lse,
+                        D ** -0.5, dropout, True)
 
     assert _kernels(jax.jit(attend).lower(q, k, k, bias).compile()) == 1
-    back = jax.jit(grads).lower(q, k, k, bias, q)
+    back = jax.jit(grad_op).lower(q, k, k, bias, q, _lse(q, one_chip))
     assert [tuple(o.shape) for o in back.out_info] == [
         (B, H, S, D), (B, kv, S, D), (B, kv, S, D)]
     assert _kernels(back.compile()) == 1
@@ -123,9 +148,10 @@ def test_causal_flash_compiles_for_v5e_in_k_tiles(one_chip, H, kv, D, block_q,
     olmoe_1b_7b's 16 heads of 128, lfm2_8b_a1b's 32 query over 8 key/value
     heads of 64) at the tiles the op takes by default, at two narrower pairs
     and at one tile a row (what a persisted decision may still choose): the
-    loops of dynamic trip count, the staged score tiles and dK^T / dV^T by
-    tile fit Mosaic's VMEM, and a grad op still holds the backward kernel
-    alone (no residual out of the forward)."""
+    loops of dynamic trip count, the staged probability and dP tiles and
+    dK^T / dV^T by tile fit Mosaic's VMEM, and a grad op holds the backward
+    kernel alone (it reads the forward op's Lse, by the group's heads where
+    the key/value heads are fewer)."""
     B, S = 4, 4096
     assert (pa.default_block_q(S, True),
             pa.default_block_k(S, True)) == pa.CAUSAL_BLOCKS
@@ -136,11 +162,12 @@ def test_causal_flash_compiles_for_v5e_in_k_tiles(one_chip, H, kv, D, block_q,
         return pa._flash(q, k, v, None, jnp.int32(3), D ** -0.5, 0.0, True,
                          False, block_q, block_k)
 
-    def grads(q, k, v, g):
-        return jax.vjp(attend, q, k, v)[1](g)
+    def grad_op(q, k, v, g, lse):
+        return _grad_op(q, k, v, None, g, lse, D ** -0.5, 0.0, True, block_q,
+                        block_k)
 
     assert _kernels(jax.jit(attend).lower(q, k, k).compile()) == 1
-    back = jax.jit(grads).lower(q, k, k, q)
+    back = jax.jit(grad_op).lower(q, k, k, q, _lse(q, one_chip))
     assert [tuple(o.shape) for o in back.out_info] == [
         (B, H, S, D), (B, kv, S, D), (B, kv, S, D)]
     assert _kernels(back.compile()) == 1
@@ -404,11 +431,13 @@ def test_expert_layer_step_holds_nine_grouped_matmuls_and_one_sort_by_expert(
                 or in_scope(ln, "moe_router_grad")]
 
 
-def _bert_s512_step(strategy=None):
+def _bert_s512_step(strategy=None, with_lse=True):
     """A 12-layer BERT train step at S=512 (bert_base.pretrain_s512's op:
     bias, dropout 0.1, d=64; narrow otherwise) with ``impl='auto'`` and no
     tuning decision on disk, under ``strategy`` where one is given: the
-    Program, the jitted step and its arguments."""
+    Program, the jitted step and its arguments. ``with_lse=False``: as a
+    desc from before the op declared ``Lse`` reads (no such output on the
+    op, no such input on its grad op)."""
     import numpy as np
 
     import paddle_tpu as fluid
@@ -429,6 +458,12 @@ def _bert_s512_step(strategy=None):
         nsp = fluid.data("nsp_label", [B, 1], "int64", **A)
         total, _, _ = bert.pretrain(*ids, mask, mpos, mlabel, nsp, cfg)
         fluid.optimizer.Adam(1e-4).minimize(total)
+    for op in [] if with_lse else main.global_block().ops:
+        if op.type == "fused_attention":
+            del op.outputs["Lse"]
+        elif op.type == "fused_attention_grad":
+            del op.inputs["Lse"]
+            op.attrs["__fwd_out_slots__"] = ["Out"]
     scope = fluid.Scope()
     exe = fluid.Executor()
     exe.run(startup, scope=scope)
@@ -444,15 +479,21 @@ def _bert_s512_step(strategy=None):
 
 
 def _lowering_counts(main):
-    """What the executor's counter would add for the trace just made:
-    {"<impl>@<block_q>": ops}."""
+    """What the executor's counters would add for the trace just made:
+    {"<impl>@<block_q>": ops} and, for the grad ops, {"<stats>": ops}."""
     from paddle_tpu.observability import attention as obs_attention
     from paddle_tpu.observability.metrics import MetricsRegistry
     registry = MetricsRegistry()
     obs_attention.count_lowerings(
         main._lowering_notes.pop("fused_attention"), "step", registry)
-    return {dict(k)["impl"] + "@" + dict(k)["block_q"]: c.value
-            for k, c in registry.get("attention_lowering_total").items()}
+    obs_attention.count_backwards(
+        main._lowering_notes.pop("fused_attention_grad"), "step", registry)
+    counts = {dict(k)["impl"] + "@" + dict(k)["block_q"]: c.value
+              for k, c in registry.get("attention_lowering_total").items()}
+    counts.update(
+        (dict(k)["stats"], c.value)
+        for k, c in registry.get("attention_backward_total").items())
+    return counts
 
 
 def test_bert_s512_step_holds_24_kernels_lowered_once_and_no_score_matrix(
@@ -460,9 +501,11 @@ def test_bert_s512_step_holds_24_kernels_lowered_once_and_no_score_matrix(
     """The step on one chip: the defaults give every layer the kernels. The
     compiled step holds 12 forward + 12 backward Mosaic calls and no
     [B, heads, S, S] array; the lowered module holds each kernel once (the
-    layers, and the forwards the grad ops trace again, share one trace and
-    one lowered function: the set-up half of ISSUE 27); the lowering counter
-    says 12 x pallas at block_q 512."""
+    layers share one trace and one lowered function: the set-up half of
+    ISSUE 27) and, before XLA has dropped anything, calls the forward's 12
+    times and the backward's 12: no grad op lowers a forward (it reads the
+    forward op's Lse), so none is there for XLA to keep; the counters say
+    12 x pallas at block_q 512 and 12 backwards on saved statistics."""
     import re
 
     import numpy as np
@@ -473,8 +516,11 @@ def test_bert_s512_step_holds_24_kernels_lowered_once_and_no_score_matrix(
         x = x if hasattr(x, "dtype") else np.asarray(x)
         return jax.ShapeDtypeStruct(x.shape, x.dtype, sharding=one_chip)
     lowered = fn.lower(*jax.tree_util.tree_map(spec, args))
-    assert lowered.as_text().count("tpu_custom_call") == 2
-    assert _lowering_counts(main) == {"pallas@512": 12}
+    module = lowered.as_text()
+    assert module.count("tpu_custom_call") == 2
+    assert len(re.findall(r"call @_fwd_call\b", module)) == 12
+    assert len(re.findall(r"call @_bwd_call\b", module)) == 12
+    assert _lowering_counts(main) == {"pallas@512": 12, "saved": 12}
     text = lowered.compile().as_text()
     kernels = [ln for ln in text.splitlines()
                if 'custom_call_target="tpu_custom_call"' in ln]
@@ -486,14 +532,34 @@ def test_bert_s512_step_holds_24_kernels_lowered_once_and_no_score_matrix(
     assert "[4,2,512,512]" not in text
 
 
+def test_bert_s512_step_from_a_desc_without_lse_still_holds_24_kernels(
+        one_chip, as_on_the_chip):
+    """A Program from before the op declared ``Lse``: its grad ops take the
+    generic path (``recomputed``: the vjp lowers the forward kernel again
+    for the statistics), and XLA merges that call with the forward op's own
+    -- the same kernel on the same operands, both outputs written by both --
+    so the compiled step still holds 12 + 12 Mosaic calls, not 36."""
+    import numpy as np
+
+    main, fn, args = _bert_s512_step(with_lse=False)
+
+    def spec(x):
+        x = x if hasattr(x, "dtype") else np.asarray(x)
+        return jax.ShapeDtypeStruct(x.shape, x.dtype, sharding=one_chip)
+    lowered = fn.lower(*jax.tree_util.tree_map(spec, args))
+    assert _lowering_counts(main) == {"pallas@512": 12, "recomputed": 12}
+    assert _kernels(lowered.compile()) == 24
+
+
 def test_bert_s512_step_under_a_dp_mesh_compiles_on_the_composed_lowering(
         v5e, as_on_the_chip, monkeypatch):
     """The same step data-parallel over the four described chips. A Mosaic
     call has no partitioning rule, so a jit over more than one device
     refuses to lower one outside a shard_map: 'auto' must read the mesh and
     keep XLA's composed lowering there (REVIEW of PR 27: with the crossover
-    at 256 alone this step stopped compiling). It compiles, holds no Mosaic
-    call and the gradients' all-reduces, and the counter says 12 x xla."""
+    at 256 alone this step stopped compiling), and its grad op the generic
+    vjp over that lowering. It compiles, holds no Mosaic call and the
+    gradients' all-reduces, and the counters say 12 x xla, 12 x generic."""
     import numpy as np
     from jax.sharding import Mesh
 
@@ -513,7 +579,7 @@ def test_bert_s512_step_under_a_dp_mesh_compiles_on_the_composed_lowering(
         x = x if hasattr(x, "dtype") else np.asarray(x)
         return jax.ShapeDtypeStruct(x.shape, x.dtype)
     lowered = fn.lower(*jax.tree_util.tree_map(spec, args))
-    assert _lowering_counts(main) == {"xla@0": 12}
+    assert _lowering_counts(main) == {"xla@0": 12, "generic": 12}
     text = lowered.compile().as_text()
     assert 'custom_call_target="tpu_custom_call"' not in text
     assert "all-reduce" in text
