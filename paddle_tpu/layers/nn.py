@@ -1057,11 +1057,18 @@ def beam_search_decode(ids, parents, scores, beam_size=None, end_id=1,
 
 
 def fused_attention(q, k, v, bias=None, scale=None, dropout_prob=0.0,
-                    causal=False, is_test=False, impl="auto", name=None):
+                    causal=False, is_test=False, impl="auto", name=None,
+                    window=None):
     """Fused scaled-dot-product attention over head-split tensors.
 
-    q/k/v: [B, heads, S, D]; bias: optional [B, 1, 1, S] additive mask. Lowers
-    to one flash-attention Pallas kernel on TPU (ops/pallas_attention.py); the
+    q: [B, heads, S, D], k/v: [B, kv_heads, S, D] with kv_heads dividing heads
+    (grouped-query attention; heads x D need not be the model's hidden size);
+    bias: optional [B, 1, 1, S] additive mask. ``window`` (with ``causal``):
+    a sliding window, query i sees the keys i - window < j <= i (HF's
+    ``sliding_window``); None, or a window of S or more, is plain causal
+    attention. Lowers
+    to one flash-attention Pallas kernel on TPU (ops/pallas_attention.py),
+    which under a window visits only the K tiles the window reaches; the
     composed softmax(QK^T)V path otherwise. Reference analog: the subgraph that
     multihead_matmul_fuse_pass.cc:1 pattern-matches, exposed as one op.
     The op has a second output, ``Lse`` [B, heads, 1, S] float32: the rows'
@@ -1074,12 +1081,16 @@ def fused_attention(q, k, v, bias=None, scale=None, dropout_prob=0.0,
     inputs = {"Q": [q], "K": [k], "V": [v]}
     if bias is not None:
         inputs["Bias"] = [bias]
+    attrs = {"scale": float(scale) if scale else 0.0,
+             "dropout_prob": float(dropout_prob),
+             "causal": bool(causal), "is_test": bool(is_test), "impl": impl}
+    if window:
+        if not causal:
+            raise ValueError("fused_attention: a sliding window needs causal")
+        attrs["window"] = int(window)
     helper.append_op("fused_attention", inputs=inputs,
                      outputs={"Out": [out], "Lse": [lse]},   # Out first: the op's salt
-                     attrs={"scale": float(scale) if scale else 0.0,
-                            "dropout_prob": float(dropout_prob),
-                            "causal": bool(causal), "is_test": bool(is_test),
-                            "impl": impl})
+                     attrs=attrs)
     return _var(helper, out)
 
 
@@ -1099,13 +1110,53 @@ def rms_norm(input, epsilon=1e-5, param_attr=None, name=None):
     return _var(helper, y)
 
 
-def rotary_embedding(x, theta=10000.0, name=None):
+def rotary_embedding(x, theta=10000.0, name=None, rotary_dim=None,
+                     scaling=None):
     """Rotary position embedding (rotate-half convention) over ``x [..., S,
-    D]``, positions 0..S-1 along axis -2."""
+    D]``, positions 0..S-1 along axis -2. ``rotary_dim`` (default D): the
+    first ``rotary_dim`` values of a head are rotated among themselves and
+    the rest pass through (HF's ``partial_rotary_factor`` x D). ``scaling``:
+    None, or a dict in HF's ``rope_scaling`` keys with ``rope_type`` ``"yarn"``
+    (``factor``, ``original_max_position_embeddings``, ``beta_fast`` 32,
+    ``beta_slow`` 1, ``attention_factor`` default ``0.1 ln(factor) + 1``):
+    the frequencies blended as HF's ``_compute_yarn_parameters`` blends them
+    over ``rotary_dim``, cos and sin times ``attention_factor``
+    (``ops/decoder_ops.py:yarn_inv_freq``)."""
+    import math
     helper = LayerHelper("rotary_embedding", name=name)
     out = _out(helper, x.dtype)
+    attrs = {"theta": float(theta)}
+    if rotary_dim and int(rotary_dim) != int(x.shape[-1]):
+        attrs["rotary_dim"] = int(rotary_dim)
+    kind = (scaling or {}).get("rope_type", (scaling or {}).get("type"))
+    if kind == "yarn":
+        factor = float(scaling["factor"])
+        attrs.update(
+            scaling="yarn", factor=factor,
+            original_max_position=float(
+                scaling["original_max_position_embeddings"]),
+            beta_fast=float(scaling.get("beta_fast") or 32.0),
+            beta_slow=float(scaling.get("beta_slow") or 1.0),
+            attention_factor=float(scaling.get("attention_factor")
+                                   or 0.1 * math.log(factor) + 1.0))
+    elif kind not in (None, "default"):
+        raise NotImplementedError(
+            f"rotary_embedding: rope_type {kind!r} is not built (only "
+            f"'default' and 'yarn')")
     helper.append_op("rotary_embedding", inputs={"X": [x]},
-                     outputs={"Out": [out]}, attrs={"theta": float(theta)})
+                     outputs={"Out": [out]}, attrs=attrs)
+    return _var(helper, out)
+
+
+def attention_gate(x, gate, name=None):
+    """Attention's per-head output gate: ``x [B, heads, S, D]``, the heads'
+    outputs as ``fused_attention`` returns them, times ``sigmoid(gate [B *
+    S, heads])``, one gate a token and head, before the output projection
+    (``ops/decoder_ops.py:attention_gate``, float32 inside)."""
+    helper = LayerHelper("attention_gate", name=name)
+    out = _out(helper, x.dtype)
+    helper.append_op("attention_gate", inputs={"X": [x], "Gate": [gate]},
+                     outputs={"Out": [out]})
     return _var(helper, out)
 
 
@@ -1182,10 +1233,12 @@ def moe_bias_update(bias, load, rate, name=None):
 
 def moe_ffn(x, num_experts, experts_per_token, expert_width, param_attr=None,
             name="moe", experts_held=None, scoring="softmax", norm_topk=False,
-            routed_scale=1.0, expert_bias=False):
+            routed_scale=1.0, expert_bias=False, row_budget=None,
+            shared_width=None):
     """A dropless mixture-of-experts feed-forward layer over tokens
     ``x [T, H]``: a float32 router (softmax over the experts, top-k values
-    used as they are), every one of the T x k assignments sent to its expert
+    used as they are, or under ``norm_topk`` over their sum, times
+    ``routed_scale``), every one of the T x k assignments sent to its expert
     (sort by expert -> grouped matmuls over three stacked weights -> sum of
     each token's k rows; no capacity, nothing dropped), each expert
     ``W_down (silu(W_gate x) * (W_up x))``, its router weight applied to the
@@ -1205,7 +1258,20 @@ def moe_ffn(x, num_experts, experts_per_token, expert_width, param_attr=None,
     only, and the output is the held experts' part of each token's sum (an
     assignment to an expert held elsewhere adds nothing here: what an
     exchange would bring is not stood in for). The row buffers keep all
-    T x k rows, so nothing can overflow whatever the routing.
+    T x k rows, so nothing can overflow whatever the routing -- unless the
+    layer states a ``row_budget`` R (with ``experts_held`` only): the sort's
+    output, the grouped products, the gated product and the combine are then
+    sized for R rows, of which an even router fills ``T x k x count /
+    num_experts``; the held experts' rows beyond R are dropped (they add
+    nothing to their tokens) and counted in ``<name>_dropped_rows [1]``, an
+    int32 state variable summed over the steps (``aux["dropped"]``). A
+    budget of T x k rows is the layer without one.
+
+    ``shared_width``: one shared expert beside the routed ones, a dense
+    SwiGLU of that width over every token (``<name>_shared_gate_w`` /
+    ``_shared_up_w [H, shared_width]``, ``_shared_down_w [shared_width,
+    H]``), added ungated to the routed experts' sum. Under ``experts_held``
+    every share computes it alike: over the shares it counts once.
 
     Parameters, by name: ``<name>_router_w [H, E]`` float32 and
     ``<name>_gate_w`` / ``<name>_up_w [held, H, width]``, ``<name>_down_w
@@ -1215,7 +1281,9 @@ def moe_ffn(x, num_experts, experts_per_token, expert_width, param_attr=None,
     ``prob [T, E]`` (the scores), ``logz [T]`` (logsumexp of the logits;
     softmax scoring only), ``index [T, k]`` and ``load [E]`` (assignments
     received by each of the E experts, held here or not, int32), the last
-    two without gradient, for the router losses and to be fetched.
+    two without gradient, for the router losses and to be fetched;
+    ``dropped`` under a ``row_budget``; ``routed [T, H]``, the routed
+    experts' part of ``out`` (all of it without a shared expert).
     """
     from ..initializer import Constant
     from ..layer_helper import ParamAttr
@@ -1228,11 +1296,12 @@ def moe_ffn(x, num_experts, experts_per_token, expert_width, param_attr=None,
                          f"range of the {E} experts")
     if scoring not in ("softmax", "sigmoid"):
         raise ValueError(f"moe_ffn: scoring={scoring!r}")
-    if scoring == "softmax" and (expert_bias or norm_topk
-                                 or routed_scale != 1.0):
+    if scoring == "softmax" and expert_bias:
         raise NotImplementedError(
-            "moe_ffn: expert_bias, norm_topk and routed_scale are built for "
-            "scoring='sigmoid' only")
+            "moe_ffn: expert_bias is built for scoring='sigmoid' only")
+    if row_budget is not None and held == E:
+        raise ValueError("moe_ffn: a row_budget is for a layer that holds a "
+                         "part of its experts (experts_held)")
     init = ParamAttr._to_attr(param_attr).initializer
 
     def param(suffix, shape, dtype):
@@ -1253,6 +1322,9 @@ def moe_ffn(x, num_experts, experts_per_token, expert_width, param_attr=None,
     if scoring == "softmax":
         routed["LogZ"] = [logz]
         aux["logz"] = logz
+        if norm_topk or routed_scale != 1.0:
+            router_attrs.update(norm_topk=bool(norm_topk),
+                                scale=float(routed_scale))
     else:
         router_attrs.update(scoring="sigmoid", norm_topk=bool(norm_topk),
                             scale=float(routed_scale))
@@ -1271,8 +1343,18 @@ def moe_ffn(x, num_experts, experts_per_token, expert_width, param_attr=None,
         groups = _out(helper, "int32", stop_gradient=True)
         sorted_to["GroupCount"] = [groups]
         sort_attrs["first_expert"] = first
+    if row_budget is not None:
+        dropped = _out(helper, "int32", stop_gradient=True)
+        sorted_to["Dropped"] = [dropped]
+        sort_attrs.update(rows=int(row_budget), held=held)
     op("moe_dispatch", {"X": [x], "Index": [index], "Weight": [weight]},
        sorted_to, sort_attrs)
+    if row_budget is not None:
+        aux["dropped"] = helper.create_global_variable(
+            [1], "int32", persistable=True, name=f"{name}_dropped_rows",
+            initializer=Constant(0))
+        op("sum", {"X": [aux["dropped"], dropped]},
+           {"Out": [aux["dropped"]]})
 
     def experts(inp, suffix, shape):
         out = _out(helper, x.dtype)
@@ -1286,6 +1368,16 @@ def moe_ffn(x, num_experts, experts_per_token, expert_width, param_attr=None,
     down = experts(gated, "down_w", [held, width, H])
     out = _out(helper, x.dtype)
     op("moe_combine", {"X": [down], "Order": [order], "Slot": [slot]},
-       {"Out": [out]})
+       {"Out": [out]},
+       {} if row_budget is None else {"rows": int(row_budget)})
+    aux["routed"] = out
+    if shared_width:
+        def dense(inp, suffix, size):
+            return fc(inp, size, bias_attr=False, param_attr=ParamAttr(
+                name=f"{name}_shared_{suffix}", initializer=init))
+        shared = dense(swiglu(dense(x, "gate_w", int(shared_width)),
+                              dense(x, "up_w", int(shared_width))),
+                       "down_w", H)
+        out = elementwise_add(_var(helper, out), shared)
     blk = helper.main_program.current_block()
     return blk.var(out.name), {n: blk.var(v.name) for n, v in aux.items()}

@@ -13,35 +13,55 @@ pre-norm, without biases or dropout: ``h = x + r operator(norm(x))``,
 
 - ``layer_types`` (default: every layer ``full_attention``), one operator a
   layer. ``full_attention`` (also spelt ``attention``): separate q / k / v
-  projections with
+  projections with ``num_attention_heads`` query heads
+  (``num_attention_heads_per_layer[i]`` in layer i where the config has
+  that list) of ``head_dim`` values (default hidden size / heads; heads x
+  ``head_dim`` need not be the hidden size) and
   ``num_key_value_heads`` key/value heads (grouped-query attention where
-  fewer than ``num_attention_heads``; ``fused_attention`` reads them in
+  fewer; ``fused_attention`` reads them in
   place), RMSNorm of q and k -- ``qk_norm`` ``"projection"``: over the whole
   projected q and k before the split into heads, one scale an element
   (OLMoE); ``"head"``: over each head's values, one scale of head size
-  shared by the heads (LFM2); ``"none"``: no norm (Granite) --, rotary
-  embedding (rotate-half, ``rope_theta``) on q and k unless
-  ``position_embedding_type`` is ``"nope"``, causal ``fused_attention`` at
+  shared by the heads (LFM2); ``"none"``: no norm (Granite, Laguna) --,
+  rotary embedding (rotate-half) on q and k unless
+  ``position_embedding_type`` is ``"nope"`` -- ``rope_theta`` over the whole
+  head, or by layer type from ``rope_parameters[<layer type>]``:
+  ``rope_theta``, ``partial_rotary_factor`` (the leading share of a head
+  that is rotated) and ``rope_type`` ``"default"`` or ``"yarn"``
+  (``layers.rotary_embedding``) --, causal ``fused_attention`` at
   scale ``attention_multiplier`` (default 1/sqrt(head dim)),
-  ``impl="auto"``. ``conv``: the gated short convolution ``W_out (C * conv(B
+  ``impl="auto"``; under ``gating: "per-head"`` each head's output times
+  ``sigmoid(W_g norm(x))``, one gate a token and head, before the output
+  projection (``layers.attention_gate``). ``sliding_attention``: the same
+  with a window of ``sliding_window`` keys (query i sees i - window < j <=
+  i). ``conv``: the gated short convolution ``W_out (C * conv(B
   * u))`` with ``B, C, u = split(W_in x, 3)`` and a causal depthwise filter
   of ``conv_L_cache`` taps (``layers.short_conv``). ``mamba``: the Mamba-2
   mixer (``mamba``, below).
-- feed-forward: the first ``num_dense_layers`` layers (default 0), and every
+- feed-forward: the first ``num_dense_layers`` layers (default 0), the
+  layers ``mlp_layer_types`` calls ``"dense"`` or ``mlp_only_layers`` lists,
+  and every
   layer of a config without experts (``num_local_experts: 0``), a dense
   SwiGLU ``W_down (silu(W_gate x) * (W_up x))`` of width
   ``intermediate_size`` (``shared_intermediate_size`` where the config has
   that key); the others ``layers.moe_ffn`` of width
   ``moe_intermediate_size`` (``intermediate_size`` where the config has no
   such key). ``router_scoring`` ``"softmax"`` (default): float32 router,
-  softmax then top-k with the values used as they are; ``"sigmoid"``:
+  softmax then top-k with the values used as they are, or over their sum
+  under ``norm_topk_prob``, times ``routed_scaling_factor`` (also spelt
+  ``moe_routed_scaling_factor``); ``"sigmoid"``:
   sigmoid scores, chosen by score + bias under ``use_expert_bias``, weighed
   by the score over the chosen scores' sum under ``norm_topk_prob``, times
-  ``routed_scaling_factor``.
+  ``routed_scaling_factor``. ``shared_expert_intermediate_size``: one
+  shared expert, a dense SwiGLU of that width over every token, added
+  ungated beside the routed experts' sum.
 - one chip's share of a layer that several chips hold: ``num_experts`` is
   the experts held here, ``num_experts_routed`` (default: the same) the
   router's width and ``first_expert_held`` (default 0) the first held; the
-  layer's output is the held experts' part (``layers.moe_ffn``). A sliced
+  layer's output is the held experts' part (``layers.moe_ffn``), plus the
+  shared expert where there is one (every chip computes it alike).
+  ``moe_row_budget``: the sorted rows such a layer keeps (``layers.moe_ffn``'s
+  ``row_budget``; rows beyond it are dropped and counted). A sliced
   vocabulary is a smaller ``vocab_size``.
 - ``embedding_multiplier`` times the looked-up rows, and the logits over
   ``logits_scaling`` (both default 1); under ``tie_word_embeddings`` the
@@ -57,9 +77,10 @@ pre-norm, without biases or dropout: ``h = x + r operator(norm(x))``,
   appended by the caller after ``minimize``.
 
 Models through it: OLMoE-1B-7B (Muennighoff et al., arXiv:2409.02060; HF
-``modeling_olmoe.py``), LFM2-8B-A1B (HF ``modeling_lfm2_moe.py``) and
+``modeling_olmoe.py``), LFM2-8B-A1B (HF ``modeling_lfm2_moe.py``),
 granite-4.0-h-micro (HF ``modeling_granitemoehybrid.py``; the scan: Dao &
-Gu, arXiv:2405.21060).
+Gu, arXiv:2405.21060) and Laguna-S-2.1 (its ``config.json``; YaRN: Peng et
+al., arXiv:2309.00071).
 
 Dtypes follow ``models/bert.py``: the embedding table is float32 whatever
 ``dtype`` says, activations are cast to ``dtype`` right after the lookup,
@@ -80,8 +101,11 @@ from ..layer_helper import ParamAttr
 _REQUIRED = {"hidden_act": "silu", "attention_bias": False,
              "clip_qkv": None, "rope_scaling": None, "conv_bias": False,
              "mamba_proj_bias": False, "mamba_n_groups": 1,
-             "normalization_function": "rmsnorm"}
-_OPERATORS = ("full_attention", "conv", "mamba")
+             "normalization_function": "rmsnorm",
+             "moe_apply_router_weight_on_input": False,
+             "moe_router_logit_softcapping": 0, "decoder_sparse_step": 1}
+_OPERATORS = ("full_attention", "sliding_attention", "conv", "mamba")
+_ATTENTION = ("full_attention", "sliding_attention")
 
 
 def _check(cfg: dict) -> None:
@@ -98,17 +122,38 @@ def _check(cfg: dict) -> None:
         if kind not in _OPERATORS:
             raise NotImplementedError(
                 f"decoder_lm: layer type {kind!r} is not built yet (only "
-                f"{_OPERATORS}: no sliding-window or chunked attention, no "
-                f"latent attention, no gated delta rule)")
+                f"{_OPERATORS}: no chunked attention, no latent attention, "
+                f"no gated delta rule)")
+    if "sliding_attention" in kinds and not cfg.get("sliding_window"):
+        raise ValueError("decoder_lm: sliding_attention layers need "
+                         "sliding_window")
     if "mamba" in kinds and (
             cfg["mamba_n_heads"] * cfg["mamba_d_head"]
             != cfg["mamba_expand"] * cfg["hidden_size"]):
         raise ValueError("mamba_n_heads x mamba_d_head must equal "
                          "mamba_expand x hidden_size")
-    if cfg["hidden_size"] % cfg["num_attention_heads"] or \
-            cfg["num_attention_heads"] % _kv_heads(cfg):
-        raise ValueError("hidden_size must be a multiple of the head count, "
-                         "the head count of num_key_value_heads")
+    per_layer = cfg.get("num_attention_heads_per_layer")
+    if per_layer is not None and len(per_layer) != len(kinds):
+        raise ValueError("num_attention_heads_per_layer must give every one "
+                         "of the num_hidden_layers layers a head count")
+    for i, kind in enumerate(kinds):
+        if kind not in _ATTENTION:
+            continue
+        if _heads(cfg, i) % _kv_heads(cfg) or (
+                "head_dim" not in cfg and cfg["hidden_size"] % _heads(cfg, i)):
+            raise ValueError(
+                "the head count must be a multiple of num_key_value_heads "
+                "and, without head_dim, divide hidden_size")
+        kind_of_rope = _rope(cfg, kind).get("rope_type", "default")
+        if kind_of_rope not in ("default", "yarn"):
+            raise NotImplementedError(
+                f"decoder_lm: rope_type={kind_of_rope!r} is not built yet "
+                f"(only 'default' and 'yarn')")
+    if cfg.get("gating", "none") not in ("none", "per-head") or any(
+            g != "per_head" for g in cfg.get("gating_types", [])):
+        raise NotImplementedError(
+            f"decoder_lm: gating={cfg.get('gating')!r} with gating_types "
+            f"other than per_head is not built yet (only 'per-head')")
     if cfg.get("qk_norm", "projection") not in ("projection", "head",
                                                  "none"):
         raise NotImplementedError(
@@ -118,18 +163,28 @@ def _check(cfg: dict) -> None:
             f"decoder_lm: position_embedding_type="
             f"{cfg['position_embedding_type']!r} is not built yet (rotary "
             f"or none)")
-    if (cfg.get("n_shared_experts") or cfg.get("num_shared_experts")
+    if (max(cfg.get("n_shared_experts") or 0,
+            cfg.get("num_shared_experts") or 0) > 1
             or cfg.get("num_local_experts")):
         raise NotImplementedError(
-            "decoder_lm: shared experts (a dense feed-forward beside the "
-            "routed experts of a layer) are not built yet")
+            "decoder_lm: of shared experts only one a layer is built "
+            "(shared_expert_intermediate_size): not n_shared_experts / "
+            "num_shared_experts above 1, nor routed experts beside a shared "
+            "feed-forward under num_local_experts")
+    if (cfg.get("n_shared_experts") or cfg.get("num_shared_experts")) \
+            and not cfg.get("shared_expert_intermediate_size"):
+        raise ValueError("decoder_lm: a shared expert needs "
+                         "shared_expert_intermediate_size")
     sigmoid = cfg.get("router_scoring", "softmax") == "sigmoid"
-    if not sigmoid and (cfg.get("norm_topk_prob") or cfg.get(
-            "use_expert_bias") or cfg.get("routed_scaling_factor", 1) != 1):
+    if not sigmoid and cfg.get("use_expert_bias"):
         raise NotImplementedError(
-            "decoder_lm: norm_topk_prob, use_expert_bias and "
-            "routed_scaling_factor are built for router_scoring='sigmoid' "
-            "only")
+            "decoder_lm: use_expert_bias is built for "
+            "router_scoring='sigmoid' only")
+    if cfg.get("moe_row_budget") and cfg.get(
+            "num_experts_routed", cfg.get("num_experts")) == cfg.get(
+                "num_experts"):
+        raise ValueError("decoder_lm: moe_row_budget is for a layer that "
+                         "holds a part of its experts (num_experts_routed)")
     if sigmoid and ("router_aux_loss_coef" in cfg
                     or "router_z_loss_coef" in cfg):
         raise NotImplementedError(
@@ -147,6 +202,29 @@ def _kv_heads(cfg: dict) -> int:
     return cfg.get("num_key_value_heads") or cfg["num_attention_heads"]
 
 
+def _heads(cfg: dict, layer: int) -> int:
+    per_layer = cfg.get("num_attention_heads_per_layer")
+    return per_layer[layer] if per_layer else cfg["num_attention_heads"]
+
+
+def _rope(cfg: dict, kind: str) -> dict:
+    """The rotary parameters of an attention layer of type ``kind``, in
+    HF's keys: ``rope_parameters[kind]`` where the config gives them by
+    layer type, else ``rope_theta`` over the whole head."""
+    by_type = cfg.get("rope_parameters")
+    if by_type is None:
+        return {"rope_theta": cfg.get("rope_theta", 10000.0)}
+    return by_type[kind]
+
+
+def _is_dense(cfg: dict, layer: int) -> bool:
+    kinds = cfg.get("mlp_layer_types")
+    return bool(layer < cfg.get("num_dense_layers", 0)
+                or "num_experts" not in cfg
+                or (kinds and kinds[layer] == "dense")
+                or layer in cfg.get("mlp_only_layers", ()))
+
+
 def _eps(cfg: dict) -> float:
     return cfg["rms_norm_eps"] if "rms_norm_eps" in cfg else cfg["norm_eps"]
 
@@ -159,26 +237,32 @@ def _linear(x, size: int, name: str):
     return layers.fc(x, size, param_attr=_attr(name), bias_attr=False)
 
 
-def attention(x, cfg: dict, batch: int, seq: int, name: str):
-    """Causal self-attention over tokens ``x [batch * seq, H]``, with
-    ``num_key_value_heads`` key/value heads."""
-    H, heads, kv_heads = (cfg["hidden_size"], cfg["num_attention_heads"],
-                          _kv_heads(cfg))
-    d = H // heads
+def attention(x, cfg: dict, batch: int, seq: int, name: str, layer: int = 0,
+              kind: str = "full_attention"):
+    """Causal self-attention of layer ``layer`` over tokens ``x [batch *
+    seq, H]``, with ``num_key_value_heads`` key/value heads; under ``kind``
+    ``"sliding_attention"`` within a window of ``sliding_window`` keys."""
+    H, heads, kv_heads = cfg["hidden_size"], _heads(cfg, layer), _kv_heads(cfg)
+    d = cfg.get("head_dim") or H // heads
     eps = _eps(cfg)
     norm = cfg.get("qk_norm", "projection")
     by_head, whole = norm == "head", norm == "projection"
     rotary = cfg.get("position_embedding_type", "rope") == "rope"
+    rope = _rope(cfg, kind)
 
     def heads_of(t, n, norm_w=None, positions=rotary):
         t = layers.reshape(t, [batch, seq, n, d])   # [B*S, n*d] -> [B, n, S, d]
         if norm_w:
             t = layers.rms_norm(t, eps, ParamAttr(name=norm_w))
         t = layers.transpose(t, [0, 2, 1, 3])
-        return layers.rotary_embedding(t, cfg["rope_theta"]) if positions \
-            else t
+        if not positions:
+            return t
+        return layers.rotary_embedding(
+            t, rope["rope_theta"],
+            rotary_dim=int(d * rope.get("partial_rotary_factor", 1)),
+            scaling=rope)
 
-    q = _linear(x, H, name + "_q_w")
+    q = _linear(x, heads * d, name + "_q_w")
     if whole:
         q = layers.rms_norm(q, eps, ParamAttr(name=name + "_q_norm_w"))
     k = _linear(x, kv_heads * d, name + "_k_w")
@@ -190,9 +274,12 @@ def attention(x, cfg: dict, batch: int, seq: int, name: str):
         heads_of(k, kv_heads, name + "_k_norm_w" if by_head else None),
         heads_of(v, kv_heads, positions=False), causal=True,
         scale=float(cfg.get("attention_multiplier", 1.0 / math.sqrt(d))),
-        impl="auto")
+        impl="auto",
+        window=cfg["sliding_window"] if kind == "sliding_attention" else None)
+    if cfg.get("gating", "none") == "per-head":
+        ctx = layers.attention_gate(ctx, _linear(x, heads, name + "_g_w"))
     ctx = layers.reshape(layers.transpose(ctx, [0, 2, 1, 3]),
-                         [batch * seq, H])
+                         [batch * seq, heads * d])
     return _linear(ctx, H, name + "_o_w")
 
 
@@ -284,7 +371,8 @@ def mamba(x, cfg: dict, batch: int, seq: int, name: str):
 
 
 def experts(x, cfg: dict, name: str):
-    """The layer's routed experts (``layers.moe_ffn``) from the config."""
+    """The layer's routed experts, and its shared expert where the config
+    has one (``layers.moe_ffn``), from the config."""
     held = cfg["num_experts"]
     routed = cfg.get("num_experts_routed", held)
     return layers.moe_ffn(
@@ -295,13 +383,18 @@ def experts(x, cfg: dict, name: str):
                       else (cfg.get("first_expert_held", 0), held)),
         scoring=cfg.get("router_scoring", "softmax"),
         norm_topk=bool(cfg.get("norm_topk_prob", False)),
-        routed_scale=float(cfg.get("routed_scaling_factor", 1.0)),
-        expert_bias=bool(cfg.get("use_expert_bias", False)))
+        routed_scale=float(cfg.get(
+            "routed_scaling_factor",
+            cfg.get("moe_routed_scaling_factor", 1.0))),
+        expert_bias=bool(cfg.get("use_expert_bias", False)),
+        row_budget=cfg.get("moe_row_budget"),
+        shared_width=cfg.get("shared_expert_intermediate_size"))
+
 
 
 def block(x, cfg: dict, batch: int, seq: int, name: str,
-          kind: str = "full_attention", dense: bool = False):
-    """One decoder layer over ``x [batch * seq, H]`` with the operator
+          kind: str = "full_attention", dense: bool = False, layer: int = 0):
+    """Decoder layer ``layer`` over ``x [batch * seq, H]`` with the operator
     ``kind``; returns the layer's output and the router's variables
     (``layers.moe_ffn``; None for a ``dense`` feed-forward layer)."""
     eps = _eps(cfg)
@@ -317,7 +410,7 @@ def block(x, cfg: dict, batch: int, seq: int, name: str,
     elif kind == "mamba":
         mixed = mamba(normed, cfg, batch, seq, op_name)
     else:
-        mixed = attention(normed, cfg, batch, seq, op_name)
+        mixed = attention(normed, cfg, batch, seq, op_name, layer, kind)
     h = add(x, mixed)
     normed = layers.rms_norm(h, eps, ParamAttr(name=name + "_ffn_norm_w"))
     if dense:
@@ -354,8 +447,11 @@ def build(cfg: dict, ids, labels) -> dict:
     (the two router losses before their coefficients; only where the config
     has them), and per expert layer ``expert_load`` (``[experts routed]``
     int32: assignments an expert received), ``expert_index`` (``[batch *
-    seq, k]``: the experts chosen) and ``expert_bias`` (the selection bias,
-    under ``use_expert_bias``)."""
+    seq, k]``: the experts chosen), ``expert_bias`` (the selection bias,
+    under ``use_expert_bias``), ``expert_dropped`` (``[1]`` int32: the
+    rows the layer's ``moe_row_budget`` has dropped since startup) and
+    ``expert_routed`` (``[batch * seq, H]``: the routed experts' part of
+    the layer's output, without the shared expert's)."""
     _check(cfg)
     batch, seq = int(ids.shape[0]), int(ids.shape[1])
     H = cfg["hidden_size"]
@@ -369,11 +465,11 @@ def build(cfg: dict, ids, labels) -> dict:
     if dtype != "float32":
         x = layers.cast(x, dtype)
     x = layers.reshape(x, [batch * seq, H])
-    balance, z, loads, indices, biases = [], [], [], [], []
+    balance, z, loads, indices, biases, dropped, routed = (
+        [] for _ in range(7))
     for i, kind in enumerate(_layer_types(cfg)):
         x, aux = block(x, cfg, batch, seq, f"layer{i}", kind,
-                       dense=(i < cfg.get("num_dense_layers", 0)
-                              or "num_experts" not in cfg))
+                       dense=_is_dense(cfg, i), layer=i)
         if aux is None:
             continue
         if router_losses:
@@ -387,8 +483,11 @@ def build(cfg: dict, ids, labels) -> dict:
             z.append(layers.mean(layers.square(aux["logz"])))
         loads.append(aux["load"])
         indices.append(aux["index"])
+        routed.append(aux["routed"])
         if "bias" in aux:
             biases.append(aux["bias"])
+        if "dropped" in aux:
+            dropped.append(aux["dropped"])
     x = layers.rms_norm(x, _eps(cfg), ParamAttr(name="final_norm_w"))
     if cfg.get("tie_word_embeddings"):
         table = x.block.program.global_block().var("tok_emb")
@@ -404,7 +503,8 @@ def build(cfg: dict, ids, labels) -> dict:
     each = layers.softmax_with_cross_entropy(logits, labels)
     ce = layers.mean(each)
     out = {"loss": ce, "ce": ce, "each": each, "expert_load": loads,
-           "expert_index": indices, "expert_bias": biases}
+           "expert_index": indices, "expert_bias": biases,
+           "expert_dropped": dropped, "expert_routed": routed}
     if router_losses:
         balance, z = _mean_of(balance), _mean_of(z)
         out["loss"] = layers.sums([

@@ -37,9 +37,9 @@ reproduction gets the counterpart the whole-program-jit design enables:
   (``moe_layers``, ``moe_experts``, ``moe_assignments_per_step``,
   ``moe_expert_param_bytes``) and ``load_stats`` for a fetched load vector.
 - ``attention`` -- ``attention_lowering_total{program,impl,s,block_q,block_k,
-  kv_heads}``: the lowering each ``fused_attention`` op of a compiled program
-  took; ``attention_k_tiles_total{program,state}``: the K tiles its flash
-  kernels visit and skip; ``attention_backward_total{program,stats}``: where
+  kv_heads,window,heads,head_dim}``: the lowering each ``fused_attention`` op
+  of a compiled program took; ``attention_k_tiles_total{program,state,
+  window}``: the K tiles its flash kernels visit and skip; ``attention_backward_total{program,stats}``: where
   each of its grad ops got the softmax statistics (``saved`` / ``recomputed``
   / ``generic``).
 

@@ -19,33 +19,39 @@ from .metrics import REGISTRY, MetricsRegistry
 
 def count_lowerings(notes: dict, program: str,
                     registry: Optional[MetricsRegistry] = None) -> None:
-    """``attention_lowering_total{program,impl,s,block_q,block_k,kv_heads}``:
-    the ``fused_attention`` ops the trace just compiled, by lowering
-    (``pallas``, ``xla``, ``ring``, ``ulysses``), sequence length, the
-    kernels' Q block and K tile (0 where no kernel ran; ``block_k`` = ``s``
-    is one tile a row) and key/value heads (fewer than the query's under
-    grouped-query attention). ``attention_k_tiles_total{program,state}``: the
-    K tiles the forward kernel of each such op passes over for one (batch,
-    head) (``visited``) and those a causal op leaves out because they lie
-    wholly above the diagonal (``skipped``), from static shapes
-    (``ops/pallas_attention.py:k_tiles``). ``notes`` maps each op's salt to
-    its ``(impl, s, block_q, block_k, kv_heads, visited, skipped)``; nothing
-    is added for a program without the op."""
+    """``attention_lowering_total{program,impl,s,block_q,block_k,kv_heads,
+    window,heads,head_dim}``: the ``fused_attention`` ops the trace just
+    compiled, by lowering (``pallas``, ``xla``, ``ring``, ``ulysses``),
+    sequence length, the kernels' Q block and K tile (0 where no kernel ran;
+    ``block_k`` = ``s`` is one tile a row), key/value heads (fewer than the
+    query's under grouped-query attention), the sliding window the lowering
+    applies (0: none, also for one no shorter than ``s``), query heads and
+    head size (a model may give its window layers more heads than its full
+    ones). ``attention_k_tiles_total{program,state,window}``: the K tiles
+    the forward kernel of each such op passes over for one (batch, head)
+    (``visited``) and those a causal op leaves out because they lie wholly
+    above the diagonal or wholly behind the window (``skipped``), from
+    static shapes (``ops/pallas_attention.py:k_tiles``). ``notes`` maps each
+    op's salt to its ``(impl, s, block_q, block_k, kv_heads, visited,
+    skipped, window, heads, head_dim)``; nothing is added for a program
+    without the op."""
     registry = registry or REGISTRY
-    for (impl, s, block_q, block_k, kv_heads, visited, skipped), n in Counter(
-            notes.values()).items():
+    for (impl, s, block_q, block_k, kv_heads, visited, skipped, window,
+         heads, head_dim), n in Counter(notes.values()).items():
         registry.counter(
             "attention_lowering_total",
             "fused_attention ops compiled, by the lowering each took",
             program=program, impl=impl, s=str(s), block_q=str(block_q),
-            block_k=str(block_k), kv_heads=str(kv_heads)).inc(n)
+            block_k=str(block_k), kv_heads=str(kv_heads),
+            window=str(window), heads=str(heads),
+            head_dim=str(head_dim)).inc(n)
         if impl == "pallas":
             for state, tiles in (("visited", visited), ("skipped", skipped)):
                 registry.counter(
                     "attention_k_tiles_total",
                     "K tiles a (batch, head) of the compiled flash-attention "
                     "ops' forward kernels", program=program,
-                    state=state).inc(n * tiles)
+                    state=state, window=str(window)).inc(n * tiles)
 
 
 def count_backwards(notes: dict, program: str,

@@ -21,26 +21,38 @@ def update_moe_gauges(program_ir, program: str,
     fewer under ``layers.moe_ffn``'s ``experts_held``),
     ``moe_assignments_per_step`` (tokens x top-k, all layers; a layer's
     sorted row buffer has a row for each of its assignments),
-    ``moe_expert_param_bytes`` (the stacked expert weights) and
+    ``moe_expert_param_bytes`` (the stacked expert weights),
+    ``moe_row_budget`` (the sorted rows a step's expert layers keep, all
+    layers: ``moe_assignments_per_step`` without a budget, the layers'
+    ``row_budget`` summed with one), ``moe_shared_experts`` (expert layers
+    with a shared expert beside the routed ones: ``layers.moe_ffn``'s
+    ``shared_width``, read off its ``<name>_shared_gate_w [H, width]``),
+    ``moe_shared_width`` (that expert's width) and
     ``short_conv_layers`` of one compiled program; nothing is set for a
-    program without an expert layer, and the last only where there is such
-    a layer."""
+    program without an expert layer, and ``short_conv_layers`` only where
+    there is such a layer."""
     from ..analysis.distributed import dtype_bytes
     registry = registry or REGISTRY
     block = program_ir.global_block()
     layers = experts = held = assignments = param_bytes = convs = 0
+    budget = shared = shared_width = 0
     for op in block.ops:
         if op.type == "moe_dispatch":
             layers += 1
             experts = int(op.attr("num_experts"))
             index = block.find_var_recursive(op.inputs["Index"][0])
             assignments += int(np.prod(index.shape))
+            budget += int(op.attr("rows", 0)) or int(np.prod(index.shape))
         elif op.type == "moe_expert_matmul":
             w = block.find_var_recursive(op.inputs["W"][0])
             held = int(w.shape[0])
             param_bytes += int(np.prod(w.shape)) * dtype_bytes(w.dtype)
         elif op.type == "short_conv":
             convs += 1
+    for param in block.all_parameters():
+        if param.name.endswith("_shared_gate_w"):
+            shared += 1
+            shared_width = int(param.shape[1])
     if convs:
         registry.gauge("short_conv_layers", "gated short-convolution "
                        "operators in the compiled program",
@@ -57,7 +69,15 @@ def update_moe_gauges(program_ir, program: str,
              "from static shapes)", assignments),
             ("moe_expert_param_bytes",
              "bytes of the stacked expert weights (a count from static "
-             "shapes)", param_bytes)):
+             "shapes)", param_bytes),
+            ("moe_row_budget",
+             "sorted rows the expert layers keep a step, all layers: the "
+             "assignments without a row budget, the budgets with one",
+             budget),
+            ("moe_shared_experts", "expert layers with a shared expert "
+             "beside the routed ones", shared),
+            ("moe_shared_width", "width of that shared expert",
+             shared_width)):
         registry.gauge(name, help, program=program).set(float(value))
 
 

@@ -1,4 +1,5 @@
-"""Decoder-LM ops: RMSNorm, rotary embedding, the SiLU-gated product, the
+"""Decoder-LM ops: RMSNorm, rotary embedding (whole or partial, plain or
+YaRN-scaled), the SiLU-gated product, attention's per-head output gate, the
 gated short convolution, and a dropless mixture-of-experts layer in four ops
 (``moe_router`` -> ``moe_dispatch`` -> ``moe_expert_matmul`` x3 around
 ``swiglu`` -> ``moe_combine``) plus ``moe_bias_update`` for a router that
@@ -19,10 +20,15 @@ holds a part of its experts (``moe_dispatch``'s ``first_expert``, stacked
 weights for the held experts only) starts the sort at its first expert, so
 its rows lead the buffer, and the grouped products stop after them: the
 rows of experts held elsewhere stay zero through the layer and add nothing.
+Such a layer may state a row budget (``moe_dispatch``'s ``rows``): the
+buffers then hold that many sorted rows and not all tokens x top-k, and the
+held experts' rows beyond it are dropped and counted (``Dropped``) -- the one
+case in which an assignment is lost, and the layer says how often.
 
 What moves rows is written so that forward and backward are both gathers
 (``_movers``: a permutation's transpose is the inverse permutation, which
-the sort already gave), never a scatter-add of wide rows. The three matmuls
+the sort already gave), never a scatter-add of wide rows -- but under a row
+budget, where the few rows kept are added to their tokens. The three matmuls
 are separate ops so that what the backward needs (sorted rows, gate, up, the
 weighted gated product) are Program variables: a grad op re-lowers its
 forward under ``jax.vjp`` (core/registry.py), a grouped matmul's own output
@@ -61,24 +67,76 @@ def rms_norm(ctx, ins):
     return {"Y": [y.astype(x.dtype)]}
 
 
+def yarn_inv_freq(theta, dim, factor, original_max_position, beta_fast,
+                  beta_slow):
+    """YaRN's blended rotary frequencies ``[dim / 2]`` (Peng et al.,
+    arXiv:2309.00071, as HF's ``_compute_yarn_parameters`` computes them):
+    with ``base_i = theta^(-2i/dim)``, ``inv_freq_i = (1 - m_i) base_i /
+    factor + m_i base_i`` where ``m_i = 1 - clip((i - low) / (high - low), 0,
+    1)`` and ``low`` / ``high`` are the floor / ceil of ``dim ln(original_
+    max_position / (n 2 pi)) / (2 ln theta)`` at ``n`` = ``beta_fast`` /
+    ``beta_slow`` rotations, held to ``[0, dim - 1]``: the fast dimensions
+    keep their frequency, the slow ones are interpolated by ``factor``.
+    float64 numpy, from static numbers."""
+    import math
+    import numpy as np
+
+    def correction(rotations):
+        return dim * math.log(original_max_position / (
+            rotations * 2 * math.pi)) / (2 * math.log(theta))
+    low = max(math.floor(correction(beta_fast)), 0)
+    high = min(math.ceil(correction(beta_slow)), dim - 1)
+    if low == high:
+        high += 0.001
+    base = float(theta) ** (-np.arange(0, dim, 2, dtype=np.float64) / dim)
+    keep = 1.0 - np.clip(
+        (np.arange(dim // 2, dtype=np.float64) - low) / (high - low), 0, 1)
+    return base / factor * (1.0 - keep) + base * keep
+
+
 @register("rotary_embedding")
 def rotary_embedding(ctx, ins):
     """Rotary position embedding in the rotate-half convention over
     ``X [..., S, D]``, positions 0..S-1 along axis -2:
     ``x * cos + concat(-x[D/2:], x[:D/2]) * sin`` with angle
-    ``pos * theta^(-2i/D)`` for both halves' element i. float32 inside."""
+    ``pos * theta^(-2i/D)`` for both halves' element i. float32 inside.
+
+    Attr ``rotary_dim`` (0: all of D): only the first ``rotary_dim`` values
+    of a row are rotated, among themselves (D stands for ``rotary_dim``
+    above), and the rest pass through. Attr ``scaling="yarn"``: the
+    frequencies are ``yarn_inv_freq``'s (attrs ``factor``,
+    ``original_max_position``, ``beta_fast``, ``beta_slow``) and cos and sin
+    are multiplied by ``attention_factor``."""
     import jax.numpy as jnp
     x = ins["X"][0]
     S, D = x.shape[-2], x.shape[-1]
-    inv_freq = ctx.attr("theta", 10000.0) ** (
-        -jnp.arange(0, D, 2, dtype=jnp.float32) / D)
+    rot = int(ctx.attr("rotary_dim", 0)) or D
+    if rot > D or rot % 2:
+        raise ValueError(f"rotary_embedding: rotary_dim={rot} of {D}")
+    scaling = ctx.attr("scaling", "")
+    if scaling == "yarn":
+        inv_freq = jnp.asarray(yarn_inv_freq(
+            float(ctx.attr("theta", 10000.0)), rot,
+            float(ctx.attr("factor")), float(ctx.attr("original_max_position")),
+            float(ctx.attr("beta_fast", 32.0)),
+            float(ctx.attr("beta_slow", 1.0))), jnp.float32)
+    elif scaling:
+        raise NotImplementedError(f"rotary_embedding: scaling={scaling!r}")
+    else:
+        inv_freq = ctx.attr("theta", 10000.0) ** (
+            -jnp.arange(0, rot, 2, dtype=jnp.float32) / rot)
     ang = jnp.arange(S, dtype=jnp.float32)[:, None] * inv_freq[None, :]
-    cos = jnp.cos(jnp.concatenate([ang, ang], axis=-1))         # [S, D]
+    cos = jnp.cos(jnp.concatenate([ang, ang], axis=-1))         # [S, rot]
     sin = jnp.sin(jnp.concatenate([-ang, ang], axis=-1))        # signed
-    xf = x.astype(jnp.float32)
-    # concat(-x2, x1) as a rotation of the lanes by D/2 with the sign in sin
-    return {"Out": [(xf * cos + jnp.roll(xf, D // 2, axis=-1) * sin)
-                    .astype(x.dtype)]}
+    if scaling:
+        factor = float(ctx.attr("attention_factor", 1.0))
+        cos, sin = cos * factor, sin * factor
+    xf = (x if rot == D else x[..., :rot]).astype(jnp.float32)
+    # concat(-x2, x1) as a rotation of the lanes by rot/2 with the sign in sin
+    out = (xf * cos + jnp.roll(xf, rot // 2, axis=-1) * sin).astype(x.dtype)
+    if rot < D:
+        out = jnp.concatenate([out, x[..., rot:]], axis=-1)
+    return {"Out": [out]}
 
 
 @register("swiglu")
@@ -97,15 +155,35 @@ def swiglu(ctx, ins):
     return {"Out": [out.astype(g.dtype)]}
 
 
+@register("attention_gate")
+def attention_gate(ctx, ins):
+    """Attention's per-head output gate: ``X [B, heads, S, D]``, the heads'
+    outputs as ``fused_attention`` leaves them, times ``sigmoid(Gate [B * S,
+    heads])``, one gate a token and head (the gate's projection is by
+    token: the small array is the one turned). float32 inside. Gating the
+    kernels' layout keeps the op one pass over X: on the token-major
+    ``[B * S, heads * D]`` XLA joined it with the transpose before it and
+    copied float32 arrays of X's size (7.3% of the Laguna step against
+    PERF.md section 6, PR 39)."""
+    import jax
+    import jax.numpy as jnp
+    x, gate = ins["X"][0], ins["Gate"][0]
+    B, heads, S, _ = x.shape
+    g = jax.nn.sigmoid(gate.astype(jnp.float32)).reshape(B, S, heads)
+    out = x.astype(jnp.float32) * g.transpose(0, 2, 1)[..., None]
+    return {"Out": [out.astype(x.dtype)]}
+
+
 @register("moe_router", nondiff_inputs=("Bias",), nondiff_outputs=("Index",))
 def moe_router(ctx, ins):
     """Router of a mixture-of-experts layer, in float32 throughout (the
     product too: at default precision a TPU multiplies float32 in bfloat16
     passes). ``X [T, H]`` any float dtype, ``W [H, E]`` ->
     ``Prob [T, E]`` = softmax(X W), ``Weight`` / ``Index [T, k]`` its k
-    largest entries as they are (not renormalised), ``LogZ [T]`` =
-    logsumexp(X W) for the router z-loss. ``Index`` carries no gradient.
-    Attr ``scoring="sigmoid"``: ``_sigmoid_routing``."""
+    largest entries as they are -- under attr ``norm_topk`` over their sum
+    (no epsilon: a softmax's k largest do not vanish), times attr ``scale``
+    --, ``LogZ [T]`` = logsumexp(X W) for the router z-loss. ``Index``
+    carries no gradient. Attr ``scoring="sigmoid"``: ``_sigmoid_routing``."""
     import jax
     import jax.numpy as jnp
     x, w = ins["X"][0], ins["W"][0]
@@ -116,6 +194,10 @@ def moe_router(ctx, ins):
     logz = jax.nn.logsumexp(logits, axis=-1)
     prob = jnp.exp(logits - logz[:, None])
     weight, index = jax.lax.top_k(prob, int(ctx.attr("k")))
+    if ctx.attr("norm_topk", False):
+        weight = weight / jnp.sum(weight, axis=-1, keepdims=True)
+    if float(ctx.attr("scale", 1.0)) != 1.0:
+        weight = weight * float(ctx.attr("scale"))
     return {"Weight": [weight], "Index": [index.astype(jnp.int32)],
             "Prob": [prob], "LogZ": [logz]}
 
@@ -169,6 +251,15 @@ def _sum_slots(g, order, slot):             # [A, H] -> [T, H]
     return jnp.sum(g[slot], axis=1, dtype=jnp.float32).astype(g.dtype)
 
 
+def _add_rows(g, order, slot):              # [R, H] -> [T, H], R rows kept
+    # a row budget: the rows kept are far fewer than the tokens' k slots,
+    # so each is added to its token (a gather of [T, k] rows, nearly all of
+    # them the fill, cost 2 ms a layer at 4096 x 10 x 3072; chip runs, PR 39)
+    import jax.numpy as jnp
+    return jnp.zeros((slot.shape[0], g.shape[1]), jnp.float32).at[
+        order // slot.shape[1]].add(g.astype(jnp.float32)).astype(g.dtype)
+
+
 def _gather_assignments(w, order, slot):    # [T, k] -> [A]
     return w.reshape(-1)[order]
 
@@ -177,17 +268,30 @@ def _gather_slots(g, order, slot):          # [A] -> [T, k]
     return g[slot]
 
 
+def _gather_kept_slots(g, order, slot):     # [R] -> [T, k], R rows kept
+    # a row budget: an assignment whose row was not kept reads zero
+    return g.at[slot].get(mode="fill", fill_value=0)
+
+
 @functools.lru_cache(maxsize=None)
-def _movers():
+def _movers(budgeted: bool = False):
     """(token rows -> sorted rows, sorted rows -> token sums, router weights
-    -> sorted weights): each one's transpose is another of these gathers."""
+    -> sorted weights): each one's transpose is another of these gathers.
+    ``budgeted`` (the ops' ``rows`` attr): the sorted side holds the rows a
+    budget kept, so sums over slots become additions of the kept rows and a
+    slot without a row reads zero."""
+    if budgeted:
+        return (_moved(_gather_tokens, _add_rows),
+                _moved(_add_rows, _gather_tokens),
+                _moved(_gather_assignments, _gather_kept_slots))
     return (_moved(_gather_tokens, _sum_slots),
             _moved(_sum_slots, _gather_tokens),
             _moved(_gather_assignments, _gather_slots))
 
 
 @register("moe_dispatch", nondiff_inputs=("Index",),
-          nondiff_outputs=("Order", "Slot", "Count", "GroupCount"))
+          nondiff_outputs=("Order", "Slot", "Count", "GroupCount",
+                           "Dropped"))
 def moe_dispatch(ctx, ins):
     """Sort the T x k assignments by expert (stable: within an expert, by
     token) and bring each one's token row and router weight into place.
@@ -204,7 +308,15 @@ def moe_dispatch(ctx, ins):
     the range, and ``GroupCount [E]`` counts the rows in the sorted order
     (expert ``first_expert`` first) while ``Count`` stays by expert id. The
     buffers keep all T*k rows -- the worst case, every assignment local --
-    and the grouped products stop after the held experts' rows."""
+    and the grouped products stop after the held experts' rows.
+
+    Attr ``rows`` (0: none), with ``held`` the experts held here: a row
+    budget. ``Out``, ``RowWeight`` and ``Order`` keep the first ``rows``
+    sorted rows only (the held experts' lead the buffer), ``Slot`` still
+    names every assignment's sorted row, those from ``rows`` up being rows
+    no buffer has (they add nothing in ``moe_combine``), ``GroupCount`` is
+    cut so that it sums to ``rows``, and ``Dropped [1]`` int32 counts the
+    held experts' rows beyond the budget, which this step lost."""
     import jax.numpy as jnp
     x, index, weight = ins["X"][0], ins["Index"][0], ins["Weight"][0]
     n_experts = int(ctx.attr("num_experts"))
@@ -218,11 +330,26 @@ def moe_dispatch(ctx, ins):
         rows, unique_indices=True).reshape(index.shape)
     count = jnp.sum(flat[:, None] == jnp.arange(n_experts, dtype=jnp.int32),
                     axis=0, dtype=jnp.int32)
-    to_rows, _, to_row_weights = _movers()
+    budget = int(ctx.attr("rows", 0))
+    to_rows, _, to_row_weights = _movers(bool(budget))
+    if not budget:
+        return {"Out": [to_rows(x, order, slot)],
+                "RowWeight": [to_row_weights(weight, order, slot)],
+                "Order": [order], "Slot": [slot], "GroupCount": [count],
+                "Count": [jnp.roll(count, first) if first else count]}
+    if budget > flat.shape[0]:
+        raise ValueError(f"moe_dispatch: a budget of {budget} rows for "
+                         f"{flat.shape[0]} assignments")
+    order = order[:budget]
+    ends = jnp.minimum(jnp.cumsum(count), budget)
+    held_rows = jnp.sum(count[:int(ctx.attr("held"))])
     return {"Out": [to_rows(x, order, slot)],
             "RowWeight": [to_row_weights(weight, order, slot)],
-            "Order": [order], "Slot": [slot], "GroupCount": [count],
-            "Count": [jnp.roll(count, first) if first else count]}
+            "Order": [order], "Slot": [slot],
+            "GroupCount": [jnp.diff(ends, prepend=0).astype(jnp.int32)],
+            "Count": [jnp.roll(count, first) if first else count],
+            "Dropped": [jnp.maximum(held_rows - budget, 0).reshape(1)
+                        .astype(jnp.int32)]}
 
 
 def grouped_matmul(x, w, count):
@@ -261,8 +388,10 @@ def moe_combine(ctx, ins):
     """Each token's output: the sum of its k assignments' rows (already
     weighted, see ``swiglu``'s ``Scale``). ``X [T*k, H]`` sorted rows,
     ``Order`` / ``Slot`` from ``moe_dispatch`` -> ``Out [T, H]`` in X's
-    dtype, summed in float32."""
-    _, to_tokens, _ = _movers()
+    dtype, summed in float32. Attr ``rows`` (``moe_dispatch``'s row budget;
+    0: none): ``X`` has that many rows, each added to its token, and an
+    assignment whose row was not kept adds nothing."""
+    _, to_tokens, _ = _movers(bool(int(ctx.attr("rows", 0))))
     return {"Out": [to_tokens(ins["X"][0], ins["Order"][0],
                               ins["Slot"][0])]}
 

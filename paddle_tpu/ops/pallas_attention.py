@@ -127,8 +127,30 @@ BLK_Q = 256
 CAUSAL_BLOCKS = (512, 1024)
 CAUSAL_TILES_MIN_S = 2048
 
+# (block_q, block_k) of a causal op with a sliding window shorter than S,
+# from WINDOW_TILES_MIN_S up where both divide S: a Q block then visits the
+# tiles its window reaches and no others, so what it costs no longer grows
+# with its index. Forward / backward kernel ms a layer of 1 x 4096 tokens at
+# 72 query over 8 key/value heads of 128, window 512, bf16, and the K tiles
+# visited of all (chip runs, PR 39); the same op without the window reads
+# 3.33 / 6.30 at CAUSAL_BLOCKS, XLA's composed lowering 34.9 / 30.3:
+#   (512, 512)   2.09 / 3.25   15 of 64     (256, 512)   2.25 / 3.55   30 of 128
+#   (512, 256)   2.49 / 3.73   30 of 128    (256, 256)   2.38 / 3.68   45 of 256
+#   (512, 1024)  2.34 / 4.02   11 of 32     (256, 1024)  2.44 / 4.28   22 of 64
+#   (1024, 512)  2.61 / 4.14   11 of 32     (128, 512)   2.98 / 4.55   60 of 256
+# Every visited tile is masked at these sizes, and at (512, 512) half the
+# pairs a Q block computes lie outside its rows' windows.
+WINDOW_BLOCKS = (512, 512)
+WINDOW_TILES_MIN_S = 1024
 
-def default_block_q(S, causal=False):
+
+def _window_tiled(S, causal, window):
+    q, k = WINDOW_BLOCKS
+    return bool(causal and window and window < S and S >= WINDOW_TILES_MIN_S
+                and S % q == 0 and S % k == 0)
+
+
+def default_block_q(S, causal=False, window=None):
     """The Q block the kernels take at sequence length S where no tuning
     decision says otherwise; always divides S. Below 1024 one block a
     (batch, head): the whole [S, S] tile in one grid step beats every
@@ -136,7 +158,10 @@ def default_block_q(S, causal=False):
     128, S=512, forward + backward of 16k tokens; table in PERF.md section 6,
     chip runs, PR 27) and compiles to S=896 in bf16 and f32. From 1024 up
     BLK_Q, or _MIN_BLK_Q where that does not divide S; with K tiles
-    (default_block_k) the Q block of CAUSAL_BLOCKS."""
+    (default_block_k) the Q block of CAUSAL_BLOCKS, or of WINDOW_BLOCKS
+    under a sliding window."""
+    if _window_tiled(S, causal, window):
+        return WINDOW_BLOCKS[0]
     if S < 1024:
         return S
     if default_block_k(S, causal) != S:
@@ -144,13 +169,17 @@ def default_block_q(S, causal=False):
     return BLK_Q if S % BLK_Q == 0 else _MIN_BLK_Q
 
 
-def default_block_k(S, causal=False):
+def default_block_k(S, causal=False, window=None):
     """The K tile at sequence length S; always divides S. Without ``causal``
     the row: every tile would be visited, a narrower one only adds loop
     iterations, and the dropout mask is drawn a tile at a time. With it, the
     K tile of CAUSAL_BLOCKS from CAUSAL_TILES_MIN_S up where the pair divides
     S (at S=4096 32% less kernel time than one tile, table above), chosen
-    from what the op sees: ``causal`` and S."""
+    from what the op sees: ``causal`` and S. Under a sliding ``window``
+    shorter than S, the K tile of WINDOW_BLOCKS from WINDOW_TILES_MIN_S
+    up."""
+    if _window_tiled(S, causal, window):
+        return WINDOW_BLOCKS[1]
     q, k = CAUSAL_BLOCKS
     if causal and S >= CAUSAL_TILES_MIN_S and S % q == 0 and S % k == 0:
         return k
@@ -166,13 +195,35 @@ def _under_diagonal(iq, block_q, block_k):
             ((iq + 1) * block_q - 1) // block_k + 1)
 
 
-def k_tiles(S, block_q, block_k, causal):
+def _behind_window(iq, block_q, block_k, window):
+    """(first, edge) of Q block ``iq`` under a sliding window (row i sees
+    the columns i - window < j <= i): K tiles [0, first) lie wholly behind
+    the window of every row of the block and are not visited, [first, edge)
+    hold a column that some row's window no longer reaches and take the
+    mask. Python ints or traced values, as ``iq`` is (``//`` floors both)."""
+    lo = iq * block_q - window + 1          # first column the first row sees
+    first, edge = lo // block_k, (lo + block_q - 2) // block_k + 1
+    if isinstance(first, int):
+        return max(first, 0), max(edge, 0)
+    import jax.numpy as jnp
+    return jnp.maximum(first, 0), jnp.maximum(edge, 0)
+
+
+def _tiles_by_block(S, block_q, block_k, window=None):
+    """The K tiles each Q block of a causal op visits, a count a block."""
+    return [_under_diagonal(iq, block_q, block_k)[1]
+            - (_behind_window(iq, block_q, block_k, window)[0] if window
+               else 0) for iq in range(S // block_q)]
+
+
+def k_tiles(S, block_q, block_k, causal, window=None):
     """(visited, skipped): the K tiles the Q blocks of one (batch, head)
     pass over in the forward kernel, and those they leave out because they
-    lie wholly above the diagonal (_KTiles, summed over the Q blocks)."""
+    lie wholly above the diagonal or, under a sliding ``window``, wholly
+    behind it (_KTiles, summed over the Q blocks)."""
     n_q, n_k = S // block_q, S // block_k
-    visited = sum(_under_diagonal(iq, block_q, block_k)[1]
-                  for iq in range(n_q)) if causal else n_q * n_k
+    visited = sum(_tiles_by_block(S, block_q, block_k, window)) \
+        if causal else n_q * n_k
     return visited, n_q * n_k - visited
 
 
@@ -209,10 +260,11 @@ def _pl():
 # --------------------------------------------------------------------------------------
 
 def composed_attention(q, k, v, bias, scale, dropout, causal, rng,
-                       bernoulli=None):
+                       bernoulli=None, window=None):
     """Plain jnp attention: the numerics oracle and the non-TPU lowering.
     ``bernoulli(key, keep, shape)`` draws the dropout mask: the op hands its
-    ``LowerCtx.bernoulli_mask``, else ``jax.random.bernoulli``."""
+    ``LowerCtx.bernoulli_mask``, else ``jax.random.bernoulli``. ``window``
+    (with ``causal``): query i sees the keys i - window < j <= i."""
     import jax
     import jax.numpy as jnp
 
@@ -234,7 +286,10 @@ def composed_attention(q, k, v, bias, scale, dropout, causal, rng,
         S_q, S_k = s.shape[-2], s.shape[-1]
         qi = jax.lax.broadcasted_iota(jnp.int32, (S_q, S_k), 0)
         ki = jax.lax.broadcasted_iota(jnp.int32, (S_q, S_k), 1)
-        s = jnp.where(ki <= qi, s, jnp.float32(-1e30))
+        seen = ki <= qi
+        if window:
+            seen = seen & (ki > qi - window)
+        s = jnp.where(seen, s, jnp.float32(-1e30))
     p = jax.nn.softmax(s, axis=-1)
     if dropout:
         keep = (bernoulli or jax.random.bernoulli)(rng, 1.0 - dropout, p.shape)
@@ -283,11 +338,13 @@ def _rows(ref, t, block_k):
     return ref[0, pl.ds(pl.multiple_of(t * block_k, block_k), block_k), :]
 
 
-def _scores(q_s, k_ref, bias_ref, iq, t, block_k, scale, masked):
+def _scores(q_s, k_ref, bias_ref, iq, t, block_k, scale, masked,
+            window=None):
     """[block_q, block_k] f32 scores of Q block ``iq`` against K tile ``t``.
     ``q_s`` comes from _fold_scale; ``masked``: the diagonal crosses the
     tile (a tile wholly under it takes no mask, one above it is not
-    visited: _KTiles)."""
+    visited: _KTiles) or, under a sliding ``window``, the window's far edge
+    does; a masked tile of a window op takes both edges."""
     import jax
     import jax.numpy as jnp
 
@@ -301,7 +358,11 @@ def _scores(q_s, k_ref, bias_ref, iq, t, block_k, scale, masked):
         # column - row inside the tile, against where the tile lies
         rel = (jax.lax.broadcasted_iota(jnp.int32, (blk_q, block_k), 1)
                - jax.lax.broadcasted_iota(jnp.int32, (blk_q, block_k), 0))
-        s = jnp.where(rel <= iq * blk_q - t * block_k, s, jnp.float32(-1e30))
+        off = iq * blk_q - t * block_k
+        seen = rel <= off
+        if window is not None:
+            seen = seen & (rel > off - window)
+        s = jnp.where(seen, s, jnp.float32(-1e30))
     return s
 
 
@@ -350,7 +411,13 @@ class _KTiles:
     """The K tiles one Q block visits, in passes. Tiles [0, clear) lie wholly
     at or under Q block ``iq``'s diagonal and take no mask, [clear, visited)
     are crossed by it, and the tiles from ``visited`` up lie wholly above it:
-    no pass goes there. Without ``causal`` every tile is clear.
+    no pass goes there. Without ``causal`` every tile is clear. Under a
+    sliding ``window`` (row i sees i - window < j <= i) the tiles [0, first)
+    lie wholly behind every row's window and are not visited either, and
+    [first, edge) are crossed by the window's far edge and take the mask
+    (_behind_window): the tiles a Q block visits no longer grow with its
+    index, and a stage holds the most one block visits, indexed from
+    ``first``.
 
     A score tile lives from one pass to a later one in a stage (``put`` /
     ``get``), a [n_k, block_q, block_k] f32 VMEM scratch. A row statistic
@@ -364,10 +431,15 @@ class _KTiles:
     [block_q, S], the BERT cells' path
     (tests/test_pallas_attention.py keeps its plain body as an oracle)."""
 
-    def __init__(self, iq, block_q, block_k, n_k, causal, stage_refs=()):
+    def __init__(self, iq, block_q, block_k, n_k, causal, stage_refs=(),
+                 window=None):
         self.whole, self.causal, self.block_q = n_k == 1, causal, block_q
         self.clear, self.visited = _under_diagonal(
             iq, block_q, block_k) if causal else (n_k, n_k)
+        self.window = window
+        if window is not None and not self.whole:
+            self.first, self.edge = _behind_window(iq, block_q, block_k,
+                                                   window)
         self.stages = list(stage_refs)
         self.staged = {}
 
@@ -377,6 +449,19 @@ class _KTiles:
         import jax
         if self.whole:
             return body(self.causal, 0, None)
+        if self.window is not None:
+            # behind the diagonal's tiles: those the window's edge crosses,
+            # then the clear ones (none where the window is no wider than
+            # a tile)
+            import jax.numpy as jnp
+            carry = jax.lax.fori_loop(
+                self.first, jnp.minimum(self.edge, self.clear),
+                functools.partial(body, True), init())
+            carry = jax.lax.fori_loop(
+                jnp.maximum(self.edge, self.first), self.clear,
+                functools.partial(body, False), carry)
+            return jax.lax.fori_loop(self.clear, self.visited,
+                                     functools.partial(body, True), carry)
         carry = jax.lax.fori_loop(0, self.clear,
                                   functools.partial(body, False), init())
         if not self.causal:
@@ -384,14 +469,18 @@ class _KTiles:
         return jax.lax.fori_loop(self.clear, self.visited,
                                  functools.partial(body, True), carry)
 
+    def _slot(self, t):
+        """Where tile ``t`` lies in a stage."""
+        return t if self.window is None else t - self.first
+
     def put(self, i, t, x):
         if self.whole:
             self.staged[i] = x
         else:
-            self.stages[i][t] = x
+            self.stages[i][self._slot(t)] = x
 
     def get(self, i, t):
-        return self.staged[i] if self.whole else self.stages[i][t]
+        return self.staged[i] if self.whole else self.stages[i][self._slot(t)]
 
     def stat(self, x, op):
         """Tile ``x``'s part in a row statistic; ``op`` is jnp.max or
@@ -431,7 +520,8 @@ class _KTiles:
             body, lambda: self.stat_init(-jnp.inf)), jnp.max)
 
 
-def _fwd_kernel(scale, dropout, causal, has_bias, block_k, *refs):
+def _fwd_kernel(scale, dropout, causal, has_bias, block_k, *refs,
+                window=None):
     """One Q block against its K tiles in two passes: (1) scores, row max;
     (2) exp, row sum and the product with V. Beside the output it writes the
     rows' softmax statistic, ``lse = max + log(sum)``, one float32 a row: all
@@ -445,10 +535,10 @@ def _fwd_kernel(scale, dropout, causal, has_bias, block_k, *refs):
     iq = pl.program_id(1)
     blk_q, D = q_ref.shape[1:]
     tiles = _KTiles(iq, blk_q, block_k, k_ref.shape[1] // block_k, causal,
-                    refs[6 + has_bias:])
+                    refs[6 + has_bias:], window)
     q_s = _fold_scale(q_ref[0], scale)
     m = tiles.row_max(lambda masked, t: _scores(
-        q_s, k_ref, bias_ref, iq, t, block_k, scale, masked))
+        q_s, k_ref, bias_ref, iq, t, block_k, scale, masked, window))
 
     def product(masked, t, carry):
         e = jnp.exp(tiles.get(0, t) - m)
@@ -469,7 +559,8 @@ def _fwd_kernel(scale, dropout, causal, has_bias, block_k, *refs):
     lse_ref[0] = _as_row(m + jnp.log(l))
 
 
-def _bwd_kernel(scale, dropout, causal, has_bias, group, block_k, *refs):
+def _bwd_kernel(scale, dropout, causal, has_bias, group, block_k, *refs,
+                window=None):
     """``group`` query heads share a key/value head. At 1 grid axis 1 is the
     Q block; above 1 it runs over the group's heads and their Q blocks in
     turn, so that dK^T / dV^T accumulate over the whole group in VMEM and a
@@ -498,7 +589,8 @@ def _bwd_kernel(scale, dropout, causal, has_bias, group, block_k, *refs):
     dtype = q_ref.dtype
     blk_q, D = q_ref.shape[1:]
     n_k = k_ref.shape[1] // block_k
-    tiles = _KTiles(iq, blk_q, block_k, n_k, causal, refs[11 + has_bias:])
+    tiles = _KTiles(iq, blk_q, block_k, n_k, causal, refs[11 + has_bias:],
+                    window)
     q_s = _fold_scale(q_ref[0], scale)
     do = do_ref[0]                                           # [BLK_Q, D]
     lse = _as_col(lse_ref[0])                                # [BLK_Q, 1]
@@ -517,7 +609,7 @@ def _bwd_kernel(scale, dropout, causal, has_bias, group, block_k, *refs):
 
     def softmax(masked, t, r):
         p = jnp.exp(_scores(q_s, k_ref, bias_ref, iq, t, block_k, scale,
-                            masked) - lse)
+                            masked, window) - lse)
         dp = _dot(do, _rows(v_ref, t, block_k), _NT)         # [BLK_Q, blk_k]
         if dropout:
             dp = jnp.where(keep(t), dp, 0.0)
@@ -604,13 +696,16 @@ def _operands(q, k, v, bias, seed, block_q, block_k, by_kv_head=False):
     return args, in_specs, qspec, kvspec, lse_spec, n_q
 
 
-def _stages(n, S, block_q, block_k):
+def _stages(n, S, block_q, block_k, window=None):
     """Scratch for ``n`` staged [block_q, block_k] f32 tiles a K tile
-    (_KTiles); none where one tile is the row."""
+    (_KTiles); none where one tile is the row. Under a sliding window a
+    stage holds the most tiles one Q block visits."""
     import jax.numpy as jnp
     _, pltpu = _pl()
     n_k = S // block_k
-    return [pltpu.VMEM((n_k, block_q, block_k), jnp.float32)] * (
+    held = n_k if window is None or n_k == 1 else max(_tiles_by_block(
+        S, block_q, block_k, window))
+    return [pltpu.VMEM((held, block_q, block_k), jnp.float32)] * (
         n if n_k > 1 else 0)
 
 
@@ -629,24 +724,36 @@ def _compiler_params(interpret, vmem_limit_bytes=None):
 import jax as _jax  # custom_vjp and jit must wrap at def time
 
 # the kernel calls' arguments after the arrays, all static
-_STATIC = ("scale", "dropout", "causal", "interpret", "block_q", "block_k")
+_STATIC = ("scale", "dropout", "causal", "interpret", "block_q", "block_k",
+           "window")
 
 
 def _flash(q, k, v, bias, seed, scale, dropout, causal, interpret,
-           block_q=None, block_k=None):
+           block_q=None, block_k=None, window=None):
     """The flash kernels, differentiable in q, k and v: _flash_stats' output
     alone."""
     return _flash_stats(q, k, v, bias, seed, scale, dropout, causal,
-                        interpret, block_q, block_k)[0]
+                        interpret, block_q, block_k, window)[0]
 
 
-def _blocks(S, causal, block_q=None, block_k=None):
+def sliding_window(window, S, causal):
+    """The window a lowering applies: None for none, and for one that
+    reaches every key of every query (``window >= S``: plain causal
+    attention, and lowered as that). A window needs ``causal``."""
+    if not window:
+        return None
+    if not causal:
+        raise ValueError("fused_attention: a sliding window needs causal")
+    return int(window) if window < S else None
+
+
+def _blocks(S, causal, block_q=None, block_k=None, window=None):
     """(block_q, block_k) as given, None taking ``default_block_q`` /
     ``default_block_k``; both must divide S in multiples of _MIN_BLK_Q."""
     if block_q is None:
-        block_q = default_block_q(S, causal)
+        block_q = default_block_q(S, causal, window)
     if block_k is None:
-        block_k = default_block_k(S, causal)
+        block_k = default_block_k(S, causal, window)
     for name, block in (("block_q", block_q), ("block_k", block_k)):
         if S % block or block % _MIN_BLK_Q:
             raise ValueError(
@@ -656,22 +763,25 @@ def _blocks(S, causal, block_q=None, block_k=None):
 
 
 def _flash_stats(q, k, v, bias, seed, scale, dropout, causal, interpret,
-                 block_q=None, block_k=None):
+                 block_q=None, block_k=None, window=None):
     """(out, lse) of the flash kernels: the output, differentiable in q, k
     and v, and the rows' softmax statistic ``lse`` [B, H, 1, S] float32 (the
     log of the sum of exp over a row's scores, bias and mask included),
     which carries no gradient: it is what _bwd_call reads in place of a
     pass for the max and the sum. ``block_q`` (Q rows a grid step) and
-    ``block_k`` (columns a K tile, both kernels) divide S."""
+    ``block_k`` (columns a K tile, both kernels) divide S. ``window``:
+    ``sliding_window``."""
+    window = sliding_window(window, q.shape[2], causal)
     return _flash_vjp(q, k, v, bias, seed, scale, dropout, causal, interpret,
-                      *_blocks(q.shape[2], causal, block_q, block_k))
+                      *_blocks(q.shape[2], causal, block_q, block_k, window),
+                      window)
 
 
-@functools.partial(_jax.custom_vjp, nondiff_argnums=(5, 6, 7, 8, 9, 10))
+@functools.partial(_jax.custom_vjp, nondiff_argnums=(5, 6, 7, 8, 9, 10, 11))
 def _flash_vjp(q, k, v, bias, seed, scale, dropout, causal, interpret,
-               block_q, block_k):
+               block_q, block_k, window):
     return _fwd_call(q, k, v, bias, seed, scale, dropout, causal, interpret,
-                     block_q, block_k)
+                     block_q, block_k, window)
 
 
 # Each kernel call sits behind a jit of its own. The layers of a model call
@@ -682,7 +792,7 @@ def _flash_vjp(q, k, v, bias, seed, scale, dropout, causal, interpret,
 # (compile.trace_lower_s 8.3 against 4.4 s, ledger, PR 26).
 @functools.partial(_jax.jit, static_argnames=_STATIC)
 def _fwd_call(q, k, v, bias, seed, scale, dropout, causal, interpret,
-              block_q, block_k):
+              block_q, block_k, window=None):
     """(out [B, H, S, D], lse [B, H, 1, S] float32: the kernel's own
     [B * H, 1, S] with the leading dim split, which costs XLA nothing; as
     [B, H, S] it is a relayout copy each way, a row of S lanes a head
@@ -695,13 +805,13 @@ def _fwd_call(q, k, v, bias, seed, scale, dropout, causal, interpret,
         q, k, v, bias, seed, block_q, block_k)
     out, lse = pl.pallas_call(
         functools.partial(_fwd_kernel, scale, dropout, causal,
-                          bias is not None, block_k),
+                          bias is not None, block_k, window=window),
         grid=(B * H, n_q),
         in_specs=in_specs,
         out_specs=[qspec, lse_spec],
         out_shape=[jax.ShapeDtypeStruct((B * H, S, D), q.dtype),
                    jax.ShapeDtypeStruct((B * H, 1, S), jnp.float32)],
-        scratch_shapes=_stages(1, S, block_q, block_k),
+        scratch_shapes=_stages(1, S, block_q, block_k, window),
         interpret=interpret,
         **_compiler_params(interpret),
     )(*args)
@@ -709,9 +819,9 @@ def _fwd_call(q, k, v, bias, seed, scale, dropout, causal, interpret,
 
 
 def _flash_fwd(q, k, v, bias, seed, scale, dropout, causal, interpret,
-               block_q, block_k):
+               block_q, block_k, window):
     out, lse = _fwd_call(q, k, v, bias, seed, scale, dropout, causal,
-                         interpret, block_q, block_k)
+                         interpret, block_q, block_k, window)
     # The inputs and the rows' statistic. Under a direct jax.vjp (the tests,
     # the tuner) nothing lowers a second forward. A Program's grad op does
     # not come here where its op declares Lse (fused_attention_grad calls
@@ -725,7 +835,7 @@ def _flash_fwd(q, k, v, bias, seed, scale, dropout, causal, interpret,
 
 @functools.partial(_jax.jit, static_argnames=_STATIC)
 def _bwd_call(q, k, v, bias, seed, g, lse, scale, dropout, causal, interpret,
-              block_q, block_k):
+              block_q, block_k, window=None):
     """(dq, dk, dv) from the cotangent ``g`` of the output and the forward
     kernel's ``lse`` (same blocks, same seed: the mask is drawn again)."""
     import jax
@@ -741,10 +851,10 @@ def _bwd_call(q, k, v, bias, seed, g, lse, scale, dropout, causal, interpret,
     # dK^T, dV^T by K tile; with more tiles than one, the p and dP tiles a
     # Q block keeps between its passes
     scratch = ([pltpu.VMEM((S // block_k, D, block_k), jnp.float32)] * 2
-               + _stages(2, S, block_q, block_k))
+               + _stages(2, S, block_q, block_k, window))
     dq, dk, dv = pl.pallas_call(
         functools.partial(_bwd_kernel, scale, dropout, causal,
-                          bias is not None, group, block_k),
+                          bias is not None, group, block_k, window=window),
         grid=(B * kv, group * n_q),
         in_specs=in_specs,
         out_specs=[qspec, kvspec, kvspec],
@@ -758,13 +868,14 @@ def _bwd_call(q, k, v, bias, seed, g, lse, scale, dropout, causal, interpret,
             dv.reshape(B, kv, S, D))
 
 
-def _flash_bwd(scale, dropout, causal, interpret, block_q, block_k, res, g):
+def _flash_bwd(scale, dropout, causal, interpret, block_q, block_k, window,
+               res, g):
     import jax
     import jax.numpy as jnp
     import numpy as np
     q, k, v, bias, seed, lse = res
     dq, dk, dv = _bwd_call(q, k, v, bias, seed, g[0], lse, scale, dropout,
-                           causal, interpret, block_q, block_k)
+                           causal, interpret, block_q, block_k, window)
     return (dq, dk, dv, None if bias is None else jnp.zeros_like(bias),
             np.zeros(np.shape(seed), jax.dtypes.float0))
 
@@ -791,16 +902,19 @@ def supports_pallas(B, H, S, D, bias_shape, dropout, is_tpu):
 
 def _plan(ctx, q, k, v, bias):
     """Which lowering this op takes, from its attrs, its shapes and where it
-    is lowered: ``(impl, scale, dropout, causal, blocks)`` with ``impl`` one
-    of 'ulysses', 'ring', 'pallas', 'xla' and ``blocks`` the kernels'
-    (block_q, block_k), None without them. The forward op and its grad op
-    both ask here, so the two cannot disagree; what cannot run raises."""
+    is lowered: ``(impl, scale, dropout, causal, blocks, window)`` with
+    ``impl`` one of 'ulysses', 'ring', 'pallas', 'xla', ``blocks`` the
+    kernels' (block_q, block_k), None without them, and ``window`` the
+    sliding window the lowering applies (``sliding_window``: None for none
+    and for one no shorter than S). The forward op and its grad op both ask
+    here, so the two cannot disagree; what cannot run raises."""
     B, H, S, D = q.shape
     kv_heads = k.shape[1]
     scale = float(ctx.attr("scale") or (1.0 / math.sqrt(D)))
     dropout = 0.0 if ctx.attr("is_test", False) else float(
         ctx.attr("dropout_prob", 0.0))
     causal = bool(ctx.attr("causal", False))
+    window = sliding_window(ctx.attr("window", 0), S, causal)
     impl = ctx.attr("impl", "auto")
     from . import pallas_mode
     is_tpu = pallas_mode.on_tpu()
@@ -812,6 +926,10 @@ def _plan(ctx, q, k, v, bias):
             "fused_attention: grouped-query attention (fewer key/value than "
             "query heads) under sequence parallelism (ring / ulysses) is "
             "not built yet")
+    if window is not None and (sp_n > 1 or impl in ("ring", "ulysses")):
+        raise NotImplementedError(
+            "fused_attention: a sliding window under sequence parallelism "
+            "(ring / ulysses) is not built yet")
     ring_ok = sp_n > 1 and S % sp_n == 0 and (
         bias is None or (len(bias.shape) == 4 and bias.shape[1] == 1
                          and bias.shape[2] == 1))
@@ -830,9 +948,9 @@ def _plan(ctx, q, k, v, bias):
                 f"[B,1,1,S] bias; got sp={sp_n}, S={S}, H={H} "
                 f"({h_local} heads per mp shard), "
                 f"bias={None if bias is None else bias.shape}")
-        return "ulysses", scale, dropout, causal, None
+        return "ulysses", scale, dropout, causal, None, None
     if ring_ok and impl in ("auto", "ring"):
-        return "ring", scale, dropout, causal, None
+        return "ring", scale, dropout, causal, None, None
 
     bias_shape = None if bias is None else bias.shape
     if impl == "pallas":
@@ -859,13 +977,15 @@ def _plan(ctx, q, k, v, bias):
     tune_params = {"b": B, "h": H, "s": S, "d": D, "dtype": str(q.dtype),
                    "has_bias": bias is not None, "dropout": dropout,
                    "causal": causal, "scale": scale}
+    if window is not None:      # a bucket of its own, and its own defaults
+        tune_params["window"] = window
     if impl == "pallas" or (
             impl == "auto" and one_device and pallas_mode.available() and
             supports_pallas(B, H, S, D, bias_shape, dropout, is_tpu) and
             _decide("fused_attention.backend", tune_params) == "pallas"):
         return "pallas", scale, dropout, causal, tuple(int(b) for b in _decide(
-            "fused_attention.block_sizes", tune_params))
-    return "xla", scale, dropout, causal, None
+            "fused_attention.block_sizes", tune_params)), window
+    return "xla", scale, dropout, causal, None, window
 
 
 def _kernel_seed(ctx, dropout):
@@ -892,6 +1012,10 @@ def fused_attention(ctx, ins):
     dividing heads (grouped-query attention: query head i reads key/value
     head i // (heads / kv_heads), in place -- no lowering repeats K or V);
     optional Bias [B, 1, 1, S] additive (already -inf-masked). Attrs: scale (default 1/sqrt(D)), dropout_prob, causal,
+    window (0: none; with causal, query i sees the keys i - window < j <= i:
+    the kernels then visit the K tiles a Q block's window reaches and mask
+    both edges, the composed lowering masks the scores; a window of S or
+    more is plain causal attention and lowers as that),
     is_test, impl ('auto' | 'pallas' | 'ring' | 'ulysses' | 'composed').
     Outputs: Out [B, heads, S, D]; Lse [B, heads, 1, S] float32, the rows'
     softmax statistic (log of the sum of exp of a row's scores), for the
@@ -935,21 +1059,22 @@ def fused_attention(ctx, ins):
         scale = ctx.attr("scale") or (1.0 / math.sqrt(D))
         return {"Out": [composed_attention(
             q, k, v, bias, float(scale), 0.0, bool(ctx.attr("causal", False)),
-            ctx.rng())], "Lse": [no_stats()]}
+            ctx.rng(), window=ctx.attr("window", 0))], "Lse": [no_stats()]}
 
-    impl, scale, dropout, causal, blocks = _plan(ctx, q, k, v, bias)
+    impl, scale, dropout, causal, blocks, window = _plan(ctx, q, k, v, bias)
+    shape = (window or 0, H, D)
     if impl == "pallas":
         from . import pallas_mode
         ctx.note("fused_attention", ("pallas", S, *blocks, kv_heads)
-                 + k_tiles(S, *blocks, causal))
+                 + k_tiles(S, *blocks, causal, window) + shape)
         out, lse = _flash_stats(q, k, v, bias, _kernel_seed(ctx, dropout),
                                 scale, dropout, causal,
-                                pallas_mode.interpret(), *blocks)
+                                pallas_mode.interpret(), *blocks, window)
         return {"Out": [out], "Lse": [lse]}
-    ctx.note("fused_attention", (impl, S, 0, 0, kv_heads, 0, 0))
+    ctx.note("fused_attention", (impl, S, 0, 0, kv_heads, 0, 0) + shape)
     if impl == "xla":
         out = composed_attention(q, k, v, bias, scale, dropout, causal,
-                                 ctx.rng(), ctx.bernoulli_mask)
+                                 ctx.rng(), ctx.bernoulli_mask, window)
     else:
         gm = ctx.gspmd_mesh
         seed = jax.random.randint(ctx.rng(), (), 0, 2**31 - 1, jnp.int32)
@@ -978,7 +1103,7 @@ def fused_attention_grad(ctx, ins, generic):
     q, k, v = ins["Q"][0], ins["K"][0], ins["V"][0]
     bias = ins.get("Bias", [None])[0]
     lse, g = ins.get("Lse", [None])[0], ins.get("Out@GRAD", [None])[0]
-    impl, scale, dropout, causal, blocks = _plan(ctx, q, k, v, bias)
+    impl, scale, dropout, causal, blocks, window = _plan(ctx, q, k, v, bias)
     if impl != "pallas" or lse is None or g is None:
         ctx.note("fused_attention_grad",
                  "recomputed" if impl == "pallas" else "generic")
@@ -987,5 +1112,5 @@ def fused_attention_grad(ctx, ins, generic):
     ctx.note("fused_attention_grad", "saved")
     dq, dk, dv = _bwd_call(
         q, k, v, bias, _kernel_seed(ctx, dropout), g.astype(q.dtype), lse,
-        scale, dropout, causal, pallas_mode.interpret(), *blocks)
+        scale, dropout, causal, pallas_mode.interpret(), *blocks, window)
     return {"Q@GRAD": [dq], "K@GRAD": [dk], "V@GRAD": [dv]}

@@ -207,6 +207,8 @@ def tune_program(program, batch: int = 128,
                           "dropout": 0.0 if op.attr("is_test", False)
                           else float(op.attr("dropout_prob", 0.0) or 0.0),
                           "causal": bool(op.attr("causal", False))}
+                if 0 < int(op.attr("window", 0) or 0) < q[2]:
+                    params["window"] = int(op.attr("window"))  # as _plan's
                 if _mark(seen, "fused_attention.backend", params):
                     out.append(_tune_one("fused_attention.backend", params,
                                          mode))

@@ -261,11 +261,14 @@ def _attn_bucket(params):
     dropout change the kernel's per-block work, so neither a backend verdict
     nor a block_q measured under one configuration may be reused for
     another."""
-    return {"bh": pow2_bucket(int(params["b"]) * int(params["h"])),
-            "s": int(params["s"]), "d": int(params["d"]),
-            "bias": bool(params.get("has_bias")),
-            "causal": bool(params.get("causal")),
-            "dropout": round(float(params.get("dropout", 0.0)), 3)}
+    bucket = {"bh": pow2_bucket(int(params["b"]) * int(params["h"])),
+              "s": int(params["s"]), "d": int(params["d"]),
+              "bias": bool(params.get("has_bias")),
+              "causal": bool(params.get("causal")),
+              "dropout": round(float(params.get("dropout", 0.0)), 3)}
+    if params.get("window"):    # a sliding window visits other tiles
+        bucket["window"] = int(params["window"])
+    return bucket
 
 
 class FlashBackend(TunableChoice):
@@ -307,7 +310,8 @@ class FlashBackend(TunableChoice):
 
         def xla_fn(q, k, v):
             return _fwd_bwd(lambda q, k, v: composed_attention(
-                q, k, v, bias, scale, dropout, causal, rng), q, k, v)
+                q, k, v, bias, scale, dropout, causal, rng,
+                window=params.get("window")), q, k, v)
 
         return xla_fn, (q, q, q)
 
@@ -342,7 +346,7 @@ def _flash_bench(params, block_q, block_k=None):
     def pallas_fn(q, k, v):
         return _fwd_bwd(lambda q, k, v: _flash(
             q, k, v, bias, 0, scale, dropout, causal, interpret, block_q,
-            block_k),
+            block_k, params.get("window")),
             q, k, v)
 
     return pallas_fn, (q, q, q)
@@ -380,7 +384,9 @@ class FlashBlockSizes(TunableChoice):
     def default(self, params):
         from ..ops.pallas_attention import default_block_k, default_block_q
         s, causal = int(params["s"]), bool(params.get("causal"))
-        return (default_block_q(s, causal), default_block_k(s, causal))
+        window = params.get("window")
+        return (default_block_q(s, causal, window),
+                default_block_k(s, causal, window))
 
     def encode(self, candidate):
         return f"{int(candidate[0])},{int(candidate[1])}"

@@ -385,10 +385,10 @@ def test_program_matches_the_reference_in_loss_gradients_and_updated_bias():
 
 
 @pytest.mark.parametrize("change,match", [
-    ({"layer_types": ["conv", "sliding_attention", "conv", "conv", "conv"]},
-     "sliding_attention"),
+    ({"layer_types": ["conv", "chunked_attention", "conv", "conv", "conv"]},
+     "chunked_attention"),
     ({"conv_bias": True}, "conv_bias"),
-    ({"n_shared_experts": 1}, "shared experts"),
+    ({"n_shared_experts": 2}, "shared experts"),
     ({"router_scoring": "softmax"}, "sigmoid"),
     ({"qk_norm": "layer"}, "qk_norm"),
     ({"router_aux_loss_coef": 0.01}, "router losses")])

@@ -142,8 +142,8 @@ def test_bfloat16_agrees_at_the_written_tolerance():
 
 
 def test_config_a_builder_does_not_build_yet_raises_by_name():
-    for key, value in (("norm_topk_prob", True), ("hidden_act", "gelu"),
-                       ("attention_bias", True)):
+    for key, value in (("moe_router_logit_softcapping", 30.0),
+                       ("hidden_act", "gelu"), ("attention_bias", True)):
         main, startup = fluid.Program(), fluid.Program()
         with fluid.unique_name.guard(), fluid.program_guard(main, startup):
             ids = fluid.data("ids", [2, 8], "int64", append_batch_size=False)

@@ -1,7 +1,8 @@
 """The Pallas kernels through Mosaic, at the benchmark's shapes, for a TPU v5e
 that is described and not attached: what the interpreter cannot see (VMEM
 budgets, tiling, the in-kernel PRNG). The flash kernels at BERT's and the
-decoder's shapes, the short convolution in both its forms, the chunked
+decoders' shapes (Laguna's window and full layers among them), the short
+convolution in both its forms, the chunked
 state-space scan at Granite's, the grouped expert matmuls at OLMoE's, and a
 small expert layer's whole train step (what a Program's grad ops leave in
 it). Nothing
@@ -165,6 +166,39 @@ def test_causal_flash_compiles_for_v5e_in_k_tiles(one_chip, H, kv, D, block_q,
     def grad_op(q, k, v, g, lse):
         return _grad_op(q, k, v, None, g, lse, D ** -0.5, 0.0, True, block_q,
                         block_k)
+
+    assert _kernels(jax.jit(attend).lower(q, k, k).compile()) == 1
+    back = jax.jit(grad_op).lower(q, k, k, q, _lse(q, one_chip))
+    assert [tuple(o.shape) for o in back.out_info] == [
+        (B, H, S, D), (B, kv, S, D), (B, kv, S, D)]
+    assert _kernels(back.compile()) == 1
+
+
+@pytest.mark.parametrize("H,window,block_q,block_k", [
+    (72, 512, *pa.WINDOW_BLOCKS), (72, 512, 512, 1024), (72, 512, 256, 256),
+    (48, None, *pa.CAUSAL_BLOCKS)])
+def test_laguna_s_attention_compiles_for_v5e(one_chip, H, window, block_q,
+                                             block_k):
+    """The Laguna cell's attention (1 x 4096 tokens, causal, bf16, 8
+    key/value heads of 128): the three window layers' 72 query heads (group
+    9, window 512) at the tiles a window op takes by default and at two
+    other pairs, and the two full layers' 48 (group 6) at the causal tiles:
+    the three loops of dynamic trip count, a stage that holds the two tiles
+    a Q block's window reaches and dK^T / dV^T by tile fit Mosaic's VMEM,
+    and a grad op holds the backward kernel alone."""
+    B, S, kv, D = 1, 4096, 8, 128
+    assert (pa.default_block_q(S, True, 512),
+            pa.default_block_k(S, True, 512)) == pa.WINDOW_BLOCKS
+    q = jax.ShapeDtypeStruct((B, H, S, D), jnp.bfloat16, sharding=one_chip)
+    k = jax.ShapeDtypeStruct((B, kv, S, D), jnp.bfloat16, sharding=one_chip)
+
+    def attend(q, k, v):
+        return pa._flash(q, k, v, None, jnp.int32(3), D ** -0.5, 0.0, True,
+                         False, block_q, block_k, window)
+
+    def grad_op(q, k, v, g, lse):
+        return pa._bwd_call(q, k, v, None, jnp.int32(3), g, lse, D ** -0.5,
+                            0.0, True, False, block_q, block_k, window)
 
     assert _kernels(jax.jit(attend).lower(q, k, k).compile()) == 1
     back = jax.jit(grad_op).lower(q, k, k, q, _lse(q, one_chip))
