@@ -734,6 +734,9 @@ class Executor:
             program._lowering_notes.pop("fused_attention", {}), label)
         _obs_attention.count_backwards(
             program._lowering_notes.pop("fused_attention_grad", {}), label)
+        from ..observability import loss as _obs_loss
+        _obs_loss.count_backwards(program._lowering_notes.pop(
+            "softmax_with_cross_entropy_grad", {}), label)
         from ..observability import masks as _obs_masks
         # takes the kinds it counts (mask_draw, gather_layout) out of them
         _obs_masks.count_data_axis(program._lowering_notes, label)

@@ -501,13 +501,19 @@ def log_softmax(input, axis=-1, name=None):
 def softmax_with_cross_entropy(logits, label, soft_label=False, ignore_index=-100,
                                numeric_stable_mode=True, return_softmax=False,
                                axis=-1):
-    """Reference nn.py:8223."""
+    """Reference nn.py:8223. The op has a third output, ``Lse`` [N..., 1]:
+    the rows' ``logsumexp``, which its own grad lowering reads (no gradient
+    flows through it). Logits narrower than float32 (hard labels, the last
+    axis, no ``ignore_index``) give a float32 ``Loss``, computed in float32
+    inside without a float32 copy of the logits (ops/math_ops.py)."""
     helper = LayerHelper("softmax_with_cross_entropy")
     softmax_out = _out(helper, logits.dtype)
     loss = _out(helper, logits.dtype)
+    lse = _out(helper, "float32", stop_gradient=True)
     helper.append_op("softmax_with_cross_entropy",
                      inputs={"Logits": [logits], "Label": [label]},
-                     outputs={"Softmax": [softmax_out], "Loss": [loss]},
+                     outputs={"Softmax": [softmax_out], "Loss": [loss],
+                              "Lse": [lse]},
                      attrs={"soft_label": soft_label, "ignore_index": ignore_index,
                             "axis": axis})
     if return_softmax:

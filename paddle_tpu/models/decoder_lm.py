@@ -496,9 +496,9 @@ def build(cfg: dict, ids, labels) -> dict:
         logits = layers.matmul(x, table, transpose_y=True)
     else:
         logits = _linear(x, cfg["vocab_size"], "lm_head_w")
-    if dtype != "float32":
-        logits = layers.cast(logits, "float32")
-    if cfg.get("logits_scaling", 1) != 1:
+    if cfg.get("logits_scaling", 1) != 1:   # applied in float32
+        if dtype != "float32":
+            logits = layers.cast(logits, "float32")
         logits = layers.scale(logits, 1.0 / float(cfg["logits_scaling"]))
     each = layers.softmax_with_cross_entropy(logits, labels)
     ce = layers.mean(each)

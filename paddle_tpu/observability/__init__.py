@@ -42,6 +42,9 @@ reproduction gets the counterpart the whole-program-jit design enables:
   window}``: the K tiles its flash kernels visit and skip; ``attention_backward_total{program,stats}``: where
   each of its grad ops got the softmax statistics (``saved`` / ``recomputed``
   / ``generic``).
+- ``loss`` -- ``loss_backward_total{program,form}``: the form each
+  ``softmax_with_cross_entropy_grad`` op of a compiled program took
+  (``written`` over the logits / ``fused`` / ``generic``).
 
 Render everything with ``python -m tools.obs_report``.
 """

@@ -7,9 +7,11 @@ letting XLA tile onto the systolic array; bf16 flows through unchanged.
 """
 from __future__ import annotations
 
+import math
+
 import numpy as np
 
-from ..core.registry import register
+from ..core.registry import register, register_grad
 
 
 def _jnp():
@@ -69,20 +71,71 @@ def log_softmax(ctx, ins):
     return {"Out": [jax.nn.log_softmax(ins["X"][0], axis=ctx.attr("axis", -1))]}
 
 
+# A written loss gradient must earn a pass over the logits. Offline compile
+# for a described v5e (PR 40; XLA's estimated_cycles, temp_gb against the
+# parent's), the head's dW product / the step's temporaries:
+#   olmoe  [16384, 50304] bf16, 1.65 GB: fused 66.6 M cycles, written 30.4 M
+#          (the forward product's 31.7 M), temp 4.975 -> 3.89 either way;
+#   lfm2   [16384, 16384], 0.54 GB: XLA lays the head's output class-major
+#          there, so a row-chunked write costs a transposing copy of the
+#          logits (6.95 M cycles, +0.54 GB live) for a dW that falls 20.8 ->
+#          9.9 M: temp 7.873 -> 8.02, over the peak's bound;
+#   laguna [4096, 12544], 0.10 GB: the same layout, less to gain.
+WRITTEN_GRAD_MIN_BYTES = 1 << 30
+# rows of the logits a trip of the written form's loop rewrites: 16 trips in
+# olmoe, each 1024 rows (103 MB read and written)
+WRITTEN_GRAD_CHUNKS = 16
+
+
+def _label_column(label, logits):
+    """Hard labels of the last axis, [N..., 1] or [N...], as int32 [N..., 1]."""
+    if label.ndim < logits.ndim:
+        label = label[..., None]
+    return label.astype("int32")
+
+
+def _lean_loss(ctx, logits) -> bool:
+    """What the lean form of ``softmax_with_cross_entropy`` and its written
+    gradient need to see: hard labels along the last axis, no active
+    ``ignore_index``, and logits narrower than float32 (what a model gets by
+    not casting its head's output). Float32 logits keep the lowering they
+    had, to the jaxpr."""
+    jnp = _jnp()
+    return (not ctx.attr("soft_label", False)
+            and ctx.attr("axis", -1) in (-1, logits.ndim - 1)
+            and ctx.attr("ignore_index", -100) < 0
+            and jnp.issubdtype(logits.dtype, jnp.floating)
+            and logits.dtype.itemsize < 4)
+
+
 @register("softmax_with_cross_entropy", nondiff_inputs=("Label",),
-          nondiff_outputs=("Softmax",))
+          nondiff_outputs=("Softmax", "Lse"))
 def softmax_with_cross_entropy(ctx, ins):
     """Fused stable softmax + CE (reference softmax_with_cross_entropy_op.cc).
 
     Hard labels: Label int [N...,1]; soft labels: Label same shape as Logits.
-    Outputs: Softmax (no grad flow), Loss [N...,1].
+    Outputs: Softmax (no grad flow), Loss [N...,1], and Lse [N...,1], the
+    rows' ``logsumexp``: the statistic the op's own grad lowering reads
+    (``softmax_with_cross_entropy_grad``), float32 in the lean form.
     NOTE: Softmax marked nondiff so the vjp grad comes only from Loss -- matching the
     reference, whose grad kernel uses only the saved Softmax.
+
+    Lean form (``_lean_loss``): float32 inside, and no float32 value of the
+    logits' shape outlives a reduction -- the label's logit is gathered from
+    the logits themselves, so ``Loss = lse - f32(x[label])``, the same
+    float32 number as ``-(f32(x) - lse)[label]``.
     """
     import jax
     jnp = _jnp()
     logits, label = ins["Logits"][0], ins["Label"][0]
     axis = ctx.attr("axis", -1)
+    if _lean_loss(ctx, logits):
+        x32 = logits.astype(jnp.float32)
+        lse = jax.scipy.special.logsumexp(x32, axis=-1, keepdims=True)
+        picked = jnp.take_along_axis(
+            logits, _label_column(label, logits), axis=-1)
+        return {"Softmax": [jnp.exp(x32 - lse).astype(logits.dtype)],
+                "Loss": [lse - picked.astype(jnp.float32)], "Lse": [lse]}
     lse = jax.scipy.special.logsumexp(logits, axis=axis, keepdims=True)
     log_probs = logits - lse
     softmax_out = jnp.exp(log_probs)
@@ -100,7 +153,62 @@ def softmax_with_cross_entropy(ctx, ins):
         if ignore >= 0:
             mask = (lab[..., None] != ignore)
             loss = jnp.where(mask, loss, jnp.zeros_like(loss))
-    return {"Softmax": [jax.lax.stop_gradient(softmax_out)], "Loss": [loss]}
+    return {"Softmax": [jax.lax.stop_gradient(softmax_out)], "Loss": [loss],
+            "Lse": [lse]}
+
+
+def _loss_grad_rows(x, lse, lab, g):
+    """``(exp(f32(x) - lse) - onehot(lab)) * g`` over rows ``x [R, V]``,
+    float32 inside, rounded once to the logits' dtype (the rounding the
+    cast's grad applied while the model cast its logits)."""
+    import jax
+    jnp = _jnp()
+    cls = jax.lax.broadcasted_iota(jnp.int32, x.shape, 1)
+    p = jnp.exp(x.astype(jnp.float32) - lse)
+    return ((p - (cls == lab).astype(jnp.float32)) * g).astype(x.dtype)
+
+
+@register_grad("softmax_with_cross_entropy")
+def softmax_with_cross_entropy_grad(ctx, ins, generic):
+    """dLogits. Where the forward took the lean form and declared ``Lse``,
+    the closed form over the logits in one elementwise pass
+    (``_loss_grad_rows``: no forward lowered, no reduction of the logits'
+    shape). From ``WRITTEN_GRAD_MIN_BYTES`` of logits on one device it is
+    ``written``: a loop over row chunks rewrites the logits' own buffer, so
+    the head's two gradient products read an array and neither re-derives
+    the softmax in its operand; below that it is ``fused``, the same
+    expression left to XLA. Every other case (soft labels, another axis, an
+    ``ignore_index``, float32 logits, a desc from before the op had ``Lse``)
+    is ``generic``. Which it was is noted for ``loss_backward_total``
+    (observability/loss.py)."""
+    import jax
+    logits, label = ins["Logits"][0], ins["Label"][0]
+    lse, g = ins.get("Lse", [None])[0], ins.get("Loss@GRAD", [None])[0]
+    if not _lean_loss(ctx, logits) or lse is None or g is None:
+        ctx.note("softmax_with_cross_entropy_grad", "generic")
+        return generic()
+    V = logits.shape[-1]
+    x = logits.reshape((-1, V))
+    lse, g = lse.reshape((-1, 1)), g.reshape((-1, 1)).astype(lse.dtype)
+    lab = _label_column(label, logits).reshape((-1, 1))
+    trips = math.gcd(x.shape[0], WRITTEN_GRAD_CHUNKS)
+    written = (x.size * x.dtype.itemsize >= WRITTEN_GRAD_MIN_BYTES
+               and trips > 1 and ctx.mesh is None and ctx.gspmd_mesh is None)
+    ctx.note("softmax_with_cross_entropy_grad",
+             "written" if written else "fused")
+    if not written:
+        dx = _loss_grad_rows(x, lse, lab, g)
+        return {"Logits@GRAD": [dx.reshape(logits.shape)]}
+    rows = x.shape[0] // trips
+
+    def chunk(i, buf):
+        at = lambda a: jax.lax.dynamic_slice_in_dim(a, i * rows, rows, 0)
+        return jax.lax.dynamic_update_slice_in_dim(
+            buf, _loss_grad_rows(at(buf), at(lse), at(lab), at(g)),
+            i * rows, 0)
+
+    dx = jax.lax.fori_loop(0, trips, chunk, x)
+    return {"Logits@GRAD": [dx.reshape(logits.shape)]}
 
 
 @register("cross_entropy", nondiff_inputs=("Label",))

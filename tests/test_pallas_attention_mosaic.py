@@ -408,27 +408,22 @@ def _captured_step(main, feed, fetch, scope):
     return taken["fn"], taken["args"]
 
 
-def test_expert_layer_step_holds_nine_grouped_matmuls_and_one_sort_by_expert(
-        one_chip, as_on_the_chip):
-    """D11 on the decoder: every grad op re-lowers its forward under
-    jax.vjp. A small decoder_lm train step (one layer, widths Mosaic takes,
-    S below the flash kernel's) compiled for the v5e must hold, a layer,
-    3 forward + 6 backward grouped-matmul kernels and one sort, and no more:
-    the copies the grad ops trace are dropped or merged."""
-    import re
+_DECODER = {"hidden_size": 256, "num_hidden_layers": 1,
+            "num_attention_heads": 2, "num_experts": 4,
+            "num_experts_per_tok": 2, "intermediate_size": 128,
+            "vocab_size": 512, "rms_norm_eps": 1e-5, "rope_theta": 10000,
+            "dtype": "bfloat16"}
 
+
+def _decoder_step_text(one_chip, model, batch, seq):
+    """A small decoder_lm train step (one layer, widths Mosaic takes, S below
+    the flash kernel's) compiled for the v5e: its optimized HLO text and the
+    Program, whose lowering notes are that compile's."""
     import numpy as np
 
     import paddle_tpu as fluid
     from paddle_tpu.models import decoder_lm
 
-    model = {"hidden_size": 256, "num_hidden_layers": 1,
-             "num_attention_heads": 2, "num_experts": 4,
-             "num_experts_per_tok": 2, "intermediate_size": 128,
-             "vocab_size": 512, "rms_norm_eps": 1e-5, "rope_theta": 10000,
-             "router_aux_loss_coef": 0.01, "router_z_loss_coef": 0.001,
-             "dtype": "bfloat16"}
-    batch, seq = 2, 128
     main, startup = fluid.Program(), fluid.Program()
     with fluid.unique_name.guard(), fluid.program_guard(main, startup):
         A = dict(append_batch_size=False)
@@ -439,6 +434,7 @@ def test_expert_layer_step_holds_nine_grouped_matmuls_and_one_sort_by_expert(
     exe, scope = fluid.Executor(), fluid.Scope()
     exe.run(startup, scope=scope)
     exe.close()
+    main._lowering_notes.clear()
     fn, args = _captured_step(main, {
         "ids": np.zeros((batch, seq), np.int32),
         "labels": np.zeros((batch * seq, 1), np.int32)}, [out["loss"]], scope)
@@ -446,8 +442,21 @@ def test_expert_layer_step_holds_nine_grouped_matmuls_and_one_sort_by_expert(
     def spec(x):
         x = x if hasattr(x, "dtype") else np.asarray(x)
         return jax.ShapeDtypeStruct(x.shape, x.dtype, sharding=one_chip)
-    text = fn.lower(
-        *jax.tree_util.tree_map(spec, args)).compile().as_text()
+    return fn.lower(
+        *jax.tree_util.tree_map(spec, args)).compile().as_text(), main
+
+
+def test_expert_layer_step_holds_nine_grouped_matmuls_and_one_sort_by_expert(
+        one_chip, as_on_the_chip):
+    """D11 on the decoder: every grad op re-lowers its forward under
+    jax.vjp. A small decoder_lm train step (one layer, widths Mosaic takes,
+    S below the flash kernel's) compiled for the v5e must hold, a layer,
+    3 forward + 6 backward grouped-matmul kernels and one sort, and no more:
+    the copies the grad ops trace are dropped or merged."""
+    import re
+
+    model = dict(_DECODER, router_aux_loss_coef=0.01, router_z_loss_coef=0.001)
+    text, _ = _decoder_step_text(one_chip, model, batch=2, seq=128)
     kernels = [ln for ln in text.splitlines()
                if 'custom_call_target="tpu_custom_call"' in ln]
     in_scope = lambda ln, scope: re.search(                 # noqa: E731
@@ -463,6 +472,50 @@ def test_expert_layer_step_holds_nine_grouped_matmuls_and_one_sort_by_expert(
     assert sum(in_scope(ln, "moe_router") for ln in sorts) == 1
     assert not [ln for ln in sorts if in_scope(ln, "moe_dispatch_grad")
                 or in_scope(ln, "moe_router_grad")]
+
+
+@pytest.mark.parametrize("form,line", [("fused", 1 << 30), ("written", 1)])
+def test_decoder_step_holds_no_float32_logits_in_either_form_of_the_loss_grad(
+        one_chip, as_on_the_chip, monkeypatch, form, line):
+    """The decoder hands ``softmax_with_cross_entropy`` its bfloat16 logits
+    (PR 40). Compiled for the v5e, no float32 value of the logits' shape
+    outlives a fusion, forward or backward; the grad op is noted ``fused``
+    or, with the line patched down, ``written``: then the step holds the
+    loop over row chunks of the logits' own buffer, no copy of a
+    logits-shaped array beside it, and the head's weight-gradient product
+    reads an array (no ``exponential`` in its fused computation)."""
+    import re
+
+    from paddle_tpu.observability.attribution import parse_hlo_computations
+    from paddle_tpu.ops import math_ops
+
+    monkeypatch.setattr(math_ops, "WRITTEN_GRAD_MIN_BYTES", line)
+    model = dict(_DECODER, vocab_size=640)
+    batch, seq = 4, 128     # 512 tokens: no size equals hidden
+    text, main = _decoder_step_text(one_chip, model, batch, seq)
+    assert list(main._lowering_notes.pop(
+        "softmax_with_cross_entropy_grad").values()) == [form]
+    comps, entry, _ = parse_hlo_computations(text)
+    called = {c for ins in comps[entry]
+              for c in re.findall(r"(?:body|condition)=%?([\w.\-]+)", ins.rest)}
+    held = [ins for name in (entry, *called) for ins in comps[name]]
+    logits = f"[{batch * seq},{model['vocab_size']}]"
+    assert any(ins.shape.startswith("bf16" + logits) for ins in held)
+    assert not [ins.name for ins in held if "f32" + logits in ins.shape]
+    assert not [ins.name for ins in held
+                if ins.opcode == "copy" and logits in ins.shape]
+    loops = [ins for ins in comps[entry] if ins.opcode == "while"
+             and "softmax_with_cross_entropy_grad#" in ins.op_name]
+    assert len(loops) == (form == "written")
+    if form == "written":
+        dw = [ins for ins in comps[entry]
+              if "mul_grad#" in ins.op_name and ins.opcode == "fusion"
+              and f"[256,{model['vocab_size']}]" in ins.shape]
+        assert dw
+        for ins in dw:
+            body = re.search(r"calls=%?([\w.\-]+)", ins.rest).group(1)
+            assert not [i.name for i in comps[body]
+                        if i.opcode == "exponential"]
 
 
 def _bert_s512_step(strategy=None, with_lse=True):
