@@ -734,6 +734,7 @@ class Executor:
             program._lowering_notes.pop("fused_attention", {}), label)
         _obs_attention.count_backwards(
             program._lowering_notes.pop("fused_attention_grad", {}), label)
+        _obs_attention.update_gate_gauges(program, label)
         from ..observability import loss as _obs_loss
         _obs_loss.count_backwards(program._lowering_notes.pop(
             "softmax_with_cross_entropy_grad", {}), label)
@@ -745,6 +746,9 @@ class Executor:
         _obs_ssm.count_lowerings(
             program._lowering_notes.pop("ssd_scan", {}),
             program._lowering_notes.pop("short_conv", {}), label)
+        _obs_ssm.update_delta_gauges(program, label)
+        _obs_ssm.count_delta_lowerings(
+            program._lowering_notes.pop("gated_delta_rule", {}), label)
         # IR->HLO attribution walk: once per compile miss, only when obs /
         # PADDLE_TPU_OBS_ATTRIB / an armed --emit-hlo capture asks for it
         # (on_compile is a no-op otherwise and never raises)

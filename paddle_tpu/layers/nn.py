@@ -1102,17 +1102,24 @@ def fused_attention(q, k, v, bias=None, scale=None, dropout_prob=0.0,
 
 # -- decoder-LM vocabulary (ops/decoder_ops.py) ------------------------------------------
 
-def rms_norm(input, epsilon=1e-5, param_attr=None, name=None):
+def rms_norm(input, epsilon=1e-5, param_attr=None, name=None,
+             zero_centered=False):
     """RMSNorm over the last axis with a learned scale (initialised to 1):
-    ``x / sqrt(mean(x^2) + epsilon) * scale``, float32 inside the op."""
+    ``x / sqrt(mean(x^2) + epsilon) * scale``, float32 inside the op. Under
+    ``zero_centered`` the scale is ``1 + w`` with ``w`` initialised to 0
+    (Qwen3-Next's, Gemma's): the same function at the start, another under a
+    weight decay, which pulls ``w`` to 0 and so the scale to 1."""
     from ..initializer import Constant
     helper = LayerHelper("rms_norm", name=name)
-    scale = helper.create_parameter(param_attr, [int(input.shape[-1])],
-                                    input.dtype,
-                                    default_initializer=Constant(1.0))
+    scale = helper.create_parameter(
+        param_attr, [int(input.shape[-1])], input.dtype,
+        default_initializer=Constant(0.0 if zero_centered else 1.0))
     y = _out(helper, input.dtype)
+    attrs = {"epsilon": float(epsilon)}
+    if zero_centered:
+        attrs["zero_centered"] = True
     helper.append_op("rms_norm", inputs={"X": [input], "Scale": [scale]},
-                     outputs={"Y": [y]}, attrs={"epsilon": float(epsilon)})
+                     outputs={"Y": [y]}, attrs=attrs)
     return _var(helper, y)
 
 
@@ -1155,9 +1162,10 @@ def rotary_embedding(x, theta=10000.0, name=None, rotary_dim=None,
 
 
 def attention_gate(x, gate, name=None):
-    """Attention's per-head output gate: ``x [B, heads, S, D]``, the heads'
-    outputs as ``fused_attention`` returns them, times ``sigmoid(gate [B *
-    S, heads])``, one gate a token and head, before the output projection
+    """Attention's output gate: ``x [B, heads, S, D]``, the heads' outputs
+    as ``fused_attention`` returns them, times ``sigmoid(gate)``, before the
+    output projection: ``gate [B * S, heads]`` is one gate a token and head,
+    ``gate [B * S, heads * D]`` one a token, head and channel
     (``ops/decoder_ops.py:attention_gate``, float32 inside)."""
     helper = LayerHelper("attention_gate", name=name)
     out = _out(helper, x.dtype)
@@ -1224,6 +1232,31 @@ def ssd_scan(x, dt, a, b, c, d, chunk=256, impl="auto", name=None):
     return _var(helper, out)
 
 
+def gated_delta_rule(q, k, v, g, beta, chunk=64, impl="auto", name=None):
+    """The gated delta rule of a Gated DeltaNet layer, a value head at a time
+    with state ``S [d_k, d_v]`` from zero at each sequence's start: ``S' =
+    exp(g_t) S_{t-1}``, ``u_t = beta_t (v_t - S'^T k_t)``, ``S_t = S' + k_t
+    u_t^T``, ``o_t = S_t^T q_t``, k and q each over its l2 norm (``x /
+    sqrt(sum(x^2) + 1e-6)``) and q over ``sqrt(d_k)`` inside the op. ``q`` /
+    ``k [B, S, key heads, d_k]``, ``v [B, S, heads, d_v]`` (value head j reads
+    key head ``j // (heads / key heads)``), ``g`` (<= 0) and ``beta [B, S,
+    heads]``; returns ``o`` like ``v``. Computed in chunks of ``chunk``
+    positions (``ops/decoder_ops.py:gated_delta_rule``: under ``impl="auto"``
+    the Pallas kernels on a TPU where they take the shapes, the composed
+    chunked form elsewhere; ``"pallas"`` / ``"composed"`` force one). The op
+    has a second output, ``States``: the state entering each chunk, for its
+    own backward (no gradient flows through it; the layer returns ``Out``)."""
+    helper = LayerHelper("gated_delta_rule", name=name)
+    out = _out(helper, v.dtype)
+    states = _out(helper, "float32", stop_gradient=True)
+    helper.append_op("gated_delta_rule",
+                     inputs={"Q": [q], "K": [k], "V": [v], "G": [g],
+                             "Beta": [beta]},
+                     outputs={"Out": [out], "States": [states]},  # Out first
+                     attrs={"chunk": int(chunk), "impl": impl})
+    return _var(helper, out)
+
+
 def moe_bias_update(bias, load, rate, name=None):
     """``bias += rate * sign(mean(load) - load)``, written into ``bias``
     itself: the router's selection bias follows the step's expert load
@@ -1240,7 +1273,7 @@ def moe_bias_update(bias, load, rate, name=None):
 def moe_ffn(x, num_experts, experts_per_token, expert_width, param_attr=None,
             name="moe", experts_held=None, scoring="softmax", norm_topk=False,
             routed_scale=1.0, expert_bias=False, row_budget=None,
-            shared_width=None):
+            shared_width=None, shared_gate=False):
     """A dropless mixture-of-experts feed-forward layer over tokens
     ``x [T, H]``: a float32 router (softmax over the experts, top-k values
     used as they are, or under ``norm_topk`` over their sum, times
@@ -1276,8 +1309,10 @@ def moe_ffn(x, num_experts, experts_per_token, expert_width, param_attr=None,
     ``shared_width``: one shared expert beside the routed ones, a dense
     SwiGLU of that width over every token (``<name>_shared_gate_w`` /
     ``_shared_up_w [H, shared_width]``, ``_shared_down_w [shared_width,
-    H]``), added ungated to the routed experts' sum. Under ``experts_held``
-    every share computes it alike: over the shares it counts once.
+    H]``), added to the routed experts' sum: ungated, or under
+    ``shared_gate`` times ``sigmoid(x . w)``, one gate a token
+    (``<name>_shared_expert_gate_w [H, 1]``). Under ``experts_held`` every
+    share computes it alike: over the shares it counts once.
 
     Parameters, by name: ``<name>_router_w [H, E]`` float32 and
     ``<name>_gate_w`` / ``<name>_up_w [held, H, width]``, ``<name>_down_w
@@ -1384,6 +1419,10 @@ def moe_ffn(x, num_experts, experts_per_token, expert_width, param_attr=None,
         shared = dense(swiglu(dense(x, "gate_w", int(shared_width)),
                               dense(x, "up_w", int(shared_width))),
                        "down_w", H)
+        if shared_gate:
+            shared = elementwise_mul(shared, sigmoid(fc(
+                x, 1, bias_attr=False, param_attr=ParamAttr(
+                    name=f"{name}_shared_expert_gate_w", initializer=init))))
         out = elementwise_add(_var(helper, out), shared)
     blk = helper.main_program.current_block()
     return blk.var(out.name), {n: blk.var(v.name) for n, v in aux.items()}

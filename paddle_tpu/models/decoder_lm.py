@@ -11,7 +11,9 @@ pre-norm, without biases or dropout: ``h = x + r operator(norm(x))``,
 ``y = h + r feed_forward(norm(h))`` with ``r`` = ``residual_multiplier``
 (default 1). What it builds, by config key:
 
-- ``layer_types`` (default: every layer ``full_attention``), one operator a
+- ``layer_types`` (default: every layer ``full_attention``, or, where the
+  config has ``full_attention_interval``, every interval-th layer
+  ``full_attention`` and the others ``linear_attention``), one operator a
   layer. ``full_attention`` (also spelt ``attention``): separate q / k / v
   projections with ``num_attention_heads`` query heads
   (``num_attention_heads_per_layer[i]`` in layer i where the config has
@@ -32,12 +34,20 @@ pre-norm, without biases or dropout: ``h = x + r operator(norm(x))``,
   scale ``attention_multiplier`` (default 1/sqrt(head dim)),
   ``impl="auto"``; under ``gating: "per-head"`` each head's output times
   ``sigmoid(W_g norm(x))``, one gate a token and head, before the output
-  projection (``layers.attention_gate``). ``sliding_attention``: the same
+  projection (``layers.attention_gate``); under ``attn_output_gate`` the q
+  projection is twice as wide, a head's first ``head_dim`` values its q and
+  the others its gate, one a token, head and channel (Qwen3-Next). Without
+  ``rope_parameters``, ``partial_rotary_factor`` is read beside
+  ``rope_theta``. ``sliding_attention``: the same
   with a window of ``sliding_window`` keys (query i sees i - window < j <=
   i). ``conv``: the gated short convolution ``W_out (C * conv(B
   * u))`` with ``B, C, u = split(W_in x, 3)`` and a causal depthwise filter
   of ``conv_L_cache`` taps (``layers.short_conv``). ``mamba``: the Mamba-2
-  mixer (``mamba``, below).
+  mixer (``mamba``, below). ``linear_attention``: the Gated DeltaNet mixer
+  (``delta_net``, below).
+- ``norm_form`` ``"plain"`` (default): every RMSNorm scales by ``w`` from 1;
+  ``"zero_centered"``: the layers' two norms, the final norm and the q / k
+  norms scale by ``1 + w`` with ``w`` from 0 (``layers.rms_norm``).
 - feed-forward: the first ``num_dense_layers`` layers (default 0), the
   layers ``mlp_layer_types`` calls ``"dense"`` or ``mlp_only_layers`` lists,
   and every
@@ -54,7 +64,8 @@ pre-norm, without biases or dropout: ``h = x + r operator(norm(x))``,
   by the score over the chosen scores' sum under ``norm_topk_prob``, times
   ``routed_scaling_factor``. ``shared_expert_intermediate_size``: one
   shared expert, a dense SwiGLU of that width over every token, added
-  ungated beside the routed experts' sum.
+  beside the routed experts' sum: ungated, or under ``shared_expert_gate``
+  times ``sigmoid(w_s . x)``, one gate a token.
 - one chip's share of a layer that several chips hold: ``num_experts`` is
   the experts held here, ``num_experts_routed`` (default: the same) the
   router's width and ``first_expert_held`` (default 0) the first held; the
@@ -79,15 +90,17 @@ pre-norm, without biases or dropout: ``h = x + r operator(norm(x))``,
 Models through it: OLMoE-1B-7B (Muennighoff et al., arXiv:2409.02060; HF
 ``modeling_olmoe.py``), LFM2-8B-A1B (HF ``modeling_lfm2_moe.py``),
 granite-4.0-h-micro (HF ``modeling_granitemoehybrid.py``; the scan: Dao &
-Gu, arXiv:2405.21060) and Laguna-S-2.1 (its ``config.json``; YaRN: Peng et
-al., arXiv:2309.00071).
+Gu, arXiv:2405.21060), Laguna-S-2.1 (its ``config.json``; YaRN: Peng et
+al., arXiv:2309.00071) and Qwen3-Next-80B-A3B (HF ``modeling_qwen3_next.py``;
+the delta rule: Yang et al., arXiv:2412.06464).
 
 Dtypes follow ``models/bert.py``: the embedding table is float32 whatever
 ``dtype`` says, activations are cast to ``dtype`` right after the lookup,
-weights are created in ``dtype`` (a Mamba mixer's ``A_log``, ``D`` and
-``dt_bias``, one number a head, in float32); RMSNorm, the router, the short
-convolution, the scan's decays and state and every softmax compute in
-float32 inside their ops; the logits are cast up for the loss.
+weights are created in ``dtype`` (a Mamba or DeltaNet mixer's ``A_log``,
+``D`` and ``dt_bias``, one number a head, in float32); RMSNorm, the router,
+the short convolution, the scan's and the delta rule's decays and state, the
+delta rule's l2 norms and every softmax compute in float32 inside their ops;
+the logits are cast up for the loss.
 """
 from __future__ import annotations
 
@@ -103,8 +116,10 @@ _REQUIRED = {"hidden_act": "silu", "attention_bias": False,
              "mamba_proj_bias": False, "mamba_n_groups": 1,
              "normalization_function": "rmsnorm",
              "moe_apply_router_weight_on_input": False,
-             "moe_router_logit_softcapping": 0, "decoder_sparse_step": 1}
-_OPERATORS = ("full_attention", "sliding_attention", "conv", "mamba")
+             "moe_router_logit_softcapping": 0, "decoder_sparse_step": 1,
+             "use_sliding_window": False}
+_OPERATORS = ("full_attention", "sliding_attention", "conv", "mamba",
+              "linear_attention")
 _ATTENTION = ("full_attention", "sliding_attention")
 
 
@@ -122,11 +137,31 @@ def _check(cfg: dict) -> None:
         if kind not in _OPERATORS:
             raise NotImplementedError(
                 f"decoder_lm: layer type {kind!r} is not built yet (only "
-                f"{_OPERATORS}: no chunked attention, no latent attention, "
-                f"no gated delta rule)")
+                f"{_OPERATORS}: no chunked attention, no latent attention)")
     if "sliding_attention" in kinds and not cfg.get("sliding_window"):
         raise ValueError("decoder_lm: sliding_attention layers need "
                          "sliding_window")
+    if "linear_attention" in kinds:
+        if cfg["linear_num_value_heads"] % cfg["linear_num_key_heads"]:
+            raise ValueError("linear_num_value_heads must be a multiple of "
+                             "linear_num_key_heads")
+        if cfg.get("linear_conv_bias"):
+            raise NotImplementedError(
+                "decoder_lm: linear_conv_bias is not built yet (a Gated "
+                "DeltaNet mixer's filter has no bias)")
+    if cfg.get("norm_form", "plain") not in ("plain", "zero_centered"):
+        raise NotImplementedError(
+            f"decoder_lm: norm_form={cfg['norm_form']!r} is not built yet "
+            f"(only 'plain' and 'zero_centered')")
+    if cfg.get("attn_output_gate") and cfg.get("gating", "none") != "none":
+        raise NotImplementedError(
+            "decoder_lm: attn_output_gate (one gate a token, head and "
+            "channel) beside gating='per-head' is not built: one gate an "
+            "attention layer")
+    if cfg.get("shared_expert_gate") and not cfg.get(
+            "shared_expert_intermediate_size"):
+        raise ValueError("decoder_lm: shared_expert_gate needs a shared "
+                         "expert (shared_expert_intermediate_size)")
     if "mamba" in kinds and (
             cfg["mamba_n_heads"] * cfg["mamba_d_head"]
             != cfg["mamba_expand"] * cfg["hidden_size"]):
@@ -193,8 +228,12 @@ def _check(cfg: dict) -> None:
 
 
 def _layer_types(cfg: dict) -> list:
-    kinds = cfg.get("layer_types") or (
-        ["full_attention"] * cfg["num_hidden_layers"])
+    kinds = cfg.get("layer_types")
+    if not kinds and cfg.get("full_attention_interval"):
+        every = cfg["full_attention_interval"]      # HF qwen3_next's rule
+        kinds = ["linear_attention" if (i + 1) % every else "full_attention"
+                 for i in range(cfg["num_hidden_layers"])]
+    kinds = kinds or ["full_attention"] * cfg["num_hidden_layers"]
     return ["full_attention" if k == "attention" else k for k in kinds]
 
 
@@ -213,7 +252,8 @@ def _rope(cfg: dict, kind: str) -> dict:
     layer type, else ``rope_theta`` over the whole head."""
     by_type = cfg.get("rope_parameters")
     if by_type is None:
-        return {"rope_theta": cfg.get("rope_theta", 10000.0)}
+        return {"rope_theta": cfg.get("rope_theta", 10000.0),
+                "partial_rotary_factor": cfg.get("partial_rotary_factor", 1)}
     return by_type[kind]
 
 
@@ -237,6 +277,14 @@ def _linear(x, size: int, name: str):
     return layers.fc(x, size, param_attr=_attr(name), bias_attr=False)
 
 
+def _norm(x, cfg: dict, name: str):
+    """The config's RMSNorm of ``x`` with the scale ``name``: ``* w`` from
+    1, or under ``norm_form: "zero_centered"`` ``* (1 + w)`` from 0."""
+    return layers.rms_norm(
+        x, _eps(cfg), ParamAttr(name=name),
+        zero_centered=cfg.get("norm_form", "plain") == "zero_centered")
+
+
 def attention(x, cfg: dict, batch: int, seq: int, name: str, layer: int = 0,
               kind: str = "full_attention"):
     """Causal self-attention of layer ``layer`` over tokens ``x [batch *
@@ -244,7 +292,6 @@ def attention(x, cfg: dict, batch: int, seq: int, name: str, layer: int = 0,
     ``"sliding_attention"`` within a window of ``sliding_window`` keys."""
     H, heads, kv_heads = cfg["hidden_size"], _heads(cfg, layer), _kv_heads(cfg)
     d = cfg.get("head_dim") or H // heads
-    eps = _eps(cfg)
     norm = cfg.get("qk_norm", "projection")
     by_head, whole = norm == "head", norm == "projection"
     rotary = cfg.get("position_embedding_type", "rope") == "rope"
@@ -253,7 +300,7 @@ def attention(x, cfg: dict, batch: int, seq: int, name: str, layer: int = 0,
     def heads_of(t, n, norm_w=None, positions=rotary):
         t = layers.reshape(t, [batch, seq, n, d])   # [B*S, n*d] -> [B, n, S, d]
         if norm_w:
-            t = layers.rms_norm(t, eps, ParamAttr(name=norm_w))
+            t = _norm(t, cfg, norm_w)
         t = layers.transpose(t, [0, 2, 1, 3])
         if not positions:
             return t
@@ -262,12 +309,19 @@ def attention(x, cfg: dict, batch: int, seq: int, name: str, layer: int = 0,
             rotary_dim=int(d * rope.get("partial_rotary_factor", 1)),
             scaling=rope)
 
-    q = _linear(x, heads * d, name + "_q_w")
+    gate = None
+    if cfg.get("attn_output_gate"):     # a head's q, then its gate
+        q, gate = layers.split(layers.reshape(
+            _linear(x, heads * 2 * d, name + "_q_w"),
+            [batch, seq, heads, 2 * d]), 2, dim=-1)
+        gate = layers.reshape(gate, [batch * seq, heads * d])
+    else:
+        q = _linear(x, heads * d, name + "_q_w")
     if whole:
-        q = layers.rms_norm(q, eps, ParamAttr(name=name + "_q_norm_w"))
+        q = _norm(q, cfg, name + "_q_norm_w")
     k = _linear(x, kv_heads * d, name + "_k_w")
     if whole:
-        k = layers.rms_norm(k, eps, ParamAttr(name=name + "_k_norm_w"))
+        k = _norm(k, cfg, name + "_k_norm_w")
     v = _linear(x, kv_heads * d, name + "_v_w")
     ctx = layers.fused_attention(
         heads_of(q, heads, name + "_q_norm_w" if by_head else None),
@@ -278,6 +332,8 @@ def attention(x, cfg: dict, batch: int, seq: int, name: str, layer: int = 0,
         window=cfg["sliding_window"] if kind == "sliding_attention" else None)
     if cfg.get("gating", "none") == "per-head":
         ctx = layers.attention_gate(ctx, _linear(x, heads, name + "_g_w"))
+    elif gate is not None:
+        ctx = layers.attention_gate(ctx, gate)
     ctx = layers.reshape(layers.transpose(ctx, [0, 2, 1, 3]),
                          [batch * seq, heads * d])
     return _linear(ctx, H, name + "_o_w")
@@ -310,6 +366,29 @@ class _Drawn(Initializer):
                             outputs={"Out": [var.name]}, attrs=attrs)
 
 
+def _conv_attr(name: str, taps: int) -> ParamAttr:
+    """A depthwise Conv1d's usual start: uniform in +-1/sqrt(taps)."""
+    return ParamAttr(name=name, initializer=Uniform(
+        -1.0 / math.sqrt(taps), 1.0 / math.sqrt(taps)))
+
+
+def _per_head(name: str, heads: int, initializer):
+    return layers.create_parameter([heads], "float32", name=name,
+                                   default_initializer=initializer)
+
+
+def _dt_bias_and_a_log(name: str, heads: int):
+    """A recurrent mixer's float32 per-head ``<name>_dt_bias`` and
+    ``<name>_A_log`` as mamba_ssm's ``Mamba2`` starts them: dt log-uniform
+    in [1e-3, 1e-1] through the inverse softplus, A uniform in [1, 16]."""
+    dt_bias = _per_head(name + "_dt_bias", heads, _Drawn(
+        math.log(1e-3), math.log(1e-1),
+        [("exp", {}), ("exp", {}), ("scale", {"scale": 1.0, "bias": -1.0}),
+         ("log", {})]))                 # log(exp(dt) - 1), dt = exp(draw)
+    return dt_bias, _per_head(name + "_A_log", heads,
+                              _Drawn(1.0, 16.0, [("log", {})]))
+
+
 def mamba(x, cfg: dict, batch: int, seq: int, name: str):
     """The Mamba-2 mixer over ``x [batch * seq, H]`` (HF
     ``GraniteMoeHybridMambaLayer``; Dao & Gu, arXiv:2405.21060): ``[z | xBC |
@@ -335,27 +414,14 @@ def mamba(x, cfg: dict, batch: int, seq: int, name: str):
         _linear(x, 2 * inner + 2 * n + heads, name + "_in_w"),
         [inner, inner + 2 * n, heads], dim=-1)
     taps = cfg["mamba_d_conv"]
-
-    def conv_attr(suffix):      # a depthwise Conv1d's usual start
-        return ParamAttr(name=name + suffix, initializer=Uniform(
-            -1.0 / math.sqrt(taps), 1.0 / math.sqrt(taps)))
     xbc = layers.short_conv(
-        xbc, seq, taps, conv_attr("_conv_w"),
-        bias_attr=(conv_attr("_conv_b") if cfg.get("mamba_conv_bias")
-                   else False),
+        xbc, seq, taps, _conv_attr(name + "_conv_w", taps),
+        bias_attr=(_conv_attr(name + "_conv_b", taps)
+                   if cfg.get("mamba_conv_bias") else False),
         gated=False, activation="silu")
     xs, b, c = layers.split(xbc, [inner, n, n], dim=-1)
-
-    def per_head(suffix, initializer):
-        return layers.create_parameter(
-            [heads], "float32", name=name + suffix,
-            default_initializer=initializer)
-    dt_bias = per_head("_dt_bias", _Drawn(
-        math.log(1e-3), math.log(1e-1),
-        [("exp", {}), ("exp", {}), ("scale", {"scale": 1.0, "bias": -1.0}),
-         ("log", {})]))                 # log(exp(dt) - 1), dt = exp(draw)
-    a_log = per_head("_A_log", _Drawn(1.0, 16.0, [("log", {})]))
-    d = per_head("_D", Constant(1.0))
+    dt_bias, a_log = _dt_bias_and_a_log(name, heads)
+    d = _per_head(name + "_D", heads, Constant(1.0))
     dt = layers.softplus(layers.elementwise_add(
         layers.cast(dt, "float32"), dt_bias))
     y = layers.ssd_scan(
@@ -367,6 +433,55 @@ def mamba(x, cfg: dict, batch: int, seq: int, name: str):
     y = layers.rms_norm(
         layers.swiglu(z, layers.reshape(y, [batch * seq, inner])), _eps(cfg),
         ParamAttr(name=name + "_gated_norm_w"))
+    return _linear(y, cfg["hidden_size"], name + "_out_w")
+
+
+def delta_net(x, cfg: dict, batch: int, seq: int, name: str):
+    """The Gated DeltaNet mixer over ``x [batch * seq, H]`` (HF
+    ``Qwen3NextGatedDeltaNet``; Yang et al., arXiv:2412.06464), with ``n_k =
+    linear_num_key_heads`` heads of ``linear_key_head_dim`` and ``n_v =
+    linear_num_value_heads`` of ``linear_value_head_dim``: ``[q | k | v | z]
+    = W_in x`` and ``[b | alpha] = W_ba x`` (contiguous columns: a
+    permutation of HF's interleave by key head); ``[q | k | v] = silu(conv([q
+    | k | v]))``, one causal depthwise filter of ``linear_conv_kernel_dim``
+    taps without a bias; a value head each, ``beta = sigmoid(b)`` and ``g =
+    -exp(A_log) softplus(alpha + dt_bias)`` in float32; the gated delta rule
+    over unit q and k (``layers.gated_delta_rule``, in chunks of ``delta_
+    chunk_size``, default 64, lowered as ``delta_rule_impl`` says, default
+    ``auto``); ``W_out (rmsnorm(o) * silu(z))``, the norm over a head's
+    values with one plain scale of head size shared by the heads, before the
+    gate (a Mamba mixer gates first). ``A_log`` and ``dt_bias`` are float32
+    and start as ``mamba``'s do (HF's constructor writes ``dt_bias = 1``,
+    which a checkpoint overwrites: no state would outlive a few positions),
+    the filter as a depthwise Conv1d's."""
+    n_k, d_k = cfg["linear_num_key_heads"], cfg["linear_key_head_dim"]
+    n_v, d_v = cfg["linear_num_value_heads"], cfg["linear_value_head_dim"]
+    keys, values = n_k * d_k, n_v * d_v
+    qkv, z = layers.split(
+        _linear(x, 2 * keys + 2 * values, name + "_in_w"),
+        [2 * keys + values, values], dim=-1)
+    b, alpha = layers.split(_linear(x, 2 * n_v, name + "_ba_w"), 2, dim=-1)
+    taps = cfg["linear_conv_kernel_dim"]
+    qkv = layers.short_conv(qkv, seq, taps, _conv_attr(name + "_conv_w", taps),
+                            gated=False, activation="silu")
+    q, k, v = layers.split(qkv, [keys, keys, values], dim=-1)
+    dt_bias, a_log = _dt_bias_and_a_log(name, n_v)
+    g = layers.elementwise_mul(
+        layers.softplus(layers.elementwise_add(
+            layers.cast(alpha, "float32"), dt_bias)),
+        layers.scale(layers.exp(a_log), -1.0))
+    o = layers.gated_delta_rule(
+        layers.reshape(q, [batch, seq, n_k, d_k]),
+        layers.reshape(k, [batch, seq, n_k, d_k]),
+        layers.reshape(v, [batch, seq, n_v, d_v]),
+        layers.reshape(g, [batch, seq, n_v]),
+        layers.reshape(layers.sigmoid(layers.cast(b, "float32")),
+                       [batch, seq, n_v]),
+        chunk=cfg.get("delta_chunk_size", 64),
+        impl=cfg.get("delta_rule_impl", "auto"))
+    o = layers.rms_norm(layers.reshape(o, [batch * seq, n_v, d_v]),
+                        _eps(cfg), ParamAttr(name=name + "_gated_norm_w"))
+    y = layers.swiglu(z, layers.reshape(o, [batch * seq, values]))
     return _linear(y, cfg["hidden_size"], name + "_out_w")
 
 
@@ -388,7 +503,8 @@ def experts(x, cfg: dict, name: str):
             cfg.get("moe_routed_scaling_factor", 1.0))),
         expert_bias=bool(cfg.get("use_expert_bias", False)),
         row_budget=cfg.get("moe_row_budget"),
-        shared_width=cfg.get("shared_expert_intermediate_size"))
+        shared_width=cfg.get("shared_expert_intermediate_size"),
+        shared_gate=bool(cfg.get("shared_expert_gate", False)))
 
 
 
@@ -397,22 +513,24 @@ def block(x, cfg: dict, batch: int, seq: int, name: str,
     """Decoder layer ``layer`` over ``x [batch * seq, H]`` with the operator
     ``kind``; returns the layer's output and the router's variables
     (``layers.moe_ffn``; None for a ``dense`` feed-forward layer)."""
-    eps = _eps(cfg)
     r = float(cfg.get("residual_multiplier", 1.0))
 
     def add(h, branch):
         return layers.elementwise_add(
             h, branch if r == 1.0 else layers.scale(branch, r))
-    op_name = name + {"conv": "_conv", "mamba": "_mamba"}.get(kind, "_attn")
-    normed = layers.rms_norm(x, eps, ParamAttr(name=op_name + "_norm_w"))
+    op_name = name + {"conv": "_conv", "mamba": "_mamba",
+                      "linear_attention": "_delta"}.get(kind, "_attn")
+    normed = _norm(x, cfg, op_name + "_norm_w")
     if kind == "conv":
         mixed = short_conv(normed, cfg, seq, op_name)
     elif kind == "mamba":
         mixed = mamba(normed, cfg, batch, seq, op_name)
+    elif kind == "linear_attention":
+        mixed = delta_net(normed, cfg, batch, seq, op_name)
     else:
         mixed = attention(normed, cfg, batch, seq, op_name, layer, kind)
     h = add(x, mixed)
-    normed = layers.rms_norm(h, eps, ParamAttr(name=name + "_ffn_norm_w"))
+    normed = _norm(h, cfg, name + "_ffn_norm_w")
     if dense:
         width = cfg.get("shared_intermediate_size", cfg["intermediate_size"])
         gated = layers.swiglu(_linear(normed, width, name + "_ffn_gate_w"),
@@ -488,7 +606,7 @@ def build(cfg: dict, ids, labels) -> dict:
             biases.append(aux["bias"])
         if "dropped" in aux:
             dropped.append(aux["dropped"])
-    x = layers.rms_norm(x, _eps(cfg), ParamAttr(name="final_norm_w"))
+    x = _norm(x, cfg, "final_norm_w")
     if cfg.get("tie_word_embeddings"):
         table = x.block.program.global_block().var("tok_emb")
         if dtype != "float32":

@@ -54,6 +54,28 @@ def count_lowerings(notes: dict, program: str,
                     state=state, window=str(window)).inc(n * tiles)
 
 
+def update_gate_gauges(program_ir, program: str,
+                       registry: Optional[MetricsRegistry] = None) -> None:
+    """``attention_gate_ops{program,form}``: the ``attention_gate`` ops of
+    the compiled program by the gate's form, from static shapes:
+    ``per_head`` (one gate a token and head) or ``elementwise`` (one a
+    token, head and channel); nothing is set for a program without the
+    op."""
+    registry = registry or REGISTRY
+    block = program_ir.global_block()
+    forms = Counter()
+    for op in block.ops:
+        if op.type == "attention_gate":
+            x = block.find_var_recursive(op.inputs["X"][0])
+            gate = block.find_var_recursive(op.inputs["Gate"][0])
+            forms["per_head" if int(gate.shape[-1]) == int(x.shape[1])
+                  else "elementwise"] += 1
+    for form, n in forms.items():
+        registry.gauge("attention_gate_ops", "attention_gate ops in the "
+                       "compiled program, by the gate's form",
+                       program=program, form=form).set(float(n))
+
+
 def count_backwards(notes: dict, program: str,
                     registry: Optional[MetricsRegistry] = None) -> None:
     """``attention_backward_total{program,stats}``: the ``fused_attention_grad``

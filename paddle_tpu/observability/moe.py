@@ -27,7 +27,9 @@ def update_moe_gauges(program_ir, program: str,
     ``row_budget`` summed with one), ``moe_shared_experts`` (expert layers
     with a shared expert beside the routed ones: ``layers.moe_ffn``'s
     ``shared_width``, read off its ``<name>_shared_gate_w [H, width]``),
-    ``moe_shared_width`` (that expert's width) and
+    ``moe_shared_width`` (that expert's width), ``moe_shared_gated`` (the
+    shared experts under a sigmoid gate a token: ``shared_gate``, read off
+    ``<name>_shared_expert_gate_w``) and
     ``short_conv_layers`` of one compiled program; nothing is set for a
     program without an expert layer, and ``short_conv_layers`` only where
     there is such a layer."""
@@ -35,7 +37,7 @@ def update_moe_gauges(program_ir, program: str,
     registry = registry or REGISTRY
     block = program_ir.global_block()
     layers = experts = held = assignments = param_bytes = convs = 0
-    budget = shared = shared_width = 0
+    budget = shared = shared_width = gated = 0
     for op in block.ops:
         if op.type == "moe_dispatch":
             layers += 1
@@ -53,6 +55,8 @@ def update_moe_gauges(program_ir, program: str,
         if param.name.endswith("_shared_gate_w"):
             shared += 1
             shared_width = int(param.shape[1])
+        elif param.name.endswith("_shared_expert_gate_w"):
+            gated += 1
     if convs:
         registry.gauge("short_conv_layers", "gated short-convolution "
                        "operators in the compiled program",
@@ -77,7 +81,9 @@ def update_moe_gauges(program_ir, program: str,
             ("moe_shared_experts", "expert layers with a shared expert "
              "beside the routed ones", shared),
             ("moe_shared_width", "width of that shared expert",
-             shared_width)):
+             shared_width),
+            ("moe_shared_gated", "shared experts under a sigmoid gate a "
+             "token", gated)):
         registry.gauge(name, help, program=program).set(float(value))
 
 

@@ -1,7 +1,8 @@
-"""State-space layer telemetry: what a compiled step's ``ssd_scan`` ops
-hold, as gauges set once per compile from the Program's static shapes
-(counts, not times), and which lowering each ``ssd_scan`` and ``short_conv``
-op took, as labelled counts added once per compile (as
+"""State-space and linear-attention layer telemetry: what a compiled step's
+``ssd_scan`` and ``gated_delta_rule`` ops hold, as gauges set once per
+compile from the Program's static shapes (counts, not times), and which
+lowering each ``ssd_scan``, ``gated_delta_rule`` and ``short_conv`` op took,
+as labelled counts added once per compile (as
 ``observability/attention.py`` counts the attention ops': the op notes its
 choice while the executor traces it, keyed by the op's salt).
 """
@@ -43,6 +44,56 @@ def update_ssm_gauges(program_ir, program: str,
             ("ssm_chunks_per_step", "sequences x chunks a sequence, all "
              "scans (a count from static shapes)", chunks)):
         registry.gauge(name, help, program=program).set(float(value))
+
+
+def update_delta_gauges(program_ir, program: str,
+                        registry: Optional[MetricsRegistry] = None) -> None:
+    """``delta_layers`` (the ``gated_delta_rule`` ops of the compiled
+    program), ``delta_heads`` (value heads), ``delta_state_bytes`` (the
+    float32 states a step carries, all layers: sequences x heads x key dim
+    x value dim x 4) and ``delta_chunks_per_step`` (sequences x chunks a
+    sequence, all layers: the ``[chunk, chunk]`` triangular inverses a head
+    builds a step); nothing is set for a program without the op."""
+    registry = registry or REGISTRY
+    block = program_ir.global_block()
+    layers = heads = state = chunks = 0
+    for op in block.ops:
+        if op.type != "gated_delta_rule":
+            continue
+        q = block.find_var_recursive(op.inputs["Q"][0])
+        v = block.find_var_recursive(op.inputs["V"][0])
+        batch, seq, heads, dv = (int(d) for d in v.shape)
+        layers += 1
+        state += batch * heads * int(q.shape[3]) * dv * 4
+        chunks += batch * (seq // min(int(op.attr("chunk")), seq))
+    if not layers:
+        return
+    for name, help, value in (
+            ("delta_layers", "gated delta rules in the compiled program",
+             layers),
+            ("delta_heads", "value heads of a gated delta rule", heads),
+            ("delta_state_bytes", "bytes of the float32 states the gated "
+             "delta rules carry, all layers (a count from static shapes)",
+             state),
+            ("delta_chunks_per_step", "sequences x chunks a sequence, all "
+             "gated delta rules (a count from static shapes)", chunks)):
+        registry.gauge(name, help, program=program).set(float(value))
+
+
+def count_delta_lowerings(notes: dict, program: str,
+                          registry: Optional[MetricsRegistry] = None) -> None:
+    """``delta_lowering_total{program,impl,chunk,heads,key_dim,value_dim}``:
+    the ``gated_delta_rule`` ops the trace just compiled, by lowering
+    (``pallas``: the kernels of ``ops/pallas_delta.py``; ``composed``: the
+    chunk form in ``jax.numpy``). ``notes`` maps each op's salt to its
+    note; nothing is added for a program without the op."""
+    registry = registry or REGISTRY
+    for (impl, chunk, heads, dk, dv), n in Counter(notes.values()).items():
+        registry.counter(
+            "delta_lowering_total",
+            "gated_delta_rule ops compiled, by the lowering each took",
+            program=program, impl=impl, chunk=str(chunk), heads=str(heads),
+            key_dim=str(dk), value_dim=str(dv)).inc(n)
 
 
 def count_lowerings(scans: dict, convs: dict, program: str,

@@ -45,7 +45,7 @@ from __future__ import annotations
 
 import functools
 
-from ..core.registry import register
+from ..core.registry import register, register_grad
 
 # m, k, n tile of the megablox kernels (chip runs, PR 26: PERF.md section 6)
 GMM_TILING = (512, 1024, 1024)
@@ -53,8 +53,9 @@ GMM_TILING = (512, 1024, 1024)
 
 @register("rms_norm")
 def rms_norm(ctx, ins):
-    """y = x / sqrt(mean(x^2, last axis) + epsilon) * Scale, computed in
-    float32 whatever x's dtype, returned in x's dtype."""
+    """y = x / sqrt(mean(x^2, last axis) + epsilon) * Scale (attr
+    ``zero_centered``: ``* (1 + Scale)``), computed in float32 whatever x's
+    dtype, returned in x's dtype."""
     import jax
     import jax.numpy as jnp
     x = ins["X"][0]
@@ -63,7 +64,8 @@ def rms_norm(ctx, ins):
                            + ctx.attr("epsilon", 1e-5))
     scale = ins.get("Scale", [None])[0]
     if scale is not None:
-        y = y * scale.astype(jnp.float32)
+        scale = scale.astype(jnp.float32)
+        y = y * (1.0 + scale if ctx.attr("zero_centered", False) else scale)
     return {"Y": [y.astype(x.dtype)]}
 
 
@@ -157,10 +159,11 @@ def swiglu(ctx, ins):
 
 @register("attention_gate")
 def attention_gate(ctx, ins):
-    """Attention's per-head output gate: ``X [B, heads, S, D]``, the heads'
-    outputs as ``fused_attention`` leaves them, times ``sigmoid(Gate [B * S,
-    heads])``, one gate a token and head (the gate's projection is by
-    token: the small array is the one turned). float32 inside. Gating the
+    """Attention's output gate: ``X [B, heads, S, D]``, the heads' outputs
+    as ``fused_attention`` leaves them, times ``sigmoid(Gate)``: ``Gate [B *
+    S, heads]`` one gate a token and head (the gate's projection is by
+    token: the small array is the one turned), ``Gate [B * S, heads * D]``
+    one a token, head and channel. float32 inside. Gating the
     kernels' layout keeps the op one pass over X: on the token-major
     ``[B * S, heads * D]`` XLA joined it with the transpose before it and
     copied float32 arrays of X's size (7.3% of the Laguna step against
@@ -168,9 +171,13 @@ def attention_gate(ctx, ins):
     import jax
     import jax.numpy as jnp
     x, gate = ins["X"][0], ins["Gate"][0]
-    B, heads, S, _ = x.shape
-    g = jax.nn.sigmoid(gate.astype(jnp.float32)).reshape(B, S, heads)
-    out = x.astype(jnp.float32) * g.transpose(0, 2, 1)[..., None]
+    B, heads, S, D = x.shape
+    g = jax.nn.sigmoid(gate.astype(jnp.float32))
+    if gate.shape[-1] == heads * D:
+        g = g.reshape(B, S, heads, D).transpose(0, 2, 1, 3)
+    else:
+        g = g.reshape(B, S, heads).transpose(0, 2, 1)[..., None]
+    out = x.astype(jnp.float32) * g
     return {"Out": [out.astype(x.dtype)]}
 
 
@@ -555,3 +562,170 @@ def ssd_scan(ctx, ins):
         return {"Y": [pallas_ssd.ssd_scan(x, dt, a, bm, cm, d, chunk,
                                           pallas_mode.interpret())]}
     return {"Y": [composed_ssd_scan(x, dt, a, bm, cm, d, chunk)]}
+
+
+def _delta_operands(q, k, g, chunk, dtype):
+    """What the chunk form reads beside ``v`` and ``beta``: q and k each
+    over its l2 norm (``x / sqrt(sum(x^2) + 1e-6)``, float32), q also over
+    ``sqrt(key dim)``, cast to ``dtype``, and the running sum of ``g`` inside
+    each chunk of ``chunk`` positions."""
+    import jax
+    import jax.numpy as jnp
+    f32 = jnp.float32
+
+    def unit(x):
+        xf = x.astype(f32)
+        return xf * jax.lax.rsqrt(
+            jnp.sum(jnp.square(xf), axis=-1, keepdims=True) + 1e-6)
+    b, s, h = g.shape
+    cum = jnp.cumsum(g.astype(f32).reshape(b, s // chunk, chunk, h), axis=2)
+    return ((unit(q) * q.shape[-1] ** -0.5).astype(dtype),
+            unit(k).astype(dtype), cum.reshape(b, s, h))
+
+
+def composed_gated_delta_rule(qn, kn, v, cum, beta, chunk):
+    """The chunk form of the gated delta rule in plain ``jax.numpy`` (HF's
+    ``torch_chunk_gated_delta_rule``; ``ops/pallas_delta.py`` has the
+    algebra), float32 throughout: ``qn`` / ``kn [B, S, key heads, d_k]`` as
+    ``_delta_operands`` leaves them, ``v [B, S, heads, d_v]``, ``cum`` /
+    ``beta [B, S, heads]`` -> ``o`` like ``v`` (float32) and the state
+    entering each chunk ``[B, chunks, heads, d_k, d_v]``. The ``[C, C]``
+    decay block and the triangular inverse of every batch, chunk and head
+    exist whole, which is what the kernels are for."""
+    import jax
+    import jax.numpy as jnp
+    from jax.scipy.linalg import solve_triangular
+    f32 = jnp.float32
+    batch, seq, heads, dv = v.shape
+    rep, dk, c = heads // qn.shape[2], qn.shape[-1], seq // chunk
+
+    def chunks_first(x, repeat=1):      # [B, S, h, ...] -> [c, B, h, C, ...]
+        x = jnp.repeat(x.astype(f32), repeat, axis=2) if repeat > 1 \
+            else x.astype(f32)
+        x = x.reshape(batch, c, chunk, *x.shape[2:])
+        return jnp.moveaxis(jnp.moveaxis(x, 3, 2), 1, 0)
+    qn, kn = chunks_first(qn, rep), chunks_first(kn, rep)   # [c, B, h, C, dk]
+    v, cum, beta = chunks_first(v), chunks_first(cum), chunks_first(beta)
+    lower = jnp.tril(jnp.ones((chunk, chunk), bool))
+    d = jnp.exp(jnp.where(lower, cum[..., :, None] - cum[..., None, :],
+                          -jnp.inf))                        # [c, B, h, C, C]
+    kk = jnp.einsum("cbhik,cbhjk->cbhij", kn, kn)
+    m = jnp.where(jnp.tril(lower, -1), kk * d, 0.0) * beta[..., None]
+    eye = jnp.eye(chunk, dtype=f32)
+    t = solve_triangular(eye + m, jnp.broadcast_to(eye, m.shape), lower=True,
+                         unit_diagonal=True)
+    p = jnp.einsum("cbhik,cbhjk->cbhij", qn, kn) * d
+    eg = jnp.exp(cum)[..., None]
+    end = cum[..., -1:, None]
+    kf = kn * jnp.exp(end - cum[..., None])
+
+    def one(s, inp):                    # s [B, h, dk, dv] enters the chunk
+        qn_c, kn_c, v_c, t_c, p_c, eg_c, kf_c, beta_c, e_end = inp
+        z = v_c - eg_c * jnp.einsum("bhik,bhkv->bhiv", kn_c, s)
+        vp = jnp.einsum("bhij,bhjv->bhiv", t_c, beta_c[..., None] * z)
+        o = eg_c * jnp.einsum("bhik,bhkv->bhiv", qn_c, s) + jnp.einsum(
+            "bhij,bhjv->bhiv", p_c, vp)
+        return e_end * s + jnp.einsum("bhik,bhiv->bhkv", kf_c, vp), (o, s)
+
+    _, (o, states) = jax.lax.scan(
+        one, jnp.zeros((batch, heads, dk, dv), f32),
+        (qn, kn, v, t, p, eg, kf, beta, jnp.exp(end)))
+    o = jnp.moveaxis(jnp.moveaxis(o, 0, 1), 2, 3)           # [B, c, C, h, dv]
+    return o.reshape(batch, seq, heads, dv), jnp.moveaxis(states, 0, 1)
+
+
+def _delta_plan(ctx, q, k, v):
+    """(whether the op lowers the kernels here, its chunk): the forward op
+    and its grad op ask alike."""
+    from . import pallas_delta, pallas_mode
+    _, seq, heads, dv = v.shape
+    key_heads, dk = q.shape[2], q.shape[3]
+    if heads % key_heads or k.shape != q.shape:
+        raise ValueError(
+            f"gated_delta_rule: {heads} value heads over {key_heads} key "
+            f"heads (q {q.shape}, k {k.shape}); the value heads must be a "
+            f"multiple of the key heads")
+    chunk = min(int(ctx.attr("chunk", 64)), seq)
+    if seq % chunk:
+        raise ValueError(
+            f"gated_delta_rule: chunk {chunk} must divide seq {seq}")
+    impl = ctx.attr("impl", "auto")
+    fits = pallas_delta.supports(seq, key_heads, heads, dk, dv, chunk)
+    if impl == "pallas" and not ctx.abstract:
+        pallas_mode.require("gated_delta_rule impl='pallas'")
+        if not fits:
+            raise ValueError(
+                f"gated_delta_rule impl='pallas' needs key and value heads "
+                f"of {pallas_delta.HEAD_DIM} and a chunk of "
+                f"{pallas_delta.CHUNKS} that divides seq; got heads of "
+                f"{dk} / {dv}, chunk={chunk}, seq={seq}")
+    return pallas_mode.lowers_kernels(impl, fits, ctx.abstract), chunk
+
+
+def _flat(x):           # [B, S, heads, d] -> [B, S, heads * d], as projected
+    return x.reshape(*x.shape[:2], -1)
+
+
+@register("gated_delta_rule", nondiff_outputs=("States",))
+def gated_delta_rule(ctx, ins):
+    """The gated delta rule of a Gated DeltaNet layer (Yang et al.,
+    arXiv:2412.06464; HF's ``torch_recurrent_gated_delta_rule``), a value
+    head at a time with state ``S [d_k, d_v]``, zero before each sequence's
+    start: ``S' = exp(g_t) S_{t-1}``, ``u_t = beta_t (v_t - S'^T k_t)``, ``S_t
+    = S' + k_t u_t^T``, ``o_t = S_t^T q_t``, with ``k_t = K_t / sqrt(sum(K_t^2)
+    + 1e-6)`` and ``q_t`` likewise over ``sqrt(d_k)``. ``Q`` / ``K [B, S, key
+    heads, d_k]``, ``V [B, S, heads, d_v]`` (value head j reads key head ``j
+    // (heads / key heads)``), ``G`` (<= 0) and ``Beta [B, S, heads]`` ->
+    ``Out`` like ``V``. Computed in chunks of ``chunk`` (attr; the sequence
+    where that is shorter) positions, which equals the recurrence in exact
+    arithmetic; the norms, the decays, their running sums and the state in
+    float32. ``States [B, chunks, heads, d_k, d_v]`` float32, the state
+    entering each chunk, is for the op's own backward and carries no
+    gradient.
+
+    Attr ``impl``: ``auto`` (default) lowers the Pallas kernels of
+    ``ops/pallas_delta.py`` where they can run (a TPU, or the test harness'
+    interpreter) and take the shapes, else ``composed_gated_delta_rule``;
+    ``pallas`` / ``composed`` force one. Which one an op took is counted at
+    each compile (``ctx.note``; observability/ssm.py)."""
+    import jax.numpy as jnp
+    from . import pallas_delta, pallas_mode
+    q, k, v, g, beta = (ins[n][0] for n in ("Q", "K", "V", "G", "Beta"))
+    kernels, chunk = _delta_plan(ctx, q, k, v)
+    ctx.note("gated_delta_rule", ("pallas" if kernels else "composed", chunk,
+                                  v.shape[2], q.shape[3], v.shape[3]))
+    if kernels:
+        qn, kn, cum = _delta_operands(q, k, g, chunk, v.dtype)
+        o, states = pallas_delta.chunked(
+            _flat(qn), _flat(kn), _flat(v), cum, beta.astype(jnp.float32),
+            chunk, pallas_mode.interpret())
+        return {"Out": [o.reshape(v.shape)], "States": [states]}
+    qn, kn, cum = _delta_operands(q, k, g, chunk, jnp.float32)
+    o, states = composed_gated_delta_rule(qn, kn, v, cum, beta, chunk)
+    return {"Out": [o.astype(v.dtype)], "States": [states]}
+
+
+@register_grad("gated_delta_rule")
+def gated_delta_rule_grad(ctx, ins, generic):
+    """dQ, dK, dV, dG, dBeta. Where the forward op took the kernels and
+    declared ``States``, the backward kernel alone on the states the
+    forward wrote: no forward is lowered here (the l2 norms and the running
+    sums again, which XLA shares with the forward's). Every other case is
+    the generic grad (``jax.vjp`` over the forward's lowering)."""
+    import jax
+    import jax.numpy as jnp
+    from . import pallas_delta, pallas_mode
+    q, k, v, g, beta = (ins[n][0] for n in ("Q", "K", "V", "G", "Beta"))
+    states, do = ins.get("States", [None])[0], ins.get("Out@GRAD", [None])[0]
+    kernels, chunk = _delta_plan(ctx, q, k, v)
+    if not kernels or states is None or do is None:
+        return generic()
+    (qn, kn, cum), back = jax.vjp(
+        lambda q, k, g: _delta_operands(q, k, g, chunk, v.dtype), q, k, g)
+    dqn, dkn, dv, dcum, dbeta = pallas_delta._bwd_call(
+        _flat(qn), _flat(kn), _flat(v), cum, beta.astype(jnp.float32),
+        states, _flat(do.astype(v.dtype)), chunk, pallas_mode.interpret())
+    dq, dk, dg = back((dqn.reshape(q.shape), dkn.reshape(k.shape), dcum))
+    return {"Q@GRAD": [dq], "K@GRAD": [dk], "V@GRAD": [dv.reshape(v.shape)],
+            "G@GRAD": [dg.astype(g.dtype)],
+            "Beta@GRAD": [dbeta.astype(beta.dtype)]}

@@ -37,6 +37,7 @@ the data files' rehearsal sizes on the CPU (a debug run: no device number).
 from __future__ import annotations
 
 import argparse
+import contextlib
 import copy
 import gc
 import json
@@ -57,7 +58,7 @@ CELL = "laguna_s_2_1.pretrain_s4096"
 
 def load_cell(args) -> dict:
     from benchmark import run
-    cell = run.load_cell(CELL, args.rehearsal)
+    cell = run.load_cell(getattr(args, "cell", CELL), args.rehearsal)
     for key in ("batch", "seq", "ring"):
         if getattr(args, key, None):
             cell["params"][key] = getattr(args, key)
@@ -176,7 +177,12 @@ def checked(s, batch, **swapped) -> dict:
     return {"ok": ok, "loss": float(loss), "each": float(each)}
 
 
-def controls(args) -> dict:
+def controls(args, without=without, mechanisms=MECHANISMS,
+             patched=lambda mechanism: contextlib.nullcontext()) -> dict:
+    """``without`` / ``mechanisms``: another cell's (``tools/qwen3_next_
+    probe.py``); ``patched(mechanism)``: a context around the build and the
+    check of that mechanism's program, for what no configuration key takes
+    out."""
     import jax.numpy as jnp
     from benchmark.jobs import common
     cell = load_cell(args)
@@ -199,10 +205,11 @@ def controls(args) -> dict:
         s, batch, params=[n + "@original" for n in params])
     for n, v in originals.items():
         s.scope.set_var(n, v)
-    for mechanism in MECHANISMS:            # a new program each: a compile
-        other = s.builder.build(without(s.model, mechanism), s.params)
-        result["no_" + mechanism] = checked(
-            s, batch, test=other["test"], check=other["check"])
+    for mechanism in mechanisms:            # a new program each: a compile
+        with patched(mechanism):
+            other = s.builder.build(without(s.model, mechanism), s.params)
+            result["no_" + mechanism] = checked(
+                s, batch, test=other["test"], check=other["check"])
     for name, got in result.items():
         if isinstance(got, dict):
             say(f"{name}: {got}" + ("" if name == "as_it_is" else
@@ -211,11 +218,12 @@ def controls(args) -> dict:
     return result
 
 
-def gradients(args) -> dict:
+def gradients(args, reference=None) -> dict:
     import jax
     import jax.numpy as jnp
     from benchmark.jobs import common
-    from benchmark.references import laguna_pretrain as reference
+    if reference is None:
+        from benchmark.references import laguna_pretrain as reference
     cell = load_cell(args)
     s = common.Session(cell, args.seed, say)
     built, model = s.built, s.model
@@ -357,9 +365,14 @@ def kernels(args) -> dict:
             "composed_temp_gb": temp.temp_size_in_bytes / 1e9}
 
 
-def main(argv=None) -> int:
-    ap = argparse.ArgumentParser(description=__doc__.splitlines()[0])
-    ap.add_argument("mode", choices=("load", "controls", "grads", "kernels"))
+def main(argv=None, modes=None, doc=__doc__, options=None) -> int:
+    """``modes``: another cell's functions by mode, in this file's place,
+    and ``options(parser)``: its own arguments and defaults
+    (``tools/qwen3_next_probe.py``)."""
+    modes = modes or {"load": held_shares, "controls": controls,
+                      "grads": gradients, "kernels": kernels}
+    ap = argparse.ArgumentParser(description=doc.splitlines()[0])
+    ap.add_argument("mode", choices=tuple(modes))
     ap.add_argument("--seed", type=int, default=2147480039)
     ap.add_argument("--steps", type=int, default=200)
     ap.add_argument("--batch", type=int)
@@ -373,11 +386,12 @@ def main(argv=None) -> int:
     ap.add_argument("--rehearsal", action="store_true")
     ap.add_argument("--out", default=None,
                     help="append the result as one JSON line to this file")
+    if options:
+        options(ap)
     args = ap.parse_args(argv)
     from paddle_tpu.utils import compile_cache
     compile_cache.arm()
-    result = {"load": held_shares, "controls": controls,
-              "grads": gradients, "kernels": kernels}[args.mode](args)
+    result = modes[args.mode](args)
     line = json.dumps(result)
     print(line if args.mode != "grads" else json.dumps({
         k: ({a: b for a, b in v.items() if a != "leaves"}
