@@ -738,6 +738,10 @@ class Executor:
         from ..observability import loss as _obs_loss
         _obs_loss.count_backwards(program._lowering_notes.pop(
             "softmax_with_cross_entropy_grad", {}), label)
+        from ..observability import rotary as _obs_rotary
+        _obs_rotary.count_lowerings(
+            program._lowering_notes.pop("rotary_embedding", {}),
+            program._lowering_notes.pop("rotary_embedding_grad", {}), label)
         from ..observability import masks as _obs_masks
         # takes the kinds it counts (mask_draw, gather_layout) out of them
         _obs_masks.count_data_axis(program._lowering_notes, label)

@@ -45,6 +45,10 @@ reproduction gets the counterpart the whole-program-jit design enables:
 - ``loss`` -- ``loss_backward_total{program,form}``: the form each
   ``softmax_with_cross_entropy_grad`` op of a compiled program took
   (``written`` over the logits / ``fused`` / ``generic``).
+- ``rotary`` -- ``rotary_lowering_total{program,direction,form}``: the form
+  each ``rotary_embedding`` op of a compiled program and each of its grad
+  ops took (``kernel``: one pass in, one pass out / ``composed`` /
+  ``generic``).
 
 Render everything with ``python -m tools.obs_report``.
 """

@@ -96,22 +96,18 @@ def yarn_inv_freq(theta, dim, factor, original_max_position, beta_fast,
     return base / factor * (1.0 - keep) + base * keep
 
 
-@register("rotary_embedding")
-def rotary_embedding(ctx, ins):
-    """Rotary position embedding in the rotate-half convention over
-    ``X [..., S, D]``, positions 0..S-1 along axis -2:
-    ``x * cos + concat(-x[D/2:], x[:D/2]) * sin`` with angle
-    ``pos * theta^(-2i/D)`` for both halves' element i. float32 inside.
+#: widest head whose grad op lowers the kernel: at D=256 (`qwen3_next`) XLA
+#: schedules the layer's weight-gradient products after the next layer's
+#: backward when the cotangent's rotation is a kernel, and the step's
+#: temporaries grow by 0.29 GB (PERF.md section 6, PR 42)
+ROTARY_GRAD_KERNEL_MAX_DIM = 128
 
-    Attr ``rotary_dim`` (0: all of D): only the first ``rotary_dim`` values
-    of a row are rotated, among themselves (D stands for ``rotary_dim``
-    above), and the rest pass through. Attr ``scaling="yarn"``: the
-    frequencies are ``yarn_inv_freq``'s (attrs ``factor``,
-    ``original_max_position``, ``beta_fast``, ``beta_slow``) and cos and sin
-    are multiplied by ``attention_factor``."""
+
+def _rotary_tables(ctx, S, D):
+    """float32 ``cos``, ``sin [S, rotary_dim]`` and ``rotary_dim`` from the
+    op's attrs: angle ``pos * inv_freq_i`` for both halves' element i, the
+    sign of the rotate-half in ``sin``'s first half."""
     import jax.numpy as jnp
-    x = ins["X"][0]
-    S, D = x.shape[-2], x.shape[-1]
     rot = int(ctx.attr("rotary_dim", 0)) or D
     if rot > D or rot % 2:
         raise ValueError(f"rotary_embedding: rotary_dim={rot} of {D}")
@@ -133,12 +129,67 @@ def rotary_embedding(ctx, ins):
     if scaling:
         factor = float(ctx.attr("attention_factor", 1.0))
         cos, sin = cos * factor, sin * factor
+    return cos, sin, rot
+
+
+def _rotary_pass(ctx, x, backward):
+    """The op's one pass over ``x [..., S, D]``, forward or (``backward``:
+    x is the cotangent, sin's sign turned) the transpose of it; float32
+    inside, one rounding to x's dtype. The Pallas kernel of
+    ``ops/pallas_rope.py`` where it can run and takes the shape
+    (``supports``; off a mesh, which cannot partition a Mosaic call; a grad
+    op up to ``ROTARY_GRAD_KERNEL_MAX_DIM``), else the rotation composed in
+    ``jax.numpy``. Which it was is noted for
+    ``rotary_lowering_total`` (observability/rotary.py)."""
+    import jax.numpy as jnp
+    from . import pallas_mode, pallas_rope
+    S, D = x.shape[-2:]
+    cos, sin, rot = _rotary_tables(ctx, S, D)
+    if backward:
+        sin = -sin
+    fits = (pallas_rope.supports(S, D) and ctx.mesh is None
+            and ctx.gspmd_mesh is None
+            and not (backward and D > ROTARY_GRAD_KERNEL_MAX_DIM))
+    kernel = pallas_mode.lowers_kernels("auto", fits, ctx.abstract)
+    ctx.note("rotary_embedding_grad" if backward else "rotary_embedding",
+             "kernel" if kernel else "composed")
+    if kernel:
+        return pallas_rope.rotate(x, cos, sin, rot, pallas_mode.interpret())
+    # a slice, a roll of its lanes by half, a concatenate for the tail: what
+    # XLA fuses best of the forms of this expression (PERF.md section 6)
     xf = (x if rot == D else x[..., :rot]).astype(jnp.float32)
-    # concat(-x2, x1) as a rotation of the lanes by rot/2 with the sign in sin
     out = (xf * cos + jnp.roll(xf, rot // 2, axis=-1) * sin).astype(x.dtype)
-    if rot < D:
-        out = jnp.concatenate([out, x[..., rot:]], axis=-1)
-    return {"Out": [out]}
+    return out if rot == D else jnp.concatenate([out, x[..., rot:]], axis=-1)
+
+
+@register("rotary_embedding")
+def rotary_embedding(ctx, ins):
+    """Rotary position embedding in the rotate-half convention over
+    ``X [..., S, D]``, positions 0..S-1 along axis -2:
+    ``x * cos + concat(-x[D/2:], x[:D/2]) * sin`` with angle
+    ``pos * theta^(-2i/D)`` for both halves' element i. float32 inside.
+
+    Attr ``rotary_dim`` (0: all of D): only the first ``rotary_dim`` values
+    of a row are rotated, among themselves (D stands for ``rotary_dim``
+    above), and the rest pass through. Attr ``scaling="yarn"``: the
+    frequencies are ``yarn_inv_freq``'s (attrs ``factor``,
+    ``original_max_position``, ``beta_fast``, ``beta_slow``) and cos and sin
+    are multiplied by ``attention_factor``. One pass over X:
+    ``_rotary_pass``."""
+    return {"Out": [_rotary_pass(ctx, ins["X"][0], backward=False)]}
+
+
+@register_grad("rotary_embedding")
+def rotary_embedding_grad(ctx, ins, generic):
+    """dX. The op is linear in X and orthogonal a position, so its transpose
+    is the same pass over the cotangent with the sign of sin turned: it
+    reads ``Out@GRAD`` alone (not X, not Out) and lowers no forward. A grad
+    op without a cotangent is ``generic``."""
+    g = ins.get("Out@GRAD", [None])[0]
+    if g is None:
+        ctx.note("rotary_embedding_grad", "generic")
+        return generic()
+    return {"X@GRAD": [_rotary_pass(ctx, g, backward=True)]}
 
 
 @register("swiglu")

@@ -4,6 +4,7 @@ gradient under ``append_backward`` against ``jax.grad`` of that form, and the
 dropless expert layer against the dense form in which every expert is applied
 to every token and masked by the router's choice."""
 import jax
+import jax.extend.core
 import jax.numpy as jnp
 import numpy as np
 import pytest
@@ -102,6 +103,179 @@ def test_rotary_embedding_matches_rotate_half_and_gradient():
     np.testing.assert_allclose(out[:, :, 0], x[:, :, 0], atol=1e-6)
     pair = lambda t: t[..., :8] ** 2 + t[..., 8:] ** 2      # noqa: E731
     np.testing.assert_allclose(pair(out), pair(x), rtol=1e-4, atol=1e-5)
+
+
+# the one-pass rotary lowering (PR 42): ops/pallas_rope.py's kernel (in the
+# harness' interpreter here) and the composed form of the same expression
+
+def _rotary_attrs(D, rot, scaling, **more):
+    attrs = {"theta": 10000.0, "rotary_dim": 0 if rot == D else rot, **more}
+    if scaling == "yarn":
+        attrs.update(scaling="yarn", factor=8.0, original_max_position=16.0,
+                     attention_factor=1.2)
+    return attrs
+
+
+def parents_rotary(x, cos, sin, rot):
+    """The lowering before PR 42, on the same tables: a slice, a roll of the
+    lanes by half, a concatenate for the tail."""
+    D = x.shape[-1]
+    xf = (x if rot == D else x[..., :rot]).astype(jnp.float32)
+    out = (xf * cos + jnp.roll(xf, rot // 2, axis=-1) * sin).astype(x.dtype)
+    return out if rot == D else jnp.concatenate([out, x[..., rot:]], -1)
+
+
+def rotate_half_reference(x, cos, sin, rot):
+    """x * cos + concat(-x2, x1) * sin in float64 over the first ``rot``
+    (``sin``'s second half is the unsigned one)."""
+    x = np.asarray(x, np.float64)
+    cos, sin = np.asarray(cos, np.float64), np.asarray(sin, np.float64)
+    head, h = x[..., :rot], rot // 2
+    turned = np.concatenate([-head[..., h:], head[..., :h]], -1)
+    out = head * cos + turned * np.concatenate([sin[:, h:], sin[:, h:]], -1)
+    return np.concatenate([out, x[..., rot:]], -1)
+
+
+def _bits(a):
+    return np.asarray(jnp.asarray(a).astype(jnp.float32))
+
+
+@pytest.mark.parametrize("dtype", ["bfloat16", "float32"])
+@pytest.mark.parametrize("scaling", ["", "yarn"])
+@pytest.mark.parametrize("part", [1, 2, 4])
+@pytest.mark.parametrize("D", [64, 128, 256])
+def test_rotary_one_pass_forms_against_the_parents_expression(
+        D, part, scaling, dtype, monkeypatch):
+    """Forward and registered grad of both lowerings (the kernel, the
+    composed form) against the rotate-half reference, the parent's
+    expression and ``jax.vjp`` of it, and the generic grad lowering on the
+    same ``ins``. The composed lowering, evaluated op by op as the parent's
+    expression is here, equals it to the bit in either dtype; the kernel,
+    which XLA's CPU backend compiles as one fusion (it may contract a
+    product into the sum), to the bit on tables of 8 significant bits,
+    where every product of a bfloat16 value is exact -- and to a rounding on
+    the op's own."""
+    from paddle_tpu.core import registry
+    from paddle_tpu.ops import decoder_ops, pallas_mode, pallas_rope
+    S, rot = 32, D // part
+    x = jnp.asarray(rng(D + part).randn(2, 3, S, D), dtype)
+    g = jnp.asarray(rng(7).randn(2, 3, S, D), dtype)
+    ctx = registry.LowerCtx(_rotary_attrs(D, rot, scaling))
+    cos, sin, got_rot = decoder_ops._rotary_tables(ctx, S, D)
+    assert got_rot == rot and cos.shape == sin.shape == (S, rot)
+    want = parents_rotary(x, cos, sin, rot)
+    want_g = jax.vjp(lambda v: parents_rotary(v, cos, sin, rot), x)[1](g)[0]
+    one = 2.0 ** -8 if dtype == "bfloat16" else 1e-6        # a rounding
+    reference = rotate_half_reference(x, cos, sin, rot)
+    scale = float(np.abs(reference).max())
+    attrs = _rotary_attrs(D, rot, scaling)
+    for kernel in (True, False):     # the interpreter stands in, or is off
+        monkeypatch.setattr(pallas_mode, "TEST_INTERPRET", kernel)
+        ins = {"X": [x], "Out@GRAD": [g]}
+        out = registry.get("rotary_embedding").lower(
+            registry.LowerCtx(attrs), {"X": [x]})["Out"][0]
+        grad_ctx = registry.LowerCtx(dict(attrs, __fwd_out_slots__=["Out"]))
+        dx = registry.get("rotary_embedding_grad").lower(
+            grad_ctx, dict(ins, Out=[out]))["X@GRAD"][0]
+        generic = registry._generic_grad_lower(
+            registry.get("rotary_embedding"), grad_ctx,
+            dict(ins, Out=[out]))["X@GRAD"][0]
+        assert out.dtype == dx.dtype == generic.dtype == x.dtype
+        np.testing.assert_allclose(_bits(out), reference, atol=one * scale,
+                                   rtol=0)
+        np.testing.assert_allclose(_bits(out), _bits(want), atol=one * scale,
+                                   rtol=0)
+        np.testing.assert_allclose(_bits(dx), _bits(want_g),
+                                   atol=one * scale, rtol=0)
+        np.testing.assert_allclose(_bits(dx), _bits(generic),
+                                   atol=one * scale, rtol=0)
+        # the values past rotary_dim pass through untouched, bit for bit
+        np.testing.assert_array_equal(_bits(out[..., rot:]),
+                                      _bits(x[..., rot:]))
+        np.testing.assert_array_equal(_bits(dx[..., rot:]),
+                                      _bits(g[..., rot:]))
+        if not kernel:
+            np.testing.assert_array_equal(_bits(out), _bits(want))
+            np.testing.assert_array_equal(_bits(dx), _bits(want_g))
+    if dtype == "bfloat16":
+        short = lambda t: t.astype(jnp.bfloat16).astype(    # noqa: E731
+            jnp.float32)
+        c8, s8 = short(cos), short(sin)
+        np.testing.assert_array_equal(
+            _bits(pallas_rope.rotate(x, c8, s8, rot, True)),
+            _bits(parents_rotary(x, c8, s8, rot)))
+        np.testing.assert_array_equal(
+            _bits(jax.vjp(lambda v: pallas_rope.rotate(v, c8, s8, rot, True),
+                          x)[1](g)[0]),
+            _bits(jax.vjp(lambda v: parents_rotary(v, c8, s8, rot),
+                          x)[1](g)[0]))
+
+
+def rotary_lowering_counts():
+    from paddle_tpu.observability.metrics import REGISTRY
+    out = {}
+    for k, c in (REGISTRY.get("rotary_lowering_total") or {}).items():
+        key = (dict(k)["direction"], dict(k)["form"])
+        out[key] = out.get(key, 0) + c.value
+    return out
+
+
+@pytest.mark.parametrize("shape,interpreter,form", [
+    ((2, 2, 16, 64), True, "kernel"),       # the interpreter stands in
+    ((2, 2, 16, 64), False, "composed"),    # off a TPU
+    ((2, 2, 6, 8), True, "composed")])      # a shape the kernel leaves
+def test_rotary_lowering_total_counts_one_forward_and_one_backward(
+        shape, interpreter, form, monkeypatch):
+    from paddle_tpu.ops import pallas_mode
+    monkeypatch.setattr(pallas_mode, "TEST_INTERPRET", interpreter)
+    x = rng().randn(*shape).astype("float32")
+    before = rotary_lowering_counts()
+    run_with_grads(lambda xv: layers.rotary_embedding(xv), {"x": x}, ["x"])
+    now = rotary_lowering_counts()
+    assert {k: v - before.get(k, 0) for k, v in now.items()
+            if v != before.get(k, 0)} == {("forward", form): 1,
+                                          ("backward", form): 1}
+
+
+def test_rotary_grad_op_of_a_decoder_program_lowers_no_forward():
+    """The grad op a decoder Program's backward holds reads its cotangent
+    alone: traced with X, Out and the cotangent as arguments, its jaxpr
+    uses neither X nor Out (no second rotation of X, no residual), and it
+    is one pass: a single kernel call with a single rotation in it."""
+    from paddle_tpu.core import registry
+    from paddle_tpu.models import decoder_lm
+    cfg = {"hidden_size": 128, "num_hidden_layers": 1,
+           "num_attention_heads": 2, "num_key_value_heads": 2,
+           "num_experts": 4, "num_experts_per_tok": 2, "intermediate_size": 32,
+           "vocab_size": 64, "hidden_act": "silu", "rms_norm_eps": 1e-5,
+           "rope_theta": 10000, "norm_topk_prob": False,
+           "tie_word_embeddings": False, "dtype": "bfloat16"}
+    main, startup = fluid.Program(), fluid.Program()
+    with fluid.unique_name.guard(), fluid.program_guard(main, startup):
+        A = dict(append_batch_size=False)
+        out = decoder_lm.build(cfg, fluid.data("ids", [2, 16], "int64", **A),
+                               fluid.data("labels", [32, 1], "int64", **A))
+        fluid.append_backward(out["loss"])
+    ops = main.global_block().ops
+    grads = [op for op in ops if op.type == "rotary_embedding_grad"]
+    assert len(grads) == len(
+        [op for op in ops if op.type == "rotary_embedding"]) == 2
+    for op in grads:
+        shape = [int(d) for d in main.global_block().var(
+            op.input("X")[0]).shape]
+        ctx = registry.LowerCtx(dict(op.attrs))
+        spec = jax.ShapeDtypeStruct(tuple(shape), jnp.bfloat16)
+        jaxpr = jax.make_jaxpr(
+            lambda X, Out, G: registry.get(op.type).lower(
+                ctx, {"X": [X], "Out": [Out], "Out@GRAD": [G]})["X@GRAD"][0]
+        )(spec, spec, spec)
+        x_in, out_in, g_in = jaxpr.jaxpr.invars
+        used = {v for eqn in jaxpr.jaxpr.eqns for v in eqn.invars
+                if isinstance(v, jax.extend.core.Var)}
+        assert x_in not in used and out_in not in used and g_in in used
+        # one kernel, one rotation of the lanes: the cotangent's
+        text = str(jaxpr)
+        assert text.count("pallas_call[") == 1 and text.count("roll[") == 1
 
 
 def test_swiglu_matches_its_one_line_form_and_gradient():

@@ -423,6 +423,36 @@ def test_held_expert_matmul_compiles_for_v5e(one_chip, as_on_the_chip, k, n):
     assert _kernels(back.compile()) == 2
 
 
+@pytest.mark.parametrize("shape,rot", [
+    ((4, 16, 4096, 128), 128),      # olmoe's q and k
+    ((1, 48, 4096, 128), 64),       # laguna's full-attention q: half rotated
+    ((4, 32, 4096, 64), 64),        # lfm2's q: half a vreg of lanes
+    ((2, 16, 4096, 256), 64)])      # qwen3_next's q: a quarter of two vregs
+def test_rotary_kernel_compiles_for_v5e(one_chip, shape, rot):
+    """The one-pass rotary kernel at the four decoder cells' shapes: one
+    kernel forward, one for the cotangent (the same pass, no forward under
+    it), and nothing of the array's size beside them -- no float32 copy, no
+    half-width array."""
+    from paddle_tpu.ops import pallas_rope
+    seq, dim = shape[-2:]
+    assert pallas_rope.supports(seq, dim)
+    x = jax.ShapeDtypeStruct(shape, jnp.bfloat16, sharding=one_chip)
+    table = jax.ShapeDtypeStruct((seq, rot), jnp.float32, sharding=one_chip)
+
+    def forward(x, cos, sin):
+        return pallas_rope.rotate(x, cos, sin, rot, False)
+
+    def backward(g, cos, sin):
+        x = jnp.zeros(shape, jnp.bfloat16)
+        return jax.vjp(lambda v: forward(v, cos, sin), x)[1](g)[0]
+    for fn in (forward, backward):
+        compiled = jax.jit(fn).lower(x, table, table).compile()
+        assert _kernels(compiled) == 1
+        text = compiled.as_text()
+        assert f"f32[{','.join(map(str, shape))}]" not in text
+        assert f",{seq},{rot // 2}]" not in text
+
+
 def _captured_step(main, feed, fetch, scope):
     """The jitted train step of ``main`` and its arguments, taken from the
     executor where it would compile them."""
@@ -504,7 +534,10 @@ def test_expert_layer_step_holds_nine_grouped_matmuls_and_one_sort_by_expert(
         r'op_name="[^"]*/' + scope + r'#\d+/', ln) is not None
     assert sum(in_scope(ln, "moe_expert_matmul") for ln in kernels) == 3
     assert sum(in_scope(ln, "moe_expert_matmul_grad") for ln in kernels) == 6
-    assert len(kernels) == 9            # S=128: attention is XLA's here
+    # q's and k's rotation, and the same pass over their cotangents (PR 42)
+    assert sum(in_scope(ln, "rotary_embedding") for ln in kernels) == 2
+    assert sum(in_scope(ln, "rotary_embedding_grad") for ln in kernels) == 2
+    assert len(kernels) == 13           # S=128: attention is XLA's here
     # the stable sort by expert, once; XLA's TPU top_k is a sort too (the
     # router's), and neither is traced a second time into the step by the
     # grad ops
