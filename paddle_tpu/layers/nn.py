@@ -1246,15 +1246,35 @@ def gated_delta_rule(q, k, v, g, beta, chunk=64, impl="auto", name=None):
     chunked form elsewhere; ``"pallas"`` / ``"composed"`` force one). The op
     has a second output, ``States``: the state entering each chunk, for its
     own backward (no gradient flows through it; the layer returns ``Out``)."""
+    return _gated_delta_rule_op(
+        {"Q": [q], "K": [k], "V": [v], "G": [g], "Beta": [beta]}, v.dtype,
+        {"chunk": int(chunk), "impl": impl}, name)
+
+
+def _gated_delta_rule_op(inputs, dtype, attrs, name):
     helper = LayerHelper("gated_delta_rule", name=name)
-    out = _out(helper, v.dtype)
+    out = _out(helper, dtype)
     states = _out(helper, "float32", stop_gradient=True)
-    helper.append_op("gated_delta_rule",
-                     inputs={"Q": [q], "K": [k], "V": [v], "G": [g],
-                             "Beta": [beta]},
+    helper.append_op("gated_delta_rule", inputs=inputs,
                      outputs={"Out": [out], "States": [states]},  # Out first
-                     attrs={"chunk": int(chunk), "impl": impl})
+                     attrs=attrs)
     return _var(helper, out)
+
+
+def gated_delta_rule_packed(qkv, g, beta, key_heads, key_dim, chunk=64,
+                            impl="auto", name=None):
+    """``gated_delta_rule`` over q, k and v as one array ``qkv [B, S, 2 *
+    key_heads * key_dim + values]`` (q | k | v along the columns, as one
+    projection and a short convolution over it write them); the value heads
+    are ``g``'s and share what is left of the columns. The same op with one
+    operand in the three's place: the Pallas kernels read the array where
+    it lies, each head by its column offset, so no q, k or v is cut out of
+    it (``ops/pallas_delta.py``); the composed form reads column ranges.
+    Returns ``o [B, S, heads, value dim]``."""
+    return _gated_delta_rule_op(
+        {"QKV": [qkv], "G": [g], "Beta": [beta]}, qkv.dtype,
+        {"chunk": int(chunk), "impl": impl, "key_heads": int(key_heads),
+         "key_dim": int(key_dim)}, name)
 
 
 def moe_bias_update(bias, load, rate, name=None):

@@ -446,9 +446,9 @@ def delta_net(x, cfg: dict, batch: int, seq: int, name: str):
     | k | v]))``, one causal depthwise filter of ``linear_conv_kernel_dim``
     taps without a bias; a value head each, ``beta = sigmoid(b)`` and ``g =
     -exp(A_log) softplus(alpha + dt_bias)`` in float32; the gated delta rule
-    over unit q and k (``layers.gated_delta_rule``, in chunks of ``delta_
-    chunk_size``, default 64, lowered as ``delta_rule_impl`` says, default
-    ``auto``); ``W_out (rmsnorm(o) * silu(z))``, the norm over a head's
+    over unit q and k (``layers.gated_delta_rule_packed``: the conv's output
+    whole, no q, k or v cut out of it; in chunks of ``delta_chunk_size``,
+    default 64, lowered as ``delta_rule_impl`` says, default ``auto``); ``W_out (rmsnorm(o) * silu(z))``, the norm over a head's
     values with one plain scale of head size shared by the heads, before the
     gate (a Mamba mixer gates first). ``A_log`` and ``dt_bias`` are float32
     and start as ``mamba``'s do (HF's constructor writes ``dt_bias = 1``,
@@ -464,20 +464,19 @@ def delta_net(x, cfg: dict, batch: int, seq: int, name: str):
     taps = cfg["linear_conv_kernel_dim"]
     qkv = layers.short_conv(qkv, seq, taps, _conv_attr(name + "_conv_w", taps),
                             gated=False, activation="silu")
-    q, k, v = layers.split(qkv, [keys, keys, values], dim=-1)
     dt_bias, a_log = _dt_bias_and_a_log(name, n_v)
     g = layers.elementwise_mul(
         layers.softplus(layers.elementwise_add(
             layers.cast(alpha, "float32"), dt_bias)),
         layers.scale(layers.exp(a_log), -1.0))
-    o = layers.gated_delta_rule(
-        layers.reshape(q, [batch, seq, n_k, d_k]),
-        layers.reshape(k, [batch, seq, n_k, d_k]),
-        layers.reshape(v, [batch, seq, n_v, d_v]),
+    # q | k | v stays one array: the rule's kernels read each head where the
+    # conv wrote it
+    o = layers.gated_delta_rule_packed(
+        layers.reshape(qkv, [batch, seq, 2 * keys + values]),
         layers.reshape(g, [batch, seq, n_v]),
         layers.reshape(layers.sigmoid(layers.cast(b, "float32")),
                        [batch, seq, n_v]),
-        chunk=cfg.get("delta_chunk_size", 64),
+        n_k, d_k, chunk=cfg.get("delta_chunk_size", 64),
         impl=cfg.get("delta_rule_impl", "auto"))
     o = layers.rms_norm(layers.reshape(o, [batch * seq, n_v, d_v]),
                         _eps(cfg), ParamAttr(name=name + "_gated_norm_w"))

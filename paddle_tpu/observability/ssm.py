@@ -60,11 +60,13 @@ def update_delta_gauges(program_ir, program: str,
     for op in block.ops:
         if op.type != "gated_delta_rule":
             continue
-        q = block.find_var_recursive(op.inputs["Q"][0])
-        v = block.find_var_recursive(op.inputs["V"][0])
-        batch, seq, heads, dv = (int(d) for d in v.shape)
+        # from the outputs: the op reads q, k, v in either operand form
+        batch, seq = (int(d) for d in block.find_var_recursive(
+            op.outputs["Out"][0]).shape[:2])
+        heads, dk, dv = (int(d) for d in block.find_var_recursive(
+            op.outputs["States"][0]).shape[2:])
         layers += 1
-        state += batch * heads * int(q.shape[3]) * dv * 4
+        state += batch * heads * dk * dv * 4
         chunks += batch * (seq // min(int(op.attr("chunk")), seq))
     if not layers:
         return
@@ -82,18 +84,23 @@ def update_delta_gauges(program_ir, program: str,
 
 def count_delta_lowerings(notes: dict, program: str,
                           registry: Optional[MetricsRegistry] = None) -> None:
-    """``delta_lowering_total{program,impl,chunk,heads,key_dim,value_dim}``:
-    the ``gated_delta_rule`` ops the trace just compiled, by lowering
-    (``pallas``: the kernels of ``ops/pallas_delta.py``; ``composed``: the
-    chunk form in ``jax.numpy``). ``notes`` maps each op's salt to its
-    note; nothing is added for a program without the op."""
+    """``delta_lowering_total{program,impl,chunk,heads,key_dim,value_dim,
+    operands}``: the ``gated_delta_rule`` ops the trace just compiled, by
+    lowering (``pallas``: the kernels of ``ops/pallas_delta.py``;
+    ``composed``: the chunk form in ``jax.numpy``) and by operand form
+    (``packed``: the kernels read q, k and v in place in the one array the
+    op was given; ``split``: three operands, given or cut out of it).
+    ``notes`` maps each op's salt to its note; nothing is added for a
+    program without the op."""
     registry = registry or REGISTRY
-    for (impl, chunk, heads, dk, dv), n in Counter(notes.values()).items():
+    for (impl, chunk, heads, dk, dv, form), n in Counter(
+            notes.values()).items():
         registry.counter(
             "delta_lowering_total",
-            "gated_delta_rule ops compiled, by the lowering each took",
+            "gated_delta_rule ops compiled, by the lowering and the operand "
+            "form each took",
             program=program, impl=impl, chunk=str(chunk), heads=str(heads),
-            key_dim=str(dk), value_dim=str(dv)).inc(n)
+            key_dim=str(dk), value_dim=str(dv), operands=form).inc(n)
 
 
 def count_lowerings(scans: dict, convs: dict, program: str,

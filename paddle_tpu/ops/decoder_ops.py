@@ -615,23 +615,27 @@ def ssd_scan(ctx, ins):
     return {"Y": [composed_ssd_scan(x, dt, a, bm, cm, d, chunk)]}
 
 
-def _delta_operands(q, k, g, chunk, dtype):
-    """What the chunk form reads beside ``v`` and ``beta``: q and k each
-    over its l2 norm (``x / sqrt(sum(x^2) + 1e-6)``, float32), q also over
-    ``sqrt(key dim)``, cast to ``dtype``, and the running sum of ``g`` inside
-    each chunk of ``chunk`` positions."""
-    import jax
+def _chunk_sums(g, chunk):
+    """The running sum of ``g [B, S, heads]`` inside each chunk of ``chunk``
+    positions, float32."""
     import jax.numpy as jnp
-    f32 = jnp.float32
-
-    def unit(x):
-        xf = x.astype(f32)
-        return xf * jax.lax.rsqrt(
-            jnp.sum(jnp.square(xf), axis=-1, keepdims=True) + 1e-6)
     b, s, h = g.shape
-    cum = jnp.cumsum(g.astype(f32).reshape(b, s // chunk, chunk, h), axis=2)
-    return ((unit(q) * q.shape[-1] ** -0.5).astype(dtype),
-            unit(k).astype(dtype), cum.reshape(b, s, h))
+    return jnp.cumsum(g.astype(jnp.float32).reshape(b, s // chunk, chunk, h),
+                      axis=2).reshape(b, s, h)
+
+
+def _delta_operands(q, k, g, chunk, dtype):
+    """What the composed chunk form reads beside ``v`` and ``beta``: q and k
+    each over its l2 norm (``pallas_delta.unit``, float32), q also over
+    ``sqrt(key dim)``, cast to ``dtype``, and the running sum of ``g`` inside
+    each chunk. The kernels form the same unit q and k in VMEM."""
+    import jax.numpy as jnp
+    from . import pallas_delta
+    f32 = jnp.float32
+    return (pallas_delta.unit(q.astype(f32), q.shape[-1] ** -0.5)
+            .astype(dtype),
+            pallas_delta.unit(k.astype(f32)).astype(dtype),
+            _chunk_sums(g, chunk))
 
 
 def composed_gated_delta_rule(qn, kn, v, cum, beta, chunk):
@@ -685,9 +689,32 @@ def composed_gated_delta_rule(qn, kn, v, cum, beta, chunk):
     return o.reshape(batch, seq, heads, dv), jnp.moveaxis(states, 0, 1)
 
 
-def _delta_plan(ctx, q, k, v):
-    """(whether the op lowers the kernels here, its chunk): the forward op
-    and its grad op ask alike."""
+def _delta_inputs(ctx, ins):
+    """(q, k [B, S, key heads, d_k], v [B, S, heads, d_v], g, beta, the
+    packed ``q | k | v [B, S, 2 keys + values]`` or None): an op given
+    ``QKV`` (attrs ``key_heads``, ``key_dim``; the value heads are ``G``'s)
+    reads q, k and v as column ranges of it."""
+    g, beta = ins["G"][0], ins["Beta"][0]
+    if "QKV" not in ins:
+        return ins["Q"][0], ins["K"][0], ins["V"][0], g, beta, None
+    qkv = ins["QKV"][0]
+    n_k, d_k = int(ctx.attr("key_heads")), int(ctx.attr("key_dim"))
+    (b, s, wide), heads, keys = qkv.shape, g.shape[2], n_k * d_k
+    d_v, rest = divmod(wide - 2 * keys, heads)
+    if rest or d_v <= 0:
+        raise ValueError(
+            f"gated_delta_rule: a packed q | k | v of {wide} columns is not "
+            f"2 x {n_k} key heads of {d_k} and {heads} value heads")
+    return (qkv[..., :keys].reshape(b, s, n_k, d_k),
+            qkv[..., keys:2 * keys].reshape(b, s, n_k, d_k),
+            qkv[..., 2 * keys:].reshape(b, s, heads, d_v), g, beta, qkv)
+
+
+def _delta_plan(ctx, q, k, v, qkv):
+    """(what the kernels read of q, k and v where the op lowers them here,
+    else None -- the packed array itself where they can address it in
+    place, else three flat operands --, the op's chunk): the forward op and
+    its grad op ask alike."""
     from . import pallas_delta, pallas_mode
     _, seq, heads, dv = v.shape
     key_heads, dk = q.shape[2], q.shape[3]
@@ -710,7 +737,11 @@ def _delta_plan(ctx, q, k, v):
                 f"of {pallas_delta.HEAD_DIM} and a chunk of "
                 f"{pallas_delta.CHUNKS} that divides seq; got heads of "
                 f"{dk} / {dv}, chunk={chunk}, seq={seq}")
-    return pallas_mode.lowers_kernels(impl, fits, ctx.abstract), chunk
+    if not pallas_mode.lowers_kernels(impl, fits, ctx.abstract):
+        return None, chunk
+    if qkv is not None and pallas_delta.packs(key_heads, heads):
+        return qkv, chunk
+    return (_flat(q), _flat(k), _flat(v)), chunk
 
 
 def _flat(x):           # [B, S, heads, d] -> [B, S, heads * d], as projected
@@ -726,30 +757,37 @@ def gated_delta_rule(ctx, ins):
     = S' + k_t u_t^T``, ``o_t = S_t^T q_t``, with ``k_t = K_t / sqrt(sum(K_t^2)
     + 1e-6)`` and ``q_t`` likewise over ``sqrt(d_k)``. ``Q`` / ``K [B, S, key
     heads, d_k]``, ``V [B, S, heads, d_v]`` (value head j reads key head ``j
-    // (heads / key heads)``), ``G`` (<= 0) and ``Beta [B, S, heads]`` ->
-    ``Out`` like ``V``. Computed in chunks of ``chunk`` (attr; the sequence
-    where that is shorter) positions, which equals the recurrence in exact
-    arithmetic; the norms, the decays, their running sums and the state in
-    float32. ``States [B, chunks, heads, d_k, d_v]`` float32, the state
-    entering each chunk, is for the op's own backward and carries no
-    gradient.
+    // (heads / key heads)``), or the three as one ``QKV [B, S, 2 keys +
+    values]`` (q | k | v along the columns, as a projection and a short
+    convolution write them; attrs ``key_heads``, ``key_dim``), ``G`` (<= 0)
+    and ``Beta [B, S, heads]`` -> ``Out [B, S, heads, d_v]``. Computed in
+    chunks of ``chunk`` (attr; the sequence where that is shorter)
+    positions, which equals the recurrence in exact arithmetic; the norms,
+    the decays, their running sums and the state in float32. ``States [B,
+    chunks, heads, d_k, d_v]`` float32, the state entering each chunk, is
+    for the op's own backward and carries no gradient.
 
     Attr ``impl``: ``auto`` (default) lowers the Pallas kernels of
     ``ops/pallas_delta.py`` where they can run (a TPU, or the test harness'
     interpreter) and take the shapes, else ``composed_gated_delta_rule``;
-    ``pallas`` / ``composed`` force one. Which one an op took is counted at
-    each compile (``ctx.note``; observability/ssm.py)."""
+    ``pallas`` / ``composed`` force one. The kernels read raw q and k and,
+    given ``QKV``, that array in place (``packed``; ``split`` is three
+    operands: ``Q`` / ``K`` / ``V``, or column ranges cut out of a ``QKV``
+    the kernels' blocks cannot address, and always the composed form).
+    Which lowering and which operand form an op took is counted at each
+    compile (``ctx.note``; observability/ssm.py)."""
     import jax.numpy as jnp
     from . import pallas_delta, pallas_mode
-    q, k, v, g, beta = (ins[n][0] for n in ("Q", "K", "V", "G", "Beta"))
-    kernels, chunk = _delta_plan(ctx, q, k, v)
-    ctx.note("gated_delta_rule", ("pallas" if kernels else "composed", chunk,
-                                  v.shape[2], q.shape[3], v.shape[3]))
-    if kernels:
-        qn, kn, cum = _delta_operands(q, k, g, chunk, v.dtype)
+    q, k, v, g, beta, qkv = _delta_inputs(ctx, ins)
+    operands, chunk = _delta_plan(ctx, q, k, v, qkv)
+    ctx.note("gated_delta_rule", (
+        "composed" if operands is None else "pallas", chunk, v.shape[2],
+        q.shape[3], v.shape[3],
+        "split" if operands is None or operands is not qkv else "packed"))
+    if operands is not None:
         o, states = pallas_delta.chunked(
-            _flat(qn), _flat(kn), _flat(v), cum, beta.astype(jnp.float32),
-            chunk, pallas_mode.interpret())
+            operands, _chunk_sums(g, chunk), beta.astype(jnp.float32), chunk,
+            pallas_mode.interpret())
         return {"Out": [o.reshape(v.shape)], "States": [states]}
     qn, kn, cum = _delta_operands(q, k, g, chunk, jnp.float32)
     o, states = composed_gated_delta_rule(qn, kn, v, cum, beta, chunk)
@@ -758,25 +796,33 @@ def gated_delta_rule(ctx, ins):
 
 @register_grad("gated_delta_rule")
 def gated_delta_rule_grad(ctx, ins, generic):
-    """dQ, dK, dV, dG, dBeta. Where the forward op took the kernels and
-    declared ``States``, the backward kernel alone on the states the
-    forward wrote: no forward is lowered here (the l2 norms and the running
-    sums again, which XLA shares with the forward's). Every other case is
-    the generic grad (``jax.vjp`` over the forward's lowering)."""
+    """dQ, dK, dV (or dQKV), dG, dBeta. Where the forward op took the
+    kernels and declared ``States``, the backward kernel alone on the states
+    the forward wrote, with the norms' vjp inside it: no forward is lowered
+    here but the running sums again, which XLA shares with the forward's.
+    Every other case is the generic grad (``jax.vjp`` over the forward's
+    lowering)."""
     import jax
     import jax.numpy as jnp
     from . import pallas_delta, pallas_mode
-    q, k, v, g, beta = (ins[n][0] for n in ("Q", "K", "V", "G", "Beta"))
+    q, k, v, g, beta, qkv = _delta_inputs(ctx, ins)
     states, do = ins.get("States", [None])[0], ins.get("Out@GRAD", [None])[0]
-    kernels, chunk = _delta_plan(ctx, q, k, v)
-    if not kernels or states is None or do is None:
+    operands, chunk = _delta_plan(ctx, q, k, v, qkv)
+    if operands is None or states is None or do is None:
         return generic()
-    (qn, kn, cum), back = jax.vjp(
-        lambda q, k, g: _delta_operands(q, k, g, chunk, v.dtype), q, k, g)
-    dqn, dkn, dv, dcum, dbeta = pallas_delta._bwd_call(
-        _flat(qn), _flat(kn), _flat(v), cum, beta.astype(jnp.float32),
-        states, _flat(do.astype(v.dtype)), chunk, pallas_mode.interpret())
-    dq, dk, dg = back((dqn.reshape(q.shape), dkn.reshape(k.shape), dcum))
-    return {"Q@GRAD": [dq], "K@GRAD": [dk], "V@GRAD": [dv.reshape(v.shape)],
-            "G@GRAD": [dg.astype(g.dtype)],
-            "Beta@GRAD": [dbeta.astype(beta.dtype)]}
+    cum, back = jax.vjp(lambda g: _chunk_sums(g, chunk), g)
+    dqkv, dcum, dbeta = pallas_delta._bwd_call(
+        operands, cum, beta.astype(jnp.float32), states,
+        _flat(do.astype(v.dtype)), chunk, pallas_mode.interpret())
+    (dg,) = back(dcum)
+    grads = {"G@GRAD": [dg.astype(g.dtype)],
+             "Beta@GRAD": [dbeta.astype(beta.dtype)]}
+    if qkv is None:
+        dq, dk, dv = dqkv
+        grads.update({"Q@GRAD": [dq.reshape(q.shape)],
+                      "K@GRAD": [dk.reshape(k.shape)],
+                      "V@GRAD": [dv.reshape(v.shape)]})
+    else:
+        grads["QKV@GRAD"] = [dqkv if operands is qkv
+                             else jnp.concatenate(dqkv, axis=-1)]
+    return grads

@@ -286,6 +286,68 @@ def test_one_adamw_step_is_the_reference_s_gradient_through_adamw(f32):
     exe.close()
 
 
+def _split_rule(qkv, g, beta, key_heads, key_dim, **kw):
+    """The mixer's delta rule as it was built before the op took q | k | v
+    whole: the conv's output cut into q, k and v, three operands."""
+    batch, seq, wide = (int(d) for d in qkv.shape)
+    keys, heads = key_heads * key_dim, int(g.shape[2])
+    q, k, v = layers.split(qkv, [keys, keys, wide - 2 * keys], dim=-1)
+    return layers.gated_delta_rule(
+        layers.reshape(q, [batch, seq, key_heads, key_dim]),
+        layers.reshape(k, [batch, seq, key_heads, key_dim]),
+        layers.reshape(v, [batch, seq, heads, -1]), g, beta, **kw)
+
+
+def test_the_delta_rule_reads_the_convs_output_whole(f32, monkeypatch):
+    """No ``split`` stands between a DeltaNet layer's ``short_conv`` and its
+    ``gated_delta_rule``: the op's one ``QKV`` operand is the conv's output
+    (reshaped, which moves nothing) and its gradient goes back the same
+    way; the first step's loss and every parameter's gradient are the split
+    form's."""
+    block = f32["b"]["main"].global_block()
+    made_by = {name: op for op in block.ops
+               for names in op.outputs.values() for name in names}
+    rules = [op for op in block.ops if op.type == "gated_delta_rule"]
+    assert len(rules) == 2
+    for op in rules:
+        assert sorted(op.inputs) == ["Beta", "G", "QKV"]
+        assert (op.attr("key_heads"), op.attr("key_dim")) == (2, 8)
+        reshape = made_by[op.inputs["QKV"][0]]
+        assert reshape.type.startswith("reshape")
+        conv = made_by[reshape.inputs["X"][0]]
+        assert conv.type == "short_conv"
+        readers = [o.type for o in block.ops
+                   if conv.outputs["Out"][0] in sum(o.inputs.values(), [])]
+        assert "split" not in readers and readers[0] == reshape.type
+    grads = [op for op in block.ops if op.type == "gated_delta_rule_grad"]
+    assert [sorted(k for k in op.outputs if op.outputs[k])
+            for op in grads] == [["Beta@GRAD", "G@GRAD", "QKV@GRAD"]] * 2
+    # q | k | v, then [qkv | z] and [b | alpha]: one split fewer a layer
+    assert [op.type for op in block.ops].count("split") == 2 * 2 + 1
+    monkeypatch.setattr(layers, "gated_delta_rule_packed", _split_rule)
+    b = built(MODEL)
+    kinds = [op.type for op in b["main"].global_block().ops]
+    assert kinds.count("split") == 2 * 3 + 1
+    assert all(sorted(op.inputs) == ["Beta", "G", "K", "Q", "V"]
+               for op in b["main"].global_block().ops
+               if op.type == "gated_delta_rule")
+    exe, scope = fluid.Executor(), fluid.Scope()
+    exe.run(b["startup"], scope=scope)
+    assert b["params"] == f32["b"]["params"]
+    for n, w in zip(b["params"], f32["weights"]):
+        scope.set_var(n, jnp.asarray(w))
+    got = exe.run(b["main"], feed=batch(), scope=scope, fetch_list=[
+        b["out"]["loss"].name] + [n + "@GRAD" for n in b["params"]])
+    exe.close()
+    assert float(got[0].reshape(-1)[0]) == pytest.approx(f32["loss"],
+                                                         rel=2e-6)
+    for n, g in zip(b["params"], got[1:]):
+        want = np.asarray(f32["grads"][n], np.float32)
+        np.testing.assert_allclose(
+            np.asarray(g, np.float32), want, rtol=0,
+            atol=3e-5 * np.abs(want).max(), err_msg=n)
+
+
 def _without(mechanism):
     model = copy.deepcopy(MODEL)
     if mechanism == "shared_gate":

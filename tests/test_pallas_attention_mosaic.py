@@ -299,22 +299,29 @@ def test_ssd_scan_kernels_compile_for_v5e(one_chip, batch, seq, chunk):
     assert compiled.memory_analysis().temp_size_in_bytes < 200e6
 
 
+@pytest.mark.parametrize("form", ["split", "packed"])
 @pytest.mark.parametrize("batch,seq,chunk", [(2, 4096, 128), (1, 2048, 64)])
 def test_gated_delta_rule_kernels_compile_for_v5e(one_chip, batch, seq,
-                                                  chunk):
+                                                  chunk, form):
     """The chunked delta rule at the Qwen3-Next cell's shape (16 key and 32
     value heads of 128, chunks of 128, two sequences of 4096, bf16 with
     float32 g and beta): one kernel forward, which also writes the state
     entering each chunk; the backward the op's grad lowering calls is one
-    kernel on those states and no forward."""
+    kernel on those states and no forward. Raw q, k and v, as three arrays
+    or (``packed``, the cell's) as the conv's one ``[B, S, 8192]`` array
+    read in place: no array of q's, k's or v's shape is made around either
+    kernel, and the per-head scalars go in unpadded."""
     from paddle_tpu.ops import pallas_delta
     n_k, n_v, d = 16, 32, 128
     assert pallas_delta.supports(seq, n_k, n_v, d, d, chunk)
+    assert pallas_delta.packs(n_k, n_v)
 
     def sds(shape, dtype):
         return jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
     keys, values = sds((batch, seq, n_k * d), jnp.bfloat16), sds(
         (batch, seq, n_v * d), jnp.bfloat16)
+    qkv = (keys, keys, values) if form == "split" else sds(
+        (batch, seq, (2 * n_k + n_v) * d), jnp.bfloat16)
     scalars = sds((batch, seq, n_v), jnp.float32)
     states = sds((batch, seq // chunk, n_v, d, d), jnp.float32)
 
@@ -323,21 +330,31 @@ def test_gated_delta_rule_kernels_compile_for_v5e(one_chip, batch, seq,
 
     def backward(*a):
         return pallas_delta._bwd_call(*a, chunk, False)
-    fwd = jax.jit(forward).lower(keys, keys, values, scalars, scalars)
+    fwd = jax.jit(forward).lower(qkv, scalars, scalars)
     assert [tuple(o.shape) for o in fwd.out_info] == [
         values.shape, states.shape]
-    assert _kernels(fwd.compile()) == 1
-    back = jax.jit(backward).lower(keys, keys, values, scalars, scalars,
-                                   states, values)
-    assert [tuple(o.shape) for o in back.out_info] == [
-        keys.shape, keys.shape, values.shape, scalars.shape, scalars.shape]
+    compiled = fwd.compile()
+    assert _kernels(compiled) == 1
+    # the scalars' [.., rep, C] rows alone: no operand of the kernel is
+    # copied, cut out or normalised around it
+    assert compiled.memory_analysis().temp_size_in_bytes < 20e6
+    back = jax.jit(backward).lower(qkv, scalars, scalars, states, values)
+    grads = jax.tree_util.tree_leaves(back.out_info)
+    assert [tuple(o.shape) for o in grads] == [
+        tuple(a.shape) for a in jax.tree_util.tree_leaves(qkv)] + [
+        scalars.shape, scalars.shape]
     compiled = back.compile()
     assert _kernels(compiled) == 1
     # no [.., chunk, chunk] block of every batch, chunk and head around
-    # them, only the padded per-head scalars in their two layouts: far under the
-    # composed form's 3.5 GB at the cell's shape (chip run, PR 41); a [..,
-    # chunk, 2] float32 block is padded to 128 lanes, 67 MB an array
-    assert compiled.memory_analysis().temp_size_in_bytes < 300e6
+    # them (the composed form's 3.5 GB at the cell's shape: chip run, PR
+    # 41) and no lane-padded [.., chunk, 2] scalars (67 MB an array until
+    # PR 43): the scalars' rows and their gradients', and for the packed
+    # form dq, dk and dv before their concatenation (134 MB at the cell's
+    # shape)
+    assert compiled.memory_analysis().temp_size_in_bytes < (
+        20e6 if form == "split" else 160e6)
+    text = compiled.as_text().split("ENTRY")[1]
+    assert f",{chunk},2]" not in text and "f32[2,4096,2048]" not in text
 
 
 @pytest.mark.parametrize("S", [384, 512])

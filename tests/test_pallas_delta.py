@@ -3,7 +3,9 @@
 form, and the Pallas kernels in the interpreter) against the float32
 recurrence position by position, outputs and every input's gradient; the
 same result whatever the chunk; the state handed across a chunk's edge and
-not a sequence's; what the op refuses and what it counts."""
+not a sequence's; what the op refuses and what it counts. Each in both
+operand forms: q, k, v apart, and packed into the one array the kernels
+read in place."""
 import jax
 import jax.numpy as jnp
 import numpy as np
@@ -33,8 +35,20 @@ def rule_inputs(batch, seq, key_heads, heads, dk, dv, seed=0):
             "float32")}
 
 
-def rule_with(impl, chunk):
-    return lambda *v: layers.gated_delta_rule(*v, chunk=chunk, impl=impl)
+def rule_with(impl, chunk, form="split"):
+    """The layer over the five feeds: as three operands, or (``packed``) as
+    the one ``q | k | v`` array a projection writes, built by a concat the
+    feeds' gradients come back through."""
+    if form == "split":
+        return lambda *v: layers.gated_delta_rule(*v, chunk=chunk, impl=impl)
+
+    def packed(q, k, v, g, beta):
+        b, s, n_k, d_k = (int(d) for d in q.shape)
+        qkv = layers.concat([layers.reshape(x, [b, s, -1])
+                             for x in (q, k, v)], axis=2)
+        return layers.gated_delta_rule_packed(qkv, g, beta, n_k, d_k,
+                                              chunk=chunk, impl=impl)
+    return packed
 
 
 def recurrence(feeds, g=None):
@@ -50,6 +64,7 @@ def recurrence(feeds, g=None):
 
 # one chunk, several chunks, a batch of two, one and two value heads a key
 # head; the kernels want heads of 128 and a chunk of 64 or 128
+@pytest.mark.parametrize("form", ["split", "packed"])
 @pytest.mark.parametrize("impl,batch,seq,key_heads,heads,dk,dv,chunk", [
     ("composed", 1, 8, 2, 4, 8, 8, 8), ("composed", 1, 24, 2, 2, 4, 8, 8),
     ("composed", 2, 16, 1, 3, 4, 8, 4), ("auto", 2, 12, 2, 4, 8, 4, 64),
@@ -58,13 +73,16 @@ def recurrence(feeds, g=None):
     ("pallas", 1, 192, 2, 2, 128, 128, 64),
     ("auto", 1, 128, 1, 2, 128, 128, 128)])
 def test_gated_delta_rule_equals_the_recurrence_and_its_gradient(
-        impl, batch, seq, key_heads, heads, dk, dv, chunk):
+        impl, batch, seq, key_heads, heads, dk, dv, chunk, form):
     """``pallas`` runs the kernel bodies in the interpreter
     (tests/conftest.py); ``auto`` takes them where the shapes allow and the
     composed form elsewhere. The reference is the recurrence over positions
-    (``lax.scan``), not a chunk form."""
+    (``lax.scan``), not a chunk form. ``packed``: q | k | v as one array,
+    which the kernels read in place and the composed form by column
+    ranges."""
     feeds = rule_inputs(batch, seq, key_heads, heads, dk, dv)
-    out, grads, _, g, _ = run_with_grads(rule_with(impl, chunk), feeds, NAMES)
+    out, grads, _, g, _ = run_with_grads(rule_with(impl, chunk, form), feeds,
+                                         NAMES)
     want, want_grads = recurrence(feeds, g)
     close(out, want, 1e-4)
     for name, got, ref in zip(NAMES, grads, want_grads):
@@ -72,19 +90,22 @@ def test_gated_delta_rule_equals_the_recurrence_and_its_gradient(
             got, ref, rtol=0, atol=1e-4 * np.abs(ref).max(), err_msg=name)
 
 
-@pytest.mark.parametrize("impl,dk", [("composed", 8), ("pallas", 128)])
-def test_chunks_of_64_and_128_give_the_same_result(impl, dk):
+@pytest.mark.parametrize("impl,dk,form", [
+    ("composed", 8, "split"), ("pallas", 128, "split"),
+    ("composed", 8, "packed"), ("pallas", 128, "packed")])
+def test_chunks_of_64_and_128_give_the_same_result(impl, dk, form):
     feeds = rule_inputs(1, 256, 1, 2, dk, dk, seed=2)
-    a, b = (run_with_grads(rule_with(impl, c), feeds, [])[0]
+    a, b = (run_with_grads(rule_with(impl, c, form), feeds, [])[0]
             for c in (64, 128))
     close(a, b, 2e-6)
     close(a, recurrence(feeds), 1e-4)
 
 
-@pytest.mark.parametrize("impl,seq,dk,chunk", [
-    ("composed", 16, 8, 8), ("pallas", 128, 128, 64)])
+@pytest.mark.parametrize("impl,seq,dk,chunk,form", [
+    ("composed", 16, 8, 8, "split"), ("pallas", 128, 128, 64, "split"),
+    ("pallas", 128, 128, 64, "packed")])
 def test_the_state_crosses_a_chunks_edge_and_not_a_sequences(
-        impl, seq, dk, chunk):
+        impl, seq, dk, chunk, form):
     """Positions after a chunk's edge see the chunk before it (another first
     chunk moves them); the second sequence of a batch sees nothing of the
     first, and a sequence's first position starts from a zero state: ``o_0 =
@@ -95,7 +116,7 @@ def test_the_state_crosses_a_chunks_edge_and_not_a_sequences(
     other["v"][0, :chunk] = rng(4).randn(chunk, 2, dk)
 
     def run(f):
-        return run_with_grads(rule_with(impl, chunk), f, [])[0]
+        return run_with_grads(rule_with(impl, chunk, form), f, [])[0]
     a, b = run(feeds), run(other)
     np.testing.assert_array_equal(a[1], b[1])
     assert np.abs(a[0, chunk:chunk + 4] - b[0, chunk:chunk + 4]).max() > \
@@ -116,21 +137,158 @@ def test_the_state_crosses_a_chunks_edge_and_not_a_sequences(
     assert np.abs(coarse - exact).max() > 5e-4 * np.abs(exact).max()
 
 
-def test_the_states_output_is_the_state_entering_each_chunk():
+def operands(feeds, form, dtype=jnp.float32):
+    """What ``pallas_delta.chunked`` takes of the feeds beside the running
+    sums and ``beta``: raw q, k, v, three arrays or one."""
+    flat = decoder_ops._flat
+    q, k, v = (flat(jnp.asarray(feeds[n], dtype)) for n in ("q", "k", "v"))
+    return (q, k, v) if form == "split" else jnp.concatenate([q, k, v], -1)
+
+
+def composed(q, k, v, g, beta, chunk):
+    qn, kn, cum = decoder_ops._delta_operands(q, k, g, chunk, jnp.float32)
+    return decoder_ops.composed_gated_delta_rule(qn, kn, v, cum, beta, chunk)
+
+
+@pytest.mark.parametrize("form", ["split", "packed"])
+def test_the_states_output_is_the_state_entering_each_chunk(form):
     """``States`` is what the backward kernel reads: both lowerings write
     the same, and chunk 0's is zero."""
     feeds = rule_inputs(1, 128, 1, 2, 128, 128, seed=5)
     args = [jnp.asarray(feeds[n]) for n in NAMES]
-    qn, kn, cum = decoder_ops._delta_operands(*args[:2], args[3], 64,
-                                              jnp.float32)
-    _, want = decoder_ops.composed_gated_delta_rule(qn, kn, args[2], cum,
-                                                    args[4], 64)
-    flat = decoder_ops._flat
-    o, got = pallas_delta.chunked(flat(qn), flat(kn), flat(args[2]), cum,
-                                  args[4], 64, True)
+    _, want = composed(*args, 64)
+    o, got = pallas_delta.chunked(
+        operands(feeds, form), decoder_ops._chunk_sums(args[3], 64), args[4],
+        64, True)
     assert got.shape == (1, 2, 2, 128, 128) and not np.asarray(got[:, 0]).any()
     close(got, want, 1e-5)
     assert np.abs(np.asarray(want[:, 1])).max() > 1e-3
+
+
+@pytest.mark.parametrize("form", ["split", "packed"])
+@pytest.mark.parametrize("batch,seq,key_heads,heads,chunk", [
+    (1, 128, 1, 2, 64), (2, 256, 2, 4, 128), (1, 128, 2, 2, 64)])
+def test_the_kernels_equal_the_composed_form_on_its_operands(
+        form, batch, seq, key_heads, heads, chunk):
+    """``pallas_delta.chunked`` on raw q and k (the norms in the kernels,
+    their vjp in the backward's) against ``composed_gated_delta_rule`` on
+    ``_delta_operands``, differentiated by JAX: the output, the states and
+    the gradient of every input, in either operand form."""
+    feeds = rule_inputs(batch, seq, key_heads, heads, 128, 128, seed=7)
+    args = [jnp.asarray(feeds[n]) for n in NAMES]
+    do = jnp.asarray(rng(8).randn(*feeds["v"].shape), jnp.float32)
+    (want, want_states), back = jax.vjp(
+        lambda *a: composed(*a, chunk), *args)
+    want_grads = back((do, jnp.zeros_like(want_states)))
+
+    def kernels(qkv, g, beta):
+        return pallas_delta.chunked(qkv, decoder_ops._chunk_sums(g, chunk),
+                                    beta, chunk, True)
+    (o, states), back = jax.vjp(kernels, operands(feeds, form), *args[3:])
+    dqkv, dg, dbeta = back((decoder_ops._flat(do), jnp.zeros_like(states)))
+    close(o.reshape(want.shape), want, 1e-5)
+    close(states, want_states, 1e-5)
+    if form == "packed":
+        keys = key_heads * 128
+        dqkv = dqkv[..., :keys], dqkv[..., keys:2 * keys], dqkv[..., 2 * keys:]
+    for name, got, ref in zip(NAMES, (*dqkv, dg, dbeta), want_grads):
+        np.testing.assert_allclose(
+            np.asarray(got).reshape(ref.shape), ref, rtol=0,
+            atol=2e-5 * np.abs(ref).max(), err_msg=name)
+
+
+def test_packed_and_split_operands_give_the_same_bits():
+    """One kernel body under two sets of index maps: the packed array read
+    in place gives bit for bit what its three parts give, forward and
+    backward, in bfloat16 as on the chip."""
+    feeds = rule_inputs(2, 128, 2, 4, 128, 128, seed=10)
+    cum = decoder_ops._chunk_sums(jnp.asarray(feeds["g"]), 64)
+    beta = jnp.asarray(feeds["beta"])
+    do = jnp.asarray(rng(11).randn(2, 128, 4 * 128), jnp.bfloat16)
+    got = {}
+    for form in ("split", "packed"):
+        qkv = operands(feeds, form, jnp.bfloat16)
+        o, states = pallas_delta._fwd_call(qkv, cum, beta, 64, True)
+        dqkv, dg, db = pallas_delta._bwd_call(qkv, cum, beta, states, do, 64,
+                                              True)
+        if form == "split":
+            dqkv = jnp.concatenate(dqkv, -1)
+        assert o.dtype == dqkv.dtype == jnp.bfloat16
+        got[form] = [np.asarray(x, np.float32)
+                     for x in (o, states, dqkv, dg, db)]
+    for a, b in zip(got["split"], got["packed"]):
+        assert np.abs(a).max() > 0
+        np.testing.assert_array_equal(a, b)
+
+
+@pytest.mark.parametrize("form", ["split", "packed"])
+def test_the_kernels_products_read_delta_operands_unit_q_and_k(form):
+    """The unit q and k a grid step forms in VMEM, rounded to bfloat16, are
+    ``_delta_operands``' bit for bit: a probe kernel behind the delta
+    kernels' own block specs (key head j of q at lane block j, of k at ``key
+    heads + j`` of the packed array) writes what the products would read."""
+    import functools
+    from jax.experimental import pallas as pl
+    feeds = rule_inputs(2, 128, 2, 4, 128, 128, seed=12)
+    bf, f32 = jnp.bfloat16, jnp.float32
+    q, k = (jnp.asarray(feeds[n], bf) for n in ("q", "k"))
+    want_q, want_k, _ = decoder_ops._delta_operands(
+        q, k, jnp.asarray(feeds["g"]), 64, bf)
+    (qa, ka, _), at, n_k = pallas_delta._laid_out(
+        operands(feeds, form, bf), 4)
+    assert (n_k, at) == (2, (0, 0, 0) if form == "split" else (0, 2, 2))
+    key, *_ = pallas_delta._specs(64, 2, lambda i: i)
+
+    def probe(q_ref, k_ref, qn_ref, kn_ref):
+        qn_ref[0] = pallas_delta.unit(
+            q_ref[0].astype(f32), pallas_delta.QUERY_SCALE).astype(bf)
+        kn_ref[0] = pallas_delta.unit(k_ref[0].astype(f32)).astype(bf)
+    shape = jax.ShapeDtypeStruct((2, 128, 2 * 128), bf)
+    qn, kn = pl.pallas_call(
+        probe, grid=(2, 2, 2), in_specs=[key(at[0]), key(at[1])],
+        out_specs=[key(), key()],
+        out_shape=[shape, shape], interpret=True)(qa, ka)
+    flat = decoder_ops._flat
+    for got, want in ((qn, want_q), (kn, want_k)):
+        np.testing.assert_array_equal(
+            np.asarray(got, np.float32), np.asarray(flat(want), np.float32))
+    assert not np.array_equal(np.asarray(qn, np.float32),
+                              np.asarray(kn, np.float32))
+
+
+@pytest.mark.parametrize("form", ["split", "packed"])
+def test_two_value_heads_a_key_head_each_read_their_own_scalars(form):
+    """``G`` and ``beta`` go in as ``[.., rep, C]`` rows and are spread to
+    columns in VMEM: with ``rep = 2`` and heads that could not differ more
+    (one forgets within a few positions and steps fully, its neighbour
+    keeps everything and barely steps) a layout that handed a head its
+    neighbour's row, or a position another's, is far off; and the
+    gradients come back head by head too."""
+    feeds = rule_inputs(1, 128, 2, 4, 128, 128, seed=13)
+    r = rng(14)
+    fast = np.arange(4) % 2 == 0            # value heads 0, 2: the first of
+    feeds["g"] = np.where(                  # each key head's two
+        fast, -r.uniform(0.5, 1.5, (1, 128, 4)),
+        -r.uniform(1e-4, 1e-3, (1, 128, 4))).astype("float32")
+    feeds["beta"] = np.where(
+        fast, r.uniform(0.9, 1.0, (1, 128, 4)),
+        r.uniform(0.02, 0.1, (1, 128, 4))).astype("float32")
+    out, grads, _, g, _ = run_with_grads(
+        rule_with("pallas", 64, form), feeds, NAMES)
+    want, want_grads = recurrence(feeds, g)
+    close(out, want, 1e-4)
+    for name, got, ref in zip(NAMES, grads, want_grads):
+        np.testing.assert_allclose(
+            got, ref, rtol=0, atol=1e-4 * np.abs(ref).max(), err_msg=name)
+    # what the comparison would see of a wrong layout: each key head's two
+    # rows exchanged, the positions reversed inside a chunk
+    for wrong in (lambda x: x.reshape(1, 128, 2, 2)[..., ::-1].reshape(
+            1, 128, 4), lambda x: x.reshape(1, 2, 64, 4)[:, :, ::-1].reshape(
+            1, 128, 4)):
+        other = np.asarray(recurrence(
+            dict(feeds, g=wrong(feeds["g"]), beta=wrong(feeds["beta"]))))
+        assert np.abs(other - np.asarray(want)).max() > \
+            0.05 * np.abs(np.asarray(want)).max()
 
 
 def test_gated_delta_rule_refuses_what_it_cannot_chunk_and_counts_its_ops():
@@ -143,11 +301,19 @@ def test_gated_delta_rule_refuses_what_it_cannot_chunk_and_counts_its_ops():
     bad.update(g=feeds["g"][..., :3], beta=feeds["beta"][..., :3])
     with pytest.raises(Exception, match="multiple of the key heads"):
         run_with_grads(rule_with("auto", 4), bad, [])
+    with pytest.raises(Exception, match="of 62 columns is not"):
+        run_with_grads(
+            lambda q, k, v, g, beta: layers.gated_delta_rule_packed(
+                layers.reshape(v, [1, 12, 62]), g, beta, 2, 8, chunk=4),
+            dict(feeds, v=rng().randn(1, 12, 2, 31).astype("float32")), [])
     assert pallas_delta.supports(4096, 16, 32, 128, 128, 64)
     assert pallas_delta.supports(4096, 16, 32, 128, 128, 128)
     assert not pallas_delta.supports(4096, 16, 32, 64, 128, 64)
     assert not pallas_delta.supports(4096, 16, 32, 128, 128, 256)
     assert not pallas_delta.supports(4000, 16, 32, 128, 128, 64)
+    # v's first column is a whole number of a key head's value blocks
+    assert pallas_delta.packs(16, 32) and pallas_delta.packs(2, 2)
+    assert pallas_delta.packs(2, 8) and not pallas_delta.packs(1, 4)
     main, startup = fluid.Program(), fluid.Program()
     with fluid.program_guard(main, startup):
         v = [fluid.data(k, list(a.shape), "float32", append_batch_size=False)
@@ -161,28 +327,73 @@ def test_gated_delta_rule_refuses_what_it_cannot_chunk_and_counts_its_ops():
                        ("delta_chunks_per_step", 3)):
         assert registry.gauge(name, program="p").value == want, name
     obs_ssm.count_delta_lowerings(
-        {1: ("pallas", 64, 32, 128, 128), 2: ("pallas", 64, 32, 128, 128),
-         3: ("composed", 4, 4, 8, 8)}, "p", registry)
-    assert registry.counter(
-        "delta_lowering_total", program="p", impl="pallas", chunk="64",
-        heads="32", key_dim="128", value_dim="128").value == 2
+        {1: ("pallas", 64, 32, 128, 128, "packed"),
+         2: ("pallas", 64, 32, 128, 128, "packed"),
+         3: ("pallas", 64, 32, 128, 128, "split"),
+         4: ("composed", 4, 4, 8, 8, "split")}, "p", registry)
+    labels = dict(program="p", impl="pallas", chunk="64", heads="32",
+                  key_dim="128", value_dim="128")
+    assert registry.counter("delta_lowering_total", **labels,
+                            operands="packed").value == 2
+    assert registry.counter("delta_lowering_total", **labels,
+                            operands="split").value == 1
     obs_ssm.update_delta_gauges(fluid.Program(), "none", registry)
     assert all(("program", "none") not in labels
                for labels, _ in registry.get("delta_layers").items())
 
 
+def lowerings(**want):
+    """``delta_lowering_total`` of this process over the children that carry
+    the labels."""
+    from paddle_tpu.observability.metrics import REGISTRY
+    family = REGISTRY.get("delta_lowering_total")
+    return sum(child.value for labels, child in family.items()
+               if set(want.items()) <= set(labels)) if family else 0
+
+
 def test_a_compiled_step_counts_the_lowering_each_op_took():
     """Through the executor: the forward op's note lands in
-    ``delta_lowering_total`` once a compile, whichever lowering it took."""
-    from paddle_tpu.observability.metrics import REGISTRY
+    ``delta_lowering_total`` once a compile, whichever lowering and operand
+    form it took; the gauges read a packed op's shapes off its outputs."""
     feeds = rule_inputs(1, 128, 1, 2, 128, 128, seed=6)
-
-    def count(impl):
-        family = REGISTRY.get("delta_lowering_total")
-        return sum(child.value for labels, child in family.items()
-                   if ("impl", impl) in labels) if family else 0
-    before = count("pallas"), count("composed")
+    kinds = [dict(impl="pallas", operands="split"),
+             dict(impl="pallas", operands="packed"),
+             dict(impl="composed", operands="split"),
+             dict(impl="composed", operands="packed")]
+    before = [lowerings(**k) for k in kinds]
     run_with_grads(rule_with("auto", 64), feeds, ["q"])
+    run_with_grads(rule_with("auto", 64, "packed"), feeds, ["q"])
     run_with_grads(rule_with("composed", 64), feeds, [])
-    assert count("pallas") - before[0] == 1
-    assert count("composed") - before[1] == 1
+    run_with_grads(rule_with("composed", 64, "packed"), feeds, [])
+    # the composed form cuts its operands out of a packed array: ``split``
+    assert [lowerings(**k) - b
+            for k, b in zip(kinds, before)] == [1, 1, 2, 0]
+    main, startup = fluid.Program(), fluid.Program()
+    with fluid.program_guard(main, startup):
+        v = [fluid.data(k, list(a.shape), "float32", append_batch_size=False)
+             for k, a in feeds.items()]
+        y = rule_with("auto", 64, "packed")(*v)
+    assert tuple(y.shape) == (1, 128, 2, 128)
+    registry = MetricsRegistry()
+    obs_ssm.update_delta_gauges(main, "p", registry)
+    for name, want in (("delta_layers", 1), ("delta_heads", 2),
+                       ("delta_state_bytes", 2 * 128 * 128 * 4),
+                       ("delta_chunks_per_step", 2)):
+        assert registry.gauge(name, program="p").value == want, name
+
+
+def test_one_value_block_short_of_a_whole_offset_falls_back_to_three_operands():
+    """Four value heads over one key head: v starts two tiles in, not a
+    whole block of four, so the kernels cannot address it in the packed
+    array; the op cuts three operands out of it and says ``split``."""
+    feeds = rule_inputs(1, 64, 1, 4, 128, 128, seed=15)
+    cut = dict(impl="pallas", operands="split", heads="4")
+    before = lowerings(**cut)
+    out, grads, _, g, _ = run_with_grads(
+        rule_with("pallas", 64, "packed"), feeds, NAMES)
+    assert lowerings(**cut) - before == 1
+    want, want_grads = recurrence(feeds, g)
+    close(out, want, 1e-4)
+    for name, got, ref in zip(NAMES, grads, want_grads):
+        np.testing.assert_allclose(
+            got, ref, rtol=0, atol=1e-4 * np.abs(ref).max(), err_msg=name)

@@ -45,7 +45,7 @@ def test_without_takes_one_mechanism_out_and_keeps_the_parameters():
     with pytest.raises(ValueError):
         probe_tool.without(model, "norm")
     from paddle_tpu import layers
-    rule = layers.gated_delta_rule
+    rule = layers.gated_delta_rule_packed
     with probe_tool.patched("decay"):
-        assert layers.gated_delta_rule is not rule
-    assert layers.gated_delta_rule is rule
+        assert layers.gated_delta_rule_packed is not rule
+    assert layers.gated_delta_rule_packed is rule

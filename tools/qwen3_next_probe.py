@@ -68,42 +68,46 @@ def without(model: dict, mechanism: str) -> dict:
 def patched(mechanism: str):
     """What no configuration key takes out, swapped around one program's
     build and check: the layer function a mixer calls (the decay, the step,
-    the attention gate), the op's prologue (the l2 norm), or -- for
-    ``bf16_state`` -- the reference's recurrence."""
+    the attention gate), the expression both lowerings normalise q and k by
+    (the l2 norm), or -- for ``bf16_state`` -- the reference's recurrence."""
     from paddle_tpu import layers
-    from paddle_tpu.ops import decoder_ops
+    from paddle_tpu.ops import pallas_delta
     from benchmark.references import qwen3_next_pretrain as reference
-    swaps = []
+    swaps, stale = [], ()
 
     def swap(owner, name, new):
         swaps.append((owner, name, getattr(owner, name)))
         setattr(owner, name, new)
-    rule = layers.gated_delta_rule
+    rule = layers.gated_delta_rule_packed
     if mechanism == "decay":
-        swap(layers, "gated_delta_rule", lambda q, k, v, g, beta, **kw: rule(
-            q, k, v, layers.scale(g, 0.0), beta, **kw))
+        swap(layers, "gated_delta_rule_packed",
+             lambda qkv, g, beta, *a, **kw: rule(
+                 qkv, layers.scale(g, 0.0), beta, *a, **kw))
     elif mechanism == "beta":
-        swap(layers, "gated_delta_rule", lambda q, k, v, g, beta, **kw: rule(
-            q, k, v, g, layers.scale(beta, 0.0, bias=1.0), **kw))
+        swap(layers, "gated_delta_rule_packed",
+             lambda qkv, g, beta, *a, **kw: rule(
+                 qkv, g, layers.scale(beta, 0.0, bias=1.0), *a, **kw))
     elif mechanism == "attention_gate":
         swap(layers, "attention_gate", lambda x, gate, name=None: x)
     elif mechanism == "l2_norm":
-        operands = decoder_ops._delta_operands
-
-        def unnormed(q, k, g, chunk, dtype):
-            _, _, cum = operands(q, k, g, chunk, dtype)
-            return ((q.astype("float32") * q.shape[-1] ** -0.5).astype(dtype),
-                    k.astype(dtype), cum)
-        swap(decoder_ops, "_delta_operands", unnormed)
+        # one expression for the composed form's operands and the kernels'
+        # prologue (whose jits hold a body traced with the real one)
+        swap(pallas_delta, "unit",
+             lambda x, scale=None: x if scale is None else x * scale)
+        stale = (pallas_delta._fwd_call, pallas_delta._bwd_call)
     elif mechanism == "bf16_state":
         import jax.numpy as jnp
         swap(reference, "delta_rule", functools.partial(
             reference.delta_rule, state_dtype=jnp.bfloat16))
     try:
+        for call in stale:
+            call.clear_cache()
         yield
     finally:
         for owner, name, old in swaps:
             setattr(owner, name, old)
+        for call in stale:
+            call.clear_cache()
 
 
 def controls(args) -> dict:
@@ -148,8 +152,9 @@ def kernels(args) -> dict:
             say(f"delta kernels: chunk {chunk} at heads of {d_k} / {d_v} is "
                 f"not theirs")
             continue
-        qn, kn, cum = decoder_ops._delta_operands(q, k, g, chunk, bf)
-        ops = (flat(qn), flat(kn), flat(v), cum, beta)
+        # the cell's operand form: raw q | k | v, one array read in place
+        ops = (jnp.concatenate([flat(q), flat(k), flat(v)], axis=-1),
+               decoder_ops._chunk_sums(g, chunk), beta)
         o, states = pallas_delta._fwd_call(*ops, chunk, interpret)
         fwd = _ms(lambda: pallas_delta._fwd_call(*ops, chunk, interpret))
         bwd = _ms(lambda: pallas_delta._bwd_call(
@@ -159,17 +164,19 @@ def kernels(args) -> dict:
         say(f"delta kernels, chunk {chunk}: forward {fwd:.3f} backward "
             f"{bwd:.3f} ms a layer ({B} x {S}, {n_k} / {n_v} heads)")
 
+    first = min(args.chunks[0], S)      # the rehearsal's sequence is shorter
+
     def composed(q, k, v, g, beta):
-        qn, kn, cum = decoder_ops._delta_operands(q, k, g, args.chunks[0],
+        qn, kn, cum = decoder_ops._delta_operands(q, k, g, first,
                                                   jnp.float32)
         return decoder_ops.composed_gated_delta_rule(
-            qn, kn, v, cum, beta, args.chunks[0])[0]
+            qn, kn, v, cum, beta, first)[0]
     both = jax.jit(lambda *a: jax.vjp(composed, *a[:5])[1](
         a[5].astype(jnp.float32)))
     c_fwd = _ms(jax.jit(composed), q, k, v, g, beta)
     c_both = _ms(both, q, k, v, g, beta, do)
     temp = both.lower(q, k, v, g, beta, do).compile().memory_analysis()
-    say(f"composed chunk form (chunk {args.chunks[0]}): forward {c_fwd:.3f}, "
+    say(f"composed chunk form (chunk {first}): forward {c_fwd:.3f}, "
         f"forward + backward {c_both:.3f} ms a layer; temporaries "
         f"{temp.temp_size_in_bytes / 1e9:.3f} GB")
     result.update(composed_fwd_ms=c_fwd, composed_fwd_bwd_ms=c_both,
