@@ -727,32 +727,9 @@ class Executor:
         if xla_parts is not None:   # a step with an executable to ask
             _obs_memory.note_compiled_step(compiled, program, label,
                                            exe_args, marks)
-        from ..observability import moe as _obs_moe
-        _obs_moe.update_moe_gauges(program, label)
-        from ..observability import attention as _obs_attention
-        _obs_attention.count_lowerings(
-            program._lowering_notes.pop("fused_attention", {}), label)
-        _obs_attention.count_backwards(
-            program._lowering_notes.pop("fused_attention_grad", {}), label)
-        _obs_attention.update_gate_gauges(program, label)
-        from ..observability import loss as _obs_loss
-        _obs_loss.count_backwards(program._lowering_notes.pop(
-            "softmax_with_cross_entropy_grad", {}), label)
-        from ..observability import rotary as _obs_rotary
-        _obs_rotary.count_lowerings(
-            program._lowering_notes.pop("rotary_embedding", {}),
-            program._lowering_notes.pop("rotary_embedding_grad", {}), label)
-        from ..observability import masks as _obs_masks
-        # takes the kinds it counts (mask_draw, gather_layout) out of them
-        _obs_masks.count_data_axis(program._lowering_notes, label)
-        from ..observability import ssm as _obs_ssm
-        _obs_ssm.update_ssm_gauges(program, label)
-        _obs_ssm.count_lowerings(
-            program._lowering_notes.pop("ssd_scan", {}),
-            program._lowering_notes.pop("short_conv", {}), label)
-        _obs_ssm.update_delta_gauges(program, label)
-        _obs_ssm.count_delta_lowerings(
-            program._lowering_notes.pop("gated_delta_rule", {}), label)
+        # what the op lowerings reported while this compile traced them
+        from ..observability import lowerings as _obs_lowerings
+        _obs_lowerings.publish(program._lowering_notes, label)
         # IR->HLO attribution walk: once per compile miss, only when obs /
         # PADDLE_TPU_OBS_ATTRIB / an armed --emit-hlo capture asks for it
         # (on_compile is a no-op otherwise and never raises)
@@ -794,9 +771,9 @@ class Executor:
                 if restored is None:
                     # the span is for restores (their own goodput cause)
                     _obs_timeline.discard()
-        # what the op lowerings note while this compile traces them
-        # (LowerCtx.note) is read by _post_compile_telemetry below; anything
-        # older was left by a trace that was not the executor's
+        # what the op lowerings report while this compile traces them
+        # (LowerCtx.report) is published by _post_compile_telemetry below;
+        # anything older was left by a trace that was not the executor's
         program._lowering_notes.clear()
         if restored is not None:
             compiled.executable = restored

@@ -82,16 +82,19 @@ class LowerCtx:
     def attr(self, name, default=None):
         return self.attrs.get(name, default)
 
-    def note(self, kind: str, value) -> None:
-        """Leave a trace-time fact about this op (which kernel its lowering
-        took, say) for the executor, which empties the notes before a
-        compile and reads them after it (``Executor._materialize_miss``).
-        Keyed by the op's salt: the forward a grad op lowers again under
-        ``jax.vjp`` lands on its forward op's entry. Nothing is kept where
-        no Program is being lowered."""
+    def report(self, family: str, amount=1, **labels) -> None:
+        """Report what this op's lowering chose, as ``amount`` of the metric
+        ``family`` under ``labels`` (observability/lowerings.py declares the
+        families and publishes them once the compile is made: the executor
+        empties the reports before a compile and hands them over after it,
+        ``Executor._materialize_miss``). Kept under the op's salt and the
+        labels: the forward a grad op lowers again under ``jax.vjp`` lands
+        on its forward op's entry. Nothing is kept where no Program is being
+        lowered."""
         if self.program is not None:
-            self.program._lowering_notes.setdefault(kind, {})[
-                self._salt] = value
+            from ..observability import lowerings
+            lowerings.note(self.program._lowering_notes, self._salt, family,
+                           amount, labels)
 
     def rng(self, offset: int = 0):
         import jax
@@ -110,16 +113,16 @@ class LowerCtx:
         the global shape on every device (PERF.md section 6, PR 31). Such a
         run's masks depend on n, like the explicit-dp step's
         (``Executor._explicit_dp``); forward and re-lowered forward reach
-        the same island with the same key. Which way the op drew is noted
-        for ``mask_draw_total`` (observability/masks.py)."""
+        the same island with the same key. Which way the op drew is
+        reported as ``mask_draw_total``."""
         import jax
         mesh, axis = self.gspmd_mesh, self.data_axis
         n = self.data_shards(*shape[:1])
         if n == 1:
-            self.note("mask_draw", ("global", 1))
+            self.report("mask_draw_total", draw="global", shards=1)
             return jax.random.bernoulli(key, keep, shape)
         from jax.sharding import PartitionSpec as P
-        self.note("mask_draw", ("shard", n))
+        self.report("mask_draw_total", draw="shard", shards=n)
         local_shape = (shape[0] // n, *shape[1:])
 
         def local(k):

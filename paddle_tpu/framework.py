@@ -460,9 +460,9 @@ class Program:
         self.random_seed: Optional[int] = None
         self._version = 0
         self._is_startup = False
-        # trace-time facts the op lowerings leave (LowerCtx.note) for the
-        # executor that is compiling this program: {kind: {op salt: value}}
-        self._lowering_notes: Dict[str, dict] = {}
+        # what the op lowerings report (LowerCtx.report) to the executor that
+        # is compiling this program: {(family, op salt, labels): amount}
+        self._lowering_notes: Dict[tuple, Any] = {}
 
     def _bump(self):
         self._version += 1

@@ -33,22 +33,15 @@ reproduction gets the counterpart the whole-program-jit design enables:
   (``hlo_op_bytes{category}`` gauges, copy-pair blame feeding PT060,
   ``--emit-hlo`` capture) and the ``hlo_diff`` regression explainer
   (``python -m paddle_tpu.observability.attribution A B``).
-- ``moe`` -- expert-layer counts of a compiled program as gauges
-  (``moe_layers``, ``moe_experts``, ``moe_assignments_per_step``,
-  ``moe_expert_param_bytes``) and ``load_stats`` for a fetched load vector.
-- ``attention`` -- ``attention_lowering_total{program,impl,s,block_q,block_k,
-  kv_heads,window,heads,head_dim}``: the lowering each ``fused_attention`` op
-  of a compiled program took; ``attention_k_tiles_total{program,state,
-  window}``: the K tiles its flash kernels visit and skip; ``attention_backward_total{program,stats}``: where
-  each of its grad ops got the softmax statistics (``saved`` / ``recomputed``
-  / ``generic``).
-- ``loss`` -- ``loss_backward_total{program,form}``: the form each
-  ``softmax_with_cross_entropy_grad`` op of a compiled program took
-  (``written`` over the logits / ``fused`` / ``generic``).
-- ``rotary`` -- ``rotary_lowering_total{program,direction,form}``: the form
-  each ``rotary_embedding`` op of a compiled program and each of its grad
-  ops took (``kernel``: one pass in, one pass out / ``composed`` /
-  ``generic``).
+- ``lowerings`` -- what the op lowerings of a compiled program chose, as
+  labelled counts added once per compile (``attention_lowering_total``,
+  ``attention_k_tiles_total``, ``attention_backward_total``,
+  ``loss_backward_total``, ``rotary_lowering_total``, ``mask_draw_total``,
+  ``gather_layout_total``, ``ssd_lowering_total``,
+  ``short_conv_lowering_total``, ``delta_lowering_total``) and the gauge
+  ``moe_row_budget``: a lowering reports through ``LowerCtx.report``, the
+  module's ``FAMILIES`` table declares and documents each family.
+- ``moe`` -- ``load_stats`` for a fetched expert-load vector.
 
 Render everything with ``python -m tools.obs_report``.
 """

@@ -1,0 +1,143 @@
+"""What the op lowerings of a compiled program chose, as labelled metrics
+published once per compile.
+
+A lowering reports while the executor traces it (``LowerCtx.report(family,
+amount, **labels)``, core/registry.py): the metric family, its labels by
+name, an amount. The report is kept on the Program under (family, the op's
+salt, the labels), so the forward a grad op lowers again under ``jax.vjp``
+lands on its forward op's entry and counts once. The executor hands the
+reports of the compile it just made to ``publish``. ``FAMILIES`` is the one
+place a family is declared and documented; a lowering that reports another
+is refused at trace time. A new kernel family costs its op file and one row
+here.
+"""
+from __future__ import annotations
+
+from collections import Counter
+from typing import Optional
+
+from .metrics import REGISTRY, MetricsRegistry
+
+#: the amounts of a program's ops are added into a counter (``count``: ops
+#: compiled so far under that label set) or summed and set as a gauge
+#: (``gauge``: what the newest compile of the program holds)
+COUNT, GAUGE = "count", "gauge"
+
+#: family -> (COUNT or GAUGE, the labels a lowering gives, help). ``publish``
+#: adds the label ``program``. benchmark/layer_metrics/*.json read these by
+#: name: a family's name and labels are a contract with them.
+FAMILIES = {
+    # ops/pallas_attention.py. impl: pallas / xla / ring / ulysses; the
+    # kernels' Q block and K tile (0 where no kernel ran; block_k = s is one
+    # tile a row); kv_heads fewer than heads under grouped-query attention;
+    # window: the sliding window the lowering applies (0: none, also for one
+    # no shorter than s)
+    "attention_lowering_total": (
+        COUNT, ("impl", "s", "block_q", "block_k", "kv_heads", "window",
+                "heads", "head_dim"),
+        "fused_attention ops compiled, by the lowering each took"),
+    # amount: the K tiles the forward kernel passes over for one (batch,
+    # head) (state=visited) and those a causal op leaves out because they lie
+    # wholly above the diagonal or behind the window (skipped), from static
+    # shapes (k_tiles); kernels only
+    "attention_k_tiles_total": (
+        COUNT, ("state", "window"),
+        "K tiles a (batch, head) of the compiled flash-attention "
+        "ops' forward kernels"),
+    # stats: saved (the backward kernel read the forward op's Lse; no forward
+    # lowered in the grad op) / recomputed (the kernels on a desc without
+    # Lse: the generic grad lowers the forward kernel again for them) /
+    # generic (jax.vjp over another lowering)
+    "attention_backward_total": (
+        COUNT, ("stats",),
+        "fused_attention_grad ops compiled, by where the softmax "
+        "statistics came from"),
+    # ops/math_ops.py. form: written (the closed form written once over the
+    # logits' own buffer) / fused (the same expression left to XLA: logits
+    # under WRITTEN_GRAD_MIN_BYTES, a mesh) / generic (jax.vjp over the
+    # forward: float32 logits, soft labels, another axis, an ignore_index, a
+    # desc without Lse)
+    "loss_backward_total": (
+        COUNT, ("form",),
+        "softmax_with_cross_entropy_grad ops compiled, by the form "
+        "of the logits' gradient"),
+    # ops/decoder_ops.py. direction: forward / backward; form: kernel (the
+    # one-pass kernel of ops/pallas_rope.py; the backward is the same pass on
+    # the cotangent and lowers no forward) / composed (the rotation left to
+    # XLA) / generic (a grad op without a cotangent)
+    "rotary_lowering_total": (
+        COUNT, ("direction", "form"),
+        "rotary_embedding ops and grad ops compiled, by form"),
+    # core/registry.py:bernoulli_mask. draw: shard (each of the data axis'
+    # `shards` devices drew its own part of the batch in a shard_map island)
+    # / global (one draw at the whole shape, shards=1); the flash kernels'
+    # in-kernel dropout is not counted
+    "mask_draw_total": (
+        COUNT, ("draw", "shards"),
+        "ops that drew a dropout mask, by how the mask was drawn"),
+    # ops/tensor_ops.py. layout: shard (the gathered rows left the op's
+    # island laid over the data axis) / global (a plain take)
+    "gather_layout_total": (
+        COUNT, ("layout", "shards"),
+        "gather ops along axis 0, by the layout of their rows"),
+    # impl: pallas (ops/pallas_ssd.py) / composed (the chunked form in
+    # jax.numpy)
+    "ssd_lowering_total": (
+        COUNT, ("impl", "chunk", "heads", "state"),
+        "ssd_scan ops compiled, by the lowering each took"),
+    # form: gated (LFM2's) / plain (a Mamba mixer's)
+    "short_conv_lowering_total": (
+        COUNT, ("impl", "form", "activation", "taps"),
+        "short_conv ops compiled, by form and the lowering each took"),
+    # operands: packed (the kernels read q, k and v in place in the one array
+    # the op was given) / split (three operands, given or cut out of it)
+    "delta_lowering_total": (
+        COUNT, ("impl", "chunk", "heads", "key_dim", "value_dim",
+                "operands"),
+        "gated_delta_rule ops compiled, by the lowering and the "
+        "operand form each took"),
+    # amount: a moe_dispatch op's row budget (attr rows), its assignments
+    # without one; the sort's output, the grouped products, swiglu and the
+    # combine are sized by it
+    "moe_row_budget": (
+        GAUGE, (),
+        "sorted rows the expert layers keep a step, all layers: the "
+        "assignments without a row budget, the budgets with one"),
+}
+
+
+def note(notes: dict, salt: int, family: str, amount, labels: dict) -> None:
+    """Keep one lowering's report in ``notes`` (``LowerCtx.report``); a
+    family or a set of labels that ``FAMILIES`` does not declare is
+    refused."""
+    if family not in FAMILIES:
+        raise KeyError(
+            f"lowering metric {family!r} is not declared in "
+            f"observability/lowerings.py:FAMILIES ({', '.join(FAMILIES)})")
+    names = FAMILIES[family][1]
+    if set(labels) != set(names):
+        raise KeyError(f"lowering metric {family!r} takes the labels "
+                       f"{names}, not {tuple(labels)}")
+    notes[family, salt, tuple(sorted(labels.items()))] = amount
+
+
+def publish(notes: dict, program: str,
+            registry: Optional[MetricsRegistry] = None) -> None:
+    """Add the reports of one compile to the registry and empty them.
+    ``notes`` maps ``(family, op salt, ((label, value), ...))`` to an amount
+    (a Program's ``_lowering_notes``); label values go through ``str``.
+    Nothing is added for a family no op of the program reported."""
+    registry = registry or REGISTRY
+    totals = Counter()
+    for (family, _, labels), amount in notes.items():
+        totals[family, labels] += amount
+    notes.clear()
+    for (family, labels), amount in totals.items():
+        kind, _, help = FAMILIES[family]
+        labels = {name: str(value) for name, value in labels}
+        if kind == COUNT:
+            registry.counter(family, help, program=program,
+                             **labels).inc(amount)
+        else:
+            registry.gauge(family, help, program=program,
+                           **labels).set(float(amount))

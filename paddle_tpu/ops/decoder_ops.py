@@ -136,23 +136,22 @@ def _rotary_pass(ctx, x, backward):
     """The op's one pass over ``x [..., S, D]``, forward or (``backward``:
     x is the cotangent, sin's sign turned) the transpose of it; float32
     inside, one rounding to x's dtype. The Pallas kernel of
-    ``ops/pallas_rope.py`` where it can run and takes the shape
-    (``supports``; off a mesh, which cannot partition a Mosaic call; a grad
-    op up to ``ROTARY_GRAD_KERNEL_MAX_DIM``), else the rotation composed in
-    ``jax.numpy``. Which it was is noted for
-    ``rotary_lowering_total`` (observability/rotary.py)."""
+    ``ops/pallas_rope.py`` where ``pallas_mode.lowers_kernels`` says so and
+    it takes the shape (``supports``; a grad op up to
+    ``ROTARY_GRAD_KERNEL_MAX_DIM``), else the rotation composed in
+    ``jax.numpy``. Which it was is reported as ``rotary_lowering_total``."""
     import jax.numpy as jnp
     from . import pallas_mode, pallas_rope
     S, D = x.shape[-2:]
     cos, sin, rot = _rotary_tables(ctx, S, D)
     if backward:
         sin = -sin
-    fits = (pallas_rope.supports(S, D) and ctx.mesh is None
-            and ctx.gspmd_mesh is None
+    fits = (pallas_rope.supports(S, D)
             and not (backward and D > ROTARY_GRAD_KERNEL_MAX_DIM))
-    kernel = pallas_mode.lowers_kernels("auto", fits, ctx.abstract)
-    ctx.note("rotary_embedding_grad" if backward else "rotary_embedding",
-             "kernel" if kernel else "composed")
+    kernel = pallas_mode.lowers_kernels(ctx, "auto", fits)
+    ctx.report("rotary_lowering_total",
+               direction="backward" if backward else "forward",
+               form="kernel" if kernel else "composed")
     if kernel:
         return pallas_rope.rotate(x, cos, sin, rot, pallas_mode.interpret())
     # a slice, a roll of its lanes by half, a concatenate for the tail: what
@@ -187,7 +186,8 @@ def rotary_embedding_grad(ctx, ins, generic):
     op without a cotangent is ``generic``."""
     g = ins.get("Out@GRAD", [None])[0]
     if g is None:
-        ctx.note("rotary_embedding_grad", "generic")
+        ctx.report("rotary_lowering_total", direction="backward",
+                   form="generic")
         return generic()
     return {"X@GRAD": [_rotary_pass(ctx, g, backward=True)]}
 
@@ -389,6 +389,7 @@ def moe_dispatch(ctx, ins):
     count = jnp.sum(flat[:, None] == jnp.arange(n_experts, dtype=jnp.int32),
                     axis=0, dtype=jnp.int32)
     budget = int(ctx.attr("rows", 0))
+    ctx.report("moe_row_budget", budget or flat.shape[0])
     to_rows, _, to_row_weights = _movers(bool(budget))
     if not budget:
         return {"Out": [to_rows(x, order, slot)],
@@ -410,18 +411,17 @@ def moe_dispatch(ctx, ins):
                         .astype(jnp.int32)]}
 
 
-def grouped_matmul(x, w, count):
+def grouped_matmul(x, w, count, kernels: bool):
     """``x [A, K]`` rows sorted by group, ``w [G, K, N]``, ``count`` rows a
     group (summing to A) -> ``[A, N]`` in x's dtype: row a times the
     weight of its group, accumulated in float32. With weights for the first
     G of ``count``'s groups only (an expert layer that holds a part of its
     experts), the later groups' rows are not computed and come out zero, in
-    the product and in both gradients: megablox visits the groups it has
-    weights for and zeroes the rest, ``ragged_dot`` leaves rows beyond its
-    group sizes zero."""
+    the product and in both gradients: megablox's kernels (``kernels``)
+    visit the groups they have weights for and zero the rest,
+    ``ragged_dot`` leaves rows beyond its group sizes zero."""
     import jax
-    from . import pallas_mode
-    if pallas_mode.on_tpu():
+    if kernels:
         from jax.experimental.pallas.ops.tpu.megablox import ops as megablox
         tiling = tuple(min(t, d) for t, d in
                        zip(GMM_TILING, (x.shape[0], x.shape[1], w.shape[2])))
@@ -436,9 +436,14 @@ def moe_expert_matmul(ctx, ins):
     """One of an expert layer's products over the sorted rows: ``X [A, K]``,
     stacked ``W [E, K, N]``, ``Count [E]`` -> ``Out [A, N]``; with ``W``
     stacking the first G < E groups only, the rows after theirs are zero
-    (``grouped_matmul``)."""
+    (``grouped_matmul``): megablox's kernels where
+    ``pallas_mode.lowers_kernels`` says so (they take every shape, but are
+    not run in the test harness' interpreter), ``ragged_dot`` elsewhere."""
+    from . import pallas_mode
+    kernels = pallas_mode.lowers_kernels(ctx, "auto",
+                                         not pallas_mode.interpret())
     return {"Out": [grouped_matmul(ins["X"][0], ins["W"][0],
-                                   ins["Count"][0])]}
+                                   ins["Count"][0], kernels)]}
 
 
 @register("moe_combine", nondiff_inputs=("Order", "Slot"))
@@ -499,19 +504,17 @@ def short_conv(ctx, ins):
     seq, (rows, wide) = int(ctx.attr("seq")), x.shape
     chan, taps = wide // 3 if gated else wide, w.shape[1]
     impl = ctx.attr("impl", "auto")
-    fits = pallas_short_conv.supports(seq, chan, taps, bias is not None)
-    if impl == "pallas":
-        pallas_mode.require("short_conv impl='pallas'")
-        if not fits:
-            raise ValueError(
-                f"short_conv impl='pallas' needs channels % "
-                f"{pallas_short_conv.BLK_C} == 0, seq % 16 == 0 and at most "
-                f"{pallas_short_conv.MAX_SEQ}; got seq={seq}, "
-                f"channels={chan}, taps={taps}")
-    kernels = pallas_mode.lowers_kernels(impl, fits, ctx.abstract)
-    ctx.note("short_conv", ("pallas" if kernels else "composed",
-                            "gated" if gated else "plain", act or "none",
-                            taps))
+    kernels = pallas_mode.lowers_kernels(
+        ctx, impl, pallas_short_conv.supports(seq, chan, taps,
+                                              bias is not None),
+        "short_conv",
+        f"needs channels % {pallas_short_conv.BLK_C} == 0, seq % 16 == 0 "
+        f"and at most {pallas_short_conv.MAX_SEQ}; got seq={seq}, "
+        f"channels={chan}, taps={taps}")
+    ctx.report("short_conv_lowering_total",
+               impl="pallas" if kernels else "composed",
+               form="gated" if gated else "plain", activation=act or "none",
+               taps=taps)
     if kernels:
         return {"Out": [pallas_short_conv.short_conv(
             x, w, seq, pallas_mode.interpret(), bias, gated, act)]}
@@ -588,7 +591,7 @@ def ssd_scan(ctx, ins):
     ``ops/pallas_ssd.py`` where they can run (a TPU, or the test harness'
     interpreter) and take the shapes, else ``composed_ssd_scan``; ``pallas``
     / ``composed`` force one. Which one an op took is counted at each compile
-    (``ctx.note``; observability/ssm.py)."""
+    (``ssd_lowering_total``; observability/lowerings.py)."""
     from . import pallas_mode, pallas_ssd
     x, dt, a, bm, cm, d = (ins[k][0] for k in ("X", "Dt", "A", "B", "C", "D"))
     _, seq, heads, p = x.shape
@@ -597,18 +600,14 @@ def ssd_scan(ctx, ins):
     if seq % chunk:
         raise ValueError(f"ssd_scan: chunk {chunk} must divide seq {seq}")
     impl = ctx.attr("impl", "auto")
-    fits = pallas_ssd.supports(seq, heads, p, n, chunk)
-    if impl == "pallas":
-        pallas_mode.require("ssd_scan impl='pallas'")
-        if not fits:
-            raise ValueError(
-                f"ssd_scan impl='pallas' needs heads of "
-                f"{pallas_ssd.HEAD_DIM}, heads % {pallas_ssd.HEADS} == 0, "
-                f"state % 128 == 0 and chunk % 128 == 0; got heads={heads} "
-                f"of {p}, state={n}, chunk={chunk}")
-    kernels = pallas_mode.lowers_kernels(impl, fits, ctx.abstract)
-    ctx.note("ssd_scan", ("pallas" if kernels else "composed", chunk, heads,
-                          n))
+    kernels = pallas_mode.lowers_kernels(
+        ctx, impl, pallas_ssd.supports(seq, heads, p, n, chunk), "ssd_scan",
+        f"needs heads of {pallas_ssd.HEAD_DIM}, heads % {pallas_ssd.HEADS} "
+        f"== 0, state % 128 == 0 and chunk % 128 == 0; got heads={heads} of "
+        f"{p}, state={n}, chunk={chunk}")
+    ctx.report("ssd_lowering_total",
+               impl="pallas" if kernels else "composed", chunk=chunk,
+               heads=heads, state=n)
     if kernels:
         return {"Y": [pallas_ssd.ssd_scan(x, dt, a, bm, cm, d, chunk,
                                           pallas_mode.interpret())]}
@@ -728,16 +727,13 @@ def _delta_plan(ctx, q, k, v, qkv):
         raise ValueError(
             f"gated_delta_rule: chunk {chunk} must divide seq {seq}")
     impl = ctx.attr("impl", "auto")
-    fits = pallas_delta.supports(seq, key_heads, heads, dk, dv, chunk)
-    if impl == "pallas" and not ctx.abstract:
-        pallas_mode.require("gated_delta_rule impl='pallas'")
-        if not fits:
-            raise ValueError(
-                f"gated_delta_rule impl='pallas' needs key and value heads "
-                f"of {pallas_delta.HEAD_DIM} and a chunk of "
-                f"{pallas_delta.CHUNKS} that divides seq; got heads of "
-                f"{dk} / {dv}, chunk={chunk}, seq={seq}")
-    if not pallas_mode.lowers_kernels(impl, fits, ctx.abstract):
+    if not pallas_mode.lowers_kernels(
+            ctx, impl,
+            pallas_delta.supports(seq, key_heads, heads, dk, dv, chunk),
+            "gated_delta_rule",
+            f"needs key and value heads of {pallas_delta.HEAD_DIM} and a "
+            f"chunk of {pallas_delta.CHUNKS} that divides seq; got heads of "
+            f"{dk} / {dv}, chunk={chunk}, seq={seq}"):
         return None, chunk
     if qkv is not None and pallas_delta.packs(key_heads, heads):
         return qkv, chunk
@@ -775,15 +771,17 @@ def gated_delta_rule(ctx, ins):
     operands: ``Q`` / ``K`` / ``V``, or column ranges cut out of a ``QKV``
     the kernels' blocks cannot address, and always the composed form).
     Which lowering and which operand form an op took is counted at each
-    compile (``ctx.note``; observability/ssm.py)."""
+    compile (``delta_lowering_total``; observability/lowerings.py)."""
     import jax.numpy as jnp
     from . import pallas_delta, pallas_mode
     q, k, v, g, beta, qkv = _delta_inputs(ctx, ins)
     operands, chunk = _delta_plan(ctx, q, k, v, qkv)
-    ctx.note("gated_delta_rule", (
-        "composed" if operands is None else "pallas", chunk, v.shape[2],
-        q.shape[3], v.shape[3],
-        "split" if operands is None or operands is not qkv else "packed"))
+    ctx.report(
+        "delta_lowering_total",
+        impl="composed" if operands is None else "pallas", chunk=chunk,
+        heads=v.shape[2], key_dim=q.shape[3], value_dim=v.shape[3],
+        operands=("split" if operands is None or operands is not qkv
+                  else "packed"))
     if operands is not None:
         o, states = pallas_delta.chunked(
             operands, _chunk_sums(g, chunk), beta.astype(jnp.float32), chunk,

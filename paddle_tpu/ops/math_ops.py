@@ -179,13 +179,12 @@ def softmax_with_cross_entropy_grad(ctx, ins, generic):
     the softmax in its operand; below that it is ``fused``, the same
     expression left to XLA. Every other case (soft labels, another axis, an
     ``ignore_index``, float32 logits, a desc from before the op had ``Lse``)
-    is ``generic``. Which it was is noted for ``loss_backward_total``
-    (observability/loss.py)."""
+    is ``generic``. Which it was is reported as ``loss_backward_total``."""
     import jax
     logits, label = ins["Logits"][0], ins["Label"][0]
     lse, g = ins.get("Lse", [None])[0], ins.get("Loss@GRAD", [None])[0]
     if not _lean_loss(ctx, logits) or lse is None or g is None:
-        ctx.note("softmax_with_cross_entropy_grad", "generic")
+        ctx.report("loss_backward_total", form="generic")
         return generic()
     V = logits.shape[-1]
     x = logits.reshape((-1, V))
@@ -194,8 +193,7 @@ def softmax_with_cross_entropy_grad(ctx, ins, generic):
     trips = math.gcd(x.shape[0], WRITTEN_GRAD_CHUNKS)
     written = (x.size * x.dtype.itemsize >= WRITTEN_GRAD_MIN_BYTES
                and trips > 1 and ctx.mesh is None and ctx.gspmd_mesh is None)
-    ctx.note("softmax_with_cross_entropy_grad",
-             "written" if written else "fused")
+    ctx.report("loss_backward_total", form="written" if written else "fused")
     if not written:
         dx = _loss_grad_rows(x, lse, lab, g)
         return {"Logits@GRAD": [dx.reshape(logits.shape)]}

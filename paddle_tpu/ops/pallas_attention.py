@@ -953,22 +953,17 @@ def _plan(ctx, q, k, v, bias):
         return "ring", scale, dropout, causal, None, None
 
     bias_shape = None if bias is None else bias.shape
-    if impl == "pallas":
-        pallas_mode.require("fused_attention impl='pallas'")
-        if not supports_pallas(B, H, S, D, bias_shape, dropout, is_tpu):
-            raise ValueError(
-                f"fused_attention impl='pallas' requires S % {_MIN_BLK_Q} "
-                f"== 0, a [B,1,1,S] bias, and (for dropout>0) a TPU; got "
-                f"S={S}, bias={bias_shape}, dropout={dropout}, "
-                f"backend_tpu={is_tpu}. Use impl='auto' to let the op "
-                f"choose the composed lowering.")
-    # A Mosaic call has no partitioning rule: a jit over more than one device
-    # refuses to lower one outside a shard_map ("Mosaic kernels cannot be
-    # automatically partitioned"). Under such a mesh only the islands above
-    # hold the kernels; 'auto' takes the composed lowering, which GSPMD
-    # partitions by batch and heads, at every S and whatever a persisted
-    # decision says (a batch/head island of its own: PERF.md section 7).
-    one_device = gm is None or gm.size == 1
+    # under a mesh of several devices only the islands above hold the
+    # kernels; 'auto' takes the composed lowering at every S and whatever a
+    # persisted decision says (a batch/head island of its own: PERF.md
+    # section 7)
+    kernels = pallas_mode.lowers_kernels(
+        ctx, impl, supports_pallas(B, H, S, D, bias_shape, dropout, is_tpu),
+        "fused_attention",
+        f"requires S % {_MIN_BLK_Q} == 0, a [B,1,1,S] bias, and (for "
+        f"dropout>0) a TPU; got S={S}, bias={bias_shape}, "
+        f"dropout={dropout}, backend_tpu={is_tpu}. Use impl='auto' to let "
+        f"the op choose the composed lowering.")
     # impl='auto' backend + block sizes are tunable choice points: a
     # persisted autotune decision (PADDLE_TPU_TUNE=cached/search) answers
     # where there is one, else the defaults measured on the v5e
@@ -979,10 +974,8 @@ def _plan(ctx, q, k, v, bias):
                    "causal": causal, "scale": scale}
     if window is not None:      # a bucket of its own, and its own defaults
         tune_params["window"] = window
-    if impl == "pallas" or (
-            impl == "auto" and one_device and pallas_mode.available() and
-            supports_pallas(B, H, S, D, bias_shape, dropout, is_tpu) and
-            _decide("fused_attention.backend", tune_params) == "pallas"):
+    if kernels and (impl == "pallas" or _decide(
+            "fused_attention.backend", tune_params) == "pallas"):
         return "pallas", scale, dropout, causal, tuple(int(b) for b in _decide(
             "fused_attention.block_sizes", tune_params)), window
     return "xla", scale, dropout, causal, None, window
@@ -1034,7 +1027,7 @@ def fused_attention(ctx, ins):
     fusion is measurably faster) where the jit spans one device, else the
     composed jnp path (a dp or mp mesh without sp: a Mosaic call cannot be
     partitioned by GSPMD). Which one an op took is counted at each compile
-    (``ctx.note``; observability/attention.py).
+    (``attention_lowering_total``; observability/lowerings.py).
     """
     import jax
     import jax.numpy as jnp
@@ -1062,16 +1055,20 @@ def fused_attention(ctx, ins):
             ctx.rng(), window=ctx.attr("window", 0))], "Lse": [no_stats()]}
 
     impl, scale, dropout, causal, blocks, window = _plan(ctx, q, k, v, bias)
-    shape = (window or 0, H, D)
+    block_q, block_k = blocks or (0, 0)
+    ctx.report("attention_lowering_total", impl=impl, s=S, block_q=block_q,
+               block_k=block_k, kv_heads=kv_heads, window=window or 0,
+               heads=H, head_dim=D)
     if impl == "pallas":
         from . import pallas_mode
-        ctx.note("fused_attention", ("pallas", S, *blocks, kv_heads)
-                 + k_tiles(S, *blocks, causal, window) + shape)
+        for state, tiles in zip(("visited", "skipped"),
+                                k_tiles(S, *blocks, causal, window)):
+            ctx.report("attention_k_tiles_total", tiles, state=state,
+                       window=window or 0)
         out, lse = _flash_stats(q, k, v, bias, _kernel_seed(ctx, dropout),
                                 scale, dropout, causal,
                                 pallas_mode.interpret(), *blocks, window)
         return {"Out": [out], "Lse": [lse]}
-    ctx.note("fused_attention", (impl, S, 0, 0, kv_heads, 0, 0) + shape)
     if impl == "xla":
         out = composed_attention(q, k, v, bias, scale, dropout, causal,
                                  ctx.rng(), ctx.bernoulli_mask, window)
@@ -1098,18 +1095,18 @@ def fused_attention_grad(ctx, ins, generic):
     case is the generic grad (``jax.vjp`` over the forward's lowering): the
     composed lowering, a mesh, and a desc from before the op had ``Lse``
     (on the kernels that one lowers the forward kernel a second time for
-    the statistics). Which it was is noted for ``attention_backward_total``
-    (observability/attention.py)."""
+    the statistics). Which it was is reported as
+    ``attention_backward_total``."""
     q, k, v = ins["Q"][0], ins["K"][0], ins["V"][0]
     bias = ins.get("Bias", [None])[0]
     lse, g = ins.get("Lse", [None])[0], ins.get("Out@GRAD", [None])[0]
     impl, scale, dropout, causal, blocks, window = _plan(ctx, q, k, v, bias)
     if impl != "pallas" or lse is None or g is None:
-        ctx.note("fused_attention_grad",
-                 "recomputed" if impl == "pallas" else "generic")
+        ctx.report("attention_backward_total",
+                   stats="recomputed" if impl == "pallas" else "generic")
         return generic()
     from . import pallas_mode
-    ctx.note("fused_attention_grad", "saved")
+    ctx.report("attention_backward_total", stats="saved")
     dq, dk, dv = _bwd_call(
         q, k, v, bias, _kernel_seed(ctx, dropout), g.astype(q.dtype), lse,
         scale, dropout, causal, pallas_mode.interpret(), *blocks, window)

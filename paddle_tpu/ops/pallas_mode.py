@@ -1,13 +1,21 @@
 """Where a Pallas TPU kernel may run in this process.
 
-One rule for every kernel (``pallas_attention``, ``pallas_int8``,
-``pallas_conv_bn``) and for the autotuner's candidates: on platform ``tpu``
-the kernel is compiled by Mosaic, always. Off TPU it does not run at all --
-``impl='auto'`` lowers the composed XLA path, ``impl='pallas'`` raises, and a
-tuning candidate that needs the kernel is unmeasurable -- unless the test
-harness has asked for the Pallas interpreter by setting ``TEST_INTERPRET``
-(``tests/conftest.py`` does; nothing else may). A number timed in the
-interpreter says nothing about the chip, so no production path reaches it.
+On platform ``tpu`` a kernel is compiled by Mosaic, always. Off TPU it does
+not run at all -- ``impl='auto'`` lowers the composed XLA path,
+``impl='pallas'`` raises, and a tuning candidate that needs the kernel is
+unmeasurable -- unless the test harness has asked for the Pallas interpreter
+by setting ``TEST_INTERPRET`` (``tests/conftest.py`` does; nothing else
+may). A number timed in the interpreter says nothing about the chip, so no
+production path reaches it.
+
+``lowers_kernels`` is the one rule by which an op with a kernel and a
+composed form chooses between them. The lowerings that ask it:
+``fused_attention`` and its grad op (``pallas_attention._plan``),
+``rotary_embedding`` and its grad op, ``short_conv``, ``ssd_scan``,
+``gated_delta_rule`` and its grad op, and ``moe_expert_matmul`` (megablox's
+``gmm``), those five in ``decoder_ops``. ``conv2d_bn_fused`` (``pallas_conv_bn``)
+and the int8 matmul (``contrib/quantize``) still test the platform
+themselves (ROADMAP D2).
 """
 from __future__ import annotations
 
@@ -32,13 +40,33 @@ def available() -> bool:
     return on_tpu() or TEST_INTERPRET
 
 
-def lowers_kernels(impl: str, fits: bool, abstract: bool) -> bool:
-    """Whether an op with attr ``impl`` (``auto`` / ``pallas`` / another
-    lowering's name) lowers its Pallas kernels here: asked for by name, or
-    ``auto`` where the shapes fit and a kernel can run; never under shape
-    inference (``abstract``), where every lowering gives the same shapes."""
-    return not abstract and (impl == "pallas" or (
-        impl == "auto" and fits and available()))
+def lowers_kernels(ctx, impl: str, fits: bool, what: str = "",
+                   needs: str = "") -> bool:
+    """Whether the op being lowered under ``ctx`` (a ``LowerCtx``), with attr
+    ``impl`` (``auto`` / ``pallas`` / another lowering's name), lowers its
+    Pallas kernels here; ``fits`` is the op's own answer to whether the
+    kernels take its shapes. Never under shape inference (``ctx.abstract``),
+    where every lowering gives the same shapes. ``pallas``: yes, and what
+    cannot run raises -- no kernel can run here (``require``), or the shapes
+    do not fit (a ``ValueError`` of ``what`` and ``needs``, the op's name and
+    its own sentence of what the kernels need and what they got). ``auto``:
+    where the shapes fit, a kernel can run, and the jit being traced spans
+    one device. A Mosaic call has no partitioning rule: a jit over more than
+    one device refuses to lower one outside a ``shard_map`` ("Mosaic kernels
+    cannot be automatically partitioned"; seen on the chip, PR 27), so under
+    a GSPMD mesh of several devices ``auto`` is the composed form, which
+    GSPMD partitions. Inside a ``shard_map`` (``ctx.mesh``, no GSPMD mesh)
+    the call is legal and stays allowed."""
+    if ctx.abstract:
+        return False
+    if impl == "pallas":
+        require(f"{what} impl='pallas'")
+        if not fits:
+            raise ValueError(f"{what} impl='pallas' {needs}")
+        return True
+    gm = ctx.gspmd_mesh
+    return (impl == "auto" and fits and available()
+            and (gm is None or gm.size == 1))
 
 
 def require(what: str) -> None:

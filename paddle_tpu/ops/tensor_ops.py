@@ -165,7 +165,8 @@ def gather(ctx, ins):
         n = 1
         if jnp.issubdtype(x.dtype, jnp.number):     # rows that psum can add
             n = ctx.data_shards(*x.shape[:1], *idx.shape[:1])
-        ctx.note("gather_layout", ("shard", n) if n > 1 else ("global", 1))
+        ctx.report("gather_layout_total",
+                   layout="shard" if n > 1 else "global", shards=n)
         if n > 1:
             return {"Out": [_rows_over_data_axis(
                 ctx.gspmd_mesh, ctx.data_axis, n, x, idx)]}

@@ -11,10 +11,11 @@ import jax.numpy as jnp
 import numpy as np
 import pytest
 
+import lowering_reports
 import paddle_tpu as fluid
 from paddle_tpu import layers
 from paddle_tpu.models import decoder_lm
-from paddle_tpu.observability import moe as obs_moe
+from paddle_tpu.observability.metrics import REGISTRY
 from benchmark.references import lfm2_pretrain as reference
 from test_decoder_ops import close, rng, run_with_grads
 
@@ -92,10 +93,8 @@ def test_short_conv_leaks_nothing_across_sequence_starts(seq, chan):
         y = layers.short_conv(v, seq, 3, fluid.ParamAttr(name="f"))
     assert tuple(main.global_block().var("f").shape) == (chan, 3)
     assert tuple(y.shape) == (2 * seq, chan)
-    registry = obs_moe.MetricsRegistry()
-    obs_moe.update_moe_gauges(main, "p", registry)
-    assert registry.get("short_conv_layers")
-    assert registry.get("moe_layers") is None
+    kinds = [op.type for op in main.global_block().ops]
+    assert kinds.count("short_conv") == 1 and "moe_dispatch" not in kinds
 
 
 def repeated(k, group):
@@ -298,12 +297,18 @@ def test_gauges_say_what_a_layer_holds():
         xv = fluid.data("x", [6, 16], "float32", append_batch_size=False)
         layers.moe_ffn(xv, 32, 4, 8, name="m", experts_held=(8, 8),
                        scoring="sigmoid", norm_topk=True)
-    registry = obs_moe.MetricsRegistry()
-    obs_moe.update_moe_gauges(main, "p", registry)
-    read = {n: next(c.value for _, c in registry.get(n).items()) for n in (
-        "moe_experts", "moe_experts_held", "moe_assignments_per_step")}
-    assert read == {"moe_experts": 32, "moe_experts_held": 8,
-                    "moe_assignments_per_step": 24}
+    # what the layer holds, off the Program: 32 experts scored, the weights
+    # of 8 stacked, a sorted row for each of the 6 x 4 assignments (no
+    # budget), which is what the compiled step's gauge then says
+    block = main.global_block()
+    (sort,) = [op for op in block.ops if op.type == "moe_dispatch"]
+    assert sort.attr("num_experts") == 32 and not sort.attr("rows", 0)
+    assert {int(block.find_var_recursive(op.inputs["W"][0]).shape[0])
+            for op in block.ops if op.type == "moe_expert_matmul"} == {8}
+    label = lowering_reports.step(main, startup,
+                                  {"x": np.zeros((6, 16), "float32")})
+    assert lowering_reports.read(REGISTRY, "moe_row_budget",
+                                 "program")[label] == 24
     with pytest.raises(Exception, match="channels % 128"):
         run_with_grads(lambda a, b: _short_conv_with(a, b, 3, "pallas"),
                        {"x": np.zeros((6, 24), "float32"),

@@ -13,10 +13,11 @@ import jax.numpy as jnp
 import numpy as np
 import pytest
 
+import lowering_reports
 import paddle_tpu as fluid
 from paddle_tpu import layers
 from paddle_tpu.models import decoder_lm
-from paddle_tpu.observability import moe as obs_moe
+from paddle_tpu.observability.metrics import REGISTRY
 from benchmark.references import laguna_pretrain as reference
 from tests.test_decoder_ops import close, rng, run_with_grads
 
@@ -278,20 +279,20 @@ def test_a_budget_needs_a_held_range_and_fits_the_assignments():
 
 
 def test_gauges_say_budget_and_shared_expert():
-    main = _layer(6, 16, 8, 32, 4, (8, 8), budget=12, shared=24)[0]
-    registry = obs_moe.MetricsRegistry()
-    obs_moe.update_moe_gauges(main, "p", registry)
-    read = {n: next(c.value for _, c in registry.get(n).items()) for n in (
-        "moe_experts_held", "moe_assignments_per_step", "moe_row_budget",
-        "moe_shared_experts", "moe_shared_width")}
-    assert read == {"moe_experts_held": 8, "moe_assignments_per_step": 24,
-                    "moe_row_budget": 12, "moe_shared_experts": 1,
-                    "moe_shared_width": 24}
-    plain = _layer(6, 16, 8, 32, 4, (8, 8))[0]
-    obs_moe.update_moe_gauges(plain, "q", registry)
-    by_program = {dict(k)["program"]: c.value
-                  for k, c in registry.get("moe_row_budget").items()}
-    assert by_program == {"p": 12, "q": 24}     # no budget: every assignment
+    """``moe_row_budget`` of a compiled step is the layer's budget and,
+    without one, its 6 x 4 assignments; the shared expert is a parameter of
+    the Program."""
+    feed = {"x": np.zeros((6, 16), "float32")}
+    main, startup = _layer(6, 16, 8, 32, 4, (8, 8), budget=12, shared=24)[:2]
+    block = main.global_block()
+    assert tuple(block.var("m_shared_gate_w").shape) == (16, 24)
+    assert {int(block.find_var_recursive(op.inputs["W"][0]).shape[0])
+            for op in block.ops if op.type == "moe_expert_matmul"} == {8}
+    budgeted = lowering_reports.step(main, startup, feed)
+    plain = lowering_reports.step(*_layer(6, 16, 8, 32, 4, (8, 8))[:2], feed)
+    by_program = lowering_reports.read(REGISTRY, "moe_row_budget", "program")
+    # no budget: every assignment
+    assert (by_program[budgeted], by_program[plain]) == (12, 24)
 
 
 MODEL = {

@@ -429,22 +429,30 @@ def test_moe_ops_have_no_capacity_attribute():
 
 
 def test_moe_gauges_and_load_stats():
-    from paddle_tpu.observability.metrics import MetricsRegistry
+    """``moe_row_budget`` of a compiled step: without a budget, every
+    assignment of every expert layer has a row (2 layers x 8 tokens x top-2),
+    as the ``moe_dispatch`` lowerings report it; a program without an expert
+    layer sets nothing."""
+    import lowering_reports
+    from paddle_tpu.observability.metrics import REGISTRY
     main, startup = fluid.Program(), fluid.Program()
     with fluid.unique_name.guard(), fluid.program_guard(main, startup):
         x = fluid.data("x", [8, 16], "bfloat16", append_batch_size=False)
         h, _ = layers.moe_ffn(x, 4, 2, 8, name="a")
-        layers.moe_ffn(h, 4, 2, 8, name="b")
-    reg = MetricsRegistry()
-    obs_moe.update_moe_gauges(main, "p", registry=reg)
-    value = lambda n: reg.gauge(n, program="p").value       # noqa: E731
-    assert value("moe_layers") == 2 and value("moe_experts") == 4
-    assert value("moe_assignments_per_step") == 2 * 8 * 2
-    assert value("moe_expert_param_bytes") == 2 * 3 * 4 * 16 * 8 * 2
-    empty = fluid.Program()
-    obs_moe.update_moe_gauges(empty, "q", registry=reg)
-    # a program without an expert layer sets nothing
-    assert set(reg.get("moe_layers").children) == {(("program", "p"),)}
+        out, _ = layers.moe_ffn(h, 4, 2, 8, name="b")
+    ops = main.global_block().ops
+    assert [op.attr("num_experts") for op in ops
+            if op.type == "moe_dispatch"] == [4, 4]
+    label = lowering_reports.step(
+        main, startup, {"x": jnp.zeros((8, 16), jnp.bfloat16)}, [out])
+    plain, plain_startup = fluid.Program(), fluid.Program()
+    with fluid.program_guard(plain, plain_startup):
+        y = layers.scale(fluid.data("x", [8, 16], "float32",
+                                    append_batch_size=False), 2.0)
+    other = lowering_reports.step(
+        plain, plain_startup, {"x": np.zeros((8, 16), "float32")}, [y])
+    budgets = lowering_reports.read(REGISTRY, "moe_row_budget", "program")
+    assert budgets[label] == 2 * 8 * 2 and other not in budgets
     stats = obs_moe.load_stats([4, 0, 8, 4])
     assert stats == {"max": 8.0, "mean": 4.0, "max_over_mean": 2.0,
                      "empty": 1}

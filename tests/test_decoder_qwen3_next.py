@@ -20,9 +20,6 @@ import pytest
 import paddle_tpu as fluid
 from paddle_tpu import layers
 from paddle_tpu.models import decoder_lm
-from paddle_tpu.observability import attention as obs_attention
-from paddle_tpu.observability import moe as obs_moe
-from paddle_tpu.observability.metrics import MetricsRegistry
 from benchmark.references import qwen3_next_pretrain as reference
 from tests.test_decoder_ops import close, rng, run_with_grads
 
@@ -80,12 +77,11 @@ def test_attention_gate_a_channel_matches_its_one_line_form_and_gradient():
             layers.attention_gate(x, fluid.data(
                 f"g{width}", [B * S, width], "float32",
                 append_batch_size=False))
-    registry = MetricsRegistry()
-    obs_attention.update_gate_gauges(main, "p", registry)
-    assert registry.gauge("attention_gate_ops", program="p",
-                          form="per_head").value == 1
-    assert registry.gauge("attention_gate_ops", program="p",
-                          form="elementwise").value == 2
+    # the gate's form is its width: one a token and head, or one a channel
+    block = main.global_block()
+    assert [int(block.find_var_recursive(op.inputs["Gate"][0]).shape[-1])
+            for op in block.ops if op.type == "attention_gate"] == [
+                heads, heads * D, heads * D]
 
 
 MODEL = {
@@ -213,10 +209,10 @@ def test_program_equals_the_reference_in_loss_positions_and_routing(f32):
     assert [op.attr("zero_centered", False) for op in ops
             if op.type == "rms_norm"] == [True, False, True] * 2 + [
                 True, True, True, True, True]
-    registry = MetricsRegistry()
-    obs_moe.update_moe_gauges(f32["b"]["main"], "p", registry)
-    assert registry.gauge("moe_shared_gated", program="p").value == 3
-    assert registry.gauge("moe_shared_experts", program="p").value == 3
+    # each expert layer has a shared expert, under a sigmoid gate a token
+    names = [p.name for p in f32["b"]["main"].global_block().all_parameters()]
+    for suffix in ("_shared_gate_w", "_shared_expert_gate_w"):
+        assert sum(n.endswith(suffix) for n in names) == 3
 
 
 LEAVES = ["tok_emb", "layer0_delta_norm_w", "layer0_delta_in_w",

@@ -186,6 +186,30 @@ def test_import_loads_no_kernel_and_no_optional_subsystem():
     assert out.stdout.strip().splitlines()[-1] == "[]", out.stdout
 
 
+def test_the_executor_names_no_kernel_familys_telemetry():
+    """The seam between an op's lowering and the executor: a lowering names
+    its own metric (``LowerCtx.report``) and one pass publishes them all
+    (``observability/lowerings.py``), so a new kernel family edits its op
+    file and one row of that module's table, not the file every program's
+    compile shares. ``core/executor.py`` imports no per-family telemetry
+    module and ``_post_compile_telemetry`` takes no kind out of the
+    Program's reports by name."""
+    import inspect
+    import re
+
+    from paddle_tpu.core import executor
+    from paddle_tpu.observability import lowerings
+    families = r"(attention|moe|ssm|masks|loss|rotary)"
+    source = inspect.getsource(executor)
+    assert not re.findall(
+        r"observability\." + families + r"\b|observability import "
+        + families + r"\b", source)
+    body = inspect.getsource(executor.Executor._post_compile_telemetry)
+    assert "_lowering_notes.pop(" not in body
+    assert body.count("_lowering_notes") == 1 and "lowerings.publish(" in body
+    assert not [family for family in lowerings.FAMILIES if family in source]
+
+
 def test_cache_keys_of_run_and_run_fused_element_by_element(monkeypatch):
     """The executor's cache key, in its documented order: (program id,
     program version, feed signature, fetch names, seed, XLA options flag,

@@ -16,6 +16,7 @@ import jax
 import jax.numpy as jnp
 import pytest
 
+import lowering_reports
 from paddle_tpu.ops import pallas_attention as pa
 
 
@@ -390,6 +391,14 @@ def test_flash_matches_composed_below_1024(S, dtype):
             np.testing.assert_allclose(x, r, atol=atol, rtol=0)
 
 
+def _expert_matmul(x, w, count):
+    """``moe_expert_matmul``'s lowering, off a mesh."""
+    from paddle_tpu.core.registry import LowerCtx
+    from paddle_tpu.ops import decoder_ops
+    return decoder_ops.moe_expert_matmul(
+        LowerCtx({}), {"X": [x], "W": [w], "Count": [count]})["Out"][0]
+
+
 @pytest.mark.parametrize("rows,k,n", [(131072, 2048, 1024),
                                       (131072, 1024, 2048),
                                       (65536, 2048, 1024)])
@@ -400,17 +409,15 @@ def test_grouped_expert_matmul_compiles_for_v5e(one_chip, as_on_the_chip,
     forward; and what a Program's grad op lowers -- the forward again under
     jax.vjp, its output unused -- holds the rows' and the weights' gradient
     kernels and nothing of the forward."""
-    from paddle_tpu.ops import decoder_ops
     x = jax.ShapeDtypeStruct((rows, k), jnp.bfloat16, sharding=one_chip)
     w = jax.ShapeDtypeStruct((64, k, n), jnp.bfloat16, sharding=one_chip)
     count = jax.ShapeDtypeStruct((64,), jnp.int32, sharding=one_chip)
     g = jax.ShapeDtypeStruct((rows, n), jnp.bfloat16, sharding=one_chip)
 
     def grads(x, w, count, g):
-        return jax.vjp(lambda x, w: decoder_ops.grouped_matmul(x, w, count),
-                       x, w)[1](g)
+        return jax.vjp(lambda x, w: _expert_matmul(x, w, count), x, w)[1](g)
 
-    fwd = jax.jit(decoder_ops.grouped_matmul).lower(x, w, count).compile()
+    fwd = jax.jit(_expert_matmul).lower(x, w, count).compile()
     assert _kernels(fwd) == 1
     assert _kernels(jax.jit(grads).lower(x, w, count, g).compile()) == 2
 
@@ -422,7 +429,6 @@ def test_held_expert_matmul_compiles_for_v5e(one_chip, as_on_the_chip, k, n):
     weights of the 8 held experts only. The megablox kernels visit the held
     groups' row tiles and the rest of the output is zero-filled: the same
     kernel counts as with every expert held."""
-    from paddle_tpu.ops import decoder_ops
     rows = 65536
     x = jax.ShapeDtypeStruct((rows, k), jnp.bfloat16, sharding=one_chip)
     w = jax.ShapeDtypeStruct((8, k, n), jnp.bfloat16, sharding=one_chip)
@@ -430,10 +436,9 @@ def test_held_expert_matmul_compiles_for_v5e(one_chip, as_on_the_chip, k, n):
     g = jax.ShapeDtypeStruct((rows, n), jnp.bfloat16, sharding=one_chip)
 
     def grads(x, w, count, g):
-        return jax.vjp(lambda x, w: decoder_ops.grouped_matmul(x, w, count),
-                       x, w)[1](g)
+        return jax.vjp(lambda x, w: _expert_matmul(x, w, count), x, w)[1](g)
 
-    fwd = jax.jit(decoder_ops.grouped_matmul).lower(x, w, count).compile()
+    fwd = jax.jit(_expert_matmul).lower(x, w, count).compile()
     assert _kernels(fwd) == 1
     back = jax.jit(grads).lower(x, w, count, g)
     assert [tuple(o.shape) for o in back.out_info] == [(rows, k), (8, k, n)]
@@ -584,8 +589,8 @@ def test_decoder_step_holds_no_float32_logits_in_either_form_of_the_loss_grad(
     model = dict(_DECODER, vocab_size=640)
     batch, seq = 4, 128     # 512 tokens: no size equals hidden
     text, main = _decoder_step_text(one_chip, model, batch, seq)
-    assert list(main._lowering_notes.pop(
-        "softmax_with_cross_entropy_grad").values()) == [form]
+    assert lowering_reports.read(lowering_reports.publish(main),
+                                 "loss_backward_total", "form") == {form: 1}
     comps, entry, _ = parse_hlo_computations(text)
     called = {c for ins in comps[entry]
               for c in re.findall(r"(?:body|condition)=%?([\w.\-]+)", ins.rest)}
@@ -659,18 +664,11 @@ def _bert_s512_step(strategy=None, with_lse=True):
 def _lowering_counts(main):
     """What the executor's counters would add for the trace just made:
     {"<impl>@<block_q>": ops} and, for the grad ops, {"<stats>": ops}."""
-    from paddle_tpu.observability import attention as obs_attention
-    from paddle_tpu.observability.metrics import MetricsRegistry
-    registry = MetricsRegistry()
-    obs_attention.count_lowerings(
-        main._lowering_notes.pop("fused_attention"), "step", registry)
-    obs_attention.count_backwards(
-        main._lowering_notes.pop("fused_attention_grad"), "step", registry)
-    counts = {dict(k)["impl"] + "@" + dict(k)["block_q"]: c.value
-              for k, c in registry.get("attention_lowering_total").items()}
-    counts.update(
-        (dict(k)["stats"], c.value)
-        for k, c in registry.get("attention_backward_total").items())
+    registry = lowering_reports.publish(main, "step")
+    counts = {"@".join(k): n for k, n in lowering_reports.read(
+        registry, "attention_lowering_total", "impl", "block_q").items()}
+    counts.update(lowering_reports.read(
+        registry, "attention_backward_total", "stats"))
     return counts
 
 

@@ -182,21 +182,31 @@ def test_the_op_and_its_grad_lowering_carry_the_window(impl):
 
 
 def test_counters_carry_window_heads_and_head_size():
-    from paddle_tpu.observability import attention as obs
-    from paddle_tpu.observability.metrics import MetricsRegistry
-    registry = MetricsRegistry()
-    obs.count_lowerings({
-        1: ("pallas", 4096, 512, 512, 8, 15, 49, 512, 72, 128),
-        2: ("pallas", 4096, 512, 512, 8, 15, 49, 512, 72, 128),
-        3: ("pallas", 4096, 512, 1024, 8, 20, 12, 0, 48, 128)},
-        "step", registry)
-    ops = {(dict(k)["window"], dict(k)["heads"], dict(k)["head_dim"]): c.value
-           for k, c in registry.get("attention_lowering_total").items()}
-    assert ops == {("512", "72", "128"): 2, ("0", "48", "128"): 1}
-    tiles = {(dict(k)["state"], dict(k)["window"]): c.value
-             for k, c in registry.get("attention_k_tiles_total").items()}
-    assert tiles == {("visited", "512"): 30, ("skipped", "512"): 98,
-                     ("visited", "0"): 20, ("skipped", "0"): 12}
+    """Three ops as ``fused_attention`` reports them at the Laguna cell's
+    shapes (two window layers, one full), through the pass the executor
+    runs."""
+    import lowering_reports
+    from paddle_tpu.core.registry import LowerCtx
+    from paddle_tpu.ops.pallas_attention import k_tiles
+    main = fluid.Program()
+    for salt, block_k, window, heads in ((1, 512, 512, 72), (2, 512, 512, 72),
+                                         (3, 1024, 0, 48)):
+        ctx = LowerCtx({}, salt=salt, program=main)
+        ctx.report("attention_lowering_total", impl="pallas", s=4096,
+                   block_q=512, block_k=block_k, kv_heads=8, window=window,
+                   heads=heads, head_dim=128)
+        for state, tiles in zip(("visited", "skipped"), k_tiles(
+                4096, 512, block_k, True, window or None)):
+            ctx.report("attention_k_tiles_total", tiles, state=state,
+                       window=window)
+    registry = lowering_reports.publish(main, "step")
+    assert lowering_reports.read(
+        registry, "attention_lowering_total", "window", "heads",
+        "head_dim") == {("512", "72", "128"): 2, ("0", "48", "128"): 1}
+    assert lowering_reports.read(
+        registry, "attention_k_tiles_total", "state", "window") == {
+            ("visited", "512"): 30, ("skipped", "512"): 98,
+            ("visited", "0"): 20, ("skipped", "0"): 12}
 
 
 def test_the_layer_refuses_a_window_without_causal():

@@ -11,10 +11,10 @@ import jax.numpy as jnp
 import numpy as np
 import pytest
 
+import lowering_reports
 import paddle_tpu as fluid
 from paddle_tpu import layers
-from paddle_tpu.observability import ssm as obs_ssm
-from paddle_tpu.observability.metrics import MetricsRegistry
+from paddle_tpu.core.registry import LowerCtx
 from paddle_tpu.ops import decoder_ops, pallas_delta
 from benchmark.references import qwen3_next_pretrain as reference
 from test_decoder_ops import close, rng, run_with_grads
@@ -320,26 +320,26 @@ def test_gated_delta_rule_refuses_what_it_cannot_chunk_and_counts_its_ops():
              for k, a in feeds.items()]
         y = layers.gated_delta_rule(*v, chunk=4)
     assert tuple(y.shape) == (1, 12, 4, 8)
-    registry = MetricsRegistry()
-    obs_ssm.update_delta_gauges(main, "p", registry)
-    for name, want in (("delta_layers", 1), ("delta_heads", 4),
-                       ("delta_state_bytes", 4 * 8 * 8 * 4),
-                       ("delta_chunks_per_step", 3)):
-        assert registry.gauge(name, program="p").value == want, name
-    obs_ssm.count_delta_lowerings(
-        {1: ("pallas", 64, 32, 128, 128, "packed"),
-         2: ("pallas", 64, 32, 128, 128, "packed"),
-         3: ("pallas", 64, 32, 128, 128, "split"),
-         4: ("composed", 4, 4, 8, 8, "split")}, "p", registry)
-    labels = dict(program="p", impl="pallas", chunk="64", heads="32",
-                  key_dim="128", value_dim="128")
-    assert registry.counter("delta_lowering_total", **labels,
-                            operands="packed").value == 2
-    assert registry.counter("delta_lowering_total", **labels,
-                            operands="split").value == 1
-    obs_ssm.update_delta_gauges(fluid.Program(), "none", registry)
-    assert all(("program", "none") not in labels
-               for labels, _ in registry.get("delta_layers").items())
+    # one op; the states it carries: a sequence x 3 chunks x 4 heads x 8 x 8
+    (op,) = [op for op in main.global_block().ops
+             if op.type == "gated_delta_rule"]
+    assert tuple(main.global_block().find_var_recursive(
+        op.outputs["States"][0]).shape) == (1, 3, 4, 8, 8)
+    big = dict(impl="pallas", chunk=64, heads=32, key_dim=128, value_dim=128)
+    for salt, labels in ((1, dict(big, operands="packed")),
+                         (2, dict(big, operands="packed")),
+                         (3, dict(big, operands="split")),
+                         (4, dict(impl="composed", chunk=4, heads=4, key_dim=8,
+                                  value_dim=8, operands="split"))):
+        LowerCtx({}, salt=salt, program=main).report(
+            "delta_lowering_total", **labels)
+    assert lowering_reports.read(
+        lowering_reports.publish(main), "delta_lowering_total", "impl",
+        "operands") == {("pallas", "packed"): 2, ("pallas", "split"): 1,
+                        ("composed", "split"): 1}
+    assert lowering_reports.read(
+        lowering_reports.publish(fluid.Program(), "none"),
+        "delta_lowering_total", "impl") == {}
 
 
 def lowerings(**want):
@@ -354,7 +354,7 @@ def lowerings(**want):
 def test_a_compiled_step_counts_the_lowering_each_op_took():
     """Through the executor: the forward op's note lands in
     ``delta_lowering_total`` once a compile, whichever lowering and operand
-    form it took; the gauges read a packed op's shapes off its outputs."""
+    form it took; a packed op's shapes are read off its outputs."""
     feeds = rule_inputs(1, 128, 1, 2, 128, 128, seed=6)
     kinds = [dict(impl="pallas", operands="split"),
              dict(impl="pallas", operands="packed"),
@@ -374,12 +374,10 @@ def test_a_compiled_step_counts_the_lowering_each_op_took():
              for k, a in feeds.items()]
         y = rule_with("auto", 64, "packed")(*v)
     assert tuple(y.shape) == (1, 128, 2, 128)
-    registry = MetricsRegistry()
-    obs_ssm.update_delta_gauges(main, "p", registry)
-    for name, want in (("delta_layers", 1), ("delta_heads", 2),
-                       ("delta_state_bytes", 2 * 128 * 128 * 4),
-                       ("delta_chunks_per_step", 2)):
-        assert registry.gauge(name, program="p").value == want, name
+    (op,) = [op for op in main.global_block().ops
+             if op.type == "gated_delta_rule"]
+    assert "QKV" in op.inputs and tuple(main.global_block().find_var_recursive(
+        op.outputs["States"][0]).shape) == (1, 2, 2, 128, 128)
 
 
 def test_one_value_block_short_of_a_whole_offset_falls_back_to_three_operands():

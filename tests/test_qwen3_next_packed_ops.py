@@ -22,7 +22,7 @@ def test_packed_ops_resolves_and_reads_six_on_the_cells_counters():
     is left out of it, and a parent's counter, which has no ``operands``
     label, reads None rather than raising."""
     import importlib
-    from paddle_tpu.observability import ssm as obs_ssm
+    from paddle_tpu.observability import lowerings
     from paddle_tpu.observability.metrics import REGISTRY
     bench = json.load(open(os.path.join(ROOT, "BENCHMARK.json")))
 
@@ -43,14 +43,19 @@ def test_packed_ops_resolves_and_reads_six_on_the_cells_counters():
         f"benchmark.reducers.{spec['reducer']}").reduce
     read = lambda s: reduce(s, None) or 0.0                 # noqa: E731
     model = cell["model"]
-    note = ("pallas", model["delta_chunk_size"],
-            model["linear_num_value_heads"], model["linear_key_head_dim"],
-            model["linear_value_head_dim"])
+    labels = {"impl": "pallas", "chunk": model["delta_chunk_size"],
+              "heads": model["linear_num_value_heads"],
+              "key_dim": model["linear_key_head_dim"],
+              "value_dim": model["linear_value_head_dim"]}
     before = read(spec), read(spec_of("gated_delta.pallas_ops"))
-    for program in ("pr43_test_clone", "pr43_train_step"):
-        obs_ssm.count_delta_lowerings(
-            {salt: note + ("packed",) for salt in range(3)}, program)
-    obs_ssm.count_delta_lowerings({9: note + ("split",)}, "pr43_split")
+    for program, salts, operands in (("pr43_test_clone", range(3), "packed"),
+                                     ("pr43_train_step", range(3), "packed"),
+                                     ("pr43_split", (9,), "split")):
+        notes = {}
+        for salt in salts:
+            lowerings.note(notes, salt, "delta_lowering_total", 1,
+                           dict(labels, operands=operands))
+        lowerings.publish(notes, program)
     assert read(spec) - before[0] == 6.0
     assert read(spec_of("gated_delta.pallas_ops")) - before[1] == 7.0
     # the parent's children carry no operands label

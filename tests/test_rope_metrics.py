@@ -79,9 +79,13 @@ def test_rope_one_pass_ops_counts_a_programs_kernel_ops_both_directions():
             fetch_list=[q])
     exe.close()
     assert read() - before == 4.0       # two ops, forward and backward
-    from paddle_tpu.observability import rotary
-    fresh = MetricsRegistry()
-    rotary.count_lowerings({1: "composed"}, {1: "generic"}, "p", fresh)
+    from paddle_tpu.observability import lowerings
+    fresh, notes = MetricsRegistry(), {}
+    lowerings.note(notes, 1, "rotary_lowering_total", 1,
+                   {"direction": "forward", "form": "composed"})
+    lowerings.note(notes, 1, "rotary_lowering_total", 1,
+                   {"direction": "backward", "form": "generic"})
+    lowerings.publish(notes, "p", fresh)
     forms = [dict(k)["form"] for k, _ in
              fresh.get("rotary_lowering_total").items()]
     assert sorted(forms) == ["composed", "generic"]

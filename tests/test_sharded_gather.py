@@ -223,6 +223,14 @@ def _islands(fn, *args):
     return str(jax.make_jaxpr(fn)(*args)).count("shard_map")
 
 
+def _layouts(program):
+    """{the salt of each op that reported ``gather_layout_total``: its
+    (layout, shards)}, of the trace just made."""
+    return {salt: (dict(labels)["layout"], dict(labels)["shards"])
+            for family, salt, labels in program._lowering_notes
+            if family == "gather_layout_total"}
+
+
 def _gather(ctx, x, idx):
     from paddle_tpu.core import registry
     return registry.get("gather").lower(ctx, {"X": [x], "Index": [idx]})[
@@ -244,14 +252,13 @@ def test_axis_0_under_the_mesh_opens_the_island_and_axis_1_does_not():
         return _islands(lambda x, i: _gather(ctx, x, i), x, idx)
 
     assert islands(ctx(0)) == 1
-    assert program._lowering_notes == {"gather_layout": {9: ("shard", 4)}}
+    assert _layouts(program) == {9: ("shard", 4)}
     assert islands(ctx(1)) == 0
     assert program._lowering_notes == {}        # outside the rule: no note
     for plain in (ctx(0, data_axis="mp"),       # an axis the mesh lacks
                   ctx(0, gspmd_mesh=None)):
         assert islands(plain) == 0
-        assert program._lowering_notes == {"gather_layout":
-                                           {9: ("global", 1)}}
+        assert _layouts(program) == {9: ("global", 1)}
     # rows or indices the data axis does not divide; rows that do not add
     assert islands(ctx(0), x=jnp.ones((18, 8))) == 0
     assert islands(ctx(0), idx=jnp.arange(6)) == 0
@@ -306,7 +313,7 @@ def test_no_island_inside_another_ops_island():
     island = jax.shard_map(stage, mesh=mesh, in_specs=(P(), P()),
                            out_specs=P("dp"))
     assert _islands(island, x, idx) == 1        # the stage's own
-    assert program._lowering_notes == {"gather_layout": {9: ("global", 1)}}
+    assert _layouts(program) == {9: ("global", 1)}
     np.testing.assert_array_equal(np.asarray(jax.jit(island)(x, idx))[:8],
                                   np.asarray(x)[:8])
 

@@ -11,10 +11,10 @@ import jax.numpy as jnp
 import numpy as np
 import pytest
 
+import lowering_reports
 import paddle_tpu as fluid
 from paddle_tpu import layers
-from paddle_tpu.observability import ssm as obs_ssm
-from paddle_tpu.observability.metrics import MetricsRegistry
+from paddle_tpu.core.registry import LowerCtx
 from paddle_tpu.ops import pallas_short_conv as psc
 from paddle_tpu.ops import pallas_ssd
 from benchmark.references import granite_pretrain as reference
@@ -123,23 +123,23 @@ def test_ssd_scan_refuses_what_it_cannot_chunk_and_counts_what_it_took():
              for k, a in feeds.items()]
         y = layers.ssd_scan(*v, chunk=4)
     assert tuple(y.shape) == (1, 12, 2, 4)
-    registry = MetricsRegistry()
-    obs_ssm.update_ssm_gauges(main, "p", registry)
-    for name, want in (("ssm_layers", 1), ("ssm_heads", 2), ("ssm_state", 8),
-                       ("ssm_chunk", 4), ("ssm_chunks_per_step", 3)):
-        assert registry.gauge(name, program="p").value == want, name
-    obs_ssm.count_lowerings({1: ("pallas", 256, 64, 128),
-                             2: ("pallas", 256, 64, 128)},
-                            {3: ("pallas", "plain", "silu", 4)}, "p",
-                            registry)
+    (op,) = [op for op in main.global_block().ops if op.type == "ssd_scan"]
+    assert op.attr("chunk") == 4 and op.attr("impl") == "auto"
+    for salt in (1, 2):
+        LowerCtx({}, salt=salt, program=main).report(
+            "ssd_lowering_total", impl="pallas", chunk=256, heads=64,
+            state=128)
+    LowerCtx({}, salt=3, program=main).report(
+        "short_conv_lowering_total", impl="pallas", form="plain",
+        activation="silu", taps=4)
+    registry = lowering_reports.publish(main)
     assert registry.counter("ssd_lowering_total", program="p", impl="pallas",
                             chunk="256", heads="64", state="128").value == 2
     assert registry.counter(
         "short_conv_lowering_total", program="p", impl="pallas",
         form="plain", activation="silu", taps="4").value == 1
-    empty = MetricsRegistry()
-    obs_ssm.update_ssm_gauges(fluid.Program(), "q", empty)
-    assert empty.get("ssm_layers") is None
+    assert lowering_reports.publish(fluid.Program(), "q").get(
+        "ssd_lowering_total") is None
 
 
 def plain_conv(x, w, b, seq, act):
