@@ -1161,6 +1161,30 @@ def rotary_embedding(x, theta=10000.0, name=None, rotary_dim=None,
     return _var(helper, out)
 
 
+def latent_qkv(q, kv, k_rope, batch, seq, heads, nope_dim, rope_dim,
+               theta=10000.0, name=None):
+    """Latent attention's q, k and v ``[batch, heads, seq, nope_dim +
+    rope_dim]`` for ``fused_attention``, in one op, from its three
+    up-projections over ``batch x seq`` tokens: ``q [T, heads x (nope_dim +
+    rope_dim)]`` laid out ``[every head's q_n | every head's q_r]``, ``kv
+    [T, heads x (nope_dim + v)]`` laid out ``[every head's k_n | every
+    head's v]`` with ``v`` as wide as a q head, ``k_rope [T, rope_dim]`` the
+    one rotary key head that every head shares. The rotary parts are rotated
+    (rotate-half, base ``theta``, positions 0..seq-1) and follow their
+    head's other part; the op's registered grad puts the cotangents' parts
+    back and sums the key head's over the heads
+    (``ops/decoder_ops.py:latent_qkv``)."""
+    helper = LayerHelper("latent_qkv", name=name)
+    outs = [_out(helper, x.dtype) for x in (q, kv, kv)]
+    helper.append_op(
+        "latent_qkv", inputs={"Q": [q], "KV": [kv], "KRope": [k_rope]},
+        outputs={"OutQ": [outs[0]], "OutK": [outs[1]], "OutV": [outs[2]]},
+        attrs={"batch": int(batch), "seq": int(seq), "heads": int(heads),
+               "nope_dim": int(nope_dim), "rope_dim": int(rope_dim),
+               "theta": float(theta)})
+    return tuple(_var(helper, o) for o in outs)
+
+
 def attention_gate(x, gate, name=None):
     """Attention's output gate: ``x [B, heads, S, D]``, the heads' outputs
     as ``fused_attention`` returns them, times ``sigmoid(gate)``, before the

@@ -38,7 +38,10 @@ pre-norm, without biases or dropout: ``h = x + r operator(norm(x))``,
   projection is twice as wide, a head's first ``head_dim`` values its q and
   the others its gate, one a token, head and channel (Qwen3-Next). Without
   ``rope_parameters``, ``partial_rotary_factor`` is read beside
-  ``rope_theta``. ``sliding_attention``: the same
+  ``rope_theta``. A config with ``kv_lora_rank`` builds its
+  ``full_attention`` layers as multi-head latent attention
+  (``latent_attention``, below; DeepSeek-V2's, GLM-4.7-Flash's).
+  ``sliding_attention``: the same
   with a window of ``sliding_window`` keys (query i sees i - window < j <=
   i). ``conv``: the gated short convolution ``W_out (C * conv(B
   * u))`` with ``B, C, u = split(W_in x, 3)`` and a causal depthwise filter
@@ -48,7 +51,8 @@ pre-norm, without biases or dropout: ``h = x + r operator(norm(x))``,
 - ``norm_form`` ``"plain"`` (default): every RMSNorm scales by ``w`` from 1;
   ``"zero_centered"``: the layers' two norms, the final norm and the q / k
   norms scale by ``1 + w`` with ``w`` from 0 (``layers.rms_norm``).
-- feed-forward: the first ``num_dense_layers`` layers (default 0), the
+- feed-forward: the first ``num_dense_layers`` layers (also spelt
+  ``first_k_dense_replace``; default 0), the
   layers ``mlp_layer_types`` calls ``"dense"`` or ``mlp_only_layers`` lists,
   and every
   layer of a config without experts (``num_local_experts: 0``), a dense
@@ -62,12 +66,17 @@ pre-norm, without biases or dropout: ``h = x + r operator(norm(x))``,
   ``moe_routed_scaling_factor``); ``"sigmoid"``:
   sigmoid scores, chosen by score + bias under ``use_expert_bias``, weighed
   by the score over the chosen scores' sum under ``norm_topk_prob``, times
-  ``routed_scaling_factor``. ``shared_expert_intermediate_size``: one
+  ``routed_scaling_factor``. ``topk_method: "noaux_tc"`` (DeepSeek-V3's
+  spelling) is sigmoid scoring chosen by score + bias; ``n_group`` /
+  ``topk_group`` must be 1 (no group limit).
+  ``shared_expert_intermediate_size`` (or ``n_shared_experts: 1``, whose
+  width is ``moe_intermediate_size``): one
   shared expert, a dense SwiGLU of that width over every token, added
   beside the routed experts' sum: ungated, or under ``shared_expert_gate``
   times ``sigmoid(w_s . x)``, one gate a token.
-- one chip's share of a layer that several chips hold: ``num_experts`` is
-  the experts held here, ``num_experts_routed`` (default: the same) the
+- one chip's share of a layer that several chips hold: ``num_experts``
+  (also spelt ``n_routed_experts``) is the experts held here,
+  ``num_experts_routed`` (default: the same) the
   router's width and ``first_expert_held`` (default 0) the first held; the
   layer's output is the held experts' part (``layers.moe_ffn``), plus the
   shared expert where there is one (every chip computes it alike).
@@ -84,20 +93,28 @@ pre-norm, without biases or dropout: ``h = x + r operator(norm(x))``,
   averaged over the layers) + ``router_z_loss_coef`` x the mean of
   logsumexp(router logits)^2 (likewise) where the config carries those keys
   (softmax scoring).
+- ``num_nextn_predict_layers: 1``: a multi-token-prediction module after
+  the trunk (``prediction_module``, below) and its loss: the cross-entropy
+  of the token after the next through the trunk's table and head, times
+  ``mtp_loss_weight`` (default 0.3), added to the trunk's.
 - ``balance_experts``: the selection bias' update from the step's load,
-  appended by the caller after ``minimize``.
+  appended by the caller after ``minimize`` (the module's layer too).
 
 Models through it: OLMoE-1B-7B (Muennighoff et al., arXiv:2409.02060; HF
 ``modeling_olmoe.py``), LFM2-8B-A1B (HF ``modeling_lfm2_moe.py``),
 granite-4.0-h-micro (HF ``modeling_granitemoehybrid.py``; the scan: Dao &
 Gu, arXiv:2405.21060), Laguna-S-2.1 (its ``config.json``; YaRN: Peng et
-al., arXiv:2309.00071) and Qwen3-Next-80B-A3B (HF ``modeling_qwen3_next.py``;
-the delta rule: Yang et al., arXiv:2412.06464).
+al., arXiv:2309.00071), Qwen3-Next-80B-A3B (HF ``modeling_qwen3_next.py``;
+the delta rule: Yang et al., arXiv:2412.06464) and GLM-4.7-Flash (its
+``config.json``, ``model_type: glm4_moe_lite``; latent attention:
+DeepSeek-V2, arXiv:2405.04434; routing and the prediction module:
+DeepSeek-V3, arXiv:2412.19437).
 
 Dtypes follow ``models/bert.py``: the embedding table is float32 whatever
 ``dtype`` says, activations are cast to ``dtype`` right after the lookup,
 weights are created in ``dtype`` (a Mamba or DeltaNet mixer's ``A_log``,
-``D`` and ``dt_bias``, one number a head, in float32); RMSNorm, the router,
+``D`` and ``dt_bias``, one number a head, in float32); RMSNorm (latent
+attention's two among them), the router,
 the short convolution, the scan's and the delta rule's decays and state, the
 delta rule's l2 norms and every softmax compute in float32 inside their ops;
 the logits are cast up for the loss.
@@ -124,6 +141,8 @@ _ATTENTION = ("full_attention", "sliding_attention")
 
 
 def _check(cfg: dict) -> None:
+    if cfg.get("kv_lora_rank"):
+        _check_latent(cfg)
     for key, want in _REQUIRED.items():
         if cfg.get(key, want) != want:
             raise NotImplementedError(
@@ -137,7 +156,7 @@ def _check(cfg: dict) -> None:
         if kind not in _OPERATORS:
             raise NotImplementedError(
                 f"decoder_lm: layer type {kind!r} is not built yet (only "
-                f"{_OPERATORS}: no chunked attention, no latent attention)")
+                f"{_OPERATORS}: no chunked attention)")
     if "sliding_attention" in kinds and not cfg.get("sliding_window"):
         raise ValueError("decoder_lm: sliding_attention layers need "
                          "sliding_window")
@@ -172,7 +191,7 @@ def _check(cfg: dict) -> None:
         raise ValueError("num_attention_heads_per_layer must give every one "
                          "of the num_hidden_layers layers a head count")
     for i, kind in enumerate(kinds):
-        if kind not in _ATTENTION:
+        if kind not in _ATTENTION or cfg.get("kv_lora_rank"):
             continue
         if _heads(cfg, i) % _kv_heads(cfg) or (
                 "head_dim" not in cfg and cfg["hidden_size"] % _heads(cfg, i)):
@@ -207,17 +226,29 @@ def _check(cfg: dict) -> None:
             "num_shared_experts above 1, nor routed experts beside a shared "
             "feed-forward under num_local_experts")
     if (cfg.get("n_shared_experts") or cfg.get("num_shared_experts")) \
-            and not cfg.get("shared_expert_intermediate_size"):
+            and not _shared_width(cfg):
         raise ValueError("decoder_lm: a shared expert needs "
-                         "shared_expert_intermediate_size")
-    sigmoid = cfg.get("router_scoring", "softmax") == "sigmoid"
+                         "shared_expert_intermediate_size, or "
+                         "moe_intermediate_size beside n_shared_experts")
+    if cfg.get("topk_method", "noaux_tc") != "noaux_tc":
+        raise NotImplementedError(
+            f"decoder_lm: topk_method={cfg['topk_method']!r} is not built "
+            f"yet (only 'noaux_tc': sigmoid scores chosen by score + bias)")
+    if max(cfg.get("n_group") or 1, cfg.get("topk_group") or 1) > 1:
+        raise NotImplementedError(
+            "decoder_lm: group-limited routing (n_group / topk_group above "
+            "1) is not built yet: the experts are chosen among all of them")
+    if (cfg.get("num_nextn_predict_layers") or 0) > 1:
+        raise NotImplementedError(
+            "decoder_lm: more than one multi-token-prediction module "
+            "(num_nextn_predict_layers above 1) is not built yet")
+    sigmoid = _scoring(cfg) == "sigmoid"
     if not sigmoid and cfg.get("use_expert_bias"):
         raise NotImplementedError(
             "decoder_lm: use_expert_bias is built for "
             "router_scoring='sigmoid' only")
     if cfg.get("moe_row_budget") and cfg.get(
-            "num_experts_routed", cfg.get("num_experts")) == cfg.get(
-                "num_experts"):
+            "num_experts_routed", _held(cfg)) == _held(cfg):
         raise ValueError("decoder_lm: moe_row_budget is for a layer that "
                          "holds a part of its experts (num_experts_routed)")
     if sigmoid and ("router_aux_loss_coef" in cfg
@@ -225,6 +256,53 @@ def _check(cfg: dict) -> None:
         raise NotImplementedError(
             "decoder_lm: the router losses are built for softmax scoring "
             "only")
+
+
+def _check_latent(cfg: dict) -> None:
+    """What a config with ``kv_lora_rank`` (latent attention) asks for and
+    ``latent_attention`` does not build."""
+    if cfg.get("q_lora_rank") is None:
+        raise NotImplementedError(
+            "decoder_lm: latent attention with q_lora_rank: null (a full "
+            "query projection beside a latent key/value) is not built yet")
+    if cfg.get("rope_scaling") is not None:
+        raise NotImplementedError(
+            "decoder_lm: rope_scaling inside latent attention (YaRN with "
+            "its mscale on the softmax scale) is not built yet (only null)")
+    if cfg["qk_nope_head_dim"] + cfg["qk_rope_head_dim"] != cfg["v_head_dim"]:
+        raise NotImplementedError(
+            "decoder_lm: a v_head_dim other than qk_nope_head_dim + "
+            "qk_rope_head_dim is not built yet (fused_attention has one "
+            "head size for q, k and v)")
+    if cfg.get("partial_rotary_factor", 1) != 1:
+        raise NotImplementedError(
+            "decoder_lm: partial_rotary_factor other than 1 inside latent "
+            "attention is not built yet (the rotary head is rotated whole)")
+    if any(k != "full_attention" for k in _layer_types(cfg)):
+        raise NotImplementedError(
+            "decoder_lm: latent attention (kv_lora_rank) is built for "
+            "full_attention layers only")
+
+
+def _held(cfg: dict):
+    """The experts a layer holds here: ``num_experts``, in DeepSeek-style
+    configs ``n_routed_experts``; None for a config without experts."""
+    return cfg.get("num_experts", cfg.get("n_routed_experts"))
+
+
+def _scoring(cfg: dict) -> str:
+    """``router_scoring``; a config with ``topk_method: "noaux_tc"`` scores
+    by sigmoid and chooses by score + bias."""
+    return cfg.get("router_scoring",
+                   "sigmoid" if "topk_method" in cfg else "softmax")
+
+
+def _shared_width(cfg: dict):
+    """The one shared expert's width: ``shared_expert_intermediate_size``,
+    or ``moe_intermediate_size`` x ``n_shared_experts``; None for none."""
+    return cfg.get("shared_expert_intermediate_size") or (
+        cfg.get("moe_intermediate_size", 0)
+        * (cfg.get("n_shared_experts") or 0)) or None
 
 
 def _layer_types(cfg: dict) -> list:
@@ -259,8 +337,9 @@ def _rope(cfg: dict, kind: str) -> dict:
 
 def _is_dense(cfg: dict, layer: int) -> bool:
     kinds = cfg.get("mlp_layer_types")
-    return bool(layer < cfg.get("num_dense_layers", 0)
-                or "num_experts" not in cfg
+    return bool(layer < cfg.get("num_dense_layers",
+                                cfg.get("first_k_dense_replace", 0))
+                or _held(cfg) is None
                 or (kinds and kinds[layer] == "dense")
                 or layer in cfg.get("mlp_only_layers", ()))
 
@@ -275,6 +354,18 @@ def _attr(name: str) -> ParamAttr:
 
 def _linear(x, size: int, name: str):
     return layers.fc(x, size, param_attr=_attr(name), bias_attr=False)
+
+
+def _embed(ids, cfg: dict):
+    """The rows of the one float32 table ``tok_emb`` for ``ids``, times
+    ``embedding_multiplier``, cast to the config's ``dtype``."""
+    x = layers.embedding(ids, [cfg["vocab_size"], cfg["hidden_size"]],
+                         dtype="float32", param_attr=_attr("tok_emb"))
+    if cfg.get("embedding_multiplier", 1) != 1:
+        x = layers.scale(x, float(cfg["embedding_multiplier"]))
+    if cfg.get("dtype", "float32") != "float32":
+        x = layers.cast(x, cfg["dtype"])
+    return x
 
 
 def _norm(x, cfg: dict, name: str):
@@ -337,6 +428,41 @@ def attention(x, cfg: dict, batch: int, seq: int, name: str, layer: int = 0,
     ctx = layers.reshape(layers.transpose(ctx, [0, 2, 1, 3]),
                          [batch * seq, heads * d])
     return _linear(ctx, H, name + "_o_w")
+
+
+def latent_attention(x, cfg: dict, batch: int, seq: int, name: str):
+    """Causal multi-head latent attention over tokens ``x [batch * seq, H]``
+    (DeepSeek-V2, arXiv:2405.04434, section 2.1; HF ``DeepseekV3Attention``)
+    with ``h = num_attention_heads`` heads of ``qk_nope_head_dim`` +
+    ``qk_rope_head_dim`` = ``v_head_dim``: ``c_q = norm(W_qa x)``
+    (``q_lora_rank``), a head's ``[q_n | q_r]`` from ``W_qb c_q``; ``[c_kv
+    | k_r] = W_kva x`` (``kv_lora_rank`` + ``qk_rope_head_dim``), a head's
+    ``[k_n | v]`` from ``W_kvb norm(c_kv)``; ``q_r`` and the one key head
+    ``k_r`` rotated at ``rope_theta`` and ``k_r`` shared by the heads
+    (``layers.latent_qkv``: the columns of ``W_qb`` are every head's q_n,
+    then every head's q_r, those of ``W_kvb`` every head's k_n, then every
+    head's v, a permutation of HF's interleave by head); causal
+    ``fused_attention`` at 1 / sqrt(the q / k head's width); ``W_o`` over
+    the heads' outputs. Both latent norms are the config's RMSNorm."""
+    heads = cfg["num_attention_heads"]
+    d_n, d_r = cfg["qk_nope_head_dim"], cfg["qk_rope_head_dim"]
+    r_kv = cfg["kv_lora_rank"]
+    c_q = _norm(_linear(x, cfg["q_lora_rank"], name + "_q_a_w"), cfg,
+                name + "_q_a_norm_w")
+    q = _linear(c_q, heads * (d_n + d_r), name + "_q_b_w")
+    c_kv, k_r = layers.split(_linear(x, r_kv + d_r, name + "_kv_a_w"),
+                             [r_kv, d_r], dim=-1)
+    kv = _linear(_norm(c_kv, cfg, name + "_kv_a_norm_w"),
+                 heads * (d_n + cfg["v_head_dim"]), name + "_kv_b_w")
+    q, k, v = layers.latent_qkv(q, kv, k_r, batch, seq, heads, d_n, d_r,
+                                theta=cfg.get("rope_theta", 10000.0))
+    ctx = layers.fused_attention(
+        q, k, v, causal=True, impl="auto",
+        scale=float(cfg.get("attention_multiplier",
+                            1.0 / math.sqrt(d_n + d_r))))
+    ctx = layers.reshape(layers.transpose(ctx, [0, 2, 1, 3]),
+                         [batch * seq, heads * (d_n + d_r)])
+    return _linear(ctx, cfg["hidden_size"], name + "_o_w")
 
 
 def short_conv(x, cfg: dict, seq: int, name: str):
@@ -487,7 +613,7 @@ def delta_net(x, cfg: dict, batch: int, seq: int, name: str):
 def experts(x, cfg: dict, name: str):
     """The layer's routed experts, and its shared expert where the config
     has one (``layers.moe_ffn``), from the config."""
-    held = cfg["num_experts"]
+    held = _held(cfg)
     routed = cfg.get("num_experts_routed", held)
     return layers.moe_ffn(
         x, routed, cfg["num_experts_per_tok"],
@@ -495,14 +621,14 @@ def experts(x, cfg: dict, name: str):
         param_attr=_attr(None), name=name,
         experts_held=(None if held == routed
                       else (cfg.get("first_expert_held", 0), held)),
-        scoring=cfg.get("router_scoring", "softmax"),
+        scoring=_scoring(cfg),
         norm_topk=bool(cfg.get("norm_topk_prob", False)),
         routed_scale=float(cfg.get(
             "routed_scaling_factor",
             cfg.get("moe_routed_scaling_factor", 1.0))),
-        expert_bias=bool(cfg.get("use_expert_bias", False)),
+        expert_bias=bool(cfg.get("use_expert_bias", "topk_method" in cfg)),
         row_budget=cfg.get("moe_row_budget"),
-        shared_width=cfg.get("shared_expert_intermediate_size"),
+        shared_width=_shared_width(cfg),
         shared_gate=bool(cfg.get("shared_expert_gate", False)))
 
 
@@ -526,6 +652,8 @@ def block(x, cfg: dict, batch: int, seq: int, name: str,
         mixed = mamba(normed, cfg, batch, seq, op_name)
     elif kind == "linear_attention":
         mixed = delta_net(normed, cfg, batch, seq, op_name)
+    elif cfg.get("kv_lora_rank"):
+        mixed = latent_attention(normed, cfg, batch, seq, op_name)
     else:
         mixed = attention(normed, cfg, batch, seq, op_name, layer, kind)
     h = add(x, mixed)
@@ -538,6 +666,23 @@ def block(x, cfg: dict, batch: int, seq: int, name: str,
                               name + "_ffn_down_w")), None
     moe, aux = experts(normed, cfg, name + "_moe")
     return add(h, moe), aux
+
+
+def prediction_module(h, next_tokens, cfg: dict, batch: int, seq: int,
+                      name: str):
+    """The multi-token-prediction module (DeepSeek-V3, arXiv:2412.19437,
+    section 2.2) over the trunk's output ``h [batch * seq, H]`` before its
+    final norm and ``next_tokens [batch * seq, 1]``, the token that follows
+    each position: ``u = W_eh [norm_h(h) | norm_e(Emb(next_tokens))]`` (2H
+    -> H, the trunk's embedding table) through one more decoder block of
+    the expert kind with its own weights. Returns the block's output, which
+    the caller norms and decodes with the trunk's head, and its router's
+    variables."""
+    e = _embed(layers.reshape(next_tokens, [batch * seq]), cfg)
+    u = _linear(layers.concat([_norm(h, cfg, name + "_h_norm_w"),
+                               _norm(e, cfg, name + "_e_norm_w")], axis=-1),
+                cfg["hidden_size"], name + "_eh_w")
+    return block(u, cfg, batch, seq, name, "full_attention", dense=False)
 
 
 def balance_experts(out: dict, rate: float) -> None:
@@ -554,9 +699,15 @@ def _mean_of(values):
     return layers.scale(total, 1.0 / len(values))
 
 
-def build(cfg: dict, ids, labels) -> dict:
+def build(cfg: dict, ids, labels, labels_next=None) -> dict:
     """Append the model to the current Program. ``ids [batch, seq]`` int
-    tokens, ``labels [batch * seq, 1]`` the next token of every position.
+    tokens, ``labels [batch * seq, 1]`` the next token of every position;
+    under ``num_nextn_predict_layers: 1`` also ``labels_next [batch * seq,
+    1]``, the token after that, for the multi-token-prediction module
+    (``prediction_module``): the result then has ``mtp_ce`` and
+    ``mtp_each`` beside ``ce`` and ``each``, ``loss`` is ``ce`` +
+    ``mtp_loss_weight`` (default 0.3) x ``mtp_ce``, and the module's router
+    variables follow the trunk's in the per-layer lists.
 
     Returns the variables a caller trains on or fetches: ``loss`` (the
     total), ``ce`` (mean cross-entropy), ``each`` (every position's
@@ -575,15 +726,36 @@ def build(cfg: dict, ids, labels) -> dict:
     E = cfg.get("num_experts_routed", cfg.get("num_experts"))
     router_losses = "router_aux_loss_coef" in cfg
     dtype = cfg.get("dtype", "float32")
-    x = layers.embedding(ids, [cfg["vocab_size"], H], dtype="float32",
-                         param_attr=_attr("tok_emb"))
-    if cfg.get("embedding_multiplier", 1) != 1:
-        x = layers.scale(x, float(cfg["embedding_multiplier"]))
-    if dtype != "float32":
-        x = layers.cast(x, dtype)
-    x = layers.reshape(x, [batch * seq, H])
+    x = layers.reshape(_embed(ids, cfg), [batch * seq, H])
     balance, z, loads, indices, biases, dropped, routed = (
         [] for _ in range(7))
+
+    def keep(aux):
+        loads.append(aux["load"])
+        indices.append(aux["index"])
+        routed.append(aux["routed"])
+        if "bias" in aux:
+            biases.append(aux["bias"])
+        if "dropped" in aux:
+            dropped.append(aux["dropped"])
+
+    def cross_entropy(x, norm_w, targets):
+        """Every position's cross-entropy of ``targets`` under the head
+        (one matrix, or the table, whoever calls) over ``norm(x)``."""
+        x = _norm(x, cfg, norm_w)
+        if cfg.get("tie_word_embeddings"):
+            table = x.block.program.global_block().var("tok_emb")
+            if dtype != "float32":
+                table = layers.cast(table, dtype)
+            logits = layers.matmul(x, table, transpose_y=True)
+        else:
+            logits = _linear(x, cfg["vocab_size"], "lm_head_w")
+        if cfg.get("logits_scaling", 1) != 1:   # applied in float32
+            if dtype != "float32":
+                logits = layers.cast(logits, "float32")
+            logits = layers.scale(logits, 1.0 / float(cfg["logits_scaling"]))
+        return layers.softmax_with_cross_entropy(logits, targets)
+
     for i, kind in enumerate(_layer_types(cfg)):
         x, aux = block(x, cfg, batch, seq, f"layer{i}", kind,
                        dense=_is_dense(cfg, i), layer=i)
@@ -598,34 +770,27 @@ def build(cfg: dict, ids, labels) -> dict:
                     share, layers.reduce_mean(aux["prob"], dim=0))),
                 float(E)))
             z.append(layers.mean(layers.square(aux["logz"])))
-        loads.append(aux["load"])
-        indices.append(aux["index"])
-        routed.append(aux["routed"])
-        if "bias" in aux:
-            biases.append(aux["bias"])
-        if "dropped" in aux:
-            dropped.append(aux["dropped"])
-    x = _norm(x, cfg, "final_norm_w")
-    if cfg.get("tie_word_embeddings"):
-        table = x.block.program.global_block().var("tok_emb")
-        if dtype != "float32":
-            table = layers.cast(table, dtype)
-        logits = layers.matmul(x, table, transpose_y=True)
-    else:
-        logits = _linear(x, cfg["vocab_size"], "lm_head_w")
-    if cfg.get("logits_scaling", 1) != 1:   # applied in float32
-        if dtype != "float32":
-            logits = layers.cast(logits, "float32")
-        logits = layers.scale(logits, 1.0 / float(cfg["logits_scaling"]))
-    each = layers.softmax_with_cross_entropy(logits, labels)
+        keep(aux)
+    each = cross_entropy(x, "final_norm_w", labels)
     ce = layers.mean(each)
     out = {"loss": ce, "ce": ce, "each": each, "expert_load": loads,
            "expert_index": indices, "expert_bias": biases,
            "expert_dropped": dropped, "expert_routed": routed}
+    if cfg.get("num_nextn_predict_layers"):
+        if labels_next is None:
+            raise ValueError("decoder_lm: num_nextn_predict_layers needs "
+                             "labels_next, the token after the next")
+        u, aux = prediction_module(x, labels, cfg, batch, seq, "mtp")
+        keep(aux)
+        out["mtp_each"] = cross_entropy(u, "mtp_final_norm_w", labels_next)
+        out["mtp_ce"] = layers.mean(out["mtp_each"])
+        out["loss"] = layers.sums([ce, layers.scale(
+            out["mtp_ce"], float(cfg.get("mtp_loss_weight", 0.3)))])
     if router_losses:
         balance, z = _mean_of(balance), _mean_of(z)
         out["loss"] = layers.sums([
-            ce, layers.scale(balance, float(cfg["router_aux_loss_coef"])),
+            out["loss"],
+            layers.scale(balance, float(cfg["router_aux_loss_coef"])),
             layers.scale(z, float(cfg["router_z_loss_coef"]))])
         out.update(load_balancing=balance, z_loss=z)
     return out

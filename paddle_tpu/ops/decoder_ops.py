@@ -192,6 +192,91 @@ def rotary_embedding_grad(ctx, ins, generic):
     return {"X@GRAD": [_rotary_pass(ctx, g, backward=True)]}
 
 
+def _latent_sizes(ctx):
+    return tuple(int(ctx.attr(k)) for k in (
+        "batch", "seq", "heads", "nope_dim", "rope_dim"))
+
+
+def _rope_rows(ctx, x, backward):
+    """The rotation over the last axis of ``x [B, S, ..., rope_dim]``
+    (positions along axis 1), float32 in and out; ``backward``: its
+    transpose, the sign of sin turned."""
+    import jax.numpy as jnp
+    S, r = x.shape[1], x.shape[-1]
+    cos, sin, _ = _rotary_tables(ctx, S, r)
+    shape = (1, S) + (1,) * (x.ndim - 3) + (r,)
+    cos, sin = cos.reshape(shape), sin.reshape(shape)
+    return x * cos + jnp.roll(x, r // 2, axis=-1) * (-sin if backward else sin)
+
+
+@register("latent_qkv")
+def latent_qkv(ctx, ins):
+    """Latent attention's q, k and v for ``fused_attention``, out of its
+    three up-projections (attrs ``batch``, ``seq``, ``heads``, ``nope_dim``,
+    ``rope_dim``, ``theta``; T = batch x seq): ``Q [T, heads x (nope +
+    rope)]`` laid out ``[every head's q_n | every head's q_r]``, ``KV [T,
+    heads x (nope + v)]`` laid out ``[every head's k_n | every head's v]``,
+    ``KRope [T, rope]`` the one rotary key head. ``OutQ`` a head ``[q_n |
+    RoPE(q_r)]``, ``OutK`` a head ``[k_n | RoPE(k_r)]`` with the one rotated
+    key head in every head, ``OutV`` the values, each ``[batch, heads, seq,
+    nope + rope]`` (``v`` is as wide as q and k: ``fused_attention`` has one
+    head size). Rotate-half, positions 0..seq-1, float32 inside the
+    rotation only: the parts that are not rotated move in their own dtype."""
+    import jax.numpy as jnp
+    q, kv, k_r = ins["Q"][0], ins["KV"][0], ins["KRope"][0]
+    B, S, h, d_n, d_r = _latent_sizes(ctx)
+    d = d_n + d_r
+    if q.shape[-1] != h * d or kv.shape[-1] != h * (d_n + d) \
+            or k_r.shape[-1] != d_r:
+        raise ValueError(
+            f"latent_qkv: Q {q.shape}, KV {kv.shape} and KRope {k_r.shape} "
+            f"are not {h} heads of [{d_n} | {d_r}], [{d_n} | {d}] and one "
+            f"of {d_r}: fused_attention takes one head size, so v must be "
+            f"as wide as q and k")
+
+    def heads_of(x, width):         # [T, h * width] -> [B, h, S, width]
+        return x.reshape(B, S, h, width).transpose(0, 2, 1, 3)
+    q_r = _rope_rows(ctx, q[:, h * d_n:].reshape(B, S, h, d_r).astype(
+        jnp.float32), False).astype(q.dtype)
+    k_r = _rope_rows(ctx, k_r.reshape(B, S, d_r).astype(jnp.float32),
+                     False).astype(kv.dtype)
+    out_q = jnp.concatenate([heads_of(q[:, :h * d_n], d_n),
+                             q_r.transpose(0, 2, 1, 3)], axis=-1)
+    out_k = jnp.concatenate(
+        [heads_of(kv[:, :h * d_n], d_n),
+         jnp.broadcast_to(k_r[:, None], (B, h, S, d_r))], axis=-1)
+    return {"OutQ": [out_q], "OutK": [out_k],
+            "OutV": [heads_of(kv[:, h * d_n:], d)]}
+
+
+@register_grad("latent_qkv")
+def latent_qkv_grad(ctx, ins, generic):
+    """dQ, dKV and dKRope from the three cotangents alone: the op is linear,
+    a cut, a rotation and a broadcast, so its transpose is the parts put
+    back where they came from, the rotary parts turned back (the sign of
+    sin) and the one key head's gradient the sum over the heads of dK's
+    rotary part (summed in float32, then turned back once). It reads no
+    forward input's values and lowers no forward; a grad op that lacks a
+    cotangent is ``generic``."""
+    import jax.numpy as jnp
+    dq, dk, dv = (ins.get(s + "@GRAD", [None])[0]
+                  for s in ("OutQ", "OutK", "OutV"))
+    if dq is None or dk is None or dv is None:
+        return generic()
+    B, S, h, d_n, d_r = _latent_sizes(ctx)
+
+    def flat(x):                    # [B, h, S, width] -> [T, h * width]
+        return x.transpose(0, 2, 1, 3).reshape(B * S, -1)
+    dq_r = _rope_rows(ctx, dq[..., d_n:].transpose(0, 2, 1, 3).astype(
+        jnp.float32), True).astype(dq.dtype).reshape(B * S, h * d_r)
+    dk_r = _rope_rows(ctx, jnp.sum(dk[..., d_n:], axis=1, dtype=jnp.float32),
+                      True).astype(dk.dtype).reshape(B * S, d_r)
+    return {"Q@GRAD": [jnp.concatenate([flat(dq[..., :d_n]), dq_r], axis=-1)],
+            "KV@GRAD": [jnp.concatenate([flat(dk[..., :d_n]), flat(dv)],
+                                        axis=-1)],
+            "KRope@GRAD": [dk_r]}
+
+
 @register("swiglu")
 def swiglu(ctx, ins):
     """silu(X) * Y, the gated product of a gated feed-forward layer; with

@@ -233,6 +233,21 @@ def k_tiles(S, block_q, block_k, causal, window=None):
 # and is 4% slower under a raised limit (chip runs, PR 25), so it keeps it.
 BWD_VMEM_LIMIT_BYTES = 64 * 1024 * 1024
 
+# The forward kernel's own, for heads wider than 128 only. At d=256 in the
+# causal 512 x 1024 tiles its stage, the double-buffered q, o and K/V rows
+# and the score tile stand at the edge of the default: the call compiles
+# inside one train step and not alone, nor in a test clone without the ops
+# that were around it (16.39 M asked of 16.00 M: PERF.md section 7 (u), PR
+# 43). Up to d=128 the call keeps Mosaic's default, under which it is 4%
+# faster (above), and those cells' programs stay as they were.
+FWD_VMEM_LIMIT_BYTES_WIDE = 32 * 1024 * 1024
+
+
+def fwd_vmem_limit_bytes(head_dim):
+    """``vmem_limit_bytes`` of the forward call: None (Mosaic's default) up
+    to heads of 128, ``FWD_VMEM_LIMIT_BYTES_WIDE`` above."""
+    return FWD_VMEM_LIMIT_BYTES_WIDE if head_dim > 128 else None
+
 # 'auto' takes the Pallas kernels from this sequence length up; the default
 # of the `fused_attention.backend` tunable choice (paddle_tpu/tuning/), which
 # a persisted autotune decision overrides per (shape bucket, device).
@@ -813,7 +828,7 @@ def _fwd_call(q, k, v, bias, seed, scale, dropout, causal, interpret,
                    jax.ShapeDtypeStruct((B * H, 1, S), jnp.float32)],
         scratch_shapes=_stages(1, S, block_q, block_k, window),
         interpret=interpret,
-        **_compiler_params(interpret),
+        **_compiler_params(interpret, fwd_vmem_limit_bytes(D)),
     )(*args)
     return out.reshape(B, H, S, D), lse.reshape(B, H, 1, S)
 

@@ -141,6 +141,37 @@ def test_grouped_query_flash_compiles_for_v5e(one_chip, B, dropout, use_bias):
     assert _kernels(back.compile()) == 1
 
 
+@pytest.mark.parametrize("B,H,kv", [(1, 20, 20), (2, 16, 2)])
+def test_wide_head_flash_compiles_alone_for_v5e(one_chip, B, H, kv):
+    """Heads of 256 at S=4096, causal, at the default 512 x 1024 tiles:
+    glm_4_7_flash's latent attention as the kernels see it (20 query = 20
+    key/value heads, group 1) and qwen3_next's one attention layer (16 over
+    2). Under Mosaic's default scoped VMEM the forward call compiled inside
+    one train step and not alone (16.39 M asked of 16.00 M: PERF.md section
+    7 (u)); with a limit of its own for heads wider than 128 it compiles
+    alone, as the backward always did. Narrower heads keep the default."""
+    S, D = 4096, 256
+    assert pa.fwd_vmem_limit_bytes(D) == pa.FWD_VMEM_LIMIT_BYTES_WIDE
+    assert pa.fwd_vmem_limit_bytes(128) is None
+    assert pa._blocks(S, True) == pa.CAUSAL_BLOCKS
+    q = jax.ShapeDtypeStruct((B, H, S, D), jnp.bfloat16, sharding=one_chip)
+    k = jax.ShapeDtypeStruct((B, kv, S, D), jnp.bfloat16, sharding=one_chip)
+
+    def attend(q, k, v):
+        return pa._flash(q, k, v, None, jnp.int32(3), D ** -0.5, 0.0, True,
+                         False)
+
+    def grad_op(q, k, v, g, lse):
+        return _grad_op(q, k, v, None, g, lse, D ** -0.5, 0.0, True)
+
+    fwd = jax.jit(attend).lower(q, k, k).compile()
+    assert _kernels(fwd) == 1
+    back = jax.jit(grad_op).lower(q, k, k, q, _lse(q, one_chip))
+    assert [tuple(o.shape) for o in back.out_info] == [
+        (B, H, S, D), (B, kv, S, D), (B, kv, S, D)]
+    assert _kernels(back.compile()) == 1
+
+
 @pytest.mark.parametrize("block_q,block_k", [
     pa.CAUSAL_BLOCKS, (512, 512), (256, 256), (256, 4096)])
 @pytest.mark.parametrize("H,kv,D", [(16, 16, 128), (32, 8, 64)])

@@ -178,11 +178,13 @@ def checked(s, batch, **swapped) -> dict:
 
 
 def controls(args, without=without, mechanisms=MECHANISMS,
-             patched=lambda mechanism: contextlib.nullcontext()) -> dict:
+             patched=lambda mechanism: contextlib.nullcontext(),
+             checked=checked) -> dict:
     """``without`` / ``mechanisms``: another cell's (``tools/qwen3_next_
     probe.py``); ``patched(mechanism)``: a context around the build and the
     check of that mechanism's program, for what no configuration key takes
-    out."""
+    out; ``checked``: another cell's reading of one check (``tools/glm_
+    probe.py`` adds the error by part)."""
     import jax.numpy as jnp
     from benchmark.jobs import common
     cell = load_cell(args)

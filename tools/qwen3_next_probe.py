@@ -124,14 +124,48 @@ def gradients(args) -> dict:
     return result
 
 
+def flash_by_blocks(B, S, heads, kv, d, blocks, interpret, rng) -> list:
+    """Forward / backward milliseconds a layer of the causal flash kernels,
+    each call compiled alone, at ``heads`` query over ``kv`` key/value heads
+    of ``d``: the default blocks, then every ``block_q x block_k`` of
+    ``blocks``. A pair Mosaic refuses is said, not hidden."""
+    import jax.numpy as jnp
+    from paddle_tpu.ops import pallas_attention as pa
+    bf = jnp.bfloat16
+    fq, fg = (jnp.asarray(rng.randn(B, heads, S, d), bf) for _ in range(2))
+    fk, fv = (jnp.asarray(rng.randn(B, kv, S, d), bf) for _ in range(2))
+    scale, seed = d ** -0.5, jnp.int32(3)
+    rows = []
+    for pair in [None] + [tuple(int(x) for x in b.split("x"))
+                          for b in blocks]:
+        try:
+            bq, bk = pair or pa._blocks(S, True, None, None, None)
+            out, lse = pa._fwd_call(fq, fk, fv, None, seed, scale, 0.0, True,
+                                    interpret, bq, bk, None)
+            fwd = _ms(lambda: pa._fwd_call(fq, fk, fv, None, seed, scale, 0.0,
+                                           True, interpret, bq, bk, None))
+            bwd = _ms(lambda: pa._bwd_call(fq, fk, fv, None, seed, fg, lse,
+                                           scale, 0.0, True, interpret, bq,
+                                           bk, None))
+        except Exception as e:      # noqa: BLE001
+            say(f"flash d={d} blocks {pair or 'default'}: "
+                f"{type(e).__name__}: {str(e)[:200]}")
+            continue
+        rows.append({"block_q": bq, "block_k": bk, "fwd_ms": fwd,
+                     "bwd_ms": bwd, "default": pair is None})
+        say(f"flash {heads} / {kv} heads of {d}, blocks {bq} x {bk}"
+            f"{' (the default)' if pair is None else ''}: forward "
+            f"{fwd:.3f} backward {bwd:.3f} ms a layer")
+    return rows
+
+
 def kernels(args) -> dict:
     """Forward / backward milliseconds a layer of the delta rule's kernels
     by chunk, of the composed chunk form, and of the flash kernels at
     d=256 by blocks."""
     import jax
     import jax.numpy as jnp
-    from paddle_tpu.ops import decoder_ops, pallas_attention as pa
-    from paddle_tpu.ops import pallas_delta, pallas_mode
+    from paddle_tpu.ops import decoder_ops, pallas_delta, pallas_mode
     cell = laguna_probe.load_cell(args)
     model, p = cell["model"], cell["params"]
     B, S = p["batch"], p["seq"]
@@ -182,33 +216,9 @@ def kernels(args) -> dict:
     result.update(composed_fwd_ms=c_fwd, composed_fwd_bwd_ms=c_both,
                   composed_temp_gb=temp.temp_size_in_bytes / 1e9)
     # the flash kernels at the cell's attention shapes
-    heads, kv, d = (model["num_attention_heads"],
-                    model["num_key_value_heads"], model["head_dim"])
-    fq, fg = (jnp.asarray(rng.randn(B, heads, S, d), bf) for _ in range(2))
-    fk, fv = (jnp.asarray(rng.randn(B, kv, S, d), bf) for _ in range(2))
-    scale, seed = d ** -0.5, jnp.int32(3)
-    pairs = [None] + [tuple(int(x) for x in b.split("x"))
-                      for b in args.blocks]
-    for blocks in pairs:
-        try:
-            bq, bk = blocks or pa._blocks(S, True, None, None, None)
-            out, lse = pa._fwd_call(fq, fk, fv, None, seed, scale, 0.0, True,
-                                    interpret, bq, bk, None)
-            fwd = _ms(lambda: pa._fwd_call(fq, fk, fv, None, seed, scale, 0.0,
-                                           True, interpret, bq, bk, None))
-            bwd = _ms(lambda: pa._bwd_call(fq, fk, fv, None, seed, fg, lse,
-                                           scale, 0.0, True, interpret, bq,
-                                           bk, None))
-        except Exception as e:      # a pair Mosaic refuses: said, not hidden
-            say(f"flash d={d} blocks {blocks or 'default'}: "
-                f"{type(e).__name__}: {str(e)[:200]}")
-            continue
-        result["flash"].append({"block_q": bq, "block_k": bk, "fwd_ms": fwd,
-                                "bwd_ms": bwd,
-                                "default": blocks is None})
-        say(f"flash {heads} / {kv} heads of {d}, blocks {bq} x {bk}"
-            f"{' (the default)' if blocks is None else ''}: forward "
-            f"{fwd:.3f} backward {bwd:.3f} ms a layer")
+    result["flash"] = flash_by_blocks(
+        B, S, model["num_attention_heads"], model["num_key_value_heads"],
+        model["head_dim"], args.blocks, interpret, rng)
     return result
 
 
