@@ -1103,12 +1103,17 @@ def fused_attention(q, k, v, bias=None, scale=None, dropout_prob=0.0,
 # -- decoder-LM vocabulary (ops/decoder_ops.py) ------------------------------------------
 
 def rms_norm(input, epsilon=1e-5, param_attr=None, name=None,
-             zero_centered=False):
+             zero_centered=False, gate=None, impl="auto"):
     """RMSNorm over the last axis with a learned scale (initialised to 1):
     ``x / sqrt(mean(x^2) + epsilon) * scale``, float32 inside the op. Under
     ``zero_centered`` the scale is ``1 + w`` with ``w`` initialised to 0
     (Qwen3-Next's, Gemma's): the same function at the start, another under a
-    weight decay, which pulls ``w`` to 0 and so the scale to 1."""
+    weight decay, which pulls ``w`` to 0 and so the scale to 1. With
+    ``gate`` (``input``'s element count: its shape, or ``[T, heads * D]``
+    beside ``[T, heads, D]``) the result times ``silu(gate)`` inside the
+    same op, one pass over both (a Gated DeltaNet mixer's output norm);
+    ``impl`` is that form's lowering, ``auto`` / ``pallas`` / ``composed``
+    (``ops/pallas_norm.py``)."""
     from ..initializer import Constant
     helper = LayerHelper("rms_norm", name=name)
     scale = helper.create_parameter(
@@ -1118,8 +1123,13 @@ def rms_norm(input, epsilon=1e-5, param_attr=None, name=None,
     attrs = {"epsilon": float(epsilon)}
     if zero_centered:
         attrs["zero_centered"] = True
-    helper.append_op("rms_norm", inputs={"X": [input], "Scale": [scale]},
-                     outputs={"Y": [y]}, attrs=attrs)
+    inputs = {"X": [input], "Scale": [scale]}
+    if gate is not None:
+        inputs["Gate"] = [gate]
+        if impl != "auto":
+            attrs["impl"] = impl
+    helper.append_op("rms_norm", inputs=inputs, outputs={"Y": [y]},
+                     attrs=attrs)
     return _var(helper, y)
 
 

@@ -576,7 +576,8 @@ def delta_net(x, cfg: dict, batch: int, seq: int, name: str):
     whole, no q, k or v cut out of it; in chunks of ``delta_chunk_size``,
     default 64, lowered as ``delta_rule_impl`` says, default ``auto``); ``W_out (rmsnorm(o) * silu(z))``, the norm over a head's
     values with one plain scale of head size shared by the heads, before the
-    gate (a Mamba mixer gates first). ``A_log`` and ``dt_bias`` are float32
+    gate (a Mamba mixer gates first), both in one ``rms_norm`` op given the
+    gate. ``A_log`` and ``dt_bias`` are float32
     and start as ``mamba``'s do (HF's constructor writes ``dt_bias = 1``,
     which a checkpoint overwrites: no state would outlive a few positions),
     the filter as a depthwise Conv1d's."""
@@ -604,9 +605,10 @@ def delta_net(x, cfg: dict, batch: int, seq: int, name: str):
                        [batch, seq, n_v]),
         n_k, d_k, chunk=cfg.get("delta_chunk_size", 64),
         impl=cfg.get("delta_rule_impl", "auto"))
-    o = layers.rms_norm(layers.reshape(o, [batch * seq, n_v, d_v]),
-                        _eps(cfg), ParamAttr(name=name + "_gated_norm_w"))
-    y = layers.swiglu(z, layers.reshape(o, [batch * seq, values]))
+    # the norm and the gate are one op: one pass over o and z each way
+    y = layers.rms_norm(layers.reshape(o, [batch * seq, n_v, d_v]),
+                        _eps(cfg), ParamAttr(name=name + "_gated_norm_w"),
+                        gate=z)
     return _linear(y, cfg["hidden_size"], name + "_out_w")
 
 

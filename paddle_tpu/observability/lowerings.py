@@ -96,6 +96,15 @@ FAMILIES = {
                 "operands"),
         "gated_delta_rule ops compiled, by the lowering and the "
         "operand form each took"),
+    # ops/decoder_ops.py:rms_norm given a Gate, and its grad op (an rms_norm
+    # without one reports nothing). impl: pallas (the one-pass kernels of
+    # ops/pallas_norm.py; the backward reads X, Gate and the cotangent and
+    # lowers no forward) / composed (the same closed forms in jax.numpy);
+    # head_dim: the normed axis
+    "rms_norm_gated_lowering_total": (
+        COUNT, ("impl", "direction", "head_dim"),
+        "gated rms_norm ops and grad ops compiled, by the lowering each "
+        "took"),
     # amount: a moe_dispatch op's row budget (attr rows), its assignments
     # without one; the sort's output, the grouped products, swiglu and the
     # combine are sized by it

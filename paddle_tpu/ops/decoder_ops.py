@@ -55,18 +55,89 @@ GMM_TILING = (512, 1024, 1024)
 def rms_norm(ctx, ins):
     """y = x / sqrt(mean(x^2, last axis) + epsilon) * Scale (attr
     ``zero_centered``: ``* (1 + Scale)``), computed in float32 whatever x's
-    dtype, returned in x's dtype."""
+    dtype, returned in x's dtype. Given ``Gate`` (X's element count: X's
+    shape, or ``[T, heads * D]`` beside ``X [T, heads, D]``) the result
+    times ``silu(Gate)``, with no rounding between the norm and the gate:
+    ``_gated_norm``."""
     import jax
     import jax.numpy as jnp
     x = ins["X"][0]
+    scale, gate = (ins.get(s, [None])[0] for s in ("Scale", "Gate"))
+    if gate is not None:
+        return {"Y": [_gated_norm(ctx, x, gate, scale)]}
     xf = x.astype(jnp.float32)
     y = xf * jax.lax.rsqrt(jnp.mean(jnp.square(xf), axis=-1, keepdims=True)
                            + ctx.attr("epsilon", 1e-5))
-    scale = ins.get("Scale", [None])[0]
     if scale is not None:
         scale = scale.astype(jnp.float32)
         y = y * (1.0 + scale if ctx.attr("zero_centered", False) else scale)
     return {"Y": [y.astype(x.dtype)]}
+
+
+def _gated_norm(ctx, x, gate, scale, dy=None):
+    """The gated ``rms_norm``'s one pass over its operands: forward, or
+    (given the cotangent ``dy``) backward, which reads X, Gate, Scale and dy
+    and returns (dX, dGate, dScale) in closed form. The Pallas kernels of
+    ``ops/pallas_norm.py`` where ``pallas_mode.lowers_kernels`` says so and
+    they take the shape, else the same expressions composed in
+    ``jax.numpy``. Which it was is reported as
+    ``rms_norm_gated_lowering_total``."""
+    import jax.numpy as jnp
+    from . import pallas_mode, pallas_norm
+    dim = x.shape[-1]
+    if gate.size != x.size:
+        raise ValueError(f"rms_norm: Gate {gate.shape} has not X's "
+                         f"{x.shape} element count")
+    eps = float(ctx.attr("epsilon", 1e-5))
+    factor = (jnp.ones((dim,), jnp.float32) if scale is None
+              else scale.astype(jnp.float32))
+    if ctx.attr("zero_centered", False):
+        factor = 1.0 + factor
+    kernels = pallas_mode.lowers_kernels(
+        ctx, ctx.attr("impl", "auto"),
+        pallas_norm.supports(
+            pallas_norm.wide_view(x.shape, gate.shape)[0], dim), "rms_norm",
+        f"needs a Gate, a last axis of a multiple of {pallas_norm.LANES} "
+        f"and rows that tile by 16; got X {x.shape}")
+    ctx.report("rms_norm_gated_lowering_total",
+               impl="pallas" if kernels else "composed",
+               direction="forward" if dy is None else "backward",
+               head_dim=dim)
+    if dy is None:
+        if kernels:
+            return pallas_norm.gated_norm(x, gate, factor, eps,
+                                          pallas_mode.interpret())
+        return pallas_norm.forward(
+            x.astype(jnp.float32), gate.reshape(x.shape).astype(jnp.float32),
+            factor, eps).astype(x.dtype)
+    if kernels:
+        dx, dz, dscale = pallas_norm._bwd_call(
+            x, gate, factor, dy.astype(x.dtype), eps, pallas_mode.interpret())
+    else:
+        dx, dz, terms = pallas_norm.backward(
+            x.astype(jnp.float32), gate.reshape(x.shape).astype(jnp.float32),
+            factor, dy.astype(jnp.float32), eps)
+        dx, dz = dx.astype(x.dtype), dz.astype(gate.dtype).reshape(gate.shape)
+        dscale = jnp.sum(terms, axis=tuple(range(x.ndim - 1)))
+    return dx, dz, None if scale is None else dscale.astype(scale.dtype)
+
+
+@register_grad("rms_norm")
+def rms_norm_grad(ctx, ins, generic):
+    """Of the gated form: dX, dGate and dScale from X, Gate, Scale and
+    ``Y@GRAD`` in closed form (``_gated_norm``); it keeps no residual
+    (a row's ``rsqrt`` is one reduction again) and lowers no forward. An
+    op without a ``Gate``, or a grad op without a cotangent, is
+    ``generic``."""
+    gate, dy = (ins.get(s, [None])[0] for s in ("Gate", "Y@GRAD"))
+    if gate is None or dy is None:
+        return generic()
+    scale = ins.get("Scale", [None])[0]
+    dx, dz, dscale = _gated_norm(ctx, ins["X"][0], gate, scale, dy)
+    grads = {"X@GRAD": [dx], "Gate@GRAD": [dz]}
+    if scale is not None:
+        grads["Scale@GRAD"] = [dscale]
+    return grads
 
 
 def yarn_inv_freq(theta, dim, factor, original_max_position, beta_fast,

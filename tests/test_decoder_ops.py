@@ -85,6 +85,37 @@ def test_rms_norm_computes_in_float32_on_bfloat16_input():
         np.asarray(jnp.asarray(want).astype(jnp.bfloat16), np.float32))
 
 
+@pytest.mark.parametrize("zero_centered", [False, True])
+@pytest.mark.parametrize("wide", [True, False], ids=["wide", "as_x"])
+def test_gated_rms_norm_matches_its_one_line_form_and_gradient(
+        wide, zero_centered):
+    """``rms_norm`` given a gate (X's shape, or ``[T, heads * D]`` beside
+    ``X [T, heads, D]``): ``rmsnorm(x) * scale * silu(gate)`` in one op,
+    forward and the three gradients, at a head size no kernel takes (the
+    composed closed forms; tests/test_pallas_norm.py has the kernels)."""
+    x = rng().randn(6, 3, 16).astype("float32")
+    z = rng(1).randn(*((6, 48) if wide else (6, 3, 16))).astype("float32")
+    w = (0.3 * rng(2).randn(16) + (0.0 if zero_centered else 1.0)).astype(
+        "float32")
+
+    def form(x, z, w):
+        unit = x / jnp.sqrt(jnp.mean(x * x, -1, keepdims=True) + 1e-5)
+        return unit * (1.0 + w if zero_centered else w) * jax.nn.silu(
+            z.reshape(x.shape))
+    out, grads, _, g, _ = run_with_grads(
+        lambda xv, zv: layers.rms_norm(
+            xv, 1e-5, fluid.ParamAttr(
+                name="w", initializer=fluid.initializer.NumpyArrayInitializer(
+                    w)), zero_centered=zero_centered, gate=zv),
+        {"x": x, "z": z}, ["x", "z", "w"])
+    assert out.shape == x.shape
+    close(out, form(x, z, w))
+    want = jax.grad(lambda *v: jnp.sum(form(*v) * g), (0, 1, 2))(x, z, w)
+    for got, ref in zip(grads, want):
+        assert got.shape == ref.shape
+        close(got, ref)
+
+
 def test_rotary_embedding_matches_rotate_half_and_gradient():
     x = rng().randn(2, 3, 8, 16).astype("float32")
 

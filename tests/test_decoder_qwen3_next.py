@@ -344,6 +344,79 @@ def test_the_delta_rule_reads_the_convs_output_whole(f32, monkeypatch):
             atol=3e-5 * np.abs(want).max(), err_msg=n)
 
 
+_one_op_norm = layers.rms_norm
+
+
+def _two_op_norm(input, epsilon=1e-5, param_attr=None, name=None,
+                 zero_centered=False, gate=None, impl="auto"):
+    """The mixer's output norm as it was built before ``rms_norm`` took the
+    gate: the norm over a head's values, then ``swiglu(z, .)``, two ops."""
+    o = _one_op_norm(input, epsilon, param_attr, name, zero_centered)
+    if gate is None:
+        return o
+    return layers.swiglu(gate, layers.reshape(o, [int(d) for d in gate.shape]))
+
+
+def test_the_mixers_norm_and_gate_are_one_op(f32, monkeypatch):
+    """A DeltaNet mixer ends ``gated_delta_rule`` -> (reshape) -> one
+    ``rms_norm`` given the gate z -> the output projection: no ``swiglu``
+    reads z, the grad op returns X's, Gate's and Scale's gradients, and the
+    first step's loss and every parameter's gradient (``_gated_norm_w``,
+    ``_in_w`` and ``_out_w`` among them) are the two-op form's, on the same
+    parameters in the same creation order."""
+    block = f32["b"]["main"].global_block()
+    made_by = {name: op for op in block.ops
+               for names in op.outputs.values() for name in names}
+
+    def readers(name):
+        return [o for o in block.ops if name in sum(o.inputs.values(), [])
+                and not o.type.endswith("_grad")]
+    gated = [op for op in block.ops
+             if op.type == "rms_norm" and "Gate" in op.inputs]
+    assert len(gated) == 2      # a DeltaNet layer each
+    for i, op in enumerate(gated):
+        assert not op.attr("zero_centered", False)
+        reshape = made_by[op.inputs["X"][0]]
+        assert reshape.type.startswith("reshape")
+        assert made_by[reshape.inputs["X"][0]].type == "gated_delta_rule"
+        assert op.inputs["Scale"] == [f"layer{i}_delta_gated_norm_w"]
+        z = op.inputs["Gate"][0]
+        assert made_by[z].type == "split"
+        assert [o.type for o in readers(z)] == ["rms_norm"]
+        (out,) = readers(op.outputs["Y"][0])
+        assert out.type == "mul" and out.inputs["Y"] == [
+            f"layer{i}_delta_out_w"]
+    grads = [op for op in block.ops
+             if op.type == "rms_norm_grad" and "Gate" in op.inputs]
+    assert [sorted(k for k in op.outputs if op.outputs[k])
+            for op in grads] == [["Gate@GRAD", "Scale@GRAD", "X@GRAD"]] * 2
+    kinds = [op.type for op in block.ops]
+    # the three expert layers' gated products (routed and shared) are left
+    assert kinds.count("swiglu") == 2 * 3
+    monkeypatch.setattr(layers, "rms_norm", _two_op_norm)
+    b = built(MODEL)
+    two = [op.type for op in b["main"].global_block().ops]
+    assert two.count("swiglu") == 2 * 3 + 2
+    assert not any("Gate" in op.inputs for op in b["main"].global_block().ops
+                   if op.type == "rms_norm")
+    assert two.count("rms_norm") == kinds.count("rms_norm")
+    assert b["params"] == f32["b"]["params"]
+    exe, scope = fluid.Executor(), fluid.Scope()
+    exe.run(b["startup"], scope=scope)
+    for n, w in zip(b["params"], f32["weights"]):
+        scope.set_var(n, jnp.asarray(w))
+    got = exe.run(b["main"], feed=batch(), scope=scope, fetch_list=[
+        b["out"]["loss"].name] + [n + "@GRAD" for n in b["params"]])
+    exe.close()
+    assert float(got[0].reshape(-1)[0]) == pytest.approx(f32["loss"],
+                                                         rel=2e-6)
+    for n, g in zip(b["params"], got[1:]):
+        want = np.asarray(f32["grads"][n], np.float32)
+        np.testing.assert_allclose(
+            np.asarray(g, np.float32), want, rtol=0,
+            atol=3e-5 * np.abs(want).max(), err_msg=n)
+
+
 def _without(mechanism):
     model = copy.deepcopy(MODEL)
     if mechanism == "shared_gate":

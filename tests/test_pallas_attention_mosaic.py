@@ -506,6 +506,49 @@ def test_rotary_kernel_compiles_for_v5e(one_chip, shape, rot):
         assert f",{seq},{rot // 2}]" not in text
 
 
+@pytest.mark.parametrize("wide", [True, False], ids=["wide", "as_x"])
+def test_gated_norm_kernels_compile_for_v5e(one_chip, wide):
+    """The gated ``rms_norm``'s kernels at qwen3_next's mixer (8,192 tokens,
+    32 value heads of 128 = ``[262144, 128]`` rows): one kernel forward, one
+    backward with no forward under it, no float32 array of the operands'
+    size beside them, and (``wide``: the delta rule's flat output and the
+    projection's ``[T, heads * D]`` gate, as the model hands them) no copy
+    or relayout of an operand either: the kernels read the 2-D arrays in
+    place, a head at its column offset."""
+    from paddle_tpu.ops import pallas_norm
+    T, heads, dim = 8192, 32, 128
+    flat = jax.ShapeDtypeStruct((2, T // 2, heads * dim), jnp.bfloat16,
+                                sharding=one_chip)
+    z = jax.ShapeDtypeStruct((T, heads * dim) if wide else (T, heads, dim),
+                             jnp.bfloat16, sharding=one_chip)
+    w = jax.ShapeDtypeStruct((dim,), jnp.float32, sharding=one_chip)
+    assert pallas_norm.supports(pallas_norm.wide_view(
+        (T, heads, dim), z.shape)[0], dim)
+
+    def heads_of(o):            # as decoder_lm.delta_net reshapes the rule's
+        return o.reshape(T, heads, dim) if wide else o.reshape(z.shape)
+
+    # read as the model's neighbours read them: the output projection
+    # flattens y, the delta rule's grad op flattens dx
+    def forward(o, z, w):
+        return pallas_norm._fwd_call(heads_of(o), z, w, 1e-6,
+                                     False).reshape(T, -1)
+
+    def backward(o, z, w, dy):
+        dx, dz, dw = pallas_norm._bwd_call(heads_of(o), z, w, heads_of(dy),
+                                           1e-6, False)
+        return dx.reshape(o.shape), dz, dw
+    for fn, args in ((forward, (flat, z, w)), (backward, (flat, z, w, flat))):
+        compiled = jax.jit(fn).lower(*args).compile()
+        assert _kernels(compiled) == 1
+        text = compiled.as_text()
+        assert f"f32[{T},{heads * dim}]" not in text
+        assert f"f32[{T},{heads},{dim}]" not in text
+        assert f"f32[{T * heads},{dim}]" not in text
+        if wide:
+            assert " copy(" not in text and " reshape(" not in text
+
+
 def _captured_step(main, feed, fetch, scope):
     """The jitted train step of ``main`` and its arguments, taken from the
     executor where it would compile them."""

@@ -25,6 +25,13 @@ leaf); this file adds:
               milliseconds a layer and the composed form's temporaries; and
               the flash kernels at the cell's attention shapes (16 query
               over 2 key/value heads of 256, causal) over ``--blocks``.
+``norm``      the gated norm at a DeltaNet mixer's end (``rms_norm`` given
+              the gate, ``ops/pallas_norm.py``) at the cell's shapes:
+              milliseconds a layer and GB/s of its kernels, forward and
+              backward, over ``--norm-blocks`` rows a block and
+              ``--norm-chunks`` rows a chunk (0: the defaults), beside the
+              two-op form it replaced (``rms_norm`` then ``swiglu``, each
+              float32 inside, the backward as ``jax.vjp`` of both).
 """
 from __future__ import annotations
 
@@ -222,14 +229,95 @@ def kernels(args) -> dict:
     return result
 
 
+def norm(args) -> dict:
+    """Forward / backward milliseconds a layer of the gated norm's kernels
+    by block and chunk, and of the two-op form left to XLA."""
+    import jax
+    import jax.numpy as jnp
+    from paddle_tpu.ops import pallas_mode, pallas_norm, pallas_rope
+    cell = laguna_probe.load_cell(args)
+    model, p = cell["model"], cell["params"]
+    T = p["batch"] * p["seq"]
+    n_v, d_v = model["linear_num_value_heads"], model["linear_value_head_dim"]
+    eps = float(model["rms_norm_eps"])
+    interpret = pallas_mode.interpret() if args.rehearsal else False
+    rng = np.random.RandomState(args.seed % (2 ** 31))
+    bf = jnp.bfloat16
+    x, dy = (jnp.asarray(rng.randn(T, n_v, d_v), bf) for _ in range(2))
+    z = jnp.asarray(rng.randn(T, n_v * d_v), bf)
+    w = jnp.asarray(1 + 0.1 * rng.randn(d_v), jnp.float32)
+    moved = x.size * 2 / 1e6       # MB of one operand
+    default_chunk = pallas_norm.CHUNK_ROWS      # kept while the sweep sets it
+    result = {"mode": "norm", "kernels": []}
+    sweep = [(rows, chunk) for rows in args.norm_blocks
+             for chunk in args.norm_chunks]
+    if not (pallas_norm.supports(T, d_v) and pallas_mode.available()):
+        say(f"gated norm kernels: rows of {d_v} on "
+            f"{jax.default_backend()!r} are not theirs")
+        sweep = []
+    for rows, chunk in sweep:
+        pallas_norm.block_rows_of = (
+            (lambda r, d, rows=rows: min(rows, r)) if rows
+            else pallas_rope.block_rows_of)
+        pallas_norm.CHUNK_ROWS = chunk or default_chunk
+        for call in (pallas_norm._fwd_call, pallas_norm._bwd_call):
+            call.clear_cache()
+        # eight calls chained inside one jit: a call is under a
+        # millisecond, and a dispatch from the host is not much less
+        def fwd8(x, z, w):
+            for _ in range(8):
+                x = pallas_norm._fwd_call(x, z, w, eps, interpret)
+            return x
+
+        def bwd8(x, z, w, dy):
+            for _ in range(8):
+                x, dz, dw = pallas_norm._bwd_call(x, z, w, dy, eps,
+                                                  interpret)
+            return x, dz, dw
+        try:
+            fwd = _ms(jax.jit(fwd8), x, z, w, calls=2, repeats=5) / 8
+            bwd = _ms(jax.jit(bwd8), x, z, w, dy, calls=2, repeats=5) / 8
+        except Exception as e:      # a block the compiler refuses
+            say(f"gated norm kernels, {rows} rows a block, {chunk} a "
+                f"chunk: {type(e).__name__}: {str(e)[:200]}")
+            continue
+        result["kernels"].append({"block_rows": rows, "chunk_rows": chunk,
+                                  "fwd_ms": fwd, "bwd_ms": bwd})
+        say(f"gated norm kernels, {rows or 'default'} rows a block, "
+            f"{chunk or 'default'} a chunk: forward {fwd:.3f} ms "
+            f"({3 * moved / fwd:.0f} GB/s) backward {bwd:.3f} ms "
+            f"({5 * moved / bwd:.0f} GB/s) a layer ({T} x {n_v} x {d_v})")
+
+    def two_ops(x, z, w):           # the parent's ops, each float32 inside
+        xf = x.astype(jnp.float32)
+        o = (xf * jax.lax.rsqrt(jnp.mean(jnp.square(xf), -1, keepdims=True)
+                                + eps) * w).astype(x.dtype)
+        gf = z.astype(jnp.float32)
+        return (gf * jax.nn.sigmoid(gf) * o.reshape(z.shape).astype(
+            jnp.float32)).astype(z.dtype)
+    both = jax.jit(lambda x, z, w, dy: jax.vjp(two_ops, x, z, w)[1](
+        dy.reshape(z.shape)))
+    t_fwd = _ms(jax.jit(two_ops), x, z, w)
+    t_both = _ms(both, x, z, w, dy)
+    say(f"two-op form left to XLA: forward {t_fwd:.3f}, forward + backward "
+        f"{t_both:.3f} ms a layer")
+    result.update(two_op_fwd_ms=t_fwd, two_op_fwd_bwd_ms=t_both)
+    return result
+
+
 def main(argv=None) -> int:
     def options(ap):
         ap.set_defaults(cell=CELL)
         ap.add_argument("--chunks", type=int, nargs="*", default=[64, 128],
                         help="kernels: the chunk lengths to time")
+        ap.add_argument("--norm-blocks", type=int, nargs="*", default=[0],
+                        help="norm: rows a block (0: the default)")
+        ap.add_argument("--norm-chunks", type=int, nargs="*", default=[0],
+                        help="norm: rows a chunk (0: the default)")
     return laguna_probe.main(
         argv, {"load": laguna_probe.held_shares, "controls": controls,
-               "grads": gradients, "kernels": kernels}, __doc__, options)
+               "grads": gradients, "kernels": kernels, "norm": norm},
+        __doc__, options)
 
 
 if __name__ == "__main__":
