@@ -1434,14 +1434,17 @@ def moe_ffn(x, num_experts, experts_per_token, expert_width, param_attr=None,
                  "Slot": [slot], "Count": [load]}
     sort_attrs = {"num_experts": E}
     groups = load           # rows a group of the sorted buffer, in its order
+    sum_attrs = {}          # moe_combine's: where the held rows end
     if held < E:            # the sort starts at the first held expert
         groups = _out(helper, "int32", stop_gradient=True)
         sorted_to["GroupCount"] = [groups]
-        sort_attrs["first_expert"] = first
+        sort_attrs.update(first_expert=first, held=held)
+        sum_attrs.update(held=held)
     if row_budget is not None:
         dropped = _out(helper, "int32", stop_gradient=True)
         sorted_to["Dropped"] = [dropped]
-        sort_attrs.update(rows=int(row_budget), held=held)
+        sort_attrs.update(rows=int(row_budget))
+        sum_attrs.update(rows=int(row_budget))
     op("moe_dispatch", {"X": [x], "Index": [index], "Weight": [weight]},
        sorted_to, sort_attrs)
     if row_budget is not None:
@@ -1462,9 +1465,8 @@ def moe_ffn(x, num_experts, experts_per_token, expert_width, param_attr=None,
                    experts(rows, "up_w", [held, H, width]), row_weight)
     down = experts(gated, "down_w", [held, width, H])
     out = _out(helper, x.dtype)
-    op("moe_combine", {"X": [down], "Order": [order], "Slot": [slot]},
-       {"Out": [out]},
-       {} if row_budget is None else {"rows": int(row_budget)})
+    op("moe_combine", {"X": [down], "Order": [order], "Slot": [slot],
+                       "GroupCount": [groups]}, {"Out": [out]}, sum_attrs)
     aux["routed"] = out
     if shared_width:
         def dense(inp, suffix, size):

@@ -105,6 +105,17 @@ FAMILIES = {
         COUNT, ("impl", "direction", "head_dim"),
         "gated rms_norm ops and grad ops compiled, by the lowering each "
         "took"),
+    # ops/decoder_ops.py: an expert layer's token sums, moe_combine's
+    # forward (op=combine) and moe_dispatch's registered grad lowering
+    # (dispatch_grad). impl: pallas (the one kernel of
+    # ops/pallas_moe_rows.py, which reads the held groups' rows and no
+    # other) / composed (the scatter-add under a row budget, the gathered
+    # reduce without); bound: held (the layer holds a part of its experts:
+    # the rows behind theirs are padding, not read) / all
+    "moe_rows_lowering_total": (
+        COUNT, ("impl", "op", "bound"),
+        "token sums of the expert layers compiled, by the lowering each "
+        "took"),
     # amount: a moe_dispatch op's row budget (attr rows), its assignments
     # without one; the sort's output, the grouped products, swiglu and the
     # combine are sized by it

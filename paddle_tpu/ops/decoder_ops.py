@@ -27,8 +27,12 @@ case in which an assignment is lost, and the layer says how often.
 
 What moves rows is written so that forward and backward are both gathers
 (``_movers``: a permutation's transpose is the inverse permutation, which
-the sort already gave), never a scatter-add of wide rows -- but under a row
-budget, where the few rows kept are added to their tokens. The three matmuls
+the sort already gave), never a scatter-add of wide rows -- but, in the
+composed form, under a row budget, where the few rows kept are added to
+their tokens. The direction that sums (``moe_combine``'s forward,
+``moe_dispatch``'s registered grad lowering) is on a TPU one kernel, budget
+or none, that reads the held experts' rows and nothing behind them
+(``ops/pallas_moe_rows.py``, PR 50). The three matmuls
 are separate ops so that what the backward needs (sorted rows, gate, up, the
 weighted gated product) are Program variables: a grad op re-lowers its
 forward under ``jax.vjp`` (core/registry.py), a grouped matmul's own output
@@ -438,69 +442,129 @@ def _sigmoid_routing(ctx, logits, bias):
 
 def _moved(fwd_rows, bwd_rows):
     """A row movement whose transpose is another row movement: ``f(x, order,
-    slot)`` with integer ``order [A]`` (the flat assignment held by each
-    sorted row) and ``slot [T, k]`` (the sorted row of each assignment)."""
+    slot, bounds, live)`` with integer ``order [A]`` (the flat assignment
+    held by each sorted row), ``slot [T, k]`` (the sorted row of each
+    assignment), ``bounds [G + 1]`` (the first sorted row of each group the
+    layer holds and the end of the last) and ``live`` (that end, under
+    ``held``: sorted rows from it on are padding; None where every row of
+    the buffer is some held expert's)."""
     import jax
 
     @jax.custom_vjp
-    def f(x, order, slot):
-        return fwd_rows(x, order, slot)
+    def f(x, order, slot, bounds, live):
+        return fwd_rows(x, order, slot, bounds, live)
 
-    def fwd(x, order, slot):
-        return fwd_rows(x, order, slot), (order, slot)
+    def fwd(x, order, slot, bounds, live):
+        return fwd_rows(x, order, slot, bounds, live), (order, slot, bounds,
+                                                        live)
 
     def bwd(res, g):        # every movement keeps its argument's dtype
-        return bwd_rows(g, *res), None, None
+        return bwd_rows(g, *res), None, None, None, None
 
     f.defvjp(fwd, bwd)
     return f
 
 
-def _gather_tokens(x, order, slot):         # [T, ...] -> [A, ...]
+def _gather_tokens(x, order, slot, bounds, live):       # [T, ...] -> [A, ...]
+    # the padding's rows too (from ``live`` on): zeroing them is a second
+    # pass over the buffer that XLA does not fuse into the gather (0.23 M
+    # cycles an op at [5120, 3072], 2.4 M at [65536, 2048]; PR 50)
     return x[order // slot.shape[1]]
 
 
-def _sum_slots(g, order, slot):             # [A, H] -> [T, H]
+def _sum_slots(g, order, slot, bounds=None, live=None):  # [A, H] -> [T, H]
     import jax.numpy as jnp
-    return jnp.sum(g[slot], axis=1, dtype=jnp.float32).astype(g.dtype)
+    rows = g[slot]
+    if live is not None:
+        rows = jnp.where((slot < live)[..., None], rows,
+                         jnp.zeros((), g.dtype))
+    return jnp.sum(rows, axis=1, dtype=jnp.float32).astype(g.dtype)
 
 
-def _add_rows(g, order, slot):              # [R, H] -> [T, H], R rows kept
-    # a row budget: the rows kept are far fewer than the tokens' k slots,
-    # so each is added to its token (a gather of [T, k] rows, nearly all of
-    # them the fill, cost 2 ms a layer at 4096 x 10 x 3072; chip runs, PR 39)
+def _add_rows(g, order, slot, bounds=None, live=None):
+    # [R, H] -> [T, H], R rows kept by a row budget: the rows kept are far
+    # fewer than the tokens' k slots, so each is added to its token (a gather
+    # of [T, k] rows, nearly all of them the fill, cost 2 ms a layer at 4096
+    # x 10 x 3072; chip runs, PR 39)
     import jax.numpy as jnp
+    kept = g.astype(jnp.float32)
+    if live is not None:
+        kept = jnp.where(jnp.arange(g.shape[0])[:, None] < live, kept, 0.0)
     return jnp.zeros((slot.shape[0], g.shape[1]), jnp.float32).at[
-        order // slot.shape[1]].add(g.astype(jnp.float32)).astype(g.dtype)
+        order // slot.shape[1]].add(kept).astype(g.dtype)
 
 
-def _gather_assignments(w, order, slot):    # [T, k] -> [A]
+def _kernel_sums(interpret, g, order, slot, bounds, live):
+    # [R, H] -> [T, H], budget or none: ops/pallas_moe_rows.py
+    from . import pallas_moe_rows
+    return pallas_moe_rows.token_sums(g, slot, bounds, interpret)
+
+
+def _gather_assignments(w, order, slot, bounds, live):  # [T, k] -> [A]
     return w.reshape(-1)[order]
 
 
-def _gather_slots(g, order, slot):          # [A] -> [T, k]
+def _gather_slots(g, order, slot, bounds, live):        # [A] -> [T, k]
     return g[slot]
 
 
-def _gather_kept_slots(g, order, slot):     # [R] -> [T, k], R rows kept
-    # a row budget: an assignment whose row was not kept reads zero
+def _gather_kept_slots(g, order, slot, bounds, live):
+    # [R] -> [T, k], R rows kept by a row budget: an assignment whose row
+    # was not kept reads zero
     return g.at[slot].get(mode="fill", fill_value=0)
 
 
+def _sums(budgeted: bool, kernel):
+    """Sorted rows -> token sums: the kernel of ``ops/pallas_moe_rows.py``,
+    budget or none (``kernel``: the call's ``interpret`` flag), or (None)
+    the composed form, additions of the kept rows under a row budget and a
+    sum over each token's gathered slots without one."""
+    if kernel is not None:
+        return functools.partial(_kernel_sums, kernel)
+    return _add_rows if budgeted else _sum_slots
+
+
 @functools.lru_cache(maxsize=None)
-def _movers(budgeted: bool = False):
+def _movers(budgeted: bool = False, kernel=None):
     """(token rows -> sorted rows, sorted rows -> token sums, router weights
-    -> sorted weights): each one's transpose is another of these gathers.
+    -> sorted weights): each one's transpose is another of these movements.
     ``budgeted`` (the ops' ``rows`` attr): the sorted side holds the rows a
-    budget kept, so sums over slots become additions of the kept rows and a
-    slot without a row reads zero."""
-    if budgeted:
-        return (_moved(_gather_tokens, _add_rows),
-                _moved(_add_rows, _gather_tokens),
-                _moved(_gather_assignments, _gather_kept_slots))
-    return (_moved(_gather_tokens, _sum_slots),
-            _moved(_sum_slots, _gather_tokens),
-            _moved(_gather_assignments, _gather_slots))
+    budget kept, and a slot without a row reads zero; ``kernel``:
+    ``_sums``."""
+    sums = _sums(budgeted, kernel)
+    return (_moved(_gather_tokens, sums), _moved(sums, _gather_tokens),
+            _moved(_gather_assignments,
+                   _gather_kept_slots if budgeted else _gather_slots))
+
+
+def _held_rows(ctx, count):
+    """(``bounds``, ``live``) of the movers from ``count [E]``, the rows of
+    each group of the sorted buffer in its order (``GroupCount``, which a
+    row budget has cut already): under attr ``held`` the bounds of the held
+    groups and the end of their rows, the padding's start; without it every
+    group's bounds and no padding (None)."""
+    import jax.numpy as jnp
+    held = int(ctx.attr("held", 0))
+    ends = jnp.cumsum(count[:held] if held else count)
+    bounds = jnp.concatenate([jnp.zeros((1,), ends.dtype),
+                              ends]).astype(jnp.int32)
+    return bounds, (bounds[-1] if held else None)
+
+
+def _sums_kernel(ctx, op, rows, slot, count):
+    """How the token sums over the sorted ``rows [R, H]`` lower in the op
+    that computes them (``op``: combine / dispatch_grad): the ``interpret``
+    flag of the kernel's call where ``pallas_mode.lowers_kernels`` says so,
+    the kernel takes the shapes and the op was given the groups' counts,
+    else None (``_sums``); reported as ``moe_rows_lowering_total``."""
+    from . import pallas_mode, pallas_moe_rows
+    kernel = pallas_mode.lowers_kernels(
+        ctx, "auto", count is not None and pallas_moe_rows.supports(
+            slot.shape[0], rows.shape[0], rows.shape[1], rows.dtype))
+    ctx.report("moe_rows_lowering_total",
+               impl="pallas" if kernel else "composed", op=op,
+               bound="held" if int(ctx.attr("held", 0)) else "all")
+    return pallas_mode.interpret() if kernel else None
 
 
 @register("moe_dispatch", nondiff_inputs=("Index",),
@@ -524,7 +588,18 @@ def moe_dispatch(ctx, ins):
     buffers keep all T*k rows -- the worst case, every assignment local --
     and the grouped products stop after the held experts' rows.
 
-    Attr ``rows`` (0: none), with ``held`` the experts held here: a row
+    Attr ``held`` (0: all): the experts held here. The sorted rows from the
+    held experts' end on (``live`` = the sum of ``GroupCount``'s first
+    ``held`` entries, at most the buffer) are *padding*: no held expert
+    multiplies them. ``Out`` holds their tokens' rows there as everywhere
+    (zeroing them would be a second pass over the buffer), but as
+    constants: ``Out = where(row < live, X[token], stop_gradient(X[token]))``.
+    So the gradient ignores the cotangent's rows there -- ``X@GRAD`` sums,
+    for each token, the cotangent's rows of its slots below ``live`` -- and
+    nothing past ``live`` is read in the backward. ``RowWeight`` is the
+    router's weight on every row.
+
+    Attr ``rows`` (0: none), with ``held``: a row
     budget. ``Out``, ``RowWeight`` and ``Order`` keep the first ``rows``
     sorted rows only (the held experts' lead the buffer), ``Slot`` still
     names every assignment's sorted row, those from ``rows`` up being rows
@@ -546,25 +621,49 @@ def moe_dispatch(ctx, ins):
                     axis=0, dtype=jnp.int32)
     budget = int(ctx.attr("rows", 0))
     ctx.report("moe_row_budget", budget or flat.shape[0])
+    outs = {"Slot": [slot], "GroupCount": [count],
+            "Count": [jnp.roll(count, first) if first else count]}
+    if budget:
+        if budget > flat.shape[0]:
+            raise ValueError(f"moe_dispatch: a budget of {budget} rows for "
+                             f"{flat.shape[0]} assignments")
+        order = order[:budget]
+        ends = jnp.minimum(jnp.cumsum(count), budget)
+        held_rows = jnp.sum(count[:int(ctx.attr("held"))])
+        outs.update(
+            GroupCount=[jnp.diff(ends, prepend=0).astype(jnp.int32)],
+            Dropped=[jnp.maximum(held_rows - budget, 0).reshape(1)
+                     .astype(jnp.int32)])
+    # the registered grad lowering chooses how the transpose's sums lower;
+    # what differentiates this lowering directly takes the composed form
     to_rows, _, to_row_weights = _movers(bool(budget))
-    if not budget:
-        return {"Out": [to_rows(x, order, slot)],
-                "RowWeight": [to_row_weights(weight, order, slot)],
-                "Order": [order], "Slot": [slot], "GroupCount": [count],
-                "Count": [jnp.roll(count, first) if first else count]}
-    if budget > flat.shape[0]:
-        raise ValueError(f"moe_dispatch: a budget of {budget} rows for "
-                         f"{flat.shape[0]} assignments")
-    order = order[:budget]
-    ends = jnp.minimum(jnp.cumsum(count), budget)
-    held_rows = jnp.sum(count[:int(ctx.attr("held"))])
-    return {"Out": [to_rows(x, order, slot)],
-            "RowWeight": [to_row_weights(weight, order, slot)],
-            "Order": [order], "Slot": [slot],
-            "GroupCount": [jnp.diff(ends, prepend=0).astype(jnp.int32)],
-            "Count": [jnp.roll(count, first) if first else count],
-            "Dropped": [jnp.maximum(held_rows - budget, 0).reshape(1)
-                        .astype(jnp.int32)]}
+    where = _held_rows(ctx, outs["GroupCount"][0])
+    return {"Out": [to_rows(x, order, slot, *where)],
+            "RowWeight": [to_row_weights(weight, order, slot, *where)],
+            "Order": [order], **outs}
+
+
+@register_grad("moe_dispatch")
+def moe_dispatch_grad(ctx, ins, generic):
+    """``X@GRAD``: each token's sum of ``Out@GRAD``'s rows at its slots
+    (below ``live`` under ``held``: the vjp of what ``moe_dispatch``
+    documents) -- the token sums ``moe_combine`` computes, on the same
+    kernel; ``Weight@GRAD``: ``RowWeight@GRAD`` gathered back to ``[T,
+    k]``. It reads the forward op's ``Order`` / ``Slot`` / ``GroupCount``
+    and sorts nothing. A grad op without both cotangents, or a desc from
+    before the op had its sort as outputs, is ``generic``."""
+    g, gw = (ins.get(s, [None])[0] for s in ("Out@GRAD", "RowWeight@GRAD"))
+    order, slot = (ins.get(s, [None])[0] for s in ("Order", "Slot"))
+    count = ins.get("GroupCount", ins.get("Count", [None]))[0]
+    if any(v is None for v in (g, gw, order, slot, count)):
+        return generic()
+    budgeted = bool(int(ctx.attr("rows", 0)))
+    sums = _sums(budgeted, _sums_kernel(ctx, "dispatch_grad", g, slot, count))
+    to_slots = _gather_kept_slots if budgeted else _gather_slots
+    return {"X@GRAD": [sums(g.astype(ins["X"][0].dtype), order, slot,
+                            *_held_rows(ctx, count))],
+            "Weight@GRAD": [to_slots(gw.astype(ins["Weight"][0].dtype),
+                                     order, slot, None, None)]}
 
 
 def grouped_matmul(x, w, count, kernels: bool):
@@ -602,17 +701,33 @@ def moe_expert_matmul(ctx, ins):
                                    ins["Count"][0], kernels)]}
 
 
-@register("moe_combine", nondiff_inputs=("Order", "Slot"))
+@register("moe_combine", nondiff_inputs=("Order", "Slot", "GroupCount"))
 def moe_combine(ctx, ins):
     """Each token's output: the sum of its k assignments' rows (already
     weighted, see ``swiglu``'s ``Scale``). ``X [T*k, H]`` sorted rows,
     ``Order`` / ``Slot`` from ``moe_dispatch`` -> ``Out [T, H]`` in X's
     dtype, summed in float32. Attr ``rows`` (``moe_dispatch``'s row budget;
     0: none): ``X`` has that many rows, each added to its token, and an
-    assignment whose row was not kept adds nothing."""
-    _, to_tokens, _ = _movers(bool(int(ctx.attr("rows", 0))))
-    return {"Out": [to_tokens(ins["X"][0], ins["Order"][0],
-                              ins["Slot"][0])]}
+    assignment whose row was not kept adds nothing.
+
+    ``GroupCount`` (``moe_dispatch``'s, optional, no gradient) and attr
+    ``held`` (0: all) say where the held experts' rows end (``live``, as in
+    ``moe_dispatch``): the sum is over a token's slots *below* ``live``,
+    and the rows from there on are padding that is not read. ``X@GRAD`` is
+    the cotangent's row of each sorted row's token; in the padding, where
+    the vjp is zero, it holds that row all the same and is padding in its
+    turn (the grouped products' grad ops read the held groups' rows only;
+    zeroing it would be a second pass). Given ``GroupCount`` the sums lower
+    as the kernel of ``ops/pallas_moe_rows.py`` where it runs, budget or
+    none (``moe_rows_lowering_total``)."""
+    x, order, slot = ins["X"][0], ins["Order"][0], ins["Slot"][0]
+    count = ins.get("GroupCount", [None])[0]
+    if count is None and int(ctx.attr("held", 0)):
+        raise ValueError("moe_combine: attr held needs the input GroupCount")
+    _, to_tokens, _ = _movers(bool(int(ctx.attr("rows", 0))), _sums_kernel(
+        ctx, "combine", x, slot, count))
+    where = (None, None) if count is None else _held_rows(ctx, count)
+    return {"Out": [to_tokens(x, order, slot, *where)]}
 
 
 @register("moe_bias_update", grad=None)

@@ -487,3 +487,123 @@ def test_moe_gauges_and_load_stats():
     stats = obs_moe.load_stats([4, 0, 8, 4])
     assert stats == {"max": 8.0, "mean": 4.0, "max_over_mean": 2.0,
                      "empty": 1}
+
+
+HELD_PARAMS = ["m_router_w", "m_gate_w", "m_up_w", "m_down_w"]
+
+
+def held_layer(held, budget, kernel, monkeypatch, dtype="float32", T=64,
+               H=128):
+    """``layers.moe_ffn`` over 8 experts (top-4, width 16) holding ``held``
+    of them, under ``budget`` rows or none, one step with its backward: the
+    token sums on the kernel of ops/pallas_moe_rows.py (in the interpreter)
+    or -- ``kernel`` False: what every process off a TPU lowers -- the
+    composed forms. Returns the output, the routed part, the gradients of x,
+    the router and the three stacked weights, and the Program."""
+    from paddle_tpu.ops import pallas_mode
+    monkeypatch.setattr(pallas_mode, "TEST_INTERPRET", kernel)
+    main, startup = fluid.Program(), fluid.Program()
+    startup.random_seed = 5
+    with fluid.unique_name.guard(), fluid.program_guard(main, startup):
+        xv = fluid.data("x", [T, H], dtype, append_batch_size=False)
+        xv.stop_gradient = False
+        out, aux = layers.moe_ffn(
+            layers.scale(xv, 1.0), 8, 4, 16, name="m", experts_held=held,
+            row_budget=budget, norm_topk=True, shared_width=16,
+            param_attr=fluid.ParamAttr(
+                initializer=fluid.initializer.Normal(0.0, 0.3)))
+        g = layers.assign(rng(9).randn(T, H).astype("float32"))
+        fluid.append_backward(layers.reduce_sum(layers.elementwise_mul(
+            layers.cast(out, "float32"), g)))
+    exe, scope = fluid.Executor(), fluid.Scope()
+    exe.run(startup, scope=scope)
+    x = np.asarray(jnp.asarray(rng(1).randn(T, H), dtype))
+    got = exe.run(main, feed={"x": x}, scope=scope, fetch_list=[
+        out.name, aux["routed"].name, "x@GRAD"]
+        + [p + "@GRAD" for p in HELD_PARAMS])
+    exe.close()
+    return got, main
+
+
+@pytest.mark.parametrize("held,budget", [
+    ((0, 2), None), ((5, 3), None), ((0, 2), 128), ((5, 3), 160),
+    ((0, 2), 40), (None, None)],
+    ids=["held", "held_from_5", "budget", "budget_from_5",
+         "budget_that_drops", "all_held"])
+def test_expert_layer_on_the_sums_kernel_equals_the_composed_lowering(
+        held, budget, monkeypatch):
+    """The layer's output, its routed part and every gradient (x, the
+    router, the three stacked weights), float32, with the token sums of
+    ``moe_combine`` and of ``moe_dispatch``'s grad op on the kernel, against
+    the composed forms: equal to float32's rounding. With a part of the
+    experts held the ops carry ``held`` and the sorted rows behind the held
+    experts' are padding in both lowerings; with all held (OLMoE's form)
+    the ops carry no ``held``."""
+    import lowering_reports
+    got, main = held_layer(held, budget, True, monkeypatch)
+    want, plain = held_layer(held, budget, False, monkeypatch)
+    for a, b in zip(got, want):
+        np.testing.assert_allclose(a, b, rtol=1e-6,
+                                   atol=1e-6 * max(1.0, np.abs(b).max()))
+    assert np.abs(want[1]).max() > 0.1 and np.abs(want[2]).max() > 0.01
+    for op in main.global_block().ops:
+        if op.type in ("moe_dispatch", "moe_combine", "moe_dispatch_grad"):
+            assert op.attr("held") == (held[1] if held else None), op.type
+            # the groups' counts: Count itself where the sort starts at 0
+            assert ("GroupCount" if held or op.type == "moe_combine"
+                    else "Count") in (op.outputs if op.type == "moe_dispatch"
+                                      else op.inputs), op.type
+    # one report a lowered op, by what it took
+    bound = "held" if held else "all"
+    from paddle_tpu.observability.metrics import REGISTRY
+    counts = lowering_reports.read(REGISTRY, "moe_rows_lowering_total",
+                                   "program", "impl", "op", "bound")
+    for program, impl in ((main, "pallas"), (plain, "composed")):
+        label = f"{id(program)}:v{program._version}"
+        assert {key[1:]: n for key, n in counts.items()
+                if key[0] == label} == {(impl, "combine", bound): 1,
+                                        (impl, "dispatch_grad", bound): 1}
+
+
+def test_expert_layer_on_the_sums_kernel_in_bfloat16(monkeypatch):
+    """bfloat16 rows: the kernel's float32 sums are rounded once, like the
+    composed forms'; a sum's last bit may differ (another order of float32
+    additions)."""
+    got, _ = held_layer((0, 2), 128, True, monkeypatch, "bfloat16")
+    want, _ = held_layer((0, 2), 128, False, monkeypatch, "bfloat16")
+    for a, b in zip(got, want):
+        a, b = np.asarray(a, np.float32), np.asarray(b, np.float32)
+        np.testing.assert_allclose(a, b, rtol=2 ** -6,
+                                   atol=2 ** -6 * np.abs(b).max())
+
+
+def test_the_dispatch_grad_op_sorts_nothing_and_reads_the_forwards_sort(
+        monkeypatch):
+    """``moe_dispatch_grad`` is a registered lowering: its jaxpr holds no
+    sort (the generic grad op lowers the forward, argsort and all, again
+    under ``jax.vjp``) and the token sums as one kernel call."""
+    from paddle_tpu.core import registry
+    from paddle_tpu.ops import pallas_mode
+    monkeypatch.setattr(pallas_mode, "TEST_INTERPRET", True)
+    T, k, H, E, held = 32, 2, 128, 8, 2
+    index = jnp.asarray(np.argsort(-rng(2).randn(T, E), 1)[:, :k], jnp.int32)
+    ins = {"X": [jnp.ones((T, H))], "Index": [index],
+           "Weight": [jnp.ones((T, k))]}
+    attrs = {"num_experts": E, "first_expert": 0, "held": held}
+    fwd = registry.get("moe_dispatch").lower(registry.LowerCtx(attrs), ins)
+
+    def grad(g, gw):
+        return registry.get("moe_dispatch_grad").lower(
+            registry.LowerCtx(dict(attrs, __fwd_out_slots__=list(fwd))),
+            {**ins, **fwd, "Out@GRAD": [g], "RowWeight@GRAD": [gw]})
+    g, gw = jnp.ones((T * k, H)), jnp.ones((T * k,))
+    text = str(jax.make_jaxpr(grad)(g, gw))
+    assert " sort[" not in text and "argsort" not in text
+    assert text.count("pallas_call") == 1
+    live = int(fwd["GroupCount"][0][:held].sum())
+    want = (np.asarray(fwd["Slot"][0]) < live).sum(1)[:, None]
+    np.testing.assert_array_equal(grad(g, gw)["X@GRAD"][0], want * np.ones(H))
+    # the cotangent's padding is not read: anything there changes nothing
+    dirty = g.at[live:].set(jnp.nan)
+    np.testing.assert_array_equal(grad(dirty, gw)["X@GRAD"][0],
+                                  want * np.ones(H))

@@ -26,6 +26,12 @@ def test_load_reads_the_held_share_and_the_rows_the_budget_dropped(capsys):
     # held over the budget
     assert sum(got["dropped"]) >= max(0, got["fullest_rows"] - got["budget"])
     assert got["loss_last"] < got["loss_first"]
+    # the rows the token sums read, a layer: the held experts', cut to the
+    # buffer
+    assert got["buffer_rows"] == 96 and len(got["live_rows_mean"]) == 2
+    assert all(0 < mean <= most <= 96 for mean, most in
+               zip(got["live_rows_mean"], got["live_rows_max"]))
+    assert max(got["live_rows_max"]) == min(got["fullest_rows"], 96)
 
 
 def test_controls_run_at_the_seeded_state_and_say_what_fails(capsys):
@@ -66,3 +72,25 @@ def test_without_takes_one_mechanism_out_and_keeps_the_parameters():
         "moe_row_budget"] == model["moe_row_budget"] // 10
     with pytest.raises(ValueError):
         laguna_probe.without(model, "norm")
+
+
+@pytest.mark.parametrize("cell,tilt", [
+    ("laguna_s_2_1", 0.0), ("laguna_s_2_1", 0.6), ("qwen3_next_80b_a3b", 0.0),
+    ("glm_4_7_flash", 0.0), ("lfm2_8b_a1b", 0.0), ("olmoe_1b_7b", 0.0)])
+def test_sums_times_the_token_sums_at_any_expert_cells_shape(capsys, cell,
+                                                             tilt):
+    """The rehearsal's width (64) is not the kernel's, so only the composed
+    form is timed here; the shapes are the cell's and a tilted router fills
+    the held experts' rows."""
+    got = probe(capsys, "sums", "--cell", cell + ".pretrain_s4096", "--tilt",
+                str(tilt))
+    assert got["mode"] == "sums" and got["composed_ms"] > 0
+    assert "kernel_ms" not in got and got["width"] % 128
+    assert got["live_rows"] <= got["buffer_rows"] <= got["tokens"] * got["k"]
+    even = got["tokens"] * got["k"] * got["held"] / got["routed"]
+    if tilt:
+        assert got["live_rows"] > 1.2 * min(even, got["buffer_rows"]) \
+            or got["live_rows"] == got["buffer_rows"]
+    else:
+        assert 0.6 * even < got["live_rows"] <= max(1.4 * even,
+                                                    got["buffer_rows"])

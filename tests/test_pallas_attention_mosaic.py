@@ -613,17 +613,55 @@ def _decoder_step_text(one_chip, model, batch, seq):
         *jax.tree_util.tree_map(spec, args)).compile().as_text(), main
 
 
+# tokens, top-k, width, buffer rows, held groups of the five cells with
+# expert layers (the held cells' buffers are their row budgets; LFM2's and
+# OLMoE's hold every assignment)
+TOKEN_SUMS = {"qwen3_next": (8192, 10, 2048, 20480, 32),
+              "lfm2": (16384, 4, 2048, 65536, 8),
+              "laguna": (4096, 10, 3072, 5120, 8),
+              "glm": (4096, 4, 2048, 8192, 8),
+              "olmoe": (16384, 8, 2048, 131072, 64)}
+
+
+@pytest.mark.parametrize("cell", sorted(TOKEN_SUMS))
+def test_token_sums_kernel_compiles_for_v5e(one_chip, cell):
+    """The expert layers' token sums (ops/pallas_moe_rows.py: slabs of rows
+    by DMA, a 0/1 product a 128 rows on the MXU) at the cells' shapes: one
+    Mosaic call, the rows read where they are (no copy or reshape of the
+    buffer's size beside it) and no scatter."""
+    from paddle_tpu.ops import pallas_moe_rows
+    T, k, H, R, G = TOKEN_SUMS[cell]
+    assert pallas_moe_rows.supports(T, R, H, jnp.bfloat16)
+    text = pallas_moe_rows.token_sums.lower(
+        jax.ShapeDtypeStruct((R, H), jnp.bfloat16, sharding=one_chip),
+        jax.ShapeDtypeStruct((T, k), jnp.int32, sharding=one_chip),
+        jax.ShapeDtypeStruct((G + 1,), jnp.int32, sharding=one_chip),
+    ).compile().as_text()
+    assert text.count('custom_call_target="tpu_custom_call"') == 1
+    entry = text[text.index("\nENTRY "):]
+    assert f"bf16[{R},{H}]" in entry and " scatter(" not in entry
+    assert not [ln for ln in entry.splitlines()
+                if f"[{R}," in ln.split(" = ")[-1].split("(")[0]
+                and " parameter(" not in ln]
+
+
+@pytest.mark.parametrize("held", [False, True], ids=["all_held", "a_part"])
 def test_expert_layer_step_holds_nine_grouped_matmuls_and_one_sort_by_expert(
-        one_chip, as_on_the_chip):
+        one_chip, as_on_the_chip, held):
     """D11 on the decoder: every grad op re-lowers its forward under
     jax.vjp. A small decoder_lm train step (one layer, widths Mosaic takes,
     S below the flash kernel's) compiled for the v5e must hold, a layer,
     3 forward + 6 backward grouped-matmul kernels and one sort, and no more:
-    the copies the grad ops trace are dropped or merged."""
+    the copies the grad ops trace are dropped or merged. The token sums are
+    two calls of one kernel (``moe_combine``, ``moe_dispatch_grad``: PR 50),
+    with all experts held or 4 of 16 under a row budget, and neither scope
+    holds a scatter or a float32 array of the buffer's rows."""
     import re
 
     model = dict(_DECODER, router_aux_loss_coef=0.01, router_z_loss_coef=0.001)
-    text, _ = _decoder_step_text(one_chip, model, batch=2, seq=128)
+    if held:
+        model.update(num_experts_routed=16, moe_row_budget=256)
+    text, main = _decoder_step_text(one_chip, model, batch=2, seq=128)
     kernels = [ln for ln in text.splitlines()
                if 'custom_call_target="tpu_custom_call"' in ln]
     in_scope = lambda ln, scope: re.search(                 # noqa: E731
@@ -633,7 +671,19 @@ def test_expert_layer_step_holds_nine_grouped_matmuls_and_one_sort_by_expert(
     # q's and k's rotation, and the same pass over their cotangents (PR 42)
     assert sum(in_scope(ln, "rotary_embedding") for ln in kernels) == 2
     assert sum(in_scope(ln, "rotary_embedding_grad") for ln in kernels) == 2
-    assert len(kernels) == 13           # S=128: attention is XLA's here
+    assert sum(in_scope(ln, "moe_combine") for ln in kernels) == 1
+    assert sum(in_scope(ln, "moe_dispatch_grad") for ln in kernels) == 1
+    assert len(kernels) == 15           # S=128: attention is XLA's here
+    sums = [ln for ln in text.splitlines() if in_scope(ln, "moe_combine")
+            or in_scope(ln, "moe_dispatch_grad")]
+    rows = model.get("moe_row_budget", 2 * 128 * 2)
+    assert not [ln for ln in sums if re.search(r"\sscatter\(", ln)
+                or f"f32[{rows},256]" in ln]
+    assert lowering_reports.read(
+        lowering_reports.publish(main), "moe_rows_lowering_total", "impl",
+        "op", "bound") == {
+            ("pallas", op, "held" if held else "all"): 1
+            for op in ("combine", "dispatch_grad")}
     # the stable sort by expert, once; XLA's TPU top_k is a sort too (the
     # router's), and neither is traced a second time into the step by the
     # grad ops

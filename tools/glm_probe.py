@@ -31,7 +31,8 @@ this file adds:
               shape (milliseconds a layer, the bytes they must move and the
               share of HBM's rate that is), and the flash kernels at 20
               query = 20 key/value heads of 256 over ``--blocks``, each
-              compiled alone.
+              compiled alone; and the expert layers' token sums
+              (``tools/laguna_probe.py``'s ``sums``, a mode here too).
 """
 from __future__ import annotations
 
@@ -228,6 +229,7 @@ def kernels(args) -> dict:
         f"{moved / bwd / 1e6:.0f} GB/s")
     result["flash"] = flash_by_blocks(B, S, h, h, d, args.blocks, interpret,
                                       rng)
+    result["token_sums"] = laguna_probe.token_sums(args)
     return result
 
 

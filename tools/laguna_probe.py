@@ -8,9 +8,11 @@ the data files' rehearsal sizes on the CPU (a debug run: no device number).
               every expert layer's load and dropped-row count beside the
               loss: the share of the tokens x top-k assignments held here,
               by windows of 20 steps, the fullest step's held rows against
-              the budget, the rows the budget dropped (must stay 0), the
-              allocator's peak as the harness sums it; the reference check
-              once more on the moved state. ``--budget R`` runs it under
+              the budget, each layer's live rows (what the held experts
+              received, at most the buffer: the rows the token sums read)
+              against its buffer's, the rows the budget dropped (must stay
+              0), the allocator's peak as the harness sums it; the reference
+              check once more on the moved state. ``--budget R`` runs it under
               another row budget, ``--budget 0`` under none (the T x k
               worst-case buffers: what the budget saves).
 ``controls``  at the cell's own check (seeded weights, before any step),
@@ -32,7 +34,13 @@ the data files' rehearsal sizes on the CPU (a debug run: no device number).
               causal kernels, forward and backward, at the cell's shapes:
               milliseconds a layer over ``--blocks`` (block_q x block_k
               pairs), what the K tiles visit, and the composed lowering's
-              time and temporaries.
+              time and temporaries; then ``sums``.
+``sums``      the expert layers' token sums alone at the cell's shape
+              (``--cell``: any cell with expert layers) under a seeded
+              router, even or ``--tilt``ed: the kernel of
+              ``ops/pallas_moe_rows.py`` against the composed form it
+              replaced, milliseconds an op and nanoseconds a live row, over
+              ``--sum-plans`` (tokens a block x rows a pass) besides.
 """
 from __future__ import annotations
 
@@ -81,7 +89,7 @@ def held_shares(args) -> dict:
     k, held = model["num_experts_per_tok"], model["num_experts"]
     first, n = model.get("first_expert_held", 0), len(built["expert_load"])
     names = built["expert_load"] + built["expert_dropped"]
-    shares, losses, uneven, dropped = [], [], [], None
+    shares, losses, uneven, dropped, load_by_step = [], [], [], None, []
     for i in range(args.steps):
         if i == 1:              # the first step compiled for these fetches
             t0 = time.perf_counter()
@@ -90,12 +98,14 @@ def held_shares(args) -> dict:
         s.step += 1
         losses.append(float(np.asarray(out[0]).reshape(-1)[0]))
         load = np.stack(out[1:1 + n]).astype(np.float64)
+        load_by_step.append(load)
         assert (load.sum(1) == tokens * k).all(), load.sum(1)
         shares.append(load[:, first:first + held].sum(1) / (tokens * k))
         uneven.append(load.max(1) / load.mean(1))
         dropped = np.array(out[1 + n:]).reshape(-1)     # summed since startup
     step_ms = (time.perf_counter() - t0) / (args.steps - 1) * 1e3
     shares, uneven = np.array(shares), np.array(uneven)
+    load_by_step = np.array(load_by_step)       # [steps, layers, experts]
     budget = model.get("moe_row_budget")        # None: T x k rows a layer
     fullest = float(shares.max() * tokens * k)
     peak_gb = probe.peak_bytes(s.devices) / 1e9
@@ -113,6 +123,13 @@ def held_shares(args) -> dict:
         f"; the allocator's peak, as peak_hbm_gb sums it: {peak_gb:.4f} GB; "
         f"{step_ms:.2f} ms a step with these fetches every step (not the "
         f"cell's window)")
+    rows = budget or tokens * k
+    live = np.minimum(load_by_step[:, :, first:first + held].sum(2), rows)
+    say(f"live rows a layer (the held experts' rows, which the token sums "
+        f"read) of {rows} buffer rows, mean over the steps "
+        f"{live.mean(0).round(0).tolist()}, fullest step "
+        f"{live.max(0).tolist()}: {100 * live.mean() / rows:.1f}% of the "
+        f"buffer")
     say("max load / mean over the routed experts, mean by windows of 20 "
         "steps: " + " ".join(f"{uneven[i:i + 20].mean():.3f}"
                              for i in range(0, len(uneven), 20)))
@@ -127,6 +144,8 @@ def held_shares(args) -> dict:
               "share_max": float(shares.max()),
               "fullest_rows": fullest, "budget": budget,
               "dropped": [int(d) for d in dropped], "peak_gb": peak_gb,
+              "buffer_rows": rows, "live_rows_mean": live.mean(0).tolist(),
+              "live_rows_max": live.max(0).tolist(),
               "step_ms_fetching": step_ms,
               "loss_first": s.first_loss, "loss_last": losses[-1],
               "reference_after": ok}
@@ -371,7 +390,98 @@ def kernels(args) -> dict:
         f"{temp.temp_size_in_bytes / 1e9:.3f} GB")
     return {"mode": "kernels", "rows": rows, "composed_fwd_ms": c_fwd,
             "composed_fwd_bwd_ms": c_both,
-            "composed_temp_gb": temp.temp_size_in_bytes / 1e9}
+            "composed_temp_gb": temp.temp_size_in_bytes / 1e9,
+            "token_sums": token_sums(args)}
+
+
+def token_sums(args) -> dict:
+    """An expert layer's token sums at the cell's shape (tokens, top-k,
+    hidden width, held of routed experts, row budget) under a seeded even
+    router: the kernel of ``ops/pallas_moe_rows.py`` against the composed
+    form it replaces (the scatter-add under a budget, the gathered reduce
+    without), milliseconds an op (four ops on four buffers inside one jit),
+    nanoseconds a live row, and the largest difference between the two."""
+    import jax
+    import jax.numpy as jnp
+    from paddle_tpu.models import decoder_lm
+    from paddle_tpu.ops import decoder_ops, pallas_mode, pallas_moe_rows
+    cell = load_cell(args)
+    model, p = cell["model"], cell["params"]
+    T, k, H = p["batch"] * p["seq"], model["num_experts_per_tok"], \
+        model["hidden_size"]
+    held = decoder_lm._held(model)
+    routed = model.get("num_experts_routed", held)
+    budget = model.get("moe_row_budget")
+    R = budget or T * k
+    interpret = pallas_mode.interpret() if args.rehearsal else False
+    rng = np.random.RandomState(args.seed % (2 ** 31))
+    logits = rng.randn(T, routed).astype(np.float32)
+    if args.tilt:       # one held expert draws that share of the tokens
+        logits[rng.rand(T) < args.tilt, 0] += 100.0
+    index = np.argsort(-logits, axis=1)[:, :k].astype(np.int32)
+    flat = index.reshape(-1)
+    order = np.argsort(flat, kind="stable").astype(np.int32)
+    slot = np.empty_like(order)
+    slot[order] = np.arange(order.size, dtype=np.int32)
+    bounds = np.minimum(np.concatenate(
+        [[0], np.cumsum(np.bincount(flat, minlength=routed)[:held])]), R)
+    live = int(bounds[-1])
+    buffers = []
+    for _ in range(4):
+        rows = rng.randn(R, H).astype(np.float32)
+        rows[live:] = 0         # what the grouped products leave there
+        buffers.append(jnp.asarray(rows, jnp.bfloat16))
+    slot, order = jnp.asarray(slot.reshape(T, k)), jnp.asarray(order[:R])
+    bounds = jnp.asarray(bounds, jnp.int32)
+    composed = decoder_ops._add_rows if budget else decoder_ops._sum_slots
+    def kernel():       # a fresh jit: the sweep below changes the plan
+        return jax.jit(lambda *rows: tuple(
+            pallas_moe_rows.token_sums(r, slot, bounds, interpret)
+            for r in rows))
+    forms = {"composed": jax.jit(lambda *rows: tuple(
+        composed(r, order, slot) for r in rows))}
+    if pallas_moe_rows.supports(T, R, H, jnp.bfloat16) \
+            and pallas_mode.available():
+        forms["kernel"] = kernel()
+    was = pallas_moe_rows.BLOCK_TOKENS, pallas_moe_rows.PASS_ROWS
+    for plan in args.sum_plans if "kernel" in forms else ():
+        pallas_moe_rows.BLOCK_TOKENS, pallas_moe_rows.PASS_ROWS = (
+            int(v) for v in plan.split("x"))    # tokens a block x rows a pass
+        pallas_moe_rows.token_sums.clear_cache()
+        try:
+            ms = _ms(kernel(), *buffers, calls=2, repeats=7) / 4
+            say(f"token sums, kernel at {plan}: {ms:.3f} ms an op")
+        except Exception as e:      # a plan the compiler refuses
+            say(f"token sums, kernel at {plan}: {type(e).__name__}: "
+                f"{str(e)[:200]}")
+    pallas_moe_rows.BLOCK_TOKENS, pallas_moe_rows.PASS_ROWS = was
+    pallas_moe_rows.token_sums.clear_cache()
+    result = {"mode": "sums", "tokens": T, "k": k, "width": H,
+              "held": held, "routed": routed, "buffer_rows": R,
+              "live_rows": live, "tilt": args.tilt}
+    outs = {}
+    for name, fn in forms.items():
+        outs[name] = fn(*buffers)[0].astype(jnp.float32)
+        result[f"{name}_ms"] = ms = _ms(fn, *buffers, calls=2, repeats=7) / 4
+        say(f"token sums, {name}: {ms:.3f} ms an op, {ms * 1e6 / live:.1f} "
+            f"ns a live row ({T} tokens x top-{k} of width {H}, {held} of "
+            f"{routed} experts held, {live} live of {R} buffer rows)")
+    if "kernel" in outs:
+        # what a step pays an op beside the kernel: the runs' starts, from
+        # the slots and the bounds (four calls share one in the jit above)
+        block = pallas_moe_rows.block_tokens_of(T)
+        starts = jax.jit(lambda s, b: tuple(pallas_moe_rows.run_starts(
+            s + i, b, block) for i in range(4)))
+        result["run_starts_ms"] = ms = _ms(starts, slot, bounds, calls=2,
+                                           repeats=7) / 4
+        say(f"the runs' starts alone ({T // block} blocks x {held} groups): "
+            f"{ms:.3f} ms an op")
+        result["max_difference"] = float(jnp.max(jnp.abs(
+            outs["kernel"] - outs["composed"])))
+        say(f"largest difference kernel - composed: "
+            f"{result['max_difference']:.3g} (entries up to "
+            f"{float(jnp.max(jnp.abs(outs['composed']))):.3g})")
+    return result
 
 
 def main(argv=None, modes=None, doc=__doc__, options=None) -> int:
@@ -380,6 +490,7 @@ def main(argv=None, modes=None, doc=__doc__, options=None) -> int:
     (``tools/qwen3_next_probe.py``)."""
     modes = modes or {"load": held_shares, "controls": controls,
                       "grads": gradients, "kernels": kernels}
+    modes.setdefault("sums", token_sums)
     ap = argparse.ArgumentParser(description=doc.splitlines()[0])
     ap.add_argument("mode", choices=tuple(modes))
     ap.add_argument("--seed", type=int, default=2147480039)
@@ -392,6 +503,14 @@ def main(argv=None, modes=None, doc=__doc__, options=None) -> int:
                     help="another moe_row_budget; 0 for none")
     ap.add_argument("--blocks", nargs="*", default=["512x512"],
                     help="kernels: block_q x block_k pairs, e.g. 256x512")
+    ap.add_argument("--cell", default=CELL,
+                    help="sums: any cell with expert layers")
+    ap.add_argument("--tilt", type=float, default=0.0,
+                    help="sums: the share of the tokens one held expert "
+                         "draws besides (0: an even router)")
+    ap.add_argument("--sum-plans", nargs="*", default=[],
+                    help="sums: also time the kernel at these tokens a "
+                         "block x rows a pass, e.g. 128x1024 256x2048")
     ap.add_argument("--rehearsal", action="store_true")
     ap.add_argument("--out", default=None,
                     help="append the result as one JSON line to this file")

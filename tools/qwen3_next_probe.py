@@ -24,7 +24,10 @@ leaf); this file adds:
               the cell's shapes, forward and backward, by chunk length:
               milliseconds a layer and the composed form's temporaries; and
               the flash kernels at the cell's attention shapes (16 query
-              over 2 key/value heads of 256, causal) over ``--blocks``.
+              over 2 key/value heads of 256, causal) over ``--blocks``; and
+              the expert layers' token sums (``tools/laguna_probe.py``'s
+              ``sums``, a mode here too: the kernel against the scatter-add
+              at 8192 x top-10 of 2048 under the budget of 20480 rows).
 ``norm``      the gated norm at a DeltaNet mixer's end (``rms_norm`` given
               the gate, ``ops/pallas_norm.py``) at the cell's shapes:
               milliseconds a layer and GB/s of its kernels, forward and
@@ -226,6 +229,7 @@ def kernels(args) -> dict:
     result["flash"] = flash_by_blocks(
         B, S, model["num_attention_heads"], model["num_key_value_heads"],
         model["head_dim"], args.blocks, interpret, rng)
+    result["token_sums"] = laguna_probe.token_sums(args)
     return result
 
 
