@@ -1067,7 +1067,9 @@ def fused_attention(q, k, v, bias=None, scale=None, dropout_prob=0.0,
                     window=None):
     """Fused scaled-dot-product attention over head-split tensors.
 
-    q: [B, heads, S, D], k/v: [B, kv_heads, S, D] with kv_heads dividing heads
+    q: [B, heads, S, D], k: [B, kv_heads, S, D], v: [B, kv_heads, S, Dv] (Dv
+    = D but for latent attention whose values are narrower than its keys;
+    the result is [B, heads, S, Dv]) with kv_heads dividing heads
     (grouped-query attention; heads x D need not be the model's hidden size);
     bias: optional [B, 1, 1, S] additive mask. ``window`` (with ``causal``):
     a sliding window, query i sees the keys i - window < j <= i (HF's
@@ -1103,7 +1105,8 @@ def fused_attention(q, k, v, bias=None, scale=None, dropout_prob=0.0,
 # -- decoder-LM vocabulary (ops/decoder_ops.py) ------------------------------------------
 
 def rms_norm(input, epsilon=1e-5, param_attr=None, name=None,
-             zero_centered=False, gate=None, impl="auto"):
+             zero_centered=False, gate=None, impl="auto",
+             gate_activation="silu"):
     """RMSNorm over the last axis with a learned scale (initialised to 1):
     ``x / sqrt(mean(x^2) + epsilon) * scale``, float32 inside the op. Under
     ``zero_centered`` the scale is ``1 + w`` with ``w`` initialised to 0
@@ -1111,9 +1114,10 @@ def rms_norm(input, epsilon=1e-5, param_attr=None, name=None,
     weight decay, which pulls ``w`` to 0 and so the scale to 1. With
     ``gate`` (``input``'s element count: its shape, or ``[T, heads * D]``
     beside ``[T, heads, D]``) the result times ``silu(gate)`` inside the
-    same op, one pass over both (a Gated DeltaNet mixer's output norm);
-    ``impl`` is that form's lowering, ``auto`` / ``pallas`` / ``composed``
-    (``ops/pallas_norm.py``)."""
+    same op, one pass over both (a Gated DeltaNet mixer's output norm), or
+    under ``gate_activation="sigmoid"`` times ``sigmoid(gate)`` (a Kimi
+    Delta Attention mixer's); ``impl`` is that form's lowering, ``auto`` /
+    ``pallas`` / ``composed`` (``ops/pallas_norm.py``)."""
     from ..initializer import Constant
     helper = LayerHelper("rms_norm", name=name)
     scale = helper.create_parameter(
@@ -1128,6 +1132,11 @@ def rms_norm(input, epsilon=1e-5, param_attr=None, name=None,
         inputs["Gate"] = [gate]
         if impl != "auto":
             attrs["impl"] = impl
+        if gate_activation != "silu":
+            if gate_activation not in ("silu", "sigmoid"):
+                raise ValueError(f"rms_norm: gate_activation="
+                                 f"{gate_activation!r} (silu or sigmoid)")
+            attrs["gate_activation"] = gate_activation
     helper.append_op("rms_norm", inputs=inputs, outputs={"Y": [y]},
                      attrs=attrs)
     return _var(helper, y)
@@ -1172,7 +1181,8 @@ def rotary_embedding(x, theta=10000.0, name=None, rotary_dim=None,
 
 
 def latent_qkv(q, kv, k_rope, batch, seq, heads, nope_dim, rope_dim,
-               theta=10000.0, name=None):
+               theta=10000.0, name=None, rotate=True, value_dim=None,
+               head_dim=None):
     """Latent attention's q, k and v ``[batch, heads, seq, nope_dim +
     rope_dim]`` for ``fused_attention``, in one op, from its three
     up-projections over ``batch x seq`` tokens: ``q [T, heads x (nope_dim +
@@ -1183,15 +1193,25 @@ def latent_qkv(q, kv, k_rope, batch, seq, heads, nope_dim, rope_dim,
     (rotate-half, base ``theta``, positions 0..seq-1) and follow their
     head's other part; the op's registered grad puts the cotangents' parts
     back and sums the key head's over the heads
-    (``ops/decoder_ops.py:latent_qkv``)."""
+    (``ops/decoder_ops.py:latent_qkv``). ``value_dim``: v's head width where
+    it is not a q head's (v is then ``[batch, heads, seq, value_dim]``);
+    ``rotate=False``: the rotary parts stay as projected (no positions);
+    ``head_dim``: the q / k head written that wide, zero columns behind its
+    two parts."""
     helper = LayerHelper("latent_qkv", name=name)
     outs = [_out(helper, x.dtype) for x in (q, kv, kv)]
+    attrs = {"batch": int(batch), "seq": int(seq), "heads": int(heads),
+             "nope_dim": int(nope_dim), "rope_dim": int(rope_dim),
+             "theta": float(theta)}
+    if not rotate:
+        attrs["rotate"] = False
+    for key, width in (("value_dim", value_dim), ("head_dim", head_dim)):
+        if width and int(width) != int(nope_dim) + int(rope_dim):
+            attrs[key] = int(width)
     helper.append_op(
         "latent_qkv", inputs={"Q": [q], "KV": [kv], "KRope": [k_rope]},
         outputs={"OutQ": [outs[0]], "OutK": [outs[1]], "OutV": [outs[2]]},
-        attrs={"batch": int(batch), "seq": int(seq), "heads": int(heads),
-               "nope_dim": int(nope_dim), "rope_dim": int(rope_dim),
-               "theta": float(theta)})
+        attrs=attrs)
     return tuple(_var(helper, o) for o in outs)
 
 

@@ -40,14 +40,21 @@ pre-norm, without biases or dropout: ``h = x + r operator(norm(x))``,
   ``rope_parameters``, ``partial_rotary_factor`` is read beside
   ``rope_theta``. A config with ``kv_lora_rank`` builds its
   ``full_attention`` layers as multi-head latent attention
-  (``latent_attention``, below; DeepSeek-V2's, GLM-4.7-Flash's).
+  (``latent_attention``, below; DeepSeek-V2's, GLM-4.7-Flash's, Kimi
+  Linear's: there beside linear layers, under ``q_lora_rank: null`` with q
+  from one projection, under ``mla_use_nope`` without rotation, with a
+  ``v_head_dim`` of its own).
   ``sliding_attention``: the same
   with a window of ``sliding_window`` keys (query i sees i - window < j <=
   i). ``conv``: the gated short convolution ``W_out (C * conv(B
   * u))`` with ``B, C, u = split(W_in x, 3)`` and a causal depthwise filter
   of ``conv_L_cache`` taps (``layers.short_conv``). ``mamba``: the Mamba-2
   mixer (``mamba``, below). ``linear_attention``: the Gated DeltaNet mixer
-  (``delta_net``, below).
+  (``delta_net``, below). ``kda``: the Kimi Delta Attention mixer
+  (``kimi_delta``, below). A config with ``linear_attn_config`` names its
+  layers there: ``kda_layers`` and ``full_attn_layers``, two lists of layer
+  numbers counted from 1, with the mixer's ``num_heads``, ``head_dim`` and
+  ``short_conv_kernel_size`` beside them.
 - ``norm_form`` ``"plain"`` (default): every RMSNorm scales by ``w`` from 1;
   ``"zero_centered"``: the layers' two norms, the final norm and the q / k
   norms scale by ``1 + w`` with ``w`` from 0 (``layers.rms_norm``).
@@ -68,9 +75,15 @@ pre-norm, without biases or dropout: ``h = x + r operator(norm(x))``,
   by the score over the chosen scores' sum under ``norm_topk_prob``, times
   ``routed_scaling_factor``. ``topk_method: "noaux_tc"`` (DeepSeek-V3's
   spelling) is sigmoid scoring chosen by score + bias; ``n_group`` /
-  ``topk_group`` must be 1 (no group limit).
-  ``shared_expert_intermediate_size`` (or ``n_shared_experts: 1``, whose
-  width is ``moe_intermediate_size``): one
+  ``topk_group`` must be 1 (no group limit). Kimi's spellings read alike:
+  ``num_experts_per_token`` (top-k), ``moe_renormalize``
+  (``norm_topk_prob``), ``moe_router_activation_func`` (``"sigmoid"``:
+  sigmoid scores chosen by score + bias), ``num_expert_group`` (with
+  ``topk_group`` 1: ``use_grouped_topk`` over one group is no limit),
+  ``moe_layer_freq`` (must be 1).
+  ``shared_expert_intermediate_size`` (or ``n_shared_experts: 1``, also
+  spelt ``num_shared_experts``, whose width is ``moe_intermediate_size``):
+  one
   shared expert, a dense SwiGLU of that width over every token, added
   beside the routed experts' sum: ungated, or under ``shared_expert_gate``
   times ``sigmoid(w_s . x)``, one gate a token.
@@ -105,15 +118,19 @@ Models through it: OLMoE-1B-7B (Muennighoff et al., arXiv:2409.02060; HF
 granite-4.0-h-micro (HF ``modeling_granitemoehybrid.py``; the scan: Dao &
 Gu, arXiv:2405.21060), Laguna-S-2.1 (its ``config.json``; YaRN: Peng et
 al., arXiv:2309.00071), Qwen3-Next-80B-A3B (HF ``modeling_qwen3_next.py``;
-the delta rule: Yang et al., arXiv:2412.06464) and GLM-4.7-Flash (its
+the delta rule: Yang et al., arXiv:2412.06464), GLM-4.7-Flash (its
 ``config.json``, ``model_type: glm4_moe_lite``; latent attention:
 DeepSeek-V2, arXiv:2405.04434; routing and the prediction module:
-DeepSeek-V3, arXiv:2412.19437).
+DeepSeek-V3, arXiv:2412.19437) and Kimi-Linear-48B-A3B-Instruct (its
+``config.json``, ``model_type: kimi_linear``; HF ``modeling_kimi.py`` of
+that repository; Kimi Delta Attention: the Kimi Linear report,
+arXiv:2510.26692).
 
 Dtypes follow ``models/bert.py``: the embedding table is float32 whatever
 ``dtype`` says, activations are cast to ``dtype`` right after the lookup,
-weights are created in ``dtype`` (a Mamba or DeltaNet mixer's ``A_log``,
-``D`` and ``dt_bias``, one number a head, in float32); RMSNorm (latent
+weights are created in ``dtype`` (a Mamba, DeltaNet or KDA mixer's ``A_log``,
+``D`` and ``dt_bias``, one number a head -- a KDA mixer's ``dt_bias`` one a
+key channel --, in float32); RMSNorm (latent
 attention's two among them), the router,
 the short convolution, the scan's and the delta rule's decays and state, the
 delta rule's l2 norms and every softmax compute in float32 inside their ops;
@@ -134,9 +151,9 @@ _REQUIRED = {"hidden_act": "silu", "attention_bias": False,
              "normalization_function": "rmsnorm",
              "moe_apply_router_weight_on_input": False,
              "moe_router_logit_softcapping": 0, "decoder_sparse_step": 1,
-             "use_sliding_window": False}
+             "use_sliding_window": False, "moe_layer_freq": 1}
 _OPERATORS = ("full_attention", "sliding_attention", "conv", "mamba",
-              "linear_attention")
+              "linear_attention", "kda")
 _ATTENTION = ("full_attention", "sliding_attention")
 
 
@@ -160,6 +177,13 @@ def _check(cfg: dict) -> None:
     if "sliding_attention" in kinds and not cfg.get("sliding_window"):
         raise ValueError("decoder_lm: sliding_attention layers need "
                          "sliding_window")
+    if "kda" in kinds:
+        missing = [k for k in ("num_heads", "head_dim",
+                               "short_conv_kernel_size")
+                   if k not in cfg.get("linear_attn_config", {})]
+        if missing:
+            raise ValueError(f"decoder_lm: kda layers need "
+                             f"linear_attn_config{missing}")
     if "linear_attention" in kinds:
         if cfg["linear_num_value_heads"] % cfg["linear_num_key_heads"]:
             raise ValueError("linear_num_value_heads must be a multiple of "
@@ -217,16 +241,14 @@ def _check(cfg: dict) -> None:
             f"decoder_lm: position_embedding_type="
             f"{cfg['position_embedding_type']!r} is not built yet (rotary "
             f"or none)")
-    if (max(cfg.get("n_shared_experts") or 0,
-            cfg.get("num_shared_experts") or 0) > 1
+    if (_shared_count(cfg) > 1
             or cfg.get("num_local_experts")):
         raise NotImplementedError(
             "decoder_lm: of shared experts only one a layer is built "
             "(shared_expert_intermediate_size): not n_shared_experts / "
             "num_shared_experts above 1, nor routed experts beside a shared "
             "feed-forward under num_local_experts")
-    if (cfg.get("n_shared_experts") or cfg.get("num_shared_experts")) \
-            and not _shared_width(cfg):
+    if _shared_count(cfg) and not _shared_width(cfg):
         raise ValueError("decoder_lm: a shared expert needs "
                          "shared_expert_intermediate_size, or "
                          "moe_intermediate_size beside n_shared_experts")
@@ -234,10 +256,19 @@ def _check(cfg: dict) -> None:
         raise NotImplementedError(
             f"decoder_lm: topk_method={cfg['topk_method']!r} is not built "
             f"yet (only 'noaux_tc': sigmoid scores chosen by score + bias)")
-    if max(cfg.get("n_group") or 1, cfg.get("topk_group") or 1) > 1:
+    if cfg.get("use_grouped_topk", True) and max(
+            cfg.get("n_group") or 1, cfg.get("num_expert_group") or 1,
+            cfg.get("topk_group") or 1) > 1:
         raise NotImplementedError(
-            "decoder_lm: group-limited routing (n_group / topk_group above "
-            "1) is not built yet: the experts are chosen among all of them")
+            "decoder_lm: group-limited routing (n_group / num_expert_group "
+            "/ topk_group above 1) is not built yet: the experts are chosen "
+            "among all of them (use_grouped_topk over one group is that)")
+    if cfg.get("moe_router_activation_func", "sigmoid") not in (
+            "sigmoid", "softmax"):
+        raise NotImplementedError(
+            f"decoder_lm: moe_router_activation_func="
+            f"{cfg['moe_router_activation_func']!r} is not built yet "
+            f"(sigmoid or softmax)")
     if (cfg.get("num_nextn_predict_layers") or 0) > 1:
         raise NotImplementedError(
             "decoder_lm: more than one multi-token-prediction module "
@@ -261,27 +292,19 @@ def _check(cfg: dict) -> None:
 def _check_latent(cfg: dict) -> None:
     """What a config with ``kv_lora_rank`` (latent attention) asks for and
     ``latent_attention`` does not build."""
-    if cfg.get("q_lora_rank") is None:
-        raise NotImplementedError(
-            "decoder_lm: latent attention with q_lora_rank: null (a full "
-            "query projection beside a latent key/value) is not built yet")
     if cfg.get("rope_scaling") is not None:
         raise NotImplementedError(
             "decoder_lm: rope_scaling inside latent attention (YaRN with "
             "its mscale on the softmax scale) is not built yet (only null)")
-    if cfg["qk_nope_head_dim"] + cfg["qk_rope_head_dim"] != cfg["v_head_dim"]:
-        raise NotImplementedError(
-            "decoder_lm: a v_head_dim other than qk_nope_head_dim + "
-            "qk_rope_head_dim is not built yet (fused_attention has one "
-            "head size for q, k and v)")
     if cfg.get("partial_rotary_factor", 1) != 1:
         raise NotImplementedError(
             "decoder_lm: partial_rotary_factor other than 1 inside latent "
             "attention is not built yet (the rotary head is rotated whole)")
-    if any(k != "full_attention" for k in _layer_types(cfg)):
+    if "sliding_attention" in _layer_types(cfg):
         raise NotImplementedError(
             "decoder_lm: latent attention (kv_lora_rank) is built for "
-            "full_attention layers only")
+            "full_attention layers only (beside conv, mamba and "
+            "linear-attention layers): no window inside it")
 
 
 def _held(cfg: dict):
@@ -291,22 +314,56 @@ def _held(cfg: dict):
 
 
 def _scoring(cfg: dict) -> str:
-    """``router_scoring``; a config with ``topk_method: "noaux_tc"`` scores
-    by sigmoid and chooses by score + bias."""
-    return cfg.get("router_scoring",
-                   "sigmoid" if "topk_method" in cfg else "softmax")
+    """``router_scoring`` (also spelt ``moe_router_activation_func``); a
+    config with ``topk_method: "noaux_tc"`` scores by sigmoid and chooses by
+    score + bias."""
+    return cfg.get("router_scoring", cfg.get(
+        "moe_router_activation_func",
+        "sigmoid" if "topk_method" in cfg else "softmax"))
+
+
+def _bias_chosen(cfg: dict) -> bool:
+    """Whether the experts are chosen by score + a selection bias:
+    ``use_expert_bias``; by default under ``topk_method`` (DeepSeek-V3's)
+    and under ``moe_router_activation_func: "sigmoid"`` (Kimi's
+    ``e_score_correction_bias``)."""
+    return bool(cfg.get("use_expert_bias", "topk_method" in cfg or cfg.get(
+        "moe_router_activation_func") == "sigmoid"))
+
+
+def _shared_count(cfg: dict) -> int:
+    """``n_shared_experts``, in Kimi's configs ``num_shared_experts``."""
+    return max(cfg.get("n_shared_experts") or 0,
+               cfg.get("num_shared_experts") or 0)
 
 
 def _shared_width(cfg: dict):
     """The one shared expert's width: ``shared_expert_intermediate_size``,
-    or ``moe_intermediate_size`` x ``n_shared_experts``; None for none."""
+    or ``moe_intermediate_size`` x ``n_shared_experts`` (also spelt
+    ``num_shared_experts``); None for none."""
     return cfg.get("shared_expert_intermediate_size") or (
-        cfg.get("moe_intermediate_size", 0)
-        * (cfg.get("n_shared_experts") or 0)) or None
+        cfg.get("moe_intermediate_size", 0) * _shared_count(cfg)) or None
+
+
+def _top_k(cfg: dict) -> int:
+    """``num_experts_per_tok``, in Kimi's configs ``num_experts_per_token``."""
+    return cfg["num_experts_per_tok"] if "num_experts_per_tok" in cfg \
+        else cfg["num_experts_per_token"]
 
 
 def _layer_types(cfg: dict) -> list:
     kinds = cfg.get("layer_types")
+    linear = cfg.get("linear_attn_config")
+    if not kinds and linear and "kda_layers" in linear:
+        # Kimi Linear's: two lists of layer numbers counted from 1
+        kda, full = linear["kda_layers"], linear.get("full_attn_layers", [])
+        if sorted(kda + full) != list(range(1, cfg["num_hidden_layers"] + 1)):
+            raise ValueError(
+                "decoder_lm: linear_attn_config's kda_layers and "
+                "full_attn_layers must name every layer from 1 to "
+                "num_hidden_layers once")
+        return ["kda" if i + 1 in kda else "full_attention"
+                for i in range(cfg["num_hidden_layers"])]
     if not kinds and cfg.get("full_attention_interval"):
         every = cfg["full_attention_interval"]      # HF qwen3_next's rule
         kinds = ["linear_attention" if (i + 1) % every else "full_attention"
@@ -432,36 +489,50 @@ def attention(x, cfg: dict, batch: int, seq: int, name: str, layer: int = 0,
 
 def latent_attention(x, cfg: dict, batch: int, seq: int, name: str):
     """Causal multi-head latent attention over tokens ``x [batch * seq, H]``
-    (DeepSeek-V2, arXiv:2405.04434, section 2.1; HF ``DeepseekV3Attention``)
-    with ``h = num_attention_heads`` heads of ``qk_nope_head_dim`` +
-    ``qk_rope_head_dim`` = ``v_head_dim``: ``c_q = norm(W_qa x)``
-    (``q_lora_rank``), a head's ``[q_n | q_r]`` from ``W_qb c_q``; ``[c_kv
-    | k_r] = W_kva x`` (``kv_lora_rank`` + ``qk_rope_head_dim``), a head's
-    ``[k_n | v]`` from ``W_kvb norm(c_kv)``; ``q_r`` and the one key head
-    ``k_r`` rotated at ``rope_theta`` and ``k_r`` shared by the heads
-    (``layers.latent_qkv``: the columns of ``W_qb`` are every head's q_n,
-    then every head's q_r, those of ``W_kvb`` every head's k_n, then every
-    head's v, a permutation of HF's interleave by head); causal
-    ``fused_attention`` at 1 / sqrt(the q / k head's width); ``W_o`` over
-    the heads' outputs. Both latent norms are the config's RMSNorm."""
+    (DeepSeek-V2, arXiv:2405.04434, section 2.1; HF ``DeepseekV3Attention``,
+    ``KimiMLAAttention``) with ``h = num_attention_heads`` heads of
+    ``qk_nope_head_dim`` + ``qk_rope_head_dim`` for q and k and
+    ``v_head_dim`` for v: ``c_q = norm(W_qa x)`` (``q_lora_rank``), a head's
+    ``[q_n | q_r]`` from ``W_qb c_q`` -- under ``q_lora_rank: null`` from
+    one projection ``W_q x`` --; ``[c_kv | k_r] = W_kva x`` (``kv_lora_rank``
+    + ``qk_rope_head_dim``), a head's ``[k_n | v]`` from ``W_kvb
+    norm(c_kv)``; ``q_r`` and the one key head ``k_r`` rotated at
+    ``rope_theta`` -- not under ``mla_use_nope`` (Kimi Linear: the linear
+    layers carry the positions; ``rope_theta`` is then not read) -- and
+    ``k_r`` shared by the heads (``layers.latent_qkv``: the columns of
+    ``W_qb`` are every head's q_n, then every head's q_r, those of ``W_kvb``
+    every head's k_n, then every head's v, a permutation of HF's interleave
+    by head); causal ``fused_attention`` at 1 / sqrt(the q / k head's
+    width), whose output has v's width; ``W_o`` over the heads' outputs. A q
+    / k head wider than one lane tile of 128 and no whole number of them is
+    written up to the next whole tile, zero columns behind its two parts
+    (192 -> 256: a zero column adds nothing to a score, the MXU's passes
+    over 192 are those over 256, and the flash kernels read whole tiles: 13%
+    faster on the chip, PR 51). Both latent norms are the config's RMSNorm."""
     heads = cfg["num_attention_heads"]
     d_n, d_r = cfg["qk_nope_head_dim"], cfg["qk_rope_head_dim"]
-    r_kv = cfg["kv_lora_rank"]
-    c_q = _norm(_linear(x, cfg["q_lora_rank"], name + "_q_a_w"), cfg,
-                name + "_q_a_norm_w")
-    q = _linear(c_q, heads * (d_n + d_r), name + "_q_b_w")
+    r_kv, d_v = cfg["kv_lora_rank"], cfg["v_head_dim"]
+    if cfg.get("q_lora_rank") is None:
+        q = _linear(x, heads * (d_n + d_r), name + "_q_w")
+    else:
+        c_q = _norm(_linear(x, cfg["q_lora_rank"], name + "_q_a_w"), cfg,
+                    name + "_q_a_norm_w")
+        q = _linear(c_q, heads * (d_n + d_r), name + "_q_b_w")
     c_kv, k_r = layers.split(_linear(x, r_kv + d_r, name + "_kv_a_w"),
                              [r_kv, d_r], dim=-1)
     kv = _linear(_norm(c_kv, cfg, name + "_kv_a_norm_w"),
-                 heads * (d_n + cfg["v_head_dim"]), name + "_kv_b_w")
+                 heads * (d_n + d_v), name + "_kv_b_w")
+    d = d_n + d_r
     q, k, v = layers.latent_qkv(q, kv, k_r, batch, seq, heads, d_n, d_r,
-                                theta=cfg.get("rope_theta", 10000.0))
+                                theta=cfg.get("rope_theta", 10000.0),
+                                rotate=not cfg.get("mla_use_nope", False),
+                                value_dim=d_v,
+                                head_dim=-(-d // 128) * 128 if d > 128 else d)
     ctx = layers.fused_attention(
         q, k, v, causal=True, impl="auto",
-        scale=float(cfg.get("attention_multiplier",
-                            1.0 / math.sqrt(d_n + d_r))))
+        scale=float(cfg.get("attention_multiplier", 1.0 / math.sqrt(d))))
     ctx = layers.reshape(layers.transpose(ctx, [0, 2, 1, 3]),
-                         [batch * seq, heads * (d_n + d_r)])
+                         [batch * seq, heads * d_v])
     return _linear(ctx, cfg["hidden_size"], name + "_o_w")
 
 
@@ -503,11 +574,12 @@ def _per_head(name: str, heads: int, initializer):
                                    default_initializer=initializer)
 
 
-def _dt_bias_and_a_log(name: str, heads: int):
-    """A recurrent mixer's float32 per-head ``<name>_dt_bias`` and
+def _dt_bias_and_a_log(name: str, heads: int, dts: int = None):
+    """A recurrent mixer's float32 per-head ``<name>_dt_bias`` (``dts`` of
+    them where given: Kimi Delta Attention's, one a key channel) and
     ``<name>_A_log`` as mamba_ssm's ``Mamba2`` starts them: dt log-uniform
     in [1e-3, 1e-1] through the inverse softplus, A uniform in [1, 16]."""
-    dt_bias = _per_head(name + "_dt_bias", heads, _Drawn(
+    dt_bias = _per_head(name + "_dt_bias", dts or heads, _Drawn(
         math.log(1e-3), math.log(1e-1),
         [("exp", {}), ("exp", {}), ("scale", {"scale": 1.0, "bias": -1.0}),
          ("log", {})]))                 # log(exp(dt) - 1), dt = exp(draw)
@@ -612,23 +684,71 @@ def delta_net(x, cfg: dict, batch: int, seq: int, name: str):
     return _linear(y, cfg["hidden_size"], name + "_out_w")
 
 
+def kimi_delta(x, cfg: dict, batch: int, seq: int, name: str):
+    """The Kimi Delta Attention mixer over ``x [batch * seq, H]`` (HF
+    ``KimiDeltaAttention``; Kimi Linear, arXiv:2510.26692), from
+    ``linear_attn_config``: ``n = num_heads`` heads of ``d = head_dim`` for
+    q, k and v alike. ``[q | k | v] = silu(conv(x [W_q | W_k | W_v]))``,
+    one causal depthwise filter of ``short_conv_kernel_size`` taps without a
+    bias over the ``3 n d`` channels (HF's three convs side by side); a
+    token, head and key channel, ``g = -exp(A_log[head]) softplus((x W_fa
+    W_fb) + dt_bias)`` through a low-rank pair of width ``d`` (float32; HF
+    ``fused_kda_gate``); a token and head, ``beta = sigmoid(x W_b)``; the
+    gated delta rule with that decay a key channel over unit q and k
+    (``layers.gated_delta_rule_packed`` given ``g [batch, seq, n, d]``: the
+    state's row c decays by ``exp(g[c])``; in chunks of
+    ``delta_chunk_size``, default 64, lowered as ``delta_rule_impl`` says);
+    ``W_o (rmsnorm(o) * sigmoid(x W_ga W_gb))``, the norm over a head's
+    values with one plain scale of head size shared by the heads, then the
+    sigmoid gate through a second low-rank pair, both in one ``rms_norm``
+    op. ``A_log`` (a head) and ``dt_bias`` (a channel) are float32 and start
+    as ``mamba``'s do, the filter as a depthwise Conv1d's."""
+    lin = cfg["linear_attn_config"]
+    n, d, taps = lin["num_heads"], lin["head_dim"], lin[
+        "short_conv_kernel_size"]
+    wide = n * d
+    qkv = layers.short_conv(_linear(x, 3 * wide, name + "_qkv_w"), seq, taps,
+                            _conv_attr(name + "_conv_w", taps), gated=False,
+                            activation="silu")
+    dt_bias, a_log = _dt_bias_and_a_log(name, n, wide)
+    decay = layers.softplus(layers.elementwise_add(layers.cast(
+        _linear(_linear(x, d, name + "_f_a_w"), wide, name + "_f_b_w"),
+        "float32"), dt_bias))
+    g = layers.elementwise_mul(
+        layers.reshape(decay, [batch, seq, n, d]),
+        layers.reshape(layers.scale(layers.exp(a_log), -1.0), [1, 1, n, 1]))
+    beta = layers.sigmoid(layers.cast(_linear(x, n, name + "_b_w"),
+                                      "float32"))
+    o = layers.gated_delta_rule_packed(
+        layers.reshape(qkv, [batch, seq, 3 * wide]), g,
+        layers.reshape(beta, [batch, seq, n]), n, d,
+        chunk=cfg.get("delta_chunk_size", 64),
+        impl=cfg.get("delta_rule_impl", "auto"))
+    z = _linear(_linear(x, d, name + "_g_a_w"), wide, name + "_g_b_w")
+    y = layers.rms_norm(layers.reshape(o, [batch * seq, n, d]), _eps(cfg),
+                        ParamAttr(name=name + "_gated_norm_w"), gate=z,
+                        gate_activation="sigmoid")
+    return _linear(y, cfg["hidden_size"], name + "_o_w")
+
+
 def experts(x, cfg: dict, name: str):
     """The layer's routed experts, and its shared expert where the config
     has one (``layers.moe_ffn``), from the config."""
     held = _held(cfg)
     routed = cfg.get("num_experts_routed", held)
     return layers.moe_ffn(
-        x, routed, cfg["num_experts_per_tok"],
+        x, routed, _top_k(cfg),
         cfg.get("moe_intermediate_size", cfg["intermediate_size"]),
         param_attr=_attr(None), name=name,
         experts_held=(None if held == routed
                       else (cfg.get("first_expert_held", 0), held)),
         scoring=_scoring(cfg),
-        norm_topk=bool(cfg.get("norm_topk_prob", False)),
+        norm_topk=bool(cfg.get("norm_topk_prob",
+                               cfg.get("moe_renormalize", False))),
         routed_scale=float(cfg.get(
             "routed_scaling_factor",
             cfg.get("moe_routed_scaling_factor", 1.0))),
-        expert_bias=bool(cfg.get("use_expert_bias", "topk_method" in cfg)),
+        expert_bias=_bias_chosen(cfg),
         row_budget=cfg.get("moe_row_budget"),
         shared_width=_shared_width(cfg),
         shared_gate=bool(cfg.get("shared_expert_gate", False)))
@@ -645,7 +765,7 @@ def block(x, cfg: dict, batch: int, seq: int, name: str,
     def add(h, branch):
         return layers.elementwise_add(
             h, branch if r == 1.0 else layers.scale(branch, r))
-    op_name = name + {"conv": "_conv", "mamba": "_mamba",
+    op_name = name + {"conv": "_conv", "mamba": "_mamba", "kda": "_kda",
                       "linear_attention": "_delta"}.get(kind, "_attn")
     normed = _norm(x, cfg, op_name + "_norm_w")
     if kind == "conv":
@@ -654,6 +774,8 @@ def block(x, cfg: dict, batch: int, seq: int, name: str,
         mixed = mamba(normed, cfg, batch, seq, op_name)
     elif kind == "linear_attention":
         mixed = delta_net(normed, cfg, batch, seq, op_name)
+    elif kind == "kda":
+        mixed = kimi_delta(normed, cfg, batch, seq, op_name)
     elif cfg.get("kv_lora_rank"):
         mixed = latent_attention(normed, cfg, batch, seq, op_name)
     else:
@@ -766,7 +888,7 @@ def build(cfg: dict, ids, labels, labels_next=None) -> dict:
         if router_losses:
             share = layers.scale(
                 layers.cast(aux["load"], "float32"),
-                1.0 / (batch * seq * cfg["num_experts_per_tok"]))
+                1.0 / (batch * seq * _top_k(cfg)))
             balance.append(layers.scale(layers.reduce_sum(
                 layers.elementwise_mul(
                     share, layers.reduce_mean(aux["prob"], dim=0))),

@@ -32,9 +32,11 @@ FAMILIES = {
     # tile a row); kv_heads fewer than heads under grouped-query attention;
     # window: the sliding window the lowering applies (0: none, also for one
     # no shorter than s)
+    # value_dim: the value head's width where it is not head_dim (latent
+    # attention with v narrower than q / k), else 0
     "attention_lowering_total": (
         COUNT, ("impl", "s", "block_q", "block_k", "kv_heads", "window",
-                "heads", "head_dim"),
+                "heads", "head_dim", "value_dim"),
         "fused_attention ops compiled, by the lowering each took"),
     # amount: the K tiles the forward kernel passes over for one (batch,
     # head) (state=visited) and those a causal op leaves out because they lie
@@ -90,19 +92,22 @@ FAMILIES = {
         COUNT, ("impl", "form", "activation", "taps"),
         "short_conv ops compiled, by form and the lowering each took"),
     # operands: packed (the kernels read q, k and v in place in the one array
-    # the op was given) / split (three operands, given or cut out of it)
+    # the op was given) / split (three operands, given or cut out of it);
+    # decay: head (one scalar a token and value head: Gated DeltaNet) /
+    # channel (a vector over the key channels: Kimi Delta Attention); a
+    # series without the label is a parent's, and is read as head
     "delta_lowering_total": (
         COUNT, ("impl", "chunk", "heads", "key_dim", "value_dim",
-                "operands"),
+                "operands", "decay"),
         "gated_delta_rule ops compiled, by the lowering and the "
         "operand form each took"),
     # ops/decoder_ops.py:rms_norm given a Gate, and its grad op (an rms_norm
     # without one reports nothing). impl: pallas (the one-pass kernels of
     # ops/pallas_norm.py; the backward reads X, Gate and the cotangent and
     # lowers no forward) / composed (the same closed forms in jax.numpy);
-    # head_dim: the normed axis
+    # head_dim: the normed axis; activation: the gate's, silu / sigmoid
     "rms_norm_gated_lowering_total": (
-        COUNT, ("impl", "direction", "head_dim"),
+        COUNT, ("impl", "direction", "head_dim", "activation"),
         "gated rms_norm ops and grad ops compiled, by the lowering each "
         "took"),
     # ops/decoder_ops.py: an expert layer's token sums, moe_combine's
@@ -116,6 +121,13 @@ FAMILIES = {
         COUNT, ("impl", "op", "bound"),
         "token sums of the expert layers compiled, by the lowering each "
         "took"),
+    # ops/decoder_ops.py:latent_qkv, the forward op. rotated: 1 where q_r and
+    # the one key head k_r are rotated, 0 under rotate=False (positions
+    # left to the linear layers); head_dim: the q / k head's width as
+    # written (zero columns behind nope + rope included); value_dim: v's
+    "latent_qkv_lowering_total": (
+        COUNT, ("rotated", "heads", "head_dim", "value_dim"),
+        "latent_qkv ops compiled, by rotation and head widths"),
     # amount: a moe_dispatch op's row budget (attr rows), its assignments
     # without one; the sort's output, the grouped products, swiglu and the
     # combine are sized by it
@@ -123,6 +135,15 @@ FAMILIES = {
         GAUGE, (),
         "sorted rows the expert layers keep a step, all layers: the "
         "assignments without a row budget, the budgets with one"),
+}
+
+
+#: labels a family gained after its first readers were written, with what a
+#: report without them means: such a report is kept under the default
+LATER_LABELS = {
+    "delta_lowering_total": {"decay": "head"},
+    "rms_norm_gated_lowering_total": {"activation": "silu"},
+    "attention_lowering_total": {"value_dim": 0},
 }
 
 
@@ -135,6 +156,7 @@ def note(notes: dict, salt: int, family: str, amount, labels: dict) -> None:
             f"lowering metric {family!r} is not declared in "
             f"observability/lowerings.py:FAMILIES ({', '.join(FAMILIES)})")
     names = FAMILIES[family][1]
+    labels = {**LATER_LABELS.get(family, {}), **labels}
     if set(labels) != set(names):
         raise KeyError(f"lowering metric {family!r} takes the labels "
                        f"{names}, not {tuple(labels)}")

@@ -61,7 +61,8 @@ def rms_norm(ctx, ins):
     ``zero_centered``: ``* (1 + Scale)``), computed in float32 whatever x's
     dtype, returned in x's dtype. Given ``Gate`` (X's element count: X's
     shape, or ``[T, heads * D]`` beside ``X [T, heads, D]``) the result
-    times ``silu(Gate)``, with no rounding between the norm and the gate:
+    times ``silu(Gate)`` (attr ``gate_activation`` ``"sigmoid"``: times
+    ``sigmoid(Gate)``), with no rounding between the norm and the gate:
     ``_gated_norm``."""
     import jax
     import jax.numpy as jnp
@@ -93,6 +94,7 @@ def _gated_norm(ctx, x, gate, scale, dy=None):
         raise ValueError(f"rms_norm: Gate {gate.shape} has not X's "
                          f"{x.shape} element count")
     eps = float(ctx.attr("epsilon", 1e-5))
+    act = ctx.attr("gate_activation", "silu")
     factor = (jnp.ones((dim,), jnp.float32) if scale is None
               else scale.astype(jnp.float32))
     if ctx.attr("zero_centered", False):
@@ -106,21 +108,22 @@ def _gated_norm(ctx, x, gate, scale, dy=None):
     ctx.report("rms_norm_gated_lowering_total",
                impl="pallas" if kernels else "composed",
                direction="forward" if dy is None else "backward",
-               head_dim=dim)
+               head_dim=dim, activation=act)
     if dy is None:
         if kernels:
             return pallas_norm.gated_norm(x, gate, factor, eps,
-                                          pallas_mode.interpret())
+                                          pallas_mode.interpret(), act)
         return pallas_norm.forward(
             x.astype(jnp.float32), gate.reshape(x.shape).astype(jnp.float32),
-            factor, eps).astype(x.dtype)
+            factor, eps, act).astype(x.dtype)
     if kernels:
         dx, dz, dscale = pallas_norm._bwd_call(
-            x, gate, factor, dy.astype(x.dtype), eps, pallas_mode.interpret())
+            x, gate, factor, dy.astype(x.dtype), eps, pallas_mode.interpret(),
+            act)
     else:
         dx, dz, terms = pallas_norm.backward(
             x.astype(jnp.float32), gate.reshape(x.shape).astype(jnp.float32),
-            factor, dy.astype(jnp.float32), eps)
+            factor, dy.astype(jnp.float32), eps, act)
         dx, dz = dx.astype(x.dtype), dz.astype(gate.dtype).reshape(gate.shape)
         dscale = jnp.sum(terms, axis=tuple(range(x.ndim - 1)))
     return dx, dz, None if scale is None else dscale.astype(scale.dtype)
@@ -268,8 +271,13 @@ def rotary_embedding_grad(ctx, ins, generic):
 
 
 def _latent_sizes(ctx):
-    return tuple(int(ctx.attr(k)) for k in (
+    """(batch, seq, heads, nope, rope, v's head width, the q / k head's
+    width as written, whether the rotary parts are rotated)."""
+    B, S, h, d_n, d_r = (int(ctx.attr(k)) for k in (
         "batch", "seq", "heads", "nope_dim", "rope_dim"))
+    return (B, S, h, d_n, d_r, int(ctx.attr("value_dim", 0)) or d_n + d_r,
+            int(ctx.attr("head_dim", 0)) or d_n + d_r,
+            bool(ctx.attr("rotate", True)))
 
 
 def _rope_rows(ctx, x, backward):
@@ -293,35 +301,42 @@ def latent_qkv(ctx, ins):
     heads x (nope + v)]`` laid out ``[every head's k_n | every head's v]``,
     ``KRope [T, rope]`` the one rotary key head. ``OutQ`` a head ``[q_n |
     RoPE(q_r)]``, ``OutK`` a head ``[k_n | RoPE(k_r)]`` with the one rotated
-    key head in every head, ``OutV`` the values, each ``[batch, heads, seq,
-    nope + rope]`` (``v`` is as wide as q and k: ``fused_attention`` has one
-    head size). Rotate-half, positions 0..seq-1, float32 inside the
+    key head in every head, each ``[batch, heads, seq, nope + rope]``,
+    ``OutV`` the values ``[batch, heads, seq, v]``. Attr ``value_dim``
+    (default nope + rope): v's head width. Attr ``rotate`` (default true):
+    false leaves q_r and k_r as projected (Kimi Linear's ``mla_use_nope``:
+    positions are the linear layers' business). Attr ``head_dim`` (default
+    nope + rope): the q / k head's width as written, zero columns behind the
+    rope part (whole lane tiles for the flash kernels; a zero column adds
+    nothing to a score). Rotate-half, positions 0..seq-1, float32 inside the
     rotation only: the parts that are not rotated move in their own dtype."""
     import jax.numpy as jnp
     q, kv, k_r = ins["Q"][0], ins["KV"][0], ins["KRope"][0]
-    B, S, h, d_n, d_r = _latent_sizes(ctx)
-    d = d_n + d_r
-    if q.shape[-1] != h * d or kv.shape[-1] != h * (d_n + d) \
-            or k_r.shape[-1] != d_r:
+    B, S, h, d_n, d_r, d_v, d, rotate = _latent_sizes(ctx)
+    if q.shape[-1] != h * (d_n + d_r) or kv.shape[-1] != h * (d_n + d_v) \
+            or k_r.shape[-1] != d_r or d < d_n + d_r:
         raise ValueError(
             f"latent_qkv: Q {q.shape}, KV {kv.shape} and KRope {k_r.shape} "
-            f"are not {h} heads of [{d_n} | {d_r}], [{d_n} | {d}] and one "
-            f"of {d_r}: fused_attention takes one head size, so v must be "
-            f"as wide as q and k")
+            f"are not {h} heads of [{d_n} | {d_r}], [{d_n} | {d_v}] and one "
+            f"of {d_r} (head_dim {d})")
+    ctx.report("latent_qkv_lowering_total", rotated=int(rotate), heads=h,
+               head_dim=d, value_dim=d_v)
 
     def heads_of(x, width):         # [T, h * width] -> [B, h, S, width]
         return x.reshape(B, S, h, width).transpose(0, 2, 1, 3)
-    q_r = _rope_rows(ctx, q[:, h * d_n:].reshape(B, S, h, d_r).astype(
-        jnp.float32), False).astype(q.dtype)
-    k_r = _rope_rows(ctx, k_r.reshape(B, S, d_r).astype(jnp.float32),
-                     False).astype(kv.dtype)
+    q_r, k_r = q[:, h * d_n:].reshape(B, S, h, d_r), k_r.reshape(B, S, d_r)
+    if rotate:
+        q_r = _rope_rows(ctx, q_r.astype(jnp.float32), False).astype(q.dtype)
+        k_r = _rope_rows(ctx, k_r.astype(jnp.float32), False).astype(kv.dtype)
+    pad = [jnp.zeros((B, h, S, d - d_n - d_r), q.dtype)] \
+        if d > d_n + d_r else []
     out_q = jnp.concatenate([heads_of(q[:, :h * d_n], d_n),
-                             q_r.transpose(0, 2, 1, 3)], axis=-1)
+                             q_r.transpose(0, 2, 1, 3)] + pad, axis=-1)
     out_k = jnp.concatenate(
         [heads_of(kv[:, :h * d_n], d_n),
-         jnp.broadcast_to(k_r[:, None], (B, h, S, d_r))], axis=-1)
+         jnp.broadcast_to(k_r[:, None], (B, h, S, d_r))] + pad, axis=-1)
     return {"OutQ": [out_q], "OutK": [out_k],
-            "OutV": [heads_of(kv[:, h * d_n:], d)]}
+            "OutV": [heads_of(kv[:, h * d_n:], d_v)]}
 
 
 @register_grad("latent_qkv")
@@ -329,27 +344,30 @@ def latent_qkv_grad(ctx, ins, generic):
     """dQ, dKV and dKRope from the three cotangents alone: the op is linear,
     a cut, a rotation and a broadcast, so its transpose is the parts put
     back where they came from, the rotary parts turned back (the sign of
-    sin) and the one key head's gradient the sum over the heads of dK's
-    rotary part (summed in float32, then turned back once). It reads no
-    forward input's values and lowers no forward; a grad op that lacks a
-    cotangent is ``generic``."""
+    sin; not under ``rotate=False``) and the one key head's gradient the sum
+    over the heads of dK's rotary part (summed in float32, then turned back
+    once). It reads no forward input's values and lowers no forward; a grad
+    op that lacks a cotangent is ``generic``."""
     import jax.numpy as jnp
     dq, dk, dv = (ins.get(s + "@GRAD", [None])[0]
                   for s in ("OutQ", "OutK", "OutV"))
     if dq is None or dk is None or dv is None:
         return generic()
-    B, S, h, d_n, d_r = _latent_sizes(ctx)
+    B, S, h, d_n, d_r, _, _, rotate = _latent_sizes(ctx)
+    rope = slice(d_n, d_n + d_r)
 
     def flat(x):                    # [B, h, S, width] -> [T, h * width]
         return x.transpose(0, 2, 1, 3).reshape(B * S, -1)
-    dq_r = _rope_rows(ctx, dq[..., d_n:].transpose(0, 2, 1, 3).astype(
-        jnp.float32), True).astype(dq.dtype).reshape(B * S, h * d_r)
-    dk_r = _rope_rows(ctx, jnp.sum(dk[..., d_n:], axis=1, dtype=jnp.float32),
-                      True).astype(dk.dtype).reshape(B * S, d_r)
-    return {"Q@GRAD": [jnp.concatenate([flat(dq[..., :d_n]), dq_r], axis=-1)],
+    dq_r = dq[..., rope].transpose(0, 2, 1, 3)
+    dk_r = jnp.sum(dk[..., rope], axis=1, dtype=jnp.float32)
+    if rotate:
+        dq_r = _rope_rows(ctx, dq_r.astype(jnp.float32), True).astype(dq.dtype)
+        dk_r = _rope_rows(ctx, dk_r, True)
+    return {"Q@GRAD": [jnp.concatenate(
+                [flat(dq[..., :d_n]), dq_r.reshape(B * S, h * d_r)], axis=-1)],
             "KV@GRAD": [jnp.concatenate([flat(dk[..., :d_n]), flat(dv)],
                                         axis=-1)],
-            "KRope@GRAD": [dk_r]}
+            "KRope@GRAD": [dk_r.astype(dk.dtype).reshape(B * S, d_r)]}
 
 
 @register("swiglu")
@@ -886,12 +904,13 @@ def ssd_scan(ctx, ins):
 
 
 def _chunk_sums(g, chunk):
-    """The running sum of ``g [B, S, heads]`` inside each chunk of ``chunk``
-    positions, float32."""
+    """The running sum of ``g [B, S, heads]`` (or ``[B, S, heads, d_k]``)
+    inside each chunk of ``chunk`` positions, float32."""
     import jax.numpy as jnp
-    b, s, h = g.shape
-    return jnp.cumsum(g.astype(jnp.float32).reshape(b, s // chunk, chunk, h),
-                      axis=2).reshape(b, s, h)
+    b, s = g.shape[:2]
+    return jnp.cumsum(
+        g.astype(jnp.float32).reshape(b, s // chunk, chunk, *g.shape[2:]),
+        axis=2).reshape(g.shape)
 
 
 def _delta_operands(q, k, g, chunk, dtype):
@@ -959,6 +978,43 @@ def composed_gated_delta_rule(qn, kn, v, cum, beta, chunk):
     return o.reshape(batch, seq, heads, dv), jnp.moveaxis(states, 0, 1)
 
 
+def composed_channel_delta_rule(qn, kn, v, cum, beta, chunk):
+    """``composed_gated_delta_rule`` under a decay a key channel (``cum [B,
+    S, heads, d_k]``; one value head a key head): ``pallas_delta.
+    channel_chunk``, the kernels' own arithmetic a chunk, mapped over batch
+    and head and scanned over the chunks. No exponent of a positive number
+    is taken (sub-blocks of ``pallas_delta.SUB`` positions), so the result
+    stays finite however steep the decay; the pairs of a sub-block exist as
+    ``[B, heads, C, d_k]`` arrays, a pass a column."""
+    import jax
+    import jax.numpy as jnp
+    from . import pallas_delta
+    f32 = jnp.float32
+    batch, seq, heads, dv = v.shape
+    dk, c = qn.shape[-1], seq // chunk
+    sub = min(pallas_delta.SUB, chunk)
+    if chunk & (chunk - 1) or chunk % sub:
+        raise ValueError(
+            f"gated_delta_rule: under a decay a key channel the chunk must "
+            f"be a power of two (the inverse's doubling); got {chunk}")
+
+    def chunks_first(x):                # [B, S, h, ...] -> [c, B, h, C, ...]
+        x = x.reshape(batch, c, chunk, *x.shape[2:])
+        return jnp.moveaxis(jnp.moveaxis(x, 3, 2), 1, 0)
+    a_chunk = jax.vmap(jax.vmap(functools.partial(
+        pallas_delta.channel_chunk, sub=sub)))
+
+    def one(s, inp):
+        o, s_next = a_chunk(*inp, s)
+        return s_next, (o, s)
+    _, (o, states) = jax.lax.scan(
+        one, jnp.zeros((batch, heads, dk, dv), f32),
+        tuple(chunks_first(x) for x in (qn, kn, v, cum.astype(f32),
+                                        beta.astype(f32))))
+    o = jnp.moveaxis(jnp.moveaxis(o, 0, 1), 2, 3)           # [B, c, C, h, dv]
+    return o.reshape(batch, seq, heads, dv), jnp.moveaxis(states, 0, 1)
+
+
 def _delta_inputs(ctx, ins):
     """(q, k [B, S, key heads, d_k], v [B, S, heads, d_v], g, beta, the
     packed ``q | k | v [B, S, 2 keys + values]`` or None): an op given
@@ -980,11 +1036,12 @@ def _delta_inputs(ctx, ins):
             qkv[..., 2 * keys:].reshape(b, s, heads, d_v), g, beta, qkv)
 
 
-def _delta_plan(ctx, q, k, v, qkv):
+def _delta_plan(ctx, q, k, v, qkv, g):
     """(what the kernels read of q, k and v where the op lowers them here,
     else None -- the packed array itself where they can address it in
     place, else three flat operands --, the op's chunk): the forward op and
-    its grad op ask alike."""
+    its grad op ask alike. ``g``'s rank says which decay: a value head's
+    scalar, or (``[B, S, heads, d_k]``) a key channel's."""
     from . import pallas_delta, pallas_mode
     _, seq, heads, dv = v.shape
     key_heads, dk = q.shape[2], q.shape[3]
@@ -993,6 +1050,12 @@ def _delta_plan(ctx, q, k, v, qkv):
             f"gated_delta_rule: {heads} value heads over {key_heads} key "
             f"heads (q {q.shape}, k {k.shape}); the value heads must be a "
             f"multiple of the key heads")
+    channel = g.ndim == 4
+    if channel and (g.shape[3] != dk or heads != key_heads):
+        raise ValueError(
+            f"gated_delta_rule: a decay a key channel (G {g.shape}) needs "
+            f"G's last axis to be the key dim {dk} and one value head a key "
+            f"head; got {heads} over {key_heads}")
     chunk = min(int(ctx.attr("chunk", 64)), seq)
     if seq % chunk:
         raise ValueError(
@@ -1000,7 +1063,8 @@ def _delta_plan(ctx, q, k, v, qkv):
     impl = ctx.attr("impl", "auto")
     if not pallas_mode.lowers_kernels(
             ctx, impl,
-            pallas_delta.supports(seq, key_heads, heads, dk, dv, chunk),
+            pallas_delta.supports(seq, key_heads, heads, dk, dv, chunk,
+                                  channel),
             "gated_delta_rule",
             f"needs key and value heads of {pallas_delta.HEAD_DIM} and a "
             f"chunk of {pallas_delta.CHUNKS} that divides seq; got heads of "
@@ -1027,7 +1091,11 @@ def gated_delta_rule(ctx, ins):
     // (heads / key heads)``), or the three as one ``QKV [B, S, 2 keys +
     values]`` (q | k | v along the columns, as a projection and a short
     convolution write them; attrs ``key_heads``, ``key_dim``), ``G`` (<= 0)
-    and ``Beta [B, S, heads]`` -> ``Out [B, S, heads, d_v]``. Computed in
+    and ``Beta [B, S, heads]`` -> ``Out [B, S, heads, d_v]``. ``G [B, S,
+    heads, d_k]`` is a decay a key channel (Kimi Delta Attention,
+    arXiv:2510.26692; one value head a key head): ``S' = diag(exp(g_t))
+    S_{t-1}``, row c of the state times ``exp(g_t[c])``, the rest as above;
+    the same op, grad lowering and counter, G's rank deciding. Computed in
     chunks of ``chunk`` (attr; the sequence where that is shorter)
     positions, which equals the recurrence in exact arithmetic; the norms,
     the decays, their running sums and the state in float32. ``States [B,
@@ -1046,20 +1114,22 @@ def gated_delta_rule(ctx, ins):
     import jax.numpy as jnp
     from . import pallas_delta, pallas_mode
     q, k, v, g, beta, qkv = _delta_inputs(ctx, ins)
-    operands, chunk = _delta_plan(ctx, q, k, v, qkv)
+    operands, chunk = _delta_plan(ctx, q, k, v, qkv, g)
     ctx.report(
         "delta_lowering_total",
         impl="composed" if operands is None else "pallas", chunk=chunk,
         heads=v.shape[2], key_dim=q.shape[3], value_dim=v.shape[3],
         operands=("split" if operands is None or operands is not qkv
-                  else "packed"))
+                  else "packed"),
+        decay="channel" if g.ndim == 4 else "head")
     if operands is not None:
         o, states = pallas_delta.chunked(
             operands, _chunk_sums(g, chunk), beta.astype(jnp.float32), chunk,
             pallas_mode.interpret())
         return {"Out": [o.reshape(v.shape)], "States": [states]}
     qn, kn, cum = _delta_operands(q, k, g, chunk, jnp.float32)
-    o, states = composed_gated_delta_rule(qn, kn, v, cum, beta, chunk)
+    o, states = (composed_channel_delta_rule if g.ndim == 4
+                 else composed_gated_delta_rule)(qn, kn, v, cum, beta, chunk)
     return {"Out": [o.astype(v.dtype)], "States": [states]}
 
 
@@ -1076,7 +1146,7 @@ def gated_delta_rule_grad(ctx, ins, generic):
     from . import pallas_delta, pallas_mode
     q, k, v, g, beta, qkv = _delta_inputs(ctx, ins)
     states, do = ins.get("States", [None])[0], ins.get("Out@GRAD", [None])[0]
-    operands, chunk = _delta_plan(ctx, q, k, v, qkv)
+    operands, chunk = _delta_plan(ctx, q, k, v, qkv, g)
     if operands is None or states is None or do is None:
         return generic()
     cum, back = jax.vjp(lambda g: _chunk_sums(g, chunk), g)

@@ -283,7 +283,8 @@ def composed_attention(q, k, v, bias, scale, dropout, causal, rng,
     import jax
     import jax.numpy as jnp
 
-    out_shape, grouped = q.shape, k.shape[1] != q.shape[1]
+    out_shape = q.shape[:-1] + v.shape[-1:]
+    grouped = k.shape[1] != q.shape[1]
     scores, values = "bhqd,bhkd->bhqk", "bhqk,bhkd->bhqd"
     if grouped:
         # grouped-query attention: query head i reads key/value head
@@ -566,7 +567,8 @@ def _fwd_kernel(scale, dropout, causal, has_bias, block_k, *refs,
         return _plus(l, l_t), _plus(o, o_t)
 
     l, o = tiles.passes(product, lambda: (
-        tiles.stat_init(0.0), jnp.zeros((blk_q, D), jnp.float32)))
+        tiles.stat_init(0.0),
+        jnp.zeros((blk_q, v_ref.shape[-1]), jnp.float32)))
     l = tiles.total(l, jnp.sum)
     # the softmax's 1/l and the dropout's 1/(1-prob) scale the [block_q, D]
     # product, one reciprocal a row, not the [block_q, S] probabilities
@@ -671,14 +673,16 @@ def _operands(q, k, v, bias, seed, block_q, block_k, by_kv_head=False):
     block; with fewer key/value heads than query heads (k, v ``[B, Hkv, S,
     D]``) a query head's program reads its key/value head's rows in place.
     ``by_kv_head`` (the grouped backward): axis 0 is the key/value's
-    batch*head, axis 1 the group's heads x Q blocks."""
+    batch*head, axis 1 the group's heads x Q blocks. ``v`` (and with it the
+    output) may have a head width of its own, so the specs come in pairs:
+    ``(q's, the output's)`` and ``(k's, v's)``."""
     import jax.numpy as jnp
     pl, pltpu = _pl()
     B, H, S, D = q.shape
-    kv = k.shape[1]
+    kv, Dv = k.shape[1], v.shape[3]
     group, n_q = H // kv, S // block_q
     args = [q.reshape(B * H, S, D), k.reshape(B * kv, S, D),
-            v.reshape(B * kv, S, D)]
+            v.reshape(B * kv, S, Dv)]
     if group == 1:
         q_at, kv_at, per_batch = (lambda b, i: (b, i, 0),
                                   lambda b, i: (b, 0, 0), H)
@@ -696,7 +700,12 @@ def _operands(q, k, v, bias, seed, block_q, block_k, by_kv_head=False):
     lse_spec = pl.BlockSpec(
         (1, 1, block_q), lambda b, i: (q_at(b, i)[0], 0, q_at(b, i)[1]),
         memory_space=pltpu.VMEM)
-    in_specs = [qspec, kvspec, kvspec]
+    # the output's and v's blocks: q's and k's unless v has its own width
+    ospec, vspec = qspec, kvspec
+    if Dv != D:
+        ospec = pl.BlockSpec((1, block_q, Dv), q_at, memory_space=pltpu.VMEM)
+        vspec = pl.BlockSpec((1, S, Dv), kv_at, memory_space=pltpu.VMEM)
+    in_specs = [qspec, kvspec, vspec]
     if bias is not None:
         # [B, n_k, 1, block_k] with block (1, n_k, 1, block_k): the last two
         # dims equal the array dims, satisfying the TPU (8,128)-divisible-
@@ -708,7 +717,7 @@ def _operands(q, k, v, bias, seed, block_q, block_k, by_kv_head=False):
             memory_space=pltpu.VMEM))
     args.append(jnp.asarray(seed, jnp.int32).reshape(1))
     in_specs.append(pl.BlockSpec(memory_space=pltpu.SMEM))
-    return args, in_specs, qspec, kvspec, lse_spec, n_q
+    return args, in_specs, (qspec, ospec), (kvspec, vspec), lse_spec, n_q
 
 
 def _stages(n, S, block_q, block_k, window=None):
@@ -816,21 +825,22 @@ def _fwd_call(q, k, v, bias, seed, scale, dropout, causal, interpret,
     import jax.numpy as jnp
     pl, _ = _pl()
     B, H, S, D = q.shape
-    args, in_specs, qspec, _, lse_spec, n_q = _operands(
+    Dv = v.shape[3]
+    args, in_specs, (_, ospec), _, lse_spec, n_q = _operands(
         q, k, v, bias, seed, block_q, block_k)
     out, lse = pl.pallas_call(
         functools.partial(_fwd_kernel, scale, dropout, causal,
                           bias is not None, block_k, window=window),
         grid=(B * H, n_q),
         in_specs=in_specs,
-        out_specs=[qspec, lse_spec],
-        out_shape=[jax.ShapeDtypeStruct((B * H, S, D), q.dtype),
+        out_specs=[ospec, lse_spec],
+        out_shape=[jax.ShapeDtypeStruct((B * H, S, Dv), q.dtype),
                    jax.ShapeDtypeStruct((B * H, 1, S), jnp.float32)],
         scratch_shapes=_stages(1, S, block_q, block_k, window),
         interpret=interpret,
         **_compiler_params(interpret, fwd_vmem_limit_bytes(D)),
     )(*args)
-    return out.reshape(B, H, S, D), lse.reshape(B, H, 1, S)
+    return out.reshape(B, H, S, Dv), lse.reshape(B, H, 1, S)
 
 
 def _flash_fwd(q, k, v, bias, seed, scale, dropout, causal, interpret,
@@ -857,30 +867,31 @@ def _bwd_call(q, k, v, bias, seed, g, lse, scale, dropout, causal, interpret,
     import jax.numpy as jnp
     pl, pltpu = _pl()
     B, H, S, D = q.shape
-    kv = k.shape[1]
+    kv, Dv = k.shape[1], v.shape[3]
     group = H // kv
-    args, in_specs, qspec, kvspec, lse_spec, n_q = _operands(
-        q, k, v, bias, seed, block_q, block_k, by_kv_head=True)
-    args += [g.reshape(B * H, S, D), lse.reshape(B * H, 1, S)]
-    in_specs += [qspec, lse_spec]
+    args, in_specs, (qspec, ospec), (kvspec, vspec), lse_spec, n_q = \
+        _operands(q, k, v, bias, seed, block_q, block_k, by_kv_head=True)
+    args += [g.reshape(B * H, S, Dv), lse.reshape(B * H, 1, S)]
+    in_specs += [ospec, lse_spec]
     # dK^T, dV^T by K tile; with more tiles than one, the p and dP tiles a
     # Q block keeps between its passes
-    scratch = ([pltpu.VMEM((S // block_k, D, block_k), jnp.float32)] * 2
+    scratch = ([pltpu.VMEM((S // block_k, width, block_k), jnp.float32)
+                for width in (D, Dv)]
                + _stages(2, S, block_q, block_k, window))
     dq, dk, dv = pl.pallas_call(
         functools.partial(_bwd_kernel, scale, dropout, causal,
                           bias is not None, group, block_k, window=window),
         grid=(B * kv, group * n_q),
         in_specs=in_specs,
-        out_specs=[qspec, kvspec, kvspec],
-        out_shape=[jax.ShapeDtypeStruct((B * x.shape[1], S, D), x.dtype)
-                   for x in (q, k, v)],
+        out_specs=[qspec, kvspec, vspec],
+        out_shape=[jax.ShapeDtypeStruct((B * x.shape[1], S, x.shape[3]),
+                                        x.dtype) for x in (q, k, v)],
         scratch_shapes=scratch,
         interpret=interpret,
         **_compiler_params(interpret, BWD_VMEM_LIMIT_BYTES),
     )(*args)
     return (dq.reshape(B, H, S, D), dk.reshape(B, kv, S, D),
-            dv.reshape(B, kv, S, D))
+            dv.reshape(B, kv, S, Dv))
 
 
 def _flash_bwd(scale, dropout, causal, interpret, block_q, block_k, window,
@@ -1016,7 +1027,9 @@ def _kernel_seed(ctx, dropout):
 def fused_attention(ctx, ins):
     """softmax(Q K^T * scale + Bias) V.
 
-    Inputs: Q [B, heads, S, D], K/V [B, kv_heads, S, D] with kv_heads
+    Inputs: Q [B, heads, S, D], K [B, kv_heads, S, D], V [B, kv_heads, S,
+    Dv] (Dv is D but for latent attention with values narrower than its
+    keys; Out is then [B, heads, S, Dv]) with kv_heads
     dividing heads (grouped-query attention: query head i reads key/value
     head i // (heads / kv_heads), in place -- no lowering repeats K or V);
     optional Bias [B, 1, 1, S] additive (already -inf-masked). Attrs: scale (default 1/sqrt(D)), dropout_prob, causal,
@@ -1051,6 +1064,9 @@ def fused_attention(ctx, ins):
     bias = ins.get("Bias", [None])[0]
     B, H, S, D = q.shape
     kv_heads = k.shape[1]
+    if k.shape[3] != D:
+        raise ValueError(f"fused_attention: q heads of {D} against k heads "
+                         f"of {k.shape[3]}")
     if H % kv_heads or v.shape[1] != kv_heads:
         raise ValueError(
             f"fused_attention: {H} query heads over {kv_heads} key and "
@@ -1073,7 +1089,8 @@ def fused_attention(ctx, ins):
     block_q, block_k = blocks or (0, 0)
     ctx.report("attention_lowering_total", impl=impl, s=S, block_q=block_q,
                block_k=block_k, kv_heads=kv_heads, window=window or 0,
-               heads=H, head_dim=D)
+               heads=H, head_dim=D,
+               value_dim=0 if v.shape[3] == D else v.shape[3])
     if impl == "pallas":
         from . import pallas_mode
         for state, tiles in zip(("visited", "skipped"),

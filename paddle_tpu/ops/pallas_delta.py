@@ -59,6 +59,39 @@ into the kernel) is applied to them while they are float32, and dq, dk, dv
 are written once (three outputs; the packed form's one gradient is their
 concatenation). Only the running sums of ``g`` (1 MB) are plain ``jax.numpy``
 around the kernels, differentiated by JAX (``ops/decoder_ops.py``).
+
+**A decay a key channel** (Kimi Delta Attention; Kimi Linear, arXiv:
+2510.26692): ``g_t`` is a vector over the ``d_k`` key channels, ``S' =
+diag(exp(g_t)) S_{t-1}`` scales the state's rows, and ``G [C, d_k]`` puts the
+decay inside the contraction over the channels:
+
+    M[i, j] = beta_i sum_c k_i[c] k_j[c] exp(G_i[c] - G_j[c])      (i > j)
+    P[i, j] =        sum_c q_i[c] k_j[c] exp(G_i[c] - G_j[c])      (i >= j)
+    V' = T (beta * (v - (k * exp(G)) S))     O = (q * exp(G)) S + P V'
+    S <- diag(exp(G_C)) S + (k * exp(G_C - G))^T V'
+
+``(k * exp(G)) (k * exp(-G))^T`` would overflow float32 (``exp(-G)`` passes
+3e38 once a channel has fallen by 88 inside a chunk), so no exponent is ever
+taken of a positive number: the chunk is cut into sub-blocks of ``SUB``
+positions; a sub-block's rows against the sub-blocks before it are one
+product of ``x * exp(G - G_ref)`` with ``k * exp(G_ref - G)``, ``G_ref`` the
+sub-block's first row (both exponents <= 0; a factor that underflows has a
+product that underflows); a sub-block against itself is formed pair by
+pair, ``SUB`` passes of ``exp(min(G_i - G_j, 0))`` over the ``[C, d_k]``
+tile, each giving one column of every diagonal block. ``T`` comes block by
+block, 8-row blocks merged pair by pair (``_inverse_blocked`` says why not
+by the doubling over the whole chunk). These are ``_intra``
+and its transpose ``_intra_bwd``: plain functions of a chunk's values, which
+the kernels' bodies trace and the composed form maps over batch, head and
+chunk (``channel_chunk``). The kernels (``_fwd_kernel_channel``,
+``_bwd_kernel_channel``) take one value head a key head, share the block
+specs, the packed operand and the states' layout with the scalar pair, and
+read ``G`` and write ``dG`` as ``[B, S, heads * 128]`` float32, a head a
+lane tile. In the backward every product that holds a decay is
+differentiated as the rounded operand the forward's product read (``dG +=
+L * dL`` for ``L = (x * exp(..)).astype(bf)``), so what cancels pair by pair
+in the running sum that turns ``dG`` into ``dg`` is a sum of the same
+products on both sides.
 """
 from __future__ import annotations
 
@@ -70,15 +103,20 @@ from .pallas_ssd import _nn, _nt, _params, _pl, _tn
 
 HEAD_DIM = 128          # key and value head size: one 128-lane tile
 CHUNKS = (64, 128)      # chunk lengths the kernels take
+SUB = 16                # sub-block of a chunk under a channel decay: one
+#                         packed bfloat16 vreg of rows
 QUERY_SCALE = HEAD_DIM ** -0.5
 
 
 def supports(seq: int, key_heads: int, value_heads: int, key_dim: int,
-             value_dim: int, chunk: int) -> bool:
-    """Whether the kernels take these shapes (else the composed form)."""
+             value_dim: int, chunk: int, channel: bool = False) -> bool:
+    """Whether the kernels take these shapes (else the composed form);
+    ``channel``: a decay a key channel, whose kernels take one value head a
+    key head."""
     return (key_dim == HEAD_DIM and value_dim == HEAD_DIM
             and value_heads % key_heads == 0 and chunk in CHUNKS
-            and seq % chunk == 0)
+            and seq % chunk == 0
+            and not (channel and value_heads != key_heads))
 
 
 def packs(key_heads: int, value_heads: int) -> bool:
@@ -267,6 +305,286 @@ def _bwd_kernel(rep, q_ref, k_ref, v_ref, do_ref, g_ref, b_ref, st_ref,
     db_ref[0, 0, 0] = dbs
 
 
+# -- a decay a key channel ---------------------------------------------------
+
+def _pick(x, jj, sub):
+    """Each sub-block's row ``jj`` of ``x [C, d]`` over that sub-block's
+    rows."""
+    import jax.numpy as jnp
+    return jnp.concatenate([
+        jnp.broadcast_to(x[b + jj:b + jj + 1], (sub, x.shape[1]))
+        for b in range(0, x.shape[0], sub)], axis=0)
+
+
+def _block_sums(x, jj, sub):
+    """Each sub-block's sum over its rows of ``x [C, d]``, at the
+    sub-block's row ``jj``; zero elsewhere."""
+    import jax
+    import jax.numpy as jnp
+    at = jax.lax.broadcasted_iota(jnp.int32, (sub, 1), 0) == jj
+    return jnp.concatenate([
+        jnp.where(at, jnp.sum(x[b:b + sub], axis=0, keepdims=True), 0.0)
+        for b in range(0, x.shape[0], sub)], axis=0)
+
+
+def _blocks_of(c, sub):
+    """Over ``[c, c]``: (row i's sub-block lies after column j's, the column
+    a row's own sub-block starts at)."""
+    import jax
+    import jax.numpy as jnp
+    rows = jax.lax.broadcasted_iota(jnp.int32, (c, c), 0)
+    cols = jax.lax.broadcasted_iota(jnp.int32, (c, c), 1)
+    return rows // sub > cols // sub, cols - rows // sub * sub
+
+
+def _strip(kn, g, a, sub, bf):
+    """Sub-block ``a``'s rows against every earlier position: (its rows, the
+    decay of its rows from its first, the growth of every position's key
+    back to that row -- ``min``: the positions from there on are masked out
+    of the product --, ``kn`` times that growth as the product's operand)."""
+    import jax.numpy as jnp
+    at = slice(a * sub, (a + 1) * sub)
+    ref = g[a * sub:a * sub + 1]
+    near, far = jnp.exp(g[at] - ref), jnp.exp(jnp.minimum(ref - g, 0.0))
+    return at, near, far, (kn * far).astype(bf)
+
+
+INVERSE_BLOCK = 8       # rows of the blocks the blocked inverse starts from
+
+
+def _inverse_blocked(m, eye, bf):
+    """``(I + m)^-1`` of a strictly lower-triangular ``m [c, c]``, block by
+    block: the diagonal blocks of ``INVERSE_BLOCK`` rows by ``_inverse``'s
+    doubling (``(I - d)(I + d^2)(I + d^4)``, all blocks in one product: ``d``
+    is ``m`` masked to them), then pairs of neighbouring blocks merged,
+    ``log2(c / INVERSE_BLOCK)`` times: with ``T`` the inverse of the
+    block-diagonal part so far and ``e`` the part of ``m`` that joins each
+    pair's lower block to its upper one, the pairs' inverse is ``T - T e T``
+    (``[[A, 0], [E, B]]^-1 = [[A^-1, 0], [-B^-1 E A^-1, B^-1]]``), every pair
+    in the same two ``[c, c]`` products.
+
+    ``_inverse``'s doubling over the whole chunk forms ``m^2, m^4, ..,
+    m^(c/2)``, whose entries grow with the number of paths between two
+    positions (``C(c, c/2)`` of them) before nilpotency ends them. With keys
+    that resemble their neighbours' (a layer that reads a gated norm's
+    output) under a decay that some channels hardly apply, ``m`` holds 0.5
+    to 0.9 over long ranges, and the series loses every digit at c = 128
+    (chip, PR 51: not-a-number from the second KDA layer on; chunks of 64
+    read 2e-3 off in the fourth). Here no power beyond an 8-row block's
+    fourth is formed (entries up to ``C(7, 3) 0.9^4 = 23``, typically about
+    1), and every ``T`` on the way is an inverse whose entries are tame."""
+    import jax
+    import jax.numpy as jnp
+    c = m.shape[0]
+    rows = jax.lax.broadcasted_iota(jnp.int32, (c, c), 0)
+    cols = jax.lax.broadcasted_iota(jnp.int32, (c, c), 1)
+    size = min(INVERSE_BLOCK, c)
+    x = jnp.where(rows // size == cols // size, -m, 0.0)
+    t = eye + x
+    for _ in range(size.bit_length() - 2):
+        xb = x.astype(bf)
+        x = _nn(xb, xb)
+        t = t + _nn(t.astype(bf), x.astype(bf))
+    while size < c:
+        # the lower block of each pair against the pair's upper block
+        joins = (rows // (2 * size) == cols // (2 * size)) & (
+            rows // size > cols // size)
+        tb = t.astype(bf)
+        t = t - _nn(tb, _nn(jnp.where(joins, m, 0.0).astype(bf), tb)
+                    .astype(bf))
+        size *= 2
+    return t
+
+
+def _intra(qn, kn, g, sub, bf):
+    """``sum_c x_i[c] kn_j[c] exp(G_i[c] - G_j[c])`` over a chunk for ``x``
+    = ``kn`` and ``qn`` (float32 values ``[C, d]``; ``g [C, d]`` the running
+    sums): two ``[C, C]`` float32 blocks, right at and under the diagonal
+    (above it the diagonal sub-blocks hold clamped values: the caller
+    masks)."""
+    import jax.numpy as jnp
+    c = g.shape[0]
+    kk, qk = ([jnp.zeros((sub, c), jnp.float32)] for _ in range(2))
+    for a in range(1, c // sub):
+        at, near, _, right = _strip(kn, g, a, sub, bf)
+        kk.append(_nt((kn[at] * near).astype(bf), right))
+        qk.append(_nt((qn[at] * near).astype(bf), right))
+    before, offset = _blocks_of(c, sub)
+    kk = jnp.where(before, jnp.concatenate(kk, axis=0), 0.0)
+    qk = jnp.where(before, jnp.concatenate(qk, axis=0), 0.0)
+    for jj in range(sub):       # a sub-block against itself, a column a pass
+        w = _pick(kn, jj, sub) * jnp.exp(
+            jnp.minimum(g - _pick(g, jj, sub), 0.0))
+        here = offset == jj
+        kk = jnp.where(here, jnp.sum(kn * w, axis=1, keepdims=True), kk)
+        qk = jnp.where(here, jnp.sum(qn * w, axis=1, keepdims=True), qk)
+    return kk, qk
+
+
+def _intra_bwd(qn, kn, g, dkk, dqk, sub, bf):
+    """``_intra``'s transpose: (dqn, dkn, dG) from the cotangents of its two
+    blocks (masked by the caller: strictly under the diagonal, at and under
+    it). A product's operand that holds a decay is differentiated as the
+    rounded array the product read."""
+    import jax.numpy as jnp
+    f32 = jnp.float32
+    c, d = g.shape
+    before, offset = _blocks_of(c, sub)
+    dkk_far = jnp.where(before, dkk, 0.0).astype(bf)
+    dqk_far = jnp.where(before, dqk, 0.0).astype(bf)
+    dq, dk, dg = ([jnp.zeros((sub, d), f32)] for _ in range(3))
+    dk_right, dg_right = jnp.zeros((c, d), f32), jnp.zeros((c, d), f32)
+    for a in range(1, c // sub):
+        at, near, far, right = _strip(kn, g, a, sub, bf)
+        kl, ql = (kn[at] * near).astype(bf), (qn[at] * near).astype(bf)
+        dkl, dql = _nn(dkk_far[at], right), _nn(dqk_far[at], right)
+        dright = _tn(dkk_far[at], kl) + _tn(dqk_far[at], ql)
+        dk.append(dkl * near)
+        dq.append(dql * near)
+        dg.append(kl.astype(f32) * dkl + ql.astype(f32) * dql)
+        dk_right += dright * far
+        dg_right += right.astype(f32) * dright
+    dq = jnp.concatenate(dq, axis=0)
+    dk = jnp.concatenate(dk, axis=0) + dk_right
+    dg = jnp.concatenate(dg, axis=0) - dg_right
+    for jj in range(sub):
+        e = jnp.exp(jnp.minimum(g - _pick(g, jj, sub), 0.0))
+        w = _pick(kn, jj, sub) * e
+        here = offset == jj
+        ckk = jnp.sum(jnp.where(here, dkk, 0.0), axis=1, keepdims=True)
+        cqk = jnp.sum(jnp.where(here, dqk, 0.0), axis=1, keepdims=True)
+        u = ckk * kn + cqk * qn
+        pair = u * w                    # at row i; its sub-block's sum at j
+        dq += cqk * w
+        dk += ckk * w + _block_sums(u * e, jj, sub)
+        dg += pair - _block_sums(pair, jj, sub)
+    return dq, dk, dg
+
+
+def _channel_forward(qn, kn, v, g, bc, s, sub):
+    """One chunk of one head under a channel decay: ``qn`` / ``kn [C, d_k]``
+    the unit operands in the products' dtype, ``v [C, d_v]``, ``g [C, d_k]``
+    float32 running sums, ``bc [C, 1]`` beta, ``s [d_k, d_v]`` float32 the
+    state entering. Returns ``o`` (float32), the state leaving, and what the
+    backward reads again."""
+    import jax.numpy as jnp
+    bf, f32 = qn.dtype, jnp.float32
+    c = g.shape[0]
+    lower, strict, diag = _masks(c)
+    qf, kf = qn.astype(f32), kn.astype(f32)
+    kk, qk = _intra(qf, kf, g, sub, bf)
+    md = jnp.where(strict, kk, 0.0)
+    p = jnp.where(lower, qk, 0.0)
+    tb = _inverse_blocked(md * bc, diag.astype(f32), bf).astype(bf)
+    eg, end = jnp.exp(g), g[c - 1:]
+    fade = jnp.exp(end - g)
+    kg, qg = (kf * eg).astype(bf), (qf * eg).astype(bf)
+    kend = (kf * fade).astype(bf)
+    e_end = jnp.exp(_column(end, _masks(g.shape[1])[2]))    # [d_k, 1]
+    sb = s.astype(bf)
+    z = v.astype(f32) - _nn(kg, sb)
+    vpb = _nn(tb, (bc * z).astype(bf)).astype(bf)
+    o = _nn(qg, sb) + _nn(p.astype(bf), vpb)
+    s_next = e_end * s + _tn(kend, vpb)
+    return o, s_next, (qf, kf, md, p, tb, eg, fade, kg, qg, kend, e_end, sb,
+                       z, vpb)
+
+
+def _channel_backward(qn, kn, v, g, bc, s, dsn, do, sub):
+    """The chunk's gradients given the state's gradient ``dsn`` leaving it
+    and ``do [C, d_v]``: (dqn, dkn, dv, dG, dbeta ``[C, 1]``, the state's
+    gradient entering), float32; dqn / dkn are the unit operands'."""
+    import jax
+    import jax.numpy as jnp
+    bf = qn.dtype
+    c = g.shape[0]
+    lower, strict, _ = _masks(c)
+    (qf, kf, md, p, tb, eg, fade, kg, qg, kend, e_end, sb, z,
+     vpb) = _channel_forward(qn, kn, v, g, bc, s, sub)[2]
+
+    def rows(x):
+        return jnp.sum(x, axis=1, keepdims=True)
+
+    dob, dsnb = do.astype(bf), dsn.astype(bf)
+    # O = (q exp(G)) S + P V';  S_next = diag(exp(G_C)) S + (k fade)^T V'
+    dvp = _tn(p.astype(bf), dob) + _nn(kend, dsnb)
+    dp = jnp.where(lower, _nt(dob, vpb), 0.0)
+    dqg = _nt(dob, sb)
+    dkend = _nt(vpb, dsnb)
+    # V' = T R, R = beta (v - (k exp(G)) S);  dM = -dR V'^T
+    dr = _tn(tb, dvp.astype(bf))
+    dm = jnp.where(strict, -_nt(dr.astype(bf), vpb), 0.0)
+    dz = bc * dr
+    dzb = dz.astype(bf)
+    dkg = -_nt(dzb, sb)
+    ds = e_end * dsn + _tn(qg, dob) - _tn(kg, dzb)
+    dq, dk, dg = _intra_bwd(qf, kf, g, dm * bc, dp, sub, bf)
+    f32 = jnp.float32
+    moved = kend.astype(f32) * dkend            # leaves row i for row C
+    at_last = jnp.sum(moved, axis=0, keepdims=True) + _row(
+        e_end * rows(dsn * s), _masks(g.shape[1])[2])
+    at_end = jax.lax.broadcasted_iota(jnp.int32, (c, 1), 0) == c - 1
+    dg = (dg + qg.astype(f32) * dqg + kg.astype(f32) * dkg - moved
+          + jnp.where(at_end, at_last, 0.0))
+    return (dq + dqg * eg, dk + dkg * eg + dkend * fade, dz, dg,
+            rows(dr * z) + rows(dm * md), ds)
+
+
+def _fwd_kernel_channel(sub, q_ref, k_ref, v_ref, g_ref, b_ref, o_ref, st_ref,
+                        s_ref):
+    import jax.numpy as jnp
+    pl, _ = _pl()
+    bf, f32 = q_ref.dtype, jnp.float32
+
+    @pl.when(pl.program_id(2) == 0)
+    def _():
+        s_ref[...] = jnp.zeros_like(s_ref)
+
+    diag = _masks(q_ref.shape[1])[2]
+    s = s_ref[0]
+    st_ref[0, 0, 0] = s
+    o, s_next, _ = _channel_forward(
+        unit(q_ref[0].astype(f32), QUERY_SCALE).astype(bf),
+        unit(k_ref[0].astype(f32)).astype(bf), v_ref[0], g_ref[0],
+        _column(b_ref[0, 0, 0], diag), s, sub)
+    o_ref[0] = o.astype(o_ref.dtype)
+    s_ref[0] = s_next
+
+
+def _bwd_kernel_channel(sub, q_ref, k_ref, v_ref, do_ref, g_ref, b_ref, st_ref,
+                        dq_ref, dk_ref, dv_ref, dg_ref, db_ref, ds_ref):
+    import jax
+    import jax.numpy as jnp
+    pl, _ = _pl()
+    bf, f32 = q_ref.dtype, jnp.float32
+
+    @pl.when(pl.program_id(2) == 0)     # the last chunk: nothing follows it
+    def _():
+        ds_ref[...] = jnp.zeros_like(ds_ref)
+
+    diag = _masks(q_ref.shape[1])[2]
+    qu, q_back = jax.vjp(lambda x: unit(x, QUERY_SCALE), q_ref[0].astype(f32))
+    ku, k_back = jax.vjp(unit, k_ref[0].astype(f32))
+    dqn, dkn, dv, dg, db, ds = _channel_backward(
+        qu.astype(bf), ku.astype(bf), v_ref[0], g_ref[0],
+        _column(b_ref[0, 0, 0], diag), st_ref[0, 0, 0], ds_ref[0],
+        do_ref[0], sub)
+    ds_ref[0] = ds
+    dq_ref[0] = q_back(dqn)[0].astype(dq_ref.dtype)
+    dk_ref[0] = k_back(dkn)[0].astype(dk_ref.dtype)
+    dv_ref[0] = dv.astype(dv_ref.dtype)
+    dg_ref[0] = dg
+    db_ref[0, 0, 0] = _row(db, diag)
+
+
+def channel_chunk(qn, kn, v, g, beta, s, sub=SUB):
+    """``_channel_forward``'s ``o`` and next state for the composed form
+    (``ops/decoder_ops.py``), ``beta [C]``: the kernels' arithmetic as plain
+    ``jax.numpy``, differentiable by JAX."""
+    o, s_next, _ = _channel_forward(qn, kn, v, g, beta[:, None], s, sub)
+    return o, s_next
+
+
 def _by_head(v, key_heads, chunk):
     """``[B, S, heads]`` -> ``[B, key heads, chunks, rep, C]``: a grid
     step's scalars, a value head a row."""
@@ -325,24 +643,31 @@ def _fwd_call(qkv, gcum, beta, chunk, interpret):
     128])`` or one packed ``[B, S, (2 key heads + value heads) * 128]``;
     ``gcum`` / ``beta [B, S, value heads]`` float32 -> ``o [B, S, value heads
     * 128]`` and the state entering each chunk ``[B, chunks, value heads,
-    128, 128]`` float32."""
+    128, 128]`` float32. ``gcum [B, S, heads, 128]``: a decay a key channel
+    (one value head a key head)."""
     import jax
     import jax.numpy as jnp
     pl, pltpu = _pl()
-    batch, seq, n_v = gcum.shape
+    batch, seq, n_v = gcum.shape[:3]
     (q, k, v), at, n_k = _laid_out(qkv, n_v)
     rep, chunks = n_v // n_k, seq // chunk
     key, value, scalars, state = _specs(chunk, rep, lambda i: i)
+    if gcum.ndim == 4:
+        kernel, decay, sums = (functools.partial(_fwd_kernel_channel, SUB),
+                               key(), gcum.reshape(batch, seq, -1))
+    else:
+        kernel, decay, sums = (functools.partial(_fwd_kernel, rep), scalars,
+                               _by_head(gcum, n_k, chunk))
     return pl.pallas_call(
-        functools.partial(_fwd_kernel, rep), grid=(batch, n_k, chunks),
-        in_specs=[key(at[0]), key(at[1]), value(at[2]), scalars, scalars],
+        kernel, grid=(batch, n_k, chunks),
+        in_specs=[key(at[0]), key(at[1]), value(at[2]), decay, scalars],
         out_specs=[value(), state],
         out_shape=[jax.ShapeDtypeStruct((batch, seq, n_v * HEAD_DIM), v.dtype),
                    jax.ShapeDtypeStruct(
                        (batch, chunks, n_v, HEAD_DIM, HEAD_DIM), jnp.float32)],
         scratch_shapes=[pltpu.VMEM((rep, HEAD_DIM, HEAD_DIM), jnp.float32)],
         interpret=interpret, **_params(interpret),
-    )(q, k, v, _by_head(gcum, n_k, chunk), _by_head(beta, n_k, chunk))
+    )(q, k, v, sums, _by_head(beta, n_k, chunk))
 
 
 @functools.partial(_jax.jit, static_argnames=("chunk", "interpret"))
@@ -353,28 +678,36 @@ def _bwd_call(qkv, gcum, beta, states, do, chunk, interpret):
     import jax
     import jax.numpy as jnp
     pl, pltpu = _pl()
-    batch, seq, n_v = gcum.shape
+    batch, seq, n_v = gcum.shape[:3]
     (q, k, v), at, n_k = _laid_out(qkv, n_v)
     rep, chunks = n_v // n_k, seq // chunk
     key, value, scalars, state = _specs(
         chunk, rep, lambda i: chunks - 1 - i)
-    gr = _by_head(gcum, n_k, chunk)
     f32 = jnp.float32
     keys = jax.ShapeDtypeStruct((batch, seq, n_k * HEAD_DIM), v.dtype)
-    by_head = jax.ShapeDtypeStruct(gr.shape, f32)
+    by_head = jax.ShapeDtypeStruct((batch, n_k, chunks, rep, chunk), f32)
+    if gcum.ndim == 4:      # a decay a key channel: G and dG a head a tile
+        kernel, decay, gr = (functools.partial(_bwd_kernel_channel, SUB),
+                             key(), gcum.reshape(batch, seq, -1))
+        dg_shape = jax.ShapeDtypeStruct(gr.shape, f32)
+    else:
+        kernel, decay, gr = (functools.partial(_bwd_kernel, rep), scalars,
+                             _by_head(gcum, n_k, chunk))
+        dg_shape = by_head
     dq, dk, dv, dg, db = pl.pallas_call(
-        functools.partial(_bwd_kernel, rep), grid=(batch, n_k, chunks),
-        in_specs=[key(at[0]), key(at[1]), value(at[2]), value(), scalars,
+        kernel, grid=(batch, n_k, chunks),
+        in_specs=[key(at[0]), key(at[1]), value(at[2]), value(), decay,
                   scalars, state],
-        out_specs=[key(), key(), value(), scalars, scalars],
+        out_specs=[key(), key(), value(), decay, scalars],
         out_shape=[keys, keys, jax.ShapeDtypeStruct(do.shape, v.dtype),
-                   by_head, by_head],
+                   dg_shape, by_head],
         scratch_shapes=[pltpu.VMEM((rep, HEAD_DIM, HEAD_DIM), f32)],
         interpret=interpret, **_params(interpret),
     )(q, k, v, do, gr, _by_head(beta, n_k, chunk), states)
     dqkv = (dq, dk, dv) if isinstance(qkv, (tuple, list)) else \
         jnp.concatenate([dq, dk, dv], axis=-1)
-    return dqkv, _from_heads(dg), _from_heads(db)
+    dg = dg.reshape(gcum.shape) if gcum.ndim == 4 else _from_heads(dg)
+    return dqkv, dg, _from_heads(db)
 
 
 @functools.partial(_jax.custom_vjp, nondiff_argnums=(3, 4))

@@ -1,7 +1,9 @@
 """The gated per-head RMSNorm (``ops/decoder_ops.py:rms_norm`` given a
 ``Gate``) as two Pallas TPU kernels, one pass over its operands in each
 direction: ``y = x * rsqrt(mean(x^2) + eps) * scale * silu(gate)`` over the
-last axis, float32 in registers, x's dtype out.
+last axis, float32 in registers, x's dtype out; under ``activation=
+"sigmoid"`` the gate is ``sigmoid(gate)`` (Kimi Delta Attention's output
+norm; HF ``FusedRMSNormGated(activation='sigmoid')``).
 
 As two Program ops (``rms_norm`` then ``swiglu``), each float32 inside and
 each lowered again under ``jax.vjp`` by its generic grad op, XLA kept float32
@@ -68,19 +70,35 @@ def _unit(x, eps):
     return x * r, r
 
 
-def forward(x, gate, scale, eps):
-    """``rmsnorm(x) * scale * silu(gate)`` on float32 ``x``, ``gate [...,
-    D]`` and ``scale [D]`` (or ``[1, D]``)."""
-    return _unit(x, eps)[0] * scale * _silu(gate)[0]
+ACTIVATIONS = ("silu", "sigmoid")
 
 
-def backward(x, gate, scale, dy, eps):
+def _gate(gate, activation):
+    """The gate's factor and its derivative: ``silu`` or ``sigmoid`` of
+    float32 ``gate``."""
+    import jax
+    if activation == "silu":
+        return _silu(gate)
+    if activation != "sigmoid":
+        raise ValueError(f"rms_norm: gate_activation={activation!r} is not "
+                         f"one of {ACTIVATIONS}")
+    s = jax.nn.sigmoid(gate)
+    return s, s * (1.0 - s)
+
+
+def forward(x, gate, scale, eps, activation="silu"):
+    """``rmsnorm(x) * scale * silu(gate)`` (or ``sigmoid(gate)``) on float32
+    ``x``, ``gate [..., D]`` and ``scale [D]`` (or ``[1, D]``)."""
+    return _unit(x, eps)[0] * scale * _gate(gate, activation)[0]
+
+
+def backward(x, gate, scale, dy, eps, activation="silu"):
     """(dx, dgate, the terms of dscale: summed over every axis but the last
     they are the scale's gradient) of ``forward`` under the cotangent
     ``dy``, in closed form on float32 values."""
     import jax.numpy as jnp
     xh, r = _unit(x, eps)
-    s, ds = _silu(gate)
+    s, ds = _gate(gate, activation)
     u = dy * s * scale
     dx = r * (u - xh * jnp.mean(xh * u, axis=-1, keepdims=True))
     return dx, dy * xh * scale * ds, dy * s * xh
@@ -93,7 +111,7 @@ def _chunks(block):
     return chunk, block // chunk
 
 
-def _fwd_kernel(eps, x_ref, z_ref, scale_ref, y_ref):
+def _fwd_kernel(eps, activation, x_ref, z_ref, scale_ref, y_ref):
     import jax
     import jax.numpy as jnp
     pl, _ = _pl()
@@ -103,13 +121,13 @@ def _fwd_kernel(eps, x_ref, z_ref, scale_ref, y_ref):
         at = pl.ds(pl.multiple_of(i * chunk, chunk), chunk)
         y_ref[at, :] = forward(
             x_ref[at, :].astype(jnp.float32), z_ref[at, :].astype(jnp.float32),
-            scale_ref[...], eps).astype(y_ref.dtype)
+            scale_ref[...], eps, activation).astype(y_ref.dtype)
         return carry
     jax.lax.fori_loop(0, n, one, 0)
 
 
-def _bwd_kernel(eps, x_ref, z_ref, scale_ref, dy_ref, dx_ref, dz_ref,
-                dscale_ref):
+def _bwd_kernel(eps, activation, x_ref, z_ref, scale_ref, dy_ref, dx_ref,
+                dz_ref, dscale_ref):
     import jax
     import jax.numpy as jnp
     pl, _ = _pl()
@@ -119,7 +137,8 @@ def _bwd_kernel(eps, x_ref, z_ref, scale_ref, dy_ref, dx_ref, dz_ref,
         at = pl.ds(pl.multiple_of(i * chunk, chunk), chunk)
         dx, dz, terms = backward(
             x_ref[at, :].astype(jnp.float32), z_ref[at, :].astype(jnp.float32),
-            scale_ref[...], dy_ref[at, :].astype(jnp.float32), eps)
+            scale_ref[...], dy_ref[at, :].astype(jnp.float32), eps,
+            activation)
         dx_ref[at, :] = dx.astype(dx_ref.dtype)
         dz_ref[at, :] = dz.astype(dz_ref.dtype)
         return acc + terms
@@ -128,21 +147,22 @@ def _bwd_kernel(eps, x_ref, z_ref, scale_ref, dy_ref, dx_ref, dz_ref,
     dscale_ref[...] = jnp.sum(acc, axis=0, keepdims=True)[None]
 
 
-@functools.partial(_jax.custom_vjp, nondiff_argnums=(3, 4))
-def gated_norm(x, gate, scale, eps, interpret):
+@functools.partial(_jax.custom_vjp, nondiff_argnums=(3, 4, 5))
+def gated_norm(x, gate, scale, eps, interpret, activation="silu"):
     """``x [..., D]`` normed over its last axis, times float32 ``scale [D]``
-    and ``silu(gate)`` (``gate``: x's element count), in x's dtype: the
-    forward kernel, and under ``jax.vjp`` the backward kernel on x, gate and
-    the cotangent alone."""
-    return _fwd_call(x, gate, scale, eps, interpret)
+    and ``silu(gate)`` or ``sigmoid(gate)`` (``gate``: x's element count),
+    in x's dtype: the forward kernel, and under ``jax.vjp`` the backward
+    kernel on x, gate and the cotangent alone."""
+    return _fwd_call(x, gate, scale, eps, interpret, activation)
 
 
-def _gated_norm_fwd(x, gate, scale, eps, interpret):
-    return _fwd_call(x, gate, scale, eps, interpret), (x, gate, scale)
+def _gated_norm_fwd(x, gate, scale, eps, interpret, activation):
+    return (_fwd_call(x, gate, scale, eps, interpret, activation),
+            (x, gate, scale))
 
 
-def _gated_norm_bwd(eps, interpret, res, dy):
-    return _bwd_call(*res, dy, eps, interpret)
+def _gated_norm_bwd(eps, interpret, activation, res, dy):
+    return _bwd_call(*res, dy, eps, interpret, activation)
 
 
 gated_norm.defvjp(_gated_norm_fwd, _gated_norm_bwd)
@@ -169,14 +189,15 @@ def _plan(x, gate, interpret):
 
 # each behind a jit of its own, like the flash kernels: the layers of a
 # model share one trace and one lowering
-@functools.partial(_jax.jit, static_argnames=("eps", "interpret"))
-def _fwd_call(x, gate, scale, eps, interpret):
+@functools.partial(_jax.jit,
+                   static_argnames=("eps", "interpret", "activation"))
+def _fwd_call(x, gate, scale, eps, interpret, activation="silu"):
     import jax
     import jax.numpy as jnp
     pl, _ = _pl()
     view, grid, by_head, whole, params = _plan(x, gate, interpret)
     y = pl.pallas_call(
-        functools.partial(_fwd_kernel, eps), grid=grid,
+        functools.partial(_fwd_kernel, eps, activation), grid=grid,
         in_specs=[by_head, by_head, whole], out_specs=by_head,
         out_shape=jax.ShapeDtypeStruct(view, x.dtype),
         interpret=interpret, **params,
@@ -185,8 +206,9 @@ def _fwd_call(x, gate, scale, eps, interpret):
     return y.reshape(x.shape)
 
 
-@functools.partial(_jax.jit, static_argnames=("eps", "interpret"))
-def _bwd_call(x, gate, scale, dy, eps, interpret):
+@functools.partial(_jax.jit,
+                   static_argnames=("eps", "interpret", "activation"))
+def _bwd_call(x, gate, scale, dy, eps, interpret, activation="silu"):
     """(dx, dgate, dscale float32 ``[D]``) in x's, gate's shape and dtype."""
     import jax
     import jax.numpy as jnp
@@ -196,7 +218,7 @@ def _bwd_call(x, gate, scale, dy, eps, interpret):
     partial = pl.BlockSpec((1, 1, dim), lambda i, h: (i * grid[1] + h, 0, 0),
                            memory_space=pltpu.VMEM)
     dx, dz, dscale = pl.pallas_call(
-        functools.partial(_bwd_kernel, eps), grid=grid,
+        functools.partial(_bwd_kernel, eps, activation), grid=grid,
         in_specs=[by_head, by_head, whole, by_head],
         out_specs=[by_head, by_head, partial],
         out_shape=[jax.ShapeDtypeStruct(view, x.dtype),

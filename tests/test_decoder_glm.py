@@ -136,8 +136,78 @@ def test_latent_qkv_keeps_float32_to_the_rotation():
 
 def test_latent_qkv_refuses_a_v_as_wide_as_nothing_it_can_attend_with():
     bad = dict(_op_inputs(), KV=[jnp.zeros((B * S, HEADS * (D_N + D + 2)))])
-    with pytest.raises(ValueError, match="one head size"):
+    with pytest.raises(ValueError, match=r"are not 3 heads of \[12 \| 4\]"):
         registry.get("latent_qkv").lower(registry.LowerCtx(dict(ATTRS)), bad)
+
+
+# -- Kimi Linear's form: no rotation, v narrower than q / k, zero columns ----
+
+D_V, D_PAD = 8, 24
+
+
+def unrotated(q, kv, k_r):
+    """``assembled`` without the rotation, v ``D_V`` wide, q and k written
+    ``D_PAD`` wide with zeros behind their two parts."""
+    q_n = q[:, :HEADS * D_N].reshape(B, S, HEADS, D_N)
+    q_r = q[:, HEADS * D_N:].reshape(B, S, HEADS, D_R)
+    k_n = kv[:, :HEADS * D_N].reshape(B, S, HEADS, D_N)
+    v = kv[:, HEADS * D_N:].reshape(B, S, HEADS, D_V)
+    k_r = jnp.broadcast_to(k_r.reshape(B, S, 1, D_R), (B, S, HEADS, D_R))
+    zeros = jnp.zeros((B, S, HEADS, D_PAD - D))
+    t = lambda x: x.transpose(0, 2, 1, 3)                    # noqa: E731
+    return (t(jnp.concatenate([q_n, q_r, zeros], -1)),
+            t(jnp.concatenate([k_n, k_r, zeros], -1)), t(v))
+
+
+@pytest.mark.parametrize("which", [0, 1, 2])
+def test_latent_qkv_unrotated_with_a_narrow_v_and_zero_columns(which):
+    feeds = dict(latent_feeds(), kv=rng(1).randn(
+        B * S, HEADS * (D_N + D_V)).astype("float32"))
+
+    def build(q, kv, k_r):
+        return layers.latent_qkv(q, kv, k_r, B, S, HEADS, D_N, D_R,
+                                 rotate=False, value_dim=D_V,
+                                 head_dim=D_PAD)[which]
+    out, grads, _, g, _ = run_with_grads(build, feeds, ["q", "kv", "k_r"])
+    args = [jnp.asarray(feeds[n]) for n in ("q", "kv", "k_r")]
+    close(out, unrotated(*args)[which])
+    want = jax.grad(lambda *a: jnp.sum(unrotated(*a)[which] * g),
+                    (0, 1, 2))(*args)
+    for got, ref in zip(grads, want):
+        close(got, ref)
+    assert out.shape == (B, HEADS, S, D_V if which == 2 else D_PAD)
+
+
+def test_latent_qkv_says_what_it_assembled_and_keeps_old_attrs_as_they_were():
+    import lowering_reports
+    import paddle_tpu as fluid
+    main = fluid.Program()
+    with fluid.program_guard(main, fluid.Program()):
+        A = dict(append_batch_size=False)
+        q = fluid.data("q", [B * S, HEADS * D], "float32", **A)
+        k_r = fluid.data("k_r", [B * S, D_R], "float32", **A)
+        layers.latent_qkv(q, fluid.data("kv", [B * S, HEADS * (D_N + D)],
+                                        "float32", **A), k_r, B, S, HEADS,
+                          D_N, D_R, theta=1e6, value_dim=D, head_dim=D)
+        layers.latent_qkv(q, fluid.data("kv2", [B * S, HEADS * (D_N + D_V)],
+                                        "float32", **A), k_r, B, S, HEADS,
+                          D_N, D_R, rotate=False, value_dim=D_V,
+                          head_dim=D_PAD)
+    old, new = [op for op in main.global_block().ops
+                if op.type == "latent_qkv"]
+    assert sorted(old.attrs) == sorted(ATTRS)       # the defaults add none
+    assert (new.attr("rotate"), new.attr("value_dim"),
+            new.attr("head_dim")) == (False, D_V, D_PAD)
+    for salt, (attrs, ins) in enumerate((
+            (ATTRS, _op_inputs()),
+            (dict(ATTRS, rotate=False, value_dim=D_V, head_dim=D_PAD),
+             dict(_op_inputs(), KV=[jnp.zeros((B * S, HEADS * (D_N + D_V)))])))):
+        registry.get("latent_qkv").lower(registry.LowerCtx(
+            dict(attrs), salt=salt + 1, program=main), ins)
+    assert lowering_reports.read(
+        lowering_reports.publish(main), "latent_qkv_lowering_total",
+        "rotated", "head_dim", "value_dim") == {
+            ("1", str(D), str(D)): 1, ("0", str(D_PAD), str(D_V)): 1}
 
 
 # -- the model ---------------------------------------------------------------
@@ -481,10 +551,8 @@ def test_deepseek_style_keys_read_as_the_repos_own():
 
 
 @pytest.mark.parametrize("change,error,match", [
-    ({"q_lora_rank": None}, NotImplementedError, "q_lora_rank: null"),
     ({"rope_scaling": {"type": "yarn", "factor": 40, "mscale": 1.0}},
      NotImplementedError, "rope_scaling inside latent attention"),
-    ({"v_head_dim": 12}, NotImplementedError, "v_head_dim other than"),
     ({"partial_rotary_factor": 0.5}, NotImplementedError,
      "partial_rotary_factor other than 1 inside latent"),
     ({"layer_types": ["full_attention", "sliding_attention",
@@ -501,6 +569,18 @@ def test_deepseek_style_keys_read_as_the_repos_own():
 def test_what_the_builder_does_not_build_raises_by_name(change, error, match):
     with pytest.raises(error, match=match):
         decoder_lm._check(dict(MODEL, **change))
+
+
+@pytest.mark.parametrize("change", [{"q_lora_rank": None},
+                                    {"v_head_dim": 12}])
+def test_what_latent_attention_refused_until_pr_51_builds(change):
+    """``q_lora_rank: null`` (q from one projection) and a ``v_head_dim``
+    other than the q / k head's width went from ``_check_latent`` with Kimi
+    Linear's latent attention (tests/test_decoder_kimi_linear.py)."""
+    decoder_lm._check(dict(MODEL, **change))
+    params = built(dict(MODEL, **change))["params"]
+    assert ("layer0_attn_q_w" in params) == (change.get(
+        "q_lora_rank", 1) is None)
 
 
 def test_the_module_needs_its_second_label():

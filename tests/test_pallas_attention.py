@@ -649,3 +649,79 @@ def test_forced_pallas_rejects_bad_shapes():
             d.lower(ctx, {"Q": [q], "K": [q], "V": [q]})
         except ValueError as e:
             raise RuntimeError(str(e))
+
+
+# -- a value head of its own width (latent attention, v narrower than q / k) --
+
+@pytest.mark.parametrize("dtype", [jnp.float32, jnp.bfloat16])
+@pytest.mark.parametrize("S,block_q,block_k,D,Dv", [
+    (256, 128, 128, 192, 128), (512, 256, 128, 256, 128),
+    (512, 128, 512, 192, 128), (256, 256, 256, 64, 128)])
+def test_causal_flash_with_a_value_head_of_its_own_width(S, block_q, block_k,
+                                                         D, Dv, dtype):
+    """q / k heads of ``D`` beside v heads of ``Dv`` (Kimi Linear's latent
+    attention: 192 -- or 256 with 64 zero columns -- and 128): the kernels'
+    output and dq, dk (``D`` wide), dv (``Dv`` wide) against plain softmax
+    attention in float32, K in tiles and one tile a row."""
+    r = np.random.RandomState(0)
+    q, k = (jnp.asarray(r.randn(2, 2, S, D), dtype) for _ in range(2))
+    v, g = (jnp.asarray(r.randn(2, 2, S, Dv), dtype) for _ in range(2))
+    scale = 192 ** -0.5
+
+    def plain(q, k, v):
+        s = jnp.einsum("bhqd,bhkd->bhqk", q, k) * scale
+        s = jnp.where(jnp.tril(jnp.ones((S, S), bool)), s, -jnp.inf)
+        return jnp.einsum("bhqk,bhkd->bhqd", jax.nn.softmax(s, -1), v)
+
+    def both(attend, *xs):
+        out, vjp = jax.vjp(attend, *xs)
+        return [np.asarray(_f32(x)) for x in (out, *vjp(g.astype(out.dtype)))]
+    with jax.default_matmul_precision("highest"):
+        ref = both(plain, _f32(q), _f32(k), _f32(v))
+    got = both(lambda q, k, v: pa._flash(
+        q, k, v, None, jnp.int32(7), scale, 0.0, True, True, block_q,
+        block_k), q, k, v)
+    assert [x.shape[-1] for x in got] == [Dv, D, D, Dv]
+    for r_, x in zip(ref, got):
+        if dtype == jnp.float32:
+            np.testing.assert_allclose(x, r_, atol=5e-5, rtol=1e-4)
+        else:
+            atol = 2.0 ** -7 * (np.sqrt(S) * np.sqrt((r_ * r_).mean())
+                                + np.abs(r_).max())
+            np.testing.assert_allclose(x, r_, atol=atol, rtol=0)
+    # the composed lowering takes the same shapes
+    close = pa.composed_attention(_f32(q), _f32(k), _f32(v), None, scale, 0.0,
+                                  True, None)
+    np.testing.assert_allclose(np.asarray(close), ref[0], atol=1e-4, rtol=1e-4)
+
+
+def test_the_attention_op_counts_a_value_width_of_its_own():
+    """Through the executor: ``fused_attention`` given v narrower than q / k
+    returns ``[B, heads, S, Dv]``, trains, and its lowering carries
+    ``value_dim`` (0 where v is as wide as q); a k that is not q's width is
+    refused."""
+    from paddle_tpu.observability.metrics import REGISTRY
+    from test_decoder_ops import run_with_grads
+
+    def count(**want):
+        family = REGISTRY.get("attention_lowering_total")
+        return sum(c.value for labels, c in family.items()
+                   if set(want.items()) <= set(labels)) if family else 0
+    r = np.random.RandomState(1)
+    feeds = {"q": r.randn(1, 2, 128, 24).astype("float32"),
+             "k": r.randn(1, 2, 128, 24).astype("float32"),
+             "v": r.randn(1, 2, 128, 8).astype("float32")}
+    before = count(value_dim="8"), count(value_dim="0")
+    out, grads, _, _, _ = run_with_grads(
+        lambda q, k, v: fluid.layers.fused_attention(q, k, v, causal=True,
+                                               scale=0.25), feeds,
+        ["q", "k", "v"])
+    assert out.shape == (1, 2, 128, 8)
+    assert [g.shape for g in grads] == [feeds[n].shape for n in "qkv"]
+    run_with_grads(lambda q, k, v: fluid.layers.fused_attention(
+        q, k, q, causal=True), feeds, [])
+    assert (count(value_dim="8") - before[0],
+            count(value_dim="0") - before[1]) == (1, 1)
+    with pytest.raises(Exception, match="against k heads"):
+        run_with_grads(lambda q, k, v: fluid.layers.fused_attention(
+            q, v, v, causal=True), feeds, [])

@@ -549,6 +549,78 @@ def test_gated_norm_kernels_compile_for_v5e(one_chip, wide):
             assert " copy(" not in text and " reshape(" not in text
 
 
+@pytest.mark.parametrize("chunk", [64, 128])
+def test_channel_decay_kernels_compile_for_v5e(one_chip, chunk):
+    """Kimi Delta Attention's rule at the Kimi Linear cell's shape (2 x 4,096
+    tokens, 32 heads of 128, q | k | v packed as the conv writes them, G a
+    key channel in float32): one kernel forward (which writes the states),
+    one backward on them; the sub-blocks' static slices, the pairwise
+    passes' broadcasts and the 128 x 128 state in VMEM are Mosaic's to
+    refuse, which the interpreter cannot say."""
+    from paddle_tpu.ops import pallas_delta
+    B, S, n = 2, 4096, 32
+
+    def arg(shape, dtype):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
+    qkv = arg((B, S, 3 * n * 128), jnp.bfloat16)
+    g, beta = arg((B, S, n, 128), jnp.float32), arg((B, S, n), jnp.float32)
+    assert pallas_delta.supports(S, n, n, 128, 128, chunk, channel=True)
+    forward = jax.jit(lambda a, b, c: pallas_delta._fwd_call(
+        a, b, c, chunk, False)).lower(qkv, g, beta).compile()
+    assert _kernels(forward) == 1
+    states = arg((B, S // chunk, n, 128, 128), jnp.float32)
+    do = arg((B, S, n * 128), jnp.bfloat16)
+    backward = jax.jit(lambda a, b, c, d, e: pallas_delta._bwd_call(
+        a, b, c, d, e, chunk, False)).lower(qkv, g, beta, states,
+                                            do).compile()
+    assert _kernels(backward) == 1
+    text = backward.as_text()
+    # dG leaves as [B, S, heads * 128] float32, a head a lane tile, and is
+    # reshaped, not relaid
+    assert f"f32[{B},{S},{n * 128}]" in text
+
+
+@pytest.mark.parametrize("d", [192, 256])
+def test_flash_kernels_with_a_narrow_value_head_compile_for_v5e(one_chip, d):
+    """The Kimi Linear cell's latent attention: 32 heads, q / k 192 wide as
+    published or 256 with 64 zero columns, v and the output 128, two
+    sequences of 4,096, CAUSAL_BLOCKS' tiles: forward and backward."""
+    B, H, S, dv = 2, 32, 4096, 128
+
+    def arg(width):
+        return jax.ShapeDtypeStruct((B, H, S, width), jnp.bfloat16,
+                                    sharding=one_chip)
+    q, v = arg(d), arg(dv)
+    blocks = pa._blocks(S, True)
+    forward = jax.jit(lambda q, k, v: pa._fwd_call(
+        q, k, v, None, jnp.int32(3), 192 ** -0.5, 0.0, True, False,
+        *blocks)).lower(q, q, v).compile()
+    assert _kernels(forward) == 1
+    assert f"bf16[{B},{H},{S},{dv}]" in forward.as_text()
+    backward = jax.jit(lambda q, k, v, g, lse: pa._bwd_call(
+        q, k, v, None, jnp.int32(3), g, lse, 192 ** -0.5, 0.0, True, False,
+        *blocks)).lower(q, q, v, v, _lse(q, one_chip)).compile()
+    assert _kernels(backward) == 1
+
+
+def test_sigmoid_gated_norm_kernels_compile_for_v5e(one_chip):
+    """The gated norm under ``activation="sigmoid"`` at the KDA mixer's
+    shape: the silu kernels' plan, one kernel each way."""
+    from paddle_tpu.ops import pallas_norm
+    T, heads, dim = 8192, 32, 128
+
+    def arg(shape, dtype=jnp.bfloat16):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
+    x, z, w = arg((T, heads, dim)), arg((T, heads * dim)), arg(
+        (dim,), jnp.float32)
+    for fn, args in (
+            (lambda x, z, w: pallas_norm._fwd_call(x, z, w, 1e-5, False,
+                                                   "sigmoid"), (x, z, w)),
+            (lambda x, z, w, dy: pallas_norm._bwd_call(
+                x, z, w, dy, 1e-5, False, "sigmoid"), (x, z, w, x))):
+        assert _kernels(jax.jit(fn).lower(*args).compile()) == 1
+
+
 def _captured_step(main, feed, fetch, scope):
     """The jitted train step of ``main`` and its arguments, taken from the
     executor where it would compile them."""

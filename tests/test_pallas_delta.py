@@ -395,3 +395,166 @@ def test_one_value_block_short_of_a_whole_offset_falls_back_to_three_operands():
     for name, got, ref in zip(NAMES, grads, want_grads):
         np.testing.assert_allclose(
             got, ref, rtol=0, atol=1e-4 * np.abs(ref).max(), err_msg=name)
+
+
+# -- a decay a key channel (Kimi Delta Attention) ------------------------------
+
+def channel_inputs(batch, seq, heads, d, seed=0, steep=False):
+    """``rule_inputs`` with ``g [batch, seq, heads, d]``: a memory of one to
+    a thousand positions a channel, or (``steep``) a fall of 0.5 to 3 a
+    position and channel, under which ``exp(-G)`` overflows float32 within
+    30 to 180 positions of a chunk."""
+    r = rng(seed)
+    feeds = rule_inputs(batch, seq, heads, heads, d, d, seed)
+    feeds["g"] = (-r.uniform(0.5, 3.0, (batch, seq, heads, d)) if steep else
+                  -np.exp(r.uniform(np.log(1e-3), np.log(1.6),
+                                    (batch, seq, heads, d)))).astype("float32")
+    return feeds
+
+
+def channel_recurrence(feeds, g=None):
+    from benchmark.references import kimi_linear_pretrain as kimi
+    with jax.default_matmul_precision("highest"):
+        args = tuple(jnp.asarray(feeds[k]) for k in NAMES)
+        want = kimi.delta_rule(*args)
+        if g is None:
+            return want
+        return want, jax.grad(
+            lambda *v: jnp.sum(kimi.delta_rule(*v) * g),
+            tuple(range(5)))(*args)
+
+
+@pytest.mark.parametrize("form", ["split", "packed"])
+@pytest.mark.parametrize("impl,batch,seq,heads,d,chunk", [
+    ("composed", 1, 16, 2, 8, 8), ("composed", 2, 32, 1, 8, 16),
+    ("composed", 1, 64, 2, 16, 32), ("auto", 2, 16, 2, 8, 4),
+    ("pallas", 1, 128, 2, 128, 64), ("pallas", 2, 256, 1, 128, 128),
+    ("auto", 1, 192, 2, 128, 64)])
+def test_channel_decay_equals_the_recurrence_and_its_gradient(
+        impl, batch, seq, heads, d, chunk, form):
+    """``G [B, S, heads, d_k]``: the state's row c decays by ``exp(g[c])``.
+    The composed chunk form and the kernels (interpreter) against the
+    recurrence position by position of the Kimi Linear reference, the
+    output and the gradient of q, k, v, g (a channel each) and beta."""
+    feeds = channel_inputs(batch, seq, heads, d)
+    out, grads, _, g, _ = run_with_grads(rule_with(impl, chunk, form), feeds,
+                                         NAMES)
+    want, want_grads = channel_recurrence(feeds, g)
+    close(out, want, 1e-4)
+    assert grads[3].shape == feeds["g"].shape
+    for name, got, ref in zip(NAMES, grads, want_grads):
+        np.testing.assert_allclose(
+            got, ref, rtol=0, atol=1e-4 * np.abs(ref).max(), err_msg=name)
+
+
+@pytest.mark.parametrize("impl,d,form", [
+    ("composed", 16, "split"), ("pallas", 128, "split"),
+    ("pallas", 128, "packed")])
+def test_channel_decay_chunks_of_64_and_128_agree(impl, d, form):
+    feeds = channel_inputs(1, 256, 2, d, seed=2)
+    a, b = (run_with_grads(rule_with(impl, c, form), feeds, [])[0]
+            for c in (64, 128))
+    close(a, b, 5e-6)
+    close(a, channel_recurrence(feeds), 1e-4)
+
+
+@pytest.mark.parametrize("impl,d,chunk", [
+    ("composed", 16, 64), ("pallas", 128, 64), ("pallas", 128, 128)])
+def test_decays_that_would_overflow_inside_a_chunk_stay_finite(impl, d, chunk):
+    """A fall of up to 3 a position: over a chunk the running sum reaches
+    -110 to -250, ``exp(-G)`` is past float32 (3e38 = exp(88.7)) within 30
+    to 60 positions, and ``(k exp(G)) (k exp(-G))^T`` is ``0 x inf``. No exponent
+    of a positive number is taken, so the output and every gradient are
+    finite and equal the recurrence's."""
+    feeds = channel_inputs(1, 256, 2, d, seed=3, steep=True)
+    sums = np.cumsum(feeds["g"].reshape(1, -1, chunk, 2, d), axis=2)
+    assert sums.min() < -100                # exp(-G) = inf
+    out, grads, _, g, _ = run_with_grads(rule_with(impl, chunk), feeds, NAMES)
+    assert np.isfinite(out).all() and all(
+        np.isfinite(x).all() for x in grads)
+    want, want_grads = channel_recurrence(feeds, g)
+    close(out, want, 1e-4)
+    for name, got, ref in zip(NAMES, grads, want_grads):
+        np.testing.assert_allclose(
+            got, ref, rtol=0, atol=1e-4 * np.abs(ref).max(), err_msg=name)
+
+
+@pytest.mark.parametrize("impl,d,chunk", [
+    ("composed", 8, 8), ("pallas", 128, 64)])
+def test_a_decay_constant_over_the_channels_is_the_scalar_rule(impl, d, chunk):
+    """With ``g[c]`` the same for every channel the channel form is the
+    Gated DeltaNet rule (one op, the rank of G deciding), and the gradient
+    of the scalar is the sum of the channels' gradients."""
+    feeds = rule_inputs(1, 128, 2, 2, d, d, seed=4)
+    wide = dict(feeds, g=np.repeat(feeds["g"][..., None], d, -1))
+    a, ga, _, _, _ = run_with_grads(rule_with(impl, chunk), feeds, NAMES)
+    b, gb, _, _, _ = run_with_grads(rule_with(impl, chunk), wide, NAMES)
+    close(a, b, 2e-5)
+    close(a, recurrence(feeds), 1e-4)
+    for i, name in enumerate(NAMES):
+        got = gb[i].sum(-1) if name == "g" else gb[i]
+        np.testing.assert_allclose(got, ga[i], rtol=0,
+                                   atol=2e-4 * np.abs(ga[i]).max(),
+                                   err_msg=name)
+
+
+def test_channel_decay_is_refused_and_counted_by_name():
+    feeds = channel_inputs(1, 16, 2, 8)
+    bad = dict(feeds, g=feeds["g"][..., :4])
+    with pytest.raises(Exception, match="a decay a key channel"):
+        run_with_grads(rule_with("composed", 8), bad, [])
+    two = rule_inputs(1, 16, 1, 2, 8, 8)            # two value heads a key head
+    two["g"] = np.repeat(two["g"][..., None], 8, -1)
+    with pytest.raises(Exception, match="one value head a key"):
+        run_with_grads(rule_with("composed", 8), two, [])
+    with pytest.raises(Exception, match="power of two"):
+        run_with_grads(rule_with("composed", 12),
+                       channel_inputs(1, 24, 1, 8), [])
+    assert pallas_delta.supports(4096, 32, 32, 128, 128, 64, channel=True)
+    assert not pallas_delta.supports(4096, 16, 32, 128, 128, 64, channel=True)
+    assert pallas_delta.packs(32, 32)       # v starts 64 tiles in: whole
+    kinds = [dict(impl="pallas", decay="channel", operands="packed"),
+             dict(impl="composed", decay="channel"),
+             dict(impl="pallas", decay="head")]
+    before = [lowerings(**k) for k in kinds]
+    big = channel_inputs(1, 128, 2, 128, seed=6)
+    run_with_grads(rule_with("auto", 64, "packed"), big, ["g"])
+    run_with_grads(rule_with("composed", 8), feeds, [])
+    run_with_grads(rule_with("auto", 64),
+                   rule_inputs(1, 128, 1, 2, 128, 128, seed=6), [])
+    assert [lowerings(**k) - b for k, b in zip(kinds, before)] == [1, 1, 1]
+    # a report without the label (an older reader's) is kept as decay=head
+    main = fluid.Program()
+    LowerCtx({}, salt=1, program=main).report(
+        "delta_lowering_total", impl="pallas", chunk=64, heads=32,
+        key_dim=128, value_dim=128, operands="packed")
+    assert lowering_reports.read(
+        lowering_reports.publish(main), "delta_lowering_total", "decay") == {
+            "head": 1}
+
+
+@pytest.mark.parametrize("impl,d,chunk", [
+    ("composed", 16, 64), ("pallas", 128, 64), ("pallas", 128, 128)])
+def test_keys_that_resemble_their_neighbours_keep_the_inverse_sound(impl, d,
+                                                                    chunk):
+    """What a KDA layer behind another's gated norm reads (chip, PR 51):
+    keys nearly alike from one position to the next (cosine 0.99), steps
+    near 1 and a decay that hardly applies, so ``M`` holds about 0.9 over
+    the whole chunk. ``(I + M)^-1`` is tame (its entries fall off by a
+    tenth a position), but the doubling ``(I - M)(I + M^2)(I + M^4)...``
+    forms powers whose entries pass 1e30 at a chunk of 128 and loses every
+    digit; forward substitution over sub-blocks holds 3e-4 in float32."""
+    r = rng(8)
+    feeds = channel_inputs(1, 256, 2, d, seed=8)
+    base = r.randn(1, 1, 2, d)
+    feeds["k"] = (base + 0.1 * r.randn(1, 256, 2, d)).astype("float32")
+    feeds["q"] = (base + 0.5 * r.randn(1, 256, 2, d)).astype("float32")
+    feeds["beta"] = np.full((1, 256, 2), 0.9, "float32")
+    feeds["g"] = np.full((1, 256, 2, d), -1e-3, "float32")
+    out, grads, _, g, _ = run_with_grads(rule_with(impl, chunk), feeds, NAMES)
+    want, want_grads = channel_recurrence(feeds, g)
+    assert np.isfinite(out).all()
+    close(out, want, 3e-4)
+    for name, got, ref in zip(NAMES, grads, want_grads):
+        np.testing.assert_allclose(
+            got, ref, rtol=0, atol=3e-4 * np.abs(ref).max(), err_msg=name)

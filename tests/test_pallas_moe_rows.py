@@ -186,14 +186,16 @@ def test_kernel_ops_metric_counts_three_an_expert_layer():
     bench = json.load(open(os.path.join(root, "BENCHMARK.json")))
     spec = json.load(open(os.path.join(
         root, "benchmark", "layer_metrics", "moe_rows.kernel_ops.json")))
-    entry = bench["per_layer"][-1]
+    entry = next(m for m in bench["per_layer"]
+                 if m["name"] == "moe_rows.kernel_ops")
     for key in ("name", "unit", "better", "source", "layer", "moves"):
         assert spec[key] == entry[key], key
     assert entry["name"] == "moe_rows.kernel_ops" and entry["layer"] == "moe"
     cells = [w["name"] for w in bench["workloads"] if w["config"] in (
         "olmoe_1b_7b", "lfm2_8b_a1b", "laguna_s_2_1", "qwen3_next_80b_a3b",
         "glm_4_7_flash")]
-    assert entry["workloads"] == cells and len(cells) == 5
+    # the five cells PR 50 gave it, then every expert cell added since
+    assert entry["workloads"][:5] == cells and len(cells) == 5
     for name in cells:
         assert "moe_rows.kernel_ops" in [
             m["name"] for m in run.load_cell(name, False)["per_layer"]]

@@ -243,7 +243,8 @@ def test_an_ungated_rms_norm_is_what_it_was_and_reports_nothing():
 
 def test_gated_kernel_ops_metric_reads_nine_on_the_cells_counters():
     """``norm.gated_kernel_ops`` (a data file on ``registry_count``) agrees
-    with its ``BENCHMARK.json`` entry, is the Qwen3-Next cell's alone, and
+    with its ``BENCHMARK.json`` entry, is the cells' with a gated norm
+    (Qwen3-Next's first; Kimi Linear's since PR 51), and
     over the counters the cell's two compiled programs add on the chip
     (three forward ops in the check's test clone, three forward and three
     backward in the train step) reads 9; composed ops are left out, and a
@@ -262,7 +263,7 @@ def test_gated_kernel_ops_metric_reads_nine_on_the_cells_counters():
                  if m["name"] == "norm.gated_kernel_ops")
     for key in ("name", "unit", "better", "source", "layer", "moves"):
         assert spec[key] == entry[key], key
-    assert entry["workloads"] == [cell_name]
+    assert entry["workloads"][0] == cell_name
     assert entry["layer"] == "dense_ops" and entry["moves"] == "tokens_per_s"
     assert spec["labels"] == {"impl": "pallas"}
     cell = run.load_cell(cell_name, rehearsal=False)
@@ -284,3 +285,80 @@ def test_gated_kernel_ops_metric_reads_nine_on_the_cells_counters():
         lowerings.publish(notes, program)
     assert reduce(spec, None) - before == 9.0
     assert reduce(dict(spec, match="pr49_no_such_counter"), None) is None
+
+
+# -- the sigmoid gate (Kimi Delta Attention's output norm) ---------------------
+
+def sigmoid_form(x, z, w):
+    unit = x * jax.lax.rsqrt(jnp.mean(x * x, -1, keepdims=True) + EPS)
+    return unit * w * jax.nn.sigmoid(z.reshape(x.shape))
+
+
+@pytest.mark.parametrize("wide", [True, False], ids=["wide", "as_x"])
+@pytest.mark.parametrize("dtype", ["bfloat16", "float32"])
+@pytest.mark.parametrize("impl", ["pallas", "composed"])
+def test_the_sigmoid_gate_equals_its_closed_form_and_gradients(impl, dtype,
+                                                               wide):
+    """``gate_activation="sigmoid"``: ``rmsnorm(x) * scale * sigmoid(z)``,
+    the norm first, then the gate (HF ``FusedRMSNormGated(activation=
+    'sigmoid')``); both lowerings, forward and the three gradients of the
+    registered grad op, to the silu form's tolerances."""
+    x, z, w = operands(dtype, 4, 128, wide)
+
+    def build(xv, zv):
+        return layers.rms_norm(
+            xv, EPS, fluid.ParamAttr(
+                name="w", initializer=NumpyArrayInitializer(w)),
+            gate=zv, impl=impl, gate_activation="sigmoid")
+    out, grads, _, g, _ = run_with_grads(build, {"x": x, "z": z},
+                                         ["x", "z", "w"])
+    assert str(out.dtype) == dtype and out.shape == x.shape
+    f = [jnp.asarray(a, jnp.float32) for a in (x, z, w)]
+    tol, grad_tol = (1e-4, 1e-4) if dtype == "float32" else (2.0 ** -8,
+                                                             2.0 ** -7)
+    want = sigmoid_form(*f)
+    np.testing.assert_allclose(np.asarray(out, np.float32), want, rtol=0,
+                               atol=tol * np.abs(want).max())
+    # not the silu form's: the two differ by the factor z
+    assert np.abs(np.asarray(out, np.float32) - form(*f, False)).max() > \
+        0.1 * np.abs(want).max()
+    want_grads = jax.grad(lambda *v: jnp.sum(sigmoid_form(*v) * g),
+                          (0, 1, 2))(*f)
+    for name, got, ref in zip("xzw", grads, want_grads):
+        np.testing.assert_allclose(
+            np.asarray(got, np.float32), ref, rtol=0,
+            atol=grad_tol * np.abs(ref).max(), err_msg=name)
+
+
+def test_the_gates_activation_is_an_attr_only_where_it_is_not_silu():
+    """The default lowers and counts what it did: no ``gate_activation``
+    attr on a silu-gated op (a Qwen3-Next Program is its parent's), the
+    label ``activation`` on every report, and a report without it (an older
+    reader's) kept as silu; anything but silu or sigmoid is refused."""
+    main = fluid.Program()
+    with fluid.program_guard(main, fluid.Program()):
+        A = dict(append_batch_size=False)
+        x = fluid.data("x", [32, 2, 128], "float32", **A)
+        z = fluid.data("z", [32, 256], "float32", **A)
+        layers.rms_norm(x, EPS, gate=z)
+        layers.rms_norm(x, EPS, gate=z, gate_activation="sigmoid")
+        with pytest.raises(ValueError, match="silu or sigmoid"):
+            layers.rms_norm(x, EPS, gate=z, gate_activation="tanh")
+    silu, sigmoid = [op for op in main.global_block().ops
+                     if op.type == "rms_norm"]
+    assert "gate_activation" not in silu.attrs
+    assert sigmoid.attr("gate_activation") == "sigmoid"
+    xs = jnp.ones((32, 2, 128))
+    for salt, op in enumerate((silu, sigmoid)):
+        registry.get("rms_norm").lower(
+            registry.LowerCtx(dict(op.attrs), salt=salt + 1, program=main),
+            {"X": [xs], "Gate": [jnp.ones((32, 256))],
+             "Scale": [jnp.ones((128,))]})
+    registry.LowerCtx({}, salt=9, program=main).report(
+        "rms_norm_gated_lowering_total", impl="pallas", direction="forward",
+        head_dim=128)
+    assert lowering_reports.read(
+        lowering_reports.publish(main), "rms_norm_gated_lowering_total",
+        "activation") == {"silu": 2, "sigmoid": 1}
+    with pytest.raises(ValueError, match="gate_activation"):
+        pallas_norm.forward(xs, xs, jnp.ones((128,)), EPS, "tanh")

@@ -34,7 +34,8 @@ def test_packed_ops_resolves_and_reads_six_on_the_cells_counters():
         "gated_delta.packed_ops"]
     for key in ("name", "unit", "better", "source", "layer", "moves"):
         assert spec[key] == entry[key], key
-    assert entry["workloads"] == [CELL] and entry["moves"] == "tokens_per_s"
+    # Qwen3-Next's first; a later cell with a packed rule follows it
+    assert entry["workloads"][0] == CELL and entry["moves"] == "tokens_per_s"
     assert spec["layer"] == spec_of("gated_delta.pallas_ops")["layer"]
     assert spec["labels"] == {"impl": "pallas", "operands": "packed"}
     cell = run.load_cell(CELL, rehearsal=False)
