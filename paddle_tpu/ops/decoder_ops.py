@@ -983,26 +983,26 @@ def composed_channel_delta_rule(qn, kn, v, cum, beta, chunk):
     S, heads, d_k]``; one value head a key head): ``pallas_delta.
     channel_chunk``, the kernels' own arithmetic a chunk, mapped over batch
     and head and scanned over the chunks. No exponent of a positive number
-    is taken (sub-blocks of ``pallas_delta.SUB`` positions), so the result
-    stays finite however steep the decay; the pairs of a sub-block exist as
-    ``[B, heads, C, d_k]`` arrays, a pass a column."""
+    is taken (the decayed ``[C, C]`` blocks are built by halving, ``log2 C``
+    levels of one ``exp`` pass over ``[C, d_k]`` and one product each), so
+    the result stays finite however steep the decay, and nothing wider than
+    a chunk's ``[C, d_k]`` and ``[C, C]`` arrays exists a batch and head."""
     import jax
     import jax.numpy as jnp
     from . import pallas_delta
     f32 = jnp.float32
     batch, seq, heads, dv = v.shape
     dk, c = qn.shape[-1], seq // chunk
-    sub = min(pallas_delta.SUB, chunk)
-    if chunk & (chunk - 1) or chunk % sub:
+    if chunk & (chunk - 1):
         raise ValueError(
             f"gated_delta_rule: under a decay a key channel the chunk must "
-            f"be a power of two (the inverse's doubling); got {chunk}")
+            f"be a power of two (the halving's levels, the inverse's "
+            f"merges); got {chunk}")
 
     def chunks_first(x):                # [B, S, h, ...] -> [c, B, h, C, ...]
         x = x.reshape(batch, c, chunk, *x.shape[2:])
         return jnp.moveaxis(jnp.moveaxis(x, 3, 2), 1, 0)
-    a_chunk = jax.vmap(jax.vmap(functools.partial(
-        pallas_delta.channel_chunk, sub=sub)))
+    a_chunk = jax.vmap(jax.vmap(pallas_delta.channel_chunk))
 
     def one(s, inp):
         o, s_next = a_chunk(*inp, s)

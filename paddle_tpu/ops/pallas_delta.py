@@ -72,13 +72,19 @@ decay inside the contraction over the channels:
 
 ``(k * exp(G)) (k * exp(-G))^T`` would overflow float32 (``exp(-G)`` passes
 3e38 once a channel has fallen by 88 inside a chunk), so no exponent is ever
-taken of a positive number: the chunk is cut into sub-blocks of ``SUB``
-positions; a sub-block's rows against the sub-blocks before it are one
-product of ``x * exp(G - G_ref)`` with ``k * exp(G_ref - G)``, ``G_ref`` the
-sub-block's first row (both exponents <= 0; a factor that underflows has a
-product that underflows); a sub-block against itself is formed pair by
-pair, ``SUB`` passes of ``exp(min(G_i - G_j, 0))`` over the ``[C, d_k]``
-tile, each giving one column of every diagonal block. ``T`` comes block by
+taken of a positive number: the two blocks are built by halving. For ``h =
+1, 2, 4, .., C / 2`` the chunk is cut into pairs of neighbouring blocks of
+``h`` rows, and the lower block's first row ``r`` is its pair's reference:
+for i in the lower block and j in the upper one ``G_i - G_j = (G_i - G_r) +
+(G_r - G_j)``, both terms <= 0 (a factor that underflows has a product that
+underflows). A level is one ``exp`` pass over the ``[C, d_k]`` tile, ``E =
+exp(-|G - G_r|)`` (every row lies in exactly one lower or upper block), and
+one product ``[k * E ; q * E] (k * E)^T`` on the MXU, kept where i lies in a
+pair's lower block and j in its upper one: the levels' regions are disjoint
+and tile the strict lower triangle (the level of a pair i > j is the highest
+bit of ``i ^ j``); from blocks of ``PACKED_FROM`` rows the left operand holds
+the lower blocks' rows alone, half the MXU's passes. ``P``'s diagonal has no
+decay. ``T`` comes block by
 block, 8-row blocks merged pair by pair (``_inverse_blocked`` says why not
 by the doubling over the whole chunk). These are ``_intra``
 and its transpose ``_intra_bwd``: plain functions of a chunk's values, which
@@ -89,9 +95,12 @@ specs, the packed operand and the states' layout with the scalar pair, and
 read ``G`` and write ``dG`` as ``[B, S, heads * 128]`` float32, a head a
 lane tile. In the backward every product that holds a decay is
 differentiated as the rounded operand the forward's product read (``dG +=
-L * dL`` for ``L = (x * exp(..)).astype(bf)``), so what cancels pair by pair
-in the running sum that turns ``dG`` into ``dg`` is a sum of the same
-products on both sides.
+lo * dlo`` for ``lo = (x * E).astype(bf)`` on a pair's lower rows, ``dG -=
+up * dup`` on its upper ones), so a pair's reference row takes nothing for
+being the reference -- ``G_r`` cancels inside every product of its pair:
+channel by channel the lower rows' ``lo * dlo`` and the upper rows' ``up *
+dup`` are the same sums of the same rounded arrays -- and so does what
+cancels pair by pair in the running sum that turns ``dG`` into ``dg``.
 """
 from __future__ import annotations
 
@@ -103,8 +112,6 @@ from .pallas_ssd import _nn, _nt, _params, _pl, _tn
 
 HEAD_DIM = 128          # key and value head size: one 128-lane tile
 CHUNKS = (64, 128)      # chunk lengths the kernels take
-SUB = 16                # sub-block of a chunk under a channel decay: one
-#                         packed bfloat16 vreg of rows
 QUERY_SCALE = HEAD_DIM ** -0.5
 
 
@@ -307,46 +314,72 @@ def _bwd_kernel(rep, q_ref, k_ref, v_ref, do_ref, g_ref, b_ref, st_ref,
 
 # -- a decay a key channel ---------------------------------------------------
 
-def _pick(x, jj, sub):
-    """Each sub-block's row ``jj`` of ``x [C, d]`` over that sub-block's
-    rows."""
+PACKED_FROM = 8         # block rows from which a level's products take the
+#                         lower blocks' rows alone: whole float32 sublane tiles
+
+
+def _reference(g, h):
+    """Each pair's reference row on every row of the pair: ``g [C, d]`` cut
+    into pairs of neighbouring blocks of ``h`` rows, the lower block's first
+    row over both blocks."""
     import jax.numpy as jnp
-    return jnp.concatenate([
-        jnp.broadcast_to(x[b + jj:b + jj + 1], (sub, x.shape[1]))
-        for b in range(0, x.shape[0], sub)], axis=0)
+    c, d = g.shape
+    pairs = g.reshape(c // (2 * h), 2 * h, d)
+    return jnp.broadcast_to(pairs[:, h:h + 1], pairs.shape).reshape(c, d)
 
 
-def _block_sums(x, jj, sub):
-    """Each sub-block's sum over its rows of ``x [C, d]``, at the
-    sub-block's row ``jj``; zero elsewhere."""
+def _lower_rows(x, h):
+    """The pairs' lower blocks of ``x [C, n]``, one after another ``[C / 2,
+    n]``: the only rows of a level's left operand that its product keeps, so
+    the MXU is given half the rows. From ``PACKED_FROM`` rows a block: under
+    that a block is part of a sublane tile, moving its rows is a relayout
+    that costs more than the passes saved (chip, PR 52), and ``x`` stays
+    whole."""
+    if h < PACKED_FROM:
+        return x
+    c, n = x.shape
+    return x.reshape(c // (2 * h), 2 * h, n)[:, h:].reshape(c // 2, n)
+
+
+def _at_lower_rows(y, h):
+    """``_lower_rows``' transpose: ``y``'s rows back at the pairs' lower
+    blocks, zero at the upper ones."""
+    import jax.numpy as jnp
+    if h < PACKED_FROM:
+        return y
+    half, n = y.shape
+    y = y.reshape(half // h, h, n)
+    return jnp.concatenate([jnp.zeros_like(y), y], axis=1).reshape(2 * half, n)
+
+
+def _level(qn, kn, g, h, bf):
+    """Level ``h`` of the halving: ``E = exp(-|G - G_ref|)`` (a pair's lower
+    rows lie under its reference row and its upper rows above it, so
+    ``-|.|`` is ``G - G_ref`` on the one and ``G_ref - G`` on the other, and
+    never positive); the product's right operand ``up = kn * E [C, d]``, of
+    which it keeps the upper blocks' rows; its left operand ``lo``:
+    ``_lower_rows`` of ``kn * E`` over those of ``qn * E``. Both rounded to
+    the products' dtype."""
+    import jax.numpy as jnp
+    e = jnp.exp(-jnp.abs(g - _reference(g, h)))
+    ke = kn * e
+    lo = jnp.concatenate([_lower_rows(ke, h),
+                          _lower_rows(qn, h) * _lower_rows(e, h)], axis=0)
+    return e, ke.astype(bf), lo.astype(bf)
+
+
+def _levels(c):
+    """The halving's block lengths, ``1, 2, 4, .., c / 2``."""
+    return [1 << n for n in range(c.bit_length() - 1)]
+
+
+def _apart(c):
+    """``i ^ j`` over ``[c, c]``: for i > j its highest bit is the one level
+    whose pair has i in its lower block and j in its upper one."""
     import jax
     import jax.numpy as jnp
-    at = jax.lax.broadcasted_iota(jnp.int32, (sub, 1), 0) == jj
-    return jnp.concatenate([
-        jnp.where(at, jnp.sum(x[b:b + sub], axis=0, keepdims=True), 0.0)
-        for b in range(0, x.shape[0], sub)], axis=0)
-
-
-def _blocks_of(c, sub):
-    """Over ``[c, c]``: (row i's sub-block lies after column j's, the column
-    a row's own sub-block starts at)."""
-    import jax
-    import jax.numpy as jnp
-    rows = jax.lax.broadcasted_iota(jnp.int32, (c, c), 0)
-    cols = jax.lax.broadcasted_iota(jnp.int32, (c, c), 1)
-    return rows // sub > cols // sub, cols - rows // sub * sub
-
-
-def _strip(kn, g, a, sub, bf):
-    """Sub-block ``a``'s rows against every earlier position: (its rows, the
-    decay of its rows from its first, the growth of every position's key
-    back to that row -- ``min``: the positions from there on are masked out
-    of the product --, ``kn`` times that growth as the product's operand)."""
-    import jax.numpy as jnp
-    at = slice(a * sub, (a + 1) * sub)
-    ref = g[a * sub:a * sub + 1]
-    near, far = jnp.exp(g[at] - ref), jnp.exp(jnp.minimum(ref - g, 0.0))
-    return at, near, far, (kn * far).astype(bf)
+    return (jax.lax.broadcasted_iota(jnp.int32, (c, c), 0)
+            ^ jax.lax.broadcasted_iota(jnp.int32, (c, c), 1))
 
 
 INVERSE_BLOCK = 8       # rows of the blocks the blocked inverse starts from
@@ -396,72 +429,62 @@ def _inverse_blocked(m, eye, bf):
     return t
 
 
-def _intra(qn, kn, g, sub, bf):
+def _intra(qn, kn, g, bf):
     """``sum_c x_i[c] kn_j[c] exp(G_i[c] - G_j[c])`` over a chunk for ``x``
     = ``kn`` and ``qn`` (float32 values ``[C, d]``; ``g [C, d]`` the running
-    sums): two ``[C, C]`` float32 blocks, right at and under the diagonal
-    (above it the diagonal sub-blocks hold clamped values: the caller
-    masks)."""
+    sums): two ``[C, C]`` float32 blocks, right under the diagonal and, for
+    ``qn``, on it (no decay there); zero above. A level of the halving is
+    one product ``lo up^T`` (``_level``), valid where i lies in a pair's
+    lower block and j in its upper one: the levels' regions tile the strict
+    lower triangle, and a level writes over what the levels before it left
+    in the regions after its own."""
     import jax.numpy as jnp
     c = g.shape[0]
-    kk, qk = ([jnp.zeros((sub, c), jnp.float32)] for _ in range(2))
-    for a in range(1, c // sub):
-        at, near, _, right = _strip(kn, g, a, sub, bf)
-        kk.append(_nt((kn[at] * near).astype(bf), right))
-        qk.append(_nt((qn[at] * near).astype(bf), right))
-    before, offset = _blocks_of(c, sub)
-    kk = jnp.where(before, jnp.concatenate(kk, axis=0), 0.0)
-    qk = jnp.where(before, jnp.concatenate(qk, axis=0), 0.0)
-    for jj in range(sub):       # a sub-block against itself, a column a pass
-        w = _pick(kn, jj, sub) * jnp.exp(
-            jnp.minimum(g - _pick(g, jj, sub), 0.0))
-        here = offset == jj
-        kk = jnp.where(here, jnp.sum(kn * w, axis=1, keepdims=True), kk)
-        qk = jnp.where(here, jnp.sum(qn * w, axis=1, keepdims=True), qk)
-    return kk, qk
+    _, strict, diag = _masks(c)
+    apart = jnp.where(strict, _apart(c), 0)
+    kk = qk = jnp.zeros((c, c), jnp.float32)
+    for h in _levels(c):
+        _, up, lo = _level(qn, kn, g, h, bf)
+        both = _nt(lo, up)                              # k's rows, then q's
+        half = both.shape[0] // 2
+        here = apart >= h
+        kk = jnp.where(here, _at_lower_rows(both[:half], h), kk)
+        qk = jnp.where(here, _at_lower_rows(both[half:], h), qk)
+    on = jnp.sum(qn * kn, axis=1, keepdims=True)
+    return kk, jnp.where(diag, on, qk)
 
 
-def _intra_bwd(qn, kn, g, dkk, dqk, sub, bf):
+def _intra_bwd(qn, kn, g, dkk, dqk, bf):
     """``_intra``'s transpose: (dqn, dkn, dG) from the cotangents of its two
     blocks (masked by the caller: strictly under the diagonal, at and under
-    it). A product's operand that holds a decay is differentiated as the
-    rounded array the product read."""
+    it), a level at a time. A product's operand that holds a decay is
+    differentiated as the rounded array the product read; a pair's
+    reference row takes nothing for being the reference (``G_ref`` cancels
+    inside every product of its pair: channel by channel the lower rows'
+    ``lo * dlo`` and the upper rows' ``up * dup`` sum to the same)."""
     import jax.numpy as jnp
     f32 = jnp.float32
-    c, d = g.shape
-    before, offset = _blocks_of(c, sub)
-    dkk_far = jnp.where(before, dkk, 0.0).astype(bf)
-    dqk_far = jnp.where(before, dqk, 0.0).astype(bf)
-    dq, dk, dg = ([jnp.zeros((sub, d), f32)] for _ in range(3))
-    dk_right, dg_right = jnp.zeros((c, d), f32), jnp.zeros((c, d), f32)
-    for a in range(1, c // sub):
-        at, near, far, right = _strip(kn, g, a, sub, bf)
-        kl, ql = (kn[at] * near).astype(bf), (qn[at] * near).astype(bf)
-        dkl, dql = _nn(dkk_far[at], right), _nn(dqk_far[at], right)
-        dright = _tn(dkk_far[at], kl) + _tn(dqk_far[at], ql)
-        dk.append(dkl * near)
-        dq.append(dql * near)
-        dg.append(kl.astype(f32) * dkl + ql.astype(f32) * dql)
-        dk_right += dright * far
-        dg_right += right.astype(f32) * dright
-    dq = jnp.concatenate(dq, axis=0)
-    dk = jnp.concatenate(dk, axis=0) + dk_right
-    dg = jnp.concatenate(dg, axis=0) - dg_right
-    for jj in range(sub):
-        e = jnp.exp(jnp.minimum(g - _pick(g, jj, sub), 0.0))
-        w = _pick(kn, jj, sub) * e
-        here = offset == jj
-        ckk = jnp.sum(jnp.where(here, dkk, 0.0), axis=1, keepdims=True)
-        cqk = jnp.sum(jnp.where(here, dqk, 0.0), axis=1, keepdims=True)
-        u = ckk * kn + cqk * qn
-        pair = u * w                    # at row i; its sub-block's sum at j
-        dq += cqk * w
-        dk += ckk * w + _block_sums(u * e, jj, sub)
-        dg += pair - _block_sums(pair, jj, sub)
+    c = g.shape[0]
+    apart = _apart(c)
+    on = _column(dqk, _masks(c)[2])             # dqk's diagonal
+    dq, dk, dg = on * kn, on * qn, jnp.zeros(g.shape, f32)
+    for h in _levels(c):
+        e, up, lo = _level(qn, kn, g, h, bf)
+        here = (apart >> (h.bit_length() - 1)) == 1
+        d_both = jnp.concatenate(
+            [_lower_rows(jnp.where(here, dkk, 0.0), h),
+             _lower_rows(jnp.where(here, dqk, 0.0), h)], axis=0).astype(bf)
+        dlo, dup = _nn(d_both, up), _tn(d_both, lo)
+        half = dlo.shape[0] // 2
+        fell = lo.astype(f32) * dlo                     # k's rows, then q's
+        dk += (_at_lower_rows(dlo[:half], h) + dup) * e
+        dq += _at_lower_rows(dlo[half:], h) * e
+        dg += _at_lower_rows(fell[:half] + fell[half:], h) \
+            - up.astype(f32) * dup
     return dq, dk, dg
 
 
-def _channel_forward(qn, kn, v, g, bc, s, sub):
+def _channel_forward(qn, kn, v, g, bc, s):
     """One chunk of one head under a channel decay: ``qn`` / ``kn [C, d_k]``
     the unit operands in the products' dtype, ``v [C, d_v]``, ``g [C, d_k]``
     float32 running sums, ``bc [C, 1]`` beta, ``s [d_k, d_v]`` float32 the
@@ -472,7 +495,7 @@ def _channel_forward(qn, kn, v, g, bc, s, sub):
     c = g.shape[0]
     lower, strict, diag = _masks(c)
     qf, kf = qn.astype(f32), kn.astype(f32)
-    kk, qk = _intra(qf, kf, g, sub, bf)
+    kk, qk = _intra(qf, kf, g, bf)
     md = jnp.where(strict, kk, 0.0)
     p = jnp.where(lower, qk, 0.0)
     tb = _inverse_blocked(md * bc, diag.astype(f32), bf).astype(bf)
@@ -490,7 +513,7 @@ def _channel_forward(qn, kn, v, g, bc, s, sub):
                        z, vpb)
 
 
-def _channel_backward(qn, kn, v, g, bc, s, dsn, do, sub):
+def _channel_backward(qn, kn, v, g, bc, s, dsn, do):
     """The chunk's gradients given the state's gradient ``dsn`` leaving it
     and ``do [C, d_v]``: (dqn, dkn, dv, dG, dbeta ``[C, 1]``, the state's
     gradient entering), float32; dqn / dkn are the unit operands'."""
@@ -500,7 +523,7 @@ def _channel_backward(qn, kn, v, g, bc, s, dsn, do, sub):
     c = g.shape[0]
     lower, strict, _ = _masks(c)
     (qf, kf, md, p, tb, eg, fade, kg, qg, kend, e_end, sb, z,
-     vpb) = _channel_forward(qn, kn, v, g, bc, s, sub)[2]
+     vpb) = _channel_forward(qn, kn, v, g, bc, s)[2]
 
     def rows(x):
         return jnp.sum(x, axis=1, keepdims=True)
@@ -518,7 +541,7 @@ def _channel_backward(qn, kn, v, g, bc, s, dsn, do, sub):
     dzb = dz.astype(bf)
     dkg = -_nt(dzb, sb)
     ds = e_end * dsn + _tn(qg, dob) - _tn(kg, dzb)
-    dq, dk, dg = _intra_bwd(qf, kf, g, dm * bc, dp, sub, bf)
+    dq, dk, dg = _intra_bwd(qf, kf, g, dm * bc, dp, bf)
     f32 = jnp.float32
     moved = kend.astype(f32) * dkend            # leaves row i for row C
     at_last = jnp.sum(moved, axis=0, keepdims=True) + _row(
@@ -530,7 +553,7 @@ def _channel_backward(qn, kn, v, g, bc, s, dsn, do, sub):
             rows(dr * z) + rows(dm * md), ds)
 
 
-def _fwd_kernel_channel(sub, q_ref, k_ref, v_ref, g_ref, b_ref, o_ref, st_ref,
+def _fwd_kernel_channel(q_ref, k_ref, v_ref, g_ref, b_ref, o_ref, st_ref,
                         s_ref):
     import jax.numpy as jnp
     pl, _ = _pl()
@@ -546,12 +569,12 @@ def _fwd_kernel_channel(sub, q_ref, k_ref, v_ref, g_ref, b_ref, o_ref, st_ref,
     o, s_next, _ = _channel_forward(
         unit(q_ref[0].astype(f32), QUERY_SCALE).astype(bf),
         unit(k_ref[0].astype(f32)).astype(bf), v_ref[0], g_ref[0],
-        _column(b_ref[0, 0, 0], diag), s, sub)
+        _column(b_ref[0, 0, 0], diag), s)
     o_ref[0] = o.astype(o_ref.dtype)
     s_ref[0] = s_next
 
 
-def _bwd_kernel_channel(sub, q_ref, k_ref, v_ref, do_ref, g_ref, b_ref, st_ref,
+def _bwd_kernel_channel(q_ref, k_ref, v_ref, do_ref, g_ref, b_ref, st_ref,
                         dq_ref, dk_ref, dv_ref, dg_ref, db_ref, ds_ref):
     import jax
     import jax.numpy as jnp
@@ -568,7 +591,7 @@ def _bwd_kernel_channel(sub, q_ref, k_ref, v_ref, do_ref, g_ref, b_ref, st_ref,
     dqn, dkn, dv, dg, db, ds = _channel_backward(
         qu.astype(bf), ku.astype(bf), v_ref[0], g_ref[0],
         _column(b_ref[0, 0, 0], diag), st_ref[0, 0, 0], ds_ref[0],
-        do_ref[0], sub)
+        do_ref[0])
     ds_ref[0] = ds
     dq_ref[0] = q_back(dqn)[0].astype(dq_ref.dtype)
     dk_ref[0] = k_back(dkn)[0].astype(dk_ref.dtype)
@@ -577,11 +600,11 @@ def _bwd_kernel_channel(sub, q_ref, k_ref, v_ref, do_ref, g_ref, b_ref, st_ref,
     db_ref[0, 0, 0] = _row(db, diag)
 
 
-def channel_chunk(qn, kn, v, g, beta, s, sub=SUB):
+def channel_chunk(qn, kn, v, g, beta, s):
     """``_channel_forward``'s ``o`` and next state for the composed form
     (``ops/decoder_ops.py``), ``beta [C]``: the kernels' arithmetic as plain
     ``jax.numpy``, differentiable by JAX."""
-    o, s_next, _ = _channel_forward(qn, kn, v, g, beta[:, None], s, sub)
+    o, s_next, _ = _channel_forward(qn, kn, v, g, beta[:, None], s)
     return o, s_next
 
 
@@ -653,8 +676,8 @@ def _fwd_call(qkv, gcum, beta, chunk, interpret):
     rep, chunks = n_v // n_k, seq // chunk
     key, value, scalars, state = _specs(chunk, rep, lambda i: i)
     if gcum.ndim == 4:
-        kernel, decay, sums = (functools.partial(_fwd_kernel_channel, SUB),
-                               key(), gcum.reshape(batch, seq, -1))
+        kernel, decay, sums = (_fwd_kernel_channel, key(),
+                               gcum.reshape(batch, seq, -1))
     else:
         kernel, decay, sums = (functools.partial(_fwd_kernel, rep), scalars,
                                _by_head(gcum, n_k, chunk))
@@ -687,8 +710,8 @@ def _bwd_call(qkv, gcum, beta, states, do, chunk, interpret):
     keys = jax.ShapeDtypeStruct((batch, seq, n_k * HEAD_DIM), v.dtype)
     by_head = jax.ShapeDtypeStruct((batch, n_k, chunks, rep, chunk), f32)
     if gcum.ndim == 4:      # a decay a key channel: G and dG a head a tile
-        kernel, decay, gr = (functools.partial(_bwd_kernel_channel, SUB),
-                             key(), gcum.reshape(batch, seq, -1))
+        kernel, decay, gr = (_bwd_kernel_channel, key(),
+                             gcum.reshape(batch, seq, -1))
         dg_shape = jax.ShapeDtypeStruct(gr.shape, f32)
     else:
         kernel, decay, gr = (functools.partial(_bwd_kernel, rep), scalars,

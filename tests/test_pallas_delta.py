@@ -558,3 +558,83 @@ def test_keys_that_resemble_their_neighbours_keep_the_inverse_sound(impl, d,
     for name, got, ref in zip(NAMES, grads, want_grads):
         np.testing.assert_allclose(
             got, ref, rtol=0, atol=3e-4 * np.abs(ref).max(), err_msg=name)
+
+
+def chunk_values(c, d, seed, fall=None):
+    """One chunk's unit-sized q and k ``[c, d]``, its running sums ``G`` (a
+    memory of one to a thousand positions a channel, or every channel falling
+    by ``fall`` a position) and cotangents for the two ``[c, c]`` blocks,
+    masked as ``_channel_backward`` hands them over."""
+    r = rng(seed)
+    q, k = (r.randn(c, d).astype("float32") / np.sqrt(d) for _ in range(2))
+    g = np.full((c, d), -fall, "float32") if fall else -np.exp(
+        r.uniform(np.log(1e-3), np.log(1.6), (c, d))).astype("float32")
+    dkk, dqk = (r.randn(c, c).astype("float32") for _ in range(2))
+    return (jnp.asarray(q), jnp.asarray(k), jnp.cumsum(jnp.asarray(g), 0),
+            jnp.asarray(np.tril(dkk, -1)), jnp.asarray(np.tril(dqk)))
+
+
+def pairwise(x, k, g):
+    """``sum_c x_i[c] k_j[c] exp(G_i[c] - G_j[c])``, pair by pair."""
+    return jnp.einsum("ic,jc,ijc->ij", x, k, jnp.exp(jnp.minimum(
+        g[:, None] - g[None], 0.0)), precision="highest")
+
+
+@pytest.mark.parametrize("fall", [None, 2.0])
+@pytest.mark.parametrize("c,d", [(4, 8), (16, 8), (64, 16), (128, 128)])
+def test_the_halved_blocks_equal_the_pairwise_definition(c, d, fall):
+    """``_intra``'s two blocks, one product a level, against every pair's
+    own sum in float32: right under the diagonal (and on it for q), zero
+    above. ``fall``: every channel falls by 2 a position, ``|G|`` reaches
+    256 in a chunk of 128, and no level takes an exponent above 0."""
+    q, k, g, _, _ = chunk_values(c, d, seed=20, fall=fall)
+    if fall and c == 128:
+        assert float(jnp.abs(g).max()) > 200
+    with jax.default_matmul_precision("highest"):
+        kk, qk = pallas_delta._intra(q, k, g, jnp.float32)
+    want_kk, want_qk = pairwise(k, k, g), pairwise(q, k, g)
+    assert np.isfinite(kk).all() and np.isfinite(qk).all()
+    close(kk, jnp.tril(want_kk, -1), 1e-5)
+    close(qk, jnp.tril(want_qk), 1e-5)
+    assert not np.asarray(jnp.triu(kk)).any()
+    assert not np.asarray(jnp.triu(qk, 1)).any()
+
+
+@pytest.mark.parametrize("fall", [None, 2.0])
+@pytest.mark.parametrize("c,d", [(4, 8), (32, 16), (128, 128)])
+def test_intra_bwd_is_the_transpose_of_intra(c, d, fall):
+    """``_intra_bwd`` (what the backward kernel runs) against ``jax.vjp`` of
+    ``_intra`` in float32: dq, dk and dG, the reference rows' share of dG
+    (which ``_intra_bwd`` leaves out as zero) included on JAX's side."""
+    q, k, g, dkk, dqk = chunk_values(c, d, seed=21, fall=fall)
+    with jax.default_matmul_precision("highest"):
+        _, back = jax.vjp(
+            lambda *a: pallas_delta._intra(*a, jnp.float32), q, k, g)
+        want = back((dkk, dqk))
+        got = pallas_delta._intra_bwd(q, k, g, dkk, dqk, jnp.float32)
+    for name, a, b in zip(("dq", "dk", "dG"), got, want):
+        assert np.isfinite(a).all(), name
+        np.testing.assert_allclose(a, b, rtol=0,
+                                   atol=1e-5 * np.abs(b).max(), err_msg=name)
+
+
+@pytest.mark.parametrize("impl,d", [("composed", 16), ("pallas", 128)])
+def test_a_channel_that_falls_by_two_a_position_equals_the_recurrence(impl, d):
+    """The steep case as the chip read it (PR 51: ``|G|`` up to 242 inside a
+    chunk of 128): every channel of one head falls by 2 a position, so the
+    chunk's running sum passes 200 and ``exp(-G)`` left float32 after 45
+    positions; the other head keeps a long memory. Finite, and equal to the
+    recurrence with the gradient of every input, ``g``'s a channel each."""
+    feeds = channel_inputs(1, 256, 2, d, seed=9)
+    feeds["g"][:, :, 0] = -2.0
+    sums = np.cumsum(feeds["g"].reshape(1, 2, 128, 2, d), axis=2)
+    assert sums[..., 0, :].min() < -200 and sums[..., 1, :].max() <= 0
+    out, grads, _, g, _ = run_with_grads(rule_with(impl, 128), feeds, NAMES)
+    assert np.isfinite(out).all() and all(
+        np.isfinite(x).all() for x in grads)
+    want, want_grads = channel_recurrence(feeds, g)
+    close(out, want, 1e-4)
+    assert np.abs(grads[3]).max() > 0
+    for name, got, ref in zip(NAMES, grads, want_grads):
+        np.testing.assert_allclose(
+            got, ref, rtol=0, atol=1e-4 * np.abs(ref).max(), err_msg=name)

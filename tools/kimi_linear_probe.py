@@ -30,9 +30,12 @@ file adds:
 ``readings``  the program as it is and float8 weights, by part, a seed
               each of ``--seeds``: what the check's limit is set from.
 ``kernels``   the rule's kernels alone at the cell's shape by chunk
-              (milliseconds a layer forward / backward) against the composed
-              form and its temporaries, and the flash kernels at 32 heads
-              with q / k 256 (64 zero columns) or 192 wide and v 128.
+              (milliseconds a layer forward / backward; ``--packed-from``:
+              also by the block length from which a level of the halving
+              hands the MXU its lower rows alone, ``pallas_delta.
+              PACKED_FROM``) against the composed form and its temporaries,
+              and the flash kernels at 32 heads with q / k 256 (64 zero
+              columns) or 192 wide and v 128.
 """
 from __future__ import annotations
 
@@ -333,17 +336,27 @@ def kernels(args) -> dict:
             continue
         ops = (jnp.concatenate([flat(q), flat(k), flat(v)], axis=-1),
                decoder_ops._chunk_sums(g, chunk), beta)
-        o, states = pallas_delta._fwd_call(*ops, chunk, interpret)
-        fwd = _ms(lambda: pallas_delta._fwd_call(*ops, chunk, interpret))
-        bwd = _ms(lambda: pallas_delta._bwd_call(
-            *ops, states, flat(do), chunk, interpret))
         sums = _ms(jax.jit(lambda g: decoder_ops._chunk_sums(g, chunk)), g)
-        result["delta"].append({"chunk": chunk, "fwd_ms": fwd, "bwd_ms": bwd,
-                                "chunk_sums_ms": sums,
-                                "finite": bool(jnp.isfinite(o).all())})
-        say(f"KDA kernels, chunk {chunk}: forward {fwd:.3f} backward "
-            f"{bwd:.3f} ms a layer ({B} x {S}, {n} heads of {d}); the "
-            f"running sums of g around them {sums:.3f} ms")
+        taken = pallas_delta.PACKED_FROM
+        for rows in args.packed_from or [taken]:
+            if rows != pallas_delta.PACKED_FROM:
+                pallas_delta.PACKED_FROM = rows     # read at a kernel's trace
+                jax.clear_caches()
+            o, states = pallas_delta._fwd_call(*ops, chunk, interpret)
+            fwd = _ms(lambda: pallas_delta._fwd_call(*ops, chunk, interpret))
+            bwd = _ms(lambda: pallas_delta._bwd_call(
+                *ops, states, flat(do), chunk, interpret))
+            result["delta"].append({
+                "chunk": chunk, "packed_from": rows, "fwd_ms": fwd,
+                "bwd_ms": bwd, "chunk_sums_ms": sums,
+                "finite": bool(jnp.isfinite(o).all())})
+            say(f"KDA kernels, chunk {chunk}, lower rows alone from blocks of "
+                f"{rows}: forward {fwd:.3f} backward {bwd:.3f} ms a layer "
+                f"({B} x {S}, {n} heads of {d}); the running sums of g "
+                f"around them {sums:.3f} ms")
+        if pallas_delta.PACKED_FROM != taken:
+            pallas_delta.PACKED_FROM = taken
+            jax.clear_caches()
 
     first = min(args.chunks[0], S)
 
@@ -379,6 +392,9 @@ def main(argv=None) -> int:
         ap.set_defaults(cell=CELL)
         ap.add_argument("--chunks", nargs="*", type=int, default=[64, 128],
                         help="kernels: chunk lengths of the rule")
+        ap.add_argument("--packed-from", nargs="*", type=int, default=[],
+                        help="kernels: block lengths in pallas_delta."
+                             "PACKED_FROM's place, e.g. 4 8 16")
         ap.add_argument("--seeds", nargs="*", type=int, default=[],
                         help="readings: a check each")
     laguna_probe.load_cell = load_cell      # its modes load the cell by it
