@@ -77,31 +77,21 @@ def say(msg: str = "") -> None:
     print(f"{_LABEL}{msg}", flush=True)
 
 
-class CompileWatch:
-    """Sums JAX's own compile events: seconds inside the backend compile
-    (a persistent-cache read counts as its retrieval time) and the
-    persistent cache's hit / miss events."""
+def compile_spans():
+    """``(seconds, persistent-cache hits, misses)`` of the program's compiles
+    so far, from the spans JAX's own events become under an executor compile
+    (``observability/timeline.py``, category ``jax``): the seconds and count
+    of ``backend_compile``, of which those with a ``cache_load`` child hit.
+    Read off their ``phase_seconds`` histograms, which never wrap. What
+    compiles outside an ``Executor`` (the kernels phase's bare ``jax.jit``s,
+    eager ``jax.numpy``) is not the program's and is not counted."""
+    from paddle_tpu.observability.metrics import REGISTRY
 
-    def __init__(self):
-        import jax
-        self.seconds = 0.0
-        self.hits = 0
-        self.misses = 0
-        jax.monitoring.register_event_duration_secs_listener(self._duration)
-        jax.monitoring.register_event_listener(self._event)
-
-    def _duration(self, event, secs, **_):
-        if event == "/jax/core/compile/backend_compile_duration":
-            self.seconds += secs
-
-    def _event(self, event, **_):
-        if event == "/jax/compilation_cache/cache_hits":
-            self.hits += 1
-        elif event == "/jax/compilation_cache/cache_misses":
-            self.misses += 1
-
-    def snapshot(self):
-        return (self.seconds, self.hits, self.misses)
+    def of(phase):
+        h = REGISTRY.histogram("phase_seconds", phase=phase, cat="jax")
+        return h.sum, h.count
+    (seconds, compiles), (_, hits) = of("backend_compile"), of("cache_load")
+    return (seconds, hits, compiles - hits)
 
 
 def counter(name: str, **labels) -> int:
@@ -794,7 +784,6 @@ def main(argv=None) -> int:
         f"({len(tune_cache.CACHE.items())} entries, mode {tune_cache.mode()})")
 
     sizes = TINY if rehearsal else FULL
-    watch = CompileWatch()
     ctx = {"platform": dev.platform}
     phases = {}
     t_all = time.perf_counter()
@@ -805,13 +794,13 @@ def main(argv=None) -> int:
             phases["mesh"] = f"not run ({jax.device_count()} device)"
             say(f"mesh: {phases['mesh']}")
             continue
-        s0, h0, m0 = watch.snapshot()
+        s0, h0, m0 = compile_spans()
         t0 = time.perf_counter()
         facts = globals()[f"phase_{name}"](sizes, ctx)
         wall = time.perf_counter() - t0
         if name == "trace" or "trace" not in wanted:
             ctx.pop("train", None)               # free the BERT state
-        s1, h1, m1 = watch.snapshot()
+        s1, h1, m1 = compile_spans()
         phases[name] = {"ok": True, "wall_seconds": round(wall, 2),
                         "compile_seconds": round(s1 - s0, 2),
                         "cache_hits": h1 - h0, "cache_misses": m1 - m0}
@@ -819,6 +808,7 @@ def main(argv=None) -> int:
             f"persistent-cache hits {h1 - h0} misses {m1 - m0}")
         for k, v in facts.items():
             say(f"  {k}: {v}")
+    seconds, hits, misses = compile_spans()
     summary = {
         "versions": {"jax": jax.__version__, "jaxlib": jaxlib.__version__,
                      "libtpu": libtpu_version},
@@ -826,8 +816,8 @@ def main(argv=None) -> int:
                           "entries_at_start": entries_at_start,
                           "entries_at_end": compile_cache.entry_count(
                               cache_dir),
-                          "hits": watch.hits, "misses": watch.misses},
-        "compile_seconds": round(watch.seconds, 2),
+                          "hits": hits, "misses": misses},
+        "compile_seconds": round(seconds, 2),
         "wall_seconds": round(time.perf_counter() - t_all, 2),
         "phases": phases,
     }

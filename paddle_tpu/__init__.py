@@ -6,7 +6,11 @@ program-level autodiff, optimizers, executors -- but Programs lower whole to XLA
 parallelism is SPMD sharding over device meshes, and custom kernels are Pallas.
 """
 
-from . import unique_name  # noqa: F401
+import time as _time
+
+_IMPORT_START = _time.perf_counter()   # process_uptime_seconds{at="import_start"}
+
+from . import unique_name  # noqa: F401,E402
 from .framework import (Program, Block, Variable, Parameter, Operator,  # noqa
                         program_guard, device_guard, default_main_program,
                         default_startup_program, switch_main_program,
@@ -53,6 +57,9 @@ from . import metrics  # noqa: F401
 from .reader import DataLoader, PyReader, DataFeeder  # noqa: F401
 
 __version__ = "0.1.0"
+
+observability.timeline.mark_uptime("import_start", _IMPORT_START)
+observability.timeline.mark_uptime("import_end")
 
 
 class CPUPlace:

@@ -21,6 +21,7 @@ import warnings
 from typing import Dict, List, Optional, Tuple
 
 from .framework import Program
+from .observability.timeline import spanned as _spanned
 
 _warned_knobs = set()
 
@@ -265,6 +266,7 @@ class CompiledProgram:
         self._mesh = None
         return self
 
+    @_spanned("with_strategy", cat="build", nested=False)
     def with_strategy(self, dist_strategy: DistributedStrategy):
         self.dist_strategy = dist_strategy
         self._mesh = None

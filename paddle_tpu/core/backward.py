@@ -12,6 +12,7 @@ from __future__ import annotations
 from typing import Dict, List, Optional, Sequence, Set, Tuple
 
 from ..framework import (Block, Parameter, Variable, grad_var_name)
+from ..observability.timeline import spanned as _spanned
 from . import registry
 from .registry import EMPTY_VAR
 
@@ -148,6 +149,7 @@ def _collect_no_grad(block: Block, no_grad_set, keep: Sequence[str] = ()) -> Set
     return no_grad
 
 
+@_spanned("append_backward", cat="build", nested=False)
 def append_backward(loss: Variable, parameter_list: Optional[Sequence] = None,
                     no_grad_set: Optional[Set[str]] = None,
                     callbacks=None) -> List[Tuple[Variable, Variable]]:

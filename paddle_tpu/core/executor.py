@@ -24,7 +24,6 @@ and RuntimeContext cache (operator.cc:865-883).
 from __future__ import annotations
 
 import contextlib
-import functools
 import threading
 import time
 import weakref
@@ -35,6 +34,7 @@ import numpy as np
 from ..framework import (Program, Block, Variable, default_main_program)
 from ..observability import fleet as _obs_fleet
 from ..observability import journal as _obs_journal
+from ..observability import lowerings as _obs_lowerings
 from ..observability import timeline as _obs_timeline
 from ..observability.metrics import REGISTRY as _OBS
 # fault-injection hook points (resilience/faults.py); every call site is
@@ -78,7 +78,15 @@ def _retire_program_gauges_if_dead(prog_id, version):
     # step peak_live_set would work from
     from ..observability import memory as _obs_memory
     _obs_memory.retire_program(label)
+    for gname in ("program_role", "program_compile_seconds"):
+        fam = _OBS.get(gname)
+        for labels in (fam.items() if fam is not None else ()):
+            if ("program", label) in labels[0]:
+                _OBS.remove_labeled(gname, **dict(labels[0]))
 
+
+#: whether ``process_uptime_seconds{at="first_executor"}`` is set
+_FIRST_EXECUTOR_MARKED = False
 
 #: whether THIS process already paid the warm store's startup directory
 #: scan (the one-door contract with tuning.prefetch -- see
@@ -201,18 +209,49 @@ def _xla_options():
     return _flags.xla_compiler_options()
 
 
-def _spanned(name: str, cat: str = "executor"):
-    """Run the decorated entry point inside one flight-recorder phase, from
-    its first statement to its return, exceptions included.  The phase is a
-    container: its self time is what its children leave (in ``run``: the
-    cache key, the scope write-back, the bookkeeping after dispatch)."""
-    def deco(fn):
-        @functools.wraps(fn)
-        def entry(*args, **kwargs):
-            with _obs_timeline.phase(name, cat=cat):
-                return fn(*args, **kwargs)
-        return entry
-    return deco
+def _program_role(program: Program, feed) -> str:
+    """What a Program being compiled is, from its ops: ``train`` (an op
+    reads a ``Param`` and its ``Grad``: the rule ``program_state_bytes``
+    knows an optimizer op by), ``startup`` (no feed, and every op writes
+    persistable variables only), else ``eval``."""
+    block = program.global_block()
+    if any("Param" in op.inputs and "Grad" in op.inputs for op in block.ops):
+        return "train"
+    if not feed and all(
+            getattr(block.find_var_recursive(n), "persistable", False)
+            for op in block.ops for n in op.output_arg_names()
+            if n != EMPTY_VAR):
+        return "startup"
+    return "eval"
+
+
+def _place_state(shardings, mut_vals, ro_vals):
+    """Lay the state a freshly compiled step takes in over its mesh: every
+    value whose sharding is not the step's own is put there, and waited for,
+    inside a ``place_state`` span (what the step's first call would do
+    unseen inside its dispatch: a startup program leaves the state on one
+    device). Nothing is opened where all of it lies as the step wants it."""
+    import jax
+
+    def misplaced(vals):
+        # device arrays that lie otherwise, on the mesh's own client: a host
+        # value goes with the call as before, and a mesh of described
+        # devices (a compile for a topology) can be put nothing
+        return {n: v for n, v in vals.items()
+                if isinstance(v, jax.Array) and v.sharding != shardings[n]
+                and next(iter(v.devices())).client
+                is next(iter(shardings[n].device_set)).client}
+    moved = [misplaced(mut_vals), misplaced(ro_vals)]
+    if not any(moved):
+        return mut_vals, ro_vals
+    with _obs_timeline.phase("place_state", cat="build"):
+        placed = [{n: jax.device_put(v, shardings[n]) for n, v in m.items()}
+                  for m in moved]
+        jax.block_until_ready(placed)
+        _obs_timeline.annotate(
+            n=sum(map(len, placed)),
+            bytes=sum(v.nbytes for m in placed for v in m.values()))
+    return {**mut_vals, **placed[0]}, {**ro_vals, **placed[1]}
 
 
 def _as_device_array(x, dtype=None):
@@ -266,6 +305,7 @@ def trace_block(block: Block, env: Dict[str, Any], base_key, block_runner=None,
     import jax
 
     ops = block.ops if stop_at is None else block.ops[:stop_at]
+    notes = block.program._lowering_notes
     for op_idx, op in enumerate(ops):
         d = registry.get(op.type)
         ins: Dict[str, List[Any]] = {}
@@ -286,6 +326,9 @@ def trace_block(block: Block, env: Dict[str, Any], base_key, block_runner=None,
         ctx = LowerCtx(op.attrs, base_key, stable_salt(salt_name),
                        block_runner=block_runner, program=block.program, mesh=mesh,
                        gspmd_mesh=gspmd_mesh, data_axis=data_axis)
+        # the call's seconds go to the program's lowering notes by op type
+        # (trace time only)
+        began = _obs_lowerings.lowering_began(notes)
         try:
             # IR->HLO attribution (observability/attribution.py): every HLO
             # instruction this lowering traces carries "<op_type>#<op_idx>"
@@ -301,6 +344,8 @@ def trace_block(block: Block, env: Dict[str, Any], base_key, block_runner=None,
                      if stack else "")
             raise RuntimeError(
                 f"lowering failed for op {op!r}: {e}{where}") from e
+        _obs_lowerings.lowering_ended(notes, began, op.type,
+                                      ctx.asked_kernels)
         from .. import flags as _flags
         check_dtype = _flags.get_flag("check_dtype")
         for slot, names in op.outputs.items():
@@ -482,6 +527,13 @@ class Executor:
             import warnings
             warnings.warn(f"paddle_tpu SLO engine disabled: {e}")
         Executor._instances.add(self)
+        global _FIRST_EXECUTOR_MARKED
+        if not _FIRST_EXECUTOR_MARKED:
+            # what lies behind by now: the interpreter, the imports, the
+            # backend's client if the caller touched it, the Program if it
+            # was built first
+            _FIRST_EXECUTOR_MARKED = True
+            _obs_timeline.mark_uptime("first_executor")
 
     def _maybe_verify(self, program: Program, feed_names, fetch_names,
                       wrapper=None, feed_shapes=None, fuse_k=None):
@@ -680,21 +732,30 @@ class Executor:
         """``lower().compile()`` the freshly cached step. A failure here is
         a real compile error (Mosaic refusing a kernel, VMEM, device OOM):
         it propagates, and the half-built entry is dropped so a retry
-        compiles again instead of dispatching through lazy jit."""
+        compiles again instead of dispatching through lazy jit. Returns the
+        seconds of JAX's own phases by name (``settle_jax_events``)."""
         try:
             # the Python trace + lower, which a persistent-cache hit does
             # not save; the rest of the enclosing compile span is the
-            # backend's (JAX's own compile event times that)
+            # backend's. JAX's own events split both (timeline's listener:
+            # jaxpr_trace / mlir_lower here, backend_compile / cache_load
+            # after), written once the compile has succeeded
             with _obs_timeline.phase("trace_lower"):
-                lowered = compiled.fn.lower(*args)
+                try:
+                    lowered = compiled.fn.lower(*args)
+                except BaseException:
+                    _obs_timeline.discard()
+                    raise
             compiled.executable = lowered.compile()
+            return _obs_timeline.settle_jax_events()
         except BaseException:
             self._cache.pop(key, None)
             raise
 
     def _post_compile_telemetry(self, compiled, program, label,
                                 feed_shapes, feed_names, fetch_names,
-                                wrapper, exe_args, warm: bool = False):
+                                wrapper, exe_args, warm: bool = False,
+                                role: str = ""):
         """Compile-time gauges shared by the step and megastep paths:
         compile histogram, XLA cost/memory gauges, the static planner's
         estimate beside them, the state the step takes in by class, and
@@ -728,8 +789,7 @@ class Executor:
             _obs_memory.note_compiled_step(compiled, program, label,
                                            exe_args, marks)
         # what the op lowerings reported while this compile traced them
-        from ..observability import lowerings as _obs_lowerings
-        _obs_lowerings.publish(program._lowering_notes, label)
+        _obs_lowerings.publish(program._lowering_notes, label, role=role)
         # IR->HLO attribution walk: once per compile miss, only when obs /
         # PADDLE_TPU_OBS_ATTRIB / an armed --emit-hlo capture asks for it
         # (on_compile is a no-op otherwise and never raises)
@@ -742,7 +802,7 @@ class Executor:
         _obs_attrib.on_compile(compiled, program, attrib_label)
 
     def _materialize_miss(self, kind, program, key, compiled, exe_args,
-                          label, step_idx, feed_shapes, feed_names,
+                          label, role, step_idx, feed_shapes, feed_names,
                           fetch_names, wrapper, world_dependent):
         """Give the freshly cached step its executable now, rather than
         letting jit compile lazily inside the first call: the executable's
@@ -751,7 +811,9 @@ class Executor:
         refusing a kernel, VMEM, device OOM) and surfaces here, once.
         Shared by the step (``kind="train_step"``) and megastep
         (``"fused_step"``) paths; returns the key the entry lives under
-        afterwards."""
+        afterwards. ``role`` (``_program_role``) goes on the spans of the
+        miss, on the lowering seconds and into ``program_role``; what the
+        compile was made of into ``program_compile_seconds``."""
         t0 = time.perf_counter()
         restored = ws_key = ws_store = ws_expect = None
         _phase = _obs_timeline.phase
@@ -759,7 +821,8 @@ class Executor:
             # armed warm store: a restore replaces the whole
             # trace+lower+compile (tier A) or the trace+lower (tier B);
             # any store trouble is just a miss
-            with _phase("warm_restore", step=step_idx, program=label):
+            with _phase("warm_restore", step=step_idx, program=label,
+                        role=role):
                 try:
                     ws_expect = {"avals": repr(_ws_avals(exe_args))}
                     ws_key = self._warmstore_key(
@@ -775,12 +838,15 @@ class Executor:
         # (LowerCtx.report) is published by _post_compile_telemetry below;
         # anything older was left by a trace that was not the executor's
         program._lowering_notes.clear()
+        parts = None
         if restored is not None:
             compiled.executable = restored
         else:
-            with _phase("compile", step=step_idx, program=label):
+            compile_phase = _phase("compile", step=step_idx, program=label,
+                                   role=role)
+            with compile_phase:
                 try:
-                    self._aot_compile(key, compiled, exe_args)
+                    parts = self._aot_compile(key, compiled, exe_args)
                 except BaseException:
                     # a compile that raised never left a span
                     _obs_timeline.discard()
@@ -799,11 +865,36 @@ class Executor:
         # (its own span: cost and memory analysis, the planner, the
         # lowering counters and the attribution hook are the program's
         # bookkeeping, not the compile)
-        with _phase("post_compile", step=step_idx, program=label):
+        post_phase = _phase("post_compile", step=step_idx, program=label,
+                            role=role)
+        with post_phase:
             self._post_compile_telemetry(compiled, program, label,
                                          feed_shapes, feed_names,
                                          fetch_names, wrapper, exe_args,
-                                         warm=restored is not None)
+                                         warm=restored is not None,
+                                         role=role)
+        _OBS.gauge("program_role", "what a compiled program is: train (an "
+                   "op reads a Param and its Grad), startup (no feed, "
+                   "writes persistables only), eval (the rest)",
+                   program=label, role=role).set(1.0)
+        if parts is not None:
+            # one program's parts for a reader that does not walk the ring:
+            # JAX's own phases as the spans hold them, the two spans' own
+            # seconds beside them
+            parts = {"trace": parts.get("jaxpr_trace", 0.0),
+                     "lower": parts.get("mlir_lower", 0.0),
+                     "cache_load": parts.get("cache_load", 0.0),
+                     "backend": parts.get("backend_compile", 0.0),
+                     "post_compile": _obs_timeline.find(post_phase.id).dur,
+                     "total": _obs_timeline.find(compile_phase.id).dur}
+            for part, secs in parts.items():
+                _OBS.gauge("program_compile_seconds",
+                           "seconds of a program's latest compile miss by "
+                           "part: JAX's trace, lowering, persistent-cache "
+                           "read and backend compile (which holds the "
+                           "read), the post_compile span, and total = the "
+                           "compile span", program=label, role=role,
+                           part=part).set(secs)
         if restored is None and ws_store is not None:
             try:
                 self._warmstore_offer(ws_store, ws_key, compiled, exe_args,
@@ -812,10 +903,13 @@ class Executor:
                 pass
         return key
 
-    def _feed_prep(self, program, compiled, scope, feed, label, k=1):
+    def _feed_prep(self, program, compiled, scope, feed, label, k=1,
+                   place=False):
         """What one dispatch of ``k`` steps hands the compiled step: the
         state read from the scope (``state_lookup``), the feeds on the
         device (``h2d``) and the run counter, which advances by ``k``.
+        ``place`` (a compile miss): under a mesh the state is laid over it
+        here, in a ``place_state`` span, and not by the step's first call.
         Returns ``(step_idx, mut_vals, ro_vals, feed_vals, rng)``."""
         import jax
 
@@ -855,6 +949,9 @@ class Executor:
                                 for n, v in mut_vals.items()}
                     ro_vals = {n: to_global(v, compiled.state_shardings[n])
                                for n, v in ro_vals.items()}
+                elif place and compiled.state_shardings:
+                    mut_vals, ro_vals = _place_state(
+                        compiled.state_shardings, mut_vals, ro_vals)
             with _phase("h2d"):
                 nbytes = 0      # of the feeds that were host arrays
                 feed_vals = {}
@@ -978,7 +1075,7 @@ class Executor:
                     validate=expect)
 
     # -- public API --------------------------------------------------------------------
-    @_spanned("run")
+    @_obs_timeline.spanned("run")
     def run(self, program: Optional[Program] = None, feed: Optional[dict] = None,
             fetch_list: Optional[Sequence] = None, scope: Optional[Scope] = None,
             return_numpy: bool = True, use_prune: bool = False):
@@ -1225,14 +1322,16 @@ class Executor:
 
         _phase = _obs_timeline.phase
         step_idx, mut_vals, ro_vals, feed_vals, rng = self._feed_prep(
-            program, compiled, scope, feed, label, k=k)
+            program, compiled, scope, feed, label, k=k, place=was_miss)
         _obs_timeline.annotate(step=step_idx, program=label)
 
         if was_miss:
+            role = _program_role(program, feed)
+            _obs_timeline.annotate(role=role)
             key = self._materialize_miss(
                 "fused_step" if fused else "train_step", program, key,
-                compiled, (mut_vals, ro_vals, feed_vals, rng), label, step_idx,
-                feed_shapes, list(feed), fetch_names, wrapper,
+                compiled, (mut_vals, ro_vals, feed_vals, rng), label, role,
+                step_idx, feed_shapes, list(feed), fetch_names, wrapper,
                 world_dependent=not fused and key[6] != ())
 
         obs_on = _obs_journal.enabled()
@@ -1409,7 +1508,7 @@ class Executor:
             return "host-table pulls/pushes (PS schedule)"
         return None
 
-    @_spanned("run")
+    @_obs_timeline.spanned("run")
     def run_fused(self, program: Optional[Program] = None, feeds=None,
                   fetch_list: Optional[Sequence] = None,
                   scope: Optional[Scope] = None, return_numpy: bool = False,
@@ -1779,7 +1878,7 @@ class Executor:
         for f in feeds:
             run_chunk([f])
 
-    @_spanned("train_from_dataset", cat="dataset")
+    @_obs_timeline.spanned("train_from_dataset", cat="dataset")
     def train_from_dataset(self, program=None, dataset=None, scope=None,
                            thread=0, debug=False, fetch_list=None,
                            fetch_info=None, print_period=100,

@@ -78,11 +78,15 @@ class LowerCtx:
         # so impl choices must not be validated and shape-equivalent fallbacks
         # should be used (e.g. fused_attention lowers its composed path)
         self.abstract = abstract
+        # set by pallas_mode.lowers_kernels: this op's lowering asked the
+        # kernel-or-composed rule (the executor books its trace seconds
+        # under family "kernel", observability/lowerings.py)
+        self.asked_kernels = False
 
     def attr(self, name, default=None):
         return self.attrs.get(name, default)
 
-    def report(self, family: str, amount=1, **labels) -> None:
+    def report(self, family: str, amount=1, /, **labels) -> None:
         """Report what this op's lowering chose, as ``amount`` of the metric
         ``family`` under ``labels`` (observability/lowerings.py declares the
         families and publishes them once the compile is made: the executor
@@ -341,6 +345,7 @@ def _generic_grad_lower(fwd: OpDef, ctx, ins):
         for (s, i), v in zip(diff_keys, diff_vals):
             full[s][i] = v
         outs = fwd.lower(fwd_ctx, full)
+        ctx.asked_kernels |= fwd_ctx.asked_kernels
         # Return only float outputs, keyed (slot, index) for exact cotangent alignment.
         return {s: {i: o for i, o in enumerate(outs[s]) if _is_float(o)}
                 for s in outs if s not in fwd.nondiff_outputs}

@@ -26,6 +26,7 @@ from typing import Any, Dict, List, Optional, Sequence
 import numpy as np
 
 from . import unique_name
+from .observability.timeline import spanned as _spanned
 
 _PKG_DIR = _os.path.dirname(_os.path.abspath(__file__))
 
@@ -492,7 +493,16 @@ class Program:
     def clone(self, for_test: bool = False) -> "Program":
         """Deep structural copy. With for_test=True, sets is_test on ops that behave
         differently in inference (dropout, batch_norm), mirroring the reference's
-        Program.clone(for_test=True) (framework.py:3720)."""
+        Program.clone(for_test=True) (framework.py:3720). The test clone a
+        user asks for is a ``clone`` span of category ``build``; the copies
+        ``_prune`` makes (``for_test`` False) are not."""
+        return self._test_clone() if for_test else self._clone(False)
+
+    @_spanned("clone", cat="build", nested=False)
+    def _test_clone(self) -> "Program":
+        return self._clone(True)
+
+    def _clone(self, for_test: bool) -> "Program":
         p = Program.from_dict(self.to_dict())
         p.random_seed = self.random_seed
         if for_test:

@@ -11,7 +11,9 @@ reproduction gets the counterpart the whole-program-jit design enables:
 - ``journal``  -- JSON-lines run journal (one event per ``Executor.run``,
   plus recompile/predict events), file sink gated on ``PADDLE_TPU_OBS=1``.
 - ``timeline`` -- flight-recorder phase spans (feed-prep/dispatch/fetch per
-  step) + the unified Chrome-trace/Perfetto exporter.
+  step; set-up: building the Program, each compile with JAX's own trace /
+  lowering / backend / cache-read events as its children) + the unified
+  Chrome-trace/Perfetto exporter.
 - ``health``   -- NaN/Inf watchdog over fetches/state, one compiled
   any-nonfinite reduction per step (``PADDLE_TPU_OBS_HEALTH=off|warn|raise``).
 - ``memory``   -- device memory_stats()/live-buffer gauges + per-program
@@ -34,13 +36,9 @@ reproduction gets the counterpart the whole-program-jit design enables:
   ``--emit-hlo`` capture) and the ``hlo_diff`` regression explainer
   (``python -m paddle_tpu.observability.attribution A B``).
 - ``lowerings`` -- what the op lowerings of a compiled program chose, as
-  labelled counts added once per compile (``attention_lowering_total``,
-  ``attention_k_tiles_total``, ``attention_backward_total``,
-  ``loss_backward_total``, ``rotary_lowering_total``, ``mask_draw_total``,
-  ``gather_layout_total``, ``ssd_lowering_total``,
-  ``short_conv_lowering_total``, ``delta_lowering_total``) and the gauge
-  ``moe_row_budget``: a lowering reports through ``LowerCtx.report``, the
-  module's ``FAMILIES`` table declares and documents each family.
+  labelled metrics added once per compile: a lowering reports through
+  ``LowerCtx.report``, and the module's ``FAMILIES`` table is the one list
+  of the families, their labels and what each counts.
 - ``moe`` -- ``load_stats`` for a fetched expert-load vector.
 
 Render everything with ``python -m tools.obs_report``.

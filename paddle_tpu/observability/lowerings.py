@@ -13,6 +13,7 @@ here.
 """
 from __future__ import annotations
 
+import time
 from collections import Counter
 from typing import Optional
 
@@ -135,7 +136,21 @@ FAMILIES = {
         GAUGE, (),
         "sorted rows the expert layers keep a step, all layers: the "
         "assignments without a row budget, the budgets with one"),
+    # core/executor.py:trace_block, every op: amount = the seconds its
+    # lowering call took while the compile traced it (self time: what a
+    # control-flow op's sub-block took is its ops'; a grad op keeps the
+    # forward it lowers again under jax.vjp). family: kernel (the lowering
+    # asked pallas_mode.lowers_kernels, whatever the answer) / xla; role:
+    # the program's (startup / eval / train), given by ``publish``
+    "lowering_seconds_total": (
+        COUNT, ("role", "op_type", "family"),
+        "seconds the compiles spent in each op type's lowering call"),
 }
+
+_SECONDS = "lowering_seconds_total"
+#: key of the seconds ``lowering_ended`` has booked since the notes were
+#: emptied, nested calls included: how an enclosing call knows its self time
+_BOOKED = ("", 0, ())
 
 
 #: labels a family gained after its first readers were written, with what a
@@ -163,20 +178,44 @@ def note(notes: dict, salt: int, family: str, amount, labels: dict) -> None:
     notes[family, salt, tuple(sorted(labels.items()))] = amount
 
 
+def lowering_began(notes: dict) -> tuple:
+    """What ``lowering_ended`` wants of the moment before a lowering call:
+    the clock, and the seconds booked so far."""
+    return time.perf_counter(), notes.get(_BOOKED, 0.0)
+
+
+def lowering_ended(notes: dict, began: tuple, op_type: str,
+                   kernel: bool) -> None:
+    """Add the seconds since ``began`` to the op type's, less what the calls
+    nested in this one booked meanwhile: self time."""
+    t0, booked = began
+    secs = time.perf_counter() - t0
+    nested = notes.get(_BOOKED, 0.0) - booked
+    key = (_SECONDS, 0, (("family", "kernel" if kernel else "xla"),
+                         ("op_type", op_type)))
+    notes[key] = notes.get(key, 0.0) + max(secs - nested, 0.0)
+    notes[_BOOKED] = booked + secs
+
+
 def publish(notes: dict, program: str,
-            registry: Optional[MetricsRegistry] = None) -> None:
+            registry: Optional[MetricsRegistry] = None,
+            role: str = "") -> None:
     """Add the reports of one compile to the registry and empty them.
     ``notes`` maps ``(family, op salt, ((label, value), ...))`` to an amount
     (a Program's ``_lowering_notes``); label values go through ``str``.
-    Nothing is added for a family no op of the program reported."""
+    Nothing is added for a family no op of the program reported. ``role``
+    is the label of that name, for the families that declare it."""
     registry = registry or REGISTRY
     totals = Counter()
+    notes.pop(_BOOKED, None)
     for (family, _, labels), amount in notes.items():
         totals[family, labels] += amount
     notes.clear()
     for (family, labels), amount in totals.items():
-        kind, _, help = FAMILIES[family]
+        kind, names, help = FAMILIES[family]
         labels = {name: str(value) for name, value in labels}
+        if "role" in names:
+            labels["role"] = role
         if kind == COUNT:
             registry.counter(family, help, program=program,
                              **labels).inc(amount)

@@ -24,6 +24,18 @@ seconds; a capture's timestamps count from its own start, and one paired
 event fixes the offset between the two (measured on the CPU, jax 0.9.0:
 five annotations over 240 ms disagreed by 4.3 us).
 
+Set-up has the same tree.  ``spanned(name, cat)`` wraps an entry point in
+one phase (``Executor.run``; category ``build``: ``append_backward``,
+``minimize``, ``Program.clone(for_test=True)``, ``with_strategy``), and JAX's
+own compile events become child spans of the executor's compile that caused
+them (category ``jax``: ``jaxpr_trace`` and ``mlir_lower`` under
+``trace_lower``, ``backend_compile`` under ``compile`` with a ``cache_load``
+child on a persistent-cache hit): ``_on_jax_event`` holds what fires on a
+thread while an executor ``compile`` phase is open there, and
+``settle_jax_events`` writes it once the compile has succeeded.
+``mark_uptime`` sets ``process_uptime_seconds{at}`` against the process's
+start as the OS gives it.
+
 Cost, always on: a ``phase()`` is two ``perf_counter`` calls, an idle
 ``TraceAnnotation``, a thread-local stack push/pop, a lock'd deque append
 and one histogram observe through a handle cached per (name, category) --
@@ -49,6 +61,7 @@ the output in chrome://tracing or https://ui.perfetto.dev.
 from __future__ import annotations
 
 import collections
+import functools
 import itertools
 import json
 import os
@@ -217,6 +230,183 @@ def record_span(name: str, t0: float, dur: float, cat: str = "executor",
     h = _handle(name, cat)
     _record(name, cat, t0, dur, args, next(_ids),
             stack[-1].id if stack else 0, h[1], h[2])
+
+
+def spanned(name: str, cat: str = "executor", nested: bool = True):
+    """Run the decorated entry point inside one phase, from its first
+    statement to its return, exceptions included.  The phase is a container:
+    its self time is what its children leave (in ``run``: the cache key, the
+    scope write-back, the bookkeeping after dispatch).  ``nested=False``
+    opens it only where no phase of ``cat`` is open on the thread already:
+    ``minimize`` calls ``append_backward`` and a wrapping optimizer the inner
+    one's ``minimize``, and one span an outermost call keeps the category's
+    ``phase_seconds`` a sum of disjoint times."""
+    def deco(fn):
+        @functools.wraps(fn)
+        def entry(*args, **kwargs):
+            if not nested and any(p.cat == cat for p in _open.stack):
+                return fn(*args, **kwargs)
+            with phase(name, cat=cat):
+                return fn(*args, **kwargs)
+        return entry
+    return deco
+
+
+def find(span_id: int) -> Optional[Span]:
+    """The ring's span of that id, looked for from the newest end (a caller
+    asks for a phase it has just closed); None where the phase was
+    ``discard()``ed or the ring has wrapped past it."""
+    with _lock:
+        for s in reversed(_spans):
+            if s.id == span_id:
+                return s
+    return None
+
+
+# ------------------------------------------------ JAX's own compile events --
+
+#: the events of jax/_src/dispatch.py and compiler.py that become spans of
+#: category ``jax``, each with the executor phase under which it counts
+_JAX_SPANS = {
+    "/jax/core/compile/jaxpr_trace_duration": ("jaxpr_trace", "trace_lower"),
+    "/jax/core/compile/jaxpr_to_mlir_module_duration":
+        ("mlir_lower", "trace_lower"),
+    "/jax/core/compile/backend_compile_duration":
+        ("backend_compile", "compile"),
+    "/jax/compilation_cache/cache_retrieval_time_sec":
+        ("cache_load", "compile"),
+}
+
+
+class _HeldSpan:
+    """One JAX event of the executor compile open on a thread, not yet
+    written."""
+    __slots__ = ("name", "t0", "end", "id", "parent")
+
+    def __init__(self, name, t0, end, span_id, parent):
+        self.name, self.t0, self.end = name, t0, end
+        self.id, self.parent = span_id, parent
+
+
+class _Held(threading.local):
+    def __init__(self):
+        self.compile = 0        # id of the compile phase the spans belong to
+        self.spans: List[_HeldSpan] = []
+
+
+_held = _Held()
+
+
+def _on_jax_event(event: str, secs: float, **_):
+    """JAX's duration listener.  An event counts where the innermost phase
+    open on the thread is the executor's ``trace_lower`` (trace, lowering) or
+    ``compile`` (backend compile, cache read) of an executor ``compile``;
+    every other is dropped: eager ``jax.numpy`` calls, a reference the caller
+    jits, an autotune candidate compiled while the step is traced -- they are
+    not the program's compile.  JAX fires the trace event for every inner
+    ``jit`` and ``custom_vjp`` it traces, over a thousand a decoder step, each
+    inside the next, and again for what a lowering rule traces while the
+    module is lowered: one is kept, the one that began first, which is the
+    step's own.  The cache read fires inside the backend compile that then
+    fires around it, and becomes its child."""
+    name, under = _JAX_SPANS.get(event, (None, None))
+    stack = _open.stack
+    if name is None or not stack:
+        return
+    top = stack[-1]
+    owner = next((p for p in reversed(stack)
+                  if p.name == "compile" and p.cat == "executor"), None)
+    if owner is None or top.cat != "executor" or top.name != under:
+        return
+    now = time.perf_counter()
+    t0 = max(now - secs, top._t0)
+    if _held.compile != owner.id:       # the last compile raised: drop it
+        _held.compile, _held.spans = owner.id, []
+    held = _held.spans
+    if name == "jaxpr_trace":
+        for h in held:
+            if h.name == name:
+                if t0 < h.t0:
+                    h.t0, h.end = t0, now
+                return
+    span_id = next(_ids)
+    if name == "backend_compile":
+        for h in held:
+            if h.name == "cache_load" and h.t0 >= t0:
+                h.parent = span_id
+    held.append(_HeldSpan(name, t0, now, span_id, top.id))
+
+
+def settle_jax_events() -> Dict[str, float]:
+    """Write what ``_on_jax_event`` holds for the executor ``compile`` phase
+    open on this thread, in the order the spans ended, and return their
+    seconds by name.  The executor calls it once lower and compile have
+    returned: a compile that raises leaves none of them.  A lowering that
+    fired inside the kept trace (an autotune candidate's) is the trace's
+    time and is left out."""
+    stack = _open.stack
+    held, _held.spans = _held.spans, []
+    if not stack or stack[-1].id != _held.compile:
+        return {}
+    traced = max((h.end for h in held if h.name == "jaxpr_trace"),
+                 default=0.0)
+    parts: Dict[str, float] = {}
+    for h in sorted(held, key=lambda h: h.end):
+        if h.name == "mlir_lower" and h.t0 < traced:
+            continue
+        _, hist, in_window = _handle(h.name, "jax")
+        _record(h.name, "jax", h.t0, h.end - h.t0, None, h.id, h.parent,
+                hist, in_window)
+        parts[h.name] = parts.get(h.name, 0.0) + h.end - h.t0
+    return parts
+
+
+def _listen_to_jax():
+    """Register ``_on_jax_event`` with JAX, once a process: a module executed
+    again (``importlib.reload``) keeps its namespace, and with it the mark
+    that the first execution registered."""
+    global _LISTENING
+    if not _LISTENING:
+        from jax import monitoring
+        monitoring.register_event_duration_secs_listener(_on_jax_event)
+        _LISTENING = True
+
+
+_LISTENING = globals().get("_LISTENING", False)
+_listen_to_jax()
+
+
+# ------------------------------------------------------- process uptime --
+
+def _process_start() -> Optional[float]:
+    """The ``perf_counter`` reading at which the OS started this process:
+    ``/proc/self/stat``'s start time (field 22, clock ticks since boot)
+    against ``CLOCK_BOOTTIME`` now.  None where either cannot be read."""
+    try:
+        with open("/proc/self/stat") as f:
+            ticks = int(f.read().rsplit(")", 1)[1].split()[19])
+        age = time.clock_gettime(time.CLOCK_BOOTTIME) \
+            - ticks / os.sysconf("SC_CLK_TCK")
+        return time.perf_counter() - age
+    except (OSError, ValueError, IndexError, AttributeError):
+        return None
+
+
+_PROCESS_START = _process_start()
+
+
+def mark_uptime(at: str, t: Optional[float] = None) -> None:
+    """Set ``process_uptime_seconds{at}`` to the seconds from the process's
+    start to the ``perf_counter`` reading ``t`` (now): before ``at`` lie the
+    interpreter, the imports and whatever the caller did until then.  Not
+    set where the OS does not say when the process began."""
+    if _PROCESS_START is not None:
+        REGISTRY.gauge(
+            "process_uptime_seconds",
+            "seconds from the process's start (the OS's) to a point of "
+            "set-up: import_start / import_end of paddle_tpu, the first "
+            "Executor", at=at).set(
+                (time.perf_counter() if t is None else t) - _PROCESS_START)
 
 
 def counter_sample(track: str, values: Dict[str, float],

@@ -58,7 +58,9 @@ def lowers_kernels(ctx, impl: str, fits: bool, what: str = "",
     cannot be automatically partitioned"; seen on the chip, PR 27), so under
     a GSPMD mesh of several devices ``auto`` is the composed form, which
     GSPMD partitions. Inside a ``shard_map`` (``ctx.mesh``, no GSPMD mesh)
-    the call is legal and stays allowed."""
+    the call is legal and stays allowed. Asking marks the op
+    (``ctx.asked_kernels``) as one this rule decides for."""
+    ctx.asked_kernels = True
     if ctx.abstract:
         return False
     if impl == "pallas":

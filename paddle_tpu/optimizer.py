@@ -17,6 +17,7 @@ from .framework import (Parameter, Program, Variable, default_main_program,
                         default_startup_program)
 from .initializer import Constant
 from .layer_helper import LayerHelper
+from .observability.timeline import spanned as _spanned
 from .regularizer import append_regularization_ops
 
 
@@ -91,6 +92,7 @@ class Optimizer:
     def apply_optimize(self, loss, startup_program, params_grads):
         return self.apply_gradients(params_grads)
 
+    @_spanned("minimize", cat="build", nested=False)
     def minimize(self, loss, startup_program=None, parameter_list=None,
                  no_grad_set=None, grad_clip=None
                  ) -> Tuple[List, List[Tuple[Parameter, Variable]]]:

@@ -680,8 +680,11 @@ _PROGRAM_FAMILIES = ("program_peak_bytes", "program_temp_bytes",
                      "program_alias_bytes", "program_xla_peak_bytes",
                      "program_static_peak_bytes", "program_static_peak_ratio",
                      "program_compile_seq", "program_state_bytes",
-                     "program_allocator_bytes")
-_MEMORY_FAMILIES = _DEVICE_FAMILIES + _PROGRAM_FAMILIES
+                     "program_allocator_bytes", "program_role",
+                     "program_compile_seconds")
+_LOWERING_SECONDS = "lowering_seconds_total"
+_MEMORY_FAMILIES = (_DEVICE_FAMILIES + _PROGRAM_FAMILIES
+                    + (_LOWERING_SECONDS,))
 
 
 def _gb(v: float) -> str:
@@ -690,7 +693,10 @@ def _gb(v: float) -> str:
 
 
 def render_memory(snapshot: dict) -> str:
-    """Device occupancy gauges + per-program XLA footprint, human units."""
+    """Device occupancy gauges + per-program XLA footprint, human units;
+    and, each compiled program being listed here, what it is (role), what
+    its compile was made of, and the op types whose lowerings took most of
+    the compiles' trace."""
     lines = ["== Device memory =="]
     # accumulate samples across same-named families: a Prometheus text dump
     # parses to one single-sample family PER series, so a last-wins dict
@@ -714,10 +720,14 @@ def render_memory(snapshot: dict) -> str:
     for name in _PROGRAM_FAMILIES:
         for s in fams.get(name, {}).get("samples", []):
             labels = s.get("labels", {})
-            # the second label of a state / allocator gauge names the part
-            part = labels.get("class") or labels.get("stat")
-            progs.setdefault(labels.get("program", "?"), {})[
-                f"{name}:{part}" if part else name] = s.get("value", 0.0)
+            # the second label of a state / allocator / compile-seconds
+            # gauge names the part
+            part = (labels.get("class") or labels.get("stat")
+                    or labels.get("part"))
+            prog = progs.setdefault(labels.get("program", "?"), {})
+            prog[f"{name}:{part}" if part else name] = s.get("value", 0.0)
+            if name == "program_role":
+                prog["role"] = labels.get("role", "?")
     # in the order they compiled: start-up, ..., the train step last
     for label, parts in sorted(progs.items(), key=lambda kv: (
             kv[1].get("program_compile_seq", 0.0), kv[0])):
@@ -756,6 +766,32 @@ def render_memory(snapshot: dict) -> str:
                 "    allocator before its first run"
                 + (f" (compile {seq:g})" if seq else "") + ": "
                 + ", ".join(f"{c} {_gb(v)}" for c, v in marks))
+        secs = {c: parts[f"program_compile_seconds:{c}"] for c in
+                ("total", "trace", "lower", "backend", "cache_load",
+                 "post_compile") if f"program_compile_seconds:{c}" in parts}
+        if "role" in parts or secs:
+            line = f"    compiled as {parts.get('role', '?')}"
+            if secs:
+                line += (
+                    f" in {secs.get('total', 0.0):.3f} s: trace "
+                    f"{secs.get('trace', 0.0):.3f}, lower "
+                    f"{secs.get('lower', 0.0):.3f}, backend "
+                    f"{secs.get('backend', 0.0):.3f} (cache load "
+                    f"{secs.get('cache_load', 0.0):.3f} of it); then "
+                    f"post_compile {secs.get('post_compile', 0.0):.3f}")
+            lines.append(line)
+    by_op = {}
+    for s in fams.get(_LOWERING_SECONDS, {}).get("samples", []):
+        labels = s.get("labels", {})
+        key = (labels.get("op_type", "?"), labels.get("family", "?"))
+        by_op[key] = by_op.get(key, 0.0) + s.get("value", 0.0)
+    if by_op:
+        top = sorted(by_op.items(), key=lambda kv: -kv[1])[:10]
+        lines.append(
+            f"  lowering seconds by op type, all compiles "
+            f"({sum(by_op.values()):.3f} s over {len(by_op)} op types): "
+            + ", ".join(f"{op} [{family}] {v:.3f}"
+                        for (op, family), v in top))
     return "\n".join(lines)
 
 
@@ -1113,6 +1149,16 @@ def selftest() -> int:
               **{"class": "optimizer"}).set(8e8)
     reg.gauge("program_allocator_bytes", program="1:v0",
               stat="peak_reserved").set(1.1e9)
+    reg.gauge("program_role", program="1:v0", role="train").set(1)
+    for part, secs in (("total", 6.5), ("trace", 1.25), ("lower", 0.75),
+                       ("backend", 4.25), ("cache_load", 0.5),
+                       ("post_compile", 0.125)):
+        reg.gauge("program_compile_seconds", program="1:v0", role="train",
+                  part=part).set(secs)
+    for op, family, secs in (("fused_attention_grad", "kernel", 0.5),
+                             ("mul", "xla", 0.25)):
+        reg.counter("lowering_seconds_total", program="1:v0", role="train",
+                    op_type=op, family=family).inc(secs)
     # attribution section sources (observability/attribution.py)
     reg.gauge("hlo_op_bytes", program="1:v0", category="fusion").set(3e8)
     reg.gauge("hlo_op_bytes", program="1:v0", category="layout").set(6.4e7)
@@ -1516,6 +1562,11 @@ def selftest() -> int:
                      # memory section (incl. the static-planner comparison)
                      "cpu:0", "512.000 MB", "peak 1.500 GB",
                      "static plan 1.800 GB", "(1.20x of XLA)",
+                     "compiled as train in 6.500 s: trace 1.250, lower "
+                     "0.750, backend 4.250 (cache load 0.500 of it); then "
+                     "post_compile 0.125",
+                     "(0.750 s over 2 op types): fused_attention_grad "
+                     "[kernel] 0.500, mul [xla] 0.250",
                      # timeline section: run 13 ms, of which its two
                      # children took 11
                      "feed_prep", "dispatch", "total=13.000 self=2.000",
