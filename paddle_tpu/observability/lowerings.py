@@ -96,10 +96,13 @@ FAMILIES = {
     # the op was given) / split (three operands, given or cut out of it);
     # decay: head (one scalar a token and value head: Gated DeltaNet) /
     # channel (a vector over the key channels: Kimi Delta Attention); a
-    # series without the label is a parent's, and is read as head
+    # series without the label is a parent's, and is read as head;
+    # step_heads: the key heads a grid step of the kernels takes (a channel
+    # decay's pallas_delta.step_heads; 1 for a scalar decay, the composed
+    # form and a series without the label)
     "delta_lowering_total": (
         COUNT, ("impl", "chunk", "heads", "key_dim", "value_dim",
-                "operands", "decay"),
+                "operands", "decay", "step_heads"),
         "gated_delta_rule ops compiled, by the lowering and the "
         "operand form each took"),
     # ops/decoder_ops.py:rms_norm given a Gate, and its grad op (an rms_norm
@@ -156,7 +159,7 @@ _BOOKED = ("", 0, ())
 #: labels a family gained after its first readers were written, with what a
 #: report without them means: such a report is kept under the default
 LATER_LABELS = {
-    "delta_lowering_total": {"decay": "head"},
+    "delta_lowering_total": {"decay": "head", "step_heads": "1"},
     "rms_norm_gated_lowering_total": {"activation": "silu"},
     "attention_lowering_total": {"value_dim": 0},
 }

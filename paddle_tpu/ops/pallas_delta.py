@@ -25,7 +25,9 @@ a chunk at a time.
 A grid step is (batch, key head, chunk), the chunks in order and the state
 of the key head's ``rep`` value heads (value head j reads key head ``j //
 rep``) carried in VMEM scratch as ``[rep, d_k, d_v]`` float32: ``k k^T`` and
-``q k^T`` are made once a step for its value heads.
+``q k^T`` are made once a step for its value heads. (Under a decay a key
+channel a step is (batch, ``step_heads`` key heads and their value heads,
+chunk): below.)
 
 The kernels read what the projection and the short convolution wrote, as
 they wrote it; everything between that and the products happens in VMEM.
@@ -34,7 +36,8 @@ or as the one packed array ``[B, S, (2 key heads + value heads) * 128]`` (q |
 k | v along the columns), which is then handed to the call three times with
 three index maps: a head is one 128-lane tile, so key head j of q is lane
 block ``j``, of k ``key heads + j``, and its value heads one block of ``rep *
-128`` lanes behind both (``packs`` says when that offset is whole blocks).
+128`` lanes behind both (``packs`` says when that offset is whole blocks;
+``_laid_out`` counts the offsets in a step's blocks).
 No copy of v is cut out. q and k are raw: a step forms ``x * rsqrt(sum(x^2)
 + 1e-6)`` (q also over ``sqrt(d_k)``: ``unit``) in float32 and rounds it to
 the inputs' dtype, which is what the products read; no unit q or k exists in
@@ -90,17 +93,34 @@ by the doubling over the whole chunk). These are ``_intra``
 and its transpose ``_intra_bwd``: plain functions of a chunk's values, which
 the kernels' bodies trace and the composed form maps over batch, head and
 chunk (``channel_chunk``). The kernels (``_fwd_kernel_channel``,
-``_bwd_kernel_channel``) take one value head a key head, share the block
-specs, the packed operand and the states' layout with the scalar pair, and
-read ``G`` and write ``dG`` as ``[B, S, heads * 128]`` float32, a head a
-lane tile. In the backward every product that holds a decay is
-differentiated as the rounded operand the forward's product read (``dG +=
-lo * dlo`` for ``lo = (x * E).astype(bf)`` on a pair's lower rows, ``dG -=
-up * dup`` on its upper ones), so a pair's reference row takes nothing for
-being the reference -- ``G_r`` cancels inside every product of its pair:
-channel by channel the lower rows' ``lo * dlo`` and the upper rows' ``up *
-dup`` are the same sums of the same rounded arrays -- and so does what
-cancels pair by pair in the running sum that turns ``dG`` into ``dg``.
+``_bwd_kernel_channel``) take one value head a key head and ``step_heads``
+such heads a grid step (the largest divisor of the head count not above
+``CHANNEL_HEADS``; grid ``(batch, heads / step_heads, chunks)``): a head's
+chunk is a chain of dependent ``[128, 128]`` products -- twelve in the
+inverse alone, each waiting some 245 cycles for 43 of MXU work -- and a
+step's heads are independent, so the body maps a head's chunk
+(``_channel_forward`` / ``_channel_backward`` as they are) over a leading
+head axis (``jax.vmap``): every value of a chunk is ``[step_heads, ..]``,
+Mosaic unrolls each operation over the heads, and in the kernel's program
+the heads' chains stand side by side, stage by stage, so that one chain's
+waits are filled with the others' products, while a step's fixed cost is
+paid ``step_heads`` times less often. (The order is the point: a loop over
+the heads, one whole chain behind the other, left the chip's schedule as it
+was; chip, PR 54.) The masks have no head axis and are made once. Nothing
+of a head's arithmetic knows of the others: the results are one head a step's
+bit for bit. The kernels share the block specs (key, value, scalar and state
+blocks ``step_heads`` heads wide), the packed operand (q, k and v start
+whole blocks in, since ``step_heads`` divides the heads) and the states'
+layout with the scalar pair, and read ``G`` and write ``dG`` as ``[B, S,
+heads * 128]`` float32, a head a lane tile. In the backward every product
+that holds a decay is differentiated as the rounded operand the forward's
+product read (``dG += lo * dlo`` for ``lo = (x * E).astype(bf)`` on a pair's
+lower rows, ``dG -= up * dup`` on its upper ones), so a pair's reference row
+takes nothing for being the reference -- ``G_r`` cancels inside every
+product of its pair: channel by channel the lower rows' ``lo * dlo`` and the
+upper rows' ``up * dup`` are the same sums of the same rounded arrays -- and
+so does what cancels pair by pair in the running sum that turns ``dG`` into
+``dg``.
 """
 from __future__ import annotations
 
@@ -383,6 +403,16 @@ def _apart(c):
 
 
 INVERSE_BLOCK = 8       # rows of the blocks the blocked inverse starts from
+CHANNEL_HEADS = 8       # heads a grid step takes at most (``step_heads``)
+
+
+def step_heads(heads: int) -> int:
+    """The heads a grid step of the channel kernels takes of ``heads``: the
+    largest divisor not above ``CHANNEL_HEADS``, so that a step's lane block
+    of a packed operand starts at a whole block (q at 0, k ``heads`` tiles
+    in, v ``2 heads``: ``_laid_out``)."""
+    return max(h for h in range(1, min(CHANNEL_HEADS, heads) + 1)
+               if heads % h == 0)
 
 
 def _inverse_blocked(m, eye, bf):
@@ -553,8 +583,29 @@ def _channel_backward(qn, kn, v, g, bc, s, dsn, do):
             rows(dr * z) + rows(dm * md), ds)
 
 
+def _heads(ref, n):
+    """A block's ``[C, n * 128]``, a head a lane tile -> ``[n, C, 128]``."""
+    import jax.numpy as jnp
+    return jnp.stack([ref[0, :, HEAD_DIM * r:HEAD_DIM * (r + 1)]
+                      for r in range(n)])
+
+
+def _to_tiles(ref, x):
+    """``_heads``' inverse: ``x [n, C, 128]`` into the block."""
+    for r in range(x.shape[0]):
+        ref[0, :, HEAD_DIM * r:HEAD_DIM * (r + 1)] = x[r]
+
+
+def _scalar_rows(ref, n):
+    """A block's ``[n, C]`` scalars, a head a row -> ``[n, 1, C]``."""
+    import jax.numpy as jnp
+    rows = ref[0, 0, 0]
+    return jnp.stack([rows[r:r + 1, :] for r in range(n)])
+
+
 def _fwd_kernel_channel(q_ref, k_ref, v_ref, g_ref, b_ref, o_ref, st_ref,
                         s_ref):
+    import jax
     import jax.numpy as jnp
     pl, _ = _pl()
     bf, f32 = q_ref.dtype, jnp.float32
@@ -563,15 +614,23 @@ def _fwd_kernel_channel(q_ref, k_ref, v_ref, g_ref, b_ref, o_ref, st_ref,
     def _():
         s_ref[...] = jnp.zeros_like(s_ref)
 
-    diag = _masks(q_ref.shape[1])[2]
-    s = s_ref[0]
-    st_ref[0, 0, 0] = s
-    o, s_next, _ = _channel_forward(
-        unit(q_ref[0].astype(f32), QUERY_SCALE).astype(bf),
-        unit(k_ref[0].astype(f32)).astype(bf), v_ref[0], g_ref[0],
-        _column(b_ref[0, 0, 0], diag), s)
-    o_ref[0] = o.astype(o_ref.dtype)
-    s_ref[0] = s_next
+    def head(q, k, v, g, b, s):         # a head's chunk, values in and out
+        o, s_next, _ = _channel_forward(
+            unit(q.astype(f32), QUERY_SCALE).astype(bf),
+            unit(k.astype(f32)).astype(bf), v, g,
+            _column(b, _masks(g.shape[0])[2]), s)
+        return o.astype(o_ref.dtype), s_next
+
+    # the step's heads on a leading axis of every value: Mosaic unrolls an
+    # operation over them, so their chains stand side by side in the program
+    n = s_ref.shape[0]
+    s = s_ref[...]
+    st_ref[0, 0] = s
+    o, s_next = jax.vmap(head)(
+        _heads(q_ref, n), _heads(k_ref, n), _heads(v_ref, n), _heads(g_ref, n),
+        _scalar_rows(b_ref, n), s)
+    _to_tiles(o_ref, o)
+    s_ref[...] = s_next
 
 
 def _bwd_kernel_channel(q_ref, k_ref, v_ref, do_ref, g_ref, b_ref, st_ref,
@@ -585,19 +644,25 @@ def _bwd_kernel_channel(q_ref, k_ref, v_ref, do_ref, g_ref, b_ref, st_ref,
     def _():
         ds_ref[...] = jnp.zeros_like(ds_ref)
 
-    diag = _masks(q_ref.shape[1])[2]
-    qu, q_back = jax.vjp(lambda x: unit(x, QUERY_SCALE), q_ref[0].astype(f32))
-    ku, k_back = jax.vjp(unit, k_ref[0].astype(f32))
-    dqn, dkn, dv, dg, db, ds = _channel_backward(
-        qu.astype(bf), ku.astype(bf), v_ref[0], g_ref[0],
-        _column(b_ref[0, 0, 0], diag), st_ref[0, 0, 0], ds_ref[0],
-        do_ref[0])
-    ds_ref[0] = ds
-    dq_ref[0] = q_back(dqn)[0].astype(dq_ref.dtype)
-    dk_ref[0] = k_back(dkn)[0].astype(dk_ref.dtype)
-    dv_ref[0] = dv.astype(dv_ref.dtype)
-    dg_ref[0] = dg
-    db_ref[0, 0, 0] = _row(db, diag)
+    def head(q, k, v, g, b, s, dsn, do):
+        diag = _masks(g.shape[0])[2]
+        qu, q_back = jax.vjp(lambda x: unit(x, QUERY_SCALE), q.astype(f32))
+        ku, k_back = jax.vjp(unit, k.astype(f32))
+        dqn, dkn, dv, dg, db, ds = _channel_backward(
+            qu.astype(bf), ku.astype(bf), v, g, _column(b, diag), s, dsn, do)
+        return (q_back(dqn)[0].astype(dq_ref.dtype),
+                k_back(dkn)[0].astype(dk_ref.dtype), dv.astype(dv_ref.dtype),
+                dg, _row(db, diag), ds)
+
+    n = ds_ref.shape[0]                 # the step's heads, as the forward's
+    dq, dk, dv, dg, db, ds = jax.vmap(head)(
+        _heads(q_ref, n), _heads(k_ref, n), _heads(v_ref, n), _heads(g_ref, n),
+        _scalar_rows(b_ref, n), st_ref[0, 0], ds_ref[...], _heads(do_ref, n))
+    ds_ref[...] = ds
+    for ref, x in ((dq_ref, dq), (dk_ref, dk), (dv_ref, dv), (dg_ref, dg)):
+        _to_tiles(ref, x)
+    for r in range(n):
+        db_ref[0, 0, 0, r:r + 1, :] = db[r]
 
 
 def channel_chunk(qn, kn, v, g, beta, s):
@@ -608,52 +673,61 @@ def channel_chunk(qn, kn, v, g, beta, s):
     return o, s_next
 
 
-def _by_head(v, key_heads, chunk):
-    """``[B, S, heads]`` -> ``[B, key heads, chunks, rep, C]``: a grid
-    step's scalars, a value head a row."""
+def _by_head(v, steps, chunk):
+    """``[B, S, heads]`` -> ``[B, steps, chunks, heads / steps, C]``: a grid
+    step's scalars (``steps``: the grid's extent over the heads), a value
+    head a row."""
     b, s, h = v.shape
-    return (v.reshape(b, s // chunk, chunk, key_heads, h // key_heads)
+    return (v.reshape(b, s // chunk, chunk, steps, h // steps)
             .transpose(0, 3, 1, 4, 2))
 
 
 def _from_heads(rows):
     """``_by_head``'s layout back to ``[B, S, heads]``."""
-    b, n_k, chunks, rep, c = rows.shape
-    return rows.transpose(0, 2, 4, 1, 3).reshape(b, chunks * c, n_k * rep)
+    b, steps, chunks, values, c = rows.shape
+    return rows.transpose(0, 2, 4, 1, 3).reshape(b, chunks * c,
+                                                 steps * values)
 
 
-def _laid_out(qkv, value_heads):
+def _laid_out(qkv, value_heads, channel=False):
     """(the q, k and v operands, the lane block each one's first head is at
-    -- q's and k's in tiles of 128, v's in a key head's ``rep`` tiles --,
-    the key heads) of ``(q, k, v)`` or of one packed ``q | k | v`` array."""
-    if isinstance(qkv, (tuple, list)):
-        q, k, v = qkv
-        return (q, k, v), (0, 0, 0), q.shape[2] // HEAD_DIM
-    key_heads = (qkv.shape[2] // HEAD_DIM - value_heads) // 2
+    -- q's and k's in blocks of a step's key heads, v's in blocks of its
+    value heads --, the key heads, the key heads a grid step takes) of ``(q,
+    k, v)`` or of one packed ``q | k | v`` array. A step takes one key head
+    and its ``rep`` value heads, or (``channel``: one value head a key head)
+    ``step_heads`` of them."""
+    packed = not isinstance(qkv, (tuple, list))
+    key_heads = ((qkv.shape[2] // HEAD_DIM - value_heads) // 2 if packed
+                 else qkv[0].shape[2] // HEAD_DIM)
+    step = step_heads(key_heads) if channel else 1
+    if not packed:
+        return qkv, (0, 0, 0), key_heads, step
     return (qkv, qkv, qkv), (
-        0, key_heads, 2 * key_heads // (value_heads // key_heads)), key_heads
+        0, key_heads // step,
+        2 * key_heads // (step * value_heads // key_heads)), key_heads, step
 
 
-def _specs(c, rep, chunk_of):
+def _specs(c, keys, values, chunk_of):
     """Block specs of what both passes read or write, the chunk a grid step
-    works on given by ``chunk_of(i)``: a key head's tile and its value
-    heads' ``rep`` tiles, each from the lane block ``first`` its array's
-    first head is at (``_laid_out``), a head's scalars, its states."""
+    works on given by ``chunk_of(i)``: the tiles of the step's ``keys`` key
+    heads and of its ``values`` value heads, each from the lane block
+    ``first`` its array's first head is at (``_laid_out``), a value head's
+    scalars a row, its states."""
     pl, pltpu = _pl()
 
     def spec(shape, index):
         return pl.BlockSpec(shape, index, memory_space=pltpu.VMEM)
 
     def key(first=0):
-        return spec((1, c, HEAD_DIM),
+        return spec((1, c, keys * HEAD_DIM),
                     lambda b, j, i: (b, chunk_of(i), first + j))
 
     def value(first=0):
-        return spec((1, c, rep * HEAD_DIM),
+        return spec((1, c, values * HEAD_DIM),
                     lambda b, j, i: (b, chunk_of(i), first + j))
-    scalars = spec((1, 1, 1, rep, c),
+    scalars = spec((1, 1, 1, values, c),
                    lambda b, j, i: (b, j, chunk_of(i), 0, 0))
-    state = spec((1, 1, rep, HEAD_DIM, HEAD_DIM),
+    state = spec((1, 1, values, HEAD_DIM, HEAD_DIM),
                  lambda b, j, i: (b, chunk_of(i), j, 0, 0))
     return key, value, scalars, state
 
@@ -667,30 +741,30 @@ def _fwd_call(qkv, gcum, beta, chunk, interpret):
     ``gcum`` / ``beta [B, S, value heads]`` float32 -> ``o [B, S, value heads
     * 128]`` and the state entering each chunk ``[B, chunks, value heads,
     128, 128]`` float32. ``gcum [B, S, heads, 128]``: a decay a key channel
-    (one value head a key head)."""
+    (one value head a key head, ``step_heads`` of them a grid step)."""
     import jax
     import jax.numpy as jnp
     pl, pltpu = _pl()
     batch, seq, n_v = gcum.shape[:3]
-    (q, k, v), at, n_k = _laid_out(qkv, n_v)
-    rep, chunks = n_v // n_k, seq // chunk
-    key, value, scalars, state = _specs(chunk, rep, lambda i: i)
+    (q, k, v), at, n_k, step = _laid_out(qkv, n_v, gcum.ndim == 4)
+    values, steps, chunks = n_v // n_k * step, n_k // step, seq // chunk
+    key, value, scalars, state = _specs(chunk, step, values, lambda i: i)
     if gcum.ndim == 4:
         kernel, decay, sums = (_fwd_kernel_channel, key(),
                                gcum.reshape(batch, seq, -1))
     else:
-        kernel, decay, sums = (functools.partial(_fwd_kernel, rep), scalars,
-                               _by_head(gcum, n_k, chunk))
+        kernel, decay, sums = (functools.partial(_fwd_kernel, values),
+                               scalars, _by_head(gcum, steps, chunk))
     return pl.pallas_call(
-        kernel, grid=(batch, n_k, chunks),
+        kernel, grid=(batch, steps, chunks),
         in_specs=[key(at[0]), key(at[1]), value(at[2]), decay, scalars],
         out_specs=[value(), state],
         out_shape=[jax.ShapeDtypeStruct((batch, seq, n_v * HEAD_DIM), v.dtype),
                    jax.ShapeDtypeStruct(
                        (batch, chunks, n_v, HEAD_DIM, HEAD_DIM), jnp.float32)],
-        scratch_shapes=[pltpu.VMEM((rep, HEAD_DIM, HEAD_DIM), jnp.float32)],
+        scratch_shapes=[pltpu.VMEM((values, HEAD_DIM, HEAD_DIM), jnp.float32)],
         interpret=interpret, **_params(interpret),
-    )(q, k, v, sums, _by_head(beta, n_k, chunk))
+    )(q, k, v, sums, _by_head(beta, steps, chunk))
 
 
 @functools.partial(_jax.jit, static_argnames=("chunk", "interpret"))
@@ -702,31 +776,31 @@ def _bwd_call(qkv, gcum, beta, states, do, chunk, interpret):
     import jax.numpy as jnp
     pl, pltpu = _pl()
     batch, seq, n_v = gcum.shape[:3]
-    (q, k, v), at, n_k = _laid_out(qkv, n_v)
-    rep, chunks = n_v // n_k, seq // chunk
+    (q, k, v), at, n_k, step = _laid_out(qkv, n_v, gcum.ndim == 4)
+    values, steps, chunks = n_v // n_k * step, n_k // step, seq // chunk
     key, value, scalars, state = _specs(
-        chunk, rep, lambda i: chunks - 1 - i)
+        chunk, step, values, lambda i: chunks - 1 - i)
     f32 = jnp.float32
     keys = jax.ShapeDtypeStruct((batch, seq, n_k * HEAD_DIM), v.dtype)
-    by_head = jax.ShapeDtypeStruct((batch, n_k, chunks, rep, chunk), f32)
+    by_head = jax.ShapeDtypeStruct((batch, steps, chunks, values, chunk), f32)
     if gcum.ndim == 4:      # a decay a key channel: G and dG a head a tile
         kernel, decay, gr = (_bwd_kernel_channel, key(),
                              gcum.reshape(batch, seq, -1))
         dg_shape = jax.ShapeDtypeStruct(gr.shape, f32)
     else:
-        kernel, decay, gr = (functools.partial(_bwd_kernel, rep), scalars,
-                             _by_head(gcum, n_k, chunk))
+        kernel, decay, gr = (functools.partial(_bwd_kernel, values), scalars,
+                             _by_head(gcum, steps, chunk))
         dg_shape = by_head
     dq, dk, dv, dg, db = pl.pallas_call(
-        kernel, grid=(batch, n_k, chunks),
+        kernel, grid=(batch, steps, chunks),
         in_specs=[key(at[0]), key(at[1]), value(at[2]), value(), decay,
                   scalars, state],
         out_specs=[key(), key(), value(), decay, scalars],
         out_shape=[keys, keys, jax.ShapeDtypeStruct(do.shape, v.dtype),
                    dg_shape, by_head],
-        scratch_shapes=[pltpu.VMEM((rep, HEAD_DIM, HEAD_DIM), f32)],
+        scratch_shapes=[pltpu.VMEM((values, HEAD_DIM, HEAD_DIM), f32)],
         interpret=interpret, **_params(interpret),
-    )(q, k, v, do, gr, _by_head(beta, n_k, chunk), states)
+    )(q, k, v, do, gr, _by_head(beta, steps, chunk), states)
     dqkv = (dq, dk, dv) if isinstance(qkv, (tuple, list)) else \
         jnp.concatenate([dq, dk, dv], axis=-1)
     dg = dg.reshape(gcum.shape) if gcum.ndim == 4 else _from_heads(dg)

@@ -79,3 +79,32 @@ def test_without_takes_one_mechanism_out_and_keeps_the_parameters():
         with probe_tool.patched(mechanism):
             assert getattr(owner, name) is not was
         assert getattr(owner, name) is was
+
+
+def test_the_kernels_sweep_takes_heads_a_step_and_puts_the_constant_back(
+        capsys):
+    """``--step-heads`` / ``--scalar-reps`` parse; the rehearsal's heads of
+    16 are not the kernels', so nothing is timed there. ``delta_kernels`` on
+    the interpreter at heads of 128: a row each value in ``CHANNEL_HEADS``'
+    place with what ``step_heads`` took of three heads and the empty bodies'
+    time beside it, the module as it was afterwards."""
+    import numpy as np
+    from paddle_tpu.ops import pallas_delta
+    got = probe(capsys, "kernels", "--chunks", "16", "--step-heads", "1", "2",
+                "--scalar-reps", "1", "2")
+    assert got["delta"] == [] and got["scalar"] == []
+    taken = (pallas_delta.CHANNEL_HEADS, pallas_delta._channel_forward,
+             pallas_delta._channel_backward)
+    rng = np.random.RandomState(5)
+    rows = probe_tool.delta_kernels(
+        probe_tool.channel_feeds(1, 128, 3, 128, rng), [64], [], [2, 4], True)
+    assert [(r["chunk"], r["step_heads"]) for r in rows] == [(64, 1), (64, 3)]
+    assert all(r["finite"] and min(r["fwd_ms"], r["bwd_ms"], r["empty_fwd_ms"],
+                                   r["empty_bwd_ms"]) > 0 for r in rows)
+    assert taken == (pallas_delta.CHANNEL_HEADS, pallas_delta._channel_forward,
+                     pallas_delta._channel_backward)
+    (row,) = probe_tool.delta_kernels(
+        probe_tool.channel_feeds(1, 64, 1, 128, rng), [64], [], [], True)
+    assert row["step_heads"] == 1 and "empty_fwd_ms" not in row
+    rows = probe_tool.scalar_kernels(1, 64, 4, 128, 64, [2, 3], True, rng)
+    assert [(r["key_heads"], r["rep"]) for r in rows] == [(2, 2)]

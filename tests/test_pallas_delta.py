@@ -234,10 +234,11 @@ def test_the_kernels_products_read_delta_operands_unit_q_and_k(form):
     q, k = (jnp.asarray(feeds[n], bf) for n in ("q", "k"))
     want_q, want_k, _ = decoder_ops._delta_operands(
         q, k, jnp.asarray(feeds["g"]), 64, bf)
-    (qa, ka, _), at, n_k = pallas_delta._laid_out(
+    (qa, ka, _), at, n_k, step = pallas_delta._laid_out(
         operands(feeds, form, bf), 4)
-    assert (n_k, at) == (2, (0, 0, 0) if form == "split" else (0, 2, 2))
-    key, *_ = pallas_delta._specs(64, 2, lambda i: i)
+    assert (n_k, step, at) == (
+        2, 1, (0, 0, 0) if form == "split" else (0, 2, 2))
+    key, *_ = pallas_delta._specs(64, step, 2, lambda i: i)
 
     def probe(q_ref, k_ref, qn_ref, kn_ref):
         qn_ref[0] = pallas_delta.unit(
@@ -638,3 +639,129 @@ def test_a_channel_that_falls_by_two_a_position_equals_the_recurrence(impl, d):
     for name, got, ref in zip(NAMES, grads, want_grads):
         np.testing.assert_allclose(
             got, ref, rtol=0, atol=1e-4 * np.abs(ref).max(), err_msg=name)
+
+
+# -- several heads a grid step of the channel kernels (PR 54) -----------------
+
+@pytest.fixture
+def heads_a_step(monkeypatch):
+    """Sets ``pallas_delta.CHANNEL_HEADS``; the kernels read it at their
+    trace, behind ``jax.jit``s, so the calls' caches go with every change."""
+    def clear():
+        pallas_delta._fwd_call.clear_cache()
+        pallas_delta._bwd_call.clear_cache()
+
+    def set_to(limit):
+        monkeypatch.setattr(pallas_delta, "CHANNEL_HEADS", limit)
+        clear()
+    yield set_to
+    monkeypatch.undo()
+    clear()
+
+
+def channel_pass(feeds, form, chunk=64):
+    """(o, the states, dq | dk | dv, dG, dbeta) of the channel kernels in the
+    interpreter, bfloat16 operands as on the chip."""
+    qkv = operands(feeds, form, jnp.bfloat16)
+    cum = decoder_ops._chunk_sums(jnp.asarray(feeds["g"]), chunk)
+    beta = jnp.asarray(feeds["beta"])
+    do = jnp.asarray(rng(31).randn(*feeds["v"].shape[:2], qkv[0].shape[-1]
+                                   if form == "split" else
+                                   qkv.shape[-1] // 3), jnp.bfloat16)
+    o, states = pallas_delta._fwd_call(qkv, cum, beta, chunk, True)
+    dqkv, dg, db = pallas_delta._bwd_call(qkv, cum, beta, states, do, chunk,
+                                          True)
+    if form == "split":
+        dqkv = jnp.concatenate(dqkv, -1)
+    return {name: np.asarray(x, np.float32) for name, x in zip(
+        ("o", "states", "dqkv", "dG", "dbeta"), (o, states, dqkv, dg, db))}
+
+
+@pytest.mark.parametrize("form", ["split", "packed"])
+@pytest.mark.parametrize("heads,limit,took", [
+    (4, 4, 4), (8, 4, 4), (8, 2, 2), (4, 8, 4),     # the limit divides, or is
+    (1, 4, 1), (3, 4, 3), (3, 2, 1)])               # past; the call falls to
+def test_heads_a_grid_step_give_the_bits_of_one_head_a_step(    # a divisor
+        heads_a_step, form, heads, limit, took):
+    """A grid step of the channel kernels takes ``step_heads`` heads, each
+    through ``_channel_forward`` / ``_channel_backward`` as one head a step
+    goes: ``o``, the states and the five gradients are the same bits, in
+    either operand form (the packed array's lane blocks are then ``took``
+    tiles wide, k's and v's offsets whole blocks of them)."""
+    feeds = channel_inputs(2, 128, heads, 128, seed=30 + heads)
+    heads_a_step(1)
+    assert pallas_delta.step_heads(heads) == 1
+    want = channel_pass(feeds, form)
+    heads_a_step(limit)
+    assert pallas_delta.step_heads(heads) == took
+    got = channel_pass(feeds, form)
+    for name in want:
+        assert np.abs(want[name]).max() > 0, name
+        np.testing.assert_array_equal(got[name], want[name], err_msg=name)
+
+
+def test_a_heads_state_never_reaches_the_next_head_of_its_step(heads_a_step):
+    """Four heads a step, v zero in the second and the fourth: their states
+    stay zero through the chunks and their ``o`` is zero, whatever the heads
+    before them in the step's scratch hold."""
+    feeds = channel_inputs(1, 256, 4, 128, seed=40)
+    feeds["v"][:, :, 1::2] = 0.0
+    heads_a_step(4)
+    got = channel_pass(feeds, "packed")
+    states = got["states"]                      # [B, chunks, heads, d_k, d_v]
+    assert not states[:, :, 1::2].any() and np.abs(states[:, 1:, ::2]).min(
+        axis=(0, 1, 2)).max() > 0
+    o = got["o"].reshape(1, 256, 4, 128)
+    assert not o[:, :, 1::2].any() and np.abs(o[:, :, ::2]).max() > 0
+
+
+def test_the_counter_says_how_many_heads_a_grid_step_took():
+    """``delta_lowering_total``'s ``step_heads``: what ``step_heads`` took of
+    a channel op's heads where it lowered the kernels, ``1`` for a scalar
+    decay and for the composed form, and for a series without the label;
+    ``gated_delta.grouped_step_ops`` reads the ops that took the module
+    constant's value, in the Kimi Linear cell."""
+    import json
+    import os
+    from benchmark.reducers import registry_count
+    n = pallas_delta.CHANNEL_HEADS
+    assert n > 1 and pallas_delta.step_heads(32) == n
+    assert [pallas_delta.step_heads(h) for h in (1, 2, 3)] == [
+        1, min(n, 2), 3 if n >= 3 else 1]
+    root = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+    with open(os.path.join(root, "benchmark", "layer_metrics",
+                           "gated_delta.grouped_step_ops.json")) as f:
+        spec = json.load(f)
+    assert (spec["reducer"], spec["match"]) == (
+        "registry_count", "delta_lowering_total")
+    assert spec["labels"] == {"impl": "pallas", "decay": "channel",
+                              "step_heads": str(n)}
+    with open(os.path.join(root, "BENCHMARK.json")) as f:
+        (entry,) = [m for m in json.load(f)["per_layer"]
+                    if m["name"] == spec["name"]]
+    assert entry["workloads"] == ["kimi_linear_48b_a3b.pretrain_s4096"]
+    assert (entry["moves"], entry["source"], entry["layer"]) == (
+        spec["moves"], spec["source"], spec["layer"]) == (
+        "tokens_per_s", "program_counter", "gated_delta_rule")
+    kinds = [dict(impl="pallas", decay="channel", step_heads=str(n)),
+             dict(impl="pallas", decay="channel", step_heads="3"),
+             dict(impl="composed", decay="channel", step_heads="1"),
+             dict(impl="pallas", decay="head", step_heads="1")]
+    before = [lowerings(**k) for k in kinds]
+    read = registry_count.reduce(spec, None) or 0
+    run_with_grads(rule_with("auto", 64, "packed"),
+                   channel_inputs(1, 64, n, 128, seed=6), ["g"])
+    run_with_grads(rule_with("auto", 64), channel_inputs(1, 64, 3, 128), [])
+    run_with_grads(rule_with("composed", 8), channel_inputs(1, 16, 2, 8), [])
+    run_with_grads(rule_with("auto", 64),
+                   rule_inputs(1, 128, 1, 2, 128, 128, seed=6), [])
+    assert [lowerings(**k) - b for k, b in zip(kinds, before)] == [1] * 4
+    assert registry_count.reduce(spec, None) - read == 1
+    # a report without the label (a parent's) is kept as one head a step
+    main = fluid.Program()
+    LowerCtx({}, salt=1, program=main).report(
+        "delta_lowering_total", impl="pallas", chunk=64, heads=32,
+        key_dim=128, value_dim=128, operands="packed", decay="channel")
+    assert lowering_reports.read(
+        lowering_reports.publish(main), "delta_lowering_total",
+        "step_heads") == {"1": 1}

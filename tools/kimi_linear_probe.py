@@ -33,15 +33,21 @@ file adds:
               (milliseconds a layer forward / backward; ``--packed-from``:
               also by the block length from which a level of the halving
               hands the MXU its lower rows alone, ``pallas_delta.
-              PACKED_FROM``) against the composed form and its temporaries,
-              and the flash kernels at 32 heads with q / k 256 (64 zero
-              columns) or 192 wide and v 128.
+              PACKED_FROM``; ``--step-heads``: also by the heads a grid step
+              takes at most, ``pallas_delta.CHANNEL_HEADS``, and beside each
+              the same grid with empty bodies: a step's fixed cost and its
+              DMAs; ``--scalar-reps``: the scalar-decay pair at as many
+              value heads by the value heads a key head, the chains a step
+              of theirs holds) against the composed form and its
+              temporaries, and the flash kernels at 32 heads with q / k 256
+              (64 zero columns) or 192 wide and v 128.
 """
 from __future__ import annotations
 
 import contextlib
 import copy
 import functools
+import itertools
 import math
 import os
 import sys
@@ -310,12 +316,144 @@ def flash_by_width(B, S, heads, widths, d_v, scale, interpret, rng) -> list:
     return rows
 
 
+@contextlib.contextmanager
+def swapped(owner, **values):
+    """``owner``'s attributes set for the block. The delta kernels read
+    theirs at a trace, behind ``jax.jit``s: where a value differs the caches
+    are cleared, going in and coming out."""
+    import jax
+    old = {name: getattr(owner, name) for name in values}
+    if old != values:
+        jax.clear_caches()
+    for name, value in values.items():
+        setattr(owner, name, value)
+    try:
+        yield
+    finally:
+        for name, value in old.items():
+            setattr(owner, name, value)
+        if old != values:
+            jax.clear_caches()
+
+
+def _no_forward(qn, kn, v, g, bc, s):
+    """In ``pallas_delta._channel_forward``'s place: nothing of a chunk."""
+    return v.astype("float32"), s, ()
+
+
+def _no_backward(qn, kn, v, g, bc, s, dsn, do):
+    """In ``pallas_delta._channel_backward``'s place: every output from an
+    input of its shape (the norms' vjp behind it stays)."""
+    return (qn.astype("float32"), kn.astype("float32"), do.astype("float32"),
+            g, bc, dsn)
+
+
+def channel_feeds(B, S, n, d, rng) -> tuple:
+    """Seeded q, k, v, do ``[B, S, n, d]`` bfloat16, g (a memory of one to a
+    thousand positions a channel) and beta, float32: a KDA layer's rule."""
+    import jax
+    import jax.numpy as jnp
+    q, k, v, do = (jnp.asarray(rng.randn(B, S, n, d), jnp.bfloat16)
+                   for _ in range(4))
+    g = -jnp.exp(jnp.asarray(rng.uniform(np.log(1e-3), np.log(1.6),
+                                         (B, S, n, d)), jnp.float32))
+    beta = jax.nn.sigmoid(jnp.asarray(rng.randn(B, S, n), jnp.float32))
+    return q, k, v, do, g, beta
+
+
+def delta_kernels(feeds, chunks, packed_from, step_heads, interpret) -> list:
+    """Forward / backward milliseconds a layer of the channel kernels on
+    ``channel_feeds``' arrays, a row each chunk, ``packed_from``
+    (``pallas_delta.PACKED_FROM``'s place) and ``step_heads`` (``CHANNEL_
+    HEADS``'s: the row says what ``pallas_delta.step_heads`` took of it, and
+    what the grid costs with empty bodies); an empty list keeps the
+    constant."""
+    import jax
+    import jax.numpy as jnp
+    from paddle_tpu.ops import decoder_ops, pallas_delta
+    q, k, v, do, g, beta = feeds
+    B, S, n, d = q.shape
+    flat = decoder_ops._flat
+    rows = []
+    for chunk in chunks:
+        if not pallas_delta.supports(S, n, n, d, d, chunk, channel=True):
+            say(f"delta kernels: chunk {chunk} at heads of {d} is not theirs")
+            continue
+        ops = (jnp.concatenate([flat(q), flat(k), flat(v)], axis=-1),
+               decoder_ops._chunk_sums(g, chunk), beta)
+        sums = _ms(jax.jit(lambda g: decoder_ops._chunk_sums(g, chunk)), g)
+
+        def both():
+            o, states = pallas_delta._fwd_call(*ops, chunk, interpret)
+            fwd = _ms(lambda: pallas_delta._fwd_call(*ops, chunk, interpret))
+            bwd = _ms(lambda: pallas_delta._bwd_call(
+                *ops, states, flat(do), chunk, interpret))
+            return fwd, bwd, bool(jnp.isfinite(o).all())
+        for size, heads in itertools.product(
+                packed_from or [pallas_delta.PACKED_FROM],
+                step_heads or [pallas_delta.CHANNEL_HEADS]):
+            with swapped(pallas_delta, PACKED_FROM=size, CHANNEL_HEADS=heads):
+                fwd, bwd, finite = both()
+                row = {"chunk": chunk, "packed_from": size,
+                       "step_heads": pallas_delta.step_heads(n),
+                       "fwd_ms": fwd, "bwd_ms": bwd, "chunk_sums_ms": sums,
+                       "finite": finite}
+                empty = ""
+                if step_heads:
+                    with swapped(pallas_delta, _channel_forward=_no_forward,
+                                 _channel_backward=_no_backward):
+                        row["empty_fwd_ms"], row["empty_bwd_ms"], _ = both()
+                    empty = (f"; empty bodies {row['empty_fwd_ms']:.3f} / "
+                             f"{row['empty_bwd_ms']:.3f}")
+            rows.append(row)
+            say(f"KDA kernels, chunk {chunk}, lower rows alone from blocks of "
+                f"{size}, {row['step_heads']} heads a grid step: forward "
+                f"{fwd:.3f} backward {bwd:.3f} ms a layer ({B} x {S}, {n} "
+                f"heads of {d}){empty}; the running sums of g around them "
+                f"{sums:.3f} ms")
+    return rows
+
+
+def scalar_kernels(B, S, n, d, chunk, reps, interpret, rng) -> list:
+    """Forward / backward milliseconds a layer of the scalar-decay kernels at
+    ``n`` value heads, by the value heads a key head: a grid step of theirs
+    is one key head and its ``rep`` value heads' chains (``qwen3_next`` has
+    16 key heads under 32 value heads, two chains a step)."""
+    import jax
+    import jax.numpy as jnp
+    from paddle_tpu.ops import decoder_ops, pallas_delta
+    bf = jnp.bfloat16
+    v, do = (jnp.asarray(rng.randn(B, S, n * d), bf) for _ in range(2))
+    g = -jnp.exp(jnp.asarray(rng.uniform(np.log(1e-3), np.log(1.6),
+                                         (B, S, n)), jnp.float32))
+    beta = jax.nn.sigmoid(jnp.asarray(rng.randn(B, S, n), jnp.float32))
+    rows = []
+    for rep in reps:
+        if n % rep or not pallas_delta.supports(S, n // rep, n, d, d, chunk):
+            say(f"scalar kernels: {n} heads over {n // rep} at chunk {chunk} "
+                f"are not theirs")
+            continue
+        q, k = (jnp.asarray(rng.randn(B, S, n // rep * d), bf)
+                for _ in range(2))
+        ops = ((q, k, v), decoder_ops._chunk_sums(g, chunk), beta)
+        _, states = pallas_delta._fwd_call(*ops, chunk, interpret)
+        fwd = _ms(lambda: pallas_delta._fwd_call(*ops, chunk, interpret))
+        bwd = _ms(lambda: pallas_delta._bwd_call(*ops, states, do, chunk,
+                                                 interpret))
+        rows.append({"chunk": chunk, "key_heads": n // rep, "rep": rep,
+                     "fwd_ms": fwd, "bwd_ms": bwd})
+        say(f"scalar-decay kernels, chunk {chunk}, {n // rep} key heads under "
+            f"{n} value heads ({rep} chains a grid step): forward {fwd:.3f} "
+            f"backward {bwd:.3f} ms a layer ({B} x {S})")
+    return rows
+
+
 def kernels(args) -> dict:
     """The rule's kernels by chunk, the composed form, the flash kernels by
     q / k width."""
     import jax
     import jax.numpy as jnp
-    from paddle_tpu.ops import decoder_ops, pallas_delta, pallas_mode
+    from paddle_tpu.ops import decoder_ops, pallas_mode
     cell = load_cell(args)
     model, p = cell["model"], cell["params"]
     B, S = p["batch"], p["seq"]
@@ -323,41 +461,13 @@ def kernels(args) -> dict:
     n, d = lin["num_heads"], lin["head_dim"]
     interpret = pallas_mode.interpret() if args.rehearsal else False
     rng = np.random.RandomState(args.seed % (2 ** 31))
-    bf = jnp.bfloat16
-    q, k, v, do = (jnp.asarray(rng.randn(B, S, n, d), bf) for _ in range(4))
-    g = -jnp.exp(jnp.asarray(rng.uniform(np.log(1e-3), np.log(1.6),
-                                         (B, S, n, d)), jnp.float32))
-    beta = jax.nn.sigmoid(jnp.asarray(rng.randn(B, S, n), jnp.float32))
-    flat = decoder_ops._flat
-    result = {"mode": "kernels", "delta": [], "flash": []}
-    for chunk in args.chunks:
-        if not pallas_delta.supports(S, n, n, d, d, chunk, channel=True):
-            say(f"delta kernels: chunk {chunk} at heads of {d} is not theirs")
-            continue
-        ops = (jnp.concatenate([flat(q), flat(k), flat(v)], axis=-1),
-               decoder_ops._chunk_sums(g, chunk), beta)
-        sums = _ms(jax.jit(lambda g: decoder_ops._chunk_sums(g, chunk)), g)
-        taken = pallas_delta.PACKED_FROM
-        for rows in args.packed_from or [taken]:
-            if rows != pallas_delta.PACKED_FROM:
-                pallas_delta.PACKED_FROM = rows     # read at a kernel's trace
-                jax.clear_caches()
-            o, states = pallas_delta._fwd_call(*ops, chunk, interpret)
-            fwd = _ms(lambda: pallas_delta._fwd_call(*ops, chunk, interpret))
-            bwd = _ms(lambda: pallas_delta._bwd_call(
-                *ops, states, flat(do), chunk, interpret))
-            result["delta"].append({
-                "chunk": chunk, "packed_from": rows, "fwd_ms": fwd,
-                "bwd_ms": bwd, "chunk_sums_ms": sums,
-                "finite": bool(jnp.isfinite(o).all())})
-            say(f"KDA kernels, chunk {chunk}, lower rows alone from blocks of "
-                f"{rows}: forward {fwd:.3f} backward {bwd:.3f} ms a layer "
-                f"({B} x {S}, {n} heads of {d}); the running sums of g "
-                f"around them {sums:.3f} ms")
-        if pallas_delta.PACKED_FROM != taken:
-            pallas_delta.PACKED_FROM = taken
-            jax.clear_caches()
-
+    feeds = q, k, v, do, g, beta = channel_feeds(B, S, n, d, rng)
+    result = {"mode": "kernels", "flash": [], "delta": delta_kernels(
+        feeds, args.chunks, args.packed_from, args.step_heads, interpret)}
+    if args.scalar_reps:
+        result["scalar"] = scalar_kernels(
+            B, S, n, d, min(args.chunks[0], S), args.scalar_reps, interpret,
+            rng)
     first = min(args.chunks[0], S)
 
     def composed(q, k, v, g, beta):
@@ -395,6 +505,14 @@ def main(argv=None) -> int:
         ap.add_argument("--packed-from", nargs="*", type=int, default=[],
                         help="kernels: block lengths in pallas_delta."
                              "PACKED_FROM's place, e.g. 4 8 16")
+        ap.add_argument("--step-heads", nargs="*", type=int, default=[],
+                        help="kernels: heads a grid step in pallas_delta."
+                             "CHANNEL_HEADS's place, e.g. 1 2 4 8, each "
+                             "with the empty bodies' time beside it")
+        ap.add_argument("--scalar-reps", nargs="*", type=int, default=[],
+                        help="kernels: the scalar-decay pair at as many "
+                             "value heads, by value heads a key head, e.g. "
+                             "1 2 4")
         ap.add_argument("--seeds", nargs="*", type=int, default=[],
                         help="readings: a check each")
     laguna_probe.load_cell = load_cell      # its modes load the cell by it
