@@ -113,7 +113,13 @@ class DistributedStrategy:
       Conventional axes: "dp" (data), "mp" (tensor/model), "pp" (pipeline),
       "sp" (sequence/context), "ep" (expert/embedding).
     param_rules: [(regex, PartitionSpec-like tuple)] matched against parameter
-      names, first match wins; unmatched params are replicated.
+      names, first match wins; unmatched params are replicated. A split the
+      program declares on a variable (``Variable.declare_sharding``, set by
+      the layer that knows which axis its state is split over: an expert
+      layer's stacked weights under ``expert_axis``, a vocabulary's rows)
+      outranks these rules, for the parameter and the optimizer's
+      accumulators of its shape alike; a rule of the variable's rank that
+      disagrees is an error (``CompiledProgram.state_sharding``).
     data_rules: [(regex, spec)] for feed vars; default shards dim 0 over "dp".
     comm_compression: 'off'|'bf16'|'int8' -- compress the dp-axis gradient
       allreduce (quantize -> psum -> dequantize with a per-tensor
@@ -316,6 +322,9 @@ class CompiledProgram:
                                          *([None] * (ndim - 1))))
         v = self.program.global_block().find_var_recursive(name)
         spec = ds.param_spec(name) if v is not None else P()
+        declared = self._declared_spec(v, spec)
+        if declared is not None:
+            return NamedSharding(mesh, declared)
         if v is not None and len(spec) > len(v.shape):
             # a param rule matched a lower-rank derived var (e.g. Adam's
             # beta_pow accumulator sharing the param's name prefix): replicate
@@ -350,6 +359,26 @@ class CompiledProgram:
                         f"the full ZeRO memory win; other state still "
                         f"shards)")
         return NamedSharding(mesh, spec)
+
+    def _declared_spec(self, v, ruled):
+        """The PartitionSpec of the split variable ``v`` declares
+        (``Variable.declare_sharding``), which outranks the strategy's
+        ``param_rules``; None where it declares none. An axis the mesh does
+        not have holds the dimension whole. ``ruled`` is what the first
+        matching rule gives the name: a rule of the variable's rank that
+        says otherwise is an error that names both."""
+        from jax.sharding import PartitionSpec as P
+        declared = getattr(v, "sharding", None) if v is not None else None
+        if declared is None:
+            return None
+        spec = P(*(a if a in self.mesh.shape else None for a in declared))
+        if len(ruled) == len(v.shape) and tuple(ruled) != tuple(declared):
+            raise ValueError(
+                f"{v.name} declares the split {tuple(declared)} "
+                f"(Variable.declare_sharding) and the strategy's param_rules "
+                f"give it {tuple(ruled)}: take the rule out, or make them "
+                f"agree")
+        return spec
 
     # Program-API passthroughs used by Executor
     def global_block(self):

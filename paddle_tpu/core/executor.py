@@ -325,7 +325,8 @@ def trace_block(block: Block, env: Dict[str, Any], base_key, block_runner=None,
             (ns[0] for ns in op.outputs.values() if ns and ns[0] != EMPTY_VAR), op.type)
         ctx = LowerCtx(op.attrs, base_key, stable_salt(salt_name),
                        block_runner=block_runner, program=block.program, mesh=mesh,
-                       gspmd_mesh=gspmd_mesh, data_axis=data_axis)
+                       gspmd_mesh=gspmd_mesh, data_axis=data_axis,
+                       op_idx=op_idx)
         # the call's seconds go to the program's lowering notes by op type
         # (trace time only)
         began = _obs_lowerings.lowering_began(notes)
@@ -431,6 +432,50 @@ def _mesh_shardings(program: Program, feed_names, fetch_names, mut_names,
     replicated = NamedSharding(mesh, P())
     return ((state(mut_names), state(ro_names), feeds, replicated),
             ([replicated] * len(fetch_names), state(state_out)))
+
+
+def _declared_shardings(program: Program, feed_names, fetch_names, state_in,
+                        state_out):
+    """The shardings of a program run without a strategy that only creates
+    state (a startup program: no feed, no state read) and names the mesh to
+    create it on (``Program.state_mesh_shape``, set by whoever builds the
+    program: the ``mesh_shape`` the steps' ``DistributedStrategy`` will
+    take, over the same leading devices): the variables that declare a
+    split (``Variable.declare_sharding``) are created split over that mesh,
+    every other one whole on each of its devices -- a model whose state no
+    one device holds, as a step under the strategy then takes it as it
+    lies. None where the program names no mesh, or one of more devices than
+    this host has (the program at a small size on one device): the state is
+    created on the default device as ever."""
+    import jax
+    shape = getattr(program, "state_mesh_shape", None)
+    sizes = list((shape or {}).values())
+    if (not shape or feed_names or state_in
+            or int(np.prod(sizes)) > jax.device_count()):
+        return None
+    from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
+    # DistributedStrategy.build_mesh's: the leading devices, in order
+    mesh = Mesh(np.array(jax.devices()[:int(np.prod(sizes))]).reshape(sizes),
+                tuple(shape))
+    var_of = program.global_block().find_var_recursive
+    whole = NamedSharding(mesh, P())
+
+    def sharding(name):
+        v = var_of(name)
+        spec = getattr(v, "sharding", None)
+        if not spec:
+            return whole
+        spec = tuple(a if a in mesh.shape else None for a in spec)
+        for d, a in zip(v.shape, spec):
+            if a is not None and d % mesh.shape[a]:
+                raise ValueError(
+                    f"{name} declares dimension {d} split over mesh axis "
+                    f"{a!r}, which has {mesh.shape[a]} devices "
+                    f"(Program.state_mesh_shape {dict(shape)})")
+        return NamedSharding(mesh, P(*spec))
+    return (({}, {}, {}, whole),
+            ([whole] * len(fetch_names),
+             {n: sharding(n) for n in state_out}))
 
 
 def _jit_step(fn, mut_names, ro_names, state_out, fetch_names,
@@ -2119,7 +2164,10 @@ class Executor:
                              fetch_names, _mesh_shardings(
                                  *names, wrapper, wrapper.state_sharding))
         return _jit_step(_make_step(program, fetch_names, state_out),
-                         mut_names, ro_names, state_out, fetch_names)
+                         mut_names, ro_names, state_out, fetch_names,
+                         _declared_shardings(program, feed_names,
+                                             fetch_names, state_in,
+                                             state_out))
 
     def _explicit_dp(self, program: Program, feed_names, fetch_names,
                      mut_names, ro_names, state_out, wrapper):

@@ -60,7 +60,7 @@ class LowerCtx:
 
     def __init__(self, attrs: dict, base_key=None, salt: int = 0, block_runner=None,
                  program=None, mesh=None, gspmd_mesh=None, abstract=False,
-                 data_axis=None):
+                 data_axis=None, op_idx=None):
         self.attrs = attrs
         self._base_key = base_key
         self._salt = salt
@@ -82,6 +82,14 @@ class LowerCtx:
         # kernel-or-composed rule (the executor books its trace seconds
         # under family "kernel", observability/lowerings.py)
         self.asked_kernels = False
+        # the op's index in its block (the ``<type>#<idx>`` of its trace
+        # scope): a lowering that names a scope of its own inside gives it
+        # the same number (``moe_exchange.out#<idx>``)
+        self.op_idx = op_idx
+        # True on the context a generic grad op lowers its forward again
+        # with (under jax.vjp): what that forward reports or names is the
+        # backward's
+        self.under_grad = False
 
     def attr(self, name, default=None):
         return self.attrs.get(name, default)
@@ -137,6 +145,33 @@ class LowerCtx:
             local, mesh=mesh, in_specs=P(),
             out_specs=P(axis, *([None] * (len(shape) - 1))))(key)
 
+    def island(self, fn, args, split, shards=None, axis=None):
+        """``fn(*args)`` on each device's own rows, in a ``shard_map``
+        island over the strategy's data axis: the arguments ``split`` marks
+        (one bool an argument) are cut along their leading dimension, the
+        others are whole on every device, and every result comes back laid
+        over the axis along its leading dimension. The one door through
+        which a kernel family calls a Mosaic kernel under a GSPMD mesh (a
+        jit over several devices refuses one outside a ``shard_map``):
+        ``pallas_mode.lowers_kernels`` answers "kernels" there exactly where
+        this opens an island. ``shards`` is ``data_shards`` of the split
+        arguments' leading dimensions where the caller has it already; with
+        1 -- one device, no mesh, inside another island -- it is
+        ``fn(*args)``. ``axis``: another mesh axis than the data axis (an
+        expert layer's ``expert_axis``)."""
+        import jax
+        axis = axis or self.data_axis
+        if shards is None:
+            shards = self.axis_shards(axis, *(
+                a.shape[0] for a, cut in zip(args, split) if cut))
+        if shards == 1:
+            return fn(*args)
+        from jax.sharding import PartitionSpec as P
+        return jax.shard_map(
+            fn, mesh=self.gspmd_mesh,
+            in_specs=tuple(P(axis) if cut else P() for cut in split),
+            out_specs=P(axis), check_vma=False)(*args)
+
     def data_shards(self, *dims) -> int:
         """The devices of the strategy's data axis over which an op may lay
         leading dimensions of sizes ``dims`` in a ``shard_map`` island of its
@@ -145,9 +180,13 @@ class LowerCtx:
         already -- an op lowered inside another op's ``shard_map`` over the
         mesh (the pipeline's stages) sees ``gspmd_mesh`` too, and opens no
         island inside the island."""
+        return self.axis_shards(self.data_axis, *dims)
+
+    def axis_shards(self, axis, *dims) -> int:
+        """``data_shards`` over the mesh axis ``axis``."""
         import jax
         mesh = self.gspmd_mesh
-        n = mesh.shape.get(self.data_axis, 1) if mesh is not None else 1
+        n = mesh.shape.get(axis, 1) if mesh is not None else 1
         if (n <= 1 or not dims or any(d % n for d in dims)
                 or jax.sharding.get_abstract_mesh().manual_axes):
             return 1
@@ -338,7 +377,8 @@ def _generic_grad_lower(fwd: OpDef, ctx, ins):
                      if not k.startswith("__fwd_")}
     fwd_ctx = LowerCtx(fwd_attrs, ctx._base_key, ctx._salt, ctx.block_runner,
                        ctx.program, ctx.mesh, gspmd_mesh=ctx.gspmd_mesh,
-                       data_axis=ctx.data_axis)
+                       data_axis=ctx.data_axis, op_idx=ctx.op_idx)
+    fwd_ctx.under_grad = True
 
     def f(*diff_vals):
         full = {s: list(ins[s]) for s in fwd_in_slots}

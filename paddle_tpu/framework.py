@@ -122,6 +122,13 @@ class Variable:
         # Initializer attached by layers/initializer.py; consumed when building the
         # startup program entry for this variable.
         self.initializer = initializer
+        # The split this variable declares over named mesh axes: None, or one
+        # entry a dimension (an axis name, or None for a dimension held whole)
+        # -- set with ``declare_sharding`` by the layer that knows which axis
+        # its state is split over (an expert layer's stacked weights, a
+        # vocabulary's rows). CompiledProgram.state_sharding reads it ahead
+        # of the strategy's param_rules.
+        self.sharding = None
 
     # -- info ------------------------------------------------------------------------
     @property
@@ -130,6 +137,20 @@ class Variable:
 
     def astype_shape(self, batch: int) -> tuple:
         return tuple(batch if d == -1 else d for d in self.shape)
+
+    def declare_sharding(self, *spec) -> "Variable":
+        """Declare this variable's split: one entry a dimension, the name of
+        the mesh axis the dimension is split over or None. The startup
+        program's variable of the same name takes the declaration too, so
+        that a startup program which names its mesh
+        (``Program.state_mesh_shape``) creates the state split."""
+        if len(spec) != len(self.shape):
+            raise ValueError(f"declare_sharding: {self.name} has "
+                             f"{len(self.shape)} dimensions, got {spec!r}")
+        self.sharding = tuple(spec) if any(a is not None for a in spec) \
+            else None
+        self.block.program._bump()
+        return self
 
     def to_dict(self) -> dict:
         d = {
@@ -140,6 +161,8 @@ class Variable:
         if isinstance(self, Parameter):
             d["is_parameter"] = True
             d["trainable"] = self.trainable
+        if self.sharding is not None:
+            d["sharding"] = list(self.sharding)
         return d
 
     def __repr__(self):
@@ -464,6 +487,12 @@ class Program:
         # what the op lowerings report (LowerCtx.report) to the executor that
         # is compiling this program: {(family, op salt, labels): amount}
         self._lowering_notes: Dict[tuple, Any] = {}
+        # a startup program's: the mesh ({axis: devices}, a
+        # DistributedStrategy's mesh_shape) to create its state on when it
+        # is run without a strategy, the variables that declare a split
+        # (Variable.declare_sharding) split over it. None: the default
+        # device, as ever (core/executor.py:_declared_shardings)
+        self.state_mesh_shape: Optional[Dict[str, int]] = None
 
     def _bump(self):
         self._version += 1
@@ -596,6 +625,8 @@ class Program:
                                  persistable=vd["persistable"],
                                  stop_gradient=vd["stop_gradient"],
                                  is_data=vd["is_data"], type=vd["type"])
+                if vd.get("sharding"):
+                    v.sharding = tuple(vd["sharding"])
                 b.vars[v.name] = v
             for od in bd["ops"]:
                 b.ops.append(Operator(b, od["type"], od["inputs"], od["outputs"],

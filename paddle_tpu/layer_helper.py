@@ -18,8 +18,11 @@ class ParamAttr:
 
     def __init__(self, name=None, initializer=None, learning_rate=1.0,
                  regularizer=None, trainable=True, gradient_clip=None,
-                 do_model_average=True):
+                 do_model_average=True, sharding=None):
         self.name = name
+        # the split the parameter declares over named mesh axes, one entry a
+        # dimension (framework.Variable.declare_sharding)
+        self.sharding = sharding
         self.initializer = initializer
         self.learning_rate = learning_rate
         self.regularizer = regularizer
@@ -43,6 +46,16 @@ class ParamAttr:
 
 
 WeightNormParamAttr = ParamAttr  # placeholder parity
+
+
+def _declare(sharding, var, startup_block) -> None:
+    """``var`` and the startup program's variable of its name declare the
+    split ``sharding`` (None: nothing is declared)."""
+    if sharding is None:
+        return
+    var.declare_sharding(*sharding)
+    if var.name in startup_block.vars:
+        startup_block.vars[var.name].declare_sharding(*sharding)
 
 
 class LayerHelper:
@@ -90,16 +103,19 @@ class LayerHelper:
         startup_block = self.startup_program.global_block()
         if not any(name in op.output_arg_names() for op in startup_block.ops):
             initializer(p, startup_block)
+        _declare(attr.sharding, p, startup_block)
         return p
 
     def create_global_variable(self, shape, dtype="float32", persistable=True,
-                               name=None, initializer=None, stop_gradient=True):
+                               name=None, initializer=None, stop_gradient=True,
+                               sharding=None):
         block = self.main_program.global_block()
         v = block.create_var(name or unique_name.generate(self.name + ".global"),
                              shape, dtype, persistable=persistable,
                              stop_gradient=stop_gradient)
         if initializer is not None:
             initializer(v, self.startup_program.global_block())
+        _declare(sharding, v, self.startup_program.global_block())
         return v
 
     def append_bias_op(self, x: Variable, dim_start=1, bias_attr=None,

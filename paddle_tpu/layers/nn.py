@@ -1347,7 +1347,7 @@ def moe_bias_update(bias, load, rate, name=None):
 def moe_ffn(x, num_experts, experts_per_token, expert_width, param_attr=None,
             name="moe", experts_held=None, scoring="softmax", norm_topk=False,
             routed_scale=1.0, expert_bias=False, row_budget=None,
-            shared_width=None, shared_gate=False):
+            shared_width=None, shared_gate=False, expert_axis=None):
     """A dropless mixture-of-experts feed-forward layer over tokens
     ``x [T, H]``: a float32 router (softmax over the experts, top-k values
     used as they are, or under ``norm_topk`` over their sum, times
@@ -1370,7 +1370,8 @@ def moe_ffn(x, num_experts, experts_per_token, expert_width, param_attr=None,
     whole layer would give it; the stacked weights hold the held experts
     only, and the output is the held experts' part of each token's sum (an
     assignment to an expert held elsewhere adds nothing here: what an
-    exchange would bring is not stood in for). The row buffers keep all
+    exchange would bring is not stood in for; ``expert_axis`` below is the
+    layer with its exchange). The row buffers keep all
     T x k rows, so nothing can overflow whatever the routing -- unless the
     layer states a ``row_budget`` R (with ``experts_held`` only): the sort's
     output, the grouped products, the gated product and the combine are then
@@ -1379,6 +1380,32 @@ def moe_ffn(x, num_experts, experts_per_token, expert_width, param_attr=None,
     nothing to their tokens) and counted in ``<name>_dropped_rows [1]``, an
     int32 state variable summed over the steps (``aux["dropped"]``). A
     budget of T x k rows is the layer without one.
+
+    ``expert_axis`` (the counterpart of ``experts_held``: the exchange
+    itself, not one device's share without it): the layer holds all its
+    experts, split over the mesh axis of that name -- device c of its n
+    holds experts ``[c E / n, (c + 1) E / n)``; the three stacked weights
+    declare that split on their first dimension
+    (``Variable.declare_sharding``: ``CompiledProgram.state_sharding``
+    honours it ahead of any ``param_rules``, for the weights and their
+    optimizer state) -- with the tokens laid over the same axis. Run under
+    ``DistributedStrategy(mesh_shape={axis: n, ...})`` each device routes
+    its own tokens over all E experts and sorts their T / n x k rows by
+    expert, sends each row to the device that holds its expert
+    (``moe_dispatch``), runs the grouped products over the rows it received
+    for its E / n experts, sends the results back and sums each token's k
+    rows (``moe_combine``); the backward crosses twice more. Shapes are
+    static, so a device receives into a buffer of ``row_budget`` rows
+    (allowed here: it is the receive buffer's; an even router delivers T x
+    k / n; without one the buffer holds all T x k rows and nothing can
+    overflow); rows over it are dropped and counted in
+    ``<name>_dropped_rows`` (all devices'). The expert weights' gradients
+    come through the exchange's transpose and are not summed over the axis;
+    the router's, like every replicated parameter's, are. On one device, or
+    under a mesh without that axis, nothing crosses and the layer is the
+    one without ``expert_axis``, bit for bit; the Program's intermediate
+    variables keep the shapes of that layer (under the mesh the sorted
+    buffers are n x ``row_budget`` rows).
 
     ``shared_width``: one shared expert beside the routed ones, a dense
     SwiGLU of that width over every token (``<name>_shared_gate_w`` /
@@ -1414,14 +1441,21 @@ def moe_ffn(x, num_experts, experts_per_token, expert_width, param_attr=None,
     if scoring == "softmax" and expert_bias:
         raise NotImplementedError(
             "moe_ffn: expert_bias is built for scoring='sigmoid' only")
-    if row_budget is not None and held == E:
+    if expert_axis is not None and held != E:
+        raise ValueError("moe_ffn: expert_axis splits all the layer's "
+                         "experts over a mesh axis; experts_held is one "
+                         "device's share without the exchange")
+    if row_budget is not None and held == E and expert_axis is None:
         raise ValueError("moe_ffn: a row_budget is for a layer that holds a "
-                         "part of its experts (experts_held)")
+                         "part of its experts (experts_held) or receives "
+                         "rows through an exchange (expert_axis)")
     init = ParamAttr._to_attr(param_attr).initializer
+    crossed = {} if expert_axis is None else {"expert_axis": str(expert_axis)}
 
-    def param(suffix, shape, dtype):
+    def param(suffix, shape, dtype, sharding=None):
         return helper.create_parameter(
-            ParamAttr(name=f"{name}_{suffix}", initializer=init), shape, dtype)
+            ParamAttr(name=f"{name}_{suffix}", initializer=init,
+                      sharding=sharding), shape, dtype)
 
     def op(type, inputs, outputs, attrs=None):
         helper.append_op(type, inputs=inputs, outputs=outputs,
@@ -1455,16 +1489,26 @@ def moe_ffn(x, num_experts, experts_per_token, expert_width, param_attr=None,
     sort_attrs = {"num_experts": E}
     groups = load           # rows a group of the sorted buffer, in its order
     sum_attrs = {}          # moe_combine's: where the held rows end
+    back_with = {}          # moe_combine's further inputs
     if held < E:            # the sort starts at the first held expert
         groups = _out(helper, "int32", stop_gradient=True)
         sorted_to["GroupCount"] = [groups]
         sort_attrs.update(first_expert=first, held=held)
         sum_attrs.update(held=held)
+    if crossed:             # the rows each expert received, every device's
+        groups, sent = (_out(helper, "int32", stop_gradient=True)
+                        for _ in range(2))
+        sorted_to.update(GroupCount=[groups], SendCount=[sent])
+        back_with["SendCount"] = [sent]
+        sort_attrs.update(crossed, recv_rows=int(row_budget or 0))
+        sum_attrs.update(crossed, recv_rows=int(row_budget or 0),
+                         num_experts=E)
     if row_budget is not None:
         dropped = _out(helper, "int32", stop_gradient=True)
         sorted_to["Dropped"] = [dropped]
-        sort_attrs.update(rows=int(row_budget))
-        sum_attrs.update(rows=int(row_budget))
+        if not crossed:
+            sort_attrs.update(rows=int(row_budget))
+            sum_attrs.update(rows=int(row_budget))
     op("moe_dispatch", {"X": [x], "Index": [index], "Weight": [weight]},
        sorted_to, sort_attrs)
     if row_budget is not None:
@@ -1477,8 +1521,10 @@ def moe_ffn(x, num_experts, experts_per_token, expert_width, param_attr=None,
     def experts(inp, suffix, shape):
         out = _out(helper, x.dtype)
         op("moe_expert_matmul",
-           {"X": [inp], "W": [param(suffix, shape, x.dtype)],
-            "Count": [groups]}, {"Out": [out]})
+           {"X": [inp], "W": [param(suffix, shape, x.dtype,
+                                    (expert_axis, None, None)
+                                    if crossed else None)],
+            "Count": [groups]}, {"Out": [out]}, dict(crossed))
         return out
 
     gated = swiglu(experts(rows, "gate_w", [held, H, width]),
@@ -1486,7 +1532,8 @@ def moe_ffn(x, num_experts, experts_per_token, expert_width, param_attr=None,
     down = experts(gated, "down_w", [held, width, H])
     out = _out(helper, x.dtype)
     op("moe_combine", {"X": [down], "Order": [order], "Slot": [slot],
-                       "GroupCount": [groups]}, {"Out": [out]}, sum_attrs)
+                       "GroupCount": [groups], **back_with}, {"Out": [out]},
+       sum_attrs)
     aux["routed"] = out
     if shared_width:
         def dense(inp, suffix, size):

@@ -96,6 +96,20 @@ pre-norm, without biases or dropout: ``h = x + r operator(norm(x))``,
   ``moe_row_budget``: the sorted rows such a layer keeps (``layers.moe_ffn``'s
   ``row_budget``; rows beyond it are dropped and counted). A sliced
   vocabulary is a smaller ``vocab_size``.
+- the deployment itself, where one host holds whole layers:
+  ``expert_axis`` names the mesh axis every expert layer's experts are split
+  over, with the exchange run (``layers.moe_ffn``'s ``expert_axis``: the
+  rows go to the chips that hold their experts and the results come back;
+  ``moe_row_budget`` is then a chip's receive buffer), and ``vocab_axis`` the
+  axis the embedding table's rows and the head's columns are split over
+  (declared on the parameters, ``Variable.declare_sharding``; the lookup,
+  the head's product and the cross-entropy over the whole vocabulary are
+  GSPMD's to partition). Both take effect under a
+  ``DistributedStrategy`` whose mesh has the axis; on one device the model
+  is the one without them.
+- ``use_sliding_window: true`` (Qwen's key set, Mellum's): read with
+  ``max_window_layers: 0`` and ``layer_types``, which names the
+  ``sliding_attention`` layers; a window only on those.
 - ``embedding_multiplier`` times the looked-up rows, and the logits over
   ``logits_scaling`` (both default 1); under ``tie_word_embeddings`` the
   logits are ``x tok_emb^T`` from the one float32 table, cast to ``dtype``
@@ -121,10 +135,12 @@ al., arXiv:2309.00071), Qwen3-Next-80B-A3B (HF ``modeling_qwen3_next.py``;
 the delta rule: Yang et al., arXiv:2412.06464), GLM-4.7-Flash (its
 ``config.json``, ``model_type: glm4_moe_lite``; latent attention:
 DeepSeek-V2, arXiv:2405.04434; routing and the prediction module:
-DeepSeek-V3, arXiv:2412.19437) and Kimi-Linear-48B-A3B-Instruct (its
+DeepSeek-V3, arXiv:2412.19437), Kimi-Linear-48B-A3B-Instruct (its
 ``config.json``, ``model_type: kimi_linear``; HF ``modeling_kimi.py`` of
 that repository; Kimi Delta Attention: the Kimi Linear report,
-arXiv:2510.26692).
+arXiv:2510.26692) and Mellum2-12B-A2.5B-Instruct (its ``config.json``,
+``model_type: mellum``: Qwen3-MoE's key set with ``layer_types`` and rotary
+parameters by layer type).
 
 Dtypes follow ``models/bert.py``: the embedding table is float32 whatever
 ``dtype`` says, activations are cast to ``dtype`` right after the lookup,
@@ -151,7 +167,7 @@ _REQUIRED = {"hidden_act": "silu", "attention_bias": False,
              "normalization_function": "rmsnorm",
              "moe_apply_router_weight_on_input": False,
              "moe_router_logit_softcapping": 0, "decoder_sparse_step": 1,
-             "use_sliding_window": False, "moe_layer_freq": 1}
+             "moe_layer_freq": 1}
 _OPERATORS = ("full_attention", "sliding_attention", "conv", "mamba",
               "linear_attention", "kda")
 _ATTENTION = ("full_attention", "sliding_attention")
@@ -177,6 +193,19 @@ def _check(cfg: dict) -> None:
     if "sliding_attention" in kinds and not cfg.get("sliding_window"):
         raise ValueError("decoder_lm: sliding_attention layers need "
                          "sliding_window")
+    if cfg.get("use_sliding_window") and (
+            "layer_types" not in cfg or cfg.get("max_window_layers", 0)):
+        # Qwen's own rule (the layers from max_window_layers on slide) is
+        # not guessed at: the config says which layers slide
+        raise NotImplementedError(
+            "decoder_lm: use_sliding_window=True is built where layer_types "
+            "names the sliding_attention layers and max_window_layers is 0 "
+            "(every layer takes the type layer_types gives it)")
+    if cfg.get("expert_axis") and cfg.get(
+            "num_experts_routed", _held(cfg)) != _held(cfg):
+        raise ValueError("decoder_lm: expert_axis splits all of a layer's "
+                         "experts over a mesh axis (the exchange is run); "
+                         "num_experts_routed is one chip's share without it")
     if "kda" in kinds:
         missing = [k for k in ("num_heads", "head_dim",
                                "short_conv_kernel_size")
@@ -278,10 +307,11 @@ def _check(cfg: dict) -> None:
         raise NotImplementedError(
             "decoder_lm: use_expert_bias is built for "
             "router_scoring='sigmoid' only")
-    if cfg.get("moe_row_budget") and cfg.get(
+    if cfg.get("moe_row_budget") and not cfg.get("expert_axis") and cfg.get(
             "num_experts_routed", _held(cfg)) == _held(cfg):
         raise ValueError("decoder_lm: moe_row_budget is for a layer that "
-                         "holds a part of its experts (num_experts_routed)")
+                         "holds a part of its experts (num_experts_routed) "
+                         "or exchanges its rows (expert_axis)")
     if sigmoid and ("router_aux_loss_coef" in cfg
                     or "router_z_loss_coef" in cfg):
         raise NotImplementedError(
@@ -405,19 +435,23 @@ def _eps(cfg: dict) -> float:
     return cfg["rms_norm_eps"] if "rms_norm_eps" in cfg else cfg["norm_eps"]
 
 
-def _attr(name: str) -> ParamAttr:
-    return ParamAttr(name=name, initializer=Normal(0.0, 0.02))
+def _attr(name: str, sharding=None) -> ParamAttr:
+    return ParamAttr(name=name, initializer=Normal(0.0, 0.02),
+                     sharding=sharding)
 
 
-def _linear(x, size: int, name: str):
-    return layers.fc(x, size, param_attr=_attr(name), bias_attr=False)
+def _linear(x, size: int, name: str, sharding=None):
+    return layers.fc(x, size, param_attr=_attr(name, sharding),
+                     bias_attr=False)
 
 
 def _embed(ids, cfg: dict):
     """The rows of the one float32 table ``tok_emb`` for ``ids``, times
     ``embedding_multiplier``, cast to the config's ``dtype``."""
+    axis = cfg.get("vocab_axis")    # the table's rows split over that axis
     x = layers.embedding(ids, [cfg["vocab_size"], cfg["hidden_size"]],
-                         dtype="float32", param_attr=_attr("tok_emb"))
+                         dtype="float32", param_attr=_attr(
+                             "tok_emb", (axis, None) if axis else None))
     if cfg.get("embedding_multiplier", 1) != 1:
         x = layers.scale(x, float(cfg["embedding_multiplier"]))
     if cfg.get("dtype", "float32") != "float32":
@@ -751,7 +785,8 @@ def experts(x, cfg: dict, name: str):
         expert_bias=_bias_chosen(cfg),
         row_budget=cfg.get("moe_row_budget"),
         shared_width=_shared_width(cfg),
-        shared_gate=bool(cfg.get("shared_expert_gate", False)))
+        shared_gate=bool(cfg.get("shared_expert_gate", False)),
+        expert_axis=cfg.get("expert_axis"))
 
 
 
@@ -873,7 +908,9 @@ def build(cfg: dict, ids, labels, labels_next=None) -> dict:
                 table = layers.cast(table, dtype)
             logits = layers.matmul(x, table, transpose_y=True)
         else:
-            logits = _linear(x, cfg["vocab_size"], "lm_head_w")
+            axis = cfg.get("vocab_axis")    # the head's columns likewise
+            logits = _linear(x, cfg["vocab_size"], "lm_head_w",
+                             (None, axis) if axis else None)
         if cfg.get("logits_scaling", 1) != 1:   # applied in float32
             if dtype != "float32":
                 logits = layers.cast(logits, "float32")

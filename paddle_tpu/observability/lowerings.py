@@ -35,9 +35,12 @@ FAMILIES = {
     # no shorter than s)
     # value_dim: the value head's width where it is not head_dim (latent
     # attention with v narrower than q / k), else 0
+    # mesh (here and on the families below that have it): island where the
+    # kernels ran on each device's own rows inside a shard_map island under a
+    # GSPMD mesh (LowerCtx.island), else none
     "attention_lowering_total": (
         COUNT, ("impl", "s", "block_q", "block_k", "kv_heads", "window",
-                "heads", "head_dim", "value_dim"),
+                "heads", "head_dim", "value_dim", "mesh"),
         "fused_attention ops compiled, by the lowering each took"),
     # amount: the K tiles the forward kernel passes over for one (batch,
     # head) (state=visited) and those a causal op leaves out because they lie
@@ -67,9 +70,11 @@ FAMILIES = {
     # ops/decoder_ops.py. direction: forward / backward; form: kernel (the
     # one-pass kernel of ops/pallas_rope.py; the backward is the same pass on
     # the cotangent and lowers no forward) / composed (the rotation left to
-    # XLA) / generic (a grad op without a cotangent)
+    # XLA) / generic (a grad op without a cotangent); impl: pallas where the
+    # form is the kernel, else composed (the label the other kernel families
+    # say it with)
     "rotary_lowering_total": (
-        COUNT, ("direction", "form"),
+        COUNT, ("direction", "form", "mesh", "impl"),
         "rotary_embedding ops and grad ops compiled, by form"),
     # core/registry.py:bernoulli_mask. draw: shard (each of the data axis'
     # `shards` devices drew its own part of the batch in a shard_map island)
@@ -122,9 +127,40 @@ FAMILIES = {
     # reduce without); bound: held (the layer holds a part of its experts:
     # the rows behind theirs are padding, not read) / all
     "moe_rows_lowering_total": (
-        COUNT, ("impl", "op", "bound"),
+        COUNT, ("impl", "op", "bound", "mesh"),
         "token sums of the expert layers compiled, by the lowering each "
         "took"),
+    # ops/decoder_ops.py:moe_expert_matmul. impl: pallas (megablox's grouped
+    # kernels) / composed (ragged_dot); mesh: island where the op ran on the
+    # device's own experts and the rows it received (attr expert_axis)
+    "moe_expert_matmul_lowering_total": (
+        COUNT, ("impl", "mesh"),
+        "grouped products of the expert layers compiled, by the lowering "
+        "each took"),
+    # ops/decoder_ops.py: a crossing of an expert layer's exchange (attr
+    # expert_axis: the rows to the devices that hold their experts, or the
+    # results back), forward or in a grad op. axis: the mesh axis the
+    # experts are split over; impl: ragged (jax.lax.ragged_all_to_all) /
+    # padded (all_to_all of a fixed part a pair of devices) / none (one
+    # device on that axis: nothing crosses); crossing: dispatch / combine /
+    # dispatch_grad / combine_grad, the four of a layer and step
+    "moe_exchange_lowering_total": (
+        COUNT, ("axis", "impl", "crossing"),
+        "crossings of the expert layers' exchange compiled, by the wire "
+        "each took"),
+    # amount: the rows a device sends away at one crossing of an expert
+    # layer's exchange IF THE ROUTER IS EVEN -- its assignments times
+    # (n - 1) / n over n devices: a constant of the shapes, set at the
+    # compile, which does not move with what a run's router sends --, all
+    # layers and the step's crossings of that direction (out: rows to their
+    # experts, in moe_dispatch and in moe_combine's backward; back: the
+    # results, in moe_combine and in moe_dispatch's backward). What a run's
+    # router sent is moe_dispatch's SendCount, which no job fetches
+    # (tools/mellum2_probe.py load does)
+    "moe_exchange_even_rows": (
+        GAUGE, ("direction",),
+        "rows a device would send away a step through the expert layers' "
+        "exchange under an even router (from shapes)"),
     # ops/decoder_ops.py:latent_qkv, the forward op. rotated: 1 where q_r and
     # the one key head k_r are rotated, 0 under rotate=False (positions
     # left to the linear layers); head_dim: the q / k head's width as
@@ -161,7 +197,9 @@ _BOOKED = ("", 0, ())
 LATER_LABELS = {
     "delta_lowering_total": {"decay": "head", "step_heads": "1"},
     "rms_norm_gated_lowering_total": {"activation": "silu"},
-    "attention_lowering_total": {"value_dim": 0},
+    "attention_lowering_total": {"value_dim": 0, "mesh": "none"},
+    "rotary_lowering_total": {"mesh": "none", "impl": "composed"},
+    "moe_rows_lowering_total": {"mesh": "none"},
 }
 
 

@@ -312,3 +312,151 @@ def c_sync_calc_stream(ctx, ins):
 @register("c_sync_comm_stream", grad="auto")
 def c_sync_comm_stream(ctx, ins):
     return {"Out": [ins["X"][0]]}
+
+
+# --------------------------------------------------------------------------------------
+# An expert layer's exchange (ops/decoder_ops.py: moe_dispatch / moe_combine
+# under attr ``expert_axis``)
+# --------------------------------------------------------------------------------------
+
+def exchange_impl() -> str:
+    """The wire an expert layer's exchange lowers here: "ragged"
+    (``jax.lax.ragged_all_to_all``: the live rows and nothing else cross,
+    into one receive buffer a device) on a TPU, "padded"
+    (``jax.lax.all_to_all`` of a fixed part of the buffer a pair of devices)
+    off one -- XLA's CPU backend has no ragged-all-to-all, so the CPU tests
+    run the padded wire. On the chip the two move a crossing in the same
+    time, and the padded wire's budget a PAIR of devices drops rows where a
+    device's one pool does not (PERF.md section 6, PR 55)."""
+    from . import pallas_mode
+    return "ragged" if pallas_mode.on_tpu() else "padded"
+
+
+class RowExchange:
+    """One device's plan of an expert layer's exchange over the ``n``
+    devices of mesh axis ``axis`` (built inside a ``shard_map`` island over
+    it), from ``cnt [n, E]``: the rows device j's sorted buffer holds for
+    expert e (every device's counts, all-gathered: each device computes the
+    whole plan and reads its own part). Device c holds experts ``[c E / n,
+    (c + 1) E / n)``. A sender's buffer is sorted by expert, so the rows for
+    device c are one run of it (``send_off``, ``sent``); the receive buffer
+    holds ``budget`` rows, source by source (``in_off``), and the grouped
+    products want them expert by expert, so a gather by ``to_expert_major``
+    follows the wire on the way out and one by ``to_source_major`` precedes
+    it on the way back.
+
+    Rows over the budget are dropped and counted (``dropped``, this
+    device's): under ``ragged`` the buffer is one pool, filled source by
+    source, and the overflow is cut from the last sources' runs; under
+    ``padded`` a pair of devices has ``budget / n`` rows, and a run over
+    that is cut. A cut run loses its last rows: its highest experts'.
+    ``kept [n]`` (of my run to each device) and ``group [E / n]`` (the rows
+    of each expert I hold, after the cuts) are what the layer's other ops
+    need; ``out`` and ``back`` are each other's transpose."""
+
+    def __init__(self, cnt, axis: str, n: int, budget: int, impl: str):
+        import jax
+        import jax.numpy as jnp
+        if impl not in ("ragged", "padded"):
+            raise ValueError(f"exchange impl {impl!r}")
+        if impl == "padded" and budget % n:
+            raise ValueError(f"a padded exchange cuts its buffer of "
+                             f"{budget} rows in {n} equal parts")
+        self.axis, self.n, self.budget, self.impl = axis, n, budget, impl
+        per = cnt.shape[1] // n
+        me = self.me = jax.lax.axis_index(axis)
+        by_owner = cnt.reshape(n, n, per)               # [source, owner, e]
+        sent = by_owner.sum(-1)                         # [source, owner]
+        if impl == "padded":
+            self.part = budget // n
+            keep = jnp.minimum(sent, self.part)
+            in_off = jnp.broadcast_to(
+                (jnp.arange(n, dtype=jnp.int32) * self.part)[:, None], (n, n))
+        else:
+            ends = jnp.minimum(jnp.cumsum(sent, axis=0), budget)
+            keep = jnp.diff(ends, axis=0, prepend=0)
+            in_off = ends - keep
+        send_off = jnp.cumsum(sent, axis=1) - sent      # [source, owner]
+        self.send_off = send_off[me]                    # my runs' starts
+        self.send_off_there = send_off[:, me]   # each source's run for me
+        self.sent, self.kept = sent[me], keep[me]               # [owner]
+        self.in_off_there = in_off[me]      # my run's place in each owner's
+        self.in_off, self.taken = in_off[:, me], keep[:, me]    # [source]
+        self.dropped = jnp.sum(sent[:, me] - keep[:, me])
+        # the rows of (source, held expert) that arrive here, after the cuts
+        ends = jnp.minimum(jnp.cumsum(by_owner[:, me], axis=-1),
+                           keep[:, me][:, None])
+        mine = jnp.diff(ends, axis=-1, prepend=0)               # [source, e]
+        self.group = mine.sum(0).astype(jnp.int32)
+        self.live = jnp.sum(self.group)
+        # a (source, expert) segment starts, in the receive buffer's order
+        # and in the experts' order
+        sm_start = (self.in_off[:, None] + jnp.cumsum(mine, axis=1)
+                    - mine).astype(jnp.int32)                   # [source, e]
+        em_len = mine.T.reshape(-1)                             # [(e, source)]
+        em_end = jnp.cumsum(em_len)
+        em_start = (em_end - em_len).astype(jnp.int32)
+        rows = jnp.arange(budget, dtype=jnp.int32)
+        seg = jnp.minimum(jnp.searchsorted(em_end, rows, side="right"),
+                          em_len.shape[0] - 1)
+        self.to_expert_major = jnp.clip(
+            sm_start.T.reshape(-1)[seg] + rows - em_start[seg], 0,
+            budget - 1)                 # expert-major row -> received row
+        sm_flat = sm_start.reshape(-1)                          # [(source, e)]
+        seg = jnp.maximum(jnp.searchsorted(sm_flat, rows, side="right") - 1,
+                          0)
+        self.to_source_major = jnp.clip(
+            em_start.reshape(per, n).T.reshape(-1)[seg] + rows - sm_flat[seg],
+            0, budget - 1)              # received row -> expert-major row
+
+    def out(self, take, sorted_rows: int):
+        """The sorted buffer's rows to the devices that hold their experts:
+        ``take(idx)`` gives the buffer's rows at the sorted positions
+        ``idx`` (so that a gather of the tokens' rows into sorted order and
+        the wire's own packing are one pass); returns ``[budget, ...]``
+        expert by expert, the rows behind ``live`` padding."""
+        import jax
+        import jax.numpy as jnp
+        n = self.n
+        if self.impl == "ragged":
+            buf = take(jnp.arange(sorted_rows, dtype=jnp.int32))
+            got = jax.lax.ragged_all_to_all(
+                buf, jnp.zeros((self.budget,) + buf.shape[1:], buf.dtype),
+                self.send_off.astype(jnp.int32), self.kept.astype(jnp.int32),
+                self.in_off_there.astype(jnp.int32),
+                self.taken.astype(jnp.int32), axis_name=self.axis)
+        else:
+            part = jnp.arange(self.part, dtype=jnp.int32)
+            idx = jnp.clip(self.send_off[:, None] + part[None, :], 0,
+                           sorted_rows - 1)
+            buf = take(idx.reshape(-1))
+            got = jax.lax.all_to_all(
+                buf.reshape((n, self.part) + buf.shape[1:]), self.axis, 0, 0
+            ).reshape((self.budget,) + buf.shape[1:])
+        return got[self.to_expert_major]
+
+    def back(self, y, sorted_rows: int):
+        """``y [budget, ...]`` expert by expert back to the devices the rows
+        came from: ``[sorted_rows, ...]`` in the sender's sorted order, a
+        dropped row's place zero."""
+        import jax
+        import jax.numpy as jnp
+        n = self.n
+        by_source = y[self.to_source_major]
+        if self.impl == "ragged":
+            return jax.lax.ragged_all_to_all(
+                by_source,
+                jnp.zeros((sorted_rows,) + y.shape[1:], y.dtype),
+                self.in_off.astype(jnp.int32), self.taken.astype(jnp.int32),
+                self.send_off_there.astype(jnp.int32),
+                self.kept.astype(jnp.int32), axis_name=self.axis)
+        got = jax.lax.all_to_all(
+            by_source.reshape((n, self.part) + y.shape[1:]), self.axis, 0, 0
+        ).reshape((self.budget,) + y.shape[1:])
+        rows = jnp.arange(sorted_rows, dtype=jnp.int32)
+        owner = jnp.maximum(
+            jnp.searchsorted(self.send_off, rows, side="right") - 1, 0)
+        at = rows - self.send_off[owner]
+        kept = (at < self.kept[owner]).reshape((-1,) + (1,) * (y.ndim - 1))
+        picked = got[owner * self.part + jnp.minimum(at, self.part - 1)]
+        return jnp.where(kept, picked, jnp.zeros((), y.dtype))

@@ -226,12 +226,19 @@ def _rotary_pass(ctx, x, backward):
         sin = -sin
     fits = (pallas_rope.supports(S, D)
             and not (backward and D > ROTARY_GRAD_KERNEL_MAX_DIM))
-    kernel = pallas_mode.lowers_kernels(ctx, "auto", fits)
+    # under a mesh: each device's own batch rows, in an island (ctx.island)
+    shards = ctx.data_shards(x.shape[0]) if x.ndim > 2 else 1
+    kernel = pallas_mode.lowers_kernels(ctx, "auto", fits, shards=shards)
     ctx.report("rotary_lowering_total",
                direction="backward" if backward else "forward",
-               form="kernel" if kernel else "composed")
+               form="kernel" if kernel else "composed",
+               impl="pallas" if kernel else "composed",
+               mesh="island" if kernel and shards > 1 else "none")
     if kernel:
-        return pallas_rope.rotate(x, cos, sin, rot, pallas_mode.interpret())
+        interpret = pallas_mode.interpret()
+        return ctx.island(
+            lambda x: pallas_rope.rotate(x, cos, sin, rot, interpret),
+            (x,), (True,), shards)
     # a slice, a roll of its lanes by half, a concatenate for the tail: what
     # XLA fuses best of the forms of this expression (PERF.md section 6)
     xf = (x if rot == D else x[..., :rot]).astype(jnp.float32)
@@ -555,39 +562,225 @@ def _movers(budgeted: bool = False, kernel=None):
                    _gather_kept_slots if budgeted else _gather_slots))
 
 
+def _bounds(count):
+    """``[G + 1]`` int32: the first sorted row of each of the groups whose
+    rows ``count [G]`` gives, and the end of the last."""
+    import jax.numpy as jnp
+    ends = jnp.cumsum(count)
+    return jnp.concatenate([jnp.zeros((1,), ends.dtype),
+                            ends]).astype(jnp.int32)
+
+
 def _held_rows(ctx, count):
     """(``bounds``, ``live``) of the movers from ``count [E]``, the rows of
     each group of the sorted buffer in its order (``GroupCount``, which a
     row budget has cut already): under attr ``held`` the bounds of the held
     groups and the end of their rows, the padding's start; without it every
     group's bounds and no padding (None)."""
-    import jax.numpy as jnp
     held = int(ctx.attr("held", 0))
-    ends = jnp.cumsum(count[:held] if held else count)
-    bounds = jnp.concatenate([jnp.zeros((1,), ends.dtype),
-                              ends]).astype(jnp.int32)
+    bounds = _bounds(count[:held] if held else count)
     return bounds, (bounds[-1] if held else None)
 
 
-def _sums_kernel(ctx, op, rows, slot, count):
+def _sums_kernel(ctx, op, rows, slot, count, shards=1):
     """How the token sums over the sorted ``rows [R, H]`` lower in the op
     that computes them (``op``: combine / dispatch_grad): the ``interpret``
     flag of the kernel's call where ``pallas_mode.lowers_kernels`` says so,
     the kernel takes the shapes and the op was given the groups' counts,
-    else None (``_sums``); reported as ``moe_rows_lowering_total``."""
+    else None (``_sums``); reported as ``moe_rows_lowering_total``.
+    ``shards`` > 1: the sums run inside the exchange's island, each device
+    over its own tokens and the sorted rows that came back to it (``rows``
+    and ``slot`` are then one device's, as shapes)."""
     from . import pallas_mode, pallas_moe_rows
     kernel = pallas_mode.lowers_kernels(
         ctx, "auto", count is not None and pallas_moe_rows.supports(
-            slot.shape[0], rows.shape[0], rows.shape[1], rows.dtype))
+            slot.shape[0], rows.shape[0], rows.shape[1], rows.dtype),
+        shards=shards)
     ctx.report("moe_rows_lowering_total",
                impl="pallas" if kernel else "composed", op=op,
-               bound="held" if int(ctx.attr("held", 0)) else "all")
+               bound="held" if int(ctx.attr("held", 0)) else "all",
+               mesh="island" if kernel and shards > 1 else "none")
     return pallas_mode.interpret() if kernel else None
+
+
+def sort_by_expert(index, n_experts: int, first: int = 0):
+    """The stable sort of the ``index [T, k]`` assignments by expert,
+    counted from expert ``first`` on and wrapping around: ``order [T * k]``
+    the flat assignment of each sorted row, ``slot [T, k]`` the sorted row
+    of each assignment, ``count [n_experts]`` the rows of each expert in
+    the sorted order."""
+    import jax.numpy as jnp
+    flat = index.reshape(-1).astype(jnp.int32)
+    if first:
+        flat = (flat - first) % n_experts
+    order = jnp.argsort(flat, stable=True).astype(jnp.int32)
+    rows = jnp.arange(flat.shape[0], dtype=jnp.int32)
+    slot = jnp.zeros_like(rows).at[order].set(
+        rows, unique_indices=True).reshape(index.shape)
+    count = jnp.sum(flat[:, None] == jnp.arange(n_experts, dtype=jnp.int32),
+                    axis=0, dtype=jnp.int32)
+    return order, slot, count
+
+
+def _exchange_shards(ctx, tokens: int) -> int:
+    """The devices an expert layer's exchange crosses here: those of the
+    mesh axis attr ``expert_axis`` names, where the op is lowered under a
+    GSPMD mesh on which that axis has several devices that divide the
+    tokens and the experts; else 1 (no such attr, one device, shape
+    inference, inside another island), and the op is the layer without an
+    exchange."""
+    axis = ctx.attr("expert_axis", "")
+    return ctx.axis_shards(axis, tokens, int(ctx.attr("num_experts", 0))
+                           or tokens) if axis else 1
+
+
+def _exchange_scope(ctx, grad: bool, way: str):
+    """The trace scope of one crossing: ``moe_exchange.<way>#<op>`` in a
+    forward op, ``moe_exchange_grad.<way>#<op>`` in a backward (``way``: out,
+    the rows to their experts, or back)."""
+    import jax
+    return jax.named_scope(
+        f"moe_exchange{'_grad' if grad else ''}.{way}#{ctx.op_idx or 0}")
+
+
+def _exchange(ctx, n: int, tokens: int, k: int):
+    """(``axis``, ``n``, rows a device's receive buffer holds, the wire) of
+    the exchange of an op over ``tokens`` tokens (all devices') choosing
+    ``k`` experts each: attr ``recv_rows``, a device's row budget, or
+    without one every assignment of every device (nothing can be dropped)."""
+    from . import collective
+    budget = int(ctx.attr("recv_rows", 0)) or tokens * k
+    return ctx.attr("expert_axis"), n, budget, collective.exchange_impl()
+
+
+def _under_grad(ctx) -> bool:
+    """Whether the lowering is a grad op's: its own registered one (only a
+    grad op's desc carries the forward's first output's name), or the
+    forward a generic grad op lowers again under ``jax.vjp``."""
+    return ctx.under_grad or bool(ctx.attr("__fwd_out0__", ""))
+
+
+def _sums_kernel_here(ctx, op, n: int, order, slot, width: int, dtype, cnt):
+    """``_sums_kernel`` for the token sums inside an exchange's island: one
+    device's ``order.shape[0] / n`` sorted rows of ``width`` for its ``slot
+    .shape[0] / n`` tokens; returns the rows' shape beside the answer."""
+    import jax
+    import jax.numpy as jnp
+    here = jax.ShapeDtypeStruct((order.shape[0] // n, width), dtype)
+    return here, _sums_kernel(
+        ctx, op, here, jax.ShapeDtypeStruct(
+            (slot.shape[0] // n, slot.shape[1]), jnp.int32), cnt, n)
+
+
+def _report_exchange(ctx, plan, rows: int, way: str, crossing: str) -> None:
+    """One crossing compiled (``crossing``: dispatch / combine and their
+    ``_grad``s, four a layer and step): the wire it took, and the rows of a
+    device's ``rows`` assignments that leave it when the router is even
+    (``moe_exchange_even_rows``: a constant of the shapes)."""
+    axis, n, _, impl = plan
+    ctx.report("moe_exchange_lowering_total", axis=axis, impl=impl,
+               crossing=crossing)
+    ctx.report("moe_exchange_even_rows", rows * (n - 1) // n, direction=way)
+
+
+def _exchange_movers(ctx, plan, k: int, sums_interpret, grad: bool):
+    """The three movements of ``_movers`` across the exchange ``plan``
+    (``_exchange``), for use inside its island: token rows ``[T, H]`` ->
+    the rows the held experts received ``[budget, H]``, those rows -> token
+    sums, router weights ``[T, k]`` -> the received rows' weights; each
+    ``f(x, order, slot, cnt)`` with the device's own sort (``order``,
+    ``slot``) and every device's counts ``cnt [n, E]``, and each one's
+    transpose another of them. ``sums_interpret``: ``_sums_kernel``'s
+    answer for the token sums over the rows that came back. ``grad``: the
+    op being lowered is a backward's (the scopes' names)."""
+    import jax
+    from .collective import RowExchange
+    sums = _sums(False, sums_interpret)
+
+    def crossing(cnt):
+        return RowExchange(cnt, *plan)
+
+    def mine(cnt):          # the bounds of my own sorted buffer's groups
+        return _bounds(cnt[jax.lax.axis_index(plan[0])])
+
+    def rows_out(x, order, slot, cnt, back: bool):
+        with _exchange_scope(ctx, grad or back, "out"):
+            return crossing(cnt).out(lambda at: x[order[at] // k],
+                                     order.shape[0])
+
+    def rows_back(y, order, slot, cnt, back: bool):
+        with _exchange_scope(ctx, grad or back, "back"):
+            home = crossing(cnt).back(y, order.shape[0])
+        return sums(home, order, slot, mine(cnt), None)
+
+    def weights_out(w, order, slot, cnt, back: bool):
+        with _exchange_scope(ctx, grad or back, "out"):
+            return crossing(cnt).out(lambda at: w.reshape(-1)[order[at]],
+                                     order.shape[0])
+
+    def weights_back(g, order, slot, cnt, back: bool):
+        with _exchange_scope(ctx, grad or back, "back"):
+            return crossing(cnt).back(g, order.shape[0])[slot]
+
+    def moved(fwd_rows, bwd_rows):
+        @jax.custom_vjp
+        def f(x, order, slot, cnt):
+            return fwd_rows(x, order, slot, cnt, False)
+
+        def fwd(x, order, slot, cnt):
+            return fwd_rows(x, order, slot, cnt, False), (order, slot, cnt)
+
+        def bwd(res, g):
+            return bwd_rows(g, *res, True), None, None, None
+
+        f.defvjp(fwd, bwd)
+        return f
+    return (moved(rows_out, rows_back), moved(rows_back, rows_out),
+            moved(weights_out, weights_back), weights_back)
+
+
+def _dispatch_exchange(ctx, x, index, weight, n: int):
+    """``moe_dispatch`` under attr ``expert_axis`` on a mesh whose axis of
+    that name has ``n`` > 1 devices: an island over the axis in which each
+    device sorts its own tokens' assignments over all the experts, sends
+    each row and its weight to the device that holds its expert (device c
+    holds experts ``[c E / n, (c + 1) E / n)``) and lays what it received
+    out expert by expert. What the outputs then are is in ``moe_dispatch``'s
+    docstring."""
+    import jax
+    import jax.numpy as jnp
+    from jax.sharding import PartitionSpec as P
+    from .collective import RowExchange
+    E, k = int(ctx.attr("num_experts")), index.shape[1]
+    plan = axis, _, budget, _ = _exchange(ctx, n, x.shape[0], k)
+    ctx.report("moe_row_budget", budget * n)
+    _report_exchange(ctx, plan, x.shape[0] // n * k, "out", "dispatch")
+    to_owners, _, weights_to_owners, _ = _exchange_movers(
+        ctx, plan, k, None, False)
+
+    def local(x, index, weight):
+        order, slot, count = sort_by_expert(index, E)
+        cnt = jax.lax.all_gather(count, axis)
+        crossing = RowExchange(cnt, *plan)
+        return (to_owners(x, order, slot, cnt),
+                weights_to_owners(weight, order, slot, cnt), order, slot,
+                jax.lax.psum(count, axis), crossing.group, cnt,
+                jax.lax.psum(crossing.dropped, axis).reshape(1)
+                .astype(jnp.int32))
+
+    cut, whole = P(axis), P()
+    out, row_weight, order, slot, load, group, cnt, dropped = jax.shard_map(
+        local, mesh=ctx.gspmd_mesh, in_specs=(cut, cut, cut),
+        out_specs=(cut, cut, cut, cut, whole, cut, whole, whole),
+        check_vma=False)(x, index, weight)
+    return {"Out": [out], "RowWeight": [row_weight], "Order": [order],
+            "Slot": [slot], "Count": [load], "GroupCount": [group],
+            "SendCount": [cnt], "Dropped": [dropped]}
 
 
 @register("moe_dispatch", nondiff_inputs=("Index",),
           nondiff_outputs=("Order", "Slot", "Count", "GroupCount",
-                           "Dropped"))
+                           "Dropped", "SendCount"))
 def moe_dispatch(ctx, ins):
     """Sort the T x k assignments by expert (stable: within an expert, by
     token) and bring each one's token row and router weight into place.
@@ -623,28 +816,51 @@ def moe_dispatch(ctx, ins):
     names every assignment's sorted row, those from ``rows`` up being rows
     no buffer has (they add nothing in ``moe_combine``), ``GroupCount`` is
     cut so that it sums to ``rows``, and ``Dropped [1]`` int32 counts the
-    held experts' rows beyond the budget, which this step lost."""
+    held experts' rows beyond the budget, which this step lost.
+
+    Attr ``expert_axis`` (with all the experts held, no ``rows``): the
+    experts are split over the mesh axis of that name, device c of its n
+    holding experts ``[c E / n, (c + 1) E / n)``, and the tokens are laid
+    over the same axis. Lowered under a mesh with n > 1 such devices
+    dividing tokens and experts, the op is an island over the axis
+    (``_dispatch_exchange``): each device sorts its own tokens' assignments
+    and sends every row, and its weight, to the device that holds its
+    expert. ``Out [n * R, H]`` and ``RowWeight [n * R]`` are then the rows
+    each device *received*, laid over the axis, expert by expert within a
+    device, R = attr ``recv_rows`` (a device's receive buffer; 0: every
+    assignment of every device, so that nothing can overflow) and the rows
+    behind a device's live ones padding; ``Order`` / ``Slot`` each device's
+    own sort of its own tokens; ``Count`` the assignments of each expert
+    over all the devices; ``GroupCount [E]`` the rows each expert received
+    after the budget's cuts (device c's part its experts'); ``SendCount [n,
+    E]`` every device's count by expert, from which ``moe_combine`` and the
+    grad ops rebuild the crossing (``collective.RowExchange``); ``Dropped
+    [1]`` the rows the receive buffers lost, all devices. The wire is
+    ``collective.exchange_impl()``. Where the axis has one device (or there
+    is no mesh) nothing crosses: the outputs are the layer's without the
+    attr, ``SendCount`` = ``Count [1, E]``, ``Dropped`` zero."""
     import jax.numpy as jnp
     x, index, weight = ins["X"][0], ins["Index"][0], ins["Weight"][0]
     n_experts = int(ctx.attr("num_experts"))
+    shards = _exchange_shards(ctx, x.shape[0])
+    if shards > 1:
+        return _dispatch_exchange(ctx, x, index, weight, shards)
     first = int(ctx.attr("first_expert", 0))
-    flat = index.reshape(-1).astype(jnp.int32)
-    if first:
-        flat = (flat - first) % n_experts
-    order = jnp.argsort(flat, stable=True).astype(jnp.int32)
-    rows = jnp.arange(flat.shape[0], dtype=jnp.int32)
-    slot = jnp.zeros_like(rows).at[order].set(
-        rows, unique_indices=True).reshape(index.shape)
-    count = jnp.sum(flat[:, None] == jnp.arange(n_experts, dtype=jnp.int32),
-                    axis=0, dtype=jnp.int32)
+    order, slot, count = sort_by_expert(index, n_experts, first)
     budget = int(ctx.attr("rows", 0))
-    ctx.report("moe_row_budget", budget or flat.shape[0])
+    ctx.report("moe_row_budget", budget or index.size)
     outs = {"Slot": [slot], "GroupCount": [count],
             "Count": [jnp.roll(count, first) if first else count]}
+    if ctx.attr("expert_axis", ""):     # one device on that axis: no wire
+        ctx.report("moe_exchange_lowering_total",
+                   axis=ctx.attr("expert_axis"), impl="none",
+                   crossing="dispatch")
+        outs.update(SendCount=[count[None]],
+                    Dropped=[jnp.zeros((1,), jnp.int32)])
     if budget:
-        if budget > flat.shape[0]:
+        if budget > index.size:
             raise ValueError(f"moe_dispatch: a budget of {budget} rows for "
-                             f"{flat.shape[0]} assignments")
+                             f"{index.size} assignments")
         order = order[:budget]
         ends = jnp.minimum(jnp.cumsum(count), budget)
         held_rows = jnp.sum(count[:int(ctx.attr("held"))])
@@ -675,6 +891,9 @@ def moe_dispatch_grad(ctx, ins, generic):
     count = ins.get("GroupCount", ins.get("Count", [None]))[0]
     if any(v is None for v in (g, gw, order, slot, count)):
         return generic()
+    shards = _exchange_shards(ctx, ins["X"][0].shape[0])
+    if shards > 1:
+        return _dispatch_grad_exchange(ctx, ins, g, gw, order, slot, shards)
     budgeted = bool(int(ctx.attr("rows", 0)))
     sums = _sums(budgeted, _sums_kernel(ctx, "dispatch_grad", g, slot, count))
     to_slots = _gather_kept_slots if budgeted else _gather_slots
@@ -682,6 +901,34 @@ def moe_dispatch_grad(ctx, ins, generic):
                             *_held_rows(ctx, count))],
             "Weight@GRAD": [to_slots(gw.astype(ins["Weight"][0].dtype),
                                      order, slot, None, None)]}
+
+
+def _dispatch_grad_exchange(ctx, ins, g, gw, order, slot, n: int):
+    """``moe_dispatch_grad`` across the exchange: the cotangent's rows (and
+    the weights') go back to the devices their tokens are on, and each
+    device sums its tokens' rows with the forward's own sort."""
+    import jax
+    from jax.sharding import PartitionSpec as P
+    x, weight, cnt = ins["X"][0], ins["Weight"][0], ins["SendCount"][0]
+    k = weight.shape[1]
+    plan = _exchange(ctx, n, x.shape[0], k)
+    axis = plan[0]
+    here, interpret = _sums_kernel_here(ctx, "dispatch_grad", n, order, slot,
+                                        g.shape[1], x.dtype, cnt)
+    _report_exchange(ctx, plan, here.shape[0], "back", "dispatch_grad")
+    _, to_tokens, _, weights_back = _exchange_movers(ctx, plan, k, interpret,
+                                                     True)
+
+    def local(g, gw, order, slot, cnt):
+        return (to_tokens(g, order, slot, cnt),
+                weights_back(gw, order, slot, cnt, True))
+
+    cut = P(axis)
+    dx, dw = jax.shard_map(
+        local, mesh=ctx.gspmd_mesh, in_specs=(cut, cut, cut, cut, P()),
+        out_specs=(cut, cut), check_vma=False)(
+            g.astype(x.dtype), gw.astype(weight.dtype), order, slot, cnt)
+    return {"X@GRAD": [dx], "Weight@GRAD": [dw]}
 
 
 def grouped_matmul(x, w, count, kernels: bool):
@@ -711,15 +958,59 @@ def moe_expert_matmul(ctx, ins):
     stacking the first G < E groups only, the rows after theirs are zero
     (``grouped_matmul``): megablox's kernels where
     ``pallas_mode.lowers_kernels`` says so (they take every shape, but are
-    not run in the test harness' interpreter), ``ragged_dot`` elsewhere."""
+    not run in the test harness' interpreter), ``ragged_dot`` elsewhere.
+
+    Attr ``expert_axis`` (``layers.moe_ffn``): the experts, and the rows
+    ``moe_dispatch`` brought them, are split over the mesh axis of that
+    name; under a mesh with several devices on it the product runs in an
+    island over the axis (``ctx.island``), each device over its own experts
+    and the rows it received, ``W``'s split being the one the parameter
+    declares. Which lowering an op took: ``moe_expert_matmul_lowering_total``."""
     from . import pallas_mode
-    kernels = pallas_mode.lowers_kernels(ctx, "auto",
-                                         not pallas_mode.interpret())
-    return {"Out": [grouped_matmul(ins["X"][0], ins["W"][0],
-                                   ins["Count"][0], kernels)]}
+    x, w, count = ins["X"][0], ins["W"][0], ins["Count"][0]
+    axis = ctx.attr("expert_axis", "")
+    shards = ctx.axis_shards(axis, x.shape[0], w.shape[0]) if axis else 1
+    kernels = pallas_mode.lowers_kernels(
+        ctx, "auto", not pallas_mode.interpret(), shards=shards)
+    ctx.report("moe_expert_matmul_lowering_total",
+               impl="pallas" if kernels else "composed",
+               mesh="island" if shards > 1 else "none")
+
+    def held(x, w, count):
+        # the rows behind the held experts' are one more group, without
+        # weights: not computed, zero (grouped_matmul)
+        import jax.numpy as jnp
+        if shards > 1:
+            count = jnp.concatenate(
+                [count, (x.shape[0] - jnp.sum(count)).reshape(1)
+                 .astype(count.dtype)])
+        return grouped_matmul(x, w, count, kernels)
+    return {"Out": [ctx.island(held, (x, w, count), (True, True, True),
+                               shards, axis or None)]}
 
 
-@register("moe_combine", nondiff_inputs=("Order", "Slot", "GroupCount"))
+def _combine_exchange(ctx, x, order, slot, cnt, n: int):
+    """``moe_combine`` across the exchange: the experts' results go back to
+    the devices their tokens are on (``RowExchange.back``), and each sums
+    its tokens' rows with its own sort, on the kernel where it runs."""
+    import jax
+    from jax.sharding import PartitionSpec as P
+    k = slot.shape[1]
+    plan = _exchange(ctx, n, slot.shape[0], k)
+    here, interpret = _sums_kernel_here(ctx, "combine", n, order, slot,
+                                        x.shape[1], x.dtype, cnt)
+    grad = _under_grad(ctx)     # the generic grad: the transpose goes out
+    _report_exchange(ctx, plan, here.shape[0], "out" if grad else "back",
+                     "combine_grad" if grad else "combine")
+    _, to_tokens, _, _ = _exchange_movers(ctx, plan, k, interpret, grad)
+    cut = P(plan[0])
+    return {"Out": [jax.shard_map(
+        to_tokens, mesh=ctx.gspmd_mesh, in_specs=(cut, cut, cut, P()),
+        out_specs=cut, check_vma=False)(x, order, slot, cnt)]}
+
+
+@register("moe_combine", nondiff_inputs=("Order", "Slot", "GroupCount",
+                                         "SendCount"))
 def moe_combine(ctx, ins):
     """Each token's output: the sum of its k assignments' rows (already
     weighted, see ``swiglu``'s ``Scale``). ``X [T*k, H]`` sorted rows,
@@ -737,8 +1028,18 @@ def moe_combine(ctx, ins):
     turn (the grouped products' grad ops read the held groups' rows only;
     zeroing it would be a second pass). Given ``GroupCount`` the sums lower
     as the kernel of ``ops/pallas_moe_rows.py`` where it runs, budget or
-    none (``moe_rows_lowering_total``)."""
+    none (``moe_rows_lowering_total``).
+
+    Attr ``expert_axis`` with the input ``SendCount`` (``moe_dispatch``'s):
+    where ``moe_dispatch`` crossed the exchange, ``X`` is the rows each
+    device's experts received, laid over the axis; they go back to the
+    devices their tokens are on and are summed there
+    (``_combine_exchange``)."""
     x, order, slot = ins["X"][0], ins["Order"][0], ins["Slot"][0]
+    shards = _exchange_shards(ctx, slot.shape[0])
+    if shards > 1:
+        return _combine_exchange(ctx, x, order, slot, ins["SendCount"][0],
+                                 shards)
     count = ins.get("GroupCount", [None])[0]
     if count is None and int(ctx.attr("held", 0)):
         raise ValueError("moe_combine: attr held needs the input GroupCount")

@@ -928,11 +928,13 @@ def supports_pallas(B, H, S, D, bias_shape, dropout, is_tpu):
 
 def _plan(ctx, q, k, v, bias):
     """Which lowering this op takes, from its attrs, its shapes and where it
-    is lowered: ``(impl, scale, dropout, causal, blocks, window)`` with
-    ``impl`` one of 'ulysses', 'ring', 'pallas', 'xla', ``blocks`` the
-    kernels' (block_q, block_k), None without them, and ``window`` the
+    is lowered: ``(impl, scale, dropout, causal, blocks, window, shards)``
+    with ``impl`` one of 'ulysses', 'ring', 'pallas', 'xla', ``blocks`` the
+    kernels' (block_q, block_k), None without them, ``window`` the
     sliding window the lowering applies (``sliding_window``: None for none
-    and for one no shorter than S). The forward op and its grad op both ask
+    and for one no shorter than S) and ``shards`` the devices of the data
+    axis over which the kernels run on each device's own batch rows
+    (``ctx.island``; 1: no island). The forward op and its grad op both ask
     here, so the two cannot disagree; what cannot run raises."""
     B, H, S, D = q.shape
     kv_heads = k.shape[1]
@@ -974,28 +976,33 @@ def _plan(ctx, q, k, v, bias):
                 f"[B,1,1,S] bias; got sp={sp_n}, S={S}, H={H} "
                 f"({h_local} heads per mp shard), "
                 f"bias={None if bias is None else bias.shape}")
-        return "ulysses", scale, dropout, causal, None, None
+        return "ulysses", scale, dropout, causal, None, None, 1
     if ring_ok and impl in ("auto", "ring"):
-        return "ring", scale, dropout, causal, None, None
+        return "ring", scale, dropout, causal, None, None, 1
 
     bias_shape = None if bias is None else bias.shape
-    # under a mesh of several devices only the islands above hold the
-    # kernels; 'auto' takes the composed lowering at every S and whatever a
-    # persisted decision says (a batch/head island of its own: PERF.md
-    # section 7)
+    # under a mesh of several devices the kernels run in an island over the
+    # data axis, each device on its own batch rows (ctx.island), where the
+    # batch divides over it and the op has neither bias nor dropout (a
+    # padding row's bias and the mask's seed would have to be cut with the
+    # batch); else 'auto' takes the composed lowering at every S and
+    # whatever a persisted decision says
+    shards = 1 if bias is not None or dropout else ctx.data_shards(B)
     kernels = pallas_mode.lowers_kernels(
-        ctx, impl, supports_pallas(B, H, S, D, bias_shape, dropout, is_tpu),
+        ctx, impl, supports_pallas(B // shards, H, S, D, bias_shape, dropout,
+                                   is_tpu),
         "fused_attention",
         f"requires S % {_MIN_BLK_Q} == 0, a [B,1,1,S] bias, and (for "
         f"dropout>0) a TPU; got S={S}, bias={bias_shape}, "
         f"dropout={dropout}, backend_tpu={is_tpu}. Use impl='auto' to let "
-        f"the op choose the composed lowering.")
+        f"the op choose the composed lowering.", shards=shards)
     # impl='auto' backend + block sizes are tunable choice points: a
     # persisted autotune decision (PADDLE_TPU_TUNE=cached/search) answers
     # where there is one, else the defaults measured on the v5e
     # (AUTO_PALLAS_MIN_S, default_block_q, default_block_k).
     from ..tuning import decide as _decide
-    tune_params = {"b": B, "h": H, "s": S, "d": D, "dtype": str(q.dtype),
+    tune_params = {"b": B // shards, "h": H, "s": S, "d": D,
+                   "dtype": str(q.dtype),
                    "has_bias": bias is not None, "dropout": dropout,
                    "causal": causal, "scale": scale}
     if window is not None:      # a bucket of its own, and its own defaults
@@ -1003,8 +1010,8 @@ def _plan(ctx, q, k, v, bias):
     if kernels and (impl == "pallas" or _decide(
             "fused_attention.backend", tune_params) == "pallas"):
         return "pallas", scale, dropout, causal, tuple(int(b) for b in _decide(
-            "fused_attention.block_sizes", tune_params)), window
-    return "xla", scale, dropout, causal, None, window
+            "fused_attention.block_sizes", tune_params)), window, shards
+    return "xla", scale, dropout, causal, None, window, 1
 
 
 def _kernel_seed(ctx, dropout):
@@ -1085,21 +1092,25 @@ def fused_attention(ctx, ins):
             q, k, v, bias, float(scale), 0.0, bool(ctx.attr("causal", False)),
             ctx.rng(), window=ctx.attr("window", 0))], "Lse": [no_stats()]}
 
-    impl, scale, dropout, causal, blocks, window = _plan(ctx, q, k, v, bias)
+    impl, scale, dropout, causal, blocks, window, shards = _plan(
+        ctx, q, k, v, bias)
     block_q, block_k = blocks or (0, 0)
     ctx.report("attention_lowering_total", impl=impl, s=S, block_q=block_q,
                block_k=block_k, kv_heads=kv_heads, window=window or 0,
                heads=H, head_dim=D,
-               value_dim=0 if v.shape[3] == D else v.shape[3])
+               value_dim=0 if v.shape[3] == D else v.shape[3],
+               mesh="island" if shards > 1 else "none")
     if impl == "pallas":
         from . import pallas_mode
         for state, tiles in zip(("visited", "skipped"),
                                 k_tiles(S, *blocks, causal, window)):
             ctx.report("attention_k_tiles_total", tiles, state=state,
                        window=window or 0)
-        out, lse = _flash_stats(q, k, v, bias, _kernel_seed(ctx, dropout),
-                                scale, dropout, causal,
-                                pallas_mode.interpret(), *blocks, window)
+        seed, interpret = _kernel_seed(ctx, dropout), pallas_mode.interpret()
+        out, lse = ctx.island(
+            lambda q, k, v: _flash_stats(q, k, v, bias, seed, scale, dropout,
+                                         causal, interpret, *blocks, window),
+            (q, k, v), (True, True, True), shards)
         return {"Out": [out], "Lse": [lse]}
     if impl == "xla":
         out = composed_attention(q, k, v, bias, scale, dropout, causal,
@@ -1132,14 +1143,18 @@ def fused_attention_grad(ctx, ins, generic):
     q, k, v = ins["Q"][0], ins["K"][0], ins["V"][0]
     bias = ins.get("Bias", [None])[0]
     lse, g = ins.get("Lse", [None])[0], ins.get("Out@GRAD", [None])[0]
-    impl, scale, dropout, causal, blocks, window = _plan(ctx, q, k, v, bias)
+    impl, scale, dropout, causal, blocks, window, shards = _plan(
+        ctx, q, k, v, bias)
     if impl != "pallas" or lse is None or g is None:
         ctx.report("attention_backward_total",
                    stats="recomputed" if impl == "pallas" else "generic")
         return generic()
     from . import pallas_mode
     ctx.report("attention_backward_total", stats="saved")
-    dq, dk, dv = _bwd_call(
-        q, k, v, bias, _kernel_seed(ctx, dropout), g.astype(q.dtype), lse,
-        scale, dropout, causal, pallas_mode.interpret(), *blocks, window)
+    seed, interpret = _kernel_seed(ctx, dropout), pallas_mode.interpret()
+    dq, dk, dv = ctx.island(
+        lambda q, k, v, g, lse: _bwd_call(
+            q, k, v, bias, seed, g, lse, scale, dropout, causal, interpret,
+            *blocks, window),
+        (q, k, v, g.astype(q.dtype), lse), (True,) * 5, shards)
     return {"Q@GRAD": [dq], "K@GRAD": [dk], "V@GRAD": [dv]}

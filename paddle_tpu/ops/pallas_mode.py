@@ -43,7 +43,7 @@ def available() -> bool:
 
 
 def lowers_kernels(ctx, impl: str, fits: bool, what: str = "",
-                   needs: str = "") -> bool:
+                   needs: str = "", shards: int = 1) -> bool:
     """Whether the op being lowered under ``ctx`` (a ``LowerCtx``), with attr
     ``impl`` (``auto`` / ``pallas`` / another lowering's name), lowers its
     Pallas kernels here; ``fits`` is the op's own answer to whether the
@@ -56,10 +56,16 @@ def lowers_kernels(ctx, impl: str, fits: bool, what: str = "",
     one device. A Mosaic call has no partitioning rule: a jit over more than
     one device refuses to lower one outside a ``shard_map`` ("Mosaic kernels
     cannot be automatically partitioned"; seen on the chip, PR 27), so under
-    a GSPMD mesh of several devices ``auto`` is the composed form, which
-    GSPMD partitions. Inside a ``shard_map`` (``ctx.mesh``, no GSPMD mesh)
-    the call is legal and stays allowed. Asking marks the op
-    (``ctx.asked_kernels``) as one this rule decides for."""
+    a GSPMD mesh of several devices ``auto`` is the kernels only where the
+    op calls them inside an island -- ``shards`` > 1: the devices of the
+    data axis over which the op will lay its batch rows through
+    ``ctx.island`` (``ctx.data_shards`` of those dimensions; ``fits`` is
+    then the op's answer for one device's rows), or the lowering is inside
+    a ``shard_map`` already (another op's island, ``ctx.mesh``) -- and the
+    composed form, which GSPMD partitions, otherwise: an op whose batch
+    does not divide over the data axis, or a family that has no island yet.
+    Asking marks the op (``ctx.asked_kernels``) as one this rule decides
+    for."""
     ctx.asked_kernels = True
     if ctx.abstract:
         return False
@@ -70,7 +76,14 @@ def lowers_kernels(ctx, impl: str, fits: bool, what: str = "",
         return True
     gm = ctx.gspmd_mesh
     return (impl == "auto" and fits and available()
-            and (gm is None or gm.size == 1))
+            and (gm is None or gm.size == 1 or shards > 1 or in_island()))
+
+
+def in_island() -> bool:
+    """Whether what is being traced is inside a ``shard_map`` (some mesh
+    axis is manual): a Mosaic call is legal there."""
+    import jax
+    return bool(jax.sharding.get_abstract_mesh().manual_axes)
 
 
 def require(what: str) -> None:

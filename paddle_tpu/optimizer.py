@@ -59,11 +59,15 @@ class Optimizer:
         if key in self._accumulators:
             return self._accumulators[key]
         helper = LayerHelper(name)
+        # an accumulator of the parameter's shape is split as the parameter
+        # declares itself split (framework.Variable.declare_sharding)
         v = helper.create_global_variable(
             list(shape if shape is not None else param.shape),
             dtype or "float32", persistable=True,
             name=unique_name.generate(f"{param.name}_{name}"),
-            initializer=Constant(float(fill_value)))
+            initializer=Constant(float(fill_value)),
+            sharding=None if shape is not None else getattr(
+                param, "sharding", None))
         self._accumulators[key] = v
         return v
 

@@ -521,8 +521,10 @@ def test_the_shares_and_the_gated_shared_expert_once_add_up_to_the_layer():
                       "full_attention"]}, NotImplementedError, "chunked"),
     ({"norm_form": "centered"}, NotImplementedError, "norm_form"),
     ({"gating": "per-head"}, NotImplementedError, "attn_output_gate"),
-    ({"use_sliding_window": True}, NotImplementedError,
-     "use_sliding_window"),
+    # since PR 55 read with layer_types and max_window_layers 0; Qwen's own
+    # rule (the layers from max_window_layers on slide) still raises
+    ({"use_sliding_window": True, "max_window_layers": 2},
+     NotImplementedError, "use_sliding_window"),
     ({"rope_scaling": {"type": "linear", "factor": 2}}, NotImplementedError,
      "rope_scaling"),
     ({"decoder_sparse_step": 2}, NotImplementedError, "decoder_sparse_step"),
