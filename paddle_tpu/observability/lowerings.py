@@ -148,6 +148,17 @@ FAMILIES = {
         COUNT, ("axis", "impl", "crossing"),
         "crossings of the expert layers' exchange compiled, by the wire "
         "each took"),
+    # ops/decoder_ops.py:_rows_kernel, once an op that crosses the exchange:
+    # how the rows it received change order (source by source <-> expert by
+    # expert, RowExchange.by_expert / by_source). impl: pallas (the kernel
+    # of ops/pallas_exchange_rows.py over the plan's segment tables) /
+    # composed (an index a row and a gather); mesh: island where the kernel
+    # ran, inside the exchange's own shard_map; way: out (rows to their
+    # experts: moe_dispatch, moe_combine's backward) / back
+    "moe_exchange_rows_lowering_total": (
+        COUNT, ("impl", "mesh", "way"),
+        "crossings of the expert layers' exchange compiled, by how the "
+        "received rows change order"),
     # amount: the rows a device sends away at one crossing of an expert
     # layer's exchange IF THE ROUTER IS EVEN -- its assignments times
     # (n - 1) / n over n devices: a constant of the shapes, set at the

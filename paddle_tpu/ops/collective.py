@@ -13,6 +13,7 @@ bootstrap (SURVEY.md §5.8); multi-host init is jax.distributed (parallel/env.py
 """
 from __future__ import annotations
 
+import functools
 from typing import Dict, Optional
 
 from ..core.registry import register
@@ -341,9 +342,16 @@ class RowExchange:
     (c + 1) E / n)``. A sender's buffer is sorted by expert, so the rows for
     device c are one run of it (``send_off``, ``sent``); the receive buffer
     holds ``budget`` rows, source by source (``in_off``), and the grouped
-    products want them expert by expert, so a gather by ``to_expert_major``
-    follows the wire on the way out and one by ``to_source_major`` precedes
-    it on the way back.
+    products want them expert by expert: what arrived from source s for
+    held expert e is one contiguous *segment* of either order
+    (``segments``: its start source by source, its start expert by expert,
+    its length), so the change of order that follows the wire on the way
+    out (``by_expert``) and precedes it on the way back (``by_source``)
+    moves ``n x E / n`` segments. ``kernel`` (the ``interpret`` flag of its
+    call, or None) says whether the kernel of ``ops/pallas_exchange_rows.py``
+    moves them, from the three tables; the composed form builds one index a
+    row (``to_expert_major`` / ``to_source_major``, computed where it is
+    read) and gathers.
 
     Rows over the budget are dropped and counted (``dropped``, this
     device's): under ``ragged`` the buffer is one pool, filled source by
@@ -354,7 +362,8 @@ class RowExchange:
     of each expert I hold, after the cuts) are what the layer's other ops
     need; ``out`` and ``back`` are each other's transpose."""
 
-    def __init__(self, cnt, axis: str, n: int, budget: int, impl: str):
+    def __init__(self, cnt, axis: str, n: int, budget: int, impl: str,
+                 kernel=None, me=None):
         import jax
         import jax.numpy as jnp
         if impl not in ("ragged", "padded"):
@@ -363,8 +372,11 @@ class RowExchange:
             raise ValueError(f"a padded exchange cuts its buffer of "
                              f"{budget} rows in {n} equal parts")
         self.axis, self.n, self.budget, self.impl = axis, n, budget, impl
+        self.kernel = kernel
         per = cnt.shape[1] // n
-        me = self.me = jax.lax.axis_index(axis)
+        # ``me``: this device's place on the axis, for a plan built outside
+        # an island over it (tools/mellum2_probe.py passes)
+        me = self.me = jax.lax.axis_index(axis) if me is None else me
         by_owner = cnt.reshape(n, n, per)               # [source, owner, e]
         sent = by_owner.sum(-1)                         # [source, owner]
         if impl == "padded":
@@ -389,25 +401,72 @@ class RowExchange:
         mine = jnp.diff(ends, axis=-1, prepend=0)               # [source, e]
         self.group = mine.sum(0).astype(jnp.int32)
         self.live = jnp.sum(self.group)
-        # a (source, expert) segment starts, in the receive buffer's order
-        # and in the experts' order
+        # where a (source, expert) segment starts in the receive buffer's
+        # order and in the experts' order, and its rows: each [source, e]
         sm_start = (self.in_off[:, None] + jnp.cumsum(mine, axis=1)
-                    - mine).astype(jnp.int32)                   # [source, e]
-        em_len = mine.T.reshape(-1)                             # [(e, source)]
-        em_end = jnp.cumsum(em_len)
-        em_start = (em_end - em_len).astype(jnp.int32)
-        rows = jnp.arange(budget, dtype=jnp.int32)
-        seg = jnp.minimum(jnp.searchsorted(em_end, rows, side="right"),
-                          em_len.shape[0] - 1)
-        self.to_expert_major = jnp.clip(
-            sm_start.T.reshape(-1)[seg] + rows - em_start[seg], 0,
-            budget - 1)                 # expert-major row -> received row
-        sm_flat = sm_start.reshape(-1)                          # [(source, e)]
-        seg = jnp.maximum(jnp.searchsorted(sm_flat, rows, side="right") - 1,
-                          0)
-        self.to_source_major = jnp.clip(
-            em_start.reshape(per, n).T.reshape(-1)[seg] + rows - sm_flat[seg],
-            0, budget - 1)              # received row -> expert-major row
+                    - mine).astype(jnp.int32)
+        em_end = jnp.cumsum(mine.T.reshape(-1)).reshape(per, n).T
+        self.segments = (sm_start, (em_end - mine).astype(jnp.int32),
+                         mine.astype(jnp.int32))
+
+    @functools.cached_property
+    def to_expert_major(self):
+        """int32 ``[budget]``: the received row each expert-major row is
+        (the composed form's index; the rows behind ``live`` name clipped
+        copies of real rows)."""
+        import jax.numpy as jnp
+        sm_start, em_start, mine = (v.T.reshape(-1) for v in self.segments)
+        rows = jnp.arange(self.budget, dtype=jnp.int32)
+        seg = jnp.minimum(
+            jnp.searchsorted(em_start + mine, rows, side="right",
+                             method="compare_all"), mine.shape[0] - 1)
+        return jnp.clip(sm_start[seg] + rows - em_start[seg], 0,
+                        self.budget - 1)
+
+    @functools.cached_property
+    def to_source_major(self):
+        """int32 ``[budget]``: the expert-major row each received row is."""
+        import jax.numpy as jnp
+        sm_start, em_start, _ = (v.reshape(-1) for v in self.segments)
+        rows = jnp.arange(self.budget, dtype=jnp.int32)
+        seg = jnp.maximum(
+            jnp.searchsorted(sm_start, rows, side="right",
+                             method="compare_all") - 1, 0)
+        return jnp.clip(em_start[seg] + rows - sm_start[seg], 0,
+                        self.budget - 1)
+
+    def _moved(self, x, index: str, src, dst, length):
+        """``x [budget, ...]`` in the other order: the kernel over the
+        segment tables (ascending in ``dst``) where it runs -- rows as they
+        are, a vector (the router weights) as the lanes of ``[budget,
+        128]``: 0.34 ms against the gather's 0.89 at 81,920 float32 (chip
+        runs, PR 56) --, else the gather by the index array ``index``."""
+        import jax.numpy as jnp
+        from . import pallas_exchange_rows as rows
+        if self.kernel is None or x.ndim > 2 or not rows.supports(
+                self.budget, x.shape[-1] if x.ndim == 2 else rows.LANES,
+                x.dtype):
+            return x[getattr(self, index)]
+        wide = x if x.ndim == 2 else jnp.broadcast_to(
+            x[:, None], (self.budget, rows.LANES))
+        moved = rows.move_segments(
+            wide, src.reshape(-1), dst.reshape(-1), length.reshape(-1),
+            interpret=self.kernel)
+        return moved if x.ndim == 2 else moved[:, 0]
+
+    def by_expert(self, got):
+        """Received rows ``[budget, ...]``, source by source -> expert by
+        expert (within an expert source by source), the rows behind
+        ``live`` padding: zero from the kernel, copies of real rows from
+        the gather; nothing reads them."""
+        sm_start, em_start, mine = (v.T for v in self.segments)
+        return self._moved(got, "to_expert_major", sm_start, em_start, mine)
+
+    def by_source(self, y):
+        """``by_expert``'s transpose: rows expert by expert -> source by
+        source, as the wire back sends them."""
+        sm_start, em_start, mine = self.segments
+        return self._moved(y, "to_source_major", em_start, sm_start, mine)
 
     def out(self, take, sorted_rows: int):
         """The sorted buffer's rows to the devices that hold their experts:
@@ -433,7 +492,7 @@ class RowExchange:
             got = jax.lax.all_to_all(
                 buf.reshape((n, self.part) + buf.shape[1:]), self.axis, 0, 0
             ).reshape((self.budget,) + buf.shape[1:])
-        return got[self.to_expert_major]
+        return self.by_expert(got)
 
     def back(self, y, sorted_rows: int):
         """``y [budget, ...]`` expert by expert back to the devices the rows
@@ -442,7 +501,7 @@ class RowExchange:
         import jax
         import jax.numpy as jnp
         n = self.n
-        by_source = y[self.to_source_major]
+        by_source = self.by_source(y)
         if self.impl == "ragged":
             return jax.lax.ragged_all_to_all(
                 by_source,

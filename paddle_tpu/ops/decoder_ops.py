@@ -683,7 +683,27 @@ def _report_exchange(ctx, plan, rows: int, way: str, crossing: str) -> None:
     ctx.report("moe_exchange_even_rows", rows * (n - 1) // n, direction=way)
 
 
-def _exchange_movers(ctx, plan, k: int, sums_interpret, grad: bool):
+def _rows_kernel(ctx, plan, width: int, dtype, way: str):
+    """How the rows an op's crossing of the exchange ``plan`` receives
+    change order (``RowExchange.by_expert`` / ``by_source``; ``way``: out /
+    back), for rows ``width`` wide: the ``interpret`` flag of the kernel of
+    ``ops/pallas_exchange_rows.py`` where ``pallas_mode.lowers_kernels``
+    says so for the exchange's island and the kernel takes the buffer, else
+    None (an index a row and a gather); reported as
+    ``moe_exchange_rows_lowering_total``."""
+    from . import pallas_exchange_rows, pallas_mode
+    _, n, budget, _ = plan
+    kernel = pallas_mode.lowers_kernels(
+        ctx, "auto", pallas_exchange_rows.supports(budget, width, dtype),
+        shards=n)
+    ctx.report("moe_exchange_rows_lowering_total",
+               impl="pallas" if kernel else "composed",
+               mesh="island" if kernel else "none", way=way)
+    return pallas_mode.interpret() if kernel else None
+
+
+def _exchange_movers(ctx, plan, k: int, sums_interpret, grad: bool,
+                     rows_kernel=None):
     """The three movements of ``_movers`` across the exchange ``plan``
     (``_exchange``), for use inside its island: token rows ``[T, H]`` ->
     the rows the held experts received ``[budget, H]``, those rows -> token
@@ -691,14 +711,16 @@ def _exchange_movers(ctx, plan, k: int, sums_interpret, grad: bool):
     ``f(x, order, slot, cnt)`` with the device's own sort (``order``,
     ``slot``) and every device's counts ``cnt [n, E]``, and each one's
     transpose another of them. ``sums_interpret``: ``_sums_kernel``'s
-    answer for the token sums over the rows that came back. ``grad``: the
-    op being lowered is a backward's (the scopes' names)."""
+    answer for the token sums over the rows that came back;
+    ``rows_kernel``: ``_rows_kernel``'s for the received rows' change of
+    order. ``grad``: the op being lowered is a backward's (the scopes'
+    names)."""
     import jax
     from .collective import RowExchange
     sums = _sums(False, sums_interpret)
 
     def crossing(cnt):
-        return RowExchange(cnt, *plan)
+        return RowExchange(cnt, *plan, kernel=rows_kernel)
 
     def mine(cnt):          # the bounds of my own sorted buffer's groups
         return _bounds(cnt[jax.lax.axis_index(plan[0])])
@@ -756,7 +778,8 @@ def _dispatch_exchange(ctx, x, index, weight, n: int):
     ctx.report("moe_row_budget", budget * n)
     _report_exchange(ctx, plan, x.shape[0] // n * k, "out", "dispatch")
     to_owners, _, weights_to_owners, _ = _exchange_movers(
-        ctx, plan, k, None, False)
+        ctx, plan, k, None, False,
+        _rows_kernel(ctx, plan, x.shape[1], x.dtype, "out"))
 
     def local(x, index, weight):
         order, slot, count = sort_by_expert(index, E)
@@ -916,8 +939,9 @@ def _dispatch_grad_exchange(ctx, ins, g, gw, order, slot, n: int):
     here, interpret = _sums_kernel_here(ctx, "dispatch_grad", n, order, slot,
                                         g.shape[1], x.dtype, cnt)
     _report_exchange(ctx, plan, here.shape[0], "back", "dispatch_grad")
-    _, to_tokens, _, weights_back = _exchange_movers(ctx, plan, k, interpret,
-                                                     True)
+    _, to_tokens, _, weights_back = _exchange_movers(
+        ctx, plan, k, interpret, True,
+        _rows_kernel(ctx, plan, g.shape[1], x.dtype, "back"))
 
     def local(g, gw, order, slot, cnt):
         return (to_tokens(g, order, slot, cnt),
@@ -1000,9 +1024,12 @@ def _combine_exchange(ctx, x, order, slot, cnt, n: int):
     here, interpret = _sums_kernel_here(ctx, "combine", n, order, slot,
                                         x.shape[1], x.dtype, cnt)
     grad = _under_grad(ctx)     # the generic grad: the transpose goes out
-    _report_exchange(ctx, plan, here.shape[0], "out" if grad else "back",
+    way = "out" if grad else "back"
+    _report_exchange(ctx, plan, here.shape[0], way,
                      "combine_grad" if grad else "combine")
-    _, to_tokens, _, _ = _exchange_movers(ctx, plan, k, interpret, grad)
+    _, to_tokens, _, _ = _exchange_movers(
+        ctx, plan, k, interpret, grad,
+        _rows_kernel(ctx, plan, x.shape[1], x.dtype, way))
     cut = P(plan[0])
     return {"Out": [jax.shard_map(
         to_tokens, mesh=ctx.gspmd_mesh, in_specs=(cut, cut, cut, P()),

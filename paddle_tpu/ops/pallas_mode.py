@@ -13,9 +13,10 @@ composed form chooses between them. The lowerings that ask it:
 ``fused_attention`` and its grad op (``pallas_attention._plan``),
 ``rotary_embedding`` and its grad op, ``short_conv``, ``ssd_scan``,
 ``gated_delta_rule`` and its grad op, ``moe_expert_matmul`` (megablox's
-``gmm``), ``rms_norm`` given a gate and its grad op, and the token sums of
-``moe_combine`` and of ``moe_dispatch``'s grad op, those seven in
-``decoder_ops``. ``conv2d_bn_fused`` (``pallas_conv_bn``)
+``gmm``), ``rms_norm`` given a gate and its grad op, the token sums of
+``moe_combine`` and of ``moe_dispatch``'s grad op, and the change of order
+of the rows an expert layer's exchange received (``_rows_kernel``), those
+eight in ``decoder_ops``. ``conv2d_bn_fused`` (``pallas_conv_bn``)
 and the int8 matmul (``contrib/quantize``) still test the platform
 themselves (ROADMAP D2).
 """

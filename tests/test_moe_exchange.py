@@ -330,3 +330,60 @@ def test_the_plan_of_a_crossing_against_brute_force(impl, budget, seed):
             row for row in bufs[j] if row in kept]
         assert all(home[i] in (None, bufs[j][i])
                    for i in range(len(bufs[j])))
+
+
+def _crossings_compiled(H, **kw):
+    """``moe_exchange_rows_lowering_total`` of a layer's train step on dp4
+    at width ``H``, by (impl, mesh, way), and what the step fetched."""
+    from paddle_tpu.observability import lowerings
+    from paddle_tpu.observability.metrics import MetricsRegistry
+    x = np.random.RandomState(4).randn(64, H).astype(np.float32)
+    seen, real = {}, lowerings.publish
+
+    def publish(notes, program, registry=None, role=""):
+        fresh = MetricsRegistry()
+        real(dict(notes), program, fresh, role)
+        seen[role] = fresh
+        return real(notes, program, registry, role)
+    lowerings.publish = publish
+    try:
+        got, held = run_layer(x, mesh=True, H=H, expert_axis="dp", **kw)
+    finally:
+        lowerings.publish = real
+    family = seen["train"].get("moe_exchange_rows_lowering_total")
+    count = {}
+    for labels, child in family.items():
+        labels = dict(labels)
+        key = labels["impl"], labels["mesh"], labels["way"]
+        count[key] = count.get(key, 0) + child.value
+    return count, got, held
+
+
+def test_the_received_rows_change_order_composed_off_a_tpu():
+    """At the CPU tests' widths (no whole vreg of lanes) the composed
+    gather stays, and says so: four crossings a layer and step."""
+    count, _, _ = _crossings_compiled(32, row_budget=64)
+    assert count == {("composed", "none", "out"): 2,
+                     ("composed", "none", "back"): 2}
+
+
+def test_the_received_rows_change_order_by_the_kernel_in_the_island():
+    """What the chip reports (``impl=pallas, mesh=island``: the labels
+    ``kernels.mesh_island_ops.mellum2`` matches), here in the interpreter
+    at a width the kernel takes: the same step as the composed gather's,
+    bit for bit -- the kernel performs the gather's permutation."""
+    from paddle_tpu.ops import pallas_mode
+    count, got, held = _crossings_compiled(128, row_budget=128)
+    assert count == {("pallas", "island", "out"): 2,
+                     ("pallas", "island", "back"): 2}
+    pallas_mode.TEST_INTERPRET = False      # no kernel can run: composed
+    try:
+        weights = {n: v for n, v in held.items() if n.startswith("moe_")
+                   and not n.endswith("dropped_rows")}
+        x = np.random.RandomState(4).randn(64, 128).astype(np.float32)
+        want, _ = run_layer(x, weights, mesh=True, H=128, expert_axis="dp",
+                            row_budget=128)
+    finally:
+        pallas_mode.TEST_INTERPRET = True
+    for a, b in zip(got, want):
+        assert np.array_equal(a, b)

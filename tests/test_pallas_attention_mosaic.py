@@ -717,6 +717,27 @@ def test_token_sums_kernel_compiles_for_v5e(one_chip, cell):
                 and " parameter(" not in ln]
 
 
+@pytest.mark.parametrize("width,dtype", [(2304, jnp.bfloat16),
+                                         (128, jnp.float32)],
+                         ids=["rows", "weights_as_lanes"])
+def test_exchange_rows_kernel_compiles_for_v5e(one_chip, width, dtype):
+    """The change of order of the exchange's received rows
+    (ops/pallas_exchange_rows.py: slabs of the segments' rows by DMA, into
+    place by a sublane rotate) at mellum2_12b_a2_5b.pretrain_s4096_ep4's
+    buffer, 64 segments: one Mosaic call, no gather, the buffer read where
+    it is."""
+    from paddle_tpu.ops import pallas_exchange_rows
+    R = 81920
+    assert pallas_exchange_rows.supports(R, width, dtype)
+    table = jax.ShapeDtypeStruct((64,), jnp.int32, sharding=one_chip)
+    text = pallas_exchange_rows.move_segments.lower(
+        jax.ShapeDtypeStruct((R, width), dtype, sharding=one_chip),
+        table, table, table).compile().as_text()
+    assert text.count('custom_call_target="tpu_custom_call"') == 1
+    entry = text[text.index("\nENTRY "):]
+    assert " gather(" not in entry and " scatter(" not in entry
+
+
 @pytest.mark.parametrize("held", [False, True], ids=["all_held", "a_part"])
 def test_expert_layer_step_holds_nine_grouped_matmuls_and_one_sort_by_expert(
         one_chip, as_on_the_chip, held):

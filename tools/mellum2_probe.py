@@ -27,6 +27,19 @@ count=4``; a debug run: no device number).
 ``readings``  the two readings the check's limit is set from, over
               ``--seeds``: as it is, and float8 weights in the program's
               place.
+``passes``    one chip: the passes of a crossing around the wire, each
+              timed apart at the cell's shapes (``[budget, H]`` bfloat16,
+              the plan of a chip's four sources' sorts under a uniformly
+              random router and under one ``--tilt`` of whose tokens choose
+              expert 0 besides): the index arrays the composed change of
+              order builds (the default ``searchsorted`` and
+              ``compare_all``), its two gathers, ``take``, the zero fill;
+              the kernel of ``ops/pallas_exchange_rows.py`` both ways
+              (``--rows-plans``: also at these block x piece rows) and
+              whether its
+              output is the gather's bit for bit on the live rows and zero
+              behind them; the router weights' crossing as a gather and as
+              lanes through the kernel.
 ``share``     one chip's share of the same program without the exchange
               (``experts_held=(0, 16)``, the chip's 2 sequences, its
               vocabulary slice): ms a step; the cell's step less this is
@@ -37,6 +50,7 @@ from __future__ import annotations
 
 import contextlib
 import copy
+import importlib
 import os
 import sys
 import time
@@ -310,6 +324,115 @@ def wire(args) -> dict:
     return result
 
 
+def passes(args) -> dict:
+    """The passes of a crossing around the wire, apart, on one chip (the
+    module docstring's ``passes``)."""
+    import jax
+    import jax.numpy as jnp
+    from paddle_tpu.ops import pallas_exchange_rows as kernel_rows
+    from paddle_tpu.ops import pallas_mode
+    from paddle_tpu.ops.collective import RowExchange
+    from paddle_tpu.ops.decoder_ops import sort_by_expert
+    ms = laguna_probe._ms
+    cell = laguna_probe.load_cell(args)
+    n, model, params = cell["chips"], cell["model"], cell["params"]
+    T, k = params["batch"] * params["seq"] // n, model["num_experts_per_tok"]
+    E, H = model["num_experts"], model["hidden_size"]
+    budget = model["moe_row_budget"]
+    if args.rehearsal:      # a debug run: a width and a buffer the kernel
+        H, budget = 128, 256 * n                                # takes
+    dtype = jnp.bfloat16
+    interpret = pallas_mode.interpret() or (
+        args.rehearsal and not pallas_mode.on_tpu())
+    result = {"mode": "passes", "budget": budget, "width": H,
+              "rows_sorted": T * k, "device": jax.devices()[0].device_kind}
+    rng = np.random.RandomState(args.seed % 2 ** 31)
+    x = jnp.asarray(rng.randn(T, H), dtype)
+    got = jnp.asarray(rng.randn(budget, H), dtype)
+    weights = jnp.asarray(rng.rand(budget), jnp.float32)
+
+    def plan_of(cnt, kernel=None):
+        return RowExchange(cnt, "dp", n, budget, "ragged", kernel=kernel,
+                           me=jnp.int32(0))
+
+    def scan_indices(cnt):
+        """The index arrays as the parent of PR 56 built them: the default
+        ``searchsorted`` (a loop of element gathers)."""
+        plan = plan_of(cnt)
+        rows = jnp.arange(budget, dtype=jnp.int32)
+        sm, em, ln = (v.T.reshape(-1) for v in plan.segments)
+        seg = jnp.minimum(jnp.searchsorted(em + ln, rows, side="right"),
+                          ln.shape[0] - 1)
+        to_expert = jnp.clip(sm[seg] + rows - em[seg], 0, budget - 1)
+        sm, em, _ = (v.reshape(-1) for v in plan.segments)
+        seg = jnp.maximum(jnp.searchsorted(sm, rows, side="right") - 1, 0)
+        return to_expert, jnp.clip(em[seg] + rows - sm[seg], 0, budget - 1)
+
+    for name, tilt in (("uniform", 0.0), ("skewed", args.tilt or 0.3)):
+        logits = rng.randn(n, T, E)
+        logits[rng.rand(n, T) < tilt, 0] += 100.0
+        index = jnp.asarray(np.argsort(-logits, axis=-1)[..., :k], jnp.int32)
+        sorts = [sort_by_expert(index[j], E) for j in range(n)]
+        order, cnt = sorts[0][0], jnp.stack([c for _, _, c in sorts])
+        plan = plan_of(cnt)
+        live = int(plan.live)
+        to_expert, to_source = plan.to_expert_major, plan.to_source_major
+        scan = jax.jit(scan_indices)(cnt)
+        here = {
+            "live_rows": live, "dropped": int(plan.dropped),
+            "segment_rows_min_max": [int(plan.segments[2].min()),
+                                     int(plan.segments[2].max())],
+            "indices_agree": bool(jnp.array_equal(scan[0], to_expert)
+                                  & jnp.array_equal(scan[1], to_source)),
+            "ms": {
+                "index_arrays_scan": ms(jax.jit(scan_indices), cnt),
+                "index_arrays_compare_all": ms(jax.jit(
+                    lambda c: (plan_of(c).to_expert_major,
+                               plan_of(c).to_source_major)), cnt),
+                "gather_by_expert": ms(jax.jit(lambda g, i: g[i]), got,
+                                          to_expert),
+                "gather_by_source": ms(jax.jit(lambda g, i: g[i]), got,
+                                          to_source),
+                "composed_by_expert": ms(jax.jit(
+                    lambda g, c: plan_of(c).by_expert(g)), got, cnt),
+                "composed_by_source": ms(jax.jit(
+                    lambda g, c: plan_of(c).by_source(g)), got, cnt),
+                "take": ms(jax.jit(lambda x, o: x[o // k]), x, order),
+                "zero_fill": ms(jax.jit(
+                    lambda: jnp.zeros((budget, H), dtype))),
+                "weights_composed": ms(jax.jit(
+                    lambda w, c: plan_of(c).by_expert(w)), weights, cnt),
+                "weights_as_lanes": ms(jax.jit(
+                    lambda w, c: plan_of(c, interpret).by_expert(w)),
+                    weights, cnt)}}
+        want = {"by_expert": got[to_expert], "by_source": got[to_source]}
+        # the ragged wire: the live rows lead the buffer in both orders
+        ahead = jnp.arange(budget)[:, None] < live
+        for rows_plan in ["default"] + list(args.rows_plans):
+            if rows_plan != "default":
+                block, sub = map(int, rows_plan.split("x"))
+                kernel_rows.BLOCK_ROWS, kernel_rows.SUB_ROWS = block, sub
+            key = f"kernel_{rows_plan}"
+            try:
+                for way in ("by_expert", "by_source"):
+                    f = jax.jit(lambda g, c, way=way: getattr(
+                        plan_of(c, interpret), way)(g))
+                    out = f(got, cnt)
+                    equal = bool(jnp.array_equal(
+                        jnp.where(ahead, out, 0).view(jnp.uint16),
+                        jnp.where(ahead, want[way], 0).view(jnp.uint16)))
+                    zero = bool(jnp.all(jnp.where(ahead, 0, out) == 0))
+                    here["ms"][f"{key}_{way}"] = ms(f, got, cnt)
+                    here[f"{key}_{way}_bit_for_bit"] = equal and zero
+            except Exception as e:      # noqa: BLE001  a plan Mosaic
+                here[key] = f"{type(e).__name__}: {e}"[:400]    # refuses
+            finally:    # the module's own rows a block and a piece again
+                importlib.reload(kernel_rows)
+        result[name] = here
+        say(f"{name}: {here}")
+    return result
+
+
 def share(args) -> dict:
     """One chip's share without the exchange: the chip's 2 sequences, 16 of
     the 64 experts held (the router keeps its 64 outputs), a quarter of the
@@ -346,10 +469,14 @@ def options(ap):
     ap.add_argument("--impls", nargs="*", default=["ragged", "padded"])
     ap.add_argument("--seeds", nargs="*", type=int,
                     default=[2147480261, 2147480297, 2147480333])
+    ap.add_argument("--rows-plans", nargs="*", default=[],
+                    help="passes: also time the kernel at these rows a "
+                         "block x rows a piece, e.g. 512x128 2048x256")
 
 
 if __name__ == "__main__":
     sys.exit(laguna_probe.main(
         modes={"load": load, "wire": wire, "controls": controls,
-               "readings": readings, "share": share}, doc=__doc__,
+               "readings": readings, "share": share, "passes": passes},
+        doc=__doc__,
         options=options))
