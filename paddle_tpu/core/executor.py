@@ -31,7 +31,8 @@ from typing import Any, Dict, List, Optional, Sequence, Tuple
 
 import numpy as np
 
-from ..framework import (Program, Block, Variable, default_main_program)
+from ..framework import (Program, Block, Variable, VarType,
+                         default_main_program)
 from ..observability import fleet as _obs_fleet
 from ..observability import journal as _obs_journal
 from ..observability import lowerings as _obs_lowerings
@@ -356,7 +357,10 @@ def trace_block(block: Block, env: Dict[str, Any], base_key, block_runner=None,
                     continue
                 if check_dtype:
                     v = block.find_var_recursive(n)
-                    if v is not None and str(vals[i].dtype) != v.dtype:
+                    # (a STEP_SCOPES variable holds what a scan op keeps for
+                    # its grad op, a pullback: no array, no dtype)
+                    if v is not None and v.type != VarType.STEP_SCOPES \
+                            and str(vals[i].dtype) != v.dtype:
                         raise TypeError(
                             f"op {op.type!r} wrote {n!r} as "
                             f"{vals[i].dtype} but the program declares "

@@ -438,7 +438,15 @@ def make_grad_op_descs(op: Operator, grad_out_map: Dict[str, str]) -> List[dict]
         return []
     if callable(fwd.grad):
         return fwd.grad(op, grad_out_map)
+    return generic_grad_op_descs(op, grad_out_map)
 
+
+def generic_grad_op_descs(op: Operator,
+                          grad_out_map: Dict[str, str]) -> List[dict]:
+    """The one '<type>_grad' desc of ``make_grad_op_descs`` for an op without
+    a maker of its own; a maker that only prepares its op (``scan``'s marks
+    it to keep what its backward reads) ends in this."""
+    fwd = get(op.type)
     clash = set(op.inputs) & set(op.outputs)
     if clash:
         # *_grad_grad ops reuse slot names on both sides; building their

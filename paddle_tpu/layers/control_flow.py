@@ -480,10 +480,20 @@ class Scan:
             scan.update_memory(h_prev, h)
             scan.step_output(h)
         outs = scan()                              # [B, T, H]
+
+    ``steps``: the trip count of a scan over no sequence -- a body applied
+    ``steps`` times to its memories on weights that exist once, as the stack
+    of a looped language model is (``models/decoder_lm.py``): memories and
+    outputs alone, outputs stacked ``[steps, ...]`` under ``time_major``.
+    The Program holds the body's ops once whatever ``steps`` is (an
+    attribute). ``finals`` (after the call): the memories after the last
+    step, in ``memory`` order.
     """
 
-    def __init__(self, time_major=False):
-        self.time_major = time_major
+    def __init__(self, time_major=False, steps=None):
+        if steps is not None and int(steps) < 1:
+            raise ValueError(f"Scan needs steps >= 1, got {steps}")
+        self.time_major, self.steps = time_major, steps
         self._seq_inputs = []   # (outer var, inner name)
         self._memories = []     # (init outer var, inner name, update name)
         self._outputs = []      # inner names
@@ -533,18 +543,18 @@ class Scan:
         prog = default_main_program()
         parent = self._parent_block
         sub = self._sub
-        # The scan op carries memories; inside the block, the memory name must be
-        # rewritten to the update value at the end of each iteration.
+        # The scan op carries memories: what an iteration hands the next is
+        # the update value (the op's ``next_names``).
         for init, inner, update in self._memories:
             if update is None:
                 raise ValueError(f"memory {inner} never updated")
-            sub.append_op("assign", inputs={"X": [update]},
-                          outputs={"Out": [inner]}, infer_shape=False)
-        if not self._seq_inputs:
-            raise ValueError("Scan requires at least one step_input to determine "
-                             "the sequence length")
-        t_axis = 0 if self.time_major else 1
-        T = self._seq_inputs[0][0].shape[t_axis]
+        if self._seq_inputs:
+            T = self._seq_inputs[0][0].shape[0 if self.time_major else 1]
+        elif self.steps is not None:
+            T = int(self.steps)
+        else:
+            raise ValueError("Scan requires at least one step_input, or "
+                             "steps, to determine the sequence length")
         outs = []
         for n in self._outputs:
             sv = sub.var(n)
@@ -574,7 +584,9 @@ class Scan:
                     "Static": list(statics)},
             outputs={"Out": outs, "FinalCarry": finals},
             attrs={"sub_block": sub.idx,
+                   "steps": 0 if self._seq_inputs else T,
                    "carry_names": [m[1] for m in self._memories],
+                   "next_names": [m[2] for m in self._memories],
                    "x_names": [si[1] for si in self._seq_inputs],
                    "out_names": list(self._outputs),
                    "static_names": list(statics),

@@ -1228,6 +1228,28 @@ def attention_gate(x, gate, name=None):
     return _var(helper, out)
 
 
+def exit_gate_loss(x, ce, steps, entropy_coef=0.0, param_attr=None,
+                   bias_attr=None, name=None):
+    """A looped model's exit gate and the expected loss over its exit
+    distribution (``ops/decoder_ops.py:exit_gate_loss``): ``x [steps * T,
+    H]`` the state after every pass, pass-major, ``ce [steps * T, 1]`` each
+    pass's per-position cross-entropy; one float32 gate ``Linear(H, 1)``
+    shared by the passes. Returns ``(loss [1], expected_ce [1], p [steps *
+    T, 1])``: the mean over positions of ``sum_r p_r ce_r - entropy_coef
+    H(p)``, of ``sum_r p_r ce_r`` alone, and the exit probabilities."""
+    helper = LayerHelper("exit_gate_loss", name=name)
+    w = helper.create_parameter(param_attr, [int(x.shape[-1]), 1], "float32")
+    b = helper.create_parameter(bias_attr, [1], "float32", is_bias=True)
+    loss, expected, p = (_out(helper, "float32") for _ in range(3))
+    helper.append_op("exit_gate_loss",
+                     inputs={"X": [x], "W": [w], "B": [b], "CE": [ce]},
+                     outputs={"Loss": [loss], "ExpectedCE": [expected],
+                              "P": [p]},
+                     attrs={"steps": int(steps),
+                            "entropy_coef": float(entropy_coef)})
+    return _var(helper, loss), _var(helper, expected), _var(helper, p)
+
+
 def swiglu(gate, up, row_scale=None, name=None):
     """``silu(gate) * up``: the gated product of a gated feed-forward layer;
     with ``row_scale [rows]``, each row of it times its scale."""

@@ -11,6 +11,42 @@ pre-norm, without biases or dropout: ``h = x + r operator(norm(x))``,
 ``y = h + r feed_forward(norm(h))`` with ``r`` = ``residual_multiplier``
 (default 1). What it builds, by config key:
 
+- ``total_ut_steps`` (default 1) above 1: a looped model (Ouro / LoopLM,
+  arXiv:2510.25741). With ``R = total_ut_steps``, ``N =
+  num_hidden_layers``, every weight shared by the passes: ``h^(0) =
+  tok_emb[t]``; pass ``r = 1..R`` runs the ``N`` layers over ``h^(r-1)``
+  and ``h^(r) = Norm_f(x_N)``, the final norm applied after every pass and
+  carried into the next; ``logits^(r) = W_head h^(r)`` (one head) and
+  ``ce^(r)_i`` its next-token cross-entropy at position ``i``; the exit
+  gate ``lambda^(r)_i = sigmoid(w_g . h^(r)_i + b_g)`` in float32 (one
+  ``Linear(H, 1)``, float32 parameters from 0); the exit distribution
+  ``p^(1) = lambda^(1)``, ``p^(r) = lambda^(r) prod_{j<r} (1 -
+  lambda^(j))`` for ``r < R``, ``p^(R) = prod_{j<R} (1 - lambda^(j))``;
+  ``loss = mean_i [sum_r p^(r)_i ce^(r)_i - exit_entropy_coef H(p_i)]``
+  (the report's stage-I objective; ``exit_entropy_coef`` default 0),
+  gradients through ``p`` and every ``ce^(r)``. In the Program the stack is
+  ONE ``scan`` op (``layers.Scan(steps=R)``) over a sub-block that holds the layers'
+  ops and the final norm once: op count, parameters and startup program do
+  not grow with ``R``, the weights enter the op as declared inputs and each
+  one's gradient is the sum over its ``R`` uses; the passes' states leave it
+  stacked, one head product and one ``softmax_with_cross_entropy`` run over
+  ``R x`` the rows, and ``layers.exit_gate_loss`` is the gate, the exit
+  distribution and the expected loss. ``build`` then returns ``loss``,
+  ``ce`` (the expected cross-entropy), ``each`` (``[R * batch * seq, 1]``,
+  pass-major), ``exit_p`` (likewise) and ``loop_checkpoints`` (what a
+  ``RecomputeOptimizer`` cuts the sub-block at for recomputation by
+  layer). Dense layers only; no prediction module, no mesh axes.
+- ``norm_placement`` ``"pre"`` (default), or ``"sandwich"``: a second norm
+  on each branch's output before the residual add, ``a = x +
+  Norm_2(operator(Norm_1(x)))``, ``y = a + Norm_4(feed_forward(Norm_3(a)))``
+  (HF ``modeling_ouro.py``'s ``input_layernorm``, ``input_layernorm_2``,
+  ``post_attention_layernorm``, ``post_attention_layernorm_2``; parameters
+  ``<operator>_post_norm_w`` and ``<layer>_ffn_post_norm_w``).
+- ``early_exit_threshold`` concerns decoding (a token leaves the loop once
+  the exit distribution's running sum reaches it; 1: never before the last
+  pass): ``build`` builds training programs, which run every pass, and
+  never reads it; no decode loop that would is built yet.
+
 - ``layer_types`` (default: every layer ``full_attention``, or, where the
   config has ``full_attention_interval``, every interval-th layer
   ``full_attention`` and the others ``linear_attention``), one operator a
@@ -31,8 +67,9 @@ pre-norm, without biases or dropout: ``h = x + r operator(norm(x))``,
   ``rope_theta``, ``partial_rotary_factor`` (the leading share of a head
   that is rotated) and ``rope_type`` ``"default"`` or ``"yarn"``
   (``layers.rotary_embedding``) --, causal ``fused_attention`` at
-  scale ``attention_multiplier`` (default 1/sqrt(head dim)),
-  ``impl="auto"``; under ``gating: "per-head"`` each head's output times
+  scale ``attention_multiplier`` (default 1/sqrt(head dim)), lowered as
+  ``attention_impl`` says (default ``"auto"``; ``"pallas"`` raises where
+  the flash kernels cannot lower); under ``gating: "per-head"`` each head's output times
   ``sigmoid(W_g norm(x))``, one gate a token and head, before the output
   projection (``layers.attention_gate``); under ``attn_output_gate`` the q
   projection is twice as wide, a head's first ``head_dim`` values its q and
@@ -140,7 +177,8 @@ DeepSeek-V3, arXiv:2412.19437), Kimi-Linear-48B-A3B-Instruct (its
 that repository; Kimi Delta Attention: the Kimi Linear report,
 arXiv:2510.26692) and Mellum2-12B-A2.5B-Instruct (its ``config.json``,
 ``model_type: mellum``: Qwen3-MoE's key set with ``layer_types`` and rotary
-parameters by layer type).
+parameters by layer type) and Ouro-2.6B (its ``config.json``,
+``model_type: ouro``; the LoopLM report, arXiv:2510.25741).
 
 Dtypes follow ``models/bert.py``: the embedding table is float32 whatever
 ``dtype`` says, activations are cast to ``dtype`` right after the lookup,
@@ -176,6 +214,7 @@ _ATTENTION = ("full_attention", "sliding_attention")
 def _check(cfg: dict) -> None:
     if cfg.get("kv_lora_rank"):
         _check_latent(cfg)
+    _check_loop(cfg)
     for key, want in _REQUIRED.items():
         if cfg.get(key, want) != want:
             raise NotImplementedError(
@@ -317,6 +356,40 @@ def _check(cfg: dict) -> None:
         raise NotImplementedError(
             "decoder_lm: the router losses are built for softmax scoring "
             "only")
+
+
+def _check_loop(cfg: dict) -> None:
+    """What a looped config (``total_ut_steps``) asks for and ``build`` does
+    not build, and the keys beside it."""
+    if cfg.get("norm_placement", "pre") not in ("pre", "sandwich"):
+        raise NotImplementedError(
+            f"decoder_lm: norm_placement={cfg['norm_placement']!r} is not "
+            f"built yet (only 'pre' and 'sandwich')")
+    steps = cfg.get("total_ut_steps", 1)
+    if not isinstance(steps, int) or steps < 1:
+        raise ValueError(f"decoder_lm: total_ut_steps={steps!r} must be a "
+                         f"whole number of passes, at least 1")
+    # early_exit_threshold is decoding's: a token leaves the loop after the
+    # first pass at which the exit distribution's running sum reaches it
+    # (1: never before the last pass). The training programs built here run
+    # every pass and never read it, whatever its value; the decode loop
+    # that reads it is to raise here for what it does not build
+    if steps == 1:
+        return
+    if not all(_is_dense(cfg, i) for i in range(cfg["num_hidden_layers"])):
+        raise NotImplementedError(
+            "decoder_lm: expert layers under total_ut_steps above 1 are not "
+            "built yet (the router's variables and losses of a layer that "
+            "runs several times a step)")
+    if cfg.get("num_nextn_predict_layers"):
+        raise NotImplementedError(
+            "decoder_lm: num_nextn_predict_layers under total_ut_steps "
+            "above 1 is not built yet (a prediction module after a looped "
+            "trunk)")
+    if cfg.get("expert_axis") or cfg.get("vocab_axis"):
+        raise NotImplementedError(
+            "decoder_lm: expert_axis / vocab_axis under total_ut_steps "
+            "above 1 is not built yet (the loop op under a mesh)")
 
 
 def _check_latent(cfg: dict) -> None:
@@ -510,7 +583,7 @@ def attention(x, cfg: dict, batch: int, seq: int, name: str, layer: int = 0,
         heads_of(k, kv_heads, name + "_k_norm_w" if by_head else None),
         heads_of(v, kv_heads, positions=False), causal=True,
         scale=float(cfg.get("attention_multiplier", 1.0 / math.sqrt(d))),
-        impl="auto",
+        impl=cfg.get("attention_impl", "auto"),
         window=cfg["sliding_window"] if kind == "sliding_attention" else None)
     if cfg.get("gating", "none") == "per-head":
         ctx = layers.attention_gate(ctx, _linear(x, heads, name + "_g_w"))
@@ -794,12 +867,20 @@ def block(x, cfg: dict, batch: int, seq: int, name: str,
           kind: str = "full_attention", dense: bool = False, layer: int = 0):
     """Decoder layer ``layer`` over ``x [batch * seq, H]`` with the operator
     ``kind``; returns the layer's output and the router's variables
-    (``layers.moe_ffn``; None for a ``dense`` feed-forward layer)."""
+    (``layers.moe_ffn``; None for a ``dense`` feed-forward layer). Under
+    ``norm_placement: "sandwich"`` each branch's output is normed once more
+    before its residual add (``<operator>_post_norm_w``,
+    ``<layer>_ffn_post_norm_w``)."""
     r = float(cfg.get("residual_multiplier", 1.0))
 
     def add(h, branch):
         return layers.elementwise_add(
             h, branch if r == 1.0 else layers.scale(branch, r))
+    sandwich = cfg.get("norm_placement", "pre") == "sandwich"
+
+    def branch(y, norm_w):
+        # sandwich: a second norm on the branch's output, before the add
+        return _norm(y, cfg, norm_w) if sandwich else y
     op_name = name + {"conv": "_conv", "mamba": "_mamba", "kda": "_kda",
                       "linear_attention": "_delta"}.get(kind, "_attn")
     normed = _norm(x, cfg, op_name + "_norm_w")
@@ -815,16 +896,17 @@ def block(x, cfg: dict, batch: int, seq: int, name: str,
         mixed = latent_attention(normed, cfg, batch, seq, op_name)
     else:
         mixed = attention(normed, cfg, batch, seq, op_name, layer, kind)
-    h = add(x, mixed)
+    h = add(x, branch(mixed, op_name + "_post_norm_w"))
     normed = _norm(h, cfg, name + "_ffn_norm_w")
     if dense:
         width = cfg.get("shared_intermediate_size", cfg["intermediate_size"])
         gated = layers.swiglu(_linear(normed, width, name + "_ffn_gate_w"),
                               _linear(normed, width, name + "_ffn_up_w"))
-        return add(h, _linear(gated, cfg["hidden_size"],
-                              name + "_ffn_down_w")), None
+        return add(h, branch(
+            _linear(gated, cfg["hidden_size"], name + "_ffn_down_w"),
+            name + "_ffn_post_norm_w")), None
     moe, aux = experts(normed, cfg, name + "_moe")
-    return add(h, moe), aux
+    return add(h, branch(moe, name + "_ffn_post_norm_w")), aux
 
 
 def prediction_module(h, next_tokens, cfg: dict, batch: int, seq: int,
@@ -858,6 +940,33 @@ def _mean_of(values):
     return layers.scale(total, 1.0 / len(values))
 
 
+def _looped(x, cfg: dict, batch: int, seq: int):
+    """The whole stack as one ``scan`` op over a sub-block that holds the
+    layers and the final norm once, applied ``total_ut_steps`` times:
+    returns the normed state after every pass ``[steps * batch * seq, H]``,
+    pass-major, and what a ``RecomputeOptimizer`` cuts the sub-block at for
+    recomputation by layer: every layer's output but the last, and the
+    pass's normed end (the final norm is recomputed with the last layer:
+    what is kept is a layer application's input, ``steps x layers``
+    arrays)."""
+    steps = cfg["total_ut_steps"]
+    loop = layers.Scan(time_major=True, steps=steps)
+    cut = []
+    with loop.step():
+        h = loop.memory(x)
+        y = h
+        for i, kind in enumerate(_layer_types(cfg)):
+            y, _ = block(y, cfg, batch, seq, f"layer{i}", kind, dense=True,
+                         layer=i)
+            cut.append(y)
+        y = cut[-1] = _norm(y, cfg, "final_norm_w")
+        loop.update_memory(h, y)
+        loop.step_output(y)
+    states = loop()
+    return layers.reshape(states, [steps * batch * seq,
+                                   cfg["hidden_size"]]), cut
+
+
 def build(cfg: dict, ids, labels, labels_next=None) -> dict:
     """Append the model to the current Program. ``ids [batch, seq]`` int
     tokens, ``labels [batch * seq, 1]`` the next token of every position;
@@ -878,7 +987,8 @@ def build(cfg: dict, ids, labels, labels_next=None) -> dict:
     under ``use_expert_bias``), ``expert_dropped`` (``[1]`` int32: the
     rows the layer's ``moe_row_budget`` has dropped since startup) and
     ``expert_routed`` (``[batch * seq, H]``: the routed experts' part of
-    the layer's output, without the shared expert's)."""
+    the layer's output, without the shared expert's). Under
+    ``total_ut_steps`` above 1: the module's docstring."""
     _check(cfg)
     batch, seq = int(ids.shape[0]), int(ids.shape[1])
     H = cfg["hidden_size"]
@@ -900,8 +1010,10 @@ def build(cfg: dict, ids, labels, labels_next=None) -> dict:
 
     def cross_entropy(x, norm_w, targets):
         """Every position's cross-entropy of ``targets`` under the head
-        (one matrix, or the table, whoever calls) over ``norm(x)``."""
-        x = _norm(x, cfg, norm_w)
+        (one matrix, or the table, whoever calls) over ``norm(x)`` (over
+        ``x`` itself where ``norm_w`` is None)."""
+        if norm_w:
+            x = _norm(x, cfg, norm_w)
         if cfg.get("tie_word_embeddings"):
             table = x.block.program.global_block().var("tok_emb")
             if dtype != "float32":
@@ -917,6 +1029,18 @@ def build(cfg: dict, ids, labels, labels_next=None) -> dict:
             logits = layers.scale(logits, 1.0 / float(cfg["logits_scaling"]))
         return layers.softmax_with_cross_entropy(logits, targets)
 
+    steps = cfg.get("total_ut_steps", 1)
+    if steps > 1:
+        # the passes' states under one head: steps x the rows, one product
+        states, cut = _looped(x, cfg, batch, seq)
+        each = cross_entropy(states, None, layers.expand(labels, [steps, 1]))
+        loss, ce, exit_p = layers.exit_gate_loss(
+            states, each, steps, float(cfg.get("exit_entropy_coef", 0.0)),
+            param_attr=ParamAttr(name="exit_gate_w",
+                                 initializer=Constant(0.0)),
+            bias_attr=ParamAttr(name="exit_gate_b"))
+        return {"loss": loss, "ce": ce, "each": each, "exit_p": exit_p,
+                "loop_checkpoints": cut}
     for i, kind in enumerate(_layer_types(cfg)):
         x, aux = block(x, cfg, batch, seq, f"layer{i}", kind,
                        dense=_is_dense(cfg, i), layer=i)

@@ -173,6 +173,50 @@ class HloInstruction:
         return toks[-1] if toks else None
 
 
+#: the Program ops that run a sub-block several times: an instruction
+#: inside one carries its scope before its own op's
+_LOOP_OPS = ("scan", "scan_grad")
+
+
+def op_phase(op_name: str) -> Optional[Tuple[Optional[str], str]]:
+    """``(loop, phase)`` of an instruction from its ``op_name`` metadata,
+    None for one with no Program op's scope. ``loop``: the
+    ``scan#<idx>`` / ``scan_grad#<idx>`` scope of the scan op the
+    instruction was traced inside (its own op's ``<op_type>#<op_idx>`` is
+    the innermost token still, which the per-op readers take), else None.
+    ``phase``: ``recompute`` -- a forward op run again for the backward,
+    inside a ``remat_segment``'s ``jax.checkpoint`` (JAX names the re-run
+    ``rematted_computation``); ``backward`` -- inside a grad op, or the
+    transpose of a loop's body (``transpose(jvp(...))``); else
+    ``forward``. So the forward, the recomputed forward and the backward of
+    one Program op inside a loop's sub-block are told apart in a device
+    trace, where all three carry that op's scope."""
+    tokens = _IR_TOKEN.findall(op_name)
+    if not tokens:
+        return None
+    loop = next((t for t in tokens if t.split("#")[0] in _LOOP_OPS), None)
+    if "rematted_computation" in op_name:
+        return loop, "recompute"
+    if "transpose(" in op_name or any(
+            t.split("#")[0].endswith("_grad") for t in tokens):
+        return loop, "backward"
+    return loop, "forward"
+
+
+def instruction_phases(text: str) -> Dict[str, Tuple[Optional[str], str]]:
+    """``{instruction name: op_phase(its op_name)}`` over every computation
+    of an HLO text dump, for the instructions that carry a Program op's
+    scope."""
+    out = {}
+    for line in text.splitlines():
+        m = _INSTR_RE.match(line)
+        meta = _OPNAME_RE.search(line) if m else None
+        found = op_phase(meta.group(1)) if meta else None
+        if found is not None:
+            out[m.group(1)] = found
+    return out
+
+
 def _parse_shape_prefix(rhs: str) -> Tuple[str, str]:
     """Split an instruction RHS into (output shape, remainder)."""
     rhs = rhs.strip()

@@ -51,7 +51,10 @@ FAMILIES = {
         "K tiles a (batch, head) of the compiled flash-attention "
         "ops' forward kernels"),
     # stats: saved (the backward kernel read the forward op's Lse; no forward
-    # lowered in the grad op) / recomputed (the kernels on a desc without
+    # lowered in the grad op -- or, for an op in a sub-block differentiated
+    # as a whole, a scan op's or a remat_segment's, the statistic the
+    # kernels' own custom_vjp kept: reported by the forward op, once an op
+    # however often the sub-block is traced) / recomputed (the kernels on a desc without
     # Lse: the generic grad lowers the forward kernel again for them) /
     # generic (jax.vjp over another lowering)
     "attention_backward_total": (
@@ -186,6 +189,20 @@ FAMILIES = {
         GAUGE, (),
         "sorted rows the expert layers keep a step, all layers: the "
         "assignments without a row budget, the budgets with one"),
+    # ops/control_flow.py:scan_op (layers.Scan: recurrences over sequences, a
+    # looped model's stack under ``steps``). amount: how often the op's lowering traced its
+    # sub-block: 1, it is one lax.scan whatever the trip count; scan_grad
+    # traces none. role: the program's, given by ``publish``
+    "loop_stack_lowerings_total": (
+        COUNT, ("role",),
+        "times the scan ops' lowerings traced their sub-blocks"),
+    # amount: the bytes of the leaves of the pullback a scan op left for its
+    # grad op (what the forward keeps for the backward: JAX's residuals of
+    # the body as lowered, stacked over the iterations), without the op's
+    # own inputs (the weights are kept by whoever holds them)
+    "loop_kept_bytes": (
+        GAUGE, ("role",),
+        "bytes the scan ops keep from forward to backward"),
     # core/executor.py:trace_block, every op: amount = the seconds its
     # lowering call took while the compile traced it (self time: what a
     # control-flow op's sub-block took is its ops'; a grad op keeps the
@@ -224,6 +241,8 @@ def note(notes: dict, salt: int, family: str, amount, labels: dict) -> None:
             f"observability/lowerings.py:FAMILIES ({', '.join(FAMILIES)})")
     names = FAMILIES[family][1]
     labels = {**LATER_LABELS.get(family, {}), **labels}
+    if "role" in names:         # ``publish``'s to give: a lowering need not
+        labels.setdefault("role", "")
     if set(labels) != set(names):
         raise KeyError(f"lowering metric {family!r} takes the labels "
                        f"{names}, not {tuple(labels)}")

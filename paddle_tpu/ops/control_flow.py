@@ -8,7 +8,7 @@ keep their shapes across iterations.
 """
 from __future__ import annotations
 
-from ..core.registry import register
+from ..core.registry import _grad_infer_shape, _is_float, register
 
 
 @register("while")
@@ -85,49 +85,150 @@ def conditional_block(ctx, ins):
     return {"Out": list(outs)}
 
 
-@register("scan", grad="auto")
-def scan_op(ctx, ins):
-    """Structured recurrence: the TPU-native replacement for recurrent_op/DynamicRNN.
+def _scan_grad_descs(op, grad_out_map):
+    """``scan``'s grad maker: mark the forward op to keep what its backward
+    reads (attr ``keep``, output ``Kept``) and hand ``scan_grad`` the
+    generic desc, which carries every forward output, ``Kept`` among them.
+    A scan no backward is built over (a test clone taken before
+    ``minimize``, a scan inside another op's sub-block) keeps nothing."""
+    from ..core import registry
+    from ..framework import VarType
+    if not op.attr("keep"):
+        block = op.block
+        kept = block.create_var(op.output("FinalCarry")[0] + "@scan_kept",
+                                (), "float32", type=VarType.STEP_SCOPES)
+        kept.stop_gradient = True
+        op.outputs["Kept"] = [kept.name]
+        op.attrs["keep"] = True
+        block.program._bump()
+    return registry.generic_grad_op_descs(op, grad_out_map)
 
-    attrs: sub_block, carry_names (loop state), x_names (per-step inputs scanned over
-    the time axis), out_names (per-step outputs stacked), static_names, time_major.
-    Inputs: Init (initial carries, ordered as carry_names), X (sequences [T, ...] or
-    [B, T, ...]), Static (loop-invariant outer vars read by the body -- params,
-    lengths. They MUST be declared inputs, not closure-captured: the generic
-    grad is jax.vjp over this lowering's declared inputs, so a closure-captured
-    param would silently get no gradient).
+
+@register("scan", grad=_scan_grad_descs, nondiff_outputs=("Kept",))
+def scan_op(ctx, ins):
+    """Structured recurrence: the TPU-native replacement for
+    recurrent_op/DynamicRNN, and the loop of a model whose stack of layers
+    runs several times a token on weights that exist once (looped /
+    recurrent-depth transformers). One ``lax.scan``: the sub-block is
+    traced, lowered and compiled once whatever the trip count.
+
+    attrs: sub_block, carry_names (the loop state at an iteration's start, as
+    the sub-block reads it), next_names (the sub-block's variables that hold
+    it at the iteration's end; ``carry_names`` where absent: the body
+    assigns the state back to its name), x_names (per-step inputs scanned
+    over the time axis), steps (the trip count where there is no X, else 0),
+    out_names (per-step outputs stacked), static_names, time_major, keep.
+    Inputs: Init (initial carries, ordered as carry_names), X (sequences
+    [T, ...] or [B, T, ...]), Static (loop-invariant outer vars read by the
+    body -- params, lengths. They MUST be declared inputs, not
+    closure-captured: the backward gives a gradient to the op's declared
+    inputs only, to a weight the sum over its uses). Outputs: FinalCarry,
+    Out and, under ``keep``, Kept.
+
+    The backward: under ``keep`` (set by the grad maker) the lowering is
+    ``jax.vjp`` of the same function, and ``Kept`` is its pullback -- a
+    pytree whose leaves are what the forward kept for the backward (a value
+    of the trace with no shape of its own in the Program: the reference's
+    ``StepScopes``, and declared as that type). ``scan_grad`` calls it on
+    the cotangents and lowers no forward. What is kept is JAX's choice of
+    residuals for the body as lowered: everything the backward reads, or,
+    where the body's ops sit in ``remat_segment`` ops (``RecomputeOptimizer``
+    with checkpoints inside the sub-block), the segments' inputs, the
+    segments' forwards then running once more inside ``scan_grad``. Its
+    bytes beyond the op's own inputs are reported as ``loop_kept_bytes``,
+    how often the sub-block was traced as ``loop_stack_lowerings_total``.
+    Each weight's gradient is accumulated over the iterations in the
+    weight's own dtype (the scan's transpose carries the sum): every term is
+    a product accumulated in float32 and rounded once to that dtype.
     """
     import jax
     import jax.numpy as jnp
 
     sub_idx = ctx.attr("sub_block")
     carry_names = list(ctx.attr("carry_names", []))
+    next_names = list(ctx.attr("next_names", carry_names))
     x_names = list(ctx.attr("x_names", []))
     out_names = list(ctx.attr("out_names", []))
+    static_names = list(ctx.attr("static_names", []))
     time_major = ctx.attr("time_major", False)
-    statics = dict(zip(ctx.attr("static_names", []), ins.get("Static", [])))
+    n_init, n_x = len(ins["Init"]), len(ins.get("X", []))
+    length = None if n_x else int(ctx.attr("steps"))
+    given = list(ins["Init"]) + list(ins.get("X", [])) \
+        + list(ins.get("Static", []))
+    diff = [i for i, v in enumerate(given) if _is_float(v)]
+    traced = [0]
 
-    init = dict(zip(carry_names, ins["Init"]))
-    seqs = ins.get("X", [])
-    seq_env = {}
-    for n, s in zip(x_names, seqs):
-        seq_env[n] = s if time_major else jnp.swapaxes(s, 0, 1)
+    def by_time(x):
+        return x if time_major else jnp.swapaxes(x, 0, 1)
 
-    def body(carry, xt):
-        env = dict(statics)
-        env.update(carry)
-        env.update(xt)
+    def body(statics, carry, xt):
+        traced[0] += 1
+        env = dict(zip(static_names, statics))
+        env.update(zip(carry_names, carry))
+        env.update(zip(x_names, xt))
         env = ctx.block_runner(sub_idx, env)
-        new_carry = {k: env[k] for k in carry_names}
-        outs = {k: env[k] for k in out_names}
-        return new_carry, outs
+        return [env[n] for n in next_names], [env[n] for n in out_names]
 
-    final_carry, stacked = jax.lax.scan(body, init, seq_env)
-    outs = []
-    for n in out_names:
-        o = stacked[n]
-        outs.append(o if time_major else jnp.swapaxes(o, 0, 1))
-    return {"Out": outs, "FinalCarry": [final_carry[n] for n in carry_names]}
+    def run(values):
+        full = list(given)
+        for i, v in zip(diff, values):
+            full[i] = v
+        init, seqs = full[:n_init], full[n_init:n_init + n_x]
+        statics = full[n_init + n_x:]
+        final, stacked = jax.lax.scan(
+            lambda c, xt: body(statics, c, xt), init,
+            [by_time(s) for s in seqs], length=length)
+        return final, [by_time(o) for o in stacked]
+
+    values = [given[i] for i in diff]
+    if ctx.attr("keep", False) and not ctx.under_grad:
+        (final, outs), pullback = jax.vjp(run, values)
+        own = {id(v) for v in given}
+        ctx.report("loop_kept_bytes", sum(
+            leaf.size * leaf.dtype.itemsize
+            for leaf in jax.tree_util.tree_leaves(pullback)
+            if id(leaf) not in own))
+        kept = {"Kept": [pullback]}
+    else:
+        (final, outs), kept = run(values), {}
+    ctx.report("loop_stack_lowerings_total", traced[0])
+    return {"Out": list(outs), "FinalCarry": list(final), **kept}
+
+
+@register("scan_grad", infer_shape=_grad_infer_shape,
+          nondiff_inputs=("Kept",))
+def scan_grad(ctx, ins):
+    """dInit, dX and dStatic from ``Kept``, the pullback the forward op left
+    (``scan``): called on the cotangents of FinalCarry and Out, zeros where
+    none flows. No forward is lowered here -- but for a double gradient
+    (``scan_grad_grad``, the generic grad op, lowers this op again under
+    ``jax.vjp``): what the pullback closed over would be constants to that,
+    so there the gradient is computed from the inputs, as the generic grad
+    op does."""
+    import jax
+    import jax.numpy as jnp
+    import numpy as np
+    from ..core import registry
+    if ctx.under_grad:
+        return registry._generic_grad_lower(
+            registry.get("scan"), ctx,
+            {slot: v for slot, v in ins.items() if slot != "Kept"})
+    pullback = ins.get("Kept", [None])[0]
+    if pullback is None:
+        raise ValueError("scan_grad: the forward scan op kept nothing "
+                         "(built by another maker than the op's own?)")
+
+    def cotangents(slot):
+        outs = ins.get(slot, [])
+        flowing = ins.get(slot + "@GRAD") or [None] * len(outs)
+        return [np.zeros(o.shape, jax.dtypes.float0) if not _is_float(o)
+                else jnp.zeros_like(o) if g is None else g.astype(o.dtype)
+                for o, g in zip(outs, flowing)]
+
+    grads = iter(pullback((cotangents("FinalCarry"), cotangents("Out")))[0])
+    return {slot + "@GRAD": [next(grads) if _is_float(v)
+                             else jnp.zeros_like(v) for v in ins.get(slot, [])]
+            for slot in ("Init", "X", "Static")}
 
 
 @register("remat_segment")

@@ -417,6 +417,45 @@ def attention_gate(ctx, ins):
     return {"Out": [out.astype(x.dtype)]}
 
 
+@register("exit_gate_loss")
+def exit_gate_loss(ctx, ins):
+    """The exit gate of a looped model and the expected loss over its exit
+    distribution (Ouro / LoopLM, arXiv:2510.25741, the stage-I objective),
+    float32 inside. ``X [steps * T, H]``: the state after each of ``steps``
+    passes, pass-major; ``W [H, 1]``, ``B [1]``: one gate shared by the
+    passes; ``CE [steps * T, 1]``: every position's cross-entropy under
+    each pass's logits. A position ``i``: ``lambda_r = sigmoid(w . x_r +
+    b)``; ``p_1 = lambda_1``, ``p_r = lambda_r prod_{j<r} (1 - lambda_j)``,
+    ``p_steps = prod_{j<steps} (1 - lambda_j)`` (the last pass takes what is
+    left: its own gate is not read); outputs ``P [steps * T, 1]``,
+    ``ExpectedCE [1] = mean_i sum_r p_r ce_r`` and ``Loss [1] = mean_i
+    [sum_r p_r ce_r - entropy_coef H(p_i)]``. The products of ``1 -
+    lambda`` are sums of ``log sigmoid(-z)`` and the entropy reads ``log p``
+    from the same sums, so a saturated gate gives 0 and no NaN; the gate's
+    product is a float32 multiply and row sum, not a matrix product (which
+    a TPU would round to bfloat16 passes). Gradients flow to X, W, B and
+    CE."""
+    import jax
+    import jax.numpy as jnp
+    x, w, b, ce = (ins[s][0] for s in ("X", "W", "B", "CE"))
+    steps = int(ctx.attr("steps"))
+    f32 = jnp.float32
+    z = (jnp.sum(x.astype(f32) * w.astype(f32).reshape(1, -1), axis=-1)
+         + b.astype(f32).reshape(())).reshape(steps, -1)
+    log_stay = jax.nn.log_sigmoid(-z)                   # log(1 - lambda)
+    before = jnp.cumsum(log_stay, axis=0) - log_stay    # log prod_{j<r}
+    log_p = jnp.concatenate(
+        [before[:-1] + jax.nn.log_sigmoid(z[:-1]), before[-1:]], axis=0)
+    prob = jnp.exp(log_p)
+    expected = jnp.sum(prob * ce.astype(f32).reshape(steps, -1), axis=0)
+    # p log p -> 0 as p -> 0: a -inf of log_p never meets its 0
+    entropy = -jnp.sum(jnp.where(prob > 0, prob * log_p, 0.0), axis=0)
+    loss = jnp.mean(expected - float(ctx.attr("entropy_coef", 0.0)) * entropy)
+    return {"Loss": [loss.reshape(1)],
+            "ExpectedCE": [jnp.mean(expected).reshape(1)],
+            "P": [prob.reshape(-1, 1)]}
+
+
 @register("moe_router", nondiff_inputs=("Bias",), nondiff_outputs=("Index",))
 def moe_router(ctx, ins):
     """Router of a mixture-of-experts layer, in float32 throughout (the

@@ -787,23 +787,26 @@ def _blocks(S, causal, block_q=None, block_k=None, window=None):
 
 
 def _flash_stats(q, k, v, bias, seed, scale, dropout, causal, interpret,
-                 block_q=None, block_k=None, window=None):
+                 block_q=None, block_k=None, window=None, kept=None):
     """(out, lse) of the flash kernels: the output, differentiable in q, k
     and v, and the rows' softmax statistic ``lse`` [B, H, 1, S] float32 (the
     log of the sum of exp over a row's scores, bias and mask included),
     which carries no gradient: it is what _bwd_call reads in place of a
     pass for the max and the sum. ``block_q`` (Q rows a grid step) and
     ``block_k`` (columns a K tile, both kernels) divide S. ``window``:
-    ``sliding_window``."""
+    ``sliding_window``. ``kept``: called (at trace time) where JAX
+    differentiates this call itself, through ``_flash_fwd``: the backward
+    kernel then reads the ``lse`` that call kept."""
     window = sliding_window(window, q.shape[2], causal)
     return _flash_vjp(q, k, v, bias, seed, scale, dropout, causal, interpret,
                       *_blocks(q.shape[2], causal, block_q, block_k, window),
-                      window)
+                      window, kept)
 
 
-@functools.partial(_jax.custom_vjp, nondiff_argnums=(5, 6, 7, 8, 9, 10, 11))
+@functools.partial(_jax.custom_vjp,
+                   nondiff_argnums=(5, 6, 7, 8, 9, 10, 11, 12))
 def _flash_vjp(q, k, v, bias, seed, scale, dropout, causal, interpret,
-               block_q, block_k, window):
+               block_q, block_k, window, kept):
     return _fwd_call(q, k, v, bias, seed, scale, dropout, causal, interpret,
                      block_q, block_k, window)
 
@@ -844,7 +847,7 @@ def _fwd_call(q, k, v, bias, seed, scale, dropout, causal, interpret,
 
 
 def _flash_fwd(q, k, v, bias, seed, scale, dropout, causal, interpret,
-               block_q, block_k, window):
+               block_q, block_k, window, kept):
     out, lse = _fwd_call(q, k, v, bias, seed, scale, dropout, causal,
                          interpret, block_q, block_k, window)
     # The inputs and the rows' statistic. Under a direct jax.vjp (the tests,
@@ -854,7 +857,12 @@ def _flash_fwd(q, k, v, bias, seed, scale, dropout, causal, interpret,
     # than that output) lowers the forward kernel a second time for it,
     # which XLA merges with the forward op's own call: same kernel, same
     # operands (24 Mosaic calls for 12 layers either way; compiled for a
-    # described v5e, tests/test_pallas_attention_mosaic.py).
+    # described v5e, tests/test_pallas_attention_mosaic.py). An op in a
+    # sub-block that is differentiated as a whole (a scan op that keeps its
+    # pullback, a remat_segment) has no grad op and comes here: it is told
+    # (``kept``) that its backward reads the statistic kept here.
+    if kept is not None:
+        kept()
     return (out, lse), (q, k, v, bias, seed, lse)
 
 
@@ -895,7 +903,7 @@ def _bwd_call(q, k, v, bias, seed, g, lse, scale, dropout, causal, interpret,
 
 
 def _flash_bwd(scale, dropout, causal, interpret, block_q, block_k, window,
-               res, g):
+               kept, res, g):
     import jax
     import jax.numpy as jnp
     import numpy as np
@@ -1107,9 +1115,13 @@ def fused_attention(ctx, ins):
             ctx.report("attention_k_tiles_total", tiles, state=state,
                        window=window or 0)
         seed, interpret = _kernel_seed(ctx, dropout), pallas_mode.interpret()
+        # (the forward a generic grad op lowers again reports for itself)
+        kept = None if ctx.under_grad else functools.partial(
+            ctx.report, "attention_backward_total", stats="saved")
         out, lse = ctx.island(
             lambda q, k, v: _flash_stats(q, k, v, bias, seed, scale, dropout,
-                                         causal, interpret, *blocks, window),
+                                         causal, interpret, *blocks, window,
+                                         kept),
             (q, k, v), (True, True, True), shards)
         return {"Out": [out], "Lse": [lse]}
     if impl == "xla":

@@ -6,7 +6,7 @@ compiles to a single XLA program (reference splits this across executors/op hand
 """
 from __future__ import annotations
 
-from typing import Dict, List, Tuple
+from typing import List, Tuple
 
 import numpy as np
 
@@ -438,10 +438,49 @@ class RecomputeOptimizer(Optimizer):
 
 
 def _rewrite_recompute(program: Program, checkpoint_names):
-    """Partition forward ops at checkpoint producers into remat_segment ops."""
-    block = program.global_block()
-    ops = block.ops
+    """Partition forward ops at checkpoint producers into remat_segment ops,
+    in every block of the program: a checkpoint written inside a
+    control-flow op's sub-block (the layers of a looped stack) cuts that
+    sub-block. A checkpoint that no op of the program writes raises by name,
+    and so do checkpoints among which no segment of two ops forms: either
+    would be no recomputation, in silence. (Checkpoints written back to
+    back, or right after a block's start, end no segment of their own and
+    are fine beside ones that do.)"""
     ckpts = set(checkpoint_names)
+    written = {n for block in program.blocks for op in block.ops
+               for n in op.output_arg_names()}
+    if ckpts - written:
+        raise ValueError(
+            f"RecomputeOptimizer: no op of the program writes the "
+            f"checkpoint(s) {sorted(ckpts - written)} (of {len(ckpts)})")
+    cut = False
+    for block in list(program.blocks):      # not the sub-blocks made here
+        cut |= _rewrite_recompute_block(program, block, ckpts)
+    if not cut:
+        raise ValueError(
+            f"RecomputeOptimizer: no segment of at least two ops ends at "
+            f"any of the checkpoints {sorted(ckpts)}: each is written right "
+            f"after its block's start or another checkpoint; nothing would "
+            f"be recomputed")
+
+
+def _exported(program: Program, block) -> set:
+    """Names of ``block`` that the op holding it as its sub-block reads out
+    of it by attribute (a scan's ``carry_names`` / ``next_names`` /
+    ``out_names``): used after the block's last op."""
+    names = set()
+    for b in program.blocks:
+        for op in b.ops:
+            if op.attr("sub_block", -1) == block.idx and b is not block:
+                for value in op.attrs.values():
+                    if isinstance(value, (list, tuple)):
+                        names.update(n for n in value if isinstance(n, str))
+    return names
+
+
+def _rewrite_recompute_block(program: Program, block, ckpts) -> bool:
+    """``_rewrite_recompute`` of one block; whether a segment formed."""
+    ops = block.ops
 
     # segment boundaries: index just after an op that produces a checkpoint var
     boundaries = [0]
@@ -450,9 +489,9 @@ def _rewrite_recompute(program: Program, checkpoint_names):
             boundaries.append(i + 1)
     segments = [(a, b) for a, b in zip(boundaries, boundaries[1:]) if b - a >= 2]
     if not segments:
-        return
+        return False
 
-    produced_after: Dict[int, set] = {}
+    exported = _exported(program, block) if block.idx else set()
     new_ops = []
     cursor = 0
     for (a, b) in segments:
@@ -466,7 +505,7 @@ def _rewrite_recompute(program: Program, checkpoint_names):
                 if n not in produced and n not in read:
                     read.append(n)
             produced.update(op.output_arg_names())
-        used_later = set()
+        used_later = set(exported)
         for op in ops[b:]:
             used_later.update(op.input_arg_names())
         out_names = []
@@ -480,7 +519,7 @@ def _rewrite_recompute(program: Program, checkpoint_names):
                     out_names.append(n)
         in_names = [n for n in read
                     if block.find_var_recursive(n) is not None]
-        sub = program._create_block(parent_idx=0)
+        sub = program._create_block(parent_idx=block.idx)
         sub.ops = list(seg_ops)
         program._rollback()
         from .framework import Operator
@@ -493,6 +532,7 @@ def _rewrite_recompute(program: Program, checkpoint_names):
     new_ops.extend(ops[cursor:])
     block.ops = new_ops
     program._bump()
+    return True
 
 
 class PipelineOptimizer:

@@ -314,6 +314,38 @@ def test_liveness_over_a_hand_written_schedule():
     assert memory._first_backward(comps[entry]) == 5
 
 
+_LOOP = """HloModule jit_step, is_scheduled=true
+
+ENTRY %main (w: f32[1024], x: f32[1024]) -> f32[1024] {
+  %w = f32[1024]{0} parameter(0)
+  %x = f32[1024]{0} parameter(1)
+  %stack = f32[4,1024]{1,0} custom-call(), custom_call_target="AllocateBuffer"
+  %zeros = f32[1024]{0} broadcast(), dimensions={}
+  %other = f32[1024]{0} broadcast(), dimensions={}
+  %in = (f32[1024]{0}, f32[4,1024]{1,0}) tuple(%x, %stack)
+  %fwd = (f32[1024]{0}, f32[4,1024]{1,0}) while(%in), condition=%c, body=%b, metadata={op_name="jit(step)/scan#3/while"}
+  %kept = f32[4,1024]{1,0} get-tuple-element(%fwd), index=1
+  %back = (f32[1024]{0}, f32[4,1024]{1,0}) tuple(%zeros, %kept)
+  %bwd = (f32[1024]{0}, f32[4,1024]{1,0}) while(%back), condition=%c, body=%b2, metadata={op_name="jit(step)/scan_grad#7/transpose(jvp())/while"}
+  %sum = f32[1024]{0} get-tuple-element(%bwd), index=0
+  ROOT %new_w = f32[1024]{0} fusion(%sum, %w, %other), kind=kLoop, calls=%f, metadata={op_name="jit(step)/sgd#9/sub"}
+}
+"""
+
+
+def test_a_buffer_made_for_a_loop_takes_the_loops_scope():
+    """The stack a forward ``while`` fills and the zeros a backward one adds
+    into carry no ``op_name``: they are the loop's, forward and backward; a
+    buffer that enters no loop keeps none."""
+    from paddle_tpu.observability.attribution import parse_hlo_computations
+    comps, entry, _ = parse_hlo_computations(_LOOP)
+    named = memory._scope_table(comps[entry])
+    assert named["stack"] == ("scan#3", 3)
+    assert named["zeros"] == ("scan_grad#7", 7)
+    assert named["kept"] == ("scan#3", 3)           # a view of the forward's
+    assert named["other"] == (None, None)
+
+
 _ASYNC = """HloModule jit_step, is_scheduled=true
 
 ENTRY %main (w: f32[1024], x: f32[1024]) -> (f32[1024], f32[4096]) {
@@ -386,6 +418,29 @@ def test_a_live_set_that_does_not_reconcile_says_so(monkeypatch):
         memory.RECONCILED[0]
     assert found["buffers"] == [{"instruction": "x", "bytes": 1.0,
                                  "scope": None, "phase": None}]
+
+
+@pytest.mark.parametrize("reserved,reconciled", [
+    (None, False), (1.1, True), (2.0, False)],
+    ids=["no_pool", "adds_up_to_the_pool", "another_program_s_mark"])
+def test_a_live_set_is_held_against_the_reserved_pool_too(monkeypatch,
+                                                          reserved,
+                                                          reconciled):
+    """Where the listed bytes miss XLA's count of its temporaries (a step
+    whose work sits in ``while``s), they are held against the device's high
+    mark of its reserved pool; the coverage stays the share of XLA's
+    count."""
+    main, startup, loss = _train_program(dim=64)
+    exe, _ = _run(main, startup, loss, dim=64)      # holds the step alive
+    monkeypatch.setattr(memory, "live_set_from_hlo", lambda comps, entry: {
+        "bytes": 1.0, "position": 0, "n": 1, "instruction": "x",
+        "live": [("x", 1.0, None, None)]})
+    monkeypatch.setattr(memory, "_reserved_high_mark", lambda: reserved)
+    found = memory.peak_live_set(_label(main))
+    assert found["coverage"] == 1.0 / found["temp_bytes"] < \
+        memory.RECONCILED[0]
+    assert found["reserved_bytes"] == reserved
+    assert found["reconciled"] is reconciled
 
 
 def test_obs_report_renders_the_new_parts_in_its_memory_section():

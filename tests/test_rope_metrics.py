@@ -17,7 +17,7 @@ ROOT = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 BENCH = json.load(open(os.path.join(ROOT, "BENCHMARK.json")))
 CELLS = ["olmoe_1b_7b.pretrain_s4096", "lfm2_8b_a1b.pretrain_s4096",
          "laguna_s_2_1.pretrain_s4096", "qwen3_next_80b_a3b.pretrain_s4096",
-         "mellum2_12b_a2_5b.pretrain_s4096_ep4"]
+         "mellum2_12b_a2_5b.pretrain_s4096_ep4", "ouro_2_6b.pretrain_s4096"]
 
 
 def spec_of(name):
