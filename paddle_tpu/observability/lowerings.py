@@ -105,9 +105,9 @@ FAMILIES = {
     # decay: head (one scalar a token and value head: Gated DeltaNet) /
     # channel (a vector over the key channels: Kimi Delta Attention); a
     # series without the label is a parent's, and is read as head;
-    # step_heads: the key heads a grid step of the kernels takes (a channel
-    # decay's pallas_delta.step_heads; 1 for a scalar decay, the composed
-    # form and a series without the label)
+    # step_heads: the key heads a grid step of the kernels takes, each with
+    # all its value heads (pallas_delta.step_heads of the op's head counts;
+    # 1 for the composed form and a series without the label)
     "delta_lowering_total": (
         COUNT, ("impl", "chunk", "heads", "key_dim", "value_dim",
                 "operands", "decay", "step_heads"),

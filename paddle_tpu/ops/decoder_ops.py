@@ -1476,10 +1476,11 @@ def gated_delta_rule(ctx, ins):
     given ``QKV``, that array in place (``packed``; ``split`` is three
     operands: ``Q`` / ``K`` / ``V``, or column ranges cut out of a ``QKV``
     the kernels' blocks cannot address, and always the composed form).
-    Which lowering and which operand form an op took, and how many heads a
-    grid step of its kernels (``step_heads``: a channel decay's take several,
-    ``pallas_delta.step_heads``), is counted at each compile
-    (``delta_lowering_total``; observability/lowerings.py)."""
+    Which lowering and which operand form an op took, and how many key
+    heads a grid step of its kernels (``step_heads``: what ``pallas_delta.
+    step_heads`` took of the op's heads, each with all its value heads), is
+    counted at each compile (``delta_lowering_total``;
+    observability/lowerings.py)."""
     import jax.numpy as jnp
     from . import pallas_delta, pallas_mode
     q, k, v, g, beta, qkv = _delta_inputs(ctx, ins)
@@ -1491,8 +1492,8 @@ def gated_delta_rule(ctx, ins):
         operands=("split" if operands is None or operands is not qkv
                   else "packed"),
         decay="channel" if g.ndim == 4 else "head",
-        step_heads=(pallas_delta.step_heads(q.shape[2])
-                    if g.ndim == 4 and operands is not None else 1))
+        step_heads=(1 if operands is None
+                    else pallas_delta.step_heads(q.shape[2], v.shape[2])))
     if operands is not None:
         o, states = pallas_delta.chunked(
             operands, _chunk_sums(g, chunk), beta.astype(jnp.float32), chunk,

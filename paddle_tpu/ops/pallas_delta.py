@@ -22,12 +22,33 @@ C]`` products. The composed form holds ``T`` and ``D`` as ``[batch, chunks,
 heads, C, C]`` float32 arrays in HBM; here they live in VMEM, a key head and
 a chunk at a time.
 
-A grid step is (batch, key head, chunk), the chunks in order and the state
-of the key head's ``rep`` value heads (value head j reads key head ``j //
-rep``) carried in VMEM scratch as ``[rep, d_k, d_v]`` float32: ``k k^T`` and
-``q k^T`` are made once a step for its value heads. (Under a decay a key
-channel a step is (batch, ``step_heads`` key heads and their value heads,
-chunk): below.)
+A grid step is (batch, ``step_heads`` key heads and all their value heads,
+chunk), the chunks in order and the state of the step's value heads (value
+head j reads key head ``j // rep``) carried in VMEM scratch as ``[value
+heads a step, d_k, d_v]`` float32. ``step_heads`` follows from the op's head
+counts: the largest divisor of the key heads whose value heads do not exceed
+``STEP_HEADS`` and leave a packed operand's blocks whole (below); one key
+head where none fits. A value head's chunk is a chain of dependent ``[128,
+128]`` products -- twelve in the inverse alone, each waiting some 245 cycles
+for 43 of MXU work -- and a step's value heads are independent, so a kernel's
+body is a plain function of one value head's values (``_scalar_forward`` /
+``_scalar_backward``; ``_channel_forward`` / ``_channel_backward`` under a
+decay a key channel) mapped over a leading head axis (``jax.vmap``): every
+value of a chunk is ``[value heads a step, ..]``, Mosaic unrolls each
+operation over the heads, and in the kernel's program the heads' chains
+stand side by side, stage by stage, so that one chain's waits are filled with
+the others' products, while a step's fixed cost is paid that many times less
+often. The order is the point: a loop over the heads, one whole chain behind
+the other, leaves the chip's schedule as one head's (chip, PR 54: the channel
+pair at 2, 4 and 8 heads a step; PR 58: the scalar pair's two value heads,
+3.67 / 4.42 ms a layer in a loop and 2.33 / 3.12 side by side). What belongs
+to a key head -- the unit q and k, their norms' vjp, ``k k^T`` and ``q k^T``
+-- is made once on a ``[key heads a step, ..]`` axis and repeated along it
+for the value heads that read it; the masks have no head axis and are made
+once. Nothing of a head's arithmetic knows of the others, and a key head's
+sums over its value heads (the gradients of its q, k, ``k k^T``, ``q k^T``)
+are added a head after another: the results are one key head a step's bit
+for bit.
 
 The kernels read what the projection and the short convolution wrote, as
 they wrote it; everything between that and the products happens in VMEM.
@@ -35,18 +56,19 @@ they wrote it; everything between that and the products happens in VMEM.
 or as the one packed array ``[B, S, (2 key heads + value heads) * 128]`` (q |
 k | v along the columns), which is then handed to the call three times with
 three index maps: a head is one 128-lane tile, so key head j of q is lane
-block ``j``, of k ``key heads + j``, and its value heads one block of ``rep *
-128`` lanes behind both (``packs`` says when that offset is whole blocks;
+tile ``j``, of k ``key heads + j``, and its ``rep`` value heads' tiles lie
+behind both; a step reads its key heads' tiles as one block and their value
+heads' as another (``packs`` says when v's offset is whole blocks;
 ``_laid_out`` counts the offsets in a step's blocks).
 No copy of v is cut out. q and k are raw: a step forms ``x * rsqrt(sum(x^2)
 + 1e-6)`` (q also over ``sqrt(d_k)``: ``unit``) in float32 and rounds it to
 the inputs' dtype, which is what the products read; no unit q or k exists in
-HBM. A head's scalars (``G``, ``beta``) come in once, ``[.., rep, C]`` along
-the lanes; the ``[C, 1]`` columns the decay block and the row scalings want
-are made from the rows through the identity's mask (a masked ``[C, C]`` sum,
-exact). The decays, their running sums, the exps, ``T`` and the state are
-float32; the products take operands in the inputs' dtype and accumulate in
-float32.
+HBM. A head's scalars (``G``, ``beta``) come in once, a value head a row of
+``[.., value heads a step, C]`` along the lanes; the ``[C, 1]`` columns the
+decay block and the row scalings want are made from the rows through the
+identity's mask (a masked ``[C, C]`` sum, exact). The decays, their running
+sums, the exps, ``T`` and the state are float32; the products take operands
+in the inputs' dtype and accumulate in float32.
 
 The forward kernel also writes the state entering each chunk (``[B, chunks,
 heads, d_k, d_v]`` float32), which the backward reads: it walks the chunks in
@@ -56,7 +78,7 @@ forward pass. The gradient of ``G`` is a head's column of row terms less the
 column sums of ``E = dM * M + dP * P`` (whose row sums cancel them pair by
 pair in the running sum that turns ``dG`` into ``dg``: both are sums of the
 one float32 array), summed in VMEM and written with ``dbeta`` in the
-scalars' own ``[.., rep, C]`` layout. The gradients of the unit q and k
+scalars' own layout, a value head a row. The gradients of the unit q and k
 never leave VMEM either: the norm's own vjp (``jax.vjp`` of ``unit``, traced
 into the kernel) is applied to them while they are float32, and dq, dk, dv
 are written once (three outputs; the packed form's one gradient is their
@@ -93,25 +115,10 @@ by the doubling over the whole chunk). These are ``_intra``
 and its transpose ``_intra_bwd``: plain functions of a chunk's values, which
 the kernels' bodies trace and the composed form maps over batch, head and
 chunk (``channel_chunk``). The kernels (``_fwd_kernel_channel``,
-``_bwd_kernel_channel``) take one value head a key head and ``step_heads``
-such heads a grid step (the largest divisor of the head count not above
-``CHANNEL_HEADS``; grid ``(batch, heads / step_heads, chunks)``): a head's
-chunk is a chain of dependent ``[128, 128]`` products -- twelve in the
-inverse alone, each waiting some 245 cycles for 43 of MXU work -- and a
-step's heads are independent, so the body maps a head's chunk
-(``_channel_forward`` / ``_channel_backward`` as they are) over a leading
-head axis (``jax.vmap``): every value of a chunk is ``[step_heads, ..]``,
-Mosaic unrolls each operation over the heads, and in the kernel's program
-the heads' chains stand side by side, stage by stage, so that one chain's
-waits are filled with the others' products, while a step's fixed cost is
-paid ``step_heads`` times less often. (The order is the point: a loop over
-the heads, one whole chain behind the other, left the chip's schedule as it
-was; chip, PR 54.) The masks have no head axis and are made once. Nothing
-of a head's arithmetic knows of the others: the results are one head a step's
-bit for bit. The kernels share the block specs (key, value, scalar and state
-blocks ``step_heads`` heads wide), the packed operand (q, k and v start
-whole blocks in, since ``step_heads`` divides the heads) and the states'
-layout with the scalar pair, and read ``G`` and write ``dG`` as ``[B, S,
+``_bwd_kernel_channel``) take one value head a key head, and share the grid,
+the block specs (key, value, scalar and state blocks a step's heads wide),
+the packed operand (q, k and v start whole blocks in) and the states' layout
+with the scalar pair; they read ``G`` and write ``dG`` as ``[B, S,
 heads * 128]`` float32, a head a lane tile. In the backward every product
 that holds a decay is differentiated as the rounded operand the forward's
 product read (``dG += lo * dlo`` for ``lo = (x * E).astype(bf)`` on a pair's
@@ -200,136 +207,223 @@ def _row(column, diag):
     return jnp.sum(jnp.where(diag, column, 0.0), axis=0, keepdims=True)
 
 
-def _head(r, g_ref, b_ref, lower, diag):
-    """Value head r of the step: ``beta`` as a column, the decay block
-    ``D``, ``exp(G)``, ``exp(G_C)`` (along a tile's lanes: Mosaic spreads a
-    ``[1, 1]`` value over one axis at a time) and ``exp(G_C - G)``."""
+STEP_HEADS = 8          # value heads a grid step takes at most
+
+
+def step_heads(key_heads: int, value_heads: int | None = None) -> int:
+    """The key heads a grid step of the kernels takes (with all their value
+    heads; ``value_heads`` defaults to one a key head): the largest divisor
+    of ``key_heads`` whose value heads do not exceed ``STEP_HEADS`` and, where
+    a packed operand can be read in place (``packs``), leave v's offset of ``2
+    key_heads`` tiles a whole number of the step's value blocks (q's and k's
+    are whole blocks of the step's key heads: ``_laid_out``); one key head
+    where no divisor fits."""
+    value_heads = value_heads or key_heads
+    rep = value_heads // key_heads
+    whole = packs(key_heads, value_heads)
+    return max((h for h in range(1, key_heads + 1)
+                if key_heads % h == 0 and h * rep <= STEP_HEADS
+                and not (whole and 2 * key_heads % (h * rep))), default=1)
+
+
+def _heads(ref, n):
+    """A block's ``[C, n * 128]``, a head a lane tile -> ``[n, C, 128]``."""
     import jax.numpy as jnp
-    gr = g_ref[0, 0, 0][r:r + 1, :]                     # [1, C]
-    gc = _column(gr, diag)                              # [C, 1]
-    bc = _column(b_ref[0, 0, 0][r:r + 1, :], diag)
-    d = jnp.exp(jnp.where(lower, gc - gr, -jnp.inf))
+    return jnp.stack([ref[0, :, HEAD_DIM * r:HEAD_DIM * (r + 1)]
+                      for r in range(n)])
+
+
+def _to_tiles(ref, x):
+    """``_heads``' inverse: ``x [n, C, 128]`` into the block."""
+    for r in range(x.shape[0]):
+        ref[0, :, HEAD_DIM * r:HEAD_DIM * (r + 1)] = x[r]
+
+
+def _scalar_rows(ref, n):
+    """A block's ``[n, C]`` scalars, a head a row -> ``[n, 1, C]``."""
+    import jax.numpy as jnp
+    rows = ref[0, 0, 0]
+    return jnp.stack([rows[r:r + 1, :] for r in range(n)])
+
+
+def _to_rows(ref, x):
+    """``_scalar_rows``' inverse: ``x [n, 1, C]`` into the block."""
+    for r in range(x.shape[0]):
+        ref[0, 0, 0, r:r + 1, :] = x[r]
+
+
+def _decays(g, b, lower, diag):
+    """A value head's scalars of a chunk, from its ``G`` and ``beta`` rows
+    ``[1, C]``: ``beta`` as a column, the decay block ``D``, ``exp(G)``,
+    ``exp(G_C)`` (along a tile's lanes: Mosaic spreads a ``[1, 1]`` value
+    over one axis at a time) and ``exp(G_C - G)``."""
+    import jax.numpy as jnp
+    gc = _column(g, diag)                               # [C, 1]
+    bc = _column(b, diag)
+    d = jnp.exp(jnp.where(lower, gc - g, -jnp.inf))
     end = gc[gc.shape[0] - 1:, :]
     return (bc, d, jnp.exp(gc),
             jnp.exp(jnp.broadcast_to(end, (1, HEAD_DIM))), jnp.exp(end - gc))
 
 
-def _fwd_kernel(rep, q_ref, k_ref, v_ref, g_ref, b_ref, o_ref, st_ref, s_ref):
+def _scalar_chunk(qn, kn, kk, qk, g, b, v, s):
+    """What both passes compute of one chunk of one value head under a
+    scalar decay: ``qn`` / ``kn [C, d_k]`` its key head's unit operands in
+    the products' dtype, ``kk`` / ``qk [C, C]`` that head's ``k k^T`` and ``q
+    k^T`` (float32), ``g`` / ``b [1, C]`` the head's running sums and beta,
+    ``v [C, d_v]``, ``s [d_k, d_v]`` float32 the state entering. What the
+    forward reads comes first."""
+    import jax.numpy as jnp
+    bf, f32 = qn.dtype, jnp.float32
+    lower, strict, diag = _masks(g.shape[1])
+    bc, d, eg, e_end, f = _decays(g, b, lower, diag)
+    md = jnp.where(strict, kk * d, 0.0)
+    tb = _inverse(md * bc, diag.astype(f32), bf).astype(bf)
+    sb = s.astype(bf)
+    ks = _nn(kn, sb)
+    z = v.astype(f32) - eg * ks
+    vpb = _nn(tb, (bc * z).astype(bf)).astype(bf)
+    return (eg, e_end, vpb, _nn(qn, sb), qk * d,
+            (kn.astype(f32) * f).astype(bf), bc, d, f, md, tb, sb, ks, z)
+
+
+def _scalar_forward(qn, kn, kk, qk, g, b, v, s):
+    """``_scalar_chunk``'s head and chunk: ``o [C, d_v]`` (float32) and the
+    state leaving."""
+    eg, e_end, vpb, qs, p, kend, *_ = _scalar_chunk(qn, kn, kk, qk, g, b, v,
+                                                    s)
+    return (eg * qs + _nn(p.astype(qn.dtype), vpb),
+            e_end * s + _tn(kend, vpb))
+
+
+def _scalar_backward(qn, kn, kk, qk, g, b, v, s, dsn, do):
+    """The chunk's gradients given the state's gradient ``dsn`` leaving it
+    and ``do [C, d_v]``: dv, the head's terms of its key head's ``dqn``,
+    ``dkn`` (two, in the order they are added), ``dkk`` and ``dqk``, its
+    ``dG`` and ``dbeta`` rows ``[1, C]``, the state's gradient entering;
+    float32."""
+    import jax
+    import jax.numpy as jnp
+    bf, f32 = qn.dtype, jnp.float32
+    c = g.shape[1]
+    lower, strict, diag = _masks(c)
+    at_end = jax.lax.broadcasted_iota(jnp.int32, (c, 1), 0) == c - 1
+    knf = kn.astype(f32)
+    eg, e_end, vpb, qs, p, kend, bc, d, f, md, tb, sb, ks, z = _scalar_chunk(
+        qn, kn, kk, qk, g, b, v, s)
+    m = md * bc
+
+    def rows(x):
+        return jnp.sum(x, axis=1, keepdims=True)
+
+    dsnb, dob = dsn.astype(bf), do.astype(bf)
+    # O = exp(G) (q S) + P V';  S_next = exp(G_C) S + (k f)^T V'
+    dvp = _tn(p.astype(bf), dob) + _nn(kend, dsnb)
+    dp = jnp.where(lower, _nt(dob, vpb), 0.0)
+    dqs = eg * do.astype(f32)
+    dqsb = dqs.astype(bf)
+    ds = e_end * dsn + _tn(qn, dqsb)
+    dkf = _nt(vpb, dsnb)
+    moved = rows(dkf * knf) * f                         # dF_i F_i
+    at_last = jnp.sum(moved, axis=0, keepdims=True) + jnp.sum(
+        rows(e_end * dsn * s), axis=0, keepdims=True)
+    # V' = T R, R = beta (v - exp(G) (k S));  dM = -dR V'^T
+    dr = _tn(tb, dvp.astype(bf))
+    dm = jnp.where(strict, -_nt(dr.astype(bf), vpb), 0.0)
+    dz = bc * dr
+    dks = -eg * dz
+    dksb = dks.astype(bf)
+    # D[i, j] = exp(G_i - G_j): E's row sums at i, its column sums at j
+    e = dm * m + dp * p
+    dg = (rows(dqs * qs) - moved + rows(dks * ks) + rows(e)
+          + jnp.where(at_end, at_last, 0.0))
+    return (dz, _nt(dqsb, sb), dkf * f, _nt(dksb, sb), dm * bc * d, dp * d,
+            _row(dg, diag) - jnp.sum(e, axis=0, keepdims=True),
+            _row(rows(dr * z) + rows(dm * md), diag), ds + _tn(kn, dksb))
+
+
+def _of_key_heads(x, rep):
+    """A key head's ``[keys, ..]`` value at each of its ``rep`` value heads,
+    ``[keys * rep, ..]``: what the step's value heads read of it."""
+    import jax.numpy as jnp
+    return x if rep == 1 else jnp.repeat(x, rep, axis=0)
+
+
+def _over_value_heads(rep, *terms):
+    """The sum of ``terms [keys * rep, ..]`` over each key head's value
+    heads, ``[keys, ..]``: a head after another and, within a head, a term
+    after another -- the order in which one head at a time adds them."""
+    terms = [t.reshape(t.shape[0] // rep, rep, *t.shape[1:]) for t in terms]
+    total = None
+    for r in range(rep):
+        for t in terms:
+            total = t[:, r] if total is None else total + t[:, r]
+    return total
+
+
+def _fwd_kernel(q_ref, k_ref, v_ref, g_ref, b_ref, o_ref, st_ref, s_ref):
+    import jax
     import jax.numpy as jnp
     pl, _ = _pl()
-    bf = q_ref.dtype            # the products' operand type
-    f32 = jnp.float32
+    bf, f32 = q_ref.dtype, jnp.float32      # bf: the products' operand type
 
     @pl.when(pl.program_id(2) == 0)
     def _():
         s_ref[...] = jnp.zeros_like(s_ref)
 
-    lower, strict, diag = _masks(q_ref.shape[1])
-    eye = diag.astype(f32)
-    qn = unit(q_ref[0].astype(f32), QUERY_SCALE).astype(bf)
-    kn = unit(k_ref[0].astype(f32)).astype(bf)
-    knf = kn.astype(f32)
-    kk, qk = _nt(kn, kn), _nt(qn, kn)                   # [C, C], shared
-    for r in range(rep):
-        sl = slice(HEAD_DIM * r, HEAD_DIM * (r + 1))
-        bc, d, eg, e_end, f = _head(r, g_ref, b_ref, lower, diag)
-        t = _inverse(jnp.where(strict, kk * d, 0.0) * bc, eye, bf)
-        s = s_ref[r]
-        st_ref[0, 0, r] = s
-        sb = s.astype(bf)
-        z = v_ref[0, :, sl].astype(f32) - eg * _nn(kn, sb)
-        vpb = _nn(t.astype(bf), (bc * z).astype(bf)).astype(bf)
-        o = eg * _nn(qn, sb) + _nn((qk * d).astype(bf), vpb)
-        o_ref[0, :, sl] = o.astype(o_ref.dtype)
-        s_ref[r] = e_end * s + _tn((knf * f).astype(bf), vpb)
+    n = s_ref.shape[0]                      # the step's value heads
+    keys = q_ref.shape[2] // HEAD_DIM       # and its key heads
+    qn = unit(_heads(q_ref, keys).astype(f32), QUERY_SCALE).astype(bf)
+    kn = unit(_heads(k_ref, keys).astype(f32)).astype(bf)
+    nt = jax.vmap(_nt)
+    # a key head's: made once, read by each of its value heads; those on a
+    # leading axis of every value, as the channel kernels' (below)
+    shared = [_of_key_heads(x, n // keys)
+              for x in (qn, kn, nt(kn, kn), nt(qn, kn))]
+    s = s_ref[...]
+    st_ref[0, 0] = s
+    o, s_next = jax.vmap(_scalar_forward)(
+        *shared, _scalar_rows(g_ref, n), _scalar_rows(b_ref, n),
+        _heads(v_ref, n), s)
+    _to_tiles(o_ref, o.astype(o_ref.dtype))
+    s_ref[...] = s_next
 
 
-def _bwd_kernel(rep, q_ref, k_ref, v_ref, do_ref, g_ref, b_ref, st_ref,
+def _bwd_kernel(q_ref, k_ref, v_ref, do_ref, g_ref, b_ref, st_ref,
                 dq_ref, dk_ref, dv_ref, dg_ref, db_ref, ds_ref):
     import jax
     import jax.numpy as jnp
     pl, _ = _pl()
-    bf = q_ref.dtype
-    f32 = jnp.float32
+    bf, f32 = q_ref.dtype, jnp.float32
 
     @pl.when(pl.program_id(2) == 0)     # the last chunk: nothing follows it
     def _():
         ds_ref[...] = jnp.zeros_like(ds_ref)
 
-    c = q_ref.shape[1]
-    lower, strict, diag = _masks(c)
-    eye = diag.astype(f32)
-    at_end = jax.lax.broadcasted_iota(jnp.int32, (c, 1), 0) == c - 1
-    head = jax.lax.broadcasted_iota(jnp.int32, (rep, c), 0)
+    n = ds_ref.shape[0]                 # the step's heads, as the forward's
+    keys = q_ref.shape[2] // HEAD_DIM
+    rep = n // keys
     # the unit q and k the forward read, and the way back through the norms
-    qu, q_back = jax.vjp(lambda x: unit(x, QUERY_SCALE), q_ref[0].astype(f32))
-    ku, k_back = jax.vjp(unit, k_ref[0].astype(f32))
+    qu, q_back = jax.vjp(lambda x: unit(x, QUERY_SCALE),
+                         _heads(q_ref, keys).astype(f32))
+    ku, k_back = jax.vjp(unit, _heads(k_ref, keys).astype(f32))
     qn, kn = qu.astype(bf), ku.astype(bf)
-    knf = kn.astype(f32)
-    kk, qk = _nt(kn, kn), _nt(qn, kn)
-
-    def rows(x):
-        return jnp.sum(x, axis=1, keepdims=True)
-
-    dqn = jnp.zeros((c, HEAD_DIM), f32)
-    dkn = jnp.zeros((c, HEAD_DIM), f32)
-    dkk = jnp.zeros((c, c), f32)
-    dqk = jnp.zeros((c, c), f32)
-    dgs = jnp.zeros((rep, c), f32)
-    dbs = jnp.zeros((rep, c), f32)
-    for r in range(rep):
-        sl = slice(HEAD_DIM * r, HEAD_DIM * (r + 1))
-        bc, d, eg, e_end, f = _head(r, g_ref, b_ref, lower, diag)
-        md = jnp.where(strict, kk * d, 0.0)
-        m = md * bc
-        tb = _inverse(m, eye, bf).astype(bf)
-        s, dsn = st_ref[0, 0, r], ds_ref[r]
-        sb, dsnb = s.astype(bf), dsn.astype(bf)
-        # the forward again, from the same rounded operands
-        ks = _nn(kn, sb)
-        z = v_ref[0, :, sl].astype(f32) - eg * ks
-        vpb = _nn(tb, (bc * z).astype(bf)).astype(bf)
-        qs = _nn(qn, sb)
-        p = qk * d
-        dof = do_ref[0, :, sl].astype(f32)
-        dob = dof.astype(bf)
-        # O = exp(G) (q S) + P V';  S_next = exp(G_C) S + (k f)^T V'
-        dvp = _tn(p.astype(bf), dob) + _nn((knf * f).astype(bf), dsnb)
-        dp = jnp.where(lower, _nt(dob, vpb), 0.0)
-        dqs = eg * dof
-        dqsb = dqs.astype(bf)
-        dqn += _nt(dqsb, sb)
-        ds = e_end * dsn + _tn(qn, dqsb)
-        dkf = _nt(vpb, dsnb)
-        dkn += dkf * f
-        moved = rows(dkf * knf) * f                     # dF_i F_i
-        at_last = jnp.sum(moved, axis=0, keepdims=True) + jnp.sum(
-            rows(e_end * dsn * s), axis=0, keepdims=True)
-        # V' = T R, R = beta (v - exp(G) (k S));  dM = -dR V'^T
-        dr = _tn(tb, dvp.astype(bf))
-        dm = jnp.where(strict, -_nt(dr.astype(bf), vpb), 0.0)
-        dz = bc * dr
-        dv_ref[0, :, sl] = dz.astype(dv_ref.dtype)
-        dks = -eg * dz
-        dksb = dks.astype(bf)
-        dkn += _nt(dksb, sb)
-        ds_ref[r] = ds + _tn(kn, dksb)
-        dkk += dm * bc * d
-        dqk += dp * d
-        # D[i, j] = exp(G_i - G_j): E's row sums at i, its column sums at j
-        e = dm * m + dp * p
-        dg = (rows(dqs * qs) - moved + rows(dks * ks) + rows(e)
-              + jnp.where(at_end, at_last, 0.0))
-        dgs = jnp.where(head == r, _row(dg, diag)
-                        - jnp.sum(e, axis=0, keepdims=True), dgs)
-        dbs = jnp.where(head == r, _row(rows(dr * z) + rows(dm * md), diag),
-                        dbs)
-    dqkb, dkkb = dqk.astype(bf), dkk.astype(bf)
-    dq_ref[0] = q_back(dqn + _nn(dqkb, kn))[0].astype(dq_ref.dtype)
-    dk_ref[0] = k_back(dkn + _tn(dqkb, qn) + _nn(dkkb, kn)
-                       + _tn(dkkb, kn))[0].astype(dk_ref.dtype)
-    dg_ref[0, 0, 0] = dgs
-    db_ref[0, 0, 0] = dbs
+    nn, nt, tn = jax.vmap(_nn), jax.vmap(_nt), jax.vmap(_tn)
+    shared = [_of_key_heads(x, rep) for x in (qn, kn, nt(kn, kn), nt(qn, kn))]
+    dv, dqn, dkn_f, dkn_s, dkk, dqk, dg, db, ds = jax.vmap(_scalar_backward)(
+        *shared, _scalar_rows(g_ref, n), _scalar_rows(b_ref, n),
+        _heads(v_ref, n), st_ref[0, 0], ds_ref[...], _heads(do_ref, n))
+    ds_ref[...] = ds
+    _to_tiles(dv_ref, dv.astype(dv_ref.dtype))
+    _to_rows(dg_ref, dg)
+    _to_rows(db_ref, db)
+    dqn = _over_value_heads(rep, dqn)
+    dkn = _over_value_heads(rep, dkn_f, dkn_s)
+    dqkb = _over_value_heads(rep, dqk).astype(bf)
+    dkkb = _over_value_heads(rep, dkk).astype(bf)
+    _to_tiles(dq_ref, q_back(dqn + nn(dqkb, kn))[0].astype(dq_ref.dtype))
+    _to_tiles(dk_ref, k_back(dkn + tn(dqkb, qn) + nn(dkkb, kn)
+                             + tn(dkkb, kn))[0].astype(dk_ref.dtype))
 
 
 # -- a decay a key channel ---------------------------------------------------
@@ -403,16 +497,6 @@ def _apart(c):
 
 
 INVERSE_BLOCK = 8       # rows of the blocks the blocked inverse starts from
-CHANNEL_HEADS = 8       # heads a grid step takes at most (``step_heads``)
-
-
-def step_heads(heads: int) -> int:
-    """The heads a grid step of the channel kernels takes of ``heads``: the
-    largest divisor not above ``CHANNEL_HEADS``, so that a step's lane block
-    of a packed operand starts at a whole block (q at 0, k ``heads`` tiles
-    in, v ``2 heads``: ``_laid_out``)."""
-    return max(h for h in range(1, min(CHANNEL_HEADS, heads) + 1)
-               if heads % h == 0)
 
 
 def _inverse_blocked(m, eye, bf):
@@ -583,26 +667,6 @@ def _channel_backward(qn, kn, v, g, bc, s, dsn, do):
             rows(dr * z) + rows(dm * md), ds)
 
 
-def _heads(ref, n):
-    """A block's ``[C, n * 128]``, a head a lane tile -> ``[n, C, 128]``."""
-    import jax.numpy as jnp
-    return jnp.stack([ref[0, :, HEAD_DIM * r:HEAD_DIM * (r + 1)]
-                      for r in range(n)])
-
-
-def _to_tiles(ref, x):
-    """``_heads``' inverse: ``x [n, C, 128]`` into the block."""
-    for r in range(x.shape[0]):
-        ref[0, :, HEAD_DIM * r:HEAD_DIM * (r + 1)] = x[r]
-
-
-def _scalar_rows(ref, n):
-    """A block's ``[n, C]`` scalars, a head a row -> ``[n, 1, C]``."""
-    import jax.numpy as jnp
-    rows = ref[0, 0, 0]
-    return jnp.stack([rows[r:r + 1, :] for r in range(n)])
-
-
 def _fwd_kernel_channel(q_ref, k_ref, v_ref, g_ref, b_ref, o_ref, st_ref,
                         s_ref):
     import jax
@@ -661,8 +725,7 @@ def _bwd_kernel_channel(q_ref, k_ref, v_ref, do_ref, g_ref, b_ref, st_ref,
     ds_ref[...] = ds
     for ref, x in ((dq_ref, dq), (dk_ref, dk), (dv_ref, dv), (dg_ref, dg)):
         _to_tiles(ref, x)
-    for r in range(n):
-        db_ref[0, 0, 0, r:r + 1, :] = db[r]
+    _to_rows(db_ref, db)
 
 
 def channel_chunk(qn, kn, v, g, beta, s):
@@ -689,17 +752,15 @@ def _from_heads(rows):
                                                  steps * values)
 
 
-def _laid_out(qkv, value_heads, channel=False):
+def _laid_out(qkv, value_heads):
     """(the q, k and v operands, the lane block each one's first head is at
     -- q's and k's in blocks of a step's key heads, v's in blocks of its
-    value heads --, the key heads, the key heads a grid step takes) of ``(q,
-    k, v)`` or of one packed ``q | k | v`` array. A step takes one key head
-    and its ``rep`` value heads, or (``channel``: one value head a key head)
-    ``step_heads`` of them."""
+    value heads --, the key heads, the key heads a grid step takes:
+    ``step_heads``) of ``(q, k, v)`` or of one packed ``q | k | v`` array."""
     packed = not isinstance(qkv, (tuple, list))
     key_heads = ((qkv.shape[2] // HEAD_DIM - value_heads) // 2 if packed
                  else qkv[0].shape[2] // HEAD_DIM)
-    step = step_heads(key_heads) if channel else 1
+    step = step_heads(key_heads, value_heads)
     if not packed:
         return qkv, (0, 0, 0), key_heads, step
     return (qkv, qkv, qkv), (
@@ -741,20 +802,20 @@ def _fwd_call(qkv, gcum, beta, chunk, interpret):
     ``gcum`` / ``beta [B, S, value heads]`` float32 -> ``o [B, S, value heads
     * 128]`` and the state entering each chunk ``[B, chunks, value heads,
     128, 128]`` float32. ``gcum [B, S, heads, 128]``: a decay a key channel
-    (one value head a key head, ``step_heads`` of them a grid step)."""
+    (one value head a key head)."""
     import jax
     import jax.numpy as jnp
     pl, pltpu = _pl()
     batch, seq, n_v = gcum.shape[:3]
-    (q, k, v), at, n_k, step = _laid_out(qkv, n_v, gcum.ndim == 4)
+    (q, k, v), at, n_k, step = _laid_out(qkv, n_v)
     values, steps, chunks = n_v // n_k * step, n_k // step, seq // chunk
     key, value, scalars, state = _specs(chunk, step, values, lambda i: i)
     if gcum.ndim == 4:
         kernel, decay, sums = (_fwd_kernel_channel, key(),
                                gcum.reshape(batch, seq, -1))
     else:
-        kernel, decay, sums = (functools.partial(_fwd_kernel, values),
-                               scalars, _by_head(gcum, steps, chunk))
+        kernel, decay, sums = (_fwd_kernel, scalars,
+                               _by_head(gcum, steps, chunk))
     return pl.pallas_call(
         kernel, grid=(batch, steps, chunks),
         in_specs=[key(at[0]), key(at[1]), value(at[2]), decay, scalars],
@@ -776,7 +837,7 @@ def _bwd_call(qkv, gcum, beta, states, do, chunk, interpret):
     import jax.numpy as jnp
     pl, pltpu = _pl()
     batch, seq, n_v = gcum.shape[:3]
-    (q, k, v), at, n_k, step = _laid_out(qkv, n_v, gcum.ndim == 4)
+    (q, k, v), at, n_k, step = _laid_out(qkv, n_v)
     values, steps, chunks = n_v // n_k * step, n_k // step, seq // chunk
     key, value, scalars, state = _specs(
         chunk, step, values, lambda i: chunks - 1 - i)
@@ -788,7 +849,7 @@ def _bwd_call(qkv, gcum, beta, states, do, chunk, interpret):
                              gcum.reshape(batch, seq, -1))
         dg_shape = jax.ShapeDtypeStruct(gr.shape, f32)
     else:
-        kernel, decay, gr = (functools.partial(_bwd_kernel, values), scalars,
+        kernel, decay, gr = (_bwd_kernel, scalars,
                              _by_head(gcum, steps, chunk))
         dg_shape = by_head
     dq, dk, dv, dg, db = pl.pallas_call(

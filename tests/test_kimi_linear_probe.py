@@ -85,26 +85,38 @@ def test_the_kernels_sweep_takes_heads_a_step_and_puts_the_constant_back(
         capsys):
     """``--step-heads`` / ``--scalar-reps`` parse; the rehearsal's heads of
     16 are not the kernels', so nothing is timed there. ``delta_kernels`` on
-    the interpreter at heads of 128: a row each value in ``CHANNEL_HEADS``'
+    the interpreter at heads of 128: a row each value in ``STEP_HEADS``'
     place with what ``step_heads`` took of three heads and the empty bodies'
-    time beside it, the module as it was afterwards."""
+    time beside it, the module as it was afterwards. ``scalar_kernels``
+    likewise by value heads a key head: the key heads a step that fit the
+    limit's value heads."""
     import numpy as np
     from paddle_tpu.ops import pallas_delta
     got = probe(capsys, "kernels", "--chunks", "16", "--step-heads", "1", "2",
                 "--scalar-reps", "1", "2")
     assert got["delta"] == [] and got["scalar"] == []
-    taken = (pallas_delta.CHANNEL_HEADS, pallas_delta._channel_forward,
-             pallas_delta._channel_backward)
+    def now():
+        return (pallas_delta.STEP_HEADS, pallas_delta._channel_forward,
+                pallas_delta._channel_backward, pallas_delta._scalar_forward,
+                pallas_delta._scalar_backward)
+    taken = now()
     rng = np.random.RandomState(5)
     rows = probe_tool.delta_kernels(
         probe_tool.channel_feeds(1, 128, 3, 128, rng), [64], [], [2, 4], True)
     assert [(r["chunk"], r["step_heads"]) for r in rows] == [(64, 1), (64, 3)]
     assert all(r["finite"] and min(r["fwd_ms"], r["bwd_ms"], r["empty_fwd_ms"],
                                    r["empty_bwd_ms"]) > 0 for r in rows)
-    assert taken == (pallas_delta.CHANNEL_HEADS, pallas_delta._channel_forward,
-                     pallas_delta._channel_backward)
+    assert taken == now()
     (row,) = probe_tool.delta_kernels(
         probe_tool.channel_feeds(1, 64, 1, 128, rng), [64], [], [], True)
     assert row["step_heads"] == 1 and "empty_fwd_ms" not in row
-    rows = probe_tool.scalar_kernels(1, 64, 4, 128, 64, [2, 3], True, rng)
-    assert [(r["key_heads"], r["rep"]) for r in rows] == [(2, 2)]
+    rows = probe_tool.scalar_kernels(1, 64, 4, 128, 64, [2, 3], [], True, rng)
+    assert [(r["key_heads"], r["rep"], r["step_heads"]) for r in rows] == [
+        (2, 2, 2)] and "empty_fwd_ms" not in rows[0]
+    rows = probe_tool.scalar_kernels(1, 64, 4, 128, 64, [2, 1], [2, 4], True,
+                                     rng)
+    assert [(r["rep"], r["step_heads"]) for r in rows] == [
+        (2, 1), (2, 2), (1, 2), (1, 4)]
+    assert all(min(r["fwd_ms"], r["bwd_ms"], r["empty_fwd_ms"],
+                   r["empty_bwd_ms"]) > 0 for r in rows)
+    assert taken == now()

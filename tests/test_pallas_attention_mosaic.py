@@ -367,7 +367,7 @@ def test_gated_delta_rule_kernels_compile_for_v5e(one_chip, batch, seq,
         values.shape, states.shape]
     compiled = fwd.compile()
     assert _kernels(compiled) == 1
-    # the scalars' [.., rep, C] rows alone: no operand of the kernel is
+    # the scalars' rows (a value head each) alone: no operand of the kernel is
     # copied, cut out or normalised around it
     assert compiled.memory_analysis().temp_size_in_bytes < 20e6
     back = jax.jit(backward).lower(qkv, scalars, scalars, states, values)

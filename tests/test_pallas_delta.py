@@ -225,8 +225,9 @@ def test_packed_and_split_operands_give_the_same_bits():
 def test_the_kernels_products_read_delta_operands_unit_q_and_k(form):
     """The unit q and k a grid step forms in VMEM, rounded to bfloat16, are
     ``_delta_operands``' bit for bit: a probe kernel behind the delta
-    kernels' own block specs (key head j of q at lane block j, of k at ``key
-    heads + j`` of the packed array) writes what the products would read."""
+    kernels' own block specs (a step's key heads of q from lane block 0 of
+    the packed array, of k from the block ``key heads`` tiles in) writes
+    what the products would read."""
     import functools
     from jax.experimental import pallas as pl
     feeds = rule_inputs(2, 128, 2, 4, 128, 128, seed=12)
@@ -237,16 +238,18 @@ def test_the_kernels_products_read_delta_operands_unit_q_and_k(form):
     (qa, ka, _), at, n_k, step = pallas_delta._laid_out(
         operands(feeds, form, bf), 4)
     assert (n_k, step, at) == (
-        2, 1, (0, 0, 0) if form == "split" else (0, 2, 2))
-    key, *_ = pallas_delta._specs(64, step, 2, lambda i: i)
+        2, 2, (0, 0, 0) if form == "split" else (0, 1, 1))
+    key, *_ = pallas_delta._specs(64, step, 4, lambda i: i)
 
     def probe(q_ref, k_ref, qn_ref, kn_ref):
-        qn_ref[0] = pallas_delta.unit(
-            q_ref[0].astype(f32), pallas_delta.QUERY_SCALE).astype(bf)
-        kn_ref[0] = pallas_delta.unit(k_ref[0].astype(f32)).astype(bf)
+        pallas_delta._to_tiles(qn_ref, pallas_delta.unit(
+            pallas_delta._heads(q_ref, step).astype(f32),
+            pallas_delta.QUERY_SCALE).astype(bf))
+        pallas_delta._to_tiles(kn_ref, pallas_delta.unit(
+            pallas_delta._heads(k_ref, step).astype(f32)).astype(bf))
     shape = jax.ShapeDtypeStruct((2, 128, 2 * 128), bf)
     qn, kn = pl.pallas_call(
-        probe, grid=(2, 2, 2), in_specs=[key(at[0]), key(at[1])],
+        probe, grid=(2, n_k // step, 2), in_specs=[key(at[0]), key(at[1])],
         out_specs=[key(), key()],
         out_shape=[shape, shape], interpret=True)(qa, ka)
     flat = decoder_ops._flat
@@ -641,33 +644,42 @@ def test_a_channel_that_falls_by_two_a_position_equals_the_recurrence(impl, d):
             got, ref, rtol=0, atol=1e-4 * np.abs(ref).max(), err_msg=name)
 
 
-# -- several heads a grid step of the channel kernels (PR 54) -----------------
+# -- several heads a grid step (the channel kernels: PR 54; the scalar: 58) --
 
 @pytest.fixture
 def heads_a_step(monkeypatch):
-    """Sets ``pallas_delta.CHANNEL_HEADS``; the kernels read it at their
-    trace, behind ``jax.jit``s, so the calls' caches go with every change."""
+    """Sets ``pallas_delta.STEP_HEADS``; the kernels read it at their trace,
+    behind ``jax.jit``s, so the calls' caches go with every change."""
     def clear():
         pallas_delta._fwd_call.clear_cache()
         pallas_delta._bwd_call.clear_cache()
 
     def set_to(limit):
-        monkeypatch.setattr(pallas_delta, "CHANNEL_HEADS", limit)
+        monkeypatch.setattr(pallas_delta, "STEP_HEADS", limit)
         clear()
     yield set_to
     monkeypatch.undo()
     clear()
 
 
-def channel_pass(feeds, form, chunk=64):
-    """(o, the states, dq | dk | dv, dG, dbeta) of the channel kernels in the
-    interpreter, bfloat16 operands as on the chip."""
+def decay_inputs(decay, batch, seq, key_heads, rep, seed):
+    """A decay a key ``channel`` (one value head a key head) or a value
+    ``head`` (``rep`` of them a key head), heads of 128."""
+    if decay == "channel":
+        assert rep == 1
+        return channel_inputs(batch, seq, key_heads, 128, seed)
+    return rule_inputs(batch, seq, key_heads, key_heads * rep, 128, 128, seed)
+
+
+def kernel_pass(feeds, form, chunk=64):
+    """(o, the states, dq | dk | dv, dG, dbeta) of the kernels in the
+    interpreter, bfloat16 operands as on the chip; ``g``'s rank says which
+    pair."""
     qkv = operands(feeds, form, jnp.bfloat16)
     cum = decoder_ops._chunk_sums(jnp.asarray(feeds["g"]), chunk)
     beta = jnp.asarray(feeds["beta"])
-    do = jnp.asarray(rng(31).randn(*feeds["v"].shape[:2], qkv[0].shape[-1]
-                                   if form == "split" else
-                                   qkv.shape[-1] // 3), jnp.bfloat16)
+    batch, seq, heads, dv = feeds["v"].shape
+    do = jnp.asarray(rng(31).randn(batch, seq, heads * dv), jnp.bfloat16)
     o, states = pallas_delta._fwd_call(qkv, cum, beta, chunk, True)
     dqkv, dg, db = pallas_delta._bwd_call(qkv, cum, beta, states, do, chunk,
                                           True)
@@ -677,37 +689,72 @@ def channel_pass(feeds, form, chunk=64):
         ("o", "states", "dqkv", "dG", "dbeta"), (o, states, dqkv, dg, db))}
 
 
-@pytest.mark.parametrize("form", ["split", "packed"])
-@pytest.mark.parametrize("heads,limit,took", [
-    (4, 4, 4), (8, 4, 4), (8, 2, 2), (4, 8, 4),     # the limit divides, or is
-    (1, 4, 1), (3, 4, 3), (3, 2, 1)])               # past; the call falls to
-def test_heads_a_grid_step_give_the_bits_of_one_head_a_step(    # a divisor
-        heads_a_step, form, heads, limit, took):
-    """A grid step of the channel kernels takes ``step_heads`` heads, each
-    through ``_channel_forward`` / ``_channel_backward`` as one head a step
-    goes: ``o``, the states and the five gradients are the same bits, in
-    either operand form (the packed array's lane blocks are then ``took``
-    tiles wide, k's and v's offsets whole blocks of them)."""
-    feeds = channel_inputs(2, 128, heads, 128, seed=30 + heads)
-    heads_a_step(1)
-    assert pallas_delta.step_heads(heads) == 1
-    want = channel_pass(feeds, form)
+@pytest.mark.parametrize("key_heads,heads,limit,took", [
+    (32, 32, 8, 8), (3, 3, 8, 3), (6, 6, 4, 3), (1, 1, 8, 1),  # one a key head
+    (16, 32, 8, 4), (16, 32, 16, 8), (16, 32, 2, 1), (16, 32, 1, 1),
+    (3, 6, 8, 3), (6, 12, 8, 3), (3, 6, 4, 1), (5, 10, 8, 1),
+    # v's offset in a packed operand, 2 key heads tiles, in whole blocks of
+    # the step's value heads: two key heads of four fit the limit and not it
+    (4, 16, 8, 2), (2, 8, 8, 1), (6, 24, 8, 1),
+    (1, 4, 8, 1), (1, 16, 8, 1)])       # never packed; wider than the limit
+def test_heads_a_step_follow_from_the_head_counts(heads_a_step, key_heads,
+                                                  heads, limit, took):
+    """``step_heads``: the largest divisor of the key heads whose value heads
+    fit ``STEP_HEADS`` and leave a packed operand's blocks whole; one key
+    head where none does (today's grid)."""
     heads_a_step(limit)
-    assert pallas_delta.step_heads(heads) == took
-    got = channel_pass(feeds, form)
+    assert pallas_delta.step_heads(key_heads, heads) == took
+    if heads == key_heads:
+        assert pallas_delta.step_heads(key_heads) == took
+    rep = heads // key_heads
+    assert key_heads % took == 0 and (took == 1 or took * rep <= limit)
+    if pallas_delta.packs(key_heads, heads):
+        assert 2 * key_heads % (took * rep) == 0
+
+
+@pytest.mark.parametrize("form", ["split", "packed"])
+@pytest.mark.parametrize("decay,heads,rep,limit,took", [
+    ("channel", 4, 1, 4, 4), ("channel", 8, 1, 4, 4),   # the limit divides,
+    ("channel", 8, 1, 2, 2), ("channel", 4, 1, 8, 4),   # or is past; the
+    ("channel", 1, 1, 4, 1), ("channel", 3, 1, 4, 3),   # call falls to a
+    ("channel", 3, 1, 2, 1),                            # divisor
+    ("head", 4, 1, 4, 4), ("head", 2, 2, 4, 2), ("head", 4, 2, 8, 4),
+    ("head", 4, 2, 2, 1), ("head", 3, 2, 8, 3), ("head", 6, 2, 8, 3),
+    ("head", 3, 2, 4, 1), ("head", 4, 4, 8, 2), ("head", 2, 4, 8, 1)])
+def test_heads_a_grid_step_give_the_bits_of_one_head_a_step(
+        heads_a_step, form, decay, heads, rep, limit, took):
+    """A grid step of the kernels takes ``step_heads`` key heads and all
+    their value heads, each value head through ``_channel_forward`` /
+    ``_channel_backward`` (``_scalar_forward`` / ``_scalar_backward``, a key
+    head's sums over its value heads added in one head at a time's order) as
+    one key head a step goes: ``o``, the states and the five gradients are
+    the same bits, in either operand form (the packed array's lane blocks
+    are then ``took`` key heads' tiles wide, k's and v's offsets whole
+    blocks of them)."""
+    feeds = decay_inputs(decay, 2, 128, heads, rep, seed=30 + heads)
+    heads_a_step(1)
+    assert pallas_delta.step_heads(heads, heads * rep) == 1
+    want = kernel_pass(feeds, form)
+    heads_a_step(limit)
+    assert pallas_delta.step_heads(heads, heads * rep) == took
+    got = kernel_pass(feeds, form)
     for name in want:
         assert np.abs(want[name]).max() > 0, name
         np.testing.assert_array_equal(got[name], want[name], err_msg=name)
 
 
-def test_a_heads_state_never_reaches_the_next_head_of_its_step(heads_a_step):
-    """Four heads a step, v zero in the second and the fourth: their states
-    stay zero through the chunks and their ``o`` is zero, whatever the heads
-    before them in the step's scratch hold."""
-    feeds = channel_inputs(1, 256, 4, 128, seed=40)
+@pytest.mark.parametrize("decay,heads,rep", [("channel", 4, 1),
+                                             ("head", 2, 2)])
+def test_a_heads_state_never_reaches_the_next_head_of_its_step(
+        heads_a_step, decay, heads, rep):
+    """Four value heads a step, v zero in the second and the fourth: their
+    states stay zero through the chunks and their ``o`` is zero, whatever
+    the heads before them in the step's scratch hold."""
+    feeds = decay_inputs(decay, 1, 256, heads, rep, seed=40)
     feeds["v"][:, :, 1::2] = 0.0
     heads_a_step(4)
-    got = channel_pass(feeds, "packed")
+    assert pallas_delta.step_heads(heads, 4) == heads
+    got = kernel_pass(feeds, "packed")
     states = got["states"]                      # [B, chunks, heads, d_k, d_v]
     assert not states[:, :, 1::2].any() and np.abs(states[:, 1:, ::2]).min(
         axis=(0, 1, 2)).max() > 0
@@ -715,53 +762,64 @@ def test_a_heads_state_never_reaches_the_next_head_of_its_step(heads_a_step):
     assert not o[:, :, 1::2].any() and np.abs(o[:, :, ::2]).max() > 0
 
 
-def test_the_counter_says_how_many_heads_a_grid_step_took():
-    """``delta_lowering_total``'s ``step_heads``: what ``step_heads`` took of
-    a channel op's heads where it lowered the kernels, ``1`` for a scalar
-    decay and for the composed form, and for a series without the label;
-    ``gated_delta.grouped_step_ops`` reads the ops that took the module
-    constant's value, in the Kimi Linear cell."""
+@pytest.mark.parametrize("decay,metric,cell,key_heads,rep", [
+    ("channel", "gated_delta.grouped_step_ops",
+     "kimi_linear_48b_a3b.pretrain_s4096", 32, 1),
+    ("head", "gated_delta.scalar_grouped_step_ops",
+     "qwen3_next_80b_a3b.pretrain_s4096", 16, 2)])
+def test_the_counter_says_how_many_heads_a_grid_step_took(
+        decay, metric, cell, key_heads, rep):
+    """``delta_lowering_total``'s ``step_heads``: the key heads
+    ``step_heads`` took of an op's head counts where it lowered the kernels,
+    ``1`` for the composed form and for a series without the label;
+    ``metric`` reads the ops of its decay that took what the cell's head
+    counts take (eight of Kimi Linear's 32 heads, four of Qwen3-Next's 16
+    key heads under 32 value heads)."""
     import json
     import os
     from benchmark.reducers import registry_count
-    n = pallas_delta.CHANNEL_HEADS
-    assert n > 1 and pallas_delta.step_heads(32) == n
-    assert [pallas_delta.step_heads(h) for h in (1, 2, 3)] == [
+    n = pallas_delta.step_heads(key_heads, key_heads * rep)
+    assert n > 1 and n * rep == pallas_delta.STEP_HEADS
+    assert [pallas_delta.step_heads(h, h * rep) for h in (1, 2, 3)] == [
         1, min(n, 2), 3 if n >= 3 else 1]
     root = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
     with open(os.path.join(root, "benchmark", "layer_metrics",
-                           "gated_delta.grouped_step_ops.json")) as f:
+                           metric + ".json")) as f:
         spec = json.load(f)
-    assert (spec["reducer"], spec["match"]) == (
-        "registry_count", "delta_lowering_total")
-    assert spec["labels"] == {"impl": "pallas", "decay": "channel",
+    assert (spec["name"], spec["reducer"], spec["match"]) == (
+        metric, "registry_count", "delta_lowering_total")
+    assert spec["labels"] == {"impl": "pallas", "decay": decay,
                               "step_heads": str(n)}
     with open(os.path.join(root, "BENCHMARK.json")) as f:
         (entry,) = [m for m in json.load(f)["per_layer"]
                     if m["name"] == spec["name"]]
-    assert entry["workloads"] == ["kimi_linear_48b_a3b.pretrain_s4096"]
+    assert entry["workloads"] == [cell]
     assert (entry["moves"], entry["source"], entry["layer"]) == (
         spec["moves"], spec["source"], spec["layer"]) == (
         "tokens_per_s", "program_counter", "gated_delta_rule")
-    kinds = [dict(impl="pallas", decay="channel", step_heads=str(n)),
-             dict(impl="pallas", decay="channel", step_heads="3"),
-             dict(impl="composed", decay="channel", step_heads="1"),
-             dict(impl="pallas", decay="head", step_heads="1")]
+    other = "head" if decay == "channel" else "channel"
+    kinds = [dict(impl="pallas", decay=decay, step_heads=str(n)),
+             dict(impl="pallas", decay=decay, step_heads="3"),
+             dict(impl="composed", decay=decay, step_heads="1"),
+             dict(impl="pallas", decay=other, step_heads="1")]
     before = [lowerings(**k) for k in kinds]
     read = registry_count.reduce(spec, None) or 0
     run_with_grads(rule_with("auto", 64, "packed"),
-                   channel_inputs(1, 64, n, 128, seed=6), ["g"])
-    run_with_grads(rule_with("auto", 64), channel_inputs(1, 64, 3, 128), [])
-    run_with_grads(rule_with("composed", 8), channel_inputs(1, 16, 2, 8), [])
+                   decay_inputs(decay, 1, 64, n, rep, seed=6), ["g"])
     run_with_grads(rule_with("auto", 64),
-                   rule_inputs(1, 128, 1, 2, 128, 128, seed=6), [])
+                   decay_inputs(decay, 1, 64, 3, rep, seed=0), [])
+    small = (channel_inputs(1, 16, 2, 8) if decay == "channel"
+             else rule_inputs(1, 16, 2, 4, 8, 8))
+    run_with_grads(rule_with("composed", 8), small, [])
+    run_with_grads(rule_with("auto", 64),
+                   decay_inputs(other, 1, 128, 1, 1, seed=6), [])
     assert [lowerings(**k) - b for k, b in zip(kinds, before)] == [1] * 4
     assert registry_count.reduce(spec, None) - read == 1
     # a report without the label (a parent's) is kept as one head a step
     main = fluid.Program()
     LowerCtx({}, salt=1, program=main).report(
         "delta_lowering_total", impl="pallas", chunk=64, heads=32,
-        key_dim=128, value_dim=128, operands="packed", decay="channel")
+        key_dim=128, value_dim=128, operands="packed", decay=decay)
     assert lowering_reports.read(
         lowering_reports.publish(main), "delta_lowering_total",
         "step_heads") == {"1": 1}

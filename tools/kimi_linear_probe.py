@@ -33,14 +33,15 @@ file adds:
               (milliseconds a layer forward / backward; ``--packed-from``:
               also by the block length from which a level of the halving
               hands the MXU its lower rows alone, ``pallas_delta.
-              PACKED_FROM``; ``--step-heads``: also by the heads a grid step
-              takes at most, ``pallas_delta.CHANNEL_HEADS``, and beside each
-              the same grid with empty bodies: a step's fixed cost and its
-              DMAs; ``--scalar-reps``: the scalar-decay pair at as many
-              value heads by the value heads a key head, the chains a step
-              of theirs holds) against the composed form and its
-              temporaries, and the flash kernels at 32 heads with q / k 256
-              (64 zero columns) or 192 wide and v 128.
+              PACKED_FROM``; ``--step-heads``: also by the value heads a
+              grid step takes at most, ``pallas_delta.STEP_HEADS``, and
+              beside each the same grid with empty bodies: a step's fixed
+              cost and its DMAs; ``--scalar-reps``: the scalar-decay pair at
+              as many value heads by the value heads a key head, each at
+              every ``--step-heads`` too: 2 4 8 16 are 1, 2, 4, 8 key heads
+              a step at two value heads a key head) against the composed
+              form and its temporaries, and the flash kernels at 32 heads
+              with q / k 256 (64 zero columns) or 192 wide and v 128.
 """
 from __future__ import annotations
 
@@ -361,10 +362,22 @@ def channel_feeds(B, S, n, d, rng) -> tuple:
     return q, k, v, do, g, beta
 
 
+def _no_scalar_forward(qn, kn, kk, qk, g, b, v, s):
+    """In ``pallas_delta._scalar_forward``'s place: nothing of a value head's
+    chunk (its key head's norms, ``k k^T`` and ``q k^T`` stay)."""
+    return v.astype("float32"), s
+
+
+def _no_scalar_backward(qn, kn, kk, qk, g, b, v, s, dsn, do):
+    """In ``pallas_delta._scalar_backward``'s place, as ``_no_backward``."""
+    qf, kf = qn.astype("float32"), kn.astype("float32")
+    return do.astype("float32"), qf, kf, kf, kk, qk, g, b, dsn
+
+
 def delta_kernels(feeds, chunks, packed_from, step_heads, interpret) -> list:
     """Forward / backward milliseconds a layer of the channel kernels on
     ``channel_feeds``' arrays, a row each chunk, ``packed_from``
-    (``pallas_delta.PACKED_FROM``'s place) and ``step_heads`` (``CHANNEL_
+    (``pallas_delta.PACKED_FROM``'s place) and ``step_heads`` (``STEP_
     HEADS``'s: the row says what ``pallas_delta.step_heads`` took of it, and
     what the grid costs with empty bodies); an empty list keeps the
     constant."""
@@ -391,8 +404,8 @@ def delta_kernels(feeds, chunks, packed_from, step_heads, interpret) -> list:
             return fwd, bwd, bool(jnp.isfinite(o).all())
         for size, heads in itertools.product(
                 packed_from or [pallas_delta.PACKED_FROM],
-                step_heads or [pallas_delta.CHANNEL_HEADS]):
-            with swapped(pallas_delta, PACKED_FROM=size, CHANNEL_HEADS=heads):
+                step_heads or [pallas_delta.STEP_HEADS]):
+            with swapped(pallas_delta, PACKED_FROM=size, STEP_HEADS=heads):
                 fwd, bwd, finite = both()
                 row = {"chunk": chunk, "packed_from": size,
                        "step_heads": pallas_delta.step_heads(n),
@@ -414,11 +427,14 @@ def delta_kernels(feeds, chunks, packed_from, step_heads, interpret) -> list:
     return rows
 
 
-def scalar_kernels(B, S, n, d, chunk, reps, interpret, rng) -> list:
+def scalar_kernels(B, S, n, d, chunk, reps, step_heads, interpret,
+                   rng) -> list:
     """Forward / backward milliseconds a layer of the scalar-decay kernels at
-    ``n`` value heads, by the value heads a key head: a grid step of theirs
-    is one key head and its ``rep`` value heads' chains (``qwen3_next`` has
-    16 key heads under 32 value heads, two chains a step)."""
+    ``n`` value heads, by the value heads a key head (``qwen3_next`` has 16
+    key heads under 32 value heads) and by ``step_heads`` as
+    ``delta_kernels`` takes them: a grid step of theirs is ``pallas_delta.
+    step_heads``' key heads and all their value heads' chains, side by
+    side."""
     import jax
     import jax.numpy as jnp
     from paddle_tpu.ops import decoder_ops, pallas_delta
@@ -436,15 +452,37 @@ def scalar_kernels(B, S, n, d, chunk, reps, interpret, rng) -> list:
         q, k = (jnp.asarray(rng.randn(B, S, n // rep * d), bf)
                 for _ in range(2))
         ops = ((q, k, v), decoder_ops._chunk_sums(g, chunk), beta)
-        _, states = pallas_delta._fwd_call(*ops, chunk, interpret)
-        fwd = _ms(lambda: pallas_delta._fwd_call(*ops, chunk, interpret))
-        bwd = _ms(lambda: pallas_delta._bwd_call(*ops, states, do, chunk,
-                                                 interpret))
-        rows.append({"chunk": chunk, "key_heads": n // rep, "rep": rep,
-                     "fwd_ms": fwd, "bwd_ms": bwd})
-        say(f"scalar-decay kernels, chunk {chunk}, {n // rep} key heads under "
-            f"{n} value heads ({rep} chains a grid step): forward {fwd:.3f} "
-            f"backward {bwd:.3f} ms a layer ({B} x {S})")
+
+        def both():
+            _, states = pallas_delta._fwd_call(*ops, chunk, interpret)
+            return (_ms(lambda: pallas_delta._fwd_call(*ops, chunk,
+                                                       interpret)),
+                    _ms(lambda: pallas_delta._bwd_call(*ops, states, do,
+                                                       chunk, interpret)))
+        for heads in step_heads or [pallas_delta.STEP_HEADS]:
+            with swapped(pallas_delta, STEP_HEADS=heads):
+                step = pallas_delta.step_heads(n // rep, n)
+                what = (f"scalar-decay kernels, chunk {chunk}, {n // rep} "
+                        f"key heads under {n} value heads, {step} key heads "
+                        f"({step * rep} chains) a grid step")
+                try:
+                    fwd, bwd = both()
+                    row = {"chunk": chunk, "key_heads": n // rep, "rep": rep,
+                           "step_heads": step, "fwd_ms": fwd, "bwd_ms": bwd}
+                    empty = ""
+                    if step_heads:
+                        with swapped(pallas_delta,
+                                     _scalar_forward=_no_scalar_forward,
+                                     _scalar_backward=_no_scalar_backward):
+                            row["empty_fwd_ms"], row["empty_bwd_ms"] = both()
+                        empty = (f"; empty bodies {row['empty_fwd_ms']:.3f} "
+                                 f"/ {row['empty_bwd_ms']:.3f}")
+                except Exception as e:      # noqa: BLE001  (Mosaic's refusal)
+                    say(f"{what}: {type(e).__name__}: {str(e)[:200]}")
+                    continue
+            rows.append(row)
+            say(f"{what}: forward {fwd:.3f} backward {bwd:.3f} ms a layer "
+                f"({B} x {S}){empty}")
     return rows
 
 
@@ -466,8 +504,8 @@ def kernels(args) -> dict:
         feeds, args.chunks, args.packed_from, args.step_heads, interpret)}
     if args.scalar_reps:
         result["scalar"] = scalar_kernels(
-            B, S, n, d, min(args.chunks[0], S), args.scalar_reps, interpret,
-            rng)
+            B, S, n, d, min(args.chunks[0], S), args.scalar_reps,
+            args.step_heads, interpret, rng)
     first = min(args.chunks[0], S)
 
     def composed(q, k, v, g, beta):
@@ -506,13 +544,13 @@ def main(argv=None) -> int:
                         help="kernels: block lengths in pallas_delta."
                              "PACKED_FROM's place, e.g. 4 8 16")
         ap.add_argument("--step-heads", nargs="*", type=int, default=[],
-                        help="kernels: heads a grid step in pallas_delta."
-                             "CHANNEL_HEADS's place, e.g. 1 2 4 8, each "
-                             "with the empty bodies' time beside it")
+                        help="kernels: value heads a grid step in "
+                             "pallas_delta.STEP_HEADS's place, e.g. 1 2 4 8, "
+                             "each with the empty bodies' time beside it")
         ap.add_argument("--scalar-reps", nargs="*", type=int, default=[],
                         help="kernels: the scalar-decay pair at as many "
                              "value heads, by value heads a key head, e.g. "
-                             "1 2 4")
+                             "1 2 4, at every --step-heads")
         ap.add_argument("--seeds", nargs="*", type=int, default=[],
                         help="readings: a check each")
     laguna_probe.load_cell = load_cell      # its modes load the cell by it
