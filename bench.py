@@ -88,24 +88,6 @@ def _timed_steps(run_one, state_probe, n_short=8, n_long=40):
                                        n_short, n_long, fallback=cons), cons
 
 
-def _timed_fused_steps(exe, main, feed, k, state_probe,
-                       n_short=4, n_long=24):
-    """Per-SUBSTEP seconds of the fused megastep path: K steps per dispatch
-    (Executor.run_fused) over a host-stacked copy of ``feed``, timed with
-    the same two-segment differencing as ``_timed_steps`` and
-    divided by K.  The identical training computation runs either way, so
-    (unfused per_step - this) is pure host dispatch/fetch overhead."""
-    stacked = {n: np.stack([np.asarray(v)] * k) for n, v in feed.items()}
-    run_one = lambda: exe.run_fused(main, stacked_feed=stacked,  # noqa: E731
-                                    fetch_list=[], return_numpy=False)
-    run_one()  # compile
-    run_one()  # warm
-    _sync(state_probe())
-    per_mega, _ = _timed_steps(run_one, state_probe,
-                               n_short=n_short, n_long=n_long)
-    return per_mega / k
-
-
 def _peak():
     """(peak bf16 FLOP/s, device_kind). The peak is None off TPU -- rows
     then carry no ``mfu`` key -- and an unknown TPU kind raises."""
@@ -132,7 +114,7 @@ def _mfu_guard(per_step, per_step_cons, flops):
 
 
 def bench_resnet50(batch=128, image=224, dtype="bfloat16", data_format="NHWC",
-                   conv1_space_to_depth=True, fuse_steps=None):
+                   conv1_space_to_depth=True):
     import jax
     import paddle_tpu as fluid
     from paddle_tpu.models import resnet
@@ -169,17 +151,12 @@ def bench_resnet50(batch=128, image=224, dtype="bfloat16", data_format="NHWC",
         per_step, per_step_cons = _timed_steps(
             lambda: exe.run(main, feed=feed, fetch_list=[], return_numpy=False),
             lambda: scope.find_var("fc_0.w_0"))
-        fused = None
-        if fuse_steps and fuse_steps > 1:
-            fused = _timed_fused_steps(exe, main, feed, fuse_steps,
-                                       lambda: scope.find_var("fc_0.w_0"))
     flops = program_flops(main, batch=batch)["total"]
     per_step, suspect = _mfu_guard(per_step, per_step_cons, flops)
-    return batch / per_step, per_step, flops, suspect, fused
+    return batch / per_step, per_step, flops, suspect
 
 
-def bench_bert_base(batch=128, seq=128, n_masks=20, dtype="bfloat16",
-                    fuse_steps=None):
+def bench_bert_base(batch=128, seq=128, n_masks=20, dtype="bfloat16"):
     """BERT-base (L12 H768 A12, vocab 30522) pretrain step: fwd+bwd+Adam."""
     import jax
     import paddle_tpu as fluid
@@ -228,13 +205,9 @@ def bench_bert_base(batch=128, seq=128, n_masks=20, dtype="bfloat16",
         per_step, per_step_cons = _timed_steps(
             lambda: exe.run(main, feed=feed, fetch_list=[], return_numpy=False),
             lambda: scope.find_var("word_emb"))
-        fused = None
-        if fuse_steps and fuse_steps > 1:
-            fused = _timed_fused_steps(exe, main, feed, fuse_steps,
-                                       lambda: scope.find_var("word_emb"))
     flops = program_flops(main, batch=1)["total"]  # shapes are fully static
     per_step, suspect = _mfu_guard(per_step, per_step_cons, flops)
-    return 1.0 / per_step, per_step, flops, batch, suspect, fused
+    return 1.0 / per_step, per_step, flops, batch, suspect
 
 
 def bench_allreduce(mbytes=256, sync_every=None):
@@ -656,7 +629,7 @@ def bench_checkpoint(n_saves=4, width=1024):
     return out
 
 
-def main(fuse_steps=None):
+def main():
     peak, kind = _peak()
 
     ck = bench_checkpoint()
@@ -670,8 +643,7 @@ def main(fuse_steps=None):
         "stall_reduction_pct": ck.get("stall_reduction_pct"),
     }), flush=True)
 
-    (bert_sps, bert_dt, bert_flops, bert_batch, bert_susp,
-     bert_fused) = bench_bert_base(fuse_steps=fuse_steps)
+    bert_sps, bert_dt, bert_flops, bert_batch, bert_susp = bench_bert_base()
     seqs = bert_sps * bert_batch
     print(json.dumps({
         "metric": "bert_base_pretrain_steps_per_sec",
@@ -684,17 +656,6 @@ def main(fuse_steps=None):
         "suspect": bert_susp,
         "device_kind": kind,
     }), flush=True)
-    if bert_fused is not None:
-        print(json.dumps({
-            "metric": "bert_base_pretrain_steps_per_sec_fused",
-            "value": round(1.0 / bert_fused, 3),
-            "unit": f"steps/sec (fuse_steps={fuse_steps} lax.scan megastep)",
-            "vs_baseline": round(1.0 / bert_fused * bert_batch / 42.0, 3),
-            "step_time_ms": round(bert_fused * 1e3, 2),
-            "vs_unfused_pct": round((bert_dt / bert_fused - 1) * 100, 1),
-            "device_kind": kind,
-        }), flush=True)
-
     allreduce = bench_allreduce()
     if allreduce is not None:       # one device: no interconnect, no line
         bw, bw_cons, mode, n = allreduce
@@ -717,19 +678,7 @@ def main(fuse_steps=None):
             "device_kind": kind,
         }), flush=True)
 
-    rn_ips, rn_dt, rn_flops, rn_susp, rn_fused = bench_resnet50(
-        fuse_steps=fuse_steps)
-    if rn_fused is not None:
-        print(json.dumps({
-            "metric": "resnet50_train_images_per_sec_per_chip_fused",
-            "value": round(128 / rn_fused, 2),
-            "unit": f"images/sec (fuse_steps={fuse_steps} lax.scan "
-                    f"megastep)",
-            "vs_baseline": round(128 / rn_fused / 360.0, 3),
-            "step_time_ms": round(rn_fused * 1e3, 2),
-            "vs_unfused_pct": round((rn_dt / rn_fused - 1) * 100, 1),
-            "device_kind": kind,
-        }), flush=True)
+    rn_ips, rn_dt, rn_flops, rn_susp = bench_resnet50()
     print(json.dumps({
         "metric": "resnet50_train_images_per_sec_per_chip",
         "value": round(rn_ips, 2),
@@ -759,13 +708,6 @@ def _parse_args(argv=None):
                          "conv+BN and attention shapes, persist the winners "
                          "in the decision cache, and let the bench runs "
                          "pick them up (PADDLE_TPU_TUNE=cached default)")
-    ap.add_argument("--fuse-steps", type=int, default=None, metavar="K",
-                    help="also measure the fused multi-step path: compile "
-                         "K training steps into one lax.scan megastep "
-                         "(Executor.run_fused) and emit *_fused metric "
-                         "lines beside the unfused numbers (the identical "
-                         "computation runs either way, so the delta is "
-                         "host dispatch/fetch overhead)")
     ap.add_argument("--comm-sweep", metavar="PATH", nargs="?",
                     const="BENCH_COMM_r01.json", default=None,
                     help="run ONLY the quantized-allreduce message-size "
@@ -856,7 +798,7 @@ if __name__ == "__main__":
         print(f"[bench] autotune: {len(_entries)} decisions "
               f"({_searched} newly searched) -> {_tuning.cache.CACHE.path}",
               file=sys.stderr)
-    main(fuse_steps=_args.fuse_steps)
+    main()
     if _args.emit_trace:
         from paddle_tpu import profiler as _prof
         _prof.stop_profiler(profile_path=os.devnull)

@@ -30,10 +30,10 @@ sys.path.insert(0, os.path.dirname(os.path.abspath(__file__)))
 
 import numpy as np
 
-from bench import _timed_steps, _sync, _peak, _timed_fused_steps
+from bench import _timed_steps, _sync, _peak
 
 
-def bench_transformer(batch=64, seq=64, fuse_steps=None):
+def bench_transformer(batch=64, seq=64):
     import jax
     import paddle_tpu as fluid
     from paddle_tpu.models import transformer
@@ -82,17 +82,11 @@ def bench_transformer(batch=64, seq=64, fuse_steps=None):
             lambda: exe.run(main, feed=feed, fetch_list=[],
                             return_numpy=False),
             lambda: scope.find_var("src_emb"), n_short=10, n_long=120)
-        fused = None
-        if fuse_steps and fuse_steps > 1:
-            fused = _timed_fused_steps(exe, main, feed, fuse_steps,
-                                       lambda: scope.find_var("src_emb"))
-    # source + target tokens processed per step; fused slot is None when
-    # the fused leg was not requested (same convention as bench.py)
-    return 2 * batch * seq / per_step, per_step, fused
+    # source + target tokens processed per step
+    return 2 * batch * seq / per_step, per_step
 
 
-def bench_deepfm(batch=4096, fields=26, vocab=1_000_000, embed=16,
-                 fuse_steps=None):
+def bench_deepfm(batch=4096, fields=26, vocab=1_000_000, embed=16):
     import jax
     import paddle_tpu as fluid
     from paddle_tpu.models import deepfm
@@ -126,15 +120,11 @@ def bench_deepfm(batch=4096, fields=26, vocab=1_000_000, embed=16,
             lambda: exe.run(main, feed=feed, fetch_list=[],
                             return_numpy=False),
             lambda: scope.find_var("fm_v"), n_short=10, n_long=120)
-        fused = None
-        if fuse_steps and fuse_steps > 1:
-            fused = _timed_fused_steps(exe, main, feed, fuse_steps,
-                                       lambda: scope.find_var("fm_v"))
-    return batch / per_step, per_step, fused
+    return batch / per_step, per_step
 
 
 def bench_deepfm_e2e(batch=4096, fields=26, vocab=1_000_000, embed=16,
-                     n_rows=200_000, fuse_steps=None):
+                     n_rows=200_000):
     """CTR epoch through the full input pipeline (VERDICT r4 #5): MultiSlot
     part files -> QueueDataset streaming parse -> prefetch thread ->
     train_from_dataset. Reports end-to-end examples/sec, the parse-only
@@ -153,14 +143,12 @@ def bench_deepfm_e2e(batch=4096, fields=26, vocab=1_000_000, embed=16,
     rng = np.random.RandomState(0)
     d = tempfile.mkdtemp(prefix="ctr_bench_")
     try:
-        return _deepfm_e2e_body(rng, d, batch, fields, vocab, embed, n_rows,
-                                fuse_steps)
+        return _deepfm_e2e_body(rng, d, batch, fields, vocab, embed, n_rows)
     finally:
         shutil.rmtree(d, ignore_errors=True)
 
 
-def _deepfm_e2e_body(rng, d, batch, fields, vocab, embed, n_rows,
-                     fuse_steps=None):
+def _deepfm_e2e_body(rng, d, batch, fields, vocab, embed, n_rows):
     import time
     import paddle_tpu as fluid
     from paddle_tpu.models import deepfm
@@ -226,41 +214,7 @@ def _deepfm_e2e_body(rng, d, batch, fields, vocab, embed, n_rows,
         exe.train_from_dataset(main, dataset=make_ds())
         _sync(fluid.global_scope().find_var("fm_v"))
         e2e_epoch = time.perf_counter() - t0
-        fused = None
-        if fuse_steps is not None and fuse_steps != 1:
-            # fused e2e epoch: same path, K steps per dispatch (the prefetch
-            # worker stacks the super-batches). fuse_steps=0 autotunes: the
-            # search epoch runs with the tune mode FORCED to search (an
-            # ambient PADDLE_TPU_TUNE=cached/off must not silently turn the
-            # "autotuned fused" leg into a mislabeled unfused re-measure),
-            # restored afterwards; the warm/timed epochs then run at the
-            # measured winner explicitly.
-            k_used = fuse_steps
-            if fuse_steps == 0:
-                from paddle_tpu import tuning
-                prev = os.environ.get("PADDLE_TPU_TUNE")
-                os.environ["PADDLE_TPU_TUNE"] = "search"
-                try:
-                    exe.train_from_dataset(main, dataset=make_ds(),
-                                           fuse_steps=0)  # search epoch
-                    params = exe._fuse_params(batches[0], [])
-                    rec = tuning.cache.CACHE.get(
-                        tuning.get_choice("fuse_steps.k").key(params))
-                finally:
-                    if prev is None:
-                        os.environ.pop("PADDLE_TPU_TUNE", None)
-                    else:
-                        os.environ["PADDLE_TPU_TUNE"] = prev
-                k_used = int(rec["winner"]) if rec else 1
-            exe.train_from_dataset(main, dataset=make_ds(),
-                                   fuse_steps=k_used)  # warm compile
-            t0 = time.perf_counter()
-            exe.train_from_dataset(main, dataset=make_ds(),
-                                   fuse_steps=k_used)
-            _sync(fluid.global_scope().find_var("fm_v"))
-            fused_epoch = time.perf_counter() - t0
-            fused = (n_ex / fused_epoch, fused_epoch, k_used)
-    return (n_ex / e2e_epoch, parse_epoch, serial_epoch, e2e_epoch, fused)
+    return n_ex / e2e_epoch, parse_epoch, serial_epoch, e2e_epoch
 
 
 # ------------------------------------------------------- auto-shard leg --
@@ -494,14 +448,9 @@ def main_autoshard():
             "device_kind": kind}), flush=True)
 
 
-def main(fuse_steps=None):
+def main():
     _, kind = _peak()
-    step_k = fuse_steps if fuse_steps else None
-    if fuse_steps == 0:
-        # the step benches have no dataset loop to search on; measure at
-        # the e2e-representative default so fused numbers still appear
-        step_k = 8
-    tps, dt, tr_fused = bench_transformer(fuse_steps=step_k)
+    tps, dt = bench_transformer()
     print(json.dumps({"metric": "transformer_nmt_tokens_per_sec",
                       "value": round(tps, 1),
                       "unit": "tokens/sec (base cfg f32, seq 64+64)",
@@ -511,16 +460,7 @@ def main(fuse_steps=None):
                                              "(no reference-published number)",
                       "step_time_ms": round(dt * 1e3, 2),
                       "device_kind": kind}), flush=True)
-    if tr_fused is not None:
-        fdt = tr_fused
-        print(json.dumps({"metric": "transformer_nmt_tokens_per_sec_fused",
-                          "value": round(2 * 64 * 64 / fdt, 1),
-                          "unit": f"tokens/sec (fuse_steps={step_k} "
-                                  f"lax.scan megastep)",
-                          "step_time_ms": round(fdt * 1e3, 2),
-                          "vs_unfused_pct": round((dt / fdt - 1) * 100, 1),
-                          "device_kind": kind}), flush=True)
-    eps, dt, fm_fused = bench_deepfm(fuse_steps=step_k)
+    eps, dt = bench_deepfm()
     print(json.dumps({"metric": "deepfm_ctr_examples_per_sec",
                       "value": round(eps, 1),
                       "unit": "examples/sec (vocab 1M, 26 fields)",
@@ -530,17 +470,7 @@ def main(fuse_steps=None):
                                              "(no reference-published number)",
                       "step_time_ms": round(dt * 1e3, 2),
                       "device_kind": kind}), flush=True)
-    if fm_fused is not None:
-        fdt = fm_fused
-        print(json.dumps({"metric": "deepfm_ctr_examples_per_sec_fused",
-                          "value": round(4096 / fdt, 1),
-                          "unit": f"examples/sec (fuse_steps={step_k} "
-                                  f"lax.scan megastep)",
-                          "step_time_ms": round(fdt * 1e3, 2),
-                          "vs_unfused_pct": round((dt / fdt - 1) * 100, 1),
-                          "device_kind": kind}), flush=True)
-    eps_e2e, parse_s, serial_s, e2e_s, fused = bench_deepfm_e2e(
-        fuse_steps=fuse_steps)
+    eps_e2e, parse_s, serial_s, e2e_s = bench_deepfm_e2e()
     print(json.dumps({"metric": "deepfm_ctr_e2e_examples_per_sec",
                       "value": round(eps_e2e, 1),
                       "unit": "examples/sec (file -> native parse -> "
@@ -552,31 +482,11 @@ def main(fuse_steps=None):
                       "prefetch_saving_pct": round(
                           (serial_s - e2e_s) / serial_s * 100, 1),
                       "device_kind": kind}), flush=True)
-    if fused is not None:
-        eps_f, fused_s, k_used = fused
-        print(json.dumps({"metric": "deepfm_ctr_e2e_examples_per_sec_fused",
-                          "value": round(eps_f, 1),
-                          "unit": "examples/sec (file -> native parse -> "
-                                  "prefetch(stacking worker) -> fused "
-                                  "megastep loop)",
-                          "fuse_steps": k_used,
-                          "fused_epoch_s": round(fused_s, 3),
-                          "e2e_epoch_s": round(e2e_s, 3),
-                          "vs_unfused_pct": round(
-                              (eps_f / eps_e2e - 1) * 100, 1),
-                          "device_kind": kind}), flush=True)
 
 
 def _parse_args(argv=None):
     import argparse
     ap = argparse.ArgumentParser(description=__doc__.splitlines()[0])
-    ap.add_argument("--fuse-steps", type=int, default=None, metavar="K",
-                    help="also measure fused multi-step execution (K "
-                         "training steps per lax.scan megastep) and emit "
-                         "*_fused metric lines beside the unfused numbers; "
-                         "0 = autotune K on the DeepFM e2e workload "
-                         "(PADDLE_TPU_TUNE=search in-loop search, winner "
-                         "persisted in the decision cache)")
     ap.add_argument("--auto-shard", action="store_true",
                     help="run the auto-shard planner leg instead of the "
                          "throughput benches: searched plan vs every "
@@ -594,4 +504,4 @@ if __name__ == "__main__":
     if _args.auto_shard:
         main_autoshard()
     else:
-        main(fuse_steps=_args.fuse_steps)
+        main()

@@ -76,7 +76,6 @@ def verify(program: Program,
            passes: Optional[Sequence[str]] = None,
            strategy=None, mem_budget: Optional[int] = None,
            batch: Optional[int] = None,
-           fuse_k: Optional[int] = None,
            auto_shard: bool = False,
            top_k: Optional[int] = None) -> List[Diagnostic]:
     """Run the analysis pipeline over ``program``; return sorted findings.
@@ -95,11 +94,6 @@ def verify(program: Program,
     when the estimate exceeds it; ``batch`` resolves dynamic (-1) dims for
     that accounting (without it the planner assumes batch 1 and says so,
     PT052).
-
-    ``fuse_k`` declares fused-megastep intent (Executor.run_fused passes
-    its K): the PT03x recompile lint then reasons about the fused feed
-    signature -- per-step shapes plus a K key component -- and flags the
-    compile-churn modes fusion adds (PT034).
 
     ``auto_shard=True`` engages the static auto-sharding planner (PT07x):
     it enumerates PT04x-legal per-tensor shard assignments over the
@@ -136,8 +130,7 @@ def verify(program: Program,
                                        fetch_names=fetch_names,
                                        strategy=strategy,
                                        mem_budget=mem_budget, batch=batch,
-                                       fuse_k=fuse_k, auto_shard=auto_shard,
-                                       top_k=top_k))
+                                       auto_shard=auto_shard, top_k=top_k))
 
 
 def verify_or_raise(program: Program,

@@ -77,10 +77,7 @@ CODES: Dict[str, tuple] = {
     "PT033": (Severity.INFO, "program has stochastic ops but no "
                              "random_seed: seed 0 is baked into the "
                              "compiled step"),
-    "PT034": (Severity.INFO, "fused multi-step execution with a dynamic "
-                             "batch dim: every distinct (K, batch) pair "
-                             "compiles its own megastep, plus the K=1 "
-                             "remainder entry"),
+    # PT034 is retired (PR 59) with what it linted; the number is not reused
     # -- distributed consistency (distributed.py) --------------------------
     "PT040": (Severity.ERROR, "collective op communicates over a mesh axis "
                               "the strategy's mesh does not define"),

@@ -65,7 +65,6 @@ class PassContext:
                  fetch_names: Optional[Sequence[str]] = None,
                  strategy=None, mem_budget: Optional[int] = None,
                  batch: Optional[int] = None,
-                 fuse_k: Optional[int] = None,
                  auto_shard: bool = False,
                  top_k: Optional[int] = None):
         self.program = program
@@ -80,11 +79,6 @@ class PassContext:
         self.strategy, self.build_strategy = split_strategy(strategy)
         self.mem_budget = mem_budget
         self.batch = batch
-        # fused-megastep intent: the executor's run_fused gate passes its K
-        # so the PT03x recompile lint reasons about the fused feed
-        # signature (per-step shapes + a K key component), not the stacked
-        # (K, batch, ...) arrays it happens to dispatch
-        self.fuse_k = fuse_k
         # auto-shard intent: arms the shardplan search pass (PT07x) and
         # upgrades the PT046 re-gather warning with the planner's priced
         # alternative; top_k bounds the ranked plans it keeps
@@ -181,12 +175,11 @@ def run_passes(program: Program, passes: Optional[Sequence[str]] = None,
                fetch_names: Optional[Sequence[str]] = None,
                strategy=None, mem_budget: Optional[int] = None,
                batch: Optional[int] = None,
-               fuse_k: Optional[int] = None,
                auto_shard: bool = False,
                top_k: Optional[int] = None) -> List[Diagnostic]:
     ctx = PassContext(program, feed_names=feed_names, fetch_names=fetch_names,
                       strategy=strategy, mem_budget=mem_budget, batch=batch,
-                      fuse_k=fuse_k, auto_shard=auto_shard, top_k=top_k)
+                      auto_shard=auto_shard, top_k=top_k)
     diags: List[Diagnostic] = []
     for name in (passes if passes is not None else default_passes()):
         diags.extend(get_pass(name).run(ctx))

@@ -19,11 +19,6 @@ first run:
 - PT033: stochastic ops with ``random_seed`` unset: seed 0 is silently
   baked into the compiled step (the seed is a cache-key component, and
   determinism across processes hinges on it being chosen, not defaulted).
-- PT034: dynamic batch dim under fused multi-step execution (the verify
-  gate passes ``fuse_k`` from ``Executor.run_fused``): the fused cache key
-  is (per-step feed signature, K), so batch variety multiplies by the K
-  values in play -- and each fused epoch also compiles a K=1 remainder
-  entry for the trailing partial chunk.
 """
 from __future__ import annotations
 
@@ -68,21 +63,6 @@ class RecompileRiskPass(AnalysisPass):
                                  f"own cache entry (keep batch sizes "
                                  f"uniform, pad the last batch)",
                         block_idx=b.idx, var=n))
-                    if ctx.fuse_k and ctx.fuse_k > 1:
-                        # fused intent: the megastep key is (per-step feed
-                        # signature, K), so batch variety multiplies by the
-                        # K values in play -- and every fused epoch also
-                        # compiles the K=1 remainder entry for the trailing
-                        # partial chunk. Expected churn, but worth naming
-                        # before the first run.
-                        diags.append(Diagnostic(
-                            "PT034", f"data var {n!r} runs under fused "
-                                     f"multi-step execution (K="
-                                     f"{ctx.fuse_k}): every distinct "
-                                     f"(K, batch) pair compiles its own "
-                                     f"megastep, plus a K=1 entry for the "
-                                     f"trailing remainder chunk",
-                            block_idx=b.idx, var=n))
         self._check_is_test_mix(ctx, diags)
         self._check_seed(ctx, diags)
         return diags

@@ -96,7 +96,7 @@ def _var_bytes(v) -> int:
 def _decide_tensor(v, mode: str, ndp: int, min_bytes: int) -> str:
     """'off'|'bf16'|'int8' for one gradient tensor: the hard gates, then
     the ``comm.compress`` TunableChoice (measured on the live workload
-    via ``tuning.record_decision``, like ``fuse_steps.k``)."""
+    via ``tuning.record_decision``)."""
     ok, _ = compression_eligible(v, mode, min_bytes)
     if not ok:
         return "off"

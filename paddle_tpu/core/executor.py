@@ -125,22 +125,16 @@ def _cache_count(kind: str, cache: str, n: int = 1):
 def materialize_fetches(fetches):
     """Force lazy (device-array) fetches to host numpy.
 
-    The ONE place the fused training loop performs a fetch d2h sync:
+    The ONE place the lazy training loop performs a fetch d2h sync:
     ``train_from_dataset`` keeps fetches as live device arrays and routes
     every materialization -- debug ``print_period`` boundaries and the
     final return -- through here, so debug mode cannot silently re-
-    introduce the per-step sync the fused loop exists to remove.  Counted
-    (``fused_fetch_materializations_total``) so the obs_report Megastep
-    section can report how often an epoch actually synced."""
+    introduce a per-step sync.  Counted
+    (``fused_fetch_materializations_total``): how often an epoch synced."""
     _OBS.counter("fused_fetch_materializations_total",
                  "lazy-fetch materializations (fetch d2h syncs) in the "
-                 "fused/lazy training loop").inc()
+                 "lazy training loop").inc()
     return [np.asarray(f) for f in fetches]
-
-
-#: K values the ``fuse_steps.k`` in-loop autotune search measures (on the
-#: live workload itself -- search steps ARE training steps)
-_FUSE_SEARCH_PROBES = 2  # timed megasteps per candidate K
 
 
 class Scope:
@@ -276,11 +270,6 @@ class _CompiledStep:
         # at cache-miss time; backs cost_analysis() and exact compile timing.
         self.executable = None
         self.compile_seconds: Optional[float] = None
-        # fused (lax.scan megastep) entries: substep count and the watched
-        # tensor names behind the in-scan health-flag rows (filled at trace
-        # time; [] when the step compiled without the health reduction)
-        self.fused_k: Optional[int] = None
-        self.health_names: List[str] = []
 
     def cost_analysis(self):
         """XLA optimized-HLO cost analysis for this step (raw jax form: a
@@ -501,8 +490,8 @@ def _jit_step(fn, mut_names, ro_names, state_out, fetch_names,
 
 
 def _front_door(program, fetch_list, scope):
-    """What ``run`` and ``run_fused`` were handed, resolved: ``(Program,
-    CompiledProgram wrapper or None, fetch names, Scope)``."""
+    """What ``run`` was handed, resolved: ``(Program, CompiledProgram
+    wrapper or None, fetch names, Scope)``."""
     program = program or default_main_program()
     wrapper = None
     if not isinstance(program, Program):  # CompiledProgram front door
@@ -545,6 +534,9 @@ class Executor:
         # key the memo). The diags are kept so raise-mode can re-apply its
         # policy on retries of a failing program.
         self._verified: Dict[Tuple, Tuple[Program, list]] = {}
+        # (program id, version, fetch names) -> (program, pruned program)
+        self._prune_cache: Dict[Tuple, Tuple[Program, Program]] = {}
+        self._obs_step = 0      # journaled runs, for the memory sampler
         # Fleet-telemetry arming points LAST -- the weak registry and the
         # hooks only see fully-constructed executors (a raised typo'd-env
         # ValueError must not leave a half-built instance in _instances
@@ -585,7 +577,7 @@ class Executor:
             _obs_timeline.mark_uptime("first_executor")
 
     def _maybe_verify(self, program: Program, feed_names, fetch_names,
-                      wrapper=None, feed_shapes=None, fuse_k=None):
+                      wrapper=None, feed_shapes=None):
         """PADDLE_TPU_VALIDATE=off|warn|raise gate, called only at compile
         cache-miss time (default off: unset costs one os.environ read per
         MISS, zero per warm step). Findings go to the journal/metrics
@@ -634,7 +626,7 @@ class Executor:
         vkey = (id(program), program._version,
                 tuple(sorted(feed_names)), tuple(fetch_names),
                 wrapper.strategy_signature() if strategy is not None else (),
-                mem_budget, batch, fuse_k)
+                mem_budget, batch)
         prev = self._verified.get(vkey)
         if prev is not None and prev[0] is program:
             # already verified this program version under this run intent
@@ -652,8 +644,7 @@ class Executor:
                 diags = analysis.verify(program, feed_names=feed_names,
                                         fetch_names=fetch_names,
                                         strategy=strategy,
-                                        mem_budget=mem_budget, batch=batch,
-                                        fuse_k=fuse_k)
+                                        mem_budget=mem_budget, batch=batch)
             self._verified[vkey] = (program, diags)
             while len(self._verified) > self._CACHE_CAP:
                 self._verified.pop(next(iter(self._verified)))
@@ -746,8 +737,8 @@ class Executor:
     def _hoisted(self, program: Program):
         """Cached host-table hoist entry for ``program``:
         ``(program, hoisted_program, pending_pulls, pending_pushes)`` --
-        shared by the step path, the fused path's eligibility check, and
-        the guardian (one hoist per program version, LRU-bounded)."""
+        shared by the step path and the guardian (one hoist per program
+        version, LRU-bounded)."""
         hkey = (id(program), program._version)
         hcache = getattr(self, "_hoist_cache", None)
         if hcache is None:
@@ -805,9 +796,9 @@ class Executor:
                                 feed_shapes, feed_names, fetch_names,
                                 wrapper, exe_args, warm: bool = False,
                                 role: str = ""):
-        """Compile-time gauges shared by the step and megastep paths:
-        compile histogram, XLA cost/memory gauges, the static planner's
-        estimate beside them, the state the step takes in by class, and
+        """Compile-time gauges of a step: compile histogram, XLA
+        cost/memory gauges, the static planner's estimate beside them, the
+        state the step takes in by class, and
         one occupancy sample, which is also the allocator's marks before
         this program's first run (the ``compile`` / ``warm_restore`` span
         itself is the phase ``_materialize_miss`` held open around the
@@ -843,26 +834,20 @@ class Executor:
         # PADDLE_TPU_OBS_ATTRIB / an armed --emit-hlo capture asks for it
         # (on_compile is a no-op otherwise and never raises)
         from ..observability import attribution as _obs_attrib
-        # megastep compiles attribute under their own label: a K=4 scan is
-        # a different executable than the K=1 step of the same program
-        # version, and hlo_diff-ing the two is the point
-        attrib_label = label if not getattr(compiled, "fused_k", None) \
-            else f"{label}:k{compiled.fused_k}"
-        _obs_attrib.on_compile(compiled, program, attrib_label)
+        _obs_attrib.on_compile(compiled, program, label)
 
-    def _materialize_miss(self, kind, program, key, compiled, exe_args,
+    def _materialize_miss(self, program, key, compiled, exe_args,
                           label, role, step_idx, feed_shapes, feed_names,
-                          fetch_names, wrapper, world_dependent):
+                          fetch_names, wrapper):
         """Give the freshly cached step its executable now, rather than
         letting jit compile lazily inside the first call: the executable's
         cost_analysis() backs the FLOPs/MFU gauges and the compile time is
         measured exactly.  A failure is a real compile error (Mosaic
         refusing a kernel, VMEM, device OOM) and surfaces here, once.
-        Shared by the step (``kind="train_step"``) and megastep
-        (``"fused_step"``) paths; returns the key the entry lives under
-        afterwards. ``role`` (``_program_role``) goes on the spans of the
-        miss, on the lowering seconds and into ``program_role``; what the
-        compile was made of into ``program_compile_seconds``."""
+        Returns the key the entry lives under afterwards. ``role``
+        (``_program_role``) goes on the spans of the miss, on the lowering
+        seconds and into ``program_role``; what the compile was made of
+        into ``program_compile_seconds``."""
         t0 = time.perf_counter()
         restored = ws_key = ws_store = ws_expect = None
         _phase = _obs_timeline.phase
@@ -874,8 +859,7 @@ class Executor:
                         role=role):
                 try:
                     ws_expect = {"avals": repr(_ws_avals(exe_args))}
-                    ws_key = self._warmstore_key(
-                        kind, program, key, world_dependent=world_dependent)
+                    ws_key = self._warmstore_key(program, key)
                     restored, ws_store = self._warmstore_consult(
                         ws_key, exe_args, ws_expect)
                 except Exception:
@@ -952,11 +936,11 @@ class Executor:
                 pass
         return key
 
-    def _feed_prep(self, program, compiled, scope, feed, label, k=1,
+    def _feed_prep(self, program, compiled, scope, feed, label,
                    place=False):
-        """What one dispatch of ``k`` steps hands the compiled step: the
-        state read from the scope (``state_lookup``), the feeds on the
-        device (``h2d``) and the run counter, which advances by ``k``.
+        """What one dispatch hands the compiled step: the state read from
+        the scope (``state_lookup``), the feeds on the device (``h2d``) and
+        the run counter, which advances by one.
         ``place`` (a compile miss): under a mesh the state is laid over it
         here, in a ``place_state`` span, and not by the step's first call.
         Returns ``(step_idx, mut_vals, ro_vals, feed_vals, rng)``."""
@@ -967,8 +951,7 @@ class Executor:
         # step's spans agree)
         counter = getattr(program, "_rng_run_counter", 0)
         _phase = _obs_timeline.phase
-        with _phase("feed_prep", step=counter, program=label,
-                    **({"k": k} if k > 1 else {})):
+        with _phase("feed_prep", step=counter, program=label):
             # Multi-host SPMD: assemble global arrays. State values are
             # host-identical full copies (deterministic startup) ->
             # device_put against the target sharding; feeds are per-host
@@ -1037,7 +1020,7 @@ class Executor:
             # device; fold_in runs inside the compiled step (an eagerly
             # computed key is a separate tiny dispatch through the runtime
             # per step; its cost on the current runtime is not measured).
-            program._rng_run_counter = counter + k
+            program._rng_run_counter = counter + 1
             rng = np.uint32(counter)
         return counter, mut_vals, ro_vals, feed_vals, rng
 
@@ -1066,15 +1049,15 @@ class Executor:
         except Exception:
             pass
 
-    def _warmstore_key(self, kind, program, key, world_dependent):
+    def _warmstore_key(self, program, key):
         """Map the in-process cache key onto the store's cross-process
         key (program content digest instead of id(), decision-record
         fingerprint instead of the in-process epoch)."""
         from .. import warmstore as _ws
-        return _ws.build_key(kind, program, feed_sig=key[2],
+        return _ws.build_key("train_step", program, feed_sig=key[2],
                              fetch_names=key[3], seed=key[4], flags=key[5],
                              strategy=key[6],
-                             world_dependent=world_dependent)
+                             world_dependent=key[6] != ())
 
     def _warmstore_consult(self, ws_key, args, expect):
         """Try to restore this miss's executable from the store.
@@ -1153,8 +1136,6 @@ class Executor:
             # the ops needed to produce the fetches — eval-style fetches must not
             # trigger optimizer updates.
             pkey = (id(program), program._version, tuple(fetch_names))
-            if not hasattr(self, "_prune_cache"):
-                self._prune_cache = {}
             entry = self._prune_cache.get(pkey)
             # the entry retains the source program: after GC, CPython id reuse
             # could otherwise hand a new Program another program's pruned graph
@@ -1247,14 +1228,12 @@ class Executor:
         return list(fetches)
 
     def _dispatch(self, program, wrapper, feed, fetch_names, scope,
-                  n_user_fetch, k=1):
-        """The one body behind ``run`` (``k`` = 1) and ``run_fused`` (``feed``
-        stacked to (k, ...), k > 1): state names, cache key, lookup or
-        compile, feed prep, the call, telemetry, scope write-back and health.
+                  n_user_fetch):
+        """The body of ``run``: state names, cache key, lookup or compile,
+        feed prep, the call, telemetry, scope write-back and health.
         Returns the fetches as the compiled step gave them."""
         import jax
 
-        fused = k > 1
         state_in, state_out = self._state_names(program, feed, fetch_names)
         if any(_comm_is_residual(n) for n in state_in):
             # error-feedback residuals start at zero; they are created by
@@ -1298,12 +1277,8 @@ class Executor:
         hmode = _obs_health.mode()
         health_on = hmode != "off"
         include_state = health_on and _obs_health.include_state()
-        # a megastep's feed signature is PER-STEP (leading K stripped): the
-        # verifier and the recompile detector reason about the program's own
-        # shapes, and K gets its own key component below
-        lead = 1 if fused else 0
         feed_sig = tuple(sorted(
-            (n, tuple(np.shape(v))[lead:], str(np.asarray(v).dtype)
+            (n, tuple(np.shape(v)), str(np.asarray(v).dtype)
              if not hasattr(v, "dtype") else str(v.dtype))
             for n, v in feed.items()))
         # random_seed is baked into the compiled step (the per-run key is derived
@@ -1311,17 +1286,9 @@ class Executor:
         # avoiding a per-step host->device key transfer that stalls dispatch).
         seed = program.random_seed if program.random_seed is not None else 0
         from .. import flags as _flags
-        # a megastep takes the strategy's slot (a fused program has none):
-        # a K=4 scan is another entry than the K=1 step, here and in the
-        # warm store, as it must be
-        fuse = (k, health_on, include_state) if fused else None
-        if fused:
-            strategy = ("__fused__",) + fuse
-        else:
-            strategy = wrapper.strategy_signature() \
-                if wrapper is not None else ()
         key = (id(program), program._version, feed_sig, tuple(fetch_names), seed,
-               _flags.get_flag("xla_compiler_options"), strategy,
+               _flags.get_flag("xla_compiler_options"),
+               wrapper.strategy_signature() if wrapper is not None else (),
                _tuning.state_token())
         label = f"{id(program)}:v{program._version}"
         compiled = self._cache.get(key)
@@ -1340,30 +1307,25 @@ class Executor:
             # CompiledProgram wrapper hands its strategy to the PT04x
             # distributed checks, the feed shapes resolve the planner batch
             # (feed_shapes is reused by the static-memory gauge below)
-            feed_shapes = {n: tuple(np.shape(v))[lead:]
-                           for n, v in feed.items()}
+            feed_shapes = {n: tuple(np.shape(v)) for n, v in feed.items()}
             self._maybe_verify(program, list(feed), fetch_names,
-                               wrapper=wrapper, feed_shapes=feed_shapes,
-                               fuse_k=k if fused else None)
+                               wrapper=wrapper, feed_shapes=feed_shapes)
             # recompile detector: which cache-key component changed since this
             # Program last compiled (shape = feed shapes/dtypes, flags = XLA
             # compiler options, strategy = dist strategy, plus version/
             # fetches/seed)?
             self._note_compile(program, {
                 "version": key[1], "shape": key[2], "fetches": key[3],
-                "seed": key[4], "flags": key[5],
-                "strategy": () if fused else key[6],
-                "fuse": key[6] if fused else None, "tuning": key[7]})
+                "seed": key[4], "flags": key[5], "strategy": key[6],
+                "tuning": key[7]})
             # black-box forensics: remember what the LAST compile saw
             # (miss-time only -- zero warm-step cost)
             self._last_compile_info = {
                 "program": label,
                 "feed_shapes": {n: list(s) for n, s in feed_shapes.items()},
-                "fetches": list(fetch_names)[:32],
-                "fuse_k": k if fused else None}
+                "fetches": list(fetch_names)[:32]}
             compiled = self._compile(program, list(feed), fetch_names,
-                                     state_in, state_out, wrapper=wrapper,
-                                     fuse=fuse)
+                                     state_in, state_out, wrapper=wrapper)
             self._store_compiled(key, compiled)
         else:
             _cache_count("hits", "compile")
@@ -1371,25 +1333,21 @@ class Executor:
 
         _phase = _obs_timeline.phase
         step_idx, mut_vals, ro_vals, feed_vals, rng = self._feed_prep(
-            program, compiled, scope, feed, label, k=k, place=was_miss)
+            program, compiled, scope, feed, label, place=was_miss)
         _obs_timeline.annotate(step=step_idx, program=label)
 
         if was_miss:
             role = _program_role(program, feed)
             _obs_timeline.annotate(role=role)
             key = self._materialize_miss(
-                "fused_step" if fused else "train_step", program, key,
-                compiled, (mut_vals, ro_vals, feed_vals, rng), label, role,
-                step_idx, feed_shapes, list(feed), fetch_names, wrapper,
-                world_dependent=not fused and key[6] != ())
+                program, key, compiled, (mut_vals, ro_vals, feed_vals, rng),
+                label, role, step_idx, feed_shapes, list(feed), fetch_names,
+                wrapper)
 
         obs_on = _obs_journal.enabled()
         step_fn = compiled.executable if compiled.executable is not None \
             else compiled.fn
-        kargs = {"k": k} if fused else {}
-        if fused:
-            around = _phase("megastep", step=step_idx, program=label, k=k)
-        elif _flags.get_flag("profile_executor"):
+        if _flags.get_flag("profile_executor"):
             from .. import profiler as _profiler
             around = _profiler.record_event(
                 f"executor_run_v{program._version}")
@@ -1402,10 +1360,9 @@ class Executor:
         t_run = time.perf_counter()
         fallback_retraced = False
         with around:
-            with _phase("dispatch", step=step_idx, program=label, **kargs):
-                # a megastep returns its in-scan health flags third
+            with _phase("dispatch", step=step_idx, program=label):
                 try:
-                    fetches, new_state, *hflags = step_fn(
+                    fetches, new_state = step_fn(
                         mut_vals, ro_vals, feed_vals, rng)
                 except TypeError:
                     if step_fn is compiled.fn:
@@ -1421,7 +1378,7 @@ class Executor:
                     # propagate, not silently re-execute.
                     compiled.executable = None
                     fallback_retraced = True
-                    fetches, new_state, *hflags = compiled.fn(
+                    fetches, new_state = compiled.fn(
                         mut_vals, ro_vals, feed_vals, rng)
             if _flags.get_flag("benchmark"):
                 with _phase("fetch_sync", step=step_idx, program=label):
@@ -1433,7 +1390,7 @@ class Executor:
         run_s = time.perf_counter() - t_run
         _OBS.histogram("executor_run_seconds",
                        "Executor.run dispatch/step wall time").observe(run_s)
-        _OBS.counter("executor_runs_total", "Executor.run calls").inc(k)
+        _OBS.counter("executor_runs_total", "Executor.run calls").inc()
         warm = not was_miss and not fallback_retraced
         if warm and (obs_on or _flags.get_flag("benchmark")):
             # warm steps only: a compile (cache miss OR the TypeError
@@ -1442,15 +1399,13 @@ class Executor:
             # only: without the block_until_ready above, run_s is bare
             # async dispatch time -- a device-side regression would be
             # invisible to the detector and host jitter would false-flag.
-            # Windowed per cache entry (key includes the feed signature
-            # and the fuse marker): two shapes of one program may differ
-            # legitimately by large factors and must not share a median,
-            # nor a K=8 megastep's amortized per-substep time with K=1
-            # steps of the same program.
+            # Windowed per cache entry (key includes the feed signature):
+            # two shapes of one program may differ legitimately by large
+            # factors and must not share a median.
             from ..observability import anomaly as _obs_anomaly
-            _obs_anomaly.DETECTOR.observe(label, run_s / k, key=key)
-        if (obs_on or _flags.get_flag("benchmark")) and not (
-                fused or fallback_retraced):
+            _obs_anomaly.DETECTOR.observe(label, run_s, key=key)
+        if (obs_on or _flags.get_flag("benchmark")) and \
+                not fallback_retraced:
             # both paths block_until_ready above, so run_s is true step wall
             # time and the derived FLOP/s + MFU gauges are meaningful (the
             # bare dispatch time of the async path would inflate them; a
@@ -1463,72 +1418,39 @@ class Executor:
             # detector; gather-mode collections key on the program's step
             # index (retry/rollback rewinds included) so every rank hits
             # the collective at the same committed step
-            _obs_fleet.MONITOR.on_step(warm=warm, step=step_idx, **kargs)
+            _obs_fleet.MONITOR.on_step(warm=warm, step=step_idx)
         if obs_on:
-            self._obs_step = getattr(self, "_obs_step", 0) + 1
+            self._obs_step += 1
             from ..observability import memory as _obs_memory
             if self._obs_step % _obs_memory.sample_interval() == 0:
                 _obs_memory.sample_device_memory("interval")
             with _phase("journal", step=step_idx, program=label):
-                # substep i of a megastep ran under step0 + i
                 _obs_journal.emit({
-                    "event": "megastep" if fused else "run",
+                    "event": "run",
                     "program": id(program), "version": program._version,
                     "cache": "miss" if was_miss else "hit",
-                    **({"k": k, "step0": step_idx} if fused else {}),
                     "compile_ms": (round(compiled.compile_seconds * 1e3, 3)
                                    if was_miss and compiled.compile_seconds
                                    is not None else None),
                     "run_ms": round(run_s * 1e3, 3),
-                    **({"amortized_ms": round(run_s / k * 1e3, 3)}
-                       if fused else {}),
                     "feed": {n: [list(shape), dtype]
                              for n, shape, dtype in feed_sig},
                     "fetch": list(fetch_names[:n_user_fetch]),
                 })
-        corrupted = False
         if _rfaults._active:
             # fault sites: transient fetch/d2h error or hang, and NaN/Inf
             # corruption of named fetches/state BEFORE the scope commit --
             # the health watchdog and the step guardian both see it
-            if not fused:
-                _rfaults.fire("fetch", step_idx, program=label)
-                fetches, new_state = _rfaults.corrupt_step(
-                    step_idx, list(fetch_names), fetches, new_state,
-                    program=label)
-            else:
-                fired0 = sum(f.fired for f in _rfaults._active)
-                for i in range(k):
-                    _rfaults.fire("fetch", step_idx + i, program=label)
-                rows = [[f[i] for f in fetches] for i in range(k)]
-                for i in range(k):
-                    rows[i], new_state = _rfaults.corrupt_step(
-                        step_idx + i, list(fetch_names), rows[i], new_state,
-                        program=label)
-                if sum(f.fired for f in _rfaults._active) != fired0:
-                    corrupted = True
-                    # restack the (possibly corrupted) substep rows; chaos
-                    # mode only -- the clean path never materializes here
-                    fetches = [np.stack([np.asarray(rows[i][j])
-                                         for i in range(k)])
-                               for j in range(len(fetch_names))]
+            _rfaults.fire("fetch", step_idx, program=label)
+            fetches, new_state = _rfaults.corrupt_step(
+                step_idx, list(fetch_names), fetches, new_state,
+                program=label)
         for n, v in new_state.items():
             scope.set_var(n, v)
-        if health_on and fused and not corrupted:
-            # the any-nonfinite reduction ran inside the scan: one packed
-            # (K, n_watch) read per megastep
-            if hflags[0] is not None:
-                _obs_health.check_flag_matrix(
-                    _obs_health.read_flags(hflags[0]), compiled.health_names,
-                    label, where="executor", health_mode=hmode,
-                    step0=step_idx)
-        elif health_on:
+        if health_on:
             # one compiled any-nonfinite reduction over the user fetches
             # (+ written state when PADDLE_TPU_OBS_HEALTH_STATE=1): a single
-            # packed-bool device->host read, never a per-tensor sync.  Also
-            # what scans a megastep whose injected corruption came AFTER the
-            # in-scan flags (chaos path; attribution loses the substep, keeps
-            # the var -- each stacked (K, ...) fetch is scanned whole)
+            # packed-bool device->host read, never a per-tensor sync
             named = list(zip(fetch_names, fetches))[:n_user_fetch]
             if include_state:
                 named += list(new_state.items())
@@ -1541,78 +1463,8 @@ class Executor:
             if bad:
                 raise FloatingPointError(
                     f"NaN/Inf detected in state vars {bad[:5]} after "
-                    f"{'fused ' if fused else ''}run (FLAGS_check_nan_inf)")
+                    f"run (FLAGS_check_nan_inf)")
         return fetches
-
-    # -- fused multi-step (megastep) execution -----------------------------------------
-    def _fuse_ineligible(self, program, wrapper=None) -> Optional[str]:
-        """Why ``program`` cannot run fused (None = it can).  Distributed
-        strategies keep the SPMD jit path and host-table programs keep the
-        hoisted pull->step->push schedule -- both per-step host work the
-        scan cannot absorb."""
-        if wrapper is not None and wrapper.dist_strategy:
-            return "CompiledProgram with a DistributedStrategy"
-        _, _, pulls, pushes = self._hoisted(program)
-        if pulls or pushes:
-            return "host-table pulls/pushes (PS schedule)"
-        return None
-
-    @_obs_timeline.spanned("run")
-    def run_fused(self, program: Optional[Program] = None, feeds=None,
-                  fetch_list: Optional[Sequence] = None,
-                  scope: Optional[Scope] = None, return_numpy: bool = False,
-                  stacked_feed: Optional[dict] = None):
-        """Dispatch K training steps as ONE compiled ``lax.scan`` megastep.
-
-        ``feeds`` is a list of K per-step feed dicts (host arrays, stacked
-        here), or pass ``stacked_feed`` = {name: (K, ...) array} when the
-        stacking already happened upstream (the prefetch worker does, so it
-        overlaps device compute).  State threads through the scan carry with
-        the same donated-buffer semantics as ``run``; the program's rng-run
-        counter advances K times (substep i uses counter0+i, exactly the
-        unfused sequence); per-step fetches come back STACKED as (K, ...)
-        arrays -- live device arrays by default (``return_numpy=False``):
-        lazy, not donated, materialize with ``np.asarray`` when needed.
-
-        K=1 delegates to ``run`` (byte-identical to today's loop, pinned by
-        test); the trailing partial chunk of ``train_from_dataset`` goes
-        through the same K=1 path, so fusion adds no padding/masking.
-        Python dispatch, feed device_put and fetch-sync overhead amortize
-        ~K-fold -- the reference's C++ device-worker amortization
-        (executor.py:920) done in the compiler instead.
-        """
-        program, compiled_wrapper, fetch_names, scope = _front_door(
-            program, fetch_list, scope)
-        reason = self._fuse_ineligible(program, compiled_wrapper)
-        if reason is not None:
-            raise ValueError(
-                f"run_fused: program cannot run fused ({reason}); run it "
-                f"unfused (fuse_steps=1 / Executor.run)")
-        if stacked_feed is not None:
-            feed = dict(stacked_feed)
-            if not feed:
-                raise ValueError("run_fused needs a non-empty feed")
-            k = int(np.shape(next(iter(feed.values())))[0])
-        else:
-            feeds = list(feeds or [])
-            if not feeds:
-                raise ValueError("run_fused needs a non-empty feeds list")
-            k = len(feeds)
-            feed = {n: np.stack([np.asarray(f[n]) for f in feeds])
-                    for n in feeds[0]}
-        if k == 1:
-            # exactly today's behavior (byte-identical, pinned by test);
-            # re-stack so the (K, ...) fetch contract holds either way
-            one = {n: v[0] for n, v in feed.items()}
-            vals = self.run(program, feed=one, fetch_list=fetch_list,
-                            scope=scope, return_numpy=return_numpy)
-            return [v[None] for v in vals]
-
-        fetches = self._dispatch(program, compiled_wrapper, feed,
-                                 fetch_names, scope, len(fetch_names), k=k)
-        if return_numpy:
-            return [np.asarray(f) for f in fetches]
-        return list(fetches)
 
     def close(self):
         # same invariant as the eviction path: dropped cache entries take
@@ -1643,7 +1495,7 @@ class Executor:
             self._closing = False
 
     @staticmethod
-    def _prefetch_batches(batches, depth, fuse: int = 1, abort=None):
+    def _prefetch_batches(batches, depth, abort=None):
         """Host-side double buffering (VERDICT r4 #5): a worker thread runs
         the dataset's parse/slice/stack generator ahead of the device loop
         through a bounded queue, so batch k+1's host work overlaps batch k's
@@ -1652,17 +1504,8 @@ class Executor:
         (trainer.h:64, hogwild_worker.cc: N device-worker threads against
         the DataFeed queue) in its TPU-sized form: one parse thread is
         enough because the device side is a single jitted step stream.
-        Single worker -> batch order is preserved.
-
-        ``fuse`` > 1 additionally groups every ``fuse`` consecutive batches
-        and STACKS them into one (K, ...) super-batch INSIDE the worker
-        (host np.stack, overlapped with device compute like the parse);
-        items then arrive tagged ``("mega", stacked_feed, k)`` or
-        ``("one", feed)`` -- the trailing partial group (and any group whose
-        shapes do not stack, e.g. an odd last batch) degrades to singles,
-        the K=1 remainder path. ``fuse=1`` yields raw feed dicts, exactly
-        the historical contract (the guardian's unfused epoch relies on
-        it)."""
+        Single worker -> batch order is preserved; the items are the
+        dataset's feed dicts as it made them."""
         import queue
 
         q = queue.Queue(maxsize=max(1, depth))
@@ -1693,16 +1536,6 @@ class Executor:
                         continue
             return False
 
-        def _stacked(group):
-            """One ("mega", ...) item when the group stacks (uniform keys
-            and per-slot shapes), else the singles unchanged."""
-            shapes = [{n: np.shape(v) for n, v in g.items()} for g in group]
-            if len(group) > 1 and all(s == shapes[0] for s in shapes[1:]):
-                return [("mega",
-                         {n: np.stack([np.asarray(g[n]) for g in group])
-                          for n in group[0]}, len(group))]
-            return [("one", g) for g in group]
-
         def produced():
             """The dataset's batches, each ``next()`` under a ``produce``
             span (roots of the worker's thread; a file read inside one is
@@ -1725,22 +1558,9 @@ class Executor:
         # is where it would be).
         def worker():
             try:
-                if fuse <= 1:
-                    for item in produced():
-                        if not _put(item):
-                            return
-                else:
-                    group = []
-                    for item in produced():
-                        group.append(item)
-                        if len(group) == fuse:
-                            for it in _stacked(group):
-                                if not _put(it):
-                                    return
-                            group = []
-                    for g in group:  # trailing partial chunk: K=1 path
-                        if not _put(("one", g)):
-                            return
+                for item in produced():
+                    if not _put(item):
+                        return
                 _put(done)
             except BaseException as e:  # surfaced in the consumer thread
                 _put(e)
@@ -1778,8 +1598,8 @@ class Executor:
             # mid-flight (the worker above may be parked inside the
             # iterator waiting on stream data, where generator close()
             # cannot reach from this thread).  Callers that WRAP the
-            # iterator (islice for skip_batches, chain for the fuse
-            # peek) pass the unwrapped hook via ``abort``.
+            # iterator (islice for skip_batches) pass the unwrapped hook
+            # via ``abort``.
             cb = abort if abort is not None \
                 else getattr(batches, "abort", None)
             if cb is not None:
@@ -1792,146 +1612,11 @@ class Executor:
         return max(2, int(thread) or
                    int(getattr(dataset, "thread_num", 0) or 0))
 
-    def _fuse_params(self, feed, fetch_names) -> dict:
-        """The ``fuse_steps.k`` TunableChoice params for one workload: the
-        per-step feed signature plus the fetch count (what the megastep's
-        host-overhead amortization actually depends on)."""
-        return {"feed": sorted(
-                    (n, [int(d) for d in np.shape(v)],
-                     str(v.dtype) if hasattr(v, "dtype")
-                     else str(np.asarray(v).dtype))
-                    for n, v in feed.items()),
-                "fetches": len(fetch_names)}
-
-    def _resolve_fuse_steps(self, batches, fetch_names):
-        """``fuse_steps=0``: consult the ``fuse_steps.k`` choice point.
-        Peeks the first batch (its shapes key the decision), returns
-        ``(k, batches-with-the-peek-restored, params-or-None)``; a non-None
-        params means PADDLE_TPU_TUNE=search with no cached decision -- the
-        caller runs the in-loop search on the live workload."""
-        import itertools
-        from .. import tuning as _tuning
-        from ..tuning import cache as _tcache
-        it = iter(batches)
-        try:
-            first = next(it)
-        except StopIteration:
-            return 1, iter(()), None
-        chained = itertools.chain([first], it)
-        tmode = _tcache.mode()
-        if tmode == "off":
-            return 1, chained, None
-        fetch_strs = [v.name if isinstance(v, Variable) else str(v)
-                      for v in fetch_names]
-        params = self._fuse_params(first, fetch_strs)
-        choice = _tuning.get_choice("fuse_steps.k")
-        cached = _tcache.CACHE.get(choice.key(params))
-        k = int(_tuning.decide("fuse_steps.k", params, allow_search=False))
-        if cached is not None or tmode != "search":
-            return k, chained, None
-        return 1, chained, params
-
-    def _fused_search_epoch(self, program, batches, depth, fetch_list,
-                            scope, params, step_cb, abort=None):
-        """In-loop ``fuse_steps.k`` search: measure candidate K values on
-        the LIVE workload (search megasteps ARE training steps -- every
-        update commits normally), persist the winner through the PR-4
-        decision cache, and finish the epoch fused at the winning K.
-
-        Measurement discipline per candidate: one untimed warm megastep
-        (absorbs the compile), then ``_FUSE_SEARCH_PROBES`` timed megasteps
-        closed by a one-element d2h read; candidates are visited
-        ascending and the search simply stops early (persisting what it
-        measured) if the epoch runs out of batches."""
-        import time as _time
-        from .. import tuning as _tuning
-        from ..tuning.measure import _force
-        choice = _tuning.get_choice("fuse_steps.k")
-        cands = sorted(int(c) for c in choice.candidates(params))
-        it = iter(self._prefetch_batches(batches, depth, abort=abort))
-        timings: Dict[str, dict] = {}
-        t_search = _time.perf_counter()
-        prog_obj = (program.program if program is not None and
-                    not isinstance(program, Program)
-                    else (program or default_main_program()))
-        scope_obj = scope or global_scope()
-
-        def sync_probe(vals, feed):
-            """Segment close: wait for, and read one element of, a fetch,
-            else a written state var."""
-            if vals:
-                _force(vals)
-                return
-            _, written = self._state_names(prog_obj, feed, ())
-            for n in written:
-                v = scope_obj.find_var(n)
-                if v is not None:
-                    _force(v)
-                    return
-
-        def run_chunk(feeds):
-            if len(feeds) == 1:
-                vals = self.run(program, feed=feeds[0],
-                                fetch_list=fetch_list, scope=scope,
-                                return_numpy=False)
-                step_cb(vals, 1, fused=False)
-            else:
-                vals = self.run_fused(program, feeds=feeds,
-                                      fetch_list=fetch_list, scope=scope)
-                step_cb(vals, len(feeds), fused=True)
-            return vals
-
-        exhausted = False
-        for cand in cands:
-            for probe in range(_FUSE_SEARCH_PROBES + 1):  # +1 warm/compile
-                feeds = []
-                for _ in range(cand):
-                    try:
-                        feeds.append(next(it))
-                    except StopIteration:
-                        exhausted = True
-                        break
-                if len(feeds) < cand:
-                    for f in feeds:       # leftover singles still train
-                        run_chunk([f])
-                    break
-                t0 = _time.perf_counter()
-                vals = run_chunk(feeds)
-                sync_probe(vals, feeds[0])
-                dt = _time.perf_counter() - t0
-                if probe > 0:
-                    rec = timings.setdefault(str(cand), {"runs_ms": []})
-                    rec["runs_ms"].append(dt / cand * 1e3)
-            if str(cand) in timings:
-                runs = sorted(timings[str(cand)]["runs_ms"])
-                timings[str(cand)]["run_ms"] = runs[len(runs) // 2]
-            if exhausted:
-                break
-        measured = {c: t["run_ms"] for c, t in timings.items()
-                    if "run_ms" in t}
-        winner = (int(min(measured, key=measured.get)) if measured else 1)
-        _tuning.record_decision(
-            "fuse_steps.k", params, winner, timings=timings,
-            search_seconds=_time.perf_counter() - t_search,
-            measured=bool(measured))
-        if exhausted:
-            return
-        # finish the epoch fused at the winner (consumer-side grouping:
-        # the prefetch worker was started unstacked for the search)
-        feeds = []
-        for feed in it:
-            feeds.append(feed)
-            if len(feeds) == winner:
-                run_chunk(feeds)
-                feeds = []
-        for f in feeds:
-            run_chunk([f])
-
     @_obs_timeline.spanned("train_from_dataset", cat="dataset")
     def train_from_dataset(self, program=None, dataset=None, scope=None,
                            thread=0, debug=False, fetch_list=None,
                            fetch_info=None, print_period=100,
-                           fuse_steps: int = 1, return_numpy: bool = True,
+                           return_numpy: bool = True,
                            skip_batches: int = 0):
         """Run one epoch over a Dataset (reference executor.py:920
         train_from_dataset, which spun up C++ device-worker threads; here
@@ -1941,13 +1626,6 @@ class Executor:
         queue depth (reference semantics: worker-thread count); 0 uses the
         dataset's thread_num, floored at 2 for double buffering.
 
-        ``fuse_steps=K`` (default 1 = exactly the historical loop, pinned
-        byte-identical) compiles K steps into one ``lax.scan`` megastep
-        (:meth:`run_fused`): the prefetch worker stacks K batches into a
-        super-batch, one dispatch covers K steps, and the trailing partial
-        chunk runs through the K=1 path. ``fuse_steps=0`` consults the
-        ``fuse_steps.k`` autotuner choice (PADDLE_TPU_TUNE=search measures
-        candidate K values on the live workload and persists the winner).
         Fetches are LAZY in this loop: materialized (one counted d2h sync)
         only at debug ``print_period`` boundaries and -- when
         ``return_numpy`` (default) -- on return; ``return_numpy=False``
@@ -1957,94 +1635,37 @@ class Executor:
         ``skip_batches=N`` fast-forwards past the first N batches of the
         epoch without running them -- the exact-resume half of
         ``Checkpointer``'s ``trainstate.json`` (a restored run continues
-        on the exact next batch; megastep grouping stays aligned when N
-        is a multiple of K, which checkpoint-at-boundary saves
-        guarantee)."""
+        on the exact next batch)."""
         if dataset is None:
             raise ValueError("train_from_dataset needs a dataset (use "
                              "fluid.DatasetFactory().create_dataset(...))")
         fetch_list = fetch_list or []
         fetch_info = fetch_info or [v.name if isinstance(v, Variable) else
                                     str(v) for v in fetch_list]
-        k = int(fuse_steps)
-        if k < 0:
-            raise ValueError("fuse_steps must be >= 0 (0 = autotune)")
-        wrapper = (program if program is not None and
-                   not isinstance(program, Program) else None)
-        prog = (wrapper.program if wrapper is not None
-                else (program or default_main_program()))
-        if k != 1:
-            reason = self._fuse_ineligible(prog, wrapper)
-            if reason is not None:
-                import warnings
-                warnings.warn(
-                    f"train_from_dataset(fuse_steps={fuse_steps}): "
-                    f"{reason}; running unfused", stacklevel=2)
-                k = 1
         depth = self._prefetch_depth(thread, dataset)
         batches = dataset._iter_batches()
-        # grab the stream-abort hook BEFORE any wrapping (islice/chain
-        # below would hide it from the prefetch loop's finally)
+        # grab the stream-abort hook BEFORE any wrapping (islice below
+        # would hide it from the prefetch loop's finally)
         abort_cb = getattr(batches, "abort", None)
         if skip_batches:
             import itertools
             batches = itertools.islice(batches, int(skip_batches), None)
-        search_params = None
-        if k == 0:
-            k, batches, search_params = self._resolve_fuse_steps(
-                batches, fetch_list)
-
-        state = {"last": None, "fused": False, "i": 0}
         period = max(print_period, 1)
-
-        def _dbg(vals_np, j):
-            msg = ", ".join(f"{n}={np.asarray(v).reshape(-1)[0]:.6g}"
-                            for n, v in zip(fetch_info, vals_np))
-            print(f"[train_from_dataset] batch {j}: {msg}")
-
-        def step_cb(vals, kk, fused):
-            i = state["i"]
-            if debug and fetch_list:
-                hits = [j for j in range(i, i + kk) if j % period == 0]
-                if hits:
-                    # ONE materialization per boundary-crossing chunk --
-                    # debug mode must not re-introduce the per-step sync
-                    with _obs_timeline.phase("fetch_sync", cat="dataset"):
-                        vals_np = materialize_fetches(vals)
-                    for j in hits:
-                        _dbg([v[j - i] for v in vals_np] if fused
-                             else vals_np, j)
-            state["last"], state["fused"] = vals, fused
-            state["i"] = i + kk
-
-        if search_params is not None:
-            self._fused_search_epoch(program, batches, depth, fetch_list,
-                                     scope, search_params, step_cb,
-                                     abort=abort_cb)
-        elif k > 1:
-            for item in self._prefetch_batches(batches, depth, fuse=k,
-                                               abort=abort_cb):
-                if item[0] == "mega":
-                    vals = self.run_fused(program, stacked_feed=item[1],
-                                          fetch_list=fetch_list,
-                                          scope=scope)
-                    step_cb(vals, item[2], fused=True)
-                else:
-                    vals = self.run(program, feed=item[1],
-                                    fetch_list=fetch_list, scope=scope,
-                                    return_numpy=False)
-                    step_cb(vals, 1, fused=False)
-        else:
-            for feed in self._prefetch_batches(batches, depth,
-                                               abort=abort_cb):
-                vals = self.run(program, feed=feed, fetch_list=fetch_list,
-                                scope=scope, return_numpy=False)
-                step_cb(vals, 1, fused=False)
-        last = state["last"]
+        last = None
+        for i, feed in enumerate(self._prefetch_batches(
+                batches, depth, abort=abort_cb)):
+            last = self.run(program, feed=feed, fetch_list=fetch_list,
+                            scope=scope, return_numpy=False)
+            if debug and fetch_list and i % period == 0:
+                # ONE materialization per boundary -- debug mode must not
+                # re-introduce the per-step sync
+                with _obs_timeline.phase("fetch_sync", cat="dataset"):
+                    vals_np = materialize_fetches(last)
+                msg = ", ".join(f"{n}={np.asarray(v).reshape(-1)[0]:.6g}"
+                                for n, v in zip(fetch_info, vals_np))
+                print(f"[train_from_dataset] batch {i}: {msg}")
         if last is None:
             return None
-        if state["fused"]:
-            last = [v[-1] for v in last]  # the LAST substep's fetches
         if return_numpy and last:
             # the epoch's one read of the device: it waits for the last step
             with _obs_timeline.phase("fetch_sync", cat="dataset"):
@@ -2128,10 +1749,9 @@ class Executor:
         return read, written
 
     def _compile(self, program: Program, feed_names, fetch_names, state_in,
-                 state_out, wrapper=None, fuse=None):
+                 state_out, wrapper=None):
         """Build the step for this call and jit it: ``_make_step``'s one
-        definition, wrapped by what the call is.  ``fuse`` is ``(k,
-        health_on, include_state)`` for a megastep of k steps, else None."""
+        definition, wrapped by what the call is."""
         # Buffers both read and written (params under an optimizer update, bn stats)
         # are donated so XLA updates them in place; read-only state is not donated so
         # eval programs can share the same Scope entries.
@@ -2139,8 +1759,6 @@ class Executor:
         ro_names = [n for n in state_in if n not in state_out]
         names = (program, feed_names, fetch_names, mut_names, ro_names,
                  state_out)
-        if fuse is not None:
-            return self._scan_of_k(*names, *fuse)
         if wrapper is not None and wrapper.dist_strategy is not None and \
                 getattr(program, "_comm_explicit", None):
             # Explicit-dp path (comm compression on): the whole step runs
@@ -2248,59 +1866,6 @@ class Executor:
                           out_specs=out_specs, check_vma=False)
         return _jit_step(local, mut_names, ro_names, state_out, fetch_names,
                          shardings)
-
-    def _scan_of_k(self, program: Program, feed_names, fetch_names,
-                   mut_names, ro_names, state_out, k: int, health_on: bool,
-                   include_state: bool):
-        """Compile K training steps as one ``lax.scan``-of-step megastep.
-
-        The scan body is the SAME trace the single step compiles (same
-        ``trace_block``, same per-substep ``fold_in`` rng), so fused and
-        unfused runs are numerically identical; mutable state threads
-        through the carry (donated), read-only state rides as scan
-        constants, and the per-step fetches stack into (K, ...) outputs.
-        Write-only persistables (in ``state_out`` but not ``state_in``)
-        ride the stacked outputs and commit their LAST substep's value.
-        With ``health_on`` the PR-2 watchdog's any-nonfinite reduction runs
-        INSIDE the scan, yielding one (K, n_watch) packed-bool matrix --
-        a single small d2h read per megastep regardless of K."""
-        import jax
-        import jax.numpy as jnp
-
-        tail_names = [n for n in state_out if n not in mut_names]
-        health_names: List[str] = []
-        step = _make_step(program, fetch_names, state_out)
-
-        def megastep(mut_state, ro_state, feeds, rng_counter0):
-            def body(carry, feed):
-                mut, cnt = carry
-                fetches, new_state = step(mut, ro_state, feed, cnt)
-                new_mut = {n: new_state.get(n, mut[n]) for n in mut_names}
-                tail = {n: new_state[n] for n in tail_names
-                        if n in new_state}
-                ys = {"fetch": fetches, "tail": tail}
-                if health_on:
-                    from ..observability import health as _obs_health
-                    named = list(zip(fetch_names, fetches))
-                    if include_state:
-                        named += sorted(new_state.items())
-                    names, flags = _obs_health.nonfinite_flags(named)
-                    health_names[:] = names
-                    ys["health"] = (flags if flags is not None
-                                    else jnp.zeros((0,), bool))
-                return (new_mut, cnt + jnp.uint32(1)), ys
-
-            carry0 = (mut_state, jnp.asarray(rng_counter0, jnp.uint32))
-            (mut, _), ys = jax.lax.scan(body, carry0, feeds)
-            new_state = dict(mut)
-            for n, v in ys["tail"].items():
-                new_state[n] = v[-1]
-            return ys["fetch"], new_state, ys.get("health")
-
-        cs = _jit_step(megastep, mut_names, ro_names, state_out, fetch_names)
-        cs.fused_k = k
-        cs.health_names = health_names  # filled when the trace runs
-        return cs
 
 
 # Convenience used widely in reference-style user code.

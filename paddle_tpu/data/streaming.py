@@ -6,8 +6,8 @@ production data feeds are flaky: files lag publishers, sockets drop,
 upstream jobs emit garbage.  :class:`StreamingDataset` is that pipeline's
 hardened TPU-native form -- a ``DatasetBase`` whose ``_iter_batches``
 plugs straight into ``Executor.train_from_dataset`` /
-``StepGuardian.train_from_dataset`` (prefetch worker, megastep fusion,
-goodput ``feed_wait`` attribution all apply unchanged), with:
+``StepGuardian.train_from_dataset`` (prefetch worker, goodput
+``feed_wait`` attribution both apply unchanged), with:
 
 - **pluggable sources** (:class:`FileTailSource`, :class:`SocketSource`,
   :class:`GeneratorSource`): each runs a reader thread pushing raw

@@ -685,22 +685,13 @@ def retire_program(label: str,
     / executor close -- mirrors the PR-1 cost-gauge retirement, but
     label-subset-aware because of the extra ``category`` label)."""
     registry = registry or REGISTRY
-
-    def _owned(key) -> bool:
-        for k, v in key:
-            # fused megasteps attribute under "<label>:k<K>" -- they die
-            # with the same cache entry as their base program
-            if k == "program" and (v == label or
-                                   v.startswith(label + ":k")):
-                return True
-        return False
-
     for fname in GAUGE_FAMILIES:
         fam = registry.get(fname)
         if fam is None:
             continue
         with fam._lock:
-            for key in [k for k in fam.children if _owned(k)]:
+            for key in [k for k in fam.children
+                        if ("program", label) in k]:
                 fam.children.pop(key, None)
 
 

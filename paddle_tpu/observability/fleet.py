@@ -204,9 +204,8 @@ class FleetMonitor:
         warnings.warn(f"paddle_tpu fleet telemetry: {msg}")
 
     # ------------------------------------------------------------- hot path
-    def on_step(self, warm: bool = True, k: int = 1,
-                step: Optional[int] = None):
-        """One executor step (or one K-substep megastep) finished.
+    def on_step(self, warm: bool = True, step: Optional[int] = None):
+        """One executor step finished.
 
         The gather cadence keys on ``step`` -- the program's rng-run
         counter, NOT a raw local call count: the resilience guardian
@@ -219,11 +218,11 @@ class FleetMonitor:
         gather_now = False
         with self._lock:
             if self._last_t is not None and warm and self._last_warm:
-                self._times.append((t - self._last_t) / max(1, k))
+                self._times.append(t - self._last_t)
             self._last_t = t
             self._last_warm = warm
-            self._steps += k
-            done = self._steps if step is None else step + k
+            self._steps += 1
+            done = self._steps if step is None else step + 1
             if self.mode == "gather":
                 boundary = done // self.interval
                 if boundary > self._last_boundary:

@@ -4,7 +4,7 @@
 not another timer: every second of a run already leaves a trace in the
 telemetry the earlier layers record -- the ``phase_seconds`` histogram
 (feed_prep / dispatch / fetch_sync / journal / compile / feed_wait spans,
-always on), the run journal (``run``/``megastep`` step times, ``ckpt_save``
+always on), the run journal (``run`` step times, ``ckpt_save``
 blocked time, ``retry`` backoff, ``skip``/``rollback`` discards,
 ``elastic_restart_downtime``) and the metrics registry
 (``autotune_search_seconds``).  This module only *reads* those sources --
@@ -60,10 +60,8 @@ CAUSES = ("dispatch", "fetch_sync", "compile", "warm_restore", "verify",
           "retry_backoff", "skipped_steps", "rollback", "elastic_restart",
           "other")
 
-# phase_seconds (phase, cat) -> ledger cause. The "megastep" phase is a
-# CONTAINER around dispatch+fetch_sync and must not be summed (it would
-# double-count every fused step); Predictor phases describe serving, not
-# this training ledger.
+# phase_seconds (phase, cat) -> ledger cause. Predictor phases describe
+# serving, not this training ledger.
 _PHASE_CAUSE = {
     ("dispatch", "executor"): "dispatch",
     ("fetch_sync", "executor"): "fetch_sync",
@@ -197,8 +195,7 @@ def _median(vals: List[float]) -> Optional[float]:
 
 
 def _step_events(events):
-    return [e for e in (events or [])
-            if e.get("event") in ("run", "megastep")]
+    return [e for e in (events or []) if e.get("event") == "run"]
 
 
 def _event_buckets(events, have_phases: bool):
@@ -214,15 +211,10 @@ def _event_buckets(events, have_phases: bool):
 
     steps = _step_events(events)
     warm_ms = []
-    n_steps = 0
+    n_steps = len(steps)
     for e in steps:
-        k = int(e.get("k") or 1)
-        n_steps += k
         if e.get("cache") == "hit" and e.get("run_ms") is not None:
-            per = (e.get("amortized_ms")
-                   if e.get("event") == "megastep" else e.get("run_ms"))
-            if per is not None:
-                warm_ms.append(float(per))
+            warm_ms.append(float(e["run_ms"]))
         if not have_phases:
             add("dispatch", float(e.get("run_ms") or 0.0) / 1e3)
             add("compile", float(e.get("compile_ms") or 0.0) / 1e3)

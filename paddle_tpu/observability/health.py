@@ -120,84 +120,6 @@ def nonfinite_names(named: Sequence[Tuple[str, object]]) -> List[str]:
     return [watch[i][0] for i in np.flatnonzero(flags)]
 
 
-def nonfinite_flags(named: Sequence[Tuple[str, object]]):
-    """TRACE-TIME variant of the scan: per-tensor any-nonfinite bool flags
-    for the inexact tensors among ``named`` [(name, traced jax value)].
-
-    Used inside the executor's fused megastep (``lax.scan`` body), where the
-    reduction must live IN the compiled program: the scan stacks one flag
-    row per substep and the whole (K, n_watch) matrix crosses to the host as
-    a single packed read per megastep (``read_flags``), never a per-step or
-    per-tensor sync.  Returns ``(names, flags)``; ``flags`` is None when
-    nothing inexact is watched.
-    """
-    import numpy as np
-
-    import jax.numpy as jnp
-
-    names, flags = [], []
-    for name, v in named:
-        dt = getattr(v, "dtype", None)
-        if dt is not None and jnp.issubdtype(np.dtype(dt), jnp.inexact):
-            names.append(name)
-            flags.append(jnp.logical_not(jnp.all(jnp.isfinite(v))))
-    if not flags:
-        return names, None
-    return names, jnp.stack(flags)
-
-
-def read_flags(flags):
-    """The ONE packed device->host read of a fused megastep's health flags
-    ((K, n_watch) bool).  A named function so the fused-loop guard test can
-    spy it: obs-off fused runs must never call it, armed runs exactly once
-    per megastep."""
-    import numpy as np
-    return np.asarray(flags)
-
-
-def check_flag_matrix(flag_rows, names: Sequence[str], program: str,
-                      where: str = "executor",
-                      health_mode: Optional[str] = None,
-                      step0: int = 0) -> List[str]:
-    """Apply the watchdog policy to an already-read (K, n_watch) flag matrix
-    (``read_flags`` output) from a fused megastep.
-
-    Same attribution/count/journal/warn/raise semantics as :func:`check`,
-    plus substep attribution: the journal event carries ``substep`` (the
-    first offending step index, ``step0`` + row) so a NaN inside a megastep
-    is pinned to the exact training step, not just the megastep."""
-    import numpy as np
-
-    m = health_mode if health_mode is not None else mode()
-    if m == "off" or flag_rows is None or not len(names):
-        return []
-    rows = np.asarray(flag_rows, dtype=bool).reshape(-1, len(names))
-    hit_r, hit_c = np.nonzero(rows)
-    if hit_r.size == 0:
-        return []
-    bad: List[str] = []
-    for c in hit_c:
-        if names[c] not in bad:
-            bad.append(names[c])
-    substep = int(step0) + int(hit_r[0])
-    _stash_verdict(program, where, bad[:8])
-    from . import journal as _journal
-    from .metrics import REGISTRY
-    REGISTRY.counter("tensor_nonfinite_total",
-                     "tensors found NaN/Inf by the health watchdog",
-                     where=where).inc(len(bad))
-    _journal.emit({"event": "tensor_nonfinite", "program": program,
-                   "where": where, "var": bad[0], "vars": bad[:8],
-                   "substep": substep, "k": int(rows.shape[0])})
-    msg = (f"NaN/Inf detected in {where} output {bad[0]!r} at substep "
-           f"{substep} of a fused megastep (program {program}; "
-           f"{len(bad)} tensor(s) affected: {bad[:8]})")
-    if m == "raise":
-        raise FloatingPointError(msg)
-    warnings.warn(msg)
-    return bad
-
-
 def check(named: Sequence[Tuple[str, object]], program: str,
           where: str = "executor", health_mode: Optional[str] = None) -> List[str]:
     """Scan ``named`` tensors; attribute, count, journal, warn/raise.

@@ -106,15 +106,15 @@ _counters: "collections.deque" = collections.deque(maxlen=_SPAN_CAP)
 # phase_seconds sums keep growing
 _window = [None, None]
 #: the phases whose extent is that window: the ones the goodput ledger gives
-#: a cause (``goodput._PHASE_CAUSE``; a test holds the two equal) and their
-#: container ``megastep``.  The tree's other containers and the prefetch
-#: worker's spans stay out: ``run`` opens before ``feed_prep``, and
+#: a cause (``goodput._PHASE_CAUSE``; a test holds the two equal).  The
+#: tree's containers and the prefetch worker's spans stay out: ``run``
+#: opens before ``feed_prep``, and
 #: ``parse_file`` also runs in ``load_into_memory()``, long before training;
 #: either would stretch the wall the ledger divides by.
 WINDOW_PHASES = frozenset(
     [(n, "executor") for n in ("feed_prep", "dispatch", "fetch_sync",
                                "journal", "compile", "warm_restore",
-                               "verify", "megastep")]
+                               "verify")]
     + [("feed_wait", "dataset")])
 
 

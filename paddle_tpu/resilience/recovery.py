@@ -339,111 +339,22 @@ class StepGuardian:
                                         self.step - 1)
         return fetches
 
-    def run_fused(self, program=None, feeds=None, fetch_list=None,
-                  scope=None, stacked_feed=None, return_numpy: bool = True,
-                  **kw) -> list:
-        """K guarded steps dispatched as ONE ``lax.scan`` megastep
-        (``Executor.run_fused``).
-
-        Recovery granularity is the MEGASTEP: snapshots land at megastep
-        boundaries, so ``skip`` drops -- and ``rollback`` rewinds -- all K
-        substeps as a unit (K batches consumed on skip, the rng counter
-        rewound by K on rollback); a nonfinite substep cannot be excised
-        individually from a fused update.  ``return_numpy`` defaults to
-        True here (unlike the executor's lazy fused default): the
-        guardian's own nonfinite scan needs host values when the env
-        watchdog is off.  With ``PADDLE_TPU_OBS_HEALTH`` armed the in-scan
-        packed reduction IS the verdict (no second scan) -- pass
-        ``return_numpy=False`` then to keep fused fetches fully lazy under
-        guard."""
-        if self._closed:
-            raise RuntimeError("StepGuardian is closed")
-        from ..core.executor import global_scope
-        from ..framework import default_main_program
-        program = program or self.program or default_main_program()
-        scope = scope or self.scope or global_scope()
-        if stacked_feed is not None:
-            k = int(np.shape(next(iter(stacked_feed.values())))[0])
-        else:
-            k = len(feeds or ())
-        if k < 1:
-            raise ValueError("run_fused needs at least one feed")
-        pending_state = self._take_pending_state()
-        if _preempt.is_set():
-            self._emergency_exit()  # raises Preempted
-        if self.nonfinite_policy != "raise" and self._snapshot_due():
-            self._take_snapshot(program, scope)
-        pre_counter = getattr(program, "_rng_run_counter", 0)
-        label = f"{id(program)}:v{getattr(program, '_version', 0)}"
-        _health.take_verdict(label)  # drop OUR stale verdict, if any
-        call = lambda: self.exe.run_fused(  # noqa: E731
-            program, feeds=feeds, stacked_feed=stacked_feed,
-            fetch_list=fetch_list, scope=scope, return_numpy=return_numpy,
-            **kw)
-        attempt = 0
-        while True:
-            try:
-                fetches = self._attempt_call(call)
-                bad = self._verdict(fetch_list, fetches, label,
-                                    watchdog_covered=True)
-                break
-            except FloatingPointError as e:
-                # watchdog raise-mode fired inside the megastep: placeholder
-                # rows, one (K,)-shaped NaN vector per requested fetch, so
-                # unpacking matches the stacked contract either way
-                v = _health.take_verdict(label)
-                bad = list((v or {}).get("vars") or [])[:8] or \
-                    [str(e)[:120]]
-                fetches = [np.full((k,), np.nan, np.float32)
-                           for _ in (fetch_list or [])]
-                break
-            except Preempted:
-                raise
-            except Exception as e:
-                if not is_transient(e) or attempt >= self.max_retries:
-                    _blackbox.maybe_write(
-                        "retries_exhausted" if is_transient(e)
-                        else "terminal_error", error=e,
-                        extra={"step": self.step, "attempt": attempt,
-                               "program": label, "fused_k": k})
-                    raise
-                attempt += 1
-                self._backoff(attempt, transient_site(e), e)
-                try:
-                    program._rng_run_counter = pre_counter
-                except AttributeError:
-                    pass
-        if bad:
-            fetches = self._apply_nonfinite_policy(bad, program, scope,
-                                                   fetches)
-        self.step += k
-        self._commit_train_state(pending_state)
-        if self.checkpointer is not None:
-            self._checkpoint_with_retry(self.checkpointer.maybe_save,
-                                        self.step - 1)
-        return fetches
-
     def train_from_dataset(self, program=None, dataset=None, scope=None,
                            thread: int = 0, fetch_list=None,
-                           fuse_steps: int = 1, skip_batches: int = 0,
-                           epoch: int = 0, step_cb=None, **kw):
+                           skip_batches: int = 0, epoch: int = 0,
+                           step_cb=None, **kw):
         """One guarded epoch over a Dataset (each batch through
         :meth:`run`, prefetched like ``Executor.train_from_dataset``).
 
-        ``fuse_steps=K`` runs the epoch in guarded megasteps
-        (:meth:`run_fused`; the trailing partial chunk through :meth:`run`)
-        -- documented skip/rollback granularity becomes K steps.
-        ``fuse_steps=0`` consults the autotuner's cached ``fuse_steps.k``
-        decision (the guardian never searches: measurement belongs to the
-        unguarded loop).  ``step_cb(batches_consumed, fetches)`` is
-        invoked after every guarded chunk (per-step loss collection
-        without materializing more than the caller asks for).
+        ``step_cb(batches_consumed, fetches)`` is invoked after every
+        guarded step (per-step loss collection without materializing more
+        than the caller asks for).
 
         Exact resume: the attached checkpointer's ``trainstate.json``
         records, for every guarded step, the batch position the save at
         that step boundary corresponds to (``epoch``, ``batch`` = batches
-        consumed including the step that just ran, ``fuse_steps``) --
-        staged when the chunk arrives, committed only after the step
+        consumed including the step that just ran) --
+        staged when the batch arrives, committed only after the step
         lands, so an emergency preemption save never persists the
         position of a step that never ran.  ``skip_batches=N``
         fast-forwards a restored run past the batches the checkpoint
@@ -451,8 +362,7 @@ class StepGuardian:
 
             start = ck.restore() + 1
             pos = ck.train_state or {}
-            g.train_from_dataset(dataset=ds, fuse_steps=k,
-                                 epoch=pos.get("epoch", 0),
+            g.train_from_dataset(dataset=ds, epoch=pos.get("epoch", 0),
                                  skip_batches=pos.get("batch", 0))
 
         A streaming dataset (``paddle_tpu.data.StreamingDataset``)
@@ -463,75 +373,39 @@ class StepGuardian:
         if dataset is None:
             raise ValueError("train_from_dataset needs a dataset")
         depth = self.exe._prefetch_depth(thread, dataset)
-        k = int(fuse_steps)
         batches = dataset._iter_batches()
-        # the stream-abort hook, captured before islice/chain wrapping
+        # the stream-abort hook, captured before islice wrapping
         # can hide it from the prefetch loop's wind-down
         abort_cb = getattr(batches, "abort", None)
         if skip_batches:
             import itertools
             batches = itertools.islice(batches, skip_batches, None)
-        if k == 0:
-            k, batches, _ = self.exe._resolve_fuse_steps(
-                batches, fetch_list or [])
         consumed = int(skip_batches)
         mark = getattr(self.checkpointer, "update_train_state", None)
         wm = getattr(dataset, "watermark", None)
 
         def _mark(n_after: int):
-            # STAGED before the step runs, committed by run()/run_fused()
-            # after the state lands (see _commit_train_state): the
-            # position a save persists is "this chunk consumed", and a
+            # STAGED before the step runs, committed by run() after the
+            # state lands (see _commit_train_state): the position a save
+            # persists is "this batch consumed", and a
             # pre-step emergency exit keeps the previous one
             if mark is None:
                 return
-            st = {"epoch": int(epoch), "batch": n_after, "fuse_steps": k}
+            st = {"epoch": int(epoch), "batch": n_after}
             if wm is not None:
                 doc = wm(n_after)
                 if doc is not None:
                     st["stream"] = doc
             self._pending_state = st
-        if k > 1:
-            from ..framework import Program as _Program
-            from ..framework import default_main_program
-            p = program or self.program or default_main_program()
-            wrapper = p if not isinstance(p, _Program) else None
-            prog = wrapper.program if wrapper is not None else p
-            reason = self.exe._fuse_ineligible(prog, wrapper)
-            if reason is not None:
-                import warnings
-                warnings.warn(
-                    f"StepGuardian.train_from_dataset(fuse_steps="
-                    f"{fuse_steps}): {reason}; running unfused",
-                    stacklevel=2)
-                k = 1
         last = None
-        if k > 1:
-            for item in self.exe._prefetch_batches(batches, depth, fuse=k,
-                                                   abort=abort_cb):
-                if item[0] == "mega":
-                    _mark(consumed + item[2])
-                    last = self.run_fused(program, stacked_feed=item[1],
-                                          fetch_list=fetch_list,
-                                          scope=scope, **kw)
-                    consumed += item[2]
-                else:
-                    _mark(consumed + 1)
-                    last = self.run(program, feed=item[1],
-                                    fetch_list=fetch_list, scope=scope,
-                                    **kw)
-                    consumed += 1
-                if step_cb is not None:
-                    step_cb(consumed, last)
-        else:
-            for feed in self.exe._prefetch_batches(batches, depth,
-                                                   abort=abort_cb):
-                _mark(consumed + 1)
-                last = self.run(program, feed=feed, fetch_list=fetch_list,
-                                scope=scope, **kw)
-                consumed += 1
-                if step_cb is not None:
-                    step_cb(consumed, last)
+        for feed in self.exe._prefetch_batches(batches, depth,
+                                               abort=abort_cb):
+            _mark(consumed + 1)
+            last = self.run(program, feed=feed, fetch_list=fetch_list,
+                            scope=scope, **kw)
+            consumed += 1
+            if step_cb is not None:
+                step_cb(consumed, last)
         return last
 
     def close(self):
@@ -550,9 +424,6 @@ class StepGuardian:
         call = lambda: self.exe.run(  # noqa: E731
             program, feed=feed, fetch_list=fetch_list, scope=scope,
             return_numpy=return_numpy, **kw)
-        return self._attempt_call(call)
-
-    def _attempt_call(self, call):
         if not self.step_timeout:
             return call()
         # hung-step watchdog: the step (incl. its d2h sync) runs in a
@@ -598,23 +469,16 @@ class StepGuardian:
                        "error": str(exc)[:200]})
         time.sleep(delay)
 
-    def _verdict(self, fetch_list, fetches, label,
-                 watchdog_covered: bool = False) -> List[str]:
+    def _verdict(self, fetch_list, fetches, label) -> List[str]:
         """Nonfinite tensor names for this step: the health watchdog's
         stashed verdict when the env gate is armed (filtered to this
         program's label), else the guardian's own scan of the returned
         fetches (free when they are already host numpy; skipped under
         policy=raise for device-array fetches, where it would add a d2h
-        sync the user didn't opt into).
-
-        ``watchdog_covered`` (the fused path): the armed in-scan watchdog
-        already reduced exactly these fetch names inside the megastep, so
-        an empty stash IS the clean verdict -- no second host scan."""
+        sync the user didn't opt into)."""
         v = _health.take_verdict(label)
         if v is not None:
             return list(v.get("vars") or [])
-        if watchdog_covered and _health.mode() != "off":
-            return []
         if not fetch_list or fetches is None:
             return []
         from ..framework import Variable

@@ -52,9 +52,9 @@ def record_decision(choice_id: str, params: dict, winner,
     """Persist an EXTERNALLY measured decision for ``choice_id``.
 
     The door for choice points whose candidates cannot be measured in
-    ``measure.search``'s isolated jit -- ``fuse_steps.k`` is measured by
-    ``Executor.train_from_dataset`` on the live workload (the search
-    megasteps are real training steps) and recorded here.  Journals the
+    ``measure.search``'s isolated jit -- ``comm.compress`` and
+    ``shardplan.plan`` are measured on the live workload and recorded
+    here.  Journals the
     same auditable ``autotune`` event a harness search would."""
     import time as _time
     from ..observability import journal as _journal

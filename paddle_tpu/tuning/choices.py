@@ -446,47 +446,7 @@ class ConvLayout(TunableChoice):
 
 
 # --------------------------------------------------------------------------------------
-# choice point 5: fused multi-step K (train_from_dataset megastep size)
-# --------------------------------------------------------------------------------------
-
-
-class FuseSteps(TunableChoice):
-    id = "fuse_steps.k"
-    doc = ("number of training steps compiled into one lax.scan megastep "
-           "by Executor.train_from_dataset(fuse_steps=0): amortizes Python "
-           "dispatch, feed device_put and fetch-sync overhead ~K-fold on "
-           "host-overhead-dominated workloads. Default 1 = today's "
-           "unfused loop. Unlike the kernel choices, candidates are NOT "
-           "measurable in an isolated jit (the payoff is per-workload loop "
-           "overhead): the executor measures them in-loop on the live "
-           "workload and persists the winner via record_decision().")
-
-    K_CANDIDATES = (1, 2, 4, 8, 16, 32)
-
-    def bucket(self, params):
-        # the amortization depends on the per-step feed signature (shapes +
-        # dtypes drive device_put and dispatch cost) and the fetch count
-        return {"feed": params["feed"],
-                "fetches": int(params.get("fetches", 0))}
-
-    def candidates(self, params):
-        return list(self.K_CANDIDATES)
-
-    def default(self, params):
-        return 1  # pre-fusion behavior, byte-identical to the unfused loop
-
-    def bench(self, params, candidate):
-        return None  # measured in-loop by train_from_dataset, never here
-
-    def encode(self, candidate) -> str:
-        return str(int(candidate))
-
-    def decode(self, raw):
-        return int(raw)
-
-
-# --------------------------------------------------------------------------------------
-# choice point 6: per-tensor gradient-allreduce compression (comm layer)
+# choice point 5: per-tensor gradient-allreduce compression (comm layer)
 # --------------------------------------------------------------------------------------
 
 
@@ -496,7 +456,7 @@ class CommCompress(TunableChoice):
            "(DistributedStrategy.comm_compression): 'on' quantizes this "
            "tensor (bf16/int8 + error feedback), 'off' keeps it f32. "
            "Tensors under the min_bytes floor have no 'on' candidate -- "
-           "compression there is pure overhead. Like fuse_steps.k the "
+           "compression there is pure overhead. The "
            "payoff is workload-level (wire time vs quantize arithmetic "
            "on the live step), not isolated-jit measurable: external "
            "measurements persist via tuning.record_decision().")
@@ -523,7 +483,7 @@ class CommCompress(TunableChoice):
 
 
 # --------------------------------------------------------------------------------------
-# choice point 7: which of the auto-shard planner's top-k plans to run
+# choice point 6: which of the auto-shard planner's top-k plans to run
 # --------------------------------------------------------------------------------------
 
 
@@ -557,6 +517,5 @@ register_choice(ConvBnBackend())
 register_choice(FlashBackend())
 register_choice(FlashBlockSizes())
 register_choice(ConvLayout())
-register_choice(FuseSteps())
 register_choice(CommCompress())
 register_choice(ShardPlanChoice())

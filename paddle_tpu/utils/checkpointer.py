@@ -31,7 +31,7 @@ Durability contract (ISSUE 9):
   join the cross-host barrier choreography) -- multi-host degrades to a
   sync save with a one-time warning.
 - **Exact resume**: each checkpoint carries ``trainstate.json`` (step, rng
-  run counter, dataset epoch/batch position, fuse_steps) so a restored
+  run counter, dataset epoch/batch position) so a restored
   run continues on the exact next batch with the exact next rng fold --
   ``restore()`` rewinds the program's rng counter and exposes
   ``.train_state``.
@@ -102,7 +102,7 @@ class Checkpointer:
     # -- saving --------------------------------------------------------------
 
     def update_train_state(self, **kw):
-        """Merge fields (dataset epoch/batch position, fuse_steps, ...)
+        """Merge fields (dataset epoch/batch position, ...)
         into the ``trainstate.json`` the NEXT save will write.  The step
         and rng counter are recorded automatically."""
         self._train_state.update(kw)

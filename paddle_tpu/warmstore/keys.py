@@ -99,10 +99,9 @@ def build_key(kind: str, program, *, feed_sig, fetch_names, seed,
               flags, strategy, world_dependent: bool,
               extra: Optional[dict] = None) -> dict:
     """The full entry key for one compiled artifact.  ``kind`` is
-    ``train_step`` / ``fused_step`` / ``predict``; ``strategy`` is the
-    executor key's strategy slot (``strategy_signature()`` tuple or the
-    ``__fused__`` slot) -- repr'd, since its tuples are content-based
-    and repr-stable across processes."""
+    ``train_step`` / ``predict``; ``strategy`` is the executor key's
+    strategy slot (``strategy_signature()`` tuple) -- repr'd, since its
+    tuples are content-based and repr-stable across processes."""
     key = {"format": KEY_FORMAT, "kind": kind,
            "program": program_digest(program),
            "feed_sig": repr(feed_sig), "fetch": list(map(str, fetch_names)),

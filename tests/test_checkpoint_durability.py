@@ -467,11 +467,14 @@ def test_failed_async_write_still_emergency_saved_on_preempt(tmp_path,
 
 # -- exact resume ------------------------------------------------------------
 
-def test_exact_resume_byte_identity_fused(tmp_path):
-    """The pinned exact-resume contract under fuse_steps=2: a run that
-    saves, is killed, and resumes from trainstate.json (rng counter +
-    batch position) commits byte-identical state to the uninterrupted
-    run."""
+@pytest.mark.parametrize("old_field", [False, True])
+def test_exact_resume_byte_identity(tmp_path, old_field):
+    """The pinned exact-resume contract: a run that saves, is killed, and
+    resumes from trainstate.json (rng counter + batch position) commits
+    byte-identical state to the uninterrupted run.  ``old_field``: the
+    file is one an older version wrote, with ``"fuse_steps": 4`` in it --
+    a batch count like any other, so the restore ignores the field and
+    the resume is exact all the same."""
     from paddle_tpu.resilience.recovery import StepGuardian
     main, startup, loss = _build(seed=13)
     batches = [_feed(i) for i in range(8)]
@@ -487,17 +490,17 @@ def test_exact_resume_byte_identity_fused(tmp_path):
         main._rng_run_counter = 0
         startup._rng_run_counter = 0
 
-    # run A: uninterrupted epoch, fused K=2
+    # run A: uninterrupted epoch
     fresh()
     scope_a = fluid.Scope()
     with fluid.scope_guard(scope_a):
         exe = fluid.Executor()
         exe.run(startup)
         ck = Checkpointer(exe, main, str(tmp_path / "a"),
-                          save_interval_steps=2)
+                          save_interval_steps=1)
         g = StepGuardian(exe, main, checkpointer=ck, handle_signals=False)
         g.train_from_dataset(dataset=_ListDataset(batches),
-                             fetch_list=[loss], fuse_steps=2)
+                             fetch_list=[loss])
         want = _state_bytes(scope_a, main)
         want_counter = main._rng_run_counter
 
@@ -508,11 +511,16 @@ def test_exact_resume_byte_identity_fused(tmp_path):
         exe = fluid.Executor()
         exe.run(startup)
         ck = Checkpointer(exe, main, str(tmp_path / "b"),
-                          save_interval_steps=2)
+                          save_interval_steps=1)
         g = StepGuardian(exe, main, checkpointer=ck, handle_signals=False)
         g.train_from_dataset(dataset=_ListDataset(batches[:4]),
-                             fetch_list=[loss], fuse_steps=2)
+                             fetch_list=[loss])
     main._rng_run_counter = 12345       # clobbered by the "crash"
+    if old_field:
+        ts_path = tmp_path / "b" / "ckpt-3" / "trainstate.json"
+        doc = json.loads(ts_path.read_text())
+        assert "fuse_steps" not in doc
+        ts_path.write_text(json.dumps({**doc, "fuse_steps": 4}))
 
     # run B phase 2: fresh executor+scope, exact resume from trainstate
     scope_c = fluid.Scope()
@@ -520,21 +528,25 @@ def test_exact_resume_byte_identity_fused(tmp_path):
         exe2 = fluid.Executor()
         exe2.run(startup)
         ck2 = Checkpointer(exe2, main, str(tmp_path / "b"),
-                           save_interval_steps=2)
+                           save_interval_steps=1)
         start = ck2.restore()
         assert start == 3               # steps 0..3 ran, saved at boundary
         ts = ck2.train_state
-        assert ts["batch"] == 4 and ts["fuse_steps"] == 2
+        assert ts["batch"] == 4 and ts.get("fuse_steps") == (
+            4 if old_field else None)
         assert main._rng_run_counter == 4   # rewound for the exact fold
         g2 = StepGuardian(exe2, main, checkpointer=ck2,
                           handle_signals=False, start_step=start + 1)
         g2.train_from_dataset(dataset=_ListDataset(batches),
-                              fetch_list=[loss], fuse_steps=2,
+                              fetch_list=[loss],
                               skip_batches=ts["batch"],
                               epoch=ts.get("epoch", 0))
         got = _state_bytes(scope_c, main)
         assert main._rng_run_counter == want_counter
     assert got == want                  # byte-identical to uninterrupted
+    newest = json.loads(
+        (tmp_path / "b" / "ckpt-7" / "trainstate.json").read_text())
+    assert newest["batch"] == 8 and "fuse_steps" not in newest
 
 
 def test_kill_during_async_save_chaos_losses_match(tmp_path):

@@ -480,3 +480,118 @@ def test_parse_error_carries_source_position(tmp_path):
     ds2.set_filelist([str(p)])
     with pytest.raises(ValueError, match=r"bad\.txt:1"):
         list(ds2._iter_batches())
+
+
+# ------------------------------------------- the one train_from_dataset loop --
+
+def _small_rig(n_batches):
+    """The feed-bound rig at a toy width: ``(main, startup, loss, dataset)``."""
+    main, startup, loss, batches = _feed_bound_rig(width=8,
+                                                   n_batches=n_batches, bs=4)
+    return main, startup, loss, _SlowDataset(batches, 0.0)
+
+
+@pytest.mark.parametrize("return_numpy", [False, True])
+def test_train_from_dataset_return_numpy_false_is_lazy(return_numpy):
+    """``return_numpy`` threads through the loop: False returns the last
+    step's fetches as live device arrays, True as host copies."""
+    main, startup, loss, ds = _small_rig(5)
+    exe = fluid.Executor()
+    with fluid.scope_guard(fluid.Scope()):
+        exe.run(startup)
+        last = exe.train_from_dataset(main, ds, fetch_list=[loss],
+                                      return_numpy=return_numpy)
+    assert isinstance(last[0], np.ndarray) == return_numpy
+    assert np.isfinite(np.asarray(last[0])).all()
+
+
+def test_prefetch_unfused_contract_unchanged():
+    """The prefetch worker hands on the dataset's feed dicts, in order."""
+    batches = _small_rig(4)[3].batches
+    items = list(fluid.Executor._prefetch_batches(iter(batches), 2))
+    assert len(items) == 4 and isinstance(items[0], dict)
+    for got, want in zip(items, batches):
+        np.testing.assert_array_equal(got["x"], want["x"])
+
+
+def test_train_from_dataset_journal_and_debug_materializer(
+        tmp_path, monkeypatch, capsys):
+    """Every step journals a ``run`` event; debug printing materializes
+    through materialize_fetches ONCE per ``print_period`` boundary instead
+    of syncing every step."""
+    from paddle_tpu.core import executor as executor_mod
+    from paddle_tpu.observability import journal
+    monkeypatch.setenv("PADDLE_TPU_OBS", "1")
+    monkeypatch.setenv("PADDLE_TPU_OBS_JOURNAL",
+                       str(tmp_path / "journal.jsonl"))
+    journal.clear()
+    calls = []
+    real = executor_mod.materialize_fetches
+
+    def spy(fetches):
+        calls.append(1)
+        return real(fetches)
+
+    monkeypatch.setattr(executor_mod, "materialize_fetches", spy)
+    main, startup, loss, ds = _small_rig(8)
+    exe = fluid.Executor()
+    with fluid.scope_guard(fluid.Scope()):
+        exe.run(startup)
+        exe.train_from_dataset(main, ds, fetch_list=[loss], debug=True,
+                               print_period=4, return_numpy=False)
+    runs = [e for e in journal.recent(event="run")
+            if e["program"] == id(main)]
+    assert len(runs) == 8
+    assert runs[0]["cache"] == "miss" and runs[1]["cache"] == "hit"
+    assert all(e["run_ms"] is not None for e in runs)
+    # 8 steps, period 4 -> boundaries at steps 0 and 4: exactly 2
+    assert len(calls) == 2
+    out = capsys.readouterr().out
+    assert "batch 0:" in out and "batch 4:" in out and "batch 1:" not in out
+
+
+def test_obs_off_train_loop_guard_no_files_no_syncs(tmp_path, monkeypatch):
+    """Tier-1 guard: with every obs env unset, a warm train_from_dataset
+    epoch opens NO files, never runs the health scan, and returns
+    un-materialized device arrays (zero fetch d2h syncs)."""
+    import builtins
+    from paddle_tpu.core import executor as executor_mod
+    from paddle_tpu.observability import health
+    for var in ("PADDLE_TPU_OBS", "PADDLE_TPU_OBS_HEALTH",
+                "PADDLE_TPU_OBS_HEALTH_STATE", "PADDLE_TPU_FAULTS"):
+        monkeypatch.delenv(var, raising=False)
+    monkeypatch.setenv("PADDLE_TPU_OBS_JOURNAL",
+                       str(tmp_path / "guard.jsonl"))
+    monkeypatch.chdir(tmp_path)
+    scans, syncs = [], []
+    monkeypatch.setattr(health, "nonfinite_names",
+                        lambda named: scans.append(1) or [])
+    monkeypatch.setattr(executor_mod, "materialize_fetches",
+                        lambda fetches: syncs.append(1) or list(fetches))
+    main, startup, loss, ds = _small_rig(4)
+    exe = fluid.Executor()
+    opened = []
+    real_open = builtins.open
+    with fluid.scope_guard(fluid.Scope()):
+        exe.run(startup)
+        exe.train_from_dataset(main, ds, fetch_list=[loss],
+                               return_numpy=False)   # compile
+
+        def spy_open(file, *a, **k):
+            opened.append(str(file))
+            return real_open(file, *a, **k)
+        monkeypatch.setattr(builtins, "open", spy_open)
+        try:
+            for _ in range(3):
+                vals = exe.train_from_dataset(main, ds, fetch_list=[loss],
+                                              return_numpy=False)
+        finally:
+            monkeypatch.setattr(builtins, "open", real_open)
+        assert not isinstance(vals[0], np.ndarray)
+    watched = [p for p in opened
+               if "journal" in p or "trace" in p or p.endswith(".jsonl")
+               or "paddle_tpu" in p]
+    assert watched == [], f"warm train loop opened files: {watched}"
+    assert scans == [], "the health scan must not run with the mode off"
+    assert syncs == [], "return_numpy=False must not sync a fetch"
+    assert list(tmp_path.iterdir()) == []

@@ -127,25 +127,19 @@ def _dropout_mlp_losses(how, monkeypatch, steps=4):
     exe = fluid.Executor()
     with fluid.scope_guard(fluid.Scope()):
         exe.run(startup)
-        if how == "fused_k4":
-            out, = exe.run_fused(main, feeds=feeds, fetch_list=[loss],
-                                 return_numpy=True)
-            losses = out.reshape(-1)
-        else:
-            losses = np.asarray([
-                exe.run(target, feed=f, fetch_list=[loss])[0].reshape(())
-                for f in feeds])
+        losses = np.asarray([
+            exe.run(target, feed=f, fetch_list=[loss])[0].reshape(())
+            for f in feeds])
     return losses.astype(np.float32), traced
 
 
-@pytest.mark.parametrize("how", ["plain", "gspmd_dp2", "explicit_dp2",
-                                 "fused_k4"])
+@pytest.mark.parametrize("how", ["plain", "gspmd_dp2", "explicit_dp2"])
 def test_every_builder_traces_the_one_step_once(how, monkeypatch):
-    """Plain jit, GSPMD over dp=2, ``shard_map`` over dp=2 and a scan of
-    four steps wrap one step definition: a compile traces the main program's
-    global block exactly once, with the mesh keyword of its builder, and the
-    losses are the plain step's: bit for bit under the scan, which draws the
-    same per-step keys; over a mesh only statistically, because both dp
+    """Plain jit, GSPMD over dp=2 and ``shard_map`` over dp=2 wrap one step
+    definition: a compile traces the main program's global block exactly
+    once, with the mesh keyword of its builder, and the losses are the plain
+    step's: bit for bit where the plain step runs again from a fresh start
+    (the same per-step keys); over a mesh only statistically, because both dp
     steps draw each shard's dropout mask on the device that uses it, with
     the shard's index folded into the key (the explicit-dp step into the
     step's key, the GSPMD step in ``LowerCtx.bernoulli_mask``'s island), so
@@ -156,7 +150,7 @@ def test_every_builder_traces_the_one_step_once(how, monkeypatch):
     mesh_kw = {"gspmd_dp2": ["gspmd_mesh"], "explicit_dp2": ["mesh"]}
     assert traced == [mesh_kw.get(how, [])]
     assert np.isfinite(losses).all()
-    if how in ("plain", "fused_k4"):
+    if how == "plain":
         assert losses.tobytes() == plain.tobytes()
     else:
         assert not np.allclose(losses, plain, rtol=2e-4, atol=1e-5)
@@ -210,11 +204,11 @@ def test_the_executor_names_no_kernel_familys_telemetry():
     assert not [family for family in lowerings.FAMILIES if family in source]
 
 
-def test_cache_keys_of_run_and_run_fused_element_by_element(monkeypatch):
+def test_cache_keys_of_run_element_by_element(monkeypatch):
     """The executor's cache key, in its documented order: (program id,
     program version, feed signature, fetch names, seed, XLA options flag,
-    strategy signature, tuning token).  A megastep's feed signature is per
-    step and its strategy slot is ("__fused__", k, health on, state too).
+    strategy signature, tuning token).  Slot 6 is ``()`` for a plain
+    Program and the wrapper's ``strategy_signature()`` under a strategy.
     The warm store derives its keys from these positions."""
     from paddle_tpu import flags, tuning
     monkeypatch.delenv("PADDLE_TPU_OBS_HEALTH", raising=False)
@@ -227,15 +221,18 @@ def test_cache_keys_of_run_and_run_fused_element_by_element(monkeypatch):
         exe.run(startup)
         exe.run(main, feed=feed, fetch_list=[loss])
         run_key = next(reversed(exe._cache))
-        exe.run_fused(main, feeds=[feed] * 4, fetch_list=[loss])
-        fused_key = next(reversed(exe._cache))
+        dp2 = fluid.CompiledProgram(main).with_strategy(
+            fluid.DistributedStrategy(mesh_shape={"dp": 2}))
+        exe.run(dp2, feed=feed, fetch_list=[loss])
+        dp2_key = next(reversed(exe._cache))
     head = (id(main), main._version, sig, (loss.name,), 9,
             flags.get_flag("xla_compiler_options"))
-    assert len(run_key) == len(fused_key) == 8
+    assert len(run_key) == len(dp2_key) == 8
     for i, want in enumerate(head + ((), tuning.state_token())):
         assert run_key[i] == want, (i, run_key[i], want)
-    for i, want in enumerate(head + (("__fused__", 4, False, False),
+    assert dp2.strategy_signature() != ()
+    for i, want in enumerate(head + (dp2.strategy_signature(),
                                      tuning.state_token())):
-        assert fused_key[i] == want, (i, fused_key[i], want)
+        assert dp2_key[i] == want, (i, dp2_key[i], want)
     assert exe._cache[run_key].executable is not None
-    assert exe._cache[fused_key].fused_k == 4
+    assert exe._cache[dp2_key].executable is not None

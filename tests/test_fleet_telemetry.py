@@ -82,7 +82,7 @@ def test_goodput_from_synthetic_sources():
                              ("compile", "executor", 0.8),
                              ("verify", "executor", 0.05),
                              ("feed_wait", "dataset", 0.5),
-                             ("megastep", "executor", 6.0)):   # container
+                             ("run", "executor", 6.0)):    # container
         reg.histogram("phase_seconds", phase=phase, cat=cat).observe(secs)
     reg.histogram("autotune_search_seconds").observe(0.25)
     events = [
@@ -113,7 +113,7 @@ def test_goodput_from_synthetic_sources():
     assert b["checkpoint"] == pytest.approx(0.4)
     assert b["retry_backoff"] == pytest.approx(0.15)
     assert b["elastic_restart"] == pytest.approx(1.5)
-    # the megastep container must NOT be double-counted
+    # the run container must NOT be double-counted
     assert sum(b.values()) == pytest.approx(12.0)
     assert rep.productive_seconds == pytest.approx(5.7)
     assert rep.goodput_fraction == pytest.approx(5.7 / 12.0)
@@ -136,11 +136,10 @@ def test_goodput_journal_only_degrades():
         {"event": "run", "cache": "miss", "run_ms": 50.0,
          "compile_ms": 900.0, "ts": 10.0},
         {"event": "run", "cache": "hit", "run_ms": 50.0, "ts": 11.0},
-        {"event": "megastep", "cache": "hit", "k": 4, "run_ms": 120.0,
-         "amortized_ms": 30.0, "ts": 12.0},
+        {"event": "run", "cache": "hit", "run_ms": 120.0, "ts": 12.0},
     ]
     rep = goodput.compute(events=events)
-    assert rep.n_steps == 6
+    assert rep.n_steps == 3
     assert rep.breakdown["dispatch"] == pytest.approx(0.22)
     assert rep.breakdown["compile"] == pytest.approx(0.9)
     # wall from the journal ts window + the first event's own duration

@@ -375,8 +375,7 @@ def test_goodput_window_keeps_to_the_phases_the_ledger_sums(tmp_path):
     a file load before training, ``run``'s bookkeeping before its first
     ``feed_prep`` and the worker's spans do not stretch it."""
     from paddle_tpu.observability import goodput
-    assert timeline.WINDOW_PHASES == (set(goodput._PHASE_CAUSE)
-                                      | {("megastep", "executor")})
+    assert timeline.WINDOW_PHASES == set(goodput._PHASE_CAUSE)
     main, startup, loss, _, data_file = _two_slot_dataset(tmp_path)
     ds = fluid.DatasetFactory().create_dataset("InMemoryDataset")
     ds.set_batch_size(4)

@@ -352,7 +352,7 @@ def test_stream_watermark_rides_trainstate(tmp_path):
         g.close()
     with open(str(tmp_path / "ck" / "ckpt-3" / "trainstate.json")) as f:
         doc = json.load(f)
-    assert doc["batch"] == 4 and doc["fuse_steps"] == 1
+    assert doc["batch"] == 4 and "fuse_steps" not in doc
     assert doc["stream"]["sources"]["stream"] == os.path.getsize(p)
     assert doc["stream"]["records"] == 12
 
@@ -645,7 +645,7 @@ def test_aborted_step_never_leaks_staged_position(tmp_path):
         start = ck2.restore() + 1
         g2 = recovery.StepGuardian(exe2, main, checkpointer=ck2,
                                    start_step=start, handle_signals=False)
-        g2._pending_state = {"epoch": 0, "batch": 999, "fuse_steps": 1}
+        g2._pending_state = {"epoch": 0, "batch": 999}
         with pytest.raises(recovery.Preempted):
             recovery.request_preemption("test")
             g2.run(feed={"x": np.ones((1, 4), "float32")},

@@ -622,7 +622,7 @@ def render_warmstore(events: Optional[List[dict]],
     return "\n".join(lines)
 
 
-# --------------------------------------------------------------- megastep --
+# --------------------------------------------------------------- counters --
 
 def _counter_total(snapshot: Optional[dict], name: str) -> Optional[float]:
     """Sum a counter family's samples from a metrics snapshot (None when
@@ -636,40 +636,6 @@ def _counter_total(snapshot: Optional[dict], name: str) -> Optional[float]:
                 total += s.get("value", 0.0)
                 seen = True
     return total if seen else None
-
-
-def render_megastep(events: List[dict],
-                    snapshot: Optional[dict] = None) -> str:
-    """Fused multi-step execution activity: ``megastep`` journal events
-    (Executor.run_fused) + the lazy-fetch materialization counter."""
-    lines = ["== Megastep =="]
-    megas = [e for e in events if e.get("event") == "megastep"]
-    mats = _counter_total(snapshot, "fused_fetch_materializations_total")
-    if not megas and not mats:
-        lines.append("unfused: no megastep events (run "
-                     "train_from_dataset(fuse_steps=K) or bench "
-                     "--fuse-steps)")
-        return "\n".join(lines)
-    substeps = sum(int(e.get("k") or 0) for e in megas)
-    ks = sorted({int(e.get("k") or 0) for e in megas})
-    lines.append(f"{len(megas)} megasteps covering {substeps} substeps "
-                 f"(K values: {ks})")
-    amort = [e["amortized_ms"] for e in megas
-             if e.get("amortized_ms") is not None]
-    if amort:
-        lines.append("amortized dispatch ms/substep: " + _stats(amort))
-    compiles = [e["compile_ms"] for e in megas
-                if e.get("compile_ms") is not None]
-    if compiles:
-        lines.append("megastep compile_ms: " + _stats(compiles))
-    hits = sum(1 for e in megas if e.get("cache") == "hit")
-    if megas:
-        lines.append(f"compile cache: {hits} hits / {len(megas) - hits} "
-                     f"misses")
-    if mats is not None:
-        lines.append(f"fetch materializations (lazy-fetch d2h syncs): "
-                     f"{mats:g}")
-    return "\n".join(lines)
 
 
 # ----------------------------------------------------------------- memory --
@@ -1090,7 +1056,6 @@ def render_report(events: Optional[List[dict]],
     parts = ["# paddle_tpu observability report"]
     if events is not None:
         parts.append(render_journal(events))
-        parts.append(render_megastep(events, snapshot))
         parts.append(render_health(events))
         parts.append(render_resilience(events))
         parts.append(render_checkpoint(events, snapshot))
@@ -1164,7 +1129,6 @@ def selftest() -> int:
     reg.gauge("hlo_op_bytes", program="1:v0", category="layout").set(6.4e7)
     reg.gauge("hlo_op_bytes", program="1:v0", category="compute").set(1e8)
     reg.gauge("hlo_attributed_bytes_fraction", program="1:v0").set(0.978)
-    reg.counter("fused_fetch_materializations_total").inc(3)
     reg.counter("tensor_nonfinite_total", where="executor").inc()
     reg.counter("anomaly_total", kind="step_time").inc()
     reg.counter("fault_injected_total", kind="nan", site="fetch").inc()
@@ -1243,15 +1207,6 @@ def selftest() -> int:
                      {"ir": "momentum#163", "bytes": 4e7}],
          "copy_pairs": [{"producer": "input", "consumer": "momentum#163",
                          "bytes": 1.9e7, "n": 1}], "ts": 2.1},
-        # megastep section (fused multi-step execution)
-        {"event": "megastep", "program": 1, "version": 0, "cache": "miss",
-         "k": 8, "step0": 0, "compile_ms": 950.0, "run_ms": 24.0,
-         "amortized_ms": 3.0, "feed": {"x": [[8, 3], "float32"]},
-         "fetch": ["loss"], "ts": 2.2},
-        {"event": "megastep", "program": 1, "version": 0, "cache": "hit",
-         "k": 8, "step0": 8, "compile_ms": None, "run_ms": 20.0,
-         "amortized_ms": 2.5, "feed": {"x": [[8, 3], "float32"]},
-         "fetch": ["loss"], "ts": 2.4},
         {"event": "tensor_nonfinite", "program": "1:v0",
          "where": "executor", "var": "loss", "vars": ["loss"], "ts": 3.0},
         {"event": "step_time_anomaly", "program": "1:v0", "step_ms": 99.0,
@@ -1441,10 +1396,6 @@ def selftest() -> int:
         for must in ("2 executor runs", "1 recompiles", "hit rate",
                      "changed ['shape']", "program_mfu", "0.42",
                      "executor_run_seconds", "n=4",
-                     # megastep section
-                     "2 megasteps covering 16 substeps",
-                     "amortized dispatch ms/substep",
-                     "fetch materializations (lazy-fetch d2h syncs): 3",
                      # health section
                      "NONFINITE executor", "'loss'", "step-time anomalies",
                      "99.0ms",
@@ -1582,7 +1533,6 @@ def selftest() -> int:
         assert "idle" in render_serving([])
         assert "quiet" in render_ingestion([])
         assert "idle" in render_online([])
-        assert "unfused" in render_megastep([])
         assert "(no trace events)" in render_timeline([])
         assert "no memory samples" in render_memory({"families": []})
         assert "no attribution samples" in \
