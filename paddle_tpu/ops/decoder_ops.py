@@ -1258,9 +1258,9 @@ def ssd_scan(ctx, ins):
     impl = ctx.attr("impl", "auto")
     kernels = pallas_mode.lowers_kernels(
         ctx, impl, pallas_ssd.supports(seq, heads, p, n, chunk), "ssd_scan",
-        f"needs heads of {pallas_ssd.HEAD_DIM}, heads % {pallas_ssd.HEADS} "
-        f"== 0, state % 128 == 0 and chunk % 128 == 0; got heads={heads} of "
-        f"{p}, state={n}, chunk={chunk}")
+        f"needs heads of {pallas_ssd.HEAD_DIM}, heads % "
+        f"{pallas_ssd.HEAD_BLOCK} == 0, state % 128 == 0 and chunk % 128 == "
+        f"0; got heads={heads} of {p}, state={n}, chunk={chunk}")
     ctx.report("ssd_lowering_total",
                impl="pallas" if kernels else "composed", chunk=chunk,
                heads=heads, state=n)

@@ -326,8 +326,8 @@ def test_ssd_scan_kernels_compile_for_v5e(one_chip, batch, seq, chunk):
     assert _kernels(compiled) == 2
     assert f"{chunk},{chunk}]" not in compiled.as_text().split(
         "ENTRY")[1].replace("custom_call", "")
-    # the state a chunk, float32, and the padded per-head scalars: well
-    # under the composed form's 0.78 GB at this shape
+    # the state a chunk, float32, and dB / dC a head block: well under the
+    # composed form's 0.78 GB at this shape
     assert compiled.memory_analysis().temp_size_in_bytes < 200e6
 
 

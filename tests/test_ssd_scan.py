@@ -17,6 +17,7 @@ from paddle_tpu import layers
 from paddle_tpu.core.registry import LowerCtx
 from paddle_tpu.ops import pallas_short_conv as psc
 from paddle_tpu.ops import pallas_ssd
+from paddle_tpu.ops.decoder_ops import _chunk_sums
 from benchmark.references import granite_pretrain as reference
 from test_decoder_ops import close, rng, run_with_grads
 
@@ -291,3 +292,93 @@ def test_bfloat16_kernels_keep_the_decays_gradient():
     for name, g, ref in zip(NAMES, got, want):
         g, ref = np.asarray(f32(g)), np.asarray(ref)
         assert np.linalg.norm(g - ref) <= 0.01 * np.linalg.norm(ref), name
+
+
+# ---- the kernels against their form before PR 60 ---------------------------
+# tools/ssd_loop_form.py holds the kernels as they were: whole [Q, Q] blocks,
+# five lane sums a tile, the scalars in two padded layouts, 8 heads a step
+
+def _kernel_feeds(dtype, batch=2, seq=512, heads=32, n=128, seed=11):
+    r = rng(seed)
+    x, dy = (jnp.asarray(r.randn(batch, seq, heads * 64) * 0.5, dtype)
+             for _ in range(2))
+    bm, cm = (jnp.asarray(r.randn(batch, seq, n) * 0.3, dtype)
+              for _ in range(2))
+    dt = jnp.asarray(np.log1p(np.exp(r.randn(batch, seq, heads) - 1)),
+                     jnp.float32)
+    a = -jnp.asarray(np.exp(r.uniform(0, np.log(16), heads)), jnp.float32)
+    drow = jnp.repeat(jnp.asarray(r.randn(heads), jnp.float32), 64)[None]
+    return x, dt, a, bm, cm, drow, dy
+
+
+@pytest.mark.parametrize("chunk", [128, 256])
+@pytest.mark.parametrize("dtype", ["float32", "bfloat16"])
+def test_kernels_are_bit_for_bit_what_they_were_but_for_one_sum(
+        dtype, chunk, monkeypatch):
+    """``y``, the states and the gradients of the kernels (the ``[Q, Q]``
+    matrices as their strips on and under the diagonal, the scalars as one
+    block a head a row) against the pinned form's at as many heads a step,
+    two sequences of 512, two head blocks. In chunks of 128 (one strip):
+    equal, not close -- except ``dcum``, whose three lane sums are now the
+    one sum of the products' difference, and that to a millionth of its
+    largest entry. In chunks of 256 a strip's products leave out the block
+    above the diagonal: the same sums without their zero terms on the MXU
+    (``tools/granite_probe.py kernels`` compares the bits on the chip), but
+    XLA:CPU's dot groups a shorter contraction otherwise, so here close: a
+    millionth, or an output's bfloat16 rounding."""
+    from tools import ssd_loop_form as loop
+    x, dt, a, bm, cm, drow, dy = _kernel_feeds(dtype)
+    assert pallas_ssd.step_heads(dt.shape[2]) == pallas_ssd.HEADS == 16
+    monkeypatch.setattr(loop, "HEADS", pallas_ssd.HEADS)
+    jax.clear_caches()
+    ops = (x, dt, _chunk_sums(dt * a, chunk), bm, cm, drow)
+
+    def outputs(form):
+        return [np.asarray(v.astype(jnp.float32)) for v in (
+            form._fwd_call(*ops, chunk, True),
+            form._fwd_call(*ops, chunk, True, "states"),
+            *form._bwd_call(*ops, dy, chunk, True))]
+    names = ["y", "states", "dx", "ddt", "dcum", "dB", "dC", "dD"]
+    rounded = {"y", "dx", "dB", "dC"} if dtype == "bfloat16" else ()
+    for name, now, was in zip(names, outputs(pallas_ssd), outputs(loop)):
+        assert np.abs(was).max() > 0, name
+        if chunk == 128 and name != "dcum":
+            np.testing.assert_array_equal(now, was, err_msg=name)
+        else:
+            np.testing.assert_allclose(
+                now, was, rtol=2.0 ** -7 if name in rounded else 0,
+                atol=1e-6 * np.abs(was).max(), err_msg=name)
+    jax.clear_caches()
+
+
+@pytest.mark.parametrize("head", [0, 7, 16, 29])
+def test_a_heads_scalar_gradients_land_in_its_column(head):
+    """The scalars travel a head a row (``_by_head``: ``[B, blocks, 2 *
+    step, S]``, ``dt``'s rows over ``cum``'s) and ``_heads_last`` turns ``ddt``
+    / ``dcum`` back: with a cotangent on one head's lanes alone, of two head
+    blocks, that head's column is the only one that is not zero."""
+    chunk = 128
+    x, dt, a, bm, cm, drow, dy = _kernel_feeds(jnp.float32, batch=1, seq=256)
+    lanes = np.arange(dy.shape[-1]) // 64 == head
+    cum = _chunk_sums(dt * a, chunk)
+    _, ddt, dcum, *_ = pallas_ssd._bwd_call(
+        x, dt, cum, bm, cm, drow, jnp.where(lanes, dy, 0.0), chunk, True)
+    for name, got in (("ddt", ddt), ("dcum", dcum)):
+        assert got.shape == dt.shape, name
+        per_head = np.abs(np.asarray(got)).max(axis=(0, 1))
+        assert per_head[head] > 0, name
+        assert not np.delete(per_head, head).any(), name
+    step = pallas_ssd.step_heads(dt.shape[2])
+    by_head = pallas_ssd._by_head(dt, cum)
+    assert by_head.shape == (1, 32 // step, 2 * step, 256)
+    for which, v in enumerate((dt, cum)):
+        np.testing.assert_array_equal(
+            by_head[0, head // step, which * step + head % step],
+            v[0, :, head])
+        np.testing.assert_array_equal(
+            pallas_ssd._heads_last(by_head, which), v)
+
+
+def test_a_step_takes_whole_blocks_of_heads():
+    assert [pallas_ssd.step_heads(h) for h in (8, 16, 24, 32, 40, 48, 64)
+            ] == [8, 16, 8, 16, 8, 16, 16]

@@ -17,10 +17,26 @@ no device number).
               float32 reference, by leaf; the small leaves (``A_log``,
               ``dt_bias``, ``D``, the filter and its bias) are where a
               scan's backward goes wrong, and each has its line.
+``kernels``   the scan's kernels alone at the cell's shapes (PR 60):
+              milliseconds a layer, ``CALLS`` calls in a row, of the
+              forward, the state pass and the whole backward (state pass,
+              reverse kernel and the ``jax.numpy`` around them; the reverse
+              kernel is the difference), by ``--heads`` (``pallas_ssd.
+              HEADS``'s place) and by form: ``loop`` is ``tools/
+              ssd_loop_form.py`` (the kernels before PR 60), ``package``
+              what ``pallas_ssd`` holds, ``empty`` the package's grids
+              with bodies that write zeros and compute nothing (what the
+              grid's DMAs take); and at each ``--heads`` which of ``y``,
+              the states, ``dx``, ``ddt``, ``dcum``, ``dB``, ``dC`` and
+              ``dD`` differ in a bit between the package's and the loop
+              form's at as many heads a step (sha256 of the float32 bytes,
+              ``-0.0`` as ``0.0``), with the largest difference over the
+              largest entry beside each that does.
 """
 from __future__ import annotations
 
 import argparse
+import functools
 import gc
 import json
 import os
@@ -34,6 +50,8 @@ sys.path.insert(0, ROOT)
 from tools.lfm2_probe import leaf_errors, say  # noqa: E402
 
 CELL = "granite_4_0_h_micro.pretrain_s4096"
+CALLS = 20      # of a kernel in a row, a timing
+OUTPUTS = ("y", "states", "dx", "ddt", "dcum", "dB", "dC", "dD")
 
 
 def load_cell(args) -> dict:
@@ -147,9 +165,145 @@ def gradients(args) -> dict:
             "min_cos": min(r["cos"] for r in rows), "leaves": rows}
 
 
+def scan_feeds(batch, seq, heads, n, chunk, rng) -> tuple:
+    """Seeded operands of ``pallas_ssd._fwd_call`` / ``_bwd_call``: x and
+    its cotangent ``[B, S, heads * 64]``, B and C bfloat16, dt in mamba_ssm's
+    range (a head's scale 1e-3 to 1e-1) with A in -(1..16), float32."""
+    import jax.numpy as jnp
+    from paddle_tpu.ops.decoder_ops import _chunk_sums
+    bf = jnp.bfloat16
+    x, dy = (jnp.asarray(rng.randn(batch, seq, heads * 64) * 0.5, bf)
+             for _ in range(2))
+    bm, cm = (jnp.asarray(rng.randn(batch, seq, n) * 0.3, bf)
+              for _ in range(2))
+    dt = jnp.asarray(
+        np.exp(rng.uniform(np.log(1e-3), np.log(1e-1), (1, 1, heads)))
+        * np.exp(rng.randn(batch, seq, heads) * 0.3), jnp.float32)
+    a = -jnp.asarray(rng.uniform(1, 16, heads), jnp.float32)
+    drow = jnp.repeat(jnp.asarray(rng.randn(heads), jnp.float32), 64)[None]
+    return (x, dt, _chunk_sums(dt * a, chunk), bm, cm, drow), dy
+
+
+def _empty_kernels() -> dict:
+    """In ``pallas_ssd``'s kernels' place: every output block written with
+    zeros, nothing read but by the grid's DMAs."""
+    import jax.numpy as jnp
+
+    def zero(*refs):
+        for ref in refs:
+            ref[...] = jnp.zeros_like(ref)
+    return {"_fwd_kernel": lambda emit, *refs: zero(refs[-2]),
+            "_bwd_kernel": lambda *refs: zero(*refs[9:14])}
+
+
+def _outputs(form, ops, dy, chunk, interpret) -> list:
+    """``OUTPUTS`` of ``form``'s calls as float32, ``-0.0`` as ``0.0``."""
+    import jax.numpy as jnp
+    return [np.asarray(v.astype(jnp.float32)) + 0.0 for v in (
+        form._fwd_call(*ops, chunk, interpret),
+        form._fwd_call(*ops, chunk, interpret, "states"),
+        *form._bwd_call(*ops, dy, chunk, interpret))]
+
+
+def in_a_row(call, dt, calls: int, repeats: int) -> float:
+    """Median milliseconds of one ``call(dt)`` on the device: ``calls`` of
+    them inside one executable, each reading ``dt`` plus a zero made from
+    every output of the one before (a host call a kernel would time the
+    host: one dispatch takes 0.2 ms, chip, PR 60, whatever it launches)."""
+    import time
+    import jax
+    import jax.numpy as jnp
+
+    @jax.jit
+    def chain(dt):
+        def body(_, dt):
+            return dt + 0.0 * sum(leaf.reshape(-1)[0].astype(jnp.float32)
+                                  for leaf in jax.tree.leaves(call(dt)))
+        return jax.lax.fori_loop(0, calls, body, dt)
+    jax.block_until_ready(chain(dt))
+    times = []
+    for _ in range(repeats):
+        t0 = time.perf_counter()
+        jax.block_until_ready(chain(dt))
+        times.append((time.perf_counter() - t0) / calls * 1e3)
+    return float(np.median(times))
+
+
+def kernels(args) -> dict:
+    """The scan's kernels alone, by heads a grid step and by form."""
+    import hashlib
+    from paddle_tpu.ops import pallas_ssd
+    from tools import ssd_loop_form
+    from tools.kimi_linear_probe import swapped
+    if args.rehearsal:      # the kernels' smallest shapes, in the interpreter
+        batch, seq, heads, n, chunk = 1, 512, 16, 128, 256
+    else:
+        cell = load_cell(args)
+        model, p = cell["model"], cell["params"]
+        batch, seq = p["batch"], p["seq"]
+        heads, n = model["mamba_n_heads"], model["mamba_d_state"]
+        chunk = model["mamba_chunk_size"]
+        assert model["mamba_d_head"] == pallas_ssd.HEAD_DIM
+    interpret = bool(args.rehearsal)
+    ops, dy = scan_feeds(batch, seq, heads, n, chunk,
+                         np.random.RandomState(args.seed % (2 ** 31)))
+    calls, repeats = (2, 1) if args.rehearsal else (CALLS, 5)
+    x, dt, *rest = ops
+
+    def times(form):
+        ms = functools.partial(in_a_row, dt=dt, calls=calls, repeats=repeats)
+        fwd = ms(lambda dt: form._fwd_call(x, dt, *rest, chunk, interpret))
+        states = ms(lambda dt: form._fwd_call(x, dt, *rest, chunk, interpret,
+                                              "states"))
+        bwd = ms(lambda dt: form._bwd_call(x, dt, *rest, dy, chunk,
+                                           interpret))
+        return {"fwd_ms": fwd, "states_ms": states, "bwd_ms": bwd,
+                "reverse_ms": bwd - states, "layer_ms": fwd + bwd}
+    rows, bits = [], []
+    for step in args.heads:
+        if heads % step:
+            say(f"{heads} heads are not whole blocks of {step}")
+            continue
+        what = (f"{batch} x {seq}, {heads} heads of 64, N {n}, chunk {chunk},"
+                f" {step} heads a grid step")
+        with swapped(pallas_ssd, HEADS=step), \
+                swapped(ssd_loop_form, HEADS=step):
+            was = _outputs(ssd_loop_form, ops, dy, chunk, interpret)
+            now = _outputs(pallas_ssd, ops, dy, chunk, interpret)
+            sha = {k: hashlib.sha256(v.tobytes()).hexdigest()
+                   for k, v in zip(OUTPUTS, now)}
+            differ = {k: float(np.abs(a - b).max() / np.abs(b).max())
+                      for k, a, b in zip(OUTPUTS, now, was)
+                      if not np.array_equal(a, b)}
+            del was, now
+            bits.append({"heads": step, "differ": differ, "sha256": sha})
+            say(f"{what}: the package's {', '.join(OUTPUTS)} against the "
+                f"loop form's, bit for bit: " + (
+                    "equal" if not differ else "differ (largest difference "
+                    f"over the largest entry) in {differ}"))
+            for form in ("loop", "package", "empty"):
+                if form == "loop":
+                    row = times(ssd_loop_form)
+                else:
+                    with swapped(pallas_ssd, **(
+                            _empty_kernels() if form == "empty" else {})):
+                        row = times(pallas_ssd)
+                rows.append({"heads": step, "form": form, **row})
+                say(f"{what}, {form}: forward {row['fwd_ms']:.3f}, state "
+                    f"pass {row['states_ms']:.3f}, backward "
+                    f"{row['bwd_ms']:.3f} (the reverse kernel and the "
+                    f"jax.numpy around it {row['reverse_ms']:.3f}), forward "
+                    f"+ backward {row['layer_ms']:.3f} ms a layer")
+    return {"mode": "kernels", "seed": args.seed, "calls": calls,
+            "rows": rows, "bits": bits}
+
+
 def main(argv=None) -> int:
     ap = argparse.ArgumentParser(description=__doc__.splitlines()[0])
-    ap.add_argument("mode", choices=("controls", "grads"))
+    ap.add_argument("mode", choices=("controls", "grads", "kernels"))
+    ap.add_argument("--heads", nargs="*", type=int, default=[8, 16],
+                    help="kernels: heads a grid step, in pallas_ssd.HEADS' "
+                         "place")
     ap.add_argument("--seed", type=int, default=2147480011)
     ap.add_argument("--batch", type=int)
     ap.add_argument("--seq", type=int)
@@ -159,7 +313,8 @@ def main(argv=None) -> int:
     args = ap.parse_args(argv)
     from paddle_tpu.utils import compile_cache
     compile_cache.arm()
-    result = {"controls": controls, "grads": gradients}[args.mode](args)
+    result = {"controls": controls, "grads": gradients,
+              "kernels": kernels}[args.mode](args)
     line = json.dumps(result)
     print(json.dumps({k: v for k, v in result.items() if k != "leaves"}),
           flush=True)
