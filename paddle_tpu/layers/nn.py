@@ -1142,6 +1142,38 @@ def rms_norm(input, epsilon=1e-5, param_attr=None, name=None,
     return _var(helper, y)
 
 
+def _rope_scaling_attrs(scaling) -> dict:
+    """The attrs a rotating op (``rotary_embedding``, ``latent_qkv``) reads
+    its frequencies from, out of a dict in HF's ``rope_scaling`` keys (None:
+    none). ``rope_type`` (also spelt ``type``) ``"yarn"``: ``factor``,
+    ``original_max_position_embeddings``, ``beta_fast`` 32, ``beta_slow`` 1
+    and the factor on cos and sin, ``attention_factor``; by default, as HF's
+    ``_compute_yarn_parameters`` has it, ``m(mscale) / m(mscale_all_dim)``
+    where the dict has both, else ``m(1)``, with ``m(s) = 0.1 s ln(factor) +
+    1``."""
+    import math
+    kind = (scaling or {}).get("rope_type", (scaling or {}).get("type"))
+    if kind in (None, "default"):
+        return {}
+    if kind != "yarn":
+        raise NotImplementedError(
+            f"rope_type {kind!r} is not built (only 'default' and 'yarn')")
+    factor = float(scaling["factor"])
+
+    def m(scale):
+        return 0.1 * scale * math.log(factor) + 1.0 if factor > 1 else 1.0
+    both = scaling.get("mscale") and scaling.get("mscale_all_dim")
+    return {"scaling": "yarn", "factor": factor,
+            "original_max_position": float(
+                scaling["original_max_position_embeddings"]),
+            "beta_fast": float(scaling.get("beta_fast") or 32.0),
+            "beta_slow": float(scaling.get("beta_slow") or 1.0),
+            "attention_factor": float(
+                scaling.get("attention_factor")
+                or (m(scaling["mscale"]) / m(scaling["mscale_all_dim"])
+                    if both else m(1.0)))}
+
+
 def rotary_embedding(x, theta=10000.0, name=None, rotary_dim=None,
                      scaling=None):
     """Rotary position embedding (rotate-half convention) over ``x [..., S,
@@ -1154,27 +1186,12 @@ def rotary_embedding(x, theta=10000.0, name=None, rotary_dim=None,
     the frequencies blended as HF's ``_compute_yarn_parameters`` blends them
     over ``rotary_dim``, cos and sin times ``attention_factor``
     (``ops/decoder_ops.py:yarn_inv_freq``)."""
-    import math
     helper = LayerHelper("rotary_embedding", name=name)
     out = _out(helper, x.dtype)
     attrs = {"theta": float(theta)}
     if rotary_dim and int(rotary_dim) != int(x.shape[-1]):
         attrs["rotary_dim"] = int(rotary_dim)
-    kind = (scaling or {}).get("rope_type", (scaling or {}).get("type"))
-    if kind == "yarn":
-        factor = float(scaling["factor"])
-        attrs.update(
-            scaling="yarn", factor=factor,
-            original_max_position=float(
-                scaling["original_max_position_embeddings"]),
-            beta_fast=float(scaling.get("beta_fast") or 32.0),
-            beta_slow=float(scaling.get("beta_slow") or 1.0),
-            attention_factor=float(scaling.get("attention_factor")
-                                   or 0.1 * math.log(factor) + 1.0))
-    elif kind not in (None, "default"):
-        raise NotImplementedError(
-            f"rotary_embedding: rope_type {kind!r} is not built (only "
-            f"'default' and 'yarn')")
+    attrs.update(_rope_scaling_attrs(scaling))
     helper.append_op("rotary_embedding", inputs={"X": [x]},
                      outputs={"Out": [out]}, attrs=attrs)
     return _var(helper, out)
@@ -1182,7 +1199,7 @@ def rotary_embedding(x, theta=10000.0, name=None, rotary_dim=None,
 
 def latent_qkv(q, kv, k_rope, batch, seq, heads, nope_dim, rope_dim,
                theta=10000.0, name=None, rotate=True, value_dim=None,
-               head_dim=None):
+               head_dim=None, scaling=None):
     """Latent attention's q, k and v ``[batch, heads, seq, nope_dim +
     rope_dim]`` for ``fused_attention``, in one op, from its three
     up-projections over ``batch x seq`` tokens: ``q [T, heads x (nope_dim +
@@ -1197,12 +1214,14 @@ def latent_qkv(q, kv, k_rope, batch, seq, heads, nope_dim, rope_dim,
     it is not a q head's (v is then ``[batch, heads, seq, value_dim]``);
     ``rotate=False``: the rotary parts stay as projected (no positions);
     ``head_dim``: the q / k head written that wide, zero columns behind its
-    two parts."""
+    two parts; ``scaling``: ``rotary_embedding``'s (a YaRN dict: the rotary
+    parts turn at the blended frequencies; the factor on the softmax scale
+    is the caller's)."""
     helper = LayerHelper("latent_qkv", name=name)
     outs = [_out(helper, x.dtype) for x in (q, kv, kv)]
     attrs = {"batch": int(batch), "seq": int(seq), "heads": int(heads),
              "nope_dim": int(nope_dim), "rope_dim": int(rope_dim),
-             "theta": float(theta)}
+             "theta": float(theta), **_rope_scaling_attrs(scaling)}
     if not rotate:
         attrs["rotate"] = False
     for key, width in (("value_dim", value_dim), ("head_dim", head_dim)):
@@ -1213,6 +1232,57 @@ def latent_qkv(q, kv, k_rope, batch, seq, heads, nope_dim, rope_dim,
         outputs={"OutQ": [outs[0]], "OutK": [outs[1]], "OutV": [outs[2]]},
         attrs=attrs)
     return tuple(_var(helper, o) for o in outs)
+
+
+def hyper_connection_pre(x, streams, iters=20, eps=1e-6, clamp=(-30.0, 30.0),
+                         phi_attr=None, b_attr=None, alpha_attr=None,
+                         name=None):
+    """The read side of a manifold-constrained hyper-connection (mHC,
+    arXiv:2512.24880; ``ops/decoder_ops.py:hyper_connection_pre``) over a
+    residual state of ``streams`` streams a token, ``x [T, streams * C]``
+    (stream j the columns ``[j C, (j + 1) C)``). Creates the float32
+    parameters ``Phi [streams * C, 2 streams + streams^2]``, ``B [2 streams
+    + streams^2]`` and ``Alpha [3]``. Returns ``(u, coef)``: ``u [T, C] =
+    H_pre X``, the input of the sub-layer's branch, in x's dtype, and the
+    token's float32 coefficients ``[T, 2 streams + streams^2]`` = ``[H_pre |
+    H_post | H_res row-major]``, which ``hyper_connection_post`` takes.
+    ``H_pre = sigmoid``, ``H_post = 2 sigmoid``, ``H_res`` ``iters``
+    Sinkhorn-Knopp iterations over ``exp`` of the logits held to ``clamp``,
+    each of ``Alpha x (RMSNorm_eps(x) Phi) + B``. The op's registered grad
+    keeps x and computes the coefficients again."""
+    helper = LayerHelper("hyper_connection_pre", name=name)
+    n = int(streams)
+    wide, k = int(x.shape[-1]), 2 * n + n * n
+    if wide % n:
+        raise ValueError(f"hyper_connection_pre: x {tuple(x.shape)} is not "
+                         f"{n} streams side by side")
+    phi = helper.create_parameter(phi_attr, [wide, k], "float32")
+    b = helper.create_parameter(b_attr, [k], "float32", is_bias=True)
+    alpha = helper.create_parameter(alpha_attr, [3], "float32")
+    u, coef = _out(helper, x.dtype), _out(helper, "float32")
+    helper.append_op(
+        "hyper_connection_pre",
+        inputs={"X": [x], "Phi": [phi], "B": [b], "Alpha": [alpha]},
+        outputs={"U": [u], "Coef": [coef]},
+        attrs={"streams": n, "iters": int(iters), "eps": float(eps),
+               "clamp_min": float(clamp[0]), "clamp_max": float(clamp[1])})
+    return _var(helper, u), _var(helper, coef)
+
+
+def hyper_connection_post(x, y, coef, streams, iters=20, name=None):
+    """The write side of a hyper-connection
+    (``ops/decoder_ops.py:hyper_connection_post``): the next residual state
+    ``H_res X + H_post^T y`` ``[T, streams * C]`` from the state ``x``, the
+    branch's output ``y [T, C]`` and ``hyper_connection_pre``'s ``coef``
+    (``iters``: the same op's, for the lowering's counter). The op's
+    registered grad keeps x, y and coef."""
+    helper = LayerHelper("hyper_connection_post", name=name)
+    out = _out(helper, x.dtype)
+    helper.append_op(
+        "hyper_connection_post", inputs={"X": [x], "Y": [y], "Coef": [coef]},
+        outputs={"Out": [out]},
+        attrs={"streams": int(streams), "iters": int(iters)})
+    return _var(helper, out)
 
 
 def attention_gate(x, gate, name=None):
@@ -1369,7 +1439,8 @@ def moe_bias_update(bias, load, rate, name=None):
 def moe_ffn(x, num_experts, experts_per_token, expert_width, param_attr=None,
             name="moe", experts_held=None, scoring="softmax", norm_topk=False,
             routed_scale=1.0, expert_bias=False, row_budget=None,
-            shared_width=None, shared_gate=False, expert_axis=None):
+            shared_width=None, shared_gate=False, expert_axis=None,
+            matmul_tiling=None):
     """A dropless mixture-of-experts feed-forward layer over tokens
     ``x [T, H]``: a float32 router (softmax over the experts, top-k values
     used as they are, or under ``norm_topk`` over their sum, times
@@ -1440,6 +1511,11 @@ def moe_ffn(x, num_experts, experts_per_token, expert_width, param_attr=None,
     Parameters, by name: ``<name>_router_w [H, E]`` float32 and
     ``<name>_gate_w`` / ``<name>_up_w [held, H, width]``, ``<name>_down_w
     [held, width, H]`` in x's dtype; ``param_attr`` supplies the initializer.
+
+    ``matmul_tiling`` ``(m, k, n)``: the rows, contraction and columns a
+    grid step of the grouped products' megablox kernels takes, in
+    ``ops.decoder_ops.GMM_TILING``'s place (each held to its dimension); the
+    composed form does not read it.
 
     Returns ``(out [T, H], aux)`` with ``aux`` the router's variables:
     ``prob [T, E]`` (the scores), ``logz [T]`` (logsumexp of the logits;
@@ -1546,7 +1622,9 @@ def moe_ffn(x, num_experts, experts_per_token, expert_width, param_attr=None,
            {"X": [inp], "W": [param(suffix, shape, x.dtype,
                                     (expert_axis, None, None)
                                     if crossed else None)],
-            "Count": [groups]}, {"Out": [out]}, dict(crossed))
+            "Count": [groups]}, {"Out": [out]},
+           dict(crossed, **({"tiling": [int(t) for t in matmul_tiling]}
+                            if matmul_tiling else {})))
         return out
 
     gated = swiglu(experts(rows, "gate_w", [held, H, width]),

@@ -47,6 +47,27 @@ pre-norm, without biases or dropout: ``h = x + r operator(norm(x))``,
   pass): ``build`` builds training programs, which run every pass, and
   never reads it; no decode loop that would is built yet.
 
+- ``hc_mult`` = n above 1: the residual path is n streams a token
+  (manifold-constrained hyper-connections: DeepSeek-AI, mHC,
+  arXiv:2512.24880, over Hyper-Connections, Zhu et al., arXiv:2409.19606).
+  A token's state is ``X [n, H]``, in the Program ``[tokens, n H]`` with
+  the streams side by side; ``X_0`` is the embedding in every stream. Each
+  sub-layer ``F`` (the pre-norm on the H-wide input, then the operator or
+  the feed-forward) has float32 ``phi [n H, 2 n + n^2]``, ``b [2 n +
+  n^2]``, ``alpha [3]`` (``<operator>_hc_*``, ``<layer>_ffn_hc_*``): with
+  ``z = RMSNorm_{hc_eps}(vec X) phi`` (no learned scale), ``H_pre =
+  sigmoid(alpha_0 z + b)`` (n), ``H_post = 2 sigmoid(alpha_1 z + b)`` (n),
+  ``H_res = SK(clip(alpha_2 z + b, mhc_h_res_clamp_min, _max))`` (n x n:
+  ``exp``, then ``hc_sinkhorn_iters`` times rows over their sums + ``hc_
+  eps``, columns over theirs); ``u = H_pre X``, ``y = F(u)``, ``X' = H_res
+  X + H_post^T y`` (``layers.hyper_connection_pre`` / ``_post``, float32
+  inside, their grad ops keep X, y and the coefficients and compute the
+  rest again). After the last layer the streams are summed, then the final
+  norm. ``hc_alpha_init`` (default 0.01) and ``hc_bias_std`` (default 0:
+  ``b`` from 0, else from a seeded normal) are the recipe's start. Absent
+  or 1: the one-stream path, op for op what it was. Not beside a
+  prediction module, a looped stack, mesh axes, sandwich norms or a
+  residual multiplier.
 - ``layer_types`` (default: every layer ``full_attention``, or, where the
   config has ``full_attention_interval``, every interval-th layer
   ``full_attention`` and the others ``linear_attention``), one operator a
@@ -80,7 +101,9 @@ pre-norm, without biases or dropout: ``h = x + r operator(norm(x))``,
   (``latent_attention``, below; DeepSeek-V2's, GLM-4.7-Flash's, Kimi
   Linear's: there beside linear layers, under ``q_lora_rank: null`` with q
   from one projection, under ``mla_use_nope`` without rotation, with a
-  ``v_head_dim`` of its own).
+  ``v_head_dim`` of its own; Xing4.0's under ``rope_scaling``, a YaRN dict:
+  blended frequencies, ``mscale_all_dim``'s factor squared on the softmax
+  scale).
   ``sliding_attention``: the same
   with a window of ``sliding_window`` keys (query i sees i - window < j <=
   i). ``conv``: the gated short convolution ``W_out (C * conv(B
@@ -131,7 +154,10 @@ pre-norm, without biases or dropout: ``h = x + r operator(norm(x))``,
   layer's output is the held experts' part (``layers.moe_ffn``), plus the
   shared expert where there is one (every chip computes it alike).
   ``moe_row_budget``: the sorted rows such a layer keeps (``layers.moe_ffn``'s
-  ``row_budget``; rows beyond it are dropped and counted). A sliced
+  ``row_budget``; rows beyond it are dropped and counted).
+  ``moe_matmul_tiling``: the (m, k, n) tile of the grouped products'
+  kernels where a configuration states one (``layers.moe_ffn``'s
+  ``matmul_tiling``; default ``ops.decoder_ops.GMM_TILING``). A sliced
   vocabulary is a smaller ``vocab_size``.
 - the deployment itself, where one host holds whole layers:
   ``expert_axis`` names the mesh axis every expert layer's experts are split
@@ -177,8 +203,11 @@ DeepSeek-V3, arXiv:2412.19437), Kimi-Linear-48B-A3B-Instruct (its
 that repository; Kimi Delta Attention: the Kimi Linear report,
 arXiv:2510.26692) and Mellum2-12B-A2.5B-Instruct (its ``config.json``,
 ``model_type: mellum``: Qwen3-MoE's key set with ``layer_types`` and rotary
-parameters by layer type) and Ouro-2.6B (its ``config.json``,
-``model_type: ouro``; the LoopLM report, arXiv:2510.25741).
+parameters by layer type), Ouro-2.6B (its ``config.json``,
+``model_type: ouro``; the LoopLM report, arXiv:2510.25741) and
+Xing4.0-29B-A4B (its ``config.json``, ``model_type: xing4_0``; the mHC
+report, arXiv:2512.24880; YaRN inside latent attention: HF
+``DeepseekV3Attention``).
 
 Dtypes follow ``models/bert.py``: the embedding table is float32 whatever
 ``dtype`` says, activations are cast to ``dtype`` right after the lookup,
@@ -187,7 +216,8 @@ weights are created in ``dtype`` (a Mamba, DeltaNet or KDA mixer's ``A_log``,
 key channel --, in float32); RMSNorm (latent
 attention's two among them), the router,
 the short convolution, the scan's and the delta rule's decays and state, the
-delta rule's l2 norms and every softmax compute in float32 inside their ops;
+delta rule's l2 norms, the hyper-connections' coefficients, reads and writes
+and every softmax compute in float32 inside their ops;
 the logits are cast up for the loss.
 """
 from __future__ import annotations
@@ -200,7 +230,7 @@ from ..initializer import Constant, Initializer, Normal, Uniform
 from ..layer_helper import ParamAttr
 
 _REQUIRED = {"hidden_act": "silu", "attention_bias": False,
-             "clip_qkv": None, "rope_scaling": None, "conv_bias": False,
+             "clip_qkv": None, "conv_bias": False,
              "mamba_proj_bias": False, "mamba_n_groups": 1,
              "normalization_function": "rmsnorm",
              "moe_apply_router_weight_on_input": False,
@@ -214,7 +244,13 @@ _ATTENTION = ("full_attention", "sliding_attention")
 def _check(cfg: dict) -> None:
     if cfg.get("kv_lora_rank"):
         _check_latent(cfg)
+    elif cfg.get("rope_scaling") is not None:
+        raise NotImplementedError(
+            f"decoder_lm: rope_scaling={cfg['rope_scaling']!r} is not built "
+            f"yet (only None; a YaRN dict beside kv_lora_rank, inside latent "
+            f"attention; by layer type under rope_parameters)")
     _check_loop(cfg)
+    _check_streams(cfg)
     for key, want in _REQUIRED.items():
         if cfg.get(key, want) != want:
             raise NotImplementedError(
@@ -392,13 +428,56 @@ def _check_loop(cfg: dict) -> None:
             "above 1 is not built yet (the loop op under a mesh)")
 
 
+def _streams(cfg: dict) -> int:
+    """``hc_mult``: the residual streams a token (absent: 1)."""
+    return cfg.get("hc_mult") or 1
+
+
+def _check_streams(cfg: dict) -> None:
+    """What a config with ``hc_mult`` above 1 (hyper-connections) asks for
+    and ``build`` does not build."""
+    n = _streams(cfg)
+    if not isinstance(n, int) or n < 1:
+        raise ValueError(f"decoder_lm: hc_mult={n!r} must be a whole number "
+                         f"of residual streams, at least 1")
+    if n == 1:
+        return
+    missing = [k for k in ("hc_sinkhorn_iters", "hc_eps",
+                           "mhc_h_res_clamp_min", "mhc_h_res_clamp_max")
+               if k not in cfg]
+    if missing:
+        raise ValueError(f"decoder_lm: hc_mult={n} needs {missing}")
+    for key, without, why in (
+            ("total_ut_steps", 1, "hyper-connections inside the scan op"),
+            ("num_nextn_predict_layers", 0,
+             "a prediction module over a multi-stream trunk: how it reads "
+             "the streams is in no key of the config"),
+            ("expert_axis", None, "hyper-connections under a mesh"),
+            ("vocab_axis", None, "hyper-connections under a mesh")):
+        if (cfg.get(key) or without) != without:
+            raise NotImplementedError(
+                f"decoder_lm: {key}={cfg[key]!r} beside hc_mult={n} is not "
+                f"built yet ({why})")
+    if cfg.get("norm_placement", "pre") != "pre" or cfg.get(
+            "residual_multiplier", 1.0) != 1.0:
+        raise NotImplementedError(
+            "decoder_lm: norm_placement='sandwich' / residual_multiplier "
+            f"beside hc_mult={n} is not built yet (the write side is H_res X "
+            "+ H_post^T y, as the mHC report has it)")
+
+
 def _check_latent(cfg: dict) -> None:
     """What a config with ``kv_lora_rank`` (latent attention) asks for and
     ``latent_attention`` does not build."""
-    if cfg.get("rope_scaling") is not None:
+    scaling = cfg.get("rope_scaling")
+    if scaling is not None and (
+            not isinstance(scaling, dict)
+            or scaling.get("rope_type", scaling.get("type")) != "yarn"
+            or cfg.get("mla_use_nope")):
         raise NotImplementedError(
-            "decoder_lm: rope_scaling inside latent attention (YaRN with "
-            "its mscale on the softmax scale) is not built yet (only null)")
+            f"decoder_lm: rope_scaling inside latent attention is built for "
+            f"null and for a YaRN dict over rotary parts that are rotated "
+            f"(its mscale on the softmax scale), not {scaling!r}")
     if cfg.get("partial_rotary_factor", 1) != 1:
         raise NotImplementedError(
             "decoder_lm: partial_rotary_factor other than 1 inside latent "
@@ -417,12 +496,12 @@ def _held(cfg: dict):
 
 
 def _scoring(cfg: dict) -> str:
-    """``router_scoring`` (also spelt ``moe_router_activation_func``); a
-    config with ``topk_method: "noaux_tc"`` scores by sigmoid and chooses by
-    score + bias."""
-    return cfg.get("router_scoring", cfg.get(
+    """``router_scoring`` (also spelt ``scoring_func``, DeepSeek-V3's, and
+    ``moe_router_activation_func``, Kimi's); a config with ``topk_method:
+    "noaux_tc"`` scores by sigmoid and chooses by score + bias."""
+    return cfg.get("router_scoring", cfg.get("scoring_func", cfg.get(
         "moe_router_activation_func",
-        "sigmoid" if "topk_method" in cfg else "softmax"))
+        "sigmoid" if "topk_method" in cfg else "softmax")))
 
 
 def _bias_chosen(cfg: dict) -> bool:
@@ -615,7 +694,12 @@ def latent_attention(x, cfg: dict, batch: int, seq: int, name: str):
     written up to the next whole tile, zero columns behind its two parts
     (192 -> 256: a zero column adds nothing to a score, the MXU's passes
     over 192 are those over 256, and the flash kernels read whole tiles: 13%
-    faster on the chip, PR 51). Both latent norms are the config's RMSNorm."""
+    faster on the chip, PR 51). Both latent norms are the config's RMSNorm.
+    Under ``rope_scaling`` (a YaRN dict; HF ``DeepseekV3RotaryEmbedding`` /
+    ``DeepseekV3Attention``): ``q_r`` and ``k_r`` turn at YaRN's blended
+    frequencies over the rotary head (``ops.decoder_ops.yarn_inv_freq``), cos
+    and sin times ``m(mscale) / m(mscale_all_dim)``, and the softmax scale
+    times ``m(mscale_all_dim)^2``, ``m(s) = 0.1 s ln(factor) + 1``."""
     heads = cfg["num_attention_heads"]
     d_n, d_r = cfg["qk_nope_head_dim"], cfg["qk_rope_head_dim"]
     r_kv, d_v = cfg["kv_lora_rank"], cfg["v_head_dim"]
@@ -630,14 +714,20 @@ def latent_attention(x, cfg: dict, batch: int, seq: int, name: str):
     kv = _linear(_norm(c_kv, cfg, name + "_kv_a_norm_w"),
                  heads * (d_n + d_v), name + "_kv_b_w")
     d = d_n + d_r
+    scaling = cfg.get("rope_scaling")
     q, k, v = layers.latent_qkv(q, kv, k_r, batch, seq, heads, d_n, d_r,
                                 theta=cfg.get("rope_theta", 10000.0),
                                 rotate=not cfg.get("mla_use_nope", False),
                                 value_dim=d_v,
-                                head_dim=-(-d // 128) * 128 if d > 128 else d)
-    ctx = layers.fused_attention(
-        q, k, v, causal=True, impl="auto",
-        scale=float(cfg.get("attention_multiplier", 1.0 / math.sqrt(d))))
+                                head_dim=-(-d // 128) * 128 if d > 128 else d,
+                                scaling=scaling)
+    scale = float(cfg.get("attention_multiplier", 1.0 / math.sqrt(d)))
+    if scaling and scaling.get("mscale_all_dim") and scaling["factor"] > 1:
+        # HF DeepseekV3Attention: yarn_get_mscale(factor, mscale_all_dim)^2
+        scale *= (0.1 * scaling["mscale_all_dim"]
+                  * math.log(scaling["factor"]) + 1.0) ** 2
+    ctx = layers.fused_attention(q, k, v, causal=True, impl="auto",
+                                 scale=scale)
     ctx = layers.reshape(layers.transpose(ctx, [0, 2, 1, 3]),
                          [batch * seq, heads * d_v])
     return _linear(ctx, cfg["hidden_size"], name + "_o_w")
@@ -859,7 +949,8 @@ def experts(x, cfg: dict, name: str):
         row_budget=cfg.get("moe_row_budget"),
         shared_width=_shared_width(cfg),
         shared_gate=bool(cfg.get("shared_expert_gate", False)),
-        expert_axis=cfg.get("expert_axis"))
+        expert_axis=cfg.get("expert_axis"),
+        matmul_tiling=cfg.get("moe_matmul_tiling"))
 
 
 
@@ -870,8 +961,13 @@ def block(x, cfg: dict, batch: int, seq: int, name: str,
     (``layers.moe_ffn``; None for a ``dense`` feed-forward layer). Under
     ``norm_placement: "sandwich"`` each branch's output is normed once more
     before its residual add (``<operator>_post_norm_w``,
-    ``<layer>_ffn_post_norm_w``)."""
+    ``<layer>_ffn_post_norm_w``). Under ``hc_mult`` = n above 1, ``x`` and
+    the output are the residual state ``[batch * seq, n * H]``, the n
+    streams side by side, and each of the two sub-layers is a
+    hyper-connection around its branch (the module's docstring; parameters
+    ``<operator>_hc_phi`` / ``_b`` / ``_alpha``, ``<layer>_ffn_hc_...``)."""
     r = float(cfg.get("residual_multiplier", 1.0))
+    n = _streams(cfg)
 
     def add(h, branch):
         return layers.elementwise_add(
@@ -881,32 +977,61 @@ def block(x, cfg: dict, batch: int, seq: int, name: str,
     def branch(y, norm_w):
         # sandwich: a second norm on the branch's output, before the add
         return _norm(y, cfg, norm_w) if sandwich else y
+
+    def sublayer(state, run, hc):
+        """``state + run(state)``; over ``hc_mult`` streams the
+        hyper-connection ``hc``: ``H_res state + H_post^T run(H_pre
+        state)``. Returns the next state and what ``run`` gave beside its
+        output."""
+        if n == 1:
+            y, aux = run(state)
+            return add(state, y), aux
+        u, coef = layers.hyper_connection_pre(
+            state, n, cfg["hc_sinkhorn_iters"], cfg["hc_eps"],
+            (cfg["mhc_h_res_clamp_min"], cfg["mhc_h_res_clamp_max"]),
+            phi_attr=_attr(hc + "_phi"),
+            b_attr=ParamAttr(name=hc + "_b", initializer=(
+                Normal(0.0, float(cfg["hc_bias_std"]))
+                if cfg.get("hc_bias_std") else Constant(0.0))),
+            alpha_attr=ParamAttr(name=hc + "_alpha", initializer=Constant(
+                float(cfg.get("hc_alpha_init", 0.01)))))
+        y, aux = run(u)
+        return layers.hyper_connection_post(
+            state, y, coef, n, cfg["hc_sinkhorn_iters"]), aux
     op_name = name + {"conv": "_conv", "mamba": "_mamba", "kda": "_kda",
                       "linear_attention": "_delta"}.get(kind, "_attn")
-    normed = _norm(x, cfg, op_name + "_norm_w")
-    if kind == "conv":
-        mixed = short_conv(normed, cfg, seq, op_name)
-    elif kind == "mamba":
-        mixed = mamba(normed, cfg, batch, seq, op_name)
-    elif kind == "linear_attention":
-        mixed = delta_net(normed, cfg, batch, seq, op_name)
-    elif kind == "kda":
-        mixed = kimi_delta(normed, cfg, batch, seq, op_name)
-    elif cfg.get("kv_lora_rank"):
-        mixed = latent_attention(normed, cfg, batch, seq, op_name)
-    else:
-        mixed = attention(normed, cfg, batch, seq, op_name, layer, kind)
-    h = add(x, branch(mixed, op_name + "_post_norm_w"))
-    normed = _norm(h, cfg, name + "_ffn_norm_w")
-    if dense:
-        width = cfg.get("shared_intermediate_size", cfg["intermediate_size"])
-        gated = layers.swiglu(_linear(normed, width, name + "_ffn_gate_w"),
-                              _linear(normed, width, name + "_ffn_up_w"))
-        return add(h, branch(
-            _linear(gated, cfg["hidden_size"], name + "_ffn_down_w"),
-            name + "_ffn_post_norm_w")), None
-    moe, aux = experts(normed, cfg, name + "_moe")
-    return add(h, branch(moe, name + "_ffn_post_norm_w")), aux
+
+    def operator(x):
+        normed = _norm(x, cfg, op_name + "_norm_w")
+        if kind == "conv":
+            mixed = short_conv(normed, cfg, seq, op_name)
+        elif kind == "mamba":
+            mixed = mamba(normed, cfg, batch, seq, op_name)
+        elif kind == "linear_attention":
+            mixed = delta_net(normed, cfg, batch, seq, op_name)
+        elif kind == "kda":
+            mixed = kimi_delta(normed, cfg, batch, seq, op_name)
+        elif cfg.get("kv_lora_rank"):
+            mixed = latent_attention(normed, cfg, batch, seq, op_name)
+        else:
+            mixed = attention(normed, cfg, batch, seq, op_name, layer, kind)
+        return branch(mixed, op_name + "_post_norm_w"), None
+
+    def feed_forward(h):
+        normed = _norm(h, cfg, name + "_ffn_norm_w")
+        if dense:
+            width = cfg.get("shared_intermediate_size",
+                            cfg["intermediate_size"])
+            gated = layers.swiglu(
+                _linear(normed, width, name + "_ffn_gate_w"),
+                _linear(normed, width, name + "_ffn_up_w"))
+            return branch(
+                _linear(gated, cfg["hidden_size"], name + "_ffn_down_w"),
+                name + "_ffn_post_norm_w"), None
+        moe, aux = experts(normed, cfg, name + "_moe")
+        return branch(moe, name + "_ffn_post_norm_w"), aux
+    h, _ = sublayer(x, operator, op_name + "_hc")
+    return sublayer(h, feed_forward, name + "_ffn_hc")
 
 
 def prediction_module(h, next_tokens, cfg: dict, batch: int, seq: int,
@@ -987,8 +1112,10 @@ def build(cfg: dict, ids, labels, labels_next=None) -> dict:
     under ``use_expert_bias``), ``expert_dropped`` (``[1]`` int32: the
     rows the layer's ``moe_row_budget`` has dropped since startup) and
     ``expert_routed`` (``[batch * seq, H]``: the routed experts' part of
-    the layer's output, without the shared expert's). Under
-    ``total_ut_steps`` above 1: the module's docstring."""
+    the layer's output, without the shared expert's). Under ``hc_mult``
+    above 1 also ``stream_states``: every layer's output state ``[batch *
+    seq, hc_mult * H]``. Under ``total_ut_steps`` above 1: the module's
+    docstring."""
     _check(cfg)
     batch, seq = int(ids.shape[0]), int(ids.shape[1])
     H = cfg["hidden_size"]
@@ -996,8 +1123,8 @@ def build(cfg: dict, ids, labels, labels_next=None) -> dict:
     router_losses = "router_aux_loss_coef" in cfg
     dtype = cfg.get("dtype", "float32")
     x = layers.reshape(_embed(ids, cfg), [batch * seq, H])
-    balance, z, loads, indices, biases, dropped, routed = (
-        [] for _ in range(7))
+    balance, z, loads, indices, biases, dropped, routed, states = (
+        [] for _ in range(8))
 
     def keep(aux):
         loads.append(aux["load"])
@@ -1041,9 +1168,14 @@ def build(cfg: dict, ids, labels, labels_next=None) -> dict:
             bias_attr=ParamAttr(name="exit_gate_b"))
         return {"loss": loss, "ce": ce, "each": each, "exit_p": exit_p,
                 "loop_checkpoints": cut}
+    n = _streams(cfg)
+    if n > 1:           # X_0: the embedding in every stream
+        x = layers.concat([x] * n, axis=-1)
     for i, kind in enumerate(_layer_types(cfg)):
         x, aux = block(x, cfg, batch, seq, f"layer{i}", kind,
                        dense=_is_dense(cfg, i), layer=i)
+        if n > 1:
+            states.append(x)
         if aux is None:
             continue
         if router_losses:
@@ -1056,11 +1188,15 @@ def build(cfg: dict, ids, labels, labels_next=None) -> dict:
                 float(E)))
             z.append(layers.mean(layers.square(aux["logz"])))
         keep(aux)
+    if n > 1:           # the streams' sum closes the stack
+        x = layers.sums(layers.split(x, n, dim=-1))
     each = cross_entropy(x, "final_norm_w", labels)
     ce = layers.mean(each)
     out = {"loss": ce, "ce": ce, "each": each, "expert_load": loads,
            "expert_index": indices, "expert_bias": biases,
            "expert_dropped": dropped, "expert_routed": routed}
+    if n > 1:
+        out["stream_states"] = states
     if cfg.get("num_nextn_predict_layers"):
         if labels_next is None:
             raise ValueError("decoder_lm: num_nextn_predict_layers needs "

@@ -179,9 +179,19 @@ FAMILIES = {
     # the one key head k_r are rotated, 0 under rotate=False (positions
     # left to the linear layers); head_dim: the q / k head's width as
     # written (zero columns behind nope + rope included); value_dim: v's
+    # frequencies: default (theta^(-2i/d)) or yarn (yarn_inv_freq's blend)
     "latent_qkv_lowering_total": (
-        COUNT, ("rotated", "heads", "head_dim", "value_dim"),
-        "latent_qkv ops compiled, by rotation and head widths"),
+        COUNT, ("rotated", "heads", "head_dim", "value_dim", "frequencies"),
+        "latent_qkv ops compiled, by rotation, head widths and the form of "
+        "the rotation's frequencies"),
+    # ops/decoder_ops.py:hyper_connection_pre / _post and their registered
+    # grads, each lowering once. part: pre (the coefficients and the read) /
+    # post (the write); streams: the residual streams a token; iters: the
+    # Sinkhorn-Knopp iterations. All composed jax.numpy until a kernel
+    # gives the family an impl label
+    "hyper_connection_lowering_total": (
+        COUNT, ("part", "direction", "streams", "iters"),
+        "hyper-connection ops and grad ops compiled, by side and direction"),
     # amount: a moe_dispatch op's row budget (attr rows), its assignments
     # without one; the sort's output, the grouped products, swiglu and the
     # combine are sized by it
@@ -228,6 +238,7 @@ LATER_LABELS = {
     "attention_lowering_total": {"value_dim": 0, "mesh": "none"},
     "rotary_lowering_total": {"mesh": "none", "impl": "composed"},
     "moe_rows_lowering_total": {"mesh": "none"},
+    "latent_qkv_lowering_total": {"frequencies": "default"},
 }
 
 

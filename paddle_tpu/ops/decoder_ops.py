@@ -315,8 +315,13 @@ def latent_qkv(ctx, ins):
     positions are the linear layers' business). Attr ``head_dim`` (default
     nope + rope): the q / k head's width as written, zero columns behind the
     rope part (whole lane tiles for the flash kernels; a zero column adds
-    nothing to a score). Rotate-half, positions 0..seq-1, float32 inside the
-    rotation only: the parts that are not rotated move in their own dtype."""
+    nothing to a score). Attr ``scaling="yarn"``: the rotation's frequencies
+    are ``yarn_inv_freq``'s over the rotary head (attrs ``factor``,
+    ``original_max_position``, ``beta_fast``, ``beta_slow``) and cos and sin
+    are multiplied by ``attention_factor`` (``rotary_embedding``'s attrs,
+    read by the same ``_rotary_tables``). Rotate-half, positions 0..seq-1,
+    float32 inside the rotation only: the parts that are not rotated move in
+    their own dtype."""
     import jax.numpy as jnp
     q, kv, k_r = ins["Q"][0], ins["KV"][0], ins["KRope"][0]
     B, S, h, d_n, d_r, d_v, d, rotate = _latent_sizes(ctx)
@@ -327,7 +332,8 @@ def latent_qkv(ctx, ins):
             f"are not {h} heads of [{d_n} | {d_r}], [{d_n} | {d_v}] and one "
             f"of {d_r} (head_dim {d})")
     ctx.report("latent_qkv_lowering_total", rotated=int(rotate), heads=h,
-               head_dim=d, value_dim=d_v)
+               head_dim=d, value_dim=d_v,
+               frequencies=ctx.attr("scaling", "") or "default")
 
     def heads_of(x, width):         # [T, h * width] -> [B, h, S, width]
         return x.reshape(B, S, h, width).transpose(0, 2, 1, 3)
@@ -375,6 +381,174 @@ def latent_qkv_grad(ctx, ins, generic):
             "KV@GRAD": [jnp.concatenate([flat(dk[..., :d_n]), flat(dv)],
                                         axis=-1)],
             "KRope@GRAD": [dk_r.astype(dk.dtype).reshape(B * S, d_r)]}
+
+
+def _hc_sizes(ctx):
+    """(streams, Sinkhorn-Knopp iterations, eps, the clamp's two ends)."""
+    return (int(ctx.attr("streams")), int(ctx.attr("iters")),
+            float(ctx.attr("eps")), float(ctx.attr("clamp_min")),
+            float(ctx.attr("clamp_max")))
+
+
+def _hc_report(ctx, part: str, backward: bool) -> None:
+    ctx.report("hyper_connection_lowering_total", part=part,
+               direction="backward" if backward else "forward",
+               streams=int(ctx.attr("streams")), iters=int(ctx.attr("iters")))
+
+
+def _hc_streams(x, n: int):
+    """The ``n`` streams of ``x [T, n * C]``, float32 ``[T, C]`` each: whole
+    lane tiles of the last axis where C is a multiple of 128."""
+    import jax.numpy as jnp
+    C = x.shape[-1] // n
+    return [x[:, j * C:(j + 1) * C].astype(jnp.float32) for j in range(n)]
+
+
+def hyper_connection_coefficients(x, phi, b, alpha, n: int, iters: int,
+                                  eps: float, lo: float, hi: float):
+    """The per-token coefficients of one hyper-connection, float32
+    throughout, as ``[2 n + n^2, T]`` (tokens along the lanes: the twenty
+    normalisations then run over whole registers): from the state ``x [T, n
+    C]``, ``xbar = x / sqrt(mean(x^2) + eps)`` over all ``n C`` values,
+    ``z = xbar Phi`` (the division after the product: one number a token
+    scales the product's ``2 n + n^2`` and not the state's ``n C``, as the
+    mHC report's own kernels order it), rows ``[0, n)`` ``H_pre =
+    sigmoid(alpha_0 z + b)``, rows ``[n, 2 n)`` ``H_post = 2 sigmoid(alpha_1
+    z + b)``, the others ``H_res`` row-major: ``M = exp(clip(alpha_2 z + b,
+    lo, hi))``, then ``iters`` times ``M / (its rows' sums + eps)`` and ``M /
+    (its columns' sums + eps)`` (Sinkhorn-Knopp: ``H_res`` ends doubly
+    stochastic to the iteration's error)."""
+    import jax
+    import jax.numpy as jnp
+    f32 = jnp.float32
+    xf = x.astype(f32)
+    r = jax.lax.rsqrt(jnp.mean(jnp.square(xf), axis=-1) + eps)      # [T]
+    z = jnp.einsum("tk,kc->ct", xf, phi.astype(f32),
+                   precision=jax.lax.Precision.HIGHEST) * r[None, :]
+    b, alpha = b.astype(f32)[:, None], alpha.astype(f32)
+    pre = jax.nn.sigmoid(alpha[0] * z[:n] + b[:n])
+    post = 2.0 * jax.nn.sigmoid(alpha[1] * z[n:2 * n] + b[n:2 * n])
+    m = jnp.exp(jnp.clip(alpha[2] * z[2 * n:] + b[2 * n:], lo, hi))
+
+    def normalise(m, _):            # [row, column, T]
+        m = m / (jnp.sum(m, axis=1, keepdims=True) + eps)
+        return m / (jnp.sum(m, axis=0, keepdims=True) + eps), None
+    # one loop, not its iterations written out: forty reductions over axes
+    # of 4 unrolled were ~170 small fusions a grad op, and as elementwise
+    # chains over the 16 entries XLA's fusion grew them past compiling
+    m, _ = jax.lax.scan(normalise, m.reshape(n, n, -1), None, length=iters)
+    return jnp.concatenate([pre, post, m.reshape(n * n, -1)], axis=0)
+
+
+def _hc_read(x, pre, n: int):
+    """``u = H_pre X``: ``pre [n, T]`` over the streams of ``x [T, n C]``,
+    float32 ``[T, C]``."""
+    return sum(pre[j][:, None] * xj for j, xj in enumerate(_hc_streams(x, n)))
+
+
+def _hc_pre(ctx, x, phi, b, alpha):
+    import jax.numpy as jnp
+    n, iters, eps, lo, hi = _hc_sizes(ctx)
+    coef = hyper_connection_coefficients(x, phi, b, alpha, n, iters, eps, lo,
+                                         hi)
+    return _hc_read(x, coef[:n], n).astype(x.dtype), coef.T
+
+
+def _hc_grad_descs(op, grad_out_map):
+    """A hyper-connection op's grad maker: the generic desc without the
+    forward's outputs, which its grad lowering never reads, so that the
+    Program says what the backward keeps: the op's inputs."""
+    from ..core import registry
+    descs = registry.generic_grad_op_descs(op, grad_out_map)
+    for slot in op.outputs:
+        del descs[0]["inputs"][slot]
+    return descs
+
+
+@register("hyper_connection_pre", grad=_hc_grad_descs)
+def hyper_connection_pre(ctx, ins):
+    """The read side of a manifold-constrained hyper-connection (DeepSeek-AI,
+    mHC, arXiv:2512.24880, over Hyper-Connections, Zhu et al.,
+    arXiv:2409.19606). ``X [T, n C]``: a token's residual state of ``n`` =
+    attr ``streams`` streams of width C side by side (``vec(X)``; stream j
+    the columns ``[j C, (j + 1) C)``); ``Phi [n C, 2 n + n^2]``, ``B [2 n +
+    n^2]``, ``Alpha [3]`` float32. ``Coef [T, 2 n + n^2]`` float32 are the
+    token's ``[H_pre | H_post | H_res row-major]``
+    (``hyper_connection_coefficients``; attrs ``iters``, ``eps``,
+    ``clamp_min``, ``clamp_max``) and ``U [T, C] = H_pre X`` the branch's
+    input, in X's dtype. float32 inside; composed ``jax.numpy``."""
+    _hc_report(ctx, "pre", backward=False)
+    u, coef = _hc_pre(ctx, *(ins[s][0] for s in ("X", "Phi", "B", "Alpha")))
+    return {"U": [u], "Coef": [coef]}
+
+
+@register_grad("hyper_connection_pre")
+def hyper_connection_pre_grad(ctx, ins, generic):
+    """dX, dPhi, dB and dAlpha from X, the three parameters and the two
+    cotangents: the norm, the product, the exponentials and the iterations
+    are computed again (the mHC report's recipe: a kept float32 ``xbar`` is
+    twice X) and differentiated in place; neither ``U`` nor ``Coef`` is
+    read. A cotangent that is absent (a ``Coef`` no write side read) is
+    zero."""
+    import jax
+    import jax.numpy as jnp
+    _hc_report(ctx, "pre", backward=True)
+    x, phi, b, alpha = (ins[s][0] for s in ("X", "Phi", "B", "Alpha"))
+    (u, coef), pullback = jax.vjp(
+        lambda *a: _hc_pre(ctx, *a), x, phi, b, alpha)
+    du, dcoef = (ins.get(s + "@GRAD", [None])[0] for s in ("U", "Coef"))
+    dx, dphi, db, dalpha = pullback((
+        jnp.zeros_like(u) if du is None else du.astype(u.dtype),
+        jnp.zeros_like(coef) if dcoef is None else dcoef.astype(coef.dtype)))
+    return {"X@GRAD": [dx], "Phi@GRAD": [dphi], "B@GRAD": [db],
+            "Alpha@GRAD": [dalpha]}
+
+
+@register("hyper_connection_post", grad=_hc_grad_descs)
+def hyper_connection_post(ctx, ins):
+    """The write side of a hyper-connection: ``Out = H_res X + H_post^T Y``,
+    a stream ``Out_i = sum_j H_res[i, j] X_j + H_post[i] Y``, from ``X [T, n
+    C]``, the branch's output ``Y [T, C]`` and ``Coef [T, 2 n + n^2]``
+    (``hyper_connection_pre``'s); attrs ``streams`` and, for the counter,
+    ``iters``. float32 inside, X's dtype out."""
+    import jax.numpy as jnp
+    _hc_report(ctx, "post", backward=False)
+    x, y, coef = (ins[s][0] for s in ("X", "Y", "Coef"))
+    n = int(ctx.attr("streams"))
+    xs, yf, coef = _hc_streams(x, n), y.astype(jnp.float32), coef.T
+    post, res = coef[n:2 * n], coef[2 * n:]
+    return {"Out": [jnp.concatenate([
+        (post[i][:, None] * yf + sum(
+            res[i * n + j][:, None] * xs[j] for j in range(n))).astype(x.dtype)
+        for i in range(n)], axis=-1)]}
+
+
+@register_grad("hyper_connection_post")
+def hyper_connection_post_grad(ctx, ins, generic):
+    """dX, dY and dCoef in closed form from X, Y, Coef and ``Out@GRAD``
+    (``Out`` is not read): ``dX_j = sum_i H_res[i, j] g_i``, ``dY = sum_i
+    H_post[i] g_i``, ``dH_res[i, j] = g_i . X_j``, ``dH_post[i] = g_i . Y``
+    over the channels, nothing to ``H_pre``'s columns; float32 sums."""
+    import jax.numpy as jnp
+    g = ins.get("Out@GRAD", [None])[0]
+    if g is None:
+        return generic()
+    _hc_report(ctx, "post", backward=True)
+    x, y, coef = (ins[s][0] for s in ("X", "Y", "Coef"))
+    n = int(ctx.attr("streams"))
+    xs, gs, yf = _hc_streams(x, n), _hc_streams(g, n), y.astype(jnp.float32)
+    post, res = coef.T[n:2 * n], coef.T[2 * n:]
+    dx = jnp.concatenate([
+        sum(res[i * n + j][:, None] * gs[i] for i in range(n)).astype(x.dtype)
+        for j in range(n)], axis=-1)
+    dy = sum(post[i][:, None] * gs[i] for i in range(n)).astype(y.dtype)
+    dcoef = jnp.stack(
+        [jnp.zeros(x.shape[:1], jnp.float32)] * n
+        + [jnp.sum(gs[i] * yf, axis=-1) for i in range(n)]
+        + [jnp.sum(gs[i] * xs[j], axis=-1)
+           for i in range(n) for j in range(n)], axis=-1)
+    return {"X@GRAD": [dx], "Y@GRAD": [dy],
+            "Coef@GRAD": [dcoef.astype(coef.dtype)]}
 
 
 @register("swiglu")
@@ -994,7 +1168,7 @@ def _dispatch_grad_exchange(ctx, ins, g, gw, order, slot, n: int):
     return {"X@GRAD": [dx], "Weight@GRAD": [dw]}
 
 
-def grouped_matmul(x, w, count, kernels: bool):
+def grouped_matmul(x, w, count, kernels: bool, tiling=None):
     """``x [A, K]`` rows sorted by group, ``w [G, K, N]``, ``count`` rows a
     group (summing to A) -> ``[A, N]`` in x's dtype: row a times the
     weight of its group, accumulated in float32. With weights for the first
@@ -1002,12 +1176,14 @@ def grouped_matmul(x, w, count, kernels: bool):
     experts), the later groups' rows are not computed and come out zero, in
     the product and in both gradients: megablox's kernels (``kernels``)
     visit the groups they have weights for and zero the rest,
-    ``ragged_dot`` leaves rows beyond its group sizes zero."""
+    ``ragged_dot`` leaves rows beyond its group sizes zero. ``tiling``: the
+    kernels' (m, k, n) tile in ``GMM_TILING``'s place."""
     import jax
     if kernels:
         from jax.experimental.pallas.ops.tpu.megablox import ops as megablox
-        tiling = tuple(min(t, d) for t, d in
-                       zip(GMM_TILING, (x.shape[0], x.shape[1], w.shape[2])))
+        tiling = tuple(min(int(t), d) for t, d in
+                       zip(tiling or GMM_TILING,
+                           (x.shape[0], x.shape[1], w.shape[2])))
         return megablox.gmm(x, w, count, x.dtype, tiling)
     if w.shape[0] < count.shape[0]:
         count = count[:w.shape[0]]
@@ -1028,7 +1204,9 @@ def moe_expert_matmul(ctx, ins):
     name; under a mesh with several devices on it the product runs in an
     island over the axis (``ctx.island``), each device over its own experts
     and the rows it received, ``W``'s split being the one the parameter
-    declares. Which lowering an op took: ``moe_expert_matmul_lowering_total``."""
+    declares. Attr ``tiling`` (``layers.moe_ffn``'s ``matmul_tiling``): the
+    kernels' tile. Which lowering an op took:
+    ``moe_expert_matmul_lowering_total``."""
     from . import pallas_mode
     x, w, count = ins["X"][0], ins["W"][0], ins["Count"][0]
     axis = ctx.attr("expert_axis", "")
@@ -1047,7 +1225,7 @@ def moe_expert_matmul(ctx, ins):
             count = jnp.concatenate(
                 [count, (x.shape[0] - jnp.sum(count)).reshape(1)
                  .astype(count.dtype)])
-        return grouped_matmul(x, w, count, kernels)
+        return grouped_matmul(x, w, count, kernels, ctx.attr("tiling", None))
     return {"Out": [ctx.island(held, (x, w, count), (True, True, True),
                                shards, axis or None)]}
 

@@ -108,7 +108,8 @@ _DEPTH_CUT_CASES = tuple(
     f"test_config_files_resolve[{config}]"
     for config in ("lfm2_8b_a1b", "granite_4_0_h_micro", "laguna_s_2_1",
                    "qwen3_next_80b_a3b", "glm_4_7_flash",
-                   "kimi_linear_48b_a3b", "mellum2_12b_a2_5b", "ouro_2_6b"))
+                   "kimi_linear_48b_a3b", "mellum2_12b_a2_5b", "ouro_2_6b",
+                   "xing4_0_29b_a4b"))
 
 
 def pytest_collection_modifyitems(config, items):

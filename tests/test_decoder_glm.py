@@ -551,7 +551,7 @@ def test_deepseek_style_keys_read_as_the_repos_own():
 
 
 @pytest.mark.parametrize("change,error,match", [
-    ({"rope_scaling": {"type": "yarn", "factor": 40, "mscale": 1.0}},
+    ({"rope_scaling": {"type": "linear", "factor": 4}},
      NotImplementedError, "rope_scaling inside latent attention"),
     ({"partial_rotary_factor": 0.5}, NotImplementedError,
      "partial_rotary_factor other than 1 inside latent"),
