@@ -187,10 +187,14 @@ FAMILIES = {
     # ops/decoder_ops.py:hyper_connection_pre / _post and their registered
     # grads, each lowering once. part: pre (the coefficients and the read) /
     # post (the write); streams: the residual streams a token; iters: the
-    # Sinkhorn-Knopp iterations. All composed jax.numpy until a kernel
-    # gives the family an impl label
+    # Sinkhorn-Knopp iterations; product: how a pre lowering multiplies the
+    # state by Phi, forward and in its grad: pieces (a bfloat16 state: the
+    # float32 operand's bfloat16 pieces side by side, one MXU pass a
+    # product) / highest (any other state: float32 at precision highest),
+    # none on post, which has no product. All composed jax.numpy: no impl
+    # label
     "hyper_connection_lowering_total": (
-        COUNT, ("part", "direction", "streams", "iters"),
+        COUNT, ("part", "direction", "streams", "iters", "product"),
         "hyper-connection ops and grad ops compiled, by side and direction"),
     # amount: a moe_dispatch op's row budget (attr rows), its assignments
     # without one; the sort's output, the grouped products, swiglu and the

@@ -390,10 +390,14 @@ def _hc_sizes(ctx):
             float(ctx.attr("clamp_max")))
 
 
-def _hc_report(ctx, part: str, backward: bool) -> None:
+def _hc_report(ctx, part: str, backward: bool, x=None) -> None:
+    """``x``: the state a read side multiplies by Phi (``product`` says how:
+    ``_phi_product``); the write side has no product."""
     ctx.report("hyper_connection_lowering_total", part=part,
                direction="backward" if backward else "forward",
-               streams=int(ctx.attr("streams")), iters=int(ctx.attr("iters")))
+               streams=int(ctx.attr("streams")), iters=int(ctx.attr("iters")),
+               product="none" if x is None else
+               "pieces" if _bf16_exact(x) else "highest")
 
 
 def _hc_streams(x, n: int):
@@ -404,6 +408,85 @@ def _hc_streams(x, n: int):
     return [x[:, j * C:(j + 1) * C].astype(jnp.float32) for j in range(n)]
 
 
+def _bf16_exact(x) -> bool:
+    import jax.numpy as jnp
+    return x.dtype == jnp.bfloat16
+
+
+def bf16_pieces(a):
+    """A float32 array as three bfloat16 arrays, largest first: each the
+    rounding of what the ones before it left (a remainder is exact in
+    float32). Three hold all 24 bits of a mantissa: they add up to ``a``
+    bit for bit."""
+    import jax.numpy as jnp
+    out = []
+    for _ in range(3):
+        out.append(a.astype(jnp.bfloat16))
+        a = a - out[-1].astype(jnp.float32)
+    return out
+
+
+def _phi_product(xf, phi, exact: bool):
+    """``(X Phi)^T [c, T]`` from the float32 ``xf [T, k]`` and ``phi [k,
+    c]``: a product at precision ``highest``, which on the MXU cuts both
+    operands into three bfloat16 pieces and multiplies the six largest
+    pairs, six passes over c of an array's 128 columns. ``exact``: xf holds
+    a bfloat16 state. It is then its own first piece and has no other, so
+    three pairs are left and they share their left operand: Phi's pieces
+    side by side, 3 c columns, are ONE bfloat16 pass with float32
+    accumulation that keeps every term ``highest`` keeps. Its gradient
+    likewise: ``dPhi = X^T g`` with g's pieces side by side, and ``dX = g
+    Phi^T``, both operands float32, with ``highest``'s own six pairs side by
+    side on the contraction (6 c deep)."""
+    import jax
+    import jax.numpy as jnp
+    f32, bf16, c = jnp.float32, jnp.bfloat16, phi.shape[1]
+    if not exact:
+        return jnp.einsum("tk,kc->ct", xf, phi,
+                          precision=jax.lax.Precision.HIGHEST)
+
+    def side_by_side(a):
+        """bfloat16 ``[3 c, m]``: the pieces of the float32 ``a [c, m]``,
+        one under the other."""
+        return jnp.concatenate(bf16_pieces(a), axis=0)
+
+    def repeated(a, order):
+        """bfloat16 ``[len(order) c, m]``: the pieces ``order`` (0 the
+        largest) of ``a``, one under the other, as a select over a
+        broadcast: a concatenation that names a piece twice XLA lowers as
+        a chain of small updates, one fusion an entry, and the step's
+        memory-space assignment then loses what the layer gains (PERF.md
+        section 6, PR 62)."""
+        hi, mid, lo = (p.astype(f32) for p in bf16_pieces(a))
+        which = jnp.asarray(order)[:, None, None]
+        rows = jnp.where(which == 0, hi, jnp.where(which == 1, mid, lo))
+        return rows.reshape(-1, a.shape[1]).astype(bf16)
+
+    def groups(a):                  # a's groups of c rows added, last first
+        return functools.reduce(jnp.add, jnp.split(a, a.shape[0] // c)[::-1])
+
+    @jax.custom_vjp
+    def product(xf, phi):
+        return groups(jnp.einsum(
+            "tk,ck->ct", xf.astype(bf16), side_by_side(phi.T),
+            preferred_element_type=f32))
+
+    def backward(kept, g):          # g [c, T]
+        xf, phi = kept
+        dphi = groups(jnp.einsum(
+            "tk,ct->ck", xf.astype(bf16), side_by_side(g),
+            preferred_element_type=f32))
+        # highest's six pairs, the smallest first: (lo, hi) (hi, lo)
+        # (mid, mid) (mid, hi) (hi, mid) (hi, hi)
+        dxf = jnp.einsum(
+            "ct,ck->tk", repeated(g, (2, 0, 1, 1, 0, 0)),
+            repeated(phi.T, (0, 2, 1, 0, 1, 0)),
+            preferred_element_type=f32)
+        return dxf, dphi.T
+    product.defvjp(lambda xf, phi: (product(xf, phi), (xf, phi)), backward)
+    return product(xf, phi)
+
+
 def hyper_connection_coefficients(x, phi, b, alpha, n: int, iters: int,
                                   eps: float, lo: float, hi: float):
     """The per-token coefficients of one hyper-connection, float32
@@ -412,19 +495,20 @@ def hyper_connection_coefficients(x, phi, b, alpha, n: int, iters: int,
     C]``, ``xbar = x / sqrt(mean(x^2) + eps)`` over all ``n C`` values,
     ``z = xbar Phi`` (the division after the product: one number a token
     scales the product's ``2 n + n^2`` and not the state's ``n C``, as the
-    mHC report's own kernels order it), rows ``[0, n)`` ``H_pre =
-    sigmoid(alpha_0 z + b)``, rows ``[n, 2 n)`` ``H_post = 2 sigmoid(alpha_1
-    z + b)``, the others ``H_res`` row-major: ``M = exp(clip(alpha_2 z + b,
-    lo, hi))``, then ``iters`` times ``M / (its rows' sums + eps)`` and ``M /
-    (its columns' sums + eps)`` (Sinkhorn-Knopp: ``H_res`` ends doubly
-    stochastic to the iteration's error)."""
+    mHC report's own kernels order it; ``_phi_product``: by bfloat16 pieces
+    under a bfloat16 state, at precision ``highest`` under another), rows
+    ``[0, n)`` ``H_pre = sigmoid(alpha_0 z + b)``, rows ``[n, 2 n)`` ``H_post
+    = 2 sigmoid(alpha_1 z + b)``, the others ``H_res`` row-major: ``M =
+    exp(clip(alpha_2 z + b, lo, hi))``, then ``iters`` times ``M / (its
+    rows' sums + eps)`` and ``M / (its columns' sums + eps)``
+    (Sinkhorn-Knopp: ``H_res`` ends doubly stochastic to the iteration's
+    error)."""
     import jax
     import jax.numpy as jnp
     f32 = jnp.float32
     xf = x.astype(f32)
     r = jax.lax.rsqrt(jnp.mean(jnp.square(xf), axis=-1) + eps)      # [T]
-    z = jnp.einsum("tk,kc->ct", xf, phi.astype(f32),
-                   precision=jax.lax.Precision.HIGHEST) * r[None, :]
+    z = _phi_product(xf, phi.astype(f32), _bf16_exact(x)) * r[None, :]
     b, alpha = b.astype(f32)[:, None], alpha.astype(f32)
     pre = jax.nn.sigmoid(alpha[0] * z[:n] + b[:n])
     post = 2.0 * jax.nn.sigmoid(alpha[1] * z[n:2 * n] + b[n:2 * n])
@@ -477,8 +561,9 @@ def hyper_connection_pre(ctx, ins):
     (``hyper_connection_coefficients``; attrs ``iters``, ``eps``,
     ``clamp_min``, ``clamp_max``) and ``U [T, C] = H_pre X`` the branch's
     input, in X's dtype. float32 inside; composed ``jax.numpy``."""
-    _hc_report(ctx, "pre", backward=False)
-    u, coef = _hc_pre(ctx, *(ins[s][0] for s in ("X", "Phi", "B", "Alpha")))
+    x, phi, b, alpha = (ins[s][0] for s in ("X", "Phi", "B", "Alpha"))
+    _hc_report(ctx, "pre", backward=False, x=x)
+    u, coef = _hc_pre(ctx, x, phi, b, alpha)
     return {"U": [u], "Coef": [coef]}
 
 
@@ -492,8 +577,8 @@ def hyper_connection_pre_grad(ctx, ins, generic):
     zero."""
     import jax
     import jax.numpy as jnp
-    _hc_report(ctx, "pre", backward=True)
     x, phi, b, alpha = (ins[s][0] for s in ("X", "Phi", "B", "Alpha"))
+    _hc_report(ctx, "pre", backward=True, x=x)
     (u, coef), pullback = jax.vjp(
         lambda *a: _hc_pre(ctx, *a), x, phi, b, alpha)
     du, dcoef = (ins.get(s + "@GRAD", [None])[0] for s in ("U", "Coef"))

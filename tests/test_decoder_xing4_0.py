@@ -13,6 +13,7 @@ uncut reference; what the builder still refuses."""
 import json
 import math
 import os
+import re
 
 import jax
 import jax.numpy as jnp
@@ -24,6 +25,7 @@ from paddle_tpu import layers
 from paddle_tpu.core import registry
 from paddle_tpu.models import decoder_lm
 from paddle_tpu.observability import lowerings
+from paddle_tpu.ops import decoder_ops
 from benchmark.references import xing4_0_pretrain as reference
 from tests import lowering_reports
 from tests.test_decoder_ops import close, rng
@@ -172,12 +174,154 @@ def test_the_ops_count_their_lowerings_by_part_and_direction():
     lower("hyper_connection_post_grad",
           {"X": f["X"], "Y": y, "Coef": coef, "Out@GRAD": f["X"]},
           salt=4, program=main)
+    # a bfloat16 state: the read side's products by pieces, both ways
+    half = dict(f, X=jnp.asarray(f["X"], jnp.bfloat16))
+    lower("hyper_connection_pre", half, salt=5, program=main)
+    lower("hyper_connection_pre_grad",
+          {**half, "U@GRAD": y, "Coef@GRAD": None}, salt=6, program=main)
+    lower("hyper_connection_post", {"X": half["X"], "Y": y, "Coef": coef},
+          salt=7, program=main)
     assert lowering_reports.read(
         lowering_reports.publish(main), "hyper_connection_lowering_total",
-        "part", "direction", "streams", "iters") == {
-            ("pre", "forward", "4", "20"): 1, ("post", "forward", "4", "20"): 1,
-            ("pre", "backward", "4", "20"): 1,
-            ("post", "backward", "4", "20"): 1}
+        "part", "direction", "streams", "iters", "product") == {
+            ("pre", "forward", "4", "20", "highest"): 1,
+            ("pre", "backward", "4", "20", "highest"): 1,
+            ("pre", "forward", "4", "20", "pieces"): 1,
+            ("pre", "backward", "4", "20", "pieces"): 1,
+            ("post", "forward", "4", "20", "none"): 2,
+            ("post", "backward", "4", "20", "none"): 1}
+
+
+# -- the product with Phi by bfloat16 pieces ---------------------------------
+
+def whole(pieces):
+    """The pieces added, the smallest first, in float32."""
+    hi, mid, lo = (p.astype(jnp.float32) for p in pieces)
+    return (lo + mid) + hi
+
+
+@pytest.mark.parametrize("scale", [0.02, 1.0, 3e-7, 5e5])
+def test_three_bfloat16_pieces_add_up_to_a_float32_phi_bit_for_bit(scale):
+    phi = jnp.asarray(rng(1).randn(512, K) * scale, jnp.float32)
+    pieces = decoder_ops.bf16_pieces(phi)
+    assert [p.dtype for p in pieces] == [jnp.bfloat16] * 3
+    assert np.array_equal(np.asarray(whole(pieces)), np.asarray(phi))
+    # and each piece is needed: two leave some of the 24 bits out
+    assert not np.array_equal(np.asarray(whole(pieces[:2] + [0 * pieces[2]])),
+                              np.asarray(phi))
+
+
+@pytest.fixture(scope="module")
+def products():
+    """At the cell's contraction (k = 14,336, c = 24; 512 tokens): each of
+    the read side's three products with Phi -- forward ``z``, ``dPhi``,
+    ``dX`` -- as (float64's, precision ``highest``'s, the pieces', and what a
+    product that keeps ONE bfloat16 piece of its float32 operands gives,
+    summed in float64: its error is the dropped pieces')."""
+    T, k = 512, 14336
+    x = jnp.asarray(rng(0).randn(T, k), jnp.bfloat16)
+    phi = jnp.asarray(rng(1).randn(k, K) * 0.02, jnp.float32)
+    g = jnp.asarray(rng(2).randn(K, T), jnp.float32)
+    xf = x.astype(jnp.float32)
+
+    def f64(a):
+        return np.asarray(a.astype(jnp.float32), np.float64)
+
+    def all_three(exact):
+        z, pullback = jax.vjp(
+            lambda a, b: decoder_ops._phi_product(a, b, exact), xf, phi)
+        return (z,) + tuple(pullback(g))[::-1]
+    one = (lambda a: a.astype(jnp.bfloat16))
+    want = (f64(phi).T @ f64(x).T, f64(x).T @ f64(g).T, f64(g).T @ f64(phi).T)
+    dropped = (f64(one(phi)).T @ f64(x).T, f64(x).T @ f64(one(g)).T,
+               f64(one(g)).T @ f64(one(phi)).T)
+    return dict(zip(("z", "dphi", "dx"), zip(
+        want, all_three(False), all_three(True), dropped)))
+
+
+@pytest.mark.parametrize("which", ["z", "dphi", "dx"])
+def test_products_by_pieces_keep_what_highest_keeps(products, which):
+    """The error is the norm of the difference over the float64 product's
+    (the largest entry's error is the tail of 12 k to 7 M roundings and
+    moves by a factor of 2 with the seed and the backend's summation
+    order; the norm does not)."""
+    want, highest, pieces, one_piece = products[which]
+
+    def error(got):
+        got = np.asarray(got, np.float64)
+        assert got.shape == want.shape
+        return np.linalg.norm(got - want) / np.linalg.norm(want)
+    assert 0 < error(highest) < 1e-6
+    assert error(pieces) <= 2 * error(highest)
+    assert error(one_piece) > 50 * error(highest)     # a lost piece shows
+
+
+def dots(kind, state_dtype):
+    """(precision, operand element types) of every ``dot_general`` in the
+    lowered text of one read-side lowering under a state of that dtype."""
+    f = {k: jnp.asarray(v) for k, v in hc_feeds().items()}
+    f["X"] = f["X"].astype(state_dtype)
+    more = {} if kind == "hyper_connection_pre" else {
+        "U@GRAD": jnp.ones((TOKENS, C), state_dtype),
+        "Coef@GRAD": jnp.ones((TOKENS, K), jnp.float32)}
+    text = jax.jit(lambda f: lower(kind, {**f, **more})).lower(f).as_text()
+    found = re.findall(r"stablehlo\.dot_general .*precision = \[(\w+), (\w+)\]"
+                       r" : \(tensor<[\dx]*x(\w+)>, tensor<[\dx]*x(\w+)>\)",
+                       text)
+    assert len(found) == text.count("dot_general") > 0
+    return found
+
+
+@pytest.mark.parametrize("kind,count", [("hyper_connection_pre", 1),
+                                        ("hyper_connection_pre_grad", 3)])
+def test_the_state_s_dtype_alone_chooses_pieces_or_highest(kind, count):
+    assert dots(kind, jnp.bfloat16) == [
+        ("DEFAULT", "DEFAULT", "bf16", "bf16")] * count
+    assert dots(kind, jnp.float32) == [
+        ("HIGHEST", "HIGHEST", "f32", "f32")] * count
+    assert dots(kind, jnp.float16) == [
+        ("HIGHEST", "HIGHEST", "f32", "f32")] * count
+
+
+def test_a_bfloat16_state_s_grads_are_the_float32_state_s():
+    """The same numbers in: the pieces' read side and ``highest``'s give the
+    same coefficients and gradients, to float32 rounding."""
+    f = hc_feeds()
+    du = jnp.asarray(rng(4).randn(TOKENS, C), jnp.bfloat16)
+    dcoef = rng(5).randn(TOKENS, K).astype("float32")
+    half = dict(f, X=jnp.asarray(f["X"], jnp.bfloat16))
+    wide = dict(half, X=half["X"].astype(jnp.float32))
+    got = lower("hyper_connection_pre_grad",
+                {**half, "U@GRAD": du, "Coef@GRAD": dcoef})
+    want = lower("hyper_connection_pre_grad",
+                 {**wide, "U@GRAD": du.astype(jnp.float32),
+                  "Coef@GRAD": dcoef})
+    for slot in ("Phi", "B", "Alpha"):
+        close(got[slot + "@GRAD"][0], want[slot + "@GRAD"][0], 2e-6)
+    assert got["X@GRAD"][0].dtype == jnp.bfloat16
+    close(got["X@GRAD"][0].astype(jnp.float32), want["X@GRAD"][0], 2e-2)
+
+
+def test_the_probe_s_hyper_mode_reads_the_products_of_the_four_lowerings(
+        capsys):
+    """``tools/xing4_0_probe.py hyper`` at the rehearsal's sizes on the CPU
+    (a debug run: its times mean nothing): the read side's lowerings hold
+    one and three products, the write side's none."""
+    from tools import xing4_0_probe
+    assert xing4_0_probe.main(["hyper", "--rehearsal", "--calls", "2"]) == 0
+    got = json.loads(capsys.readouterr().out.strip().splitlines()[-1])
+    rows = got["lowerings"]
+    assert list(rows) == ["pre", "post", "post_grad", "pre_grad"]
+    assert [len(rows[k]["products"]) for k in rows] == [1, 0, 0, 3]
+    for row in rows.values():
+        assert row["ms"] > 0 and row["device_ms"] >= row["product_ms"]
+    assert xing4_0_probe._products(
+        "ENTRY %main (a: f32[2]) -> f32[2] {\n"
+        "  %d = f32[2] dot(%a, %a)\n  %f = f32[2] fusion(%a), calls=%c\n"
+        "  %g = f32[2] fusion(%a), calls=%e\n}\n"
+        "%c (p: f32[2]) -> f32[2] {\n  %x = f32[2] convolution(%p, %p)\n}\n"
+        "%e (p: f32[2]) -> f32[2] {\n  %y = f32[2] add(%p, %p)\n}\n"
+    ) == {"d", "f", "x"}
 
 
 # -- YaRN inside latent_qkv --------------------------------------------------

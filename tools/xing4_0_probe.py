@@ -26,6 +26,7 @@ from __future__ import annotations
 import contextlib
 import functools
 import os
+import re
 import sys
 
 import numpy as np
@@ -117,6 +118,103 @@ def controls(args) -> dict:
     return result
 
 
+def _products(hlo_text: str) -> set:
+    """The instructions of a compiled module that are a ``dot`` or a
+    ``convolution``, or a fusion whose computation holds one."""
+    from paddle_tpu.observability.attribution import parse_hlo_computations
+    comps = parse_hlo_computations(hlo_text)[0]
+
+    def product(i):
+        return i.opcode in ("dot", "convolution")
+    holds = {name for name, body in comps.items() if any(map(product, body))}
+    return {i.name for body in comps.values() for i in body
+            if product(i) or holds & set(
+                re.findall(r"calls=%?([\w.\-]+)", i.rest))}
+
+
+def hyper(args) -> dict:
+    import tempfile
+
+    import jax
+    import jax.numpy as jnp
+    from benchmark import trace
+    from paddle_tpu.core import registry
+    from tools.laguna_probe import _ms
+    cell = load_cell(args)
+    model, p = cell["model"], cell["params"]
+    T, n, C = p["batch"] * p["seq"], model["hc_mult"], model["hidden_size"]
+    K = 2 * n + n * n
+    attrs = {"streams": n, "iters": model["hc_sinkhorn_iters"],
+             "eps": model["hc_eps"],
+             "clamp_min": float(model["mhc_h_res_clamp_min"]),
+             "clamp_max": float(model["mhc_h_res_clamp_max"])}
+    rng = np.random.RandomState(args.seed % (2 ** 31))
+    x = jnp.asarray(rng.randn(T, n * C), jnp.bfloat16)
+    y = jnp.asarray(rng.randn(T, C), jnp.bfloat16)
+    weights = {"Phi": jnp.asarray(rng.randn(n * C, K) * 0.02, jnp.float32),
+               "B": jnp.asarray(rng.randn(K) * model.get("hc_bias_std", 0.0),
+                                jnp.float32),
+               "Alpha": jnp.full((3,), model.get("hc_alpha_init", 0.01),
+                                 jnp.float32)}
+
+    def lowered(kind, slots, operands):
+        """One lowering alone, compiled for these operands."""
+        def fn(*operands):
+            out = registry.get(kind).lower(
+                registry.LowerCtx(dict(attrs)),
+                {s: [v] for s, v in zip(slots, operands)})
+            return tuple(v[0] for v in out.values())
+        fn.__name__ = kind
+        return jax.jit(fn).lower(*operands).compile()
+    read = ("X", "Phi", "B", "Alpha")
+    u, coef = lowered("hyper_connection_pre", read, (x, *weights.values()))(
+        x, *weights.values())
+    calls = {
+        "pre": ("hyper_connection_pre", read, (x, *weights.values())),
+        "post": ("hyper_connection_post", ("X", "Y", "Coef"), (x, y, coef)),
+        "post_grad": ("hyper_connection_post_grad",
+                      ("X", "Y", "Coef", "Out@GRAD"), (x, y, coef, x)),
+        "pre_grad": ("hyper_connection_pre_grad",
+                     read + ("U@GRAD", "Coef@GRAD"),
+                     (x, *weights.values(), u, coef))}
+    result = {"mode": "hyper", "seed": args.seed, "tokens": T, "streams": n,
+              "width": C, "iters": attrs["iters"], "calls": args.calls,
+              "lowerings": {}}
+    for label, (kind, slots, operands) in calls.items():
+        fn = lowered(kind, slots, operands)
+        products = _products(fn.as_text())
+        ms = _ms(fn, *operands)
+        with tempfile.TemporaryDirectory() as d:
+            jax.profiler.start_trace(d)
+            with jax.profiler.TraceAnnotation(trace.WINDOW):
+                for _ in range(args.calls):
+                    out = fn(*operands)
+                jax.block_until_ready(out)
+            jax.profiler.stop_trace()
+            ops = trace.load(trace.newest_xplane(d), args.rehearsal
+                             ).first_device()[trace.OPS_LINE]
+        by = {}
+        for name, a, b in ops:
+            name = trace.instruction(name)
+            by[name] = by.get(name, 0.0) + (b - a) / 1e6 / args.calls
+        inside = {k: v for k, v in by.items() if k in products}
+        row = {"ms": ms,
+               "device_ms": trace.length(trace.union(trace.spans_of(ops)))
+               / 1e6 / args.calls,
+               "product_ms": sum(inside.values()),
+               "products": {k: round(v, 4) for k, v in sorted(
+                   inside.items(), key=lambda kv: -kv[1])},
+               "rest": dict([(k, round(v, 4)) for k, v in sorted(
+                   by.items(), key=lambda kv: -kv[1])
+                   if k not in inside][:8])}
+        result["lowerings"][label] = row
+        say(f"{label}: {ms:.3f} ms a call; on the device "
+            f"{row['device_ms']:.3f} ms, of it {row['product_ms']:.3f} in "
+            f"{len(inside)} product fusions {row['products']}; the rest's "
+            f"largest {row['rest']}")
+    return result
+
+
 def main(argv=None) -> int:
     def options(ap):
         ap.set_defaults(cell=CELL)
@@ -125,11 +223,14 @@ def main(argv=None) -> int:
                              "seeded normal, in hc_bias_std's place")
         ap.add_argument("--controls", nargs="*",
                         help="controls: these of the reference's CONTROLS")
+        ap.add_argument("--calls", type=int, default=10,
+                        help="hyper: calls of each lowering in its capture")
     laguna_probe.load_cell = load_cell      # its modes load the cell by it
     try:
         return laguna_probe.main(
             argv, modes={"load": laguna_probe.held_shares,
-                         "controls": controls}, doc=__doc__, options=options)
+                         "controls": controls, "hyper": hyper},
+            doc=__doc__, options=options)
     finally:
         laguna_probe.load_cell = _laguna_load_cell
 
